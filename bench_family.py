@@ -4,9 +4,9 @@ One family per process (PT_BENCH_FAMILY in {se_resnext, bert, deepfm,
 ssd300}):
 co-resident compiled programs contaminate each other's HBM/timing, so
 bench.py spawns this as a fresh subprocess per family, same as
-bench_resnet.py (methodology in BASELINE.md). Prints ONE JSON line.
+bench_resnet.py. Prints ONE JSON line.
 
-Configs match the BASELINE.md target table:
+Configs:
 - se_resnext: SE-ResNeXt-50 ImageNet-shape b=128 bf16 AMP + momentum
   (reference: benchmark/fluid/models/se_resnext.py); shares ResNet-50's
   >=35% MFU target row, so vs_baseline = MFU / 0.35.
@@ -28,8 +28,7 @@ Configs match the BASELINE.md target table:
 - ssd300: real-scale detection — full VGG16-SSD300 (6 feature maps,
   exactly 8732 priors, 21 classes, 50-row dense-padded gt) b=32 bf16
   AMP + momentum. Metric is images/sec (no committed target; the row
-  validates the dense-padded detection design under load — BASELINE.md
-  "SSD-300 at realistic scale").
+  validates the dense-padded detection design under load).
 """
 
 from __future__ import annotations
@@ -40,9 +39,9 @@ import os
 import numpy as np
 
 from bench_common import (
-    AllBatchesOOM,
     attach_metrics,
     compile_with_oom_backoff,
+    configure_process,
     enable_bench_metrics,
     log,
     measured_mfu,
@@ -100,15 +99,12 @@ def main():
     # metrics-only telemetry: the registry snapshot rides every BENCH
     # row's `metrics` field (PT_BENCH_METRICS=0 opts out)
     enable_bench_metrics()
+    configure_process()
     import jax
-
-    jax.config.update("jax_compilation_cache_dir", "/tmp/pt_jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
     import paddle_tpu as fluid
 
-    log(f"backend: {jax.default_backend()}, devices: {jax.devices()}, "
-        f"family: {FAMILY}")
+    log(f"family: {FAMILY}")
     steps = 30
 
     if FAMILY == "se_resnext":
@@ -134,14 +130,10 @@ def main():
             exe.run(startup)
             return exe
 
-        try:
-            exe, batch = compile_with_oom_backoff(
-                make_exe, lambda e, b: e.run(main_prog, feed=feed(b, 0),
-                                             fetch_list=[model["loss"]]), batch)
-        except AllBatchesOOM:
-            print(json.dumps(attach_metrics({"metric": "se_resnext50_train_images_per_sec", "value": 0,
-                              "unit": "images/sec", "vs_baseline": 0.0})))
-            return
+        exe, batch = compile_with_oom_backoff(
+            make_exe, lambda e, b: e.run(main_prog, feed=feed(b, 0),
+                                         fetch_list=[model["loss"]]),
+            batch)
         feeds = [{k: jax.device_put(v) for k, v in feed(batch, s).items()}
                  for s in range(4)]
         best, mean = run_windows(exe, main_prog, model["loss"], feeds, steps)
@@ -177,17 +169,11 @@ def main():
             exe.run(startup)
             return exe
 
-        try:
-            exe, batch = compile_with_oom_backoff(
-                make_exe,
-                lambda e, b: e.run(main_prog,
-                                   feed=bert.make_batch(cfg, b, seq, seed=0),
-                                   fetch_list=[model["loss"]]), batch)
-        except AllBatchesOOM:
-            print(json.dumps(attach_metrics({"metric": "bert_base_pretrain_tokens_per_sec",
-                              "value": 0, "unit": "tokens/sec",
-                              "vs_baseline": 0.0})))
-            return
+        exe, batch = compile_with_oom_backoff(
+            make_exe,
+            lambda e, b: e.run(main_prog,
+                               feed=bert.make_batch(cfg, b, seq, seed=0),
+                               fetch_list=[model["loss"]]), batch)
         feeds = [{k: jax.device_put(v)
                   for k, v in bert.make_batch(cfg, batch, seq, seed=s).items()}
                  for s in range(4)]
@@ -226,17 +212,12 @@ def main():
             exe.run(startup)
             return exe
 
-        try:
-            exe, batch = compile_with_oom_backoff(
-                make_exe,
-                lambda e, b: e.run(main_prog,
-                                   feed=deepfm.make_batch(cfg, b, seed=0),
-                                   fetch_list=[model["loss"]]), batch,
-                floor=256)
-        except AllBatchesOOM:
-            print(json.dumps(attach_metrics({"metric": "deepfm_train_examples_per_sec",
-                              "value": 0, "unit": "examples/sec"})))
-            return
+        exe, batch = compile_with_oom_backoff(
+            make_exe,
+            lambda e, b: e.run(main_prog,
+                               feed=deepfm.make_batch(cfg, b, seed=0),
+                               fetch_list=[model["loss"]]), batch,
+            floor=256)
         feeds = [{k: jax.device_put(v)
                   for k, v in deepfm.make_batch(cfg, batch, seed=s).items()}
                  for s in range(4)]
@@ -281,15 +262,10 @@ def main():
             exe.run(startup)
             return exe
 
-        try:
-            exe, batch = compile_with_oom_backoff(
-                make_exe, lambda e, b: e.run(main_prog, feed=feed(b, 0),
-                                             fetch_list=[model["loss"]]),
-                batch)
-        except AllBatchesOOM:
-            print(json.dumps(attach_metrics({"metric": "ssd300_train_images_per_sec",
-                              "value": 0, "unit": "images/sec"})))
-            return
+        exe, batch = compile_with_oom_backoff(
+            make_exe, lambda e, b: e.run(main_prog, feed=feed(b, 0),
+                                         fetch_list=[model["loss"]]),
+            batch)
         feeds = [{k: jax.device_put(v) for k, v in feed(batch, s).items()}
                  for s in range(4)]
         best, mean = run_windows(exe, main_prog, model["loss"], feeds,

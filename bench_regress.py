@@ -1,8 +1,8 @@
 """Bench-trajectory regression gate.
 
 Compares a fresh bench row (bench.py's driver-format JSON, headline +
-nested family rows) against the committed BENCH_r*.json history and
-exits nonzero when any family's throughput regressed: a metric fails
+nested family rows) against a history of earlier rounds (``--history``,
+a glob of round files; the repo commits none) and exits nonzero when any family's throughput regressed: a metric fails
 when its ``value_mean`` (falling back to ``value``) drops more than the
 family tolerance below the TRAILING BEST across the history rounds.
 
@@ -17,10 +17,11 @@ reported informationally but never gate: their CPU-vs-TPU variance is
 not a regression signal.
 
 Usage:
-    python bench_regress.py                  # newest BENCH_r*.json vs
-                                             # the earlier rounds
-    python bench_regress.py --row fresh.json # a fresh row vs ALL rounds
-    python bench_regress.py --tolerance 0.2  # loosen every family
+    python bench_regress.py --history 'rounds/r*.json'
+                                   # newest round vs the earlier ones
+    python bench_regress.py --history 'rounds/r*.json' --row fresh.json
+                                   # a fresh row vs ALL rounds
+    python bench_regress.py ... --tolerance 0.2  # loosen every family
 
 ``--row`` accepts either a bare bench row or the driver wrapper
 (``{"parsed": {...}}``). Exit code: 0 = no gated metric regressed,
@@ -37,16 +38,15 @@ import sys
 from typing import Any, Dict, List, Optional, Tuple
 
 # Per-family tolerance: fraction below the trailing best that still
-# passes. 0.10 is the measured round-to-round noise envelope of the
-# committed history (worst healthy ratio: deepfm r05/r04 = 0.979);
-# widen a family here — not globally — when its methodology says so.
+# passes. 0.10 was the round-to-round noise envelope of rounds 1-5
+# (worst healthy ratio: deepfm r05/r04 = 0.979; the round files are no
+# longer in the repo); widen a family here — not globally — when its methodology says so.
 DEFAULT_TOLERANCE = 0.10
 FAMILY_TOLERANCE: Dict[str, float] = {
     # the serving decode loop is host-scheduler-paced (one Python tick
     # per emitted token), so its throughput carries more host jitter
-    # than the compiled train-step families; first appears in r06 and
-    # gates under the union-baseline rules from its first committed
-    # round onward
+    # than the compiled train-step families; gates under the
+    # union-baseline rules from its first history round onward
     "serving_decode_tokens_per_sec": 0.15,
     # the degraded-mode serving row (bench_serving.py: the same sweep
     # under a seeded serve.decode delay fault at 1% of steps) measures
@@ -65,8 +65,8 @@ FAMILY_TOLERANCE: Dict[str, float] = {
 # minimum across history) that still passes. The serving latency
 # riders are host-timed tail percentiles over a small request sample,
 # so they carry far more noise than the throughput means — hence the
-# wide 50% envelope; tighten per-family once the committed history
-# shows a stable floor.
+# wide 50% envelope; tighten per-family once a history shows a
+# stable floor.
 LATENCY_TOLERANCE: Dict[str, float] = {
     "serving_ttft_ms_p95": 0.50,
     "serving_queue_wait_ms_p95": 0.50,
@@ -227,15 +227,13 @@ def check(fresh: Dict[str, Dict[str, Any]],
 
 
 def main(argv=None) -> int:
-    here = os.path.dirname(os.path.abspath(__file__))
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--row", default=None,
                     help="fresh bench row JSON (bare row or driver "
                          "{'parsed': ...} wrapper); default: the newest "
                          "history round, gated against the earlier ones")
-    ap.add_argument("--history", default=os.path.join(here, "BENCH_r*.json"),
-                    help="glob of history rounds (default: the repo's "
-                         "BENCH_r*.json)")
+    ap.add_argument("--history", required=True,
+                    help="glob of history round files")
     ap.add_argument("--tolerance", type=float, default=DEFAULT_TOLERANCE,
                     help="allowed fraction below the trailing best "
                          f"(default {DEFAULT_TOLERANCE})")

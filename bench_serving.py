@@ -47,16 +47,15 @@ arms a temporary persistent compile cache so replicas 2..N spin up
 through the disk-tier warm start (the autoscaler's path) instead of
 recompiling.
 
-CPU-measured caveat: on one shared host every replica's loop thread
-dispatches through the same cores and interpreter lock, so
-``vs_single`` < 1 is EXPECTED here — the throughput multiple is a
-device-parallel signal and must be re-measured on TPU hardware where
-each replica owns its devices. The CPU-valid absorption signal is the
-refusal comparison: the N-replica fleet takes the offered load with
-``shed == 0`` while the fleet of one spins on backpressure
-(``single.shed`` large) for the SAME load.
+Caveat: every replica's loop thread dispatches through the same host
+cores and interpreter lock, and all replicas share one device, so
+``vs_single`` < 1 is EXPECTED — the throughput multiple is a
+device-parallel signal that needs each replica on its own chip. Until
+then the absorption signal is the refusal comparison: the N-replica
+fleet takes the offered load with ``shed == 0`` while the fleet of one
+spins on backpressure (``single.shed`` large) for the SAME load.
 
-Env knobs: ``PT_BENCH_CPU=1`` forces the CPU backend;
+Env knobs (``JAX_PLATFORMS=cpu`` runs it on the CPU):
 ``PT_BENCH_SERVE_SIZE=tiny|base`` picks the model (tiny for CPU smokes);
 ``PT_BENCH_SERVE_SLOTS`` (default 8), ``PT_BENCH_SERVE_SRC`` source
 length (default 32), ``PT_BENCH_SERVE_NEW`` max new tokens per request
@@ -83,14 +82,6 @@ REPLICAS = int(os.environ.get("PT_BENCH_SERVE_REPLICAS", "3"))
 
 def log(msg):
     print(f"[bench_serving] {msg}", file=sys.stderr, flush=True)
-
-
-def _configure_platform():
-    if os.environ.get("PT_BENCH_CPU", "0") != "1":
-        return
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 
 def _cfg():
@@ -249,16 +240,15 @@ def _fleet_level(cfg, scope, replicas, concurrency, n_requests):
 
 
 def main():
-    _configure_platform()
-    import jax
+    from bench_common import configure_process
 
+    configure_process()
     import paddle_tpu as fluid
-    from paddle_tpu import flags, monitor
+    from paddle_tpu import flags, jax_cache, monitor
     from paddle_tpu.models import transformer as T
 
     flags.set_flags({"telemetry": True})
-    log(f"backend: {jax.default_backend()}, size={SIZE}, slots={SLOTS}, "
-        f"src={SRC_LEN}, new={MAX_NEW}")
+    log(f"size={SIZE}, slots={SLOTS}, src={SRC_LEN}, new={MAX_NEW}")
     cfg = _cfg()
     scope = fluid.Scope()
     main_prog, startup = fluid.Program(), fluid.Program()
@@ -305,19 +295,16 @@ def main():
                            if full["tokens_per_sec"] else 0.0),
         }
     # fleet row: N routed replicas vs ONE at the same offered load,
-    # behind a temporary persistent compile cache so replicas 2..N (and
-    # the fleet-of-one rerun) warm-start from disk instead of paying N
-    # fresh XLA compiles
+    # behind a fresh disk-tier compile cache (a fixed, emptied path) so
+    # replicas 2..N (and the fleet-of-one rerun) warm-start from disk
+    # instead of paying N fresh XLA compiles
     fleet_row = None
     if os.environ.get("PT_BENCH_SERVE_FLEET", "1") == "1" and REPLICAS > 1:
-        import shutil
-        import tempfile
-
         conc = REPLICAS * SLOTS
         n_req = 2 * conc
-        cc_dir = tempfile.mkdtemp(prefix="pt_bench_fleet_cc_")
         old_cc = flags.get_flag("compile_cache_dir")
-        flags.set_flags({"compile_cache_dir": cc_dir})
+        flags.set_flags(
+            {"compile_cache_dir": jax_cache.fresh_dir("bench_fleet_cc")})
         try:
             multi = _fleet_level(cfg, scope, REPLICAS, conc, n_req)
             log(f"fleet x{REPLICAS}: {multi}")
@@ -325,7 +312,6 @@ def main():
             log(f"fleet x1 (same offered load): {single}")
         finally:
             flags.set_flags({"compile_cache_dir": old_cc})
-            shutil.rmtree(cc_dir, ignore_errors=True)
         fleet_row = {
             "metric": "serving_fleet_tokens_per_sec",
             "value": multi["tokens_per_sec"],
@@ -337,10 +323,10 @@ def main():
             # both fleet sizes complete the SAME offered load (refusals
             # retried), so the tokens/s ratio is the measured multiple
             # of single-replica sustainable throughput the fleet
-            # absorbs — meaningful on device-parallel hardware; on a
-            # shared CPU host the replicas contend for the same cores,
-            # vs_single < 1 is expected, and the absorption evidence is
-            # shed == 0 here vs single["shed"] backpressure spins
+            # absorbs — meaningful once each replica owns a device; on
+            # a shared one vs_single < 1 is expected, and the absorption
+            # evidence is shed == 0 here vs single["shed"] backpressure
+            # spins
             "vs_single": (round(multi["tokens_per_sec"]
                                 / single["tokens_per_sec"], 3)
                           if single["tokens_per_sec"] else 0.0),

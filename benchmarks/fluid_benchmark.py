@@ -117,8 +117,9 @@ def main():
     if args.device == "cpu":
         jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_num_cpu_devices", 8)
-    jax.config.update("jax_compilation_cache_dir", "/tmp/pt_jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from paddle_tpu import jax_cache
+
+    jax_cache.configure()
 
     import paddle_tpu as fluid
 

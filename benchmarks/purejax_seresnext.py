@@ -33,7 +33,7 @@ def conv(x, w, stride=1, groups=1):
 
 def bn(x, p, name):
     """One-pass E[x],E[x^2] batch-stat BN in affine y=k*x+c form — the
-    same formulation ops/nn_ops.py batch_norm emits (BASELINE.md r3)."""
+    same formulation ops/nn_ops.py batch_norm emits."""
     xf = x.astype(jnp.float32)
     m = jnp.mean(xf, axis=(0, 1, 2))
     m2 = jnp.mean(jnp.square(xf), axis=(0, 1, 2))
@@ -148,7 +148,7 @@ def main():
             p, mom, loss = step(p, mom, img, label)
         jax.block_until_ready(loss)
         dt = (time.perf_counter() - t0) / 30
-        fwd_flops = 8.47e9  # BASELINE.md analytic fwd GFLOP/image
+        fwd_flops = 8.47e9  # analytic fwd GFLOP/image
         mfu = 3 * fwd_flops * B / dt / 197e12
         print(f"window {w}: {dt*1e3:.1f} ms/step  "
               f"{B/dt:.0f} img/s  MFU {mfu:.3f}")

@@ -1,0 +1,578 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+``python chip_smoke.py`` drives the main path once, in ONE process, at
+the full width of Transformer-base (d_model 512, d_inner 2048, 8 heads,
+6+6 layers, vocab 10000; random weights from a seed), through the entry
+points a user calls:
+
+1. kernels — each Pallas attention family compiles with the local
+   libtpu and agrees, forward and backward, with the dense reference at
+   a shape a ROADMAP cell sits on;
+2. train   — ``T.build`` + ``Adam.minimize`` under bf16 AMP, a few
+   ``Executor.run`` steps and one ``Executor.run_steps`` window at
+   b=64 s=256 with dropout 0.1 (no OOM back-off: full batch or fail);
+3. serve   — ``serving.serve`` with 8 slots: eight requests of different
+   source lengths submitted together must each equal their solo greedy
+   decode on the same engine, with zero executor compiles after
+   warm-up;
+4. dp      — where jax reports more than one chip: the same train
+   program under ``CompiledProgram.with_data_parallel`` over all of
+   them, loss parity with the one-chip steps.
+
+It needs a TPU: without one it exits non-zero before doing anything, and
+nothing makes it pass off-chip. A failed check raises, so the exit code
+is 0 only if every phase passed. The last line of stdout is one JSON
+object naming the device. Every time it prints is an observation of this
+run, not a benchmark; it measures no rate.
+
+The phases are importable functions: tests/test_chip_smoke.py drives
+them at a tiny config on the CPU.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPORT_PATH = os.path.join(HERE, "chiprun_out", "chip_smoke.json")
+
+# (expected family, batch, seq len, causal, dropout, backward too?) at
+# h=8, dh=64 — one per ROADMAP cell the family serves, plus the serving
+# prefill shape
+KERNEL_CASES = (
+    ("bthd_small", 64, 256, False, 0.1, True),    # transformer-base train
+    ("bthd_kblock", 8, 1024, True, 0.0, True),    # long-context t1024
+    ("bhtd", 2, 4096, True, 0.0, True),           # long-context t4096
+    ("bthd_small", 1, 32, False, 0.0, False),     # serving prefill
+)
+# max |kernel - reference| over max |reference|: bf16 matmul inputs and a
+# bf16 probability tile against an f32 "highest"-precision reference —
+# five bf16 epsilons (2^-8)
+KERNEL_REL_TOL = 0.02
+# data-parallel loss vs the one-chip loss on the same feeds: same masks,
+# same math, bf16 matmuls tiled and reduced in another order (4e-6 seen
+# on four v5e chips)
+DP_LOSS_REL_TOL = 1e-3
+
+
+class SmokeFailure(AssertionError):
+    """A check of the smoke did not hold."""
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+def transformer_base(**overrides):
+    from paddle_tpu.models import transformer as T
+
+    kw = dict(src_vocab_size=10000, trg_vocab_size=10000, d_model=512,
+              d_inner=2048, n_head=8, n_layer=6)
+    kw.update(overrides)
+    return T.TransformerConfig(**kw)
+
+
+def attention_dispatch():
+    """{(family, pass, shape): traces} — which implementation every
+    attention call traced so far took (pt_attention_dispatch_total)."""
+    from paddle_tpu import monitor
+
+    rows = monitor.snapshot().get("pt_attention_dispatch_total",
+                                  {"values": []})["values"]
+    return {(r["labels"]["family"], r["labels"]["pass"],
+             r["labels"]["shape"]): int(r["value"]) for r in rows}
+
+
+def _dispatch_since(before):
+    now = attention_dispatch()
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if v > before.get(k, 0)}
+
+
+def _cache_misses():
+    from paddle_tpu import monitor
+
+    return int(monitor.counter("pt_executor_cache_misses_total").value())
+
+
+# ---------------------------------------------------------------------------
+# phase 1: kernels
+# ---------------------------------------------------------------------------
+
+def kernel_phase(cases=KERNEL_CASES, h=8, dh=64):
+    """Lower + compile each case with the local toolchain, run it once
+    forward (and backward) and compare with the dense reference under
+    ``default_matmul_precision("highest")``. With dropout the reference
+    is fed the kernels' own masks."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import flash_attention as fa
+
+    out = []
+    for family, b, t, causal, p_drop, backward in cases:
+        got = fa.bthd_family(t, t, h, dh)
+        check(got == family,
+              f"b{b} t{t} h{h} dh{dh}: expected family {family}, the "
+              f"dispatch picked {got}")
+        r = np.random.RandomState(t)
+        q, k, v = (jnp.asarray(r.normal(0, 1, (b, t, h, dh)), jnp.bfloat16)
+                   for _ in range(3))
+        w = jnp.asarray(r.normal(0, 1, (b, t, h, dh)), jnp.float32)
+        # pad-only bias [b, 1, 1, t] as the model feeds it: the last
+        # eighth of the keys is padding
+        pad = np.zeros((b, 1, 1, t), np.float32)
+        pad[..., t - t // 8:] = -1e9
+        bias = jnp.asarray(pad)
+        seed = jnp.asarray(1234, jnp.int32)
+
+        def kernel_loss(q, k, v):
+            o, _ = fa.flash_attention_bthd_with_lse(
+                q, k, v, bias, seed if p_drop else None, None, p_drop,
+                causal)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+
+        masks = (jnp.swapaxes(
+            fa.bthd_dropout_masks(b, t, t, h, dh, p_drop, seed), 1, 2)
+            if p_drop else None)                      # [b, h, tq, tk]
+
+        def reference_loss(q, k, v):
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(dh) + bias
+            if causal:
+                s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            if masks is not None:
+                p = p * masks
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+            return jnp.sum(o * w), o
+
+        def build(fn):
+            if backward:
+                return jax.jit(jax.value_and_grad(fn, (0, 1, 2),
+                                                  has_aux=True))
+            return jax.jit(fn)
+
+        t0 = time.perf_counter()
+        lowered = build(kernel_loss).lower(q, k, v)
+        compiled = lowered.compile()
+        compile_s = time.perf_counter() - t0
+        got_out = jax.block_until_ready(compiled(q, k, v))
+        with jax.default_matmul_precision("highest"):
+            want_out = jax.block_until_ready(build(reference_loss)(q, k, v))
+
+        def flat(res):
+            if backward:
+                (_, o), grads = res
+                return {"out": o, "dq": grads[0], "dk": grads[1],
+                        "dv": grads[2]}
+            return {"out": res[1]}
+
+        errs = {}
+        for name, a in flat(got_out).items():
+            ref = np.asarray(flat(want_out)[name], np.float32)
+            a = np.asarray(a, np.float32)
+            check(np.isfinite(a).all(), f"{family} t{t}: {name} not finite")
+            errs[name] = float(np.abs(a - ref).max()
+                               / max(np.abs(ref).max(), 1e-6))
+            check(errs[name] <= KERNEL_REL_TOL,
+                  f"{family} b{b} t{t}: {name} off the reference by "
+                  f"{errs[name]:.4f} of its max (tolerance "
+                  f"{KERNEL_REL_TOL})")
+        row = {"family": family, "b": b, "t": t, "causal": causal,
+               "p_drop": p_drop, "backward": backward,
+               "compile_s": round(compile_s, 2),
+               "pallas_calls": lowered.as_text().count("tpu_custom_call"),
+               "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+        say(f"  kernel {row}")
+        out.append(row)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# phase 2: train
+# ---------------------------------------------------------------------------
+
+def build_train(cfg):
+    import paddle_tpu as fluid
+    from paddle_tpu.models import transformer as T
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = T.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True  # bf16 matmuls, f32 master weights
+    return main, startup, model["loss"]
+
+
+def _timed_steps(exe, program, feeds, steps, loss, scope):
+    """``steps`` Executor.run calls -> (losses, seconds per call)."""
+    import jax
+
+    losses, secs = [], []
+    for i in range(steps):
+        t0 = time.perf_counter()
+        out = exe.run(program, feed=feeds[i % len(feeds)],
+                      fetch_list=[loss], scope=scope, return_numpy=False)
+        jax.block_until_ready(out)
+        secs.append(time.perf_counter() - t0)
+        losses.append(float(np.asarray(out[0])))
+    return losses, secs
+
+
+def train_phase(cfg, batch=64, seq=256, steps=4, window_steps=8):
+    """Startup, ``steps`` single steps, one compiled ``window_steps``
+    window. Returns losses, first-call seconds per program and the
+    attention dispatch it traced."""
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import transformer as T
+
+    before = attention_dispatch()
+    main, startup, loss = build_train(cfg)
+    scope = fluid.Scope()
+    exe = fluid.Executor()
+    t0 = time.perf_counter()
+    exe.run(startup, scope=scope)
+    jax.block_until_ready([scope.find_var(n) for n in scope.var_names()])
+    startup_s = time.perf_counter() - t0
+
+    feeds = [T.make_batch(cfg, batch, seq, seq, seed=s) for s in range(4)]
+    losses, secs = _timed_steps(exe, main, feeds, steps, loss, scope)
+    t0 = time.perf_counter()
+    out = exe.run_steps(main, feed_list=feeds, steps=window_steps,
+                        fetch_list=[loss], scope=scope, return_numpy=False)
+    jax.block_until_ready(out)
+    window_s = time.perf_counter() - t0
+    window_loss = float(np.asarray(out[0]))
+    exe.close()
+
+    check(all(np.isfinite(x) for x in losses + [window_loss]),
+          f"train loss not finite: steps {losses}, window {window_loss}")
+    check(len(set(losses)) == len(losses) and window_loss != losses[-1],
+          f"train loss is not changing: steps {losses}, window "
+          f"{window_loss}")
+    rep = {
+        "batch": batch, "seq": seq, "n_layer": cfg.n_layer,
+        "executor_device": repr(exe.device),
+        "step_losses": [round(x, 5) for x in losses],
+        "window_steps": window_steps, "window_loss": round(window_loss, 5),
+        "first_call_s": {"startup": round(startup_s, 2),
+                         "step": round(secs[0], 2),
+                         "window": round(window_s, 2)},
+        "later_step_s": [round(s, 4) for s in secs[1:]],
+        "dispatch": {" ".join(k): v
+                     for k, v in sorted(_dispatch_since(before).items())},
+    }
+    say(f"  train {rep}")
+    return rep, losses
+
+
+# ---------------------------------------------------------------------------
+# phase 3: serve
+# ---------------------------------------------------------------------------
+
+def serve_phase(cfg, slots=8, src_len=32, max_len=57, max_new=24,
+                src_lens=(32, 9, 17, 25, 12, 30, 21, 5)):
+    """Requests of ``src_lens`` submitted together to a ``slots``-slot
+    engine must each complete and equal the same request decoded ALONE
+    on that engine, and the engine may not compile after its warm-up
+    request.
+
+    The solo oracle runs on the same geometry, i.e. the same
+    executables: that isolates what continuous batching must guarantee
+    (no slot leaks into a neighbour) from what no TPU guarantees — an
+    engine of another geometry is another XLA program whose bf16-pass
+    matmuls tile differently, and on random weights greedy near-ties
+    then flip (seen on the v5e: 8-slot vs 1-slot streams part at token
+    11). On the CPU in f32 the 1-slot oracle holds too
+    (tests/test_serving.py)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import serving
+    from paddle_tpu.models import transformer as T
+
+    before = attention_dispatch()
+    scope = fluid.Scope()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        T.build(cfg, is_test=True)
+    fluid.Executor().run(startup, scope=scope)
+    r = np.random.RandomState(17)
+    srcs = [r.randint(2, cfg.src_vocab_size, (n,)).astype(np.int64)
+            for n in src_lens]
+
+    eng = serving.serve(cfg, scope, slots=slots, src_len=src_len,
+                        max_len=max_len)
+    t0 = time.perf_counter()
+    warm = eng.submit(srcs[0], max_new_tokens=2)
+    eng.run_until_idle()
+    warm_s = time.perf_counter() - t0
+    check(warm.outcome in ("completed", "length"),
+          f"warm-up request ended '{warm.outcome}'")
+    misses = _cache_misses()
+
+    def decode(group):
+        handles = [eng.submit(s, max_new_tokens=max_new) for s in group]
+        eng.run_until_idle()
+        for h_ in handles:
+            check(h_.outcome in ("completed", "length") and h_.tokens,
+                  f"a request ended '{h_.outcome}' with "
+                  f"{len(h_.tokens)} tokens")
+        return [list(h_.tokens) for h_ in handles]
+
+    solo = [decode([s])[0] for s in srcs]
+    together = decode(srcs)
+    fresh = _cache_misses() - misses
+    eng.close()
+    check(fresh == 0, f"executor compiled {fresh} time(s) after warm-up")
+    for i, (a, b) in enumerate(zip(together, solo)):
+        check(a == b, f"request {i} (src len {src_lens[i]}): batched "
+                      f"stream {a} != solo stream {b}")
+    rep = {
+        "slots": slots, "src_len": src_len, "max_len": max_len,
+        "requests": len(srcs), "tokens": [len(t) for t in together],
+        "equal_to_solo": True, "compiles_after_warmup": fresh,
+        "warmup_s": round(warm_s, 2),
+        "dispatch": {" ".join(k): v
+                     for k, v in sorted(_dispatch_since(before).items())},
+    }
+    say(f"  serve {rep}")
+    return rep
+
+
+# ---------------------------------------------------------------------------
+# phase 4: data parallel over every visible device
+# ---------------------------------------------------------------------------
+
+def dp_phase(cfg, one_chip_losses, batch=64, seq=256):
+    """The train program of phase 2 under with_data_parallel over all
+    devices, same seed and feeds: the losses must match the one-chip
+    steps, state must span the mesh, each feed shard is batch/n."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import transformer as T
+
+    n = len(jax.devices())
+    check(batch % n == 0, f"global batch {batch} does not split over {n}")
+    before = attention_dispatch()
+    main, startup, loss = build_train(cfg)
+    compiled = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name)
+    scope = fluid.Scope()
+    exe = fluid.Executor()
+    exe.run(startup, scope=scope)
+    batch_sharding = NamedSharding(compiled.mesh, P("data"))
+    feeds = []
+    for s in range(4):
+        fd = {k: jax.device_put(v, batch_sharding) for k, v in
+              T.make_batch(cfg, batch, seq, seq, seed=s).items()}
+        for k, a in fd.items():
+            shard = a.addressable_shards[0].data.shape[0]
+            check(shard == batch // n and len(a.addressable_shards) == n,
+                  f"feed {k}: {len(a.addressable_shards)} shards of "
+                  f"{shard} rows, expected {n} of {batch // n}")
+        feeds.append(fd)
+    losses, secs = _timed_steps(exe, compiled, feeds, len(one_chip_losses),
+                                loss, scope)
+    check(all(np.isfinite(x) for x in losses),
+          f"data-parallel loss not finite: {losses}")
+    rel = [abs(a - b) / abs(b) for a, b in zip(losses, one_chip_losses)]
+    check(max(rel) <= DP_LOSS_REL_TOL,
+          f"data-parallel losses {losses} vs one-chip {one_chip_losses}: "
+          f"relative difference {max(rel):.2e} > {DP_LOSS_REL_TOL}")
+    spans = {name: len(scope.find_var(name).sharding.device_set)
+             for name in scope.var_names()}
+    narrow = {k: v for k, v in spans.items() if v != n}
+    check(not narrow, f"state arrays not spanning {n} devices: {narrow}")
+    exe.close()
+    rep = {
+        "devices": n, "global_batch": batch, "feed_shard_rows": batch // n,
+        "step_losses": [round(x, 5) for x in losses],
+        "one_chip_losses": [round(x, 5) for x in one_chip_losses],
+        "max_rel_diff": float(f"{max(rel):.3e}"),
+        "state_arrays": len(spans),
+        "first_step_s": round(secs[0], 2),
+        "device_bytes_in_use": [
+            (d.memory_stats() or {}).get("bytes_in_use")
+            for d in jax.devices()],
+        "dispatch": {" ".join(k): v
+                     for k, v in sorted(_dispatch_since(before).items())},
+    }
+    say(f"  dp {rep}")
+    return rep
+
+
+# ---------------------------------------------------------------------------
+# the chip run
+# ---------------------------------------------------------------------------
+
+class IrDump:
+    """The StableHLO modules jax handed to the compiler
+    (``jax_dump_ir_to``): the compiled programs themselves, not a
+    re-lowering. ``take()`` returns {module file: [operand/result types
+    of each Pallas custom call in it]} for everything dumped since the
+    last call."""
+
+    def __init__(self, path):
+        import jax
+
+        self.path = path
+        self.seen = set()
+        jax.config.update("jax_dump_ir_to", path)
+
+    def take(self):
+        new = sorted(set(os.listdir(self.path)) - self.seen)
+        self.seen.update(new)
+        out = {}
+        for name in new:
+            with open(os.path.join(self.path, name), errors="replace") as f:
+                # "... @tpu_custom_call(...) {config} : (types) -> types loc(..)"
+                out[name] = [
+                    line.rsplit("} : ", 1)[-1].split(" loc(")[0]
+                    for line in f if "@tpu_custom_call" in line]
+        return out
+
+
+def _pallas_calls(modules, fn_name):
+    """(most Pallas custom calls in one dumped module of jitted
+    ``fn_name``, the types of that module's first call)."""
+    calls = max((c for name, c in modules.items()
+                 if f"_jit_{fn_name}_" in name), key=len, default=[])
+    return len(calls), (calls[0] if calls else None)
+
+
+def main() -> int:
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU, but jax found platform "
+              f"'{dev.platform}' ({jax.devices()}); it does not run "
+              f"anywhere else", file=sys.stderr)
+        return 2
+    t_start = time.perf_counter()
+    import importlib.metadata
+
+    import jaxlib
+
+    from paddle_tpu import flags, jax_cache
+    from paddle_tpu.parallel import flash_attention as fa
+
+    n_dev = len(jax.devices())
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": n_dev}
+    versions = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                "libtpu": importlib.metadata.version("libtpu")}
+    cache_dir = jax_cache.configure()
+    say(f"chip_smoke: device {device}, versions {versions}, "
+        f"jax cache {cache_dir}")
+    check(fa._INTERPRET is False,
+          "flash_attention._INTERPRET is set: kernels would not be real")
+    flags.set_flags({"telemetry": True})
+    ir = IrDump(jax_cache.fresh_dir("chip_smoke_ir"))
+    report = {"device": device, "versions": versions,
+              "jax_cache_dir": cache_dir, "phase_s": {}}
+
+    def phase(name, fn, *args, **kw):
+        say(f"[{name}]")
+        t0 = time.perf_counter()
+        out = fn(*args, **kw)
+        report["phase_s"][name] = round(time.perf_counter() - t0, 1)
+        say(f"[{name}] passed in {report['phase_s'][name]} s (observation)")
+        return out, ir.take()
+
+    # 1. kernels: every case really is a Pallas call
+    report["kernels"], _ = phase("kernels", kernel_phase)
+    for row in report["kernels"]:
+        check(row["pallas_calls"] >= 1,
+              f"kernel case {row['family']} t{row['t']} lowered without a "
+              f"Pallas custom call")
+
+    # 2. train: the step and the window contain the kernels, and no
+    # attention call fell to the dense composition
+    cfg = transformer_base(max_length=258, dropout=0.1)
+    (report["train"], losses), mods = phase("train", train_phase, cfg)
+    per_step = 6 * cfg.n_layer  # 3 attentions a layer pair, fwd + bwd
+    n_step, first_call = _pallas_calls(mods, "step_fn")
+    n_window, _ = _pallas_calls(mods, "multi_fn")
+    report["train"]["pallas_calls"] = {
+        "step": n_step, "window": n_window, "first_call": first_call}
+    say(f"  train step module: {n_step} Pallas custom calls (window "
+        f"{n_window}), the first {first_call}")
+    check(n_step >= per_step and n_window >= per_step,
+          f"train step/window modules hold {n_step}/{n_window} Pallas "
+          f"custom calls, expected >= {per_step} each")
+    check(all(k.startswith("bthd_small ") for k in
+              report["train"]["dispatch"]),
+          f"train attention left the BTHD-small kernels: "
+          f"{report['train']['dispatch']}")
+
+    # 3. serve: prefill is a Pallas call at tq=tk=32; decode (tq=1) is
+    # the dense composition BY DESIGN (no kernel family takes one query
+    # row) — said here rather than left silent
+    serve_cfg = transformer_base(max_length=256, dropout=0.0,
+                                 label_smooth_eps=0.0)
+    report["serve"], mods = phase("serve", serve_phase, serve_cfg)
+    n_prefill, first_call = _pallas_calls(mods, "step_fn")
+    report["serve"]["pallas_calls"] = {"prefill": n_prefill,
+                                       "first_call": first_call}
+    disp = report["serve"]["dispatch"]
+    check(any(k.startswith("bthd_small fwd b1 tq32 tk32 ") for k in disp)
+          and n_prefill >= 1,
+          f"serving prefill did not take the Pallas kernel: dispatch "
+          f"{disp}, {n_prefill} custom calls")
+    check(all(" tq1 " in k for k in disp if k.startswith("dense ")),
+          f"a serving attention other than decode went dense: {disp}")
+    say(f"  note: prefill (tq=tk=32) runs the BTHD-small Pallas kernel "
+        f"({n_prefill} custom calls, the first {first_call}); decode "
+        f"attention (tq=1) runs the dense jnp composition by design")
+
+    # 4. every visible chip
+    if n_dev > 1:
+        report["dp"], mods = phase("dp", dp_phase, cfg, losses)
+        n_dp, first_call = _pallas_calls(mods, "step_fn")
+        report["dp"]["pallas_calls"] = {"step": n_dp,
+                                        "first_call": first_call}
+        say(f"  dp step module: {n_dp} Pallas custom calls, per-device "
+            f"operands of the first {first_call}")
+        check(n_dp >= per_step,
+              f"data-parallel step holds {n_dp} Pallas custom calls, "
+              f"expected >= {per_step}")
+        local = f" b{64 // n_dev} "
+        check(all(k.startswith("bthd_small ") and local in k
+                  for k in report["dp"]["dispatch"]),
+              f"data-parallel attention is not per-device batch "
+              f"{64 // n_dev} BTHD-small: {report['dp']['dispatch']}")
+        check(f"<{64 // n_dev}x256x512x" in (first_call or ""),
+              f"the data-parallel attention call's operands are not the "
+              f"per-device batch: {first_call}")
+        check(all(b_ and b_ > 0 for b_ in
+                  report["dp"]["device_bytes_in_use"]),
+              f"a device reports no memory in use: "
+              f"{report['dp']['device_bytes_in_use']}")
+
+    report["total_s"] = round(time.perf_counter() - t_start, 1)
+    os.makedirs(os.path.dirname(REPORT_PATH), exist_ok=True)
+    with open(REPORT_PATH, "w") as f:
+        json.dump(report, f, indent=1)
+    say(f"chip_smoke: all phases passed in {report['total_s']} s "
+        f"(observation); report at {REPORT_PATH}")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -51,10 +51,8 @@ AND the deserialized executable's input avals against the expected
 arguments; any mismatch, read error or deserialization failure degrades
 to a fresh compile — metered, warned, never an abort.
 
-Fallback tier: when the flag is set, jax's own persistent compilation
-cache is additionally pointed at ``<dir>/xla`` (unless the user already
-configured one), so even entries this module cannot serialize skip the
-XLA backend work on a recompile (tracing is still paid on that path).
+jax's own persistent compilation cache is a separate tier this module
+never touches: ``paddle_tpu.jax_cache`` places it.
 
 Cache files are pickles and therefore as trusted as the directory they
 live in — point ``compile_cache_dir`` only at directories you own, same
@@ -78,6 +76,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
+from jax.experimental import serialize_executable as _se
 
 from paddle_tpu import faults as _faults
 from paddle_tpu import flags as _flags
@@ -118,57 +117,17 @@ _M_EVICTIONS = _monitor.counter(
 _F_LOAD = _faults.site("ccache.load")
 _F_STORE = _faults.site("ccache.store")
 
-try:
-    from jax.experimental import serialize_executable as _se
-
-    _HAVE_SERIALIZE = hasattr(_se, "serialize") and hasattr(
-        _se, "deserialize_and_load")
-except Exception:  # pragma: no cover - jax without the experimental API
-    _se = None
-    _HAVE_SERIALIZE = False
-
 
 # --------------------------------------------------------------------------
 # flag plumbing (cached-hot-flag pattern, monitor.py)
 # --------------------------------------------------------------------------
 
 _dir = ""
-_xla_fallback: Optional[str] = None
-
-
-def _enable_xla_fallback(dirpath: str):
-    """Point jax's persistent compilation cache at ``<dir>/xla`` so the
-    entries this module cannot serialize still skip XLA backend work on
-    recompile. Never overrides a cache dir the user configured (e.g.
-    tests/conftest.py, bench.py)."""
-    global _xla_fallback
-    try:
-        cur = jax.config.jax_compilation_cache_dir
-        if cur and cur != _xla_fallback:
-            return
-        target = os.path.join(dirpath, "xla")
-        os.makedirs(target, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", target)
-        _xla_fallback = target
-    except Exception:
-        pass  # fallback tier is strictly best-effort
 
 
 def _sync_dir(v):
-    global _dir, _xla_fallback
+    global _dir
     _dir = str(v or "")
-    if _dir:
-        _enable_xla_fallback(_dir)
-    elif _xla_fallback is not None:
-        # flag cleared: release the fallback tier too, or every later
-        # XLA compile keeps writing into the now-disabled (possibly
-        # deleted temp) directory. Never touches a dir the user set.
-        try:
-            if jax.config.jax_compilation_cache_dir == _xla_fallback:
-                jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
-        _xla_fallback = None
 
 
 _max_bytes = 0
@@ -433,7 +392,7 @@ def executor_spec(program, *, feed_vals, fetch_names, scope, base_key,
     (multi-host run, non-portable fingerprint, uninitialized state).
     Called only on a level-1 miss, so its cost is irrelevant next to the
     compile it replaces."""
-    if not _dir or not _HAVE_SERIALIZE:
+    if not _dir:
         return None
     if fingerprint.startswith("local-"):
         return None  # content not canonical -> not portable across procs
@@ -734,8 +693,6 @@ def stats() -> Dict[str, Any]:
     """Operator-facing snapshot (debugging, tests)."""
     return {
         "dir": _dir,
-        "serializer": _HAVE_SERIALIZE,
-        "xla_fallback": _xla_fallback,
         "hits": _M_HITS.value(),
         "misses": _M_MISSES.value(),
         "evictions": _M_EVICTIONS.value(),

@@ -16,7 +16,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from paddle_tpu.framework import Program
 
@@ -72,13 +72,18 @@ class CompiledProgram:
         places=None,
         devices=None,
     ) -> "CompiledProgram":
-        """Data-parallel over all visible devices (or ``devices``)."""
+        """Data-parallel over all visible devices (or ``devices``): a
+        one-axis mesh under a rule-less strategy (batch sharded, every
+        parameter replicated)."""
+        from paddle_tpu.parallel.strategy import DistributedStrategy
+
         self._data_parallel = True
         self._loss_name = loss_name
         self.build_strategy = build_strategy or BuildStrategy()
         self.exec_strategy = exec_strategy or ExecutionStrategy()
         devs = devices if devices is not None else jax.devices()
         self._mesh = Mesh(np.asarray(devs), ("data",))
+        self._strategy = DistributedStrategy(self._mesh, data_axis="data")
         return self
 
     def with_strategy(self, strategy) -> "CompiledProgram":
@@ -110,15 +115,31 @@ class CompiledProgram:
         fn(state, feeds, key) -> (fetches, new_state)."""
         if not self._data_parallel or self._mesh is None:
             return None, None
-        repl = NamedSharding(self._mesh, P())
-        if self._strategy is None:
-            return (repl, self._batch_sharding(), repl), (repl, repl)
         st = self._strategy
         state_in = {n: st.sharding_for(n) for n in lowered.state_in_names}
         state_out = {n: st.sharding_for(n) for n in lowered.state_out_names}
         in_shardings = (state_in, self._batch_sharding(), st.replicated())
         out_shardings = (st.replicated(), state_out)
         return in_shardings, out_shardings
+
+    def commit_state(self, scope, state):
+        """Place state that lives off the mesh — fresh from a startup
+        program, or read-only, so no step ever returns it re-sharded —
+        on its sharding, in the scope too: jit would otherwise
+        re-broadcast it from one device on every step. Called once per
+        compiled entry (its first run). Multi-host jobs keep their
+        host-replicated state (see shard_inputs)."""
+        if self._mesh is None or jax.process_count() > 1:
+            return state
+        placed = {}
+        for n, v in state.items():
+            sh = self._strategy.sharding_for(n)
+            if not (isinstance(v, jax.Array)
+                    and v.sharding.is_equivalent_to(sh, v.ndim)):
+                v = jax.device_put(v, sh)
+                scope.set(n, v)
+            placed[n] = v
+        return placed
 
     def shard_inputs(self, state, feeds):
         """Pre-place inputs; jit's in_shardings handles the real placement.
@@ -146,6 +167,4 @@ class CompiledProgram:
     def _batch_sharding(self):
         """Feed sharding — single source for shardings() and
         shard_inputs(), which must agree on placement."""
-        if self._strategy is not None:
-            return self._strategy.batch_sharding()
-        return NamedSharding(self._mesh, P("data"))
+        return self._strategy.batch_sharding()

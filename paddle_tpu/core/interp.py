@@ -97,12 +97,12 @@ def set_amp_active(flag: bool):
     return _AMP_ACTIVE.set(bool(flag))
 
 
-# SPMD context for ops that need explicit shard_map collectives (ring
-# attention over a context axis, psum-sharded embedding tables, expert-
-# parallel MoE all_to_all dispatch) rather than relying on GSPMD
-# propagation. Set by the Executor while tracing a program compiled with a
-# DistributedStrategy that declares those axes; kernels read it at trace
-# time. An ``SpmdCtx`` or None.
+# SPMD context for ops that need an explicit shard_map rather than GSPMD
+# propagation: collectives (ring attention over a context axis,
+# psum-sharded embedding tables, expert-parallel MoE all_to_all dispatch)
+# and Pallas kernels, which GSPMD cannot partition at all. Set by the
+# Executor while tracing a program compiled for a mesh; kernels read it
+# at trace time. An ``SpmdCtx`` or None (single device).
 SpmdCtx = collections.namedtuple(
     "SpmdCtx", ["mesh", "context_axis", "table_axis", "data_axis",
                 "expert_axis", "pipe_axis", "pipe_micro"]
@@ -124,15 +124,11 @@ def set_spmd_ctx(ctx):
 @contextlib.contextmanager
 def spmd_ctx_scope(strategy):
     """Activate a DistributedStrategy's SPMD context (ring attention /
-    sharded tables / expert-parallel MoE) for the enclosed trace. The
-    single place that builds the context — kernels read fields by name."""
+    sharded tables / expert-parallel MoE / mesh-wrapped Pallas kernels)
+    for the enclosed trace. The single place that builds the context —
+    kernels read fields by name."""
     ctx = None
-    if strategy is not None and (
-        strategy.context_axis
-        or strategy.table_axis
-        or getattr(strategy, "expert_axis", None)
-        or getattr(strategy, "pipe_axis", None)
-    ):
+    if strategy is not None:
         # Multi-slice: the batch axis kernels see is the COMPOSED
         # (slice, data) tuple so shard_map specs and collective axis
         # lists span both — the batch is sharded over their product

@@ -154,8 +154,8 @@ _DEFS: Dict[str, tuple] = {
     "device_profile_xplane": (bool, False,
                               "capture + parse xplane around sampled "
                               "steps"),
-    # roofline peaks: override the backend table (roofline.BACKEND_PEAKS)
-    # when the attached device differs from the defaults; 0 = auto
+    # roofline peaks: override the device table (roofline.DEVICE_PEAKS);
+    # 0 = the table's row for the attached device_kind
     "device_peak_flops": (float, 0.0,
                           "peak device FLOP/s for roofline verdicts "
                           "(0 = backend default)"),
@@ -167,8 +167,7 @@ _DEFS: Dict[str, tuple] = {
     # AOT executables resolved from this directory BEFORE tracing, so a
     # fresh process warm-starts a known program in seconds instead of
     # minutes; entries are keyed by a canonical content fingerprint +
-    # environment token and written atomically. Also points jax's own
-    # persistent compilation cache at <dir>/xla as a fallback tier.
+    # environment token and written atomically.
     # Empty = disabled (the executor hot path is one boolean check).
     "compile_cache_dir": (str, "", "persistent compile-cache directory"),
     # disk budget for compile_cache_dir: after each store the cache runs

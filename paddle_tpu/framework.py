@@ -17,6 +17,7 @@ OpDescs into blocks of a serializable Program — but:
 
 from __future__ import annotations
 
+import contextvars
 import hashlib
 
 from typing import Any, Dict, List, Optional, Sequence
@@ -35,6 +36,16 @@ from paddle_tpu.proto import framework_pb2 as pb
 # Sentinel used to stand in for a symbolic (-1) batch dim during abstract
 # shape inference. Prime and unlikely to appear as a real static dim.
 _BATCH_SENTINEL = 997
+
+# True while an op's compute function runs under jax.eval_shape for
+# build-time shape inference. That is not a trace that gets compiled, so
+# trace-time instruments (pt_attention_dispatch_total) skip it.
+_SHAPE_INFERENCE: contextvars.ContextVar = contextvars.ContextVar(
+    "paddle_tpu_shape_inference", default=False)
+
+
+def in_shape_inference() -> bool:
+    return _SHAPE_INFERENCE.get()
 
 
 def grad_var_name(name: str) -> str:
@@ -499,9 +510,13 @@ def infer_op_outputs(block: "Block", op: Operator):
         if opdef.needs_rng:
             kwargs["rng"] = jax.random.PRNGKey(0)
 
-        outs = jax.eval_shape(
-            lambda i: opdef.compute(i, dict(op.attrs), **kwargs), ins
-        )
+        tok = _SHAPE_INFERENCE.set(True)
+        try:
+            outs = jax.eval_shape(
+                lambda i: opdef.compute(i, dict(op.attrs), **kwargs), ins
+            )
+        finally:
+            _SHAPE_INFERENCE.reset(tok)
         return outs, None
     except Exception as e:
         # the message carries the real diagnostic (broadcast shapes,
@@ -764,19 +779,49 @@ def name_scope(prefix: str):
     yield
 
 
-# Simple device "places" for API parity (reference: platform/place.h:79).
-# Actual placement is JAX device assignment; these select default device kind.
+# Device "places" (reference: platform/place.h:79). Programs run on jax's
+# default backend; a place NAMES the platform the caller expects, and
+# ``resolve_place`` refuses a place the process cannot honor instead of
+# running somewhere else under its label.
 class CPUPlace:
+    platform = "cpu"
+
     def __repr__(self):
         return "CPUPlace"
 
 
 class TPUPlace:
+    platform = "tpu"
+
     def __init__(self, device_id: int = 0):
         self.device_id = device_id
 
     def __repr__(self):
         return f"TPUPlace({self.device_id})"
+
+
+def resolve_place(place=None):
+    """-> (place, jax device) an Executor runs on. ``None`` takes jax's
+    default device and reports what that is; an explicit place whose
+    platform is not jax's default backend raises."""
+    import jax
+
+    if place is None:
+        dev = jax.devices()[0]
+        return (TPUPlace(dev.id) if dev.platform == "tpu"
+                else CPUPlace()), dev
+    backend = jax.default_backend()
+    if place.platform != backend:
+        raise RuntimeError(
+            f"{place!r} requested but jax's default backend is "
+            f"'{backend}' (devices: {jax.devices()}); pass no place to run "
+            f"on the default device, or select the platform with "
+            f"JAX_PLATFORMS")
+    if getattr(place, "device_id", 0) != 0:
+        raise NotImplementedError(
+            f"{place!r}: an Executor drives jax's default device (index "
+            f"0); use CompiledProgram to span several devices")
+    return place, jax.devices()[0]
 
 
 # Alias so reference-style `fluid.CUDAPlace(0)` code keeps working on TPU.

@@ -19,7 +19,7 @@ import numpy as np
 
 from paddle_tpu import io as _io
 from paddle_tpu.executor import Executor, Scope, scope_guard
-from paddle_tpu.framework import CPUPlace, TPUPlace
+from paddle_tpu.framework import CPUPlace
 
 
 class Config:
@@ -76,9 +76,10 @@ class Predictor:
         self._config = config
         self._closed = False
         self.scope = Scope()
-        self._exe = Executor(
-            TPUPlace(0) if config._use_tpu else CPUPlace()
-        )
+        # default: jax's default device, whatever it is; disable_tpu()
+        # asks for the CPU backend by name (Executor refuses a place the
+        # process cannot honor)
+        self._exe = Executor(None if config._use_tpu else CPUPlace())
         with scope_guard(self.scope):
             if os.path.exists(os.path.join(config.model_dir,
                                            "__params_int8__.npz")):

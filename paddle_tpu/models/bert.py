@@ -1,4 +1,4 @@
-"""BERT-base pretraining model (BASELINE.md row "BERT-base pretraining").
+"""BERT-base pretraining model (the "BERT-base pretraining" bench row).
 
 Encoder-only transformer with masked-LM + next-sentence heads. Reuses the
 flagship transformer's encoder layer (models/transformer.py — fused QKV

@@ -1,4 +1,4 @@
-"""DeepFM CTR model (sparse-embedding benchmark config, BASELINE.md).
+"""DeepFM CTR model (sparse-embedding benchmark config).
 
 The capability twin of the reference's distributed-lookup-table CTR path:
 sparse feature embeddings served by row-sharded tables (reference:

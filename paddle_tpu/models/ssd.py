@@ -140,7 +140,7 @@ def ssd300_net(img, num_classes=21):
 def get_ssd300_model(num_classes=21, gt_capacity=50):
     """Real-scale SSD-300 training graph (8732 priors, VOC-sized class
     count, 50-row dense-padded gt) — the load-scale validation of the
-    dense-padded detection design (BASELINE.md detection row)."""
+    dense-padded detection design (the detection bench row)."""
     img = layers.data("image", shape=[3, 300, 300], dtype="float32")
     gt_box = layers.data("gt_box", shape=[gt_capacity, 4], dtype="float32")
     gt_label = layers.data("gt_label", shape=[gt_capacity], dtype="int64")

@@ -24,16 +24,18 @@ def _load() -> ctypes.CDLL:
     if _lib is not None:
         return _lib
     # Always invoke make: its dependency check rebuilds when csrc/ changed
-    # and is a no-op otherwise (the .so is never committed; see .gitignore).
+    # and is a no-op otherwise (the .so is never committed; see
+    # .gitignore). A failed build is an error even when an older .so is
+    # lying around: loading it would run code built from other sources.
     try:
         subprocess.run(
             ["make", "-C", os.path.abspath(_CSRC)],
-            check=True,
-            capture_output=True,
+            check=True, capture_output=True, text=True,
         )
-    except (subprocess.CalledProcessError, FileNotFoundError):
-        if not os.path.exists(_LIB_PATH):
-            raise
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {_LIB_PATH} failed (make exit {e.returncode}):\n"
+            f"{e.stderr[-2000:]}") from e
     lib = ctypes.CDLL(_LIB_PATH)
     # recordio
     lib.rio_writer_open.restype = ctypes.c_void_p
@@ -99,10 +101,13 @@ def _load() -> ctypes.CDLL:
 
 
 def available() -> bool:
+    """Whether the native runtime can be used here: False without a
+    toolchain (no ``make``) or a loadable library. A build that FAILS
+    raises — broken sources are not "unavailable"."""
     try:
         _load()
         return True
-    except Exception:
+    except OSError:  # FileNotFoundError: no make; OSError: CDLL refused
         return False
 
 

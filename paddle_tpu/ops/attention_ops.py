@@ -50,13 +50,18 @@ def _attn_bias(ins, attrs):
 
 
 def _sdpa_config(ins, attrs, rng):
-    """Shared fwd/grad config: (scale, p_drop, seed, use_pallas).
+    """Shared fwd/grad config: (scale, p_drop, seed, family, dims).
 
     The grad op's rng is folded with the SAME forward_op_idx as the
     forward's (core/lowering.py), so the derived dropout seed — and hence
-    the in-kernel mask — is identical in both directions.
+    the in-kernel mask — is identical in both directions. ``family`` is
+    the Pallas kernel family the shapes take on this backend, or "dense"
+    for the jnp composition (parallel/flash_attention.py); ``dims`` is
+    (b, tq, tk, h, dh), the dispatch record's shape.
     """
-    q = _x(ins, "Q")
+    from paddle_tpu.parallel import flash_attention as fa
+
+    q, k = _x(ins, "Q"), _x(ins, "K")
     scale = attrs.get("scale", None)
     if scale is None:
         scale = 1.0 / math.sqrt(jnp.shape(q)[-1])
@@ -67,11 +72,67 @@ def _sdpa_config(ins, attrs, rng):
     if training_dropout:
         drop = float(p_drop)
         seed = jax.random.randint(rng, (), 0, 2**31 - 1, dtype=jnp.int32)
-    use_pallas = (
-        jax.default_backend() == "tpu"
-        and attrs.get("use_pallas", True)
-    )
-    return scale, drop, seed, use_pallas
+    if attrs.get("layout", "bhtd") == "bthd":
+        b, tq, h, dh = q.shape
+        tk = k.shape[1]
+        family = fa.bthd_family(tq, tk, h, dh)
+    else:
+        b, h, tq, dh = q.shape
+        tk = k.shape[2]
+        family = fa.bhtd_family(h, tq, tk)
+    if not attrs.get("use_pallas", True):
+        family = "dense"
+    return scale, drop, seed, family, (b, tq, tk, h, dh)
+
+
+def _on_mesh(kernel, arrays, seed):
+    """``kernel(*arrays, seed)`` — a Pallas attention call whose array
+    arguments (None allowed) and results all lead with the batch dim —
+    under the program's mesh. GSPMD cannot partition a Mosaic kernel
+    (jax refuses to lower one in a multi-device jit), so under a mesh
+    the call is a shard_map: the batch splits over the data axis and
+    every other axis computes replicas. Each shard hands the kernels its
+    first GLOBAL batch row along with the seed, so the dropout masks do
+    not depend on how many devices split the batch."""
+    from paddle_tpu.core.interp import spmd_ctx
+
+    ctx = spmd_ctx()
+    if ctx is None:
+        return kernel(*arrays, seed)
+    mesh = ctx.mesh
+    # axes an enclosing shard_map (a GPipe stage) already made manual
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    free = frozenset(a for a in mesh.axis_names if a not in manual)
+    if not free:
+        return kernel(*arrays, seed)
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel.mesh import axis_size, axis_tuple
+
+    b = arrays[0].shape[0]
+    data = axis_tuple(ctx.data_axis) if ctx.data_axis else ()
+    axis = tuple(a for a in data if a in free)  # batch axes to split here
+    n = axis_size(mesh, axis)
+    if b % n != 0:
+        axis, n = (), 1  # replicate rather than shard unevenly
+    batch = P(axis) if axis else P()
+    present = [a for a in arrays if a is not None]
+    # a [1, ...] bias broadcasts over the batch: it stays replicated
+    specs = [batch if a.shape[0] == b else P() for a in present]
+    if seed is None:
+        seed = jnp.zeros((), jnp.int32)
+
+    def local(seed, *xs):
+        it = iter(xs)
+        full = [None if a is None else next(it) for a in arrays]
+        row0 = jax.lax.axis_index(axis) * (b // n) if axis else 0
+        return kernel(*full, jnp.stack(
+            [seed.astype(jnp.int32), jnp.asarray(row0, jnp.int32)]))
+
+    # (nested in a manual region, the mesh is the context's)
+    return jax.shard_map(local, mesh=None if manual else mesh,
+                         in_specs=(P(), *specs), out_specs=batch,
+                         axis_names=free)(seed, *present)
 
 
 def _ring_config_t(q, k, t_axis=2):
@@ -127,7 +188,7 @@ def _sdpa(ins, attrs, rng=None):
     """
     q, k, v = _x(ins, "Q"), _x(ins, "K"), _x(ins, "V")
     bias = _x(ins, "Bias")
-    scale, drop, seed, use_pallas = _sdpa_config(ins, attrs, rng)
+    scale, drop, seed, family, dims = _sdpa_config(ins, attrs, rng)
     bthd = attrs.get("layout", "bhtd") == "bthd"
     causal = bool(attrs.get("causal", False))
     from paddle_tpu.parallel import flash_attention as fa
@@ -151,29 +212,32 @@ def _sdpa(ins, attrs, rng=None):
                                     data_axis=data_axis, causal=causal,
                                     p_drop=float(drop), seed=seed)
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
-    elif bthd:
-        if use_pallas:
-            out, lse = fa.flash_attention_bthd_with_lse(
-                q, k, v, bias, seed, scale, float(drop), causal)
-        else:
+    elif family == "dense":  # (the kernel entries record their own)
+        fa.note_dispatch("dense", "fwd", *dims)
+        sd = seed if drop > 0.0 else None
+        if bthd:
             out = fa._reference_attention_bthd(
                 q, k, v,
                 fa._combined_causal_bias(bias, q.shape[1], k.shape[1])
                 if causal else bias,
-                scale, drop, seed if drop > 0.0 else None)
-            lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
-    elif use_pallas:
+                scale, drop, sd)
+        else:
+            out = fa._reference_attention(q, k, v, bias, scale, drop, sd,
+                                          causal=causal)
+        lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
+    elif bthd:
+        out, lse = _on_mesh(
+            lambda q, k, v, bias, seed: fa.flash_attention_bthd_with_lse(
+                q, k, v, bias, seed, scale, float(drop), causal),
+            (q, k, v, bias), seed)
+    else:
         # the custom-vjp wrapper makes the op differentiable through
         # jax.vjp too (scan-over-layers grad); the paired grad op below
         # remains the unrolled path's backward
-        out, lse = fa.flash_attention_with_lse(q, k, v, bias, seed,
-                                               scale, float(drop),
-                                               causal=causal)
-    else:
-        out = fa._reference_attention(q, k, v, bias, scale, drop,
-                                      seed if drop > 0.0 else None,
-                                      causal=causal)
-        lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
+        out, lse = _on_mesh(
+            lambda q, k, v, bias, seed: fa.flash_attention_with_lse(
+                q, k, v, bias, seed, scale, float(drop), causal=causal),
+            (q, k, v, bias), seed)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -187,7 +251,7 @@ def _sdpa_grad(ins, attrs, rng=None):
     bias = _x(ins, "Bias")
     out, lse = _x(ins, "Out"), _x(ins, "Lse")
     g = _x(ins, "GRAD::Out")
-    scale, drop, seed, use_pallas = _sdpa_config(ins, attrs, rng)
+    scale, drop, seed, family, dims = _sdpa_config(ins, attrs, rng)
     bthd = attrs.get("layout", "bhtd") == "bthd"
     causal = bool(attrs.get("causal", False))
     from paddle_tpu.parallel import flash_attention as fa
@@ -214,36 +278,30 @@ def _sdpa_grad(ins, attrs, rng=None):
 
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
-    elif bthd:
-        if use_pallas:
-            dq, dk, dv = fa.flash_attention_bthd_bwd(
-                q, k, v, bias, seed, out, lse, g.astype(q.dtype),
-                scale=scale, p_drop=drop, causal=causal)
-        else:
-            sd = seed if drop > 0.0 else None
+    elif family == "dense":
+        fa.note_dispatch("dense", "bwd", *dims)
+        sd = seed if drop > 0.0 else None
+        if bthd:
             eff_bias = fa._combined_causal_bias(
                 bias, q.shape[1], k.shape[1]) if causal else bias
 
             def f(q, k, v):
                 return fa._reference_attention_bthd(
                     q, k, v, eff_bias, scale, drop, sd).astype(q.dtype)
-
-            _, vjp = jax.vjp(f, q, k, v)
-            dq, dk, dv = vjp(g.astype(q.dtype))
-    elif use_pallas:
-        # gates internally between the blocked Pallas kernels and a vjp of
-        # the same dense composition the forward used — one source of truth
-        # for masks and fallback conditions
-        dq, dk, dv = fa.flash_attention_bwd(
-            q, k, v, bias, seed, out, lse, g.astype(q.dtype),
-            scale=scale, p_drop=drop, causal=causal)
-    else:
-        sd = seed if drop > 0.0 else None
-
-        def f(q, k, v):
-            return fa._reference_attention(q, k, v, bias, scale, drop,
-                                           sd, causal=causal).astype(q.dtype)
+        else:
+            def f(q, k, v):
+                return fa._reference_attention(
+                    q, k, v, bias, scale, drop, sd,
+                    causal=causal).astype(q.dtype)
 
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
+    else:
+        bwd = (fa.flash_attention_bthd_bwd if bthd
+               else fa.flash_attention_bwd)
+        dq, dk, dv = _on_mesh(
+            lambda q, k, v, bias, out, lse, g, seed: bwd(
+                q, k, v, bias, seed, out, lse, g, scale=scale,
+                p_drop=drop, causal=causal),
+            (q, k, v, bias, out, lse, g.astype(q.dtype)), seed)
     return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

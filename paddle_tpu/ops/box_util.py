@@ -36,7 +36,7 @@ def greedy_bipartite_match(dist):
 
     The loop is inherently sequential; a device While at realistic
     scale (m=50 gt, n=8732 priors, b=32) measured ~80 ms/step of
-    per-iteration overhead (BASELINE.md SSD-300 trace), so small static
+    per-iteration overhead (round-4 SSD-300 trace), so small static
     trip counts unroll into straight-line code XLA fuses.
     """
     import jax

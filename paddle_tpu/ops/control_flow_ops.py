@@ -247,7 +247,7 @@ def _scan(ins, attrs, rng=None):
     # no dynamic-update-slices in the backward; this is the re-plumbed
     # "unrolled build over stacked weights" path (measured: lax.scan
     # unroll=1 0.216 MFU / full-unroll-inside-scan 0.341 / this path
-    # matches build() — BASELINE.md "scan-over-layers"). Intermediate
+    # matches build() — round-4 "scan-over-layers" measurement). Intermediate
     # unrolls measured SLOWER than unroll=1 (0.18-0.19) and are kept
     # only for completeness.
     unroll = int(attrs.get("unroll", 1))

@@ -169,10 +169,8 @@ def _fkey_file(fkey: str) -> str:
 def _copy_async(arr):
     """Start a device->host transfer without blocking; materializing the
     same array later finds the bytes already (or soon) resident."""
-    try:
+    if isinstance(arr, jax.Array):  # host numpy: nothing to start
         arr.copy_to_host_async()
-    except AttributeError:
-        pass  # host numpy / older jax: np.asarray below does the copy
 
 
 # ---------------------------------------------------------------------------
@@ -758,11 +756,8 @@ def reshard(values: Dict[str, object], shardings: dict) -> Dict[str, object]:
             out[n] = v
             continue
         host = np.asarray(v)
-        try:
-            out[n] = jax.make_array_from_callback(
-                host.shape, sh, lambda idx, _h=host: _h[idx])
-        except (TypeError, AttributeError):  # older jax fallback
-            out[n] = jax.device_put(host, sh)
+        out[n] = jax.make_array_from_callback(
+            host.shape, sh, lambda idx, _h=host: _h[idx])
     return out
 
 

@@ -7,9 +7,9 @@ TPU-first design. The reference pairs a CUDA k-select kernel with an
 NCCL allgather of (index, value) pairs; here the whole step is one pure
 function built from ``lax.top_k`` + ``lax.all_gather`` + scatter-add, so
 it composes with ``shard_map`` over any mesh axis — the data axis (ICI)
-or the slice axis (DCN), where sparse exchange actually pays (see
-BASELINE.md: ICI dense psum is byte-cheap enough that DGC only wins on
-slow inter-slice links or at extreme sparsity).
+or the slice axis (DCN), where sparse exchange actually pays (round-4
+note: ICI dense psum is byte-cheap enough that DGC only wins on slow
+inter-slice links or at extreme sparsity).
 
 One deliberate divergence: the reference's ``k`` varies at runtime with
 the sparsity rampup schedule. A dynamic ``k`` would force a dynamic
@@ -177,7 +177,7 @@ def clip_by_norm_rampup(g, step, *, clip_norm: float,
 
 
 def dgc_allreduce_bytes(numel: int, k: int, world: int) -> dict:
-    """Comm cost model for the BASELINE.md note: per-device bytes moved
+    """Comm cost model for the note above: per-device bytes moved
     by a ring dense allreduce vs the DGC allgather of (idx, val) pairs.
     Dense ring: 2 * numel * 4 * (W-1)/W. DGC allgather: (W-1) * k * 8
     received per device (4B value + 4B index per entry)."""

@@ -29,8 +29,10 @@ input: its cotangent is zeros on the Pallas path (computing it would
 materialize a t x t gradient). Use the dense composition for a learnable
 additive bias.
 
-Falls back to the dense jnp composition off-TPU or when the sequence
-lengths don't divide the block sizes.
+Off-TPU, or for a shape no kernel family takes, attention runs as the
+dense jnp composition. The choice is a pure function of backend and
+shape (``bthd_family`` / ``bhtd_family``) and every trace records it in
+``pt_attention_dispatch_total`` — a dense fallback is never silent.
 """
 
 from __future__ import annotations
@@ -43,6 +45,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu import monitor as _monitor
+from paddle_tpu.framework import in_shape_inference
 
 DEFAULT_Q_BLOCK = 256
 DEFAULT_K_BLOCK = 256
@@ -59,6 +64,29 @@ _SCORE_VMEM_BYTES = 3 * 2**19
 # blocked online-softmax path itself is exercised by the pytest suite
 # (the reference-composition fallback would otherwise shadow it off-TPU).
 _INTERPRET = False
+
+# Runs at TRACE time (once per compile, like the ring-attention
+# counters): which implementation each attention call took.
+_M_DISPATCH = _monitor.counter(
+    "pt_attention_dispatch_total",
+    "attention implementation chosen at trace time, by family "
+    "(bthd_small / bthd_kblock / bhtd Pallas kernels, or the dense jnp "
+    "composition), pass (fwd/bwd) and shape")
+
+
+def kernels_enabled() -> bool:
+    """The Pallas kernels need a TPU backend (tests reach them on CPU
+    through the interpreter)."""
+    return jax.default_backend() == "tpu" or bool(_INTERPRET)
+
+
+def note_dispatch(family: str, direction: str, b, tq, tk, h, dh):
+    # off with telemetry; build-time eval_shape is not a compile
+    if not _monitor.enabled() or in_shape_inference():
+        return
+    _M_DISPATCH.inc(labels={
+        "family": family, "pass": direction,
+        "shape": f"b{b} tq{tq} tk{tk} h{h} dh{dh}"})
 
 
 def _block_seed(seed, i, j, kk):
@@ -89,12 +117,14 @@ def _pick_blocks(h, tq, tk, q_block, k_block):
     return bq, bk
 
 
-def _use_pallas(tq, tk, bq, bk):
-    return (
-        (jax.default_backend() == "tpu" or _INTERPRET)
-        and tq % bq == 0
-        and tk % bk == 0
-    )
+def bhtd_family(h, tq, tk, q_block=DEFAULT_Q_BLOCK,
+                 k_block=DEFAULT_K_BLOCK) -> str:
+    """"bhtd" (the head-batched K-blocked kernels) when the picked
+    blocks tile both sequence lengths, else "dense"."""
+    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
+    if kernels_enabled() and tq % bq == 0 and tk % bk == 0:
+        return "bhtd"
+    return "dense"
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +186,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
         if p_drop > 0.0:
             pltpu.prng_seed(
-                _block_seed(seed_ref[0], pl.program_id(0), j, kk))
+                _block_seed(seed_ref[0], pl.program_id(0) + seed_ref[1],
+                            j, kk))
             p = p * _dropout_mask(1.0 - p_drop, p.shape)
 
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
@@ -212,7 +243,8 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         )
         if p_drop > 0.0:
             pltpu.prng_seed(
-                _block_seed(seed_ref[0], pl.program_id(0), j, kk))
+                _block_seed(seed_ref[0], pl.program_id(0) + seed_ref[1],
+                            j, kk))
             dp = dp * _dropout_mask(1.0 - p_drop, dp.shape)
         ds = p * (dp - delta) * scale
         dq_scr[:] += jax.lax.dot_general(
@@ -265,7 +297,8 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             # Same (b, q-block, k-block) stream as the forward, generated
             # in the forward's (h, bq, bk) orientation then transposed.
             pltpu.prng_seed(
-                _block_seed(seed_ref[0], pl.program_id(0), jq, kk))
+                _block_seed(seed_ref[0], pl.program_id(0) + seed_ref[1],
+                            jq, kk))
             drop_t = jnp.transpose(
                 _dropout_mask(
                     1.0 - p_drop,
@@ -360,9 +393,26 @@ def _reference_attention(q, k, v, bias, scale, p_drop=0.0, seed=None,
 
 
 def _seed_arr(seed):
+    """The kernels' scalar-prefetch operand, (2,) int32: [dropout seed,
+    global index of this call's first batch row]. ``seed`` is a scalar
+    (row offset 0) or already that pair — a batch-sharded caller
+    (ops/attention_ops.py) passes its shard's offset so the masks do not
+    depend on how many devices split the batch."""
     if seed is None:
-        return jnp.zeros((1,), jnp.int32)
-    return jnp.asarray(seed, jnp.int32).reshape((1,))
+        return jnp.zeros((2,), jnp.int32)
+    seed = jnp.asarray(seed, jnp.int32).reshape((-1,))
+    if seed.shape[0] == 1:
+        seed = jnp.concatenate([seed, jnp.zeros((1,), jnp.int32)])
+    return seed
+
+
+def _result(operands, shape, dtype):
+    """out_shape entry of a pallas_call over ``operands``: the result
+    varies over the same manual mesh axes as they do. Inside a shard_map
+    that checks varying axes the annotation is required; outside one the
+    set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _seed_cotangent(seed):
@@ -404,7 +454,9 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     b, h, tq, dh = q.shape
     tk = k.shape[2]
     bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
-    if not _use_pallas(tq, tk, bq, bk):
+    family = bhtd_family(h, tq, tk, q_block, k_block)
+    note_dispatch(family, "fwd", b, tq, tk, h, dh)
+    if family == "dense":
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
         # derive from one score tensor (_reference_attention_with_lse).
@@ -431,6 +483,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
             scale=scale, nk=nk, p_drop=p_drop, causal=causal,
         )
 
+    operands = (_seed_arr(seed), *args)
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -448,11 +501,11 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
-            jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
+            _result(operands, (b, h, tq, dh), q.dtype),
+            _result(operands, (b, h, tq, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
-    )(_seed_arr(seed), *args)
+    )(*operands)
     return out, lse
 
 
@@ -474,7 +527,9 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     b, h, tq, dh = q.shape
     tk = k.shape[2]
     bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
-    if not _use_pallas(tq, tk, bq, bk):
+    family = bhtd_family(h, tq, tk, q_block, k_block)
+    note_dispatch(family, "bwd", b, tq, tk, h, dh)
+    if family == "dense":
         def f(q, k, v):
             return _reference_attention_with_lse(
                 q, k, v, bias, scale, p_drop,
@@ -516,6 +571,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     ]
     dq_args += [g, lse, delta]
 
+    operands = (seed_arr, *dq_args)
     dq = pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -526,9 +582,9 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                                    lambda i, j, kk, *_: (i, 0, j, 0)),
             scratch_shapes=[pltpu.VMEM((h, bq, dh), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
+        out_shape=_result(operands, (b, h, tq, dh), q.dtype),
         interpret=_INTERPRET,
-    )(seed_arr, *dq_args)
+    )(*operands)
 
     # --- dk/dv: grid (b, nk, nq), q-blocks inner ---
     dkv_specs = [
@@ -556,6 +612,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     ]
     dkv_args += [g, lse, delta]
 
+    operands = (seed_arr, *dkv_args)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -574,11 +631,11 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tk, dh), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, dh), v.dtype),
+            _result(operands, (b, h, tk, dh), k.dtype),
+            _result(operands, (b, h, tk, dh), v.dtype),
         ],
         interpret=_INTERPRET,
-    )(seed_arr, *dkv_args)
+    )(*operands)
     return dq, dk, dv
 
 
@@ -616,9 +673,8 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
     q, k, v, bias, seed, out, lse = res
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    bq, bk = _pick_blocks(q.shape[1], q.shape[2], k.shape[2],
-                          q_block, k_block)
-    if _use_pallas(q.shape[2], k.shape[2], bq, bk):
+    if bhtd_family(q.shape[1], q.shape[2], k.shape[2],
+                    q_block, k_block) == "bhtd":
         dq, dk, dv = flash_attention_bwd(q, k, v, bias, seed, out, lse, g,
                                          scale, p_drop, q_block, k_block,
                                          causal, g_lse=g_lse)
@@ -626,6 +682,8 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
         # (see module docstring).
         dbias = None if bias is None else jnp.zeros_like(bias)
     else:
+        note_dispatch("dense", "bwd", q.shape[0], q.shape[2], k.shape[2],
+                      q.shape[1], q.shape[3])
         sd = seed if p_drop > 0.0 else None
         glse = (jnp.zeros_like(lse) if g_lse is None else g_lse)
 
@@ -707,15 +765,31 @@ flash_attention_with_lse.defvjp(_fa_lse_vjp_fwd, _fa_lse_vjp_bwd)
 _SMALL_T_MAX = 512
 
 
-def _use_bthd_small(tq, tk):
-    return (
-        (jax.default_backend() == "tpu" or _INTERPRET)
-        and 8 <= tq <= _SMALL_T_MAX
-        and 8 <= tk <= _SMALL_T_MAX
-        # tq is walked in _CQ-row grid steps: a non-dividing tq would
-        # truncate nq = tq // cq and leave the tail rows unwritten
-        and (tq <= _CQ or tq % _CQ == 0)
-    )
+def bthd_family(tq, tk, h, dh) -> str:
+    """Which implementation [b, t, h, dh] attention takes: "bthd_small"
+    (whole tk resident per program), "bthd_kblock" (k walked in blocks,
+    tk <= _KB_T_MAX), "bhtd" (one transpose pair into the head-batched
+    K-blocked kernels) or "dense" (the jnp composition). A pure function
+    of backend and shape, so forward and backward always agree."""
+    if not kernels_enabled():
+        return "dense"
+    # tq is walked in _CQ-row grid steps: a non-dividing tq would
+    # truncate nq = tq // cq and leave the tail rows unwritten
+    rows_ok = tq >= 8 and (tq <= _CQ or tq % _CQ == 0)
+    if rows_ok and tq <= _SMALL_T_MAX and 8 <= tk <= _SMALL_T_MAX:
+        return "bthd_small"
+    # dk/dv live whole in f32 VMEM scratch: 2 * tk * h * dh * 4 bytes must
+    # stay well inside the scoped-vmem budget (h*dh=512, tk=1024 -> 4MB,
+    # the measured-safe point; cap at 2x that product). _pick_bk
+    # additionally bounds the per-head score temps.
+    if (rows_ok and _SMALL_T_MAX < tk <= _KB_T_MAX
+            and _pick_bk(tk, h, dh) is not None
+            and tk * h * dh <= 2 * 1024 * 512):
+        return "bthd_kblock"
+    if tk > _SMALL_T_MAX:
+        # very long context: dk/dv won't fit VMEM scratch as one piece
+        return bhtd_family(h, tq, tk)
+    return "dense"
 
 
 def _small_dropout(seed_ref, i, jc, hi, shape, p_drop):
@@ -726,7 +800,7 @@ def _small_dropout(seed_ref, i, jc, hi, shape, p_drop):
     bits-bound (uint32 masks measured 0.165 ms/call extra across
     fwd+bwd at b=64 t=256 h=8); 1/65536 keep-rate granularity is far
     below dropout's statistical noise."""
-    pltpu.prng_seed(_block_seed(seed_ref[0], i, jc, hi))
+    pltpu.prng_seed(_block_seed(seed_ref[0], i + seed_ref[1], jc, hi))
     p_keep = 1.0 - p_drop
     rows, tk = shape
     if rows % 2 == 0:
@@ -1091,21 +1165,6 @@ def _dqdkv_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _use_bthd_kblock(tq, tk, h, dh):
-    # dk/dv live whole in f32 VMEM scratch: 2 * tk * h * dh * 4 bytes must
-    # stay well inside the ~16MB scoped-vmem budget (h*dh=512, tk=1024 ->
-    # 4MB, the measured-safe point; cap at 2x that product). _pick_bk
-    # additionally bounds the per-head score temps.
-    return (
-        (jax.default_backend() == "tpu" or _INTERPRET)
-        and _SMALL_T_MAX < tk <= _KB_T_MAX
-        and _pick_bk(tk, h, dh) is not None
-        and tq >= 8
-        and (tq <= _CQ or tq % _CQ == 0)
-        and tk * h * dh <= 2 * 1024 * 512
-    )
-
-
 def _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop, causal=False):
     b, tq, h, dh = q.shape
     tk = k.shape[1]
@@ -1135,6 +1194,7 @@ def _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop, causal=False):
             scale=scale, p_drop=p_drop, nk=nk, h=h, dh=dh, hb=hb, bk=bk,
             causal=causal,
         )
+    operands = (_seed_arr(seed), *args)
     out2, lse2 = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1152,11 +1212,11 @@ def _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop, causal=False):
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, tq, hdh), q.dtype),
-            jax.ShapeDtypeStruct((b, tq, h), jnp.float32),
+            _result(operands, (b, tq, hdh), q.dtype),
+            _result(operands, (b, tq, h), jnp.float32),
         ],
         interpret=_INTERPRET,
-    )(_seed_arr(seed), *args)
+    )(*operands)
     return out2.reshape(b, tq, h, dh), lse2[..., None]
 
 
@@ -1199,6 +1259,7 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
             scale=scale, p_drop=p_drop, nq=nq, nk=nk, h=h, dh=dh, hb=hb,
             bk=bk, causal=causal,
         )
+    operands = (_seed_arr(seed), *base_args, *tail_args)
     dq2, dk2, dv2 = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1217,9 +1278,9 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, tq, hdh), q.dtype),
-            jax.ShapeDtypeStruct((b, tk, hdh), k.dtype),
-            jax.ShapeDtypeStruct((b, tk, hdh), v.dtype),
+            _result(operands, (b, tq, hdh), q.dtype),
+            _result(operands, (b, tk, hdh), k.dtype),
+            _result(operands, (b, tk, hdh), v.dtype),
         ],
         # The fused kb backward's phase temps land at ~16.7M of Mosaic
         # scoped-vmem stack when compiled inside a run_steps While body
@@ -1230,9 +1291,43 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=24 * 1024 * 1024),
         interpret=_INTERPRET,
-    )(_seed_arr(seed), *base_args, *tail_args)
+    )(*operands)
     return (dq2.reshape(b, tq, h, dh), dk2.reshape(b, tk, h, dh),
             dv2.reshape(b, tk, h, dh))
+
+
+def bthd_dropout_masks(b, tq, tk, h, dh, p_drop, seed):
+    """The BTHD kernels' scaled keep masks as one [b, tq, h, tk] f32
+    array, regenerated on the device by the kernels' OWN helpers and
+    keys. A dense reference fed these masks must agree with the kernels
+    (the hardware tests and chip_smoke.py use it); needs the TPU PRNG."""
+    family = bthd_family(tq, tk, h, dh)
+    if family not in ("bthd_small", "bthd_kblock"):
+        raise ValueError(f"no BTHD dropout stream for family '{family}'")
+    cq = min(tq, _CQ)
+
+    def kern(seed_ref, o_ref):
+        i, j = pl.program_id(0), pl.program_id(1)
+        for hi in range(h):
+            if family == "bthd_kblock":
+                bk = _pick_bk(tk, h, dh)
+                m = jnp.concatenate(
+                    [_kb_dropout(seed_ref, i, j, cq, hi, kk, bk, p_drop)
+                     for kk in range(tk // bk)], axis=-1)
+            else:
+                m = _small_dropout_abs(seed_ref, i, j, cq, hi, tk, p_drop)
+            o_ref[0, :, hi * tk:(hi + 1) * tk] = m.astype(jnp.float32)
+
+    operands = (_seed_arr(seed),)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, tq // cq), in_specs=[],
+            out_specs=pl.BlockSpec((1, cq, h * tk),
+                                   lambda i, j, *_: (i, j, 0))),
+        out_shape=_result(operands, (b, tq, h * tk), jnp.float32),
+    )(*operands)
+    return out.reshape(b, tq, h, tk)
 
 
 def _combined_causal_bias(bias, tq, tk):
@@ -1273,26 +1368,25 @@ def flash_attention_bthd_fwd(q, k, v, bias=None, seed=None, scale=None,
     tk = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
-    if not _use_bthd_small(tq, tk):
-        if _use_bthd_kblock(tq, tk, h, dh):
-            return _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop,
-                                causal=causal)
-        if (jax.default_backend() == "tpu" or _INTERPRET) and tk > _SMALL_T_MAX:
-            # very long context: one transpose pair into the head-batched
-            # K-blocked kernels (dk/dv won't fit VMEM scratch as one
-            # piece); causal rides the in-kernel mask + block skip
-            out, lse = flash_attention_fwd(
-                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                jnp.swapaxes(v, 1, 2), bias, seed, scale, p_drop,
-                causal=causal)
-            return jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2)
-        if causal:
-            bias = _combined_causal_bias(bias, tq, tk)
+    family = bthd_family(tq, tk, h, dh)
+    if family == "bhtd":
+        # causal rides the in-kernel mask + block skip; the BHTD entry
+        # records the dispatch
+        out, lse = flash_attention_fwd(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2), bias, seed, scale, p_drop,
+            causal=causal)
+        return jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2)
+    note_dispatch(family, "fwd", b, tq, tk, h, dh)
+    if family == "bthd_kblock":
+        return _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop,
+                            causal=causal)
+    if causal:
+        bias = _combined_causal_bias(bias, tq, tk)
+    if family == "dense":
         out = _reference_attention_bthd(q, k, v, bias, scale, p_drop,
                                         seed if p_drop > 0.0 else None)
         return out, jnp.zeros((b, tq, h, 1), jnp.float32)
-    if causal:
-        bias = _combined_causal_bias(bias, tq, tk)
 
     cq = _pick_cq(tq, tk, h)
     nq = tq // cq
@@ -1316,6 +1410,7 @@ def flash_attention_bthd_fwd(q, k, v, bias=None, seed=None, scale=None,
                 sr, qr, kr, vr, None, orf, lr, **kw),
             scale=scale, p_drop=p_drop, h=h, dh=dh, hb=hb,
         )
+    operands = (_seed_arr(seed), *args)
     out2, lse2 = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1328,11 +1423,11 @@ def flash_attention_bthd_fwd(q, k, v, bias=None, seed=None, scale=None,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, tq, hdh), q.dtype),
-            jax.ShapeDtypeStruct((b, tq, h), jnp.float32),
+            _result(operands, (b, tq, hdh), q.dtype),
+            _result(operands, (b, tq, h), jnp.float32),
         ],
         interpret=_INTERPRET,
-    )(_seed_arr(seed), *args)
+    )(*operands)
     return out2.reshape(b, tq, h, dh), lse2[..., None]
 
 
@@ -1345,21 +1440,22 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     tk = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
-    if not _use_bthd_small(tq, tk):
-        if _use_bthd_kblock(tq, tk, h, dh):
-            return _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale,
-                                p_drop, causal=causal)
-        if (jax.default_backend() == "tpu" or _INTERPRET) and tk > _SMALL_T_MAX:
-            dq, dk, dv = flash_attention_bwd(
-                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                jnp.swapaxes(v, 1, 2), bias, seed,
-                jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2),
-                jnp.swapaxes(g, 1, 2), scale, p_drop, causal=causal)
-            return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-                    jnp.swapaxes(dv, 1, 2))
-        if causal:
-            bias = _combined_causal_bias(bias, tq, tk)
-
+    family = bthd_family(tq, tk, h, dh)
+    if family == "bhtd":
+        dq, dk, dv = flash_attention_bwd(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2), bias, seed,
+            jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2),
+            jnp.swapaxes(g, 1, 2), scale, p_drop, causal=causal)
+        return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
+                jnp.swapaxes(dv, 1, 2))
+    note_dispatch(family, "bwd", b, tq, tk, h, dh)
+    if family == "bthd_kblock":
+        return _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale,
+                            p_drop, causal=causal)
+    if causal:
+        bias = _combined_causal_bias(bias, tq, tk)
+    if family == "dense":
         def f(q, k, v):
             return _reference_attention_bthd(
                 q, k, v, bias, scale, p_drop,
@@ -1367,8 +1463,6 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 
         _, vjp = jax.vjp(f, q, k, v)
         return vjp(g)
-    if causal:
-        bias = _combined_causal_bias(bias, tq, tk)
 
     # The fused kernel keeps four (cq, tk) f32 temps per head live; halve
     # the chunk relative to the forward so the per-head phase temps fit
@@ -1407,6 +1501,7 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
             scale=scale, p_drop=p_drop, nq=nq, h=h, dh=dh, hb=hb,
         )
 
+    operands = (_seed_arr(seed), *base_args, *tail_args)
     dq2, dk2, dv2 = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1424,12 +1519,12 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, tq, hdh), q.dtype),
-            jax.ShapeDtypeStruct((b, tk, hdh), k.dtype),
-            jax.ShapeDtypeStruct((b, tk, hdh), v.dtype),
+            _result(operands, (b, tq, hdh), q.dtype),
+            _result(operands, (b, tk, hdh), k.dtype),
+            _result(operands, (b, tk, hdh), v.dtype),
         ],
         interpret=_INTERPRET,
-    )(_seed_arr(seed), *base_args, *tail_args)
+    )(*operands)
     return (dq2.reshape(b, tq, h, dh), dk2.reshape(b, tk, h, dh),
             dv2.reshape(b, tk, h, dh))
 
@@ -1462,14 +1557,16 @@ def _bthd_vjp_bwd(scale, p_drop, causal, res, gs):
     q, k, v, bias, seed, out, lse = res
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if _use_bthd_small(q.shape[1], k.shape[1]) or k.shape[1] > _SMALL_T_MAX:
+    b, tq_, h, dh = q.shape
+    tk_ = k.shape[1]
+    if bthd_family(tq_, tk_, h, dh) != "dense":
         dq, dk, dv = flash_attention_bthd_bwd(
             q, k, v, bias, seed, out, lse, g.astype(q.dtype), scale, p_drop,
             causal)
         dbias = None if bias is None else jnp.zeros_like(bias)
     else:
+        note_dispatch("dense", "bwd", b, tq_, tk_, h, dh)
         sd = seed if p_drop > 0.0 else None
-        tq_, tk_ = q.shape[1], k.shape[1]
         if bias is None:
             # the causal fold is a constant here — fold it outside vjp
             eff_bias = (_combined_causal_bias(None, tq_, tk_)

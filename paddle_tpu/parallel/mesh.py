@@ -15,7 +15,7 @@ from typing import Dict, Optional, Sequence
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 _current_mesh: Optional[Mesh] = None
 
@@ -116,10 +116,6 @@ def sharding_descriptor(sharding) -> Optional[dict]:
     spec are the whole layout. Non-Named shardings (positional/GSPMD) and
     host values return None — their checkpoints still restore, they just
     cannot advertise a layout to rebuild."""
-    try:
-        from jax.sharding import NamedSharding, PartitionSpec
-    except ImportError:  # pragma: no cover
-        return None
     if not isinstance(sharding, NamedSharding):
         return None
     spec = []
@@ -139,8 +135,6 @@ def sharding_from_descriptor(desc: dict, devices=None):
     only axis names/sizes with the saving one — which is all a layout
     is; use it to restore a checkpoint in its original sharding when the
     restoring program has no strategy of its own."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
     mesh = create_mesh(dict(desc["mesh"]), devices=devices,
                        set_as_default=False)
     entries = []

@@ -190,6 +190,9 @@ def gpipe(
     if data_axis:
         manual |= (set(data_axis) if isinstance(data_axis, (tuple, list))
                    else {data_axis})
+    # a size-1 axis shards nothing: manual costs nothing, and a stage
+    # body over a FULLY manual mesh can hold Pallas kernels directly
+    manual |= {a for a in mesh.axis_names if mesh.shape[a] == 1}
     # multi-host dispatch can block inside the call (compile-time
     # rendezvous, a stage rank that never arrives): watchdog-guarded so
     # a hung pipeline schedule produces a stall record, not a silent job

@@ -56,8 +56,8 @@ def profiler(state: str = "All", sorted_key: Optional[str] = None,
 
     Writes <profile_path>.json (chrome trace of host spans). With
     ``with_xplane=True`` also captures the XLA device trace to
-    <profile_path>_xplane/ via jax.profiler (can hang on tunneled/remote
-    TPU backends, hence opt-in).
+    <profile_path>_xplane/ via jax.profiler (opt-in: traces are large
+    and tracing slows the host).
     """
     global _host_enabled, _last_xplane_dir
     from paddle_tpu import native

@@ -29,7 +29,7 @@ timeline pair (reference: platform/device_tracer.cc + tools/timeline.py
 3. **Roofline verdict + measured MFU** — joining device seconds with
    the compile report's cost_analysis flops/bytes gives arithmetic
    intensity; against the backend's ridge point
-   (``peak_flops / peak_bytes_per_sec``, table in ``BACKEND_PEAKS``,
+   (``peak_flops / peak_bytes_per_sec``, table in ``DEVICE_PEAKS``,
    overridable via the ``device_peak_*`` flags) the program is
    ``compute_bound`` (intensity >= ridge), ``memory_bound`` (below it),
    or ``overhead`` when it achieves under ``OVERHEAD_FRACTION`` of the
@@ -70,34 +70,44 @@ from paddle_tpu import monitor as _monitor
 # backend peaks + ridge point
 # ---------------------------------------------------------------------------
 
-# Peak dense-matmul FLOP/s (bf16) per v5e chip — THE single definition;
-# bench_common re-exports it for the analytic-MFU helper so the bench
-# tables and the roofline verdicts share one denominator.
+# Peak dense-matmul FLOP/s (bf16) per v5e chip — THE single definition:
+# bench_common.mfu reads it through backend_peaks, so the bench tables
+# and the roofline verdicts share one denominator.
 V5E_PEAK_BF16 = 197e12
 
-# backend -> (peak FLOP/s, peak memory bytes/s). The ridge point
-# (intensity where the compute and memory roofs meet) is their ratio:
-# v5e ~240 FLOP/B. CPU numbers are rough single-socket defaults — on
-# the CPU container the verdicts are still *ordered* correctly, and the
-# device_peak_* flags override both for any specific part.
-BACKEND_PEAKS: Dict[str, Tuple[float, float]] = {
-    "tpu": (V5E_PEAK_BF16, 819e9),
-    "gpu": (989e12, 3.35e12),   # H100 SXM bf16 dense / HBM3
+# device_kind (the string jax reports for the device) -> (peak FLOP/s,
+# peak memory bytes/s). The ridge point (intensity where the compute and
+# memory roofs meet) is their ratio: v5e ~240 FLOP/B. The v5e row is the
+# published peak (Google Cloud "TPU v5e": 197 TFLOP/s bf16, 819 GB/s);
+# the "cpu" row is a rough single-socket figure that only ORDERS the
+# verdicts of the CPU test suite. A device that is not listed is an
+# error, not a default: add its row (or set both device_peak_* flags).
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (V5E_PEAK_BF16, 819e9),
     "cpu": (5e11, 5e10),
 }
 
 
-def backend_peaks(backend: Optional[str] = None) -> Tuple[float, float]:
-    """(peak_flops, peak_bytes_per_sec) for ``backend`` (default: the
-    current jax backend), honoring the ``device_peak_flops`` /
-    ``device_peak_bytes_per_sec`` flag overrides."""
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    pf, pb = BACKEND_PEAKS.get(str(backend), BACKEND_PEAKS["cpu"])
+def backend_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
+    """(peak_flops, peak_bytes_per_sec) for ``device_kind`` (default:
+    jax's first device), honoring the ``device_peak_flops`` /
+    ``device_peak_bytes_per_sec`` flag overrides. Raises for a device
+    the table does not list unless both flags are set."""
     f = float(_flags.get_flag("device_peak_flops"))
     b = float(_flags.get_flag("device_peak_bytes_per_sec"))
+    if f > 0 and b > 0:
+        return f, b
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no roofline peaks for device kind '{device_kind}' (known: "
+            f"{sorted(DEVICE_PEAKS)}); add a DEVICE_PEAKS row with its "
+            f"source, or set the device_peak_flops and "
+            f"device_peak_bytes_per_sec flags")
+    pf, pb = DEVICE_PEAKS[device_kind]
     return (f if f > 0 else pf), (b if b > 0 else pb)
 
 
@@ -542,11 +552,13 @@ def build_device_profile(program, *, source: str,
     ``device_seconds`` defaults to its sum. Estimate source: no per-op
     seconds; ``top_ops`` lists the op histogram's types (count-ordered)
     with null seconds so the shape is stable across sources."""
+    # an explicit ``backend`` also names the peaks row (the CPU's
+    # device_kind is its platform name)
+    peak_flops, peak_bw = backend_peaks(backend)
     if backend is None:
         import jax
 
         backend = jax.default_backend()
-    peak_flops, peak_bw = backend_peaks(backend)
     if op_histogram is None and compile_report is not None:
         op_histogram = compile_report.get("op_histogram")
     flops, bytes_accessed, rep = _report_costs(
@@ -750,8 +762,8 @@ def summary() -> Dict[str, Any]:
     peak_flops, peak_bw = None, None
     try:
         peak_flops, peak_bw = backend_peaks()
-    except Exception:
-        pass
+    except KeyError:
+        pass  # a device the table does not list: the route still serves
     return {
         "profiles": profiles(),
         "peak_flops": peak_flops,

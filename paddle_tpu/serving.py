@@ -102,7 +102,6 @@ from paddle_tpu import numerics as _numerics
 from paddle_tpu import retry as _retry
 from paddle_tpu import serving_trace as _strace
 from paddle_tpu.executor import Executor, Scope, scope_guard
-from paddle_tpu.framework import CPUPlace, TPUPlace
 
 # --- telemetry (no-ops while the 'telemetry' flag is off) ---
 
@@ -457,8 +456,7 @@ class ServingEngine:
                                        self.max_len, bos_id=self.bos_id,
                                        end_id=self.end_id)
         self.scope = Scope()
-        self._exe = Executor(place if place is not None else CPUPlace()
-                             if not _is_tpu_default() else TPUPlace(0))
+        self._exe = Executor(place)
         self.int8 = _load_weights_into(self.scope, weights)
         # device-resident serving state, zero-initialized (live=False
         # everywhere: every slot starts free)
@@ -1621,15 +1619,6 @@ class EngineSupervisor:
             new._enqueue_replay(r)
         self._work.set()
         self._loop_thread = self._start_loop(self._gen, new)
-
-
-def _is_tpu_default() -> bool:
-    import jax
-
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 _ENGINES: "weakref.WeakSet[ServingEngine]" = weakref.WeakSet()

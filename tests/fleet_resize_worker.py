@@ -63,13 +63,7 @@ import jax
 
 if __name__ == "__main__":
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 1)
-    except AttributeError:
-        # older jax (< 0.5): virtual-device count is an XLA flag
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=1")
+    jax.config.update("jax_num_cpu_devices", 1)
 
 import numpy as np  # noqa: E402
 

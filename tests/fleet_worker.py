@@ -8,7 +8,6 @@ Run: PT_TRAINER_ID=<r> PT_TRAINERS=2 PT_COORD_ENDPOINT=127.0.0.1:<p> \
 """
 
 import json
-import os
 
 import jax
 
@@ -17,13 +16,7 @@ if __name__ == "__main__":
     # this module (for build()/global_batches()) inside a pytest process
     # whose jax backend is already configured.
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        # older jax (< 0.5): virtual-device count is an XLA flag
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=2")
+    jax.config.update("jax_num_cpu_devices", 2)
 
 import numpy as np  # noqa: E402
 

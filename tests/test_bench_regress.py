@@ -1,15 +1,11 @@
 """Bench-trajectory regression gate (bench_regress.py): fixture-row
-checks, tolerance semantics, the CLI exit contract, and the committed
-BENCH_r*.json history gating itself."""
+checks, tolerance semantics and the CLI exit contract."""
 
 import json
-import os
 
 import pytest
 
 import bench_regress
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _row(value_mean, metric="transformer_base_train_tokens_per_sec",
@@ -177,37 +173,6 @@ def test_main_needs_enough_history(tmp_path, capsys):
         ["--history", str(tmp_path / "BENCH_r*.json")])
     assert rc == 2
     capsys.readouterr()
-
-
-def test_committed_history_passes_the_gate(capsys):
-    """The acceptance row: the repo's own BENCH_r*.json trajectory must
-    pass — r05 gated against r01..r04 regresses nothing at the default
-    tolerance."""
-    rc = bench_regress.main(
-        ["--history", os.path.join(REPO, "BENCH_r*.json")])
-    out = json.loads(capsys.readouterr().out)
-    assert rc == 0, out["regressions"]
-    assert out["row"] == "BENCH_r05.json"
-    assert "transformer_base_train_tokens_per_sec" in out["gated_metrics"]
-
-
-def test_committed_history_flags_synthetic_twenty_percent_drop(
-        tmp_path, capsys):
-    """The other acceptance half: a synthetic 20% throughput drop on
-    the REAL history is flagged."""
-    r05 = json.load(open(os.path.join(REPO, "BENCH_r05.json")))["parsed"]
-    degraded = json.loads(json.dumps(r05))  # deep copy
-    for key in ("value", "value_mean"):
-        degraded[key] = r05[key] * 0.8
-    fresh = tmp_path / "degraded.json"
-    fresh.write_text(json.dumps(degraded))
-    rc = bench_regress.main(
-        ["--history", os.path.join(REPO, "BENCH_r*.json"),
-         "--row", str(fresh)])
-    assert rc == 1
-    out = json.loads(capsys.readouterr().out)
-    assert any(f["metric"] == "transformer_base_train_tokens_per_sec"
-               for f in out["regressions"])
 
 
 def test_degraded_serving_family_gates_with_wide_tolerance():

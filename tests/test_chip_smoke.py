@@ -1,0 +1,176 @@
+"""chip_smoke.py off the chip: its phases driven at a tiny config on the
+CPU (so the script cannot rot between chip runs), its refusal to pass
+without a TPU, and the fallbacks this bring-up removed — decorative
+places, a cache dir forced in code, a default peaks row, a bench parent
+that holds the chip and shrugs off a failed child."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+import bench
+import chip_smoke
+import paddle_tpu as fluid
+from paddle_tpu import flags, jax_cache, monitor, roofline
+from paddle_tpu.parallel import flash_attention as fa
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def telemetry():
+    monitor.reset()
+    flags.set_flags({"telemetry": True})
+    yield
+    flags.set_flags({"telemetry": False})
+    monitor.reset()
+
+
+def tiny(**kw):
+    return chip_smoke.transformer_base(
+        src_vocab_size=61, trg_vocab_size=67, d_model=32, d_inner=64,
+        n_head=2, n_layer=1, max_length=64, **kw)
+
+
+# --- the phases, tiny, on the CPU ---
+
+def test_kernel_phase_runs_the_kernels_through_the_interpreter():
+    """Interpret mode is allowed only here, set by the test: the phase's
+    compile/run/compare loop over the real kernel code (no dropout — the
+    hardware PRNG has no CPU lowering)."""
+    fa._INTERPRET = True
+    try:
+        rows = chip_smoke.kernel_phase(
+            cases=(("bthd_small", 2, 32, True, 0.0, True),
+                   ("bthd_small", 1, 32, False, 0.0, False)),
+            h=2, dh=16)
+    finally:
+        fa._INTERPRET = False
+    assert [r["family"] for r in rows] == ["bthd_small", "bthd_small"]
+    assert set(rows[0]["rel_err"]) == {"out", "dq", "dk", "dv"}
+    assert max(rows[0]["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+def test_kernel_phase_fails_when_the_dispatch_leaves_the_family():
+    # kernels off (CPU, no interpreter): every shape is "dense"
+    with pytest.raises(chip_smoke.SmokeFailure, match="expected family"):
+        chip_smoke.kernel_phase(
+            cases=(("bthd_small", 1, 32, False, 0.0, False),), h=2, dh=16)
+
+
+def test_train_then_data_parallel_phases(telemetry):
+    cfg = tiny(dropout=0.1)
+    rep, losses = chip_smoke.train_phase(cfg, batch=8, seq=16, steps=2,
+                                         window_steps=2)
+    assert len(losses) == 2 and np.isfinite(rep["window_loss"])
+    assert "cpu" in rep["executor_device"].lower()
+    # off the chip the dispatch is observable too: everything went dense
+    assert rep["dispatch"] and all(
+        k.startswith("dense ") for k in rep["dispatch"])
+    dp = chip_smoke.dp_phase(cfg, losses, batch=8, seq=16)
+    assert dp["devices"] == len(jax.devices()) == 8
+    assert dp["feed_shard_rows"] == 1
+    assert dp["max_rel_diff"] <= chip_smoke.DP_LOSS_REL_TOL
+
+
+def test_serve_phase(telemetry):
+    rep = chip_smoke.serve_phase(
+        tiny(dropout=0.0, label_smooth_eps=0.0), slots=2, src_len=8,
+        max_len=12, max_new=3, src_lens=(8, 3, 5))
+    assert rep["requests"] == 3 and rep["equal_to_solo"]
+    assert rep["compiles_after_warmup"] == 0
+    # decode's tq=1 attention is named, not silent
+    assert any(" tq1 " in k for k in rep["dispatch"])
+
+
+def test_chip_smoke_exits_nonzero_without_a_tpu():
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode != 0
+    assert "needs a TPU" in out.stderr and "cpu" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+# --- the fallbacks that went ---
+
+def test_explicit_tpu_place_raises_without_a_tpu():
+    with pytest.raises(RuntimeError, match="TPUPlace.*default backend"):
+        fluid.Executor(fluid.TPUPlace(0))
+    exe = fluid.Executor()  # no place: jax's default device, recorded
+    assert repr(exe.place) == "CPUPlace" and exe.device.platform == "cpu"
+
+
+def test_backend_peaks_raises_for_an_unknown_device():
+    assert roofline.backend_peaks("cpu") == roofline.DEVICE_PEAKS["cpu"]
+    assert roofline.backend_peaks("TPU v5 lite")[0] == roofline.V5E_PEAK_BF16
+    with pytest.raises(KeyError, match="no roofline peaks"):
+        roofline.backend_peaks("tpu")  # a platform is not a device kind
+
+
+def _configured_cache_dir(env_value):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_value:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "before = jax.config.jax_compilation_cache_dir\n"
+         "from paddle_tpu import jax_cache\n"
+         "print(repr((before, jax_cache.configure(), "
+         "jax.config.jax_compilation_cache_dir)))"],
+        capture_output=True, text=True, timeout=300, cwd=REPO,
+        env={**env, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-800:]
+    return eval(out.stdout.strip().splitlines()[-1])
+
+
+def test_cache_helper_sets_nothing_when_the_variable_places_the_cache(
+        tmp_path):
+    placed = str(tmp_path / "placed")
+    assert _configured_cache_dir(placed) == (placed, placed, placed)
+
+
+def test_cache_helper_uses_one_fixed_in_checkout_dir_otherwise():
+    before, used, after = _configured_cache_dir(None)
+    assert before is None
+    assert used == after == jax_cache.JAX_CACHE_DIR
+    assert used == os.path.join(REPO, ".cache", "jax")
+
+
+def test_bench_parent_is_jax_free_when_it_launches_children():
+    """The launcher must never touch jax (the chip belongs to whichever
+    process does): checked from inside a child it launched."""
+    probe = ("import json, sys, os; "
+             "print(json.dumps({'metric': 'probe', 'value': 1}))")
+    code = (
+        "import sys, bench\n"
+        f"rows = [('headline', [sys.executable, '-c', {probe!r}], {{}})]\n"
+        "rc = bench.main(rows)\n"
+        "assert 'jax' not in sys.modules, 'bench parent imported jax'\n"
+        "assert 'paddle_tpu' not in sys.modules\n"
+        "sys.exit(rc)\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-800:]
+    assert json.loads(out.stdout.strip().splitlines()[-1])["value"] == 1
+
+
+def test_bench_exits_nonzero_when_a_child_fails(capsys):
+    ok = [sys.executable, "-c",
+          "print('{\"metric\": \"m\", \"value\": 2}')"]
+    boom = [sys.executable, "-c", "import sys; sys.exit(3)"]
+    mute = [sys.executable, "-c", "print('no row here')"]
+    assert bench.main([("headline", ok, {})]) == 0
+    assert bench.main([("headline", ok, {}), ("resnet50", boom, {})]) == 1
+    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert row["value"] == 2 and row["resnet50"] is None
+    assert bench.main([("headline", mute, {})]) == 1
+    capsys.readouterr()

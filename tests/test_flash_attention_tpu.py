@@ -1,15 +1,19 @@
-"""TPU-only attention kernel checks (skipped on CPU backends).
+"""Hardware attention-kernel checks: the real Pallas kernels on a real
+TPU, skipped on CPU backends. Run on the chip in one pytest process:
 
-These pin the invariants the Pallas interpreter cannot reach:
+    PT_TEST_TPU=1 python -m pytest tests/test_flash_attention_tpu.py -q
+
+These pin what the Pallas interpreter cannot reach:
 1. the forward (cq up to 256) and fused backward (cq=128) kernels
    regenerate bit-identical dropout masks from the absolute 128-row-block
    keying (incl. the u32->u16 bitcast shape convention), verified by
    comparing the kernel path against a dense reference fed the kernels'
-   OWN masks (dumped via the same helpers);
+   OWN masks (``fa.bthd_dropout_masks``);
 2. hardware numerical parity of the single-block and K-blocked BTHD
-   kernels (fwd + grads) against the dense composition.
-
-The driver runs the suite on TPU each round; on CPU these skip.
+   kernels (fwd + grads) against the dense composition;
+3. in-kernel dropout (``prng_seed`` has no CPU lowering): determinism,
+   keep-rate, and the exact-linear-in-v gradient, for the BHTD and the
+   BTHD kernels.
 """
 
 import numpy as np
@@ -24,39 +28,9 @@ pytestmark = pytest.mark.skipif(
     jax.default_backend() != "tpu", reason="needs a real TPU backend")
 
 
-def _dump_masks(b, tq, tk, h, pd, seed):
-    """The kernels' dropout masks, reproduced with the kernels' own
-    helpers/keys: (b, tq, h, tk) f32 scaled keep masks."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    kblock = tk > fa._SMALL_T_MAX
-    cq = 128 if tq >= 128 else tq
-    nq = tq // cq
-
-    def kern(seed_ref, x_ref, o_ref):
-        i, j = pl.program_id(0), pl.program_id(1)
-        for hi in range(h):
-            if kblock:
-                bk = fa._pick_bk(tk, h, 64)
-                parts = [fa._kb_dropout(seed_ref, i, j, cq, hi, kk, bk, pd)
-                         for kk in range(tk // bk)]
-                m = jnp.concatenate(parts, axis=-1)
-            else:
-                m = fa._small_dropout_abs(seed_ref, i, j, cq, hi, tk, pd)
-            o_ref[0, :, hi * tk:(hi + 1) * tk] = m.astype(jnp.float32)
-
-    out = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, nq),
-            in_specs=[pl.BlockSpec((1, 8, 128), lambda i, j, *_: (0, 0, 0))],
-            out_specs=[pl.BlockSpec((1, cq, h * tk),
-                                    lambda i, j, *_: (i, j, 0))]),
-        out_shape=[jax.ShapeDtypeStruct((b, tq, h * tk), jnp.float32)])(
-            jnp.asarray([seed], jnp.uint32),
-            jnp.zeros((1, 8, 128), jnp.float32))[0]
-    return np.asarray(out).reshape(b, tq, h, tk)
+def _rand(shape, seed):
+    return jnp.asarray(
+        np.random.RandomState(seed).randn(*shape).astype(np.float32) * 0.3)
 
 
 @pytest.mark.parametrize("b,tq,tk,h,dh,pd", [
@@ -66,7 +40,7 @@ def _dump_masks(b, tq, tk, h, pd, seed):
 def test_dropout_fwd_bwd_mask_consistency(b, tq, tk, h, dh, pd):
     seedv = 11
     r = np.random.RandomState(7)
-    masks = _dump_masks(b, tq, tk, h, pd, seedv)
+    masks = fa.bthd_dropout_masks(b, tq, tk, h, dh, pd, seedv)
     q = jnp.asarray(r.normal(0, 1, (b, tq, h, dh))).astype(jnp.bfloat16)
     k = jnp.asarray(r.normal(0, 1, (b, tk, h, dh))).astype(jnp.bfloat16)
     v = jnp.asarray(r.normal(0, 1, (b, tk, h, dh))).astype(jnp.bfloat16)
@@ -75,7 +49,7 @@ def test_dropout_fwd_bwd_mask_consistency(b, tq, tk, h, dh, pd):
 
     def fk(q, k, v):
         o, _ = fa.flash_attention_bthd_with_lse(
-            q, k, v, None, jnp.uint32(seedv), None, pd)
+            q, k, v, None, jnp.int32(seedv), None, pd)
         return jnp.sum(o.astype(jnp.float32) * w)
 
     def fr(q, k, v):
@@ -118,3 +92,86 @@ def test_hw_parity_vs_dense(b, tq, tk, h, dh):
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b_, np.float32), atol=0.05)
+
+
+# --- in-kernel dropout: determinism, keep-rate, exact-linear dv ---
+
+
+def _assert_linear_in_v(f, v):
+    """out is linear in v for a fixed dropout mask, so the analytic dv
+    must equal the directional finite difference. The dot is
+    cancellation-heavy: the tolerance is relative to its positive mass."""
+    dv = jax.grad(f)(v)
+    direction = _rand(v.shape, 9) * 0.03
+    fd = float(f(v + direction) - f(v - direction)) / 2.0
+    an = float(jnp.vdot(dv, direction))
+    mass = float(jnp.vdot(jnp.abs(dv), jnp.abs(direction)))
+    assert abs(an - fd) < 2e-3 * mass, (an, fd, mass)
+
+
+def _bhtd(tq=128, tk=128):
+    return (_rand((1, 1, tq, 64), 0), _rand((1, 1, tk, 64), 1),
+            _rand((1, 1, tk, 64), 2))
+
+
+def _bthd(b=2, h=1, t=128):
+    return (_rand((b, t, h, 64), 0), _rand((b, t, h, 64), 1),
+            _rand((b, t, h, 64), 2))
+
+
+def test_dropout_deterministic_and_normalized():
+    q, k, v = _bhtd()
+    seed = jnp.asarray(42, jnp.int32)
+    o1 = fa.flash_attention(q, k, v, seed=seed, p_drop=0.3,
+                            q_block=128, k_block=128)
+    o2 = fa.flash_attention(q, k, v, seed=seed, p_drop=0.3,
+                            q_block=128, k_block=128)
+    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+    # Expectation of dropped attention == undropped attention; with 128
+    # keys the row means should be close.
+    ref = fa._reference_attention(q, k, v, None, 1.0 / np.sqrt(64))
+    assert np.abs(np.asarray(o1) - np.asarray(ref)).mean() < 0.15
+
+
+def test_dropout_grad_v_is_exact_linear():
+    q, k, v = _bhtd()
+    seed = jnp.asarray(7, jnp.int32)
+    _assert_linear_in_v(
+        lambda v: jnp.sum(fa.flash_attention(
+            q, k, v, seed=seed, p_drop=0.4, q_block=128, k_block=128)), v)
+
+
+def test_bthd_dropout_deterministic():
+    q, k, v = _bthd()
+    seed = jnp.asarray(13, jnp.int32)
+    o1, _ = fa.flash_attention_bthd_fwd(q, k, v, seed=seed, p_drop=0.3)
+    o2, _ = fa.flash_attention_bthd_fwd(q, k, v, seed=seed, p_drop=0.3)
+    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+    ref = fa._reference_attention_bthd(q, k, v, None, 1.0 / np.sqrt(64))
+    assert np.abs(np.asarray(o1) - np.asarray(ref)).mean() < 0.15
+
+
+def test_bthd_dropout_grad_v_linear():
+    q, k, v = _bthd()
+    seed = jnp.asarray(5, jnp.int32)
+
+    _assert_linear_in_v(
+        lambda v: jnp.sum(fa.flash_attention_bthd_with_lse(
+            q, k, v, None, seed, None, 0.4)[0]), v)
+
+
+def test_dropout_masks_do_not_depend_on_the_batch_split():
+    """A batch-sharded caller hands each shard its first GLOBAL row with
+    the seed ([seed, row0], ops/attention_ops._on_mesh): two half-batch
+    calls must reproduce the full-batch masks."""
+    q, k, v = _bthd(b=4)
+    full, _ = fa.flash_attention_bthd_fwd(
+        q, k, v, seed=jnp.asarray(21, jnp.int32), p_drop=0.3)
+    halves = [
+        fa.flash_attention_bthd_fwd(
+            q[r:r + 2], k[r:r + 2], v[r:r + 2],
+            seed=jnp.asarray([21, r], jnp.int32), p_drop=0.3)[0]
+        for r in (0, 2)
+    ]
+    np.testing.assert_array_equal(
+        np.asarray(full), np.asarray(jnp.concatenate(halves, axis=0)))

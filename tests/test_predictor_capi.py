@@ -55,7 +55,7 @@ def test_c_api_serves_exported_model(tmp_path):
     batch.tofile(input_bin)
     expected.tofile(expected_bin)
 
-    env = {**os.environ, "PT_REPO": REPO, "PT_CAPI_PLATFORM": "cpu"}
+    env = {**os.environ, "PT_REPO": REPO, "JAX_PLATFORMS": "cpu"}
     out = subprocess.run(
         [BIN, model_dir, input_bin, "2", "4", "12", "img", expected_bin],
         capture_output=True, text=True, env=env, timeout=300)
