@@ -80,17 +80,25 @@ AMP_FLOW_OP_TYPES = {
 # Slots that must stay f32 under AMP (saved numerical stats, not streams).
 AMP_KEEP_F32_SLOTS = frozenset({"Lse", "GRAD::Lse"})
 
-# Whether AMP casting is active for the block currently being traced.
-# Control-flow op computes read this so sub-blocks inherit the policy of
-# the block that contains them (a contextvar because op computes only
-# receive (ins, attrs)).
-_AMP_ACTIVE: contextvars.ContextVar[bool] = contextvars.ContextVar(
-    "paddle_tpu_amp_active", default=False
+# Whether AMP casting is active for the block currently being traced;
+# None while no block is (core/lowering.run_block sets it for the length
+# of its trace). Control-flow op computes read this so sub-blocks inherit
+# the policy of the block that contains them (a contextvar because op
+# computes only receive (ins, attrs)).
+_AMP_ACTIVE: contextvars.ContextVar[Optional[bool]] = contextvars.ContextVar(
+    "paddle_tpu_amp_active", default=None
 )
 
 
 def amp_active() -> bool:
-    return _AMP_ACTIVE.get()
+    return bool(_AMP_ACTIVE.get())
+
+
+def lowering_active() -> bool:
+    """True inside the trace of a program block being lowered — not in
+    build-time shape inference, which runs op computes (and through
+    control-flow ops, exec_ops) under jax.eval_shape."""
+    return _AMP_ACTIVE.get() is not None
 
 
 def set_amp_active(flag: bool):

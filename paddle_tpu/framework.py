@@ -17,7 +17,6 @@ OpDescs into blocks of a serializable Program — but:
 
 from __future__ import annotations
 
-import contextvars
 import hashlib
 
 from typing import Any, Dict, List, Optional, Sequence
@@ -36,16 +35,6 @@ from paddle_tpu.proto import framework_pb2 as pb
 # Sentinel used to stand in for a symbolic (-1) batch dim during abstract
 # shape inference. Prime and unlikely to appear as a real static dim.
 _BATCH_SENTINEL = 997
-
-# True while an op's compute function runs under jax.eval_shape for
-# build-time shape inference. That is not a trace that gets compiled, so
-# trace-time instruments (pt_attention_dispatch_total) skip it.
-_SHAPE_INFERENCE: contextvars.ContextVar = contextvars.ContextVar(
-    "paddle_tpu_shape_inference", default=False)
-
-
-def in_shape_inference() -> bool:
-    return _SHAPE_INFERENCE.get()
 
 
 def grad_var_name(name: str) -> str:
@@ -510,13 +499,9 @@ def infer_op_outputs(block: "Block", op: Operator):
         if opdef.needs_rng:
             kwargs["rng"] = jax.random.PRNGKey(0)
 
-        tok = _SHAPE_INFERENCE.set(True)
-        try:
-            outs = jax.eval_shape(
-                lambda i: opdef.compute(i, dict(op.attrs), **kwargs), ins
-            )
-        finally:
-            _SHAPE_INFERENCE.reset(tok)
+        outs = jax.eval_shape(
+            lambda i: opdef.compute(i, dict(op.attrs), **kwargs), ins
+        )
         return outs, None
     except Exception as e:
         # the message carries the real diagnostic (broadcast shapes,

@@ -14,9 +14,46 @@ import math
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import monitor as _monitor
+from paddle_tpu.core import interp
 from paddle_tpu.core.registry import register_op
 
 NEG_INF = -1e9
+
+# Runs at TRACE time (once per compile, like the ring-attention
+# counters): which implementation each lowered sdpa call took.
+_M_DISPATCH = _monitor.counter(
+    "pt_attention_dispatch_total",
+    "attention implementation chosen at trace time, by family "
+    "(bthd_small / bthd_kblock / bhtd Pallas kernels, the dense jnp "
+    "composition, or ring), pass (fwd/bwd), shape (one device's share "
+    "for a kernel family under a mesh) and replicated_over (mesh axes "
+    "whose every rank repeats that same call)")
+
+
+def _note_dispatch(family, direction, dims, replicated_over=()):
+    # off with telemetry; build-time shape inference is not a lowering
+    if not _monitor.enabled() or not interp.lowering_active():
+        return
+    b, tq, tk, h, dh = dims
+    _M_DISPATCH.inc(labels={
+        "family": family, "pass": direction,
+        "shape": f"b{b} tq{tq} tk{tk} h{h} dh{dh}",
+        "replicated_over": ",".join(replicated_over)})
+
+
+def dispatch_counts():
+    """{"family pass shape[ replicated_over=axes]": calls lowered so
+    far} — the dispatch counter as chip_smoke.py and the multi-chip dry
+    run print it."""
+    out = {}
+    for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
+        lb = row["labels"]
+        name = " ".join(lb.get(k, "?") for k in ("family", "pass", "shape"))
+        if lb.get("replicated_over"):
+            name += f" replicated_over={lb['replicated_over']}"
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
 
 
 def _x(ins, slot="X", i=0):
@@ -85,36 +122,43 @@ def _sdpa_config(ins, attrs, rng):
     return scale, drop, seed, family, (b, tq, tk, h, dh)
 
 
-def _on_mesh(kernel, arrays, seed):
+def _on_mesh(kernel, arrays, seed, family, direction, dims):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
     (jax refuses to lower one in a multi-device jit), so under a mesh
-    the call is a shard_map: the batch splits over the data axis and
-    every other axis computes replicas. Each shard hands the kernels its
-    first GLOBAL batch row along with the seed, so the dropout masks do
-    not depend on how many devices split the batch."""
-    from paddle_tpu.core.interp import spmd_ctx
-
-    ctx = spmd_ctx()
-    if ctx is None:
-        return kernel(*arrays, seed)
-    mesh = ctx.mesh
+    the call is a shard_map: the batch splits over the data axis, and
+    over any other axis every rank repeats the same call — recorded as
+    ``replicated_over`` in the dispatch counter, never silent. Each
+    shard hands the kernels its first GLOBAL batch row along with the
+    seed, so the dropout masks do not depend on how many devices split
+    the batch."""
+    ctx = interp.spmd_ctx()
     # axes an enclosing shard_map (a GPipe stage) already made manual
     manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-    free = frozenset(a for a in mesh.axis_names if a not in manual)
+    free = (frozenset(a for a in ctx.mesh.axis_names if a not in manual)
+            if ctx is not None else frozenset())
     if not free:
+        _note_dispatch(family, direction, dims)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.parallel.mesh import axis_size, axis_tuple
 
+    mesh = ctx.mesh
     b = arrays[0].shape[0]
     data = axis_tuple(ctx.data_axis) if ctx.data_axis else ()
     axis = tuple(a for a in data if a in free)  # batch axes to split here
     n = axis_size(mesh, axis)
     if b % n != 0:
-        axis, n = (), 1  # replicate rather than shard unevenly
+        # (the batch-sharded feeds could not split it either)
+        raise ValueError(
+            f"attention batch {b} does not split over the {n} ranks of "
+            f"mesh axis {axis}: every device would repeat the whole "
+            f"batch; feed a batch that is a multiple of {n}")
+    _note_dispatch(
+        family, direction, (b // n,) + tuple(dims[1:]),
+        sorted(a for a in free - set(axis) if mesh.shape[a] > 1))
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -145,9 +189,7 @@ def _ring_config_t(q, k, t_axis=2):
     identically in forward and backward. Non-qualifying attention falls
     back to the flash/dense path. ``t_axis`` is the sequence dim: 2 for
     BHTD, 1 for BTHD."""
-    from paddle_tpu.core.interp import spmd_ctx
-
-    ctx = spmd_ctx()
+    ctx = interp.spmd_ctx()
     if ctx is None:
         return None
     mesh, ctx_axis, data_axis = ctx.mesh, ctx.context_axis, ctx.data_axis
@@ -196,6 +238,7 @@ def _sdpa(ins, attrs, rng=None):
     t_axis = 1 if bthd else 2
     ring = _ring_config_t(q, k, t_axis)
     if ring is not None:
+        _note_dispatch("ring", "fwd", dims)
         mesh, ctx_axis, data_axis = ring
         from paddle_tpu.parallel import ring_attention as ra
 
@@ -212,8 +255,8 @@ def _sdpa(ins, attrs, rng=None):
                                     data_axis=data_axis, causal=causal,
                                     p_drop=float(drop), seed=seed)
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
-    elif family == "dense":  # (the kernel entries record their own)
-        fa.note_dispatch("dense", "fwd", *dims)
+    elif family == "dense":
+        _note_dispatch("dense", "fwd", dims)
         sd = seed if drop > 0.0 else None
         if bthd:
             out = fa._reference_attention_bthd(
@@ -229,7 +272,7 @@ def _sdpa(ins, attrs, rng=None):
         out, lse = _on_mesh(
             lambda q, k, v, bias, seed: fa.flash_attention_bthd_with_lse(
                 q, k, v, bias, seed, scale, float(drop), causal),
-            (q, k, v, bias), seed)
+            (q, k, v, bias), seed, family, "fwd", dims)
     else:
         # the custom-vjp wrapper makes the op differentiable through
         # jax.vjp too (scan-over-layers grad); the paired grad op below
@@ -237,7 +280,7 @@ def _sdpa(ins, attrs, rng=None):
         out, lse = _on_mesh(
             lambda q, k, v, bias, seed: fa.flash_attention_with_lse(
                 q, k, v, bias, seed, scale, float(drop), causal=causal),
-            (q, k, v, bias), seed)
+            (q, k, v, bias), seed, family, "fwd", dims)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -259,6 +302,7 @@ def _sdpa_grad(ins, attrs, rng=None):
     t_axis = 1 if bthd else 2
     ring = _ring_config_t(q, k, t_axis)
     if ring is not None:
+        _note_dispatch("ring", "bwd", dims)
         mesh, ctx_axis, data_axis = ring
         from paddle_tpu.parallel import ring_attention as ra
 
@@ -279,7 +323,7 @@ def _sdpa_grad(ins, attrs, rng=None):
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
     elif family == "dense":
-        fa.note_dispatch("dense", "bwd", *dims)
+        _note_dispatch("dense", "bwd", dims)
         sd = seed if drop > 0.0 else None
         if bthd:
             eff_bias = fa._combined_causal_bias(
@@ -303,5 +347,6 @@ def _sdpa_grad(ins, attrs, rng=None):
             lambda q, k, v, bias, out, lse, g, seed: bwd(
                 q, k, v, bias, seed, out, lse, g, scale=scale,
                 p_drop=drop, causal=causal),
-            (q, k, v, bias, out, lse, g.astype(q.dtype)), seed)
+            (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
+            "bwd", dims)
     return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

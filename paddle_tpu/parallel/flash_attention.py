@@ -31,8 +31,9 @@ additive bias.
 
 Off-TPU, or for a shape no kernel family takes, attention runs as the
 dense jnp composition. The choice is a pure function of backend and
-shape (``bthd_family`` / ``bhtd_family``) and every trace records it in
-``pt_attention_dispatch_total`` — a dense fallback is never silent.
+shape (``bthd_family`` / ``bhtd_family``); the sdpa op records it per
+lowered call in ``pt_attention_dispatch_total`` (ops/attention_ops.py),
+so a dense fallback is never silent.
 """
 
 from __future__ import annotations
@@ -45,9 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from paddle_tpu import monitor as _monitor
-from paddle_tpu.framework import in_shape_inference
 
 DEFAULT_Q_BLOCK = 256
 DEFAULT_K_BLOCK = 256
@@ -65,28 +63,11 @@ _SCORE_VMEM_BYTES = 3 * 2**19
 # (the reference-composition fallback would otherwise shadow it off-TPU).
 _INTERPRET = False
 
-# Runs at TRACE time (once per compile, like the ring-attention
-# counters): which implementation each attention call took.
-_M_DISPATCH = _monitor.counter(
-    "pt_attention_dispatch_total",
-    "attention implementation chosen at trace time, by family "
-    "(bthd_small / bthd_kblock / bhtd Pallas kernels, or the dense jnp "
-    "composition), pass (fwd/bwd) and shape")
-
 
 def kernels_enabled() -> bool:
     """The Pallas kernels need a TPU backend (tests reach them on CPU
     through the interpreter)."""
     return jax.default_backend() == "tpu" or bool(_INTERPRET)
-
-
-def note_dispatch(family: str, direction: str, b, tq, tk, h, dh):
-    # off with telemetry; build-time eval_shape is not a compile
-    if not _monitor.enabled() or in_shape_inference():
-        return
-    _M_DISPATCH.inc(labels={
-        "family": family, "pass": direction,
-        "shape": f"b{b} tq{tq} tk{tk} h{h} dh{dh}"})
 
 
 def _block_seed(seed, i, j, kk):
@@ -455,7 +436,6 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     tk = k.shape[2]
     bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
     family = bhtd_family(h, tq, tk, q_block, k_block)
-    note_dispatch(family, "fwd", b, tq, tk, h, dh)
     if family == "dense":
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
@@ -528,7 +508,6 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     tk = k.shape[2]
     bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
     family = bhtd_family(h, tq, tk, q_block, k_block)
-    note_dispatch(family, "bwd", b, tq, tk, h, dh)
     if family == "dense":
         def f(q, k, v):
             return _reference_attention_with_lse(
@@ -682,8 +661,6 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
         # (see module docstring).
         dbias = None if bias is None else jnp.zeros_like(bias)
     else:
-        note_dispatch("dense", "bwd", q.shape[0], q.shape[2], k.shape[2],
-                      q.shape[1], q.shape[3])
         sd = seed if p_drop > 0.0 else None
         glse = (jnp.zeros_like(lse) if g_lse is None else g_lse)
 
@@ -1370,14 +1347,12 @@ def flash_attention_bthd_fwd(q, k, v, bias=None, seed=None, scale=None,
         scale = 1.0 / math.sqrt(dh)
     family = bthd_family(tq, tk, h, dh)
     if family == "bhtd":
-        # causal rides the in-kernel mask + block skip; the BHTD entry
-        # records the dispatch
+        # causal rides the in-kernel mask + block skip
         out, lse = flash_attention_fwd(
             jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
             jnp.swapaxes(v, 1, 2), bias, seed, scale, p_drop,
             causal=causal)
         return jnp.swapaxes(out, 1, 2), jnp.swapaxes(lse, 1, 2)
-    note_dispatch(family, "fwd", b, tq, tk, h, dh)
     if family == "bthd_kblock":
         return _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop,
                             causal=causal)
@@ -1449,7 +1424,6 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
             jnp.swapaxes(g, 1, 2), scale, p_drop, causal=causal)
         return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
                 jnp.swapaxes(dv, 1, 2))
-    note_dispatch(family, "bwd", b, tq, tk, h, dh)
     if family == "bthd_kblock":
         return _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale,
                             p_drop, causal=causal)
@@ -1557,7 +1531,7 @@ def _bthd_vjp_bwd(scale, p_drop, causal, res, gs):
     q, k, v, bias, seed, out, lse = res
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    b, tq_, h, dh = q.shape
+    _, tq_, h, dh = q.shape
     tk_ = k.shape[1]
     if bthd_family(tq_, tk_, h, dh) != "dense":
         dq, dk, dv = flash_attention_bthd_bwd(
@@ -1565,7 +1539,6 @@ def _bthd_vjp_bwd(scale, p_drop, causal, res, gs):
             causal)
         dbias = None if bias is None else jnp.zeros_like(bias)
     else:
-        note_dispatch("dense", "bwd", b, tq_, tk_, h, dh)
         sd = seed if p_drop > 0.0 else None
         if bias is None:
             # the causal fold is a constant here — fold it outside vjp

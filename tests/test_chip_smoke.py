@@ -23,11 +23,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
-def telemetry():
+def telemetry(tmp_path):
     monitor.reset()
-    flags.set_flags({"telemetry": True})
+    flags.set_flags({"telemetry": True, "trace_dir": str(tmp_path)})
     yield
-    flags.set_flags({"telemetry": False})
+    flags.set_flags({"telemetry": False, "trace_dir": ""})
     monitor.reset()
 
 
@@ -82,10 +82,26 @@ def test_serve_phase(telemetry):
     rep = chip_smoke.serve_phase(
         tiny(dropout=0.0, label_smooth_eps=0.0), slots=2, src_len=8,
         max_len=12, max_new=3, src_lens=(8, 3, 5))
-    assert rep["requests"] == 3 and rep["equal_to_solo"]
+    assert rep["requests"] == 3 and rep["equal_to_solo_same_engine"]
     assert rep["compiles_after_warmup"] == 0
+    # f32 on the CPU: the 2-slot and the 1-slot engine agree outright
+    assert rep["equal_to_solo_1slot_highest"]
+    assert rep["partings_vs_1slot_default"] == []
+    assert rep["rel_logit_diff_vs_1slot"]["default"] < 1e-4
     # decode's tq=1 attention is named, not silent
     assert any(" tq1 " in k for k in rep["dispatch"])
+
+
+def test_parting_names_the_first_differing_step_and_the_logit_gaps():
+    a = [(5, 1.0), (7, 2.0), (9, 1.0)]
+    assert chip_smoke._parting(a, list(a)) == (None, 0.0, None)
+    first, before, at = chip_smoke._parting(
+        a, [(5, 1.01), (8, 2.2), (9, 1.0)])
+    assert first == 1
+    assert before == pytest.approx(0.01 / 1.01)
+    assert at == pytest.approx(0.2 / 2.2)
+    # one stream ended on EOS where the other went on: they part there
+    assert chip_smoke._parting(a, a[:2]) == (2, 0.0, None)
 
 
 def test_chip_smoke_exits_nonzero_without_a_tpu():
@@ -105,6 +121,45 @@ def test_explicit_tpu_place_raises_without_a_tpu():
         fluid.Executor(fluid.TPUPlace(0))
     exe = fluid.Executor()  # no place: jax's default device, recorded
     assert repr(exe.place) == "CPUPlace" and exe.device.platform == "cpu"
+
+
+def test_mesh_attention_names_replicated_axes_and_refuses_uneven_batch(
+        telemetry):
+    """ops/attention_ops._on_mesh (a stand-in for the Pallas call: the
+    interpreter cannot run one inside a shard_map): under dp x tp the
+    batch splits over the data axis, each shard gets its first GLOBAL
+    row, and the model axis repeating the call is on the record; a batch
+    the data axis cannot split raises instead of running n times."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import parallel
+    from paddle_tpu.core import interp
+    from paddle_tpu.ops import attention_ops
+
+    mesh = parallel.create_mesh({"data": 2, "model": 2},
+                                devices=jax.devices()[:4])
+    strategy = parallel.DistributedStrategy(
+        mesh, "data", parallel.transformer_rules("model"))
+
+    def kernel(q, bias, seed):
+        return q + seed[1].astype(q.dtype)  # seed = [seed, first row]
+
+    def call(q):
+        return attention_ops._on_mesh(
+            kernel, (q, None), None, "bthd_small", "fwd",
+            (q.shape[0], 8, 8, 2, 16))
+
+    tok = interp.set_amp_active(False)  # as inside a block being lowered
+    try:
+        with interp.spmd_ctx_scope(strategy):
+            out = jax.jit(call)(jnp.zeros((4, 8, 2, 16)))
+            with pytest.raises(ValueError, match="does not split"):
+                jax.jit(call)(jnp.zeros((3, 8, 2, 16)))
+    finally:
+        interp._AMP_ACTIVE.reset(tok)
+    assert np.asarray(out[:, 0, 0, 0]).tolist() == [0, 0, 2, 2]
+    assert attention_ops.dispatch_counts() == {
+        "bthd_small fwd b2 tq8 tk8 h2 dh16 replicated_over=model": 1}
 
 
 def test_backend_peaks_raises_for_an_unknown_device():

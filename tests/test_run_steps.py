@@ -124,3 +124,18 @@ def test_run_steps_continues_the_step_counter():
     out = exe.run(main, feed=feeds[1], fetch_list=[loss], scope=scope)
     assert np.isfinite(np.asarray(out[0])).all()
     assert float(np.asarray(out[0])) < float(np.asarray(l0[0]))
+
+
+def test_run_steps_refuses_a_compiled_program():
+    """A mesh program has one hot loop, Executor.run (where its state is
+    committed to the mesh); a window over one is refused, not run on one
+    device under its name (ROADMAP Queue 1 item 7)."""
+    import pytest
+
+    main, startup, loss = _build()
+    compiled = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with pytest.raises(TypeError, match="does not support CompiledProgram"):
+        exe.run_steps(compiled, feed_list=_feeds(1), steps=2,
+                      fetch_list=[loss])
