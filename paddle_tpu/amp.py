@@ -29,7 +29,7 @@ doubling accumulator memory.
 
 from __future__ import annotations
 
-from paddle_tpu.framework import default_main_program
+from paddle_tpu.framework import default_main_program, op_role_guard
 
 
 class AmpOptimizer:
@@ -86,8 +86,7 @@ class AmpOptimizer:
 
     def _dynamic_minimize(self, loss, program, startup_program=None,
                           parameter_list=None, no_grad_set=None):
-        from paddle_tpu import numerics, unique_name
-        from paddle_tpu.layers import more as lmore
+        from paddle_tpu import unique_name
         from paddle_tpu.layers import nn, tensor
 
         program._amp = True
@@ -108,6 +107,19 @@ class AmpOptimizer:
         scaled_loss = nn.elementwise_mul(loss, block.var(scale_var.name))
         params_grads = self._inner.backward(
             scaled_loss, startup_program, parameter_list, no_grad_set)
+        # what follows the backward (unscale, the scale's state machine,
+        # the gated update) is the step's optimizer phase
+        with op_role_guard(program, "opt"):
+            return self._unscale_and_update(
+                program, block, params_grads, scale_var, good_var,
+                bad_var, skips_var)
+
+    def _unscale_and_update(self, program, block, params_grads, scale_var,
+                            good_var, bad_var, skips_var):
+        from paddle_tpu import numerics
+        from paddle_tpu.layers import more as lmore
+        from paddle_tpu.layers import nn, tensor
+
         if any(getattr(g, "is_selected_rows", False)
                for _, g in params_grads if g is not None):
             raise NotImplementedError(

@@ -384,7 +384,7 @@ _FLOAT_NARROW = {"float16", "bfloat16"}
 def _eval_key(block: Block, op: Operator):
     try:
         attrs = []
-        for k, v in op.attrs.items():
+        for k, v in op.compute_attrs().items():
             if isinstance(v, Block) or (
                     isinstance(v, (list, tuple))
                     and any(isinstance(x, Block) for x in v)):

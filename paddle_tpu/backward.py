@@ -19,7 +19,14 @@ import numpy as np
 from paddle_tpu.core.autodiff import GRAD_SLOT_PREFIX
 from paddle_tpu.core.lowering import resolve_op_def
 from paddle_tpu.core.registry import GRAD_OP_SUFFIX
-from paddle_tpu.framework import Block, Parameter, Variable, grad_var_name
+from paddle_tpu.framework import (
+    OP_NAMESCOPE_ATTR,
+    Block,
+    Parameter,
+    Variable,
+    grad_var_name,
+    op_role_guard,
+)
 
 
 def _is_float_var(block: Block, name: str) -> bool:
@@ -48,6 +55,13 @@ def append_backward(
     no_grad_set: Optional[Set[str]] = None,
     callbacks=None,
 ) -> List[Tuple[Parameter, Variable]]:
+    # everything emitted here (the *_grad ops, the gradient sums and
+    # fills between them) is the step's backward phase
+    with op_role_guard(loss.block.program, "bwd"):
+        return _append_backward(loss, parameter_list, no_grad_set)
+
+
+def _append_backward(loss, parameter_list, no_grad_set):
     block = loss.block
     program = block.program
     no_grad = set(no_grad_set or ())
@@ -158,6 +172,10 @@ def append_backward(
             descs = opdef.grad_maker(op, block, out_grads, provide, should_skip)
             if descs is not None:  # None = defer to the generic emitter
                 for d in descs:
+                    if op.namescope:  # as the generic emitter's attr copy
+                        d = {**d, "attrs": {
+                            OP_NAMESCOPE_ATTR: op.namescope,
+                            **(d.get("attrs") or {})}}
                     block.append_op(**d)
                 continue
 

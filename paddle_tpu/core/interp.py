@@ -207,6 +207,15 @@ def resolve_op_def(op_type: str) -> OpDef:
     return get_op_def(op_type)  # raises with a helpful message
 
 
+def op_scope_name(op) -> str:
+    """``<phase>/<name scope>/<op type>``: the scope an op's compute is
+    lowered under (phase is fwd, bwd or opt; the name scope may be
+    empty)."""
+    scope = op.namescope
+    return (f"{op.role}/{scope}/{op.type}" if scope
+            else f"{op.role}/{op.type}")
+
+
 def exec_ops(
     ops,
     env: Dict[str, Any],
@@ -232,22 +241,29 @@ def exec_ops(
             slot: [env[n] if n else None for n in names]
             for slot, names in op.inputs.items()
         }
-        kwargs = {}
-        if opdef.needs_rng:
-            fold = op.attrs.get("forward_op_idx", idx)
-            kwargs["rng"] = (
-                jax.random.fold_in(key, fold) if key is not None else None
-            )
         base_type = (
             op.type[: -len(GRAD_OP_SUFFIX)]
             if op.type.endswith(GRAD_OP_SUFFIX)
             else op.type
         )
-        if amp and base_type in AMP_OP_TYPES:
-            ins = _amp_cast_ins(ins)
-        elif amp and base_type in AMP_FLOW_OP_TYPES:
-            ins = _amp_flow_cast_ins(ins)
-        outs = opdef.compute(ins, dict(op.attrs), **kwargs)
+        # the program's names into the HLO's op_name metadata (and from
+        # there into the device trace): <phase>/<name scope>/<op type>.
+        # Trace time only. The op's key derivation and its AMP casts sit
+        # inside, so both are charged to the op that asked for them. A
+        # control-flow op's sub-block re-enters here inside this scope
+        # and nests under it.
+        with jax.named_scope(op_scope_name(op)):
+            kwargs = {}
+            if opdef.needs_rng:
+                fold = op.attrs.get("forward_op_idx", idx)
+                kwargs["rng"] = (
+                    jax.random.fold_in(key, fold) if key is not None else None
+                )
+            if amp and base_type in AMP_OP_TYPES:
+                ins = _amp_cast_ins(ins)
+            elif amp and base_type in AMP_FLOW_OP_TYPES:
+                ins = _amp_flow_cast_ins(ins)
+            outs = opdef.compute(ins, op.compute_attrs(), **kwargs)
         for slot, names in op.outputs.items():
             vals = outs.get(slot, [])
             for i, n in enumerate(names):

@@ -36,6 +36,19 @@ from paddle_tpu.proto import framework_pb2 as pb
 # shape inference. Prime and unlikely to appear as a real static dim.
 _BATCH_SENTINEL = 997
 
+# Attrs that NAME an op and never reach its kernel (reference:
+# framework/op_proto_maker.h kOpRoleAttrName / kOpNameScopeAttrName):
+# the phase of the step the op belongs to ("bwd" and "opt" are recorded,
+# a forward op carries no role) and the name scope it was appended in.
+# core/interp.exec_ops lowers each op under
+# jax.named_scope("<phase>/<scope>/<op.type>"), which is how the device
+# trace gets the program's names; Operator.compute_attrs() is what a
+# kernel, shape inference and CSE see.
+OP_ROLE_ATTR = "op_role"
+OP_NAMESCOPE_ATTR = "op_namescope"
+OP_META_ATTRS = (OP_ROLE_ATTR, OP_NAMESCOPE_ATTR)
+OP_ROLES = ("fwd", "bwd", "opt")
+
 
 def grad_var_name(name: str) -> str:
     return name + GRAD_SUFFIX
@@ -192,6 +205,27 @@ class Operator:
         self.inputs: Dict[str, List[str]] = _normalize_slots(inputs)
         self.outputs: Dict[str, List[str]] = _normalize_slots(outputs)
         self.attrs: Dict[str, Any] = dict(attrs or {})
+        role = block.program._op_role
+        if role != "fwd":  # a grad op copies its forward's attrs: override
+            self.attrs[OP_ROLE_ATTR] = role
+        if _name_scope_ and OP_NAMESCOPE_ATTR not in self.attrs:
+            self.attrs[OP_NAMESCOPE_ATTR] = "/".join(_name_scope_)
+
+    @property
+    def role(self) -> str:
+        """The phase of the step this op belongs to: fwd, bwd or opt."""
+        return self.attrs.get(OP_ROLE_ATTR, "fwd")
+
+    @property
+    def namescope(self) -> str:
+        """The ``name_scope`` path the op was appended in ("" outside
+        any); a grad op carries its forward's."""
+        return self.attrs.get(OP_NAMESCOPE_ATTR, "")
+
+    def compute_attrs(self) -> Dict[str, Any]:
+        """The attrs the op's kernel sees: all but the naming ones."""
+        return {k: v for k, v in self.attrs.items()
+                if k not in OP_META_ATTRS}
 
     def input(self, slot: str) -> List[str]:
         return self.inputs.get(slot, [])
@@ -500,7 +534,7 @@ def infer_op_outputs(block: "Block", op: Operator):
             kwargs["rng"] = jax.random.PRNGKey(0)
 
         outs = jax.eval_shape(
-            lambda i: opdef.compute(i, dict(op.attrs), **kwargs), ins
+            lambda i: opdef.compute(i, op.compute_attrs(), **kwargs), ins
         )
         return outs, None
     except Exception as e:
@@ -545,6 +579,8 @@ class Program:
         self.random_seed: Optional[int] = None
         # bf16 mixed-precision execution flag (see paddle_tpu/amp.py)
         self._amp = False
+        # role recorded on ops appended now (OP_ROLE_ATTR; op_role_guard)
+        self._op_role = "fwd"
         # populated by append_backward: {param_name: grad_name}
         self._param_grad_map: Dict[str, str] = {}
         # version-keyed def-use index cache (analysis.DefUseIndex per
@@ -758,10 +794,39 @@ class program_guard:
 import contextlib
 
 
+# the name scopes open now, outermost first (reference: framework.py
+# name_scope / _name_scope, a process-wide stack there too)
+_name_scope_: List[str] = []
+
+
 @contextlib.contextmanager
 def name_scope(prefix: str):
-    """Cosmetic name scoping for debugging/profiling."""
-    yield
+    """Ops appended inside carry ``prefix`` (scopes nest with "/") as
+    their ``op_namescope`` attr, their grad ops inherit it, and the
+    lowering names each op's compute ``<phase>/<scope>/<op type>`` in
+    the compiled HLO, so a device trace reads in the program's own
+    terms (README "Observability"). A scope is a name, never
+    arithmetic; keep parameter names out of it (one scope per layer,
+    not per tensor)."""
+    _name_scope_.append(str(prefix).strip("/"))
+    try:
+        yield
+    finally:
+        _name_scope_.pop()
+
+
+@contextlib.contextmanager
+def op_role_guard(program: "Program", role: str):
+    """Ops appended to ``program`` inside belong to phase ``role`` (the
+    reference's Program._optimized_guard / _backward_role_guard):
+    backward.append_backward uses "bwd", Optimizer.apply_gradients
+    "opt"."""
+    assert role in OP_ROLES, role
+    old, program._op_role = program._op_role, role
+    try:
+        yield
+    finally:
+        program._op_role = old
 
 
 # Device "places" (reference: platform/place.h:79). Programs run on jax's

@@ -79,87 +79,93 @@ def build(cfg: Optional[BertConfig] = None, is_test: bool = False):
     helper.append_op("attn_bias", inputs={"PadMask": pad},
                      outputs={"Out": bias}, attrs={"causal": False})
 
-    tok = layers.embedding(
-        ids, size=[cfg.vocab_size, cfg.d_model],
-        param_attr=ParamAttr(
-            name="bert_tok_emb.w",
-            initializer=fluid.initializer.NormalInitializer(0.0, 0.02)),
-    )
-    seg = layers.embedding(
-        type_ids, size=[cfg.type_vocab_size, cfg.d_model],
-        param_attr=ParamAttr(
-            name="bert_seg_emb.w",
-            initializer=fluid.initializer.NormalInitializer(0.0, 0.02)),
-    )
-    pos_ids = helper.create_variable_for_type_inference("int64", True)
-    helper.append_op("position_ids", inputs={"X": ids},
-                     outputs={"Out": pos_ids})
-    pos = layers.embedding(
-        pos_ids, size=[cfg.max_position, cfg.d_model],
-        param_attr=ParamAttr(
-            name="bert_pos_emb.w",
-            initializer=fluid.initializer.NormalInitializer(0.0, 0.02)),
-    )
-    x = layers.elementwise_add(layers.elementwise_add(tok, seg), pos)
-    x = layers.layer_norm(
-        x, begin_norm_axis=2,
-        param_attr=ParamAttr(name="bert_emb_ln.scale"),
-        bias_attr=ParamAttr(name="bert_emb_ln.bias"),
-    )
-    if cfg.dropout and not is_test:
-        x = layers.dropout(x, cfg.dropout,
-                           dropout_implementation="upscale_in_train")
+    with fluid.name_scope("embed"):
+        tok = layers.embedding(
+            ids, size=[cfg.vocab_size, cfg.d_model],
+            param_attr=ParamAttr(
+                name="bert_tok_emb.w",
+                initializer=fluid.initializer.NormalInitializer(0.0, 0.02)),
+        )
+        seg = layers.embedding(
+            type_ids, size=[cfg.type_vocab_size, cfg.d_model],
+            param_attr=ParamAttr(
+                name="bert_seg_emb.w",
+                initializer=fluid.initializer.NormalInitializer(0.0, 0.02)),
+        )
+        pos_ids = helper.create_variable_for_type_inference("int64", True)
+        helper.append_op("position_ids", inputs={"X": ids},
+                         outputs={"Out": pos_ids})
+        pos = layers.embedding(
+            pos_ids, size=[cfg.max_position, cfg.d_model],
+            param_attr=ParamAttr(
+                name="bert_pos_emb.w",
+                initializer=fluid.initializer.NormalInitializer(0.0, 0.02)),
+        )
+        x = layers.elementwise_add(layers.elementwise_add(tok, seg), pos)
+        x = layers.layer_norm(
+            x, begin_norm_axis=2,
+            param_attr=ParamAttr(name="bert_emb_ln.scale"),
+            bias_attr=ParamAttr(name="bert_emb_ln.bias"),
+        )
+        if cfg.dropout and not is_test:
+            x = layers.dropout(x, cfg.dropout,
+                               dropout_implementation="upscale_in_train")
 
     for i in range(cfg.n_layer):
         x = T.encoder_layer(x, bias, ecfg, i, is_test)
     x = T._ln(x, "enc_post")
 
-    # MLM head: transform + vocab projection
-    mlm = layers.fc(
-        x, cfg.d_model, num_flatten_dims=2, act="gelu",
-        param_attr=ParamAttr(name="mlm_tr_colp.w"),
-        bias_attr=ParamAttr(name="mlm_tr_colp.b"),
-    )
-    mlm = layers.layer_norm(
-        mlm, begin_norm_axis=2,
-        param_attr=ParamAttr(name="mlm_ln.scale"),
-        bias_attr=ParamAttr(name="mlm_ln.bias"),
-    )
-    mlm_logits = layers.fc(
-        mlm, cfg.vocab_size, num_flatten_dims=2,
-        param_attr=ParamAttr(name="mlm_proj_colp.w"), bias_attr=False,
-    )
+    with fluid.name_scope("mlm_head"):
+        # MLM head: transform + vocab projection
+        mlm = layers.fc(
+            x, cfg.d_model, num_flatten_dims=2, act="gelu",
+            param_attr=ParamAttr(name="mlm_tr_colp.w"),
+            bias_attr=ParamAttr(name="mlm_tr_colp.b"),
+        )
+        mlm = layers.layer_norm(
+            mlm, begin_norm_axis=2,
+            param_attr=ParamAttr(name="mlm_ln.scale"),
+            bias_attr=ParamAttr(name="mlm_ln.bias"),
+        )
+        mlm_logits = layers.fc(
+            mlm, cfg.vocab_size, num_flatten_dims=2,
+            param_attr=ParamAttr(name="mlm_proj_colp.w"), bias_attr=False,
+        )
 
-    # NSP head over the [CLS] (first) position
-    cls = layers.squeeze(
-        layers.slice(x, axes=[1], starts=[0], ends=[1]), [1])
-    nsp_logits = layers.fc(
-        cls, 2,
-        param_attr=ParamAttr(name="nsp.w"),
-        bias_attr=ParamAttr(name="nsp.b"),
-    )
+    with fluid.name_scope("nsp_head"):
+        # NSP head over the [CLS] (first) position
+        cls = layers.squeeze(
+            layers.slice(x, axes=[1], starts=[0], ends=[1]), [1])
+        nsp_logits = layers.fc(
+            cls, 2,
+            param_attr=ParamAttr(name="nsp.w"),
+            bias_attr=ParamAttr(name="nsp.b"),
+        )
 
-    # masked-LM loss over masked positions only (mlm_labels == -1 ignored)
-    safe_lbl = layers.elementwise_max(
-        mlm_lbl, layers.fill_constant_like(mlm_lbl, 0.0))
-    ce = layers.softmax_with_cross_entropy(
-        mlm_logits, layers.unsqueeze(safe_lbl, [2]))
-    ce = layers.reshape(ce, [0, -1])
-    is_masked = layers.cast(
-        layers.greater_than(
-            layers.cast(mlm_lbl, "float32"),
-            layers.fill_constant_like(
-                layers.cast(mlm_lbl, "float32"), -0.5)),
-        "float32",
-    )
-    mlm_count = layers.elementwise_max(
-        layers.reduce_sum(is_masked),
-        layers.fill_constant([], "float32", 1.0))
-    mlm_loss = layers.elementwise_div(
-        layers.reduce_sum(layers.elementwise_mul(ce, is_masked)), mlm_count)
+    with fluid.name_scope("mlm_head"):
+        # masked-LM loss over masked positions only (mlm_labels == -1
+        # ignored)
+        safe_lbl = layers.elementwise_max(
+            mlm_lbl, layers.fill_constant_like(mlm_lbl, 0.0))
+        ce = layers.softmax_with_cross_entropy(
+            mlm_logits, layers.unsqueeze(safe_lbl, [2]))
+        ce = layers.reshape(ce, [0, -1])
+        is_masked = layers.cast(
+            layers.greater_than(
+                layers.cast(mlm_lbl, "float32"),
+                layers.fill_constant_like(
+                    layers.cast(mlm_lbl, "float32"), -0.5)),
+            "float32",
+        )
+        mlm_count = layers.elementwise_max(
+            layers.reduce_sum(is_masked),
+            layers.fill_constant([], "float32", 1.0))
+        mlm_loss = layers.elementwise_div(
+            layers.reduce_sum(layers.elementwise_mul(ce, is_masked)), mlm_count)
 
-    nsp_loss = layers.mean(
-        layers.softmax_with_cross_entropy(nsp_logits, nsp_lbl))
+    with fluid.name_scope("nsp_head"):
+        nsp_loss = layers.mean(
+            layers.softmax_with_cross_entropy(nsp_logits, nsp_lbl))
     loss = layers.elementwise_add(mlm_loss, nsp_loss)
     return {
         "feeds": [ids, type_ids, pad, mlm_lbl, nsp_lbl],

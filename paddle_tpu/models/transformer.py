@@ -206,26 +206,36 @@ def _position_ids(ids):
 
 
 def encoder_layer(x, bias, cfg, i, is_test):
+    # name scopes (framework.name_scope): enc<i>/attn, enc<i>/ffn
     p = f"enc{i}"
-    ln_x = _ln(x, f"{p}_preattn")
-    attn = _multi_head_attention(ln_x, ln_x, bias, cfg, f"{p}_attn", is_test)
-    x = _pre_post(attn, x, cfg, p, is_test)
-    ff = _ffn(_ln(x, f"{p}_preffn"), cfg, p, is_test)
-    return _pre_post(ff, x, cfg, p, is_test)
+    with fluid.name_scope(p):
+        with fluid.name_scope("attn"):
+            ln_x = _ln(x, f"{p}_preattn")
+            attn = _multi_head_attention(ln_x, ln_x, bias, cfg, f"{p}_attn",
+                                         is_test)
+            x = _pre_post(attn, x, cfg, p, is_test)
+        with fluid.name_scope("ffn"):
+            ff = _ffn(_ln(x, f"{p}_preffn"), cfg, p, is_test)
+            return _pre_post(ff, x, cfg, p, is_test)
 
 
 def decoder_layer(x, enc_out, self_bias, cross_bias, cfg, i, is_test):
+    # name scopes: dec<i>/self, dec<i>/cross, dec<i>/ffn
     p = f"dec{i}"
-    attn = _multi_head_attention(_ln(x, f"{p}_preself"), _ln(x, f"{p}_preself"),
-                                 self_bias, cfg, f"{p}_self", is_test,
-                                 causal=True)
-    x = _pre_post(attn, x, cfg, p, is_test)
-    ln_x = _ln(x, f"{p}_precross")
-    cross = _multi_head_attention(ln_x, enc_out, cross_bias, cfg,
-                                  f"{p}_cross", is_test)
-    x = _pre_post(cross, x, cfg, p, is_test)
-    ff = _ffn(_ln(x, f"{p}_preffn"), cfg, p, is_test)
-    return _pre_post(ff, x, cfg, p, is_test)
+    with fluid.name_scope(p):
+        with fluid.name_scope("self"):
+            attn = _multi_head_attention(
+                _ln(x, f"{p}_preself"), _ln(x, f"{p}_preself"), self_bias,
+                cfg, f"{p}_self", is_test, causal=True)
+            x = _pre_post(attn, x, cfg, p, is_test)
+        with fluid.name_scope("cross"):
+            ln_x = _ln(x, f"{p}_precross")
+            cross = _multi_head_attention(ln_x, enc_out, cross_bias, cfg,
+                                          f"{p}_cross", is_test)
+            x = _pre_post(cross, x, cfg, p, is_test)
+        with fluid.name_scope("ffn"):
+            ff = _ffn(_ln(x, f"{p}_preffn"), cfg, p, is_test)
+            return _pre_post(ff, x, cfg, p, is_test)
 
 
 
@@ -288,17 +298,22 @@ def build(cfg: Optional[TransformerConfig] = None, is_test: bool = False):
      enc_bias, dec_self_bias) = _train_feeds_and_biases()
     cross_bias = enc_bias  # same src padding bias, broadcast over query dim
 
-    enc = _embed(src, cfg.src_vocab_size, cfg, "src_emb.w", "src_pos.w", is_test)
+    with fluid.name_scope("embed_src"):
+        enc = _embed(src, cfg.src_vocab_size, cfg, "src_emb.w", "src_pos.w",
+                     is_test)
     for i in range(cfg.n_layer):
         enc = encoder_layer(enc, enc_bias, cfg, i, is_test)
     enc = _ln(enc, "enc_post")
 
-    dec = _embed(trg, cfg.trg_vocab_size, cfg, "trg_emb.w", "trg_pos.w", is_test)
+    with fluid.name_scope("embed_trg"):
+        dec = _embed(trg, cfg.trg_vocab_size, cfg, "trg_emb.w", "trg_pos.w",
+                     is_test)
     for i in range(cfg.n_layer):
         dec = decoder_layer(dec, enc, dec_self_bias, cross_bias, cfg, i, is_test)
     dec = _ln(dec, "dec_post")
 
-    logits, token_count, loss = _loss_head(dec, lbl, trg_pad, cfg)
+    with fluid.name_scope("loss_head"):
+        logits, token_count, loss = _loss_head(dec, lbl, trg_pad, cfg)
     return {
         "feeds": [src, trg, lbl, src_pad, trg_pad],
         "loss": loss,

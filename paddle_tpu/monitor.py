@@ -26,7 +26,9 @@ process-wide plane with three pillars.
    consistent dotted names; with telemetry on, every span additionally
    feeds the ``pt_span_seconds`` histogram (interval measured with
    ``time.perf_counter`` — wall clock is only ever used for
-   human-readable timestamps).
+   human-readable timestamps) and opens a
+   ``jax.profiler.TraceAnnotation``, so that inside a ``jax.profiler``
+   session the span sits on the device trace's clock.
 
 Grown in PR 2 with the compile & memory observability plane:
 
@@ -797,31 +799,49 @@ def span_stack() -> Tuple[str, ...]:
     return tuple(getattr(_TLS, "spans", ()))
 
 
-def span(name: str):
-    """RAII span with one timeline: always emits a host chrome-trace span
-    through ``profiler.record_event`` (a no-op unless the profiler is
-    on); with telemetry on, additionally times the body with
-    ``perf_counter`` into the ``pt_span_seconds`` histogram labelled by
-    span name. When telemetry is off this returns the record_event
-    context manager directly — byte-identical behavior and allocation
-    profile to calling the profiler yourself."""
+# what span() hands out on the off path: one shared object, no generator
+_NULL_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **ids):
+    """RAII span, the one span API. Sinks: the host chrome trace and
+    the trace ring through ``profiler.record_event`` (no-ops unless
+    the profiler is on / trace collection is active); with telemetry
+    on, additionally the ``pt_span_seconds`` histogram labelled by span
+    name (``perf_counter``) and a ``jax.profiler.TraceAnnotation(name,
+    **ids)``, which records only while a ``jax.profiler`` session is
+    open and puts the span on the DEVICE trace's clock (plane
+    ``/host:CPU``, the calling thread's line, ``ids`` as stats), so an
+    idle gap of the device can be named by what the host was doing.
+    ``ids`` (e.g. ``step=``) reach only that sink.
+
+    Off path (telemetry off and the profiler not recording: what every
+    untraced run and every user runs): two boolean checks and one
+    shared null context."""
     if not _enabled:
+        if not _profiler._host_enabled:
+            return _NULL_SPAN
         return _profiler.record_event(name)
-    return _timed_span(name)
+    return _timed_span(name, ids)
+
+
+_TraceAnnotation = None   # jax.profiler's, imported at the first span
 
 
 @contextlib.contextmanager
-def _timed_span(name: str):
-    global _span_seconds
+def _timed_span(name: str, ids: Dict[str, Any]):
+    global _span_seconds, _TraceAnnotation
     if _span_seconds is None:
         _span_seconds = histogram(
             "pt_span_seconds", "host span durations by span name")
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
     stack = getattr(_TLS, "spans", None)
     if stack is None:
         stack = _TLS.spans = []
     stack.append(name)
     t0 = time.perf_counter()
-    with _profiler.record_event(name):
+    with _profiler.record_event(name), _TraceAnnotation(name, **ids):
         try:
             yield
         finally:

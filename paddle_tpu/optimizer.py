@@ -16,6 +16,7 @@ from paddle_tpu.framework import (
     Parameter,
     Variable,
     default_main_program,
+    op_role_guard,
     program_guard,
 )
 from paddle_tpu.layer_helper import LayerHelper
@@ -121,6 +122,12 @@ class Optimizer:
 
     def apply_gradients(self, params_grads):
         """Returns the optimizer update Operators appended to the block."""
+        # clip, regularizer, learning-rate and update ops: the step's
+        # optimizer phase
+        with op_role_guard(default_main_program(), "opt"):
+            return self._apply_gradients(params_grads)
+
+    def _apply_gradients(self, params_grads):
         prog = default_main_program()
         block = prog.global_block()
         self._create_lr_var()

@@ -387,6 +387,14 @@ def _seed_arr(seed):
     return seed
 
 
+# Every pl.pallas_call below carries name="attn.<family>.<pass>" (the
+# families are attention_ops' dispatch counter's; the passes fwd, bwd,
+# bwd_dq, bwd_dkv): jax puts a kernel's name on the HLO instruction AND
+# into its op_name, under the sdpa op's scope (core/interp.exec_ops), so
+# a device trace tells one attention kernel from another and forward
+# from backward.
+
+
 def _result(operands, shape, dtype):
     """out_shape entry of a pallas_call over ``operands``: the result
     varies over the same manual mesh axes as they do. Inside a shard_map
@@ -465,7 +473,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
 
     operands = (_seed_arr(seed), *args)
     out, lse = pl.pallas_call(
-        kernel,
+        kernel, name="attn.bhtd.fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nq, nk),
@@ -552,7 +560,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 
     operands = (seed_arr, *dq_args)
     dq = pl.pallas_call(
-        dq_kernel,
+        dq_kernel, name="attn.bhtd.bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nq, nk),
@@ -593,7 +601,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 
     operands = (seed_arr, *dkv_args)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        dkv_kernel, name="attn.bhtd.bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nk, nq),
@@ -1173,7 +1181,7 @@ def _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop, causal=False):
         )
     operands = (_seed_arr(seed), *args)
     out2, lse2 = pl.pallas_call(
-        kernel,
+        kernel, name="attn.bthd_kblock.fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nq, nk),
@@ -1238,7 +1246,7 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
         )
     operands = (_seed_arr(seed), *base_args, *tail_args)
     dq2, dk2, dv2 = pl.pallas_call(
-        kernel,
+        kernel, name="attn.bthd_kblock.bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nq, nk),
@@ -1297,7 +1305,7 @@ def bthd_dropout_masks(b, tq, tk, h, dh, p_drop, seed):
 
     operands = (_seed_arr(seed),)
     out = pl.pallas_call(
-        kern,
+        kern, name=f"attn.{family}.dropout_masks",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, tq // cq), in_specs=[],
             out_specs=pl.BlockSpec((1, cq, h * tk),
@@ -1387,7 +1395,7 @@ def flash_attention_bthd_fwd(q, k, v, bias=None, seed=None, scale=None,
         )
     operands = (_seed_arr(seed), *args)
     out2, lse2 = pl.pallas_call(
-        kernel,
+        kernel, name="attn.bthd_small.fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nq),
@@ -1477,7 +1485,7 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 
     operands = (_seed_arr(seed), *base_args, *tail_args)
     dq2, dk2, dv2 = pl.pallas_call(
-        kernel,
+        kernel, name="attn.bthd_small.bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nq),

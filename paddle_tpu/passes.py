@@ -271,9 +271,12 @@ def _unstable_vars(block):
 
 def _op_key(op):
     """Hashable identity of a pure op: (type, sorted inputs, sorted
-    attrs). None when any attr resists cheap stable serialization."""
+    attrs its kernel sees: two ops that differ only in name scope
+    compute the same value). None when any attr resists cheap stable
+    serialization."""
     try:
-        attrs = tuple(sorted((k, repr(v)) for k, v in op.attrs.items()))
+        attrs = tuple(sorted((k, repr(v))
+                             for k, v in op.compute_attrs().items()))
     except Exception:
         return None
     ins = tuple(sorted((slot, tuple(ns)) for slot, ns in op.inputs.items()))

@@ -125,8 +125,13 @@ OVERHEAD_FRACTION = 1 / 3
 # — the fields read here have never been renumbered):
 #   XSpace.planes = 1;  XPlane.name = 2, .lines = 3, .event_metadata = 4
 #   (map<int64, XEventMetadata>: key = 1, value = 2; XEventMetadata.name
-#   = 2);  XLine.name = 2, .events = 4;  XEvent.metadata_id = 1,
-#   .duration_ps = 3.
+#   = 2, .stats = 5), .stat_metadata = 5 (map<int64, XStatMetadata>,
+#   XStatMetadata.name = 2);  XLine.name = 2, .events = 4;
+#   XEvent.metadata_id = 1, .duration_ps = 3;  XStat.metadata_id = 1,
+#   .str_value = 5, .ref_value = 7 (a stat_metadata id whose NAME is the
+#   value). The one stat read is an event metadata's ``tf_op``: the HLO
+#   instruction's op_name, which carries the scope the lowering opened
+#   around the program op it came from (core/interp.exec_ops).
 
 
 def _read_varint(buf: bytes, i: int) -> Tuple[int, int]:
@@ -166,26 +171,50 @@ def _fields(buf: bytes):
         yield fnum, wt, v
 
 
+def _map_entry(buf: bytes):
+    """(key, value bytes) of one protobuf map entry (key = 1, value = 2)."""
+    key, val = None, b""
+    for fnum, _wt, v in _fields(buf):
+        if fnum == 1:
+            key = v
+        elif fnum == 2:
+            val = v
+    return key, val
+
+
+def _name_and_stats(buf: bytes):
+    """(name, [raw XStat, ...]) of an XEventMetadata / XStatMetadata."""
+    name, stats = "", []
+    for fnum, _wt, v in _fields(buf):
+        if fnum == 2:
+            name = v.decode(errors="replace")
+        elif fnum == 5:
+            stats.append(v)
+    return name, stats
+
+
 def _parse_plane(buf: bytes):
     """(name, {metadata_id: event_name},
-    [(line_name, [(metadata_id, duration_ps), ...]), ...])."""
+    [(line_name, [(metadata_id, duration_ps), ...]), ...],
+    {metadata_id: tf_op})."""
     name = ""
     meta: Dict[int, str] = {}
+    raw_stats: Dict[int, List[bytes]] = {}
+    stat_names: Dict[int, str] = {}
     lines: List[Tuple[str, List[Tuple[int, int]]]] = []
     for fnum, _wt, v in _fields(buf):
         if fnum == 2:
             name = v.decode(errors="replace")
+        elif fnum == 5:  # stat_metadata map entry
+            sid, sm = _map_entry(v)
+            if sid is not None:
+                stat_names[sid] = _name_and_stats(sm)[0]
         elif fnum == 4:  # event_metadata map entry
-            mid, mname = None, ""
-            for f2, _w2, v2 in _fields(v):
-                if f2 == 1:
-                    mid = v2
-                elif f2 == 2:  # XEventMetadata
-                    for f3, _w3, v3 in _fields(v2):
-                        if f3 == 2:
-                            mname = v3.decode(errors="replace")
+            mid, em = _map_entry(v)
             if mid is not None:
-                meta[mid] = mname
+                meta[mid], mstats = _name_and_stats(em)
+                if mstats:
+                    raw_stats[mid] = mstats
         elif fnum == 3:  # XLine
             line_name = ""
             events: List[Tuple[int, int]] = []
@@ -201,7 +230,51 @@ def _parse_plane(buf: bytes):
                             dur_ps = v3
                     events.append((mid, dur_ps))
             lines.append((line_name, events))
-    return name, meta, lines
+    # the stat names follow the event metadata in the file: resolve last
+    tf_ops: Dict[int, str] = {}
+    for mid, mstats in raw_stats.items():
+        for st in mstats:
+            key, val = None, ""
+            for f, _w, v in _fields(st):
+                if f == 1:
+                    key = stat_names.get(v)
+                elif f == 5:
+                    val = v.decode(errors="replace")
+                elif f == 7:
+                    val = stat_names.get(v, "")
+            if key == "tf_op" and val:
+                tf_ops[mid] = val
+    return name, meta, lines, tf_ops
+
+
+def scope_of(tf_op: str) -> Optional[str]:
+    """``<phase>/<name scope>/<op type>`` out of an HLO op_name
+    (``jit(step_fn)/bwd/enc0/ffn/mul_grad/transpose(jvp())/dot_general``
+    -> ``bwd/enc0/ffn/mul_grad``): from the first component that is a
+    phase up to the first that is a registered op type; None when the
+    lowering put no scope into it. A control-flow op's sub-block nests
+    under it, so the scope is the OUTERMOST op's."""
+    parts, depth, cur = [], 0, []
+    for ch in tf_op.rsplit(":", 1)[0]:   # the stat is <op_name>:<type>
+        if ch == "/" and depth == 0:
+            parts.append("".join(cur))
+            cur = []
+            continue
+        depth += (ch == "(") - (ch == ")")
+        cur.append(ch)
+    parts.append("".join(cur))
+    from paddle_tpu.core.registry import GRAD_OP_SUFFIX, has_op
+    from paddle_tpu.framework import OP_ROLES
+
+    at = next((i for i, p in enumerate(parts) if p in OP_ROLES), None)
+    if at is None:
+        return None
+    for j in range(at + 1, len(parts)):
+        p = parts[j]
+        if has_op(p) or (p.endswith(GRAD_OP_SUFFIX)
+                         and has_op(p[:-len(GRAD_OP_SUFFIX)])):
+            return "/".join(parts[at:j + 1])
+    return None
 
 
 # A TPU device plane carries SEVERAL lines covering the same wall
@@ -258,7 +331,7 @@ def _parse_capture(path: str, warn: bool = True):
             for fnum, _wt, v in _fields(buf):
                 if fnum != 1:  # XSpace.planes
                     continue
-                name, meta, lines = _parse_plane(v)
+                name, meta, lines, tf_ops = _parse_plane(v)
                 if "/device:" not in name:
                     continue
                 total = 0.0
@@ -269,6 +342,8 @@ def _parse_capture(path: str, warn: bool = True):
                         if cell is None:
                             cell = ops[op] = {"seconds": 0.0,
                                               "count": 0}
+                            if mid in tf_ops:  # only where the trace
+                                cell["tf_op"] = tf_ops[mid]  # has one
                         cell["seconds"] += dur_ps / 1e12
                         cell["count"] += 1
                         total += dur_ps / 1e12
@@ -297,8 +372,10 @@ def parse_xplane(path: str,
 
     ``path``: a trace dir (searched recursively for ``*.xplane.pb`` —
     the layout ``jax.profiler.start_trace`` writes) or one ``.pb``
-    file. Returns ``{op_name: {"seconds", "count"}}`` summed over every
-    ``/device:*`` plane, or ``None`` — with exactly ONE warning — when
+    file. Returns ``{op_name: {"seconds", "count"}}`` (plus ``"tf_op"``,
+    the HLO instruction's op_name, where the trace carries that stat)
+    summed over every ``/device:*`` plane, or ``None`` — with exactly
+    ONE warning — when
     the capture is unavailable: no file, a truncated/corrupt proto, or
     no device plane at all (the CPU container's trace has only host
     planes). Callers then take the ``source: "estimate"`` path
@@ -469,8 +546,12 @@ DEVICE_PROFILE_FIELDS: Dict[str, tuple] = {
                 "(no cost numbers)"),
     "top_ops": ((list,), True,
                 "top-K ops by device seconds: [{name, group, seconds, "
-                "count, share, framework_ops}]; on the estimate path "
-                "the op_histogram's types with null seconds"),
+                "count, share, framework_ops}] plus 'scope' "
+                "('<phase>/<name scope>/<op type>') where the trace's "
+                "tf_op stat names the program op, in which case "
+                "framework_ops is that op's type and not the group "
+                "shortlist; on the estimate path the op_histogram's "
+                "types with null seconds"),
     "groups": ((dict,), True,
                "per-group device-time rollup: group -> {seconds, "
                "share, count} (empty on the estimate path)"),
@@ -588,14 +669,23 @@ def build_device_profile(program, *, source: str,
             g["share"] = g["seconds"] / total
         ranked = sorted(op_seconds.items(),
                         key=lambda kv: -kv[1]["seconds"])[:top_k]
-        top_ops = [{
-            "name": name,
-            "group": classify_hlo(name),
-            "seconds": cell["seconds"],
-            "count": int(cell["count"]),
-            "share": cell["seconds"] / total,
-            "framework_ops": map_to_framework_ops(name, op_histogram),
-        } for name, cell in ranked]
+        for name, cell in ranked:
+            row = {
+                "name": name,
+                "group": classify_hlo(name),
+                "seconds": cell["seconds"],
+                "count": int(cell["count"]),
+                "share": cell["seconds"] / total,
+            }
+            scope = scope_of(cell.get("tf_op", ""))
+            if scope is not None:
+                # the trace names the program op: no guess needed
+                row["scope"] = scope
+                row["framework_ops"] = [scope.rsplit("/", 1)[1]]
+            else:
+                row["framework_ops"] = map_to_framework_ops(
+                    name, op_histogram)
+            top_ops.append(row)
     elif op_histogram:
         top_ops = [{
             "name": op, "group": "framework", "seconds": None,
