@@ -60,10 +60,16 @@ KERNEL_REL_TOL = 0.02
 # epsilons (on the v5e 3.6e-3 at most before a parting and 1.2e-3 at
 # one; 3.7e-7 at "highest" precision, where no stream parts)
 SERVE_LOGIT_REL_TOL = 0.02
-# data-parallel loss vs the one-chip loss on the same feeds: same masks,
-# same math, bf16 matmuls tiled and reduced in another order (4e-6 seen
-# on four v5e chips)
+# data-parallel loss vs the one-chip loss on the same feeds. Without
+# dropout: same math, bf16 matmuls tiled and reduced in another order
+# (4e-6 seen on four v5e chips with equal masks). With dropout the
+# residual and FFN masks are drawn per shard under a mesh (the dropout
+# op's stream is keyed by the shard's index, ops/nn_ops._draw_bits), so
+# the losses agree as two draws of the masks do: 1.6e-2 seen over 128
+# tokens on the CPU mesh, 2.0e-4 over this phase's 16k tokens on four
+# v5e chips
 DP_LOSS_REL_TOL = 1e-3
+DP_DROPOUT_LOSS_REL_TOL = 5e-2
 
 
 class SmokeFailure(AssertionError):
@@ -464,7 +470,8 @@ def serve_phase(cfg, slots=8, src_len=32, max_len=57, max_new=24,
 def dp_phase(cfg, one_chip_losses, batch=64, seq=256):
     """The train program of phase 2 under with_data_parallel over all
     devices, same seed and feeds: the losses must match the one-chip
-    steps, state must span the mesh, each feed shard is batch/n."""
+    steps (as closely as the masks allow: DP_LOSS_REL_TOL), state must
+    span the mesh, each feed shard is batch/n."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -496,9 +503,10 @@ def dp_phase(cfg, one_chip_losses, batch=64, seq=256):
     check(all(np.isfinite(x) for x in losses),
           f"data-parallel loss not finite: {losses}")
     rel = [abs(a - b) / abs(b) for a, b in zip(losses, one_chip_losses)]
-    check(max(rel) <= DP_LOSS_REL_TOL,
+    tol = DP_DROPOUT_LOSS_REL_TOL if cfg.dropout else DP_LOSS_REL_TOL
+    check(max(rel) <= tol,
           f"data-parallel losses {losses} vs one-chip {one_chip_losses}: "
-          f"relative difference {max(rel):.2e} > {DP_LOSS_REL_TOL}")
+          f"relative difference {max(rel):.2e} > {tol}")
     spans = {name: len(scope.find_var(name).sharding.device_set)
              for name in scope.var_names()}
     narrow = {k: v for k, v in spans.items() if v != n}
@@ -508,7 +516,7 @@ def dp_phase(cfg, one_chip_losses, batch=64, seq=256):
         "devices": n, "global_batch": batch, "feed_shard_rows": batch // n,
         "step_losses": [round(x, 5) for x in losses],
         "one_chip_losses": [round(x, 5) for x in one_chip_losses],
-        "max_rel_diff": float(f"{max(rel):.3e}"),
+        "max_rel_diff": float(f"{max(rel):.3e}"), "rel_tol": tol,
         "state_arrays": len(spans),
         "first_step_s": round(secs[0], 2),
         "device_bytes_in_use": [

@@ -162,6 +162,32 @@ def spmd_ctx_scope(strategy):
         _SPMD_CTX.reset(tok)
 
 
+MeshSplit = collections.namedtuple(
+    "MeshSplit", ["mesh", "free", "nested", "axis", "n"])
+
+
+def mesh_batch_split():
+    """How an op that GSPMD cannot partition splits its batch when it
+    wraps itself in a shard_map under the program's mesh (the Pallas
+    attention calls, dropout's random words): ``free`` are the mesh
+    axes still automatic here, ``nested`` says an enclosing shard_map (a
+    GPipe stage) already made the others manual, ``axis`` are the free
+    data axes (dim 0 splits over them), ``n`` their ranks. None on one
+    device and where every axis is manual already."""
+    ctx = spmd_ctx()
+    if ctx is None:
+        return None
+    from paddle_tpu.parallel.mesh import axis_size, axis_tuple
+
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    free = frozenset(a for a in ctx.mesh.axis_names if a not in manual)
+    if not free:
+        return None
+    axis = tuple(a for a in axis_tuple(ctx.data_axis) if a in free)
+    return MeshSplit(ctx.mesh, free, bool(manual), axis,
+                     axis_size(ctx.mesh, axis))
+
+
 def _is_f32(v):
     return v is not None and hasattr(v, "dtype") and v.dtype == jnp.float32
 

@@ -133,23 +133,14 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims):
     shard hands the kernels its first GLOBAL batch row along with the
     seed, so the dropout masks do not depend on how many devices split
     the batch."""
-    ctx = interp.spmd_ctx()
-    # axes an enclosing shard_map (a GPipe stage) already made manual
-    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-    free = (frozenset(a for a in ctx.mesh.axis_names if a not in manual)
-            if ctx is not None else frozenset())
-    if not free:
+    split = interp.mesh_batch_split()
+    if split is None:
         _note_dispatch(family, direction, dims)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import axis_size, axis_tuple
-
-    mesh = ctx.mesh
+    mesh, free, nested, axis, n = split
     b = arrays[0].shape[0]
-    data = axis_tuple(ctx.data_axis) if ctx.data_axis else ()
-    axis = tuple(a for a in data if a in free)  # batch axes to split here
-    n = axis_size(mesh, axis)
     if b % n != 0:
         # (the batch-sharded feeds could not split it either)
         raise ValueError(
@@ -174,7 +165,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims):
             [seed.astype(jnp.int32), jnp.asarray(row0, jnp.int32)]))
 
     # (nested in a manual region, the mesh is the context's)
-    return jax.shard_map(local, mesh=None if manual else mesh,
+    return jax.shard_map(local, mesh=None if nested else mesh,
                          in_specs=(P(), *specs), out_specs=batch,
                          axis_names=free)(seed, *present)
 

@@ -13,7 +13,33 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import monitor as _monitor
+from paddle_tpu.core import interp
 from paddle_tpu.core.registry import register_op
+
+# Runs at TRACE time (once per compile, like pt_attention_dispatch_total):
+# how each lowered draw of random words was laid over the program's mesh.
+_M_RNG_DRAW = _monitor.counter(
+    "pt_rng_draw_total",
+    "random-word draws lowered, by op, sharded_over (the data axes the "
+    "draw was split over, each shard drawing its own rows; empty on one "
+    "device) and replicated_over (mesh axes of size > 1 whose every rank "
+    "repeats the same draw)")
+
+
+def rng_draw_counts():
+    """{"op[ sharded_over=axes][ replicated_over=axes]": draws lowered
+    so far} — pt_rng_draw_total as attention_ops.dispatch_counts() gives
+    the dispatch counter."""
+    out = {}
+    for row in _monitor.snapshot()[_M_RNG_DRAW.name]["values"]:
+        lb = row["labels"]
+        name = lb.get("op", "?")
+        for k in ("sharded_over", "replicated_over"):
+            if lb.get(k):
+                name += f" {k}={lb[k]}"
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
 
 
 def _x(ins, slot="X", i=0):
@@ -240,8 +266,63 @@ def _layer_norm(ins, attrs):
     }
 
 
+def _draw_bits(op, rng, shape):
+    """uint16 random words of ``shape`` for op ``op``, each device
+    drawing only the rows it holds. XLA's SPMD partitioner cannot split
+    a RngBitGenerator: under a mesh a plain ``jax.random.bits`` makes
+    every chip generate the words of the GLOBAL batch and keep its
+    share. So where the program is lowered for a mesh whose data axes
+    are still automatic and dim 0 divides over them, only the draw goes
+    into a shard_map over those axes: each shard folds its index along
+    them into the key and draws its local ``[b/n, ...]``. Anywhere else
+    (one device; no data axis; an indivisible or missing dim 0; inside a
+    GPipe stage, where the data axes are manual already) it is the plain
+    draw, and pt_rng_draw_total names the axes that repeat it."""
+    split = interp.mesh_batch_split()
+    sharded = (split is not None and not split.nested and split.n > 1
+               and len(shape) >= 1 and shape[0] % split.n == 0)
+    axis = split.axis if sharded else ()
+    if _monitor.enabled() and interp.lowering_active():
+        ctx = interp.spmd_ctx()
+        # a key that already varies over a manual axis (a GPipe stage
+        # folds its pipe rank in) is not repeated over that axis
+        varies = set(axis) | set(getattr(jax.typeof(rng), "vma", ()))
+        _M_RNG_DRAW.inc(labels={
+            "op": op, "sharded_over": ",".join(axis),
+            "replicated_over": ",".join(sorted(
+                a for a in (ctx.mesh.axis_names if ctx else ())
+                if ctx.mesh.shape[a] > 1 and a not in varies))})
+    if not sharded:
+        return jax.random.bits(rng, shape, dtype=jnp.uint16)
+    from jax.sharding import PartitionSpec as P
+
+    local_shape = (shape[0] // split.n, *shape[1:])
+
+    def local(key):
+        key = jax.random.fold_in(key, jax.lax.axis_index(axis))
+        return jax.random.bits(key, local_shape, dtype=jnp.uint16)
+
+    return jax.shard_map(local, mesh=split.mesh, in_specs=P(),
+                         out_specs=P(axis), axis_names=set(axis))(rng)
+
+
 @register_op("dropout", needs_rng=True)
 def _dropout(ins, attrs, rng=None):
+    """Out = X with each element kept with probability 1 - p, Mask the
+    uint8 keep-mask the backward consumes.
+
+    The random stream's contract. On one device the mask is
+    ``jax.random.bits(key, shape(x), uint16) < threshold`` on the op's
+    key for this step, nothing else. Under a mesh with data axes the
+    mask is keyed by (the op's key for this step, the shard's index
+    along the data axes): each shard draws its own rows (_draw_bits), so
+    for one seed the mask depends on how many shards split the batch, as
+    any non-partitionable generator's does, and two shards never share a
+    mask. The keep probability, the uint16 threshold, upscale_in_train,
+    the saved Mask and dropout_grad are the same mathematics at the
+    same precision on every path. Under dp x tp an activation that is
+    also split over the model axis is drawn whole by every model rank
+    (the op cannot observe that split)."""
     x = _x(ins)
     p = attrs.get("dropout_prob", 0.5)
     is_test = attrs.get("is_test", False)
@@ -256,7 +337,7 @@ def _dropout(ins, attrs, rng=None):
     # random-bits-bound on TPU, so uint16 halves its cost vs the uint32
     # words bernoulli() draws; 1/65536 probability granularity (~2e-5
     # keep-rate bias worst case) is far below dropout's statistical noise.
-    bits = jax.random.bits(rng, jnp.shape(x), dtype=jnp.uint16)
+    bits = _draw_bits("dropout", rng, jnp.shape(x))
     keep = bits < jnp.uint16(min(round((1.0 - p) * 65536.0), 65535))
     if impl == "upscale_in_train":
         y = jnp.where(keep, x / (1.0 - p), jnp.zeros((), x.dtype))
