@@ -4,6 +4,10 @@ from perf import data, flops
 
 CONFIG_KEYS = ("vocab_size", "max_position", "type_vocab_size", "d_model",
                "d_inner", "n_head", "n_layer", "dropout")
+# the family's sizes for the CPU tests (tests/perfbench/perfbench_tiny):
+# laid over a configuration file, they compile in seconds
+TINY = dict(d_model=32, d_inner=64, n_head=4, n_layer=2,
+            vocab_size=50, max_position=16)
 
 
 def program_config(cfg, **overrides):

@@ -6,6 +6,10 @@ CONFIG_KEYS = ("src_vocab_size", "trg_vocab_size", "max_length", "d_model",
                "d_inner", "n_head", "n_layer", "dropout", "label_smooth_eps")
 # the inference graph: no dropout, no label smoothing
 SERVE_OVERRIDES = {"dropout": 0.0, "label_smooth_eps": 0.0}
+# the family's sizes for the CPU tests (tests/perfbench/perfbench_tiny):
+# laid over a configuration file, they compile in seconds
+TINY = dict(d_model=32, d_inner=64, n_head=4, n_layer=2,
+            src_vocab_size=50, trg_vocab_size=60, max_length=32)
 
 
 def program_config(cfg, **overrides):

@@ -31,7 +31,7 @@ def say_first_calls(run: "Run"):
 
 def say_trace(run: "Run", what: str):
     keys = ("devices", "window_s", "busy_s", "idle_share", "by_kind_s",
-            "ops_seen")
+            "by_kernel_s", "ops_seen")
     say(f"perf: traced {what}: "
         f"{run.trace and {k: run.trace[k] for k in keys}}")
 
@@ -56,7 +56,7 @@ class Run:
         self.window: Dict[str, Any] = {}    # the kind's observations
         self.counters: Dict[str, Dict] = {}  # snapshots, by name
         self.trace: Optional[Dict] = None   # perf.trace.reduce(...)
-        self.check: Dict[str, float] = {}   # the correctness sample
+        self.check: Dict[str, Any] = {}     # the correctness sample
         self.attempted = 0
         self.failed = 0
         self.problems: List[str] = []    # why correct is False

@@ -39,7 +39,7 @@ def run(run: harness.Run):
     harness.telemetry(run.traced)
 
     # --- set-up: weights on the device from the seed --------------------
-    main, startup, evalp, loss = models.build_train(cfg, run.seed)
+    main, startup, evalp, loss, model = models.build_train(cfg, run.seed)
     scope = fluid.Scope()
     exe = fluid.Executor()
     t0 = time.perf_counter()
@@ -54,6 +54,10 @@ def run(run: harness.Run):
     t0 = time.perf_counter()
     check_loss(run, exe, evalp, loss, scope, feeds_np[0])
     run.first_calls["eval_sample"] = time.perf_counter() - t0
+    if hasattr(models.reference(cfg), "second_check"):
+        t0 = time.perf_counter()
+        check_second(run, exe, evalp, model, scope, feeds_np[0])
+        run.first_calls["eval_second"] = time.perf_counter() - t0
 
     program = main
     sharding = None
@@ -151,6 +155,11 @@ def steady(step, drain, first, seconds):
     return len(pend)
 
 
+def sample_of(feed):
+    """The first SAMPLE_SEQUENCES sequences of a feed."""
+    return {k: np.asarray(v)[:SAMPLE_SEQUENCES] for k, v in feed.items()}
+
+
 def check_loss(run, exe, evalp, loss, scope, feed):
     """Eval-mode clone of the program on SAMPLE_SEQUENCES sequences of
     the cell's length against the plain float32 reference on the same
@@ -161,7 +170,7 @@ def check_loss(run, exe, evalp, loss, scope, feed):
     from perf.reference.common import weights_from_scope
 
     ref = models.reference(run.config)
-    sample = {k: np.asarray(v)[:SAMPLE_SEQUENCES] for k, v in feed.items()}
+    sample = sample_of(feed)
     got = float(np.asarray(exe.run(evalp, feed=sample, fetch_list=[loss],
                                    scope=scope)[0]))
     w = weights_from_scope(scope)
@@ -179,3 +188,36 @@ def check_loss(run, exe, evalp, loss, scope, feed):
         run.problem(f"eval loss {got} differs from the reference {want} "
                     f"by {rel:.2e} > {LOSS_REL_TOL}")
 
+
+def check_second(run, exe, evalp, model, scope, feed):
+    """The family's own second check, where its reference defines one:
+    it can only add to ``check_loss``, whose verdict stands. The
+    family file names what to fetch (``CHECK_FETCH``: keys of the dict
+    its ``build_graph`` returns, each a variable or a list of them; a
+    language model's would be the logits of the sample's last
+    positions), the loop fetches that from the same eval clone on the
+    same sample, and the reference's ``second_check(w, cfg, sample,
+    fetched)`` (float32 weights, the sample's feed, {key: array or
+    list of arrays}; run at "highest" matmul precision) returns
+    (problems, record): each problem, in words, makes the run
+    incorrect, the record is printed and kept as
+    ``run.check["second"]``."""
+    import jax
+
+    from perf.reference.common import weights_from_scope
+
+    sample = sample_of(feed)
+    # (a Variable is a leaf to jax.tree: lists of them come back as lists)
+    fetch, shape = jax.tree.flatten(
+        {k: model[k] for k in models.family(run.config).CHECK_FETCH})
+    fetched = jax.tree.unflatten(shape, [np.asarray(g) for g in exe.run(
+        evalp, feed=sample, fetch_list=fetch, scope=scope)])
+    w = weights_from_scope(scope)
+    with jax.default_matmul_precision("highest"):
+        problems, record = models.reference(run.config).second_check(
+            w, run.config, sample, fetched)
+    del w
+    run.check["second"] = record
+    say(f"perf: correctness sample, second check: {record}")
+    for p in problems:
+        run.problem(p)

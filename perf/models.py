@@ -32,11 +32,12 @@ def program_seed(seed: int) -> int:
 
 
 def build_train(cfg: Dict, seed: int, lr: float = 1e-4):
-    """(main, startup, eval clone, loss variable) of the family's
-    training graph under bf16 AMP with Adam. The eval clone is the same
-    graph with dropout off and no optimizer: the correctness sample
-    runs it. Weights are drawn from ``seed`` by the startup program, on
-    the device."""
+    """(main, startup, eval clone, loss variable, the dict the family's
+    ``build_graph`` returned) of the family's training graph under bf16
+    AMP with Adam. The eval clone is the same graph with dropout off
+    and no optimizer: the correctness sample runs it, and fetches from
+    it by the dict's variables. Weights are drawn from ``seed`` by the
+    startup program, on the device."""
     import paddle_tpu as fluid
 
     fam = family(cfg)
@@ -48,7 +49,7 @@ def build_train(cfg: Dict, seed: int, lr: float = 1e-4):
         fluid.optimizer.Adam(lr).minimize(model["loss"])
     main._amp = True   # bf16 matmuls, f32 master weights
     evalp._amp = True  # the sample runs at the precision that is trained
-    return main, startup, evalp, model["loss"]
+    return main, startup, evalp, model["loss"], model
 
 
 def build_serve_weights(cfg: Dict, seed: int):

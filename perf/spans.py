@@ -2,7 +2,8 @@
 .xplane.pb: per chip the ``XLA Ops`` events with the ``tf_op`` stat of
 their metadata (the HLO instruction's op_name, which carries the
 scopes the program's lowering opens: ``<phase>/<name scope>/<op
-type>`` and, on a Pallas call, ``attn.<family>.<pass>``), and the
+type>`` and, on a Pallas call, the kernel's ``<family>.<what>.<pass>``
+name, as in ``attn.bthd_small.bwd``), and the
 ``/host:CPU`` lines with the spans the program annotates
 (``executor.run`` and its children), on the same clock.
 
@@ -41,7 +42,9 @@ from perf import harness, trace
 
 PHASES = ("fwd", "bwd", "opt")
 HEAD_SCOPES = ("loss_head", "mlm_head")
-KERNEL = re.compile(r"^attn\.[\w]+\.[\w]+$")
+# the name= of a pl.pallas_call: <family>.<what>.<pass>, the family any
+# word (attn today; a later moe needs no edit here)
+KERNEL = re.compile(r"^[A-Za-z]\w*\.\w+\.\w+$")
 RUN_SPANS = ("executor.run", "executor.run_window")
 CHILD_SPANS = ("executor.prepare", "executor.state", "executor.run_step",
                "executor.commit")
@@ -269,7 +272,7 @@ def parse_scope(tf_op: str) -> Optional[Dict]:
     is jax's own (a primitive is the last component; transforms and
     jitted helpers carry brackets; control flow and partitioning are
     JAX_WORDS), the last of them the Fluid op type; kernel is the
-    ``attn.<family>.<pass>`` component where there is one."""
+    ``<family>.<what>.<pass>`` component where there is one."""
     parts = _components(tf_op.rsplit(":", 1)[0])
     at = next((i for i, p in enumerate(parts) if p in PHASES), None)
     if at is None:
@@ -352,8 +355,10 @@ def reduce(doc: Dict, top: int = 15) -> Optional[Dict]:
 
     - ``scoped_ns``: self time of ops whose tf_op carries a phase;
       ``by_phase_ns``; ``head_ns`` (scope under loss_head / mlm_head);
-      ``kernel_ns`` by ``attn.<family>.<pass>``;
-    - ``top_scopes``: [phase/scope/op, ns] by self time; ``collectives``:
+      ``kernel_ns`` by the kernel's ``<family>.<what>.<pass>``;
+    - ``by_scope_ns``: {phase/scope/op: ns}, the whole table (its sum
+      is ``scoped_ns``; ``scope_ns`` sums a part of it), ``top_scopes``
+      its ``top`` largest as [key, ns]; ``collectives``:
       [instruction label, phase/scope/op, ns]; ``unscoped``: the largest
       ops without a phase, by their instruction's label;
     - ``host``: None without executor root spans on the host plane, else
@@ -410,7 +415,7 @@ def reduce(doc: Dict, top: int = 15) -> Optional[Dict]:
     return {
         "chips": len(chips), "busy_ns": busy, "scoped_ns": scoped,
         "by_phase_ns": by_phase, "head_ns": head, "kernel_ns": kernels,
-        "top_scopes": ranked(by_scope),
+        "by_scope_ns": by_scope, "top_scopes": ranked(by_scope),
         "collectives": [[k[0], k[1], v] for k, v in sorted(
             coll.items(), key=lambda kv: -kv[1])],
         "unscoped": ranked(unscoped, 5),
@@ -450,22 +455,44 @@ def _host(doc: Dict, first_chip) -> Optional[Dict]:
 # --- a run's trace -----------------------------------------------------
 
 
-def for_run(run) -> Optional[Dict]:
-    """``reduce`` of the raw trace harness.DeviceTrace left for this
-    run's cell, once per run (the first reader that asks also prints
-    the report); None when the run traced no device op."""
+def doc_for_run(run) -> Optional[Dict]:
+    """``load`` of the raw trace harness.DeviceTrace left for this
+    run's cell, parsed once per run: ``for_run`` and any reader that
+    needs the events themselves share it. None when the run traced no
+    device op or the file is gone."""
     if not run.trace:
         return None
-    if getattr(run, "_spans", None) is None:
+    if getattr(run, "_spans_doc", None) is None:
         try:
             path = trace.find_xplane(
                 os.path.join(harness.TRACE_ROOT, run.cell["name"]))
         except FileNotFoundError:
             return None
-        run._spans = reduce(load(path))
+        run._spans_doc = load(path)
+    return run._spans_doc
+
+
+def for_run(run) -> Optional[Dict]:
+    """``reduce`` of this run's raw trace, once per run (the first
+    reader that asks also prints the report); None when the run traced
+    no device op."""
+    if getattr(run, "_spans", None) is None:
+        doc = doc_for_run(run)
+        if doc is None:
+            return None
+        run._spans = reduce(doc)
         if run._spans:
             report(run._spans, run.window.get("traced_steps") or 1)
     return run._spans
+
+
+def scope_ns(s: Dict, accept) -> float:
+    """Self time (ns) of the scopes ``accept`` takes: it is given the
+    components of a ``by_scope_ns`` key, phase first, op type last
+    (``["bwd", "blk3", "moe", "mul_grad"]``). "Under a ``moe`` scope" is
+    ``scope_ns(s, lambda parts: "moe" in parts[1:-1])``."""
+    return sum(v for k, v in s["by_scope_ns"].items()
+               if accept(k.split("/")))
 
 
 def share(run, key) -> Optional[float]:

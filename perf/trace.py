@@ -11,11 +11,15 @@ executed HLO instruction, NAMED BY THE INSTRUCTION'S FULL TEXT
 ``XLA Modules`` one event per executed program (``jit_step_fn(...)``),
 ``Steps`` one per step, ``Async XLA Ops`` the spans of asynchronous
 copies, slices and collectives whose start/done halves sit on
-``XLA Ops``. A Pallas kernel is a ``custom-call`` whose text carries
-``custom_call_target="tpu_custom_call"``; it is named after the jitted
-function (``%step_fn.36``) and its ``kernel_metadata`` is empty, so the
-trace tells Mosaic kernels from other custom calls but not one kernel
-family from another. Host threads are lines of ``/host:CPU``.
+``XLA Ops``. A Mosaic kernel is a ``custom-call`` whose text carries
+``custom_call_target="tpu_custom_call"``. Its instruction is named
+after the ``name=`` its ``pl.pallas_call`` carries and a number
+(``%attn.bthd_small.bwd.35``: the program's kernels since PR 24), after
+XLA's own word where the compiler made the call (``%ragged-dot-none.3``)
+and after the jitted function where the call has no name
+(``%step_fn.36``): ``kernel_name`` is that name without the number, its
+first dotted component the kernel's family. Host threads are lines of
+``/host:CPU``.
 
 Times are nanoseconds on the profiler's clock. Busy time is the UNION
 of the ``XLA Ops`` intervals (nested or overlapping events count once),
@@ -110,6 +114,12 @@ def op_kind(name: str) -> str:
     return "other"
 
 
+def kernel_name(name: str) -> str:
+    """The kernel of a Mosaic call: its instruction's name without the
+    trailing ``.N`` (``attn.bthd_small.bwd``, ``ragged-dot-none``)."""
+    return re.sub(r"\.\d+$", "", parse(name)[0])
+
+
 def label(name: str) -> str:
     """A short stable label for the breakdown: the instruction (its
     number tells one fusion from the next within a compile), what it
@@ -131,6 +141,11 @@ def reduce(doc: Dict, top: int = 10) -> Optional[Dict]:
       other), averaged over chips (an op's self time is its duration
       minus the part its nested children cover, so a while loop does
       not count its body twice);
+    - ``by_kernel_s``: the ``pallas`` seconds by ``kernel_name``, and
+      ``by_family_s``: by the name's first dotted component (``attn``
+      for every attention kernel of the program), each summed in the
+      events' order, as ``by_kind_s`` is: a program whose Mosaic calls
+      are all one family's reads the same number under both;
     - ``async_collective_s``: seconds of collective spans on the
       ``Async XLA Ops`` line, averaged over chips: what a collective
       took from start to done, hidden under compute or not;
@@ -152,6 +167,8 @@ def reduce(doc: Dict, top: int = 10) -> Optional[Dict]:
     hi = max(e[1] + e[2] for _, ops in per_dev for e in ops)
     busy, by_name = [], {}
     by_kind = {"collective": 0.0, "pallas": 0.0, "other": 0.0}
+    by_kernel: Dict[str, float] = {}
+    by_family: Dict[str, float] = {}
     kinds: Dict[str, str] = {}     # an instruction's text repeats each step
     for _, ops in per_dev:
         busy.append(union_ns([(e[1], e[1] + e[2]) for e in ops]))
@@ -160,6 +177,11 @@ def reduce(doc: Dict, top: int = 10) -> Optional[Dict]:
                 kinds[name] = op_kind(name)
             by_kind[kinds[name]] += self_ns
             by_name[name] = by_name.get(name, 0.0) + self_ns
+            if kinds[name] == "pallas":
+                kernel = kernel_name(name)
+                family = kernel.split(".")[0]
+                by_kernel[kernel] = by_kernel.get(kernel, 0.0) + self_ns
+                by_family[family] = by_family.get(family, 0.0) + self_ns
     async_coll = 0.0
     for plane in doc["planes"]:
         for ln in plane["lines"]:
@@ -188,6 +210,10 @@ def reduce(doc: Dict, top: int = 10) -> Optional[Dict]:
         "busy_s": busy_s,
         "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
         "by_kind_s": {k: v / n / 1e9 for k, v in sorted(by_kind.items())},
+        "by_kernel_s": {k: v / n / 1e9
+                        for k, v in sorted(by_kernel.items())},
+        "by_family_s": {k: v / n / 1e9
+                        for k, v in sorted(by_family.items())},
         "async_collective_s": async_coll / n / 1e9,
         "device_ops": [[label(k), v / n / 1e9] for k, v in sorted(
             by_name.items(), key=lambda kv: -kv[1])[:top]],

@@ -1,32 +1,49 @@
 """Tiny configurations and cells for the benchmark's CPU tests: the
 same files the chip runs, cut to sizes a CPU compiles in seconds."""
 
+import copy
 import time
 
 import jax
 
-from perf import harness
+from perf import harness, models
 
-TINY_TRANSFORMER = dict(d_model=32, d_inner=64, n_head=4, n_layer=2,
-                        src_vocab_size=50, trg_vocab_size=60, max_length=32)
-TINY_BERT = dict(d_model=32, d_inner=64, n_head=4, n_layer=2,
-                 vocab_size=50, max_position=16)
+BENCH = harness.load_json("BENCHMARK.json")
+# what the tests that run every cell and configuration are parametrised
+# over: a cell or configuration a later PR appends is picked up here
+CONFIGS = [c["name"] for c in BENCH["configs"]]
+
+
+def cells_of(kind):
+    """[(cell, chips)] of BENCHMARK.json's cells of one kind."""
+    return [(w["name"], w["chips"]) for w in BENCH["workloads"]
+            if harness.load_json("perf", "workloads",
+                                 f"{w['name']}.json")["kind"] == kind]
+
+
+def train_cell_of(config_name):
+    """The tiny form of the first train cell of a configuration."""
+    return next(cell for cell in (train_cell(c) for c, _ in cells_of("train"))
+                if cell["config"] == config_name)
 
 
 def config(name):
+    """A configuration file at its family's tiny sizes (``TINY`` in
+    perf/families/<family>.py)."""
     cfg = harness.load_json("perf", "configs", f"{name}.json")
-    cfg.update(TINY_TRANSFORMER if cfg["family"] == "transformer"
-               else TINY_BERT)
+    cfg.update(models.family(cfg).TINY)
     return cfg
 
 
-def train_cell(name, chips=1):
+def train_cell(name, chips=None):
+    """A train cell's file cut to 8 x 16 positions: its other traffic
+    keys (what a family's generator reads besides) stay."""
     cell = harness.load_json("perf", "workloads", f"{name}.json")
-    cell["chips"] = chips
+    cell["chips"] = chips or cell["chips"]
     cell["trace_seconds"] = 0.3
     full = cell["traffic"]["real_len"][0] == cell["traffic"]["real_len"][1]
-    cell["traffic"] = {"batch": 8, "seq_len": 16, "feeds": 4,
-                       "real_len": [16, 16] if full else [8, 16]}
+    cell["traffic"] = dict(cell["traffic"], batch=8, seq_len=16, feeds=4,
+                           real_len=[16, 16] if full else [8, 16])
     return cell
 
 
@@ -40,6 +57,49 @@ def serve_cell(rate=30.0):
             "name": "steady", "rate_per_s": rate, "drain_seconds": 5.0,
             "src_len": {"median": 6, "sigma": 0.6, "min": 2, "max": 16},
             "max_new": {"ratio": 1.1, "min": 2, "max": 23}}}
+
+
+def appended():
+    """An in-memory copy of BENCHMARK.json as a ``model_config`` PR
+    leaves it: one more configuration, one more one-chip train cell and
+    three more per-layer metrics, each at the END of its list, the cell
+    also listed under ``train_tokens_per_s`` and the ``.train`` metrics
+    every train cell reports. No file of theirs exists on disk."""
+    b = copy.deepcopy(BENCH)
+    b["configs"].append({
+        "name": "rehearsal-lm", "source": "https://example.org/rehearsal-lm",
+        "file": f"{b['paths'][0]}/configs/rehearsal-lm.json",
+        "reduced": ["num_hidden_layers"],
+        "why": "a decoder-only family with experts, as a later PR adds"})
+    b["workloads"].append({
+        "name": "rehearsal-train-s4096", "config": "rehearsal-lm",
+        "traffic": "b2-s4096", "chips": 1,
+        "why": "batch 2 x 4096 packed positions; BHTD attention and the "
+               "experts' grouped matmuls do the work"})
+    train = set(cells_named(BENCH, "train_tokens_per_s"))
+    for m in b["end_to_end"] + b["per_layer"]:
+        if train <= set(m.get("workloads", ())):
+            m["workloads"].append("rehearsal-train-s4096")
+    b["per_layer"] += [
+        {"name": n, "unit": "%", "better": better, "source": source,
+         "layer": layer, "moves": "train_tokens_per_s",
+         "workloads": ["rehearsal-train-s4096"]}
+        for n, better, source, layer in (
+            ("moe.time_share.train", "lower", "program_span",
+             "Program lowering"),
+            ("moe.dispatch_share.train", "lower", "program_span",
+             "Program lowering"),
+            ("train_moe_roofline", "higher", "device_trace", "Kernels"))]
+    return b
+
+
+def cells_named(bench, metric):
+    """The cells a metric of BENCHMARK.json lists."""
+    return next(m for m in bench["end_to_end"] + bench["per_layer"]
+                if m["name"] == metric).get("workloads", [])
+
+
+BENCHES = {"as-it-is": BENCH, "appended": appended()}
 
 
 def bench_with(cell_name, end_to_end=(), per_layer=()):
@@ -59,7 +119,7 @@ def bench_with(cell_name, end_to_end=(), per_layer=()):
 
 def make_run(cell, cfg, seconds=0.5, traced=False, seed=2 ** 31 + 7,
              bench=None):
-    run = harness.Run(bench or harness.load_json("BENCHMARK.json"), cell,
-                      cfg, seed, seconds, traced, time.perf_counter())
+    run = harness.Run(bench or BENCH, cell, cfg, seed, seconds, traced,
+                      time.perf_counter())
     run.devices = jax.devices()
     return run

@@ -11,10 +11,10 @@ import perfbench_tiny as tiny
 BIG_SEED = 2 ** 31 + 12345  # the driver's seeds pass 32 signed bits
 
 
-@pytest.mark.parametrize("name", ["transformer-base", "bert-base"])
+@pytest.mark.parametrize("name", tiny.CONFIGS)
 def test_train_feeds_repeat_for_a_seed_and_differ_across_seeds(name):
     cfg = tiny.config(name)
-    traffic = {"batch": 8, "seq_len": 16, "real_len": [8, 16], "feeds": 3}
+    traffic = dict(tiny.train_cell_of(name)["traffic"], feeds=3)
     fam = models.family(cfg)
     a = fam.feeds(cfg, traffic, BIG_SEED)
     b = fam.feeds(cfg, traffic, BIG_SEED)
@@ -97,3 +97,37 @@ def test_bursty_gaps_keep_the_mean_rate():
     gaps = np.diff(due)
     assert len(reqs) == 400 and due[-1] < 10.0
     assert gaps.std() / gaps.mean() > 2.0
+
+
+# --- the tests' own cut-down cells and configurations ---------------------
+
+
+@pytest.mark.parametrize("name", tiny.CONFIGS)
+def test_a_configurations_tiny_sizes_are_its_familys(name):
+    from perf import harness
+
+    full = harness.load_json("perf", "configs", f"{name}.json")
+    cfg, fam = tiny.config(name), models.family(full)
+    # TINY shrinks sizes the file has; every other key stays as it is run
+    assert fam.TINY and set(fam.TINY) <= set(full)
+    assert {k: cfg[k] for k in fam.TINY} == fam.TINY
+    assert {k: v for k, v in cfg.items() if k not in fam.TINY} == \
+        {k: v for k, v in full.items() if k not in fam.TINY}
+    assert fam.program_config(cfg) is not None
+
+
+@pytest.mark.parametrize("cell_name,chips", tiny.cells_of("train"))
+def test_a_tiny_train_cell_keeps_its_other_traffic_keys(cell_name, chips):
+    from perf import harness
+
+    full = harness.load_json("perf", "workloads", f"{cell_name}.json")
+    cell = tiny.train_cell(cell_name)
+    assert cell["chips"] == chips == full["chips"]
+    assert tiny.train_cell(cell_name, 1)["chips"] == 1
+    cut = {"batch": 8, "seq_len": 16, "feeds": 4}
+    assert {k: cell["traffic"][k] for k in cut} == cut
+    assert cell["traffic"]["real_len"][1] == 16
+    assert {k: v for k, v in cell["traffic"].items()
+            if k not in (*cut, "real_len")} == \
+        {k: v for k, v in full["traffic"].items()
+         if k not in (*cut, "real_len")}

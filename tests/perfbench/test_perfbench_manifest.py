@@ -1,6 +1,11 @@
 """BENCHMARK.json against the files it names and the contract's rules a
-reader can check without a chip."""
+reader can check without a chip. The rules that need no file on disk
+run twice: on the file as it is, and on an in-memory copy with a later
+PR's entries APPENDED (perfbench_tiny.appended: a configuration, a
+one-chip cell, three per-layer metrics), so that a check which only
+holds for today's lists fails here and not in that PR's review."""
 
+import json
 import os
 import re
 
@@ -8,52 +13,89 @@ import pytest
 
 from perf import harness
 
-BENCH = harness.load_json("BENCHMARK.json")
+import perfbench_tiny as tiny
+
+BENCH = tiny.BENCH
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+both = pytest.mark.parametrize("bench", tiny.BENCHES.values(),
+                               ids=tiny.BENCHES.keys())
 
 
 def exists(*parts):
     return os.path.exists(os.path.join(harness.ROOT, *parts))
 
 
-def test_top_level_keys_and_limits():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def test_the_rehearsal_appends_and_edits_no_entry_that_is_there():
+    more = tiny.BENCHES["appended"]
+    for group, added in (("configs", 1), ("workloads", 1),
+                         ("end_to_end", 0), ("per_layer", 3)):
+        old, new = BENCH[group], more[group]
+        assert len(new) == len(old) + added
+        for a, b in zip(old, new):   # the old entries, in their places
+            assert {k: v for k, v in b.items() if k != "workloads"} \
+                == {k: v for k, v in a.items() if k != "workloads"}
+            assert b.get("workloads", [])[:len(a.get("workloads", []))] \
+                == a.get("workloads", [])
+    cell = more["workloads"][-1]["name"]
+    assert cell in tiny.cells_named(more, "train_tokens_per_s")
+    assert cell in tiny.cells_named(more, "attn.time_share.train")
+    assert cell not in tiny.cells_named(more, "mesh.collective_share")
+
+
+@both
+def test_top_level_keys_and_limits(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(harness.ROOT, "BENCHMARK.json")) \
-        < 64 * 1024
-    four = sum(1 for w in BENCH["workloads"] if w["chips"] == 4)
-    assert four <= max(1, len(CELLS) // 4)
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert 1 <= bench["run_seconds"] <= 51
+    assert len(json.dumps(bench, indent=1)) < 64 * 1024
+    assert 1 <= len(bench["configs"]) <= 24
+    assert 1 <= len(bench["workloads"]) <= 24
+    assert 1 <= len(bench["end_to_end"]) <= 16
+    assert 1 <= len(bench["per_layer"]) <= 128
+    four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(bench["workloads"]) // 4)
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(set(pairs)) == len(pairs)
 
 
-def test_names_units_and_lines():
-    names = [m["name"] for m in METRICS]
-    assert len(set(names)) == len(names) and len(set(CELLS)) == len(CELLS)
-    for m in METRICS:
+@both
+def test_names_units_and_lines(bench):
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    cells = [w["name"] for w in bench["workloads"]]
+    names = [m["name"] for m in metrics]
+    assert len(set(names)) == len(names) and len(set(cells)) == len(cells)
+    for m in metrics:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
-    for m in BENCH["end_to_end"]:
+        assert set(m.get("workloads", ())) <= set(cells), m
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
-    for m in BENCH["per_layer"]:
+    layers = {}
+    for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for w in BENCH["workloads"]:
+        assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
+        layers.setdefault(m["layer"].lower(), set()).add(m["layer"])
+    # one layer, one spelling, letter for letter
+    assert all(len(v) == 1 for v in layers.values()), layers
+    for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert 1 <= len(w["why"]) <= 200 and w["chips"] in (1, 4)
-    for c in BENCH["configs"]:
+    for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"])
         assert len(c["source"]) <= 200 and len(c["why"]) <= 200
+        assert len(c["reduced"]) <= 16
+        assert all(NAME.match(k) for k in c["reduced"])
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -79,21 +121,32 @@ def test_every_metric_has_a_reader(metric):
     assert callable(harness.reader_for(metric).read)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_cell_reports_what_its_metrics_move(cell):
-    e2e = {m["name"] for m in harness.cell_metrics(BENCH, cell,
+@pytest.mark.parametrize("bench,cell", [
+    (b, w["name"]) for b in tiny.BENCHES.values() for w in b["workloads"]],
+    ids=[f"{k}-{w['name']}" for k, b in tiny.BENCHES.items()
+         for w in b["workloads"]])
+def test_every_cell_reports_what_its_metrics_move(bench, cell):
+    e2e = {m["name"] for m in harness.cell_metrics(bench, cell,
                                                    "end_to_end")}
-    layer = harness.cell_metrics(BENCH, cell, "per_layer")
+    layer = harness.cell_metrics(bench, cell, "per_layer")
     assert "setup_s" in e2e and len(e2e) >= 2 and layer
     for m in layer:
         assert m["moves"] in e2e, (cell, m["name"], m["moves"])
 
 
-def test_every_config_is_used_and_paths_hold_the_files():
-    used = {w["config"] for w in BENCH["workloads"]}
-    assert used == {c["name"] for c in BENCH["configs"]}
-    for c in BENCH["configs"]:
-        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
-    assert BENCH["command"] == ["python3", "perf/run.py"]
+@both
+def test_every_config_is_used_and_lies_under_paths(bench):
+    used = {w["config"] for w in bench["workloads"]}
+    assert used == {c["name"] for c in bench["configs"]}
+    files = [c["file"] for c in bench["configs"]]
+    assert len(set(files)) == len(files)
+    for f in files:
+        assert f.startswith(tuple(p + "/" for p in bench["paths"]))
+    assert bench["command"] == ["python3", "perf/run.py"]
+
+
+def test_the_file_is_small_and_paths_hold_the_files():
+    assert os.path.getsize(os.path.join(harness.ROOT, "BENCHMARK.json")) \
+        < 64 * 1024
     assert all(os.path.isdir(os.path.join(harness.ROOT, p))
                for p in BENCH["paths"])

@@ -14,15 +14,16 @@ import perfbench_tiny as tiny
 
 
 def eval_loss_pair(name, cfg=None, ref_cfg=None, amp=True):
-    """(program's eval-mode loss, reference's) on two sequences."""
+    """(program's eval-mode loss, reference's) on two sequences of the
+    configuration's first train cell, cut down."""
     cfg = cfg or tiny.config(name)
-    main, startup, evalp, loss = models.build_train(cfg, seed=11)
+    main, startup, evalp, loss, _ = models.build_train(cfg, seed=11)
     evalp._amp = amp
     scope, exe = fluid.Scope(), fluid.Executor()
     exe.run(startup, scope=scope)
     fam = models.family(cfg)
-    feed = fam.feeds(cfg, {"batch": 2, "seq_len": 16, "feeds": 1,
-                           "real_len": [8, 16]}, 5)[0]
+    feed = fam.feeds(cfg, dict(tiny.train_cell_of(name)["traffic"],
+                               batch=2, feeds=1), 5)[0]
     got = float(np.asarray(exe.run(evalp, feed=feed, fetch_list=[loss],
                                    scope=scope)[0]))
     with jax.default_matmul_precision("highest"):
@@ -31,7 +32,8 @@ def eval_loss_pair(name, cfg=None, ref_cfg=None, amp=True):
     return got, want
 
 
-@pytest.mark.parametrize("name", ["transformer-base", "bert-base"])
+# every configuration of BENCHMARK.json, a later PR's too
+@pytest.mark.parametrize("name", tiny.CONFIGS)
 def test_reference_agrees_with_the_eval_program(name):
     # in float32 the two are the same mathematics
     got, want = eval_loss_pair(name, amp=False)

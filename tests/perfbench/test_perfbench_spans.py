@@ -137,6 +137,9 @@ def test_the_scoped_fixture_is_small_and_keeps_the_host_spans():
     ("jit(step_fn)/fwd/dec0/cross/scaled_dot_product_attention/"
      "bqhd,bkhd->bhqk/dot_general:",
      ("fwd", "dec0/cross", "scaled_dot_product_attention", None)),
+    # any family's kernel name, not attention's alone
+    ("jit(step_fn)/fwd/blk3/moe/moe_experts/moe.gmm.fwd/pallas_call:",
+     ("fwd", "blk3/moe", "moe_experts", "moe.gmm.fwd")),
     # a control-flow op's sub-block nests under the op that owns it
     ("jit(main)/fwd/decode/while/while/body/fwd/decode/step/mul/dot_general:",
      ("fwd", "decode", "while", None)),
@@ -326,3 +329,92 @@ def test_the_eight_metrics_on_the_scoped_fixture():
     assert s["top_scopes"][0][0] == "bwd/loss_head/mul_grad"
     # every executor.run_step span begins before its module's first op
     assert s["host"]["skew_ns"][0] > 0
+
+
+# --- the whole table by scope, and any family's kernels -------------------
+
+MOE_BWD = ('%moe.gmm.bwd.4 = (bf16[8,16,32]{2,1,0}) custom-call('
+           'bf16[8,16,32]{2,1,0} %x), custom_call_target="tpu_custom_call"')
+
+
+def with_experts():
+    """The synthetic trace with a block of experts on the first chip:
+    a router's fusion and a ``moe.gmm.bwd`` kernel under ``blk0/moe``."""
+    doc = synthetic()
+    doc["planes"][1]["lines"][0]["events"] += [
+        ["%fusion.8 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop", 100600.,
+         30., J + "fwd/blk0/moe/softmax/reduce_max:"],
+        [MOE_BWD, 100700., 70.,
+         J + "bwd/blk0/moe/moe_experts_grad/moe.gmm.bwd/pallas_call:"]]
+    return doc
+
+
+@pytest.mark.parametrize("doc", [synthetic(), with_experts(),
+                                 spans.load(SCOPED)],
+                         ids=["synthetic", "experts", "scoped-fixture"])
+def test_the_whole_table_by_scope_sums_to_the_scoped_time(doc):
+    s = spans.reduce(doc)
+    assert sum(s["by_scope_ns"].values()) == pytest.approx(
+        s["scoped_ns"], rel=1e-12)
+    assert len(s["top_scopes"]) == min(15, len(s["by_scope_ns"]))
+    assert all(s["by_scope_ns"][k] == v for k, v in s["top_scopes"])
+    assert min(v for _, v in s["top_scopes"]) >= sorted(
+        s["by_scope_ns"].values())[-len(s["top_scopes"])]
+    # the sums share() knows are predicate sums over the table
+    assert spans.scope_ns(s, lambda parts: True) == pytest.approx(
+        s["scoped_ns"], rel=1e-12)
+    assert spans.scope_ns(
+        s, lambda parts: parts[1] in spans.HEAD_SCOPES) == pytest.approx(
+            s["head_ns"], rel=1e-12)
+    for phase, ns in s["by_phase_ns"].items():
+        assert spans.scope_ns(
+            s, lambda parts: parts[0] == phase) == pytest.approx(
+                ns, rel=1e-12, abs=1e-9)
+
+
+def test_self_time_under_a_scope_component_is_a_few_lines():
+    s = spans.reduce(with_experts())
+    assert s["by_scope_ns"]["fwd/blk0/moe/softmax"] == 30.0
+    assert spans.scope_ns(s, lambda parts: "moe" in parts[1:-1]) == 100.0
+    # what a later family's reader is, whole (cf. step.head_share.py)
+    run = run_with(s)
+    assert spans.share(run, lambda s_: spans.scope_ns(
+        s_, lambda parts: "moe" in parts[1:-1])) == pytest.approx(
+            100 * 100 / 1000)
+    assert spans.scope_ns(s, lambda parts: "nowhere" in parts) == 0
+
+
+def test_another_familys_kernel_shows_and_is_not_read_as_attention():
+    s = spans.reduce(with_experts())
+    assert s["kernel_ns"] == {"attn.bthd_small.bwd": 200.0,
+                              "moe.gmm.bwd": 70.0}
+    run = run_with(s)
+    assert harness.reader_for("attn.bwd_time_share.train").read(
+        run) == pytest.approx(100 * 200 / 1000)
+    # kernels, but none of attention's: the reader finds nothing
+    s["kernel_ns"] = {"moe.gmm.bwd": 70.0}
+    assert harness.reader_for("attn.bwd_time_share.train").read(
+        run_with(s)) is None
+
+
+def test_the_raw_trace_is_parsed_once_for_every_reader(monkeypatch,
+                                                       tmp_path, capsys):
+    import shutil
+
+    d = tmp_path / "tbase-train" / "plugins" / "profile" / "x"
+    d.mkdir(parents=True)
+    with gzip.open(SCOPED, "rb") as src, \
+            open(d / "host.xplane.pb", "wb") as dst:
+        shutil.copyfileobj(src, dst)
+    monkeypatch.setattr(harness, "TRACE_ROOT", str(tmp_path))
+    loads, real = [], spans.load.__wrapped__
+    monkeypatch.setattr(spans, "load",
+                        lambda path: loads.append(path) or real(path))
+    run = run_with(None)
+    run.window["traced_steps"] = 1
+    read_all(run)                       # eight readers over for_run
+    doc = spans.doc_for_run(run)        # a later reader of the events
+    assert doc is spans.doc_for_run(run) and len(loads) == 1
+    assert spans.reduce(doc)["by_scope_ns"] == run._spans["by_scope_ns"]
+    # a run that traced nothing has no document either
+    assert spans.doc_for_run(run_with(None, traced=False)) is None

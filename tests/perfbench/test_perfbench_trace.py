@@ -99,3 +99,95 @@ def test_several_chips_are_averaged_and_collectives_counted():
 def test_a_trace_without_device_ops_reduces_to_nothing():
     assert trace.reduce({"planes": [{"name": "/host:CPU",
                                      "lines": []}]}) is None
+
+
+# --- a Mosaic call is named by its kernel --------------------------------
+
+SCOPED = os.path.join(harness.HERE, "fixtures",
+                      "tbase-train-v5e-scoped-one-step.xplane.pb.gz")
+V5E = harness.load_json("perf", "peaks.json")["TPU v5 lite"]
+
+
+def mosaic(inst):
+    return (f'%{inst} = (bf16[8,16,32]{{2,1,0}}) custom-call(bf16[8,16,32]'
+            f'{{2,1,0}} %q), custom_call_target="tpu_custom_call"')
+
+
+def traced_run(summary, steps=1):
+    """What the two attention readers ask of a run record."""
+    import types
+
+    from perf import flops
+
+    cfg = harness.load_json("perf", "configs", "transformer-base.json")
+    n = cfg["n_layer"]
+    return types.SimpleNamespace(
+        trace=summary, devices=[types.SimpleNamespace(
+            device_kind="TPU v5 lite")],
+        window={"traced_steps": steps,
+                "attention": flops.attention_train_cost(
+                    {"enc_self": n, "dec_self_causal": n, "dec_cross": n},
+                    cfg, 128, 256)})
+
+
+def attention_readers(run):
+    return [harness.reader_for(m).read(run)
+            for m in ("attn.time_share.train", "train_attn_roofline")]
+
+
+@pytest.mark.parametrize("path,kernels,family", [
+    (SCOPED, {"attn.bthd_small.fwd", "attn.bthd_small.bwd"}, "attn"),
+    # cut before PR 24 named the kernels: the jitted function's name
+    (FIXTURE, {"step_fn"}, "step_fn")])
+def test_mosaic_time_by_kernel_name_on_the_fixtures(path, kernels, family):
+    r = trace.reduce(trace.load(path))
+    assert set(r["by_kernel_s"]) == kernels
+    assert sum(r["by_kernel_s"].values()) == pytest.approx(
+        r["by_kind_s"]["pallas"], rel=1e-12)
+    # one family: summed in the same order as the kind, the same bits
+    assert r["by_family_s"] == {family: r["by_kind_s"]["pallas"]}
+
+
+def test_the_attention_readers_on_the_scoped_fixture_bit_for_bit(
+        monkeypatch):
+    """The values the parent's readers (``by_kind_s["pallas"]``) gave on
+    this fixture, to the last bit: every Mosaic call in it is attn.*."""
+    monkeypatch.setattr(harness, "peaks_for", lambda kind: V5E)
+    r = trace.reduce(trace.load(SCOPED))
+    assert attention_readers(traced_run(r)) == [12.140539216516435,
+                                                48.65800830206971]
+    assert attention_readers(traced_run(r))[0] == \
+        100.0 * r["by_kind_s"]["pallas"] / r["busy_s"]
+    # the older trace names no kernel: nothing is read as attention
+    old = trace.reduce(trace.load(FIXTURE))
+    assert attention_readers(traced_run(old)) == [None, None]
+
+
+def test_only_the_attn_family_counts_as_attention(monkeypatch):
+    monkeypatch.setattr(harness, "peaks_for", lambda kind: V5E)
+    ops = [[mosaic("attn.x.fwd.7"), 0, 100], [FUSION, 100, 400],
+           [mosaic("moe.gmm.fwd.2"), 500, 300],
+           [mosaic("ragged-dot-none.3"), 800, 150],
+           [mosaic("attn.x.fwd.8"), 950, 50], [OTHER_CC, 1000, 10]]
+    r = trace.reduce({"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": ops}]}]})
+    assert r["by_kind_s"]["pallas"] == pytest.approx(600e-9)
+    assert r["by_kernel_s"] == {
+        "attn.x.fwd": pytest.approx(150e-9),
+        "moe.gmm.fwd": pytest.approx(300e-9),
+        "ragged-dot-none": pytest.approx(150e-9)}
+    assert r["by_family_s"] == {
+        "attn": pytest.approx(150e-9), "moe": pytest.approx(300e-9),
+        "ragged-dot-none": pytest.approx(150e-9)}
+    share, roofline = attention_readers(traced_run(r))
+    assert share == pytest.approx(100 * 150 / 1010)
+    alone = trace.reduce({"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": [ops[0], ops[4]]}]}]})
+    assert roofline == attention_readers(traced_run(alone))[1]
+    # a program with Mosaic calls but no attention kernel reads nothing
+    none = trace.reduce({"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": ops[1:4]}]}]})
+    assert attention_readers(traced_run(none)) == [None, None]
+    assert trace.kernel_name(mosaic("attn.bhtd.bwd_dq.12")) == \
+        "attn.bhtd.bwd_dq"
+    assert trace.kernel_name(mosaic("step_fn.36")) == "step_fn"
