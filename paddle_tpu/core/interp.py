@@ -52,6 +52,11 @@ AMP_OP_TYPES = {
     "depthwise_conv2d",
     "conv2d_transpose",
     "scaled_dot_product_attention",
+    # the experts' grouped matmuls (rows and the stacked weights to
+    # bf16). The rest of the top-k MoE keeps what it must in f32 by
+    # itself: moe_router its logits, softmax and top-k, moe_combine its
+    # weighted sum; rms_norm, like layer_norm, its statistics.
+    "moe_experts",
 }
 
 # Precision-following ops: when any input is already bf16, their remaining

@@ -33,7 +33,8 @@ __all__ = [
     "flatten", "sums", "elementwise_mod", "elementwise_floordiv", "maxout",
     "mean_iou",
     "linear_chain_crf", "crf_decoding", "warpctc", "edit_distance",
-    "bilinear_tensor_product", "nce", "switch_moe",
+    "bilinear_tensor_product", "nce", "switch_moe", "topk_moe",
+    "rms_norm", "rotary_embedding",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
     "yolo_box", "sequence_conv", "add_position_encoding", "conv3d",
     "spectral_norm", "hsigmoid", "sample_logits",
@@ -369,6 +370,32 @@ def layer_norm(
         attrs={"begin_norm_axis": begin_norm_axis, "epsilon": epsilon},
     )
     return helper.append_activation(out)
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """Root-mean-square normalisation over the last axis with a learned
+    gain and no bias (Zhang & Sennrich 2019): the pre-norm of the
+    decoder-only language models (models/olmoe.py)."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        ParamAttr._to_attr(param_attr), shape=[input.shape[-1]],
+        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(dtype=input.dtype)
+    helper.append_op("rms_norm", inputs={"X": input, "Scale": scale},
+                     outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(q, k, theta=10000.0, name=None):
+    """Rotary positions (rotate-half form) on q and k [b, h, t, dh];
+    position p of the sequence is p. Returns the rotated (q, k)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
+    k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
+    helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
+                     outputs={"QOut": q_out, "KOut": k_out},
+                     attrs={"theta": float(theta)})
+    return q_out, k_out
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,
@@ -1259,6 +1286,78 @@ def switch_moe(input, num_experts, d_ff=None, capacity_factor=2.0,
         attrs={"capacity_factor": float(capacity_factor), "act": act},
     )
     return out, aux
+
+
+def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
+             param_attr=None, name=None):
+    """Dropless top-k Mixture-of-Experts with SwiGLU experts (OLMoE,
+    arXiv:2409.02060): ``input`` [.., d] tokens -> ``(out, lb_loss,
+    z_loss, expert_rows, top_i)``. out, in input's shape, is the sum
+    over the token's ``top_k`` experts of p_e * (silu(x WGate[e]) * (x WUp[e])) WDown[e], p the
+    float32 softmax over all ``num_experts`` (renormalised over the k
+    only if ``norm_topk_prob``); every chosen pair is computed, no
+    capacity. ``lb_loss`` is the load-balancing loss, ``z_loss`` the
+    router z-loss (add multiples of both to the training loss),
+    ``expert_rows`` [E] int32 the rows each expert got, ``top_i`` [n, k]
+    int32 the experts each token chose.
+
+    Four ops (ops/moe_ops.py), each under a name scope of its own:
+    router, dispatch, experts, combine. Parameters: ``{name}_router.w``
+    [d, E], ``{name}_gate.w`` / ``{name}_up.w`` [E, d, d_ff],
+    ``{name}_down.w`` [E, d_ff, d]."""
+    from paddle_tpu.framework import name_scope
+    from paddle_tpu.initializer import NormalInitializer
+
+    helper = LayerHelper("topk_moe", name=name)
+    d = input.shape[-1]
+    base = ParamAttr._to_attr(param_attr) or ParamAttr()
+
+    def param(suffix, shape):
+        attr = ParamAttr(
+            name=f"{helper.name}{suffix}", initializer=base.initializer,
+            learning_rate=base.learning_rate, regularizer=base.regularizer,
+            trainable=base.trainable)
+        return helper.create_parameter(
+            attr, shape=shape, dtype=input.dtype,
+            default_initializer=NormalInitializer(0.0, 0.02))
+
+    def var(dtype, stop_gradient=False):
+        return helper.create_variable_for_type_inference(
+            dtype=dtype, stop_gradient=stop_gradient)
+
+    with name_scope("router"):
+        top_w, top_i = var("float32"), var("int32", True)
+        lb, z = var("float32"), var("float32")
+        helper.append_op(
+            "moe_router",
+            inputs={"X": input, "W": param("_router.w", [d, num_experts])},
+            outputs={"TopW": top_w, "TopI": top_i, "LBLoss": lb,
+                     "ZLoss": z},
+            attrs={"k": int(top_k), "norm_topk": bool(norm_topk_prob)})
+    with name_scope("dispatch"):
+        xs = var(input.dtype)
+        rows, order, slot = (var("int32", True) for _ in range(3))
+        helper.append_op(
+            "moe_dispatch", inputs={"X": input, "TopI": top_i},
+            outputs={"Xs": xs, "Rows": rows, "Order": order, "Slot": slot},
+            attrs={"num_experts": int(num_experts)})
+    with name_scope("experts"):
+        ys = var(input.dtype)
+        helper.append_op(
+            "moe_experts",
+            inputs={"Xs": xs, "Rows": rows,
+                    "WGate": param("_gate.w", [num_experts, d, d_ff]),
+                    "WUp": param("_up.w", [num_experts, d, d_ff]),
+                    "WDown": param("_down.w", [num_experts, d_ff, d])},
+            outputs={"Ys": ys})
+    with name_scope("combine"):
+        out = var(input.dtype)
+        helper.append_op(
+            "moe_combine",
+            inputs={"Ys": ys, "TopW": top_w, "Order": order, "Slot": slot,
+                    "Like": input},
+            outputs={"Out": out})
+    return out, lb, z, rows, top_i
 
 
 def _simple_op_layer(op_type, inputs, attrs=None, out_slot="Out",

@@ -4,6 +4,7 @@ from paddle_tpu.models import (  # noqa: F401
     bert,
     deepfm,
     mnist,
+    olmoe,
     resnet,
     se_resnext,
     seq2seq,

@@ -86,6 +86,31 @@ def _attn_bias(ins, attrs):
     return {"Out": [out]}
 
 
+def _rotate(x, theta):
+    """Rotary position embedding of x [b, h, t, dh], rotate-half form
+    (Su et al. 2021 as GPT-NeoX and HF lay it out): feature i pairs
+    with i + dh/2, position p turns the pair by p * theta^(-2i/dh)."""
+    t, dh = x.shape[-2], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :dh // 2], xf[..., dh // 2:]
+    out = xf * cos + jnp.concatenate([-x2, x1], -1) * sin
+    return out.astype(x.dtype)
+
+
+@register_op("rotary_embedding", diff_inputs=("Q", "K"))
+def _rotary_embedding(ins, attrs):
+    """Q, K [b, h, t, dh] -> the same with rotary positions 0..t-1
+    applied (attr ``theta``, the base). The angles and the rotation are
+    f32; the results return to the inputs' dtype."""
+    theta = float(attrs.get("theta", 10000.0))
+    return {"QOut": [_rotate(_x(ins, "Q"), theta)],
+            "KOut": [_rotate(_x(ins, "K"), theta)]}
+
+
 def _sdpa_config(ins, attrs, rng):
     """Shared fwd/grad config: (scale, p_drop, seed, family, dims).
 
@@ -116,7 +141,7 @@ def _sdpa_config(ins, attrs, rng):
     else:
         b, h, tq, dh = q.shape
         tk = k.shape[2]
-        family = fa.bhtd_family(h, tq, tk)
+        family = fa.bhtd_family(h, tq, tk, dh=dh)
     if not attrs.get("use_pallas", True):
         family = "dense"
     return scale, drop, seed, family, (b, tq, tk, h, dh)

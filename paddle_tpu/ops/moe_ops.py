@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import monitor as _monitor
 from paddle_tpu.core.registry import register_op
 
 _ACTS = {
@@ -118,3 +119,133 @@ def _switch_moe(ins, attrs):
         "Out": [jnp.reshape(out, shape).astype(x.dtype)],
         "AuxLoss": [aux.astype(jnp.float32)],
     }
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k MoE (OLMoE, arXiv:2409.02060): four ops, so that the
+# program's name scopes (router / dispatch / experts / combine) reach the
+# device trace in both directions. Every chosen (token, expert) pair is
+# computed: the pairs are sorted by expert and the experts' matmuls run
+# over the ragged groups (jax.lax.ragged_dot), no capacity, no padding
+# to a worst case, nothing dropped. ``layers.topk_moe`` wires them up.
+# What it shares with switch_moe above: the float32 router and the
+# stacked [E, ...] expert weights; switch_moe is argmax top-1 with a
+# fixed capacity per expert, biases and ReLU/GELU experts.
+# ---------------------------------------------------------------------------
+
+
+_M_ROWS = _monitor.counter(
+    "pt_moe_rows_total",
+    "(token, expert) rows the experts of a top-k MoE layer computed, by "
+    "layer and expert: fed by record_expert_rows from a fetched "
+    "expert_rows, telemetry on only")
+
+
+def record_expert_rows(layer: str, rows):
+    """Add one step's ``expert_rows`` ([E], as ``layers.topk_moe``
+    returns it and a trainer fetched it) of ``layer`` to
+    ``pt_moe_rows_total{layer, expert}``. The rows are device values:
+    nothing counts them unless a caller fetches them, and nothing is
+    counted while telemetry is off."""
+    if not _monitor.enabled():
+        return
+    for e, n in enumerate(rows):
+        if n:
+            _M_ROWS.inc(int(n), labels={"layer": layer, "expert": str(e)})
+
+
+@register_op("moe_router", diff_inputs=("X", "W"))
+def _moe_router(ins, attrs):
+    """X [.., d] tokens (n of them), W [d, E] -> TopW [n, k] f32 (the softmax
+    probabilities of the k largest, renormalised only if ``norm_topk``),
+    TopI [n, k] int32, LBLoss [] (E * sum_e f_e * P_e: f_e the share of
+    tokens that chose e, summed over the k slots; P_e the mean
+    probability) and ZLoss [] (mean squared logsumexp of the logits).
+
+    Float32 whatever the activation stream: logits at the highest matmul
+    precision (on a TPU a default f32 matmul is one bf16 pass), softmax
+    and top-k in f32. Which experts a token takes is a decision, not a
+    bandwidth bound."""
+    w = _x(ins, "W").astype(jnp.float32)
+    x = _x(ins, "X").astype(jnp.float32).reshape(-1, w.shape[0])
+    k = int(attrs["k"])
+    e = int(w.shape[-1])
+    logits = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, k)
+    if attrs.get("norm_topk", False):
+        top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
+    chosen = jnp.sum(jax.nn.one_hot(top_i, e, dtype=jnp.float32), axis=1)
+    lb = e * jnp.sum(jnp.mean(chosen, 0) * jnp.mean(probs, 0))
+    z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    return {"TopW": [top_w], "TopI": [top_i.astype(jnp.int32)],
+            "LBLoss": [lb], "ZLoss": [z]}
+
+
+@jax.custom_vjp
+def _rows(x, idx, back):
+    """``x[idx]`` where ``back`` [n, r] lists, for each row of x, the r
+    places of the result that copy it: the cotangent is then a gather
+    and a sum over r, not a scatter-add over repeated indices."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _rows_fwd(x, idx, back):
+    return jnp.take(x, idx, axis=0), back
+
+
+def _rows_bwd(back, g):
+    return (jnp.sum(jnp.take(g, back, axis=0), axis=1), None, None)
+
+
+_rows.defvjp(_rows_fwd, _rows_bwd)
+
+
+@register_op("moe_dispatch", diff_inputs=("X",))
+def _moe_dispatch(ins, attrs):
+    """X [.., d], TopI [n, k] -> Xs [n*k, d]: one row per chosen (token,
+    expert) pair, sorted by expert (stable: by token inside an expert);
+    Rows [E] int32, the rows each expert got; Order [n*k] int32, the
+    flat pair (token * k + slot) at each row of Xs; Slot [n, k] int32,
+    its inverse: the row of Xs that holds pair (token, slot)."""
+    x, top_i = _x(ins, "X"), _x(ins, "TopI")
+    x = x.reshape(-1, x.shape[-1])
+    n, k = top_i.shape
+    e = int(attrs["num_experts"])
+    flat = top_i.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    slot = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+    rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
+    return {"Xs": [_rows(x, order // k, slot)], "Rows": [rows],
+            "Order": [order], "Slot": [slot]}
+
+
+@register_op("moe_experts", diff_inputs=("Xs", "WGate", "WUp", "WDown"))
+def _moe_experts(ins, attrs):
+    """SwiGLU experts over ragged groups: Xs [m, d] sorted by expert,
+    Rows [E] its group sizes, WGate / WUp [E, d, f], WDown [E, f, d] ->
+    Ys [m, d] = (silu(Xs WGate[e]) * (Xs WUp[e])) WDown[e], e the
+    row's expert. Under AMP the lowering casts rows and weights to bf16
+    (core/interp.AMP_OP_TYPES); on the v5e each ragged_dot is libtpu's
+    ``ragged-dot-none`` Mosaic call."""
+    xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
+    wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
+    gate = jax.lax.ragged_dot(xs, wg.astype(xs.dtype), rows)
+    up = jax.lax.ragged_dot(xs, wu.astype(xs.dtype), rows)
+    h = (jax.nn.silu(gate) * up).astype(xs.dtype)
+    return {"Ys": [jax.lax.ragged_dot(h, wd.astype(xs.dtype), rows)]}
+
+
+@register_op("moe_combine", diff_inputs=("Ys", "TopW"))
+def _moe_combine(ins, attrs):
+    """Ys [n*k, d] expert outputs in dispatch order, TopW [n, k], Order
+    and Slot of moe_dispatch -> Out = sum_j TopW[t, j] * Ys[Slot[t, j]],
+    in the shape of Like (the tokens as the router got them): summed
+    in f32, returned in Ys's dtype."""
+    ys, top_w = _x(ins, "Ys"), _x(ins, "TopW")
+    order, slot = _x(ins, "Order"), _x(ins, "Slot")
+    n, k = slot.shape
+    picked = _rows(ys, slot.reshape(-1), order[:, None]).reshape(n, k, -1)
+    out = jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
+                     top_w.astype(jnp.float32))
+    return {"Out": [out.astype(ys.dtype).reshape(_x(ins, "Like").shape)]}

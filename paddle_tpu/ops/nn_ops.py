@@ -266,6 +266,21 @@ def _layer_norm(ins, attrs):
     }
 
 
+@register_op("rms_norm", diff_inputs=("X", "Scale"))
+def _rms_norm(ins, attrs):
+    """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale (Zhang &
+    Sennrich 2019): no mean, no bias. As layer_norm above, the mean of
+    squares and the gain run in f32 whatever the activation dtype (bf16
+    under AMP), so the gain's gradient reduction is f32 too; only Y
+    returns to X's dtype."""
+    x, scale = _x(ins), _x(ins, "Scale")
+    stat_dtype = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(stat_dtype)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    y = xf * jax.lax.rsqrt(ms + attrs.get("epsilon", 1e-5))
+    return {"Y": [(y * scale.astype(stat_dtype)).astype(x.dtype)]}
+
+
 def _draw_bits(op, rng, shape):
     """uint16 random words of ``shape`` for op ``op``, each device
     drawing only the rows it holds. XLA's SPMD partitioner cannot split

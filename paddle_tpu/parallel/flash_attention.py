@@ -57,6 +57,13 @@ _NEG_INF = -1e30
 # must stay well under limit/6 — 1.5MB lands bq=128 at h=8, bk=256,
 # which compiles with a [*, tq, tk] bias at t=1024 and beyond.
 _SCORE_VMEM_BYTES = 3 * 2**19
+# Soft cap on what the dk/dv kernel keeps of (h, bk, dh) besides its
+# score temps: k and v (bf16, double-buffered), dk and dv out (the same)
+# and two f32 accumulators, 24 bytes an element. At h=16, dh=128 a
+# 256-row k block is 12.6MB of them and the kernel asked for 17.1MB of
+# Mosaic's 16MB (compiled for a v5e, PR 28); h=8, dh=64 holds 3.1MB and
+# keeps its 256.
+_KV_VMEM_BYTES = 2**23
 
 # Test hook: run the Pallas kernels in interpreter mode on CPU so the
 # blocked online-softmax path itself is exercised by the pytest suite
@@ -88,9 +95,11 @@ def _dropout_mask(p_keep: float, shape):
     return (bits < thresh).astype(jnp.float32) * (1.0 / p_keep)
 
 
-def _pick_blocks(h, tq, tk, q_block, k_block):
+def _pick_blocks(h, tq, tk, q_block, k_block, dh):
     bq = min(q_block, tq)
     bk = min(k_block, tk)
+    while 24 * h * bk * dh > _KV_VMEM_BYTES and bk > 128:
+        bk //= 2
     while h * bq * bk * 4 > _SCORE_VMEM_BYTES and bq > 64:
         bq //= 2
     while h * bq * bk * 4 > _SCORE_VMEM_BYTES and bk > 128:
@@ -99,10 +108,10 @@ def _pick_blocks(h, tq, tk, q_block, k_block):
 
 
 def bhtd_family(h, tq, tk, q_block=DEFAULT_Q_BLOCK,
-                 k_block=DEFAULT_K_BLOCK) -> str:
+                 k_block=DEFAULT_K_BLOCK, *, dh) -> str:
     """"bhtd" (the head-batched K-blocked kernels) when the picked
     blocks tile both sequence lengths, else "dense"."""
-    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
+    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block, dh)
     if kernels_enabled() and tq % bq == 0 and tk % bk == 0:
         return "bhtd"
     return "dense"
@@ -387,10 +396,11 @@ def _seed_arr(seed):
     return seed
 
 
-# Every pl.pallas_call below carries name="attn.<family>.<pass>" (the
-# families are attention_ops' dispatch counter's; the passes fwd, bwd,
-# bwd_dq, bwd_dkv): jax puts a kernel's name on the HLO instruction AND
-# into its op_name, under the sdpa op's scope (core/interp.exec_ops), so
+# Every pl.pallas_call below carries name="<family>.<what>.<pass>" as
+# perf/ reads it: the family is ``attn``, <what> the kernel family of
+# attention_ops' dispatch counter (bhtd, bthd_small, bthd_kblock), the
+# passes fwd, bwd, bwd_dq, bwd_dkv: jax puts a kernel's name on the HLO
+# instruction AND into its op_name, under the sdpa op's scope (core/interp.exec_ops), so
 # a device trace tells one attention kernel from another and forward
 # from backward.
 
@@ -442,8 +452,8 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
-    family = bhtd_family(h, tq, tk, q_block, k_block)
+    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block, dh)
+    family = bhtd_family(h, tq, tk, q_block, k_block, dh=dh)
     if family == "dense":
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
@@ -514,8 +524,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block)
-    family = bhtd_family(h, tq, tk, q_block, k_block)
+    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block, dh)
+    family = bhtd_family(h, tq, tk, q_block, k_block, dh=dh)
     if family == "dense":
         def f(q, k, v):
             return _reference_attention_with_lse(
@@ -661,7 +671,7 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if bhtd_family(q.shape[1], q.shape[2], k.shape[2],
-                    q_block, k_block) == "bhtd":
+                    q_block, k_block, dh=q.shape[3]) == "bhtd":
         dq, dk, dv = flash_attention_bwd(q, k, v, bias, seed, out, lse, g,
                                          scale, p_drop, q_block, k_block,
                                          causal, g_lse=g_lse)
@@ -773,7 +783,7 @@ def bthd_family(tq, tk, h, dh) -> str:
         return "bthd_kblock"
     if tk > _SMALL_T_MAX:
         # very long context: dk/dv won't fit VMEM scratch as one piece
-        return bhtd_family(h, tq, tk)
+        return bhtd_family(h, tq, tk, dh=dh)
     return "dense"
 
 

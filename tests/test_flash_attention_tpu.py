@@ -94,6 +94,37 @@ def test_hw_parity_vs_dense(b, tq, tk, h, dh):
             np.asarray(a, np.float32), np.asarray(b_, np.float32), atol=0.05)
 
 
+def test_bhtd_causal_at_16_heads_of_128_matches_dense():
+    # OLMoE's attention (models/olmoe.py: [b, 16, 4096, 128], causal, no
+    # bias), the first shape with heads of 128: the dk/dv kernel holds
+    # (h, bk, dh) six times over, so _pick_blocks counts dh (PR 28).
+    b, h, t, dh = 1, 16, 4096, 128
+    assert fa._pick_blocks(h, t, t, 256, 256, dh) == (128, 128)
+    assert fa.bhtd_family(h, t, t, dh=dh) == "bhtd"
+    r = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(r.normal(0, 1, (b, h, t, dh))).astype(
+        jnp.bfloat16) for _ in range(3))
+    w = jnp.asarray(r.normal(0, 1, (b, h, t, dh)).astype(np.float32))
+
+    def f(q, k, v):
+        o, _ = fa.flash_attention_with_lse(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def ref(q, k, v):
+        o = fa._reference_attention(q, k, v, None, 1.0 / np.sqrt(dh),
+                                    causal=True)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    (_, o1), g1 = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v)
+    (_, o2), g2 = jax.value_and_grad(ref, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v)
+    # bf16 inputs and outputs on both sides, as the other parity cases
+    for a, b_ in zip((o1, *g1), (o2, *g2)):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b_, np.float32), atol=0.05)
+
+
 # --- in-kernel dropout: determinism, keep-rate, exact-linear dv ---
 
 
