@@ -95,12 +95,13 @@ def transformer_base(**overrides):
 
 
 def attention_dispatch():
-    """{"family pass shape[ replicated_over=axes]": calls} — which
-    implementation every attention call lowered so far took
-    (pt_attention_dispatch_total)."""
+    """{"family pass shape[ replicated_over=axes][ [tile]]": calls} —
+    which implementation every attention call lowered so far took, and
+    the tile of a family that picks one by the shape: "bhtd fwd <shape>
+    [hb1 bq512 bk512]" (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
-    return attention_ops.dispatch_counts()
+    return attention_ops.dispatch_counts(tiles=True)
 
 
 def _dispatch_since(before):
@@ -200,6 +201,9 @@ def kernel_phase(cases=KERNEL_CASES, h=8, dh=64):
                   f"{errs[name]:.4f} of its max (tolerance "
                   f"{KERNEL_REL_TOL})")
         row = {"family": family, "b": b, "t": t, "causal": causal,
+               # the dispatch counter's ``tile`` label for this shape
+               "tile": fa.tile_label(fa.bhtd_tile(h, t, t, dh=dh))
+               if family == "bhtd" else "",
                "p_drop": p_drop, "backward": backward,
                "compile_s": round(compile_s, 2),
                "pallas_calls": lowered.as_text().count("tpu_custom_call"),

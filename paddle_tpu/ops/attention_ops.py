@@ -27,8 +27,10 @@ _M_DISPATCH = _monitor.counter(
     "attention implementation chosen at trace time, by family "
     "(bthd_small / bthd_kblock / bhtd Pallas kernels, the dense jnp "
     "composition, or ring), pass (fwd/bwd), shape (one device's share "
-    "for a kernel family under a mesh) and replicated_over (mesh axes "
-    "whose every rank repeats that same call)")
+    "for a kernel family under a mesh), tile (heads, query rows and key "
+    "rows of one grid step, where the family picks them by the shape: "
+    "bhtd) and replicated_over (mesh axes whose every rank repeats that "
+    "same call)")
 
 
 def _note_dispatch(family, direction, dims, replicated_over=()):
@@ -36,22 +38,32 @@ def _note_dispatch(family, direction, dims, replicated_over=()):
     if not _monitor.enabled() or not interp.lowering_active():
         return
     b, tq, tk, h, dh = dims
+    tile = ""
+    if family == "bhtd":
+        # the kernel layer's own answer for the call the op hands it (the
+        # op passes no q_block / k_block)
+        from paddle_tpu.parallel import flash_attention as fa
+
+        tile = fa.tile_label(fa.bhtd_tile(h, tq, tk, dh=dh))
     _M_DISPATCH.inc(labels={
         "family": family, "pass": direction,
-        "shape": f"b{b} tq{tq} tk{tk} h{h} dh{dh}",
+        "shape": f"b{b} tq{tq} tk{tk} h{h} dh{dh}", "tile": tile,
         "replicated_over": ",".join(replicated_over)})
 
 
-def dispatch_counts():
+def dispatch_counts(tiles=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
-    run print it."""
+    run print it. ``tiles``: a row whose family tiles by the shape names
+    its tile too, "bhtd fwd <shape> [hb1 bq512 bk512]"."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
         name = " ".join(lb.get(k, "?") for k in ("family", "pass", "shape"))
         if lb.get("replicated_over"):
             name += f" replicated_over={lb['replicated_over']}"
+        if tiles and lb.get("tile"):
+            name += f" [{lb['tile']}]"
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 

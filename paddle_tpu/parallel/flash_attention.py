@@ -1,28 +1,38 @@
-"""Pallas TPU flash attention (FlashAttention-2 style, head-batched).
+"""Pallas TPU flash attention (FlashAttention-2 style).
 
 The hot op of the transformer family (SURVEY.md section 7: "pallas kernels
 for the hot ops"). Both directions are K-blocked with online softmax: the
 score matrix never exists at full [tq, tk] size in any memory space, so
-VMEM use is O(h * block^2) and HBM traffic is O(t) regardless of context
+VMEM use is O(hb * block^2) and HBM traffic is O(t) regardless of context
 length — the property the long-context/ring-attention path builds on.
 
-Blocks batch ALL heads of one batch element per grid step ((1, h, bq, dh)
-blocks over the native [b, h, t, dh] layout). At short sequence lengths a
-per-(b*h) grid is dominated by per-step DMA/setup overhead (measured 331us
-per 44us-ideal forward at t=256); head-batching amortizes it 8x.
+The BHTD kernels tile a call by the shape they see (``_pick_tile``): a grid
+step works on hb heads x bq query rows x bk key rows, (1, hb, bq|bk, dh)
+blocks of the native [b, h, t, dh] layout over the grid (b, h/hb, tq/bq,
+tk/bk). Where all heads fit the VMEM caps at blocks of 256 they share a
+step (hb = h): a per-head grid at a short sequence is dominated by per-step
+DMA/setup overhead (measured 331us per 44us-ideal forward at t=256), and
+batching the heads amortizes it 8x. Where they do not (16 heads of 128, 8
+of 64), the heads go onto the grid and the blocks grow to 512 (256 or 128
+where 512 does not divide the sequence): batching the heads left a long
+sequence blocks of 128 x 128, 128 FLOPs per byte fetched against the v5e's
+ridge of 240.
 
-- Forward: grid (b, tq/bq, tk/bk); running (m, l, acc) in VMEM scratch
-  across the k-block loop; emits the output AND the logsumexp rows.
+- Forward: k-blocks inner; running (m, l, acc) in VMEM scratch across
+  the k-block loop; emits the output AND the logsumexp rows.
 - Backward: recompute p = exp(s - lse) per block (no stored attention).
   dq in one kernel (k-blocks inner), dk/dv in a second (q-blocks inner),
   using the standard delta = rowsum(do * o) reduction. Exposed as
   ``flash_attention_bwd`` so the framework's sdpa_grad op can consume the
   forward's saved (out, lse) instead of re-running the forward kernel
   (XLA cannot CSE custom calls, so a vjp-style recompute would execute).
+- ``causal``: a step whose block lies above the diagonal computes nothing
+  and fetches nothing (its index maps repeat the row's nearest live
+  block, and Pallas copies only a block whose index changed).
 - Attention dropout runs inside the kernels via the TPU PRNG: the mask for
   score block (b, jq, jk) is regenerated from a hash of (seed, b, jq, jk)
-  in every kernel, so forward and backward see identical masks and nothing
-  is stored.
+  in every kernel (and of the head group, where hb < h), so forward and
+  backward see identical masks and nothing is stored.
 
 ``bias`` is additive [b, 1|h, 1|tq, tk] mask plumbing, NOT a trainable
 input: its cotangent is zeros on the Pallas path (computing it would
@@ -49,20 +59,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_Q_BLOCK = 256
 DEFAULT_K_BLOCK = 256
+# Blocks of a tile whose heads went onto the grid (see _pick_tile).
+_GRID_HEADS_BLOCK = 512
 _NEG_INF = -1e30
 
-# Soft cap on the f32 score block (h * bq * bk * 4B). Mosaic sums ALL of
+# The two caps a tile (hb heads x bq query rows x bk key rows) is held
+# to. Both count what ONE grid step keeps in VMEM, so they scale with the
+# heads in the block, not with the heads of the call.
+#
+# Soft cap on the f32 score block (hb * bq * bk * 4B). Mosaic sums ALL of
 # a kernel's score-sized temps on its ~16MB scoped-vmem stack (the dkv
 # kernel holds ~6 of them plus casts and scratch), so the per-block cap
-# must stay well under limit/6 — 1.5MB lands bq=128 at h=8, bk=256,
-# which compiles with a [*, tq, tk] bias at t=1024 and beyond.
+# must stay well under limit/6. 1.5MB admits 8 heads of 128 x 256 (with
+# a [*, tq, tk] bias at t=1024 and beyond) and one head of 512 x 512.
 _SCORE_VMEM_BYTES = 3 * 2**19
-# Soft cap on what the dk/dv kernel keeps of (h, bk, dh) besides its
+# Soft cap on what the dk/dv kernel keeps of (hb, bk, dh) besides its
 # score temps: k and v (bf16, double-buffered), dk and dv out (the same)
-# and two f32 accumulators, 24 bytes an element. At h=16, dh=128 a
-# 256-row k block is 12.6MB of them and the kernel asked for 17.1MB of
-# Mosaic's 16MB (compiled for a v5e, PR 28); h=8, dh=64 holds 3.1MB and
-# keeps its 256.
+# and two f32 accumulators, 24 bytes an element. 16 heads of 128 with a
+# 256-row k block are 12.6MB of them and the kernel asked for 17.1MB of
+# Mosaic's 16MB (compiled for a v5e, PR 28); 8 heads of 64 hold 3.1MB,
+# one head of 128 with 512 rows 1.6MB.
 _KV_VMEM_BYTES = 2**23
 
 # Test hook: run the Pallas kernels in interpreter mode on CPU so the
@@ -77,12 +93,12 @@ def kernels_enabled() -> bool:
     return jax.default_backend() == "tpu" or bool(_INTERPRET)
 
 
-def _block_seed(seed, i, j, kk):
-    """Mix (seed, batch, q-block, k-block) into one scalar for the per-core
-    PRNG (the multi-operand prng_seed form doesn't lower on all backends).
-    int32 wraparound is the hash."""
+def _block_seed(seed, *keys):
+    """Mix (seed, batch row, [head group,] q-block, k-block) into one
+    scalar for the per-core PRNG (the multi-operand prng_seed form
+    doesn't lower on all backends). int32 wraparound is the hash."""
     s = seed
-    for x in (i, j, kk):
+    for x in keys:
         s = (s * jnp.int32(1000003)) ^ jnp.int32(x)
     return s
 
@@ -95,38 +111,84 @@ def _dropout_mask(p_keep: float, shape):
     return (bits < thresh).astype(jnp.float32) * (1.0 / p_keep)
 
 
-def _pick_blocks(h, tq, tk, q_block, k_block, dh):
-    bq = min(q_block, tq)
-    bk = min(k_block, tk)
-    while 24 * h * bk * dh > _KV_VMEM_BYTES and bk > 128:
-        bk //= 2
-    while h * bq * bk * 4 > _SCORE_VMEM_BYTES and bq > 64:
+def _tile_fits(hb, bq, bk, dh):
+    return (24 * hb * bk * dh <= _KV_VMEM_BYTES
+            and 4 * hb * bq * bk <= _SCORE_VMEM_BYTES)
+
+
+def _pick_tile(h, tq, tk, q_block, k_block, dh):
+    """-> (hb, bq, bk): heads, query rows and key rows of one grid step,
+    from what the call shows (h, dh, tq, tk) and the two VMEM caps.
+    ``q_block`` / ``k_block``: None for the kernels' own choice, a number
+    for an upper bound the caller sets (tests reach several blocks at a
+    small t that way).
+
+    Where all h heads fit at blocks of 256 they stay in one step: a
+    short sequence has few steps, and the per-step cost is what head
+    batching was built against. Where a cap refuses that, the heads go
+    onto the grid before a block shrinks, and the blocks grow to 512
+    instead: a step's FLOPs per byte fetched go with its q rows, and at
+    h * dh = 2048 a step over all heads could keep 128 of them. A block
+    that does not divide its sequence halves until it does (not under
+    128), and a step then keeps as many heads as the caps admit: hb4
+    256 x 256 at t = 768, all 16 heads of 128 at 128 x 128 at t = 640."""
+    bq = min(q_block or DEFAULT_Q_BLOCK, tq)
+    bk = min(k_block or DEFAULT_K_BLOCK, tk)
+    if _tile_fits(h, bq, bk, dh):
+        return h, bq, bk
+    bq = min(q_block or _GRID_HEADS_BLOCK, tq)
+    bk = min(k_block or _GRID_HEADS_BLOCK, tk)
+    # (a ring's quarter of 3072 is 768: 512 does not divide it, 256 does)
+    while tq % bq and bq > 128:
         bq //= 2
-    while h * bq * bk * 4 > _SCORE_VMEM_BYTES and bk > 128:
+    while tk % bk and bk > 128:
         bk //= 2
-    return bq, bk
+    while not _tile_fits(1, bq, bk, dh) and bk > 128:
+        bk //= 2
+    while not _tile_fits(1, bq, bk, dh) and bq > 64:
+        bq //= 2
+    hb = max(d for d in range(1, h + 1)
+             if h % d == 0 and (d == 1 or _tile_fits(d, bq, bk, dh)))
+    return hb, bq, bk
 
 
-def bhtd_family(h, tq, tk, q_block=DEFAULT_Q_BLOCK,
-                 k_block=DEFAULT_K_BLOCK, *, dh) -> str:
-    """"bhtd" (the head-batched K-blocked kernels) when the picked
-    blocks tile both sequence lengths, else "dense"."""
-    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block, dh)
+def bhtd_tile(h, tq, tk, q_block=None, k_block=None, *, dh):
+    """-> (hb, bq, bk), the tile the K-blocked [b, h, t, dh] kernels take
+    for a call of this shape, or None where they do not take it (no TPU
+    backend, or blocks that do not tile both sequence lengths) and it
+    runs as the dense composition. The one place that decides either:
+    the kernels' entry points, ``bhtd_family`` and the dispatch counter's
+    ``tile`` label all read it."""
+    hb, bq, bk = _pick_tile(h, tq, tk, q_block, k_block, dh)
     if kernels_enabled() and tq % bq == 0 and tk % bk == 0:
-        return "bhtd"
-    return "dense"
+        return hb, bq, bk
+    return None
+
+
+def tile_label(tile) -> str:
+    """A tile as the dispatch counter's ``tile`` label has it:
+    "hb1 bq512 bk512" ("" for None)."""
+    return "hb%d bq%d bk%d" % tile if tile else ""
+
+
+def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh) -> str:
+    """"bhtd" (the K-blocked [b, h, t, dh] kernels) when the picked
+    blocks tile both sequence lengths, else "dense"."""
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh)
+    return "bhtd" if tile else "dense"
 
 
 # ---------------------------------------------------------------------------
-# kernels — refs are blocks of the native [b, h, t, dh] layout; index 0
-# drops the leading size-1 batch-block dim, so shapes below are
-# q (h, bq, dh) / k, v (h, bk, dh) / bias (hb, 1|bq, bk) / lse (h, bq, 1).
+# kernels — refs are blocks of the native [b, h, t, dh] layout over the
+# grid (batch row, head group, q-block, k-block); index 0 drops the
+# leading size-1 batch-block dim, so shapes below are q (hb, bq, dh) /
+# k, v (hb, bk, dh) / bias (1|hb, 1|bq, bk) / lse (hb, bq, 1).
 # ---------------------------------------------------------------------------
 
 
 def _causal_mask(s, j, kk, bq, bk, transposed=False):
-    """Mask future positions inside score block (h, bq, bk) for q-block
-    j / k-block kk (``transposed``: block is (h, bk, bq))."""
+    """Mask future positions inside score block (hb, bq, bk) for q-block
+    j / k-block kk (``transposed``: block is (hb, bk, bq))."""
     if transposed:
         k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + kk * bk
         q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + j * bq
@@ -137,15 +199,55 @@ def _causal_mask(s, j, kk, bq, bk, transposed=False):
 
 
 def _causal_live(j, kk, bq, bk):
-    """Does block (q=j, k=kk) contain ANY unmasked element? Blocks fully
-    above the diagonal are skipped outright — the causal 2x compute cut
-    (loads still stream; compute and softmax are the bound)."""
+    """Does block (q=j, k=kk) contain ANY unmasked element? A block fully
+    above the diagonal is a dead step: its compute is skipped here, and
+    its operands are not fetched either, because the index maps hand a
+    dead step the block of its row's nearest live step (_live_k,
+    _live_q) and Pallas copies nothing when a block index repeats."""
     return kk * bk <= (j + 1) * bq - 1
 
 
+def _live_k(j, kk, bq, bk):
+    """The k-block step (j, kk) of a q-row reads under ``causal``: its
+    own while it is live, then the row's last live one. The k axis is the
+    inner one of the forward and dq grids, and a row's dead steps are its
+    tail."""
+    return jnp.minimum(kk, ((j + 1) * bq - 1) // bk)
+
+
+def _live_q(j, kk, bq, bk):
+    """The q-block step (j, kk) of a k-row reads under ``causal``: the
+    row's first live one until the walk reaches it, then its own. The q
+    axis is the inner one of the dk/dv grid, and a row's dead steps are
+    its head. (A k-row past the last query has no live step: the caller
+    bounds the result by the last q-block.)"""
+    return jnp.maximum(j, (kk * bk) // bq)
+
+
+def _seed_step(seed_ref, ng, j, kk):
+    """Seed the PRNG for score block (batch row, head group, j, kk).
+    With all heads in one group the key is (row, j, kk), the stream the
+    kernels have always drawn; with heads on the grid the group joins,
+    or every group would draw the same mask."""
+    keys = (pl.program_id(0) + seed_ref[1],)
+    if ng > 1:
+        keys += (pl.program_id(1),)
+    pltpu.prng_seed(_block_seed(seed_ref[0], *keys, j, kk))
+
+
+def _lanes(x, n):
+    """``x`` (.., 128), every lane of a row the same value, as (.., n)."""
+    if n <= 128:
+        return x[..., :n]
+    if n % 128 == 0:
+        return pltpu.repeat(x, n // 128, axis=x.ndim - 1)
+    return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, nk, p_drop, causal=False):
-    j, kk = pl.program_id(1), pl.program_id(2)
+                m_scr, l_scr, acc_scr, *, scale, nk, ng, p_drop,
+                causal=False):
+    j, kk = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
 
     @pl.when(kk == 0)
@@ -167,25 +269,29 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         if causal:
             s = _causal_mask(s, j, kk, bq, bk)
 
-        m_prev = m_scr[:, :, :1]
-        l_prev = l_scr[:, :, :1]
+        # m and l live replicated along the 128 lanes of their scratch:
+        # a row's max and sum are broadcast once each, and the score
+        # block and the accumulator read whole vregs of them (sliced to
+        # one lane and broadcast again every step, the forward took 2.95
+        # ms where it takes 1.65: OLMoE's shape on a v5e, PR 29)
+        m_prev = m_scr[:]
+        l_prev = l_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, bk))
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
 
         if p_drop > 0.0:
-            pltpu.prng_seed(
-                _block_seed(seed_ref[0], pl.program_id(0) + seed_ref[1],
-                            j, kk))
+            _seed_step(seed_ref, ng, j, kk)
             p = p * _dropout_mask(1.0 - p_drop, p.shape)
 
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[:] = (acc_scr[:] * _lanes(corr, acc_scr.shape[2])
+                      + jax.lax.dot_general(
+                          p.astype(v.dtype), v,
+                          (((2,), (1,)), ((0,), (0,))),
+                          preferred_element_type=jnp.float32))
+        m_scr[:] = m_new
+        l_scr[:] = l_new
 
     if causal:
         pl.when(_causal_live(j, kk, bq, bk))(_compute)
@@ -200,9 +306,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-               delta_ref, dq_ref, dq_scr, *, scale, nk, p_drop,
+               delta_ref, dq_ref, dq_scr, *, scale, nk, ng, p_drop,
                causal=False):
-    j, kk = pl.program_id(1), pl.program_id(2)
+    j, kk = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
 
     @pl.when(kk == 0)
@@ -214,8 +320,8 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0]        # (h, bq, 1) f32
-        delta = delta_ref[0]    # (h, bq, 1) f32
+        lse = lse_ref[0]        # (hb, bq, 1) f32
+        delta = delta_ref[0]    # (hb, bq, 1) f32
 
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
@@ -232,9 +338,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
         if p_drop > 0.0:
-            pltpu.prng_seed(
-                _block_seed(seed_ref[0], pl.program_id(0) + seed_ref[1],
-                            j, kk))
+            _seed_step(seed_ref, ng, j, kk)
             dp = dp * _dropout_mask(1.0 - p_drop, dp.shape)
         ds = p * (dp - delta) * scale
         dq_scr[:] += jax.lax.dot_general(
@@ -254,8 +358,8 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                scale, nq, p_drop, causal=False):
-    kk, jq = pl.program_id(1), pl.program_id(2)
+                scale, nq, ng, p_drop, causal=False):
+    kk, jq = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
 
     @pl.when(jq == 0)
@@ -268,10 +372,13 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse_t = jnp.transpose(lse_ref[0], (0, 2, 1))      # (h, 1, bq)
-        delta_t = jnp.transpose(delta_ref[0], (0, 2, 1))  # (h, 1, bq)
+        lse_t = lse_ref[0]      # (hb, 1, bq) f32: rows, as s_t wants them
+        delta_t = delta_ref[0]
+        if lse_t.shape[1] != 1:  # (hb, bq, 1) columns: flash_attention_bwd
+            lse_t = jnp.transpose(lse_t, (0, 2, 1))
+            delta_t = jnp.transpose(delta_t, (0, 2, 1))
 
-        # Work in the transposed orientation: s_t (h, bk, bq)
+        # Work in the transposed orientation: s_t (hb, bk, bq)
         s_t = jax.lax.dot_general(
             k, q, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -284,11 +391,10 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         p_t = jnp.exp(s_t - lse_t)
 
         if p_drop > 0.0:
-            # Same (b, q-block, k-block) stream as the forward, generated
-            # in the forward's (h, bq, bk) orientation then transposed.
-            pltpu.prng_seed(
-                _block_seed(seed_ref[0], pl.program_id(0) + seed_ref[1],
-                            jq, kk))
+            # Same (row, group, q-block, k-block) stream as the forward,
+            # generated in the forward's (hb, bq, bk) orientation then
+            # transposed.
+            _seed_step(seed_ref, ng, jq, kk)
             drop_t = jnp.transpose(
                 _dropout_mask(
                     1.0 - p_drop,
@@ -326,23 +432,57 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bias_spec(bias, bq, bk, *, transposed=False):
-    """BlockSpec for the stored-rank bias [b, 1|h, 1|tq, tk]. Index maps
-    take grid (i=batch, j=qblk, kk=kblk); ``transposed`` grids are
-    (i, kk, j)."""
-    hb, tq_b = bias.shape[1], bias.shape[2]
-    qdim = 1 if tq_b == 1 else bq
-    if transposed:
-        if tq_b == 1:
-            idx = lambda i, kk, j, *_: (i, 0, 0, kk)
-        else:
-            idx = lambda i, kk, j, *_: (i, 0, j, kk)
-    else:
-        if tq_b == 1:
-            idx = lambda i, j, kk, *_: (i, 0, 0, kk)
-        else:
-            idx = lambda i, j, kk, *_: (i, 0, j, kk)
-    return pl.BlockSpec((1, hb, qdim, bk), idx)
+def _step_blocks(causal, k_inner, bq, bk, nq):
+    """-> f(*grid ids) = (i, g, j, kk): batch row, head group, q-block
+    and k-block a grid step READS. The grid is (i, g, j, kk) with the k
+    axis inner (forward, dq) or (i, g, kk, j) with the q axis inner
+    (dk/dv); under ``causal`` the inner index of a dead step is its
+    row's nearest live one, so the step fetches nothing."""
+    def f(*ids):
+        i, g = ids[0], ids[1]
+        j, kk = (ids[2], ids[3]) if k_inner else (ids[3], ids[2])
+        if causal and k_inner:
+            kk = _live_k(j, kk, bq, bk)
+        elif causal:
+            j = jnp.minimum(_live_q(j, kk, bq, bk), nq - 1)
+        return i, g, j, kk
+    return f
+
+
+def _row_specs(at, hb, bq, bk, dh):
+    """Specs read at ``at``'s blocks: a (1, hb, bq, dh) block of q, out or
+    their gradients; a (1, hb, bq, 1) block of a [b, h, tq, 1] statistic;
+    a (1, hb, 1, bq) block of the same statistic laid out [b, h, 1, tq];
+    a (1, hb, bk, dh) block of k, v or their gradients."""
+    def q_idx(*ids):
+        i, g, j, _ = at(*ids)
+        return i, g, j, 0
+
+    def row_idx(*ids):
+        i, g, j, _ = at(*ids)
+        return i, g, 0, j
+
+    def k_idx(*ids):
+        i, g, _, kk = at(*ids)
+        return i, g, kk, 0
+
+    return (pl.BlockSpec((1, hb, bq, dh), q_idx),
+            pl.BlockSpec((1, hb, bq, 1), q_idx),
+            pl.BlockSpec((1, hb, 1, bq), row_idx),
+            pl.BlockSpec((1, hb, bk, dh), k_idx))
+
+
+def _bias_spec(bias, at, hb, bq, bk):
+    """BlockSpec for the stored-rank bias [b, 1|h, 1|tq, tk], read at
+    ``at``'s blocks."""
+    per_head, per_row = bias.shape[1] > 1, bias.shape[2] > 1
+
+    def idx(*ids):
+        i, g, j, kk = at(*ids)
+        return i, g if per_head else 0, j if per_row else 0, kk
+
+    return pl.BlockSpec(
+        (1, hb if per_head else 1, bq if per_row else 1, bk), idx)
 
 
 def _reference_scores(q, k, bias, scale, causal):
@@ -428,10 +568,25 @@ def _seed_cotangent(seed):
 # ---------------------------------------------------------------------------
 
 
+def _call_parts(kernel, at, tile, q, k, v, bias):
+    """What the three calls share: -> (the kernel, the specs and the
+    operands of q, k, v and the bias if there is one, _row_specs). With
+    no bias the kernel's bias_ref slot (the fifth) is None."""
+    rows = _row_specs(at, *tile, q.shape[3])
+    specs, args = [rows[0], rows[3], rows[3]], [q, k, v]
+    if bias is None:
+        body = kernel
+        kernel = lambda *refs, **kw: body(*refs[:4], None, *refs[4:], **kw)
+    else:
+        specs.append(_bias_spec(bias, at, *tile))
+        args.append(bias)
+    return kernel, specs, args, rows
+
+
 def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
                         p_drop: float = 0.0,
-                        q_block: int = DEFAULT_Q_BLOCK,
-                        k_block: int = DEFAULT_K_BLOCK,
+                        q_block: Optional[int] = None,
+                        k_block: Optional[int] = None,
                         causal: bool = False):
     """-> (out, lse) with lse [b, h, tq, 1] f32 — REAL logsumexp rows on
     every path including the dense fallback (the ring-attention merge
@@ -440,8 +595,8 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     ``causal=True`` applies the future mask IN-KERNEL (block-position
     iota compare) and skips fully-masked k-blocks outright — no [tq, tk]
     bias tensor exists anywhere, preserving the O(t) HBM property for
-    decoder self-attention, and the dead upper-triangle blocks cost no
-    MXU time (the causal ~2x)."""
+    decoder self-attention, and the dead upper-triangle blocks cost
+    neither MXU time nor a fetch (the causal ~2x)."""
     if p_drop > 0.0 and seed is None:
         raise ValueError(
             "flash_attention: p_drop > 0 requires a per-step `seed`; "
@@ -452,9 +607,8 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block, dh)
-    family = bhtd_family(h, tq, tk, q_block, k_block, dh=dh)
-    if family == "dense":
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh)
+    if tile is None:
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
         # derive from one score tensor (_reference_attention_with_lse).
@@ -462,40 +616,25 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
             q, k, v, bias, scale, p_drop,
             seed if p_drop > 0.0 else None, causal=causal)
 
-    nq, nk = tq // bq, tk // bk
-    in_specs = [
-        pl.BlockSpec((1, h, bq, dh), lambda i, j, kk, *_: (i, 0, j, 0)),
-        pl.BlockSpec((1, h, bk, dh), lambda i, j, kk, *_: (i, 0, kk, 0)),
-        pl.BlockSpec((1, h, bk, dh), lambda i, j, kk, *_: (i, 0, kk, 0)),
-    ]
-    args = [q, k, v]
-    if bias is not None:
-        in_specs.append(_bias_spec(bias, bq, bk))
-        args.append(bias)
-        kernel = functools.partial(_fwd_kernel, scale=scale, nk=nk,
-                                   p_drop=p_drop, causal=causal)
-    else:
-        kernel = functools.partial(
-            lambda sr, qr, kr, vr, orf, lr, ms, ls, accs, **kw: _fwd_kernel(
-                sr, qr, kr, vr, None, orf, lr, ms, ls, accs, **kw),
-            scale=scale, nk=nk, p_drop=p_drop, causal=causal,
-        )
-
+    hb, bq, bk = tile
+    ng, nq, nk = h // hb, tq // bq, tk // bk
+    kernel, in_specs, args, (q_spec, stat_spec, _, _) = _call_parts(
+        _fwd_kernel, _step_blocks(causal, True, bq, bk, nq), tile,
+        q, k, v, bias)
+    kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
+                               p_drop=p_drop, causal=causal)
     operands = (_seed_arr(seed), *args)
     out, lse = pl.pallas_call(
         kernel, name="attn.bhtd.fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, nq, nk),
+            grid=(b, ng, nq, nk),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, h, bq, dh), lambda i, j, kk, *_: (i, 0, j, 0)),
-                pl.BlockSpec((1, h, bq, 1), lambda i, j, kk, *_: (i, 0, j, 0)),
-            ],
+            out_specs=[q_spec, stat_spec],
             scratch_shapes=[
-                pltpu.VMEM((h, bq, 128), jnp.float32),
-                pltpu.VMEM((h, bq, 128), jnp.float32),
-                pltpu.VMEM((h, bq, dh), jnp.float32),
+                pltpu.VMEM((hb, bq, 128), jnp.float32),
+                pltpu.VMEM((hb, bq, 128), jnp.float32),
+                pltpu.VMEM((hb, bq, dh), jnp.float32),
             ],
         ),
         out_shape=[
@@ -509,8 +648,8 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
 
 def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                         p_drop: float = 0.0,
-                        q_block: int = DEFAULT_Q_BLOCK,
-                        k_block: int = DEFAULT_K_BLOCK,
+                        q_block: Optional[int] = None,
+                        k_block: Optional[int] = None,
                         causal: bool = False, g_lse=None):
     """-> (dq, dk, dv), consuming the forward's saved (out, lse).
 
@@ -524,9 +663,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    bq, bk = _pick_blocks(h, tq, tk, q_block, k_block, dh)
-    family = bhtd_family(h, tq, tk, q_block, k_block, dh=dh)
-    if family == "dense":
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh)
+    if tile is None:
         def f(q, k, v):
             return _reference_attention_with_lse(
                 q, k, v, bias, scale, p_drop,
@@ -536,95 +674,61 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         return vjp((g, jnp.zeros((b, h, tq, 1), jnp.float32)
                     if g_lse is None else g_lse))
 
-    nq, nk = tq // bq, tk // bk
+    hb, bq, bk = tile
+    ng, nq, nk = h // hb, tq // bq, tk // bk
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)  # [b, h, tq, 1]
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     seed_arr = _seed_arr(seed)
+    kw = dict(scale=scale, ng=ng, p_drop=p_drop, causal=causal)
 
-    # --- dq: grid (b, nq, nk), k-blocks inner ---
-    dq_specs = [
-        pl.BlockSpec((1, h, bq, dh), lambda i, j, kk, *_: (i, 0, j, 0)),   # q
-        pl.BlockSpec((1, h, bk, dh), lambda i, j, kk, *_: (i, 0, kk, 0)),  # k
-        pl.BlockSpec((1, h, bk, dh), lambda i, j, kk, *_: (i, 0, kk, 0)),  # v
-    ]
-    dq_args = [q, k, v]
-    if bias is not None:
-        dq_specs.append(_bias_spec(bias, bq, bk))
-        dq_args.append(bias)
-        dq_kernel = functools.partial(_dq_kernel, scale=scale, nk=nk,
-                                      p_drop=p_drop, causal=causal)
-    else:
-        dq_kernel = functools.partial(
-            lambda sr, qr, kr, vr, dor, lr, der, dqr, dqs, **kw: _dq_kernel(
-                sr, qr, kr, vr, None, dor, lr, der, dqr, dqs, **kw),
-            scale=scale, nk=nk, p_drop=p_drop, causal=causal,
-        )
-    dq_specs += [
-        pl.BlockSpec((1, h, bq, dh), lambda i, j, kk, *_: (i, 0, j, 0)),  # do
-        pl.BlockSpec((1, h, bq, 1), lambda i, j, kk, *_: (i, 0, j, 0)),   # lse
-        pl.BlockSpec((1, h, bq, 1), lambda i, j, kk, *_: (i, 0, j, 0)),   # delta
-    ]
-    dq_args += [g, lse, delta]
-
-    operands = (seed_arr, *dq_args)
+    # --- dq: grid (b, ng, nq, nk), k-blocks inner ---
+    kernel, specs, args, (q_spec, stat_spec, _, _) = _call_parts(
+        _dq_kernel, _step_blocks(causal, True, bq, bk, nq), tile,
+        q, k, v, bias)
+    kernel = functools.partial(kernel, nk=nk, **kw)
+    operands = (seed_arr, *args, g, lse, delta)
     dq = pl.pallas_call(
-        dq_kernel, name="attn.bhtd.bwd_dq",
+        kernel, name="attn.bhtd.bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, nq, nk),
-            in_specs=dq_specs,
-            out_specs=pl.BlockSpec((1, h, bq, dh),
-                                   lambda i, j, kk, *_: (i, 0, j, 0)),
-            scratch_shapes=[pltpu.VMEM((h, bq, dh), jnp.float32)],
+            grid=(b, ng, nq, nk),
+            in_specs=specs + [q_spec, stat_spec, stat_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((hb, bq, dh), jnp.float32)],
         ),
         out_shape=_result(operands, (b, h, tq, dh), q.dtype),
         interpret=_INTERPRET,
     )(*operands)
 
-    # --- dk/dv: grid (b, nk, nq), q-blocks inner ---
-    dkv_specs = [
-        pl.BlockSpec((1, h, bq, dh), lambda i, kk, j, *_: (i, 0, j, 0)),   # q
-        pl.BlockSpec((1, h, bk, dh), lambda i, kk, j, *_: (i, 0, kk, 0)),  # k
-        pl.BlockSpec((1, h, bk, dh), lambda i, kk, j, *_: (i, 0, kk, 0)),  # v
-    ]
-    dkv_args = [q, k, v]
-    if bias is not None:
-        dkv_specs.append(_bias_spec(bias, bq, bk, transposed=True))
-        dkv_args.append(bias)
-        dkv_kernel = functools.partial(_dkv_kernel, scale=scale, nq=nq,
-                                       p_drop=p_drop, causal=causal)
-    else:
-        dkv_kernel = functools.partial(
-            lambda sr, qr, kr, vr, dor, lr, der, dkr, dvr, dks, dvs, **kw:
-                _dkv_kernel(sr, qr, kr, vr, None, dor, lr, der, dkr, dvr,
-                            dks, dvs, **kw),
-            scale=scale, nq=nq, p_drop=p_drop, causal=causal,
-        )
-    dkv_specs += [
-        pl.BlockSpec((1, h, bq, dh), lambda i, kk, j, *_: (i, 0, j, 0)),  # do
-        pl.BlockSpec((1, h, bq, 1), lambda i, kk, j, *_: (i, 0, j, 0)),   # lse
-        pl.BlockSpec((1, h, bq, 1), lambda i, kk, j, *_: (i, 0, j, 0)),   # delta
-    ]
-    dkv_args += [g, lse, delta]
-
-    operands = (seed_arr, *dkv_args)
+    # --- dk/dv: grid (b, ng, nk, nq), q-blocks inner ---
+    # lse and delta as [b, h, 1, tq] rows: the kernel works on the
+    # transposed score block and wants them along the lanes. As
+    # [b, h, tq, 1] columns XLA pads each to 128 lanes for the call (64 MB
+    # at OLMoE's shape), the q axis being this grid's inner one a step
+    # fetches bq x 512 B of each, and the kernel transposes both. Only a
+    # q block off the 128-lane tiling (a caller's q_block of 64) keeps
+    # that form: it cannot be cut from a row.
+    kernel, specs, args, (q_spec, stat_spec, row_spec, kv_spec) = _call_parts(
+        _dkv_kernel, _step_blocks(causal, False, bq, bk, nq), tile,
+        q, k, v, bias)
+    kernel = functools.partial(kernel, nq=nq, **kw)
+    stats = [lse, delta]
+    if bq % 128 == 0 or bq == tq:
+        stat_spec = row_spec
+        stats = [x.reshape(b, h, 1, tq) for x in stats]
+    operands = (seed_arr, *args, g, *stats)
     dk, dv = pl.pallas_call(
-        dkv_kernel, name="attn.bhtd.bwd_dkv",
+        kernel, name="attn.bhtd.bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, nk, nq),
-            in_specs=dkv_specs,
-            out_specs=[
-                pl.BlockSpec((1, h, bk, dh),
-                             lambda i, kk, j, *_: (i, 0, kk, 0)),
-                pl.BlockSpec((1, h, bk, dh),
-                             lambda i, kk, j, *_: (i, 0, kk, 0)),
-            ],
+            grid=(b, ng, nk, nq),
+            in_specs=specs + [q_spec, stat_spec, stat_spec],
+            out_specs=[kv_spec, kv_spec],
             scratch_shapes=[
-                pltpu.VMEM((h, bk, dh), jnp.float32),
-                pltpu.VMEM((h, bk, dh), jnp.float32),
+                pltpu.VMEM((hb, bk, dh), jnp.float32),
+                pltpu.VMEM((hb, bk, dh), jnp.float32),
             ],
         ),
         out_shape=[
@@ -645,8 +749,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def flash_attention(q, k, v, bias=None, seed=None,
                     scale: Optional[float] = None, p_drop: float = 0.0,
-                    q_block: int = DEFAULT_Q_BLOCK,
-                    k_block: int = DEFAULT_K_BLOCK,
+                    q_block: Optional[int] = None,
+                    k_block: Optional[int] = None,
                     causal: bool = False):
     """o = dropout(softmax(q k^T * scale + bias)) v.
 
@@ -714,8 +818,8 @@ flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 def flash_attention_with_lse(q, k, v, bias=None, seed=None,
                              scale: Optional[float] = None,
                              p_drop: float = 0.0,
-                             q_block: int = DEFAULT_Q_BLOCK,
-                             k_block: int = DEFAULT_K_BLOCK,
+                             q_block: Optional[int] = None,
+                             k_block: Optional[int] = None,
                              causal: bool = False):
     """(out, lse) variant of ``flash_attention`` — same backward rule
     (shared ``_vjp_bwd``: blocked Pallas kernels, true dbias on the dense

@@ -1,0 +1,109 @@
+"""The BHTD attention kernels compile for a TPU v5e at the shapes the
+chip runs them at, on this CPU-only machine: the TPU's compiler is
+installed and compiles for a chip that is described, not attached
+(.claude/skills/verify/SKILL.md, "Compile for the chip without a chip").
+What Mosaic refuses (a tile over its scoped VMEM, a slice off the tiling)
+shows here at no chip time; nothing runs, so this says nothing about
+results or times. The topology is described inside a fixture: only the
+worker that is handed this file loads libtpu.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.parallel import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def real_kernels(monkeypatch):
+    """The dispatch takes the Pallas path (this process's backend is the
+    CPU) and lowers it through Mosaic, not the interpreter; as on the
+    chip, without 64-bit types (conftest.py turns them on for the CPU
+    suite and Mosaic takes none) and without the persistent compile
+    cache (an executable for a described chip cannot be read back here:
+    the next run would warn and compile again)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(fa, "kernels_enabled", lambda: True)
+    monkeypatch.setattr(fa, "_INTERPRET", False)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+# (b, h, t, dh, dtype, bias shape or None, causal, p_drop, the caller's
+# q_block or None, the tile)
+_CASES = {
+    "olmoe": (2, 16, 4096, 128, jnp.bfloat16, None, True, 0.0, None,
+              (1, 512, 512)),
+    "olmoe_row_bias": (1, 16, 4096, 128, jnp.bfloat16, (1, 1, 4096, 4096),
+                       True, 0.0, None, (1, 512, 512)),
+    "chip_smoke_bhtd": (2, 8, 4096, 64, jnp.bfloat16, (2, 1, 1, 4096), True,
+                        0.0, None, (1, 512, 512)),
+    "head_groups_dropout": (1, 16, 1024, 128, jnp.float32, None, False, 0.3,
+                            None, (1, 512, 512)),
+    "all_heads_in_a_step": (8, 2, 1024, 64, jnp.bfloat16, (8, 2, 1024, 1024),
+                            True, 0.1, None, (2, 256, 256)),
+    # 512 does not divide t: a ring's quarter of 3072 at OLMoE's heads,
+    # 12 heads of 64 at the score cap with a bias of every row and head
+    "t768_h16_dh128": (2, 16, 768, 128, jnp.bfloat16, (2, 1, 1, 768), True,
+                       0.1, None, (4, 256, 256)),
+    "t768_h12_dh64": (2, 12, 768, 64, jnp.bfloat16, (2, 12, 768, 768), False,
+                      0.1, None, (6, 256, 256)),
+    "t640_h16_dh128": (2, 16, 640, 128, jnp.bfloat16, None, True, 0.0, None,
+                       (16, 128, 128)),
+    # blocks of the whole of a t that is no power of two
+    "t384": (2, 8, 384, 64, jnp.bfloat16, (2, 1, 384, 384), True, 0.1, None,
+             (2, 384, 384)),
+    # a caller's q block off the 128 lanes: dk/dv reads lse and delta as
+    # columns
+    "q_block_64": (2, 2, 256, 64, jnp.bfloat16, (2, 1, 1, 256), True, 0.0,
+                   64, (2, 64, 256)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_bhtd_forward_and_backward_compile(case, one_chip, real_kernels):
+    (b, h, t, dh, dtype, bias_shape, causal, p_drop, q_block,
+     tile) = _CASES[case]
+    assert fa.bhtd_tile(h, t, t, q_block, dh=dh) == tile
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x = arg((b, h, t, dh), dtype)
+    bias = None if bias_shape is None else arg(bias_shape, jnp.float32)
+    seed = arg((), jnp.int32)
+
+    def loss(q, k, v, bias, seed):
+        out, lse = fa.flash_attention_with_lse(
+            q, k, v, bias, seed if p_drop else None, None, p_drop,
+            q_block, causal=causal)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, bias, seed).compile().as_text()
+    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
+        assert name in text, name
+    assert text.count("tpu_custom_call") >= 3

@@ -165,6 +165,39 @@ def test_mesh_attention_names_replicated_axes_and_refuses_uneven_batch(
         "bthd_small fwd b2 tq8 tk8 h2 dh16 replicated_over=model": 1}
 
 
+def test_dispatch_rows_name_the_bhtd_tile_and_the_keys_stay(telemetry,
+                                                            monkeypatch):
+    """pt_attention_dispatch_total: a ``bhtd`` row carries the tile its
+    shape takes (label ``tile``); the other families' is empty, and
+    ``dispatch_counts()`` is keyed as it was (family pass shape)."""
+    from paddle_tpu import monitor
+    from paddle_tpu.core import interp
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.parallel import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_INTERPRET", True)   # the kernels take calls
+    tok = interp.set_amp_active(False)  # as inside a block being lowered
+    try:
+        attention_ops._note_dispatch("bhtd", "fwd", (2, 4096, 4096, 16, 128))
+        attention_ops._note_dispatch("bhtd", "bwd", (2, 1024, 1024, 2, 64))
+        attention_ops._note_dispatch("bthd_small", "fwd", (64, 256, 256, 8, 64))
+    finally:
+        interp._AMP_ACTIVE.reset(tok)
+    rows = {(r["labels"]["family"], r["labels"]["pass"]): r["labels"]["tile"]
+            for r in monitor.snapshot()["pt_attention_dispatch_total"]["values"]}
+    assert rows == {("bhtd", "fwd"): "hb1 bq512 bk512",
+                    ("bhtd", "bwd"): "hb2 bq256 bk256",
+                    ("bthd_small", "fwd"): ""}
+    assert attention_ops.dispatch_counts() == {
+        "bhtd fwd b2 tq4096 tk4096 h16 dh128": 1,
+        "bhtd bwd b2 tq1024 tk1024 h2 dh64": 1,
+        "bthd_small fwd b64 tq256 tk256 h8 dh64": 1}
+    assert attention_ops.dispatch_counts(tiles=True) == {
+        "bhtd fwd b2 tq4096 tk4096 h16 dh128 [hb1 bq512 bk512]": 1,
+        "bhtd bwd b2 tq1024 tk1024 h2 dh64 [hb2 bq256 bk256]": 1,
+        "bthd_small fwd b64 tq256 tk256 h8 dh64": 1}
+
+
 def test_backend_peaks_raises_for_an_unknown_device():
     assert roofline.backend_peaks("cpu") == roofline.DEVICE_PEAKS["cpu"]
     assert roofline.backend_peaks("TPU v5 lite")[0] == roofline.V5E_PEAK_BF16

@@ -321,3 +321,185 @@ def test_lse_cotangent_flows_through_blocked_backward():
     for a, b, name in zip(g1, g2, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, err_msg=name)
+
+
+# --- heads on the grid: a tile of hb < h heads (PR 29) ---
+
+
+def _live_table(nq, nk, bq, bk):
+    return np.array([[bool(fa._causal_live(j, kk, bq, bk))
+                      for kk in range(nk)] for j in range(nq)])
+
+
+@pytest.mark.parametrize("nq,nk,bq,bk", [
+    (8, 8, 512, 512),     # OLMoE's tile at 4096
+    (4, 8, 256, 128),     # q blocks twice the k blocks
+    (8, 4, 128, 256),     # and half
+    (2, 6, 128, 128),     # tq < tk: the last k-rows have no live step
+    (6, 2, 64, 128),      # tq > tk: the last q-rows have no dead step
+    (3, 5, 192, 64),      # a ratio that is no power of two
+])
+def test_a_dead_step_reads_its_rows_nearest_live_block(nq, nk, bq, bk):
+    """The index maps of the operands that move along the inner grid
+    axis: a live step reads its own block, a dead one the block the
+    nearest live step of its row read (so Pallas copies nothing), and no
+    live step is skipped."""
+    live = _live_table(nq, nk, bq, bk)
+    for j in range(nq):          # forward and dq: k is the inner axis
+        reads = [int(fa._live_k(j, kk, bq, bk)) for kk in range(nk)]
+        alive = [kk for kk in range(nk) if live[j, kk]]
+        assert alive and alive == list(range(len(alive)))  # a dead tail
+        for kk in range(nk):
+            assert reads[kk] == (kk if live[j, kk] else alive[-1])
+        assert set(alive) <= set(reads)
+    for kk in range(nk):         # dk/dv: q is the inner axis
+        reads = [int(fa._live_q(j, kk, bq, bk)) for j in range(nq)]
+        alive = [j for j in range(nq) if live[j, kk]]
+        if not alive:            # the caller bounds it by nq - 1
+            assert min(reads) >= nq
+            continue
+        assert alive == list(range(alive[0], nq))          # a dead head
+        for j in range(nq):
+            assert reads[j] == (j if live[j, kk] else alive[0])
+        assert set(alive) <= set(reads)
+    # what the grid steps read through the specs' own index function
+    for k_inner in (True, False):
+        at = fa._step_blocks(True, k_inner, bq, bk, nq)
+        for j in range(nq):
+            for kk in range(nk):
+                ids = (0, 1, j, kk) if k_inner else (0, 1, kk, j)
+                i, g, jj, kkk = (int(x) for x in at(*ids, None))
+                assert (i, g) == (0, 1) and 0 <= jj < nq and 0 <= kkk < nk
+                if live[j, kk]:
+                    assert (jj, kkk) == (j, kk)
+                elif live[:, kk].any() or k_inner:
+                    assert live[jj, kkk]
+    at = fa._step_blocks(False, True, bq, bk, nq)
+    assert tuple(at(1, 0, 2, 3, None)) == (1, 0, 2, 3)
+
+
+# (h, dh): what trips a cap at blocks of 128 — the k/v cap (24 * h * 128 *
+# dh over 8 MB) or the score cap (4 * h * 128 * 128 over 1.5 MB) — and
+# the heads a step keeps
+_GRID_HEAD_SHAPES = [(4, 768, 2), (3, 1024, 1), (32, 16, 16)]
+
+
+def _assert_kernels_match_dense(b, h, t, dh, q_block, k_block, causal,
+                                bias_kind):
+    """Forward, dq / dk / dv and the lse cotangent of the kernels (at the
+    caller's blocks or, None, their own) against the dense
+    composition."""
+    q, k, v = _make_qkv(b=b, h=h, tq=t, tk=t, dh=dh)
+    bias = _pad_bias(b, t, 19)
+    if bias_kind == "per_head":
+        bias = bias + jnp.asarray(_rand((b, h, t, t), 11))
+    scale = float(1.0 / np.sqrt(dh))
+    w = jnp.asarray(_rand((b, h, t, dh), 12))
+    w_lse = jnp.linspace(0.1, 1.0, t)[None, None, :, None]
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            return (out * w).sum() + (lse * w_lse).sum()
+        return f
+
+    def kernels(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, bias, None, scale, 0.0,
+                                           q_block, k_block, causal)
+
+    def dense(q, k, v):
+        return fa._reference_attention_with_lse(q, k, v, bias, scale,
+                                                causal=causal)
+
+    o1, l1 = kernels(q, k, v)
+    o2, l2 = dense(q, k, v)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=3e-5)
+    np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=3e-5)
+    g1 = jax.grad(loss(kernels), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, r, name in zip(g1, g2, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=1e-4,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("bias_kind", ["pad", "per_head"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("h,dh,hb", _GRID_HEAD_SHAPES)
+def test_heads_on_the_grid_match_dense(h, dh, hb, causal, bias_kind):
+    """A call whose heads went onto the grid (hb < h), through 2 x 2
+    blocks."""
+    assert fa._pick_tile(h, 256, 256, 128, 128, dh) == (hb, 128, 128)
+    _assert_kernels_match_dense(2, h, 256, dh, 128, 128, causal, bias_kind)
+
+
+@pytest.mark.parametrize("t,q_block,tile", [
+    (768, None, (4, 256, 256)),   # 512 does not divide t: 3 x 3 of 256
+    (256, 64, (8, 64, 128)),      # a q block off the 128 lanes: dk/dv
+])                                # takes lse and delta as columns
+def test_blocks_that_512_does_not_give_match_dense(t, q_block, tile):
+    k_block = q_block and 128
+    assert fa._pick_tile(8, t, t, q_block, k_block, 64) == tile
+    _assert_kernels_match_dense(1, 8, t, 64, q_block, k_block, True, "pad")
+
+
+# (h, dh, t): the tile before PR 29 (all h heads in a step, blocks shrunk
+# by the caps) and the tile now. Where no cap shrank the blocks the tile
+# is what it was; where one did, the heads went onto the grid instead.
+# ``before`` None: the blocks did not divide t and the call ran dense.
+_TILES = [
+    (2, 64, 128, (2, 128, 128), (2, 128, 128)),
+    (2, 64, 256, (2, 256, 256), (2, 256, 256)),
+    (2, 64, 512, (2, 256, 256), (2, 256, 256)),
+    (2, 64, 1024, (2, 256, 256), (2, 256, 256)),
+    (8, 64, 128, (8, 128, 128), (8, 128, 128)),
+    (8, 64, 256, (8, 128, 256), (4, 256, 256)),     # ring blocks of 256
+    (8, 64, 512, (8, 128, 256), (1, 512, 512)),
+    (8, 64, 1024, (8, 128, 256), (1, 512, 512)),
+    (8, 64, 4096, (8, 128, 256), (1, 512, 512)),    # chip_smoke's bhtd case
+    (16, 128, 4096, (16, 128, 128), (1, 512, 512)),  # OLMoE
+    (16, 64, 4096, (16, 64, 256), (1, 512, 512)),
+    (4, 256, 1024, (4, 256, 256), (4, 256, 256)),
+    # 512 does not divide t: the blocks halve until they do, and the heads
+    # a step keeps follow the blocks
+    (8, 64, 768, (8, 128, 256), (4, 256, 256)),     # a ring's 3072 / 4
+    (8, 64, 1280, (8, 128, 256), (4, 256, 256)),
+    (8, 64, 1792, (8, 128, 256), (4, 256, 256)),
+    (12, 64, 768, (12, 128, 256), (6, 256, 256)),
+    (16, 128, 640, (16, 128, 128), (16, 128, 128)),
+    (16, 128, 768, (16, 128, 128), (4, 256, 256)),
+    (16, 128, 1280, (16, 128, 128), (4, 256, 256)),
+    (16, 128, 1792, (16, 128, 128), (4, 256, 256)),
+    (16, 128, 6400, (16, 128, 128), (4, 256, 256)),
+    (8, 64, 384, None, (2, 384, 384)),
+    (8, 64, 640, None, (8, 128, 128)),
+]
+
+
+@pytest.mark.parametrize("h,dh,t,before,now", _TILES)
+def test_tile_by_shape(h, dh, t, before, now):
+    assert fa._pick_tile(h, t, t, None, None, dh) == now
+    hb, bq, bk = now
+    assert h % hb == 0 and fa._tile_fits(hb, bq, bk, dh)
+    # the kernels take the call: every shape they took before, they take
+    assert fa.bhtd_tile(h, t, t, dh=dh) == now
+    assert fa.bhtd_family(h, t, t, dh=dh) == "bhtd"
+    assert fa.tile_label(now) == "hb%d bq%d bk%d" % now
+    if before is None:
+        pass
+    elif fa._tile_fits(*before, dh) and before[1:] == (min(256, t),) * 2:
+        assert now == before     # no cap had shrunk it: untouched
+    else:
+        assert t % before[1] == 0 and t % before[2] == 0
+        assert now == before or (
+            hb < h and bq * bk >= before[1] * before[2])
+    # a caller's bound holds the blocks down, whatever the heads do
+    assert fa._pick_tile(h, t, t, 64, 128, dh)[1:] == (64, 128)
+
+
+def test_no_kernels_no_tile(monkeypatch):
+    monkeypatch.setattr(fa, "_INTERPRET", False)    # the CPU backend
+    assert fa.bhtd_tile(16, 4096, 4096, dh=128) is None
+    assert fa.tile_label(None) == ""
+    assert fa.bhtd_family(16, 4096, 4096, dh=128) == "dense"
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    assert fa.bhtd_tile(8, 700, 700, dh=64) is None    # no block tiles 700

@@ -94,13 +94,17 @@ def test_hw_parity_vs_dense(b, tq, tk, h, dh):
             np.asarray(a, np.float32), np.asarray(b_, np.float32), atol=0.05)
 
 
-def test_bhtd_causal_at_16_heads_of_128_matches_dense():
+@pytest.mark.parametrize("t,tile", [
+    (4096, (1, 512, 512)), (768, (4, 256, 256)), (640, (16, 128, 128))])
+def test_bhtd_causal_at_16_heads_of_128_matches_dense(t, tile):
     # OLMoE's attention (models/olmoe.py: [b, 16, 4096, 128], causal, no
-    # bias), the first shape with heads of 128: the dk/dv kernel holds
-    # (h, bk, dh) six times over, so _pick_blocks counts dh (PR 28).
-    b, h, t, dh = 1, 16, 4096, 128
-    assert fa._pick_blocks(h, t, t, 256, 256, dh) == (128, 128)
-    assert fa.bhtd_family(h, t, t, dh=dh) == "bhtd"
+    # bias), the first shape with heads of 128. All 16 heads in a step
+    # left room for 128 x 128 blocks only (PR 28); the heads go onto the
+    # grid and a step works on one head's 512 x 512 (PR 29). Where 512
+    # does not divide t (a ring's quarter of 3072) the blocks halve until
+    # they do, and the kernels still take the call.
+    b, h, dh = 1, 16, 128
+    assert fa.bhtd_tile(h, t, t, dh=dh) == tile
     r = np.random.RandomState(5)
     q, k, v = (jnp.asarray(r.normal(0, 1, (b, h, t, dh))).astype(
         jnp.bfloat16) for _ in range(3))
@@ -170,6 +174,40 @@ def test_dropout_grad_v_is_exact_linear():
     _assert_linear_in_v(
         lambda v: jnp.sum(fa.flash_attention(
             q, k, v, seed=seed, p_drop=0.4, q_block=128, k_block=128)), v)
+
+
+def test_head_groups_draw_their_own_dropout_masks():
+    """Heads on the grid (hb < h): the head group joins the seed of a
+    score block's mask. Every head is fed the same q, k, v, so two heads
+    differ by their masks alone; the dk/dv kernel regenerates the
+    forward's mask (out is linear in v) and so does the dq kernel (a
+    directional difference in q, at a fixed mask)."""
+    h, t, dh = 16, 1024, 128
+    assert fa._pick_tile(h, t, t, None, None, dh) == (1, 512, 512)
+    q, k, v = (jnp.broadcast_to(_rand((1, 1, t, dh), i), (1, h, t, dh))
+               for i in range(3))
+    seed = jnp.asarray(17, jnp.int32)
+
+    def attend(q, k, v, p_drop=0.3):
+        return fa.flash_attention(q, k, v, seed=seed, p_drop=p_drop)
+
+    plain = np.asarray(attend(q, k, v, 0.0))
+    np.testing.assert_array_equal(plain[0, 0], plain[0, 1])
+    out = np.asarray(attend(q, k, v))
+    np.testing.assert_array_equal(out, np.asarray(attend(q, k, v)))
+    for other in range(1, h):
+        assert np.abs(out[0, 0] - out[0, other]).mean() > 1e-3, other
+    assert np.abs(out - plain).mean() < 0.15
+    w = _rand((1, h, t, dh), 5)
+    _assert_linear_in_v(lambda v: jnp.sum(attend(q, k, v) * w), v)
+    # dq: the loss is smooth in q at a fixed mask, so the analytic
+    # directional derivative meets the central difference
+    f = lambda q: jnp.sum(attend(q, k, v) * w)
+    direction = _rand(q.shape, 9) * 0.01
+    an = float(jnp.vdot(jax.grad(f)(q), direction))
+    fd = float(f(q + direction) - f(q - direction)) / 2.0
+    mass = float(jnp.vdot(jnp.abs(jax.grad(f)(q)), jnp.abs(direction)))
+    assert abs(an - fd) < 2e-2 * mass, (an, fd, mass)
 
 
 def test_bthd_dropout_deterministic():

@@ -311,11 +311,14 @@ def test_expert_rows_reach_the_monitor_with_telemetry_on():
 
 
 @pytest.mark.parametrize("h,dh,want", [
-    (8, 64, (128, 256)),      # as before PR 28: the score cap alone
-    (16, 128, (128, 128)),    # OLMoE: 256 rows of k at 16 x 128 overflow
-    (16, 64, (64, 256)),
+    # where all heads at blocks of 256 pass a VMEM cap (PR 28: 16 x 128
+    # overflowed the dk/dv kernel), the heads go onto the grid and the
+    # blocks grow (PR 29); tests/test_flash_attention.py has the table
+    (8, 64, (1, 512, 512)),
+    (16, 128, (1, 512, 512)),     # OLMoE
+    (2, 64, (2, 256, 256)),       # no cap reached: all heads in a step
 ])
-def test_bhtd_blocks_count_the_head_width(h, dh, want):
+def test_bhtd_tile_counts_the_head_width(h, dh, want):
     from paddle_tpu.parallel import flash_attention as fa
 
-    assert fa._pick_blocks(h, 4096, 4096, 256, 256, dh) == want
+    assert fa._pick_tile(h, 4096, 4096, None, None, dh) == want
