@@ -6,7 +6,7 @@ first touches jax, so this parent never imports jax: it runs each row —
 the headline included — as a child process in turn (``python bench.py
 --row`` for the transformer rows, the other ``bench_*.py`` scripts for
 the rest), merges their JSON rows, and exits non-zero when any child
-failed. ``PT_BENCH_{RESNET,LONGCTX,FAMILIES,WARMSTART,PIPELINE,SERVING}=0``
+failed. ``PT_BENCH_{RESNET,LONGCTX,FAMILIES,PIPELINE,SERVING}=0``
 drop rows.
 
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...}.
@@ -189,11 +189,6 @@ def plan():
         for t, bt in (("1024", "8"), ("4096", "2"), ("8192", "1")):
             rows.append((f"long_context_t{t}", script("bench.py", "--row"),
                          {"PT_BENCH_BATCH": bt, "PT_BENCH_SEQ": t}))
-    if on("PT_BENCH_WARMSTART"):
-        # cold-vs-warm start through the persistent compile cache: two
-        # fresh children against one fresh cache dir; the second must
-        # resolve every executable from disk (zero fresh XLA compiles)
-        rows.append(("warm_start", script("bench_warmstart.py"), {}))
     if on("PT_BENCH_SERVING"):
         # continuous-batching decode: tokens/s + per-token latency
         # quantiles under a concurrency sweep through the serving

@@ -12,7 +12,7 @@ examples/sec) — fail when they drop below the trailing best. A small
 explicit allowlist of lower-is-better latency metrics
 (``LATENCY_TOLERANCE``: serving TTFT / queue-wait p95) fail when they
 rise above the trailing best (the MINIMUM across history). All other
-lower-is-better riders (warm-start seconds, pipeline step times) are
+lower-is-better riders (pipeline step times) are
 reported informationally but never gate: their CPU-vs-TPU variance is
 not a regression signal.
 

@@ -42,10 +42,8 @@ bench_regress.py at the degraded-row envelope), per-token p99
 signal; ``shed_rate`` = refusals per offered request, can exceed 1),
 and ``vs_single`` — the fleet's tokens/s over the single replica's at
 the same offered load: the measured multiple of single-replica
-sustainable throughput the fleet absorbs. The fleet section
-arms a temporary persistent compile cache so replicas 2..N spin up
-through the disk-tier warm start (the autoscaler's path) instead of
-recompiling.
+sustainable throughput the fleet absorbs. Replicas 2..N read their
+XLA compiles from jax's persistent cache (the autoscaler's path).
 
 Caveat: every replica's loop thread dispatches through the same host
 cores and interpreter lock, and all replicas share one device, so
@@ -244,7 +242,7 @@ def main():
 
     configure_process()
     import paddle_tpu as fluid
-    from paddle_tpu import flags, jax_cache, monitor
+    from paddle_tpu import flags, monitor
     from paddle_tpu.models import transformer as T
 
     flags.set_flags({"telemetry": True})
@@ -294,24 +292,17 @@ def main():
                                  / full["tokens_per_sec"], 3)
                            if full["tokens_per_sec"] else 0.0),
         }
-    # fleet row: N routed replicas vs ONE at the same offered load,
-    # behind a fresh disk-tier compile cache (a fixed, emptied path) so
-    # replicas 2..N (and the fleet-of-one rerun) warm-start from disk
-    # instead of paying N fresh XLA compiles
+    # fleet row: N routed replicas vs ONE at the same offered load
+    # (replicas 2..N and the fleet-of-one rerun read their XLA compiles
+    # from jax's persistent cache, placed by configure_process)
     fleet_row = None
     if os.environ.get("PT_BENCH_SERVE_FLEET", "1") == "1" and REPLICAS > 1:
         conc = REPLICAS * SLOTS
         n_req = 2 * conc
-        old_cc = flags.get_flag("compile_cache_dir")
-        flags.set_flags(
-            {"compile_cache_dir": jax_cache.fresh_dir("bench_fleet_cc")})
-        try:
-            multi = _fleet_level(cfg, scope, REPLICAS, conc, n_req)
-            log(f"fleet x{REPLICAS}: {multi}")
-            single = _fleet_level(cfg, scope, 1, conc, n_req)
-            log(f"fleet x1 (same offered load): {single}")
-        finally:
-            flags.set_flags({"compile_cache_dir": old_cc})
+        multi = _fleet_level(cfg, scope, REPLICAS, conc, n_req)
+        log(f"fleet x{REPLICAS}: {multi}")
+        single = _fleet_level(cfg, scope, 1, conc, n_req)
+        log(f"fleet x1 (same offered load): {single}")
         fleet_row = {
             "metric": "serving_fleet_tokens_per_sec",
             "value": multi["tokens_per_sec"],

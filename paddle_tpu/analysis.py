@@ -64,9 +64,9 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
-from paddle_tpu import compile_cache as _ccache
 from paddle_tpu import flags as _flags
 from paddle_tpu import monitor as _monitor
+from paddle_tpu.core import fingerprint as _fingerprint
 from paddle_tpu.framework import (
     _BATCH_SENTINEL,
     Block,
@@ -1117,10 +1117,10 @@ def lint_active() -> bool:
     return _mode != "off"
 
 
-# Canonical (compile_cache.program_fingerprint) signatures already
+# Canonical (core.fingerprint.program_fingerprint) signatures already
 # linted pre-compile: a recompile of the same signature never re-lints.
-# Content-keyed like the executor/compile caches — two identically-built
-# programs share one lint run.
+# Content-keyed like the executor's compiled-entry cache — two
+# identically-built programs share one lint run.
 _SEEN: "collections.OrderedDict[str, bool]" = collections.OrderedDict()
 _SEEN_CAP = 512
 
@@ -1137,12 +1137,11 @@ def _dispatch(findings: List[Finding], site: str):
 
 def _strategy_token(strategy) -> tuple:
     """Content fingerprint of a DistributedStrategy — THE canonical one
-    (compile_cache.strategy_token), shared with the executor cache key
-    and the persistent compile cache so the three subsystems can never
-    drift. id() would alias a fresh strategy to a GC-reused address (the
+    (core.fingerprint.strategy_token), shared with the executor cache key
+    and the compile report so the three subsystems can never drift. id() would alias a fresh strategy to a GC-reused address (the
     same hazard executor._latest_stacked pins references against);
     content keying also lets two equal strategies share one lint run."""
-    return _ccache.strategy_token(strategy)
+    return _fingerprint.strategy_token(strategy)
 
 
 def lint_before_compile(program: Program,
@@ -1154,7 +1153,7 @@ def lint_before_compile(program: Program,
     strategy) fingerprint, right before the first compile of that
     signature. Logs warning/error findings; raises LintError under
     ``static_lint=error``. Callers must gate on ``lint_active()``."""
-    key = _ccache.fingerprint_for(
+    key = _fingerprint.fingerprint_for(
         ("lint", program._uid, program.version, tuple(feed_names),
          tuple(fetch_names), _strategy_token(strategy)),
         program, strategy=strategy, feed_sig=tuple(feed_names),
@@ -1180,7 +1179,7 @@ def lint_at_build(program: Program, strategy=None,
     on ``lint_active()`` internally — call sites stay one-liners."""
     if not lint_active():
         return
-    key = _ccache.fingerprint_for(
+    key = _fingerprint.fingerprint_for(
         ("lint-build", program._uid, program.version, site,
          _strategy_token(strategy)),
         program, strategy=strategy, extra=("lint-build", site))

@@ -156,7 +156,6 @@ def jit_lowered(
     lowered: LoweredBlock,
     in_shardings=None,
     out_shardings=None,
-    donate_state: bool = True,
     fold_step: bool = False,
 ):
     """Wrap the traced block in jax.jit with parameter-buffer donation.
@@ -171,9 +170,7 @@ def jit_lowered(
     async-prefetched off the critical path) and executables with custom
     entry layouts deserialized broken from the persistent XLA compilation
     cache."""
-    kwargs: Dict[str, Any] = {}
-    if donate_state:
-        kwargs["donate_argnums"] = (0,)
+    kwargs: Dict[str, Any] = {"donate_argnums": (0,)}
     if in_shardings is not None:
         kwargs["in_shardings"] = in_shardings
     if out_shardings is not None:
@@ -188,8 +185,7 @@ def jit_lowered(
 
 
 def jit_lowered_multi(lowered: LoweredBlock, n_feeds: int,
-                      track_nonfinite: bool = False,
-                      donate_state: bool = True):
+                      track_nonfinite: bool = False):
     """Compile ``n_steps`` training steps as ONE XLA program.
 
     The returned fn has signature
@@ -277,13 +273,7 @@ def jit_lowered_multi(lowered: LoweredBlock, n_feeds: int,
             return fetches, {**st, **ex}, bad
         return fetches, {**st, **ex}
 
-    kwargs: Dict[str, Any] = {}
-    if donate_state:
-        # the serialized-executable tier compiles a donation-free twin:
-        # deserialized donating executables mishandle buffer ownership
-        # from their second call on (jax 0.4.x) — see compile_cache.py
-        kwargs["donate_argnums"] = (0,)
-    return jax.jit(multi_fn, static_argnums=(4,), **kwargs)
+    return jax.jit(multi_fn, static_argnums=(4,), donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------
@@ -362,12 +352,7 @@ def build_compile_report(
         report["window_steps"] = int(window_steps)
     try:
         t0 = _time.perf_counter()
-        # an entry built through the persistent compile cache carries
-        # its AOT executable (compile_cache._wrap): analyze that instead
-        # of AOT-compiling a twin
-        compiled = getattr(jitfn, "_pt_compiled", None)
-        if compiled is None:
-            compiled = jitfn.lower(*args).compile()
+        compiled = jitfn.lower(*args).compile()
         report["analysis_ms"] = (_time.perf_counter() - t0) * 1e3
     except Exception:
         return report

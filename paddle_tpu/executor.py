@@ -22,11 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu import analysis as _analysis
-from paddle_tpu import compile_cache as _ccache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor as _monitor
 from paddle_tpu import numerics as _numerics
 from paddle_tpu import roofline as _roofline
+from paddle_tpu.core import fingerprint as _fingerprint
 from paddle_tpu.core import lowering
 from paddle_tpu.framework import (
     CPUPlace,
@@ -351,12 +351,11 @@ class Executor:
                 (k, tuple(np.shape(v)), str(jnp.result_type(v)))
                 for k, v in feed_vals.items()
             )
-            # Canonical fingerprint (compile_cache.program_fingerprint):
-            # content-keyed, shared with the lint-once cache, the compile
-            # report cache_key, and the persistent disk tier. The memo keyed
-            # by this cheap identity tuple keeps the hot path at one dict
-            # read (program._amp is identity-relevant: flipping it does NOT
-            # bump the version).
+            # Canonical fingerprint (core.fingerprint.program_fingerprint):
+            # content-keyed, shared with the lint-once cache and the compile
+            # report cache_key. The memo keyed by this cheap identity tuple
+            # keeps the hot path at one dict read (program._amp is
+            # identity-relevant: flipping it does NOT bump the version).
             ident = (
                 program._uid,
                 program.version,
@@ -365,32 +364,15 @@ class Executor:
                 sig,
                 tuple(run_fetch_names),
             )
-            fp = _ccache.fingerprint_for(ident, program, compiled=compiled,
-                                         feed_sig=sig,
-                                         fetch_names=run_fetch_names)
+            fp = _fingerprint.fingerprint_for(
+                ident, program, compiled=compiled, feed_sig=sig,
+                fetch_names=run_fetch_names)
             key = (fp, scope._uid)
 
             def build():
                 return self._compile(
                     program, compiled, feed_names, run_fetch_names, scope
                 )
-
-            def pure_build(lowered):
-                # the donation-free twin the disk tier stores (see
-                # _cache_entry / _jit_for)
-                return self._jit_for(lowered, compiled, donate_state=False)
-
-            spec_factory = None
-            if use_program_cache and _ccache.active():
-                # level-2 disk tier: the spec (state avals gathered from the
-                # scope, digest, example args) is only built on a level-1
-                # miss — see _cache_entry
-                def spec_factory():
-                    return _ccache.executor_spec(
-                        program, feed_vals=feed_vals,
-                        fetch_names=run_fetch_names, scope=scope,
-                        base_key=self._base_key_for(program),
-                        fingerprint=fp, compiled=compiled)
 
             if _analysis.lint_active():
                 # static verifier BEFORE the first compile of this signature
@@ -412,7 +394,7 @@ class Executor:
                     program, {k: np.shape(v) for k, v in feed_vals.items()})
             if use_program_cache:
                 entry, outcome, evictions, compile_ms = self._cache_entry(
-                    key, build, spec_factory, program, pure_build=pure_build)
+                    key, build, program)
             else:
                 entry, compile_ms = self._timed_build(build, program)
                 outcome, evictions = "miss", 0
@@ -736,15 +718,15 @@ class Executor:
             )
             # Canonical fingerprint (see run()); the window variant folds in
             # the feed-rotation length and the nan-track flavor. ``steps``
-            # rides the L1 KEY, not the fingerprint content hash: the jit
-            # treats it as a static argument, but a disk-resolved executable
-            # bakes it in, so entries must be steps-distinct end to end.
+            # rides the KEY, not the fingerprint content hash: it is a static
+            # argument of the jit, so each value is a compile of its own and
+            # its entry reports its own ``miss`` and ``compile_ms``.
             ident = (
                 "multi", program._uid, program.version,
                 getattr(program, "_amp", False), len(feed_list), sig,
                 tuple(run_fetch_names), nan_track,
             )
-            fp = _ccache.fingerprint_for(
+            fp = _fingerprint.fingerprint_for(
                 ident, program, feed_sig=sig, fetch_names=run_fetch_names,
                 extra=("multi", len(feed_list), bool(nan_track)))
             key = (fp, scope._uid, int(steps))
@@ -759,23 +741,6 @@ class Executor:
                 return (lowering.jit_lowered_multi(lowered, len(feed_list),
                                                    track_nonfinite=nan_track),
                         lowered)
-
-            def pure_build(lowered):
-                # donation-free twin for the disk tier (see _cache_entry)
-                return lowering.jit_lowered_multi(
-                    lowered, len(feed_list), track_nonfinite=nan_track,
-                    donate_state=False)
-
-            spec_factory = None
-            if _ccache.active():
-                # level-2 disk tier (see run()): built only on a level-1 miss
-                def spec_factory():
-                    return _ccache.executor_spec(
-                        program, feed_vals=stacked,
-                        fetch_names=run_fetch_names, scope=scope,
-                        base_key=self._base_key_for(program),
-                        fingerprint=fp, window_steps=int(steps),
-                        n_feeds=len(feed_list), nan_track=nan_track)
 
             if _analysis.lint_active():
                 # static verifier before the window's first compile (run()
@@ -792,7 +757,7 @@ class Executor:
                     program,
                     {k: tuple(v.shape[1:]) for k, v in stacked.items()})
             entry, outcome, evictions, compile_ms = self._cache_entry(
-                key, build, spec_factory, program, pure_build=pure_build)
+                key, build, program)
             cache_hit = outcome != "miss"
             fn, lowered = entry
         with _monitor.span("executor.state"):
@@ -940,20 +905,16 @@ class Executor:
 
     # --- shared plumbing for run()/run_steps() ---
 
-    def _cache_entry(self, key, build, spec_factory=None, program=None,
-                     pure_build=None):
-        """LRU lookup-or-build with the capacity eviction policy and the
-        persistent level-2 tier (compile_cache.py) between them.
+    def _cache_entry(self, key, build, program=None):
+        """LRU lookup-or-build with the capacity eviction policy.
 
         Returns ``(entry, outcome, evictions, compile_ms)`` where
-        ``outcome`` is ``"hit"`` (in-memory), ``"disk"`` (executable
-        deserialized from the persistent cache — no trace, no XLA
-        compile; ``compile_ms`` is then the load time) or ``"miss"``
-        (fresh compile). The outcome rides the return value (not
-        instance state) so the step-log assembly can never read a stale
-        previous call's outcome. ``spec_factory`` — passed only while
-        the disk tier is active — builds the disk-resolution spec
-        lazily: a level-1 hit never pays for it."""
+        ``outcome`` is ``"hit"`` (in-memory) or ``"miss"`` (built now:
+        traced and lowered on first call, its XLA compile read from
+        jax's persistent cache where one is placed and warm). The
+        outcome rides the return value (not instance state) so the
+        step-log assembly can never read a stale previous call's
+        outcome."""
         entry = self._cache.get(key)
         if entry is not None:
             self._cache.pop(key)
@@ -961,38 +922,7 @@ class Executor:
             _M_CACHE_HITS.inc()
             return entry, "hit", 0, None
         _M_CACHE_MISSES.inc()
-        outcome = "miss"
-        entry = compile_ms = None
-        spec = spec_factory() if spec_factory is not None else None
-        if spec is not None:
-            loaded = _ccache.load(spec)
-            if loaded is not None:
-                fn, compile_ms = loaded
-                # block analysis only — a disk hit never traces
-                entry = (fn, spec.make_lowered())
-                outcome = "disk"
-        if entry is None:
-            if spec is not None:
-                # disk miss with the tier on: AOT-compile through the
-                # spec (one trace + one XLA compile — the same cost the
-                # eager jit would pay lazily) and persist the executable
-                # for the next process; an AOT failure keeps the eager
-                # jit and stores nothing. The AOT twin is built WITHOUT
-                # input donation (``pure_build``): a deserialized
-                # donating executable corrupts buffer ownership from its
-                # second call on (jax 0.4.x flaky use-after-free), and a
-                # stored entry must execute correctly in every process —
-                # the memory win of donation is not worth wrong values.
-                def build_aot(_build=build):
-                    fn, lowered = _build()
-                    target = (pure_build(lowered)
-                              if pure_build is not None else fn)
-                    aot = _ccache.aot_build(spec, target)
-                    return (fn if aot is None else aot), lowered
-
-                entry, compile_ms = self._timed_build(build_aot, program)
-            else:
-                entry, compile_ms = self._timed_build(build, program)
+        entry, compile_ms = self._timed_build(build, program)
         self._cache[key] = entry
         from paddle_tpu import flags as _flags_mod
 
@@ -1009,7 +939,7 @@ class Executor:
             evicted += 1
         if evicted:
             _M_CACHE_EVICTIONS.inc(evicted)
-        return entry, outcome, evicted, compile_ms
+        return entry, "miss", evicted, compile_ms
 
     def _timed_build(self, build, program=None):
         """Compile under the unified span; returns ``(entry,
@@ -1255,13 +1185,8 @@ class Executor:
         return self._jit_for(lowered, compiled), lowered
 
     @staticmethod
-    def _jit_for(lowered, compiled, donate_state=True):
-        """jax.jit wrapper in the executor call convention.
-        ``donate_state=False`` builds the serialization-safe twin the
-        persistent compile cache stores (see compile_cache.aot_build):
-        deserialized DONATING executables corrupt buffer ownership from
-        their second call on (jax 0.4.x use-after-free), so disk-tier
-        executables run without input donation."""
+    def _jit_for(lowered, compiled):
+        """jax.jit wrapper in the executor call convention."""
         in_shardings = out_shardings = None
         if compiled is not None:
             in_shardings, out_shardings = compiled.shardings(lowered)
@@ -1271,5 +1196,5 @@ class Executor:
                 in_shardings = (*in_shardings, repl)
         return lowering.jit_lowered(
             lowered, in_shardings=in_shardings, out_shardings=out_shardings,
-            fold_step=True, donate_state=donate_state,
+            fold_step=True,
         )

@@ -181,12 +181,6 @@ BUILTIN_SITES = {
                       "fetch boundary — must still run donated-buffer "
                       "hygiene + OOM forensics)",
     "io.export": "inference-model export publish (io.py)",
-    "ccache.load": "persistent compile-cache entry read, pre-deserialize "
-                   "(compile_cache.load; truncate = corrupt published "
-                   "entry, which must degrade to a metered miss)",
-    "ccache.store": "persistent compile-cache staged write, pre-rename "
-                    "(compile_cache.store; raise/truncate = torn store — "
-                    "the atomic publish must leave no torn entry)",
     "serve.enqueue": "serving request intake, pre-queue (serving.py "
                      "ServingEngine.submit; raise = failed admission "
                      "path — the request must surface the error, not "

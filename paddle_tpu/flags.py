@@ -163,20 +163,6 @@ _DEFS: Dict[str, tuple] = {
                                   "peak device memory bandwidth for "
                                   "roofline verdicts (0 = backend "
                                   "default)"),
-    # persistent level-2 compile cache (compile_cache.py): serialized
-    # AOT executables resolved from this directory BEFORE tracing, so a
-    # fresh process warm-starts a known program in seconds instead of
-    # minutes; entries are keyed by a canonical content fingerprint +
-    # environment token and written atomically.
-    # Empty = disabled (the executor hot path is one boolean check).
-    "compile_cache_dir": (str, "", "persistent compile-cache directory"),
-    # disk budget for compile_cache_dir: after each store the cache runs
-    # a size-capped LRU-by-mtime sweep (loads refresh mtime, so the
-    # least-recently-USED entries go first; evictions metered by
-    # pt_compile_cache_evictions_total); 0 = unbounded
-    "compile_cache_max_bytes": (int, 0,
-                                "disk size cap for the persistent "
-                                "compile cache (LRU-by-mtime sweep)"),
     # pre-compile static program verifier (analysis.py): 'warn' lints
     # every program before its first compile and logs warning/error
     # findings; 'error' additionally raises LintError on error-severity
@@ -204,8 +190,8 @@ _DEFS: Dict[str, tuple] = {
                                 "submit time"),
     # EngineSupervisor wedge detection: a busy engine whose decode-loop
     # heartbeat is older than this is declared wedged, torn down and
-    # warm-restarted through the persistent compile cache; declaring a
-    # wedge also emits a monitor stall record for site "serve.decode"
+    # rebuilt; declaring a wedge also emits a monitor stall record for
+    # site "serve.decode"
     # (per-dispatch stall_guard deadlines stay on the global
     # stall_timeout_ms flag)
     "serve_wedge_timeout_ms": (int, 30_000,
@@ -255,8 +241,8 @@ _DEFS: Dict[str, tuple] = {
     # aggregate queue capacity for autoscale_window consecutive pump
     # ticks (up to max_replicas), and drains-then-retires one replica
     # after scale_down_idle_ticks consecutive fully-idle ticks (down to
-    # min_replicas). Spin-up goes through the persistent compile cache:
-    # a warm replica joins with zero fresh XLA compiles.
+    # min_replicas). A spin-up's XLA compiles are reads from jax's
+    # persistent cache where one is placed (jax_cache.py).
     "serve_fleet_autoscale": (bool, False,
                               "ServingFleet queue-pressure autoscaling"),
     "serve_fleet_min_replicas": (int, 1,

@@ -33,8 +33,8 @@ invisible to every in-flight request.
   (the ServeRequest handle, and with it the pinned track, survives).
 - **Autoscaling** (``serve_fleet_autoscale``): sustained aggregate
   queue saturation over a window of pump ticks spins up a replica —
-  warm, through the persistent/multi-host compile cache (zero fresh
-  XLA compiles; see tests/fleet_serve_worker.py) — and sustained
+  its XLA compiles read from jax's persistent cache where one is
+  placed (see tests/fleet_serve_worker.py) — and sustained
   idleness drains-then-retires one. A custom ``replica_factory`` is
   the seam for spinning replicas on OTHER hosts via the fleet join
   machinery (fleet_base.join_world); the default factory builds local
@@ -228,8 +228,9 @@ class ServingFleet:
     ``factory(cfg, weights, on_handoff=..., **engine_kwargs) ->
     EngineSupervisor``-shaped object. The default builds a local
     EngineSupervisor; a multi-host deployment plugs the fleet join
-    machinery in here. All replicas should share ``compile_cache_dir``
-    so spin-ups and rollout rejoins are warm (zero fresh compiles)."""
+    machinery in here. A spin-up or rollout rejoin traces and lowers
+    again; its XLA compiles are reads from jax's persistent cache where
+    one is placed (``jax_cache.configure``)."""
 
     def __init__(self, cfg, weights, *, replicas: int = 2,
                  min_replicas: Optional[int] = None,
@@ -616,8 +617,7 @@ class ServingFleet:
                 drain_timeout_s: Optional[float] = None) -> Dict:
         """Zero-downtime rolling weight rollout: bump the fleet
         generation, then rotate replicas one at a time — spawn the
-        replacement FIRST (warm via the compile cache, so capacity
-        never dips below N), then drain the old replica and re-home
+        replacement FIRST (so capacity never dips below N), then drain the old replica and re-home
         whatever it could not finish. No request is rejected for the
         rollout's sake; responses carry the generation that served
         them, so the mixed fleet mid-rollout is observable."""

@@ -640,7 +640,7 @@ class Program:
         identically-built programs in two different processes digest
         identically. Cached per version (any op append/rewrite bumps the
         version and invalidates). The canonical program token of
-        ``compile_cache.program_fingerprint``."""
+        ``core.fingerprint.program_fingerprint``."""
         cache = self._content_digest_cache
         if cache is not None and cache[0] == self._version:
             return cache[1]

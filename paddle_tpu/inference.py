@@ -58,16 +58,6 @@ class Config:
         self._batch_buckets = tuple(sizes)
         return self
 
-    def enable_compile_cache(self, cache_dir: str):
-        """Route this process through the persistent compile cache
-        (sets the global ``compile_cache_dir`` flag): a fresh serving
-        replica loading a known model resolves its executables from
-        disk — zero fresh XLA compiles at spin-up."""
-        from paddle_tpu import flags as _flags
-
-        _flags.set_flags({"compile_cache_dir": cache_dir})
-        return self
-
 
 class Predictor:
     """Compiled-program predictor (reference: AnalysisPredictor::Run)."""
@@ -248,9 +238,8 @@ class Predictor:
         predictor's weights (serving.py; the reference parity point is
         AnalysisPredictor as a LONG-LIVED self-healing server process).
         ``supervised=True`` (default) wraps it in an EngineSupervisor —
-        decode-loop thread, wedge watchdog, warm restart through the
-        persistent compile cache; pass False for a caller-driven
-        ServingEngine. ``cfg`` is the transformer config; ``kwargs``
+        decode-loop thread, wedge watchdog, restart with replay; pass
+        False for a caller-driven ServingEngine. ``cfg`` is the transformer config; ``kwargs``
         are the engine geometry/SLO knobs (slots, src_len, ...)."""
         from paddle_tpu import serving as _serving
 

@@ -1,6 +1,7 @@
 """Where compiled programs are cached between processes.
 
-One rule, used by every entry point (chip_smoke.py, bench*.py,
+One rule, used by every entry point (perf/run.py, chip_smoke.py,
+bench_common.py for the bench*.py family, benchmarks/fluid_benchmark.py,
 tests/conftest.py): where ``JAX_COMPILATION_CACHE_DIR`` is set, jax
 already reads it and nothing is set in code, so whoever runs the program
 places the cache; otherwise jax's persistent compilation cache lives in
@@ -34,8 +35,8 @@ def configure() -> str:
 
 
 def fresh_dir(name: str) -> str:
-    """A fixed, emptied directory under the checkout's cache root, for
-    measurements whose subject is a cold cache (warm-start riders)."""
+    """A fixed, emptied directory under the checkout's cache root
+    (chip_smoke.py's IR dumps)."""
     path = os.path.join(_ROOT, name)
     shutil.rmtree(path, ignore_errors=True)
     os.makedirs(path)

@@ -1147,8 +1147,8 @@ def build_serving(cfg: TransformerConfig, slots: int, src_len: int,
     """Build (or return cached) the serving program pair for this
     (config, geometry). Engines sharing a geometry share program
     OBJECTS — their executors' compile caches then key per scope, and
-    the persistent compile cache sees content-identical programs across
-    replicas (the warm-replica start path)."""
+    every replica lowers the same HLO, which is what jax's persistent
+    cache keys on (the warm-replica start path)."""
     key = (
         cfg.src_vocab_size, cfg.trg_vocab_size, cfg.d_model, cfg.d_inner,
         cfg.n_head, cfg.n_layer, cfg.max_length, cfg.dtype,

@@ -632,12 +632,10 @@ STEP_LOG_FIELDS: Dict[str, tuple] = {
     "wall_ms": ((float, int), True,
                 "host wall time of the run call, perf_counter-based"),
     "compile_ms": ((float, int, type(None)), True,
-                   "XLA lower+jit wrap time (disk-cache deserialize "
-                   "time on a 'disk' outcome); null on an in-memory hit"),
+                   "XLA lower+jit wrap time; null on an in-memory hit"),
     "cache": ((str,), True,
-              "compile-cache outcome: 'hit' (in-memory), 'disk' "
-              "(executable resolved from the persistent level-2 cache) "
-              "or 'miss' (fresh compile)"),
+              "compiled-entry cache outcome: 'hit' (in-memory) or "
+              "'miss' (built by this call)"),
     "evictions": ((int,), True,
                   "cache entries evicted by this step's insert"),
     "feed_bytes": ((int,), True, "total bytes across feed arrays"),

@@ -23,9 +23,10 @@ built on the pieces the training stack already proved:
   materializes under step N+1's dispatch — the serving twin of the
   training pipeline's overlapped fetch.
 - **Warm replica start**: engines sharing a geometry share program
-  objects (transformer.build_serving), so the persistent compile cache
-  (``compile_cache_dir`` flag) resolves a fresh replica's prefill +
-  decode executables from disk — zero fresh XLA compiles at spin-up.
+  objects (transformer.build_serving). A fresh replica traces and
+  lowers its prefill + decode programs again; their XLA compiles are
+  reads from jax's persistent cache where one is placed
+  (``jax_cache.configure`` / ``JAX_COMPILATION_CACHE_DIR``).
 - **SLO plane for free**: ``pt_serve_*`` metrics (queue depth, tokens/s,
   TTFT + per-token latency histograms) ride the monitor registry; the
   live endpoint serves an engine summary at ``/serve``; chaos plans can
@@ -58,8 +59,8 @@ Resilience (the serving analog of the training fault-tolerance plane):
   declaration also emits a ``monitor`` stall record for site
   ``serve.decode``). A crashed (engine-fatal error) or wedged
   (heartbeat older than ``serve_wedge_timeout_ms`` while busy) engine
-  is torn down and rebuilt through the persistent compile cache (zero
-  fresh compiles — the warm-replica path), and every surviving queued +
+  is torn down and rebuilt (its XLA compiles read jax's persistent
+  cache where one is placed), and every surviving queued +
   in-flight request is re-prefilled under a retry.py budget; greedy
   decode is deterministic, so replayed requests produce byte-identical
   tokens. Metered by ``pt_serve_engine_restarts_total`` and
@@ -144,8 +145,8 @@ _M_SLOT_EVICTIONS = _monitor.counter(
     "keeps its partial output and every healthy slot keeps decoding")
 _M_RESTARTS = _monitor.counter(
     "pt_serve_engine_restarts_total",
-    "supervised warm engine restarts (crashed or wedged decode loop "
-    "torn down and rebuilt through the persistent compile cache)")
+    "supervised engine restarts (crashed or wedged decode loop torn "
+    "down and rebuilt)")
 _M_REPLAYED = _monitor.counter(
     "pt_serve_requests_replayed_total",
     "queued + in-flight requests re-prefilled onto the restarted "
@@ -1244,13 +1245,13 @@ class EngineSupervisor:
       detection arms only after the engine's FIRST decode step
       completes: a first-step XLA compile legitimately holds the
       heartbeat for 10-100x a steady-state step and must not read as a
-      wedge (set ``compile_cache_dir`` so rebuilds skip even that).
+      wedge.
 
     Either way the old engine is harvested (every queued + in-flight
     handle taken before close() can finish it), torn down, and a new
-    engine is built — through the persistent compile cache when
-    ``compile_cache_dir`` is set, i.e. zero fresh XLA compiles — under
-    a retry.py policy; the harvested requests are re-prefilled in their
+    engine is built under a retry.py policy — it traces and lowers
+    again, and its XLA compiles are reads from jax's persistent cache
+    where one is placed; the harvested requests are re-prefilled in their
     original order and decode from scratch (greedy is deterministic:
     byte-identical tokens). The restart budget (``serve_max_restarts``)
     bounds a permanently failing engine: past it, pending handles
@@ -1591,9 +1592,8 @@ class EngineSupervisor:
         except Exception:
             pass
         try:
-            # warm rebuild under the retry budget: with
-            # compile_cache_dir set every executable resolves from disk
-            # (zero fresh compiles — the warm-replica path)
+            # rebuild under the retry budget: the new engine re-traces;
+            # its XLA compiles read jax's persistent cache where placed
             new = _retry.call(self._build, site="serve.restart",
                               policy=self._restart_policy,
                               retry_on=(Exception,),

@@ -26,10 +26,9 @@ recovery endpoints for the joiners, and EVERYONE re-execs to
 generation 1. The 8-worker generation restores the newest valid
 4-writer checkpoint — optimizer slot state re-keyed through
 ``checkpoint.reshard_optimizer_state`` — and, with
-PT_FLAGS_compile_cache_dir set, warm-starts every executable from the
-persistent compile cache (zero fresh compiles on rejoin: the
-generation-0 incumbents populated the disk tier, and the owning-shard
-topology key is world-size independent for local executables).
+``JAX_COMPILATION_CACHE_DIR`` placed by the harness, reads its XLA
+compiles from jax's persistent cache (the generation-0 incumbents wrote
+them; every result line carries jax's own cache events).
 
 Generation 1 (both drills): workers rendezvous fresh, restore the
 newest VALID checkpoint via ``checkpoint.load_latest`` and finish the
@@ -68,10 +67,12 @@ if __name__ == "__main__":
 import numpy as np  # noqa: E402
 
 import paddle_tpu as fluid  # noqa: E402
-from paddle_tpu import compile_cache, faults, layers  # noqa: E402
+from paddle_tpu import faults, layers  # noqa: E402
 from paddle_tpu.executor import global_scope  # noqa: E402
 from paddle_tpu.incubate.fleet import fleet  # noqa: E402
 from paddle_tpu.parallel import checkpoint as ckpt  # noqa: E402
+
+from jax_cache_events import CacheEvents  # noqa: E402
 
 GLOBAL_BATCH = 24
 STEPS = 6
@@ -136,6 +137,7 @@ def build():
 
 
 def main():
+    events = CacheEvents()
     gen = fleet.generation()
     ckpt_dir = os.environ["PT_CKPT_DIR"]
 
@@ -237,14 +239,10 @@ def main():
         "rank": rank, "gen": gen, "world": n, "start_step": start_step,
         "dead_seen": os.environ.get("PT_DEAD_SEEN", "").split(",")
         if os.environ.get("PT_DEAD_SEEN") else [],
-        "losses": losses}
-    if compile_cache.active():
-        # the grow drill's warm-start accounting: generation 1 must
-        # resolve every executable from the disk tier (zero fresh
-        # compiles on rejoin)
-        st = compile_cache.stats()
-        result["ccache"] = {"hits": st["hits"], "misses": st["misses"],
-                            "errors": st["errors"]}
+        "losses": losses,
+        # the grow drill's warm-start accounting: what generation 1
+        # asked of the compiler, and what jax's cache answered
+        "jax_cache": events.snapshot()}
     print("FLEET_RESULT " + json.dumps(result), flush=True)
     fleet.barrier(f"done-g{gen}")
     fleet.stop_worker()

@@ -1,27 +1,26 @@
 """Subprocess worker for the warm fleet spin-up drill
 (tests/test_fleet_serving.py): one fresh "fleet host" process that
 
-1. starts a single-replica ServingFleet with the persistent compile
-   cache armed (``compile_cache_dir`` flag) and serves two requests,
+1. starts a single-replica ServingFleet and serves two requests,
 2. scales OUT by one replica (the autoscaler's spin-up path) and
    serves two more through the router,
 
-and prints ONE JSON line with the compile-cache accounting and the
-token streams. The in-process claim: the scaled-up replica shares the
-fleet's geometry, so its prefill + decode executables resolve from the
-cache the first replica just populated — the spin-up itself adds ZERO
-disk-tier misses even on a cold cache. Run the worker twice against
-the same cache dir and the second (warm) process must resolve EVERY
-executable from disk — misses == 0 — with byte-identical tokens: the
+and prints ONE JSON line with jax's persistent-cache events and the
+token streams. The parent places the cache in this process's
+environment (``JAX_COMPILATION_CACHE_DIR``, write threshold 0 s). The
+in-process claim: the scaled-up replica shares the fleet's geometry, so
+it lowers the same programs the first replica just compiled — the
+spin-up adds ZERO cache misses even on a cold cache. Run the worker
+twice against the same directory and the second (warm) process must
+compile nothing — misses == 0 — with byte-identical tokens: the
 cross-host warm-start contract fleet autoscaling rides.
 
 Determinism contract (same as tests/serving_worker.py): every program
-built here must be content-identical across processes.
+built here must lower to the same HLO in every process.
 """
 
 import json
 import os
-import sys
 
 # A serving fleet host is a single-device process. Scrub the parent
 # test session's virtual-8-device XLA flag (tests/conftest.py) BEFORE
@@ -39,17 +38,15 @@ jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: E402
 
 import paddle_tpu as fluid  # noqa: E402
-from paddle_tpu import (  # noqa: E402
-    compile_cache,
-    fleet_serving,
-    flags,
-)
+from paddle_tpu import fleet_serving, flags  # noqa: E402
 from paddle_tpu.models import transformer as T  # noqa: E402
+
+from jax_cache_events import CacheEvents  # noqa: E402
 
 
 def main():
-    cache_dir = sys.argv[1]
-    flags.set_flags({"telemetry": True, "compile_cache_dir": cache_dir})
+    events = CacheEvents()
+    flags.set_flags({"telemetry": True})
 
     cfg = T.TransformerConfig(
         src_vocab_size=37, trg_vocab_size=41, max_length=64, d_model=16,
@@ -70,21 +67,21 @@ def main():
     r2 = fleet.submit([9, 4])
     cold_tokens = [r1.result(timeout=120), r2.result(timeout=120)]
 
-    # the autoscaler's spin-up path: the new replica must resolve its
-    # prefill + decode executables from the cache the first replica
-    # populated — zero NEW disk-tier misses
-    misses0 = compile_cache.stats()["misses"]
+    # the autoscaler's spin-up path: the new replica must read its
+    # prefill + decode compiles from the cache the first replica
+    # populated — zero NEW cache misses
+    before = events.snapshot()
     fleet._spawn_replica()
     r3 = fleet.submit([5, 6, 7])
     r4 = fleet.submit([9, 4])
     scaled_tokens = [r3.result(timeout=120), r4.result(timeout=120)]
-    spinup_misses = compile_cache.stats()["misses"] - misses0
+    spinup = events.since(before)
     replica_count = fleet.stats()["replica_count"]
     fleet.close()
 
     print(json.dumps({
-        "stats": compile_cache.stats(),
-        "spinup_misses": spinup_misses,
+        "jax_cache": events.snapshot(),
+        "spinup": spinup,
         "replica_count": replica_count,
         "tokens": [[int(t) for t in s] for s in cold_tokens],
         "scaled_tokens": [[int(t) for t in s] for s in scaled_tokens],

@@ -1,19 +1,19 @@
-"""Subprocess worker for the warm-replica serving tests
-(tests/test_serving.py): one fresh "serving replica" process that
+"""Subprocess worker for the fresh-process warm-start test
+(tests/test_warm_start.py): one fresh "serving replica" process that
 
 1. serves a saved inference model through the Predictor surface
-   (``Config.enable_compile_cache`` routes it through the persistent
-   compile cache; ``close()`` releases its compiled entries), then
+   (``close()`` releases its compiled entries), then
 2. spins a tiny-transformer ServingEngine and decodes two requests
    through the prefill + single-token-decode program pair,
 
-and prints ONE JSON line with the compile-cache/executor accounting the
-parent asserts on. Run twice against the same cache dir, the second
-(warm) replica must resolve every executable from disk — zero fresh XLA
-compiles — and emit byte-identical tokens.
+and prints ONE JSON line with jax's persistent-cache events and the
+executor accounting the parent asserts on. The parent places the cache
+in this process's environment. Run twice against the same directory,
+the second (warm) replica must compile nothing — every XLA compile a
+cache hit — and emit byte-identical tokens.
 
-Determinism contract (same as tests/ccache_worker.py): every program
-built here must be content-identical across processes.
+Determinism contract (same as tests/executor_worker.py): every program
+built here must lower to the same HLO in every process.
 """
 
 import json
@@ -36,24 +36,20 @@ jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: E402
 
 import paddle_tpu as fluid  # noqa: E402
-from paddle_tpu import (  # noqa: E402
-    compile_cache,
-    flags,
-    inference,
-    monitor,
-    serving,
-)
+from paddle_tpu import flags, inference, monitor, serving  # noqa: E402
 from paddle_tpu.models import transformer as T  # noqa: E402
+
+from jax_cache_events import CacheEvents  # noqa: E402
 
 
 def main():
-    cache_dir, model_dir = sys.argv[1], sys.argv[2]
+    model_dir = sys.argv[1]
+    events = CacheEvents()
     flags.set_flags({"telemetry": True})
 
     # --- the Predictor surface of the replica ---
     pred = inference.create_predictor(
-        inference.Config(model_dir).disable_tpu()
-        .enable_compile_cache(cache_dir).set_batch_buckets([4]))
+        inference.Config(model_dir).disable_tpu().set_batch_buckets([4]))
     x = np.linspace(-1.0, 1.0, 4 * 16, dtype=np.float32).reshape(4, 16)
     (probs,) = pred.run([x])
     pred_entries = len(pred._exe._cache)
@@ -79,15 +75,17 @@ def main():
     eng.close()
 
     print(json.dumps({
-        "stats": compile_cache.stats(),
+        "jax_cache": events.snapshot(),
         "exec_misses":
             monitor.counter("pt_executor_cache_misses_total").value(),
         "outcomes": [r["cache"] for r in monitor.recent_steps()],
         "pred_entries": pred_entries,
         "closed_entries": closed_entries,
-        "probs_sum": float(np.asarray(probs).sum()),
-        "tokens": [[int(t) for t in r1.tokens],
-                   [int(t) for t in r2.tokens]],
+        "result": {
+            "probs": [float(v).hex() for v in np.ravel(np.asarray(probs))],
+            "tokens": [[int(t) for t in r1.tokens],
+                       [int(t) for t in r2.tokens]],
+        },
     }))
 
 

@@ -205,35 +205,38 @@ def test_backend_peaks_raises_for_an_unknown_device():
         roofline.backend_peaks("tpu")  # a platform is not a device kind
 
 
-def _configured_cache_dir(env_value):
+@pytest.mark.parametrize("case", ["env", "default"])
+def test_jax_cache_placement(case, tmp_path):
+    """One rule (jax_cache.configure): a placed JAX_COMPILATION_CACHE_DIR
+    wins and NOTHING is set in code, the write threshold included; else
+    the one fixed directory in the checkout, with the 1 s threshold."""
+    placed = str(tmp_path / "placed") if case == "env" else None
     env = {k: v for k, v in os.environ.items()
-           if k != "JAX_COMPILATION_CACHE_DIR"}
-    if env_value:
-        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS")}
+    if placed:
+        env.update(JAX_COMPILATION_CACHE_DIR=placed,
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0.25")
     out = subprocess.run(
         [sys.executable, "-c",
          "import jax\n"
          "before = jax.config.jax_compilation_cache_dir\n"
          "from paddle_tpu import jax_cache\n"
          "print(repr((before, jax_cache.configure(), "
-         "jax.config.jax_compilation_cache_dir)))"],
+         "jax.config.jax_compilation_cache_dir, "
+         "jax.config.jax_persistent_cache_min_compile_time_secs)))"],
         capture_output=True, text=True, timeout=300, cwd=REPO,
         env={**env, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-800:]
-    return eval(out.stdout.strip().splitlines()[-1])
-
-
-def test_cache_helper_sets_nothing_when_the_variable_places_the_cache(
-        tmp_path):
-    placed = str(tmp_path / "placed")
-    assert _configured_cache_dir(placed) == (placed, placed, placed)
-
-
-def test_cache_helper_uses_one_fixed_in_checkout_dir_otherwise():
-    before, used, after = _configured_cache_dir(None)
-    assert before is None
-    assert used == after == jax_cache.JAX_CACHE_DIR
-    assert used == os.path.join(REPO, ".cache", "jax")
+    before, used, after, threshold = eval(out.stdout.strip().splitlines()[-1])
+    if placed:
+        assert (before, used, after, threshold) == (
+            placed, placed, placed, 0.25)
+    else:
+        assert before is None
+        assert used == after == jax_cache.JAX_CACHE_DIR
+        assert used == os.path.join(REPO, ".cache", "jax")
+        assert threshold == 1.0
 
 
 def test_bench_parent_is_jax_free_when_it_launches_children():

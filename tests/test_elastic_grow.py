@@ -1,36 +1,23 @@
 """Elastic scale-OUT (ISSUE 14): the grow half of fleet elasticity.
 
-Before this PR the contract was shrink-only, pinned by the first two
-tests below in their original form (run against the pre-change tree):
+Before ISSUE 14 the contract was shrink-only: ``plan_resize`` had no
+``joins`` parameter at all — a grow spec was inexpressible
+(``TypeError: unexpected keyword argument 'joins'``) and a world could
+only ever get smaller. The first tests below pin the after-contract: a
+grow spec admits joining workers with deterministic rank assignment.
 
-- ``plan_resize`` had no ``joins`` parameter at all — a grow spec was
-  inexpressible (``TypeError: unexpected keyword argument 'joins'``)
-  and a world could only ever get smaller;
-- ``compile_cache.executor_spec`` DECLINED every multi-host process
-  (``jax.process_count() > 1 -> None``): a joining host always paid the
-  ~60x cold compile, with no disk entry even attempted.
-
-Both asserts are now FLIPPED to the after-contract (the tentpole): a
-grow spec admits joining workers with deterministic rank assignment,
-and multi-host processes build disk specs keyed by the owning shard's
-process index/count (local executables share entries across worlds —
-what lets a gen-N+1 newcomer warm-start from gen-N's cache).
-
-The full 4->8 grow drill (seeded, multi-process, warm-start + loss
-parity) is the ``chaos``-marked test at the bottom of
-tests/test_elastic_resize.py.
+The full 4->8 grow drill (seeded, multi-process, loss parity, with
+newcomers reading their XLA compiles from jax's persistent cache) is
+the ``chaos``-marked test at the bottom of tests/test_elastic_resize.py.
 """
 
-import glob
 import json
 import threading
 import time
 
-import numpy as np
 import pytest
 
-import paddle_tpu as fluid
-from paddle_tpu import compile_cache, faults, flags, monitor
+from paddle_tpu import faults, flags, monitor
 from paddle_tpu.incubate.fleet.fleet_base import Fleet
 
 
@@ -80,84 +67,6 @@ def test_plan_resize_rejects_joiner_id_not_in_joins():
     f = Fleet()
     with pytest.raises(ValueError, match="join"):
         f.plan_resize((), joins=[0, 1], join_id=5, world=4)
-
-
-def test_multihost_executor_spec_now_builds_with_owning_shard_key(
-        monkeypatch, tmp_path):
-    """BEFORE: ``jax.process_count() > 1`` made executor_spec return
-    None unconditionally (pinned by the old
-    test_multihost_and_local_fingerprints_build_no_spec) — the decline
-    surfaced as a plain fresh compile with no disk entry. AFTER: a
-    multi-host process whose executable only spans LOCAL devices (the
-    replicated-compute fleet shape) builds a spec whose topology token
-    is world-size independent, so entries stored by a 4-process
-    generation warm-start an 8-process one."""
-    import jax as _jax
-
-    flags.set_flags({"compile_cache_dir": str(tmp_path / "cc")})
-    try:
-        main, startup = fluid.Program(), fluid.Program()
-        from paddle_tpu import layers
-
-        with fluid.program_guard(main, startup):
-            x = layers.data("x", shape=[4, 8], append_batch_size=False,
-                            stop_gradient=True)
-            out = layers.reduce_sum(x)
-        scope = fluid.Scope()
-        exe = fluid.Executor(fluid.CPUPlace())
-        feed = {"x": np.ones((4, 8), np.float32)}
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-
-            monkeypatch.setattr(_jax, "process_count", lambda: 4)
-            spec4 = compile_cache.executor_spec(
-                main, feed_vals=feed, fetch_names=(out.name,), scope=scope,
-                base_key=exe._base_key_for(main),
-                fingerprint=compile_cache.program_fingerprint(
-                    main, feed_sig=(("x", (4, 8), "float32"),),
-                    fetch_names=(out.name,)))
-            assert spec4 is not None, \
-                "multi-host executor_spec declined (pre-ISSUE-14 contract)"
-            monkeypatch.setattr(_jax, "process_count", lambda: 8)
-            spec8 = compile_cache.executor_spec(
-                main, feed_vals=feed, fetch_names=(out.name,), scope=scope,
-                base_key=exe._base_key_for(main),
-                fingerprint=compile_cache.program_fingerprint(
-                    main, feed_sig=(("x", (4, 8), "float32"),),
-                    fetch_names=(out.name,)))
-            # local executable: the digest must NOT bake the world size —
-            # this equality is exactly the 4->8 warm-start property
-            assert spec8 is not None and spec8.digest == spec4.digest
-            # and the real run against the spec'd cache dir round-trips
-            monkeypatch.setattr(_jax, "process_count", lambda: 1)
-            exe.run(main, feed=feed, fetch_list=[out])
-        assert glob.glob(str(tmp_path / "cc") + "/pcc-*.bin")
-    finally:
-        flags.set_flags({"compile_cache_dir": ""})
-
-
-def test_spmd_executor_spec_keys_on_process_index_and_count(monkeypatch):
-    """A genuinely multi-host SPMD executable (state spanning
-    non-addressable devices) keys on the owning shard's (process index,
-    process count): rank 3's entry can never resolve as rank 5's."""
-    t_local = compile_cache.topology_token()
-    assert t_local[0] == "local"
-    import jax as _jax
-
-    monkeypatch.setattr(_jax, "process_count", lambda: 8)
-    monkeypatch.setattr(_jax, "process_index", lambda: 3)
-
-    # duck-typed probe: topology_token treats any non-local device in
-    # the referenced set as SPMD ownership
-    class _Dev:
-        pass
-
-    foreign = _Dev()
-    t_spmd = compile_cache.topology_token(extra_devices={foreign})
-    assert t_spmd[:3] == ("spmd", 3, 8)
-    monkeypatch.setattr(_jax, "process_index", lambda: 5)
-    assert compile_cache.topology_token(
-        extra_devices={foreign})[:3] == ("spmd", 5, 8)
 
 
 # --------------------------------------------------------------------------

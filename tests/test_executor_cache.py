@@ -1,12 +1,18 @@
-"""Executor compile-cache accounting: exact hit/miss/eviction counts
-(pt_executor_cache_* counters) and the ``executor_cache_capacity``
-eviction policy — previously untested."""
+"""The executor's compiled-entry cache: exact hit/miss/eviction counts
+(pt_executor_cache_* counters), the ``executor_cache_capacity`` eviction
+policy, the content fingerprint that keys it (core/fingerprint.py,
+shared with the lint-once cache and the compile report), and the one
+executable behind every entry: it donates its state."""
+
+import glob
+import json
 
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import flags, layers, monitor
+from paddle_tpu import analysis, flags, layers, monitor, unique_name
+from paddle_tpu.core import fingerprint
 
 
 @pytest.fixture(autouse=True)
@@ -18,14 +24,18 @@ def _clean():
     flags.set_flags({"telemetry": False, "executor_cache_capacity": 0})
 
 
-def _build():
+def _build(stateless=False):
+    # name counters restart per build (the fresh-process condition):
+    # identical build code -> identical content
     main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
+    with unique_name.guard(), fluid.program_guard(main, startup):
         x = layers.data("x", shape=[4, 8], append_batch_size=False,
                         stop_gradient=True)
-        h = layers.fc(x, 4)
-        loss = layers.mean(h)
-        fluid.optimizer.SGD(0.1).minimize(loss)
+        if stateless:
+            loss = layers.reduce_sum(x)
+        else:
+            loss = layers.mean(layers.fc(x, 4))
+            fluid.optimizer.SGD(0.1).minimize(loss)
     return main, startup, loss
 
 
@@ -215,3 +225,175 @@ def test_lru_refresh_keeps_hot_entry():
             "pt_executor_cache_misses_total").value() == before
         assert monitor.counter(
             "pt_executor_cache_hits_total").value() == 1
+
+
+# --------------------------------------------------------------------------
+# the content fingerprint: ONE helper for the executor key, the lint-once
+# cache and the compile report's cache_key
+# --------------------------------------------------------------------------
+
+def test_program_fingerprint_is_content_keyed_across_builds():
+    """Two identically-built programs (different uids — the
+    cross-process stand-in) fingerprint identically; any content change
+    diverges."""
+    m1, _, _ = _build(stateless=True)
+    m2, _, _ = _build(stateless=True)
+    assert m1._uid != m2._uid
+    assert m1.content_digest() == m2.content_digest()
+    fp = fingerprint.program_fingerprint
+    assert fp(m1, feed_sig=("x",), fetch_names=("o",)) == \
+        fp(m2, feed_sig=("x",), fetch_names=("o",))
+    # feed/fetch signature rides the fingerprint
+    assert fp(m1, feed_sig=("x",), fetch_names=("o",)) != \
+        fp(m1, feed_sig=("x",), fetch_names=("other",))
+    # content mutation diverges (and the per-version digest cache sees it)
+    with fluid.program_guard(m2, fluid.Program()):
+        layers.scale(m2.global_block().var("x"), scale=2.0)
+    assert m1.content_digest() != m2.content_digest()
+
+
+def test_noncanonical_content_degrades_to_local_fingerprint(monkeypatch):
+    """A program whose content cannot be canonicalized still keys
+    in-process caches (local- prefix)."""
+    main, startup, out = _build(stateless=True)
+    monkeypatch.setattr(fluid.framework.Program, "content_digest",
+                        lambda self: (_ for _ in ()).throw(TypeError("x")))
+    fp = fingerprint.program_fingerprint(main)
+    assert fp.startswith("local-")
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=_feed(), fetch_list=[out])
+        hits = _counts()[0]
+        exe.run(main, feed=_feed(), fetch_list=[out])
+    assert _counts()[0] == hits + 1
+
+
+def test_lint_once_cache_is_content_keyed_via_canonical_fingerprint():
+    """The static verifier's lint-once cache keys on the same canonical
+    fingerprint: two identically-built programs share ONE lint run."""
+    m1, _, _ = _build(stateless=True)
+    m2, _, _ = _build(stateless=True)
+
+    def runs():
+        return monitor.counter("pt_lint_runs_total").value()
+
+    r0 = runs()
+    analysis.lint_before_compile(m1, ["x"], ["o"], site="t-ccfp")
+    assert runs() == r0 + 1
+    analysis.lint_before_compile(m2, ["x"], ["o"], site="t-ccfp")
+    assert runs() == r0 + 1  # same content: cached
+    analysis.lint_before_compile(m2, ["x"], [], site="t-ccfp")
+    assert runs() == r0 + 2  # different fetch signature: re-lints
+
+
+def test_compile_report_cache_key_is_canonical(tmp_path):
+    """Identical programs run through different executors produce
+    compile reports with the SAME cache_key digest — the canonical
+    fingerprint, not a process-local identity tuple."""
+    d = tmp_path / "reports"
+    flags.set_flags({"compile_report_dir": str(d)})
+    try:
+        for _ in range(2):
+            main, startup, out = _build(stateless=True)
+            scope = fluid.Scope()
+            exe = fluid.Executor(fluid.CPUPlace())
+            with fluid.scope_guard(scope):
+                exe.run(startup)
+                exe.run(main, feed=_feed(), fetch_list=[out])
+                exe.run_steps(main, feed_list=[_feed()], steps=2,
+                              fetch_list=[out])
+        reports = [json.load(open(f)) for f in glob.glob(str(d) + "/*.json")]
+        # 2 iterations x (startup step + main step + window) = 6 reports;
+        # each pair of identically-built programs must share ONE key, so
+        # the step reports collapse to 2 distinct keys (startup, main)
+        # and the window reports to 1
+        step_keys = [r["cache_key"] for r in reports if r["kind"] == "step"]
+        window_keys = [r["cache_key"] for r in reports
+                       if r["kind"] == "window"]
+        assert len(step_keys) == 4 and len(set(step_keys)) == 2, step_keys
+        assert len(window_keys) == 2 and len(set(window_keys)) == 1
+    finally:
+        flags.set_flags({"compile_report_dir": ""})
+
+
+def test_fingerprint_memo_serves_the_hot_path_and_is_bounded(monkeypatch):
+    """``fingerprint_for`` is what Executor.run calls on every step: a
+    seen identity tuple is one dict read (the digest is not computed
+    again), and the memo holds at most ``_FP_CAP`` signatures."""
+    main, _, _ = _build(stateless=True)
+    calls = []
+    real = fingerprint.program_fingerprint
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(fingerprint, "program_fingerprint", counted)
+    monkeypatch.setattr(fingerprint, "_FP_CAP", 4)
+    ident = ("t-memo", main._uid, main.version)
+    fp = fingerprint.fingerprint_for(ident, main, feed_sig=("x",))
+    assert fingerprint.fingerprint_for(ident, main, feed_sig=("x",)) == fp
+    assert len(calls) == 1
+    assert fp == real(main, feed_sig=("x",))
+    for i in range(8):
+        fingerprint.fingerprint_for(ident + (i,), main, feed_sig=("x", i))
+    assert len(fingerprint._FP_MEMO) == 4
+    assert ident not in fingerprint._FP_MEMO  # oldest went first
+
+
+def test_run_steps_entries_are_steps_keyed():
+    """``steps`` is a static argument of the window's jit: a different
+    count is a different entry, reported as its own miss."""
+    main, startup, loss = _build()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        for steps, outcome in ((3, "miss"), (3, "hit"), (2, "miss")):
+            exe.run_steps(main, feed_list=[_feed()], steps=steps,
+                          fetch_list=[loss])
+            rec = monitor.recent_steps()[-1]
+            assert rec["cache"] == outcome, (steps, rec)
+            assert (rec["compile_ms"] is None) == (outcome == "hit")
+
+
+# --------------------------------------------------------------------------
+# one executable per program, and it donates its state
+# --------------------------------------------------------------------------
+
+def _call(exe, mode, main, loss):
+    if mode == "run_steps":
+        return exe.run_steps(main, feed_list=[_feed()], steps=2,
+                             fetch_list=[loss])
+    if mode == "data_parallel":
+        main = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name)
+    return exe.run(main, feed=_feed(8), fetch_list=[loss])
+
+
+@pytest.mark.parametrize("mode", ["run", "run_steps", "data_parallel"])
+def test_state_is_donated(mode):
+    """A step consumes the state it was given: after the call the
+    previous parameter arrays are deleted and the scope holds the new
+    ones (no second copy of the state in flight)."""
+    main, startup, loss = _build()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        l0 = float(np.asarray(_call(exe, mode, main, loss)[0]))
+        _, lowered = next(reversed(exe._cache.values()))
+        names = list(lowered.state_in_names)
+        assert names
+        before = {n: scope.find_var(n) for n in names}
+        l1 = float(np.asarray(_call(exe, mode, main, loss)[0]))
+        assert all(v.is_deleted() for v in before.values()), [
+            n for n, v in before.items() if not v.is_deleted()]
+        assert not any(scope.find_var(n).is_deleted() for n in names)
+        # a fresh executor (its own entry, its own executable) carries
+        # the trained state on from the scope
+        l2 = float(np.asarray(
+            _call(fluid.Executor(fluid.CPUPlace()), mode, main, loss)[0]))
+    assert l2 < l1 < l0

@@ -21,15 +21,11 @@ The load-bearing drills:
   new generation with zero rejected-for-rollout requests; responses
   carry the generation that served them.
 - **autoscale**: sustained queue saturation spins a replica up,
-  sustained idleness drains-then-retires one; the warm spin-up adds
-  zero compile-cache misses (subprocess drill via
-  tests/fleet_serve_worker.py).
+  sustained idleness drains-then-retires one; a spin-up reads its XLA
+  compiles from jax's persistent cache (in-process under the suite's
+  mesh, and across processes via tests/fleet_serve_worker.py).
 """
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -39,8 +35,9 @@ import paddle_tpu as fluid
 from paddle_tpu import faults, fleet_serving, flags, monitor, serving
 from paddle_tpu.models import transformer as T
 
+from jax_cache_events import placed_in_process, run_worker
+
 BOS, EOS = 0, 1
-HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def tiny_cfg():
@@ -105,21 +102,6 @@ def _wait_tokens(frs, n=1, timeout=60.0):
             return
         time.sleep(0.002)
     raise TimeoutError("requests never reached mid-decode")
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _shared_compile_cache(tmp_path_factory):
-    """Every fleet in this module shares one persistent compile-cache
-    dir: replica spin-ups after the first resolve their executables
-    from disk (the warm-start path the autoscaler rides) instead of
-    re-compiling per test."""
-    d = tmp_path_factory.mktemp("fleet_cc")
-    old = flags.get_flag("compile_cache_dir")
-    flags.set_flags({"compile_cache_dir": str(d)})
-    try:
-        yield
-    finally:
-        flags.set_flags({"compile_cache_dir": old})
 
 
 @pytest.fixture()
@@ -189,13 +171,28 @@ def test_fleet_sheds_only_when_every_replica_refuses(weights,
         labels={"kind": "queue_full"})
     fleet = _fleet(cfg, scope, replicas=2, slots=1, queue_depth=1)
     try:
+        # one request through EACH replica to completion first: while a
+        # replica's first request is still compiling it sits in the
+        # queue, not in the slot, and the burst would be refused early
+        warmup = [fleet.submit(s, max_new_tokens=2)
+                  for s in _srcs(2, seed=20)]
+        assert len({fr.replica_id for fr in warmup}) == 2
+        for fr in warmup:
+            fr.result(timeout=120)
         faults.arm("serve.decode:delay(0.1)@p1.0", seed=5)
         try:
             # capacity: 2 replicas x (1 slot + 1 queue entry) = 4
-            admitted = []
             srcs = _srcs(8, seed=21)
+            # fill both slots, and let the loop threads take them out
+            # of the queues, before the burst fills the queues
+            admitted = [fleet.submit(s, max_new_tokens=4)
+                        for s in srcs[:2]]
+            t0 = time.time()
+            while (fleet.stats()["queue_depth"] > 0
+                   and time.time() - t0 < 30):
+                time.sleep(0.002)
             with pytest.raises(serving.QueueFull):
-                for s in srcs:
+                for s in srcs[2:]:
                     admitted.append(
                         fleet.submit(s, max_new_tokens=4))
         finally:
@@ -254,10 +251,17 @@ def test_kill_one_replica_mid_decode_chaos_drill(weights, telemetry,
         frs = [fleet.submit(s, max_new_tokens=8) for s in srcs]
         _wait_tokens(frs, n=1)
         snapshots = {id(fr): list(fr.tokens) for fr in frs}
-        # re-arm with the kill riding along (hit 1 = next pump tick);
-        # replica=0 is the lowest-id live replica
+        # re-arm with the kill riding along (hit 1 = next pump tick).
+        # The victim is the replica of the request that has streamed
+        # least: each replica compiles its own programs, so the one
+        # that compiled first may be done by the time the last one
+        # emits a token, and a kill there would find no work
+        least = min(frs, key=lambda fr: len(fr.tokens))
+        victim = sorted(r["replica"] for r in fleet.stats()["replicas"]
+                        ).index(least.replica_id)  # the hint is an index
         faults.arm("serve.decode:delay(0.03)@p1.0;"
-                   "router.replica_crash:raise(replica=0)@1", seed=11)
+                   f"router.replica_crash:raise(replica={victim})@1",
+                   seed=11)
         try:
             streams = []
             for fr in frs:
@@ -464,32 +468,54 @@ def test_autoscale_up_under_saturation_and_down_when_idle(weights):
                          "serve_fleet_scale_up_queue_factor")})
 
 
+def test_fleet_replica_spinup_reads_jax_cache(weights, tmp_path):
+    """Under the suite's 8-device mesh, in one process: the second
+    replica's build traces again but compiles nothing — every XLA
+    compile it asks for is a hit in jax's persistent cache — and it
+    streams what the first replica streamed."""
+    cfg, scope = weights
+    srcs = _srcs(2, seed=61)
+    with placed_in_process(tmp_path / "jax_cache") as events:
+        fleet = _fleet(cfg, scope, replicas=1)
+        try:
+            first = [fleet.submit(s).result(timeout=120) for s in srcs]
+            before = events.snapshot()
+            assert before["misses"] >= 2  # prefill + decode written
+            new = fleet._spawn_replica()
+            frs = [fleet.submit(s) for s in srcs]
+            second = [fr.result(timeout=120) for fr in frs]
+            # the router prefers the newcomer (no backlog estimate yet),
+            # so its prefill and decode programs were built and run
+            assert new.id in {fr.replica_id for fr in frs}
+            got = events.since(before)
+        finally:
+            fleet.close()
+    assert second == first
+    assert got["misses"] == 0, got
+    assert got["hits"] == got["requests"] >= 2, got
+
+
 def test_warm_spinup_zero_fresh_compiles(tmp_path):
     """Two fresh 'fleet host' processes (tests/fleet_serve_worker.py)
-    against one compile-cache dir: scaling out a replica in-process
-    adds zero disk-tier misses (the spin-up resolves from the cache
-    the first replica populated), and the warm process resolves EVERY
-    executable from disk — misses == 0 — with byte-identical tokens."""
-    cache_d = str(tmp_path / "cc")
-    env = {**os.environ, "PYTHONPATH": os.path.dirname(HERE)}
-
+    against one jax cache directory: scaling out a replica in-process
+    adds no cache miss (the spin-up reads what the first replica
+    wrote), and the second process compiles nothing at all — every
+    request a hit — with byte-identical tokens."""
     def launch():
-        out = subprocess.run(
-            [sys.executable, os.path.join(HERE, "fleet_serve_worker.py"),
-             cache_d],
-            capture_output=True, text=True, timeout=600, env=env)
-        assert out.returncode == 0, out.stderr[-2000:]
-        return json.loads(out.stdout.strip().splitlines()[-1])
+        return run_worker("fleet_serve_worker.py",
+                          cache_dir=tmp_path / "jax_cache")
 
     cold = launch()
-    assert cold["stats"]["misses"] > 0
-    assert cold["spinup_misses"] == 0, cold
+    assert cold["jax_cache"]["misses"] > 0
+    assert cold["spinup"]["misses"] == 0, cold
+    assert cold["spinup"]["hits"] >= 2, cold
     assert cold["replica_count"] == 2
     assert cold["scaled_tokens"] == cold["tokens"]
 
     warm = launch()
-    assert warm["stats"]["misses"] == 0, warm
-    assert warm["spinup_misses"] == 0
+    assert warm["jax_cache"]["misses"] == 0, warm
+    assert warm["jax_cache"]["hits"] == warm["jax_cache"]["requests"]
+    assert warm["spinup"]["misses"] == 0
     assert warm["tokens"] == cold["tokens"]
     assert warm["scaled_tokens"] == cold["tokens"]
 

@@ -11,9 +11,6 @@ PTQ artifact as a deployable weight source.
 """
 
 import json
-import os
-import subprocess
-import sys
 import time
 import urllib.request
 
@@ -498,60 +495,6 @@ def test_int8_artifact_deploys_into_engine(weights, tmp_path):
                                               for q in reqs]
     eng.close()
     eng2.close()
-
-
-# --------------------------------------------------------------------------
-# warm replica start through the persistent compile cache
-# --------------------------------------------------------------------------
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def test_warm_replica_zero_fresh_compiles(tmp_path):
-    """Two fresh 'serving replica' processes (tests/serving_worker.py:
-    Predictor with enable_compile_cache + a tiny ServingEngine decode)
-    against one cache dir: the warm replica resolves EVERY executable —
-    predictor run, serving prefill, decode step — from disk, with
-    byte-identical predictor output and decode tokens."""
-    # the saved model the replica's Predictor serves
-    from paddle_tpu import io, layers
-
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = layers.data("x", shape=[16], dtype="float32")
-        probs = layers.softmax(layers.fc(x, 4))
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.Scope()
-    model_d = str(tmp_path / "model")
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        io.save_inference_model(model_d, ["x"], [probs], exe, main)
-
-    cache_d = str(tmp_path / "cc")
-    env = {**os.environ, "PYTHONPATH": os.path.dirname(HERE)}
-
-    def launch():
-        out = subprocess.run(
-            [sys.executable, os.path.join(HERE, "serving_worker.py"),
-             cache_d, model_d],
-            capture_output=True, text=True, timeout=600, env=env)
-        assert out.returncode == 0, out.stderr[-2000:]
-        return json.loads(out.stdout.strip().splitlines()[-1])
-
-    cold = launch()
-    assert cold["stats"]["misses"] >= 4  # pred + startup + prefill + decode
-    assert cold["stats"]["errors"] == {"spec": 0, "load": 0, "store": 0}
-    assert cold["pred_entries"] == 1 and cold["closed_entries"] == 0
-
-    warm = launch()
-    assert warm["stats"]["misses"] == 0, warm
-    assert warm["stats"]["hits"] == cold["stats"]["misses"]
-    assert "miss" not in warm["outcomes"]
-    assert set(warm["outcomes"]) <= {"disk", "hit"}, warm["outcomes"]
-    # the disk-resolved executables compute the same functions
-    assert warm["tokens"] == cold["tokens"]
-    np.testing.assert_allclose(warm["probs_sum"], cold["probs_sum"],
-                               rtol=1e-6)
 
 
 # --------------------------------------------------------------------------
