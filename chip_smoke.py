@@ -7,7 +7,10 @@ points a user calls:
 
 1. kernels — each Pallas attention family compiles with the local
    libtpu and agrees, forward and backward, with the dense reference at
-   a shape a ROADMAP cell sits on;
+   a shape a ROADMAP cell sits on; and the experts' grouped matmuls
+   (``layers.topk_moe`` at OLMoE's widths, 65,536 routed rows) through
+   the ``moe.*`` kernels agree, forward and every gradient, with the
+   same program through ``jax.lax.ragged_dot``;
 2. train   — ``T.build`` + ``Adam.minimize`` under bf16 AMP, a few
    ``Executor.run`` steps and one ``Executor.run_steps`` window at
    b=64 s=256 with dropout 0.1 (no OOM back-off: full batch or fail);
@@ -104,8 +107,8 @@ def attention_dispatch():
     return attention_ops.dispatch_counts(tiles=True)
 
 
-def _dispatch_since(before):
-    now = attention_dispatch()
+def _dispatch_since(before, read=attention_dispatch):
+    now = read()
     return {k: v - before.get(k, 0) for k, v in sorted(now.items())
             if v > before.get(k, 0)}
 
@@ -211,6 +214,100 @@ def kernel_phase(cases=KERNEL_CASES, h=8, dh=64):
         say(f"  kernel {row}")
         out.append(row)
     return out
+
+
+def gmm_dispatch():
+    """{"pass shape [tile]": calls} — the grouped matmuls lowered so far
+    and the tile of each (pt_moe_gmm_dispatch_total)."""
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    return gm.gmm_dispatch_counts()
+
+
+def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
+    """``layers.topk_moe`` under bf16 AMP with its backward pass, once
+    through the program's grouped-matmul kernels and once, the same
+    weights and tokens, through ``jax.lax.ragged_dot``: the output (the
+    forward products), the tokens' gradient (the rows' gradients) and
+    the three weights' gradients (the matrices' gradients) must agree.
+    The default is olmoe-train-s4096's layer: 65,536 rows over 64
+    experts of 2048 x 1024. Returns the errors and the counter's rows,
+    which name the tile of each of the nine calls."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.backward import append_backward
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    def build():
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 7
+        with fluid.program_guard(main, startup):
+            x = layers.data("x", shape=[tokens, d], dtype="float32",
+                            append_batch_size=False)
+            x.stop_gradient = False
+            probe = layers.data("p", shape=[tokens, d], dtype="float32",
+                                append_batch_size=False)
+            out, _, _, rows, _ = layers.topk_moe(x, experts, top_k, d_ff,
+                                                 name="smoke_moe")
+            loss = layers.reduce_sum(layers.elementwise_mul(out, probe))
+            grads = append_backward(loss)
+        main._amp = True
+        names = ["out", "rows", "dx"] + ["d" + p.name for p, _ in grads]
+        fetch = [out, rows, "x@GRAD", *(g for _, g in grads)]
+        return main, startup, fetch, names
+
+    r = np.random.RandomState(5)
+    feed = {"x": r.randn(tokens, d).astype(np.float32),
+            "p": r.randn(tokens, d).astype(np.float32)}
+    scope, exe = fluid.Scope(), fluid.Executor()
+    before = gmm_dispatch()
+    results = {}
+    enabled = gm.kernels_enabled
+    for path in ("kernels", "ragged_dot"):
+        main, startup, fetch, names = build()
+        if path == "kernels":
+            exe.run(startup, scope=scope)     # both read these weights
+        else:
+            gm.kernels_enabled = lambda: False
+        try:
+            got = exe.run(main, feed=feed, fetch_list=fetch, scope=scope,
+                          return_numpy=False)
+        finally:
+            gm.kernels_enabled = enabled
+        results[path] = dict(zip(names, jax.block_until_ready(got)))
+    dispatch = _dispatch_since(before, gmm_dispatch)
+    exe.close()
+
+    m = tokens * top_k
+    tiled = {k: v for k, v in dispatch.items() if k.endswith("]")}
+    check(sum(tiled.values()) == 9 and sum(dispatch.values()) == 18,
+          f"the layer's nine grouped matmuls did not all take a tile on "
+          f"the kernel path, or the ragged_dot path took one: {dispatch}")
+    rows = np.asarray(results["kernels"]["rows"])
+    check(int(rows.sum()) == m and (
+        rows == np.asarray(results["ragged_dot"]["rows"])).all(),
+        f"the two paths routed differently: {rows.tolist()}")
+    errs = {}
+    for name in names:
+        if name == "rows":
+            continue
+        a = jnp.asarray(results["kernels"][name], jnp.float32)
+        b = jnp.asarray(results["ragged_dot"][name], jnp.float32)
+        check(bool(jnp.isfinite(a).all()), f"moe {name} not finite")
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= KERNEL_REL_TOL,
+              f"moe {name}: the kernels are off ragged_dot by "
+              f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
+    row = {"rows": m, "experts": experts, "k": d, "n": d_ff,
+           "fullest_over_mean": round(float(rows.max()) * experts / m, 3),
+           "dispatch": dispatch,
+           "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+    say(f"  moe {row}")
+    return row
 
 
 # ---------------------------------------------------------------------------
@@ -620,6 +717,13 @@ def main() -> int:
         check(row["pallas_calls"] >= 1,
               f"kernel case {row['family']} t{row['t']} lowered without a "
               f"Pallas custom call")
+    report["moe_kernels"], mods = phase("moe_kernels", moe_phase)
+    n_moe, first_call = _pallas_calls(mods, "step_fn")
+    report["moe_kernels"]["pallas_calls"] = n_moe
+    say(f"  moe layer module: {n_moe} Pallas custom calls, the first "
+        f"{first_call}")
+    check(n_moe == 9, f"the layer's module holds {n_moe} Pallas custom "
+          f"calls, expected its nine grouped matmuls")
 
     # 2. train: the step and the window contain the kernels, and no
     # attention call fell to the dense composition

@@ -1343,13 +1343,15 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
             attrs={"num_experts": int(num_experts)})
     with name_scope("experts"):
         ys = var(input.dtype)
+        # the two projections, kept for the op's backward pass
+        gate, up = var(input.dtype, True), var(input.dtype, True)
         helper.append_op(
             "moe_experts",
             inputs={"Xs": xs, "Rows": rows,
                     "WGate": param("_gate.w", [num_experts, d, d_ff]),
                     "WUp": param("_up.w", [num_experts, d, d_ff]),
                     "WDown": param("_down.w", [num_experts, d_ff, d])},
-            outputs={"Ys": ys})
+            outputs={"Ys": ys, "Gate": gate, "Up": up})
     with name_scope("combine"):
         out = var(input.dtype)
         helper.append_op(

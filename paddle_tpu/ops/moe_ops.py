@@ -126,8 +126,9 @@ def _switch_moe(ins, attrs):
 # program's name scopes (router / dispatch / experts / combine) reach the
 # device trace in both directions. Every chosen (token, expert) pair is
 # computed: the pairs are sorted by expert and the experts' matmuls run
-# over the ragged groups (jax.lax.ragged_dot), no capacity, no padding
-# to a worst case, nothing dropped. ``layers.topk_moe`` wires them up.
+# over the ragged groups (parallel/grouped_matmul.py: the program's own
+# kernels, or jax.lax.ragged_dot), no capacity, no padding to a worst
+# case, nothing dropped. ``layers.topk_moe`` wires them up.
 # What it shares with switch_moe above: the float32 router and the
 # stacked [E, ...] expert weights; switch_moe is argmax top-1 with a
 # fixed capacity per expert, biases and ReLU/GELU experts.
@@ -220,20 +221,55 @@ def _moe_dispatch(ins, attrs):
             "Order": [order], "Slot": [slot]}
 
 
+def _swiglu(gate, up):
+    return (jax.nn.silu(gate) * up).astype(gate.dtype)
+
+
 @register_op("moe_experts", diff_inputs=("Xs", "WGate", "WUp", "WDown"))
 def _moe_experts(ins, attrs):
     """SwiGLU experts over ragged groups: Xs [m, d] sorted by expert,
     Rows [E] its group sizes, WGate / WUp [E, d, f], WDown [E, f, d] ->
     Ys [m, d] = (silu(Xs WGate[e]) * (Xs WUp[e])) WDown[e], e the
     row's expert. Under AMP the lowering casts rows and weights to bf16
-    (core/interp.AMP_OP_TYPES); on the v5e each ragged_dot is libtpu's
-    ``ragged-dot-none`` Mosaic call."""
+    (core/interp.AMP_OP_TYPES). Each grouped matmul is
+    ``parallel/grouped_matmul.grouped_matmul``: the program's ``moe.*``
+    Pallas kernels where ``gmm_tile`` gives the call a tile, else
+    ``jax.lax.ragged_dot`` (on the v5e libtpu's ``ragged-dot-none``
+    Mosaic call). Also emits the two projections (Gate, Up [m, f]) so
+    that the paired grad op below does not run them again: XLA cannot
+    CSE custom calls (dead when nothing reads them)."""
+    from paddle_tpu.parallel.grouped_matmul import grouped_matmul
+
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
     wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
-    gate = jax.lax.ragged_dot(xs, wg.astype(xs.dtype), rows)
-    up = jax.lax.ragged_dot(xs, wu.astype(xs.dtype), rows)
-    h = (jax.nn.silu(gate) * up).astype(xs.dtype)
-    return {"Ys": [jax.lax.ragged_dot(h, wd.astype(xs.dtype), rows)]}
+    gate = grouped_matmul(xs, wg.astype(xs.dtype), rows)
+    up = grouped_matmul(xs, wu.astype(xs.dtype), rows)
+    ys = grouped_matmul(_swiglu(gate, up), wd.astype(xs.dtype), rows)
+    return {"Ys": [ys], "Gate": [gate], "Up": [up]}
+
+
+@register_op("moe_experts_grad", no_grad=True)
+def _moe_experts_grad(ins, attrs):
+    """The six grouped matmuls of the backward pass from the forward's
+    saved Gate and Up: no projection runs twice (the generic vjp-style
+    grad op would trace the forward again, and a custom call that is
+    traced twice executes twice)."""
+    from paddle_tpu.parallel.grouped_matmul import grouped_matmul_grads
+
+    xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
+    wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
+    gate = _x(ins, "Gate").astype(xs.dtype)
+    up = _x(ins, "Up").astype(xs.dtype)
+    g = _x(ins, "GRAD::Ys").astype(xs.dtype)
+    h, swiglu_vjp = jax.vjp(_swiglu, gate, up)
+    dh, dwd = grouped_matmul_grads(h, wd.astype(xs.dtype), rows, g)
+    dgate, dup = swiglu_vjp(dh)
+    dx_gate, dwg = grouped_matmul_grads(xs, wg.astype(xs.dtype), rows, dgate)
+    dx_up, dwu = grouped_matmul_grads(xs, wu.astype(xs.dtype), rows, dup)
+    return {"GRAD::Xs": [dx_gate + dx_up],
+            "GRAD::WGate": [dwg.astype(wg.dtype)],
+            "GRAD::WUp": [dwu.astype(wu.dtype)],
+            "GRAD::WDown": [dwd.astype(wd.dtype)]}
 
 
 @register_op("moe_combine", diff_inputs=("Ys", "TopW"))

@@ -1,0 +1,465 @@
+"""Pallas TPU grouped matmuls: the experts of a dropless top-k MoE layer.
+
+``grouped_matmul(lhs [m, k], rhs [E, k, n], group_sizes [E]) -> [m, n]``
+is ``jax.lax.ragged_dot``: the rows of ``lhs`` are sorted by expert,
+``group_sizes`` says how many each expert got (they sum to m: dropless,
+so there is no row past the last group), and row i is multiplied by the
+matrix of its expert. Every row is computed; nothing is padded, capped
+or dropped. Operands keep their dtype (bf16 under AMP), a product is
+accumulated in float32 inside the kernel and returned in the operands'
+dtype, as ``ragged_dot`` returns it.
+
+Three kernels, the program's own in place of libtpu's expansion of
+``ragged_dot`` (a ``ragged-dot-none`` Mosaic call whose tile no caller
+sees or sets, at 45% of the v5e's FLOP roofline at OLMoE's widths):
+
+- ``moe.gmm.fwd``: ``gmm``, the product above.
+- ``moe.gmm.bwd_dx``: the same kernel for the rows' gradient, g [m, n]
+  against the SAME [E, k, n] weights read transposed by the index map
+  and the matmul's dimension numbers, not by a copy.
+- ``moe.tgmm.bwd_dw``: ``tgmm(lhs [m, k], g [m, n]) -> [E, k, n]``, the
+  matrix's gradient lhs_e^T g_e over each expert's rows, zeros for an
+  expert that got none.
+
+A grid step works on one VISIT: one tile of ``tm`` rows and one expert
+with rows in it (``_visits``; the bookkeeping of
+``jax.experimental.pallas.ops.tpu.megablox``, from scalar prefetch). A
+tile that straddles a group boundary is visited once for each group in
+it, and the rows of the other groups are masked: in ``gmm`` on the
+write (the block stays in VMEM across consecutive visits of one tile),
+in ``tgmm`` on the read. Consecutive visits of one expert repeat the
+weight block's index, and Pallas copies only a block whose index
+changed: with the whole contraction resident (``tk`` = k) a weight
+block is fetched once a group and n tile, not once a row tile.
+
+``gmm_tile`` is the one function that says tile or ``ragged_dot``, from
+the shapes, the dtype and the backend; ``pt_moe_gmm_dispatch_total``
+records its answer per lowered call, so a fallback is never silent.
+XLA cannot CSE custom calls: a caller that differentiates by re-running
+its forward (the generic ``*_grad``) would execute these kernels twice,
+so ``ops/moe_ops.moe_experts`` saves what its backward needs.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu import monitor as _monitor
+
+# Test hook, as flash_attention._INTERPRET: run the kernels in
+# interpreter mode on the CPU so the suite reaches them.
+_INTERPRET = False
+
+# What a call's blocks may take of the v5e's 128 MiB of VMEM (Mosaic's
+# default scoped limit is 16 MiB: the calls below raise it to what their
+# blocks need, _vmem_bytes, and a tile over this cap is not chosen).
+_VMEM_CAP_BYTES = 48 * 2**20
+# Row tiles, and the rate of a visit at each, per row, against one of 512
+# rows: on a v5e at OLMoE's widths with an expert's whole matrix
+# resident a visit cost 22.9 / 24.1 / 27.7 ns a row at 512 / 256 / 128
+# rows over the groups a router really gives (my chip run, PR 31: a
+# weight tile latched in the MXU serves fewer rows at the smaller ones).
+_ROW_TILE_RATE = {512: 1.0, 256: 0.95, 128: 0.83}
+_WIDTH_TILES = (2048, 1024, 512, 256, 128)
+
+_M_DISPATCH = _monitor.counter(
+    "pt_moe_gmm_dispatch_total",
+    "grouped matmuls of a top-k MoE layer lowered, by pass (fwd, bwd_dx, "
+    "bwd_dw), shape (m rows, k x n an expert, E experts: the forward "
+    "product's) and tile (rows, contraction and width of one grid step "
+    "of the pass's moe.* Pallas kernel; empty where the call ran as "
+    "jax.lax.ragged_dot)")
+
+
+def kernels_enabled() -> bool:
+    """The Pallas kernels need a TPU backend (tests reach them on CPU
+    through the interpreter)."""
+    return jax.default_backend() == "tpu" or bool(_INTERPRET)
+
+
+def _under_mesh() -> bool:
+    from paddle_tpu.core import interp
+
+    return interp.spmd_ctx() is not None
+
+
+def _vmem_bytes(tm, tk, tn, itemsize):
+    """What one grid step of the largest of the three kernels keeps in
+    VMEM at this tile: the three blocks double-buffered, and a float32
+    accumulator or product of the widest block beside them twice (the
+    value and the store's temporary)."""
+    blocks = tm * tk + tk * tn + tm * tn
+    return 2 * blocks * itemsize + 8 * max(tm * tn, tk * tn)
+
+
+def _vmem_limit(tm, tk, tn, itemsize):
+    """Mosaic's scoped limit for a call at this tile: what the blocks
+    need and half as much again, not under its default of 16 MiB."""
+    return max(16 * 2**20, _vmem_bytes(tm, tk, tn, itemsize) * 3 // 2)
+
+
+def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None):
+    """-> (tm, tk, tn), the rows, contraction and width of one grid step
+    for the product ``[m, k] x [e, k, n]``, or None where the call runs
+    as ``jax.lax.ragged_dot``: no TPU backend (``backend``: None for
+    this process's, with the interpreter counting as one), operands
+    that are not bf16, a program under a mesh (a Mosaic call is not
+    auto-partitioned, and no expert-parallel path calls this yet), a k
+    or n off the 128 lanes, no row tile that divides m, or fewer rows an
+    expert than the smallest tile holds (every tile would be visited by
+    several experts and most of each visit masked: serving a few rows a
+    step is bound by the weights' bytes and wants another kernel; not
+    measured below 1024 rows an expert).
+
+    The tile follows the shape and the VMEM the blocks need, not a flag.
+    Rows: a call makes up to m / tm + e - 1 visits (a tile is visited
+    once for each group in it) and a visit costs tm rows at that tile's
+    rate, so the tm with the least (1 + (e - 1) tm / m) / rate: 256 at
+    1024 rows an expert, 512 from about 4600, 128 under about 750.
+    Widths: the contraction whole where the cap admits, then the widest
+    n (a split contraction costs an accumulator pass a step; with both
+    whole an expert's matrix is fetched once a group). The matrix's
+    gradient runs at the same tile; the rows' gradient asks for its own
+    product, ``gmm_tile(m, n, k, ...)``."""
+    on_tpu = kernels_enabled() if backend is None else backend == "tpu"
+    if on_mesh is None:
+        on_mesh = _under_mesh()
+    rows = [t for t in _ROW_TILE_RATE if m % t == 0]
+    if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
+            or k % 128 or n % 128 or not rows or m // e < min(rows)):
+        return None
+    tm = min(rows, key=lambda t: (1 + (e - 1) * t / m) / _ROW_TILE_RATE[t])
+    for tk in [t for t in (k,) + _WIDTH_TILES if t <= k and k % t == 0]:
+        for tn in [t for t in (n,) + _WIDTH_TILES if t <= n and n % t == 0]:
+            if _vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES:
+                return tm, tk, tn
+    return None
+
+
+def tile_label(tile) -> str:
+    """A tile as the dispatch counter's ``tile`` label has it:
+    "tm512 tk2048 tn1024" ("" for None)."""
+    return "tm%d tk%d tn%d" % tile if tile else ""
+
+
+def _note_dispatch(direction, m, k, n, e, tile):
+    # off with telemetry; build-time shape inference is not a lowering
+    from paddle_tpu.core import interp
+
+    if not _monitor.enabled() or not interp.lowering_active():
+        return
+    _M_DISPATCH.inc(labels={
+        "pass": direction, "shape": f"m{m} k{k} n{n} e{e}",
+        "tile": tile_label(tile)})
+
+
+def gmm_dispatch_counts():
+    """{"pass shape[ [tile]]": calls lowered so far}: the grouped
+    matmuls' dispatch counter as chip_smoke.py prints it, the twin of
+    ``attention_ops.dispatch_counts(tiles=True)``."""
+    out = {}
+    for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
+        lb = row["labels"]
+        name = f"{lb.get('pass', '?')} {lb.get('shape', '?')}"
+        if lb.get("tile"):
+            name += f" [{lb['tile']}]"
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
+
+
+# ---------------------------------------------------------------------------
+# which (row tile, expert) pairs a call visits
+# ---------------------------------------------------------------------------
+
+
+def _visits(group_sizes, m, tm, visit_empty):
+    """-> (offsets [E+1], gids [V], tids [V], nvis [1]), int32, for the
+    scalar prefetch: group g holds rows offsets[g]..offsets[g+1]; visit
+    v < nvis works on row tile tids[v] for expert gids[v], visits in the
+    order of the experts and, inside one, of the tiles, so that the
+    visits of one tile and those of one expert are consecutive. V =
+    m / tm + E - 1 is the most there can be (every boundary inside a
+    tile adds one); the visits past nvis repeat the last one and compute
+    nothing. ``visit_empty``: an expert without rows still gets one
+    visit (``tgmm`` has its zeros to write)."""
+    e = group_sizes.shape[0]
+    tiles_m = m // tm
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes, dtype=jnp.int32)
+    starts = ends - sizes
+    first = starts // tm
+    count = jnp.where(sizes > 0, (ends - 1) // tm - first + 1,
+                      1 if visit_empty else 0).astype(jnp.int32)
+    vis_end = jnp.cumsum(count, dtype=jnp.int32)
+    nvis = vis_end[-1:]
+    v = jnp.minimum(jnp.arange(tiles_m + e - 1, dtype=jnp.int32),
+                    nvis - 1)
+    gids = jnp.minimum(
+        jnp.sum(vis_end[None, :] <= v[:, None], axis=1, dtype=jnp.int32),
+        e - 1)
+    tids = jnp.clip(first[gids] + v - (vis_end - count)[gids],
+                    0, tiles_m - 1)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    return offsets, gids, tids.astype(jnp.int32), nvis
+
+
+def _rows_of(offs_ref, gids_ref, tids_ref, v, tm):
+    """(first row of the visit's tile, the visit's group's start and
+    end)."""
+    g = gids_ref[v]
+    return tids_ref[v] * tm, offs_ref[g], offs_ref[g + 1]
+
+
+def _row_mask(row0, start, end, tm):
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return jnp.logical_and(rows >= start, rows < end)
+
+
+# ---------------------------------------------------------------------------
+# gmm: [m, k] x [E, k, n] (or its transpose [E, n, k]) -> [m, n]
+# ---------------------------------------------------------------------------
+
+
+def _gmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, rhs_ref,
+                out_ref, *scratch, tm, tiles_k, transpose_rhs):
+    v, kk = pl.program_id(1), pl.program_id(2)
+    dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+
+    def store(acc):
+        row0, start, end = _rows_of(offs_ref, gids_ref, tids_ref, v, tm)
+        whole = jnp.logical_and(start <= row0, end >= row0 + tm)
+
+        @pl.when(whole)
+        def _():
+            out_ref[...] = acc.astype(out_ref.dtype)
+
+        # the other groups' rows of a straddling tile keep what the
+        # block holds: what their visit wrote or, before it, anything
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            out_ref[...] = jnp.where(
+                _row_mask(row0, start, end, tm), acc,
+                out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+    @pl.when(v < nvis_ref[0])
+    def _():
+        part = jax.lax.dot_general(lhs_ref[...], rhs_ref[...], dims,
+                                   preferred_element_type=jnp.float32)
+        if tiles_k == 1:
+            store(part)
+            return
+        acc_ref, = scratch
+
+        @pl.when(kk == 0)
+        def _():
+            acc_ref[...] = part
+
+        @pl.when(kk > 0)
+        def _():
+            acc_ref[...] += part
+
+        @pl.when(kk == tiles_k - 1)
+        def _():
+            store(acc_ref[...])
+
+
+def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
+        name="moe.gmm.fwd"):
+    """``lhs [m, k]`` x ``rhs [E, k, n]`` -> [m, n] over the row groups;
+    ``transpose_rhs``: rhs is [E, n, k] and is read transposed. ``tile``
+    (tm, tk, tn) are the rows, the contraction and the result's width of
+    one grid step: tm divides m, tk k and tn n."""
+    m, k = lhs.shape
+    e = rhs.shape[0]
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tm, tk, tn = tile
+    assert m % tm == 0 and k % tk == 0 and n % tn == 0, (lhs.shape,
+                                                         rhs.shape, tile)
+    tiles_k = k // tk
+    meta = _visits(group_sizes, m, tm, visit_empty=False)
+
+    if transpose_rhs:
+        rhs_spec = pl.BlockSpec(
+            (None, tn, tk), lambda j, v, kk, o, g, t, nv: (g[v], j, kk))
+    else:
+        rhs_spec = pl.BlockSpec(
+            (None, tk, tn), lambda j, v, kk, o, g, t, nv: (g[v], kk, j))
+    item = jnp.dtype(lhs.dtype).itemsize
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, tiles_k=tiles_k,
+                          transpose_rhs=transpose_rhs),
+        name=name,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, m // tm + e - 1, tiles_k),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda j, v, kk, o, g, t, nv: (t[v], kk)),
+                rhs_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda j, v, kk, o, g, t, nv: (t[v], j)),
+            scratch_shapes=([pltpu.VMEM((tm, tn), jnp.float32)]
+                            if tiles_k > 1 else []),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(tm, tk, tn, item)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=item * (m * k * (n // tn) + e * k * n + m * n)),
+        interpret=_INTERPRET,
+    )(*meta, lhs, rhs)
+
+
+# ---------------------------------------------------------------------------
+# tgmm: [m, k]^T x [m, n] over each group's rows -> [E, k, n]
+# ---------------------------------------------------------------------------
+
+
+def _tgmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, g_ref,
+                 out_ref, acc_ref, *, tm):
+    v, last_v = pl.program_id(2), pl.num_programs(2) - 1
+    group = gids_ref[v]
+    first = jnp.logical_or(
+        v == 0, gids_ref[jnp.maximum(v - 1, 0)] != group)
+    last = jnp.logical_or(
+        v == last_v, gids_ref[jnp.minimum(v + 1, last_v)] != group)
+    row0, start, end = _rows_of(offs_ref, gids_ref, tids_ref, v, tm)
+    live = jnp.logical_and(v < nvis_ref[0], end > start)
+    whole = jnp.logical_and(start <= row0, end >= row0 + tm)
+    dims = (((0,), (0,)), ((), ()))
+
+    @pl.when(first)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(jnp.logical_and(live, whole))
+    def _():
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], g_ref[...], dims,
+            preferred_element_type=jnp.float32)
+
+    # a straddling tile: the other groups' rows of g count as zeros
+    @pl.when(jnp.logical_and(live, jnp.logical_not(whole)))
+    def _():
+        g = jnp.where(_row_mask(row0, start, end, tm), g_ref[...],
+                      jnp.zeros_like(g_ref))
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], g, dims, preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
+    """``lhs [m, k]``, ``g [m, n]`` -> [E, k, n]: lhs_e^T g_e over the
+    rows of each group e, exact zeros for a group without rows. ``tile``
+    (tm, tk, tn): tm rows are contracted a grid step into a float32
+    accumulator [tk, tn] that is held across a group's row tiles."""
+    m, k = lhs.shape
+    n = g.shape[1]
+    e = group_sizes.shape[0]
+    tm, tk, tn = tile
+    assert m % tm == 0 and k % tk == 0 and n % tn == 0, (lhs.shape,
+                                                         g.shape, tile)
+    meta = _visits(group_sizes, m, tm, visit_empty=True)
+    item = jnp.dtype(lhs.dtype).itemsize
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm),
+        name=name,
+        out_shape=jax.ShapeDtypeStruct((e, k, n), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, k // tk, m // tm + e - 1),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda j, i, v, o, gi, t, nv: (t[v], i)),
+                pl.BlockSpec((tm, tn),
+                             lambda j, i, v, o, gi, t, nv: (t[v], j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, tk, tn), lambda j, i, v, o, gi, t, nv: (gi[v], i, j)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(tm, tk, tn, item)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=item * (m * k * (n // tn) + m * n * (k // tk)
+                                   + e * k * n)),
+        interpret=_INTERPRET,
+    )(*meta, lhs, g)
+
+
+# ---------------------------------------------------------------------------
+# the entry points
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_vjp(lhs, rhs, group_sizes, tile):
+    return gmm(lhs, rhs, group_sizes, tile)
+
+
+def _gmm_vjp_fwd(lhs, rhs, group_sizes, tile):
+    return gmm(lhs, rhs, group_sizes, tile), (lhs, rhs, group_sizes)
+
+
+def _gmm_vjp_bwd(_, res, g):
+    return (*grouped_matmul_grads(*res, g), None)
+
+
+_gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
+def _call_tiles(lhs, rhs):
+    """((m, k, n, e), the call's tile, the tile of its rows' gradient):
+    both tiles, or neither."""
+    (m, k), (e, _, n) = lhs.shape, rhs.shape
+    tile = dx_tile = None
+    if rhs.dtype == lhs.dtype:
+        tile = gmm_tile(m, k, n, e, lhs.dtype)
+        dx_tile = gmm_tile(m, n, k, e, lhs.dtype)
+    if tile is None or dx_tile is None:
+        tile = dx_tile = None
+    return (m, k, n, e), tile, dx_tile
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``jax.lax.ragged_dot(lhs, rhs, group_sizes)``, differentiable in
+    lhs and rhs: through the kernels above at the tile ``gmm_tile``
+    gives the call, else ``ragged_dot`` itself."""
+    dims, tile, _ = _call_tiles(lhs, rhs)
+    _note_dispatch("fwd", *dims, tile)
+    if tile is None:
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return _gmm_vjp(lhs, rhs, group_sizes, tile)
+
+
+def grouped_matmul_grads(lhs, rhs, group_sizes, g):
+    """(d lhs, d rhs) of ``grouped_matmul(lhs, rhs, group_sizes)`` for
+    the cotangent ``g`` [m, n], in the operands' dtypes: for a caller
+    that saved its forward's results and does not run it again
+    (``moe_experts_grad``), and the kernels' own vjp rule."""
+    dims, tile, dx_tile = _call_tiles(lhs, rhs)
+    _note_dispatch("bwd_dx", *dims, dx_tile)
+    _note_dispatch("bwd_dw", *dims, tile)
+    g = g.astype(lhs.dtype)
+    if tile is None:
+        # jax's transposes of ragged_dot; the forward it traces is dead
+        _, vjp = jax.vjp(
+            lambda a, b: jax.lax.ragged_dot(a, b, group_sizes), lhs, rhs)
+        return vjp(g)
+    # the rows' gradient contracts n and is k wide: a product of its own
+    dx = gmm(g, rhs, group_sizes, dx_tile, transpose_rhs=True,
+             name="moe.gmm.bwd_dx")
+    return dx, tgmm(lhs, g, group_sizes, tile)

@@ -1,5 +1,7 @@
-"""The BHTD attention kernels compile for a TPU v5e at the shapes the
-chip runs them at, on this CPU-only machine: the TPU's compiler is
+"""The BHTD attention kernels and the experts' grouped-matmul kernels
+compile for a TPU v5e at the shapes the chip runs them at, on this
+CPU-only machine (one file for both: the worker that is handed it is
+the one that loads libtpu): the TPU's compiler is
 installed and compiles for a chip that is described, not attached
 (.claude/skills/verify/SKILL.md, "Compile for the chip without a chip").
 What Mosaic refuses (a tile over its scoped VMEM, a slice off the tiling)
@@ -14,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.parallel import flash_attention as fa
+from paddle_tpu.parallel import grouped_matmul as gm
 
 
 @pytest.fixture(scope="module")
@@ -107,3 +110,42 @@ def test_bhtd_forward_and_backward_compile(case, one_chip, real_kernels):
     for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
         assert name in text, name
     assert text.count("tpu_custom_call") >= 3
+
+
+# (m, k, n, e) of a forward product [m, k] x [e, k, n] that gmm_tile
+# admits on the chip: olmoe-train-s4096's two, and every way the tile
+# can come out (each row tile; a width the VMEM cap narrows, where the
+# raised vmem_limit_bytes has to hold; a width that is no power of two)
+_GMM_CASES = {
+    "olmoe_gate_up": (65536, 2048, 1024, 64),
+    "olmoe_down": (65536, 1024, 2048, 64),
+    "rows_512": (65536, 2048, 1024, 8),
+    "rows_128": (8192, 2048, 1024, 64),
+    "narrowed_4096": (65536, 4096, 4096, 8),
+    "narrowed_8192": (65536, 8192, 2048, 8),
+    "moonlight_1408": (65536, 2048, 1408, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GMM_CASES))
+def test_grouped_matmul_kernels_compile(case, one_chip, real_kernels):
+    m, k, n, e = _GMM_CASES[case]
+    bf = jnp.bfloat16
+    tile = gm.gmm_tile(m, k, n, e, bf, "tpu", False)
+    dx_tile = gm.gmm_tile(m, n, k, e, bf, "tpu", False)
+    assert tile and dx_tile
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def three(lhs, rhs, g, sizes):
+        return (gm.gmm(lhs, rhs, sizes, tile),
+                gm.gmm(g, rhs, sizes, dx_tile, transpose_rhs=True,
+                       name="moe.gmm.bwd_dx"),
+                gm.tgmm(lhs, g, sizes, tile))
+
+    text = jax.jit(three).lower(
+        arg((m, k), bf), arg((e, k, n), bf), arg((m, n), bf),
+        arg((e,), jnp.int32)).compile().as_text()
+    for name in ("moe.gmm.fwd", "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"):
+        assert name in text, name

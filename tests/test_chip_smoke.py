@@ -63,6 +63,33 @@ def test_kernel_phase_fails_when_the_dispatch_leaves_the_family():
             cases=(("bthd_small", 1, 32, False, 0.0, False),), h=2, dh=16)
 
 
+def test_moe_phase_runs_both_paths_of_the_layer(telemetry, monkeypatch):
+    """The grouped matmuls' smoke at a size ``gmm_tile`` takes, through
+    the interpreter: nine calls with a tile, nine through ragged_dot,
+    and the two paths agree."""
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    row = chip_smoke.moe_phase(tokens=256, d=128, d_ff=128, experts=4,
+                               top_k=2)
+    assert row["dispatch"] == {
+        **{f"{p} m512 k128 n128 e4 [tm128 tk128 tn128]": 3
+           for p in ("fwd", "bwd_dx", "bwd_dw")},
+        **{f"{p} m512 k128 n128 e4": 3
+           for p in ("fwd", "bwd_dx", "bwd_dw")}}
+    assert set(row["rel_err"]) == {
+        "out", "dx", "dsmoke_moe_router.w", "dsmoke_moe_gate.w",
+        "dsmoke_moe_up.w", "dsmoke_moe_down.w"}
+    assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+def test_moe_phase_fails_when_no_call_takes_a_tile(telemetry):
+    # kernels off (CPU, no interpreter): all eighteen are ragged_dot
+    with pytest.raises(chip_smoke.SmokeFailure, match="did not all take"):
+        chip_smoke.moe_phase(tokens=256, d=128, d_ff=128, experts=4,
+                             top_k=2)
+
+
 @pytest.mark.parametrize("dropout,tol", [
     (0.1, chip_smoke.DP_DROPOUT_LOSS_REL_TOL),  # masks drawn per shard
     (0.0, chip_smoke.DP_LOSS_REL_TOL)])         # the same math

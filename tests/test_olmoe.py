@@ -322,3 +322,65 @@ def test_bhtd_tile_counts_the_head_width(h, dh, want):
     from paddle_tpu.parallel import flash_attention as fa
 
     assert fa._pick_tile(h, 4096, 4096, None, None, dh) == want
+
+
+def test_topk_moe_kernel_path_agrees_with_the_ragged_dot_path(monkeypatch):
+    """The layer under bf16 AMP at a size ``gmm_tile`` takes (512 rows
+    over 4 experts of 128 x 128): forward and every gradient through the
+    interpreted ``moe.*`` kernels against the same program through
+    ``jax.lax.ragged_dot``. Both accumulate in float32 and round the
+    result to bf16 once; the order of the sums differs. And the counter
+    names the tile of each of the nine calls, with telemetry on only."""
+    from paddle_tpu import flags, monitor
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    n, d, f, e, k = 256, 128, 128, 4, 2
+    r = np.random.RandomState(11)
+    x = r.randn(n, d).astype(np.float32)
+    probe = r.randn(n, d).astype(np.float32)
+    wr = r.randn(d, e).astype(np.float32) * 0.3
+
+    def run():
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 5
+        with fluid.program_guard(main, startup):
+            xv = data("x", x.shape)
+            out, lb, z, rows, _ = layers.topk_moe(xv, e, k, f, name="m")
+            loss = layers.reduce_sum(
+                layers.elementwise_mul(out, data("p", x.shape)))
+            grads = append_backward(loss)
+        main._amp = True
+        scope, exe = fluid.Scope(), fluid.Executor()
+        exe.run(startup, scope=scope)
+        scope.set("m_router.w", jnp.asarray(wr))
+        for name in ("m_gate.w", "m_up.w", "m_down.w"):
+            scope.set(name, jnp.asarray(r2.randn(
+                *np.shape(scope.find_var(name))).astype(np.float32) * 0.1))
+        got = exe.run(main, feed={"x": x, "p": probe}, scope=scope,
+                      fetch_list=[out, rows, "x@GRAD",
+                                  *(g for _, g in grads)])
+        return got, [p.name for p, _ in grads]
+
+    r2 = np.random.RandomState(12)
+    want, names = run()
+    assert gm.gmm_dispatch_counts() == {}          # telemetry off
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    flags.set_flags({"telemetry": True})
+    try:
+        r2 = np.random.RandomState(12)
+        got, _ = run()
+        counts = gm.gmm_dispatch_counts()
+    finally:
+        flags.set_flags({"telemetry": False})
+        monitor.reset()
+    tiles = {"m512 k128 n128 e4": "[tm128 tk128 tn128]"}
+    assert counts == {f"{p} {s} {t}": 3 for s, t in tiles.items()
+                      for p in ("fwd", "bwd_dx", "bwd_dw")}
+    assert (got[1] == want[1]).all() and got[1].sum() == n * k
+    # outputs of 0.1..1 rounded to bf16 (2**-8); a gradient sums up to
+    # 512 such products
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-2, atol=2e-2)
+    for name, a, b in zip(["x", *names], got[2:], want[2:]):
+        scale = np.abs(b).max()
+        np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2 * scale,
+                                   err_msg=name)
