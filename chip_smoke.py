@@ -58,6 +58,11 @@ KERNEL_CASES = (
 # bf16 probability tile against an f32 "highest"-precision reference —
 # five bf16 epsilons (2^-8)
 KERNEL_REL_TOL = 0.02
+# the chunkwise delta rule with bf16 matmul operands against the float32
+# recurrence over 1024 positions: every product of a chunk is rounded
+# to bf16 (2**-8) once or twice on its way into the state, and g's and
+# beta's gradients sum such products over a head's 128 x 128 state
+GDN_REL_TOL = 0.05
 # serving, an 8-slot against a 1-slot engine at default precision: the
 # greedy head's logit for the same prefix, relative — the same five bf16
 # epsilons (on the v5e 3.6e-3 at most before a parting and 1.2e-3 at
@@ -307,6 +312,162 @@ def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
            "dispatch": dispatch,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  moe {row}")
+    return row
+
+
+def gdn_dispatch():
+    """{"impl pass shape chunk<C>": calls}: the gated delta-rule calls
+    lowered so far (pt_linear_attention_dispatch_total)."""
+    from paddle_tpu.ops import linear_attention_ops as la
+
+    return la.dispatch_counts()
+
+
+def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
+              **overrides):
+    """The hybrid decoder's new mechanisms (models/qwen3_next.py).
+
+    1. The cell ``qwen3next-train-s8192``'s train step (one period of
+       Qwen3-Next-80B-A3B at its published widths, 32 of 512 experts
+       held, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
+       and the dispatch counters are held to what the cell must lower:
+       three delta-rule calls forward and three backward, none
+       ``recurrent``; one attention call each way at 2 key/value heads
+       of 256 with its tile; every grouped matmul of the held experts
+       on a tile chosen for 160 rows an expert (tm128), none through
+       ``ragged_dot``. ``overrides`` cut the config for the CPU tests.
+    2. On the device: the chunkwise delta rule with bf16 operands,
+       forward and its own backward, against the step-by-step
+       recurrence in float32; and grouped-query attention through the
+       BHTD kernels against the dense composition that copies K and V."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core import lowering
+    from paddle_tpu.executor import Executor
+    from paddle_tpu.models import qwen3_next as M
+    from paddle_tpu.ops import linear_attention_ops as la
+    from paddle_tpu.parallel import flash_attention as fa
+
+    cfg = M.Qwen3NextConfig(**{**dict(
+        num_hidden_layers=4, vocab_size=18992, held_experts=(0, 32)),
+        **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    before = (attention_dispatch(), gmm_dispatch(), gdn_dispatch())
+    low = lowering.lower_block(main, 0, ("input_ids", "labels"),
+                               (model["loss"].name,))
+    block = main.global_block()
+
+    def aval(name):
+        var = block._find_var_recursive(name)
+        dtype = jnp.dtype(var.dtype)
+        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(
+            {"int64": "int32", "float64": "float32"}.get(dtype.name,
+                                                         dtype.name)))
+
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    Executor._jit_for(low, None).lower(
+        {n: aval(n) for n in low.state_in_names},
+        {"input_ids": ids, "labels": ids},
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((), jnp.uint32))
+    attn, gmm, gdn = (_dispatch_since(b, read) for b, read in zip(
+        before, (attention_dispatch, gmm_dispatch, gdn_dispatch)))
+    n_gdn = sum(not cfg.is_full_attention(i)
+                for i in range(cfg.num_hidden_layers))
+    say(f"  lowered: gdn {gdn}; attention {attn}; grouped matmuls {gmm}")
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in gdn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_gdn and all(
+            k.split()[0] in ("chunked", "kernel") for k in rows),
+            f"expected {n_gdn} chunked or kernel delta-rule calls {direction}"
+            f", none recurrent: {gdn}")
+    kv = f"kv{cfg.num_key_value_heads} dh{cfg.head_dim}"
+    check(len(attn) == 2 and all(
+        k.startswith("bhtd ") and kv in k and k.endswith("]") for k in attn),
+        f"expected one bhtd attention call each way at {kv} with its tile: "
+        f"{attn}")
+    n_layers = cfg.num_hidden_layers
+    check(sum(gmm.values()) == 9 * n_layers and all(
+        "[tm128 " in k for k in gmm),
+        f"expected {9 * n_layers} grouped matmuls on a tile of 128 rows "
+        f"(160 rows an expert), none through ragged_dot: {gmm}")
+
+    # --- on the device ----------------------------------------------------
+    r = np.random.RandomState(3)
+    hk, hv = heads
+    f32, bf = jnp.float32, jnp.bfloat16
+    q, k = (jnp.asarray(r.randn(1, t_check, hk, width), f32) for _ in "qk")
+    v, do = (jnp.asarray(r.randn(1, t_check, hv, width), f32) for _ in "vd")
+    g = -jnp.asarray(r.rand(1, t_check, hv) * 0.5, f32)
+    beta = jnp.asarray(r.rand(1, t_check, hv), f32)
+
+    @jax.jit
+    def chunked(q, k, v, g, beta, do):
+        ins = {"Q": [q.astype(bf)], "K": [k.astype(bf)],
+               "V": [v.astype(bf)], "G": [g], "Beta": [beta]}
+        out = la._gated_delta_rule(ins, {"chunk": cfg.gdn_chunk})
+        grads = la._gated_delta_rule_grad(
+            {**ins, "States": out["States"], "GRAD::Out": [do.astype(bf)]},
+            {"chunk": cfg.gdn_chunk})
+        return (out["Out"][0], *(grads[f"GRAD::{s}"][0]
+                                 for s in ("Q", "K", "V", "G", "Beta")))
+
+    @jax.jit
+    def recurrent(q, k, v, g, beta, do):
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(la.recurrent_gated_delta_rule, q, k, v, g,
+                               beta)
+            return (out, *vjp(do))
+
+    errs = {}
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+    for name, a, b in zip(names, chunked(q, k, v, g, beta, do),
+                          recurrent(q, k, v, g, beta, do)):
+        a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
+        check(bool(jnp.isfinite(a).all()), f"delta rule {name} not finite")
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= GDN_REL_TOL,
+              f"delta rule {name}: the chunkwise form (bf16 operands) is "
+              f"off the float32 recurrence by {errs[name]:.4f} of its max "
+              f"(tolerance {GDN_REL_TOL})")
+    h, hkv, dh = gqa
+    tile = fa.bhtd_tile(h, t_check, t_check, dh=dh, group=h // hkv)
+    check(tile is not None, f"no bhtd tile for h{h} kv{hkv} dh{dh}")
+    qa = jnp.asarray(r.randn(1, h, t_check, dh) * 0.3, bf)
+    ka = jnp.asarray(r.randn(1, hkv, t_check, dh) * 0.3, bf)
+    va, ga = (jnp.asarray(r.randn(1, n, t_check, dh), bf) for n in (hkv, h))
+
+    @jax.jit
+    def kernels(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=True)
+        return (out, *fa.flash_attention_bwd(q, k, v, None, None, out, lse,
+                                             g, causal=True))
+
+    @jax.jit
+    def dense(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
+            q, k, v, None, dh ** -0.5, causal=True).astype(q.dtype), q, k, v)
+        return (out, *vjp(g))
+
+    for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
+                          kernels(qa, ka, va, ga), dense(qa, ka, va, ga)):
+        a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= KERNEL_REL_TOL,
+              f"grouped-query attention {name} off the dense composition "
+              f"by {errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
+    row = {"gdn": gdn, "attention": attn, "grouped_matmuls": gmm,
+           "gqa_tile": fa.tile_label(tile),
+           "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+    say(f"  gdn {row['rel_err']}")
     return row
 
 
@@ -724,6 +885,8 @@ def main() -> int:
         f"{first_call}")
     check(n_moe == 9, f"the layer's module holds {n_moe} Pallas custom "
           f"calls, expected its nine grouped matmuls")
+
+    report["gdn"], _ = phase("gdn", gdn_phase)
 
     # 2. train: the step and the window contain the kernels, and no
     # attention call fell to the dense composition

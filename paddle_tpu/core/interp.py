@@ -57,6 +57,14 @@ AMP_OP_TYPES = {
     # itself: moe_router its logits, softmax and top-k, moe_combine its
     # weighted sum; rms_norm, like layer_norm, its statistics.
     "moe_experts",
+    # the gated delta rule (ops/linear_attention_ops.py): Q, K, V to
+    # bf16, the operands of its matmuls. What stays float32: its G and
+    # Beta (AMP_KEEP_F32_SLOTS below: exponentiated and summed over a
+    # chunk), and inside the op the normalisation of q and k, the
+    # triangular solve and the state. causal_conv1d, gdn_gates and
+    # gated_rms_norm are not listed: each computes in float32 and
+    # returns its input's dtype (gdn_gates float32) by itself.
+    "gated_delta_rule",
 }
 
 # Precision-following ops: when any input is already bf16, their remaining
@@ -83,7 +91,7 @@ AMP_FLOW_OP_TYPES = {
 # internal math, x-dtype output — so no input casting is wanted.)
 
 # Slots that must stay f32 under AMP (saved numerical stats, not streams).
-AMP_KEEP_F32_SLOTS = frozenset({"Lse", "GRAD::Lse"})
+AMP_KEEP_F32_SLOTS = frozenset({"Lse", "GRAD::Lse", "G", "Beta"})
 
 # Whether AMP casting is active for the block currently being traced;
 # None while no block is (core/lowering.run_block sets it for the length

@@ -50,6 +50,16 @@ class UniformInitializer(Initializer):
         )
 
 
+class LogUniformInitializer(UniformInitializer):
+    """log of uniform(low, high), low > 0: a decay rate's logarithm
+    (Gated DeltaNet's ``A_log``)."""
+
+    def __call__(self, var, block):
+        super().__call__(var, block)
+        block.append_op("log", inputs={"X": var.name},
+                        outputs={"Out": var.name})
+
+
 class NormalInitializer(Initializer):
     def __init__(self, loc=0.0, scale=1.0, seed=0):
         self.loc, self.scale, self.seed = loc, scale, seed
@@ -157,6 +167,7 @@ class NumpyArrayInitializer(Initializer):
 # Aliases matching the reference's public names.
 Constant = ConstantInitializer
 Uniform = UniformInitializer
+LogUniform = LogUniformInitializer
 Normal = NormalInitializer
 TruncatedNormal = TruncatedNormalInitializer
 Xavier = XavierInitializer

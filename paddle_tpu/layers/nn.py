@@ -34,7 +34,8 @@ __all__ = [
     "mean_iou",
     "linear_chain_crf", "crf_decoding", "warpctc", "edit_distance",
     "bilinear_tensor_product", "nce", "switch_moe", "topk_moe",
-    "rms_norm", "rotary_embedding",
+    "rms_norm", "rotary_embedding", "causal_conv1d", "gdn_gates",
+    "gated_delta_rule", "gated_rms_norm", "silu",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
     "yolo_box", "sequence_conv", "add_position_encoding", "conv3d",
     "spectral_norm", "hsigmoid", "sample_logits",
@@ -372,30 +373,123 @@ def layer_norm(
     return helper.append_activation(out)
 
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
+             name=None):
     """Root-mean-square normalisation over the last axis with a learned
     gain and no bias (Zhang & Sennrich 2019): the pre-norm of the
-    decoder-only language models (models/olmoe.py)."""
+    decoder-only language models (models/olmoe.py). ``zero_centered``:
+    the gain is 1 + the parameter, which then starts at 0
+    (models/qwen3_next.py)."""
     helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        ParamAttr._to_attr(param_attr), shape=[input.shape[-1]],
+        dtype=input.dtype, default_initializer=ConstantInitializer(
+            0.0 if zero_centered else 1.0))
+    out = helper.create_variable_for_type_inference(dtype=input.dtype)
+    attrs = {"epsilon": float(epsilon)}
+    if zero_centered:
+        attrs["zero_centered"] = True
+    helper.append_op("rms_norm", inputs={"X": input, "Scale": scale},
+                     outputs={"Y": out}, attrs=attrs)
+    return out
+
+
+def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None):
+    """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
+    may have fewer heads); position p of the sequence is p.
+    ``rotary_dim``: only the first rotary_dim features of a head turn,
+    as a head of that width would, and the others pass. Returns the
+    rotated (q, k)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
+    k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
+    attrs = {"theta": float(theta)}
+    if rotary_dim is not None and rotary_dim != q.shape[-1]:
+        attrs["rotary_dim"] = int(rotary_dim)
+    helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
+                     outputs={"QOut": q_out, "KOut": k_out}, attrs=attrs)
+    return q_out, k_out
+
+
+def causal_conv1d(input, taps=4, act="silu", param_attr=None, name=None):
+    """Depthwise convolution over the sequence of ``input`` [b, t, c]
+    that sees no later position, ``taps`` wide, no bias, then ``act``
+    ("silu" or None): the short convolution in front of a linear
+    attention (ops/linear_attention_ops.py). Parameter [c, taps]."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    w = helper.create_parameter(
+        ParamAttr._to_attr(param_attr), shape=[input.shape[-1], int(taps)],
+        dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(dtype=input.dtype)
+    helper.append_op("causal_conv1d", inputs={"X": input, "W": w},
+                     outputs={"Y": out}, attrs={"act": act or ""})
+    return out
+
+
+def gdn_gates(b, a, a_log_attr=None, dt_bias_attr=None, name=None):
+    """(beta, g) of a gated delta rule from two projections of the
+    token, ``b`` and ``a`` [b, t, h]: beta = sigmoid(b), g = -exp(A_log)
+    * softplus(a + dt_bias), float32 both. Parameters A_log and dt_bias
+    [h] (defaults: log of uniform(0, 16), and 1)."""
+    from paddle_tpu.initializer import LogUniformInitializer
+
+    helper = LayerHelper("gdn_gates", name=name)
+    h = b.shape[-1]
+    a_log = helper.create_parameter(
+        ParamAttr._to_attr(a_log_attr), shape=[h], dtype="float32",
+        default_initializer=LogUniformInitializer(1e-4, 16.0))
+    dt_bias = helper.create_parameter(
+        ParamAttr._to_attr(dt_bias_attr), shape=[h], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    beta = helper.create_variable_for_type_inference(dtype="float32")
+    g = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        "gdn_gates",
+        inputs={"B": b, "A": a, "ALog": a_log, "DtBias": dt_bias},
+        outputs={"Beta": beta, "G": g})
+    return beta, g
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="chunked",
+                     epsilon=1e-6, name=None):
+    """Gated delta-rule linear attention (Gated DeltaNet,
+    arXiv:2412.06464): q, k [b, t, hk, dk] (normalised to unit length
+    inside, q also divided by sqrt(dk)), v [b, t, hv, dv] with hv a
+    multiple of hk (key head i serves value heads i * hv / hk ...), g
+    [b, t, hv] the log of the state's decay and beta [b, t, hv] the
+    write strength -> o [b, t, hv, dv]. Per value head a state S
+    [dk, dv] from zero: S = exp(g_t) S; S += k_t (beta_t (v_t - S^T
+    k_t))^T; o_t = S^T q_t. ``impl``: "chunked" (the chunkwise form,
+    ``chunk`` positions a scan step; a sequence the chunk does not
+    divide is padded) or "recurrent" (a step a position)."""
+    if impl not in ("chunked", "recurrent"):
+        raise ValueError(f"gated_delta_rule: impl {impl!r}")
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_variable_for_type_inference(dtype=v.dtype)
+    # the state each chunk starts from, kept for the backward pass
+    states = helper.create_variable_for_type_inference(
+        dtype=q.dtype, stop_gradient=True)
+    helper.append_op(
+        "gated_delta_rule",
+        inputs={"Q": q, "K": k, "V": v, "G": g, "Beta": beta},
+        outputs={"Out": out, "States": states},
+        attrs={"chunk": int(chunk), "impl": impl,
+               "epsilon": float(epsilon)})
+    return out
+
+
+def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
+    """rms_norm(input) * gain * silu(gate) over the last axis (a plain
+    gain that starts at 1): the norm behind a gated delta rule."""
+    helper = LayerHelper("gated_rms_norm", name=name)
     scale = helper.create_parameter(
         ParamAttr._to_attr(param_attr), shape=[input.shape[-1]],
         dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(dtype=input.dtype)
-    helper.append_op("rms_norm", inputs={"X": input, "Scale": scale},
-                     outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
+    helper.append_op(
+        "gated_rms_norm", inputs={"X": input, "Z": gate, "Scale": scale},
+        outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
     return out
-
-
-def rotary_embedding(q, k, theta=10000.0, name=None):
-    """Rotary positions (rotate-half form) on q and k [b, h, t, dh];
-    position p of the sequence is p. Returns the rotated (q, k)."""
-    helper = LayerHelper("rotary_embedding", name=name)
-    q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
-    k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
-    helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
-                     outputs={"QOut": q_out, "KOut": k_out},
-                     attrs={"theta": float(theta)})
-    return q_out, k_out
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,
@@ -477,6 +571,7 @@ softplus = _make_act("softplus")
 softsign = _make_act("softsign")
 relu6 = _make_act("relu6")
 swish = _make_act("swish")
+silu = _make_act("silu")
 hard_swish = _make_act("hard_swish")
 hard_sigmoid = _make_act("hard_sigmoid")
 elu = _make_act("elu")
@@ -1289,7 +1384,7 @@ def switch_moe(input, num_experts, d_ff=None, capacity_factor=2.0,
 
 
 def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
-             param_attr=None, name=None):
+             param_attr=None, name=None, held=None, shared_d_ff=None):
     """Dropless top-k Mixture-of-Experts with SwiGLU experts (OLMoE,
     arXiv:2409.02060): ``input`` [.., d] tokens -> ``(out, lb_loss,
     z_loss, expert_rows, top_i)``. out, in input's shape, is the sum
@@ -1301,25 +1396,53 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
     ``expert_rows`` [E] int32 the rows each expert got, ``top_i`` [n, k]
     int32 the experts each token chose.
 
+    ``held=(first, count)``: the layer holds only experts first ..
+    first + count - 1 of the ``num_experts`` its router scores: one
+    chip's share of an expert-parallel layer, with nothing standing in
+    for the other chips. The router keeps all its outputs and its
+    ``top_k`` a token; ``out`` is the held experts' part of the sum (the
+    pairs on experts held elsewhere add nothing; the shares of all
+    chips, summed, are the whole layer), the expert parameters are
+    [count, ...] and ``expert_rows`` [count]. The row buffer keeps a row
+    for every (token, slot) pair, n * top_k of them, so that no routing,
+    all pairs on held experts included, drops a token; only the held
+    pairs' rows are multiplied.
+
+    ``shared_d_ff``: a shared SwiGLU expert of that width that every
+    token takes, behind a sigmoid gate of its own (Qwen3-Next):
+    out += sigmoid(x w_s) * (silu(x Wg) * (x Wu)) Wd.
+
     Four ops (ops/moe_ops.py), each under a name scope of its own:
-    router, dispatch, experts, combine. Parameters: ``{name}_router.w``
-    [d, E], ``{name}_gate.w`` / ``{name}_up.w`` [E, d, d_ff],
-    ``{name}_down.w`` [E, d_ff, d]."""
+    router, dispatch, experts, combine; the shared expert's ops under a
+    fifth, shared. Parameters: ``{name}_router.w`` [d, E],
+    ``{name}_gate.w`` / ``{name}_up.w`` [E or count, d, d_ff],
+    ``{name}_down.w`` [E or count, d_ff, d]; ``{name}_shared_gate.w`` /
+    ``_shared_up.w`` [d, shared_d_ff], ``_shared_down.w`` [shared_d_ff,
+    d], ``_shared_mix.w`` [d, 1]."""
     from paddle_tpu.framework import name_scope
     from paddle_tpu.initializer import NormalInitializer
 
     helper = LayerHelper("topk_moe", name=name)
     d = input.shape[-1]
     base = ParamAttr._to_attr(param_attr) or ParamAttr()
+    n_held, held_attrs = num_experts, {}
+    if held is not None:
+        first, n_held = (int(v) for v in held)
+        if not (0 <= first and 0 < n_held and first + n_held <= num_experts):
+            raise ValueError(f"topk_moe: held={held} of {num_experts}")
+        held_attrs = {"num_experts": int(num_experts), "held_first": first,
+                      "held_count": n_held}
+    init = base.initializer or NormalInitializer(0.0, 0.02)
 
-    def param(suffix, shape):
-        attr = ParamAttr(
-            name=f"{helper.name}{suffix}", initializer=base.initializer,
+    def attr(suffix):
+        return ParamAttr(
+            name=f"{helper.name}{suffix}", initializer=init,
             learning_rate=base.learning_rate, regularizer=base.regularizer,
             trainable=base.trainable)
-        return helper.create_parameter(
-            attr, shape=shape, dtype=input.dtype,
-            default_initializer=NormalInitializer(0.0, 0.02))
+
+    def param(suffix, shape):
+        return helper.create_parameter(attr(suffix), shape=shape,
+                                       dtype=input.dtype)
 
     def var(dtype, stop_gradient=False):
         return helper.create_variable_for_type_inference(
@@ -1340,25 +1463,38 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
         helper.append_op(
             "moe_dispatch", inputs={"X": input, "TopI": top_i},
             outputs={"Xs": xs, "Rows": rows, "Order": order, "Slot": slot},
-            attrs={"num_experts": int(num_experts)})
+            attrs={"num_experts": int(num_experts), **held_attrs})
     with name_scope("experts"):
         ys = var(input.dtype)
         # the two projections, kept for the op's backward pass
         gate, up = var(input.dtype, True), var(input.dtype, True)
+        regather = {"X": input, "Order": order} if held is not None else {}
         helper.append_op(
             "moe_experts",
-            inputs={"Xs": xs, "Rows": rows,
-                    "WGate": param("_gate.w", [num_experts, d, d_ff]),
-                    "WUp": param("_up.w", [num_experts, d, d_ff]),
-                    "WDown": param("_down.w", [num_experts, d_ff, d])},
-            outputs={"Ys": ys, "Gate": gate, "Up": up})
+            inputs={"Xs": xs, "Rows": rows, **regather,
+                    "WGate": param("_gate.w", [n_held, d, d_ff]),
+                    "WUp": param("_up.w", [n_held, d, d_ff]),
+                    "WDown": param("_down.w", [n_held, d_ff, d])},
+            outputs={"Ys": ys, "Gate": gate, "Up": up}, attrs=held_attrs)
     with name_scope("combine"):
         out = var(input.dtype)
         helper.append_op(
             "moe_combine",
             inputs={"Ys": ys, "TopW": top_w, "Order": order, "Slot": slot,
                     "Like": input},
-            outputs={"Out": out})
+            outputs={"Out": out}, attrs=held_attrs)
+    if shared_d_ff:
+        with name_scope("shared"):
+            def linear(x, size, suffix):
+                return fc(x, size, num_flatten_dims=len(x.shape) - 1,
+                          param_attr=attr(suffix), bias_attr=False)
+
+            h = elementwise_mul(silu(linear(input, shared_d_ff,
+                                            "_shared_gate.w")),
+                                linear(input, shared_d_ff, "_shared_up.w"))
+            mix = sigmoid(linear(input, 1, "_shared_mix.w"))
+            out = elementwise_add(out, elementwise_mul(
+                linear(h, d, "_shared_down.w"), mix))
     return out, lb, z, rows, top_i
 
 

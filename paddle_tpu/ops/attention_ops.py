@@ -37,17 +37,20 @@ def _note_dispatch(family, direction, dims, replicated_over=()):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
-    b, tq, tk, h, dh = dims
+    b, tq, tk, h, dh = dims[:5]
+    hk = dims[5] if len(dims) > 5 else h
     tile = ""
     if family == "bhtd":
         # the kernel layer's own answer for the call the op hands it (the
         # op passes no q_block / k_block)
         from paddle_tpu.parallel import flash_attention as fa
 
-        tile = fa.tile_label(fa.bhtd_tile(h, tq, tk, dh=dh))
+        tile = fa.tile_label(fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk))
+    # (grouped-query attention names its key/value heads: "h16 kv2")
+    heads = f"h{h}" if hk == h else f"h{h} kv{hk}"
     _M_DISPATCH.inc(labels={
         "family": family, "pass": direction,
-        "shape": f"b{b} tq{tq} tk{tk} h{h} dh{dh}", "tile": tile,
+        "shape": f"b{b} tq{tq} tk{tk} {heads} dh{dh}", "tile": tile,
         "replicated_over": ",".join(replicated_over)})
 
 
@@ -98,10 +101,15 @@ def _attn_bias(ins, attrs):
     return {"Out": [out]}
 
 
-def _rotate(x, theta):
+def _rotate(x, theta, rotary_dim=None):
     """Rotary position embedding of x [b, h, t, dh], rotate-half form
     (Su et al. 2021 as GPT-NeoX and HF lay it out): feature i pairs
-    with i + dh/2, position p turns the pair by p * theta^(-2i/dh)."""
+    with i + dh/2, position p turns the pair by p * theta^(-2i/dh).
+    ``rotary_dim`` < dh: only the FIRST rotary_dim features turn (as a
+    head of that width would), the others pass."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [_rotate(x[..., :rotary_dim], theta), x[..., rotary_dim:]], -1)
     t, dh = x.shape[-2], x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
@@ -115,12 +123,15 @@ def _rotate(x, theta):
 
 @register_op("rotary_embedding", diff_inputs=("Q", "K"))
 def _rotary_embedding(ins, attrs):
-    """Q, K [b, h, t, dh] -> the same with rotary positions 0..t-1
-    applied (attr ``theta``, the base). The angles and the rotation are
-    f32; the results return to the inputs' dtype."""
+    """Q, K [b, h, t, dh] (K may have fewer heads) -> the same with
+    rotary positions 0..t-1 applied (attr ``theta``, the base;
+    ``rotary_dim``, 0 or absent for the whole head: the leading
+    features that turn). The angles and the rotation are f32; the
+    results return to the inputs' dtype."""
     theta = float(attrs.get("theta", 10000.0))
-    return {"QOut": [_rotate(_x(ins, "Q"), theta)],
-            "KOut": [_rotate(_x(ins, "K"), theta)]}
+    rd = int(attrs.get("rotary_dim", 0)) or None
+    return {"QOut": [_rotate(_x(ins, "Q"), theta, rd)],
+            "KOut": [_rotate(_x(ins, "K"), theta, rd)]}
 
 
 def _sdpa_config(ins, attrs, rng):
@@ -131,7 +142,9 @@ def _sdpa_config(ins, attrs, rng):
     the in-kernel mask — is identical in both directions. ``family`` is
     the Pallas kernel family the shapes take on this backend, or "dense"
     for the jnp composition (parallel/flash_attention.py); ``dims`` is
-    (b, tq, tk, h, dh), the dispatch record's shape.
+    (b, tq, tk, h, dh), the dispatch record's shape, with the key/value
+    heads behind it where K and V have fewer than Q (grouped-query
+    attention: BHTD layout only, no dropout, no mesh).
     """
     from paddle_tpu.parallel import flash_attention as fa
 
@@ -149,14 +162,22 @@ def _sdpa_config(ins, attrs, rng):
     if attrs.get("layout", "bhtd") == "bthd":
         b, tq, h, dh = q.shape
         tk = k.shape[1]
+        if k.shape[2] != h:
+            raise ValueError("grouped key/value heads need layout='bhtd'")
         family = fa.bthd_family(tq, tk, h, dh)
+        dims = (b, tq, tk, h, dh)
     else:
         b, h, tq, dh = q.shape
-        tk = k.shape[2]
-        family = fa.bhtd_family(h, tq, tk, dh=dh)
+        tk, hk = k.shape[2], k.shape[1]
+        family = fa.bhtd_family(h, tq, tk, dh=dh, group=h // hk)
+        dims = (b, tq, tk, h, dh) + ((hk,) if hk != h else ())
+        if hk != h and (training_dropout or interp.spmd_ctx() is not None):
+            raise NotImplementedError(
+                "scaled_dot_product_attention: grouped key/value heads "
+                "with attention dropout or under a mesh")
     if not attrs.get("use_pallas", True):
         family = "dense"
-    return scale, drop, seed, family, (b, tq, tk, h, dh)
+    return scale, drop, seed, family, dims
 
 
 def _on_mesh(kernel, arrays, seed, family, direction, dims):
@@ -247,6 +268,9 @@ def _ring_config(q, k):
              needs_rng=True)
 def _sdpa(ins, attrs, rng=None):
     """Fused attention: Q,K,V [b, h, t, dh] + optional additive Bias.
+    K and V may have fewer heads than Q (grouped-query attention: query
+    head i reads key/value head i // (h / kv heads)); the BHTD kernels
+    pick the head in their index maps and never copy K or V.
 
     On TPU this routes to the Pallas flash-attention kernel
     (paddle_tpu/parallel/flash_attention.py), including training-time

@@ -1,0 +1,376 @@
+"""Gated delta-rule linear attention (Gated DeltaNet: Yang, Kautz &
+Hatamizadeh 2024, arXiv:2412.06464, as Qwen3-Next lays it out in HF
+``modeling_qwen3_next.py``) and the small ops around it: the causal
+depthwise convolution in front, the gates, the gated RMSNorm behind.
+
+Per value head, with a state S in R^{dk x dv} that starts at zero:
+
+    S_t = exp(g_t) S_{t-1}
+    S_t += k_t (beta_t (v_t - S_t^T k_t))^T
+    o_t  = S_t^T q_t
+
+``gated_delta_rule`` runs that recurrence in its CHUNKWISE form (chunk
+C = 64 by default). Inside a chunk, with G the running sum of g from the
+chunk's first position and D_ij = exp(G_i - G_j) for i >= j:
+
+    A   = strictly_lower((beta K) K^T . D)             # [C, C]
+    U   = (I + A)^{-1} (beta V),   W = (I + A)^{-1} (beta K exp(G))
+    per chunk, S the state it starts from:
+        V' = U - W S
+        O  = (Q exp(G)) S + lower(Q K^T . D) V'
+        S <- exp(G_C) S + (K exp(G_C - G))^T V'
+
+so the sequence costs t / C scan steps of matmuls, not t steps of rank-1
+updates. What is float32 whatever the activation stream: g, its running
+sums and every exp of them, beta, the normalisation of q and k, the
+triangular solve, and the state the scan carries. Matmul OPERANDS are
+the dtype Q arrives in (bf16 under AMP: core/interp.AMP_OP_TYPES casts
+Q, K, V and keeps G and Beta), accumulated in float32.
+
+The backward pass is the op's own (``gated_delta_rule_grad``): the
+forward saves the state each chunk starts from (``States``, in the
+operands' dtype: every use of it is a matmul operand); the grad op
+recomputes the per-chunk quantities above ONCE (parallel over chunks:
+no second run of the scan), walks the chunks backwards with the
+hand-derived transposes of the four products of a step, and sends the
+per-chunk cotangents through ``jax.vjp`` of the parallel part. Nothing
+here is a custom call, so nothing is traced twice at a cost.
+
+``impl="recurrent"`` is the recurrence step by step (``lax.scan`` over
+positions, differentiated by jax): the fallback a caller asks for, never
+taken silently: ``pt_linear_attention_dispatch_total`` records the
+implementation of every lowered call.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import monitor as _monitor
+from paddle_tpu.core.registry import register_op
+
+DEFAULT_CHUNK = 64
+
+_M_DISPATCH = _monitor.counter(
+    "pt_linear_attention_dispatch_total",
+    "gated delta-rule calls lowered, by pass (fwd, bwd), shape (batch, "
+    "positions, key heads, value heads and their widths), chunk (the "
+    "positions of one scan step; 1 for the recurrent form) and impl "
+    "(kernel: a gdn.* Pallas kernel; chunked: the chunkwise form as XLA "
+    "ops; recurrent: one scan step a position)")
+
+
+def _x(ins, slot, i=0):
+    v = ins.get(slot)
+    return v[i] if v else None
+
+
+def _note_dispatch(direction, q, v, chunk, impl):
+    # off with telemetry; build-time shape inference is not a lowering
+    from paddle_tpu.core import interp
+
+    if not _monitor.enabled() or not interp.lowering_active():
+        return
+    b, t, hk, dk = q.shape
+    _M_DISPATCH.inc(labels={
+        "pass": direction,
+        "shape": f"b{b} t{t} hk{hk} hv{v.shape[2]} dk{dk} dv{v.shape[3]}",
+        "chunk": str(chunk), "impl": impl})
+
+
+def dispatch_counts():
+    """{"impl pass shape chunk<C>": calls lowered so far}: the counter
+    as chip_smoke.py prints it."""
+    out = {}
+    for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
+        lb = row["labels"]
+        name = (f"{lb.get('impl', '?')} {lb.get('pass', '?')} "
+                f"{lb.get('shape', '?')} chunk{lb.get('chunk', '?')}")
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
+
+
+# ---------------------------------------------------------------------------
+# the small ops around the recurrence
+# ---------------------------------------------------------------------------
+
+
+@register_op("causal_conv1d", diff_inputs=("X", "W"))
+def _causal_conv1d(ins, attrs):
+    """X [b, t, c], W [c, taps] -> Y [b, t, c]: a depthwise convolution
+    over the sequence that sees no later position,
+    y_t = sum_j W[:, j] * x_{t - (taps - 1) + j} (positions before the
+    first count as zeros; HF's ``Conv1d(groups=c, padding=taps - 1)`` cut
+    to t), no bias, then ``act`` ("silu" or ""). Products and the sum
+    in float32, the result in X's dtype."""
+    x, w = _x(ins, "X"), _x(ins, "W")
+    taps, t = w.shape[-1], x.shape[1]
+    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    y = sum(xf[:, j:j + t] * wf[:, j] for j in range(taps))
+    if attrs.get("act", "silu") == "silu":
+        y = jax.nn.silu(y)
+    return {"Y": [y.astype(x.dtype)]}
+
+
+@register_op("gdn_gates", diff_inputs=("B", "A", "ALog", "DtBias"))
+def _gdn_gates(ins, attrs):
+    """B, A [b, t, h] (two projections of the token), ALog, DtBias [h]
+    -> Beta = sigmoid(B), the write strength, and G = -exp(ALog) *
+    softplus(A + DtBias), the log of the state's decay (<= 0). Float32
+    whatever the inputs' dtype: both are exponentiated and summed over
+    a chunk."""
+    f32 = jnp.float32
+    b, a = _x(ins, "B").astype(f32), _x(ins, "A").astype(f32)
+    a_log, dt = _x(ins, "ALog").astype(f32), _x(ins, "DtBias").astype(f32)
+    return {"Beta": [jax.nn.sigmoid(b)],
+            "G": [-jnp.exp(a_log) * jax.nn.softplus(a + dt)]}
+
+
+@register_op("gated_rms_norm", diff_inputs=("X", "Z", "Scale"))
+def _gated_rms_norm(ins, attrs):
+    """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale * silu(Z):
+    Qwen3-Next's norm behind the delta rule, over each value head's
+    width, a plain gain. Statistics, gain and gate in float32, Y in X's
+    dtype."""
+    f32 = jnp.float32
+    x, z, scale = _x(ins, "X"), _x(ins, "Z"), _x(ins, "Scale")
+    xf = x.astype(f32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                           + attrs.get("epsilon", 1e-6))
+    y = y * scale.astype(f32) * jax.nn.silu(z.astype(f32))
+    return {"Y": [y.astype(x.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule
+# ---------------------------------------------------------------------------
+
+
+def _normalised(q, k, eps):
+    """float32 q / |q| / sqrt(dk) and k / |k| over the last axis (HF's
+    ``l2norm``: x * rsqrt(sum(x^2) + eps))."""
+    def l2(x):
+        xf = x.astype(jnp.float32)
+        return xf * jax.lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + eps)
+
+    return l2(q) * (q.shape[-1] ** -0.5), l2(k)
+
+
+def _heads_first(x, rep=1):
+    """[b, t, h, ...] -> [b, h * rep, t, ...], each head ``rep`` times
+    in a row (key head i serves value heads i * rep .. i * rep + rep - 1,
+    HF's ``repeat_interleave``)."""
+    x = jnp.moveaxis(x, 2, 1)
+    return jnp.repeat(x, rep, axis=1) if rep > 1 else x
+
+
+def _mm(spec, a, b, dtype):
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _chunk_parts(q, k, v, g, beta, dtype):
+    """The part of the chunkwise form that is parallel over chunks.
+    q, k [b, h, n, C, dk] float32 (normalised), v [b, h, n, C, dv], g,
+    beta [b, h, n, C] float32 -> (w [.., C, dk], u [.., C, dv],
+    qg [.., C, dk], kd [.., C, dk], attn [.., C, C], dec [..]): the
+    module docstring's W, U, Q exp(G), K exp(G_C - G), lower(Q K^T . D)
+    and exp(G_C). U (subtracted from) and exp(G_C) (the state's decay)
+    are float32; the other four are only ever matmul operands and leave
+    in ``dtype``."""
+    c = q.shape[-2]
+    gc = jnp.cumsum(g, -1)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    strictly_lower = jnp.tril(lower, -1)
+    diff = gc[..., :, None] - gc[..., None, :]
+    # (the inner where: exp of a masked, positive difference overflows)
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    kb = k * beta[..., None]
+    a = jnp.where(strictly_lower,
+                  _mm("...ik,...jk->...ij", kb, k, dtype) * decay, 0.0)
+    rhs = jnp.concatenate(
+        [v.astype(jnp.float32) * beta[..., None],
+         kb * jnp.exp(gc)[..., None]], -1)
+    sol = jax.lax.linalg.triangular_solve(
+        a + jnp.eye(c, dtype=a.dtype), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    dv = v.shape[-1]
+    u, w = sol[..., :dv], sol[..., dv:]
+    attn = _mm("...ik,...jk->...ij", q, k, dtype) * decay
+    g_last = gc[..., -1:]
+    return (w.astype(dtype), u, (q * jnp.exp(gc)[..., None]).astype(dtype),
+            (k * jnp.exp(g_last - gc)[..., None]).astype(dtype),
+            attn.astype(dtype), jnp.exp(g_last[..., 0]))
+
+
+def _chunks_first(x):
+    """[b, h, n, ...] -> [n, b, h, ...]: the scan's axis in front."""
+    return jnp.moveaxis(x, 2, 0)
+
+
+def _chunk_scan(parts, dtype):
+    """The scan over chunks -> (o [b, h, n, C, dv] float32, the state
+    each chunk started from [n, b, h, dk, dv] in ``dtype``)."""
+    w, u, qg, kd, attn, dec = parts
+    b, h = w.shape[:2]
+    s0 = jnp.zeros((b, h, w.shape[-1], u.shape[-1]), jnp.float32)
+
+    def step(s, xs):
+        w, u, qg, kd, attn, dec = xs
+        sm = s.astype(dtype)
+        vn = u - _mm("bhck,bhkv->bhcv", w, sm, dtype)
+        o = (_mm("bhck,bhkv->bhcv", qg, sm, dtype)
+             + _mm("bhij,bhjv->bhiv", attn, vn, dtype))
+        s = (s * dec[..., None, None]
+             + _mm("bhck,bhcv->bhkv", kd, vn, dtype))
+        return s, (o, sm)
+
+    _, (o, states) = jax.lax.scan(
+        step, s0, tuple(_chunks_first(x) for x in parts))
+    return jnp.moveaxis(o, 0, 2), states
+
+
+def _chunk_scan_bwd(parts, states, do, dtype):
+    """Cotangents of ``parts`` for the cotangent ``do`` [b, h, n, C, dv]
+    of the scan's output: the chunks in reverse, each step the
+    transposes of the forward step's four products around the state it
+    started from (saved) and V' (one product to make again)."""
+    def step(ds, xs):
+        w, u, qg, kd, attn, dec, sm, do = xs
+        vn = u - _mm("bhck,bhkv->bhcv", w, sm, dtype)
+        dvn = (_mm("bhij,bhiv->bhjv", attn, do, dtype)
+               + _mm("bhck,bhkv->bhcv", kd, ds, dtype))
+        dattn = _mm("bhiv,bhjv->bhij", do, vn, dtype)
+        dqg = _mm("bhcv,bhkv->bhck", do, sm, dtype)
+        dkd = _mm("bhcv,bhkv->bhck", vn, ds, dtype)
+        dw = -_mm("bhcv,bhkv->bhck", dvn, sm, dtype)
+        ddec = jnp.sum(ds * sm.astype(jnp.float32), (-2, -1))
+        ds = (ds * dec[..., None, None]
+              + _mm("bhck,bhcv->bhkv", qg, do, dtype)
+              - _mm("bhck,bhcv->bhkv", w, dvn, dtype))
+        return ds, (dw.astype(dtype), dvn, dqg.astype(dtype),
+                    dkd.astype(dtype), dattn.astype(dtype), ddec)
+
+    xs = tuple(_chunks_first(x) for x in parts) + (states, _chunks_first(do))
+    ds0 = jnp.zeros(states.shape[1:], jnp.float32)
+    _, grads = jax.lax.scan(step, ds0, xs, reverse=True)
+    return tuple(jnp.moveaxis(x, 0, 2) for x in grads)
+
+
+def _chunked(x, n, c):
+    """[b, h, t, ...] -> [b, h, n, c, ...], zeros behind position t."""
+    t = x.shape[2]
+    if n * c != t:
+        x = jnp.pad(x, [(0, 0), (0, 0), (0, n * c - t)]
+                    + [(0, 0)] * (x.ndim - 3))
+    return x.reshape(x.shape[:2] + (n, c) + x.shape[3:])
+
+
+def _chunk_inputs(q, k, v, g, beta, chunk, eps):
+    """The op's inputs as the chunkwise form takes them. A sequence the
+    chunk does not divide is padded behind its last position with
+    zeros: beta 0 writes nothing, g 0 forgets nothing and q 0 reads
+    nothing, and the padded positions come after every real one."""
+    rep = v.shape[2] // q.shape[2]
+    n = -(-q.shape[1] // chunk)
+    qn, kn = _normalised(q, k, eps)
+    return tuple(_chunked(x, n, chunk) for x in (
+        _heads_first(qn, rep), _heads_first(kn, rep), _heads_first(v),
+        _heads_first(g.astype(jnp.float32)),
+        _heads_first(beta.astype(jnp.float32))))
+
+
+def _unchunked(o, t, dtype):
+    """[b, h, n, C, dv] -> [b, t, h, dv]."""
+    b, h, n, c, dv = o.shape
+    return jnp.moveaxis(o.reshape(b, h, n * c, dv)[:, :, :t], 1, 2).astype(
+        dtype)
+
+
+def recurrent_gated_delta_rule(q, k, v, g, beta, eps=1e-6):
+    """The recurrence of the module docstring, one scan step a position:
+    q, k [b, t, hk, dk], v [b, t, hv, dv], g, beta [b, t, hv] -> o
+    [b, t, hv, dv] in v's dtype. Float32 throughout."""
+    rep = v.shape[2] // q.shape[2]
+    qn, kn = _normalised(q, k, eps)
+    f32 = jnp.float32
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (
+        _heads_first(qn, rep), _heads_first(kn, rep),
+        _heads_first(v.astype(f32)), _heads_first(g.astype(f32)),
+        _heads_first(beta.astype(f32))))       # [t, b, h, ...]
+
+    def step(s, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        s = s * jnp.exp(g_t)[..., None, None]
+        delta = (v_t - jnp.einsum("bhkv,bhk->bhv", s, k_t)) * b_t[..., None]
+        s = s + k_t[..., :, None] * delta[..., None, :]
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q_t)
+
+    b, _, h, dv = v.shape
+    s0 = jnp.zeros((b, h, q.shape[-1], dv), f32)
+    _, o = jax.lax.scan(step, s0, xs)
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype)
+
+
+def _args(ins, attrs):
+    return (tuple(_x(ins, s) for s in ("Q", "K", "V", "G", "Beta")),
+            int(attrs.get("chunk", DEFAULT_CHUNK)),
+            attrs.get("impl", "chunked"),
+            float(attrs.get("epsilon", 1e-6)))
+
+
+@register_op("gated_delta_rule", diff_inputs=("Q", "K", "V", "G", "Beta"))
+def _gated_delta_rule(ins, attrs):
+    """Q, K [b, t, hk, dk] (normalised here), V [b, t, hv, dv] (hv a
+    multiple of hk), G [b, t, hv] (log decay, <= 0) and Beta [b, t, hv]
+    (write strength), float32 both -> Out [b, t, hv, dv] in V's dtype
+    and States [n, b, hv, dk, dv], the state each chunk of ``chunk``
+    positions started from, for the paired grad op (dead at inference;
+    one zero for ``impl="recurrent"``). See the module docstring."""
+    (q, k, v, g, beta), chunk, impl, eps = _args(ins, attrs)
+    if impl == "recurrent":
+        _note_dispatch("fwd", q, v, 1, impl)
+        return {"Out": [recurrent_gated_delta_rule(q, k, v, g, beta, eps)],
+                "States": [jnp.zeros((1,), q.dtype)]}
+    _note_dispatch("fwd", q, v, chunk, impl)
+    parts = _chunk_parts(*_chunk_inputs(q, k, v, g, beta, chunk, eps),
+                         q.dtype)
+    o, states = _chunk_scan(parts, q.dtype)
+    return {"Out": [_unchunked(o, q.shape[1], v.dtype)],
+            "States": [states]}
+
+
+@register_op("gated_delta_rule_grad", no_grad=True)
+def _gated_delta_rule_grad(ins, attrs):
+    """The backward pass of ``gated_delta_rule`` from the saved States
+    (module docstring): one recomputation of the parallel part, a
+    reverse scan over chunks, and jax's transpose of the parallel
+    part. ``impl="recurrent"``: jax's vjp of the step-by-step scan."""
+    (q, k, v, g, beta), chunk, impl, eps = _args(ins, attrs)
+    do = _x(ins, "GRAD::Out")
+    if impl == "recurrent":
+        _note_dispatch("bwd", q, v, 1, impl)
+        _, vjp = jax.vjp(
+            lambda *a: recurrent_gated_delta_rule(*a, eps), q, k, v, g, beta)
+        grads = vjp(do.astype(v.dtype))
+    else:
+        _note_dispatch("bwd", q, v, chunk, impl)
+        dtype = q.dtype
+
+        def parallel_part(q, k, v, g, beta):
+            return _chunk_parts(
+                *_chunk_inputs(q, k, v, g, beta, chunk, eps), dtype)
+
+        # (behind a barrier, or XLA merges this recomputation with the
+        # forward op's and keeps every per-chunk quantity, most of them
+        # float32, from the forward pass to here: 0.8 GB a layer at
+        # 8192 positions of 32 heads, compiled for a v5e)
+        parts, vjp = jax.vjp(parallel_part, *jax.lax.optimization_barrier(
+            (q, k, v, g, beta)))
+        n = parts[0].shape[2]
+        do = _chunked(_heads_first(do.astype(jnp.float32)), n, chunk)
+        grads = vjp(_chunk_scan_bwd(parts, _x(ins, "States"), do, dtype))
+    return {f"GRAD::{s}": [d.astype(x.dtype)] for s, d, x in zip(
+        ("Q", "K", "V", "G", "Beta"), grads, (q, k, v, g, beta))}

@@ -202,23 +202,77 @@ def _rows_bwd(back, g):
 _rows.defvjp(_rows_fwd, _rows_bwd)
 
 
+def _slot_major(x, slot):
+    """The rows of ``x`` [n*k, d] that hold each token's k pairs, as
+    [k, n, d] (``slot`` [n, k]: moe_dispatch's): the slot in FRONT. A
+    [n, k, d] array whose k is no multiple of 8 (Qwen3-Next's 10) is
+    padded to one on the chip, k being the tile's second-minor
+    dimension: 60% more bytes in every pass over it and a relayout
+    copy that carries no scope (2.7 ms a layer at 8192 x 10 x 2048, my
+    chip run, PR 32). In front k is padded by nothing."""
+    n, k = slot.shape
+    return jnp.take(x, slot.T.reshape(-1), axis=0).reshape(k, n, -1)
+
+
+@jax.custom_vjp
+def _rows_of_pairs(x, idx, slot):
+    """``_rows`` for a held share: ``x[idx]`` whose cotangent is the sum
+    over each token's k rows, gathered slot-major (``_slot_major``)."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _rows_of_pairs_fwd(x, idx, slot):
+    return jnp.take(x, idx, axis=0), slot
+
+
+def _rows_of_pairs_bwd(slot, g):
+    return (jnp.sum(_slot_major(g, slot).astype(jnp.float32), axis=0
+                    ).astype(g.dtype), None, None)
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
 @register_op("moe_dispatch", diff_inputs=("X",))
 def _moe_dispatch(ins, attrs):
     """X [.., d], TopI [n, k] -> Xs [n*k, d]: one row per chosen (token,
     expert) pair, sorted by expert (stable: by token inside an expert);
     Rows [E] int32, the rows each expert got; Order [n*k] int32, the
     flat pair (token * k + slot) at each row of Xs; Slot [n, k] int32,
-    its inverse: the row of Xs that holds pair (token, slot)."""
+    its inverse: the row of Xs that holds pair (token, slot).
+
+    ``held_first`` / ``held_count``: this layer holds only experts
+    first .. first + count - 1 of the ``num_experts`` the router scored
+    (one chip's share under expert parallelism). Rows is then [count],
+    the held experts' rows, which sum to less than n*k; the pairs on
+    held experts come first in Xs, sorted by expert, and the pairs on
+    experts held elsewhere lie behind them (by token): they keep their
+    place in the buffer, which has a row for every pair whatever the
+    routing, and no expert here reads them."""
     x, top_i = _x(ins, "X"), _x(ins, "TopI")
     x = x.reshape(-1, x.shape[-1])
     n, k = top_i.shape
     e = int(attrs["num_experts"])
     flat = top_i.reshape(-1)
+    if "held_count" in attrs:
+        e = int(attrs["held_count"])
+        local = flat - int(attrs.get("held_first", 0))
+        flat = jnp.where(jnp.logical_and(local >= 0, local < e), local, e)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     slot = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
     rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
-    return {"Xs": [_rows(x, order // k, slot)], "Rows": [rows],
+    gather = _rows_of_pairs if "held_count" in attrs else _rows
+    return {"Xs": [gather(x, order // k, slot)], "Rows": [rows],
             "Order": [order], "Slot": [slot]}
+
+
+def _live_rows(attrs, m):
+    """grouped_matmul's ``live_rows`` for an experts op over m rows:
+    nothing for a layer that holds every expert its router scores."""
+    if "held_count" not in attrs:
+        return {}
+    return {"live_rows": -(-m * int(attrs["held_count"])
+                           // int(attrs["num_experts"]))}
 
 
 def _swiglu(gate, up):
@@ -237,14 +291,26 @@ def _moe_experts(ins, attrs):
     ``jax.lax.ragged_dot`` (on the v5e libtpu's ``ragged-dot-none``
     Mosaic call). Also emits the two projections (Gate, Up [m, f]) so
     that the paired grad op below does not run them again: XLA cannot
-    CSE custom calls (dead when nothing reads them)."""
+    CSE custom calls (dead when nothing reads them).
+
+    ``held_count`` of ``num_experts`` (a held share of the experts,
+    see moe_dispatch): Rows sum to less than m; the rows behind the last
+    group are multiplied by nothing and Ys has zeros there, so they add
+    nothing to moe_combine's sum. The grouped matmuls are told the rows
+    an even router would put on the held experts (``live_rows``: their
+    row tile goes with those, not with the buffer). Such a layer also
+    hands over X, the tokens, and Order (moe_dispatch's): the grad op
+    gathers Xs again from them and does not keep the buffer, 16 times
+    its live rows, from the forward pass (0.32 GB a layer at 81,920
+    rows of 2048)."""
     from paddle_tpu.parallel.grouped_matmul import grouped_matmul
 
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
     wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
-    gate = grouped_matmul(xs, wg.astype(xs.dtype), rows)
-    up = grouped_matmul(xs, wu.astype(xs.dtype), rows)
-    ys = grouped_matmul(_swiglu(gate, up), wd.astype(xs.dtype), rows)
+    kw = _live_rows(attrs, xs.shape[0])
+    gate = grouped_matmul(xs, wg.astype(xs.dtype), rows, **kw)
+    up = grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
+    ys = grouped_matmul(_swiglu(gate, up), wd.astype(xs.dtype), rows, **kw)
     return {"Ys": [ys], "Gate": [gate], "Up": [up]}
 
 
@@ -257,15 +323,26 @@ def _moe_experts_grad(ins, attrs):
     from paddle_tpu.parallel.grouped_matmul import grouped_matmul_grads
 
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
+    if _x(ins, "X") is not None:
+        # a held share: gathered again (behind a barrier, or XLA merges
+        # this gather with moe_dispatch's and keeps that one's result)
+        x, order = jax.lax.optimization_barrier(
+            (_x(ins, "X"), _x(ins, "Order")))
+        x = x.reshape(-1, x.shape[-1])
+        xs = jnp.take(x, order // (xs.shape[0] // x.shape[0]),
+                      axis=0).astype(xs.dtype)
     wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
     gate = _x(ins, "Gate").astype(xs.dtype)
     up = _x(ins, "Up").astype(xs.dtype)
     g = _x(ins, "GRAD::Ys").astype(xs.dtype)
+    kw = _live_rows(attrs, xs.shape[0])
     h, swiglu_vjp = jax.vjp(_swiglu, gate, up)
-    dh, dwd = grouped_matmul_grads(h, wd.astype(xs.dtype), rows, g)
+    dh, dwd = grouped_matmul_grads(h, wd.astype(xs.dtype), rows, g, **kw)
     dgate, dup = swiglu_vjp(dh)
-    dx_gate, dwg = grouped_matmul_grads(xs, wg.astype(xs.dtype), rows, dgate)
-    dx_up, dwu = grouped_matmul_grads(xs, wu.astype(xs.dtype), rows, dup)
+    dx_gate, dwg = grouped_matmul_grads(xs, wg.astype(xs.dtype), rows,
+                                        dgate, **kw)
+    dx_up, dwu = grouped_matmul_grads(xs, wu.astype(xs.dtype), rows, dup,
+                                      **kw)
     return {"GRAD::Xs": [dx_gate + dx_up],
             "GRAD::WGate": [dwg.astype(wg.dtype)],
             "GRAD::WUp": [dwu.astype(wu.dtype)],
@@ -281,7 +358,49 @@ def _moe_combine(ins, attrs):
     ys, top_w = _x(ins, "Ys"), _x(ins, "TopW")
     order, slot = _x(ins, "Order"), _x(ins, "Slot")
     n, k = slot.shape
+    if "held_count" in attrs:   # a held share: slot-major, its own grad op
+        out = jnp.einsum("knd,nk->nd",
+                         _slot_major(ys, slot).astype(jnp.float32),
+                         top_w.astype(jnp.float32))
+        return {"Out": [out.astype(ys.dtype).reshape(
+            _x(ins, "Like").shape)]}
     picked = _rows(ys, slot.reshape(-1), order[:, None]).reshape(n, k, -1)
     out = jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
                      top_w.astype(jnp.float32))
     return {"Out": [out.astype(ys.dtype).reshape(_x(ins, "Like").shape)]}
+
+
+@register_op("moe_combine_grad", no_grad=True)
+def _moe_combine_grad(ins, attrs):
+    """A layer that holds every expert its router scores: jax's
+    transposes of the forward above, as the generic grad op derives
+    them. A held share (``held_count``, see moe_dispatch), whose row
+    buffer is num_experts / held_count times its live rows, writes the
+    two cotangents out instead: jax's transpose of the weighted sum
+    keeps the gathered rows AND their products as float32 [n, k, d]
+    (1.6 GB at 81,920 rows of 2048 compiled for a v5e, and the gathered
+    rows saved from the forward pass beside them); here GRAD::Ys is one
+    gather of the cotangent's rows times the pair's weight, and
+    GRAD::TopW one product of Ys's rows, gathered again, with the
+    cotangent, accumulated in float32: nothing is kept from the forward
+    pass and nothing float32 is [n, k, d]. Where the pairs of a token
+    lie side by side they do so slot-major (``_slot_major``)."""
+    if "held_count" not in attrs:
+        from paddle_tpu.core import autodiff
+        from paddle_tpu.core.registry import get_op_def
+
+        return autodiff.make_grad_compute(get_op_def("moe_combine"))(
+            ins, attrs)
+    # (behind a barrier, or XLA merges the gather of Ys's rows below
+    # with the forward op's and keeps that one's result)
+    ys, slot = jax.lax.optimization_barrier((_x(ins, "Ys"), _x(ins, "Slot")))
+    top_w, order = _x(ins, "TopW"), _x(ins, "Order")
+    n, k = slot.shape
+    g = _x(ins, "GRAD::Out").reshape(n, -1)
+    pair_w = jnp.take(top_w.astype(jnp.float32).reshape(-1), order)
+    d_ys = (jnp.take(g, order // k, axis=0).astype(jnp.float32)
+            * pair_w[:, None]).astype(ys.dtype)
+    d_w = jnp.einsum("knd,nd->nk", _slot_major(ys, slot),
+                     g.astype(ys.dtype),
+                     preferred_element_type=jnp.float32)
+    return {"GRAD::Ys": [d_ys], "GRAD::TopW": [d_w.astype(top_w.dtype)]}

@@ -272,13 +272,18 @@ def _rms_norm(ins, attrs):
     Sennrich 2019): no mean, no bias. As layer_norm above, the mean of
     squares and the gain run in f32 whatever the activation dtype (bf16
     under AMP), so the gain's gradient reduction is f32 too; only Y
-    returns to X's dtype."""
+    returns to X's dtype. ``zero_centered``: the gain is 1 + Scale
+    (Qwen3-Next: the parameter starts at 0 and weight decay pulls the
+    gain to 1)."""
     x, scale = _x(ins), _x(ins, "Scale")
     stat_dtype = jnp.promote_types(x.dtype, jnp.float32)
     xf = x.astype(stat_dtype)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(ms + attrs.get("epsilon", 1e-5))
-    return {"Y": [(y * scale.astype(stat_dtype)).astype(x.dtype)]}
+    gain = scale.astype(stat_dtype)
+    if attrs.get("zero_centered", False):
+        gain = 1.0 + gain
+    return {"Y": [(y * gain).astype(x.dtype)]}
 
 
 def _draw_bits(op, rng, shape):

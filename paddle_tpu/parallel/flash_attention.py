@@ -116,7 +116,7 @@ def _tile_fits(hb, bq, bk, dh):
             and 4 * hb * bq * bk <= _SCORE_VMEM_BYTES)
 
 
-def _pick_tile(h, tq, tk, q_block, k_block, dh):
+def _pick_tile(h, tq, tk, q_block, k_block, dh, group=1):
     """-> (hb, bq, bk): heads, query rows and key rows of one grid step,
     from what the call shows (h, dh, tq, tk) and the two VMEM caps.
     ``q_block`` / ``k_block``: None for the kernels' own choice, a number
@@ -131,10 +131,16 @@ def _pick_tile(h, tq, tk, q_block, k_block, dh):
     h * dh = 2048 a step over all heads could keep 128 of them. A block
     that does not divide its sequence halves until it does (not under
     128), and a step then keeps as many heads as the caps admit: hb4
-    256 x 256 at t = 768, all 16 heads of 128 at 128 x 128 at t = 640."""
+    256 x 256 at t = 768, all 16 heads of 128 at 128 x 128 at t = 640.
+
+    ``group`` > 1 (grouped-query attention: that many query heads read
+    one key/value head): the heads always go onto the grid, one a step,
+    so that a step's K and V block is one head's and the index maps pick
+    it (``q head // group``): the group's K and V are read from HBM where
+    they lie, never copied ``group`` times."""
     bq = min(q_block or DEFAULT_Q_BLOCK, tq)
     bk = min(k_block or DEFAULT_K_BLOCK, tk)
-    if _tile_fits(h, bq, bk, dh):
+    if group == 1 and _tile_fits(h, bq, bk, dh):
         return h, bq, bk
     bq = min(q_block or _GRID_HEADS_BLOCK, tq)
     bk = min(k_block or _GRID_HEADS_BLOCK, tk)
@@ -147,19 +153,21 @@ def _pick_tile(h, tq, tk, q_block, k_block, dh):
         bk //= 2
     while not _tile_fits(1, bq, bk, dh) and bq > 64:
         bq //= 2
+    if group > 1:
+        return 1, bq, bk
     hb = max(d for d in range(1, h + 1)
              if h % d == 0 and (d == 1 or _tile_fits(d, bq, bk, dh)))
     return hb, bq, bk
 
 
-def bhtd_tile(h, tq, tk, q_block=None, k_block=None, *, dh):
+def bhtd_tile(h, tq, tk, q_block=None, k_block=None, *, dh, group=1):
     """-> (hb, bq, bk), the tile the K-blocked [b, h, t, dh] kernels take
     for a call of this shape, or None where they do not take it (no TPU
     backend, or blocks that do not tile both sequence lengths) and it
     runs as the dense composition. The one place that decides either:
     the kernels' entry points, ``bhtd_family`` and the dispatch counter's
     ``tile`` label all read it."""
-    hb, bq, bk = _pick_tile(h, tq, tk, q_block, k_block, dh)
+    hb, bq, bk = _pick_tile(h, tq, tk, q_block, k_block, dh, group)
     if kernels_enabled() and tq % bq == 0 and tk % bk == 0:
         return hb, bq, bk
     return None
@@ -171,10 +179,11 @@ def tile_label(tile) -> str:
     return "hb%d bq%d bk%d" % tile if tile else ""
 
 
-def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh) -> str:
+def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
+                group=1) -> str:
     """"bhtd" (the K-blocked [b, h, t, dh] kernels) when the picked
     blocks tile both sequence lengths, else "dense"."""
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group)
     return "bhtd" if tile else "dense"
 
 
@@ -358,11 +367,16 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                scale, nq, ng, p_drop, causal=False):
+                scale, nq, ng, p_drop, causal=False, group=1):
     kk, jq = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
+    walk, steps = jq, nq   # the inner axis: the q-blocks of one head
+    if group > 1:
+        # grouped-query attention: the inner axis walks the group's
+        # query heads, each over its q-blocks, and dk, dv gather all
+        steps, jq = group * nq, walk % nq
 
-    @pl.when(jq == 0)
+    @pl.when(walk == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -426,21 +440,26 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     else:
         _compute()
 
-    @pl.when(jq == nq - 1)
+    @pl.when(walk == steps - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _step_blocks(causal, k_inner, bq, bk, nq):
+def _step_blocks(causal, k_inner, bq, bk, nq, group=1):
     """-> f(*grid ids) = (i, g, j, kk): batch row, head group, q-block
     and k-block a grid step READS. The grid is (i, g, j, kk) with the k
     axis inner (forward, dq) or (i, g, kk, j) with the q axis inner
     (dk/dv); under ``causal`` the inner index of a dead step is its
-    row's nearest live one, so the step fetches nothing."""
+    row's nearest live one, so the step fetches nothing. ``group`` > 1
+    (one head a step): g is the QUERY head (_row_specs reads K and V at
+    g // group); the dk/dv grid is then (i, kv head, kk, r) with r over
+    the group's heads and, inside one, its q-blocks."""
     def f(*ids):
         i, g = ids[0], ids[1]
         j, kk = (ids[2], ids[3]) if k_inner else (ids[3], ids[2])
+        if group > 1 and not k_inner:
+            g, j = g * group + j // nq, j % nq
         if causal and k_inner:
             kk = _live_k(j, kk, bq, bk)
         elif causal:
@@ -449,11 +468,12 @@ def _step_blocks(causal, k_inner, bq, bk, nq):
     return f
 
 
-def _row_specs(at, hb, bq, bk, dh):
+def _row_specs(at, hb, bq, bk, dh, group=1):
     """Specs read at ``at``'s blocks: a (1, hb, bq, dh) block of q, out or
     their gradients; a (1, hb, bq, 1) block of a [b, h, tq, 1] statistic;
     a (1, hb, 1, bq) block of the same statistic laid out [b, h, 1, tq];
-    a (1, hb, bk, dh) block of k, v or their gradients."""
+    a (1, hb, bk, dh) block of k, v or their gradients (``group`` > 1:
+    of the key/value head the step's query head reads)."""
     def q_idx(*ids):
         i, g, j, _ = at(*ids)
         return i, g, j, 0
@@ -464,7 +484,7 @@ def _row_specs(at, hb, bq, bk, dh):
 
     def k_idx(*ids):
         i, g, _, kk = at(*ids)
-        return i, g, kk, 0
+        return i, g if group == 1 else g // group, kk, 0
 
     return (pl.BlockSpec((1, hb, bq, dh), q_idx),
             pl.BlockSpec((1, hb, bq, 1), q_idx),
@@ -505,7 +525,12 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
     """(out, lse) from ONE score tensor — the fallback twin of the
     kernels' contract. out and lse must never derive from separately
     constructed scores (different dtype promotion would desynchronize
-    them at exactly the tolerance the ring merge relies on)."""
+    them at exactly the tolerance the ring merge relies on). K and V
+    with fewer heads than Q (grouped-query attention) are repeated
+    here: the composition, unlike the kernels, makes the copies."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = _reference_scores(q, k, bias, scale, causal)
     lse = jax.scipy.special.logsumexp(s, axis=-1, keepdims=True)
     p = jax.nn.softmax(s, axis=-1)
@@ -572,7 +597,7 @@ def _call_parts(kernel, at, tile, q, k, v, bias):
     """What the three calls share: -> (the kernel, the specs and the
     operands of q, k, v and the bias if there is one, _row_specs). With
     no bias the kernel's bias_ref slot (the fifth) is None."""
-    rows = _row_specs(at, *tile, q.shape[3])
+    rows = _row_specs(at, *tile, q.shape[3], q.shape[1] // k.shape[1])
     specs, args = [rows[0], rows[3], rows[3]], [q, k, v]
     if bias is None:
         body = kernel
@@ -581,6 +606,21 @@ def _call_parts(kernel, at, tile, q, k, v, bias):
         specs.append(_bias_spec(bias, at, *tile))
         args.append(bias)
     return kernel, specs, args, rows
+
+
+def _kv_group(q, k, p_drop):
+    """Query heads a key/value head of the call: 1, or under
+    grouped-query attention h / kv heads (K and V [b, kv heads, tk,
+    dh])."""
+    h, hk = q.shape[1], k.shape[1]
+    if h % hk:
+        raise ValueError(f"attention: {h} query heads do not divide over "
+                         f"{hk} key/value heads")
+    if h != hk and p_drop > 0.0:
+        raise ValueError(
+            "attention dropout with grouped key/value heads: the dk/dv "
+            "kernel's mask stream is keyed by its grid's head group")
+    return h // hk
 
 
 def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
@@ -607,7 +647,8 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh)
+    group = _kv_group(q, k, p_drop)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group)
     if tile is None:
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
@@ -619,7 +660,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     hb, bq, bk = tile
     ng, nq, nk = h // hb, tq // bq, tk // bk
     kernel, in_specs, args, (q_spec, stat_spec, _, _) = _call_parts(
-        _fwd_kernel, _step_blocks(causal, True, bq, bk, nq), tile,
+        _fwd_kernel, _step_blocks(causal, True, bq, bk, nq, group), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
                                p_drop=p_drop, causal=causal)
@@ -663,7 +704,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh)
+    group = _kv_group(q, k, p_drop)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group)
     if tile is None:
         def f(q, k, v):
             return _reference_attention_with_lse(
@@ -685,7 +727,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 
     # --- dq: grid (b, ng, nq, nk), k-blocks inner ---
     kernel, specs, args, (q_spec, stat_spec, _, _) = _call_parts(
-        _dq_kernel, _step_blocks(causal, True, bq, bk, nq), tile,
+        _dq_kernel, _step_blocks(causal, True, bq, bk, nq, group), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, nk=nk, **kw)
     operands = (seed_arr, *args, g, lse, delta)
@@ -711,9 +753,15 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     # q block off the 128-lane tiling (a caller's q_block of 64) keeps
     # that form: it cannot be cut from a row.
     kernel, specs, args, (q_spec, stat_spec, row_spec, kv_spec) = _call_parts(
-        _dkv_kernel, _step_blocks(causal, False, bq, bk, nq), tile,
+        _dkv_kernel, _step_blocks(causal, False, bq, bk, nq, group), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, nq=nq, **kw)
+    dkv_grid = (b, ng, nk, nq)
+    if group > 1:
+        # a step's dk, dv block is one key/value head's: the inner axis
+        # walks the group's query heads, and the scratch sums them
+        kernel = functools.partial(kernel, group=group)
+        dkv_grid = (b, h // group, nk, group * nq)
     stats = [lse, delta]
     if bq % 128 == 0 or bq == tq:
         stat_spec = row_spec
@@ -723,7 +771,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         kernel, name="attn.bhtd.bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, ng, nk, nq),
+            grid=dkv_grid,
             in_specs=specs + [q_spec, stat_spec, stat_spec],
             out_specs=[kv_spec, kv_spec],
             scratch_shapes=[
@@ -732,8 +780,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
             ],
         ),
         out_shape=[
-            _result(operands, (b, h, tk, dh), k.dtype),
-            _result(operands, (b, h, tk, dh), v.dtype),
+            _result(operands, k.shape, k.dtype),
+            _result(operands, v.shape, v.dtype),
         ],
         interpret=_INTERPRET,
     )(*operands)
@@ -775,7 +823,8 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if bhtd_family(q.shape[1], q.shape[2], k.shape[2],
-                    q_block, k_block, dh=q.shape[3]) == "bhtd":
+                    q_block, k_block, dh=q.shape[3],
+                    group=q.shape[1] // k.shape[1]) == "bhtd":
         dq, dk, dv = flash_attention_bwd(q, k, v, bias, seed, out, lse, g,
                                          scale, p_drop, q_block, k_block,
                                          causal, g_lse=g_lse)

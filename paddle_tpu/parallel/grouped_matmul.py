@@ -5,7 +5,11 @@ is ``jax.lax.ragged_dot``: the rows of ``lhs`` are sorted by expert,
 ``group_sizes`` says how many each expert got (they sum to m: dropless,
 so there is no row past the last group), and row i is multiplied by the
 matrix of its expert. Every row is computed; nothing is padded, capped
-or dropped. Operands keep their dtype (bf16 under AMP), a product is
+or dropped. A caller that holds only some of the experts its router
+scores (``live_rows``: an expert-parallel share, ops/moe_ops.py) passes
+group sizes that sum to LESS than m: the rows behind the last group
+belong to no expert here, no visit reaches their tiles, and the result
+has zeros there, as ``ragged_dot`` has. Operands keep their dtype (bf16 under AMP), a product is
 accumulated in float32 inside the kernel and returned in the operands'
 dtype, as ``ragged_dot`` returns it.
 
@@ -103,7 +107,7 @@ def _vmem_limit(tm, tk, tn, itemsize):
     return max(16 * 2**20, _vmem_bytes(tm, tk, tn, itemsize) * 3 // 2)
 
 
-def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None):
+def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None, live_rows=None):
     """-> (tm, tk, tn), the rows, contraction and width of one grid step
     for the product ``[m, k] x [e, k, n]``, or None where the call runs
     as ``jax.lax.ragged_dot``: no TPU backend (``backend``: None for
@@ -125,15 +129,23 @@ def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None):
     n (a split contraction costs an accumulator pass a step; with both
     whole an expert's matrix is fetched once a group). The matrix's
     gradient runs at the same tile; the rows' gradient asks for its own
-    product, ``gmm_tile(m, n, k, ...)``."""
+    product, ``gmm_tile(m, n, k, ...)``.
+
+    ``live_rows``: the rows the caller expects inside groups, where the
+    group sizes sum to less than m (a share of the experts: the buffer
+    holds every pair the router made, the groups only the held ones').
+    The visits, and so the row tile, go with the live rows, not with
+    the buffer: 5,120 live rows of 81,920 over 32 experts take tm 128."""
     on_tpu = kernels_enabled() if backend is None else backend == "tpu"
     if on_mesh is None:
         on_mesh = _under_mesh()
+    live = m if live_rows is None else max(1, min(int(live_rows), m))
     rows = [t for t in _ROW_TILE_RATE if m % t == 0]
     if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
-            or k % 128 or n % 128 or not rows or m // e < min(rows)):
+            or k % 128 or n % 128 or not rows or live // e < min(rows)):
         return None
-    tm = min(rows, key=lambda t: (1 + (e - 1) * t / m) / _ROW_TILE_RATE[t])
+    tm = min(rows,
+             key=lambda t: (1 + (e - 1) * t / live) / _ROW_TILE_RATE[t])
     for tk in [t for t in (k,) + _WIDTH_TILES if t <= k and k % t == 0]:
         for tn in [t for t in (n,) + _WIDTH_TILES if t <= n and n % t == 0]:
             if _vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES:
@@ -185,7 +197,8 @@ def _visits(group_sizes, m, tm, visit_empty):
     visits of one tile and those of one expert are consecutive. V =
     m / tm + E - 1 is the most there can be (every boundary inside a
     tile adds one); the visits past nvis repeat the last one and compute
-    nothing. ``visit_empty``: an expert without rows still gets one
+    nothing. Group sizes that sum to less than m make fewer visits: no
+    tile behind the last group's is ever one's. ``visit_empty``: an expert without rows still gets one
     visit (``tgmm`` has its zeros to write)."""
     e = group_sizes.shape[0]
     tiles_m = m // tm
@@ -405,52 +418,67 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm_vjp(lhs, rhs, group_sizes, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm_vjp(lhs, rhs, group_sizes, tile, live_rows):
     return gmm(lhs, rhs, group_sizes, tile)
 
 
-def _gmm_vjp_fwd(lhs, rhs, group_sizes, tile):
+def _gmm_vjp_fwd(lhs, rhs, group_sizes, tile, live_rows):
     return gmm(lhs, rhs, group_sizes, tile), (lhs, rhs, group_sizes)
 
 
-def _gmm_vjp_bwd(_, res, g):
-    return (*grouped_matmul_grads(*res, g), None)
+def _gmm_vjp_bwd(_, live_rows, res, g):
+    return (*grouped_matmul_grads(*res, g, live_rows=live_rows), None)
+
+
+def _zero_behind(x, group_sizes):
+    """``x`` [m, ..] with zeros in the rows behind the last group (the
+    kernels write no tile there; ``ragged_dot`` has zeros there)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+    return jnp.where(rows < jnp.sum(group_sizes.astype(jnp.int32)), x,
+                     jnp.zeros_like(x))
 
 
 _gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 
-def _call_tiles(lhs, rhs):
+def _call_tiles(lhs, rhs, live_rows=None):
     """((m, k, n, e), the call's tile, the tile of its rows' gradient):
     both tiles, or neither."""
     (m, k), (e, _, n) = lhs.shape, rhs.shape
     tile = dx_tile = None
     if rhs.dtype == lhs.dtype:
-        tile = gmm_tile(m, k, n, e, lhs.dtype)
-        dx_tile = gmm_tile(m, n, k, e, lhs.dtype)
+        tile = gmm_tile(m, k, n, e, lhs.dtype, live_rows=live_rows)
+        dx_tile = gmm_tile(m, n, k, e, lhs.dtype, live_rows=live_rows)
     if tile is None or dx_tile is None:
         tile = dx_tile = None
     return (m, k, n, e), tile, dx_tile
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def grouped_matmul(lhs, rhs, group_sizes, live_rows=None):
     """``jax.lax.ragged_dot(lhs, rhs, group_sizes)``, differentiable in
     lhs and rhs: through the kernels above at the tile ``gmm_tile``
-    gives the call, else ``ragged_dot`` itself."""
-    dims, tile, _ = _call_tiles(lhs, rhs)
+    gives the call, else ``ragged_dot`` itself. ``live_rows`` (a
+    number, not traced): the group sizes may sum to less than the rows,
+    about that many are expected inside groups, and the rows behind the
+    last group come back as zeros; None: they sum to all of them."""
+    dims, tile, _ = _call_tiles(lhs, rhs, live_rows)
     _note_dispatch("fwd", *dims, tile)
     if tile is None:
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
-    return _gmm_vjp(lhs, rhs, group_sizes, tile)
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    else:
+        out = _gmm_vjp(lhs, rhs, group_sizes, tile, live_rows)
+    return out if live_rows is None else _zero_behind(out, group_sizes)
 
 
-def grouped_matmul_grads(lhs, rhs, group_sizes, g):
+def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None):
     """(d lhs, d rhs) of ``grouped_matmul(lhs, rhs, group_sizes)`` for
     the cotangent ``g`` [m, n], in the operands' dtypes: for a caller
     that saved its forward's results and does not run it again
-    (``moe_experts_grad``), and the kernels' own vjp rule."""
-    dims, tile, dx_tile = _call_tiles(lhs, rhs)
+    (``moe_experts_grad``), and the kernels' own vjp rule. ``live_rows``
+    as ``grouped_matmul``'s: d lhs has zeros behind the last group, and
+    what g or lhs hold there is never read into d rhs."""
+    dims, tile, dx_tile = _call_tiles(lhs, rhs, live_rows)
     _note_dispatch("bwd_dx", *dims, dx_tile)
     _note_dispatch("bwd_dw", *dims, tile)
     g = g.astype(lhs.dtype)
@@ -458,8 +486,12 @@ def grouped_matmul_grads(lhs, rhs, group_sizes, g):
         # jax's transposes of ragged_dot; the forward it traces is dead
         _, vjp = jax.vjp(
             lambda a, b: jax.lax.ragged_dot(a, b, group_sizes), lhs, rhs)
-        return vjp(g)
-    # the rows' gradient contracts n and is k wide: a product of its own
-    dx = gmm(g, rhs, group_sizes, dx_tile, transpose_rhs=True,
-             name="moe.gmm.bwd_dx")
-    return dx, tgmm(lhs, g, group_sizes, tile)
+        dx, dw = vjp(g)
+    else:
+        # the rows' gradient contracts n and is k wide: its own product
+        dx = gmm(g, rhs, group_sizes, dx_tile, transpose_rhs=True,
+                 name="moe.gmm.bwd_dx")
+        dw = tgmm(lhs, g, group_sizes, tile)
+    if live_rows is not None:
+        dx = _zero_behind(dx, group_sizes)
+    return dx, dw

@@ -149,3 +149,54 @@ def test_grouped_matmul_kernels_compile(case, one_chip, real_kernels):
         arg((e,), jnp.int32)).compile().as_text()
     for name in ("moe.gmm.fwd", "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"):
         assert name in text, name
+
+
+def test_grouped_query_attention_compiles(one_chip, real_kernels):
+    """Qwen3-Next's attention layer: 16 query heads over 2 key/value
+    heads of 256 at 8192 positions, one head a step at blocks of 512;
+    the dk/dv call's grid walks a group's 8 heads and writes [b, 2, t,
+    256]."""
+    b, h, hk, t, dh = 1, 16, 2, 8192, 256
+    assert fa.bhtd_tile(h, t, t, dh=dh, group=h // hk) == (1, 512, 512)
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, t, dh), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(hk), arg(hk)).compile()
+    text = compiled.as_text()
+    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
+        assert name in text, name
+    # K and V are read where they lie: nothing [b, 16, t, 256] besides
+    # q, out and their gradients
+    assert "bf16[1,2,8192,256]" in text
+
+
+def test_held_share_grouped_matmuls_compile(one_chip, real_kernels):
+    """One chip's 32 of 512 experts: a buffer of 81,920 rows of which an
+    even router fills 5,120, so the row tile is 128."""
+    m, k, n, e, live = 81920, 2048, 512, 32, 5120
+    bf = jnp.bfloat16
+    tile = gm.gmm_tile(m, k, n, e, bf, "tpu", False, live_rows=live)
+    dx_tile = gm.gmm_tile(m, n, k, e, bf, "tpu", False, live_rows=live)
+    assert tile == (128, 2048, 512) and dx_tile == (128, 512, 2048)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def three(lhs, rhs, g, sizes):
+        return (gm.gmm(lhs, rhs, sizes, tile),
+                gm.gmm(g, rhs, sizes, dx_tile, transpose_rhs=True,
+                       name="moe.gmm.bwd_dx"),
+                gm.tgmm(lhs, g, sizes, tile))
+
+    text = jax.jit(three).lower(
+        arg((m, k), bf), arg((e, k, n), bf), arg((m, n), bf),
+        arg((e,), jnp.int32)).compile().as_text()
+    for name in ("moe.gmm.fwd", "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"):
+        assert name in text, name

@@ -90,6 +90,53 @@ def test_moe_phase_fails_when_no_call_takes_a_tile(telemetry):
                              top_k=2)
 
 
+GDN_TINY = dict(
+    vocab_size=50, hidden_size=128, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=128, linear_key_head_dim=128,
+    linear_value_head_dim=128, linear_num_key_heads=1,
+    linear_num_value_heads=2, moe_intermediate_size=128,
+    shared_expert_intermediate_size=128, num_experts=8,
+    held_experts=(0, 4), num_experts_per_tok=2)
+
+
+def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters: a period of
+    three delta-rule layers and one grouped-query attention layer
+    lowers 3 + 3 chunked calls, one attention call each way that names
+    its 2 key/value heads, and 36 grouped matmuls on tiles of 128 rows;
+    on the device (here: the CPU) the chunkwise form agrees with the
+    recurrence and the kernels with the dense composition."""
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    row = chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2),
+                               width=16, gqa=(4, 2, 128), **GDN_TINY)
+    shape = "b1 t512 hk1 hv2 dk128 dv128 chunk64"
+    assert row["gdn"] == {f"chunked fwd {shape}": 3,
+                          f"chunked bwd {shape}": 3}
+    assert sorted(row["attention"]) == [
+        f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]"
+        for d in ("bwd", "fwd")]
+    assert sum(row["grouped_matmuls"].values()) == 36
+    assert set(row["rel_err"]) == {
+        "o", "dq", "dk", "dv", "dg", "dbeta", "attn_o", "attn_dq",
+        "attn_dk", "attn_dv"}
+    assert max(row["rel_err"].values()) < chip_smoke.GDN_REL_TOL
+
+
+def test_gdn_phase_fails_on_a_recurrent_call(telemetry, monkeypatch):
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    with pytest.raises(chip_smoke.SmokeFailure, match="none recurrent"):
+        chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2), width=16,
+                             gqa=(4, 2, 128), gdn_impl="recurrent",
+                             **GDN_TINY)
+
+
 @pytest.mark.parametrize("dropout,tol", [
     (0.1, chip_smoke.DP_DROPOUT_LOSS_REL_TOL),  # masks drawn per shard
     (0.0, chip_smoke.DP_LOSS_REL_TOL)])         # the same math

@@ -129,6 +129,47 @@ def test_bhtd_causal_at_16_heads_of_128_matches_dense(t, tile):
             np.asarray(a, np.float32), np.asarray(b_, np.float32), atol=0.05)
 
 
+@pytest.mark.parametrize("t", [8192, 1024])
+def test_grouped_query_attention_at_heads_of_256_matches_dense(t):
+    # Qwen3-Next's attention layer (models/qwen3_next.py: q [b, 16, t,
+    # 256], K and V [b, 2, t, 256], causal): one query head a grid step,
+    # its key/value head picked by the index maps (q head // 8), dk and
+    # dv summed over a group's 8 heads inside the dk/dv kernel. The dense
+    # composition copies K and V eight times.
+    b, h, hk, dh = 1, 16, 2, 256
+    assert fa.bhtd_tile(h, t, t, dh=dh, group=h // hk) == (1, 512, 512)
+    r = np.random.RandomState(6)
+    q = jnp.asarray(r.normal(0, 0.5, (b, h, t, dh))).astype(jnp.bfloat16)
+    k, v = (jnp.asarray(r.normal(0, 0.5, (b, hk, t, dh))).astype(
+        jnp.bfloat16) for _ in range(2))
+    w = jnp.asarray(r.normal(0, 1, (b, h, t, dh)).astype(np.float32))
+
+    def f(q, k, v):
+        o, _ = fa.flash_attention_with_lse(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def ref(q, k, v):
+        # a query head at a time: 16 heads' [t, t] scores at 8192 are 4 GB
+        def one(i):
+            kv = i // (h // hk)
+            return fa._reference_attention(
+                q[:, i:i + 1], k[:, kv:kv + 1], v[:, kv:kv + 1], None,
+                1.0 / np.sqrt(dh), causal=True)
+        o = jnp.concatenate([one(i) for i in range(h)], 1)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    (_, o1), g1 = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, o2), g2 = jax.jit(jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    assert g1[1].shape == k.shape and g1[2].shape == v.shape
+    for name, a, b_ in zip(("o", "dq", "dk", "dv"), (o1, *g1), (o2, *g2)):
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        # bf16 on both sides; dk and dv sum 8 heads x up to 8192 rows
+        np.testing.assert_allclose(a, b_, atol=0.02 * np.abs(b_).max() + 0.05,
+                                   err_msg=name)
+
+
 # --- in-kernel dropout: determinism, keep-rate, exact-linear dv ---
 
 

@@ -93,6 +93,46 @@ def test_the_cells_shapes_against_ragged_dot(k, n, which):
     assert _rel(dx2, want_dx) <= REL_TOL and _rel(dw2, want_dw) <= REL_TOL
 
 
+# One chip's share of Qwen3-Next's expert layer (32 of 512 experts, top
+# 10 of 8192 tokens): a buffer of 81,920 rows of which the held experts'
+# groups fill the front, 160 rows an expert on an even router
+HELD_M, HELD_E = 81920, 32
+
+
+@pytest.mark.parametrize("which", ["even_160", "skewed", "all_rows", "none"])
+@pytest.mark.parametrize("k,n", [(2048, 512), (512, 2048)],
+                         ids=["gate_up", "down"])
+def test_a_held_share_against_ragged_dot(k, n, which):
+    r = np.random.RandomState(k + len(which))
+    sizes = {"even_160": [160] * HELD_E,
+             "skewed": (r.multinomial(5120, r.dirichlet([0.3] * HELD_E))
+                        ).tolist(),
+             "all_rows": [HELD_M // HELD_E] * HELD_E,
+             "none": [0] * HELD_E}[which]
+    live = sum(sizes)
+    bf = jnp.bfloat16
+    lhs = jnp.asarray(r.randn(HELD_M, k), bf)
+    rhs = jnp.asarray(r.randn(HELD_E, k, n) * 0.02, bf)
+    g = jnp.asarray(r.randn(HELD_M, n), bf)
+    gs = jnp.asarray(sizes, jnp.int32)
+    assert gm.gmm_tile(HELD_M, k, n, HELD_E, bf, live_rows=5120) \
+        == (128, k, n)
+    got = jax.jit(lambda a, b, s: gm.grouped_matmul(
+        a, b, s, live_rows=5120))(lhs, rhs, gs)
+    dx, dw = jax.jit(lambda a, b, s, c: gm.grouped_matmul_grads(
+        a, b, s, c, live_rows=5120))(lhs, rhs, gs, g)
+    assert not bool(jnp.any(got[live:] != 0))
+    assert not bool(jnp.any(dx[live:] != 0))
+    if not live:
+        assert not bool(jnp.any(dw != 0))
+        return
+    want, want_dx, want_dw = jax.jit(_ragged_with_grads)(
+        lhs[:live], rhs, gs, g[:live])
+    assert _rel(got[:live], want) <= REL_TOL
+    assert _rel(dx[:live], want_dx) <= REL_TOL
+    assert _rel(dw, want_dw) <= REL_TOL
+
+
 def test_the_lowered_calls_are_the_programs_kernels():
     lhs = jax.ShapeDtypeStruct((M, 2048), jnp.bfloat16)
     rhs = jax.ShapeDtypeStruct((E, 2048, 1024), jnp.bfloat16)
