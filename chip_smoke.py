@@ -331,12 +331,13 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
        Qwen3-Next-80B-A3B at its published widths, 32 of 512 experts
        held, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
        and the dispatch counters are held to what the cell must lower:
-       three delta-rule calls forward and three backward, none
-       ``recurrent``; one attention call each way at 2 key/value heads
-       of 256 with its tile; every grouped matmul of the held experts
-       on a tile chosen for 160 rows an expert (tm128), none through
-       ``ragged_dot``. ``overrides`` cut the config for the CPU tests.
-    2. On the device: the chunkwise delta rule with bf16 operands,
+       three delta-rule calls forward and three backward, all ``kernel``
+       at the configuration's chunk; one attention call each way at 2
+       key/value heads of 256 with its tile; every grouped matmul of
+       the held experts on a tile chosen for 160 rows an expert (tm128),
+       none through ``ragged_dot``. ``overrides`` cut the config for the CPU tests.
+    2. On the device: the chunkwise delta rule with bf16 operands (the
+       ``gdn.rule.*`` kernels, whose time by name a short trace gives),
        forward and its own backward, against the step-by-step
        recurrence in float32; and grouped-query attention through the
        BHTD kernels against the dense composition that copies K and V."""
@@ -384,9 +385,11 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     for direction in ("fwd", "bwd"):
         rows = {k: v for k, v in gdn.items() if f" {direction} " in k}
         check(sum(rows.values()) == n_gdn and all(
-            k.split()[0] in ("chunked", "kernel") for k in rows),
-            f"expected {n_gdn} chunked or kernel delta-rule calls {direction}"
-            f", none recurrent: {gdn}")
+            k.split()[0] == "kernel" and k.endswith(f"chunk{cfg.gdn_chunk}")
+            for k in rows),
+            f"expected {n_gdn} delta-rule calls {direction} through the "
+            f"gdn.* kernels at chunk {cfg.gdn_chunk}, none chunked, none "
+            f"recurrent: {gdn}")
     kv = f"kv{cfg.num_key_value_heads} dh{cfg.head_dim}"
     check(len(attn) == 2 and all(
         k.startswith("bhtd ") and kv in k and k.endswith("]") for k in attn),
@@ -427,8 +430,28 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
 
     errs = {}
     names = ("o", "dq", "dk", "dv", "dg", "dbeta")
-    for name, a, b in zip(names, chunked(q, k, v, g, beta, do),
-                          recurrent(q, k, v, g, beta, do)):
+    got = jax.block_until_ready(chunked(q, k, v, g, beta, do))
+    kernel_ms = {}
+    if jax.default_backend() == "tpu":
+        # the gdn.* kernels' time by name, from a trace of three calls
+        from perf import trace as perf_trace
+        trace_dir = os.path.join(os.path.dirname(REPORT_PATH), "gdn_trace")
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(3):
+            jax.block_until_ready(chunked(q, k, v, g, beta, do))
+        jax.profiler.stop_trace()
+        summary = perf_trace.reduce(perf_trace.load(
+            perf_trace.find_xplane(trace_dir))) or {}
+        kernel_ms = {
+            name: round(s_ / 3 * 1e3, 4)
+            for name, s_ in summary.get("by_kernel_s", {}).items()
+            if name.startswith("gdn.")}
+        say(f"  gdn kernels, ms a call at t{t_check} hk{hk} hv{hv}: "
+            f"{kernel_ms}")
+        check(len(kernel_ms) == 2,
+              f"expected the forward and the backward gdn.* kernel in the "
+              f"trace: {summary.get('by_kernel_s')}")
+    for name, a, b in zip(names, got, recurrent(q, k, v, g, beta, do)):
         a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
         check(bool(jnp.isfinite(a).all()), f"delta rule {name} not finite")
         errs[name] = float(jnp.abs(a - b).max()
@@ -465,7 +488,7 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
               f"grouped-query attention {name} off the dense composition "
               f"by {errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
     row = {"gdn": gdn, "attention": attn, "grouped_matmuls": gmm,
-           "gqa_tile": fa.tile_label(tile),
+           "gdn_kernel_ms": kernel_ms, "gqa_tile": fa.tile_label(tile),
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  gdn {row['rel_err']}")
     return row

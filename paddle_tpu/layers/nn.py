@@ -460,8 +460,12 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="chunked",
     write strength -> o [b, t, hv, dv]. Per value head a state S
     [dk, dv] from zero: S = exp(g_t) S; S += k_t (beta_t (v_t - S^T
     k_t))^T; o_t = S^T q_t. ``impl``: "chunked" (the chunkwise form,
-    ``chunk`` positions a scan step; a sequence the chunk does not
-    divide is padded) or "recurrent" (a step a position)."""
+    ``chunk`` positions a step; a sequence the chunk does not divide is
+    padded: as the ``gdn.rule.*`` Pallas kernels where
+    ``parallel/gated_delta_rule.gdn_tile`` gives the call a tile (bf16
+    operands, heads of 128, chunk 64, a TPU, no mesh), as XLA ops
+    elsewhere; the dispatch counter says which) or "recurrent" (a step
+    a position: the fallback a caller asks for)."""
     if impl not in ("chunked", "recurrent"):
         raise ValueError(f"gated_delta_rule: impl {impl!r}")
     helper = LayerHelper("gated_delta_rule", name=name)

@@ -33,13 +33,25 @@ operands' dtype: every use of it is a matmul operand); the grad op
 recomputes the per-chunk quantities above ONCE (parallel over chunks:
 no second run of the scan), walks the chunks backwards with the
 hand-derived transposes of the four products of a step, and sends the
-per-chunk cotangents through ``jax.vjp`` of the parallel part. Nothing
-here is a custom call, so nothing is traced twice at a cost.
+per-chunk cotangents through the transpose of the parallel part.
+
+Two writings of that chunkwise form, chosen per call by
+``parallel/gated_delta_rule.gdn_tile`` from the call's own shapes
+(never by a flag): the ``gdn.rule.fwd`` / ``gdn.rule.bwd`` Pallas
+kernels where it gives a tile (bf16 operands, dk = dv = 128, chunk 64,
+a TPU backend, no mesh: the state stays in VMEM across the chunks, each
+chunk's triangle is inverted once, nothing is staged through HBM), and
+XLA ops everywhere else (``_chunk_parts`` / ``_chunk_scan`` below, with
+``jax.vjp`` of the parallel part behind a barrier): every CPU run,
+float32 operands, other widths or chunks, a program under a mesh. The
+kernels are custom calls, which XLA cannot CSE: the grad op recomputes
+inside its one kernel and runs no kernel of the forward again.
 
 ``impl="recurrent"`` is the recurrence step by step (``lax.scan`` over
 positions, differentiated by jax): the fallback a caller asks for, never
 taken silently: ``pt_linear_attention_dispatch_total`` records the
-implementation of every lowered call.
+implementation of every lowered call (``kernel``, ``chunked`` or
+``recurrent``).
 """
 
 from __future__ import annotations
@@ -49,6 +61,7 @@ import jax.numpy as jnp
 
 from paddle_tpu import monitor as _monitor
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.parallel import gated_delta_rule as _kernels
 
 DEFAULT_CHUNK = 64
 
@@ -321,6 +334,16 @@ def _args(ins, attrs):
             float(attrs.get("epsilon", 1e-6)))
 
 
+def _kernel_tile(q, k, v, chunk):
+    """``gdn_tile``'s answer for a chunked call: the tile of the gdn.*
+    kernels, or None for the XLA ops below."""
+    if not q.dtype == k.dtype == v.dtype:
+        return None
+    _, t, hk, dk = q.shape
+    return _kernels.gdn_tile(t, hk, v.shape[2], dk, v.shape[3], chunk,
+                             q.dtype)
+
+
 @register_op("gated_delta_rule", diff_inputs=("Q", "K", "V", "G", "Beta"))
 def _gated_delta_rule(ins, attrs):
     """Q, K [b, t, hk, dk] (normalised here), V [b, t, hv, dv] (hv a
@@ -334,7 +357,12 @@ def _gated_delta_rule(ins, attrs):
         _note_dispatch("fwd", q, v, 1, impl)
         return {"Out": [recurrent_gated_delta_rule(q, k, v, g, beta, eps)],
                 "States": [jnp.zeros((1,), q.dtype)]}
-    _note_dispatch("fwd", q, v, chunk, impl)
+    tile = _kernel_tile(q, k, v, chunk)
+    _note_dispatch("fwd", q, v, chunk, "kernel" if tile else impl)
+    if tile:
+        o, states = _kernels.gated_delta_rule_fwd(q, k, v, g, beta, tile,
+                                                  eps)
+        return {"Out": [o], "States": [states]}
     parts = _chunk_parts(*_chunk_inputs(q, k, v, g, beta, chunk, eps),
                          q.dtype)
     o, states = _chunk_scan(parts, q.dtype)
@@ -345,9 +373,10 @@ def _gated_delta_rule(ins, attrs):
 @register_op("gated_delta_rule_grad", no_grad=True)
 def _gated_delta_rule_grad(ins, attrs):
     """The backward pass of ``gated_delta_rule`` from the saved States
-    (module docstring): one recomputation of the parallel part, a
-    reverse scan over chunks, and jax's transpose of the parallel
-    part. ``impl="recurrent"``: jax's vjp of the step-by-step scan."""
+    (module docstring): the ``gdn.rule.bwd`` kernel where the call has
+    a tile; else one recomputation of the parallel part, a reverse scan
+    over chunks, and jax's transpose of the parallel part.
+    ``impl="recurrent"``: jax's vjp of the step-by-step scan."""
     (q, k, v, g, beta), chunk, impl, eps = _args(ins, attrs)
     do = _x(ins, "GRAD::Out")
     if impl == "recurrent":
@@ -355,6 +384,10 @@ def _gated_delta_rule_grad(ins, attrs):
         _, vjp = jax.vjp(
             lambda *a: recurrent_gated_delta_rule(*a, eps), q, k, v, g, beta)
         grads = vjp(do.astype(v.dtype))
+    elif tile := _kernel_tile(q, k, v, chunk):
+        _note_dispatch("bwd", q, v, chunk, "kernel")
+        grads = _kernels.gated_delta_rule_bwd(
+            q, k, v, g, beta, _x(ins, "States"), do, tile, eps)
     else:
         _note_dispatch("bwd", q, v, chunk, impl)
         dtype = q.dtype

@@ -1,0 +1,547 @@
+"""Pallas TPU kernels for the chunkwise gated delta rule (the mathematics
+and the precision contract are ops/linear_attention_ops.py's module
+docstring; this is the same chunkwise form, chunk 64).
+
+``gdn.rule.fwd`` and ``gdn.rule.bwd``, one call a pass. A grid step
+works on one key head's group of value heads and ``chunks`` chunks of
+the sequence; the grid is (batch, key heads, chunk blocks) with the
+last axis ``"arbitrary"``: the state S [dk, dv] of each value head is a
+float32 VMEM scratch that lives from chunk to chunk and block to block
+and reaches HBM only as the bf16 ``States`` the backward pass reads.
+The backward kernel walks the blocks and the chunks inside one in
+reverse with dS in the scratch, and recomputes what the forward made of
+a chunk from q, k, v, g, beta and ``States``: nothing else is kept
+between the passes and no kernel runs twice.
+
+What XLA's ops cannot do and a grid step does:
+
+- **No copy in front.** A head's 128 features are one lane tile: q, k
+  [b, t, hk * dk] and v [b, t, hv * dv] are read in place by a block of
+  (rows, 128) at lane-block index ``head``; a key head serves its group
+  through the index map and dq, dk are summed over the group in VMEM.
+  The L2 normalisation, the running sum of g, every exp and mask happen
+  on the block, in float32. o alone leaves heads first, [b, hv, t, dv],
+  and is handed on as its transpose: XLA keeps the gated norm behind it
+  in that layout (as it did behind the XLA form) and the transpose costs
+  nothing, where an o [b, t, hv * dv] paid a relayout either side of the
+  norm (qwen3next-train-s8192: 24,396 -> 26,049 tokens/s for this alone;
+  dO, dq, dk, dv heads first as well: no gain or a loss; my chip runs,
+  PR 33).
+- **One inversion a chunk, applied as products.** T = (I + A)^-1 by
+  forward substitution in float32 on the VPU (row j of T is final after
+  step j - 1 and is subtracted, times A[i, j], from every row i > j),
+  for all the chunks and heads of the grid step at once: 63 steps whose
+  latency the (heads x chunks) independent matrices hide. Exact to
+  float32 rounding whatever the keys (no power of A is formed). Then
+  U = T (beta V), W = T (beta K e^G) and, backward, with dU = dV' and
+  dW = -dV' S^T: d(beta V) = T^T dU, d(beta K e^G) = T^T dW and
+  dA = -(T^T dU) U^T - (T^T dW) W^T: no transposed solve.
+- **The sequential part is two products a chunk and head**: V' = U - W S
+  and S <- e^{G_C} S + (K e^{G_C - G})^T V' (dV' and dS backward);
+  the rest of a chunk overlaps with them in the same loop body. The
+  chunks of a grid step are a ``fori_loop``, traced once: unrolled into
+  one straight line a call runs 0.55 ms sooner of 5.5 and 8.7 (forward,
+  backward, at b1 t8192 hk16 hv32 on a v5e) and costs a second of
+  tracing and lowering a layer, every process start (my chip runs,
+  PR 33: ``setup_s`` 35.7 -> 40.2 s warm over three layers).
+
+float32: g, its running sums and their exps, beta, the normalisation,
+A, T and its application (``precision=HIGHEST``), U, the state and dS.
+bf16 operands with float32 accumulation where the chunked XLA form has
+them: K K^T, Q K^T, W, Q e^G, K e^{G_C - G}, the chunk's attention, V',
+the state as an operand, and the cotangents' products.
+
+``gdn_tile`` is the one function that says tile or the chunked XLA form
+(ops/linear_attention_ops._chunk_parts / _chunk_scan, unchanged), from
+the call's own shapes, the dtype, the backend and the mesh;
+``pt_linear_attention_dispatch_total{impl}`` records its answer.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Test hook, as grouped_matmul._INTERPRET: run the kernels in
+# interpreter mode on the CPU so the suite reaches them.
+_INTERPRET = False
+
+CHUNK = 64      # the chunk the kernels are written for (gdn_chunk)
+_LANES = 128    # dk and dv: a head is one lane tile
+# Chunks a grid step: 8 chunks are 512 rows of q, k, v a block, and 8
+# rows of g and beta [.., chunks, 64] are one float32 sublane tile.
+_STEP_CHUNKS = 8
+# What a call's blocks and scratch may take of the v5e's 128 MiB of VMEM
+# (the calls raise Mosaic's scoped limit to what they need, _vmem_limit).
+_VMEM_CAP_BYTES = 48 * 2**20
+
+_F32 = jnp.float32
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def kernels_enabled() -> bool:
+    """The Pallas kernels need a TPU backend (tests reach them on CPU
+    through the interpreter)."""
+    return jax.default_backend() == "tpu" or bool(_INTERPRET)
+
+
+def _under_mesh() -> bool:
+    from paddle_tpu.core import interp
+
+    return interp.spmd_ctx() is not None
+
+
+def _vmem_bytes(heads, chunks, dk, dv):
+    """What one grid step of the backward kernel (the larger) keeps in
+    VMEM: its blocks double-buffered (q, k, dq, dk; v, dO, dv; the
+    states; g, beta and their gradients padded to a sublane tile) and
+    the scratch (A and T as [64, 128] float32 tiles a matrix, S or dS)."""
+    rows = chunks * CHUNK
+    blocks = (4 * rows * dk * 2 + 3 * rows * heads * dv * 2
+              + chunks * heads * dk * dv * 2
+              + 4 * heads * max(chunks, 8) * _LANES * 4)
+    scratch = (2 * heads * chunks * CHUNK * _LANES * 4
+               + heads * dk * dv * 4)
+    return 2 * blocks + scratch
+
+
+def _vmem_limit(heads, chunks, dk, dv):
+    """Mosaic's scoped limit for a call at this tile: the blocks and the
+    scratch, and as much again for the values of a loop body, not under
+    its default of 16 MiB."""
+    return max(16 * 2**20, 2 * _vmem_bytes(heads, chunks, dk, dv))
+
+
+def gdn_tile(t, hk, hv, dk, dv, chunk, dtype, backend=None, on_mesh=None):
+    """-> (heads, chunks): the value heads (one key head's group) and
+    the chunks of one grid step of ``gdn.rule.*``, or None where the
+    call runs as the chunked XLA form: no TPU backend (``backend``: None
+    for this process's, with the interpreter counting as one), operands
+    that are not bf16, a program under a mesh (a Mosaic call is not
+    auto-partitioned), dk or dv other than the 128 lanes, a chunk other
+    than the 64 the kernels are written for, value heads that are not a
+    multiple of the key heads, or a group too large for the VMEM cap.
+
+    The tile follows the shape, not a flag: a key head's whole group in
+    a step (its normalised q, k and their products are shared, dq and dk
+    summed in VMEM, and the heads' state chains interleave), 8 chunks a
+    step, or all of a sequence that has fewer (it is padded to a
+    multiple)."""
+    on_tpu = kernels_enabled() if backend is None else backend == "tpu"
+    if on_mesh is None:
+        on_mesh = _under_mesh()
+    if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
+            or dk != _LANES or dv != _LANES or chunk != CHUNK
+            or hk < 1 or hv % hk or t < 1):
+        return None
+    heads = hv // hk
+    chunks = min(_STEP_CHUNKS, -(-t // CHUNK))
+    if _vmem_bytes(heads, chunks, dk, dv) > _VMEM_CAP_BYTES:
+        return None
+    return heads, chunks
+
+
+# ---------------------------------------------------------------------------
+# what a grid step computes of a chunk
+# ---------------------------------------------------------------------------
+
+
+def _dot(a, b, ca, cb, precision=None):
+    """a x b contracting a's axis ``ca`` with b's ``cb``, float32 out."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=_F32)
+
+
+def _iotas():
+    shape = (CHUNK, CHUNK)
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _col(row, ii, jj):
+    """[1, C] -> [C, 1]: the row's entries down a column (a masked sum
+    over the lanes: no transpose of a 1-row array)."""
+    return jnp.sum(jnp.where(ii == jj, row, 0.0), axis=1, keepdims=True)
+
+
+def _l2(x, eps):
+    """float32 x, x / |x| and 1 / |x| over the last axis (HF's l2norm:
+    x * rsqrt(sum(x^2) + eps))."""
+    xf = x.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(xf * xf, axis=1, keepdims=True) + eps)
+    return xf * r, r
+
+
+def _gates(g_row, b_row, ii, jj):
+    """From a chunk's g and beta as rows [1, C]: the running sum of g
+    down a column, beta down a column, D = exp(G_i - G_j) for i >= j
+    (the inner where: exp of a masked, positive difference overflows),
+    exp(G), exp(G_C - G) and exp(G_C) [1, 1]."""
+    lower = ii >= jj
+    gc = jnp.sum(jnp.where(lower, g_row, 0.0), axis=1, keepdims=True)
+    gc_row = jnp.sum(jnp.where(ii <= jj, _col(g_row, ii, jj), 0.0),
+                     axis=0, keepdims=True)
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, gc - gc_row, 0.0)),
+                      0.0)
+    g_last = jnp.sum(g_row, axis=1, keepdims=True)
+    return dict(beta=_col(b_row, ii, jj), decay=decay, eg=jnp.exp(gc),
+                ekd=jnp.exp(g_last - gc), dec=jnp.exp(g_last))
+
+
+def _rows(c):
+    """The rows of chunk ``c`` in a block of q, k, v."""
+    return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+
+
+def _row(c):
+    """Chunk ``c``'s row of a block of g or beta [heads, chunks, C]."""
+    return pl.ds(c, 1)
+
+
+def _triangles(k_ref, g_ref, beta_ref, a_ref, *, heads, chunks, eps):
+    """a_ref[r * chunks + c] <- A = strictly_lower(beta_i (k_i . k_j)
+    D_ij) of every chunk c and value head r of a block."""
+    ii, jj = _iotas()
+
+    def chunk(c, carry):
+        kn, _ = _l2(k_ref[_rows(c), :], eps)
+        kb = kn.astype(k_ref.dtype)
+        kk = _dot(kb, kb, 1, 1)
+        for r in range(heads):
+            gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
+            a_ref[r * chunks + c] = jnp.where(
+                ii > jj, gt["beta"] * kk * gt["decay"], 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, None)
+
+
+def _invert(a_ref, t_ref):
+    """t_ref[m] <- (I + a_ref[m])^-1 for every strictly lower a_ref[m]
+    [C, C]: forward substitution, step j on every matrix at once (row j
+    of T, final since step j - 1, times A[i, j] leaves every row
+    i > j). Rows above the sublane tile of row j hold A[i, j] = 0:
+    skipped whole."""
+    ii, jj = _iotas()
+    t_ref[...] = jnp.broadcast_to((ii == jj).astype(_F32), t_ref.shape)
+    for j in range(CHUNK - 1):
+        r0 = j // 8 * 8
+        t_ref[:, r0:, :] = (t_ref[:, r0:, :]
+                            - a_ref[:, r0:, j:j + 1] * t_ref[:, j:j + 1, :])
+
+
+def _chunk_parts(qn, kn, v, gt, t, dtype):
+    """The module docstring's per-chunk quantities of one value head
+    from the normalised q, k [C, dk] (float32), v [C, dv], the gates
+    and T: U, W (float32), Q K^T, and Q e^G, K e^{G_C - G}, the chunk's
+    attention as matmul operands."""
+    ii, jj = _iotas()
+    beta = gt["beta"]
+    u = _dot(t, beta * v.astype(_F32), 1, 0, _HIGHEST)
+    w = _dot(t, (beta * gt["eg"]) * kn, 1, 0, _HIGHEST)
+    qk = _dot(qn.astype(dtype), kn.astype(dtype), 1, 1)
+    attn = jnp.where(ii >= jj, qk * gt["decay"], 0.0)
+    return dict(u=u, w=w, qk=qk, attn=attn.astype(dtype),
+                qg=(qn * gt["eg"]).astype(dtype),
+                kd=(kn * gt["ekd"]).astype(dtype))
+
+
+# ---------------------------------------------------------------------------
+# gdn.rule.fwd
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
+                s_ref, a_ref, t_ref, *, heads, chunks, eps, scale):
+    dtype = q_ref.dtype
+    dv = v_ref.shape[-1] // heads
+    ii, jj = _iotas()
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    _triangles(k_ref, g_ref, beta_ref, a_ref, heads=heads, chunks=chunks,
+               eps=eps)
+    _invert(a_ref, t_ref)
+
+    def chunk(c, carry):
+        rows = _rows(c)
+        qn, _ = _l2(q_ref[rows, :], eps)
+        kn, _ = _l2(k_ref[rows, :], eps)
+        qn = qn * scale
+        for r in range(heads):
+            cols = slice(r * dv, (r + 1) * dv)
+            gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
+            p = _chunk_parts(qn, kn, v_ref[rows, cols], gt,
+                             t_ref[r * chunks + c], dtype)
+            s = s_ref[r]
+            sb = s.astype(dtype)
+            states_ref[c, r] = sb
+            vn = (p["u"] - _dot(p["w"].astype(dtype), sb, 1, 0)).astype(dtype)
+            o = _dot(p["qg"], sb, 1, 0) + _dot(p["attn"], vn, 1, 0)
+            o_ref[r, rows, :] = o.astype(o_ref.dtype)
+            s_ref[r] = s * gt["dec"] + _dot(p["kd"], vn, 0, 0)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, None)
+
+
+def _padded(x, axis, size):
+    if x.shape[axis] == size:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, size - x.shape[axis])
+    return jnp.pad(x, pad)
+
+
+def _gate_rows(x, n_chunks):
+    """g or beta [b, t, hv] -> float32 [b, hv, n, C], zeros behind t."""
+    x = jnp.moveaxis(x.astype(_F32), 2, 1)
+    b, hv, _ = x.shape
+    return _padded(x, 2, n_chunks * CHUNK).reshape(b, hv, n_chunks, CHUNK)
+
+
+def _operands(q, k, v, g, beta, tile):
+    """The op's inputs as the kernels' blocks read them: the heads
+    folded into the lanes (no copy), the gates chunked and heads first
+    (a megabyte), everything padded to whole grid steps with zeros
+    (beta 0 writes nothing, g 0 forgets nothing, q 0 reads nothing)."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    chunks = tile[1]
+    n = -(-t // CHUNK)
+    n_pad = -(-n // chunks) * chunks
+    tp = n_pad * CHUNK
+    return (_padded(q, 1, tp).reshape(b, tp, hk * dk),
+            _padded(k, 1, tp).reshape(b, tp, hk * dk),
+            _padded(v, 1, tp).reshape(b, tp, hv * dv),
+            _gate_rows(g, n_pad), _gate_rows(beta, n_pad)), n, n_pad
+
+
+def _specs(heads, chunks, dk, dv, blk):
+    """BlockSpecs of (q or k [b, t, hk * dk], v-like [b, t, hv * dv], o
+    [b, hv, t, dv], gate-like [b, hv, n, C], states) for a grid (batch,
+    key head, chunk block) whose block index along the sequence is
+    ``blk(c)``."""
+    rows = chunks * CHUNK
+    return (pl.BlockSpec((None, rows, dk), lambda i, h, c: (i, blk(c), h)),
+            pl.BlockSpec((None, rows, heads * dv),
+                         lambda i, h, c: (i, blk(c), h)),
+            pl.BlockSpec((None, heads, rows, dv),
+                         lambda i, h, c: (i, h, blk(c), 0)),
+            pl.BlockSpec((None, heads, chunks, CHUNK),
+                         lambda i, h, c: (i, h, blk(c), 0)),
+            pl.BlockSpec((chunks, None, heads, dk, dv),
+                         lambda i, h, c: (blk(c), i, h, 0, 0)))
+
+
+def _scratch(heads, chunks, dk, dv):
+    """S or dS; A and T of the block's chunks and heads."""
+    tri = pltpu.VMEM((heads * chunks, CHUNK, CHUNK), _F32)
+    return [pltpu.VMEM((heads, dk, dv), _F32), tri, tri]
+
+
+def _cost(b, hv, n, dk, dv, passes, bytes_accessed):
+    # a chunk and head: K K^T, Q K^T, T's two products, and the four of
+    # the state pass (2 dk dv + C (dk + dv) MACs a row, about), times
+    # ``passes`` (1 forward, 3 backward: recomputed + two transposes)
+    flops = 2 * CHUNK * (2 * CHUNK * dk + CHUNK * (dk + dv)
+                         + 3 * dk * dv + CHUNK * dv)
+    return pl.CostEstimate(
+        flops=passes * b * hv * n * flops,
+        transcendentals=b * hv * n * (CHUNK * CHUNK + 3 * CHUNK),
+        bytes_accessed=bytes_accessed)
+
+
+def gated_delta_rule_fwd(q, k, v, g, beta, tile, eps=1e-6):
+    """q, k [b, t, hk, dk], v [b, t, hv, dv] (bf16), g, beta [b, t, hv]
+    -> (o [b, t, hv, dv] in v's dtype, states [n, b, hv, dk, dv] in q's:
+    the state each of the n = ceil(t / 64) chunks started from).
+    ``tile``: ``gdn_tile``'s answer for the call."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    heads, chunks = tile
+    assert heads * hk == hv and dk == _LANES and dv == _LANES, (q.shape,
+                                                                 v.shape)
+    (q2, k2, v2, g4, b4), n, n_pad = _operands(q, k, v, g, beta, tile)
+    qk_spec, v_spec, o_spec, gate_spec, st_spec = _specs(
+        heads, chunks, dk, dv, lambda c: c)
+    item = jnp.dtype(q.dtype).itemsize
+    o, states = pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads, chunks=chunks, eps=eps,
+                          scale=dk ** -0.5),
+        name="gdn.rule.fwd",
+        out_shape=(jax.ShapeDtypeStruct((b, hv, n_pad * CHUNK, dv), v.dtype),
+                   jax.ShapeDtypeStruct((n_pad, b, hv, dk, dv), q.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=(b, hk, n_pad // chunks),
+            in_specs=[qk_spec, qk_spec, v_spec, gate_spec, gate_spec],
+            out_specs=(o_spec, st_spec),
+            scratch_shapes=_scratch(heads, chunks, dk, dv)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv)),
+        cost_estimate=_cost(
+            b, hv, n_pad, dk, dv, 1,
+            item * (2 * q2.size + 2 * v2.size + n_pad * b * hv * dk * dv)
+            + 8 * g4.size),
+        interpret=_INTERPRET,
+    )(q2, k2, v2, g4, b4)
+    return jnp.moveaxis(o[:, :, :t], 1, 2), states[:n]
+
+
+# ---------------------------------------------------------------------------
+# gdn.rule.bwd
+# ---------------------------------------------------------------------------
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                ds_ref, a_ref, t_ref, *, heads, chunks, eps, scale):
+    dtype = q_ref.dtype
+    dv = v_ref.shape[-1] // heads
+    ii, jj = _iotas()
+    lower = ii >= jj
+    last_row = jax.lax.broadcasted_iota(
+        jnp.int32, (CHUNK, 1), 0) == CHUNK - 1
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    _triangles(k_ref, g_ref, beta_ref, a_ref, heads=heads, chunks=chunks,
+               eps=eps)
+    _invert(a_ref, t_ref)
+
+    def rowsum(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    def chunk(i, carry):
+        c = chunks - 1 - i       # a block's chunks as the blocks: in reverse
+        rows = _rows(c)
+        yq, rq = _l2(q_ref[rows, :], eps)
+        kn, rk = _l2(k_ref[rows, :], eps)
+        qn = yq * scale
+        qb, kb = qn.astype(dtype), kn.astype(dtype)
+        kk = _dot(kb, kb, 1, 1)
+        dqn = jnp.zeros_like(qn)
+        dkn = jnp.zeros_like(kn)
+        for r in range(heads):
+            cols = slice(r * dv, (r + 1) * dv)
+            gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
+            beta, decay, eg, ekd, dec = (gt[x] for x in (
+                "beta", "decay", "eg", "ekd", "dec"))
+            t = t_ref[r * chunks + c]
+            vf = v_ref[rows, cols].astype(_F32)
+            p = _chunk_parts(qn, kn, vf, gt, t, dtype)
+            u, w, qk, attn, qg, kd = (p[x] for x in (
+                "u", "w", "qk", "attn", "qg", "kd"))
+            wb = w.astype(dtype)
+            sb = states_ref[c, r]
+            ds = ds_ref[r]
+            dsb = ds.astype(dtype)
+            dob = do_ref[rows, cols]
+            # the state pass backwards: the transposes of its four
+            # products around the saved state and V' (made again)
+            vn = (u - _dot(wb, sb, 1, 0)).astype(dtype)
+            dvn = _dot(attn, dob, 0, 0) + _dot(kd, dsb, 1, 0)
+            dvnb = dvn.astype(dtype)
+            dattn = jnp.where(lower, _dot(dob, vn, 1, 1), 0.0)
+            dqg = _dot(dob, sb, 1, 1)
+            dkd = _dot(vn, dsb, 1, 1)
+            dw = -_dot(dvnb, sb, 1, 1)
+            ddec = jnp.sum(rowsum(ds * sb.astype(_F32)), axis=0,
+                           keepdims=True)
+            ds_ref[r] = (ds * dec + _dot(qg, dob, 0, 0)
+                         - _dot(wb, dvnb, 0, 0))
+            # through U = T (beta V), W = T (beta K e^G), T = (I + A)^-1
+            dru = _dot(t, dvn, 0, 0, _HIGHEST)
+            drw = _dot(t, dw, 0, 0, _HIGHEST)
+            da = jnp.where(ii > jj, -(_dot(dru, u, 1, 1, _HIGHEST)
+                                      + _dot(drw, w, 1, 1, _HIGHEST)), 0.0)
+            f = da * kk * decay
+            drw_k = rowsum(drw * kn)
+            dbeta = rowsum(f) + rowsum(dru * vf) + eg * drw_k
+            deg = beta * drw_k + rowsum(dqg * qn)
+            dekd = rowsum(dkd * kn)
+            e = beta * f + dattn * qk * decay   # d/d(G_i - G_j), i >= j
+            dgc = (rowsum(e)
+                   - _col(jnp.sum(e, axis=0, keepdims=True), ii, jj)
+                   + deg * eg - dekd * ekd)
+            dg_last = (jnp.sum(dekd * ekd, axis=0, keepdims=True)
+                       + ddec * dec)
+            dgc = dgc + jnp.where(last_row, dg_last, 0.0)
+            # G is g's running sum: dg_m = sum of dG_i over i >= m
+            dg_ref[r, _row(c), :] = jnp.sum(
+                jnp.where(lower, dgc, 0.0), axis=0, keepdims=True)
+            dbeta_ref[r, _row(c), :] = jnp.sum(
+                jnp.where(ii == jj, dbeta, 0.0), axis=0, keepdims=True)
+            dv_ref[rows, cols] = (beta * dru).astype(dv_ref.dtype)
+            dkk = (da * (beta * decay)).astype(dtype)
+            dqk = (dattn * decay).astype(dtype)
+            dqn = dqn + _dot(dqk, kb, 1, 0) + dqg * eg
+            dkn = (dkn + _dot(dqk, qb, 0, 0) + _dot(dkk, kb, 1, 0)
+                   + _dot(dkk, kb, 0, 0) + dkd * ekd + (beta * eg) * drw)
+        # through y = x rsqrt(|x|^2 + eps): dx = r (dy - y (y . dy))
+        dyq = dqn * scale
+        dq_ref[rows, :] = (rq * (dyq - yq * rowsum(yq * dyq))).astype(
+            dq_ref.dtype)
+        dk_ref[rows, :] = (rk * (dkn - kn * rowsum(kn * dkn))).astype(
+            dk_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, None)
+
+
+def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
+    """The cotangents (dq, dk, dv in the operands' dtype, dg, dbeta
+    float32, each shaped as its input) of ``gated_delta_rule_fwd`` for
+    the cotangent ``do`` of o, from the forward's ``states``."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    heads, chunks = tile
+    (q2, k2, v2, g4, b4), n, n_pad = _operands(q, k, v, g, beta, tile)
+    do2 = _padded(do.astype(v.dtype), 1, n_pad * CHUNK).reshape(v2.shape)
+    states = _padded(states, 0, n_pad)
+    last = n_pad // chunks - 1
+    qk_spec, v_spec, _, gate_spec, st_spec = _specs(
+        heads, chunks, dk, dv, lambda c: last - c)
+    item = jnp.dtype(q.dtype).itemsize
+    dq2, dk2, dv2, dg4, db4 = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, chunks=chunks, eps=eps,
+                          scale=dk ** -0.5),
+        name="gdn.rule.bwd",
+        out_shape=(jax.ShapeDtypeStruct(q2.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k2.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v2.shape, v.dtype),
+                   jax.ShapeDtypeStruct(g4.shape, _F32),
+                   jax.ShapeDtypeStruct(b4.shape, _F32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=(b, hk, last + 1),
+            in_specs=[qk_spec, qk_spec, v_spec, gate_spec, gate_spec,
+                      st_spec, v_spec],
+            out_specs=(qk_spec, qk_spec, v_spec, gate_spec, gate_spec),
+            scratch_shapes=_scratch(heads, chunks, dk, dv)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv)),
+        cost_estimate=_cost(
+            b, hv, n_pad, dk, dv, 3,
+            item * (4 * q2.size + 3 * v2.size + n_pad * b * hv * dk * dv)
+            + 16 * g4.size),
+        interpret=_INTERPRET,
+    )(q2, k2, v2, g4, b4, states, do2)
+
+    def gate(x):   # [b, hv, n, C] -> [b, t, hv]
+        return jnp.moveaxis(x.reshape(b, hv, -1)[:, :, :t], 1, 2)
+
+    return (dq2[:, :t].reshape(q.shape), dk2[:, :t].reshape(k.shape),
+            dv2[:, :t].reshape(v.shape), gate(dg4), gate(db4))

@@ -1,6 +1,6 @@
-"""The BHTD attention kernels and the experts' grouped-matmul kernels
-compile for a TPU v5e at the shapes the chip runs them at, on this
-CPU-only machine (one file for both: the worker that is handed it is
+"""The BHTD attention kernels, the experts' grouped-matmul kernels and
+the gated delta rule's kernels compile for a TPU v5e at the shapes the chip runs them at, on this
+CPU-only machine (one file for all: the worker that is handed it is
 the one that loads libtpu): the TPU's compiler is
 installed and compiles for a chip that is described, not attached
 (.claude/skills/verify/SKILL.md, "Compile for the chip without a chip").
@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.parallel import flash_attention as fa
+from paddle_tpu.parallel import gated_delta_rule as gdr
 from paddle_tpu.parallel import grouped_matmul as gm
 
 
@@ -200,3 +201,36 @@ def test_held_share_grouped_matmuls_compile(one_chip, real_kernels):
         arg((e,), jnp.int32)).compile().as_text()
     for name in ("moe.gmm.fwd", "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"):
         assert name in text, name
+
+
+# (t, hk, hv): the cell's call; a sequence of fewer chunks than a grid
+# step holds, hk = hv (the block is the whole padded sequence)
+@pytest.mark.parametrize("t,hk,hv", [(8192, 16, 32), (200, 2, 2)],
+                         ids=["qwen3next_s8192", "t200_one_step"])
+def test_gated_delta_rule_kernels_compile(t, hk, hv, one_chip, real_kernels):
+    """Qwen3-Next's DeltaNet layer as qwen3next-train-s8192 lowers it:
+    16 key and 32 value heads of 128, a key head's two value heads and
+    8 chunks of 64 a grid step, forward and the backward pass from the
+    saved states: the substitution's lane slices, the transposed
+    float32 products and the blocks' VMEM pass Mosaic."""
+    bf = jnp.bfloat16
+    tile = gdr.gdn_tile(t, hk, hv, 128, 128, 64, bf, "tpu", False)
+    assert tile == (hv // hk, min(8, -(-t // 64)))
+
+    def arg(shape, dt=bf):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def both(q, k, v, g, beta, do):
+        o, states = gdr.gated_delta_rule_fwd(q, k, v, g, beta, tile)
+        return o, gdr.gated_delta_rule_bwd(q, k, v, g, beta, states, do,
+                                           tile)
+
+    qk, v = arg((1, t, hk, 128)), arg((1, t, hv, 128))
+    gate = arg((1, t, hv), jnp.float32)
+    text = jax.jit(both).lower(qk, qk, v, gate, gate, v).compile().as_text()
+    for name in ("gdn.rule.fwd", "gdn.rule.bwd"):
+        assert name in text, name
+    # no copy in front: q, k, v are read where they lie, nothing float32
+    # is staged heads-first or repeated to the value heads (o alone
+    # leaves heads-first, in bf16)
+    assert f"f32[1,{hv},{t},128]" not in text

@@ -103,19 +103,22 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
         telemetry, monkeypatch):
     """The phase at a cut config through the interpreters: a period of
     three delta-rule layers and one grouped-query attention layer
-    lowers 3 + 3 chunked calls, one attention call each way that names
-    its 2 key/value heads, and 36 grouped matmuls on tiles of 128 rows;
-    on the device (here: the CPU) the chunkwise form agrees with the
+    lowers 3 + 3 delta-rule calls through the gdn.* kernels, one
+    attention call each way that names its 2 key/value heads, and 36
+    grouped matmuls on tiles of 128 rows; on the device (here: the CPU,
+    at a width that gets no tile) the chunkwise form agrees with the
     recurrence and the kernels with the dense composition."""
+    from paddle_tpu.parallel import gated_delta_rule as gdr
     from paddle_tpu.parallel import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(gdr, "_INTERPRET", True)
     row = chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2),
                                width=16, gqa=(4, 2, 128), **GDN_TINY)
     shape = "b1 t512 hk1 hv2 dk128 dv128 chunk64"
-    assert row["gdn"] == {f"chunked fwd {shape}": 3,
-                          f"chunked bwd {shape}": 3}
+    assert row["gdn"] == {f"kernel fwd {shape}": 3,
+                          f"kernel bwd {shape}": 3}
     assert sorted(row["attention"]) == [
         f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]"
         for d in ("bwd", "fwd")]
@@ -126,15 +129,19 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert max(row["rel_err"].values()) < chip_smoke.GDN_REL_TOL
 
 
-def test_gdn_phase_fails_on_a_recurrent_call(telemetry, monkeypatch):
+@pytest.mark.parametrize("why,overrides", [
+    ("recurrent", dict(gdn_impl="recurrent")),      # the caller's fallback
+    ("chunked", {})])                 # no tile: the kernels are off here
+def test_gdn_phase_fails_on_a_call_without_the_kernel(
+        why, overrides, telemetry, monkeypatch):
     from paddle_tpu.parallel import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    with pytest.raises(chip_smoke.SmokeFailure, match="none recurrent"):
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="none chunked, none recurrent"):
         chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2), width=16,
-                             gqa=(4, 2, 128), gdn_impl="recurrent",
-                             **GDN_TINY)
+                             gqa=(4, 2, 128), **overrides, **GDN_TINY)
 
 
 @pytest.mark.parametrize("dropout,tol", [

@@ -1,0 +1,232 @@
+"""The gated delta rule's Pallas kernels
+(paddle_tpu/parallel/gated_delta_rule.py) on the CPU through the Pallas
+interpreter, at dk = dv = 128 and a few chunks: against the float32
+recurrence and its ``jax.vjp`` (Out and all five gradients), against the
+chunked XLA form they replace (``States``), the picker's table, the
+dispatch counter, and the op through a Program under AMP. The chip's
+run of the cell's shapes is tests/test_gated_delta_rule_tpu.py.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import flags, layers, monitor
+from paddle_tpu.backward import append_backward
+from paddle_tpu.ops import linear_attention_ops as L
+from paddle_tpu.parallel import gated_delta_rule as gdr
+
+BF, F32 = jnp.bfloat16, jnp.float32
+NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setattr(gdr, "_INTERPRET", True)
+
+
+def operands(t, hk, hv, seed=0, dtype=BF, b=1):
+    r = np.random.RandomState(seed)
+    q, k = (jnp.asarray(r.randn(b, t, hk, 128), dtype) for _ in "qk")
+    v, do = (jnp.asarray(r.randn(b, t, hv, 128), dtype) for _ in "vd")
+    return (q, k, v, -jnp.asarray(r.rand(b, t, hv) * 0.5, F32),
+            jnp.asarray(r.rand(b, t, hv), F32), do)
+
+
+def through_the_op(q, k, v, g, beta, do, **attrs):
+    """(Out, the five gradients), States: the registered op and its
+    grad op, as the Program runs them."""
+    ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
+    out = L._gated_delta_rule(ins, attrs)
+    grads = L._gated_delta_rule_grad(
+        {**ins, "States": out["States"], "GRAD::Out": [do]}, attrs)
+    return (out["Out"][0], *(grads[f"GRAD::{s}"][0] for s in (
+        "Q", "K", "V", "G", "Beta"))), out["States"][0]
+
+
+def recurrence(q, k, v, g, beta, do):
+    """The float32 recurrence on the operands as given, and jax's vjp."""
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(L.recurrent_gated_delta_rule, q.astype(F32),
+                           k.astype(F32), v.astype(F32), g, beta)
+        return (out, *vjp(do.astype(F32)))
+
+
+def rel(a, b):
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    assert np.isfinite(a).all()
+    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-6))
+
+
+# (positions, key heads, value heads): one chunk; several chunks in one
+# grid step; a padded last chunk; more chunks than a grid step holds,
+# padded to two steps; hk = hv and hv = 2 hk
+CASES = [(64, 1, 1), (256, 1, 2), (150, 1, 2), (600, 1, 1), (128, 2, 2),
+         (192, 2, 4)]
+
+
+@pytest.mark.parametrize("t,hk,hv", CASES)
+def test_kernels_are_the_recurrence(t, hk, hv, interpreted):
+    """bf16 operands, so to bf16's rounding of the chunk's matmuls: the
+    chunked XLA form reads 0.003-0.006 of the largest entry here."""
+    args = operands(t, hk, hv, seed=t)
+    assert gdr.gdn_tile(t, hk, hv, 128, 128, 64, BF) == (
+        hv // hk, min(8, -(-t // 64)))
+    got, states = through_the_op(*args, chunk=64)
+    assert states.shape == (-(-t // 64), 1, hv, 128, 128)
+    assert states.dtype == BF and got[0].dtype == BF
+    assert got[4].dtype == F32 and got[5].dtype == F32
+    for name, a, b in zip(NAMES, got, recurrence(*args)):
+        assert a.shape == b.shape, name
+        assert rel(a, b) < 0.012, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("t,hk,hv", [(150, 1, 2), (600, 1, 1)])
+def test_float32_operands_show_the_same_mathematics(t, hk, hv, interpreted):
+    """The kernels' algebra without bf16's rounding (the picker gives
+    float32 operands no tile: called directly): the recurrence to
+    float32's rounding, every gradient."""
+    args = operands(t, hk, hv, seed=1, dtype=F32)
+    q, k, v, g, beta, do = args
+    tile = gdr.gdn_tile(t, hk, hv, 128, 128, 64, BF)
+    with jax.default_matmul_precision("highest"):
+        o, states = gdr.gated_delta_rule_fwd(q, k, v, g, beta, tile)
+        grads = gdr.gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile)
+    for name, a, b in zip(NAMES, (o, *grads), recurrence(*args)):
+        assert rel(a, b) < 2e-5, (name, rel(a, b))
+
+
+def test_states_are_the_chunked_forms(interpreted, monkeypatch):
+    args = operands(256, 1, 2, seed=2)
+    got, states = through_the_op(*args, chunk=64)
+    monkeypatch.setattr(gdr, "_INTERPRET", False)      # no tile: XLA ops
+    want, want_states = through_the_op(*args, chunk=64)
+    assert states.shape == want_states.shape
+    assert states.dtype == want_states.dtype
+    assert rel(states, want_states) < 0.01
+    assert bool((states[0] == 0).all())
+    for name, a, b in zip(NAMES, got, want):
+        assert rel(a, b) < 0.012, (name, rel(a, b))
+
+
+def test_strong_decay_stays_finite(interpreted):
+    """g down to -21 a position (A = 16, the initialiser's largest, as
+    tests/test_linear_attention.py's g x 10.5): exp of the running sum
+    underflows inside a chunk and no masked, positive difference
+    reaches an exp."""
+    q, k, v, g, beta, do = operands(128, 1, 2, seed=3)
+    args = (q, k, v, g * 42.0, beta, do)
+    got, _ = through_the_op(*args, chunk=64)
+    for name, a, b in zip(NAMES, got, recurrence(*args)):
+        assert rel(a, b) < 0.012, (name, rel(a, b))
+
+
+def test_repeated_keys_with_beta_near_one(interpreted, monkeypatch):
+    """k_i equal inside a chunk, beta 0.98-1, almost no decay: A is
+    nearly the all-ones triangle, whose powers grow to binomial size
+    (the closed product of (I + A^{2^i}) cancels them in float32 and is
+    wrong here). The substitution forms no power: in float32 the
+    kernels sit as near the recurrence as the chunked form's triangular
+    solve does, every gradient."""
+    q, k, v, g, beta, do = operands(128, 1, 2, seed=4, dtype=F32)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    args = (q, k, v, g * 0.01, 0.98 + 0.02 * beta, do)
+    tile = gdr.gdn_tile(128, 1, 2, 128, 128, 64, BF)
+    want = recurrence(*args)
+    with jax.default_matmul_precision("highest"):
+        o, states = gdr.gated_delta_rule_fwd(*args[:5], tile)
+        got = (o, *gdr.gated_delta_rule_bwd(*args[:5], states, do, tile))
+        monkeypatch.setattr(gdr, "_INTERPRET", False)
+        chunked, _ = through_the_op(*args, chunk=64)
+    for name, a, c, b in zip(NAMES, got, chunked, want):
+        # (dg is a difference of large terms here: 1e-3 of its largest
+        # entry both ways, 1e-6 for the others)
+        assert rel(a, b) < max(2 * rel(c, b), 1e-5), (name, rel(a, b),
+                                                       rel(c, b))
+
+
+# (t, hk, hv, dk, dv, chunk, dtype, backend, on_mesh) -> tile
+PICKS = {
+    "the_cell": ((8192, 16, 32, 128, 128, 64, BF, "tpu", False), (2, 8)),
+    "hk_equals_hv": ((4096, 8, 8, 128, 128, 64, BF, "tpu", False), (1, 8)),
+    "fewer_chunks_than_a_step": ((200, 2, 4, 128, 128, 64, BF, "tpu", False),
+                                 (2, 4)),
+    "cpu_backend": ((8192, 16, 32, 128, 128, 64, BF, "cpu", False), None),
+    "under_a_mesh": ((8192, 16, 32, 128, 128, 64, BF, "tpu", True), None),
+    "float32": ((8192, 16, 32, 128, 128, 64, F32, "tpu", False), None),
+    "dk_64": ((8192, 16, 32, 64, 128, 64, BF, "tpu", False), None),
+    "dv_256": ((8192, 16, 32, 128, 256, 64, BF, "tpu", False), None),
+    "chunk_32": ((8192, 16, 32, 128, 128, 32, BF, "tpu", False), None),
+    "ragged_group": ((8192, 3, 4, 128, 128, 64, BF, "tpu", False), None),
+    "group_over_the_vmem_cap": ((8192, 1, 64, 128, 128, 64, BF, "tpu",
+                                 False), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PICKS))
+def test_the_picker_by_shape_dtype_backend_and_mesh(case):
+    args, want = PICKS[case]
+    assert gdr.gdn_tile(*args) == want
+    if want:
+        assert gdr._vmem_bytes(*want, 128, 128) <= gdr._VMEM_CAP_BYTES
+
+
+def test_no_backend_no_tile():
+    """This process's backend is the CPU and the interpreter is off:
+    every call of the suite's other files runs the chunked XLA form."""
+    assert not gdr.kernels_enabled()
+    assert gdr.gdn_tile(8192, 16, 32, 128, 128, 64, BF) is None
+
+
+def _layer_program(t, hk, hv, width, amp):
+    q, k, v, g, beta, probe = operands(t, hk, hv, seed=5, dtype=F32)
+    q, k, v, probe = (x[..., :width] for x in (q, k, v, probe))
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        def data(name, x):
+            var = layers.data(name, shape=list(x.shape), dtype="float32",
+                              append_batch_size=False)
+            var.stop_gradient = False
+            return var
+
+        o = layers.gated_delta_rule(
+            data("q", q), data("k", k), data("v", v), data("g", g),
+            data("beta", beta), chunk=64, impl="chunked")
+        loss = layers.reduce_sum(layers.elementwise_mul(o, data("p", probe)))
+        append_backward(loss)
+    main._amp = amp
+    feed = dict(q=q, k=k, v=v, g=g, beta=beta, p=probe)
+    return fluid.Executor().run(
+        main, feed={n: np.asarray(x) for n, x in feed.items()},
+        scope=fluid.Scope(),
+        fetch_list=[o] + [f"{n}@GRAD" for n in ("q", "k", "v", "g", "beta")])
+
+
+def test_through_the_program_under_amp_and_the_counter(interpreted):
+    """AMP casts Q, K, V to bf16 and keeps G, Beta: the call gets a
+    tile, both passes, and the counter says ``kernel`` with one chunk
+    value; the same program in float32, and one at a width off the
+    lanes, run the XLA ops and say ``chunked``."""
+    monitor.reset()   # the counter is the process's, not this file's
+    flags.set_flags({"telemetry": True})
+    try:
+        got = _layer_program(130, 1, 2, 128, amp=True)
+        want = _layer_program(130, 1, 2, 128, amp=False)
+        _layer_program(130, 1, 2, 64, amp=True)
+        counts = L.dispatch_counts()
+    finally:
+        flags.set_flags({"telemetry": False})
+        monitor.reset()
+    wide, narrow = ("b1 t130 hk1 hv2 dk%d dv%d" % (w, w) for w in (128, 64))
+    assert counts == {f"kernel fwd {wide} chunk64": 1,
+                      f"kernel bwd {wide} chunk64": 1,
+                      f"chunked fwd {wide} chunk64": 1,
+                      f"chunked bwd {wide} chunk64": 1,
+                      f"chunked fwd {narrow} chunk64": 1,
+                      f"chunked bwd {narrow} chunk64": 1}
+    assert got[0].dtype.itemsize == 2
+    assert got[4].dtype == np.float32 and got[5].dtype == np.float32
+    for name, a, b in zip(NAMES, got, want):
+        assert rel(a, b) < 0.03, (name, rel(a, b))

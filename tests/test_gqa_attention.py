@@ -99,6 +99,8 @@ def test_sdpa_op_names_the_key_value_heads(interpreted):
              "is_test": True}
     from paddle_tpu.core import interp
 
+    # (the counter is the process's: another file's rows may be in it)
+    monitor.reset()
     flags.set_flags({"telemetry": True})
     tok = interp.set_amp_active(False)
     try:
