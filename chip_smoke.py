@@ -10,7 +10,12 @@ points a user calls:
    a shape a ROADMAP cell sits on; and the experts' grouped matmuls
    (``layers.topk_moe`` at OLMoE's widths, 65,536 routed rows) through
    the ``moe.*`` kernels agree, forward and every gradient, with the
-   same program through ``jax.lax.ragged_dot``;
+   same program through ``jax.lax.ragged_dot``; the ``gdn`` and ``mla``
+   phases lower the cells ``qwen3next-train-s8192`` and
+   ``joyai-train-s4096`` and hold their dispatch rows (the delta rule
+   and grouped-query attention; latent attention at queries and keys
+   of 192 over values of 128, sigmoid routers with a selection bias)
+   and run the new kernels against their plain forms;
 2. train   — ``T.build`` + ``Adam.minimize`` under bf16 AMP, a few
    ``Executor.run`` steps and one ``Executor.run_steps`` window at
    b=64 s=256 with dropout 0.1 (no OOM back-off: full batch or fail);
@@ -315,6 +320,68 @@ def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
     return row
 
 
+def _lower_train_step(main, loss, seq):
+    """Lower (not run) the train step of a language-model program whose
+    feeds are ``input_ids`` and ``labels`` [1, seq], as Executor.run
+    would: the dispatch counters then hold what the step lowers."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.core import lowering
+    from paddle_tpu.executor import Executor
+
+    low = lowering.lower_block(main, 0, ("input_ids", "labels"), (loss.name,))
+    block = main.global_block()
+
+    def aval(name):
+        var = block._find_var_recursive(name)
+        dtype = jnp.dtype(var.dtype)
+        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(
+            {"int64": "int32", "float64": "float32"}.get(dtype.name,
+                                                         dtype.name)))
+
+    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    Executor._jit_for(low, None).lower(
+        {n: aval(n) for n in low.state_in_names},
+        {"input_ids": ids, "labels": ids},
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((), jnp.uint32))
+
+
+def _bhtd_against_dense(q, k, v, g, errs, what):
+    """The BHTD kernels, forward and the three gradients, against the
+    dense composition (causal, scale 1 / sqrt of q's width): each
+    result's largest difference over the composition's largest into
+    ``errs`` under ``attn_o`` .. ``attn_dv``, held to KERNEL_REL_TOL."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import flash_attention as fa
+
+    @jax.jit
+    def kernels(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=True)
+        return (out, *fa.flash_attention_bwd(q, k, v, None, None, out, lse,
+                                             g, causal=True))
+
+    @jax.jit
+    def dense(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
+            q, k, v, None, q.shape[-1] ** -0.5, causal=True).astype(q.dtype),
+            q, k, v)
+        return (out, *vjp(g))
+
+    for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
+                          kernels(q, k, v, g), dense(q, k, v, g)):
+        check(a.shape == b.shape, f"{what} {name}: {a.shape} != {b.shape}")
+        a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= KERNEL_REL_TOL,
+              f"{what} {name} off the dense composition by "
+              f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
+
+
 def gdn_dispatch():
     """{"impl pass shape chunk<C>": calls}: the gated delta-rule calls
     lowered so far (pt_linear_attention_dispatch_total)."""
@@ -345,8 +412,6 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     import jax.numpy as jnp
 
     import paddle_tpu as fluid
-    from paddle_tpu.core import lowering
-    from paddle_tpu.executor import Executor
     from paddle_tpu.models import qwen3_next as M
     from paddle_tpu.ops import linear_attention_ops as la
     from paddle_tpu.parallel import flash_attention as fa
@@ -360,23 +425,7 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
         fluid.optimizer.Adam(1e-4).minimize(model["loss"])
     main._amp = True
     before = (attention_dispatch(), gmm_dispatch(), gdn_dispatch())
-    low = lowering.lower_block(main, 0, ("input_ids", "labels"),
-                               (model["loss"].name,))
-    block = main.global_block()
-
-    def aval(name):
-        var = block._find_var_recursive(name)
-        dtype = jnp.dtype(var.dtype)
-        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(
-            {"int64": "int32", "float64": "float32"}.get(dtype.name,
-                                                         dtype.name)))
-
-    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
-    Executor._jit_for(low, None).lower(
-        {n: aval(n) for n in low.state_in_names},
-        {"input_ids": ids, "labels": ids},
-        jax.ShapeDtypeStruct((2,), jnp.uint32),
-        jax.ShapeDtypeStruct((), jnp.uint32))
+    _lower_train_step(main, model["loss"], seq)
     attn, gmm, gdn = (_dispatch_since(b, read) for b, read in zip(
         before, (attention_dispatch, gmm_dispatch, gdn_dispatch)))
     n_gdn = sum(not cfg.is_full_attention(i)
@@ -466,31 +515,101 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     qa = jnp.asarray(r.randn(1, h, t_check, dh) * 0.3, bf)
     ka = jnp.asarray(r.randn(1, hkv, t_check, dh) * 0.3, bf)
     va, ga = (jnp.asarray(r.randn(1, n, t_check, dh), bf) for n in (hkv, h))
-
-    @jax.jit
-    def kernels(q, k, v, g):
-        out, lse = fa.flash_attention_fwd(q, k, v, causal=True)
-        return (out, *fa.flash_attention_bwd(q, k, v, None, None, out, lse,
-                                             g, causal=True))
-
-    @jax.jit
-    def dense(q, k, v, g):
-        out, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
-            q, k, v, None, dh ** -0.5, causal=True).astype(q.dtype), q, k, v)
-        return (out, *vjp(g))
-
-    for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
-                          kernels(qa, ka, va, ga), dense(qa, ka, va, ga)):
-        a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
-        check(errs[name] <= KERNEL_REL_TOL,
-              f"grouped-query attention {name} off the dense composition "
-              f"by {errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
+    _bhtd_against_dense(qa, ka, va, ga, errs, "grouped-query attention")
     row = {"gdn": gdn, "attention": attn, "grouped_matmuls": gmm,
            "gdn_kernel_ms": kernel_ms, "gqa_tile": fa.tile_label(tile),
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  gdn {row['rel_err']}")
+    return row
+
+
+def router_dispatch():
+    """{"score=.. bias=.. k=.. experts=..": calls}: the moe_router calls
+    lowered so far (pt_moe_router_dispatch_total)."""
+    from paddle_tpu import monitor
+
+    rows = monitor.snapshot().get("pt_moe_router_dispatch_total", {})
+    out = {}
+    for r in rows.get("values", []):
+        key = " ".join(f"{k}={r['labels'].get(k, '?')}"
+                       for k in ("score", "bias", "k", "experts"))
+        out[key] = out.get(key, 0) + int(r["value"])
+    return out
+
+
+def mla_phase(seq=4096, t_check=1024, heads=4, **overrides):
+    """The latent-attention decoder's new mechanisms
+    (models/joyai_flash.py).
+
+    1. The cell ``joyai-train-s4096``'s train step (the dense layer,
+       four expert layers and the MTP module of JoyAI-LLM-Flash at its
+       published widths, 16 of 256 experts held, bf16 AMP, Adam) is
+       LOWERED, not run (perf/run.py runs it), and the dispatch
+       counters are held to what the cell must lower: one attention
+       call a block each way through the BHTD kernels at queries and
+       keys of 192 over values of 128 with its tile, none dense; every
+       router in its sigmoid form with a selection bias; every grouped
+       matmul of the held experts on a tile chosen for 128 rows an
+       expert (tm128), none through ``ragged_dot``. ``overrides`` cut
+       the config for the CPU tests.
+    2. On the device: the BHTD kernels at the model's two widths,
+       forward and the three gradients, against the dense composition."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import joyai_flash as M
+    from paddle_tpu.parallel import flash_attention as fa
+
+    cfg = M.JoyaiFlashConfig(**{**dict(
+        num_hidden_layers=5, vocab_size=16160, held_experts=(0, 16)),
+        **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    reads = (attention_dispatch, gmm_dispatch, router_dispatch)
+    before = [read() for read in reads]
+    _lower_train_step(main, model["loss"], seq)
+    attn, gmm, routers = (_dispatch_since(b, read)
+                          for b, read in zip(before, reads))
+    say(f"  lowered: attention {attn}; routers {routers}; grouped "
+        f"matmuls {gmm}")
+    blocks = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    n_moe = blocks - cfg.first_k_dense_replace
+    dk, dv = cfg.qk_head_dim, cfg.v_head_dim
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == blocks and all(
+            k.startswith("bhtd ") and f" dk{dk} dv{dv} [" in k
+            for k in rows),
+            f"expected {blocks} bhtd attention calls {direction} at "
+            f"dk{dk} dv{dv} with their tile, none dense: {attn}")
+    want = (f"score=sigmoid bias=1 k={cfg.num_experts_per_tok} "
+            f"experts={cfg.n_routed_experts}")
+    # (a router's grad op runs it again: a row counts both lowerings)
+    check(set(routers) == {want} and routers[want] >= n_moe,
+          f"expected {n_moe} routers lowered as {want}: {routers}")
+    check(sum(gmm.values()) == 9 * n_moe and all(
+        "[tm128 " in k for k in gmm),
+        f"expected {9 * n_moe} grouped matmuls on a tile of 128 rows "
+        f"(128 rows an expert), none through ragged_dot: {gmm}")
+
+    # --- on the device ----------------------------------------------------
+    r = np.random.RandomState(5)
+    bf = jnp.bfloat16
+    tile = fa.bhtd_tile(heads, t_check, t_check, dh=dk, dv=dv)
+    check(tile is not None, f"no bhtd tile for h{heads} dk{dk} dv{dv}")
+    qa, ka = (jnp.asarray(r.randn(1, heads, t_check, dk) * 0.3, bf)
+              for _ in "qk")
+    va, ga = (jnp.asarray(r.randn(1, heads, t_check, dv), bf) for _ in "vg")
+    errs = {}
+    _bhtd_against_dense(qa, ka, va, ga, errs, "latent attention")
+    row = {"attention": attn, "routers": routers, "grouped_matmuls": gmm,
+           "tile": fa.tile_label(tile),
+           "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+    say(f"  mla {row['rel_err']}")
     return row
 
 
@@ -910,6 +1029,7 @@ def main() -> int:
           f"calls, expected its nine grouped matmuls")
 
     report["gdn"], _ = phase("gdn", gdn_phase)
+    report["mla"], _ = phase("mla", mla_phase)
 
     # 2. train: the step and the window contain the kernels, and no
     # attention call fell to the dense composition

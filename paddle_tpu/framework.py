@@ -583,6 +583,12 @@ class Program:
         self._op_role = "fwd"
         # populated by append_backward: {param_name: grad_name}
         self._param_grad_map: Dict[str, str] = {}
+        # state a training step moves without a gradient (a router's
+        # selection bias): op specs a layer leaves here, which
+        # Optimizer.apply_gradients appends behind the parameters'
+        # updates under role "opt". A program without an optimizer (an
+        # eval clone) never runs them.
+        self._step_updates: List[Dict[str, Any]] = []
         # version-keyed def-use index cache (analysis.DefUseIndex per
         # block); every _bump_version invalidates it implicitly
         self._def_use_cache: Optional[tuple] = None
@@ -738,6 +744,8 @@ class Program:
                         op.attrs["is_test"] = True
                     if op.type == "batch_norm":
                         op.attrs["is_test"] = True
+        else:   # a training clone keeps what its optimizer will append
+            p._step_updates = [dict(u) for u in self._step_updates]
         p._bump_version()
         return p
 

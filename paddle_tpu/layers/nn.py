@@ -394,18 +394,22 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
     return out
 
 
-def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None):
+def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
+                     interleaved=False):
     """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
     may have fewer heads); position p of the sequence is p.
     ``rotary_dim``: only the first rotary_dim features of a head turn,
-    as a head of that width would, and the others pass. Returns the
-    rotated (q, k)."""
+    as a head of that width would, and the others pass.
+    ``interleaved``: feature 2i pairs with 2i + 1 (not i with
+    i + dh/2). Returns the rotated (q, k)."""
     helper = LayerHelper("rotary_embedding", name=name)
     q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
     k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
     attrs = {"theta": float(theta)}
     if rotary_dim is not None and rotary_dim != q.shape[-1]:
         attrs["rotary_dim"] = int(rotary_dim)
+    if interleaved:
+        attrs["interleaved"] = True
     helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
                      outputs={"QOut": q_out, "KOut": k_out}, attrs=attrs)
     return q_out, k_out
@@ -1388,7 +1392,9 @@ def switch_moe(input, num_experts, d_ff=None, capacity_factor=2.0,
 
 
 def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
-             param_attr=None, name=None, held=None, shared_d_ff=None):
+             param_attr=None, name=None, held=None, shared_d_ff=None,
+             shared_gate=True, score="softmax", routed_scale=1.0,
+             select_bias=False, bias_update_rate=0.001):
     """Dropless top-k Mixture-of-Experts with SwiGLU experts (OLMoE,
     arXiv:2409.02060): ``input`` [.., d] tokens -> ``(out, lb_loss,
     z_loss, expert_rows, top_i)``. out, in input's shape, is the sum
@@ -1414,7 +1420,21 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
 
     ``shared_d_ff``: a shared SwiGLU expert of that width that every
     token takes, behind a sigmoid gate of its own (Qwen3-Next):
-    out += sigmoid(x w_s) * (silu(x Wg) * (x Wu)) Wd.
+    out += sigmoid(x w_s) * (silu(x Wg) * (x Wu)) Wd;
+    ``shared_gate=False``: without the gate (DeepSeek-V3), and without
+    its parameter.
+
+    ``score="sigmoid"`` (DeepSeek-V3, arXiv:2412.19437 2.1.2): the
+    scores are sigmoid(x Wr), a pair's weight its score (renormalised
+    over the k if ``norm_topk_prob``) times ``routed_scale``, and
+    ``lb_loss`` the sequence-wise balance loss (ops/moe_ops.
+    _sigmoid_router). ``select_bias``: a float32 ``{name}_router.bias``
+    [E], zero at the start, is added to the scores for the CHOICE of
+    the k only. It takes no gradient; the optimizer appends its update
+    to the training step (op ``moe_bias_update``, role opt, under this
+    layer's ``router`` scope): b_e += ``bias_update_rate`` *
+    sign(mean(count) - count_e) from this step's choices. A program
+    with no optimizer (an eval clone) never moves it.
 
     Four ops (ops/moe_ops.py), each under a name scope of its own:
     router, dispatch, experts, combine; the shared expert's ops under a
@@ -1423,8 +1443,8 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
     ``{name}_down.w`` [E or count, d_ff, d]; ``{name}_shared_gate.w`` /
     ``_shared_up.w`` [d, shared_d_ff], ``_shared_down.w`` [shared_d_ff,
     d], ``_shared_mix.w`` [d, 1]."""
-    from paddle_tpu.framework import name_scope
-    from paddle_tpu.initializer import NormalInitializer
+    from paddle_tpu.framework import OP_NAMESCOPE_ATTR, name_scope
+    from paddle_tpu.initializer import ConstantInitializer, NormalInitializer
 
     helper = LayerHelper("topk_moe", name=name)
     d = input.shape[-1]
@@ -1452,15 +1472,37 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
         return helper.create_variable_for_type_inference(
             dtype=dtype, stop_gradient=stop_gradient)
 
+    if score not in ("softmax", "sigmoid") or (
+            score == "softmax" and (select_bias or routed_scale != 1.0)):
+        raise ValueError(f"topk_moe: score={score!r} with a selection bias "
+                         f"or a routed_scale")
     with name_scope("router"):
         top_w, top_i = var("float32"), var("int32", True)
         lb, z = var("float32"), var("float32")
-        helper.append_op(
-            "moe_router",
-            inputs={"X": input, "W": param("_router.w", [d, num_experts])},
+        router_in = {"X": input, "W": param("_router.w", [d, num_experts])}
+        router_attrs = {"k": int(top_k), "norm_topk": bool(norm_topk_prob)}
+        if score == "sigmoid":
+            router_attrs.update(score=score, routed_scale=float(routed_scale))
+        if select_bias:
+            bias = helper.create_parameter(
+                ParamAttr(name=f"{helper.name}_router.bias",
+                          initializer=ConstantInitializer(0.0),
+                          trainable=False),
+                shape=[num_experts], dtype="float32")
+            router_in["Bias"] = bias
+        router = helper.append_op(
+            "moe_router", inputs=router_in,
             outputs={"TopW": top_w, "TopI": top_i, "LBLoss": lb,
-                     "ZLoss": z},
-            attrs={"k": int(top_k), "norm_topk": bool(norm_topk_prob)})
+                     "ZLoss": z}, attrs=router_attrs)
+        if select_bias:
+            # appended by Optimizer.apply_gradients, behind the
+            # parameters' updates: nothing of the step reads the new bias
+            helper.main_program._step_updates.append(dict(
+                type="moe_bias_update",
+                inputs={"Bias": bias.name, "TopI": top_i.name},
+                outputs={"BiasOut": bias.name},
+                attrs={"gamma": float(bias_update_rate),
+                       OP_NAMESCOPE_ATTR: router.namescope}))
     with name_scope("dispatch"):
         xs = var(input.dtype)
         rows, order, slot = (var("int32", True) for _ in range(3))
@@ -1496,9 +1538,12 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
             h = elementwise_mul(silu(linear(input, shared_d_ff,
                                             "_shared_gate.w")),
                                 linear(input, shared_d_ff, "_shared_up.w"))
-            mix = sigmoid(linear(input, 1, "_shared_mix.w"))
-            out = elementwise_add(out, elementwise_mul(
-                linear(h, d, "_shared_down.w"), mix))
+            if shared_gate:
+                mix = sigmoid(linear(input, 1, "_shared_mix.w"))
+                out = elementwise_add(out, elementwise_mul(
+                    linear(h, d, "_shared_down.w"), mix))
+            else:
+                out = elementwise_add(out, linear(h, d, "_shared_down.w"))
     return out, lb, z, rows, top_i
 
 

@@ -1,0 +1,341 @@
+"""JoyAI-LLM-Flash: a decoder-only language model of DeepSeek-V3's
+shape (its ``config.json`` has DeepSeek-V3's keys; the layer equations:
+arXiv:2412.19437 sections 2.1-2.2 and HF ``modeling_deepseek_v3.py``):
+multi-head latent attention (MLA) whose queries and keys are wider than
+its values, a leading dense layer, then mixtures of experts routed by
+sigmoid scores with a selection bias beside an ungated shared expert,
+and one multi-token-prediction (MTP) module. As published (48B-A2.7B):
+
+    norm_n(x) = x * rsqrt(mean(x^2) + eps) * w                  # plain gain
+    layer i   : h = x + MLA(norm(x));  y = h + FFN_i(norm(h))
+                FFN_i = SwiGLU at ``intermediate_size`` for i <
+                first_k_dense_replace, else the MoE
+
+    MLA (h heads; nope, rope, dv = qk_nope_head_dim, qk_rope_head_dim,
+    v_head_dim):
+      c_q = norm(x Wqa);  q = c_q Wqb        -> per head [q_nope | q_rope]
+      [c_kv | k_rope] = x Wkva;  c_kv <- norm(c_kv)
+      [k_nope | v] = c_kv Wkvb               -> per head [nope | dv]
+      q_rope, k_rope <- RoPE over their ``rope`` features, pairs
+        (2i, 2i + 1); k_rope is ONE head that all h query heads share
+      q = [q_nope | q_rope], k = [k_nope | k_rope]  (nope + rope wide)
+      o = causal softmax(q k^T / sqrt(nope + rope)) v  (dv);  out = o Wo
+      (rope_scaling null: no yarn factor on the scale. HF de-interleaves
+      the rope features before a rotate-half: the scores are those above.)
+
+    MoE (E experts, top k, one group):
+      s = sigmoid_f32(x Wr);  chosen = top k of (s + b)
+      w_j = routed_scaling_factor * s_j / sum_chosen s
+      out = sum_j w_j SwiGLU_{e_j}(x) + SwiGLU_shared(x)    # no gate
+      b [E] takes no gradient; after each step b_e += gamma *
+      sign(mean(count) - count_e) (``layers.topk_moe(select_bias=True)``)
+      balance loss: the sequence-wise alpha * sum_e f_e P_e
+
+    LM  : logits = norm(y_L) Wout (untied);  L_main = mean CE(logits_i, t_{i+1})
+    MTP : h'_i = [norm_h(y_L,i) | norm_e(Emb(t_{i+1}))] Weh   # y_L before the
+          final norm; Emb is the model's own table
+          z = Layer_mtp(h')  (one MLA + MoE layer, weights of its own)
+          logits' = norm_mtp(z) Wout                      # the model's own head
+          L = L_main + lambda * mean_{i < T-1} CE(logits'_i, t_{i+2})
+              + alpha * balance losses
+
+The MTP layer runs over all T positions and the last (which has no
+t_{T+1} in the row) is left out of its loss: causal attention lets it
+change no other position. ``Emb`` and ``Wout`` are each one parameter
+with two uses; ``append_backward`` sums the two gradients.
+``held_experts=(first, count)`` builds one chip's share of every expert
+layer (``layers.topk_moe(held=...)``), the MTP module's too.
+
+Name scopes (README "Names in the device trace"): ``embed``,
+``blk<i>/attn`` with ``q_lora``, ``kv_lora``, ``rope`` (the splits, the
+rotation, the shared key head's copies and the assembly of the wide q
+and k), ``core`` (the sdpa op) and ``out`` under it, ``blk0/ffn``,
+``blk<i>/moe`` with ``router``, ``dispatch``, ``experts``, ``shared`` and
+``combine``; the MTP module ``blk_mtp/{merge,attn,moe}`` and its pass
+through the head ``loss_head/mtp``; ``final_norm``, ``loss_head``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, Optional, Tuple
+
+import numpy as np
+
+import paddle_tpu as fluid
+from paddle_tpu import layers
+from paddle_tpu.initializer import NormalInitializer
+from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.param_attr import ParamAttr
+
+# logits of the last positions a build offers (model["last_logits"] and
+# ["mtp_last_logits"]): the second check of perf/reference/joyai.py
+LAST_POSITIONS = 8
+_END = 2 ** 31 - 1   # a slice's "to the end"
+
+
+class JoyaiFlashConfig:
+    """Keys as in the model's published ``config.json`` (defaults:
+    JoyAI-LLM-Flash); ``bias_update_rate`` (gamma), ``balance_alpha``
+    and ``mtp_lambda`` are the paper's training settings (the config
+    carries none), ``held_experts`` is this builder's."""
+
+    def __init__(
+        self,
+        vocab_size: int = 129280,
+        hidden_size: int = 2048,
+        num_hidden_layers: int = 40,
+        first_k_dense_replace: int = 1,
+        intermediate_size: int = 7168,
+        num_attention_heads: int = 32,
+        q_lora_rank: int = 1536,
+        kv_lora_rank: int = 512,
+        qk_nope_head_dim: int = 128,
+        qk_rope_head_dim: int = 64,
+        v_head_dim: int = 128,
+        rope_theta: float = 3.2e7,
+        rms_norm_eps: float = 1e-6,
+        n_routed_experts: int = 256,
+        num_experts_per_tok: int = 8,
+        moe_intermediate_size: int = 768,
+        n_shared_experts: int = 1,
+        norm_topk_prob: bool = True,
+        routed_scaling_factor: float = 2.5,
+        num_nextn_predict_layers: int = 1,
+        bias_update_rate: float = 0.001,
+        balance_alpha: float = 1e-4,
+        mtp_lambda: float = 0.1,
+        held_experts: Optional[Tuple[int, int]] = None,
+    ):
+        assert num_nextn_predict_layers in (0, 1)
+        assert 0 < first_k_dense_replace <= num_hidden_layers
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.num_hidden_layers = num_hidden_layers
+        self.first_k_dense_replace = first_k_dense_replace
+        self.intermediate_size = intermediate_size
+        self.num_attention_heads = num_attention_heads
+        self.q_lora_rank = q_lora_rank
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rope_theta = rope_theta
+        self.rms_norm_eps = rms_norm_eps
+        self.n_routed_experts = n_routed_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.moe_intermediate_size = moe_intermediate_size
+        self.n_shared_experts = n_shared_experts
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.num_nextn_predict_layers = num_nextn_predict_layers
+        self.bias_update_rate = bias_update_rate
+        self.balance_alpha = balance_alpha
+        self.mtp_lambda = mtp_lambda
+        self.held_experts = tuple(held_experts) if held_experts else None
+
+    @property
+    def qk_head_dim(self):
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+def joyai_llm_flash() -> JoyaiFlashConfig:
+    return JoyaiFlashConfig()
+
+
+def _w(name):
+    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
+
+
+def _norm(x, cfg, name):
+    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps,
+                           param_attr=ParamAttr(name=f"{name}.scale"))
+
+
+def _linear(x, size, name):
+    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
+                     bias_attr=False)
+
+
+def _heads_first(z):   # [b, t, heads, dh] -> [b, heads, t, dh]
+    return layers.transpose(z, [0, 2, 1, 3])
+
+
+def _latent_attention(x, cfg: JoyaiFlashConfig, p: str):
+    h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+    xn = _norm(x, cfg, f"{p}_attn_norm")
+    with fluid.name_scope("q_lora"):
+        c_q = _norm(_linear(xn, cfg.q_lora_rank, f"{p}_attn_q_a.w"), cfg,
+                    f"{p}_attn_q_a_norm")
+        q = _linear(c_q, h * (nope + rope), f"{p}_attn_q_b_colp.w")
+    with fluid.name_scope("kv_lora"):
+        kva = _linear(xn, cfg.kv_lora_rank + rope, f"{p}_attn_kv_a.w")
+        c_kv, k_rope = layers.split(kva, [cfg.kv_lora_rank, rope], dim=-1)
+        kv = _linear(_norm(c_kv, cfg, f"{p}_attn_kv_a_norm"),
+                     h * (nope + dv), f"{p}_attn_kv_b_colp.w")
+    with fluid.name_scope("rope"):
+        q_nope, q_rope = layers.split(
+            _heads_first(layers.reshape(q, [0, 0, h, nope + rope])),
+            [nope, rope], dim=-1)
+        k_nope, v = layers.split(
+            _heads_first(layers.reshape(kv, [0, 0, h, nope + dv])),
+            [nope, dv], dim=-1)
+        # the rotary key is one head: [b, 1, t, rope]
+        q_rope, k_rope = layers.rotary_embedding(
+            q_rope, layers.unsqueeze(k_rope, [1]), theta=cfg.rope_theta,
+            interleaved=True)
+        q = layers.concat([q_nope, q_rope], axis=3)
+        k = layers.concat(
+            [k_nope, layers.expand(k_rope, [1, h, 1, 1])], axis=3)
+    with fluid.name_scope("core"):
+        helper = LayerHelper(f"{p}_attn_sdpa")
+        ctx = helper.create_variable_for_type_inference(dtype=x.dtype)
+        # logsumexp rows, consumed by the paired grad op
+        lse = helper.create_variable_for_type_inference(dtype="float32")
+        lse.stop_gradient = True
+        helper.append_op(
+            "scaled_dot_product_attention",
+            # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
+            inputs={"Q": q, "K": k, "V": v},
+            outputs={"Out": ctx, "Lse": lse},
+            attrs={"scale": 1.0 / math.sqrt(nope + rope),
+                   "dropout_prob": 0.0, "is_test": True, "layout": "bhtd",
+                   "causal": True})
+    with fluid.name_scope("out"):
+        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                             [0, 0, h * dv])
+        return _linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+
+
+def _dense_ffn(x, cfg: JoyaiFlashConfig, p: str):
+    xn = _norm(x, cfg, f"{p}_ffn_norm")
+    h = layers.elementwise_mul(
+        layers.silu(_linear(xn, cfg.intermediate_size, f"{p}_ffn_gate_colp.w")),
+        _linear(xn, cfg.intermediate_size, f"{p}_ffn_up_colp.w"))
+    return _linear(h, cfg.hidden_size, f"{p}_ffn_down_rowp.w")
+
+
+def _moe(x, cfg: JoyaiFlashConfig, p: str):
+    return layers.topk_moe(
+        _norm(x, cfg, f"{p}_moe_norm"), cfg.n_routed_experts,
+        cfg.num_experts_per_tok, cfg.moe_intermediate_size,
+        norm_topk_prob=cfg.norm_topk_prob, name=f"{p}_moe",
+        held=cfg.held_experts,
+        shared_d_ff=cfg.n_shared_experts * cfg.moe_intermediate_size,
+        shared_gate=False, score="sigmoid",
+        routed_scale=cfg.routed_scaling_factor, select_bias=True,
+        bias_update_rate=cfg.bias_update_rate)
+
+
+def _layer_body(x, cfg: JoyaiFlashConfig, p: str, dense: bool):
+    """(y, routing or None) of one decoder layer's two residual
+    branches, inside the caller's ``blk`` scope."""
+    with fluid.name_scope("attn"):
+        x = layers.elementwise_add(x, _latent_attention(x, cfg, p))
+    if dense:
+        with fluid.name_scope("ffn"):
+            return layers.elementwise_add(x, _dense_ffn(x, cfg, p)), None
+    with fluid.name_scope("moe"):
+        out, lb, _, rows, top_i = _moe(x, cfg, p)
+        return layers.elementwise_add(x, out), (lb, rows, top_i)
+
+
+def _cross_entropy(logits, labels):
+    return layers.softmax_with_cross_entropy(logits,
+                                             layers.unsqueeze(labels, [2]))
+
+
+def _last(logits):
+    return layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
+                        ends=[_END])
+
+
+def build(cfg: Optional[JoyaiFlashConfig] = None, is_test: bool = False):
+    """Language-modelling graph. Feeds: ``input_ids`` [b, t] and
+    ``labels`` [b, t] (the next token of every position; the MTP
+    module's targets, the token after that, are the labels shifted by
+    one). Every position is real: packed documents, attended across
+    their boundaries. The graph has no dropout, so ``is_test`` changes
+    nothing."""
+    cfg = cfg or joyai_llm_flash()
+    mtp = bool(cfg.num_nextn_predict_layers)
+    ids = layers.data("input_ids", shape=[-1], dtype="int64")
+    lbl = layers.data("labels", shape=[-1], dtype="int64")
+    feeds = [ids, lbl]
+    emb_attr = _w("joyai_tok_emb.w")
+
+    with fluid.name_scope("embed"):
+        x = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
+                             param_attr=emb_attr)
+    lbs, rows, top_i = [], [], []
+
+    def keep(routing):
+        lbs.append(routing[0])
+        rows.append(routing[1])
+        top_i.append(routing[2])
+
+    for i in range(cfg.num_hidden_layers):
+        with fluid.name_scope(f"blk{i}"):
+            x, routing = _layer_body(x, cfg, f"blk{i}",
+                                     dense=i < cfg.first_k_dense_replace)
+        if routing:
+            keep(routing)
+    with fluid.name_scope("final_norm"):
+        xn = _norm(x, cfg, "final_norm")
+    with fluid.name_scope("loss_head"):
+        logits = _linear(xn, cfg.vocab_size, "lm_head_colp.w")
+        lm_loss = layers.mean(_cross_entropy(logits, lbl))
+    model = {"logits": logits, "lm_loss": lm_loss}
+
+    losses = [lm_loss]
+    if mtp:
+        with fluid.name_scope("blk_mtp"):
+            with fluid.name_scope("merge"):
+                # the next token's embedding, from the model's own table
+                nxt = layers.embedding(
+                    lbl, size=[cfg.vocab_size, cfg.hidden_size],
+                    param_attr=emb_attr)
+                z = _linear(layers.concat(
+                    [_norm(x, cfg, "mtp_hnorm"),
+                     _norm(nxt, cfg, "mtp_enorm")], axis=2),
+                    cfg.hidden_size, "mtp_eh_proj.w")
+            z, routing = _layer_body(z, cfg, "mtp", dense=False)
+            keep(routing)
+            with fluid.name_scope("merge"):
+                zn = _norm(z, cfg, "mtp_final_norm")
+        with fluid.name_scope("loss_head"):
+            with fluid.name_scope("mtp"):
+                # the model's own head, a second time
+                mtp_logits = _linear(zn, cfg.vocab_size, "lm_head_colp.w")
+                # position i's second target is position i + 1's first;
+                # the row's last position has none: it runs (every
+                # kernel sees the whole row) and its loss is left out
+                lbl2 = layers.concat(
+                    [layers.slice(lbl, axes=[1], starts=[1], ends=[_END]),
+                     layers.slice(lbl, axes=[1], starts=[-1], ends=[_END])],
+                    axis=1)
+                mtp_loss = layers.mean(layers.slice(
+                    _cross_entropy(mtp_logits, lbl2), axes=[1], starts=[0],
+                    ends=[-1]))
+        losses.append(layers.scale(mtp_loss, scale=cfg.mtp_lambda))
+        model.update(mtp_logits=mtp_logits, mtp_loss=mtp_loss,
+                     mtp_last_logits=_last(mtp_logits))
+
+    with fluid.name_scope("loss_head"):
+        lb_loss = lbs[0] if len(lbs) == 1 else layers.sums(lbs)
+        losses.append(layers.scale(lb_loss, scale=cfg.balance_alpha))
+        loss = layers.sums(losses)
+        last = _last(logits)
+    model.update(feeds=feeds, loss=loss, lb_loss=lb_loss, last_logits=last,
+                 expert_rows=rows, top_i=top_i, config=cfg)
+    return model
+
+
+def make_batch(cfg: JoyaiFlashConfig, batch: int, seq_len: int,
+               seed: int = 0) -> Dict[str, np.ndarray]:
+    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
+    ``seq_len``, labels the same shifted by one."""
+    r = np.random.RandomState(seed)
+    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
+    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

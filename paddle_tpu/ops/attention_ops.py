@@ -27,7 +27,9 @@ _M_DISPATCH = _monitor.counter(
     "attention implementation chosen at trace time, by family "
     "(bthd_small / bthd_kblock / bhtd Pallas kernels, the dense jnp "
     "composition, or ring), pass (fwd/bwd), shape (one device's share "
-    "for a kernel family under a mesh), tile (heads, query rows and key "
+    "for a kernel family under a mesh; dh the one width of a head, or "
+    "\"dk192 dv128\" where queries and keys are wider than values), tile "
+    "(heads, query rows and key "
     "rows of one grid step, where the family picks them by the shape: "
     "bhtd) and replicated_over (mesh axes whose every rank repeats that "
     "same call)")
@@ -38,19 +40,23 @@ def _note_dispatch(family, direction, dims, replicated_over=()):
     if not _monitor.enabled() or not interp.lowering_active():
         return
     b, tq, tk, h, dh = dims[:5]
-    hk = dims[5] if len(dims) > 5 else h
+    # (behind them, where the call is not plain: key/value heads, dv)
+    hk, dv = dims[5:] if len(dims) > 5 else (h, dh)
     tile = ""
     if family == "bhtd":
         # the kernel layer's own answer for the call the op hands it (the
         # op passes no q_block / k_block)
         from paddle_tpu.parallel import flash_attention as fa
 
-        tile = fa.tile_label(fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk))
-    # (grouped-query attention names its key/value heads: "h16 kv2")
+        tile = fa.tile_label(fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk,
+                                          dv=dv))
+    # (grouped-query attention names its key/value heads: "h16 kv2";
+    # values narrower than queries and keys both widths: "dk192 dv128")
     heads = f"h{h}" if hk == h else f"h{h} kv{hk}"
+    width = f"dh{dh}" if dv == dh else f"dk{dh} dv{dv}"
     _M_DISPATCH.inc(labels={
         "family": family, "pass": direction,
-        "shape": f"b{b} tq{tq} tk{tk} {heads} dh{dh}", "tile": tile,
+        "shape": f"b{b} tq{tq} tk{tk} {heads} {width}", "tile": tile,
         "replicated_over": ",".join(replicated_over)})
 
 
@@ -101,18 +107,27 @@ def _attn_bias(ins, attrs):
     return {"Out": [out]}
 
 
-def _rotate(x, theta, rotary_dim=None):
+def _rotate(x, theta, rotary_dim=None, interleaved=False):
     """Rotary position embedding of x [b, h, t, dh], rotate-half form
     (Su et al. 2021 as GPT-NeoX and HF lay it out): feature i pairs
     with i + dh/2, position p turns the pair by p * theta^(-2i/dh).
     ``rotary_dim`` < dh: only the FIRST rotary_dim features turn (as a
-    head of that width would), the others pass."""
+    head of that width would), the others pass. ``interleaved``: the
+    pairs are the neighbours (2i, 2i + 1) instead (the paper's own
+    layout, DeepSeek's ``rope_interleave``), the angles the same."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         return jnp.concatenate(
-            [_rotate(x[..., :rotary_dim], theta), x[..., rotary_dim:]], -1)
+            [_rotate(x[..., :rotary_dim], theta, None, interleaved),
+             x[..., rotary_dim:]], -1)
     t, dh = x.shape[-2], x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (dh // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+        return out.reshape(x.shape).astype(x.dtype)
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
     xf = x.astype(jnp.float32)
@@ -126,12 +141,14 @@ def _rotary_embedding(ins, attrs):
     """Q, K [b, h, t, dh] (K may have fewer heads) -> the same with
     rotary positions 0..t-1 applied (attr ``theta``, the base;
     ``rotary_dim``, 0 or absent for the whole head: the leading
-    features that turn). The angles and the rotation are f32; the
-    results return to the inputs' dtype."""
+    features that turn; ``interleaved``: pairs of neighbours, not
+    rotate-half). The angles and the rotation are f32; the results
+    return to the inputs' dtype."""
     theta = float(attrs.get("theta", 10000.0))
     rd = int(attrs.get("rotary_dim", 0)) or None
-    return {"QOut": [_rotate(_x(ins, "Q"), theta, rd)],
-            "KOut": [_rotate(_x(ins, "K"), theta, rd)]}
+    il = bool(attrs.get("interleaved", False))
+    return {"QOut": [_rotate(_x(ins, "Q"), theta, rd, il)],
+            "KOut": [_rotate(_x(ins, "K"), theta, rd, il)]}
 
 
 def _sdpa_config(ins, attrs, rng):
@@ -142,13 +159,14 @@ def _sdpa_config(ins, attrs, rng):
     the in-kernel mask — is identical in both directions. ``family`` is
     the Pallas kernel family the shapes take on this backend, or "dense"
     for the jnp composition (parallel/flash_attention.py); ``dims`` is
-    (b, tq, tk, h, dh), the dispatch record's shape, with the key/value
-    heads behind it where K and V have fewer than Q (grouped-query
-    attention: BHTD layout only, no dropout, no mesh).
+    (b, tq, tk, h, dh, key/value heads, dv), the dispatch record's
+    shape (``_note_dispatch`` takes the first five alone too). K and V with fewer heads than Q (grouped-query attention) and
+    V narrower than Q and K (dv != dh: latent attention) take the BHTD
+    layout only, no dropout, no mesh.
     """
     from paddle_tpu.parallel import flash_attention as fa
 
-    q, k = _x(ins, "Q"), _x(ins, "K")
+    q, k, v = _x(ins, "Q"), _x(ins, "K"), _x(ins, "V")
     scale = attrs.get("scale", None)
     if scale is None:
         scale = 1.0 / math.sqrt(jnp.shape(q)[-1])
@@ -162,19 +180,23 @@ def _sdpa_config(ins, attrs, rng):
     if attrs.get("layout", "bhtd") == "bthd":
         b, tq, h, dh = q.shape
         tk = k.shape[1]
-        if k.shape[2] != h:
-            raise ValueError("grouped key/value heads need layout='bhtd'")
+        if k.shape[2] != h or v.shape[3] != dh:
+            raise ValueError("grouped key/value heads, and values of "
+                             "another width than the keys, need "
+                             "layout='bhtd'")
         family = fa.bthd_family(tq, tk, h, dh)
-        dims = (b, tq, tk, h, dh)
+        dims = (b, tq, tk, h, dh, h, dh)
     else:
         b, h, tq, dh = q.shape
-        tk, hk = k.shape[2], k.shape[1]
-        family = fa.bhtd_family(h, tq, tk, dh=dh, group=h // hk)
-        dims = (b, tq, tk, h, dh) + ((hk,) if hk != h else ())
-        if hk != h and (training_dropout or interp.spmd_ctx() is not None):
+        tk, hk, dv = k.shape[2], k.shape[1], v.shape[3]
+        family = fa.bhtd_family(h, tq, tk, dh=dh, group=h // hk, dv=dv)
+        dims = (b, tq, tk, h, dh, hk, dv)
+        if (hk != h or dv != dh) and (
+                training_dropout or interp.spmd_ctx() is not None):
             raise NotImplementedError(
-                "scaled_dot_product_attention: grouped key/value heads "
-                "with attention dropout or under a mesh")
+                "scaled_dot_product_attention: grouped key/value heads, "
+                "or values of another width than the keys, with "
+                "attention dropout or under a mesh")
     if not attrs.get("use_pallas", True):
         family = "dense"
     return scale, drop, seed, family, dims
@@ -268,7 +290,10 @@ def _ring_config(q, k):
              needs_rng=True)
 def _sdpa(ins, attrs, rng=None):
     """Fused attention: Q,K,V [b, h, t, dh] + optional additive Bias.
-    K and V may have fewer heads than Q (grouped-query attention: query
+    V (and Out with it) may be narrower or wider than Q and K (latent
+    attention: 192-wide queries and keys over 128-wide values; the
+    default scale is 1 / sqrt of Q's width). K and V may have fewer
+    heads than Q (grouped-query attention: query
     head i reads key/value head i // (h / kv heads)); the BHTD kernels
     pick the head in their index maps and never copy K or V.
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu import monitor as _monitor
+from paddle_tpu.core import interp
 from paddle_tpu.core.registry import register_op
 
 _ACTS = {
@@ -155,6 +156,41 @@ def record_expert_rows(layer: str, rows):
             _M_ROWS.inc(int(n), labels={"layer": layer, "expert": str(e)})
 
 
+_M_ROUTER = _monitor.counter(
+    "pt_moe_router_dispatch_total",
+    "moe_router calls lowered (trace time, telemetry on), by score "
+    "(softmax / sigmoid), bias (1 where a selection bias moves the "
+    "choice), k and experts (the router's outputs)")
+
+
+def _sigmoid_router(logits, rows, bias, attrs):
+    """The sigmoid form (DeepSeek-V3, arXiv:2412.19437 2.1.2) of logits
+    [n, E]: scores s = sigmoid(logits); the k experts are the largest of
+    s + Bias (the bias moves the CHOICE and never the weight), the
+    weights the chosen s themselves (renormalised over the k if
+    ``norm_topk``) times ``routed_scale``. -> (TopW, TopI, LBLoss), the
+    last the sequence-wise balance loss: the mean over the ``rows``
+    sequences the n tokens are of sum_e f_e P_e, f_e = E / (k t) * the
+    row's count of e, P_e the row's mean of s_e / sum_e' s_e'. One group
+    of experts only (``n_group`` 1: no group step)."""
+    if (int(attrs.get("n_group", 1)), int(attrs.get("topk_group", 1))) \
+            != (1, 1):
+        raise NotImplementedError(
+            "moe_router: experts chosen by groups first (n_group > 1)")
+    k, e = int(attrs["k"]), int(logits.shape[-1])
+    s = jax.nn.sigmoid(logits)
+    pick = s if bias is None else s + bias.astype(jnp.float32)
+    _, top_i = jax.lax.top_k(jax.lax.stop_gradient(pick), k)
+    top_w = jnp.take_along_axis(s, top_i, axis=-1)
+    if attrs.get("norm_topk", False):
+        top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
+    top_w = top_w * float(attrs.get("routed_scale", 1.0))
+    chosen = jnp.sum(jax.nn.one_hot(top_i, e, dtype=jnp.float32), axis=1)
+    f = jnp.mean(chosen.reshape(rows, -1, e), 1) * (e / k)
+    p = jnp.mean((s / jnp.sum(s, -1, keepdims=True)).reshape(rows, -1, e), 1)
+    return top_w, top_i, jnp.mean(jnp.sum(f * p, -1))
+
+
 @register_op("moe_router", diff_inputs=("X", "W"))
 def _moe_router(ins, attrs):
     """X [.., d] tokens (n of them), W [d, E] -> TopW [n, k] f32 (the softmax
@@ -162,25 +198,59 @@ def _moe_router(ins, attrs):
     TopI [n, k] int32, LBLoss [] (E * sum_e f_e * P_e: f_e the share of
     tokens that chose e, summed over the k slots; P_e the mean
     probability) and ZLoss [] (mean squared logsumexp of the logits).
+    ``score="sigmoid"`` (with the optional input Bias [E] and the attr
+    ``routed_scale``): ``_sigmoid_router``'s TopW, TopI and LBLoss, a
+    row of X [b, t, d] a sequence.
 
     Float32 whatever the activation stream: logits at the highest matmul
-    precision (on a TPU a default f32 matmul is one bf16 pass), softmax
-    and top-k in f32. Which experts a token takes is a decision, not a
-    bandwidth bound."""
+    precision (on a TPU a default f32 matmul is one bf16 pass), scores,
+    bias add and top-k in f32. Which experts a token takes is a
+    decision, not a bandwidth bound."""
     w = _x(ins, "W").astype(jnp.float32)
-    x = _x(ins, "X").astype(jnp.float32).reshape(-1, w.shape[0])
+    tokens = _x(ins, "X")
+    x = tokens.astype(jnp.float32).reshape(-1, w.shape[0])
     k = int(attrs["k"])
     e = int(w.shape[-1])
+    score, bias = attrs.get("score", "softmax"), _x(ins, "Bias")
+    if score not in ("softmax", "sigmoid") or (
+            score == "softmax" and bias is not None):
+        raise ValueError(f"moe_router: score={score!r} with"
+                         f"{'' if bias is not None else 'out'} a Bias")
+    if _monitor.enabled() and interp.lowering_active():
+        _M_ROUTER.inc(labels={
+            "score": score, "bias": str(int(bias is not None)),
+            "k": str(k), "experts": str(e)})
     logits = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, k)
-    if attrs.get("norm_topk", False):
-        top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
-    chosen = jnp.sum(jax.nn.one_hot(top_i, e, dtype=jnp.float32), axis=1)
-    lb = e * jnp.sum(jnp.mean(chosen, 0) * jnp.mean(probs, 0))
+    if score == "sigmoid":
+        top_w, top_i, lb = _sigmoid_router(
+            logits, tokens.shape[0] if tokens.ndim == 3 else 1, bias, attrs)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = jax.lax.top_k(probs, k)
+        if attrs.get("norm_topk", False):
+            top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
+        chosen = jnp.sum(jax.nn.one_hot(top_i, e, dtype=jnp.float32), axis=1)
+        lb = e * jnp.sum(jnp.mean(chosen, 0) * jnp.mean(probs, 0))
     z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     return {"TopW": [top_w], "TopI": [top_i.astype(jnp.int32)],
             "LBLoss": [lb], "ZLoss": [z]}
+
+
+@register_op("moe_bias_update", no_grad=True)
+def _moe_bias_update(ins, attrs):
+    """The selection bias's step (auxiliary-loss-free balancing,
+    arXiv:2412.19437 2.1.2): Bias [E] f32, TopI [n, k] (the router's
+    choices of this step, over all E) -> BiasOut = Bias + ``gamma`` *
+    sign(mean_e(count) - count_e): an expert chosen less than the
+    average is lifted, one chosen more is lowered. No gradient reaches
+    or leaves it; ``layers.topk_moe(select_bias=True)`` has the
+    optimizer append it to the training step (role opt)."""
+    bias, top_i = _x(ins, "Bias"), _x(ins, "TopI")
+    e = int(bias.shape[0])
+    count = jnp.sum(jax.nn.one_hot(top_i.reshape(-1), e, dtype=jnp.float32),
+                    axis=0)
+    step = float(attrs["gamma"]) * jnp.sign(jnp.mean(count) - count)
+    return {"BiasOut": [bias + step.astype(bias.dtype)]}
 
 
 @jax.custom_vjp

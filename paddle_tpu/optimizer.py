@@ -174,6 +174,10 @@ class Optimizer:
             else:
                 self._append_optimize_op(block, pg)
         self._finish_update(block, params_grads)
+        # what the step moves without a gradient (Program._step_updates)
+        for spec in prog._step_updates:
+            block.append_op(**spec)
+        prog._step_updates = []
         return block.ops[n_before:]
 
     def _append_sparse_optimize_op(self, block, param_and_grad):

@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -111,14 +111,18 @@ def _dropout_mask(p_keep: float, shape):
     return (bits < thresh).astype(jnp.float32) * (1.0 / p_keep)
 
 
-def _tile_fits(hb, bq, bk, dh):
-    return (24 * hb * bk * dh <= _KV_VMEM_BYTES
+def _tile_fits(hb, bq, bk, dh, dv=None):
+    # (12 bytes an element of k and dk, 12 of v and dv: 24 * dh where
+    # the values are as wide as the keys)
+    return (12 * hb * bk * (dh + (dv or dh)) <= _KV_VMEM_BYTES
             and 4 * hb * bq * bk <= _SCORE_VMEM_BYTES)
 
 
-def _pick_tile(h, tq, tk, q_block, k_block, dh, group=1):
+def _pick_tile(h, tq, tk, q_block, k_block, dh, group=1, dv=None):
     """-> (hb, bq, bk): heads, query rows and key rows of one grid step,
-    from what the call shows (h, dh, tq, tk) and the two VMEM caps.
+    from what the call shows (h, dh, tq, tk; ``dv`` where the values
+    and the output are not as wide as the queries and keys: latent
+    attention's 192 over 128) and the two VMEM caps.
     ``q_block`` / ``k_block``: None for the kernels' own choice, a number
     for an upper bound the caller sets (tests reach several blocks at a
     small t that way).
@@ -140,7 +144,7 @@ def _pick_tile(h, tq, tk, q_block, k_block, dh, group=1):
     they lie, never copied ``group`` times."""
     bq = min(q_block or DEFAULT_Q_BLOCK, tq)
     bk = min(k_block or DEFAULT_K_BLOCK, tk)
-    if group == 1 and _tile_fits(h, bq, bk, dh):
+    if group == 1 and _tile_fits(h, bq, bk, dh, dv):
         return h, bq, bk
     bq = min(q_block or _GRID_HEADS_BLOCK, tq)
     bk = min(k_block or _GRID_HEADS_BLOCK, tk)
@@ -149,25 +153,26 @@ def _pick_tile(h, tq, tk, q_block, k_block, dh, group=1):
         bq //= 2
     while tk % bk and bk > 128:
         bk //= 2
-    while not _tile_fits(1, bq, bk, dh) and bk > 128:
+    while not _tile_fits(1, bq, bk, dh, dv) and bk > 128:
         bk //= 2
-    while not _tile_fits(1, bq, bk, dh) and bq > 64:
+    while not _tile_fits(1, bq, bk, dh, dv) and bq > 64:
         bq //= 2
     if group > 1:
         return 1, bq, bk
     hb = max(d for d in range(1, h + 1)
-             if h % d == 0 and (d == 1 or _tile_fits(d, bq, bk, dh)))
+             if h % d == 0 and (d == 1 or _tile_fits(d, bq, bk, dh, dv)))
     return hb, bq, bk
 
 
-def bhtd_tile(h, tq, tk, q_block=None, k_block=None, *, dh, group=1):
+def bhtd_tile(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
+              dv=None):
     """-> (hb, bq, bk), the tile the K-blocked [b, h, t, dh] kernels take
     for a call of this shape, or None where they do not take it (no TPU
     backend, or blocks that do not tile both sequence lengths) and it
     runs as the dense composition. The one place that decides either:
     the kernels' entry points, ``bhtd_family`` and the dispatch counter's
     ``tile`` label all read it."""
-    hb, bq, bk = _pick_tile(h, tq, tk, q_block, k_block, dh, group)
+    hb, bq, bk = _pick_tile(h, tq, tk, q_block, k_block, dh, group, dv)
     if kernels_enabled() and tq % bq == 0 and tk % bk == 0:
         return hb, bq, bk
     return None
@@ -180,10 +185,11 @@ def tile_label(tile) -> str:
 
 
 def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
-                group=1) -> str:
+                group=1, dv=None) -> str:
     """"bhtd" (the K-blocked [b, h, t, dh] kernels) when the picked
     blocks tile both sequence lengths, else "dense"."""
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group,
+                     dv=dv)
     return "bhtd" if tile else "dense"
 
 
@@ -468,12 +474,26 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1):
     return f
 
 
-def _row_specs(at, hb, bq, bk, dh, group=1):
-    """Specs read at ``at``'s blocks: a (1, hb, bq, dh) block of q, out or
-    their gradients; a (1, hb, bq, 1) block of a [b, h, tq, 1] statistic;
+class _Specs(NamedTuple):
+    """_row_specs' answer: the specs of q, a [b, h, tq, 1] statistic, the
+    same statistic as [b, h, 1, tq] rows, k, out and v."""
+    q: pl.BlockSpec
+    stat: pl.BlockSpec
+    row: pl.BlockSpec
+    k: pl.BlockSpec
+    o: pl.BlockSpec
+    v: pl.BlockSpec
+
+
+def _row_specs(at, hb, bq, bk, dh, group=1, dv=None):
+    """Specs read at ``at``'s blocks: a (1, hb, bq, dh) block of q or its
+    gradient; a (1, hb, bq, 1) block of a [b, h, tq, 1] statistic;
     a (1, hb, 1, bq) block of the same statistic laid out [b, h, 1, tq];
-    a (1, hb, bk, dh) block of k, v or their gradients (``group`` > 1:
-    of the key/value head the step's query head reads)."""
+    a (1, hb, bk, dh) block of k or its gradient (``group`` > 1: of the
+    key/value head the step's query head reads); and (1, hb, bq, dv) /
+    (1, hb, bk, dv) blocks of out and v or their gradients (``dv``: dh
+    where the call has one width)."""
+    dv = dv or dh
     def q_idx(*ids):
         i, g, j, _ = at(*ids)
         return i, g, j, 0
@@ -486,10 +506,12 @@ def _row_specs(at, hb, bq, bk, dh, group=1):
         i, g, _, kk = at(*ids)
         return i, g if group == 1 else g // group, kk, 0
 
-    return (pl.BlockSpec((1, hb, bq, dh), q_idx),
-            pl.BlockSpec((1, hb, bq, 1), q_idx),
-            pl.BlockSpec((1, hb, 1, bq), row_idx),
-            pl.BlockSpec((1, hb, bk, dh), k_idx))
+    return _Specs(q=pl.BlockSpec((1, hb, bq, dh), q_idx),
+                  stat=pl.BlockSpec((1, hb, bq, 1), q_idx),
+                  row=pl.BlockSpec((1, hb, 1, bq), row_idx),
+                  k=pl.BlockSpec((1, hb, bk, dh), k_idx),
+                  o=pl.BlockSpec((1, hb, bq, dv), q_idx),
+                  v=pl.BlockSpec((1, hb, bk, dv), k_idx))
 
 
 def _bias_spec(bias, at, hb, bq, bk):
@@ -597,8 +619,9 @@ def _call_parts(kernel, at, tile, q, k, v, bias):
     """What the three calls share: -> (the kernel, the specs and the
     operands of q, k, v and the bias if there is one, _row_specs). With
     no bias the kernel's bias_ref slot (the fifth) is None."""
-    rows = _row_specs(at, *tile, q.shape[3], q.shape[1] // k.shape[1])
-    specs, args = [rows[0], rows[3], rows[3]], [q, k, v]
+    rows = _row_specs(at, *tile, q.shape[3], q.shape[1] // k.shape[1],
+                      v.shape[3])
+    specs, args = [rows.q, rows.k, rows.v], [q, k, v]
     if bias is None:
         body = kernel
         kernel = lambda *refs, **kw: body(*refs[:4], None, *refs[4:], **kw)
@@ -646,9 +669,9 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
     if tile is None:
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
@@ -659,7 +682,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
 
     hb, bq, bk = tile
     ng, nq, nk = h // hb, tq // bq, tk // bk
-    kernel, in_specs, args, (q_spec, stat_spec, _, _) = _call_parts(
+    kernel, in_specs, args, rows = _call_parts(
         _fwd_kernel, _step_blocks(causal, True, bq, bk, nq, group), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
@@ -671,15 +694,15 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
             num_scalar_prefetch=1,
             grid=(b, ng, nq, nk),
             in_specs=in_specs,
-            out_specs=[q_spec, stat_spec],
+            out_specs=[rows.o, rows.stat],
             scratch_shapes=[
                 pltpu.VMEM((hb, bq, 128), jnp.float32),
                 pltpu.VMEM((hb, bq, 128), jnp.float32),
-                pltpu.VMEM((hb, bq, dh), jnp.float32),
+                pltpu.VMEM((hb, bq, dv), jnp.float32),
             ],
         ),
         out_shape=[
-            _result(operands, (b, h, tq, dh), q.dtype),
+            _result(operands, (b, h, tq, dv), q.dtype),
             _result(operands, (b, h, tq, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
@@ -703,9 +726,9 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
     if tile is None:
         def f(q, k, v):
             return _reference_attention_with_lse(
@@ -726,7 +749,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     kw = dict(scale=scale, ng=ng, p_drop=p_drop, causal=causal)
 
     # --- dq: grid (b, ng, nq, nk), k-blocks inner ---
-    kernel, specs, args, (q_spec, stat_spec, _, _) = _call_parts(
+    kernel, specs, args, rows = _call_parts(
         _dq_kernel, _step_blocks(causal, True, bq, bk, nq, group), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, nk=nk, **kw)
@@ -736,8 +759,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, ng, nq, nk),
-            in_specs=specs + [q_spec, stat_spec, stat_spec],
-            out_specs=q_spec,
+            in_specs=specs + [rows.o, rows.stat, rows.stat],
+            out_specs=rows.q,
             scratch_shapes=[pltpu.VMEM((hb, bq, dh), jnp.float32)],
         ),
         out_shape=_result(operands, (b, h, tq, dh), q.dtype),
@@ -752,7 +775,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     # fetches bq x 512 B of each, and the kernel transposes both. Only a
     # q block off the 128-lane tiling (a caller's q_block of 64) keeps
     # that form: it cannot be cut from a row.
-    kernel, specs, args, (q_spec, stat_spec, row_spec, kv_spec) = _call_parts(
+    kernel, specs, args, rows = _call_parts(
         _dkv_kernel, _step_blocks(causal, False, bq, bk, nq, group), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, nq=nq, **kw)
@@ -762,9 +785,9 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         # walks the group's query heads, and the scratch sums them
         kernel = functools.partial(kernel, group=group)
         dkv_grid = (b, h // group, nk, group * nq)
-    stats = [lse, delta]
+    stats, stat_spec = [lse, delta], rows.stat
     if bq % 128 == 0 or bq == tq:
-        stat_spec = row_spec
+        stat_spec = rows.row
         stats = [x.reshape(b, h, 1, tq) for x in stats]
     operands = (seed_arr, *args, g, *stats)
     dk, dv = pl.pallas_call(
@@ -772,11 +795,11 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=dkv_grid,
-            in_specs=specs + [q_spec, stat_spec, stat_spec],
-            out_specs=[kv_spec, kv_spec],
+            in_specs=specs + [rows.o, stat_spec, stat_spec],
+            out_specs=[rows.k, rows.v],
             scratch_shapes=[
                 pltpu.VMEM((hb, bk, dh), jnp.float32),
-                pltpu.VMEM((hb, bk, dh), jnp.float32),
+                pltpu.VMEM((hb, bk, dv), jnp.float32),
             ],
         ),
         out_shape=[
@@ -824,7 +847,8 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if bhtd_family(q.shape[1], q.shape[2], k.shape[2],
                     q_block, k_block, dh=q.shape[3],
-                    group=q.shape[1] // k.shape[1]) == "bhtd":
+                    group=q.shape[1] // k.shape[1],
+                    dv=v.shape[3]) == "bhtd":
         dq, dk, dv = flash_attention_bwd(q, k, v, bias, seed, out, lse, g,
                                          scale, p_drop, q_block, k_block,
                                          causal, g_lse=g_lse)

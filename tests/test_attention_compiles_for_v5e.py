@@ -178,6 +178,34 @@ def test_grouped_query_attention_compiles(one_chip, real_kernels):
     assert "bf16[1,2,8192,256]" in text
 
 
+def test_latent_attention_compiles(one_chip, real_kernels):
+    """JoyAI-LLM-Flash's attention call: 32 heads whose queries and keys
+    are 192 wide (a lane and a half) over values of 128 at 4096
+    positions, one head a step at blocks of 512; q and k go in as they
+    are (nothing [.., 256]), out and dv are [b, 32, t, 128]."""
+    b, h, t, dk, dv = 1, 32, 4096, 192, 128
+    assert fa.bhtd_tile(h, t, t, dh=dk, dv=dv) == (1, 512, 512)
+
+    def arg(width):
+        return jax.ShapeDtypeStruct((b, h, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(dk), arg(dk), arg(dv)).compile()
+    text = compiled.as_text()
+    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
+        assert name in text, name
+    assert "bf16[1,32,4096,192]" in text and "4096,256]" not in text
+    # q, k and v in: 2 x (2 x 192 + 128) bytes a position and head, no
+    # padded copy among them
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        == b * h * t * (2 * dk + dv) * 2
+
+
 def test_held_share_grouped_matmuls_compile(one_chip, real_kernels):
     """One chip's 32 of 512 experts: a buffer of 81,920 rows of which an
     even router fills 5,120, so the row tile is 128."""

@@ -144,6 +144,45 @@ def test_gdn_phase_fails_on_a_call_without_the_kernel(
                              gqa=(4, 2, 128), **overrides, **GDN_TINY)
 
 
+MLA_TINY = dict(
+    vocab_size=50, hidden_size=128, num_hidden_layers=2,
+    intermediate_size=128, num_attention_heads=2, q_lora_rank=32,
+    kv_lora_rank=32, moe_intermediate_size=128, n_routed_experts=8,
+    held_experts=(0, 4), num_experts_per_tok=2)
+
+
+def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (the widths
+    of a head are the model's: 192 over 128): the dense layer, one
+    expert layer and the MTP module lower three attention calls each way
+    at ``dk192 dv128`` with their tile, none dense, two sigmoid routers
+    with a bias, and 18 grouped matmuls on tiles of 128 rows; on the
+    device (here: the CPU) the kernels agree with the dense
+    composition."""
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    row = chip_smoke.mla_phase(seq=512, t_check=256, heads=2, **MLA_TINY)
+    assert row["attention"] == {
+        f"bhtd {d} b1 tq512 tk512 h2 dk192 dv128 [hb2 bq256 bk256]": 3
+        for d in ("bwd", "fwd")}
+    assert list(row["routers"]) == ["score=sigmoid bias=1 k=2 experts=8"]
+    assert sum(row["grouped_matmuls"].values()) == 18
+    assert set(row["rel_err"]) == {"attn_o", "attn_dq", "attn_dk", "attn_dv"}
+    assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+def test_mla_phase_fails_on_a_dense_attention_call(telemetry, monkeypatch):
+    # the attention kernels off (CPU, no interpreter): every call is dense
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    with pytest.raises(chip_smoke.SmokeFailure, match="none dense"):
+        chip_smoke.mla_phase(seq=512, t_check=256, heads=2, **MLA_TINY)
+
+
 @pytest.mark.parametrize("dropout,tol", [
     (0.1, chip_smoke.DP_DROPOUT_LOSS_REL_TOL),  # masks drawn per shard
     (0.0, chip_smoke.DP_LOSS_REL_TOL)])         # the same math

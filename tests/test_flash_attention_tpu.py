@@ -170,6 +170,49 @@ def test_grouped_query_attention_at_heads_of_256_matches_dense(t):
                                    err_msg=name)
 
 
+@pytest.mark.parametrize("t", [4096, 1024])
+def test_latent_attention_at_192_over_128_matches_dense(t):
+    # JoyAI-LLM-Flash's attention call (models/joyai_flash.py: q and k
+    # [b, 32, t, 192], v and out [b, 32, t, 128], causal): one head a grid
+    # step at blocks of 512, q and k read at their 192 (a lane and a
+    # half, nothing padded in HBM), against the composition in float32
+    # at "highest" precision, a head at a time.
+    b, h, dk, dv = 1, 32, 192, 128
+    assert fa.bhtd_tile(h, t, t, dh=dk, dv=dv) == (1, 512, 512)
+    r = np.random.RandomState(8)
+    q, k = (jnp.asarray(r.normal(0, 0.5, (b, h, t, dk))).astype(
+        jnp.bfloat16) for _ in range(2))
+    v = jnp.asarray(r.normal(0, 0.5, (b, h, t, dv))).astype(jnp.bfloat16)
+    w = jnp.asarray(r.normal(0, 1, (b, h, t, dv)).astype(np.float32))
+
+    def f(q, k, v):
+        o, _ = fa.flash_attention_with_lse(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def ref(q, k, v):
+        # a head at a time: 32 heads' [t, t] float32 scores at 4096 are 2 GB
+        def one(i):
+            with jax.default_matmul_precision("highest"):
+                return fa._reference_attention(
+                    *(z[:, i:i + 1].astype(jnp.float32) for z in (q, k, v)),
+                    None, 1.0 / np.sqrt(dk), causal=True)
+        o = jnp.concatenate([one(i) for i in range(h)], 1)
+        return jnp.sum(o * w), o
+
+    (_, o1), g1 = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, o2), g2 = jax.jit(jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    assert o1.shape == v.shape and [g.shape for g in g1] == [
+        q.shape, k.shape, v.shape]
+    for name, a, b_ in zip(("o", "dq", "dk", "dv"), (o1, *g1), (o2, *g2)):
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        # bf16 operands, probabilities and results against float32; dk
+        # and dv sum up to 4096 rows
+        np.testing.assert_allclose(a, b_, atol=0.02 * np.abs(b_).max() + 0.05,
+                                   err_msg=name)
+
+
 # --- in-kernel dropout: determinism, keep-rate, exact-linear dv ---
 
 
