@@ -234,6 +234,32 @@ def gmm_dispatch():
     return gm.gmm_dispatch_counts()
 
 
+def _moe_layer_program(tokens, d, experts, top_k, d_ff, **kw):
+    """(main, startup, fetch, names) of one ``layers.topk_moe`` layer
+    under bf16 AMP with its backward pass: the output against a probe
+    ``p`` as the loss; fetched are the output, the experts' rows, the
+    tokens' gradient and the parameters' gradients."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.backward import append_backward
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[tokens, d], dtype="float32",
+                        append_batch_size=False)
+        x.stop_gradient = False
+        probe = layers.data("p", shape=[tokens, d], dtype="float32",
+                            append_batch_size=False)
+        out, _, _, rows, _ = layers.topk_moe(x, experts, top_k, d_ff, **kw)
+        loss = layers.reduce_sum(layers.elementwise_mul(out, probe))
+        grads = append_backward(loss)
+    main._amp = True
+    names = ["out", "rows", "dx"] + ["d" + p.name for p, _ in grads]
+    fetch = [out, rows, "x@GRAD", *(g for _, g in grads)]
+    return main, startup, fetch, names
+
+
 def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
     """``layers.topk_moe`` under bf16 AMP with its backward pass, once
     through the program's grouped-matmul kernels and once, the same
@@ -247,27 +273,11 @@ def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
     import jax.numpy as jnp
 
     import paddle_tpu as fluid
-    from paddle_tpu import layers
-    from paddle_tpu.backward import append_backward
     from paddle_tpu.parallel import grouped_matmul as gm
 
     def build():
-        main, startup = fluid.Program(), fluid.Program()
-        main.random_seed = startup.random_seed = 7
-        with fluid.program_guard(main, startup):
-            x = layers.data("x", shape=[tokens, d], dtype="float32",
-                            append_batch_size=False)
-            x.stop_gradient = False
-            probe = layers.data("p", shape=[tokens, d], dtype="float32",
-                                append_batch_size=False)
-            out, _, _, rows, _ = layers.topk_moe(x, experts, top_k, d_ff,
-                                                 name="smoke_moe")
-            loss = layers.reduce_sum(layers.elementwise_mul(out, probe))
-            grads = append_backward(loss)
-        main._amp = True
-        names = ["out", "rows", "dx"] + ["d" + p.name for p, _ in grads]
-        fetch = [out, rows, "x@GRAD", *(g for _, g in grads)]
-        return main, startup, fetch, names
+        return _moe_layer_program(tokens, d, experts, top_k, d_ff,
+                                  name="smoke_moe")
 
     r = np.random.RandomState(5)
     feed = {"x": r.randn(tokens, d).astype(np.float32),
@@ -317,6 +327,96 @@ def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
            "dispatch": dispatch,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  moe {row}")
+    return row
+
+
+def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
+                   held=(0, 32), steps=5):
+    """A held share's ``layers.topk_moe`` (``held`` of ``experts``: one
+    chip of an expert-parallel group) under bf16 AMP with its backward
+    pass through the ``moe.*`` kernels, once as the program lowers it,
+    every pass a loop over the windows of live rows
+    (ops/moe_ops.over_live_rows), and once, the same weights and tokens,
+    with ONE window of all n * k rows, so that every pass walks the
+    whole buffer: output and gradients must agree, no row of the
+    counter may say ``whole``, and the seconds of a step of each are
+    printed beside the live share. The default is
+    qwen3next-train-s8192's layer: a buffer of 81,920 rows, about 5,120
+    of them live."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.ops import moe_ops
+
+    def build():
+        return _moe_layer_program(tokens, d, experts, top_k, d_ff,
+                                  name="smoke_held", held=held,
+                                  norm_topk_prob=True)
+
+    r = np.random.RandomState(5)
+    feed = {"x": r.randn(tokens, d).astype(np.float32),
+            "p": r.randn(tokens, d).astype(np.float32)}
+    scope, exe = fluid.Scope(), fluid.Executor()
+    before = (gmm_dispatch(), moe_ops.rows_dispatch_counts())
+    results, step_ms = {}, {}
+    window = moe_ops.live_window
+    for form in ("windowed", "whole"):
+        main, startup, fetch, names = build()
+        if form == "windowed":
+            exe.run(startup, scope=scope)     # both read these weights
+        else:
+            moe_ops.live_window = lambda m, live_rows: m
+        try:
+            took = []
+            for _ in range(steps + 1):        # the first call compiles
+                t0 = time.perf_counter()
+                got = jax.block_until_ready(exe.run(
+                    main, feed=feed, fetch_list=fetch, scope=scope,
+                    return_numpy=False))
+                took.append(time.perf_counter() - t0)
+        finally:
+            moe_ops.live_window = window
+        results[form] = dict(zip(names, got))
+        step_ms[form] = round(1e3 * float(np.median(took[1:])), 2)
+    gmm = _dispatch_since(before[0], gmm_dispatch)
+    passes = _dispatch_since(before[1], moe_ops.rows_dispatch_counts)
+    exe.close()
+
+    m = tokens * top_k
+    check(sum(v for k, v in gmm.items() if k.endswith("]")) == 18,
+          f"the two layers' eighteen grouped matmuls did not all take a "
+          f"tile: {gmm}")
+    check(passes and all(" windowed" in k for k in passes),
+          f"a pass of the held layer walks its buffer whole: {passes}")
+    w = window(m, -(-m * held[1] // experts))
+    check({k.rsplit(" w", 1)[1] for k in passes} == {str(w), str(m)},
+          f"the passes' windows are not {w} (and {m} for the whole "
+          f"form): {passes}")
+    rows = np.asarray(results["windowed"]["rows"])
+    live = int(rows.sum())
+    check(0 < live < m and (
+        rows == np.asarray(results["whole"]["rows"])).all(),
+        f"the two forms routed differently, or no row is live: "
+        f"{rows.tolist()}")
+    errs = {}
+    for name in names:
+        if name == "rows":
+            continue
+        a = jnp.asarray(results["windowed"][name], jnp.float32)
+        b = jnp.asarray(results["whole"][name], jnp.float32)
+        check(bool(jnp.isfinite(a).all()), f"held moe {name} not finite")
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= KERNEL_REL_TOL,
+              f"held moe {name}: the windowed form is off the whole "
+              f"buffer's by {errs[name]:.4f} of its max (tolerance "
+              f"{KERNEL_REL_TOL})")
+    row = {"rows": m, "live": live, "live_share": round(live / m, 4),
+           "held": list(held), "experts": experts, "window": w,
+           "step_ms": step_ms, "passes": passes,
+           "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+    say(f"  moe_held {row}")
     return row
 
 
@@ -1028,6 +1128,7 @@ def main() -> int:
     check(n_moe == 9, f"the layer's module holds {n_moe} Pallas custom "
           f"calls, expected its nine grouped matmuls")
 
+    report["moe_held"], _ = phase("moe_held", moe_held_phase)
     report["gdn"], _ = phase("gdn", gdn_phase)
     report["mla"], _ = phase("mla", mla_phase)
 

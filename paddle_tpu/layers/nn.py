@@ -1527,7 +1527,8 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
         helper.append_op(
             "moe_combine",
             inputs={"Ys": ys, "TopW": top_w, "Order": order, "Slot": slot,
-                    "Like": input},
+                    "Like": input,
+                    **({"Rows": rows} if held is not None else {})},
             outputs={"Out": out}, attrs=held_attrs)
     if shared_d_ff:
         with name_scope("shared"):

@@ -17,12 +17,17 @@ load-balancing loss; add ``aux_weight * AuxLoss`` to the training loss).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu import monitor as _monitor
 from paddle_tpu.core import interp
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.parallel import grouped_matmul as _gm
+from paddle_tpu.parallel.grouped_matmul import (live_window, over_live_rows,
+                                                put_rows, rows_at)
 
 _ACTS = {
     "relu": jax.nn.relu,
@@ -272,32 +277,176 @@ def _rows_bwd(back, g):
 _rows.defvjp(_rows_fwd, _rows_bwd)
 
 
-def _slot_major(x, slot):
-    """The rows of ``x`` [n*k, d] that hold each token's k pairs, as
-    [k, n, d] (``slot`` [n, k]: moe_dispatch's): the slot in FRONT. A
-    [n, k, d] array whose k is no multiple of 8 (Qwen3-Next's 10) is
-    padded to one on the chip, k being the tile's second-minor
-    dimension: 60% more bytes in every pass over it and a relayout
-    copy that carries no scope (2.7 ms a layer at 8192 x 10 x 2048, my
-    chip run, PR 32). In front k is padded by nothing."""
+# A held share (``held_count`` of ``num_experts``: one chip of an
+# expert-parallel group). Its row buffer has a row for every pair the
+# router made, n * k, so that no routing drops a token; the pairs on held
+# experts, the LIVE rows, come first, and an even router makes a
+# sixteenth of the buffer live. Every pass such a layer makes over the
+# buffer is a loop over the windows that hold a live row
+# (``over_live_rows``: the trip count is sum(Rows), the step's own, a
+# device scalar), as the grouped matmuls visit no tile behind the last
+# group. Row-major passes write their windows into zeros; token-major
+# ones (a token's sum over its pairs) walk the live rows and add each
+# into its token while the live rows are few, where a walk by token
+# gathers all k rows of every token to add up the 0.6 of them that are
+# live (``_add_into_tokens``). Every buffer an op hands on (Xs, Gate, Up,
+# Ys and the cotangents) has zeros behind the last live row: the experts'
+# products are written into zeros. The three that stay inside an op (dh
+# and the two halves of d Xs) are taken as their kernels leave them in
+# memory nothing filled (``zero_behind=False``: not defined behind the
+# last live row) and read by window alone, under ``keep``.
+
+_M_PASSES = _monitor.counter(
+    "pt_moe_rows_dispatch_total",
+    "passes of a top-k MoE layer over its row buffer lowered (trace time, "
+    "telemetry on), by op, pass, form (windowed: a loop over the windows "
+    "of `window` rows that hold a live row, the trip count the step's "
+    "own; windowed|by_token: that loop while the live rows are few and a "
+    "walk of the buffer by token from there, chosen by the step's own "
+    "count; whole: the pass walks all buffer_rows) and buffer_rows")
+
+
+def _live_rows(attrs, m):
+    """grouped_matmul's ``live_rows`` for an experts op over m rows:
+    nothing for a layer that holds every expert its router scores."""
+    if "held_count" not in attrs:
+        return {}
+    return {"live_rows": -(-m * int(attrs["held_count"])
+                           // int(attrs["num_experts"]))}
+
+
+def _window(attrs, m):
+    """The rows a trip of a held layer's passes works on
+    (``live_window``, from the shape); None for a layer
+    that holds every expert: its buffer is all live rows and its passes
+    walk it whole."""
+    kw = _live_rows(attrs, m)
+    return live_window(m, kw["live_rows"]) if kw else None
+
+
+def _note_passes(op, m, w, *passes, by_token=()):
+    """One row of ``pt_moe_rows_dispatch_total`` a pass of ``op`` over
+    its m-row buffer, from the branch that lowers them: ``w`` the window
+    its loop takes, None where it walks the buffer whole; ``by_token``
+    those of the passes that may also walk it by token
+    (``_add_into_tokens``). A grad op that traces its forward again
+    counts that one's passes again, as the router's counter does."""
+    if not _monitor.enabled() or not interp.lowering_active():
+        return
+    for name in passes:
+        form = "windowed|by_token" if name in by_token else "windowed"
+        _M_PASSES.inc(labels={
+            "op": op, "pass": name, "form": form if w else "whole",
+            "buffer_rows": str(m), "window": str(w or "")})
+
+
+def rows_dispatch_counts():
+    """{"op pass form buffer_rows[ window]": passes lowered so far}: the
+    counter above as chip_smoke.py prints it."""
+    out = {}
+    for row in _monitor.snapshot()[_M_PASSES.name]["values"]:
+        lb = row["labels"]
+        name = " ".join(lb.get(key, "?") for key in
+                        ("op", "pass", "form", "buffer_rows"))
+        if lb.get("window"):
+            name += f" w{lb['window']}"
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
+
+
+def _live_pass(live, w, m, widths, body):
+    """A row-major pass over the live rows: ``body(r0) -> rows`` ([w,
+    width] each, one per (width, dtype) of ``widths``), written in place
+    into [m, width] buffers that are zeros in every window no trip
+    reached and behind the last live row."""
+    def trip(r0, keep, bufs):
+        return tuple(put_rows(b, r0, jnp.where(keep, v, 0))
+                     for b, v in zip(bufs, body(r0)))
+
+    return over_live_rows(live, w, trip, tuple(
+        jnp.zeros((m, width), dtype) for width, dtype in widths))
+
+
+def _gather_live(x, order, live, w):
+    """Xs of the tokens x [n, d]: row r < live is the token of pair
+    ``order[r]``; zeros behind."""
+    k = order.shape[0] // x.shape[0]
+    return _live_pass(
+        live, w, order.shape[0], [(x.shape[1], x.dtype)],
+        lambda r0: (jnp.take(x, rows_at(order, r0, w) // k, axis=0),))[0]
+
+
+# What adding a row of 2048 into its token costs on a v5e, ns (my chip
+# run, PR 35: 0.73 / 4.5 / 8.8 ms at 5,140 / 40,839 / 81,920 live rows of
+# 81,920 by XLA's scatter-add over the live rows, whatever hints it gets:
+# unique and sorted indices inside one expert's group made it slower;
+# 3.3 ms by token, a gather of all k rows of every token and their sum,
+# whatever is live).
+_ADD_NS = {"live_row": 107, "buffer_row": 40}
+
+
+def _add_into_tokens(note, rows, order, slot, live, w, top_w=None):
+    """[n, d] float32: each live row r of ``rows`` [n * k, d], times its
+    pair's weight where ``top_w`` [n, k] is given, added into the token
+    of pair ``order[r]`` (``slot`` [n, k] is order's inverse). The sum
+    is float32 whatever the rows' dtype; the caller casts it once.
+    ``note`` (op, pass) names it in ``pt_moe_rows_dispatch_total``.
+
+    By live row while that is the cheaper (``_ADD_NS``: under three
+    eighths of the buffer live), else by token, the one choice of a
+    step's own count that is not a trip count: a router that learns to
+    send most pairs to the held experts, as the last layer's does
+    within a benchmark run, must not pay 2.7 times the walk by token.
+    That walk is the form of a layer that holds every expert, kept for
+    this case alone; a kernel that adds rows by a prefetched token index
+    at a gather's speed would retire it and ``_ADD_NS`` (PERF.md 7)."""
     n, k = slot.shape
-    return jnp.take(x, slot.T.reshape(-1), axis=0).reshape(k, n, -1)
+    weight = None if top_w is None else top_w.astype(jnp.float32)
+    _note_passes(note[0], n * k, w, note[1], by_token=note[1:])
+
+    def by_live_row():
+        def trip(r0, keep, acc):
+            pairs = rows_at(order, r0, w)
+            v = rows_at(rows, r0, w).astype(jnp.float32)
+            if weight is not None:
+                v = v * jnp.take(weight.reshape(-1), pairs)[:, None]
+            return acc.at[pairs // k].add(jnp.where(keep, v, 0.0),
+                                          mode="promise_in_bounds")
+
+        return over_live_rows(
+            live, w, trip, jnp.zeros((n, rows.shape[1]), jnp.float32))
+
+    def by_token():
+        # slot-major: [n, k, d] with k no multiple of 8 is padded to
+        # one on the chip, the slot in front by nothing (PR 32)
+        at = slot.T
+        picked = jnp.where(
+            (at < live)[..., None],
+            jnp.take(rows, at.reshape(-1), axis=0).reshape(k, n, -1), 0
+        ).astype(jnp.float32)
+        if weight is None:
+            return jnp.sum(picked, axis=0)
+        return jnp.einsum("knd,nk->nd", picked, weight)
+
+    return jax.lax.cond(
+        _ADD_NS["live_row"] * live <= _ADD_NS["buffer_row"] * n * k,
+        by_live_row, by_token)
 
 
-@jax.custom_vjp
-def _rows_of_pairs(x, idx, slot):
-    """``_rows`` for a held share: ``x[idx]`` whose cotangent is the sum
-    over each token's k rows, gathered slot-major (``_slot_major``)."""
-    return jnp.take(x, idx, axis=0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_of_pairs(x, order, slot, live, w):
+    """``_rows`` for a held share: the live rows' tokens, whose
+    cotangent is the live rows added into their tokens."""
+    return _gather_live(x, order, live, w)
 
 
-def _rows_of_pairs_fwd(x, idx, slot):
-    return jnp.take(x, idx, axis=0), slot
+def _rows_of_pairs_fwd(x, order, slot, live, w):
+    return _gather_live(x, order, live, w), (order, slot, live)
 
 
-def _rows_of_pairs_bwd(slot, g):
-    return (jnp.sum(_slot_major(g, slot).astype(jnp.float32), axis=0
-                    ).astype(g.dtype), None, None)
+def _rows_of_pairs_bwd(w, res, g):
+    return (_add_into_tokens(("moe_dispatch_grad", "d_x"), g, *res,
+                             w).astype(g.dtype), None, None, None)
 
 
 _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
@@ -318,7 +467,7 @@ def _moe_dispatch(ins, attrs):
     held experts come first in Xs, sorted by expert, and the pairs on
     experts held elsewhere lie behind them (by token): they keep their
     place in the buffer, which has a row for every pair whatever the
-    routing, and no expert here reads them."""
+    routing; no expert here reads them, and Xs has zeros there."""
     x, top_i = _x(ins, "X"), _x(ins, "TopI")
     x = x.reshape(-1, x.shape[-1])
     n, k = top_i.shape
@@ -331,22 +480,25 @@ def _moe_dispatch(ins, attrs):
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     slot = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
     rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
-    gather = _rows_of_pairs if "held_count" in attrs else _rows
-    return {"Xs": [gather(x, order // k, slot)], "Rows": [rows],
-            "Order": [order], "Slot": [slot]}
-
-
-def _live_rows(attrs, m):
-    """grouped_matmul's ``live_rows`` for an experts op over m rows:
-    nothing for a layer that holds every expert its router scores."""
-    if "held_count" not in attrs:
-        return {}
-    return {"live_rows": -(-m * int(attrs["held_count"])
-                           // int(attrs["num_experts"]))}
+    w = _window(attrs, n * k)
+    _note_passes("moe_dispatch", n * k, w, "gather_xs")
+    if w is not None:
+        xs = _rows_of_pairs(x, order, slot, jnp.sum(rows), w)
+    else:
+        xs = _rows(x, order // k, slot)
+    return {"Xs": [xs], "Rows": [rows], "Order": [order], "Slot": [slot]}
 
 
 def _swiglu(gate, up):
     return (jax.nn.silu(gate) * up).astype(gate.dtype)
+
+
+def _swiglu_live(gate, up, live, w):
+    """``_swiglu`` on the live rows of Gate and Up [m, f], zeros behind."""
+    return _live_pass(
+        live, w, gate.shape[0], [(gate.shape[1], gate.dtype)],
+        lambda r0: (_swiglu(rows_at(gate, r0, w),
+                            rows_at(up, r0, w)),))[0]
 
 
 @register_op("moe_experts", diff_inputs=("Xs", "WGate", "WUp", "WDown"))
@@ -365,22 +517,24 @@ def _moe_experts(ins, attrs):
 
     ``held_count`` of ``num_experts`` (a held share of the experts,
     see moe_dispatch): Rows sum to less than m; the rows behind the last
-    group are multiplied by nothing and Ys has zeros there, so they add
-    nothing to moe_combine's sum. The grouped matmuls are told the rows
+    group are multiplied by nothing, and Ys, Gate and Up have zeros
+    there. The grouped matmuls are told the rows
     an even router would put on the held experts (``live_rows``: their
-    row tile goes with those, not with the buffer). Such a layer also
-    hands over X, the tokens, and Order (moe_dispatch's): the grad op
-    gathers Xs again from them and does not keep the buffer, 16 times
-    its live rows, from the forward pass (0.32 GB a layer at 81,920
-    rows of 2048)."""
-    from paddle_tpu.parallel.grouped_matmul import grouped_matmul
-
+    row tile goes with those, not with the buffer), and SwiGLU runs over
+    the windows that hold a live row. Such a layer also hands over X,
+    the tokens, and Order (moe_dispatch's): the grad op gathers Xs again
+    from them and does not keep the buffer, 16 times its live rows, from
+    the forward pass (0.32 GB a layer at 81,920 rows of 2048)."""
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
     wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
-    kw = _live_rows(attrs, xs.shape[0])
-    gate = grouped_matmul(xs, wg.astype(xs.dtype), rows, **kw)
-    up = grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
-    ys = grouped_matmul(_swiglu(gate, up), wd.astype(xs.dtype), rows, **kw)
+    m = xs.shape[0]
+    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    _note_passes("moe_experts", m, w, "swiglu")
+    gate = _gm.grouped_matmul(xs, wg.astype(xs.dtype), rows, **kw)
+    up = _gm.grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
+    h = (_swiglu(gate, up) if w is None
+         else _swiglu_live(gate, up, jnp.sum(rows), w))
+    ys = _gm.grouped_matmul(h, wd.astype(xs.dtype), rows, **kw)
     return {"Ys": [ys], "Gate": [gate], "Up": [up]}
 
 
@@ -389,31 +543,53 @@ def _moe_experts_grad(ins, attrs):
     """The six grouped matmuls of the backward pass from the forward's
     saved Gate and Up: no projection runs twice (the generic vjp-style
     grad op would trace the forward again, and a custom call that is
-    traced twice executes twice)."""
-    from paddle_tpu.parallel.grouped_matmul import grouped_matmul_grads
-
+    traced twice executes twice). A held share gathers Xs again and runs
+    SwiGLU, its gradient and the sum of the rows' two gradients over the
+    windows that hold a live row."""
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
-    if _x(ins, "X") is not None:
-        # a held share: gathered again (behind a barrier, or XLA merges
-        # this gather with moe_dispatch's and keeps that one's result)
+    wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
+    m, dtype = xs.shape[0], xs.dtype
+    gate = _x(ins, "Gate").astype(dtype)
+    up = _x(ins, "Up").astype(dtype)
+    g = _x(ins, "GRAD::Ys").astype(dtype)
+    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    if w is not None:
+        kw["zero_behind"] = False   # dh, dx_gate, dx_up: read by window
+    _note_passes("moe_experts_grad", m, w, "gather_xs", "swiglu",
+                 "swiglu_grad", "sum_dx")
+    if w is None:
+        h, swiglu_vjp = jax.vjp(_swiglu, gate, up)
+        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g)
+        dgate, dup = swiglu_vjp(dh)
+    else:
+        live = jnp.sum(rows)
+        # gathered again (behind a barrier, or XLA merges this gather
+        # with moe_dispatch's and keeps that one's result)
         x, order = jax.lax.optimization_barrier(
             (_x(ins, "X"), _x(ins, "Order")))
-        x = x.reshape(-1, x.shape[-1])
-        xs = jnp.take(x, order // (xs.shape[0] // x.shape[0]),
-                      axis=0).astype(xs.dtype)
-    wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
-    gate = _x(ins, "Gate").astype(xs.dtype)
-    up = _x(ins, "Up").astype(xs.dtype)
-    g = _x(ins, "GRAD::Ys").astype(xs.dtype)
-    kw = _live_rows(attrs, xs.shape[0])
-    h, swiglu_vjp = jax.vjp(_swiglu, gate, up)
-    dh, dwd = grouped_matmul_grads(h, wd.astype(xs.dtype), rows, g, **kw)
-    dgate, dup = swiglu_vjp(dh)
-    dx_gate, dwg = grouped_matmul_grads(xs, wg.astype(xs.dtype), rows,
-                                        dgate, **kw)
-    dx_up, dwu = grouped_matmul_grads(xs, wu.astype(xs.dtype), rows, dup,
-                                      **kw)
-    return {"GRAD::Xs": [dx_gate + dx_up],
+        xs = _gather_live(x.reshape(-1, x.shape[-1]).astype(dtype), order,
+                          live, w)
+        h = _swiglu_live(gate, up, live, w)
+        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
+                                           **kw)
+
+        def swiglu_grad(r0):
+            _, vjp = jax.vjp(_swiglu, rows_at(gate, r0, w),
+                             rows_at(up, r0, w))
+            return vjp(rows_at(dh, r0, w))
+
+        dgate, dup = _live_pass(live, w, m, [(gate.shape[1], dtype)] * 2,
+                                swiglu_grad)
+    dx_gate, dwg = _gm.grouped_matmul_grads(xs, wg.astype(dtype), rows,
+                                            dgate, **kw)
+    dx_up, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
+                                          **kw)
+    if w is None:
+        dx = dx_gate + dx_up
+    else:
+        dx, = _live_pass(live, w, m, [(xs.shape[1], dtype)], lambda r0: (
+            rows_at(dx_gate, r0, w) + rows_at(dx_up, r0, w),))
+    return {"GRAD::Xs": [dx],
             "GRAD::WGate": [dwg.astype(wg.dtype)],
             "GRAD::WUp": [dwu.astype(wu.dtype)],
             "GRAD::WDown": [dwd.astype(wd.dtype)]}
@@ -424,16 +600,19 @@ def _moe_combine(ins, attrs):
     """Ys [n*k, d] expert outputs in dispatch order, TopW [n, k], Order
     and Slot of moe_dispatch -> Out = sum_j TopW[t, j] * Ys[Slot[t, j]],
     in the shape of Like (the tokens as the router got them): summed
-    in f32, returned in Ys's dtype."""
+    in f32, returned in Ys's dtype. A held share (which also gets Rows,
+    moe_dispatch's) adds each live row, times its pair's weight, into
+    its token (``_add_into_tokens``) and reads no other row of Ys."""
     ys, top_w = _x(ins, "Ys"), _x(ins, "TopW")
     order, slot = _x(ins, "Order"), _x(ins, "Slot")
     n, k = slot.shape
-    if "held_count" in attrs:   # a held share: slot-major, its own grad op
-        out = jnp.einsum("knd,nk->nd",
-                         _slot_major(ys, slot).astype(jnp.float32),
-                         top_w.astype(jnp.float32))
+    w = _window(attrs, n * k)
+    if w is not None:   # a held share: its own grad op
+        out = _add_into_tokens(("moe_combine", "sum_pairs"), ys, order, slot,
+                               jnp.sum(_x(ins, "Rows")), w, top_w)
         return {"Out": [out.astype(ys.dtype).reshape(
             _x(ins, "Like").shape)]}
+    _note_passes("moe_combine", n * k, None, "sum_pairs")
     picked = _rows(ys, slot.reshape(-1), order[:, None]).reshape(n, k, -1)
     out = jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),
                      top_w.astype(jnp.float32))
@@ -444,33 +623,42 @@ def _moe_combine(ins, attrs):
 def _moe_combine_grad(ins, attrs):
     """A layer that holds every expert its router scores: jax's
     transposes of the forward above, as the generic grad op derives
-    them. A held share (``held_count``, see moe_dispatch), whose row
-    buffer is num_experts / held_count times its live rows, writes the
-    two cotangents out instead: jax's transpose of the weighted sum
-    keeps the gathered rows AND their products as float32 [n, k, d]
-    (1.6 GB at 81,920 rows of 2048 compiled for a v5e, and the gathered
-    rows saved from the forward pass beside them); here GRAD::Ys is one
-    gather of the cotangent's rows times the pair's weight, and
-    GRAD::TopW one product of Ys's rows, gathered again, with the
-    cotangent, accumulated in float32: nothing is kept from the forward
-    pass and nothing float32 is [n, k, d]. Where the pairs of a token
-    lie side by side they do so slot-major (``_slot_major``)."""
-    if "held_count" not in attrs:
+    them. A held share (``held_count``, see moe_dispatch) writes the two
+    cotangents out, one walk over its live rows for both (jax's
+    transpose of a weighted sum by token keeps the gathered rows AND
+    their products as float32 [n, k, d]: 1.6 GB at 81,920 rows of 2048
+    compiled for a v5e): a window of the cotangent's rows is gathered by
+    token once; GRAD::Ys is that times the pair's weight, and GRAD::TopW
+    its row-wise product with Ys's window, summed in float32 and put at
+    the pair's own place. Both are zeros for a pair held elsewhere."""
+    m = _x(ins, "Order").shape[0]
+    w = _window(attrs, m)
+    _note_passes("moe_combine_grad", m, w, "d_ys", "d_w")
+    if w is None:
         from paddle_tpu.core import autodiff
         from paddle_tpu.core.registry import get_op_def
 
         return autodiff.make_grad_compute(get_op_def("moe_combine"))(
             ins, attrs)
-    # (behind a barrier, or XLA merges the gather of Ys's rows below
-    # with the forward op's and keeps that one's result)
-    ys, slot = jax.lax.optimization_barrier((_x(ins, "Ys"), _x(ins, "Slot")))
-    top_w, order = _x(ins, "TopW"), _x(ins, "Order")
-    n, k = slot.shape
+    ys, top_w, order = _x(ins, "Ys"), _x(ins, "TopW"), _x(ins, "Order")
+    n, k = top_w.shape
     g = _x(ins, "GRAD::Out").reshape(n, -1)
-    pair_w = jnp.take(top_w.astype(jnp.float32).reshape(-1), order)
-    d_ys = (jnp.take(g, order // k, axis=0).astype(jnp.float32)
-            * pair_w[:, None]).astype(ys.dtype)
-    d_w = jnp.einsum("knd,nd->nk", _slot_major(ys, slot),
-                     g.astype(ys.dtype),
-                     preferred_element_type=jnp.float32)
-    return {"GRAD::Ys": [d_ys], "GRAD::TopW": [d_w.astype(top_w.dtype)]}
+    pair_w = top_w.astype(jnp.float32).reshape(-1)
+
+    def trip(r0, keep, carry):
+        d_ys, d_w = carry
+        pairs = rows_at(order, r0, w)
+        g_rows = jnp.take(g, pairs // k, axis=0).astype(jnp.float32)
+        d_ys = put_rows(d_ys, r0, jnp.where(
+            keep, g_rows * jnp.take(pair_w, pairs)[:, None], 0.0))
+        dots = jnp.sum(rows_at(ys, r0, w).astype(jnp.float32) * g_rows,
+                       axis=-1)
+        return d_ys, d_w.at[pairs].set(
+            jnp.where(keep[:, 0], dots, 0.0), unique_indices=True,
+            mode="promise_in_bounds")
+
+    d_ys, d_w = over_live_rows(
+        jnp.sum(_x(ins, "Rows")), w, trip,
+        (jnp.zeros_like(ys), jnp.zeros(n * k, jnp.float32)))
+    return {"GRAD::Ys": [d_ys],
+            "GRAD::TopW": [d_w.reshape(n, k).astype(top_w.dtype)]}

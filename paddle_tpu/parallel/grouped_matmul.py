@@ -9,7 +9,10 @@ or dropped. A caller that holds only some of the experts its router
 scores (``live_rows``: an expert-parallel share, ops/moe_ops.py) passes
 group sizes that sum to LESS than m: the rows behind the last group
 belong to no expert here, no visit reaches their tiles, and the result
-has zeros there, as ``ragged_dot`` has. Operands keep their dtype (bf16 under AMP), a product is
+has zeros there, as ``ragged_dot`` has (the kernel writes its tiles INTO
+zeros, ``gmm(zero_behind=True)``; ``zero_behind=False`` hands out what a
+kernel left in memory nothing filled to a caller that reads the live
+rows alone). Operands keep their dtype (bf16 under AMP), a product is
 accumulated in float32 inside the kernel and returned in the operands'
 dtype, as ``ragged_dot`` returns it.
 
@@ -239,7 +242,9 @@ def _row_mask(row0, start, end, tm):
 
 
 def _gmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, rhs_ref,
-                out_ref, *scratch, tm, tiles_k, transpose_rhs):
+                *refs, tm, tiles_k, transpose_rhs, aliased):
+    # (aliased: the zeros the result is written into ride in front of it)
+    out_ref, *scratch = refs[aliased:]
     v, kk = pl.program_id(1), pl.program_id(2)
     dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
 
@@ -282,11 +287,17 @@ def _gmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, rhs_ref,
 
 
 def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
-        name="moe.gmm.fwd"):
+        name="moe.gmm.fwd", zero_behind=False):
     """``lhs [m, k]`` x ``rhs [E, k, n]`` -> [m, n] over the row groups;
     ``transpose_rhs``: rhs is [E, n, k] and is read transposed. ``tile``
     (tm, tk, tn) are the rows, the contraction and the result's width of
-    one grid step: tm divides m, tk k and tn n."""
+    one grid step: tm divides m, tk k and tn n. ``zero_behind``, for
+    group sizes that sum to less than m: the result is an array of zeros
+    that the call's tiles are written into (it rides in as an operand
+    the result aliases and no step reads), so the tiles no visit
+    reaches hold zeros; the one tile the last group ends in (or, with
+    no row in any group, the tile an idle grid still writes back) has
+    its rows behind the last group zeroed afterwards."""
     m, k = lhs.shape
     e = rhs.shape[0]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
@@ -303,9 +314,11 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
         rhs_spec = pl.BlockSpec(
             (None, tk, tn), lambda j, v, kk, o, g, t, nv: (g[v], kk, j))
     item = jnp.dtype(lhs.dtype).itemsize
-    return pl.pallas_call(
+    zeros = ([jnp.zeros((m, n), lhs.dtype)] if zero_behind else [])
+    out = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tiles_k=tiles_k,
-                          transpose_rhs=transpose_rhs),
+                          transpose_rhs=transpose_rhs,
+                          aliased=len(zeros)),
         name=name,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -315,12 +328,13 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
                 pl.BlockSpec((tm, tk),
                              lambda j, v, kk, o, g, t, nv: (t[v], kk)),
                 rhs_spec,
-            ],
+            ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(zeros),
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda j, v, kk, o, g, t, nv: (t[v], j)),
             scratch_shapes=([pltpu.VMEM((tm, tn), jnp.float32)]
                             if tiles_k > 1 else []),
         ),
+        input_output_aliases={6: 0} if zeros else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(tm, tk, tn, item)),
@@ -328,7 +342,13 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=item * (m * k * (n // tn) + e * k * n + m * n)),
         interpret=_INTERPRET,
-    )(*meta, lhs, rhs)
+    )(*meta, lhs, rhs, *zeros)
+    if not zero_behind:
+        return out
+    live = meta[0][-1]
+    r0 = jnp.minimum(live // tm * tm, m - tm)
+    rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return put_rows(out, r0, jnp.where(rows < live, rows_at(out, r0, tm), 0))
 
 
 # ---------------------------------------------------------------------------
@@ -418,28 +438,73 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gmm_vjp(lhs, rhs, group_sizes, tile, live_rows):
-    return gmm(lhs, rhs, group_sizes, tile)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gmm_vjp(lhs, rhs, group_sizes, tile, live_rows, zero_behind):
+    return gmm(lhs, rhs, group_sizes, tile, zero_behind=zero_behind)
 
 
-def _gmm_vjp_fwd(lhs, rhs, group_sizes, tile, live_rows):
-    return gmm(lhs, rhs, group_sizes, tile), (lhs, rhs, group_sizes)
+def _gmm_vjp_fwd(lhs, rhs, group_sizes, tile, live_rows, zero_behind):
+    return (gmm(lhs, rhs, group_sizes, tile, zero_behind=zero_behind),
+            (lhs, rhs, group_sizes))
 
 
-def _gmm_vjp_bwd(_, live_rows, res, g):
+def _gmm_vjp_bwd(_, live_rows, zero_behind, res, g):
     return (*grouped_matmul_grads(*res, g, live_rows=live_rows), None)
 
 
-def _zero_behind(x, group_sizes):
-    """``x`` [m, ..] with zeros in the rows behind the last group (the
-    kernels write no tile there; ``ragged_dot`` has zeros there)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
-    return jnp.where(rows < jnp.sum(group_sizes.astype(jnp.int32)), x,
-                     jnp.zeros_like(x))
-
-
 _gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# passes over the rows inside groups, where they are fewer than the rows
+# ---------------------------------------------------------------------------
+
+
+def live_window(m, live_rows):
+    """W, the rows one trip of ``over_live_rows`` works on, for a
+    buffer of m rows of which an even router fills ``live_rows``: the
+    largest power of two that divides m, is at most half of them (at
+    least 8) and at most the kernels' largest row tile. An even routing
+    takes a few trips and an uneven one as many as its rows need; a
+    gather or an elementwise pass costs the same a row at any window
+    from 512 to 4096 rows, XLA's scatter-add twice as much at 2048 as
+    at 512 (my chip run, PR 35). 512 at 81,920 rows with 5,120 expected
+    and at 32,768 with 2,048. A buffer that no window of 8 rows divides
+    is one window (m: a loop of a trip per row or two is no pass)."""
+    w = 1
+    while m % (2 * w) == 0 and 2 * w <= min(max(int(live_rows) // 2, 8),
+                                            max(_ROW_TILE_RATE)):
+        w *= 2
+    return w if w >= 8 else m
+
+
+def over_live_rows(live, w, body, init):
+    """``init`` after ``body(r0, keep, carry)`` for each window of ``w``
+    rows that holds a live one: r0 = 0, w, .. < ``live``, a DEVICE
+    scalar (the rows inside groups: those the step's own router put on
+    the held experts), so the trip count is the data's and one compiled
+    loop serves every routing; ``keep`` [w, 1] is true on the window's
+    rows before ``live``. A carry that is a buffer is updated in place
+    (``put_rows``); what no trip reaches keeps what ``init`` held."""
+    live = jnp.asarray(live, jnp.int32)
+
+    def trip(i, carry):
+        r0 = i * w
+        keep = r0 + jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0) < live
+        return body(r0, keep, carry)
+
+    return jax.lax.fori_loop(0, (live + w - 1) // w, trip, init)
+
+
+def rows_at(x, r0, w):
+    """The window ``x[r0:r0 + w]``."""
+    return jax.lax.dynamic_slice_in_dim(x, r0, w, axis=0)
+
+
+def put_rows(buf, r0, rows):
+    """``buf`` with ``rows`` written at r0 (in place inside a loop)."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        buf, rows.astype(buf.dtype), r0, axis=0)
 
 
 def _call_tiles(lhs, rhs, live_rows=None):
@@ -455,29 +520,36 @@ def _call_tiles(lhs, rhs, live_rows=None):
     return (m, k, n, e), tile, dx_tile
 
 
-def grouped_matmul(lhs, rhs, group_sizes, live_rows=None):
+def grouped_matmul(lhs, rhs, group_sizes, live_rows=None, zero_behind=True):
     """``jax.lax.ragged_dot(lhs, rhs, group_sizes)``, differentiable in
     lhs and rhs: through the kernels above at the tile ``gmm_tile``
     gives the call, else ``ragged_dot`` itself. ``live_rows`` (a
     number, not traced): the group sizes may sum to less than the rows,
     about that many are expected inside groups, and the rows behind the
-    last group come back as zeros; None: they sum to all of them."""
+    last group come back as zeros; None: they sum to all of them.
+    ``zero_behind=False``, for a caller that reads the result by
+    ``over_live_rows`` and nowhere else: what it holds behind the last
+    group is then NOT DEFINED (a kernel leaves what the buffer held)."""
     dims, tile, _ = _call_tiles(lhs, rhs, live_rows)
     _note_dispatch("fwd", *dims, tile)
     if tile is None:
         out = jax.lax.ragged_dot(lhs, rhs, group_sizes)
     else:
-        out = _gmm_vjp(lhs, rhs, group_sizes, tile, live_rows)
-    return out if live_rows is None else _zero_behind(out, group_sizes)
+        out = _gmm_vjp(lhs, rhs, group_sizes, tile, live_rows,
+                       live_rows is not None and zero_behind)
+    return out
 
 
-def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None):
+def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None,
+                         zero_behind=True):
     """(d lhs, d rhs) of ``grouped_matmul(lhs, rhs, group_sizes)`` for
     the cotangent ``g`` [m, n], in the operands' dtypes: for a caller
     that saved its forward's results and does not run it again
     (``moe_experts_grad``), and the kernels' own vjp rule. ``live_rows``
-    as ``grouped_matmul``'s: d lhs has zeros behind the last group, and
-    what g or lhs hold there is never read into d rhs."""
+    and ``zero_behind`` as ``grouped_matmul``'s: d lhs has zeros behind
+    the last group (or, not zeroed, is not defined there), and what g
+    holds there is never read into d rhs (lhs is multiplied by zeros
+    there: it has to be finite in every tile a group reaches)."""
     dims, tile, dx_tile = _call_tiles(lhs, rhs, live_rows)
     _note_dispatch("bwd_dx", *dims, dx_tile)
     _note_dispatch("bwd_dw", *dims, tile)
@@ -490,8 +562,7 @@ def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None):
     else:
         # the rows' gradient contracts n and is k wide: its own product
         dx = gmm(g, rhs, group_sizes, dx_tile, transpose_rhs=True,
-                 name="moe.gmm.bwd_dx")
+                 name="moe.gmm.bwd_dx",
+                 zero_behind=live_rows is not None and zero_behind)
         dw = tgmm(lhs, g, group_sizes, tile)
-    if live_rows is not None:
-        dx = _zero_behind(dx, group_sizes)
     return dx, dw

@@ -355,12 +355,14 @@ def _parse_capture(path: str, warn: bool = True):
                 f"{e}); device profile degrades to source=\"estimate\"",
                 RuntimeWarning, stacklevel=3)
         return None
-    if not plane_totals:
+    # (a CPU process that has loaded libtpu, as one that compiled for a
+    # described chip has, writes /device:TPU planes without an event)
+    if not any(plane_totals):
         if warn:
             warnings.warn(
                 f"xplane capture under {path!r} has no /device:* plane "
-                f"(backend without device tracing, e.g. CPU); device "
-                f"profile degrades to source=\"estimate\"",
+                f"with an op (backend without device tracing, e.g. CPU); "
+                f"device profile degrades to source=\"estimate\"",
                 RuntimeWarning, stacklevel=3)
         return None
     return ops, plane_totals
