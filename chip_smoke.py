@@ -129,6 +129,24 @@ def _cache_misses():
     return int(monitor.counter("pt_executor_cache_misses_total").value())
 
 
+def compile_stages():
+    """{program: {"trace" | "lower" | "backend": seconds, "hit" |
+    "written": executables}} so far, from jax's own compile events as
+    monitor charges them (pt_compile_stage_seconds,
+    pt_compile_cache_total): "(outside)" is what no executor's first
+    call was around."""
+    from paddle_tpu import monitor
+
+    snap, out = monitor.snapshot(), {}
+    for r in snap["pt_compile_stage_seconds"]["values"]:
+        row = out.setdefault(r["labels"]["program"], {})
+        row[r["labels"]["stage"]] = round(r["sum"], 3)
+    for r in snap["pt_compile_cache_total"]["values"]:
+        row = out.setdefault(r["labels"]["program"], {})
+        row[r["labels"]["outcome"]] = int(r["value"])
+    return out
+
+
 # ---------------------------------------------------------------------------
 # phase 1: kernels
 # ---------------------------------------------------------------------------
@@ -792,6 +810,85 @@ def train_phase(cfg, batch=64, seq=256, steps=4, window_steps=8):
     return rep, losses
 
 
+def recompile_phase(width=2048, depth=6, rows=(4096, 2048), steps=4):
+    """A recompile in the middle of a profiled stretch: ``steps`` steady
+    steps of a small program, then a feed of another shape. The trace
+    must show ONE ``executor.first_call`` span on the host line, with
+    ``cause=feed_signature``, and of the program's spans it must be the
+    innermost at the start of the device's idle gap it made
+    (perf/spans.py's ``idle_by_span``)."""
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu import jax_cache, layers
+    from perf import spans, trace
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[width], dtype="float32")
+        h = x
+        for _ in range(depth):
+            h = layers.fc(h, width, act="relu")
+        loss = layers.mean(h)
+        fluid.optimizer.SGD(1e-3).minimize(loss)
+    main._amp = True
+    scope = fluid.Scope()
+    exe = fluid.Executor()
+    exe.run(startup, scope=scope)
+    feeds = [{"x": jax.device_put(np.ones((n, width), np.float32))}
+             for n in rows]
+
+    def step(feed):
+        return exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
+                       return_numpy=False)[0]
+
+    jax.block_until_ready([step(feeds[0]) for _ in range(2)])
+    trace_dir = jax_cache.fresh_dir("chip_smoke_recompile")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        # nothing waits between the calls: the device still runs the
+        # last steady step when the host meets the miss
+        outs = [step(feeds[0]) for _ in range(steps)]
+        outs += [step(feeds[1]) for _ in range(2)]
+        jax.block_until_ready(outs)
+    finally:
+        jax.profiler.stop_trace()
+    exe.close()
+    doc = spans.load(trace.find_xplane(trace_dir))
+    firsts = [e for e in spans._dispatch_line(doc)
+              if e[0] == "executor.first_call"]
+    rep = {"first_calls": [[round(e[2] / 1e6, 3), e[3]] for e in firsts]}
+    check(len(firsts) == 1
+          and firsts[0][3].get("cause") == "feed_signature"
+          and firsts[0][3].get("kind") == "step"
+          and firsts[0][3].get("program") == f"program{main._uid}",
+          f"expected one executor.first_call span with "
+          f"cause=feed_signature on the host line: {rep['first_calls']}")
+    reduced = spans.reduce(doc)
+    check(reduced is not None and reduced["host"] is not None,
+          f"the trace holds no device op to find an idle gap between "
+          f"(host line: {rep['first_calls']})")
+    # the host line also holds jax's and XLA's own events (PjitFunction,
+    # the HLO passes): by those the gap is named as they nest, by the
+    # program's spans alone as the program nests
+    program = {"planes": [
+        p if p["name"] != "/host:CPU" else dict(p, lines=[
+            dict(ln, events=[e for e in ln["events"]
+                             if e[0].startswith("executor.")])
+            for ln in p["lines"]])
+        for p in doc["planes"]]}
+    idle = spans.reduce(program)["host"]["idle_by_span"]
+    rep["idle_by_span_ms"] = [[k, round(v / 1e6, 3)] for k, v in idle]
+    rep["idle_by_any_host_event_ms"] = [
+        [k, round(v / 1e6, 3)]
+        for k, v in reduced["host"]["idle_by_span"][:3]]
+    say(f"  recompile {rep}")
+    check(idle and idle[0][0] == "executor.first_call",
+          f"the idle gap of the recompile is not named by its span: "
+          f"{rep['idle_by_span_ms']}")
+    return rep
+
+
 # ---------------------------------------------------------------------------
 # phase 3: serve
 # ---------------------------------------------------------------------------
@@ -1116,6 +1213,10 @@ def main() -> int:
 
     # 1. kernels: every case really is a Pallas call
     report["kernels"], _ = phase("kernels", kernel_phase)
+    # did this machine compile them or read them? jax's own account
+    report["compile_stages_after_kernels"] = compile_stages()
+    say(f"  compile stages (s) and cache outcomes so far: "
+        f"{report['compile_stages_after_kernels']}")
     for row in report["kernels"]:
         check(row["pallas_calls"] >= 1,
               f"kernel case {row['family']} t{row['t']} lowered without a "
@@ -1150,6 +1251,7 @@ def main() -> int:
               report["train"]["dispatch"]),
           f"train attention left the BTHD-small kernels: "
           f"{report['train']['dispatch']}")
+    report["recompile"], _ = phase("recompile", recompile_phase)
 
     # 3. serve: prefill is a Pallas call at tq=tk=32; decode (tq=1) is
     # the dense composition BY DESIGN (no kernel family takes one query

@@ -16,6 +16,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from paddle_tpu import monitor as _monitor
 from paddle_tpu.core.autodiff import GRAD_SLOT_PREFIX
 from paddle_tpu.core.lowering import resolve_op_def
 from paddle_tpu.core.registry import GRAD_OP_SUFFIX
@@ -57,7 +58,8 @@ def append_backward(
 ) -> List[Tuple[Parameter, Variable]]:
     # everything emitted here (the *_grad ops, the gradient sums and
     # fills between them) is the step's backward phase
-    with op_role_guard(loss.block.program, "bwd"):
+    with _monitor.span("backward.append_backward"), \
+            op_role_guard(loss.block.program, "bwd"):
         return _append_backward(loss, parameter_list, no_grad_set)
 
 

@@ -18,6 +18,8 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import threading
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -27,15 +29,27 @@ from paddle_tpu import monitor as _monitor
 from paddle_tpu.core import autodiff
 from paddle_tpu.core.registry import GRAD_OP_SUFFIX, OpDef, get_op_def, has_op
 
-# Ops lowered into XLA programs. exec_ops runs at TRACE time (cached
-# compiled steps never re-enter Python), so these count per COMPILE —
-# a growing rate mid-training means recompiles, the classic silent
-# step-time killer this telemetry exists to surface.
-_M_OPS_LOWERED = _monitor.counter(
-    "pt_ops_lowered_total", "ops traced into XLA programs (per compile)")
-_M_BLOCKS_TRACED = _monitor.counter(
-    "pt_blocks_traced_total",
-    "op-list traces (top-level blocks + control-flow sub-blocks)")
+# What this repo's op rules cost to trace. exec_ops runs at TRACE time
+# (a cached compiled step never re-enters Python), so the histogram's
+# count is the ops lowered, by op type, and its sum the seconds of a
+# first call's jaxpr trace spent in the rules and not in jax's machinery
+# around them; a count that grows mid-training means recompiles.
+_M_OP_TRACE = _monitor.histogram(
+    "pt_op_trace_seconds",
+    "trace-time seconds of an op's compute (its key derivation and AMP "
+    "casts included) while a block is lowered, by op type; a "
+    "control-flow op is charged what its sub-block's ops are not")
+
+
+class _Nested(threading.local):
+    """Seconds this thread has spent in exec_ops calls nested in an op's
+    compute (a control-flow op's sub-block), so the outer op is charged
+    only what its inner ops were not."""
+
+    s = 0.0
+
+
+_NESTED = _Nested()
 
 # MXU-heavy ops that run in bfloat16 under AMP: every f32 input (master
 # weights included) is cast to bf16 and the output STAYS bf16, so the whole
@@ -272,10 +286,16 @@ def exec_ops(
         amp = amp_active()
     if op_defs is None:
         op_defs = [resolve_op_def(op.type) for op in ops]
-    if _monitor.enabled():
-        _M_BLOCKS_TRACED.inc()
-        _M_OPS_LOWERED.inc(len(ops))
+    # trace time only, and only while a block is lowered (build-time shape
+    # inference runs sub-blocks through here too)
+    timed = _monitor.enabled() and lowering_active()
+    if timed:
+        t_block = time.perf_counter()
+        nested_before = _NESTED.s
     for idx, (op, opdef) in enumerate(zip(ops, op_defs)):
+        if timed:
+            t_op = time.perf_counter()
+            nested_op = _NESTED.s
         ins = {
             slot: [env[n] if n else None for n in names]
             for slot, names in op.inputs.items()
@@ -303,6 +323,10 @@ def exec_ops(
             elif amp and base_type in AMP_FLOW_OP_TYPES:
                 ins = _amp_flow_cast_ins(ins)
             outs = opdef.compute(ins, op.compute_attrs(), **kwargs)
+        if timed:
+            _M_OP_TRACE.observe(
+                time.perf_counter() - t_op - (_NESTED.s - nested_op),
+                labels={"op": op.type})
         for slot, names in op.outputs.items():
             vals = outs.get(slot, [])
             for i, n in enumerate(names):
@@ -311,4 +335,7 @@ def exec_ops(
                 v = vals[i] if i < len(vals) else None
                 if v is not None:
                     env[n] = v
+    if timed:
+        # this whole call, once, whatever its own sub-blocks added
+        _NESTED.s = nested_before + (time.perf_counter() - t_block)
     return env

@@ -58,6 +58,12 @@ _M_FEED_BYTES = _monitor.counter(
     "transfer: device-resident or staging-cached feeds count too)")
 _M_FETCH_BYTES = _monitor.counter(
     "pt_executor_fetch_bytes_total", "bytes across fetch arrays per step")
+_M_FIRST_CALLS = _monitor.counter(
+    "pt_executor_first_calls_total",
+    "first calls of a compiled entry (the call that traces, lowers and "
+    "compiles it) by kind (step | window) and by why the executor had no "
+    "entry (new_program, program_version, amp, strategy, feed_signature, "
+    "fetch_list, scope, evicted)")
 _M_NAN_FAILS = _monitor.counter(
     "pt_executor_nan_check_failures_total",
     "check_nan_inf scans that found non-finite values")
@@ -70,6 +76,26 @@ _F_STEP = _faults.site("executor.step")
 # RESOURCE_EXHAUSTED here drills the async-dispatch error path — the
 # device failure that surfaces only when the fetch lands
 _F_FETCH = _faults.site("executor.fetch")
+
+
+# Why a call found no compiled entry: the first part of its identity that
+# differs from the last entry built for the same program, in this order
+# (Executor._miss_cause; before them new_program, after them evicted).
+# A window's feed signature holds its length, its rotation and its
+# nan-tracking flavour too: everything the call passes beside the program.
+_MISS_CAUSES = ("program_version", "amp", "strategy", "feed_signature",
+                "fetch_list", "scope")
+# identities remembered per program: a recompile storm must not grow it
+_BUILT_CAPACITY = 64
+# what a call whose entry was cached opens in place of the first-call span
+_NOT_FIRST = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def _first_call_span(program, kind, cause, step):
+    with _monitor.span("executor.first_call", step=step, program=program,
+                       kind=kind, cause=cause), _monitor.compiling(program):
+        yield
 
 
 def _stage_feeds(feed_vals):
@@ -274,6 +300,9 @@ class Executor:
         self._cache: Dict[tuple, Any] = {}
         self._step = 0
         self._base_keys: Dict[tuple, Any] = {}
+        # (kind, program uid) -> the identities built for it, oldest
+        # first (_miss_cause reads it on a miss, nothing on a hit)
+        self._built: Dict[tuple, Dict[tuple, None]] = {}
         # keyed LRU of run_steps feed stagings: id-tuple of the host
         # arrays -> {"arrs": pinned host refs (id identity stays valid),
         # "stacked": device window, "owner": compiled-cache key}.
@@ -305,7 +334,11 @@ class Executor:
         # executor.state (gather, commit_state, shard_inputs),
         # executor.run_step (the jitted call alone), executor.commit.
         # None waits for the device: they time the host while the
-        # device runs ahead.
+        # device runs ahead. The call that built its entry (a cache
+        # miss) opens executor.first_call inside executor.run_step, with
+        # the program, the kind and the cause of the miss: the trace,
+        # the lowering and XLA (or the read from jax's cache) happen
+        # there, and monitor's jax listeners charge them to the program.
         with _monitor.span("executor.run", step=self._step):
             return self._run(program, feed, fetch_list, scope,
                              return_numpy, use_program_cache, async_fetch)
@@ -400,6 +433,11 @@ class Executor:
                 outcome, evictions = "miss", 0
             cache_hit = outcome != "miss"
             fn, lowered = entry
+            first = _NOT_FIRST if cache_hit else self._first_call(
+                "step", program, self._step,
+                (program.version, getattr(program, "_amp", False),
+                 compiled._uid if compiled is not None else 0, sig,
+                 tuple(run_fetch_names), scope._uid))
 
         with _monitor.span("executor.state"):
             state = self._gather_state(scope, lowered)
@@ -500,7 +538,7 @@ class Executor:
         cap = _roofline.begin_capture() if roof else None
         try:
             with _interp.spmd_ctx_scope(strategy), \
-                    _monitor.span("executor.run_step"):
+                    _monitor.span("executor.run_step"), first:
                 try:
                     _F_STEP.hit()
                     fetches, new_state = fn(state, feed_vals, base_key,
@@ -760,6 +798,11 @@ class Executor:
                 key, build, program)
             cache_hit = outcome != "miss"
             fn, lowered = entry
+            first = _NOT_FIRST if cache_hit else self._first_call(
+                "window", program, self._step,
+                (program.version, getattr(program, "_amp", False), 0,
+                 (sig, len(feed_list), int(steps), nan_track),
+                 tuple(run_fetch_names), scope._uid))
         with _monitor.span("executor.state"):
             state = self._gather_state(scope, lowered)
         base_key = self._base_key_for(program)
@@ -804,7 +847,7 @@ class Executor:
         # dispatch, yet a failure names the exact step inside it
         try:
             first_bad = None
-            with _monitor.span("executor.run_step"):
+            with _monitor.span("executor.run_step"), first:
                 try:
                     _F_STEP.hit()
                     if nan_track:
@@ -941,8 +984,48 @@ class Executor:
             _M_CACHE_EVICTIONS.inc(evicted)
         return entry, "miss", evicted, compile_ms
 
+    def _miss_cause(self, kind, uid, what):
+        """Why this executor holds no entry for the call: ``what`` (a
+        tuple aligned with _MISS_CAUSES) against the identities built
+        before for the same program. ``new_program``: none was;
+        ``evicted``: this very one was (the LRU dropped it, close() or
+        release_scope() did, or the caller passed
+        ``use_program_cache=False``); else the first part that differs
+        from the last one built. Runs on a miss only."""
+        seen = self._built.setdefault((kind, uid), {})
+        if not seen:
+            cause = "new_program"
+        elif what in seen:
+            cause = "evicted"
+        else:
+            last = next(reversed(seen))
+            cause = next(c for c, a, b in zip(_MISS_CAUSES, last, what)
+                         if a != b)
+        seen.pop(what, None)
+        seen[what] = None   # the last one built is the last key
+        while len(seen) > _BUILT_CAPACITY:
+            del seen[next(iter(seen))]
+        return cause
+
+    def _first_call(self, kind, program, step, what):
+        """The context the call that built its entry runs ``fn`` in: the
+        ``executor.first_call`` span with the program's id (the compile
+        reports' ``program<uid>``), the kind and the cause of the miss,
+        and ``monitor.compiling``, by which jax's compile events of the
+        call are charged to the program."""
+        cause = self._miss_cause(kind, program._uid, what)
+        if not _monitor.enabled():
+            return _NOT_FIRST
+        _M_FIRST_CALLS.inc(labels={"kind": kind, "cause": cause})
+        return _first_call_span(f"program{program._uid}", kind, cause, step)
+
     def _timed_build(self, build, program=None):
-        """Compile under the unified span; returns ``(entry,
+        """The executor's OWN build under the ``executor.compile`` span:
+        block analysis (``lowering.lower_block``) and the ``jax.jit``
+        wrap, milliseconds. NOT the trace of the op list into a jaxpr,
+        the lowering to StableHLO or XLA's compile (or the read from
+        jax's persistent cache): those happen inside the entry's first
+        call, under ``executor.first_call``. Returns ``(entry,
         compile_ms)`` (perf_counter interval) for the step log."""
         with _monitor.span("executor.compile"):
             t0 = time.perf_counter()

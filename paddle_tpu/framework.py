@@ -23,6 +23,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from paddle_tpu import monitor as _monitor
 from paddle_tpu import unique_name
 from paddle_tpu.core.registry import (
     GRAD_OP_SUFFIX,
@@ -732,22 +733,23 @@ class Program:
         return Program.from_proto(d)
 
     def clone(self, for_test: bool = False) -> "Program":
-        p = Program.parse_from_string(self.desc_str())
-        p._param_grad_map = dict(self._param_grad_map)
-        p._amp = self._amp
-        if for_test:
-            for b in p.blocks:
-                for op in b.ops:
-                    if "is_test" in op.attrs:
-                        op.attrs["is_test"] = True
-                    if op.type == "dropout":
-                        op.attrs["is_test"] = True
-                    if op.type == "batch_norm":
-                        op.attrs["is_test"] = True
-        else:   # a training clone keeps what its optimizer will append
-            p._step_updates = [dict(u) for u in self._step_updates]
-        p._bump_version()
-        return p
+        with _monitor.span("program.clone"):
+            p = Program.parse_from_string(self.desc_str())
+            p._param_grad_map = dict(self._param_grad_map)
+            p._amp = self._amp
+            if for_test:
+                for b in p.blocks:
+                    for op in b.ops:
+                        if "is_test" in op.attrs:
+                            op.attrs["is_test"] = True
+                        if op.type == "dropout":
+                            op.attrs["is_test"] = True
+                        if op.type == "batch_norm":
+                            op.attrs["is_test"] = True
+            else:   # a training clone keeps what its optimizer will append
+                p._step_updates = [dict(u) for u in self._step_updates]
+            p._bump_version()
+            return p
 
     def __repr__(self):
         return "\n".join(repr(b) for b in self.blocks)

@@ -94,6 +94,17 @@ Grown in PR 9 with the fleet observability plane:
     report (compile-report peak bytes vs the budget flag, largest live
     buffers, recent step records) dumped under ``stall_dump_dir``.
 
+Grown in PR 36 with set-up seen from inside:
+
+11. **Compile stages** — with telemetry on, listeners on
+    ``jax.monitoring`` time every jaxpr trace, lowering to StableHLO and
+    backend compile (or read from jax's persistent cache) into
+    ``pt_compile_stage_seconds{program, stage}``, count the cache's
+    outcomes into ``pt_compile_cache_total`` and every traced function
+    into ``pt_jax_traces_total{fun_name}``, each under the program whose
+    first call (``compiling``) was on the thread, ``(outside)`` when
+    none was.
+
 Everything is off by default behind typed flags (flags.py); flipping
 ``telemetry`` at runtime takes effect immediately via a flag watcher,
 and every disabled instrument call costs one module-level boolean check.
@@ -146,6 +157,8 @@ def enabled() -> bool:
 def _sync_from_flags(_value=None):
     global _enabled
     _enabled = bool(_flags.get_flag("telemetry"))
+    if _enabled:
+        _listen_to_jax()
 
 
 def enable(step_log_path: Optional[str] = None,
@@ -632,7 +645,10 @@ STEP_LOG_FIELDS: Dict[str, tuple] = {
     "wall_ms": ((float, int), True,
                 "host wall time of the run call, perf_counter-based"),
     "compile_ms": ((float, int, type(None)), True,
-                   "XLA lower+jit wrap time; null on an in-memory hit"),
+                   "the executor's own build of a missed entry (block "
+                   "analysis and the jit wrap; the trace, the lowering "
+                   "and XLA are inside the first call: "
+                   "pt_compile_stage_seconds); null on an in-memory hit"),
     "cache": ((str,), True,
               "compiled-entry cache outcome: 'hit' (in-memory) or "
               "'miss' (built by this call)"),
@@ -875,7 +891,10 @@ COMPILE_REPORT_FIELDS: Dict[str, tuple] = {
                "executable; 'estimate' when the analysis APIs were "
                "unavailable and only op-count estimates are present"),
     "compile_ms": ((float, int, type(None)), True,
-                   "executor-side build time (trace + jit wrap)"),
+                   "the executor's own build (block analysis and the "
+                   "jit wrap: the executor.compile span), NOT the jaxpr "
+                   "trace, the lowering or XLA, which happen inside "
+                   "the first call (pt_compile_stage_seconds)"),
     "analysis_ms": ((float, int, type(None)), True,
                     "AOT lower+compile time of the analysis twin — the "
                     "closest measure of true XLA compile cost; null "
@@ -951,7 +970,9 @@ def _compile_instruments():
             "by program")
         _M_COMPILE_SECONDS = histogram(
             "pt_compile_seconds",
-            "XLA compile time per fresh executor compile")
+            "per compile report: the AOT lower+compile of its analysis "
+            "twin, else the executor's own build (compile_ms); a first "
+            "call's trace, lowering and XLA are pt_compile_stage_seconds")
 
 
 def compile_reports_active() -> bool:
@@ -1005,6 +1026,128 @@ def compile_reports() -> Dict[str, Dict[str, Any]]:
     order, oldest first)."""
     with _COMPILE_LOCK:
         return {k: dict(v) for k, v in _COMPILE_REPORTS.items()}
+
+
+# ---------------------------------------------------------------------------
+# compile stages: jax's own compile events, by the program that caused them
+# ---------------------------------------------------------------------------
+
+# the program label of a compile no executor first call was around (a
+# benchmark's reference under jax.jit, device_put helpers, the AOT twin
+# of a compile report)
+OUTSIDE = "(outside)"
+
+# jax.monitoring's duration events of a compile -> stage. jax fires each
+# from one context manager (jax._src.dispatch.log_elapsed_time): a scalar
+# of the same name when the stage begins, then its duration and its time
+# span (start, end: time.time()) when it ends, each with fun_name.
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# cache_misses fires exactly when jax WRITES an entry: a program over the
+# cache's minimum compile time that the cache did not hold
+_JAX_CACHE_OUTCOMES = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "written",
+}
+
+_M_COMPILE_STAGE = None
+_M_COMPILE_CACHE = None
+_M_JAX_TRACES = None
+_jax_listening = False
+
+
+def _stage_instruments():
+    global _M_COMPILE_STAGE, _M_COMPILE_CACHE, _M_JAX_TRACES
+    if _M_COMPILE_STAGE is None:
+        _M_COMPILE_STAGE = histogram(
+            "pt_compile_stage_seconds",
+            "jax's compile stages by the program whose first call they "
+            "ran in ('(outside)': none): trace (jaxpr trace), lower (to "
+            "StableHLO), backend (XLA's compile, or the read from jax's "
+            "persistent cache); a stage nested in another is part of "
+            "the outer one and not observed again")
+        _M_COMPILE_CACHE = counter(
+            "pt_compile_cache_total",
+            "outcomes of jax's persistent compile cache by program: hit "
+            "(executable read) or written (compiled, and stored because "
+            "the cache did not hold it)")
+        _M_JAX_TRACES = counter(
+            "pt_jax_traces_total",
+            "jaxpr traces, nested ones included: inside an executor's "
+            "first call by the name of the function traced, all others "
+            "in the one row fun_name='(outside)'")
+
+
+@contextlib.contextmanager
+def compiling(program: str):
+    """For the length of the block, this thread's jax compile events are
+    the first call of ``program`` (``'program<uid>'``): the executors
+    open it with their ``executor.first_call`` span, with telemetry on."""
+    prev = getattr(_TLS, "compiling", None)
+    _TLS.compiling = program
+    try:
+        yield
+    finally:
+        _TLS.compiling = prev
+
+
+def _on_jax_stage_begin(event, value, **kw):
+    if not _enabled:
+        return
+    if event in _JAX_STAGES:
+        _TLS.jax_stages = getattr(_TLS, "jax_stages", 0) + 1
+
+
+def _on_jax_stage_end(event, start_time, end_time, **kw):
+    if not _enabled:
+        return
+    stage = _JAX_STAGES.get(event)
+    if stage is None:
+        return
+    program = getattr(_TLS, "compiling", None)
+    if stage == "trace":
+        # by name only what a first call traces: the label cap goes to
+        # the program's own step, not to whatever else the process jits
+        _M_JAX_TRACES.inc(labels={"fun_name": kw.get("fun_name", "?")
+                                  if program else OUTSIDE})
+    # stages still open on this thread (one whose beginning telemetry
+    # missed counts as outermost)
+    open_ = max(getattr(_TLS, "jax_stages", 1) - 1, 0)
+    _TLS.jax_stages = open_
+    if open_ == 0:
+        # outermost only: a jitted helper traced (or a constant's small
+        # program compiled) inside a trace is seconds of that trace
+        _M_COMPILE_STAGE.observe(
+            end_time - start_time,
+            labels={"program": program or OUTSIDE, "stage": stage})
+
+
+def _on_jax_cache_event(event, **kw):
+    if not _enabled:
+        return
+    outcome = _JAX_CACHE_OUTCOMES.get(event)
+    if outcome is not None:
+        _M_COMPILE_CACHE.inc(
+            labels={"program": getattr(_TLS, "compiling", None) or OUTSIDE,
+                    "outcome": outcome})
+
+
+def _listen_to_jax():
+    """Register the three listeners, once a process, the first time
+    telemetry is turned on: a process that never turns it on carries
+    none."""
+    global _jax_listening
+    if _jax_listening:
+        return
+    _jax_listening = True
+    import jax.monitoring as jm
+
+    jm.register_scalar_listener(_on_jax_stage_begin)
+    jm.register_event_time_span_listener(_on_jax_stage_end)
+    jm.register_event_listener(_on_jax_cache_event)
 
 
 # ---------------------------------------------------------------------------
@@ -2403,6 +2546,7 @@ _span_seconds = histogram(
 _overflow_total()
 _stall_counter()
 _compile_instruments()
+_stage_instruments()
 _phase_instruments()
 _trace_instruments()
 _devmem_instruments()

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+from paddle_tpu import monitor as _monitor
 from paddle_tpu import unique_name
 from paddle_tpu.backward import append_backward
 from paddle_tpu.framework import (
@@ -124,7 +125,8 @@ class Optimizer:
         """Returns the optimizer update Operators appended to the block."""
         # clip, regularizer, learning-rate and update ops: the step's
         # optimizer phase
-        with op_role_guard(default_main_program(), "opt"):
+        with _monitor.span("optimizer.apply_gradients"), \
+                op_role_guard(default_main_program(), "opt"):
             return self._apply_gradients(params_grads)
 
     def _apply_gradients(self, params_grads):
