@@ -508,8 +508,16 @@ def gdn_dispatch():
     return la.dispatch_counts()
 
 
+def conv_dispatch():
+    """{"impl pass shape taps<n>": calls}: the causal convolutions
+    lowered so far (pt_causal_conv_dispatch_total)."""
+    from paddle_tpu.ops import linear_attention_ops as la
+
+    return la.conv_dispatch_counts()
+
+
 def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
-              **overrides):
+              conv_c=1024, **overrides):
     """The hybrid decoder's new mechanisms (models/qwen3_next.py).
 
     1. The cell ``qwen3next-train-s8192``'s train step (one period of
@@ -517,21 +525,25 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
        held, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
        and the dispatch counters are held to what the cell must lower:
        three delta-rule calls forward and three backward, all ``kernel``
-       at the configuration's chunk; one attention call each way at 2
+       at the configuration's chunk, and as many causal convolutions in
+       front of them, all ``kernel`` too; one attention call each way at 2
        key/value heads of 256 with its tile; every grouped matmul of
        the held experts on a tile chosen for 160 rows an expert (tm128),
        none through ``ragged_dot``. ``overrides`` cut the config for the CPU tests.
     2. On the device: the chunkwise delta rule with bf16 operands (the
        ``gdn.rule.*`` kernels, whose time by name a short trace gives),
        forward and its own backward, against the step-by-step
-       recurrence in float32; and grouped-query attention through the
-       BHTD kernels against the dense composition that copies K and V."""
+       recurrence in float32; the causal convolution's ``gdn.conv.*``
+       kernels at ``conv_c`` channels against the XLA form they replace
+       (Y, dX, dW); and grouped-query attention through the BHTD kernels
+       against the dense composition that copies K and V."""
     import jax
     import jax.numpy as jnp
 
     import paddle_tpu as fluid
     from paddle_tpu.models import qwen3_next as M
     from paddle_tpu.ops import linear_attention_ops as la
+    from paddle_tpu.parallel import causal_conv
     from paddle_tpu.parallel import flash_attention as fa
 
     cfg = M.Qwen3NextConfig(**{**dict(
@@ -542,13 +554,15 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
         model = M.build(cfg)
         fluid.optimizer.Adam(1e-4).minimize(model["loss"])
     main._amp = True
-    before = (attention_dispatch(), gmm_dispatch(), gdn_dispatch())
+    reads = (attention_dispatch, gmm_dispatch, gdn_dispatch, conv_dispatch)
+    before = tuple(read() for read in reads)
     _lower_train_step(main, model["loss"], seq)
-    attn, gmm, gdn = (_dispatch_since(b, read) for b, read in zip(
-        before, (attention_dispatch, gmm_dispatch, gdn_dispatch)))
+    attn, gmm, gdn, conv = (_dispatch_since(b, read)
+                            for b, read in zip(before, reads))
     n_gdn = sum(not cfg.is_full_attention(i)
                 for i in range(cfg.num_hidden_layers))
-    say(f"  lowered: gdn {gdn}; attention {attn}; grouped matmuls {gmm}")
+    say(f"  lowered: gdn {gdn}; conv {conv}; attention {attn}; grouped "
+        f"matmuls {gmm}")
     for direction in ("fwd", "bwd"):
         rows = {k: v for k, v in gdn.items() if f" {direction} " in k}
         check(sum(rows.values()) == n_gdn and all(
@@ -557,6 +571,11 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
             f"expected {n_gdn} delta-rule calls {direction} through the "
             f"gdn.* kernels at chunk {cfg.gdn_chunk}, none chunked, none "
             f"recurrent: {gdn}")
+        rows = {k: v for k, v in conv.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_gdn and all(
+            k.split()[0] == "kernel" for k in rows),
+            f"expected {n_gdn} causal convolutions {direction} through the "
+            f"gdn.conv.* kernels, none as XLA ops: {conv}")
     kv = f"kv{cfg.num_key_value_heads} dh{cfg.head_dim}"
     check(len(attn) == 2 and all(
         k.startswith("bhtd ") and kv in k and k.endswith("]") for k in attn),
@@ -595,9 +614,27 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
                                beta)
             return (out, *vjp(do))
 
+    taps = cfg.linear_conv_kernel_dim
+    xc, dyc = (jnp.asarray(r.randn(1, t_check, conv_c), bf) for _ in "xd")
+    wc = jnp.asarray(r.randn(conv_c, taps) * 0.5, f32)
+    conv_tile = causal_conv.conv_tile(t_check, conv_c, taps, bf)
+    check(conv_tile is not None,
+          f"no conv tile for t{t_check} c{conv_c} taps{taps}")
+
+    @jax.jit
+    def conv_kernels(x, w, dy):
+        return (causal_conv.causal_conv_fwd(x, w, conv_tile),
+                *causal_conv.causal_conv_bwd(x, w, dy, conv_tile))
+
+    @jax.jit
+    def conv_xla(x, w, dy):
+        y, vjp = jax.vjp(lambda x, w: la._conv_xla(x, w, "silu"), x, w)
+        return (y, *vjp(dy))
+
     errs = {}
     names = ("o", "dq", "dk", "dv", "dg", "dbeta")
     got = jax.block_until_ready(chunked(q, k, v, g, beta, do))
+    got_conv = jax.block_until_ready(conv_kernels(xc, wc, dyc))
     kernel_ms = {}
     if jax.default_backend() == "tpu":
         # the gdn.* kernels' time by name, from a trace of three calls
@@ -605,7 +642,8 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
         trace_dir = os.path.join(os.path.dirname(REPORT_PATH), "gdn_trace")
         jax.profiler.start_trace(trace_dir)
         for _ in range(3):
-            jax.block_until_ready(chunked(q, k, v, g, beta, do))
+            jax.block_until_ready((chunked(q, k, v, g, beta, do),
+                                   conv_kernels(xc, wc, dyc)))
         jax.profiler.stop_trace()
         summary = perf_trace.reduce(perf_trace.load(
             perf_trace.find_xplane(trace_dir))) or {}
@@ -613,11 +651,13 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
             name: round(s_ / 3 * 1e3, 4)
             for name, s_ in summary.get("by_kernel_s", {}).items()
             if name.startswith("gdn.")}
-        say(f"  gdn kernels, ms a call at t{t_check} hk{hk} hv{hv}: "
-            f"{kernel_ms}")
-        check(len(kernel_ms) == 2,
-              f"expected the forward and the backward gdn.* kernel in the "
-              f"trace: {summary.get('by_kernel_s')}")
+        say(f"  gdn kernels, ms a call at t{t_check} hk{hk} hv{hv} "
+            f"c{conv_c}: {kernel_ms}")
+        check(sorted(kernel_ms) == ["gdn.conv.bwd", "gdn.conv.fwd",
+                                    "gdn.rule.bwd", "gdn.rule.fwd"],
+              f"expected the forward and the backward gdn.rule.* and "
+              f"gdn.conv.* kernels in the trace: "
+              f"{summary.get('by_kernel_s')}")
     for name, a, b in zip(names, got, recurrent(q, k, v, g, beta, do)):
         a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
         check(bool(jnp.isfinite(a).all()), f"delta rule {name} not finite")
@@ -627,6 +667,15 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
               f"delta rule {name}: the chunkwise form (bf16 operands) is "
               f"off the float32 recurrence by {errs[name]:.4f} of its max "
               f"(tolerance {GDN_REL_TOL})")
+    for name, a, b in zip(("conv_y", "conv_dx", "conv_dw"), got_conv,
+                          conv_xla(xc, wc, dyc)):
+        a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
+        check(bool(jnp.isfinite(a).all()), f"{name} not finite")
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= KERNEL_REL_TOL,
+              f"{name}: the gdn.conv.* kernels are off the XLA form by "
+              f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
     h, hkv, dh = gqa
     tile = fa.bhtd_tile(h, t_check, t_check, dh=dh, group=h // hkv)
     check(tile is not None, f"no bhtd tile for h{h} kv{hkv} dh{dh}")
@@ -634,8 +683,8 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     ka = jnp.asarray(r.randn(1, hkv, t_check, dh) * 0.3, bf)
     va, ga = (jnp.asarray(r.randn(1, n, t_check, dh), bf) for n in (hkv, h))
     _bhtd_against_dense(qa, ka, va, ga, errs, "grouped-query attention")
-    row = {"gdn": gdn, "attention": attn, "grouped_matmuls": gmm,
-           "gdn_kernel_ms": kernel_ms, "gqa_tile": fa.tile_label(tile),
+    row = {"gdn": gdn, "conv": conv, "attention": attn,
+           "grouped_matmuls": gmm, "gdn_kernel_ms": kernel_ms, "gqa_tile": fa.tile_label(tile),
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  gdn {row['rel_err']}")
     return row

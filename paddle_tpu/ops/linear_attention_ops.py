@@ -47,6 +47,15 @@ float32 operands, other widths or chunks, a program under a mesh. The
 kernels are custom calls, which XLA cannot CSE: the grad op recomputes
 inside its one kernel and runs no kernel of the forward again.
 
+The causal convolution in front is written twice as well, chosen per
+call by ``parallel/causal_conv.conv_tile``: the ``gdn.conv.fwd`` /
+``gdn.conv.bwd`` Pallas kernels (bf16 on a TPU, channels a multiple of
+128, no mesh: X, Y, dY and dX cross HBM once each as bf16, float32 only
+in VMEM, the earlier rows a halo) and float32 XLA ops over a padded
+copy of X everywhere else (``_conv_xla``); its backward pass is its own
+grad op (``causal_conv1d_grad``), which saves nothing but X;
+``pt_causal_conv_dispatch_total`` records which (``kernel`` or ``xla``).
+
 ``impl="recurrent"`` is the recurrence step by step (``lax.scan`` over
 positions, differentiated by jax): the fallback a caller asks for, never
 taken silently: ``pt_linear_attention_dispatch_total`` records the
@@ -61,6 +70,7 @@ import jax.numpy as jnp
 
 from paddle_tpu import monitor as _monitor
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.parallel import causal_conv as _conv
 from paddle_tpu.parallel import gated_delta_rule as _kernels
 
 DEFAULT_CHUNK = 64
@@ -72,6 +82,13 @@ _M_DISPATCH = _monitor.counter(
     "positions of one scan step; 1 for the recurrent form) and impl "
     "(kernel: a gdn.* Pallas kernel; chunked: the chunkwise form as XLA "
     "ops; recurrent: one scan step a position)")
+
+
+_M_CONV_DISPATCH = _monitor.counter(
+    "pt_causal_conv_dispatch_total",
+    "causal_conv1d calls lowered, by pass (fwd, bwd), shape (batch, "
+    "positions, channels), taps and impl (kernel: a gdn.conv.* Pallas "
+    "kernel; xla: float32 XLA ops over a padded copy of X)")
 
 
 def _x(ins, slot, i=0):
@@ -92,21 +109,66 @@ def _note_dispatch(direction, q, v, chunk, impl):
         "chunk": str(chunk), "impl": impl})
 
 
+def _note_conv(direction, x, taps, impl):
+    from paddle_tpu.core import interp
+
+    if not _monitor.enabled() or not interp.lowering_active():
+        return
+    _M_CONV_DISPATCH.inc(labels={
+        "pass": direction, "shape": " ".join(
+            f"{n}{d}" for n, d in zip("btc", x.shape)),
+        "taps": str(taps), "impl": impl})
+
+
+def _counts(counter, name_of):
+    out = {}
+    for row in _monitor.snapshot()[counter.name]["values"]:
+        name = name_of(row["labels"])
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
+
+
+def conv_dispatch_counts():
+    """{"impl pass shape taps<n>": calls lowered so far}: the conv's
+    counter as chip_smoke.py prints it."""
+    return _counts(_M_CONV_DISPATCH, lambda lb: (
+        f"{lb.get('impl', '?')} {lb.get('pass', '?')} "
+        f"{lb.get('shape', '?')} taps{lb.get('taps', '?')}"))
+
+
 def dispatch_counts():
     """{"impl pass shape chunk<C>": calls lowered so far}: the counter
     as chip_smoke.py prints it."""
-    out = {}
-    for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
-        lb = row["labels"]
-        name = (f"{lb.get('impl', '?')} {lb.get('pass', '?')} "
-                f"{lb.get('shape', '?')} chunk{lb.get('chunk', '?')}")
-        out[name] = out.get(name, 0) + int(row["value"])
-    return out
+    return _counts(_M_DISPATCH, lambda lb: (
+        f"{lb.get('impl', '?')} {lb.get('pass', '?')} "
+        f"{lb.get('shape', '?')} chunk{lb.get('chunk', '?')}"))
 
 
 # ---------------------------------------------------------------------------
 # the small ops around the recurrence
 # ---------------------------------------------------------------------------
+
+
+def _conv_xla(x, w, act):
+    """``causal_conv1d`` as XLA ops: float32 passes over a padded X."""
+    taps, t = w.shape[-1], x.shape[1]
+    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    y = sum(xf[:, j:j + t] * wf[:, j] for j in range(taps))
+    if act == "silu":
+        y = jax.nn.silu(y)
+    return y.astype(x.dtype)
+
+
+def _conv_args(ins, attrs, direction):
+    """(X, W, act, ``conv_tile``'s answer for the call), noted."""
+    x, w = _x(ins, "X"), _x(ins, "W")
+    act = attrs.get("act", "silu")
+    tile = None
+    if x.ndim == 3 and w.ndim == 2 and act in ("silu", ""):
+        tile = _conv.conv_tile(x.shape[1], x.shape[2], w.shape[1], x.dtype)
+    _note_conv(direction, x, w.shape[-1], "kernel" if tile else "xla")
+    return x, w, act, tile
 
 
 @register_op("causal_conv1d", diff_inputs=("X", "W"))
@@ -116,15 +178,29 @@ def _causal_conv1d(ins, attrs):
     y_t = sum_j W[:, j] * x_{t - (taps - 1) + j} (positions before the
     first count as zeros; HF's ``Conv1d(groups=c, padding=taps - 1)`` cut
     to t), no bias, then ``act`` ("silu" or ""). Products and the sum
-    in float32, the result in X's dtype."""
-    x, w = _x(ins, "X"), _x(ins, "W")
-    taps, t = w.shape[-1], x.shape[1]
-    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    wf = w.astype(jnp.float32)
-    y = sum(xf[:, j:j + t] * wf[:, j] for j in range(taps))
-    if attrs.get("act", "silu") == "silu":
-        y = jax.nn.silu(y)
-    return {"Y": [y.astype(x.dtype)]}
+    in float32, the result in X's dtype: the ``gdn.conv.fwd`` kernel
+    where ``parallel/causal_conv.conv_tile`` gives the call a tile (bf16
+    on a TPU, no mesh, channels a multiple of 128), XLA ops everywhere
+    else."""
+    x, w, act, tile = _conv_args(ins, attrs, "fwd")
+    if tile:
+        return {"Y": [_conv.causal_conv_fwd(x, w, tile, act)]}
+    return {"Y": [_conv_xla(x, w, act)]}
+
+
+@register_op("causal_conv1d_grad", no_grad=True)
+def _causal_conv1d_grad(ins, attrs):
+    """The backward pass of ``causal_conv1d`` from X, W and Y's
+    cotangent (nothing else is saved: the pre-activation is made again):
+    the ``gdn.conv.bwd`` kernel where the call has a tile, else jax's
+    vjp of the XLA form. dX in X's dtype, dW in W's."""
+    x, w, act, tile = _conv_args(ins, attrs, "bwd")
+    dy = _x(ins, "GRAD::Y").astype(x.dtype)
+    if tile:
+        dx, dw = _conv.causal_conv_bwd(x, w, dy, tile, act)
+    else:
+        dx, dw = jax.vjp(lambda x, w: _conv_xla(x, w, act), x, w)[1](dy)
+    return {"GRAD::X": [dx], "GRAD::W": [dw.astype(w.dtype)]}
 
 
 @register_op("gdn_gates", diff_inputs=("B", "A", "ALog", "DtBias"))

@@ -1,5 +1,6 @@
-"""The BHTD attention kernels, the experts' grouped-matmul kernels and
-the gated delta rule's kernels compile for a TPU v5e at the shapes the chip runs them at, on this
+"""The BHTD attention kernels, the experts' grouped-matmul kernels, the
+gated delta rule's kernels and the causal convolution's in front of it
+compile for a TPU v5e at the shapes the chip runs them at, on this
 CPU-only machine (one file for all: the worker that is handed it is
 the one that loads libtpu): the TPU's compiler is
 installed and compiles for a chip that is described, not attached
@@ -15,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.parallel import causal_conv as cc
 from paddle_tpu.parallel import flash_attention as fa
 from paddle_tpu.parallel import gated_delta_rule as gdr
 from paddle_tpu.parallel import grouped_matmul as gm
@@ -262,3 +264,26 @@ def test_gated_delta_rule_kernels_compile(t, hk, hv, one_chip, real_kernels):
     # is staged heads-first or repeated to the value heads (o alone
     # leaves heads-first, in bf16)
     assert f"f32[1,{hv},{t},128]" not in text
+
+
+def test_causal_conv_kernels_compile(one_chip, real_kernels):
+    """The conv in front of Qwen3-Next's delta rule as
+    qwen3next-train-s8192 lowers it: [1, 8192, 8192] bf16, 4 taps, silu,
+    blocks of 1024 x 512, forward and the backward pass from X alone:
+    the sublane rolls over [halo; rows], the 16-row block in front and
+    the dW block that stays over a lane block's walk pass Mosaic."""
+    bf, t, c, taps = jnp.bfloat16, 8192, 8192, 4
+    tile = cc.conv_tile(t, c, taps, bf, "tpu", False)
+    assert tile == (1024, 512)
+    x = jax.ShapeDtypeStruct((1, t, c), bf, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((c, taps), jnp.float32, sharding=one_chip)
+
+    def both(x, w, dy):
+        return (cc.causal_conv_fwd(x, w, tile),
+                cc.causal_conv_bwd(x, w, dy, tile))
+
+    text = jax.jit(both).lower(x, w, x).compile().as_text()
+    for name in ("gdn.conv.fwd", "gdn.conv.bwd"):
+        assert name in text, name
+    # nothing float32 of X's size: no padded copy, no float32 Y
+    assert f"f32[1,{t}," not in text and f"f32[1,{t + taps - 1}," not in text

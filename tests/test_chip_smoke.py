@@ -130,30 +130,38 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
         telemetry, monkeypatch):
     """The phase at a cut config through the interpreters: a period of
     three delta-rule layers and one grouped-query attention layer
-    lowers 3 + 3 delta-rule calls through the gdn.* kernels, one
-    attention call each way that names its 2 key/value heads, and 36
+    lowers 3 + 3 delta-rule calls through the gdn.* kernels and 3 + 3
+    causal convolutions through theirs, one attention call each way that names its 2 key/value heads, and 36
     grouped matmuls on tiles of 128 rows; on the device (here: the CPU,
     at a width that gets no tile) the chunkwise form agrees with the
-    recurrence and the kernels with the dense composition."""
+    recurrence, the conv's kernels with the XLA form and the attention
+    kernels with the dense composition."""
+    from paddle_tpu.parallel import causal_conv as cc
     from paddle_tpu.parallel import gated_delta_rule as gdr
     from paddle_tpu.parallel import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(gdr, "_INTERPRET", True)
+    monkeypatch.setattr(cc, "_INTERPRET", True)
     row = chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2),
-                               width=16, gqa=(4, 2, 128), **GDN_TINY)
+                               width=16, gqa=(4, 2, 128), conv_c=256,
+                               **GDN_TINY)
     shape = "b1 t512 hk1 hv2 dk128 dv128 chunk64"
     assert row["gdn"] == {f"kernel fwd {shape}": 3,
                           f"kernel bwd {shape}": 3}
+    assert row["conv"] == {"kernel fwd b1 t512 c512 taps4": 3,
+                           "kernel bwd b1 t512 c512 taps4": 3}
     assert sorted(row["attention"]) == [
         f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]"
         for d in ("bwd", "fwd")]
     assert sum(row["grouped_matmuls"].values()) == 36
     assert set(row["rel_err"]) == {
-        "o", "dq", "dk", "dv", "dg", "dbeta", "attn_o", "attn_dq",
-        "attn_dk", "attn_dv"}
+        "o", "dq", "dk", "dv", "dg", "dbeta", "conv_y", "conv_dx",
+        "conv_dw", "attn_o", "attn_dq", "attn_dk", "attn_dv"}
     assert max(row["rel_err"].values()) < chip_smoke.GDN_REL_TOL
+    assert max(v for k, v in row["rel_err"].items()
+               if k.startswith("conv_")) < chip_smoke.KERNEL_REL_TOL
 
 
 @pytest.mark.parametrize("why,overrides", [
@@ -169,6 +177,20 @@ def test_gdn_phase_fails_on_a_call_without_the_kernel(
                        match="none chunked, none recurrent"):
         chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2), width=16,
                              gqa=(4, 2, 128), **overrides, **GDN_TINY)
+
+
+def test_gdn_phase_fails_on_a_convolution_without_the_kernel(
+        telemetry, monkeypatch):
+    # the conv's kernels off (CPU, no interpreter): six XLA forms
+    from paddle_tpu.parallel import gated_delta_rule as gdr
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(gdr, "_INTERPRET", True)
+    with pytest.raises(chip_smoke.SmokeFailure, match="none as XLA ops"):
+        chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2), width=16,
+                             gqa=(4, 2, 128), conv_c=256, **GDN_TINY)
 
 
 MLA_TINY = dict(
