@@ -1394,7 +1394,8 @@ def switch_moe(input, num_experts, d_ff=None, capacity_factor=2.0,
 def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
              param_attr=None, name=None, held=None, shared_d_ff=None,
              shared_gate=True, score="softmax", routed_scale=1.0,
-             select_bias=False, bias_update_rate=0.001):
+             select_bias=False, bias_update_rate=0.001, act="silu",
+             router_input=None):
     """Dropless top-k Mixture-of-Experts with SwiGLU experts (OLMoE,
     arXiv:2409.02060): ``input`` [.., d] tokens -> ``(out, lb_loss,
     z_loss, expert_rows, top_i)``. out, in input's shape, is the sum
@@ -1436,6 +1437,12 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
     sign(mean(count) - count_e) from this step's choices. A program
     with no optimizer (an eval clone) never moves it.
 
+    ``act="relu"``: ReGLU experts, (relu(x WGate[e]) * (x WUp[e]))
+    WDown[e] (SmallThinker); the shared expert stays SwiGLU.
+    ``router_input`` (input's shape): the router scores THAT tensor
+    (SmallThinker: the attention's normalised input) while dispatch and
+    the experts work on ``input``; the router's gradient flows into it.
+
     Four ops (ops/moe_ops.py), each under a name scope of its own:
     router, dispatch, experts, combine; the shared expert's ops under a
     fifth, shared. Parameters: ``{name}_router.w`` [d, E],
@@ -1476,11 +1483,18 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
             score == "softmax" and (select_bias or routed_scale != 1.0)):
         raise ValueError(f"topk_moe: score={score!r} with a selection bias "
                          f"or a routed_scale")
+    if act not in ("silu", "relu"):
+        raise ValueError(f"topk_moe: act={act!r}")
+    # (absent for SwiGLU: the op's default, and the program's text today's)
+    act_attrs = {} if act == "silu" else {"act": act}
     with name_scope("router"):
         top_w, top_i = var("float32"), var("int32", True)
         lb, z = var("float32"), var("float32")
-        router_in = {"X": input, "W": param("_router.w", [d, num_experts])}
+        router_in = {"X": input if router_input is None else router_input,
+                     "W": param("_router.w", [d, num_experts])}
         router_attrs = {"k": int(top_k), "norm_topk": bool(norm_topk_prob)}
+        if router_input is not None:
+            router_attrs["input"] = "other"
         if score == "sigmoid":
             router_attrs.update(score=score, routed_scale=float(routed_scale))
         if select_bias:
@@ -1521,7 +1535,8 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
                     "WGate": param("_gate.w", [n_held, d, d_ff]),
                     "WUp": param("_up.w", [n_held, d, d_ff]),
                     "WDown": param("_down.w", [n_held, d_ff, d])},
-            outputs={"Ys": ys, "Gate": gate, "Up": up}, attrs=held_attrs)
+            outputs={"Ys": ys, "Gate": gate, "Up": up},
+            attrs={**held_attrs, **act_attrs})
     with name_scope("combine"):
         out = var(input.dtype)
         helper.append_op(

@@ -9,6 +9,7 @@ dist_transformer.py (slice/pad helpers) with static-shape mask tensors
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -32,10 +33,28 @@ _M_DISPATCH = _monitor.counter(
     "(heads, query rows and key "
     "rows of one grid step, where the family picks them by the shape: "
     "bhtd) and replicated_over (mesh axes whose every rank repeats that "
-    "same call)")
+    "same call). A windowed call's shape ends in w<window> and the row "
+    "carries band: skip (the kernels walk the band, no block outside it "
+    "is a step), mask (the triangle walked and masked) or dense")
 
 
-def _note_dispatch(family, direction, dims, replicated_over=()):
+def _windowed(attrs, q, k, bthd, ring):
+    """The op's ``window`` attr as the kernels run it: None where the
+    call forgets nothing (absent, or as long as the sequence)."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    t_axis = 1 if bthd else 2
+    window = fa._band(attrs.get("window") or None,
+                      bool(attrs.get("causal", False)),
+                      q.shape[t_axis], k.shape[t_axis])
+    if window is not None and (bthd or ring is not None):
+        raise NotImplementedError(
+            "scaled_dot_product_attention: a window needs layout='bhtd' "
+            "and no context-parallel ring")
+    return window
+
+
+def _note_dispatch(family, direction, dims, replicated_over=(), window=None):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
@@ -54,10 +73,14 @@ def _note_dispatch(family, direction, dims, replicated_over=()):
     # values narrower than queries and keys both widths: "dk192 dv128")
     heads = f"h{h}" if hk == h else f"h{h} kv{hk}"
     width = f"dh{dh}" if dv == dh else f"dk{dh} dv{dv}"
-    _M_DISPATCH.inc(labels={
+    labels = {
         "family": family, "pass": direction,
         "shape": f"b{b} tq{tq} tk{tk} {heads} {width}", "tile": tile,
-        "replicated_over": ",".join(replicated_over)})
+        "replicated_over": ",".join(replicated_over)}
+    if window is not None:
+        labels["shape"] += f" w{window}"
+        labels["band"] = "skip" if family == "bhtd" else "dense"
+    _M_DISPATCH.inc(labels=labels)
 
 
 def dispatch_counts(tiles=False):
@@ -202,7 +225,7 @@ def _sdpa_config(ins, attrs, rng):
     return scale, drop, seed, family, dims
 
 
-def _on_mesh(kernel, arrays, seed, family, direction, dims):
+def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -215,7 +238,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims):
     the batch."""
     split = interp.mesh_batch_split()
     if split is None:
-        _note_dispatch(family, direction, dims)
+        _note_dispatch(family, direction, dims, window=window)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -229,7 +252,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims):
             f"batch; feed a batch that is a multiple of {n}")
     _note_dispatch(
         family, direction, (b // n,) + tuple(dims[1:]),
-        sorted(a for a in free - set(axis) if mesh.shape[a] > 1))
+        sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window)
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -295,7 +318,9 @@ def _sdpa(ins, attrs, rng=None):
     default scale is 1 / sqrt of Q's width). K and V may have fewer
     heads than Q (grouped-query attention: query
     head i reads key/value head i // (h / kv heads)); the BHTD kernels
-    pick the head in their index maps and never copy K or V.
+    pick the head in their index maps and never copy K or V. Attr
+    ``window`` (with ``causal``, layout bhtd, no ring): a query sees the
+    last ``window`` positions only, itself among them.
 
     On TPU this routes to the Pallas flash-attention kernel
     (paddle_tpu/parallel/flash_attention.py), including training-time
@@ -314,6 +339,7 @@ def _sdpa(ins, attrs, rng=None):
 
     t_axis = 1 if bthd else 2
     ring = _ring_config_t(q, k, t_axis)
+    window = _windowed(attrs, q, k, bthd, ring)
     if ring is not None:
         _note_dispatch("ring", "fwd", dims)
         mesh, ctx_axis, data_axis = ring
@@ -333,7 +359,7 @@ def _sdpa(ins, attrs, rng=None):
                                     p_drop=float(drop), seed=seed)
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif family == "dense":
-        _note_dispatch("dense", "fwd", dims)
+        _note_dispatch("dense", "fwd", dims, window=window)
         sd = seed if drop > 0.0 else None
         if bthd:
             out = fa._reference_attention_bthd(
@@ -343,7 +369,7 @@ def _sdpa(ins, attrs, rng=None):
                 scale, drop, sd)
         else:
             out = fa._reference_attention(q, k, v, bias, scale, drop, sd,
-                                          causal=causal)
+                                          causal=causal, window=window)
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif bthd:
         out, lse = _on_mesh(
@@ -356,8 +382,9 @@ def _sdpa(ins, attrs, rng=None):
         # remains the unrolled path's backward
         out, lse = _on_mesh(
             lambda q, k, v, bias, seed: fa.flash_attention_with_lse(
-                q, k, v, bias, seed, scale, float(drop), causal=causal),
-            (q, k, v, bias), seed, family, "fwd", dims)
+                q, k, v, bias, seed, scale, float(drop), causal=causal,
+                window=window),
+            (q, k, v, bias), seed, family, "fwd", dims, window)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -378,6 +405,7 @@ def _sdpa_grad(ins, attrs, rng=None):
 
     t_axis = 1 if bthd else 2
     ring = _ring_config_t(q, k, t_axis)
+    window = _windowed(attrs, q, k, bthd, ring)
     if ring is not None:
         _note_dispatch("ring", "bwd", dims)
         mesh, ctx_axis, data_axis = ring
@@ -400,7 +428,7 @@ def _sdpa_grad(ins, attrs, rng=None):
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
     elif family == "dense":
-        _note_dispatch("dense", "bwd", dims)
+        _note_dispatch("dense", "bwd", dims, window=window)
         sd = seed if drop > 0.0 else None
         if bthd:
             eff_bias = fa._combined_causal_bias(
@@ -412,18 +440,18 @@ def _sdpa_grad(ins, attrs, rng=None):
         else:
             def f(q, k, v):
                 return fa._reference_attention(
-                    q, k, v, bias, scale, drop, sd,
-                    causal=causal).astype(q.dtype)
+                    q, k, v, bias, scale, drop, sd, causal=causal,
+                    window=window).astype(q.dtype)
 
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
     else:
         bwd = (fa.flash_attention_bthd_bwd if bthd
-               else fa.flash_attention_bwd)
+               else functools.partial(fa.flash_attention_bwd, window=window))
         dq, dk, dv = _on_mesh(
             lambda q, k, v, bias, out, lse, g, seed: bwd(
                 q, k, v, bias, seed, out, lse, g, scale=scale,
                 p_drop=drop, causal=causal),
             (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
-            "bwd", dims)
+            "bwd", dims, window)
     return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

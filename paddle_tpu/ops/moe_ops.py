@@ -224,7 +224,8 @@ def _moe_router(ins, attrs):
     if _monitor.enabled() and interp.lowering_active():
         _M_ROUTER.inc(labels={
             "score": score, "bias": str(int(bias is not None)),
-            "k": str(k), "experts": str(e)})
+            "k": str(k), "experts": str(e),
+            "input": attrs.get("input", "own")})
     logits = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
     if score == "sigmoid":
         top_w, top_i, lb = _sigmoid_router(
@@ -493,20 +494,30 @@ def _swiglu(gate, up):
     return (jax.nn.silu(gate) * up).astype(gate.dtype)
 
 
-def _swiglu_live(gate, up, live, w):
-    """``_swiglu`` on the live rows of Gate and Up [m, f], zeros behind."""
+def _reglu(gate, up):
+    return (jax.nn.relu(gate) * up).astype(gate.dtype)
+
+
+def _gated_unit(attrs):
+    """The experts' gated unit act(gate) * up of attr ``act``: "silu"
+    (SwiGLU, the default) or "relu" (ReGLU)."""
+    return {"silu": _swiglu, "relu": _reglu}[attrs.get("act", "silu")]
+
+
+def _glu_live(glu, gate, up, live, w):
+    """``glu`` on the live rows of Gate and Up [m, f], zeros behind."""
     return _live_pass(
         live, w, gate.shape[0], [(gate.shape[1], gate.dtype)],
-        lambda r0: (_swiglu(rows_at(gate, r0, w),
-                            rows_at(up, r0, w)),))[0]
+        lambda r0: (glu(rows_at(gate, r0, w), rows_at(up, r0, w)),))[0]
 
 
 @register_op("moe_experts", diff_inputs=("Xs", "WGate", "WUp", "WDown"))
 def _moe_experts(ins, attrs):
-    """SwiGLU experts over ragged groups: Xs [m, d] sorted by expert,
+    """Gated experts over ragged groups: Xs [m, d] sorted by expert,
     Rows [E] its group sizes, WGate / WUp [E, d, f], WDown [E, f, d] ->
-    Ys [m, d] = (silu(Xs WGate[e]) * (Xs WUp[e])) WDown[e], e the
-    row's expert. Under AMP the lowering casts rows and weights to bf16
+    Ys [m, d] = (act(Xs WGate[e]) * (Xs WUp[e])) WDown[e], e the
+    row's expert; attr ``act`` "silu" (SwiGLU, the default) or "relu"
+    (ReGLU). Under AMP the lowering casts rows and weights to bf16
     (core/interp.AMP_OP_TYPES). Each grouped matmul is
     ``parallel/grouped_matmul.grouped_matmul``: the program's ``moe.*``
     Pallas kernels where ``gmm_tile`` gives the call a tile, else
@@ -529,11 +540,13 @@ def _moe_experts(ins, attrs):
     wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
     m = xs.shape[0]
     kw, w = _live_rows(attrs, m), _window(attrs, m)
+    glu = _gated_unit(attrs)
+    # (the pass keeps its name whatever the activation: readers know it)
     _note_passes("moe_experts", m, w, "swiglu")
     gate = _gm.grouped_matmul(xs, wg.astype(xs.dtype), rows, **kw)
     up = _gm.grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
-    h = (_swiglu(gate, up) if w is None
-         else _swiglu_live(gate, up, jnp.sum(rows), w))
+    h = (glu(gate, up) if w is None
+         else _glu_live(glu, gate, up, jnp.sum(rows), w))
     ys = _gm.grouped_matmul(h, wd.astype(xs.dtype), rows, **kw)
     return {"Ys": [ys], "Gate": [gate], "Up": [up]}
 
@@ -553,12 +566,13 @@ def _moe_experts_grad(ins, attrs):
     up = _x(ins, "Up").astype(dtype)
     g = _x(ins, "GRAD::Ys").astype(dtype)
     kw, w = _live_rows(attrs, m), _window(attrs, m)
+    glu = _gated_unit(attrs)
     if w is not None:
         kw["zero_behind"] = False   # dh, dx_gate, dx_up: read by window
     _note_passes("moe_experts_grad", m, w, "gather_xs", "swiglu",
                  "swiglu_grad", "sum_dx")
     if w is None:
-        h, swiglu_vjp = jax.vjp(_swiglu, gate, up)
+        h, swiglu_vjp = jax.vjp(glu, gate, up)
         dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g)
         dgate, dup = swiglu_vjp(dh)
     else:
@@ -569,12 +583,12 @@ def _moe_experts_grad(ins, attrs):
             (_x(ins, "X"), _x(ins, "Order")))
         xs = _gather_live(x.reshape(-1, x.shape[-1]).astype(dtype), order,
                           live, w)
-        h = _swiglu_live(gate, up, live, w)
+        h = _glu_live(glu, gate, up, live, w)
         dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
                                            **kw)
 
         def swiglu_grad(r0):
-            _, vjp = jax.vjp(_swiglu, rows_at(gate, r0, w),
+            _, vjp = jax.vjp(glu, rows_at(gate, r0, w),
                              rows_at(up, r0, w))
             return vjp(rows_at(dh, r0, w))
 

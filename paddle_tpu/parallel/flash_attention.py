@@ -29,6 +29,13 @@ ridge of 240.
 - ``causal``: a step whose block lies above the diagonal computes nothing
   and fetches nothing (its index maps repeat the row's nearest live
   block, and Pallas copies only a block whose index changed).
+- ``window`` (with ``causal``): a query also FORGETS, it sees the last
+  ``window`` positions (p - s < window). The BHTD kernels' inner grid
+  axis is then as long as the band is wide in blocks, not as the
+  sequence: step r of a row works on the r-th block of the row's band,
+  dead steps (the first rows' bands are shorter) repeat the row's last
+  live block, and the two-sided mask runs only on the blocks the
+  diagonal or the band's far edge crosses.
 - Attention dropout runs inside the kernels via the TPU PRNG: the mask for
   score block (b, jq, jk) is regenerated from a hash of (seed, b, jq, jk)
   in every kernel (and of the head group, where hb < h), so forward and
@@ -201,16 +208,20 @@ def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
 # ---------------------------------------------------------------------------
 
 
-def _causal_mask(s, j, kk, bq, bk, transposed=False):
+def _causal_mask(s, j, kk, bq, bk, transposed=False, window=None):
     """Mask future positions inside score block (hb, bq, bk) for q-block
-    j / k-block kk (``transposed``: block is (hb, bk, bq))."""
+    j / k-block kk (``transposed``: block is (hb, bk, bq)); with a
+    ``window`` also the positions it has forgotten (p - s >= window)."""
     if transposed:
         k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + kk * bk
         q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + j * bq
     else:
         q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bq
         k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + kk * bk
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = jnp.logical_and(seen, q_pos - k_pos < window)
+    return jnp.where(seen, s, _NEG_INF)
 
 
 def _causal_live(j, kk, bq, bk):
@@ -239,6 +250,48 @@ def _live_q(j, kk, bq, bk):
     return jnp.maximum(j, (kk * bk) // bq)
 
 
+# A window's band. visible(p, s) = s <= p and p - s < window, so block
+# (j, kk) holds a visible pair iff kk * bk <= (j + 1) * bq - 1 (the
+# diagonal's side, _causal_live) and j * bq <= (kk + 1) * bk + window - 2
+# (the far edge's side): the two conditions are independent, a q-row's
+# live k-blocks are _first_k .. _live_k's bound and a k-row's live
+# q-blocks (kk * bk) // bq .. _last_q. The inner grid axis walks the
+# band: step r of a row is the r-th block from the row's first.
+
+
+def _first_k(j, bq, bk, window):
+    """The first k-block a q-row's window reaches back to."""
+    return jnp.maximum(j * bq - window + 1, 0) // bk
+
+
+def _last_q(kk, bq, bk, window):
+    """The last q-block that still remembers a k-row's positions."""
+    return ((kk + 1) * bk + window - 2) // bq
+
+
+def _band_steps(n_rows, first, last):
+    """Blocks of the widest row's band: the inner grid axis's length
+    (``first`` / ``last`` take a row's index as a Python int)."""
+    return max(int(last(r)) - int(first(r)) + 1 for r in range(n_rows))
+
+
+def _on_edge(j, kk, bq, bk, window):
+    """Does the diagonal or the band's far edge cross block (j, kk):
+    is some pair of it in the future, or forgotten? Every other live
+    block is all visible and takes no mask."""
+    return jnp.logical_or((kk + 1) * bk - 1 > j * bq,
+                          (j + 1) * bq - 1 - kk * bk >= window)
+
+
+def _when_live(compute, live, edge):
+    """Run a windowed step: masked on an edge block, plain inside the
+    band, not at all where dead."""
+    pl.when(jnp.logical_and(live, edge))(
+        functools.partial(compute, masked=True))
+    pl.when(jnp.logical_and(live, jnp.logical_not(edge)))(
+        functools.partial(compute, masked=False))
+
+
 def _seed_step(seed_ref, ng, j, kk):
     """Seed the PRNG for score block (batch row, head group, j, kk).
     With all heads in one group the key is (row, j, kk), the stream the
@@ -261,17 +314,19 @@ def _lanes(x, n):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, nk, ng, p_drop,
-                causal=False):
-    j, kk = pl.program_id(2), pl.program_id(3)
+                causal=False, window=None):
+    # r: the inner axis's step, nk of them; kk the k-block it works on
+    j, r = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
+    kk = r if window is None else _first_k(j, bq, bk, window) + r
 
-    @pl.when(kk == 0)
+    @pl.when(r == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
+    def _compute(masked=causal):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -281,8 +336,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         ) * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, j, kk, bq, bk)
+        if masked:
+            s = _causal_mask(s, j, kk, bq, bk, window=window)
 
         # m and l live replicated along the 128 lanes of their scratch:
         # a row's max and sum are broadcast once each, and the score
@@ -308,12 +363,15 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    if causal:
+    if window is not None:
+        _when_live(_compute, _causal_live(j, kk, bq, bk),
+                   _on_edge(j, kk, bq, bk, window))
+    elif causal:
         pl.when(_causal_live(j, kk, bq, bk))(_compute)
     else:
         _compute()
 
-    @pl.when(kk == nk - 1)
+    @pl.when(r == nk - 1)
     def _finish():
         l = l_scr[:, :, :1]
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -322,15 +380,16 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                delta_ref, dq_ref, dq_scr, *, scale, nk, ng, p_drop,
-               causal=False):
-    j, kk = pl.program_id(2), pl.program_id(3)
+               causal=False, window=None):
+    j, r = pl.program_id(2), pl.program_id(3)   # as in _fwd_kernel
     bq, bk = q_ref.shape[2], k_ref.shape[2]
+    kk = r if window is None else _first_k(j, bq, bk, window) + r
 
-    @pl.when(kk == 0)
+    @pl.when(r == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute():
+    def _compute(masked=causal):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -344,8 +403,8 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         ) * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, j, kk, bq, bk)
+        if masked:
+            s = _causal_mask(s, j, kk, bq, bk, window=window)
         p = jnp.exp(s - lse)  # post-softmax probabilities, recomputed
 
         dp = jax.lax.dot_general(
@@ -361,19 +420,23 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
+    if window is not None:
+        _when_live(_compute, _causal_live(j, kk, bq, bk),
+                   _on_edge(j, kk, bq, bk, window))
+    elif causal:
         pl.when(_causal_live(j, kk, bq, bk))(_compute)
     else:
         _compute()
 
-    @pl.when(kk == nk - 1)
+    @pl.when(r == nk - 1)
     def _finish():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                scale, nq, ng, p_drop, causal=False, group=1):
+                scale, nq, ng, p_drop, causal=False, group=1, window=None,
+                last_q=None):
     kk, jq = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
     walk, steps = jq, nq   # the inner axis: the q-blocks of one head
@@ -381,13 +444,17 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         # grouped-query attention: the inner axis walks the group's
         # query heads, each over its q-blocks, and dk, dv gather all
         steps, jq = group * nq, walk % nq
+    if window is not None:
+        # nq steps a head over the k-row's band, from its first q-block
+        # (``last_q``: the sequence's last one)
+        jq = (kk * bk) // bq + jq
 
     @pl.when(walk == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
+    def _compute(masked=causal):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -406,8 +473,9 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         if bias_ref is not None:
             s_t = s_t + jnp.transpose(bias_ref[0].astype(jnp.float32),
                                       (0, 2, 1))
-        if causal:
-            s_t = _causal_mask(s_t, jq, kk, bq, bk, transposed=True)
+        if masked:
+            s_t = _causal_mask(s_t, jq, kk, bq, bk, transposed=True,
+                               window=window)
         p_t = jnp.exp(s_t - lse_t)
 
         if p_drop > 0.0:
@@ -441,7 +509,12 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
+    if window is not None:
+        _when_live(
+            _compute,
+            jq <= jnp.minimum(_last_q(kk, bq, bk, window), last_q),
+            _on_edge(jq, kk, bq, bk, window))
+    elif causal:
         pl.when(_causal_live(jq, kk, bq, bk))(_compute)
     else:
         _compute()
@@ -452,7 +525,8 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _step_blocks(causal, k_inner, bq, bk, nq, group=1):
+def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
+                 steps=None):
     """-> f(*grid ids) = (i, g, j, kk): batch row, head group, q-block
     and k-block a grid step READS. The grid is (i, g, j, kk) with the k
     axis inner (forward, dq) or (i, g, kk, j) with the q axis inner
@@ -460,13 +534,24 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1):
     row's nearest live one, so the step fetches nothing. ``group`` > 1
     (one head a step): g is the QUERY head (_row_specs reads K and V at
     g // group); the dk/dv grid is then (i, kv head, kk, r) with r over
-    the group's heads and, inside one, its q-blocks."""
+    the group's heads and, inside one, its q-blocks. With a ``window``
+    the inner axis has ``steps`` steps (a head), the band's width in
+    blocks: step r reads the r-th block of its row's band, a dead step
+    the band's last."""
+    steps = steps or nq
+
     def f(*ids):
         i, g = ids[0], ids[1]
         j, kk = (ids[2], ids[3]) if k_inner else (ids[3], ids[2])
         if group > 1 and not k_inner:
-            g, j = g * group + j // nq, j % nq
-        if causal and k_inner:
+            g, j = g * group + j // steps, j % steps
+        if window is not None and k_inner:
+            kk = jnp.minimum(_first_k(j, bq, bk, window) + kk,
+                             ((j + 1) * bq - 1) // bk)
+        elif window is not None:
+            j = jnp.minimum((kk * bk) // bq + j, jnp.minimum(
+                _last_q(kk, bq, bk, window), nq - 1))
+        elif causal and k_inner:
             kk = _live_k(j, kk, bq, bk)
         elif causal:
             j = jnp.minimum(_live_q(j, kk, bq, bk), nq - 1)
@@ -527,23 +612,27 @@ def _bias_spec(bias, at, hb, bq, bk):
         (1, hb if per_head else 1, bq if per_row else 1, bk), idx)
 
 
-def _reference_scores(q, k, bias, scale, causal):
-    """Scaled scores + bias + causal mask — the ONE copy both the dense
-    forward and its lse statistic derive from (the ring-attention merge
-    combines (out, lse), so they must never desynchronize)."""
+def _reference_scores(q, k, bias, scale, causal, window=None):
+    """Scaled scores + bias + causal (and window) mask — the ONE copy
+    both the dense forward and its lse statistic derive from (the
+    ring-attention merge combines (out, lse), so they must never
+    desynchronize)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias.astype(s.dtype)
     if causal:
         tq, tk = q.shape[2], k.shape[2]
-        mask = (jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :])
+        ago = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+        mask = ago >= 0
+        if window is not None:
+            mask = jnp.logical_and(mask, ago < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     return s
 
 
 def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
-                                  seed=None, causal=False):
+                                  seed=None, causal=False, window=None):
     """(out, lse) from ONE score tensor — the fallback twin of the
     kernels' contract. out and lse must never derive from separately
     constructed scores (different dtype promotion would desynchronize
@@ -553,7 +642,7 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    s = _reference_scores(q, k, bias, scale, causal)
+    s = _reference_scores(q, k, bias, scale, causal, window)
     lse = jax.scipy.special.logsumexp(s, axis=-1, keepdims=True)
     p = jax.nn.softmax(s, axis=-1)
     if p_drop > 0.0:
@@ -564,9 +653,9 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
 
 
 def _reference_attention(q, k, v, bias, scale, p_drop=0.0, seed=None,
-                         causal=False):
+                         causal=False, window=None):
     return _reference_attention_with_lse(q, k, v, bias, scale, p_drop,
-                                         seed, causal)[0]
+                                         seed, causal, window)[0]
 
 
 def _seed_arr(seed):
@@ -646,11 +735,40 @@ def _kv_group(q, k, p_drop):
     return h // hk
 
 
+def _band(window, causal, tq, tk):
+    """The ``window`` a call runs with: None where it forgets nothing
+    (no window, or one as long as the keys: the causal call itself)."""
+    if window is None:
+        return None
+    if not causal or tq != tk or window < 1:
+        raise ValueError(
+            f"attention: window={window} needs causal self-attention "
+            f"(causal={causal}, tq={tq}, tk={tk})")
+    return None if window >= tk else int(window)
+
+
+def _k_steps(window, nq, nk, bq, bk):
+    """The forward and dq grids' inner axis: a q-row's band in blocks."""
+    if window is None:
+        return nk
+    return _band_steps(nq, lambda j: max(j * bq - window + 1, 0) // bk,
+                       lambda j: ((j + 1) * bq - 1) // bk)
+
+
+def _q_steps(window, nq, nk, bq, bk):
+    """The dk/dv grid's inner axis (a head): a k-row's band in blocks."""
+    if window is None:
+        return nq
+    return _band_steps(nk, lambda kk: (kk * bk) // bq, lambda kk: min(
+        ((kk + 1) * bk + window - 2) // bq, nq - 1))
+
+
 def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
                         p_drop: float = 0.0,
                         q_block: Optional[int] = None,
                         k_block: Optional[int] = None,
-                        causal: bool = False):
+                        causal: bool = False,
+                        window: Optional[int] = None):
     """-> (out, lse) with lse [b, h, tq, 1] f32 — REAL logsumexp rows on
     every path including the dense fallback (the ring-attention merge
     consumes them; the fallback backward still recomputes via vjp).
@@ -659,7 +777,9 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     iota compare) and skips fully-masked k-blocks outright — no [tq, tk]
     bias tensor exists anywhere, preserving the O(t) HBM property for
     decoder self-attention, and the dead upper-triangle blocks cost
-    neither MXU time nor a fetch (the causal ~2x)."""
+    neither MXU time nor a fetch (the causal ~2x). ``window``: each
+    query sees the last ``window`` positions only; the grid walks the
+    band and no block outside it is a step at all."""
     if p_drop > 0.0 and seed is None:
         raise ValueError(
             "flash_attention: p_drop > 0 requires a per-step `seed`; "
@@ -671,6 +791,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
+    window = _band(window, causal, tq, tk)
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
     if tile is None:
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
@@ -678,15 +799,18 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         # derive from one score tensor (_reference_attention_with_lse).
         return _reference_attention_with_lse(
             q, k, v, bias, scale, p_drop,
-            seed if p_drop > 0.0 else None, causal=causal)
+            seed if p_drop > 0.0 else None, causal=causal, window=window)
 
     hb, bq, bk = tile
-    ng, nq, nk = h // hb, tq // bq, tk // bk
+    ng, nq = h // hb, tq // bq
+    # the inner axis: the key blocks, or those of a row's band
+    nk = _k_steps(window, nq, tk // bk, bq, bk)
     kernel, in_specs, args, rows = _call_parts(
-        _fwd_kernel, _step_blocks(causal, True, bq, bk, nq, group), tile,
+        _fwd_kernel,
+        _step_blocks(causal, True, bq, bk, nq, group, window, nk), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
-                               p_drop=p_drop, causal=causal)
+                               p_drop=p_drop, causal=causal, window=window)
     operands = (_seed_arr(seed), *args)
     out, lse = pl.pallas_call(
         kernel, name="attn.bhtd.fwd",
@@ -714,7 +838,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                         p_drop: float = 0.0,
                         q_block: Optional[int] = None,
                         k_block: Optional[int] = None,
-                        causal: bool = False, g_lse=None):
+                        causal: bool = False, g_lse=None,
+                        window: Optional[int] = None):
     """-> (dq, dk, dv), consuming the forward's saved (out, lse).
 
     ``g_lse``: optional cotangent of the lse OUTPUT ([b, h, tq, 1]).
@@ -728,12 +853,14 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
+    window = _band(window, causal, tq, tk)
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
     if tile is None:
         def f(q, k, v):
             return _reference_attention_with_lse(
                 q, k, v, bias, scale, p_drop,
-                seed if p_drop > 0.0 else None, causal=causal)
+                seed if p_drop > 0.0 else None, causal=causal,
+                window=window)
 
         _, vjp = jax.vjp(f, q, k, v)
         return vjp((g, jnp.zeros((b, h, tq, 1), jnp.float32)
@@ -746,19 +873,25 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     seed_arr = _seed_arr(seed)
-    kw = dict(scale=scale, ng=ng, p_drop=p_drop, causal=causal)
+    kw = dict(scale=scale, ng=ng, p_drop=p_drop, causal=causal,
+              window=window)
+    # the inner axes: all nk key blocks a q-row and all nq query blocks
+    # a k-row, or, with a window, those of the row's band
+    k_steps = _k_steps(window, nq, nk, bq, bk)
+    q_steps = _q_steps(window, nq, nk, bq, bk)
 
     # --- dq: grid (b, ng, nq, nk), k-blocks inner ---
     kernel, specs, args, rows = _call_parts(
-        _dq_kernel, _step_blocks(causal, True, bq, bk, nq, group), tile,
+        _dq_kernel,
+        _step_blocks(causal, True, bq, bk, nq, group, window, k_steps), tile,
         q, k, v, bias)
-    kernel = functools.partial(kernel, nk=nk, **kw)
+    kernel = functools.partial(kernel, nk=k_steps, **kw)
     operands = (seed_arr, *args, g, lse, delta)
     dq = pl.pallas_call(
         kernel, name="attn.bhtd.bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, ng, nq, nk),
+            grid=(b, ng, nq, k_steps),
             in_specs=specs + [rows.o, rows.stat, rows.stat],
             out_specs=rows.q,
             scratch_shapes=[pltpu.VMEM((hb, bq, dh), jnp.float32)],
@@ -776,15 +909,16 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     # q block off the 128-lane tiling (a caller's q_block of 64) keeps
     # that form: it cannot be cut from a row.
     kernel, specs, args, rows = _call_parts(
-        _dkv_kernel, _step_blocks(causal, False, bq, bk, nq, group), tile,
+        _dkv_kernel,
+        _step_blocks(causal, False, bq, bk, nq, group, window, q_steps), tile,
         q, k, v, bias)
-    kernel = functools.partial(kernel, nq=nq, **kw)
-    dkv_grid = (b, ng, nk, nq)
+    kernel = functools.partial(kernel, nq=q_steps, last_q=nq - 1, **kw)
+    dkv_grid = (b, ng, nk, q_steps)
     if group > 1:
         # a step's dk, dv block is one key/value head's: the inner axis
         # walks the group's query heads, and the scratch sums them
         kernel = functools.partial(kernel, group=group)
-        dkv_grid = (b, h // group, nk, group * nq)
+        dkv_grid = (b, h // group, nk, group * q_steps)
     stats, stat_spec = [lse, delta], rows.stat
     if bq % 128 == 0 or bq == tq:
         stat_spec = rows.row
@@ -817,30 +951,31 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_attention(q, k, v, bias=None, seed=None,
                     scale: Optional[float] = None, p_drop: float = 0.0,
                     q_block: Optional[int] = None,
                     k_block: Optional[int] = None,
-                    causal: bool = False):
+                    causal: bool = False,
+                    window: Optional[int] = None):
     """o = dropout(softmax(q k^T * scale + bias)) v.
 
     ``seed``: int32 scalar array driving attention dropout (ignored when
     p_drop == 0). See the module docstring for the bias-gradient caveat.
     """
     out, _ = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                                 q_block, k_block, causal)
+                                 q_block, k_block, causal, window)
     return out
 
 
 def _vjp_fwd(q, k, v, bias, seed, scale, p_drop, q_block, k_block,
-             causal=False):
+             causal=False, window=None):
     out, lse = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                                   q_block, k_block, causal)
+                                   q_block, k_block, causal, window)
     return out, (q, k, v, bias, seed, out, lse)
 
 
-def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
+def _vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res, g,
              g_lse=None):
     q, k, v, bias, seed, out, lse = res
     if scale is None:
@@ -851,7 +986,7 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
                     dv=v.shape[3]) == "bhtd":
         dq, dk, dv = flash_attention_bwd(q, k, v, bias, seed, out, lse, g,
                                          scale, p_drop, q_block, k_block,
-                                         causal, g_lse=g_lse)
+                                         causal, g_lse=g_lse, window=window)
         # Pallas path: bias is mask plumbing, cotangent intentionally zero
         # (see module docstring).
         dbias = None if bias is None else jnp.zeros_like(bias)
@@ -860,8 +995,9 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, res, g,
         glse = (jnp.zeros_like(lse) if g_lse is None else g_lse)
 
         def out_and_lse(a, b, c, bb):
-            return _reference_attention_with_lse(a, b, c, bb, scale,
-                                                 p_drop, sd, causal)
+            return _reference_attention_with_lse(
+                a, b, c, bb, scale, p_drop, sd, causal,
+                _band(window, causal, a.shape[2], b.shape[2]))
 
         if bias is None:
             _, vjp = jax.vjp(
@@ -887,33 +1023,35 @@ flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 # path, sharing the same kernels.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_attention_with_lse(q, k, v, bias=None, seed=None,
                              scale: Optional[float] = None,
                              p_drop: float = 0.0,
                              q_block: Optional[int] = None,
                              k_block: Optional[int] = None,
-                             causal: bool = False):
+                             causal: bool = False,
+                             window: Optional[int] = None):
     """(out, lse) variant of ``flash_attention`` — same backward rule
     (shared ``_vjp_bwd``: blocked Pallas kernels, true dbias on the dense
     fallback, float0 seed cotangent). The sdpa op uses this so its saved
     Lse output exists AND jax.vjp through the op (scan-over-layers grad)
     works despite pallas_call having no JVP rule."""
     return flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                               q_block, k_block, causal)
+                               q_block, k_block, causal, window)
 
 
 def _fa_lse_vjp_fwd(q, k, v, bias, seed, scale, p_drop, q_block, k_block,
-                    causal=False):
+                    causal=False, window=None):
     out, lse = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                                   q_block, k_block, causal)
+                                   q_block, k_block, causal, window)
     return (out, lse), (q, k, v, bias, seed, out, lse)
 
 
-def _fa_lse_vjp_bwd(scale, p_drop, q_block, k_block, causal, res, gs):
+def _fa_lse_vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res,
+                    gs):
     g, g_lse = gs
     q = res[0]
-    return _vjp_bwd(scale, p_drop, q_block, k_block, causal, res,
+    return _vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res,
                     g.astype(q.dtype), g_lse=g_lse)
 
 

@@ -233,6 +233,61 @@ def test_held_share_grouped_matmuls_compile(one_chip, real_kernels):
         assert name in text, name
 
 
+def test_windowed_attention_compiles(one_chip, real_kernels):
+    """SmallThinker's window layers: 28 query heads over 4 key/value
+    heads of 128 (a group of 7) at 16,384 positions that see the last
+    4096, one head a step at blocks of 512. All three grids' inner axis
+    is the band's 9 blocks, not the sequence's 32 (a head of the dk/dv
+    grid: 7 x 9), and the two branches of a windowed step (masked on an
+    edge block, plain inside the band) fit Mosaic's VMEM."""
+    b, h, hk, t, dh, window = 1, 28, 4, 16384, 128, 4096
+    assert fa.bhtd_tile(h, t, t, dh=dh, group=h // hk) == (1, 512, 512)
+    assert fa._k_steps(window, 32, 32, 512, 512) == 9
+    assert fa._q_steps(window, 32, 32, 512, 512) == 9
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, t, dh), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=True,
+                                               window=window)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(hk), arg(hk)).compile().as_text()
+    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
+        assert name in text, name
+    assert "bf16[1,4,16384,128]" in text
+
+
+def test_smallthinker_held_grouped_matmuls_compile(one_chip, real_kernels):
+    """One chip's 8 of 64 ReGLU experts of 768 over a hidden size of
+    2560 (5 x 512: the first contraction that is no power of two): a
+    buffer of 98,304 rows of which an even router fills 12,288."""
+    m, k, n, e, live = 98304, 2560, 768, 8, 12288
+    bf = jnp.bfloat16
+    tile = gm.gmm_tile(m, k, n, e, bf, "tpu", False, live_rows=live)
+    dx_tile = gm.gmm_tile(m, n, k, e, bf, "tpu", False, live_rows=live)
+    assert tile is not None and dx_tile is not None
+    assert k % tile[1] == 0 and n % tile[2] == 0
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def three(lhs, rhs, g, sizes):
+        return (gm.gmm(lhs, rhs, sizes, tile),
+                gm.gmm(g, rhs, sizes, dx_tile, transpose_rhs=True,
+                       name="moe.gmm.bwd_dx"),
+                gm.tgmm(lhs, g, sizes, tile))
+
+    text = jax.jit(three).lower(
+        arg((m, k), bf), arg((e, k, n), bf), arg((m, n), bf),
+        arg((e,), jnp.int32)).compile().as_text()
+    for name in ("moe.gmm.fwd", "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"):
+        assert name in text, name
+
+
 # (t, hk, hv): the cell's call; a sequence of fewer chunks than a grid
 # step holds, hk = hv (the block is the whole padded sequence)
 @pytest.mark.parametrize("t,hk,hv", [(8192, 16, 32), (200, 2, 2)],

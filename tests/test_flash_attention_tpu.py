@@ -170,6 +170,56 @@ def test_grouped_query_attention_at_heads_of_256_matches_dense(t):
                                    err_msg=name)
 
 
+@pytest.mark.parametrize("t,window", [(8192, 4096), (4096, 1000)])
+def test_windowed_attention_at_a_group_of_7_matches_dense(t, window):
+    # SmallThinker's window layers (models/smallthinker.py: q [b, 28, t,
+    # 128], K and V [b, 4, t, 128], causal, the last ``window``
+    # positions): the grids' inner axis walks the band, the two-sided
+    # mask runs on edge blocks only, dk and dv sum a group's 7 heads. A
+    # window of 1000: no block divides it, and the band's width in
+    # blocks differs from row to row.
+    b, h, hk, dh = 1, 28, 4, 128
+    assert fa.bhtd_tile(h, t, t, dh=dh, group=h // hk) == (1, 512, 512)
+    r = np.random.RandomState(7)
+    # (scores of unit variance: a softmax peaked enough that which keys
+    # a query sees moves its output by far more than bf16 rounds it)
+    q, k, v = (jnp.asarray(r.normal(0, 1.0, (b, n, t, dh))).astype(
+        jnp.bfloat16) for n in (h, hk, hk))
+    w = jnp.asarray(r.normal(0, 1, (b, h, t, dh)).astype(np.float32))
+
+    def f(q, k, v):
+        o, _ = fa.flash_attention_with_lse(q, k, v, causal=True,
+                                           window=window)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def ref(q, k, v):
+        def one(i):   # a query head at a time
+            kv = i // (h // hk)
+            return fa._reference_attention(
+                q[:, i:i + 1], k[:, kv:kv + 1], v[:, kv:kv + 1], None,
+                1.0 / np.sqrt(dh), causal=True, window=window)
+        o = jnp.concatenate([one(i) for i in range(h)], 1)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    (_, o1), g1 = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, o2), g2 = jax.jit(jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    assert g1[1].shape == k.shape and g1[2].shape == v.shape
+    for name, a, b_ in zip(("o", "dq", "dk", "dv"), (o1, *g1), (o2, *g2)):
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        # bf16 on both sides; dk and dv sum 7 heads x up to 4096 rows
+        np.testing.assert_allclose(a, b_, atol=0.03 * np.abs(b_).max(),
+                                   err_msg=name)
+    # and the window is computed: the causal call gives another result,
+    # further from this one than this one is from the composition
+    o3, _ = jax.jit(lambda q, k, v: fa.flash_attention_with_lse(
+        q, k, v, causal=True))(q, k, v)
+    o1, o2, o3 = (np.asarray(o, np.float32)[:, :, window:]
+                  for o in (o1, o2, o3))
+    assert np.abs(o3 - o1).max() > 5 * np.abs(o2 - o1).max()
+
+
 @pytest.mark.parametrize("t", [4096, 1024])
 def test_latent_attention_at_192_over_128_matches_dense(t):
     # JoyAI-LLM-Flash's attention call (models/joyai_flash.py: q and k

@@ -306,7 +306,8 @@ def test_router_dispatch_counter_names_the_form():
         flags.set_flags({"telemetry": False})
         monitor.reset()
     assert {tuple(sorted(r["labels"].items())) for r in rows} == {
-        (("bias", "1"), ("experts", "16"), ("k", "3"), ("score", "sigmoid"))}
+        (("bias", "1"), ("experts", "16"), ("input", "own"), ("k", "3"),
+         ("score", "sigmoid"))}
     assert sum(r["value"] for r in rows) >= 3
 
 
