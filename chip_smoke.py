@@ -111,10 +111,21 @@ def attention_dispatch():
     """{"family pass shape[ replicated_over=axes][ [tile]]": calls} —
     which implementation every attention call lowered so far took, and
     the tile of a family that picks one by the shape: "bhtd fwd <shape>
-    [hb1 bq512 bk512]" (pt_attention_dispatch_total)."""
+    [hb1 bq512 bk512]", a backward row of that family also whether it is
+    one call or the pair: "... form=fused" (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
-    return attention_ops.dispatch_counts(tiles=True)
+    return attention_ops.dispatch_counts(tiles=True, forms=True)
+
+
+def _one_backward_call(attn):
+    """Every BHTD backward row of a lowered cell is the ONE call
+    ``attn.bhtd.bwd`` (flash_attention.bhtd_bwd_form: the cells' rows
+    fit the kernel's VMEM cap), none the pair bwd_dq + bwd_dkv."""
+    rows = [k for k in attn if k.startswith("bhtd bwd ")]
+    check(rows and all(k.endswith(" form=fused") for k in rows),
+          f"expected every bhtd backward call as one fused kernel "
+          f"(form=fused), none split: {attn}")
 
 
 def _dispatch_since(before, read=attention_dispatch):
@@ -466,11 +477,35 @@ def _lower_train_step(main, loss, seq):
         jax.ShapeDtypeStruct((), jnp.uint32))
 
 
+def _traced_kernel_ms(name, run, prefix, calls=3):
+    """{kernel: ms a call} of the Mosaic kernels whose name starts with
+    ``prefix``, from a short trace of ``calls`` calls of ``run()``
+    (chiprun_out/<name>), and every kernel's seconds as the trace's
+    reduction has them."""
+    import jax
+
+    from perf import trace as perf_trace
+
+    trace_dir = os.path.join(os.path.dirname(REPORT_PATH), name)
+    jax.profiler.start_trace(trace_dir)
+    for _ in range(calls):
+        jax.block_until_ready(run())
+    jax.profiler.stop_trace()
+    summary = perf_trace.reduce(perf_trace.load(
+        perf_trace.find_xplane(trace_dir))) or {}
+    by_kernel = summary.get("by_kernel_s", {})
+    return {k: round(s_ / calls * 1e3, 4) for k, s_ in by_kernel.items()
+            if k.startswith(prefix)}, by_kernel
+
+
 def _bhtd_against_dense(q, k, v, g, errs, what):
     """The BHTD kernels, forward and the three gradients, against the
     dense composition (causal, scale 1 / sqrt of q's width): each
     result's largest difference over the composition's largest into
-    ``errs`` under ``attn_o`` .. ``attn_dv``, held to KERNEL_REL_TOL."""
+    ``errs`` under ``attn_o`` .. ``attn_dv``, held to KERNEL_REL_TOL.
+    -> on a TPU, where the call's backward is the one fused kernel, the
+    backward kernels' ms a call by name from a short trace:
+    ``attn.bhtd.bwd`` beside the pair it replaces."""
     import jax
     import jax.numpy as jnp
 
@@ -498,6 +533,34 @@ def _bhtd_against_dense(q, k, v, g, errs, what):
         check(errs[name] <= KERNEL_REL_TOL,
               f"{what} {name} off the dense composition by "
               f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
+    h, hk = q.shape[1], k.shape[1]
+    if jax.default_backend() != "tpu" or fa.bhtd_bwd_form(
+            h, q.shape[2], k.shape[2], dh=q.shape[3], group=h // hk,
+            dv=v.shape[3], itemsize=q.dtype.itemsize) != "fused":
+        return {}       # (no trace off the chip; the pair has no other form)
+    # the backward kernels' time by name: the one call beside the pair
+    # (the same call with no room for a resident row: bhtd_bwd_form
+    # reads the cap while a call is traced)
+    def as_the_pair(q, k, v, g):    # (a function jax has not traced)
+        return kernels.__wrapped__(q, k, v, g)
+
+    cap = fa._BWD_VMEM_CAP_BYTES
+    fa._BWD_VMEM_CAP_BYTES = 0
+    try:
+        pair = jax.jit(as_the_pair).lower(q, k, v, g).compile()
+    finally:
+        fa._BWD_VMEM_CAP_BYTES = cap
+    jax.block_until_ready(pair(q, k, v, g))
+    kernel_ms, seen = _traced_kernel_ms(
+        "attn_trace", lambda: (kernels(q, k, v, g), pair(q, k, v, g)),
+        "attn.bhtd.bwd")
+    say(f"  {what}: backward kernels, ms a call at {tuple(q.shape)}: "
+        f"{kernel_ms}")
+    check(sorted(kernel_ms) == ["attn.bhtd.bwd", "attn.bhtd.bwd_dkv",
+                                "attn.bhtd.bwd_dq"],
+          f"expected the one backward call and the pair in the trace: "
+          f"{seen}")
+    return kernel_ms
 
 
 def gdn_dispatch():
@@ -578,9 +641,10 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
             f"gdn.conv.* kernels, none as XLA ops: {conv}")
     kv = f"kv{cfg.num_key_value_heads} dh{cfg.head_dim}"
     check(len(attn) == 2 and all(
-        k.startswith("bhtd ") and kv in k and k.endswith("]") for k in attn),
+        k.startswith("bhtd ") and kv in k and " [hb" in k for k in attn),
         f"expected one bhtd attention call each way at {kv} with its tile: "
         f"{attn}")
+    _one_backward_call(attn)
     n_layers = cfg.num_hidden_layers
     check(sum(gmm.values()) == 9 * n_layers and all(
         "[tm128 " in k for k in gmm),
@@ -638,26 +702,15 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     kernel_ms = {}
     if jax.default_backend() == "tpu":
         # the gdn.* kernels' time by name, from a trace of three calls
-        from perf import trace as perf_trace
-        trace_dir = os.path.join(os.path.dirname(REPORT_PATH), "gdn_trace")
-        jax.profiler.start_trace(trace_dir)
-        for _ in range(3):
-            jax.block_until_ready((chunked(q, k, v, g, beta, do),
-                                   conv_kernels(xc, wc, dyc)))
-        jax.profiler.stop_trace()
-        summary = perf_trace.reduce(perf_trace.load(
-            perf_trace.find_xplane(trace_dir))) or {}
-        kernel_ms = {
-            name: round(s_ / 3 * 1e3, 4)
-            for name, s_ in summary.get("by_kernel_s", {}).items()
-            if name.startswith("gdn.")}
+        kernel_ms, seen = _traced_kernel_ms(
+            "gdn_trace", lambda: (chunked(q, k, v, g, beta, do),
+                                  conv_kernels(xc, wc, dyc)), "gdn.")
         say(f"  gdn kernels, ms a call at t{t_check} hk{hk} hv{hv} "
             f"c{conv_c}: {kernel_ms}")
         check(sorted(kernel_ms) == ["gdn.conv.bwd", "gdn.conv.fwd",
                                     "gdn.rule.bwd", "gdn.rule.fwd"],
               f"expected the forward and the backward gdn.rule.* and "
-              f"gdn.conv.* kernels in the trace: "
-              f"{summary.get('by_kernel_s')}")
+              f"gdn.conv.* kernels in the trace: {seen}")
     for name, a, b in zip(names, got, recurrent(q, k, v, g, beta, do)):
         a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
         check(bool(jnp.isfinite(a).all()), f"delta rule {name} not finite")
@@ -682,9 +735,11 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     qa = jnp.asarray(r.randn(1, h, t_check, dh) * 0.3, bf)
     ka = jnp.asarray(r.randn(1, hkv, t_check, dh) * 0.3, bf)
     va, ga = (jnp.asarray(r.randn(1, n, t_check, dh), bf) for n in (hkv, h))
-    _bhtd_against_dense(qa, ka, va, ga, errs, "grouped-query attention")
+    attn_ms = _bhtd_against_dense(qa, ka, va, ga, errs,
+                                  "grouped-query attention")
     row = {"gdn": gdn, "conv": conv, "attention": attn,
-           "grouped_matmuls": gmm, "gdn_kernel_ms": kernel_ms, "gqa_tile": fa.tile_label(tile),
+           "grouped_matmuls": gmm, "gdn_kernel_ms": kernel_ms,
+           "attn_bwd_kernel_ms": attn_ms, "gqa_tile": fa.tile_label(tile),
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  gdn {row['rel_err']}")
     return row
@@ -704,7 +759,7 @@ def router_dispatch():
     return out
 
 
-def mla_phase(seq=4096, t_check=1024, heads=4, **overrides):
+def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
     """The latent-attention decoder's new mechanisms
     (models/joyai_flash.py).
 
@@ -719,8 +774,10 @@ def mla_phase(seq=4096, t_check=1024, heads=4, **overrides):
        matmul of the held experts on a tile chosen for 128 rows an
        expert (tm128), none through ``ragged_dot``. ``overrides`` cut
        the config for the CPU tests.
-    2. On the device: the BHTD kernels at the model's two widths,
-       forward and the three gradients, against the dense composition."""
+    2. On the device: the BHTD kernels at the model's two widths (8
+       heads: one a step at blocks of 512, the cell's tile, so the
+       backward is the fused call), forward and the three gradients,
+       against the dense composition."""
     import jax
     import jax.numpy as jnp
 
@@ -753,6 +810,7 @@ def mla_phase(seq=4096, t_check=1024, heads=4, **overrides):
             for k in rows),
             f"expected {blocks} bhtd attention calls {direction} at "
             f"dk{dk} dv{dv} with their tile, none dense: {attn}")
+    _one_backward_call(attn)
     want = (f"score=sigmoid bias=1 k={cfg.num_experts_per_tok} "
             f"experts={cfg.n_routed_experts}")
     # (a router's grad op runs it again: a row counts both lowerings)
@@ -772,9 +830,9 @@ def mla_phase(seq=4096, t_check=1024, heads=4, **overrides):
               for _ in "qk")
     va, ga = (jnp.asarray(r.randn(1, heads, t_check, dv), bf) for _ in "vg")
     errs = {}
-    _bhtd_against_dense(qa, ka, va, ga, errs, "latent attention")
+    attn_ms = _bhtd_against_dense(qa, ka, va, ga, errs, "latent attention")
     row = {"attention": attn, "routers": routers, "grouped_matmuls": gmm,
-           "tile": fa.tile_label(tile),
+           "tile": fa.tile_label(tile), "attn_bwd_kernel_ms": attn_ms,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  mla {row['rel_err']}")
     return row

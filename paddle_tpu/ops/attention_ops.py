@@ -35,7 +35,10 @@ _M_DISPATCH = _monitor.counter(
     "bhtd) and replicated_over (mesh axes whose every rank repeats that "
     "same call). A windowed call's shape ends in w<window> and the row "
     "carries band: skip (the kernels walk the band, no block outside it "
-    "is a step), mask (the triangle walked and masked) or dense")
+    "is a step), mask (the triangle walked and masked) or dense. A bwd "
+    "row of family bhtd carries form: fused (ONE call, attn.bhtd.bwd) or "
+    "split (the pair bwd_dq + bwd_dkv), flash_attention.bhtd_bwd_form's "
+    "answer for the call")
 
 
 def _windowed(attrs, q, k, bthd, ring):
@@ -54,7 +57,8 @@ def _windowed(attrs, q, k, bthd, ring):
     return window
 
 
-def _note_dispatch(family, direction, dims, replicated_over=(), window=None):
+def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
+                   form=None):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
@@ -80,14 +84,18 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None):
     if window is not None:
         labels["shape"] += f" w{window}"
         labels["band"] = "skip" if family == "bhtd" else "dense"
+    if form is not None:
+        labels["form"] = form
     _M_DISPATCH.inc(labels=labels)
 
 
-def dispatch_counts(tiles=False):
+def dispatch_counts(tiles=False, forms=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
     run print it. ``tiles``: a row whose family tiles by the shape names
-    its tile too, "bhtd fwd <shape> [hb1 bq512 bk512]"."""
+    its tile too, "bhtd fwd <shape> [hb1 bq512 bk512]". ``forms``: a
+    backward row of that family says whether it is one call or the
+    pair, "bhtd bwd <shape> form=fused"."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
@@ -96,6 +104,8 @@ def dispatch_counts(tiles=False):
             name += f" replicated_over={lb['replicated_over']}"
         if tiles and lb.get("tile"):
             name += f" [{lb['tile']}]"
+        if forms and lb.get("form"):
+            name += f" form={lb['form']}"
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 
@@ -225,7 +235,8 @@ def _sdpa_config(ins, attrs, rng):
     return scale, drop, seed, family, dims
 
 
-def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None):
+def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
+             form=None):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -238,7 +249,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None):
     the batch."""
     split = interp.mesh_batch_split()
     if split is None:
-        _note_dispatch(family, direction, dims, window=window)
+        _note_dispatch(family, direction, dims, window=window, form=form)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -252,7 +263,8 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None):
             f"batch; feed a batch that is a multiple of {n}")
     _note_dispatch(
         family, direction, (b // n,) + tuple(dims[1:]),
-        sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window)
+        sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window,
+        form)
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -448,10 +460,15 @@ def _sdpa_grad(ins, attrs, rng=None):
     else:
         bwd = (fa.flash_attention_bthd_bwd if bthd
                else functools.partial(fa.flash_attention_bwd, window=window))
+        # one call or the pair: the kernel layer's own answer, as the
+        # entry point reads it (the op passes no q_block / k_block)
+        form = None if bthd else fa.bhtd_bwd_form(
+            dims[3], dims[1], dims[2], dh=dims[4], group=dims[3] // dims[5],
+            dv=dims[6], itemsize=q.dtype.itemsize, p_drop=drop)
         dq, dk, dv = _on_mesh(
             lambda q, k, v, bias, out, lse, g, seed: bwd(
                 q, k, v, bias, seed, out, lse, g, scale=scale,
                 p_drop=drop, causal=causal),
             (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
-            "bwd", dims, window)
+            "bwd", dims, window, form)
     return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

@@ -20,9 +20,17 @@ ridge of 240.
 
 - Forward: k-blocks inner; running (m, l, acc) in VMEM scratch across
   the k-block loop; emits the output AND the logsumexp rows.
-- Backward: recompute p = exp(s - lse) per block (no stored attention).
-  dq in one kernel (k-blocks inner), dk/dv in a second (q-blocks inner),
-  using the standard delta = rowsum(do * o) reduction. Exposed as
+- Backward: recompute p = exp(s - lse) per block (no stored attention),
+  using the standard delta = rowsum(do * o) reduction. ONE kernel
+  (``attn.bhtd.bwd``, q-blocks inner) computes a live block's scores,
+  exp and dp once and adds to all three gradients, 5 matmuls; what a
+  k-row's scratch cannot gather (dq; dk and dv where a group of query
+  heads shares them) stays resident in VMEM for a head and is written
+  once, so nothing gradient-sized but dq, dk, dv reaches HBM. Where
+  ``bhtd_bwd_form`` says a call does not fit that kernel (resident rows
+  over its VMEM cap, heads batched in a step, dropout) the pair runs:
+  dq in one kernel (k-blocks inner), dk/dv in a second (q-blocks
+  inner), 7 matmuls and two walks. Exposed as
   ``flash_attention_bwd`` so the framework's sdpa_grad op can consume the
   forward's saved (out, lse) instead of re-running the forward kernel
   (XLA cannot CSE custom calls, so a vjp-style recompute would execute).
@@ -200,6 +208,65 @@ def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
     return "bhtd" if tile else "dense"
 
 
+# What the fused backward call may keep in VMEM: its resident rows, its
+# blocks and its score temps (_bwd_vmem_bytes). Half of a v5e core's 128
+# MiB: the call raises Mosaic's scoped limit (16 MiB by default) to a
+# quarter over its own count, and the half left is room for what the
+# count does not see (Mosaic's spills and relayout buffers, the
+# pipeline's semaphores). The longest calls of the cells count 59 MB
+# (28 / 4 heads of 128 x 16,384 and 16 / 2 of 256 x 8192); twice their
+# rows under a shared key/value head is the pair's.
+_BWD_VMEM_CAP_BYTES = 64 * 2**20
+
+
+def _bwd_vmem_bytes(tq, tk, dh, dv, group, bq, bk, itemsize):
+    """What ``attn.bhtd.bwd`` keeps in VMEM at this call: the float32
+    accumulators (dq's resident rows; dk's and dv's, or a block of each
+    where no group shares them), the gradients' output blocks and the
+    operands' blocks, double-buffered, and eight score-sized float32
+    temps."""
+    rk = tk if group > 1 else bk
+    grads = tq * dh + rk * (dh + dv)
+    blocks = bq * (dh + dv) + bk * (dh + dv)
+    return (4 * grads + 2 * itemsize * (grads + blocks) + 2 * 4 * 2 * bq
+            + 8 * 4 * bq * bk)
+
+
+def _bwd_vmem_limit(*call):
+    """Mosaic's scoped limit for the fused call: what it keeps and a
+    quarter more, not under the default of 16 MiB."""
+    return max(16 * 2**20, _bwd_vmem_bytes(*call) * 5 // 4)
+
+
+def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
+                  dv=None, itemsize=2, p_drop=0.0):
+    """-> "fused" (ONE call, ``attn.bhtd.bwd``: a live block's scores,
+    exp and dp computed once, dq, dk and dv taken from them), "split"
+    (the pair ``attn.bhtd.bwd_dq`` + ``attn.bhtd.bwd_dkv``) or None (no
+    tile: the dense composition), from what the call shows. The one
+    place that decides: ``flash_attention_bwd``, the dispatch counter's
+    ``form`` label and the tests read it.
+
+    Fused keeps the gradients that a step's scratch cannot hold RESIDENT
+    in VMEM for a whole head, so it takes the calls whose rows fit
+    ``_BWD_VMEM_CAP_BYTES``; one head a step (a tile that batches heads
+    is a short sequence: few steps, nothing resident to win); blocks
+    that cut lse and delta from [1, tq] rows (a multiple of the 128
+    lanes, or the whole row); no dropout (the mask stream is keyed by
+    the split grids' head group). A bias does not matter: both forms
+    carry it."""
+    dv = dv or dh
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
+    if tile is None:
+        return None
+    hb, bq, bk = tile
+    if (hb > 1 or p_drop > 0.0 or (bq % 128 and bq != tq)
+            or _bwd_vmem_bytes(tq, tk, dh, dv, group, bq, bk, itemsize)
+            > _BWD_VMEM_CAP_BYTES):
+        return "split"
+    return "fused"
+
+
 # ---------------------------------------------------------------------------
 # kernels — refs are blocks of the native [b, h, t, dh] layout over the
 # grid (batch row, head group, q-block, k-block); index 0 drops the
@@ -279,8 +346,10 @@ def _on_edge(j, kk, bq, bk, window):
     """Does the diagonal or the band's far edge cross block (j, kk):
     is some pair of it in the future, or forgotten? Every other live
     block is all visible and takes no mask."""
-    return jnp.logical_or((kk + 1) * bk - 1 > j * bq,
-                          (j + 1) * bq - 1 - kk * bk >= window)
+    diagonal = (kk + 1) * bk - 1 > j * bq
+    if window is None:
+        return diagonal
+    return jnp.logical_or(diagonal, (j + 1) * bq - 1 - kk * bk >= window)
 
 
 def _when_live(compute, live, edge):
@@ -523,6 +592,119 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_block(q, k, v, do, lse, delta, bias, scale, mask):
+    """One (q-block, k-block) pair's part of the three gradients, its
+    scores, their exp and dp computed ONCE: q, do [bq, .], k, v [bk, .],
+    lse and delta [1, bq] rows -> (dq [bq, dh], dk [bk, dh], dv [bk, dv])
+    in float32. The block is worked on transposed, s_t [bk, bq], as
+    _dkv_kernel does: the statistics lie along the lanes (no padded
+    column), dv and dk are plain products and only dq takes a
+    transposed left operand. ``mask``: None, or what an edge block's
+    scores go through."""
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))       # a b^T
+    s_t = jax.lax.dot_general(k, q, nt, preferred_element_type=f32) * scale
+    if bias is not None:
+        s_t = s_t + bias.astype(f32).T
+    if mask is not None:
+        s_t = mask(s_t)
+    p_t = jnp.exp(s_t - lse)
+    dp_t = jax.lax.dot_general(v, do, nt, preferred_element_type=f32)
+    ds_t = (p_t * (dp_t - delta) * scale).astype(q.dtype)
+    dv = jnp.dot(p_t.astype(do.dtype), do, preferred_element_type=f32)
+    dk = jnp.dot(ds_t, q, preferred_element_type=f32)
+    dq = jax.lax.dot_general(ds_t, k, (((0,), (0,)), ((), ())),
+                             preferred_element_type=f32)
+    return dq, dk, dv
+
+
+def _block_rows(acc, idx, rows):
+    """Block ``idx`` of an accumulator's rows: all of one that holds a
+    single block, else that block of a resident one."""
+    if acc.shape[0] == rows:
+        return slice(None)
+    return pl.ds(pl.multiple_of(idx * rows, rows), rows)
+
+
+def _each_block(acc, rows, body):
+    """``body(rows of block c)`` for every block of an accumulator (a
+    loop: a resident one is thousands of vregs)."""
+    def step(c, carry):
+        body(_block_rows(acc, c, rows))
+        return carry
+    jax.lax.fori_loop(0, acc.shape[0] // rows, step, 0)
+
+
+def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
+                delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                *, scale, nq, nk, group, causal=False, window=None,
+                last_q=None):
+    """attn.bhtd.bwd: grid (batch row, key/value head, member of its
+    group, k-block, step), one head a step: the dk/dv kernel's walk, a
+    k-row's ``nq`` steps over the q-blocks (with a window: over its
+    band, from its first q-block; ``last_q``: the sequence's last one).
+    Every live block adds to all three gradients, so what a row's
+    scratch cannot gather stays RESIDENT in VMEM: dq [tq, dh] for the
+    query head (every k-row adds to the q-blocks it sees), and, where a
+    group shares a key/value head, dk and dv [tk, .] for the group
+    (under one head a step they gather in a k-row's scratch, as in
+    _dkv_kernel). Each is zeroed at the first step of what it gathers
+    and written, once, at the last."""
+    del seed_ref                        # (no dropout: bhtd_bwd_form)
+    m, kk, r = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    j = r if window is None else (kk * bk) // bq + r
+
+    def span(resident, shared):
+        """(first, last) step of what an accumulator gathers: a k-row's
+        steps; a head's k-rows where it is resident; the group's heads
+        where they share it."""
+        first, last = r == 0, r == nq - 1
+        if resident:
+            first = jnp.logical_and(first, kk == 0)
+            last = jnp.logical_and(last, kk == nk - 1)
+        if resident and shared:
+            first = jnp.logical_and(first, m == 0)
+            last = jnp.logical_and(last, m == group - 1)
+        return first, last
+
+    # (accumulator, its output, rows of a block, the block a step adds
+    # to, its first and last step)
+    accs = ((dq_acc, dq_ref, bq, j, span(True, False)),
+            (dk_acc, dk_ref, bk, kk, span(group > 1, True)),
+            (dv_acc, dv_ref, bk, kk, span(group > 1, True)))
+
+    for acc, _, rows, _, (first, _) in accs:
+        def _zero(at, acc=acc, rows=rows):
+            acc[at, :] = jnp.zeros((rows, acc.shape[1]), acc.dtype)
+        pl.when(first)(functools.partial(_each_block, acc, rows, _zero))
+
+    def _compute(masked=False):
+        mask = None
+        if masked:
+            mask = lambda s_t: _causal_mask(
+                s_t[None], j, kk, bq, bk, transposed=True, window=window)[0]
+        parts = _bwd_block(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+            lse_ref[0, 0], delta_ref[0, 0],
+            None if bias_ref is None else bias_ref[0, 0], scale, mask)
+        for (acc, _, rows, idx, _), part in zip(accs, parts):
+            acc[_block_rows(acc, idx, rows), :] += part
+
+    if causal:
+        # (a band's steps start at the k-row's first live q-block)
+        live = (_causal_live(j, kk, bq, bk) if window is None else
+                j <= jnp.minimum(_last_q(kk, bq, bk, window), last_q))
+        _when_live(_compute, live, _on_edge(j, kk, bq, bk, window))
+    else:
+        _compute()
+
+    for acc, out_ref, rows, _, (_, last) in accs:
+        def _write(at, acc=acc, out_ref=out_ref):
+            out_ref[0, 0, at, :] = acc[at, :].astype(out_ref.dtype)
+        pl.when(last)(functools.partial(_each_block, acc, rows, _write))
 
 
 def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
@@ -834,6 +1016,63 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     return out, lse
 
 
+def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
+               window):
+    """dq, dk, dv as ONE call (_bwd_kernel); ``delta`` with the lse
+    cotangent folded in, ``window`` as _band gives it."""
+    b, h, tq, dh = q.shape
+    tk, dv = k.shape[2], v.shape[3]
+    group = h // k.shape[1]
+    _, bq, bk = tile
+    nq, nk = tq // bq, tk // bk
+    q_steps = _q_steps(window, nq, nk, bq, bk)
+    block_of = _step_blocks(causal, False, bq, bk, nq, 1, window, q_steps)
+
+    def at(i, hk, m, kk, r, *_):
+        # (the grid's heads: key/value head, then the member of its group)
+        return block_of(i, hk * group + m, kk, r)
+
+    kernel, specs, args, rows = _call_parts(_bwd_kernel, at, tile, q, k, v,
+                                            bias)
+    kernel = functools.partial(
+        kernel, scale=scale, nq=q_steps, nk=nk, group=group, causal=causal,
+        window=window, last_q=nq - 1)
+    # a resident gradient: all rows of one head, one block of the output
+    dq_spec = pl.BlockSpec((1, 1, tq, dh),
+                           lambda i, hk, m, *_: (i, hk * group + m, 0, 0))
+    dk_spec, dv_spec, kv_rows = rows.k, rows.v, bk
+    if group > 1:
+        dk_spec, dv_spec = (pl.BlockSpec((1, 1, tk, d),
+                                         lambda i, hk, *_: (i, hk, 0, 0))
+                            for d in (dh, dv))
+        kv_rows = tk
+    # lse and delta as [b, h, 1, tq] rows, as the dk/dv kernel takes them
+    operands = (seed_arr, *args, g, lse.reshape(b, h, 1, tq),
+                delta.reshape(b, h, 1, tq))
+    return pl.pallas_call(
+        kernel, name="attn.bhtd.bwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // group, group, nk, q_steps),
+            in_specs=specs + [rows.o, rows.row, rows.row],
+            out_specs=[dq_spec, dk_spec, dv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((tq, dh), jnp.float32),
+                pltpu.VMEM((kv_rows, dh), jnp.float32),
+                pltpu.VMEM((kv_rows, dv), jnp.float32)],
+        ),
+        out_shape=[
+            _result(operands, q.shape, q.dtype),
+            _result(operands, k.shape, k.dtype),
+            _result(operands, v.shape, v.dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_vmem_limit(
+                tq, tk, dh, dv, group, bq, bk, q.dtype.itemsize)),
+        interpret=_INTERPRET,
+    )(*operands)
+
+
 def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                         p_drop: float = 0.0,
                         q_block: Optional[int] = None,
@@ -873,6 +1112,10 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     seed_arr = _seed_arr(seed)
+    if bhtd_bwd_form(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+                     itemsize=q.dtype.itemsize, p_drop=p_drop) == "fused":
+        return _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile,
+                          scale, causal, window)
     kw = dict(scale=scale, ng=ng, p_drop=p_drop, causal=causal,
               window=window)
     # the inner axes: all nk key blocks a q-row and all nq query blocks

@@ -11,6 +11,8 @@ results or times. The topology is described inside a fixture: only the
 worker that is handed this file loads libtpu.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -58,8 +60,30 @@ def real_kernels(monkeypatch):
         compilation_cache.reset_cache()
 
 
+# The Mosaic calls of a BHTD forward and backward, by what
+# ``fa.bhtd_bwd_form`` answers for the call.
+_NAMES = {
+    "fused": ("attn.bhtd.fwd", "attn.bhtd.bwd"),
+    "split": ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"),
+}
+
+
+def _calls(text):
+    """The BHTD attention kernels an HLO text calls, by the name their
+    op_name carries (".../attn.bhtd.bwd/pallas_call", under a grad
+    ".../transpose(jvp(attn.bhtd.bwd))/pallas_call")."""
+    return set(re.findall(r"[/(](attn\.bhtd\.\w+)[)/]", text))
+
+
+def _holds_the_calls(text, form):
+    # one form or the other, never both
+    assert _calls(text) == set(_NAMES[form])
+
+
 # (b, h, t, dh, dtype, bias shape or None, causal, p_drop, the caller's
-# q_block or None, the tile)
+# q_block or None, the tile); the backward of the calls that keep one
+# head a step, draw no dropout mask and cut lse and delta from rows is
+# ONE call (attn.bhtd.bwd), of the others the pair
 _CASES = {
     "olmoe": (2, 16, 4096, 128, jnp.bfloat16, None, True, 0.0, None,
               (1, 512, 512)),
@@ -87,6 +111,7 @@ _CASES = {
     "q_block_64": (2, 2, 256, 64, jnp.bfloat16, (2, 1, 1, 256), True, 0.0,
                    64, (2, 64, 256)),
 }
+_FUSED = {"olmoe", "olmoe_row_bias", "chip_smoke_bhtd"}
 
 
 @pytest.mark.parametrize("case", sorted(_CASES))
@@ -94,6 +119,10 @@ def test_bhtd_forward_and_backward_compile(case, one_chip, real_kernels):
     (b, h, t, dh, dtype, bias_shape, causal, p_drop, q_block,
      tile) = _CASES[case]
     assert fa.bhtd_tile(h, t, t, q_block, dh=dh) == tile
+    form = fa.bhtd_bwd_form(
+        h, t, t, q_block, dh=dh, itemsize=jnp.dtype(dtype).itemsize,
+        p_drop=p_drop)
+    assert form == ("fused" if case in _FUSED else "split")
 
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -110,9 +139,8 @@ def test_bhtd_forward_and_backward_compile(case, one_chip, real_kernels):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x, bias, seed).compile().as_text()
-    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
-        assert name in text, name
-    assert text.count("tpu_custom_call") >= 3
+    _holds_the_calls(text, form)
+    assert text.count("tpu_custom_call") >= len(_NAMES[form])
 
 
 # (m, k, n, e) of a forward product [m, k] x [e, k, n] that gmm_tile
@@ -157,8 +185,8 @@ def test_grouped_matmul_kernels_compile(case, one_chip, real_kernels):
 def test_grouped_query_attention_compiles(one_chip, real_kernels):
     """Qwen3-Next's attention layer: 16 query heads over 2 key/value
     heads of 256 at 8192 positions, one head a step at blocks of 512;
-    the dk/dv call's grid walks a group's 8 heads and writes [b, 2, t,
-    256]."""
+    the backward call's grid walks a group's 8 heads and writes [b, 2,
+    t, 256] from rows resident in VMEM."""
     b, h, hk, t, dh = 1, 16, 2, 8192, 256
     assert fa.bhtd_tile(h, t, t, dh=dh, group=h // hk) == (1, 512, 512)
 
@@ -173,8 +201,7 @@ def test_grouped_query_attention_compiles(one_chip, real_kernels):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         arg(h), arg(hk), arg(hk)).compile()
     text = compiled.as_text()
-    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
-        assert name in text, name
+    _holds_the_calls(text, "fused")
     # K and V are read where they lie: nothing [b, 16, t, 256] besides
     # q, out and their gradients
     assert "bf16[1,2,8192,256]" in text
@@ -199,8 +226,7 @@ def test_latent_attention_compiles(one_chip, real_kernels):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         arg(dk), arg(dk), arg(dv)).compile()
     text = compiled.as_text()
-    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
-        assert name in text, name
+    _holds_the_calls(text, "fused")
     assert "bf16[1,32,4096,192]" in text and "4096,256]" not in text
     # q, k and v in: 2 x (2 x 192 + 128) bytes a position and head, no
     # padded copy among them
@@ -236,10 +262,10 @@ def test_held_share_grouped_matmuls_compile(one_chip, real_kernels):
 def test_windowed_attention_compiles(one_chip, real_kernels):
     """SmallThinker's window layers: 28 query heads over 4 key/value
     heads of 128 (a group of 7) at 16,384 positions that see the last
-    4096, one head a step at blocks of 512. All three grids' inner axis
-    is the band's 9 blocks, not the sequence's 32 (a head of the dk/dv
-    grid: 7 x 9), and the two branches of a windowed step (masked on an
-    edge block, plain inside the band) fit Mosaic's VMEM."""
+    4096, one head a step at blocks of 512. Both grids' inner axis is
+    the band's 9 blocks, not the sequence's 32, and the two branches of
+    a windowed step (masked on an edge block, plain inside the band)
+    fit Mosaic's VMEM beside the backward call's resident rows."""
     b, h, hk, t, dh, window = 1, 28, 4, 16384, 128, 4096
     assert fa.bhtd_tile(h, t, t, dh=dh, group=h // hk) == (1, 512, 512)
     assert fa._k_steps(window, 32, 32, 512, 512) == 9
@@ -256,9 +282,67 @@ def test_windowed_attention_compiles(one_chip, real_kernels):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         arg(h), arg(hk), arg(hk)).compile().as_text()
-    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"):
-        assert name in text, name
+    _holds_the_calls(text, "fused")
     assert "bf16[1,4,16384,128]" in text
+
+
+# (b, query heads, key/value heads, t, dh, dv, window) of the four decoder
+# cells' attention calls
+_CELL_CALLS = {
+    "smallthinker_w4096": (1, 28, 4, 16384, 128, 128, 4096),
+    "smallthinker_global": (1, 28, 4, 16384, 128, 128, None),
+    "joyai": (1, 32, 32, 4096, 192, 128, None),
+    "qwen3next": (1, 16, 2, 8192, 256, 256, None),
+    "olmoe": (2, 16, 16, 4096, 128, 128, None),
+}
+
+
+def _lowered_bwd(call, one_chip):
+    b, h, hk, t, dh, dv, window = _CELL_CALLS[call]
+
+    def arg(heads, width, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((b, heads, t, width), dt,
+                                    sharding=one_chip)
+
+    def bwd(q, k, v, out, lse, g):
+        return fa.flash_attention_bwd(q, k, v, None, None, out, lse, g,
+                                      causal=True, window=window)
+
+    return jax.jit(bwd).lower(
+        arg(h, dh), arg(hk, dh), arg(hk, dv), arg(h, dv),
+        arg(h, 1, jnp.float32), arg(h, dv))
+
+
+@pytest.mark.parametrize("call", sorted(_CELL_CALLS))
+def test_fused_backward_compiles_at_the_cells_calls(call, one_chip,
+                                                    real_kernels):
+    """The backward of every BHTD call of the four decoder cells is ONE
+    Mosaic call whose resident rows (dq for a query head; dk and dv for
+    a group's key/value head) fit the VMEM it asks for, and nothing
+    gradient-sized leaves it besides dq, dk and dv."""
+    b, h, hk, t, dh, dv, _ = _CELL_CALLS[call]
+    assert fa.bhtd_bwd_form(h, t, t, dh=dh, group=h // hk, dv=dv) == "fused"
+    assert fa._bwd_vmem_bytes(t, t, dh, dv, h // hk, 512, 512, 2) \
+        <= fa._BWD_VMEM_CAP_BYTES
+    compiled = _lowered_bwd(call, one_chip).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert _calls(text) == {"attn.bhtd.bwd"}
+    # (the result tuple's table besides)
+    assert 0 <= compiled.memory_analysis().output_size_in_bytes \
+        - 2 * b * t * (h * dh + hk * (dh + dv)) < 4096
+
+
+def test_the_split_pair_compiles_where_the_rows_pass_the_cap(
+        one_chip, real_kernels, monkeypatch):
+    """``bhtd_bwd_form`` alone chooses: with no room for a resident row
+    the same call lowers as the pair."""
+    monkeypatch.setattr(fa, "_BWD_VMEM_CAP_BYTES", 2**20)
+    b, h, hk, t, dh, dv, _ = _CELL_CALLS["smallthinker_w4096"]
+    assert fa.bhtd_bwd_form(h, t, t, dh=dh, group=h // hk, dv=dv) == "split"
+    text = _lowered_bwd("smallthinker_w4096", one_chip).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _calls(text) == {"attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"}
 
 
 def test_smallthinker_held_grouped_matmuls_compile(one_chip, real_kernels):

@@ -153,8 +153,9 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert row["conv"] == {"kernel fwd b1 t512 c512 taps4": 3,
                            "kernel bwd b1 t512 c512 taps4": 3}
     assert sorted(row["attention"]) == [
-        f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]"
-        for d in ("bwd", "fwd")]
+        f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]{form}"
+        for d, form in (("bwd", " form=fused"), ("fwd", ""))]
+    assert row["attn_bwd_kernel_ms"] == {}      # (a trace needs the chip)
     assert sum(row["grouped_matmuls"].values()) == 36
     assert set(row["rel_err"]) == {
         "o", "dq", "dk", "dv", "dg", "dbeta", "conv_y", "conv_dx",
@@ -213,14 +214,28 @@ def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(
 
     monkeypatch.setattr(gm, "_INTERPRET", True)
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    row = chip_smoke.mla_phase(seq=512, t_check=256, heads=2, **MLA_TINY)
+    # (one head: a tile that batches heads lowers the backward as the
+    # pair, which the phase refuses: the test below)
+    row = chip_smoke.mla_phase(seq=512, t_check=256, heads=1,
+                               **dict(MLA_TINY, num_attention_heads=1))
     assert row["attention"] == {
-        f"bhtd {d} b1 tq512 tk512 h2 dk192 dv128 [hb2 bq256 bk256]": 3
-        for d in ("bwd", "fwd")}
+        f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}": 3
+        for d, form in (("bwd", " form=fused"), ("fwd", ""))}
     assert list(row["routers"]) == ["score=sigmoid bias=1 k=2 experts=8"]
     assert sum(row["grouped_matmuls"].values()) == 18
     assert set(row["rel_err"]) == {"attn_o", "attn_dq", "attn_dk", "attn_dv"}
     assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+def test_mla_phase_fails_on_a_split_backward_call(telemetry, monkeypatch):
+    """Two heads in a step (a tile of a short sequence): the backward
+    lowers as bwd_dq + bwd_dkv, and the phase says so."""
+    from paddle_tpu.parallel import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_INTERPRET", True)
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    with pytest.raises(chip_smoke.SmokeFailure, match="none split"):
+        chip_smoke.mla_phase(seq=512, t_check=256, heads=2, **MLA_TINY)
 
 
 def test_mla_phase_fails_on_a_dense_attention_call(telemetry, monkeypatch):

@@ -503,3 +503,189 @@ def test_no_kernels_no_tile(monkeypatch):
     assert fa.bhtd_family(16, 4096, 4096, dh=128) == "dense"
     monkeypatch.setattr(fa, "_INTERPRET", True)
     assert fa.bhtd_tile(8, 700, 700, dh=64) is None    # no block tiles 700
+
+
+# --- one backward call: attn.bhtd.bwd (PR 39) ---
+#
+# A live block's scores, exp and dp are computed once and dq, dk and dv
+# taken from them, against the split pair (bwd_dq + bwd_dkv) and against
+# jax.vjp of the dense composition.
+
+# (query heads, key/value heads, tq, tk, dh, dv, block, causal, window,
+# bias, a cotangent for lse)
+_FUSED_CASES = {
+    "causal": (1, 1, 512, 512, 16, 16, 128, True, None, None, False),
+    "causal_one_block": (1, 1, 128, 128, 16, 16, 128, True, None, None,
+                         False),
+    # a band three blocks wide over five: dead steps behind the last
+    # rows' bands, edge blocks on the diagonal and on the far side
+    "band": (1, 1, 640, 640, 16, 16, 128, True, 200, None, False),
+    "band_group7": (7, 1, 640, 640, 16, 16, 128, True, 200, None, False),
+    "band_group8": (8, 1, 512, 512, 16, 16, 128, True, 129, None, True),
+    "window_of_the_row": (2, 1, 384, 384, 16, 16, 128, True, 384, None,
+                          False),
+    "group7": (7, 1, 384, 384, 16, 16, 128, True, None, None, False),
+    "group8_two_kv_heads": (16, 2, 256, 256, 16, 16, 128, True, None, None,
+                            False),
+    "dk192_dv128": (1, 1, 256, 256, 192, 128, 128, True, None, None, False),
+    "lse_cotangent": (2, 1, 384, 384, 16, 16, 128, True, None, None, True),
+    "non_causal": (1, 1, 384, 384, 16, 16, 128, False, None, None, False),
+    "cross_tq_tk": (2, 1, 256, 512, 16, 16, 128, False, None, None, True),
+    "pad_bias": (1, 1, 384, 384, 16, 16, 128, True, None, "pad", False),
+    "row_bias_per_head": (2, 1, 256, 256, 16, 16, 128, False, None, "rows",
+                          False),
+}
+
+
+def _fused_case(case):
+    (h, hk, tq, tk, dh, dv, blk, causal, window, bias_kind,
+     with_g_lse) = _FUSED_CASES[case]
+    r = np.random.RandomState(len(case))
+
+    def rand(*shape, s=1.0):
+        return jnp.asarray(r.randn(*shape) * s, jnp.float32)
+
+    q, k = rand(1, h, tq, dh, s=0.5), rand(1, hk, tk, dh, s=0.5)
+    v, g = rand(1, hk, tk, dv), rand(1, h, tq, dv)
+    bias = None
+    if bias_kind == "pad":
+        bias = _pad_bias(1, tk, 37)
+    elif bias_kind == "rows":
+        bias = rand(1, h, tq, tk)
+    g_lse = rand(1, h, tq, 1) if with_g_lse else None
+    kw = dict(causal=causal, window=window, q_block=blk, k_block=blk)
+    return (q, k, v, bias, g, g_lse), kw
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED_CASES))
+def test_fused_backward_matches_the_pair_and_the_composition(case,
+                                                             monkeypatch):
+    (q, k, v, bias, g, g_lse), kw = _fused_case(case)
+    h, hk, tq, tk, dh, dv, blk = _FUSED_CASES[case][:7]
+    form = dict(dh=dh, group=h // hk, dv=dv, itemsize=4)
+    assert fa.bhtd_tile(h, tq, tk, blk, blk, dh=dh, group=h // hk,
+                        dv=dv) == (1, blk, blk)
+    assert fa.bhtd_bwd_form(h, tq, tk, blk, blk, **form) == "fused"
+    window = fa._band(kw["window"], kw["causal"], tq, tk)
+    scale = dh ** -0.5
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa.flash_attention_fwd(q, k, v, bias, **kw)
+        got = fa.flash_attention_bwd(q, k, v, bias, None, out, lse, g,
+                                     g_lse=g_lse, **kw)
+        # no room for a resident row: the pair
+        monkeypatch.setattr(fa, "_BWD_VMEM_CAP_BYTES", 0)
+        assert fa.bhtd_bwd_form(h, tq, tk, blk, blk, **form) == "split"
+        pair = fa.flash_attention_bwd(q, k, v, bias, None, out, lse, g,
+                                      g_lse=g_lse, **kw)
+        _, vjp = jax.vjp(
+            lambda q, k, v: fa._reference_attention_with_lse(
+                q, k, v, bias, scale, causal=kw["causal"], window=window),
+            q, k, v)
+        want = vjp((g, jnp.zeros_like(lse) if g_lse is None else g_lse))
+    for a, p, w, name in zip(got, pair, want, ("dq", "dk", "dv")):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        # the pair's arithmetic in another order of float32 additions
+        np.testing.assert_allclose(a, p, rtol=1e-5, atol=2e-6, err_msg=name)
+        np.testing.assert_allclose(a, w, rtol=1e-4, atol=2e-5, err_msg=name)
+
+
+def _pallas_calls(f, *args):
+    """[(name, kernel jaxpr, eqn)] of the pallas_calls ``f`` lowers."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"], eqn.params["jaxpr"], eqn))
+                continue
+            for v in eqn.params.values():
+                for x in v if isinstance(v, (list, tuple)) else [v]:
+                    inner = getattr(x, "jaxpr", x)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(f)(*args).jaxpr)
+    return found
+
+
+def _count(jaxpr, primitive):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == primitive
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    n += _count(inner, primitive)
+    return n
+
+
+@pytest.mark.parametrize("case,branches", [
+    ("non_causal", 1), ("causal", 2), ("band_group7", 2), ("pad_bias", 2)])
+def test_fused_backward_is_one_call_of_five_matmuls_and_one_exp(case,
+                                                                branches):
+    """ONE Mosaic call named attn.bhtd.bwd; the body of a live step
+    holds five dot_generals and one exp (a causal call has two such
+    bodies, masked for an edge block and plain inside, of which a step
+    runs one); its only results are dq, dk and dv."""
+    (q, k, v, bias, g, _), kw = _fused_case(case)
+    out, lse = jax.eval_shape(
+        lambda q, k, v: fa.flash_attention_fwd(q, k, v, bias, **kw), q, k, v)
+    calls = _pallas_calls(
+        lambda q, k, v, g: fa.flash_attention_bwd(
+            q, k, v, bias, None, jnp.zeros(out.shape), jnp.zeros(lse.shape),
+            g, **kw), q, k, v, g)
+    ((name, kernel, eqn),) = calls
+    assert name == "attn.bhtd.bwd"
+    assert _count(kernel, "dot_general") == 5 * branches
+    assert _count(kernel, "exp") == branches
+    assert [x.aval.shape for x in eqn.outvars] == [q.shape, k.shape, v.shape]
+
+
+def test_bhtd_bwd_form_follows_the_call(monkeypatch):
+    """Fused where the resident rows fit the cap, one head a step, no
+    dropout, statistics cut from rows; the pair elsewhere, and it runs."""
+    cells = dict(smallthinker=(28, 16384, 128, 128, 7),
+                 qwen3next=(16, 8192, 256, 256, 8),
+                 joyai=(32, 4096, 192, 128, 1), olmoe=(16, 4096, 128, 128, 1))
+    for h, t, dh, dv, group in cells.values():
+        assert fa.bhtd_bwd_form(h, t, t, dh=dh, group=group, dv=dv) == "fused"
+    # a row of 64k at heads of 128: 78 MB of rows and blocks
+    assert fa._bwd_vmem_bytes(65536, 65536, 128, 128, 1, 512, 512, 2) \
+        > fa._BWD_VMEM_CAP_BYTES
+    assert fa.bhtd_bwd_form(16, 65536, 65536, dh=128) == "split"
+    # twice smallthinker's row under its group of 7
+    assert fa.bhtd_bwd_form(28, 32768, 32768, dh=128, group=7) == "split"
+    assert fa.bhtd_bwd_form(16, 32768, 32768, dh=128) == "fused"
+    # heads batched in a step, dropout, a q block off the lanes
+    assert fa.bhtd_tile(2, 256, 256, dh=64) == (2, 256, 256)
+    assert fa.bhtd_bwd_form(2, 256, 256, dh=64) == "split"
+    assert fa.bhtd_bwd_form(16, 4096, 4096, dh=128, p_drop=0.1) == "split"
+    assert fa.bhtd_bwd_form(1, 256, 256, 64, 128, dh=16) == "split"
+    # no tile, no form: the dense composition
+    assert fa.bhtd_bwd_form(2, 100, 100, 64, 64, dh=16) is None
+    monkeypatch.setattr(fa, "_INTERPRET", False)
+    assert fa.bhtd_bwd_form(16, 4096, 4096, dh=128) is None
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+
+    # the pair is what a "split" call lowers
+    (q, k, v, bias, g, _), kw = _fused_case("group7")
+    monkeypatch.setattr(fa, "_BWD_VMEM_CAP_BYTES", 2**16)
+    names = [name for name, _, _ in _pallas_calls(
+        lambda q, k, v, g: fa.flash_attention_bwd(
+            q, k, v, None, None, jnp.zeros(g.shape),
+            jnp.zeros(g.shape[:3] + (1,)), g, **kw), q, k, v, g)]
+    assert names == ["attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv"]
+
+
+def test_a_window_as_long_as_the_row_lowers_the_plain_fused_call():
+    (q, k, v, _, g, _), kw = _fused_case("window_of_the_row")
+    out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+
+    def text(window):
+        return jax.jit(lambda q, k, v, g: fa.flash_attention_bwd(
+            q, k, v, None, None, out, lse, g, **dict(kw, window=window))
+        ).lower(q, k, v, g).as_text()
+
+    assert text(384) == text(None) == text(1000)
+    assert text(383) != text(None)

@@ -247,7 +247,7 @@ def pallas_names(f, *args):
     return found
 
 
-def test_the_eight_pallas_calls_carry_family_and_pass(monkeypatch):
+def test_the_nine_pallas_calls_carry_family_and_pass(monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
     q = jnp.zeros((1, 2, 256, 64))          # [b, h, t, dh]
     o, lse = jax.eval_shape(
@@ -274,17 +274,23 @@ def test_the_eight_pallas_calls_carry_family_and_pass(monkeypatch):
         + pallas_names(lambda q: fa.flash_attention_bwd(
             q, q, q, None, None, jnp.zeros(o.shape), jnp.zeros(lse.shape),
             jnp.zeros(o.shape), q_block=128, k_block=128), q)
+        # one head a step: the backward is ONE call (fa.bhtd_bwd_form)
+        + pallas_names(lambda q: fa.flash_attention_bwd(
+            q, q, q, None, None, jnp.zeros(q.shape),
+            jnp.zeros(q.shape[:3] + (1,)), jnp.zeros(q.shape),
+            q_block=128, k_block=128), q[:, :1])
         + bthd(k) + bthd(x)
         + pallas_names(lambda s: fa.bthd_dropout_masks(
             1, 128, 128, 2, 64, 0.1, s), jnp.zeros((), jnp.int32)))
     assert got == [
         "attn.bhtd.fwd", "attn.bhtd.bwd_dq", "attn.bhtd.bwd_dkv",
+        "attn.bhtd.bwd",
         "attn.bthd_kblock.fwd", "attn.bthd_kblock.bwd",
         "attn.bthd_small.fwd", "attn.bthd_small.bwd",
         "attn.bthd_small.dropout_masks"]
     # every call site of the file is one of them
     src = open(fa.__file__).read()
-    assert src.count("pl.pallas_call(") == 8 == len(
+    assert src.count("pl.pallas_call(") == 9 == len(
         re.findall(r'^ +\w+, name=f?"attn\.', src, re.M))
 
 
