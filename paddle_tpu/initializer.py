@@ -60,6 +60,20 @@ class LogUniformInitializer(UniformInitializer):
                         outputs={"Out": var.name})
 
 
+class LogRangeInitializer(ConstantInitializer):
+    """log(1), log(2), .. along the last axis, the same in every row: a
+    state-space layer's ``A_log`` (Mamba's S4D-real initialisation)."""
+
+    def __init__(self):
+        super().__init__(1.0)
+
+    def __call__(self, var, block):
+        super().__call__(var, block)
+        for op, attrs in (("cumsum", {"axis": -1}), ("log", {})):
+            block.append_op(op, inputs={"X": var.name},
+                            outputs={"Out": var.name}, attrs=attrs)
+
+
 class NormalInitializer(Initializer):
     def __init__(self, loc=0.0, scale=1.0, seed=0):
         self.loc, self.scale, self.seed = loc, scale, seed

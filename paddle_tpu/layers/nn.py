@@ -35,7 +35,8 @@ __all__ = [
     "linear_chain_crf", "crf_decoding", "warpctc", "edit_distance",
     "bilinear_tensor_product", "nce", "switch_moe", "topk_moe",
     "rms_norm", "rotary_embedding", "causal_conv1d", "gdn_gates",
-    "gated_delta_rule", "gated_rms_norm", "silu",
+    "gated_delta_rule", "gated_rms_norm", "silu", "selective_scan",
+    "diff_attention_combine",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
     "yolo_box", "sequence_conv", "add_position_encoding", "conv3d",
     "spectral_norm", "hsigmoid", "sample_logits",
@@ -415,17 +416,25 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
     return q_out, k_out
 
 
-def causal_conv1d(input, taps=4, act="silu", param_attr=None, name=None):
+def causal_conv1d(input, taps=4, act="silu", param_attr=None,
+                  bias_attr=False, name=None):
     """Depthwise convolution over the sequence of ``input`` [b, t, c]
-    that sees no later position, ``taps`` wide, no bias, then ``act``
-    ("silu" or None): the short convolution in front of a linear
-    attention (ops/linear_attention_ops.py). Parameter [c, taps]."""
+    that sees no later position, ``taps`` wide, then ``act`` ("silu" or
+    None): the short convolution in front of a linear attention or a
+    selective scan (ops/linear_attention_ops.py). Parameter [c, taps];
+    ``bias_attr`` (False: none, as Qwen3-Next's) adds a bias [c] in
+    front of ``act``, as Mamba's."""
     helper = LayerHelper("causal_conv1d", name=name)
     w = helper.create_parameter(
         ParamAttr._to_attr(param_attr), shape=[input.shape[-1], int(taps)],
         dtype=input.dtype)
+    inputs = {"X": input, "W": w}
+    if bias_attr is not False:
+        inputs["Bias"] = helper.create_parameter(
+            ParamAttr._to_attr(bias_attr), shape=[input.shape[-1]],
+            dtype=input.dtype, is_bias=True)
     out = helper.create_variable_for_type_inference(dtype=input.dtype)
-    helper.append_op("causal_conv1d", inputs={"X": input, "W": w},
+    helper.append_op("causal_conv1d", inputs=inputs,
                      outputs={"Y": out}, attrs={"act": act or ""})
     return out
 
@@ -483,6 +492,90 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="chunked",
         outputs={"Out": out, "States": states},
         attrs={"chunk": int(chunk), "impl": impl,
                "epsilon": float(epsilon)})
+    return out
+
+
+def selective_scan(x, dt, b, c, z=None, state_size=16, chunk=64,
+                   impl="chunked", a_log_attr=None, d_attr=None,
+                   dt_bias_attr=None, name=None):
+    """Mamba-1's selective scan (ops/selective_scan_ops.py): x, dt
+    [b, t, e] (dt the pre-activation of the step size), b, c [b, t, n]
+    with n = ``state_size``, optional gate z [b, t, e] -> out [b, t, e]:
+
+        delta = softplus(dt + dt_bias);  s_t = exp(delta A) s_{t-1}
+        + delta b_t x_t;  y_t = c_t . s_t + D x_t;  out = y * silu(z)
+
+    with A = -exp(A_log). Parameters A_log [e, n], D [e] and dt_bias [e]
+    (defaults: log(1 .. n) in every channel, 1 and 0), float32 all.
+    ``impl``: "chunked" (a state saved every ``chunk`` positions: the
+    ``ssm.scan.*`` Pallas kernels where
+    ``parallel/selective_scan.ssm_tile`` gives the call a tile, XLA ops
+    elsewhere; the dispatch counter says which) or "recurrent" (one scan
+    over all positions: the fallback a caller asks for)."""
+    from paddle_tpu.initializer import LogRangeInitializer
+
+    if impl not in ("chunked", "recurrent"):
+        raise ValueError(f"selective_scan: impl {impl!r}")
+    helper = LayerHelper("selective_scan", name=name)
+    e, n = x.shape[-1], int(state_size)
+    a_log = helper.create_parameter(
+        ParamAttr._to_attr(a_log_attr), shape=[e, n], dtype="float32",
+        default_initializer=LogRangeInitializer())
+    d = helper.create_parameter(
+        ParamAttr._to_attr(d_attr), shape=[e], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    dt_bias = helper.create_parameter(
+        ParamAttr._to_attr(dt_bias_attr), shape=[e], dtype="float32",
+        default_initializer=ConstantInitializer(0.0))
+    a = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op("exp", inputs={"X": a_log}, outputs={"Out": a})
+    neg_a = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op("scale", inputs={"X": a}, outputs={"Out": neg_a},
+                     attrs={"scale": -1.0})
+    inputs = {"X": x, "Dt": dt, "A": neg_a, "B": b, "C": c, "D": d,
+              "DtBias": dt_bias}
+    if z is not None:
+        inputs["Z"] = z
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    # the state each chunk starts from, kept for the backward pass
+    states = helper.create_variable_for_type_inference(
+        dtype="float32", stop_gradient=True)
+    helper.append_op(
+        "selective_scan", inputs=inputs,
+        outputs={"Out": out, "States": states},
+        attrs={"chunk": int(chunk), "impl": impl})
+    return out
+
+
+def diff_attention_combine(o1, o2, lambda_init, head_dim, epsilon=1e-5,
+                           lambda_attr=None, param_attr=None, name=None):
+    """Differential attention's combination of the two softmax maps'
+    outputs o1, o2 [.., dv] (ops/attention_ops.py): rms_norm(o1 - lambda
+    o2) * gain * (1 - lambda_init), lambda = exp(lq1 . lk1) - exp(lq2 .
+    lk2) + lambda_init. Parameters: four vectors [head_dim]
+    (``lambda_attr``: a ParamAttr whose name is their prefix; normal(0,
+    0.1)) and the gain [dv] (1)."""
+    from paddle_tpu.initializer import NormalInitializer
+
+    helper = LayerHelper("diff_attention_combine", name=name)
+    base = ParamAttr._to_attr(lambda_attr)
+    prefix = (base.name if base is not None and base.name
+              else helper.name + ".lambda")
+    vecs = {
+        slot: helper.create_parameter(
+            ParamAttr(name=f"{prefix}_{slot.lower()}",
+                      initializer=NormalInitializer(0.0, 0.1)),
+            shape=[int(head_dim)], dtype="float32")
+        for slot in ("LQ1", "LK1", "LQ2", "LK2")}
+    scale = helper.create_parameter(
+        ParamAttr._to_attr(param_attr), shape=[o1.shape[-1]],
+        dtype="float32", default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(dtype=o1.dtype)
+    helper.append_op(
+        "diff_attention_combine",
+        inputs={"O1": o1, "O2": o2, "Scale": scale, **vecs},
+        outputs={"Out": out},
+        attrs={"lambda_init": float(lambda_init), "epsilon": float(epsilon)})
     return out
 
 

@@ -23,6 +23,7 @@ from paddle_tpu.ops import (  # noqa: F401
     optimizer_ops,
     quant_ops,
     rnn_ops,
+    selective_scan_ops,
     sequence_ops,
     serving_ops,
     sparse_ops,

@@ -115,6 +115,32 @@ def _x(ins, slot="X", i=0):
     return v[i] if v else None
 
 
+@register_op("diff_attention_combine",
+             diff_inputs=("O1", "O2", "LQ1", "LK1", "LQ2", "LK2", "Scale"))
+def _diff_attention_combine(ins, attrs):
+    """Differential attention's combination (Ye et al. 2024,
+    arXiv:2410.05258, as HF ``modeling_phi4flash.py`` has it): O1, O2
+    [.., dv], the outputs of the pair's two softmax maps over the same
+    values; LQ1, LK1, LQ2, LK2 [dh] and Scale [dv] ->
+
+        lambda = exp(LQ1 . LK1) - exp(LQ2 . LK2) + lambda_init
+        Out    = rms_norm(O1 - lambda O2) * Scale * (1 - lambda_init)
+
+    lambda, the difference and the norm's statistics in float32, Out in
+    O1's dtype."""
+    f32 = jnp.float32
+    o1, o2 = _x(ins, "O1"), _x(ins, "O2")
+    lq1, lk1, lq2, lk2 = (_x(ins, s).astype(f32)
+                          for s in ("LQ1", "LK1", "LQ2", "LK2"))
+    init = float(attrs["lambda_init"])
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + init
+    o = o1.astype(f32) - lam * o2.astype(f32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                          + attrs.get("epsilon", 1e-5))
+    o = o * (_x(ins, "Scale").astype(f32) * (1.0 - init))
+    return {"Out": [o.astype(o1.dtype)]}
+
+
 @register_op("position_ids", no_grad=True)
 def _position_ids(ins, attrs):
     x = _x(ins)  # [b, t] any int dtype

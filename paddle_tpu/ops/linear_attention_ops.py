@@ -51,7 +51,8 @@ The causal convolution in front is written twice as well, chosen per
 call by ``parallel/causal_conv.conv_tile``: the ``gdn.conv.fwd`` /
 ``gdn.conv.bwd`` Pallas kernels (bf16 on a TPU, channels a multiple of
 128, no mesh: X, Y, dY and dX cross HBM once each as bf16, float32 only
-in VMEM, the earlier rows a halo) and float32 XLA ops over a padded
+in VMEM, the earlier rows a halo; an optional bias, Mamba's, rides as a
+row behind the taps) and float32 XLA ops over a padded
 copy of X everywhere else (``_conv_xla``); its backward pass is its own
 grad op (``causal_conv1d_grad``), which saves nothing but X;
 ``pt_causal_conv_dispatch_total`` records which (``kernel`` or ``xla``).
@@ -149,58 +150,70 @@ def dispatch_counts():
 # ---------------------------------------------------------------------------
 
 
-def _conv_xla(x, w, act):
+def _conv_xla(x, w, act, bias=None):
     """``causal_conv1d`` as XLA ops: float32 passes over a padded X."""
     taps, t = w.shape[-1], x.shape[1]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
     wf = w.astype(jnp.float32)
     y = sum(xf[:, j:j + t] * wf[:, j] for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     if act == "silu":
         y = jax.nn.silu(y)
     return y.astype(x.dtype)
 
 
 def _conv_args(ins, attrs, direction):
-    """(X, W, act, ``conv_tile``'s answer for the call), noted."""
-    x, w = _x(ins, "X"), _x(ins, "W")
+    """(X, W, Bias or None, act, ``conv_tile``'s answer for the call),
+    noted."""
+    x, w, bias = _x(ins, "X"), _x(ins, "W"), _x(ins, "Bias")
     act = attrs.get("act", "silu")
     tile = None
     if x.ndim == 3 and w.ndim == 2 and act in ("silu", ""):
         tile = _conv.conv_tile(x.shape[1], x.shape[2], w.shape[1], x.dtype)
     _note_conv(direction, x, w.shape[-1], "kernel" if tile else "xla")
-    return x, w, act, tile
+    return x, w, bias, act, tile
 
 
-@register_op("causal_conv1d", diff_inputs=("X", "W"))
+@register_op("causal_conv1d", diff_inputs=("X", "W", "Bias"))
 def _causal_conv1d(ins, attrs):
     """X [b, t, c], W [c, taps] -> Y [b, t, c]: a depthwise convolution
     over the sequence that sees no later position,
     y_t = sum_j W[:, j] * x_{t - (taps - 1) + j} (positions before the
     first count as zeros; HF's ``Conv1d(groups=c, padding=taps - 1)`` cut
-    to t), no bias, then ``act`` ("silu" or ""). Products and the sum
+    to t), plus the optional Bias [c] (Mamba's convolution has one,
+    Qwen3-Next's none), then ``act`` ("silu" or ""). Products and the sum
     in float32, the result in X's dtype: the ``gdn.conv.fwd`` kernel
     where ``parallel/causal_conv.conv_tile`` gives the call a tile (bf16
     on a TPU, no mesh, channels a multiple of 128), XLA ops everywhere
     else."""
-    x, w, act, tile = _conv_args(ins, attrs, "fwd")
+    x, w, bias, act, tile = _conv_args(ins, attrs, "fwd")
     if tile:
-        return {"Y": [_conv.causal_conv_fwd(x, w, tile, act)]}
-    return {"Y": [_conv_xla(x, w, act)]}
+        return {"Y": [_conv.causal_conv_fwd(x, w, tile, act, bias)]}
+    return {"Y": [_conv_xla(x, w, act, bias)]}
 
 
 @register_op("causal_conv1d_grad", no_grad=True)
 def _causal_conv1d_grad(ins, attrs):
-    """The backward pass of ``causal_conv1d`` from X, W and Y's
+    """The backward pass of ``causal_conv1d`` from X, W, Bias and Y's
     cotangent (nothing else is saved: the pre-activation is made again):
     the ``gdn.conv.bwd`` kernel where the call has a tile, else jax's
-    vjp of the XLA form. dX in X's dtype, dW in W's."""
-    x, w, act, tile = _conv_args(ins, attrs, "bwd")
+    vjp of the XLA form. dX in X's dtype, dW in W's, dBias in Bias's."""
+    x, w, bias, act, tile = _conv_args(ins, attrs, "bwd")
     dy = _x(ins, "GRAD::Y").astype(x.dtype)
+    if bias is None:
+        if tile:
+            dx, dw = _conv.causal_conv_bwd(x, w, dy, tile, act)
+        else:
+            dx, dw = jax.vjp(lambda x, w: _conv_xla(x, w, act), x, w)[1](dy)
+        return {"GRAD::X": [dx], "GRAD::W": [dw.astype(w.dtype)]}
     if tile:
-        dx, dw = _conv.causal_conv_bwd(x, w, dy, tile, act)
+        dx, dw, db = _conv.causal_conv_bwd(x, w, dy, tile, act, bias)
     else:
-        dx, dw = jax.vjp(lambda x, w: _conv_xla(x, w, act), x, w)[1](dy)
-    return {"GRAD::X": [dx], "GRAD::W": [dw.astype(w.dtype)]}
+        dx, dw, db = jax.vjp(
+            lambda x, w, b: _conv_xla(x, w, act, b), x, w, bias)[1](dy)
+    return {"GRAD::X": [dx], "GRAD::W": [dw.astype(w.dtype)],
+            "GRAD::Bias": [db.astype(bias.dtype)]}
 
 
 @register_op("gdn_gates", diff_inputs=("B", "A", "ALog", "DtBias"))

@@ -181,13 +181,22 @@ def _rows_in_front(x_ref, p, rows):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_passes(x_ref, front, w_ref, y_ref, *, taps, act, rows):
+def _bias_row(w_ref, taps, bias):
+    """The bias [1, L] where the call has one: the row behind the taps
+    of the W operand."""
+    return w_ref[taps:taps + 1, :] if bias else None
+
+
+def _fwd_passes(x_ref, front, w_ref, y_ref, *, taps, act, rows, bias):
     """Y of a block from X's block and the 16 rows in front of it."""
     w = _w_rows(w_ref, taps)
+    b = _bias_row(w_ref, taps, bias)
 
     def one(p, front):
         at = _pass_rows(p, rows)
         pre = _taps_sum(_shifted(front, x_ref[at, :], taps), w)
+        if bias:
+            pre = pre + b
         if act == "silu":
             pre = pre * jax.nn.sigmoid(pre)
         y_ref[at, :] = pre.astype(y_ref.dtype)
@@ -212,9 +221,9 @@ def _padded(x, size):
     return jnp.pad(x, ((0, 0), (0, size - x.shape[1]), (0, 0)))
 
 
-def _specs(rows, lanes, taps, blk):
+def _specs(rows, lanes, w_rows, blk):
     """BlockSpecs of (X-like [b, t, c], the 16 rows in front of such a
-    block, W [taps, c]) for a grid whose step (i, j, k) works on batch
+    block, W [taps (+ 1: the bias), c]) for a grid whose step (i, j, k) works on batch
     ``i``, lane block ``j`` and row block ``k``, as ``blk`` reads them
     off the grid's indices."""
     per = rows // _HALO
@@ -229,33 +238,39 @@ def _specs(rows, lanes, taps, blk):
 
     return (pl.BlockSpec((None, rows, lanes), at),
             pl.BlockSpec((None, _HALO, lanes), in_front),
-            pl.BlockSpec((taps, lanes), lambda *g: (0, blk(*g)[1])))
+            pl.BlockSpec((w_rows, lanes), lambda *g: (0, blk(*g)[1])))
 
 
-def _operands(x, w, tile):
+def _operands(x, w, tile, bias=None):
     """X padded behind its last row to whole blocks (zeros: they come
-    after every real row) and W with the channels on the lanes."""
+    after every real row) and W with the channels on the lanes, the bias
+    (where the call has one) a row behind the taps."""
     rows = tile[0]
-    return _padded(x, -(-x.shape[1] // rows) * rows), w.astype(_F32).T
+    wt = w.astype(_F32).T
+    if bias is not None:
+        wt = jnp.concatenate([wt, bias.astype(_F32)[None]], axis=0)
+    return _padded(x, -(-x.shape[1] // rows) * rows), wt
 
 
 def _act_ops(act):
     return 4 if act == "silu" else 0
 
 
-def causal_conv_fwd(x, w, tile, act="silu"):
-    """x [b, t, c] (bf16), w [c, taps] -> y [b, t, c] in x's dtype:
-    y_t = act(sum_j w[:, j] x_{t - (taps - 1) + j}), zeros before the
-    first position. ``tile``: ``conv_tile``'s answer for the call."""
+def causal_conv_fwd(x, w, tile, act="silu", bias=None):
+    """x [b, t, c] (bf16), w [c, taps], bias [c] or None -> y [b, t, c]
+    in x's dtype: y_t = act(sum_j w[:, j] x_{t - (taps - 1) + j} + bias),
+    zeros before the first position. ``tile``: ``conv_tile``'s answer
+    for the call."""
     b, t, c = x.shape
     taps = w.shape[-1]
     rows, lanes = tile
-    x2, wt = _operands(x, w, tile)
-    x_spec, front_spec, w_spec = _specs(rows, lanes, taps,
+    x2, wt = _operands(x, w, tile, bias)
+    x_spec, front_spec, w_spec = _specs(rows, lanes, wt.shape[0],
                                         lambda i, j, k: (i, j, k))
     y = pl.pallas_call(
         functools.partial(_fwd_kernel, taps=taps, act=act,
-                          rows=min(rows, _PASS_ROWS)),
+                          rows=min(rows, _PASS_ROWS),
+                          bias=bias is not None),
         name="gdn.conv.fwd",
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         grid=(b, c // lanes, x2.shape[1] // rows),
@@ -278,9 +293,10 @@ def causal_conv_fwd(x, w, tile, act="silu"):
 
 
 def _bwd_kernel(x_ref, front_ref, dy_ref, w_ref, dx_ref, dw_ref, behind_ref,
-                *, taps, act, rows):
+                *, taps, act, rows, bias):
     k = pl.program_id(2)        # the blocks of a sequence, last to first
     w = _w_rows(w_ref, taps)
+    b = _bias_row(w_ref, taps, bias)
     passes = x_ref.shape[0] // rows
 
     @pl.when(k == 0)
@@ -298,6 +314,8 @@ def _bwd_kernel(x_ref, front_ref, dy_ref, w_ref, dx_ref, dw_ref, behind_ref,
         dpre = dy_ref[at, :].astype(_F32)
         if act == "silu":
             pre = _taps_sum(xs, w)
+            if bias:
+                pre = pre + b
             sig = jax.nn.sigmoid(pre)
             dpre = dpre * (sig * (1.0 + pre * (1.0 - sig)))
         # dx_r = sum_s w[taps - 1 - s] dpre_{r + s}: shifts UP, into the
@@ -308,8 +326,11 @@ def _bwd_kernel(x_ref, front_ref, dy_ref, w_ref, dx_ref, dw_ref, behind_ref,
             dx = dx + (pltpu.roll(ext, rows + _TAIL - s, 0)[:rows]
                        * w[taps - 1 - s])
         dx_ref[at, :] = dx.astype(dx_ref.dtype)
+        # (the bias's gradient, where there is one, is dpre's column
+        # sum: a last accumulator beside the taps')
         return dpre[:_TAIL], tuple(
-            dw + _fold(x * dpre) for dw, x in zip(dws, xs))
+            dw + _fold(x * dpre) for dw, x in zip(dws, xs)) + tuple(
+            db + _fold(dpre) for db in dws[taps:])
 
     def earlier(i, carry):
         p = passes - 1 - i
@@ -317,35 +338,39 @@ def _bwd_kernel(x_ref, front_ref, dy_ref, w_ref, dx_ref, dw_ref, behind_ref,
 
     zeros = jnp.zeros(behind_ref.shape, _F32)
     carry = jax.lax.fori_loop(0, passes - 1, earlier,
-                              (behind_ref[...], (zeros,) * taps))
+                              (behind_ref[...], (zeros,) * (taps + bias)))
     behind, dws = one(
         0, _block_in_front(front_ref, k == pl.num_programs(2) - 1), carry)
     behind_ref[...] = behind
-    for s, dw in enumerate(dws):
+    for s, dw in enumerate(dws[:taps]):
         dw_ref[taps - 1 - s] += dw
+    if bias:
+        dw_ref[taps] += dws[taps]
 
 
-def causal_conv_bwd(x, w, dy, tile, act="silu"):
-    """The cotangents (dx [b, t, c] in x's dtype, dw [c, taps] float32)
-    of ``causal_conv_fwd`` for the cotangent ``dy`` of y, from x alone
-    (the pre-activation is made again)."""
+def causal_conv_bwd(x, w, dy, tile, act="silu", bias=None):
+    """The cotangents (dx [b, t, c] in x's dtype, dw [c, taps] float32
+    and, where the call has a bias, db [c] float32) of
+    ``causal_conv_fwd`` for the cotangent ``dy`` of y, from x alone (the
+    pre-activation is made again)."""
     b, t, c = x.shape
     taps = w.shape[-1]
     rows, lanes = tile
-    x2, wt = _operands(x, w, tile)
+    x2, wt = _operands(x, w, tile, bias)
+    w_rows = wt.shape[0]
     dy2 = _padded(dy.astype(x.dtype), x2.shape[1])
     last = x2.shape[1] // rows - 1
-    x_spec, front_spec, w_spec = _specs(rows, lanes, taps,
+    x_spec, front_spec, w_spec = _specs(rows, lanes, w_rows,
                                         lambda j, i, k: (i, j, last - k))
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, taps=taps, act=act,
-                          rows=min(rows, _PASS_ROWS)),
+                          rows=min(rows, _PASS_ROWS), bias=bias is not None),
         name="gdn.conv.bwd",
         out_shape=(jax.ShapeDtypeStruct(x2.shape, x.dtype),
-                   jax.ShapeDtypeStruct((taps, _TAIL, c), _F32)),
+                   jax.ShapeDtypeStruct((w_rows, _TAIL, c), _F32)),
         grid=(c // lanes, b, last + 1),
         in_specs=[x_spec, front_spec, x_spec, w_spec],
-        out_specs=(x_spec, pl.BlockSpec((taps, _TAIL, lanes),
+        out_specs=(x_spec, pl.BlockSpec((w_rows, _TAIL, lanes),
                                         lambda j, i, k: (0, 0, j))),
         scratch_shapes=[pltpu.VMEM((_TAIL, lanes), _F32)],
         compiler_params=pltpu.CompilerParams(
@@ -356,4 +381,7 @@ def causal_conv_bwd(x, w, dy, tile, act="silu"):
             bytes_accessed=3 * x2.size * x.dtype.itemsize + 8 * wt.size),
         interpret=_INTERPRET,
     )(x2, x2, dy2, wt)
-    return dx[:, :t], jnp.sum(dw, axis=1).T
+    dw = jnp.sum(dw, axis=1)
+    if bias is None:
+        return dx[:, :t], dw.T
+    return dx[:, :t], dw[:taps].T, dw[taps]

@@ -22,6 +22,7 @@ from paddle_tpu.parallel import causal_conv as cc
 from paddle_tpu.parallel import flash_attention as fa
 from paddle_tpu.parallel import gated_delta_rule as gdr
 from paddle_tpu.parallel import grouped_matmul as gm
+from paddle_tpu.parallel import selective_scan as ss
 
 
 @pytest.fixture(scope="module")
@@ -426,3 +427,81 @@ def test_causal_conv_kernels_compile(one_chip, real_kernels):
         assert name in text, name
     # nothing float32 of X's size: no padded copy, no float32 Y
     assert f"f32[1,{t}," not in text and f"f32[1,{t + taps - 1}," not in text
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "memory"])
+def test_selective_scan_kernels_compile(gated, one_chip, real_kernels):
+    """The selective scan as phi4flash-train-s4096 lowers it: [1, 4096,
+    5120] bf16 with 16 states, blocks of 128 positions x 1024 channels,
+    gated (layer 14) and not (layer 16, the memory source): the bf16
+    [rows, 8, 128] blocks, B and C in SMEM, the sublane and lane
+    butterflies and the 8 MB scratch of recomputed states pass Mosaic,
+    and nothing of size t x e x n exists."""
+    bf, f32, t, e, n = jnp.bfloat16, jnp.float32, 4096, 5120, ss.STATE
+    tile = ss.ssm_tile(t, e, n, bf, "tpu", False)
+    assert tile == (128, 1024)
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    x, bc = S((1, t, e), bf), S((1, t, n), bf)
+    a, row = S((e, n), f32), S((e,), f32)
+
+    def both(x, dt, a, b, c, d, z, bias, dy):
+        z = z if gated else None
+        y, states = ss.selective_scan_fwd(x, dt, a, b, c, d, z, bias, tile)
+        return y, ss.selective_scan_bwd(x, dt, a, b, c, d, z, bias, states,
+                                        dy, tile)
+
+    text = jax.jit(both).lower(x, x, a, bc, bc, row, x, row,
+                               x).compile().as_text()
+    for name in ("ssm.scan.fwd", "ssm.scan.bwd"):
+        assert name in text, name
+    assert f"[1,{t},{e},{n}]" not in text and f"[{t},{e},{n}]" not in text
+    # the saved states: one a block of 128 positions, float32
+    assert f"f32[1,{t // 128},{n},{e // 128},128]" in text
+
+
+def test_causal_conv_kernels_with_a_bias_compile(one_chip, real_kernels):
+    """Mamba's convolution as phi4flash-train-s4096 lowers it: [1, 4096,
+    5120] bf16, 4 taps, a bias in front of the silu (a row behind the
+    taps of the W operand), forward and backward."""
+    bf, t, c, taps = jnp.bfloat16, 4096, 5120, 4
+    tile = cc.conv_tile(t, c, taps, bf, "tpu", False)
+    assert tile == (1024, 512)
+    x = jax.ShapeDtypeStruct((1, t, c), bf, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((c, taps), jnp.float32, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((c,), jnp.float32, sharding=one_chip)
+
+    def both(x, w, b, dy):
+        return (cc.causal_conv_fwd(x, w, tile, bias=b),
+                cc.causal_conv_bwd(x, w, dy, tile, bias=b))
+
+    text = jax.jit(both).lower(x, w, b, x).compile().as_text()
+    for name in ("gdn.conv.fwd", "gdn.conv.bwd"):
+        assert name in text, name
+    assert f"f32[1,{t}," not in text
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "w512"])
+def test_differential_attention_maps_compile(window, one_chip, real_kernels):
+    """One softmax map of phi4flash-train-s4096's attention layers: 20
+    query over 10 key pair-heads of 64 over values of 128 x 4096, with
+    and without the 512-wide window (blocks of 512: a band of two
+    blocks a row), forward and the ONE backward call."""
+    bf, t, h, hk, dh, dv = jnp.bfloat16, 4096, 20, 10, 64, 128
+    assert fa.bhtd_tile(h, t, t, dh=dh, group=2, dv=dv) == (1, 512, 512)
+    assert fa.bhtd_bwd_form(h, t, t, dh=dh, group=2, dv=dv) == "fused"
+    S = lambda heads, width: jax.ShapeDtypeStruct(
+        (1, heads, t, width), bf, sharding=one_chip)
+
+    def both(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=True,
+                                          window=window, scale=dh ** -0.5)
+        return out, fa.flash_attention_bwd(
+            q, k, v, None, None, out, lse, g, causal=True, window=window,
+            scale=dh ** -0.5)
+
+    text = jax.jit(both).lower(S(h, dh), S(hk, dh), S(hk, dv),
+                               S(h, dv)).compile().as_text()
+    for name in ("attn.bhtd.fwd", "attn.bhtd.bwd"):
+        assert name in text, name
+    assert "attn.bhtd.bwd_dq" not in text

@@ -452,3 +452,58 @@ def test_bench_exits_nonzero_when_a_child_fails(capsys):
     assert row["value"] == 2 and row["resnet50"] is None
     assert bench.main([("headline", mute, {})]) == 1
     capsys.readouterr()
+
+
+SSM_TINY = dict(
+    vocab_size=50, hidden_size=512, num_attention_heads=8,
+    num_key_value_heads=4, intermediate_size=128, sliding_window=128)
+
+
+def _ssm_interpreters(monkeypatch):
+    from paddle_tpu.parallel import causal_conv as cc
+    from paddle_tpu.parallel import selective_scan as ss
+
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(cc, "_INTERPRET", True)
+    monkeypatch.setattr(ss, "_INTERPRET", True)
+
+
+def test_ssm_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (heads of 64
+    over values of 128 and a state of 16 are the model's; 1024 channels):
+    layers 14-19 lower two selective scans and two convolutions each way
+    through their kernels and six attention calls each way at dk64
+    dv128, the window layer's two with their band, every backward one
+    call; on the device (here: the CPU) the scan's and the convolution's
+    kernels agree with the XLA writings."""
+    _ssm_interpreters(monkeypatch)
+    row = chip_smoke.ssm_phase(seq=512, t_check=64, **SSM_TINY)
+    assert row["selective_scans"] == {
+        f"kernel {d} b1 t512 e1024 n16 chunk128": 2 for d in ("fwd", "bwd")}
+    assert row["convolutions"] == {
+        f"kernel {d} b1 t512 c1024 taps4": 2 for d in ("fwd", "bwd")}
+    attn = row["attention"]
+    assert sum(attn.values()) == 12 and all(
+        k.startswith("bhtd ") and " h4 kv2 dk64 dv128" in k for k in attn)
+    assert sum(v for k, v in attn.items() if " w128" in k) == 4
+    assert all(k.endswith(" form=fused") for k in attn if " bwd " in k)
+    assert row["kernel_ms"] == {}               # (a trace needs the chip)
+    assert set(row["rel_err"]) == {
+        "scan Out", *(f"scan GRAD::{s}" for s in (
+            "X", "Dt", "A", "B", "C", "D", "Z", "DtBias")),
+        "conv Y", "conv GRAD::X", "conv GRAD::W", "conv GRAD::Bias"}
+    assert max(row["rel_err"].values()) < 2e-2
+
+
+def test_ssm_phase_fails_on_a_scan_without_the_kernel(telemetry,
+                                                      monkeypatch):
+    # the scan's kernels off (no interpreter): both calls are the
+    # chunked XLA form, and the phase says so
+    from paddle_tpu.parallel import causal_conv as cc
+
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    monkeypatch.setattr(cc, "_INTERPRET", True)
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="on the ssm.scan kernels"):
+        chip_smoke.ssm_phase(seq=512, t_check=64, **SSM_TINY)
