@@ -263,11 +263,14 @@ def gmm_dispatch():
     return gm.gmm_dispatch_counts()
 
 
-def _moe_layer_program(tokens, d, experts, top_k, d_ff, **kw):
+def _moe_layer_program(tokens, d, experts, top_k, d_ff, stream=None, **kw):
     """(main, startup, fetch, names) of one ``layers.topk_moe`` layer
     under bf16 AMP with its backward pass: the output against a probe
     ``p`` as the loss; fetched are the output, the experts' rows, the
-    tokens' gradient and the parameters' gradients."""
+    tokens' gradient and the parameters' gradients. ``stream``: the name
+    of a projection put in front of the layer, so that it gets its
+    tokens as a decoder block hands them over, in the bf16 stream, and
+    the rows' gradient comes back in it."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
     from paddle_tpu.backward import append_backward
@@ -280,7 +283,11 @@ def _moe_layer_program(tokens, d, experts, top_k, d_ff, **kw):
         x.stop_gradient = False
         probe = layers.data("p", shape=[tokens, d], dtype="float32",
                             append_batch_size=False)
-        out, _, _, rows, _ = layers.topk_moe(x, experts, top_k, d_ff, **kw)
+        h = x
+        if stream:      # a projection in front: its product is bf16
+            h = layers.fc(x, d, bias_attr=False, num_flatten_dims=1,
+                          param_attr=fluid.ParamAttr(name=f"{stream}.w"))
+        out, _, _, rows, _ = layers.topk_moe(h, experts, top_k, d_ff, **kw)
         loss = layers.reduce_sum(layers.elementwise_mul(out, probe))
         grads = append_backward(loss)
     main._amp = True
@@ -368,10 +375,13 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     (ops/moe_ops.over_live_rows), and once, the same weights and tokens,
     with ONE window of all n * k rows, so that every pass walks the
     whole buffer: output and gradients must agree, no row of the
-    counter may say ``whole``, and the seconds of a step of each are
-    printed beside the live share. The default is
-    qwen3next-train-s8192's layer: a buffer of 81,920 rows, about 5,120
-    of them live."""
+    counter may say ``whole``, the two token-major sums (``moe_combine
+    sum_pairs``, ``moe_dispatch_grad d_x``) must say ``kernel`` in both
+    (parallel/pair_sum.py's ``pairs.sum.*`` takes no window), and the
+    seconds of a step of each are printed beside the live share and, on
+    a TPU, the ``pairs.*`` and ``moe.*`` kernels' ms a call from a trace
+    of three steps. The default is qwen3next-train-s8192's layer: a
+    buffer of 81,920 rows, about 5,120 of them live."""
     import jax
     import jax.numpy as jnp
 
@@ -380,15 +390,15 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
 
     def build():
         return _moe_layer_program(tokens, d, experts, top_k, d_ff,
-                                  name="smoke_held", held=held,
-                                  norm_topk_prob=True)
+                                  stream="smoke_stream", name="smoke_held",
+                                  held=held, norm_topk_prob=True)
 
     r = np.random.RandomState(5)
     feed = {"x": r.randn(tokens, d).astype(np.float32),
             "p": r.randn(tokens, d).astype(np.float32)}
     scope, exe = fluid.Scope(), fluid.Executor()
     before = (gmm_dispatch(), moe_ops.rows_dispatch_counts())
-    results, step_ms = {}, {}
+    results, step_ms, kernel_ms = {}, {}, {}
     window = moe_ops.live_window
     for form in ("windowed", "whole"):
         main, startup, fetch, names = build()
@@ -408,6 +418,17 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
             moe_ops.live_window = window
         results[form] = dict(zip(names, got))
         step_ms[form] = round(1e3 * float(np.median(took[1:])), 2)
+        if form == "windowed" and jax.default_backend() == "tpu":
+            kernel_ms = {k: round(s_ / 3 * 1e3, 4) for k, s_ in sorted(
+                _traced_kernel_ms("moe_held_trace", lambda: exe.run(
+                    main, feed=feed, fetch_list=fetch, scope=scope,
+                    return_numpy=False), "")[1].items())
+                if k.startswith(("pairs.", "moe."))}
+            say(f"  moe_held kernels, ms a step: {kernel_ms}")
+            check(sorted(k for k in kernel_ms if k.startswith("pairs.")) == [
+                "pairs.sum.combine", "pairs.sum.dispatch_grad"],
+                f"expected the two pairs.sum.* kernels in the trace: "
+                f"{kernel_ms}")
     gmm = _dispatch_since(before[0], gmm_dispatch)
     passes = _dispatch_since(before[1], moe_ops.rows_dispatch_counts)
     exe.close()
@@ -416,8 +437,12 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     check(sum(v for k, v in gmm.items() if k.endswith("]")) == 18,
           f"the two layers' eighteen grouped matmuls did not all take a "
           f"tile: {gmm}")
-    check(passes and all(" windowed" in k for k in passes),
+    sums = [k for k in passes if " sum_pairs " in k or " d_x " in k]
+    check(passes and all(" windowed " in k or k in sums for k in passes),
           f"a pass of the held layer walks its buffer whole: {passes}")
+    check(len(sums) == 4 and all(" kernel " in k for k in sums),
+          f"a token-major sum of the held layer is not the pairs.sum.* "
+          f"kernel's: {sums}")
     w = window(m, -(-m * held[1] // experts))
     check({k.rsplit(" w", 1)[1] for k in passes} == {str(w), str(m)},
           f"the passes' windows are not {w} (and {m} for the whole "
@@ -443,7 +468,7 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
               f"{KERNEL_REL_TOL})")
     row = {"rows": m, "live": live, "live_share": round(live / m, 4),
            "held": list(held), "experts": experts, "window": w,
-           "step_ms": step_ms, "passes": passes,
+           "step_ms": step_ms, "kernel_ms": kernel_ms, "passes": passes,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  moe_held {row}")
     return row
@@ -1488,8 +1513,9 @@ def main() -> int:
     report["moe_kernels"]["pallas_calls"] = n_moe
     say(f"  moe layer module: {n_moe} Pallas custom calls, the first "
         f"{first_call}")
-    check(n_moe == 9, f"the layer's module holds {n_moe} Pallas custom "
-          f"calls, expected its nine grouped matmuls")
+    check(n_moe == 10, f"the layer's module holds {n_moe} Pallas custom "
+          f"calls, expected its nine grouped matmuls and the tokens' "
+          f"gradient's pairs.sum.dispatch_grad")
 
     report["moe_held"], _ = phase("moe_held", moe_held_phase)
     report["gdn"], _ = phase("gdn", gdn_phase)

@@ -26,6 +26,7 @@ from paddle_tpu import monitor as _monitor
 from paddle_tpu.core import interp
 from paddle_tpu.core.registry import register_op
 from paddle_tpu.parallel import grouped_matmul as _gm
+from paddle_tpu.parallel import pair_sum as _ps
 from paddle_tpu.parallel.grouped_matmul import (live_window, over_live_rows,
                                                 put_rows, rows_at)
 
@@ -263,7 +264,9 @@ def _moe_bias_update(ins, attrs):
 def _rows(x, idx, back):
     """``x[idx]`` where ``back`` [n, r] lists, for each row of x, the r
     places of the result that copy it: the cotangent is then a gather
-    and a sum over r, not a scatter-add over repeated indices."""
+    and a sum over r, not a scatter-add over repeated indices
+    (moe_combine's gather of Ys in a layer that holds every expert; the
+    tokens' gather has ``_rows_of_pairs``)."""
     return jnp.take(x, idx, axis=0)
 
 
@@ -286,11 +289,12 @@ _rows.defvjp(_rows_fwd, _rows_bwd)
 # buffer is a loop over the windows that hold a live row
 # (``over_live_rows``: the trip count is sum(Rows), the step's own, a
 # device scalar), as the grouped matmuls visit no tile behind the last
-# group. Row-major passes write their windows into zeros; token-major
-# ones (a token's sum over its pairs) walk the live rows and add each
-# into its token while the live rows are few, where a walk by token
-# gathers all k rows of every token to add up the 0.6 of them that are
-# live (``_add_into_tokens``). Every buffer an op hands on (Xs, Gate, Up,
+# group. Row-major passes write their windows into zeros; the two
+# token-major ones (a token's sum over its pairs) are one Pallas kernel
+# that fetches the groups of 16 rows a token tile's pairs lie in and adds
+# them on the MXU (``_add_into_tokens``, parallel/pair_sum.py), and
+# without a TPU a gather of all k rows of every token and their sum
+# (``_sum_by_token``). Every buffer an op hands on (Xs, Gate, Up,
 # Ys and the cotangents) has zeros behind the last live row: the experts'
 # products are written into zeros. The three that stay inside an op (dh
 # and the two halves of d Xs) are taken as their kernels leave them in
@@ -302,9 +306,12 @@ _M_PASSES = _monitor.counter(
     "passes of a top-k MoE layer over its row buffer lowered (trace time, "
     "telemetry on), by op, pass, form (windowed: a loop over the windows "
     "of `window` rows that hold a live row, the trip count the step's "
-    "own; windowed|by_token: that loop while the live rows are few and a "
-    "walk of the buffer by token from there, chosen by the step's own "
-    "count; whole: the pass walks all buffer_rows) and buffer_rows")
+    "own; kernel: a token-major sum as the pairs.sum.* Pallas kernel, "
+    "which fetches the groups of rows a token tile's pairs lie in; "
+    "windowed|by_token: that sum where the kernel takes no tile, a gather "
+    "of all k rows of every token of which the live ones are added (the "
+    "label is older than the form: readers know it); whole: the pass "
+    "walks all buffer_rows) and buffer_rows")
 
 
 def _live_rows(attrs, m):
@@ -325,19 +332,21 @@ def _window(attrs, m):
     return live_window(m, kw["live_rows"]) if kw else None
 
 
-def _note_passes(op, m, w, *passes, by_token=()):
+def _note_passes(op, m, w, *passes, by_token=(), kernel=()):
     """One row of ``pt_moe_rows_dispatch_total`` a pass of ``op`` over
     its m-row buffer, from the branch that lowers them: ``w`` the window
     its loop takes, None where it walks the buffer whole; ``by_token``
-    those of the passes that may also walk it by token
-    (``_add_into_tokens``). A grad op that traces its forward again
-    counts that one's passes again, as the router's counter does."""
+    those of the passes that walk it by token and ``kernel`` those the
+    ``pairs.sum.*`` kernel does (``_add_into_tokens``). A grad op that
+    traces its forward again counts that one's passes again, as the
+    router's counter does."""
     if not _monitor.enabled() or not interp.lowering_active():
         return
     for name in passes:
-        form = "windowed|by_token" if name in by_token else "windowed"
+        form = ("kernel" if name in kernel else "whole" if not w else
+                "windowed|by_token" if name in by_token else "windowed")
         _M_PASSES.inc(labels={
-            "op": op, "pass": name, "form": form if w else "whole",
+            "op": op, "pass": name, "form": form,
             "buffer_rows": str(m), "window": str(w or "")})
 
 
@@ -377,77 +386,70 @@ def _gather_live(x, order, live, w):
         lambda r0: (jnp.take(x, rows_at(order, r0, w) // k, axis=0),))[0]
 
 
-# What adding a row of 2048 into its token costs on a v5e, ns (my chip
-# run, PR 35: 0.73 / 4.5 / 8.8 ms at 5,140 / 40,839 / 81,920 live rows of
-# 81,920 by XLA's scatter-add over the live rows, whatever hints it gets:
-# unique and sorted indices inside one expert's group made it slower;
-# 3.3 ms by token, a gather of all k rows of every token and their sum,
-# whatever is live).
-_ADD_NS = {"live_row": 107, "buffer_row": 40}
-
-
-def _add_into_tokens(note, rows, order, slot, live, w, top_w=None):
-    """[n, d] float32: each live row r of ``rows`` [n * k, d], times its
-    pair's weight where ``top_w`` [n, k] is given, added into the token
-    of pair ``order[r]`` (``slot`` [n, k] is order's inverse). The sum
-    is float32 whatever the rows' dtype; the caller casts it once.
-    ``note`` (op, pass) names it in ``pt_moe_rows_dispatch_total``.
-
-    By live row while that is the cheaper (``_ADD_NS``: under three
-    eighths of the buffer live), else by token, the one choice of a
-    step's own count that is not a trip count: a router that learns to
-    send most pairs to the held experts, as the last layer's does
-    within a benchmark run, must not pay 2.7 times the walk by token.
-    That walk is the form of a layer that holds every expert, kept for
-    this case alone; a kernel that adds rows by a prefetched token index
-    at a gather's speed would retire it and ``_ADD_NS`` (PERF.md 7)."""
+def _sum_by_token(rows, slot, live, top_w=None):
+    """The token-major sum as XLA ops, float32: all k rows of every
+    token gathered (slot-major: [n, k, d] with k no multiple of 8 is
+    padded to one on the chip, the slot in front by nothing, PR 32), the
+    pairs behind ``live`` masked, times the weight where there is one,
+    summed over k. The form of a process without a TPU and of a call
+    ``pair_sum.sum_tile`` gives no tile."""
     n, k = slot.shape
-    weight = None if top_w is None else top_w.astype(jnp.float32)
-    _note_passes(note[0], n * k, w, note[1], by_token=note[1:])
+    at = slot.T
+    picked = jnp.where(
+        (at < live)[..., None],
+        jnp.take(rows, at.reshape(-1), axis=0).reshape(k, n, -1), 0
+    ).astype(jnp.float32)
+    if top_w is None:
+        return jnp.sum(picked, axis=0)
+    return jnp.einsum("knd,nk->nd", picked, top_w.astype(jnp.float32))
 
-    def by_live_row():
-        def trip(r0, keep, acc):
-            pairs = rows_at(order, r0, w)
-            v = rows_at(rows, r0, w).astype(jnp.float32)
-            if weight is not None:
-                v = v * jnp.take(weight.reshape(-1), pairs)[:, None]
-            return acc.at[pairs // k].add(jnp.where(keep, v, 0.0),
-                                          mode="promise_in_bounds")
 
-        return over_live_rows(
-            live, w, trip, jnp.zeros((n, rows.shape[1]), jnp.float32))
+_SUM_KERNELS = {"moe_combine": "pairs.sum.combine",
+                "moe_dispatch_grad": "pairs.sum.dispatch_grad"}
 
-    def by_token():
-        # slot-major: [n, k, d] with k no multiple of 8 is padded to
-        # one on the chip, the slot in front by nothing (PR 32)
-        at = slot.T
-        picked = jnp.where(
-            (at < live)[..., None],
-            jnp.take(rows, at.reshape(-1), axis=0).reshape(k, n, -1), 0
-        ).astype(jnp.float32)
-        if weight is None:
-            return jnp.sum(picked, axis=0)
-        return jnp.einsum("knd,nk->nd", picked, weight)
 
-    return jax.lax.cond(
-        _ADD_NS["live_row"] * live <= _ADD_NS["buffer_row"] * n * k,
-        by_live_row, by_token)
+def _add_into_tokens(note, rows, slot, sizes, w, top_w=None):
+    """[n, d] in the rows' dtype: each live row r of ``rows`` [n * k, d]
+    (``sizes``: moe_dispatch's Rows, the held experts' groups, which lie
+    first and sum to the live rows), times its pair's weight where
+    ``top_w`` [n, k] is given, added into its token (``slot`` [n, k]:
+    the row of every pair). Each product and the sum are float32
+    whatever the rows' dtype, cast once at the end. ``note`` (op, pass)
+    names it in ``pt_moe_rows_dispatch_total`` beside ``w``, the window
+    of the layer's other passes.
+
+    ONE kernel, ``parallel/pair_sum.pair_sum``, at the tile ``sum_tile``
+    gives the call from its shapes, dtype, backend and mesh; where it
+    gives none, ``_sum_by_token``."""
+    n, k = slot.shape
+    tile = _ps.sum_tile(n, k, rows.shape[1], rows.dtype)
+    _note_passes(note[0], n * k, w, note[1],
+                 **{"kernel" if tile else "by_token": note[1:]})
+    if tile is not None:
+        return _ps.pair_sum(rows, slot, sizes, tile, top_w,
+                            name=_SUM_KERNELS[note[0]])
+    return _sum_by_token(rows, slot, jnp.sum(sizes), top_w).astype(
+        rows.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_pairs(x, order, slot, live, w):
-    """``_rows`` for a held share: the live rows' tokens, whose
-    cotangent is the live rows added into their tokens."""
-    return _gather_live(x, order, live, w)
+def _rows_of_pairs(x, order, slot, sizes, w):
+    """Xs of the tokens x: the token of pair ``order[r]`` at every row r
+    inside a group (``w``: the layer's window, None for one that holds
+    every expert), whose cotangent is those rows added into their
+    tokens, not a scatter-add over repeated indices."""
+    if w is None:
+        return jnp.take(x, order // slot.shape[1], axis=0)
+    return _gather_live(x, order, jnp.sum(sizes), w)
 
 
-def _rows_of_pairs_fwd(x, order, slot, live, w):
-    return _gather_live(x, order, live, w), (order, slot, live)
+def _rows_of_pairs_fwd(x, order, slot, sizes, w):
+    return _rows_of_pairs(x, order, slot, sizes, w), (slot, sizes)
 
 
 def _rows_of_pairs_bwd(w, res, g):
-    return (_add_into_tokens(("moe_dispatch_grad", "d_x"), g, *res,
-                             w).astype(g.dtype), None, None, None)
+    return (_add_into_tokens(("moe_dispatch_grad", "d_x"), g, *res, w),
+            None, None, None)
 
 
 _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
@@ -483,11 +485,22 @@ def _moe_dispatch(ins, attrs):
     rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
     w = _window(attrs, n * k)
     _note_passes("moe_dispatch", n * k, w, "gather_xs")
-    if w is not None:
-        xs = _rows_of_pairs(x, order, slot, jnp.sum(rows), w)
-    else:
-        xs = _rows(x, order // k, slot)
+    xs = _rows_of_pairs(x, order, slot, rows, w)
     return {"Xs": [xs], "Rows": [rows], "Order": [order], "Slot": [slot]}
+
+
+@register_op("moe_dispatch_grad", no_grad=True)
+def _moe_dispatch_grad(ins, attrs):
+    """GRAD::X of moe_dispatch: every row of GRAD::Xs inside a group
+    added into its token (``_add_into_tokens``), in X's shape and
+    dtype. The generic grad op would trace the forward again for the
+    same sum (``_rows_of_pairs``' rule, which a caller that
+    differentiates the forward itself still gets) and hand the kernel a
+    name of jax's making."""
+    x, slot = _x(ins, "X"), _x(ins, "Slot")
+    d_x = _add_into_tokens(("moe_dispatch_grad", "d_x"), _x(ins, "GRAD::Xs"),
+                           slot, _x(ins, "Rows"), _window(attrs, slot.size))
+    return {"GRAD::X": [d_x.astype(x.dtype).reshape(x.shape)]}
 
 
 def _swiglu(gate, up):
@@ -616,16 +629,21 @@ def _moe_combine(ins, attrs):
     in the shape of Like (the tokens as the router got them): summed
     in f32, returned in Ys's dtype. A held share (which also gets Rows,
     moe_dispatch's) adds each live row, times its pair's weight, into
-    its token (``_add_into_tokens``) and reads no other row of Ys."""
+    its token (``_add_into_tokens``) and reads no row of Ys behind the
+    last live one. A layer that holds every expert gathers all k rows
+    of every token: its grad op, jax's transposes of these lines,
+    multiplies the same gathered rows again and XLA keeps them from here
+    (with the ``pairs.sum.combine`` kernel in this place the backward
+    gathers them itself: 2.3 ms more for 0.6 less at OLMoE's layer, my
+    chip run, PR 41)."""
     ys, top_w = _x(ins, "Ys"), _x(ins, "TopW")
     order, slot = _x(ins, "Order"), _x(ins, "Slot")
     n, k = slot.shape
     w = _window(attrs, n * k)
     if w is not None:   # a held share: its own grad op
-        out = _add_into_tokens(("moe_combine", "sum_pairs"), ys, order, slot,
-                               jnp.sum(_x(ins, "Rows")), w, top_w)
-        return {"Out": [out.astype(ys.dtype).reshape(
-            _x(ins, "Like").shape)]}
+        out = _add_into_tokens(("moe_combine", "sum_pairs"), ys, slot,
+                               _x(ins, "Rows"), w, top_w)
+        return {"Out": [out.reshape(_x(ins, "Like").shape)]}
     _note_passes("moe_combine", n * k, None, "sum_pairs")
     picked = _rows(ys, slot.reshape(-1), order[:, None]).reshape(n, k, -1)
     out = jnp.einsum("nkd,nk->nd", picked.astype(jnp.float32),

@@ -22,6 +22,7 @@ from paddle_tpu.parallel import causal_conv as cc
 from paddle_tpu.parallel import flash_attention as fa
 from paddle_tpu.parallel import gated_delta_rule as gdr
 from paddle_tpu.parallel import grouped_matmul as gm
+from paddle_tpu.parallel import pair_sum as ps
 from paddle_tpu.parallel import selective_scan as ss
 
 
@@ -427,6 +428,42 @@ def test_causal_conv_kernels_compile(one_chip, real_kernels):
         assert name in text, name
     # nothing float32 of X's size: no padded copy, no float32 Y
     assert f"f32[1,{t}," not in text and f"f32[1,{t + taps - 1}," not in text
+
+
+# n tokens, k a token, d, held experts: the four cells with expert layers
+_PAIR_SUMS = {"smallthinker": (16384, 6, 2560, 8),
+              "qwen3next": (8192, 10, 2048, 32),
+              "joyai": (4096, 8, 2048, 16), "olmoe": (8192, 8, 2048, 64)}
+
+
+@pytest.mark.parametrize("cell", sorted(_PAIR_SUMS))
+def test_pair_sum_kernel_compiles_at_the_cells_calls(cell, one_chip,
+                                                     real_kernels):
+    """The token-major sums' kernel (PR 41) as the four MoE cells lower
+    it, with a weight (``pairs.sum.combine``) and without
+    (``pairs.sum.dispatch_grad``): the row DMAs in whole groups of 16,
+    the dynamic walk of the segments, the two staging buffers and the
+    stacked three-piece left operand pass Mosaic, and no gathered
+    [k, n, d] copy or float32 [n, d] target is left in the program."""
+    n, k, d, held = _PAIR_SUMS[cell]
+    tile = ps.sum_tile(n, k, d, jnp.bfloat16, "tpu", False)
+    assert tile == (128, 32)
+
+    def at(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(rows, slot, sizes, top_w):
+        return (ps.pair_sum(rows, slot, sizes, tile, top_w,
+                            name="pairs.sum.combine"),
+                ps.pair_sum(rows, slot, sizes, tile,
+                            name="pairs.sum.dispatch_grad"))
+
+    text = jax.jit(both).lower(
+        at((n * k, d), jnp.bfloat16), at((n, k), jnp.int32),
+        at((held,), jnp.int32), at((n, k), jnp.float32)).compile().as_text()
+    for name in ("pairs.sum.combine", "pairs.sum.dispatch_grad"):
+        assert name in text, name
+    assert f"[{k},{n},{d}]" not in text and f"f32[{n},{d}]" not in text
 
 
 @pytest.mark.parametrize("gated", [True, False], ids=["gated", "memory"])

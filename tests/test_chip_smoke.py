@@ -92,19 +92,26 @@ def test_moe_phase_fails_when_no_call_takes_a_tile(telemetry):
 
 def test_moe_held_phase_runs_the_layer_windowed_and_whole(telemetry,
                                                           monkeypatch):
-    """A held layer at a size ``gmm_tile`` takes, through the
-    interpreter: 512 of 1024 rows expected live, windows of 256, every
-    pass ``windowed``, and the one-window form agrees."""
+    """A held layer at a size ``gmm_tile`` and ``sum_tile`` take,
+    through the interpreter: 512 of 1024 rows expected live, windows of
+    256, the two token-major sums the ``pairs.sum.*`` kernel's and every
+    other pass ``windowed``, and the one-window form agrees."""
     from paddle_tpu.ops import moe_ops
     from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import pair_sum
 
     monkeypatch.setattr(gm, "_INTERPRET", True)
+    monkeypatch.setattr(pair_sum, "_INTERPRET", True)
     row = chip_smoke.moe_held_phase(tokens=512, d=128, d_ff=128, experts=8,
                                     top_k=2, held=(2, 4), steps=1)
     assert row["window"] == 256 and row["rows"] == 1024
     assert 0.2 < row["live_share"] < 0.8
     assert len(row["passes"]) == 20 and all(
-        " windowed" in k and " 1024 w" in k for k in row["passes"])
+        " 1024 w" in k for k in row["passes"])
+    assert sorted(k for k in row["passes"] if " windowed " not in k) == [
+        f"{op} kernel 1024 w{w}" for op in (
+            "moe_combine sum_pairs", "moe_dispatch_grad d_x")
+        for w in (1024, 256)]
     assert set(row["step_ms"]) == {"windowed", "whole"}
     assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
     assert moe_ops.live_window(1024, 512) == 256     # put back
