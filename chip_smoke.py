@@ -863,6 +863,106 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
     return row
 
 
+def rope_dispatch():
+    """{"impl pass layout dh": calls} of the rotary embeddings lowered
+    so far (pt_rope_dispatch_total)."""
+    from paddle_tpu import monitor
+
+    rows = monitor.snapshot().get("pt_rope_dispatch_total", {})
+    return {" ".join(r["labels"][k] for k in ("impl", "pass", "layout", "dh")):
+            int(r["value"]) for r in rows.get("values", [])}
+
+
+def rope_phase(seq=4096, heads=(28, 4), dh=128, **overrides):
+    """The rotary embedding's kernels (parallel/rope.py).
+
+    1. A one-layer Program at SmallThinker's head shape (28 / 4 heads
+       of 128, a rotating layer, bf16 AMP, Adam) runs a train step, and
+       ``pt_rope_dispatch_total`` is held to one call each way through
+       ``rope.fwd`` / ``rope.bwd`` from token-major q and k, none
+       through XLA's ops. ``overrides`` cut the config for the CPU
+       tests.
+    2. On the device (Mosaic, not the interpreter): ``rope.fwd`` and
+       ``rope.bwd`` at that shape, token-major in and head-major out and
+       back, against ``ops/attention_ops._rotate`` behind XLA's
+       transpose and its vjp, to bf16 rounding; on a TPU the two
+       kernels' ms a call from a trace of three calls."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import smallthinker as M
+    from paddle_tpu.ops.attention_ops import _rotate
+    from paddle_tpu.parallel import rope
+
+    h, hk = heads
+    cfg = M.SmallThinkerConfig(**{**dict(
+        vocab_size=512, hidden_size=256, num_hidden_layers=1,
+        num_attention_heads=h, num_key_value_heads=hk, head_dim=dh,
+        sliding_window_layout=(0,), rope_layout=(1,),
+        moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+        moe_ffn_hidden_size=128), **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    scope, exe = fluid.Scope(), fluid.Executor()
+    exe.run(startup, scope=scope)
+    before = rope_dispatch()
+    loss = float(exe.run(main, feed=M.make_batch(cfg, 1, seq, seed=3),
+                         scope=scope, fetch_list=[model["loss"]])[0])
+    exe.close()
+    lowered = _dispatch_since(before, rope_dispatch)
+    say(f"  lowered: rotary embeddings {lowered}; loss {loss:.4f}")
+    check(np.isfinite(loss), f"the one-layer step's loss is {loss}")
+    check(lowered == {f"kernel fwd bthd {dh}": 1, f"kernel bwd bthd {dh}": 1},
+          f"expected one rope.fwd and one rope.bwd from token-major q and "
+          f"k, none through XLA's ops: {lowered}")
+
+    # --- on the device ----------------------------------------------------
+    theta = cfg.rope_theta
+    tile = rope.rope_tile(1, seq, h, dh, None, False, jnp.bfloat16, hk=hk)
+    check(tile is not None, f"no rope tile for t{seq} h{h} kv{hk} dh{dh}")
+    r = np.random.RandomState(5)
+    q, k = (jnp.asarray(r.randn(1, seq, n, dh), jnp.bfloat16) for n in heads)
+    gq, gk = (jnp.asarray(r.randn(1, n, seq, dh), jnp.bfloat16)
+              for n in heads)
+
+    def kernels(q, k, gq, gk):
+        return (*rope.rope_fwd(q, k, theta, tile, tokens=True),
+                *rope.rope_bwd(gq, gk, theta, tile, tokens=True))
+
+    def xla(q, k, gq, gk):
+        outs, vjp = jax.vjp(lambda *xs: tuple(
+            _rotate(jnp.swapaxes(x, 1, 2), theta) for x in xs), q, k)
+        return (*outs, *vjp((gq, gk)))
+
+    run = jax.jit(kernels)
+    got, want = run(q, k, gq, gk), jax.jit(xla)(q, k, gq, gk)
+    errs = {}
+    for name, a, b in zip(("q", "k", "dq", "dk"), got, want):
+        a, b = (jnp.asarray(x, jnp.float32) for x in (a, b))
+        check(bool(jnp.isfinite(a).all()), f"rope {name} not finite")
+        # one bf16 rounding of the same float32 rotation: the last of 8
+        # bits, where an FMA moved the float32 sum across a tie
+        errs[name] = float(jnp.max(jnp.abs(a - b)
+                                   / jnp.maximum(jnp.abs(b), 2.0 ** -6)))
+        check(errs[name] <= 2.0 ** -7,
+              f"rope {name}: the kernel is off _rotate by {errs[name]:.5f} "
+              f"of a value (one bf16 rounding is {2.0 ** -8:.5f})")
+    kernel_ms = {}
+    if jax.default_backend() == "tpu":
+        kernel_ms, _ = _traced_kernel_ms(
+            "rope_trace", lambda: run(q, k, gq, gk), "rope.")
+        check(sorted(kernel_ms) == ["rope.bwd", "rope.fwd"],
+              f"expected rope.fwd and rope.bwd in the trace: {kernel_ms}")
+    row = {"lowered": lowered, "tile": list(tile), "kernel_ms": kernel_ms,
+           "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+    say(f"  rope {row}")
+    return row
+
+
 def ssm_dispatch():
     from paddle_tpu.ops import selective_scan_ops
 
@@ -1521,6 +1621,7 @@ def main() -> int:
     report["gdn"], _ = phase("gdn", gdn_phase)
     report["mla"], _ = phase("mla", mla_phase)
     report["ssm"], _ = phase("ssm", ssm_phase)
+    report["rope"], _ = phase("rope", rope_phase)
 
     # 2. train: the step and the window contain the kernels, and no
     # attention call fell to the dense composition

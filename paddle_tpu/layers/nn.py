@@ -396,13 +396,16 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
 
 
 def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
-                     interleaved=False):
+                     interleaved=False, layout="bhtd"):
     """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
     may have fewer heads); position p of the sequence is p.
     ``rotary_dim``: only the first rotary_dim features of a head turn,
     as a head of that width would, and the others pass.
     ``interleaved``: feature 2i pairs with 2i + 1 (not i with
-    i + dh/2). Returns the rotated (q, k)."""
+    i + dh/2). ``layout`` "bthd": q and k come token-major
+    [b, t, h, dh], as a projection leaves them (the op transposes as it
+    rotates: one pass). Returns the rotated (q, k), head-major
+    [b, h, t, dh] whatever the layout."""
     helper = LayerHelper("rotary_embedding", name=name)
     q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
     k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
@@ -411,6 +414,11 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
         attrs["rotary_dim"] = int(rotary_dim)
     if interleaved:
         attrs["interleaved"] = True
+    if layout != "bhtd":
+        if layout != "bthd":
+            raise ValueError(f"rotary_embedding: layout {layout!r} is "
+                             "neither 'bhtd' nor 'bthd'")
+        attrs["layout"] = layout
     helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
                      outputs={"QOut": q_out, "KOut": k_out}, attrs=attrs)
     return q_out, k_out

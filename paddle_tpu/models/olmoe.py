@@ -105,11 +105,15 @@ def _attention(x, cfg: OlmoeConfig, p: str):
     q = _norm(q, cfg, f"{p}_attn_qnorm")
     k = _norm(k, cfg, f"{p}_attn_knorm")
 
-    def heads(z):   # [b, t, d] -> [b, h, t, dh]
-        return layers.transpose(layers.reshape(z, [0, 0, h, dh]),
-                                [0, 2, 1, 3])
+    def by_head(z):   # [b, t, d] -> [b, t, h, dh]
+        return layers.reshape(z, [0, 0, h, dh])
 
-    q, k = layers.rotary_embedding(heads(q), heads(k), theta=cfg.rope_theta)
+    def heads(z):   # [b, t, d] -> [b, h, t, dh]
+        return layers.transpose(by_head(z), [0, 2, 1, 3])
+
+    # q and k where the norms left them: the op transposes as it rotates
+    q, k = layers.rotary_embedding(by_head(q), by_head(k),
+                                   theta=cfg.rope_theta, layout="bthd")
     helper = LayerHelper(f"{p}_attn_sdpa")
     ctx = helper.create_variable_for_type_inference(dtype=x.dtype)
     # logsumexp rows, consumed by the paired grad op (DCE'd at inference)

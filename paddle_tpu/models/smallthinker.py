@@ -140,17 +140,25 @@ def _attention(a, cfg: SmallThinkerConfig, p: str, i: int):
                  cfg.head_dim)
     window = cfg.window(i)
 
+    def by_head(z, n):   # [b, t, n dh] -> [b, t, n, dh]
+        return layers.reshape(z, [0, 0, n, dh])
+
     def heads_first(z, n):   # [b, t, n dh] -> [b, n, t, dh]
-        return layers.transpose(layers.reshape(z, [0, 0, n, dh]),
-                                [0, 2, 1, 3])
+        return layers.transpose(by_head(z, n), [0, 2, 1, 3])
 
     with fluid.name_scope("qkv"):
         qkv = _linear(a, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
         q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
-        q, k, v = heads_first(q, h), heads_first(k, hk), heads_first(v, hk)
+        v = heads_first(v, hk)
+        if not cfg.rotates(i):
+            q, k = heads_first(q, h), heads_first(k, hk)
     if cfg.rotates(i):
         with fluid.name_scope("rope"):
-            q, k = layers.rotary_embedding(q, k, theta=cfg.rope_theta)
+            # q and k where the projection left them: the op transposes
+            # as it rotates
+            q, k = layers.rotary_embedding(by_head(q, h), by_head(k, hk),
+                                           theta=cfg.rope_theta,
+                                           layout="bthd")
     with fluid.name_scope("swa" if window else "core"):
         helper = LayerHelper(f"{p}_attn_sdpa")
         ctx = helper.create_variable_for_type_inference(dtype=a.dtype)

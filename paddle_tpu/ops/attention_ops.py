@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu import monitor as _monitor
-from paddle_tpu.core import interp
-from paddle_tpu.core.registry import register_op
+from paddle_tpu.core import autodiff, interp
+from paddle_tpu.core.registry import OpDef, register_op
 
 NEG_INF = -1e9
 
@@ -195,6 +195,63 @@ def _rotate(x, theta, rotary_dim=None, interleaved=False):
     return out.astype(x.dtype)
 
 
+_M_ROPE = _monitor.counter(
+    "pt_rope_dispatch_total",
+    "rotary embedding implementation chosen at trace time, one row a "
+    "lowered call (q and k together): impl kernel (rope.fwd / rope.bwd, "
+    "parallel/rope.py) or xla (_rotate, behind XLA's transpose where "
+    "layout is bthd), pass (fwd/bwd), layout (bthd: q and k come "
+    "token-major; bhtd: head-major) and dh; parallel/rope.rope_tile's "
+    "answer for the call")
+
+
+def _rope_attrs(attrs):
+    """(theta, rotary_dim or None, interleaved, token-major?) of a
+    rotary_embedding op."""
+    return (float(attrs.get("theta", 10000.0)),
+            int(attrs.get("rotary_dim", 0)) or None,
+            bool(attrs.get("interleaved", False)),
+            attrs.get("layout", "bhtd") == "bthd")
+
+
+def _rope_tile(q, k, attrs, direction):
+    """``parallel/rope.rope_tile``'s answer for a rotary_embedding call
+    on Q and K (None: the XLA form), noted in
+    ``pt_rope_dispatch_total``."""
+    from paddle_tpu.parallel import rope
+
+    _, rd, il, tokens = _rope_attrs(attrs)
+    t_axis, h_axis = (1, 2) if tokens else (2, 1)
+    tile = None
+    if q.dtype == k.dtype and q.shape[-1] == k.shape[-1]:
+        tile = rope.rope_tile(
+            q.shape[0], q.shape[t_axis], q.shape[h_axis], q.shape[-1], rd,
+            il, q.dtype, hk=k.shape[h_axis])
+    # off with telemetry; build-time shape inference is not a lowering
+    if _monitor.enabled() and interp.lowering_active():
+        _M_ROPE.inc(labels={"impl": "kernel" if tile else "xla",
+                            "pass": direction,
+                            "layout": "bthd" if tokens else "bhtd",
+                            "dh": str(q.shape[-1])})
+    return tile
+
+
+def _rotary_xla(ins, attrs):
+    """rotary_embedding as XLA's ops: ``_rotate`` on head-major Q and
+    K, behind a transpose where they come token-major."""
+    theta, rd, il, tokens = _rope_attrs(attrs)
+    q, k = _x(ins, "Q"), _x(ins, "K")
+    if tokens:
+        q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
+    return {"QOut": [_rotate(q, theta, rd, il)],
+            "KOut": [_rotate(k, theta, rd, il)]}
+
+
+# (the generic grad op's rule for the XLA form: the vjp of _rotary_xla)
+_ROTARY_XLA_GRAD = autodiff.make_grad_compute(OpDef(
+    type="rotary_embedding", compute=_rotary_xla, diff_inputs=("Q", "K")))
+
+
 @register_op("rotary_embedding", diff_inputs=("Q", "K"))
 def _rotary_embedding(ins, attrs):
     """Q, K [b, h, t, dh] (K may have fewer heads) -> the same with
@@ -202,12 +259,49 @@ def _rotary_embedding(ins, attrs):
     ``rotary_dim``, 0 or absent for the whole head: the leading
     features that turn; ``interleaved``: pairs of neighbours, not
     rotate-half). The angles and the rotation are f32; the results
-    return to the inputs' dtype."""
-    theta = float(attrs.get("theta", 10000.0))
-    rd = int(attrs.get("rotary_dim", 0)) or None
-    il = bool(attrs.get("interleaved", False))
-    return {"QOut": [_rotate(_x(ins, "Q"), theta, rd, il)],
-            "KOut": [_rotate(_x(ins, "K"), theta, rd, il)]}
+    return to the inputs' dtype. ``layout`` "bthd": Q and K come
+    token-major [b, t, h, dh], as a projection leaves them; the results
+    are head-major [b, h, t, dh] all the same.
+
+    ONE kernel for Q and K, ``parallel/rope.rope_fwd``, at the tile
+    ``rope_tile`` gives the call from its shapes, dtype, backend and
+    mesh; where it gives none, ``_rotary_xla``."""
+    q, k = _x(ins, "Q"), _x(ins, "K")
+    tile = _rope_tile(q, k, attrs, "fwd")
+    if tile is None:
+        return _rotary_xla(ins, attrs)
+    from paddle_tpu.parallel import rope
+
+    theta, _, _, tokens = _rope_attrs(attrs)
+    q, k = rope.rope_fwd(q, k, theta, tile, tokens=tokens)
+    return {"QOut": [q], "KOut": [k]}
+
+
+@register_op("rotary_embedding_grad", no_grad=True)
+def _rotary_embedding_grad(ins, attrs):
+    """GRAD::Q and GRAD::K of rotary_embedding, in Q's and K's layout:
+    the rotation's transpose (the rotation by the negated angles) of
+    the head-major cotangents, ``parallel/rope.rope_bwd`` where the
+    forward took the kernel (a kernel called from a ``custom_vjp`` rule
+    would be traced twice and named by jax), else the vjp of
+    ``_rotary_xla``, as the generic grad op took it."""
+    q, k = _x(ins, "Q"), _x(ins, "K")
+    tile = _rope_tile(q, k, attrs, "bwd")
+    if tile is None:
+        return _ROTARY_XLA_GRAD(ins, attrs)
+    from paddle_tpu.parallel import rope
+
+    theta, _, _, tokens = _rope_attrs(attrs)
+
+    def cotangent(g, x):   # head-major; zeros where the program gave none
+        if g is not None:
+            return g.astype(x.dtype)
+        return jnp.zeros_like(jnp.swapaxes(x, 1, 2) if tokens else x)
+
+    dq, dk = rope.rope_bwd(cotangent(_x(ins, "GRAD::QOut"), q),
+                           cotangent(_x(ins, "GRAD::KOut"), k), theta, tile,
+                           tokens=tokens)
+    return {"GRAD::Q": [dq], "GRAD::K": [dk]}
 
 
 def _sdpa_config(ins, attrs, rng):

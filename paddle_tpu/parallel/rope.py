@@ -1,0 +1,234 @@
+"""Pallas TPU kernels for the rotary position embedding of q and k
+(ops/attention_ops.rotary_embedding; the mathematics and the precision
+contract are ``_rotate``'s there: rotate-half form, float32 angles,
+float32 rotation of the bf16 values, one rounding back to bf16).
+
+``rope.fwd`` and ``rope.bwd``, one call a pass for q AND k. The rotation
+and the heads-first transpose are ONE pass over bf16: a grid step reads a
+block of rows of q where the projection left it, token-major
+[b, t, h * dh] (a head is a block of dh lanes), and writes it head-major
+[b, h, t, dh]; the backward reads the head-major cotangents and writes
+token-major ones. What XLA's lowering of transpose + ``_rotate`` does
+and a grid step does not: a relayout of q with t as the minor dimension
+and one back, a float32 copy of q and the two negated float32 halves in
+HBM (2.63 GB accessed a forward call at [1, 28 + 4, 16384, 128] for
+0.30 GB of q, k and tables: the compiled module's own count, PR 42).
+
+A grid step holds ``rows`` positions of ``hb`` heads of q; k's heads
+(all of them: there are few) ride in the step of q's first head block,
+their blocks' indices constant over the head axis. A pass of the loop
+inside the step takes ``_PASS_ROWS`` rows: the tables' rows once, then
+head after head: load [pass, dh] bf16, convert to float32 in registers,
+swap the halves (a lane roll by dh / 2 on the XLU where a head is one
+vreg wide, whole vregs otherwise), multiply by cos and by a sin table
+that carries the sign ([-sin | +sin]; the backward's, a rotation's
+transpose, [+sin | -sin]), add, round to bf16, store. The tables
+[t, dh] float32 are built by XLA once a call, inside the call's jitted
+function; their block's index does not change over the head axis, so
+each is fetched once a block of rows.
+
+``rope_tile`` is the one function that says tile or the XLA form
+(``ops/attention_ops._rotate`` behind XLA's transpose), from the call's
+own shapes, the dtype, the backend and the mesh;
+``pt_rope_dispatch_total{impl}`` records its answer.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Test hook, as causal_conv._INTERPRET: run the kernels in interpreter
+# mode on the CPU so the suite reaches them.
+_INTERPRET = False
+
+_LANES = 128
+# Rows of one pass of the loop inside a grid step, and of the step's
+# block, with all of q's heads in it. Timed alone on a v5e
+# (benchmarks/rope_candidates.py; my chip runs, PR 42; forward / backward
+# ms a call from the projection's [1, 16384, 4608] to head-major 28 + 4
+# heads of 128 and back, the copies of q and k out of it, 0.27, included):
+# 256 rows x 28 heads 0.63 / 0.64, 128 0.65 / 0.66, 64 0.68 / 0.69, but
+# 512 0.91 / 0.93 and 1024 0.91 / 0.93 (the step's compute and its DMAs
+# are about as long, 0.35 ms a call each, and from 512 rows up they no
+# longer overlap); 4 heads a step (1 KB pieces of a row) 0.92-1.02 at
+# 128 .. 2048 rows, a head a step 1.94; passes of 16 and 64 rows as 32.
+# At [2, 4096, 16 + 16 heads] 64 .. 1024 rows read 0.41-0.44 / 0.27-0.30.
+_PASS_ROWS = 32
+_BLOCK_ROWS = (256, 128, 64, 32)
+# What a call's blocks may take of VMEM (the call raises Mosaic's scoped
+# default of 16 MiB to what they need, as pair_sum does).
+_VMEM_CAP_BYTES = 40 * 2**20
+
+_F32 = jnp.float32
+
+
+def kernels_enabled() -> bool:
+    """The Pallas kernels need a TPU backend (tests reach them on CPU
+    through the interpreter)."""
+    return jax.default_backend() == "tpu" or bool(_INTERPRET)
+
+
+def _under_mesh() -> bool:
+    from paddle_tpu.core import interp
+
+    return interp.spmd_ctx() is not None
+
+
+def _vmem_bytes(rows, hb, hk, dh):
+    """What a grid step keeps in VMEM: q's and k's blocks in and out as
+    bf16 and the two tables' as float32, all double-buffered."""
+    return 2 * (2 * rows * (hb + hk) * dh * 2 + 2 * rows * dh * 4)
+
+
+def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
+              backend=None, on_mesh=None):
+    """-> (rows, hb): the positions and the heads of q one grid step of
+    ``rope.*`` works on, or None where the call runs as the XLA form: no
+    TPU backend (``backend``: None for this process's, with the
+    interpreter counting as one), values that are not bf16, a program
+    under a mesh (a Mosaic call is not auto-partitioned), a head of
+    which only a part turns (``rotary_dim``) or whose pairs are
+    neighbours (``interleaved``), a head that is not whole vregs of 128
+    lanes, a sequence no block of rows divides, or blocks over the VMEM
+    cap. ``h`` and ``hk`` (None: as many) are q's and k's heads.
+
+    The tile follows the shape, not a flag: all of q's heads (a block of
+    the token-major side is then whole rows of q, contiguous in HBM) by
+    the most of 256 .. 32 rows that divide t and fit."""
+    on_tpu = kernels_enabled() if backend is None else backend == "tpu"
+    if on_mesh is None:
+        on_mesh = _under_mesh()
+    hk = h if hk is None else hk
+    if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
+            or interleaved or (rotary_dim or dh) != dh or dh % _LANES
+            or min(b, t, h, hk) < 1):
+        return None
+    for rows in _BLOCK_ROWS:
+        if t % rows == 0 and _vmem_bytes(rows, h, hk, dh) <= _VMEM_CAP_BYTES:
+            return rows, h
+    return None
+
+
+def tables(t, dh, theta):
+    """(cos, signed sin) [t, dh] float32 of positions 0 .. t - 1, the
+    angles as ``_rotate`` computes them: feature i and i + dh / 2 share
+    angle p * theta^(-2i / dh); sin is [-sin | +sin], the sign the
+    forward's swapped halves take."""
+    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=_F32) / dh)
+    ang = jnp.arange(t, dtype=_F32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return (jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1))
+
+
+def _swap_halves(x):
+    """[p, dh] -> the same with its two halves of lanes exchanged."""
+    half = x.shape[-1] // 2
+    if half % _LANES:
+        return pltpu.roll(x, half, 1)
+    return jnp.concatenate([x[:, half:], x[:, :half]], axis=-1)
+
+
+def _kernel(q_ref, k_ref, cos_ref, sin_ref, qo_ref, ko_ref, *, rows, hb, hk,
+            dh, tokens_in, tokens_out, sign):
+    def head(tokens, n, at):
+        """Rows ``at`` of head ``n`` of a block, as an index."""
+        if tokens:
+            return (0, at, pl.ds(n * dh, dh))
+        return (0, n, at, slice(None))
+
+    def turn(src, dst, heads):
+        def a_pass(p, carry):
+            at = pl.ds(pl.multiple_of(p * _PASS_ROWS, _PASS_ROWS),
+                       _PASS_ROWS)
+            cos = cos_ref[at, :]
+            sin = sin_ref[at, :] * sign
+            for n in range(heads):
+                x = src[head(tokens_in, n, at)].astype(_F32)
+                y = x * cos + _swap_halves(x) * sin
+                dst[head(tokens_out, n, at)] = y.astype(dst.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, rows // _PASS_ROWS, a_pass, 0)
+
+    turn(q_ref, qo_ref, hb)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        turn(k_ref, ko_ref, hk)
+
+
+def _specs(rows, hb, hk, dh, tokens):
+    """(q's, k's) BlockSpec on a side that is token-major [b, t, h dh]
+    or head-major [b, h, t, dh], for the grid (batch, blocks of rows,
+    blocks of q's heads)."""
+    if tokens:
+        return (pl.BlockSpec((1, rows, hb * dh), lambda bi, i, j: (bi, i, j)),
+                pl.BlockSpec((1, rows, hk * dh), lambda bi, i, j: (bi, i, 0)))
+    return (pl.BlockSpec((1, hb, rows, dh), lambda bi, i, j: (bi, j, i, 0)),
+            pl.BlockSpec((1, hk, rows, dh), lambda bi, i, j: (bi, 0, i, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "theta", "tile", "tokens_in", "tokens_out", "sign", "name", "interpret"))
+def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret):
+    rows, hb = tile
+    if tokens_in:
+        (b, t, h, dh), hk = q.shape, k.shape[2]
+        q, k = q.reshape(b, t, h * dh), k.reshape(b, t, hk * dh)
+    else:
+        (b, h, t, dh), hk = q.shape, k.shape[1]
+    assert t % rows == 0 and rows % _PASS_ROWS == 0 and h % hb == 0, (
+        q.shape, tile)
+    out_shapes = [(b, t, n * dh) if tokens_out else (b, n, t, dh)
+                  for n in (h, hk)]
+    cos, sin = tables(t, dh, theta)
+    table = pl.BlockSpec((rows, dh), lambda bi, i, j: (i, 0))
+    moved = (h + hk) * b * t * dh
+    qo, ko = pl.pallas_call(
+        functools.partial(_kernel, rows=rows, hb=hb, hk=hk, dh=dh,
+                          tokens_in=tokens_in, tokens_out=tokens_out,
+                          sign=sign),
+        name=name,
+        out_shape=[jax.ShapeDtypeStruct(s, x.dtype)
+                   for s, x in zip(out_shapes, (q, k))],
+        grid=(b, t // rows, h // hb),
+        in_specs=[*_specs(rows, hb, hk, dh, tokens_in), table, table],
+        out_specs=list(_specs(rows, hb, hk, dh, tokens_out)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(16 * 2**20,
+                                 _vmem_bytes(rows, hb, hk, dh) * 3 // 2)),
+        cost_estimate=pl.CostEstimate(
+            flops=3 * moved, transcendentals=0,
+            bytes_accessed=2 * q.dtype.itemsize * moved + 8 * t * dh),
+        interpret=interpret,
+    )(q, k, cos, sin)
+    if tokens_out:
+        return qo.reshape(b, t, h, dh), ko.reshape(b, t, hk, dh)
+    return qo, ko
+
+
+def rope_fwd(q, k, theta, tile, tokens=False):
+    """(q, k) with rotary positions 0 .. t - 1 applied, head-major
+    [b, h, t, dh] (k may have fewer heads), at ``tile`` as ``rope_tile``
+    gives it. ``tokens``: q and k come token-major, [b, t, h, dh]. One
+    jitted function a (shape, layout): the layers of a model make the
+    same call, and a step traces and lowers the kernel once for all."""
+    return _rope(q, k, theta=float(theta), tile=tuple(tile),
+                 tokens_in=bool(tokens), tokens_out=False, sign=1.0,
+                 name="rope.fwd", interpret=bool(_INTERPRET))
+
+
+def rope_bwd(dq, dk, theta, tile, tokens=False):
+    """The cotangents of ``rope_fwd``'s q and k from those of its
+    results (head-major): the rotation's transpose, which is the
+    rotation by the negated angles, written token-major where the
+    forward read so."""
+    return _rope(dq, dk, theta=float(theta), tile=tuple(tile),
+                 tokens_in=False, tokens_out=bool(tokens), sign=-1.0,
+                 name="rope.bwd", interpret=bool(_INTERPRET))

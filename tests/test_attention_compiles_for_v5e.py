@@ -23,6 +23,7 @@ from paddle_tpu.parallel import flash_attention as fa
 from paddle_tpu.parallel import gated_delta_rule as gdr
 from paddle_tpu.parallel import grouped_matmul as gm
 from paddle_tpu.parallel import pair_sum as ps
+from paddle_tpu.parallel import rope
 from paddle_tpu.parallel import selective_scan as ss
 
 
@@ -464,6 +465,43 @@ def test_pair_sum_kernel_compiles_at_the_cells_calls(cell, one_chip,
     for name in ("pairs.sum.combine", "pairs.sum.dispatch_grad"):
         assert name in text, name
     assert f"[{k},{n},{d}]" not in text and f"f32[{n},{d}]" not in text
+
+
+# (b, t, q heads, k heads, dh) of the cells whose rotary embedding
+# rope_tile takes, and a head two vregs wide
+_ROPES = {"smallthinker": (1, 16384, 28, 4, 128),
+          "olmoe": (2, 4096, 16, 16, 128), "dh256": (1, 8192, 16, 2, 256)}
+
+
+@pytest.mark.parametrize("tokens", [True, False],
+                         ids=["token_major", "head_major"])
+@pytest.mark.parametrize("cell", sorted(_ROPES))
+def test_rope_kernels_compile_at_the_cells_calls(cell, tokens, one_chip,
+                                                 real_kernels):
+    """``rope.fwd`` / ``rope.bwd`` (PR 42) as the two cells lower them,
+    q and k token-major in and head-major out (and back), and head-major
+    both ways: the lane-blocks of a head in a [rows, h dh] block, the
+    lane roll by half a head (whole vregs at dh 256) and k's blocks
+    riding in the first head step pass Mosaic, and no float32 copy of q
+    is left in the program."""
+    b, t, h, hk, dh = _ROPES[cell]
+    tile = rope.rope_tile(b, t, h, dh, None, False, jnp.bfloat16, hk=hk,
+                          backend="tpu", on_mesh=False)
+    assert tile == (256, h)
+
+    def at(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def both(q, k, dq, dk):
+        return (rope.rope_fwd(q, k, 1e6, tile, tokens=tokens),
+                rope.rope_bwd(dq, dk, 1e6, tile, tokens=tokens))
+
+    heads = (at(b, h, t, dh), at(b, hk, t, dh))
+    ins = (at(b, t, h, dh), at(b, t, hk, dh)) if tokens else heads
+    text = jax.jit(both).lower(*ins, *heads).compile().as_text()
+    for name in ("rope.fwd", "rope.bwd"):
+        assert name in text, name
+    assert f"f32[{b},{h},{t}," not in text and f"f32[{b},{t},{h}" not in text
 
 
 @pytest.mark.parametrize("gated", [True, False], ids=["gated", "memory"])

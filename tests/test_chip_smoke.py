@@ -124,6 +124,29 @@ def test_moe_held_phase_fails_without_the_kernels(telemetry):
                                   top_k=2, held=(2, 4), steps=1)
 
 
+def test_rope_phase_holds_the_program_to_the_kernels(telemetry, monkeypatch):
+    """The phase at a short sequence and 4 / 2 heads through the
+    interpreter: the one-layer Program lowers one ``rope.fwd`` and one
+    ``rope.bwd`` from token-major q and k, and the kernels agree with
+    ``_rotate`` and its vjp to bf16 rounding."""
+    from paddle_tpu.parallel import rope
+
+    monkeypatch.setattr(rope, "_INTERPRET", True)
+    row = chip_smoke.rope_phase(seq=64, heads=(4, 2),
+                                moe_num_primary_experts=4)
+    assert row["lowered"] == {"kernel fwd bthd 128": 1,
+                              "kernel bwd bthd 128": 1}
+    assert row["tile"] == [64, 4] and row["kernel_ms"] == {}
+    assert max(row["rel_err"].values()) <= 2.0 ** -7
+
+
+def test_rope_phase_fails_without_the_kernels(telemetry):
+    # kernels off (CPU, no interpreter): XLA's transpose and _rotate
+    with pytest.raises(chip_smoke.SmokeFailure, match="none through XLA"):
+        chip_smoke.rope_phase(seq=64, heads=(4, 2),
+                              moe_num_primary_experts=4)
+
+
 GDN_TINY = dict(
     vocab_size=50, hidden_size=128, num_attention_heads=4,
     num_key_value_heads=2, head_dim=128, linear_key_head_dim=128,

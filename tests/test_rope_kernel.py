@@ -1,0 +1,336 @@
+"""The rotary embedding's kernels (paddle_tpu/parallel/rope.py:
+``rope.fwd`` / ``rope.bwd``) through the Pallas interpreter on the CPU,
+as tests/test_pair_sum_kernel.py runs its kernel: against
+``ops/attention_ops._rotate`` and its ``jax.vjp`` on the same bf16
+values at heads of 128 and 256, grouped heads (28 / 4), both input
+layouts and several blocks; forward then backward returns the input;
+what ``rope_tile`` takes and refuses; the op under ``layout="bthd"``
+against transpose-then-rotate, with and without the kernel; the rows of
+``pt_rope_dispatch_total``; and SmallThinker's and OLMoE's tiny Programs
+under AMP at heads of 128, kernel against the XLA form."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import flags, layers, monitor
+from paddle_tpu.backward import append_backward
+from paddle_tpu.models import olmoe, smallthinker
+from paddle_tpu.ops import attention_ops as ao
+from paddle_tpu.parallel import rope
+
+BF16 = jnp.bfloat16
+THETA = 1.5e6
+
+
+@pytest.fixture
+def interpreter(monkeypatch):
+    monkeypatch.setattr(rope, "_INTERPRET", True)
+
+
+@pytest.fixture
+def calls(monkeypatch):
+    """(kernel's name, token-major?) of every call of the kernels, in
+    order."""
+    made = []
+    for fn, name in ((rope.rope_fwd, "rope.fwd"), (rope.rope_bwd, "rope.bwd")):
+        def spy(*a, _fn=fn, _name=name, **kw):
+            made.append((_name, bool(kw.get("tokens", False))))
+            return _fn(*a, **kw)
+        monkeypatch.setattr(rope, fn.__name__, spy)
+    return made
+
+
+def values(b, t, h, hk, dh, seed=0):
+    """Token-major bf16 q [b, t, h, dh] and k [b, t, hk, dh]."""
+    r = np.random.RandomState(seed)
+    return (jnp.asarray(r.randn(b, t, h, dh), BF16),
+            jnp.asarray(r.randn(b, t, hk, dh), BF16))
+
+
+def heads_first(z):
+    return jnp.swapaxes(z, 1, 2)
+
+
+def to_bf16_rounding(got, want):
+    """``got`` (bf16) is the float32 ``want`` rounded once, give or take
+    the last float32 bit of a product's sum (an FMA or not)."""
+    assert got.dtype == BF16 and got.shape == want.shape
+    got, want = (np.asarray(x.astype(jnp.float32)) for x in (got, want))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_less(
+        np.abs(got - want), np.abs(want) * 2.0 ** -7 + 1e-6)
+
+
+def rotate32(x):
+    """``_rotate`` of head-major bf16 values, left in float32."""
+    return ao._rotate(x.astype(jnp.float32), THETA)
+
+
+# (b, t, q heads, k heads, dh, (rows, heads of q a step))
+CASES = {
+    "grouped_28_4": (1, 64, 28, 4, 128, (32, 28)),
+    "grouped_a_k_head_a_step": (1, 64, 28, 4, 128, (32, 4)),
+    "a_head_a_step": (2, 96, 4, 4, 128, (32, 1)),
+    "two_passes_a_block": (2, 128, 4, 2, 128, (64, 4)),
+    "dh256": (1, 64, 4, 2, 256, (32, 4)),
+    "dh256_one_block": (2, 32, 2, 1, 256, (32, 2)),
+}
+
+
+@pytest.mark.parametrize("tokens", [True, False],
+                         ids=["token_major", "head_major"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_forward_kernel_is_rotate(case, tokens, interpreter):
+    b, t, h, hk, dh, tile = CASES[case]
+    q, k = values(b, t, h, hk, dh)
+    qh, kh = heads_first(q), heads_first(k)
+    got = rope.rope_fwd(*((q, k) if tokens else (qh, kh)), THETA, tile,
+                        tokens=tokens)
+    for g, x in zip(got, (qh, kh)):
+        to_bf16_rounding(g, rotate32(x))
+
+
+@pytest.mark.parametrize("tokens", [True, False],
+                         ids=["token_major", "head_major"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_backward_kernel_is_rotate_s_vjp(case, tokens, interpreter):
+    b, t, h, hk, dh, tile = CASES[case]
+    q, k = values(b, t, h, hk, dh)
+    gq, gk = (heads_first(z) for z in values(b, t, h, hk, dh, seed=1))
+    got = rope.rope_bwd(gq, gk, THETA, tile, tokens=tokens)
+    for d, x, g in zip(got, (q, k), (gq, gk)):
+        want = jax.vjp(rotate32, heads_first(x).astype(jnp.float32))[1](
+            g.astype(jnp.float32))[0]
+        to_bf16_rounding(heads_first(d) if tokens else d, want)
+        assert d.shape == (x.shape if tokens else heads_first(x).shape)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_forward_then_backward_returns_the_input(case, interpreter):
+    """A rotation's transpose is its inverse: two bf16 roundings, each
+    of a value no longer than the pair it belongs to."""
+    b, t, h, hk, dh, tile = CASES[case]
+    q, k = values(b, t, h, hk, dh, seed=2)
+    back = rope.rope_bwd(*rope.rope_fwd(q, k, THETA, tile, tokens=True),
+                         THETA, tile, tokens=True)
+    for got, x in zip(back, (q, k)):
+        got, x = (np.asarray(z.astype(jnp.float32)) for z in (got, x))
+        pair = np.hypot(x, np.roll(x, dh // 2, axis=-1))
+        np.testing.assert_array_less(np.abs(got - x),
+                                     pair * 2.0 ** -7 + 1e-6)
+
+
+def test_position_zero_is_not_turned_and_a_turn_keeps_a_pair_s_length(
+        interpreter):
+    q, k = values(1, 64, 4, 2, 128, seed=3)
+    qo, _ = rope.rope_fwd(q, k, THETA, (32, 4), tokens=True)
+    np.testing.assert_array_equal(qo[:, :, 0], heads_first(q)[:, :, 0])
+    np.testing.assert_allclose(
+        np.linalg.norm(np.asarray(qo.astype(jnp.float32)), axis=-1),
+        np.linalg.norm(np.asarray(q.astype(jnp.float32)), axis=-1).swapaxes(
+            1, 2), rtol=1e-2)
+
+
+TAKEN = dict(b=1, t=16384, h=28, dh=128, rotary_dim=None, interleaved=False,
+             dtype=BF16, hk=4, backend="tpu", on_mesh=False)
+
+
+@pytest.mark.parametrize("call,want", [
+    ({}, (256, 28)),                                   # smallthinker
+    (dict(b=2, t=4096, h=16, hk=16), (256, 16)),       # olmoe
+    (dict(rotary_dim=128), (256, 28)),                 # the whole head, said
+    (dict(t=4096 + 128), (128, 28)),
+    (dict(t=96), (32, 28)),
+    (dict(dh=256, h=16, hk=2), (256, 16)),
+    (dict(dtype=jnp.float32), None),
+    (dict(on_mesh=True), None),
+    (dict(backend="cpu"), None),
+    (dict(rotary_dim=64), None),                       # a part of the head
+    (dict(dh=256, rotary_dim=64), None),               # qwen3next
+    (dict(dh=64, interleaved=True, hk=1), None),       # joyai
+    (dict(interleaved=True), None),
+    (dict(dh=64), None),
+    (dict(dh=192), None),
+    (dict(t=40), None),                                # off every block
+    (dict(t=0), None),
+    (dict(h=1024, hk=1024), None),                     # over the VMEM cap
+], ids=lambda v: "_".join(f"{k}{getattr(x, '__name__', x)}"
+                          for k, x in v.items()) or "smallthinker"
+   if isinstance(v, dict) else None)
+def test_rope_tile_follows_the_shape_the_dtype_the_backend_and_the_mesh(
+        call, want):
+    kw = {**TAKEN, **call}
+    assert rope.rope_tile(kw.pop("b"), kw.pop("t"), kw.pop("h"),
+                          kw.pop("dh"), kw.pop("rotary_dim"),
+                          kw.pop("interleaved"), kw.pop("dtype"),
+                          **kw) == want
+
+
+def test_no_tile_without_a_tpu_or_the_interpreter():
+    assert rope.rope_tile(1, 16384, 28, 128, None, False, BF16, hk=4) is None
+
+
+# --- the op ---------------------------------------------------------------
+
+
+def op_pair(q, k, gq, gk, layout, **attrs):
+    """(QOut, KOut, GRAD::Q, GRAD::K) of the op rules on Q and K."""
+    attrs = {"theta": THETA, **attrs}
+    if layout != "bhtd":
+        attrs["layout"] = layout
+    ins = {"Q": [q], "K": [k]}
+    out = ao._rotary_embedding(ins, attrs)
+    grad = ao._rotary_embedding_grad(
+        {**ins, **out, "GRAD::QOut": [gq], "GRAD::KOut": [gk]},
+        {**attrs, "fwd_input_slots": ["Q", "K"],
+         "fwd_output_slots": ["QOut", "KOut"]})
+    return (out["QOut"][0], out["KOut"][0], grad["GRAD::Q"][0],
+            grad["GRAD::K"][0])
+
+
+@pytest.mark.parametrize("t", [64, 40], ids=["on_the_row_block", "off_it"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_op_token_major_is_transpose_then_rotate(dtype, t, interpreter,
+                                                     calls):
+    """``layout="bthd"``: the head-major op on transposed inputs, its
+    gradients transposed back; through the kernel where ``rope_tile``
+    takes the call (bf16, t on a block of rows), XLA's ops elsewhere."""
+    q, k = (z.astype(dtype) for z in values(2, t, 4, 2, 128, seed=4))
+    gq, gk = (heads_first(z).astype(dtype)
+              for z in values(2, t, 4, 2, 128, seed=5))
+    got = op_pair(q, k, gq, gk, "bthd")
+    kernel = dtype == "bfloat16" and t == 64
+    assert calls == [("rope.fwd", True), ("rope.bwd", True)] * kernel
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(rope, "rope_tile", lambda *a, **kw: None)
+        want = op_pair(heads_first(q), heads_first(k), gq, gk, "bhtd")
+    assert len(calls) == 2 * kernel
+    for g, w, back in zip(got, want, (False, False, True, True)):
+        w = heads_first(w) if back else w
+        assert g.dtype == w.dtype and g.shape == w.shape
+        if kernel:
+            to_bf16_rounding(g, w.astype(jnp.float32))
+        else:
+            np.testing.assert_array_equal(g, w)
+
+
+def test_the_op_head_major_takes_the_kernel_too(interpreter, calls):
+    q, k = (heads_first(z) for z in values(1, 64, 4, 4, 128, seed=6))
+    got = op_pair(q, k, q, k, "bhtd")
+    assert calls == [("rope.fwd", False), ("rope.bwd", False)]
+    for g, x in zip(got[:2], (q, k)):
+        to_bf16_rounding(g, rotate32(x))
+
+
+def test_a_cotangent_the_program_does_not_give_is_zeros(interpreter):
+    """Only q's result reaches the loss: GRAD::K is zeros, GRAD::Q the
+    kernel's."""
+    q, k = values(1, 64, 4, 2, 128, seed=7)
+    g = heads_first(q)
+    ins = {"Q": [q], "K": [k], "GRAD::QOut": [g], "GRAD::KOut": [None]}
+    grad = ao._rotary_embedding_grad(ins, {"theta": THETA, "layout": "bthd"})
+    assert not np.asarray(grad["GRAD::K"][0].astype(jnp.float32)).any()
+    want = jax.vjp(rotate32, g.astype(jnp.float32))[1](
+        g.astype(jnp.float32))[0]
+    to_bf16_rounding(heads_first(grad["GRAD::Q"][0]), want)
+
+
+def test_the_layer_names_the_layout_and_refuses_another():
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        q = layers.data("q", shape=[2, 16, 4, 8], append_batch_size=False)
+        k = layers.data("k", shape=[2, 16, 2, 8], append_batch_size=False)
+        qo, ko = layers.rotary_embedding(q, k, layout="bthd")
+        assert tuple(qo.shape) == (2, 4, 16, 8)
+        assert tuple(ko.shape) == (2, 2, 16, 8)
+        with pytest.raises(ValueError, match="layout"):
+            layers.rotary_embedding(q, k, layout="tbhd")
+    op = main.global_block().ops[-1]
+    assert op.type == "rotary_embedding" and op.attrs["layout"] == "bthd"
+
+
+# --- Programs ---------------------------------------------------------------
+
+
+def rope_rows(before=()):
+    """{labels: calls} of pt_rope_dispatch_total, the rows that moved
+    since ``before`` (an earlier reading) alone."""
+    rows = {tuple(sorted(r["labels"].items())): int(r["value"])
+            for r in monitor.snapshot().get(
+                "pt_rope_dispatch_total", {}).get("values", [])}
+    before = dict(before)
+    return {k: v - before.get(k, 0) for k, v in rows.items()
+            if v != before.get(k, 0)}
+
+
+SMALLTHINKER = dict(
+    vocab_size=50, hidden_size=32, num_hidden_layers=4,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+    rope_theta=1.5e6, rms_norm_eps=1e-6, sliding_window_size=5,
+    sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+    moe_num_primary_experts=4, moe_num_active_primary_experts=2,
+    moe_ffn_hidden_size=16, norm_topk_prob=True)
+OLMOE = dict(vocab_size=50, hidden_size=256, num_hidden_layers=1,
+             num_attention_heads=2, intermediate_size=16, num_experts=4,
+             num_experts_per_tok=2, rope_theta=10000.0, rms_norm_eps=1e-5,
+             norm_topk_prob=False, router_aux_loss_coef=0.01,
+             router_z_loss_coef=0.001)
+MODELS = {"smallthinker": (smallthinker, smallthinker.SmallThinkerConfig,
+                           SMALLTHINKER, 3),
+          "olmoe": (olmoe, olmoe.OlmoeConfig, OLMOE, 1)}
+
+
+def loss_and_gradients(model, cfg, seq):
+    """(loss, {parameter: gradient}) of a model's Program under AMP."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.program_guard(main, startup):
+        built = model.build(cfg)
+        grads = append_backward(built["loss"])
+    main._amp = True
+    scope, exe = fluid.Scope(), fluid.Executor()
+    exe.run(startup, scope=scope)
+    out = exe.run(main, feed=model.make_batch(cfg, 2, seq, seed=1),
+                  scope=scope, fetch_list=[built["loss"],
+                                           *(g for _, g in grads)])
+    return float(out[0]), {p.name: np.asarray(g, np.float32)
+                           for (p, _), g in zip(grads, out[1:])}
+
+
+@pytest.mark.parametrize("name", list(MODELS))
+def test_a_model_s_program_with_the_kernel_is_the_program_without(
+        name, interpreter, monkeypatch):
+    """The tiny Program at heads of 128 under AMP, its rotating layers
+    through ``rope.fwd`` / ``rope.bwd`` (one row a lowered call in
+    ``pt_rope_dispatch_total``), against the same Program with
+    ``rope_tile`` giving no tile (XLA's transpose and ``_rotate``: the
+    parent's lowering): the loss and every parameter's gradient."""
+    model, config, sizes, rotating = MODELS[name]
+    cfg = config(**sizes)
+    flags.set_flags({"telemetry": True})
+    try:
+        before = rope_rows()
+        loss, grads = loss_and_gradients(model, cfg, 32)
+        by_impl = {}
+        for labels, n in rope_rows(before).items():
+            labels = dict(labels)
+            assert labels["layout"] == "bthd" and labels["dh"] == "128"
+            key = labels["impl"], labels["pass"]
+            by_impl[key] = by_impl.get(key, 0) + n
+        assert by_impl == {("kernel", "fwd"): rotating,
+                           ("kernel", "bwd"): rotating}
+        monkeypatch.setattr(rope, "rope_tile", lambda *a, **kw: None)
+        before = rope_rows()
+        want_loss, want = loss_and_gradients(model, cfg, 32)
+        assert {dict(k)["impl"] for k in rope_rows(before)} == {"xla"}
+    finally:
+        flags.set_flags({"telemetry": False})
+    np.testing.assert_allclose(loss, want_loss, rtol=2e-3)
+    assert grads.keys() == want.keys()
+    for key in want:
+        scale = float(np.abs(want[key]).max()) or 1.0
+        np.testing.assert_allclose(grads[key] / scale, want[key] / scale,
+                                   atol=3e-2, err_msg=key)
