@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import threading
 import time
 
@@ -96,6 +97,25 @@ def _first_call_span(program, kind, cause, step):
     with _monitor.span("executor.first_call", step=step, program=program,
                        kind=kind, cause=cause), _monitor.compiling(program):
         yield
+
+
+def _first_call_fn(fn, *args):
+    """``fn(*args)`` for the call that traces and lowers ``fn``, on a
+    data-stack chunk of its own. CPython keeps a thread's frames in
+    16 KiB chunks and unmaps a chunk when the frame at its base returns,
+    so a call that does not fit the chunk in use costs an mmap and a
+    munmap every time it is made. jax's trace and lowering make millions
+    of calls 1,500 to 2,000 slots under ``fn``, which is where the
+    harness's and the executor's own frames put the first chunk's end:
+    40 slots more of executor frame took ``tbase-train-dp4``'s lowering
+    from 17.3 to 39.7 s (PERF.md section 6, PR 43). A frame of 8,000
+    slots never fits a chunk in use, and the 128 KiB chunk made for it
+    has 8,000 slots left under it, three times what a first call was
+    seen to reach."""
+    return fn(*args)
+
+
+_first_call_fn.__code__ = _first_call_fn.__code__.replace(co_stacksize=8000)
 
 
 def _stage_feeds(feed_vals):
@@ -270,6 +290,42 @@ def scope_guard(scope: Scope):
             _scope_tls.stack.pop()
 
 
+@dataclasses.dataclass(slots=True)
+class _Call:
+    """What a prepare decided (``Executor._run``: a step; ``_run_steps``:
+    a window), as ``Executor._dispatch`` runs it."""
+
+    program: Any
+    scope: "Scope"
+    fn: Any                     # the entry's jitted function
+    lowered: Any
+    feeds: Dict[str, Any]       # as fn takes them: a step's feed dict, or
+    #                             a window's, stacked along a leading axis
+    fetch_names: List[str]      # the caller's (the numerics bundle apart)
+    nplan: Any                  # the numerics plan whose bundle rides last
+    start: int                  # the call's (first) step index
+    outcome: str                # _cache_entry's: "hit" | "miss"
+    evictions: int
+    compile_ms: Optional[float]
+    fp: Any                     # the fingerprint, the compile report's key
+    first: Any                  # the context fn runs in (_first_call)
+    return_numpy: bool
+    async_fetch: bool
+    tele: bool                  # telemetry as the call found it
+    t_run0: float               # the call's start (0.0 with telemetry off)
+    ph: bool                    # the phase plane is on
+    sampled: bool               # and samples this call: it alone blocks
+    compiled: Any = None        # the CompiledProgram a step came as
+    steps: Optional[int] = None  # a window's length; None: a step
+    t_f0: float = 0.0           # the feed phase's marks, where the
+    t_f1: float = 0.0           # prepare staged the feeds (a window)
+
+    @property
+    def kind(self) -> str:
+        """The label of the call's record, compile report and spans."""
+        return "step" if self.steps is None else "window"
+
+
 def _prng_impl():
     """Program-level PRNG implementation. On TPU, threefry random-bit
     generation is slow enough to dominate dropout (ablation: 21.5ms of a
@@ -345,6 +401,7 @@ class Executor:
 
     def _run(self, program, feed, fetch_list, scope, return_numpy,
              use_program_cache, async_fetch):
+        """What a step is: run()'s prepare. _dispatch runs the call."""
         from paddle_tpu.compiler import CompiledProgram
 
         tele = _monitor.enabled()
@@ -431,207 +488,22 @@ class Executor:
             else:
                 entry, compile_ms = self._timed_build(build, program)
                 outcome, evictions = "miss", 0
-            cache_hit = outcome != "miss"
-            fn, lowered = entry
-            first = _NOT_FIRST if cache_hit else self._first_call(
-                "step", program, self._step,
+            start = self._step
+            first = _NOT_FIRST if outcome != "miss" else self._first_call(
+                "step", program, start,
                 (program.version, getattr(program, "_amp", False),
                  compiled._uid if compiled is not None else 0, sig,
                  tuple(run_fetch_names), scope._uid))
-
-        with _monitor.span("executor.state"):
-            state = self._gather_state(scope, lowered)
-            if compiled is not None and outcome != "hit":
-                state = compiled.commit_state(scope, state)
-            # typed base key (rbg on TPU), created ONCE per (seed, impl): the
-            # per-step fold_in happens INSIDE the compiled step (the step index
-            # rides along as a scalar arg) instead of costing two extra
-            # host-side jit dispatches per step.
-            base_key = self._base_key_for(program)
-            step_idx = self._step
-            self._step += 1
-
-            # Phase attribution timestamps (perf_counter; 0.0 = not reached,
-            # so a step that failed before commit logs a record without
-            # phases — truncated phase durations would skew the verdict
-            # window). Phases: feed = host->device staging, dispatch =
-            # Python + tracing overhead (both segments around the staged
-            # feed), device = delta to block_until_ready, fetch =
-            # device->host + decode in _commit. Gated separately from
-            # `tele`: the device phase costs a per-step sync, and the
-            # step_phases / step_phases_every_n flags let metrics-only (or
-            # merely steady-state) telemetry keep async dispatch — only a
-            # SAMPLED step pays the honest-device-timing block_until_ready.
             ph = tele and _monitor.phases_active()
-            sampled = ph and _monitor.phases_sampled(step_idx)
-            t_f0 = t_f1 = t_c1 = t_b1 = t_x0 = t_x1 = 0.0
-            if sampled:
-                t_f0 = time.perf_counter()
-            if compiled is not None:
-                state, feed_vals = compiled.shard_inputs(state, feed_vals)
-            if sampled:
-                if compiled is None:
-                    # stage feeds explicitly so the feed phase measures the
-                    # real host->device transfer instead of hiding it inside
-                    # the jitted call's dispatch (the transfer happens either
-                    # way; committed default-device arrays are what jit would
-                    # produce; an already-device-resident feed dict skips
-                    # staging entirely — see _stage_feeds). The compiled
-                    # path keeps shard_inputs as its staging step — an extra
-                    # unsharded device_put would fight the jit's
-                    # in_shardings.
-                    feed_vals = _stage_feeds(feed_vals)
-                jax.block_until_ready(list(feed_vals.values()))
-                t_f1 = time.perf_counter()
-
-        # Ops needing explicit collectives (ring attention, sharded tables)
-        # read the SPMD context at trace time, which happens inside the
-        # first jitted call.
-        from paddle_tpu.core import interp as _interp
-
-        strategy = compiled._strategy if compiled is not None else None
-        rec = None
-        if tele:
-            strat_label = _strategy_id(strategy)
-            _M_STEPS.inc()
-            feed_bytes = _sum_nbytes(feed_vals.values())
-            _M_FEED_BYTES.inc(feed_bytes)
-            if not cache_hit and _monitor.compile_reports_active():
-                # fresh compile: produce the cost/memory report BEFORE
-                # the step executes (lowering only reads avals; after
-                # the call the donated state buffers are deleted). The
-                # SPMD context scope matters: collective ops read it at
-                # trace time.
-                with _interp.spmd_ctx_scope(strategy):
-                    _monitor.record_compile_report(
-                        lowering.build_compile_report(
-                            fn, lowered,
-                            (state, feed_vals, base_key,
-                             np.uint32(step_idx)),
-                            program=program, kind="step",
-                            compile_ms=compile_ms,
-                            strategy=strat_label,
-                            cache_key=fp))
-            if _monitor.step_records_active():
-                rec = {
-                    "kind": "step",
-                    "step": step_idx,
-                    "compile_ms": compile_ms,
-                    "cache": outcome,
-                    "evictions": evictions,
-                    "feed_bytes": feed_bytes,
-                    "fetch_bytes": 0,
-                    "nan_check": None,
-                    "strategy": strat_label,
-                }
-                if ph:
-                    # phase plane on: mark whether THIS step paid the
-                    # honest sync (sampled=False walls are host-only —
-                    # /trace and the fleet digest medians filter on it)
-                    rec["sampled"] = sampled
-        # Roofline plane (roofline.py): profiles ride phase-SAMPLED
-        # steps — the honest device phase below supplies device time;
-        # take_sample counts them PER PROGRAM so the cadence is every
-        # Nth one, whatever else interleaves. Off (the default) this is
-        # the short-circuited `sampled` check.
-        roof = sampled and _roofline.take_sample(program)
-        cap = _roofline.begin_capture() if roof else None
-        try:
-            with _interp.spmd_ctx_scope(strategy), \
-                    _monitor.span("executor.run_step"), first:
-                try:
-                    _F_STEP.hit()
-                    fetches, new_state = fn(state, feed_vals, base_key,
-                                            np.uint32(step_idx))
-                except Exception as e:
-                    self._drop_donated(scope, lowered)
-                    _monitor.maybe_record_oom(e, program=program,
-                                              phase="run")
-                    raise
-            if sampled:
-                t_c1 = time.perf_counter()
-                # device phase: drain the async dispatch queue. A
-                # deferred device error surfaces here instead of inside
-                # _commit — same donated-buffer hygiene as a failed call.
-                try:
-                    jax.block_until_ready((fetches, new_state))
-                except Exception as e:
-                    self._drop_donated(scope, lowered)
-                    _monitor.maybe_record_oom(e, program=program,
-                                              phase="run")
-                    raise
-                t_b1 = time.perf_counter()
-            bundle = None
-            if nplan is not None:
-                bundle, fetches = fetches[-1], fetches[:-1]
-            try:
-                if sampled:
-                    t_x0 = time.perf_counter()
-                try:
-                    with _monitor.span("executor.commit"):
-                        out = self._commit(
-                            scope, fetch_names, fetches, new_state,
-                            return_numpy, rec, async_fetch=async_fetch,
-                            error_cb=self._fetch_error_cb(
-                                scope, lowered, program)
-                            if async_fetch else None)
-                        if sampled:  # only a COMMITTED step is attributed
-                            t_x1 = time.perf_counter()
-                        # the call's (donated) input state dies inside
-                        # the span (and after the fetch phase's mark, which
-                        # times _commit alone as it always has): releasing
-                        # its buffers is host time of the call, not an
-                        # unnamed tail of it
-                        state = None
-                except Exception as e:
-                    # with phases off/unsampled there is no pre-commit
-                    # block_until_ready: an async-dispatched device
-                    # failure surfaces HERE, in the commit transfer —
-                    # same donated-buffer hygiene + OOM hook as the
-                    # dispatch/device sites above
-                    self._drop_donated(scope, lowered)
-                    _monitor.maybe_record_oom(e, program=program,
-                                              phase="run")
-                    raise
-                return out
-            finally:
-                # decoded even when check_nan_inf raises — the provenance
-                # record is most valuable exactly then
-                if bundle is not None and _numerics.should_sample(step_idx):
-                    summary = _numerics.decode(program, nplan, bundle,
-                                               step_idx, kind="step")
-                    if rec is not None:
-                        rec["numerics"] = summary
-        finally:
-            # logged even when the step raises (NaN scan, device/runtime
-            # error): the crashed step's record is the one an operator
-            # needs for postmortem, and must be the last line of the log
-            if roof:
-                if t_b1 > 0.0:  # device drain completed: honest timing
-                    _roofline.note_step(
-                        program, lowered,
-                        device_s=t_b1 - t_c1,
-                        wall_s=time.perf_counter() - t_run0,
-                        capture=cap)
-                elif cap is not None:  # failed step: abandon the capture
-                    cap.stop()
-                    cap.cleanup()
-            if tele:
-                # watermarks read AFTER the step (success or failure):
-                # the post-step high-water is the number an OOM
-                # post-mortem wants; self-gating on the sampling period
-                _monitor.sample_device_memory(step_idx)
-            if rec is not None:
-                rec["wall_ms"] = (time.perf_counter() - t_run0) * 1e3
-                if t_x1 > 0.0:  # phases only for steps that completed
-                    self._attribute_phases(
-                        rec, step_idx, t_run0, t_f0, t_f1, t_c1, t_b1,
-                        t_x0, t_x1, scored=(outcome == "hit"))
-                elif ph:
-                    # unsampled (or failed) step: its input waits must
-                    # not pile into the next sampled step's verdict
-                    _monitor.discard_input_wait()
-                _monitor.log_step(rec)
+            call = _Call(
+                program=program, compiled=compiled, scope=scope, fn=entry[0],
+                lowered=entry[1], feeds=feed_vals, fetch_names=fetch_names,
+                nplan=nplan, start=start, outcome=outcome,
+                evictions=evictions, compile_ms=compile_ms, fp=fp,
+                first=first, return_numpy=return_numpy,
+                async_fetch=async_fetch, tele=tele, t_run0=t_run0, ph=ph,
+                sampled=ph and _monitor.phases_sampled(start))
+        return self._dispatch(call)
 
     def run_steps(
         self,
@@ -661,6 +533,8 @@ class Executor:
 
     def _run_steps(self, program, feed_list, steps, fetch_list, scope,
                    return_numpy, async_fetch):
+        """What a window is: run_steps()'s prepare. _dispatch runs the
+        call."""
         from paddle_tpu.compiler import CompiledProgram
 
         if isinstance(program, CompiledProgram):
@@ -683,6 +557,8 @@ class Executor:
                 for f in fetch_list
             ]
             feed_names = sorted(feed_list[0])
+            steps = int(steps)
+            start = self._step
             from paddle_tpu import flags as _flags_mod
 
             # Per-step in-graph finiteness tracking (core/lowering.py): the
@@ -708,12 +584,12 @@ class Executor:
             # alternating rotations stay staged — the next rotation's
             # device_put overlaps the current window's device work instead
             # of thrashing a single slot.
-            # Phase marks (see run()): the stacking below IS the window's
-            # feed phase — device_put of the whole window dominates host
-            # cost, and the breakdown must show it.
+            # Phase marks (see _dispatch): the stacking below IS the
+            # window's feed phase — device_put of the whole window dominates
+            # host cost, and the breakdown must show it.
             ph = tele and _monitor.phases_active()
-            sampled = ph and _monitor.phases_sampled(self._step, int(steps))
-            t_f0 = t_f1 = t_c1 = t_b1 = t_x0 = t_x1 = 0.0
+            sampled = ph and _monitor.phases_sampled(start, steps)
+            t_f0 = t_f1 = 0.0
             if sampled:
                 t_f0 = time.perf_counter()
             arrs = [fb[k] for fb in feed_list for k in feed_names]
@@ -754,7 +630,7 @@ class Executor:
                 (k, tuple(v.shape), str(v.dtype)) for k, v in sorted(
                     stacked.items())
             )
-            # Canonical fingerprint (see run()); the window variant folds in
+            # Canonical fingerprint (see _run); the window variant folds in
             # the feed-rotation length and the nan-track flavor. ``steps``
             # rides the KEY, not the fingerprint content hash: it is a static
             # argument of the jit, so each value is a compile of its own and
@@ -767,7 +643,7 @@ class Executor:
             fp = _fingerprint.fingerprint_for(
                 ident, program, feed_sig=sig, fetch_names=run_fetch_names,
                 extra=("multi", len(feed_list), bool(nan_track)))
-            key = (fp, scope._uid, int(steps))
+            key = (fp, scope._uid, steps)
             if staged_key is not None and staged_key in self._staged:
                 # eviction coupling: remember which compiled entry owns the
                 # staged window (see _cache_entry)
@@ -781,10 +657,10 @@ class Executor:
                         lowered)
 
             if _analysis.lint_active():
-                # static verifier before the window's first compile (run()
-                # twin; the whole-window donation/dataflow semantics are the
-                # same single-step block repeated). Gated on the verifier's
-                # own fingerprint cache — see run().
+                # static verifier before the window's first compile (the
+                # whole-window donation/dataflow semantics are the same
+                # single-step block repeated). Gated on the verifier's own
+                # fingerprint cache — see _run.
                 _analysis.lint_before_compile(
                     program, feed_names, run_fetch_names,
                     site="executor.run_steps")
@@ -796,81 +672,154 @@ class Executor:
                     {k: tuple(v.shape[1:]) for k, v in stacked.items()})
             entry, outcome, evictions, compile_ms = self._cache_entry(
                 key, build, program)
-            cache_hit = outcome != "miss"
-            fn, lowered = entry
-            first = _NOT_FIRST if cache_hit else self._first_call(
-                "window", program, self._step,
+            first = _NOT_FIRST if outcome != "miss" else self._first_call(
+                "window", program, start,
                 (program.version, getattr(program, "_amp", False), 0,
-                 (sig, len(feed_list), int(steps), nan_track),
+                 (sig, len(feed_list), steps, nan_track),
                  tuple(run_fetch_names), scope._uid))
+            call = _Call(
+                program=program, scope=scope, fn=entry[0], lowered=entry[1],
+                feeds=stacked, fetch_names=fetch_names, nplan=nplan,
+                start=start, steps=steps, outcome=outcome,
+                evictions=evictions, compile_ms=compile_ms, fp=fp,
+                first=first, return_numpy=return_numpy,
+                async_fetch=async_fetch, tele=tele, t_run0=t_run0, ph=ph,
+                sampled=sampled, t_f0=t_f0, t_f1=t_f1)
+        return self._dispatch(call)
+
+    # --- the one body of run() and run_steps() ---
+
+    def _dispatch(self, call):
+        """Run a prepared call, a step or a window, from the
+        ``executor.state`` span to its step-log record."""
+        # Ops needing explicit collectives (ring attention, sharded tables)
+        # read the SPMD context at trace time, which happens inside the
+        # first jitted call.
+        from paddle_tpu.core import interp as _interp
+
+        program, compiled, scope = call.program, call.compiled, call.scope
+        fn, lowered, feeds, nplan = (call.fn, call.lowered, call.feeds,
+                                     call.nplan)
+        start, steps = call.start, call.steps
+        n = 1 if steps is None else steps
+        tele, t_run0 = call.tele, call.t_run0
+        # Phase attribution timestamps (perf_counter; 0.0 = not reached,
+        # so a call that failed before commit logs a record without
+        # phases — truncated phase durations would skew the verdict
+        # window). Phases: feed = host->device staging, dispatch =
+        # Python + tracing overhead (both segments around the staged
+        # feed), device = delta to block_until_ready, fetch =
+        # device->host + decode in _commit. Gated separately from
+        # `tele`: the device phase costs a per-call sync, and the
+        # step_phases / step_phases_every_n flags let metrics-only (or
+        # merely steady-state) telemetry keep async dispatch — only a
+        # SAMPLED call pays the honest-device-timing block_until_ready.
+        ph, sampled = call.ph, call.sampled
+        t_f0, t_f1 = call.t_f0, call.t_f1
+        t_c1 = t_b1 = t_x0 = t_x1 = 0.0
         with _monitor.span("executor.state"):
             state = self._gather_state(scope, lowered)
-        base_key = self._base_key_for(program)
-        start = self._step
-        self._step += int(steps)
+            if compiled is not None and call.outcome != "hit":
+                state = compiled.commit_state(scope, state)
+            # typed base key (rbg on TPU), created ONCE per (seed, impl): the
+            # per-step fold_in happens INSIDE the compiled call (the step
+            # index rides along as a scalar arg) instead of costing two extra
+            # host-side jit dispatches per step.
+            base_key = self._base_key_for(program)
+            self._step += n
+            if steps is None:
+                # a step's feed phase (a window's is its prepare's stacking)
+                if sampled:
+                    t_f0 = time.perf_counter()
+                if compiled is not None:
+                    state, feeds = compiled.shard_inputs(state, feeds)
+                if sampled:
+                    if compiled is None:
+                        # stage feeds explicitly so the feed phase measures
+                        # the real host->device transfer instead of hiding it
+                        # inside the jitted call's dispatch (the transfer
+                        # happens either way; committed default-device arrays
+                        # are what jit would produce; an already-device-
+                        # resident feed dict skips staging entirely — see
+                        # _stage_feeds). The compiled path keeps shard_inputs
+                        # as its staging step — an extra unsharded device_put
+                        # would fight the jit's in_shardings.
+                        feeds = _stage_feeds(feeds)
+                    jax.block_until_ready(list(feeds.values()))
+                    t_f1 = time.perf_counter()
+        # what fn takes after the state and the feeds; a window's length is
+        # a static argument of its jit
+        tail = (base_key, np.uint32(start)) + (
+            () if steps is None else (steps,))
+        strategy = compiled._strategy if compiled is not None else None
         rec = None
         if tele:
-            _M_STEPS.inc(int(steps))
-            feed_bytes = _sum_nbytes(stacked.values())
+            strat_label = _strategy_id(strategy)
+            _M_STEPS.inc(n)
+            feed_bytes = _sum_nbytes(feeds.values())
             _M_FEED_BYTES.inc(feed_bytes)
-            if not cache_hit and _monitor.compile_reports_active():
-                _monitor.record_compile_report(
-                    lowering.build_compile_report(
-                        fn, lowered,
-                        (state, stacked, base_key, np.uint32(start),
-                         int(steps)),
-                        program=program, kind="window",
-                        compile_ms=compile_ms, strategy=None,
-                        cache_key=fp, window_steps=int(steps)))
+            if call.outcome == "miss" and _monitor.compile_reports_active():
+                # fresh compile: produce the cost/memory report BEFORE
+                # the call executes (lowering only reads avals; after
+                # the call the donated state buffers are deleted). The
+                # SPMD context scope matters: collective ops read it at
+                # trace time.
+                with _interp.spmd_ctx_scope(strategy):
+                    _monitor.record_compile_report(
+                        lowering.build_compile_report(
+                            fn, lowered, (state, feeds, *tail),
+                            program=program, kind=call.kind,
+                            compile_ms=call.compile_ms,
+                            strategy=strat_label, cache_key=call.fp,
+                            window_steps=steps))
             if _monitor.step_records_active():
-                rec = {
-                    "kind": "window",
-                    "step": start,
-                    "steps": int(steps),
-                    "compile_ms": compile_ms,
-                    "cache": outcome,
-                    "evictions": evictions,
-                    "feed_bytes": feed_bytes,
-                    "fetch_bytes": 0,
-                    "nan_check": None,
-                    "strategy": None,
-                }
+                rec = {"kind": call.kind, "step": start}
+                if steps is not None:
+                    rec["steps"] = steps
+                rec.update(
+                    compile_ms=call.compile_ms, cache=call.outcome,
+                    evictions=call.evictions, feed_bytes=feed_bytes,
+                    fetch_bytes=0, nan_check=None, strategy=strat_label)
                 if ph:
+                    # phase plane on: mark whether THIS call paid the
+                    # honest sync (sampled=False walls are host-only —
+                    # /trace and the fleet digest medians filter on it)
                     rec["sampled"] = sampled
-        # roofline plane: window samples ride phase-sampled calls (see
-        # run(), one take_sample per window); the profile covers the
-        # whole window's steps
+        # Roofline plane (roofline.py): profiles ride phase-SAMPLED
+        # calls — the honest device phase below supplies device time;
+        # take_sample counts them PER PROGRAM (a window is one sample and
+        # its profile covers all its steps) so the cadence is every
+        # Nth one, whatever else interleaves. Off (the default) this is
+        # the short-circuited `sampled` check.
         roof = sampled and _roofline.take_sample(program)
         cap = _roofline.begin_capture() if roof else None
-        # under check_nan_inf the window tracks per-step finiteness
-        # IN-GRAPH (track_nonfinite): the compiled loop stays one
-        # dispatch, yet a failure names the exact step inside it
         try:
-            first_bad = None
-            with _monitor.span("executor.run_step"), first:
+            with _interp.spmd_ctx_scope(strategy), \
+                    _monitor.span("executor.run_step"), call.first:
                 try:
                     _F_STEP.hit()
-                    if nan_track:
-                        fetches, new_state, first_bad = fn(
-                            state, stacked, base_key, np.uint32(start),
-                            int(steps))
+                    if call.outcome == "miss":
+                        fetches, new_state, *bad = _first_call_fn(
+                            fn, state, feeds, *tail)
                     else:
-                        fetches, new_state = fn(state, stacked, base_key,
-                                                np.uint32(start),
-                                                int(steps))
+                        fetches, new_state, *bad = fn(state, feeds, *tail)
                 except Exception as e:
-                    self._drop_donated(scope, lowered)
-                    _monitor.maybe_record_oom(e, program=program,
-                                              phase="run")
+                    self._failed(call, e)
                     raise
+            # under check_nan_inf a window tracks per-step finiteness
+            # IN-GRAPH (track_nonfinite) and returns the first bad step's
+            # index: the compiled loop stays one dispatch, yet a failure
+            # names the exact step inside it
+            first_bad = bad[0] if bad else None
             if sampled:
                 t_c1 = time.perf_counter()
+                # device phase: drain the async dispatch queue. A
+                # deferred device error surfaces here instead of inside
+                # _commit — same donated-buffer hygiene as a failed call.
                 try:
                     jax.block_until_ready((fetches, new_state, first_bad))
                 except Exception as e:
-                    self._drop_donated(scope, lowered)
-                    _monitor.maybe_record_oom(e, program=program,
-                                              phase="run")
+                    self._failed(call, e)
                     raise
                 t_b1 = time.perf_counter()
             bundle = None
@@ -882,14 +831,14 @@ class Executor:
                 try:
                     with _monitor.span("executor.commit"):
                         out = self._commit(
-                            scope, fetch_names, fetches, new_state,
-                            return_numpy, rec, nan_first_bad=first_bad,
-                            window=(start, int(steps)),
-                            async_fetch=async_fetch,
+                            scope, call.fetch_names, fetches, new_state,
+                            call.return_numpy, rec, nan_first_bad=first_bad,
+                            window=None if steps is None else (start, steps),
+                            async_fetch=call.async_fetch,
                             error_cb=self._fetch_error_cb(
                                 scope, lowered, program)
-                            if async_fetch else None)
-                        if sampled:  # only a COMMITTED window is attributed
+                            if call.async_fetch else None)
+                        if sampled:  # only a COMMITTED call is attributed
                             t_x1 = time.perf_counter()
                         # the call's (donated) input state dies inside
                         # the span (and after the fetch phase's mark, which
@@ -903,48 +852,62 @@ class Executor:
                     # failure surfaces HERE, in the commit transfer —
                     # same donated-buffer hygiene + OOM hook as the
                     # dispatch/device sites above
-                    self._drop_donated(scope, lowered)
-                    _monitor.maybe_record_oom(e, program=program,
-                                              phase="run")
+                    self._failed(call, e)
                     raise
                 return out
             finally:
-                if bundle is not None and _numerics.should_sample_window(
-                        start, int(steps)):
-                    # the bundle holds the LAST step's stats; nan_step
-                    # (when the in-graph tracker fired) names the first
-                    # bad step of the window
-                    last = start + int(steps) - 1
+                # decoded even when check_nan_inf raises — the provenance
+                # record is most valuable exactly then. A window's bundle
+                # holds its LAST step's stats; nan_step (when the in-graph
+                # tracker fired) names its first bad step.
+                if bundle is not None and _numerics.should_sample(start, n):
                     summary = _numerics.decode(
-                        program, nplan, bundle, last, kind="window",
+                        program, nplan, bundle, start + n - 1,
+                        kind=call.kind,
                         nan_step=rec.get("nan_step") if rec else None)
                     if rec is not None:
                         rec["numerics"] = summary
         finally:
-            # logged even when the window raises (see run())
+            # logged even when the call raises (NaN scan, device/runtime
+            # error): the crashed call's record is the one an operator
+            # needs for postmortem, and must be the last line of the log
             if roof:
-                if t_b1 > 0.0:
+                if t_b1 > 0.0:  # device drain completed: honest timing
                     _roofline.note_step(
-                        program, lowered, steps=int(steps),
+                        program, lowered, steps=n,
                         device_s=t_b1 - t_c1,
                         wall_s=time.perf_counter() - t_run0,
                         capture=cap)
-                elif cap is not None:
+                elif cap is not None:  # failed call: abandon the capture
                     cap.stop()
                     cap.cleanup()
             if tele:
-                _monitor.sample_device_memory(start, int(steps))
+                # watermarks read AFTER the call (success or failure):
+                # the post-step high-water is the number an OOM
+                # post-mortem wants; self-gating on the sampling period
+                _monitor.sample_device_memory(start, n)
             if rec is not None:
                 rec["wall_ms"] = (time.perf_counter() - t_run0) * 1e3
-                if t_x1 > 0.0:  # whole-window totals, one verdict entry
+                if t_x1 > 0.0:  # phases only for calls that completed (a
+                    # window's are whole-window totals, one verdict entry)
                     self._attribute_phases(
                         rec, start, t_run0, t_f0, t_f1, t_c1, t_b1,
-                        t_x0, t_x1, steps=int(steps),
-                        scored=(outcome == "hit"))
+                        t_x0, t_x1, steps=n,
+                        scored=(call.outcome == "hit"))
                 elif ph:
-                    # unsampled (or failed) window: see run()
+                    # unsampled (or failed) call: its input waits must
+                    # not pile into the next sampled call's verdict
                     _monitor.discard_input_wait()
                 _monitor.log_step(rec)
+
+    def _failed(self, call, e):
+        """A call's device failure, wherever it surfaced (the dispatch,
+        the sampled drain, the commit's transfer): the donated state
+        buffers it consumed are dropped and an OOM leaves its forensics.
+        The site re-raises. (A deferred fetch's twin, _fetch_error_cb,
+        outlives the call and so holds no record of it: no feeds.)"""
+        self._drop_donated(call.scope, call.lowered)
+        _monitor.maybe_record_oom(e, program=call.program, phase="run")
 
     # --- shared plumbing for run()/run_steps() ---
 

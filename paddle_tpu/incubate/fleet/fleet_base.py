@@ -298,7 +298,12 @@ class Fleet:
         requires; epochs are keyed by call count). A TimeoutError is
         NOT retryable in place, and a replacement worker cannot join an
         existing world mid-sequence: both must go through a fresh
-        rendezvous (new coord world), as the recovery protocol does."""
+        rendezvous (new coord world), as the recovery protocol does.
+        Survivors cross the staleness threshold at different polls: one
+        that leaves on its own reading while it hosts the coordination
+        server fails every peer still polling here (``OSError: heartbeat
+        failed``). Pass the result through ``settle_dead`` first: the
+        host then leaves after every survivor's ack."""
         if self._client is None:
             return []
         t_wait0 = _time.perf_counter()

@@ -115,16 +115,12 @@ def _sync_every_n(value):
     _every_n = max(1, int(value))
 
 
-def should_sample(step: int) -> bool:
-    """Whether this executor step's bundle gets decoded (the
-    ``numerics_every_n_steps`` sampling gate)."""
-    return step % _every_n == 0
-
-
-def should_sample_window(start: int, steps: int) -> bool:
-    """A compiled window samples once when ANY of its steps lands on the
-    period (the window's single bundle stands in for all of them)."""
-    return (start + steps - 1) // _every_n > (start - 1) // _every_n
+def should_sample(step: int, steps: int = 1) -> bool:
+    """Whether a call's bundle gets decoded (the
+    ``numerics_every_n_steps`` sampling gate): a compiled window samples
+    once when ANY of its steps lands on the period (its single bundle
+    stands in for all of them)."""
+    return (step + steps - 1) // _every_n > (step - 1) // _every_n
 
 
 # ---------------------------------------------------------------------------

@@ -7,9 +7,11 @@ start of step PT_KILL_STEP (no farewell — just process exit, so only
 its heartbeat going stale reveals the death). The survivors' per-step
 ``fleet.barrier_or_dead`` (liveness-guarded barrier over csrc/coord.cc
 op 'L') returns the dead id instead of hanging in the next psum; they
-agree on the shrunk world (surviving old ranks in order), and each
+agree on the dead set (``fleet.settle_dead``: rank 0, which hosts the
+generation's coordination server, leaves only when every survivor has
+the set), plan the shrunk world (surviving old ranks in order), and each
 re-execs itself as generation 1 with the pre-provisioned recovery
-endpoints.
+endpoints (``fleet.reexec_resized``).
 
 Generation 1: 3 workers rendezvous fresh, restore the checkpoint, and
 finish the remaining steps on 3-way shards of the SAME global batches —
@@ -24,7 +26,6 @@ Run (harness: tests/test_fleet_recovery.py):
 
 import json
 import os
-import sys
 
 import jax
 
@@ -94,30 +95,6 @@ def build():
     return main, startup, loss
 
 
-def _reexec_shrunk(dead_ids, resume_step):
-    """Agree on the shrunk world and re-exec as generation 1."""
-    n = fleet.worker_num()
-    me = fleet.worker_index()
-    dead_ranks = {int(d.split("-")[1]) for d in dead_ids}
-    survivors = [r for r in range(n) if r not in dead_ranks]
-    new_rank = survivors.index(me)
-    host = os.environ["PT_COORD_ENDPOINT"].rsplit(":", 1)[0]
-    env = dict(os.environ)
-    env.update({
-        "PT_TRAINER_ID": str(new_rank),
-        "PT_TRAINERS": str(len(survivors)),
-        "PT_COORD_ENDPOINT": f"{host}:{os.environ['PT_RECOVER_PORT']}",
-        "PT_JAX_COORD_ENDPOINT":
-            f"{host}:{os.environ['PT_RECOVER_JAX_PORT']}",
-        "PT_GEN": "1",
-        "PT_RESUME_STEP": str(resume_step),
-        "PT_DEAD_SEEN": ",".join(sorted(dead_ids)),
-    })
-    fleet.stop_worker()
-    os.execve(sys.executable, [sys.executable, os.path.abspath(__file__)],
-              env)
-
-
 def main():
     gen = int(os.environ.get("PT_GEN", "0"))
     kill_rank = int(os.environ.get("PT_KILL_RANK", "-1"))
@@ -145,7 +122,22 @@ def main():
             os._exit(1)  # abrupt death: no farewell, heartbeat goes stale
         dead = fleet.barrier_or_dead(f"step{i}-g{gen}", max_age_ms=1500)
         if dead:
-            _reexec_shrunk(dead, resume_step=i)
+            # The survivors cross the staleness threshold at different
+            # polls. Rank 0 hosts this generation's coordination server
+            # (and jax's service): were it to re-exec on its own reading,
+            # a survivor still polling that server would die of the
+            # closed connection and generation 1 would wait for it for
+            # ever. settle_dead gives every survivor ONE dead set and
+            # has rank 0 collect their acks before it leaves; a survivor
+            # asks the server nothing after its ack.
+            dead = fleet.settle_dead(dead, max_age_ms=1500)
+            host = os.environ["PT_COORD_ENDPOINT"].rsplit(":", 1)[0]
+            fleet.reexec_resized(
+                fleet.plan_resize(dead),
+                coord_endpoint=f"{host}:{os.environ['PT_RECOVER_PORT']}",
+                jax_endpoint=f"{host}:{os.environ['PT_RECOVER_JAX_PORT']}",
+                extra_env={"PT_RESUME_STEP": i,
+                           "PT_DEAD_SEEN": ",".join(dead)})
         x, y = batches[i]
         xs = x[rank * shard:(rank + 1) * shard]
         ys = y[rank * shard:(rank + 1) * shard]

@@ -297,23 +297,31 @@ def test_run_steps_async_fetch():
     assert float(np.asarray(out[0])) == float(np.asarray(ref[0]))
 
 
-def test_deferred_fetch_error_runs_hygiene_and_oom_forensics():
+@pytest.mark.parametrize("kind", ["step", "window"])
+def test_deferred_fetch_error_runs_hygiene_and_oom_forensics(kind):
     """A device failure surfacing only at the async fetch boundary
     (drilled via the executor.fetch fault site) must run the same
     donated-buffer drop + OOM forensics as the synchronous commit
-    sites, then re-raise — and leave the committed state usable."""
+    sites, then re-raise — and leave the committed state usable. A
+    step's fetches and a window's."""
     flags.set_flags({"telemetry": True})
     main, startup, loss = _tiny_program()
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     feed = {"x": np.ones((2, 8), np.float32)}
+
+    def call(**kw):
+        if kind == "step":
+            return exe.run(main, feed=feed, fetch_list=[loss], **kw)
+        return exe.run_steps(main, feed_list=[feed], steps=2,
+                             fetch_list=[loss], **kw)
+
     with fluid.scope_guard(scope):
         exe.run(startup)
-        exe.run(main, feed=feed, fetch_list=[loss])  # warm compile
+        call()  # warm compile
         faults.arm("executor.fetch:raise(RESOURCE_EXHAUSTED synthetic "
                    "deferred device OOM)@1")
-        out = exe.run(main, feed=feed, fetch_list=[loss],
-                      async_fetch=True)
+        out = call(async_fetch=True)
         with pytest.raises(faults.InjectedFault):
             out.wait()
         faults.disarm()
@@ -321,7 +329,7 @@ def test_deferred_fetch_error_runs_hygiene_and_oom_forensics():
         assert recs and recs[-1]["phase"] == "fetch"
         assert "RESOURCE_EXHAUSTED" in recs[-1]["error"]
         # state committed before the fetch: training continues cleanly
-        nxt = exe.run(main, feed=feed, fetch_list=[loss])
+        nxt = call()
         assert np.isfinite(np.asarray(nxt[0])).all()
 
 

@@ -168,9 +168,11 @@ def test_staged_window_lru_keeps_alternating_rotations():
         assert len(exe._staged) == exe.STAGED_WINDOW_CAPACITY
 
 
-def test_failing_step_still_logs_a_record(tmp_path):
-    """A raising step (here: NaN scan) must still append its step-log
-    record — the crashed step is the record a postmortem needs."""
+@pytest.mark.parametrize("kind", ["step", "window"])
+def test_failing_step_still_logs_a_record(tmp_path, kind):
+    """A raising call (here: NaN scan), a step's or a window's, must
+    still append its step-log record — the crashed step is the record a
+    postmortem needs."""
     import json
 
     path = tmp_path / "s.jsonl"
@@ -178,22 +180,64 @@ def test_failing_step_still_logs_a_record(tmp_path):
     main, startup, loss = _build()
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.CPUPlace())
+
+    def call(feed):
+        if kind == "step":
+            return exe.run(main, feed=feed, fetch_list=[loss])
+        return exe.run_steps(main, feed_list=[feed], steps=2,
+                             fetch_list=[loss])
+
     try:
         with fluid.scope_guard(scope):
             exe.run(startup)
-            exe.run(main, feed=_feed(), fetch_list=[loss])
+            call(_feed())
             with pytest.raises(FloatingPointError):
-                exe.run(main,
-                        feed={"x": np.full((4, 8), np.nan, np.float32)},
-                        fetch_list=[loss])
+                call({"x": np.full((4, 8), np.nan, np.float32)})
     finally:
         flags.set_flags({"check_nan_inf": False, "step_log_path": ""})
     recs = [json.loads(l) for l in path.read_text().splitlines()]
     for r in recs:
         monitor.validate_step_record(r)
     assert len(recs) == 3
+    assert [r["kind"] for r in recs] == ["step", kind, kind]
     assert recs[1]["nan_check"] == "ok"
     assert recs[2]["nan_check"] == "fail" and recs[2]["wall_ms"] > 0
+    if kind == "window":  # the in-graph tracker names the first bad step
+        assert recs[2]["nan_step"] == recs[2]["step"]
+
+
+def test_a_step_and_a_window_log_the_same_record(tmp_path):
+    """One body writes both records: a window's holds what a step's
+    does and ``steps``, with phases on (sampled calls) and off."""
+    import json
+
+    path = tmp_path / "s.jsonl"
+    flags.set_flags({"step_log_path": str(path), "step_phases_every_n": 1})
+    main, startup, loss = _build()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    try:
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+            for phases in (True, False):
+                flags.set_flags({"step_phases": phases})
+                for _ in range(2):  # a first call, then a cached one
+                    exe.run(main, feed=_feed(), fetch_list=[loss])
+                    exe.run_steps(main, feed_list=[_feed()], steps=3,
+                                  fetch_list=[loss])
+    finally:
+        flags.set_flags({"step_log_path": "", "step_phases": True,
+                         "step_phases_every_n": 16})
+    recs = [json.loads(l) for l in path.read_text().splitlines()][1:]
+    assert [r["kind"] for r in recs] == ["step", "window"] * 4
+    for step, window in zip(recs[::2], recs[1::2]):
+        monitor.validate_step_record(window)
+        assert window["steps"] == 3
+        assert list(step) == [k for k in window if k != "steps"]
+        assert step["cache"] == window["cache"]
+    assert "phases" in recs[2] and "phases" not in recs[-1]
+    # the window's steps moved the PRNG index as three steps do
+    assert [r["step"] for r in recs[:4]] == [1, 2, 5, 6]
 
 
 def test_lru_refresh_keeps_hot_entry():

