@@ -1118,6 +1118,187 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
     return row
 
 
+def mamba2_dispatch():
+    from paddle_tpu.ops import mamba2_scan_ops
+
+    return mamba2_scan_ops.dispatch_counts()
+
+
+def mamba2_phase(seq=4096, t_check=1024, **overrides):
+    """The Mamba-2 / attention hybrid's new mechanisms
+    (models/nemotron_h.py).
+
+    1. The cell ``nemotron3nano-train-s4096``'s train step (blocks 34-42
+       of NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, 8 of
+       128 experts held, an eighth of the vocabulary, bf16 AMP, Adam) is
+       LOWERED, not run (perf/run.py runs it), and the dispatch counters
+       are held to what the cell must lower: its four Mamba-2 scans each
+       way on the ``mamba2.chunk.*`` kernels (``impl=kernel``), their
+       four convolutions (with a bias) on the ``gdn.conv.*`` kernels,
+       every grouped matmul of the four expert layers on a tile (none as
+       ``ragged_dot``: the experts' width of 1856 is off the 128 lanes),
+       and the one attention call each way through the BHTD kernels at
+       32 / 2 heads, the backward one call (``form=fused``).
+       ``overrides`` cut the config for the CPU tests.
+    2. On the device, at the cell's heads and ``t_check`` positions: the
+       scan kernels against the chunked XLA writing (forward and every
+       gradient), the grouped matmuls at the experts' widths against
+       ``ragged_dot``, and the kernels' ms a call by name."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import nemotron_h as M
+    from paddle_tpu.ops import mamba2_scan_ops as S
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import mamba2_scan as K
+
+    cfg = M.NemotronHConfig(**{**dict(
+        num_hidden_layers=9, first_layer=34, vocab_size=16384,
+        held_experts=(0, 8)), **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    reads = (attention_dispatch, mamba2_dispatch, conv_dispatch,
+             gmm_dispatch)
+    before = [read() for read in reads]
+    _lower_train_step(main, model["loss"], seq)
+    attn, scans, convs, gmms = (_dispatch_since(b, read)
+                                for b, read in zip(before, reads))
+    say(f"  lowered: attention {attn}; mamba2 scans {scans}; convolutions "
+        f"{convs}; grouped matmuls {gmms}")
+    kinds = [k for _, k in cfg.blocks]
+    n_scan, n_moe, n_attn = (kinds.count(k)
+                             for k in ("mamba2", "moe", "attn"))
+    shape = (f"t{seq} h{cfg.mamba_num_heads} p{cfg.mamba_head_dim} "
+             f"g{cfg.n_groups} n{cfg.ssm_state_size}")
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in scans.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_scan and all(
+            k.startswith("kernel ") and shape in k for k in rows),
+            f"expected {n_scan} mamba2 scans {direction} on the "
+            f"mamba2.chunk kernels: {scans}")
+        rows = {k: v for k, v in convs.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_scan and all(
+            k.startswith("kernel ") for k in rows),
+            f"expected {n_scan} convolutions {direction} on the gdn.conv "
+            f"kernels: {convs}")
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_attn and all(
+            k.startswith("bhtd ") and f" h{cfg.num_attention_heads} "
+            f"kv{cfg.num_key_value_heads} " in k for k in rows),
+            f"expected {n_attn} bhtd attention call {direction} at "
+            f"{cfg.num_attention_heads} / {cfg.num_key_value_heads} heads, "
+            f"none dense: {attn}")
+    _one_backward_call(attn)
+    check(sum(gmms.values()) == 6 * n_moe and all(
+        k.endswith("]") for k in gmms),
+        f"expected {6 * n_moe} grouped matmuls (two matrices an expert, "
+        f"three products each), every one on a tile: {gmms}")
+
+    # --- on the device ----------------------------------------------------
+    r = np.random.RandomState(7)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    t, heads, p = t_check, cfg.mamba_num_heads, cfg.mamba_head_dim
+    groups, n = cfg.n_groups, cfg.ssm_state_size
+    ins = {"X": jnp.asarray(r.randn(1, t, heads * p), bf),
+           "Dt": jnp.asarray(r.randn(1, t, heads) - 3.0, bf),
+           "ALog": jnp.asarray(np.log(np.arange(1, heads + 1)), f32),
+           "B": jnp.asarray(r.randn(1, t, groups * n) * 0.5, bf),
+           "C": jnp.asarray(r.randn(1, t, groups * n) * 0.5, bf),
+           "D": jnp.ones((heads,), f32),
+           "DtBias": jnp.asarray(r.randn(heads) * 0.5, f32)}
+    dy = jnp.asarray(r.randn(1, t, heads * p), bf)
+    attrs = {"groups": groups, "chunk": cfg.chunk_size}
+    check(K.mamba2_tile(t, heads, groups, p, n, cfg.chunk_size, bf)
+          is not None, f"no mamba2 tile for t{t} {shape}")
+
+    def scan(ins, dy):
+        wrapped = {k: [v] for k, v in ins.items()}
+        out = S._mamba2_scan(wrapped, attrs)
+        grads = S._mamba2_scan_grad(
+            {**wrapped, "States": out["States"], "GRAD::Out": [dy]}, attrs)
+        return {"Out": out["Out"][0], **{k: v[0] for k, v in grads.items()}}
+
+    def rel(a, b):
+        a, b = (jnp.asarray(x, f32) for x in (a, b))
+        return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(),
+                                                        1e-6))
+
+    kernels = jax.jit(scan)
+    got = jax.block_until_ready(kernels(ins, dy))
+    tile, K.mamba2_tile = K.mamba2_tile, lambda *a, **k: None
+    try:
+        # (a function of its own: jax.jit(scan) again would hand back
+        # the kernels' executable)
+        want = jax.block_until_ready(
+            jax.jit(lambda ins, dy: scan(ins, dy))(ins, dy))
+    finally:
+        K.mamba2_tile = tile
+    check(want["Out"] is not got["Out"] and any(
+        bool(jnp.any(got[k] != want[k])) for k in want),
+        "the XLA writing's results are the kernels' bit for bit: the "
+        "comparison ran one of them twice")
+    errs = {f"scan {k}": rel(got[k], want[k]) for k in want}
+
+    # the experts' grouped matmuls at their widths and the cell's own
+    # rows (a held share: an eighth of a row tile an expert at t_check
+    # positions would be ragged_dot's)
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    held = cfg.held_experts[1]
+    m = seq * cfg.num_experts_per_tok
+    live = m * held // cfg.n_routed_experts
+    check(gm.gmm_tile(m, d, f, held, bf, live_rows=live) is not None,
+          f"no tile for {m} rows of {d} x {f}, {live} live")
+    sizes = jnp.asarray(np.diff(np.r_[0, np.sort(r.randint(
+        0, live, held - 1)), live]), jnp.int32)
+    x, w = (jnp.asarray(r.randn(m, d), bf),
+            jnp.asarray(r.randn(held, d, f) * 0.05, bf))
+    g = jnp.asarray(r.randn(m, f), bf)
+
+    def products(x, w, g, sizes):
+        y = gm.grouped_matmul(x, w, sizes, live_rows=live)
+        return (y, *gm.grouped_matmul_grads(x, w, sizes, g, live_rows=live))
+
+    def ragged(x, w, g, sizes):
+        y, vjp = jax.vjp(lambda a, b_: jax.lax.ragged_dot(a, b_, sizes),
+                         x, w)
+        return (y, *vjp(g))
+
+    gmm_kernels = jax.jit(products)
+    got = jax.block_until_ready(gmm_kernels(x, w, g, sizes))
+    # the rows inside groups against ragged_dot's (what its transposes
+    # leave behind the last group is not defined); ours: zeros behind
+    want = jax.jit(ragged)(x, w, jnp.where(
+        jnp.arange(m)[:, None] < live, g, 0), sizes)
+    for name, a, b in zip(("gmm y", "gmm dx"), got, want):
+        errs[name] = rel(a[:live], b[:live])
+        check(not bool(jnp.any(a[live:] != 0)),
+              f"{name} holds something behind the last live row")
+    errs["gmm dw"] = rel(got[2], want[2])
+    check(max(errs.values()) < 2e-2,
+          f"mamba2 and off-lane moe kernels against the XLA writings: "
+          f"{errs}")
+    ms, _ = _traced_kernel_ms(
+        "chip_smoke_mamba2", lambda: (kernels(ins, dy),
+                                      gmm_kernels(x, w, g, sizes)), "")
+    ms = {k: v for k, v in ms.items() if k.startswith(("mamba2.", "moe."))}
+    # (a trace needs the chip: the CPU tests run this phase through the
+    # interpreters and read {})
+    check(jax.default_backend() != "tpu"
+          or {"mamba2.chunk.fwd", "mamba2.chunk.bwd", "moe.gmm.fwd",
+              "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"} <= set(ms),
+          f"kernels in the trace: {ms}")
+    row = {"attention": attn, "mamba2_scans": scans, "convolutions": convs,
+           "grouped_matmuls": gmms, "kernel_ms": ms,
+           "rel_err": {k_: round(v, 5) for k_, v in errs.items()}}
+    say(f"  mamba2 kernels, ms a call at t{t} {shape}: {ms}")
+    say(f"  mamba2 {row['rel_err']}")
+    return row
+
+
 # ---------------------------------------------------------------------------
 # phase 2: train
 # ---------------------------------------------------------------------------
@@ -1621,6 +1802,7 @@ def main() -> int:
     report["gdn"], _ = phase("gdn", gdn_phase)
     report["mla"], _ = phase("mla", mla_phase)
     report["ssm"], _ = phase("ssm", ssm_phase)
+    report["mamba2"], _ = phase("mamba2", mamba2_phase)
     report["rope"], _ = phase("rope", rope_phase)
 
     # 2. train: the step and the window contain the kernels, and no

@@ -36,7 +36,7 @@ __all__ = [
     "bilinear_tensor_product", "nce", "switch_moe", "topk_moe",
     "rms_norm", "rotary_embedding", "causal_conv1d", "gdn_gates",
     "gated_delta_rule", "gated_rms_norm", "silu", "selective_scan",
-    "diff_attention_combine",
+    "mamba2_scan", "diff_attention_combine",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
     "yolo_box", "sequence_conv", "add_position_encoding", "conv3d",
     "spectral_norm", "hsigmoid", "sample_logits",
@@ -555,6 +555,56 @@ def selective_scan(x, dt, b, c, z=None, state_size=16, chunk=64,
     return out
 
 
+def mamba2_scan(x, dt, b, c, heads, groups=8, chunk=128, impl="chunked",
+                a_log_attr=None, d_attr=None, dt_bias_attr=None, name=None):
+    """Mamba-2's state-space scan (ops/mamba2_scan_ops.py): x [b, t,
+    heads * p], dt [b, t, heads] (the pre-activation of the step size),
+    b, c [b, t, groups * n], which the heads of a group share -> out
+    [b, t, heads * p]. Per head a state S [p, n] from zero:
+
+        dt = softplus(dt + dt_bias);  S_t = exp(-exp(A_log) dt_t) S_{t-1}
+        + dt_t x_t B_t^T;  y_t = S_t C_t + D x_t
+
+    Parameters A_log, D and dt_bias [heads] (defaults: log(1 .. heads),
+    1 and 0), float32 all. ``impl``: "chunked" (the chunkwise matmul
+    form, ``chunk`` positions a step and a state saved for each: the
+    ``mamba2.chunk.*`` Pallas kernels where
+    ``parallel/mamba2_scan.mamba2_tile`` gives the call a tile, XLA ops
+    elsewhere; the dispatch counter says which) or "recurrent" (one
+    scan over all positions: the form a test asks for)."""
+    from paddle_tpu.initializer import LogRangeInitializer
+
+    if impl not in ("chunked", "recurrent"):
+        raise ValueError(f"mamba2_scan: impl {impl!r}")
+    heads, groups = int(heads), int(groups)
+    if (dt.shape[-1] != heads or x.shape[-1] % heads or heads % groups
+            or b.shape[-1] % groups or b.shape[-1] != c.shape[-1]):
+        raise ValueError(
+            f"mamba2_scan: x {x.shape}, dt {dt.shape}, b {b.shape}, c "
+            f"{c.shape} with {heads} heads in {groups} groups")
+    helper = LayerHelper("mamba2_scan", name=name)
+    a_log = helper.create_parameter(
+        ParamAttr._to_attr(a_log_attr), shape=[heads], dtype="float32",
+        default_initializer=LogRangeInitializer())
+    d = helper.create_parameter(
+        ParamAttr._to_attr(d_attr), shape=[heads], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    dt_bias = helper.create_parameter(
+        ParamAttr._to_attr(dt_bias_attr), shape=[heads], dtype="float32",
+        default_initializer=ConstantInitializer(0.0))
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    # the state each chunk starts from, kept for the backward pass
+    states = helper.create_variable_for_type_inference(
+        dtype="float32", stop_gradient=True)
+    helper.append_op(
+        "mamba2_scan",
+        inputs={"X": x, "Dt": dt, "ALog": a_log, "B": b, "C": c, "D": d,
+                "DtBias": dt_bias},
+        outputs={"Out": out, "States": states},
+        attrs={"groups": groups, "chunk": int(chunk), "impl": impl})
+    return out
+
+
 def diff_attention_combine(o1, o2, lambda_init, head_dim, epsilon=1e-5,
                            lambda_attr=None, param_attr=None, name=None):
     """Differential attention's combination of the two softmax maps'
@@ -587,17 +637,32 @@ def diff_attention_combine(o1, o2, lambda_init, head_dim, epsilon=1e-5,
     return out
 
 
-def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
+def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
+                   gate_first=False, group_size=None):
     """rms_norm(input) * gain * silu(gate) over the last axis (a plain
-    gain that starts at 1): the norm behind a gated delta rule."""
+    gain that starts at 1): the norm behind a gated delta rule.
+    ``gate_first``: rms_norm(input * silu(gate)) * gain, the gate in
+    front of the statistics (Mamba-2's); ``group_size``: the statistics
+    over each group of that many features of the last axis, not over all
+    of it (the gain stays one a feature)."""
     helper = LayerHelper("gated_rms_norm", name=name)
     scale = helper.create_parameter(
         ParamAttr._to_attr(param_attr), shape=[input.shape[-1]],
         dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(dtype=input.dtype)
+    attrs = {"epsilon": float(epsilon)}
+    # (absent by default: the op's defaults, and the program's text
+    # today's)
+    if gate_first:
+        attrs["gate_first"] = True
+    if group_size is not None and int(group_size) != input.shape[-1]:
+        if input.shape[-1] % int(group_size):
+            raise ValueError(f"gated_rms_norm: groups of {group_size} in "
+                             f"{input.shape[-1]} features")
+        attrs["group_size"] = int(group_size)
     helper.append_op(
         "gated_rms_norm", inputs={"X": input, "Z": gate, "Scale": scale},
-        outputs={"Y": out}, attrs={"epsilon": float(epsilon)})
+        outputs={"Y": out}, attrs=attrs)
     return out
 
 
@@ -1496,7 +1561,8 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
              param_attr=None, name=None, held=None, shared_d_ff=None,
              shared_gate=True, score="softmax", routed_scale=1.0,
              select_bias=False, bias_update_rate=0.001, act="silu",
-             router_input=None):
+             router_input=None, gated=True, shared_act="silu",
+             shared_gated=True):
     """Dropless top-k Mixture-of-Experts with SwiGLU experts (OLMoE,
     arXiv:2409.02060): ``input`` [.., d] tokens -> ``(out, lb_loss,
     z_loss, expert_rows, top_i)``. out, in input's shape, is the sum
@@ -1540,6 +1606,13 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
 
     ``act="relu"``: ReGLU experts, (relu(x WGate[e]) * (x WUp[e]))
     WDown[e] (SmallThinker); the shared expert stays SwiGLU.
+    ``gated=False``: the experts are not gated units but act(x
+    WUp[e]) WDown[e], two matrices an expert and no ``{name}_gate.w``,
+    with ``act`` "relu2" (relu squared: Nemotron-H) or "relu".
+    ``shared_act`` / ``shared_gated``: the same two choices for the
+    shared expert ("silu", "relu" or "relu2"; ``shared_gated=False``: no
+    ``_shared_gate.w``; not to be confused with ``shared_gate``, the
+    sigmoid mix in front of the shared expert's output).
     ``router_input`` (input's shape): the router scores THAT tensor
     (SmallThinker: the attention's normalised input) while dispatch and
     the experts work on ``input``; the router's gradient flows into it.
@@ -1584,10 +1657,16 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
             score == "softmax" and (select_bias or routed_scale != 1.0)):
         raise ValueError(f"topk_moe: score={score!r} with a selection bias "
                          f"or a routed_scale")
-    if act not in ("silu", "relu"):
-        raise ValueError(f"topk_moe: act={act!r}")
+    if act not in (("silu", "relu") if gated else ("relu2", "relu")):
+        raise ValueError(f"topk_moe: act={act!r} with gated={gated}")
+    if shared_act not in ("silu", "relu", "relu2") or (
+            shared_gated and shared_act == "relu2"):
+        raise ValueError(f"topk_moe: shared_act={shared_act!r} with "
+                         f"shared_gated={shared_gated}")
     # (absent for SwiGLU: the op's default, and the program's text today's)
     act_attrs = {} if act == "silu" else {"act": act}
+    if not gated:
+        act_attrs["gated"] = False
     with name_scope("router"):
         top_w, top_i = var("float32"), var("int32", True)
         lb, z = var("float32"), var("float32")
@@ -1627,16 +1706,19 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
             attrs={"num_experts": int(num_experts), **held_attrs})
     with name_scope("experts"):
         ys = var(input.dtype)
-        # the two projections, kept for the op's backward pass
-        gate, up = var(input.dtype, True), var(input.dtype, True)
+        # the projections, kept for the op's backward pass
+        kept = {"Up": var(input.dtype, True)}
+        weights = {}
+        if gated:
+            kept = {"Gate": var(input.dtype, True), **kept}
+            weights["WGate"] = param("_gate.w", [n_held, d, d_ff])
         regather = {"X": input, "Order": order} if held is not None else {}
         helper.append_op(
             "moe_experts",
-            inputs={"Xs": xs, "Rows": rows, **regather,
-                    "WGate": param("_gate.w", [n_held, d, d_ff]),
+            inputs={"Xs": xs, "Rows": rows, **regather, **weights,
                     "WUp": param("_up.w", [n_held, d, d_ff]),
                     "WDown": param("_down.w", [n_held, d_ff, d])},
-            outputs={"Ys": ys, "Gate": gate, "Up": up},
+            outputs={"Ys": ys, **kept},
             attrs={**held_attrs, **act_attrs})
     with name_scope("combine"):
         out = var(input.dtype)
@@ -1652,9 +1734,15 @@ def topk_moe(input, num_experts, top_k, d_ff, norm_topk_prob=False,
                 return fc(x, size, num_flatten_dims=len(x.shape) - 1,
                           param_attr=attr(suffix), bias_attr=False)
 
-            h = elementwise_mul(silu(linear(input, shared_d_ff,
-                                            "_shared_gate.w")),
-                                linear(input, shared_d_ff, "_shared_up.w"))
+            unit = {"silu": silu, "relu": relu,
+                    "relu2": lambda v: square(relu(v))}[shared_act]
+            if shared_gated:
+                h = elementwise_mul(unit(linear(input, shared_d_ff,
+                                                "_shared_gate.w")),
+                                    linear(input, shared_d_ff,
+                                           "_shared_up.w"))
+            else:
+                h = unit(linear(input, shared_d_ff, "_shared_up.w"))
             if shared_gate:
                 mix = sigmoid(linear(input, 1, "_shared_mix.w"))
                 out = elementwise_add(out, elementwise_mul(

@@ -15,6 +15,7 @@ from paddle_tpu.ops import (  # noqa: F401
     decode_ops,
     detection_ops,
     linear_attention_ops,
+    mamba2_scan_ops,
     math_ops,
     metric_ops,
     misc_ops,

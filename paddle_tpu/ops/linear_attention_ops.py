@@ -235,13 +235,27 @@ def _gated_rms_norm(ins, attrs):
     """Y = X / sqrt(mean(X^2, last axis) + epsilon) * Scale * silu(Z):
     Qwen3-Next's norm behind the delta rule, over each value head's
     width, a plain gain. Statistics, gain and gate in float32, Y in X's
-    dtype."""
+    dtype. ``gate_first``: Y = norm(X * silu(Z)) * Scale, the gate in
+    front of the statistics (Mamba-2's); ``group_size``: the mean over
+    each group of that many features of the last axis."""
     f32 = jnp.float32
     x, z, scale = _x(ins, "X"), _x(ins, "Z"), _x(ins, "Scale")
     xf = x.astype(f32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
-                           + attrs.get("epsilon", 1e-6))
-    y = y * scale.astype(f32) * jax.nn.silu(z.astype(f32))
+    eps = attrs.get("epsilon", 1e-6)
+    if not attrs.get("gate_first") and not attrs.get("group_size"):
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+        y = y * scale.astype(f32) * jax.nn.silu(z.astype(f32))
+        return {"Y": [y.astype(x.dtype)]}
+    gate = jax.nn.silu(z.astype(f32))
+    if attrs.get("gate_first"):
+        xf = xf * gate
+    size = int(attrs.get("group_size") or x.shape[-1])
+    by_group = xf.reshape(xf.shape[:-1] + (-1, size))
+    y = (by_group * jax.lax.rsqrt(
+        jnp.mean(by_group * by_group, -1, keepdims=True) + eps)
+         ).reshape(xf.shape) * scale.astype(f32)
+    if not attrs.get("gate_first"):
+        y = y * gate
     return {"Y": [y.astype(x.dtype)]}
 
 

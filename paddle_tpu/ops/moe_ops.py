@@ -517,11 +517,30 @@ def _gated_unit(attrs):
     return {"silu": _swiglu, "relu": _reglu}[attrs.get("act", "silu")]
 
 
+def _relu2(up):
+    return jnp.square(jax.nn.relu(up)).astype(up.dtype)
+
+
+def _plain_unit(attrs):
+    """The activation of experts that are not gated units (attr
+    ``gated`` false: two matrices an expert, act(x WUp) WDown): "relu2",
+    relu(.)^2 (Nemotron-H's), or "relu"."""
+    return {"relu2": _relu2,
+            "relu": lambda up: jax.nn.relu(up)}[attrs.get("act", "relu2")]
+
+
 def _glu_live(glu, gate, up, live, w):
     """``glu`` on the live rows of Gate and Up [m, f], zeros behind."""
     return _live_pass(
         live, w, gate.shape[0], [(gate.shape[1], gate.dtype)],
         lambda r0: (glu(rows_at(gate, r0, w), rows_at(up, r0, w)),))[0]
+
+
+def _act_live(act, up, live, w):
+    """``act`` on the live rows of Up [m, f], zeros behind."""
+    return _live_pass(
+        live, w, up.shape[0], [(up.shape[1], up.dtype)],
+        lambda r0: (act(rows_at(up, r0, w)),))[0]
 
 
 @register_op("moe_experts", diff_inputs=("Xs", "WGate", "WUp", "WDown"))
@@ -539,6 +558,10 @@ def _moe_experts(ins, attrs):
     that the paired grad op below does not run them again: XLA cannot
     CSE custom calls (dead when nothing reads them).
 
+    Attr ``gated`` false (Nemotron-H's experts): no WGate and no Gate,
+    Ys = act(Xs WUp[e]) WDown[e] with ``act`` "relu2" (relu squared) or
+    "relu": two grouped matmuls where a gated unit has three.
+
     ``held_count`` of ``num_experts`` (a held share of the experts,
     see moe_dispatch): Rows sum to less than m; the rows behind the last
     group are multiplied by nothing, and Ys, Gate and Up have zeros
@@ -550,9 +573,17 @@ def _moe_experts(ins, attrs):
     from them and does not keep the buffer, 16 times its live rows, from
     the forward pass (0.32 GB a layer at 81,920 rows of 2048)."""
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
-    wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
+    wu, wd = _x(ins, "WUp"), _x(ins, "WDown")
     m = xs.shape[0]
     kw, w = _live_rows(attrs, m), _window(attrs, m)
+    if not attrs.get("gated", True):
+        act = _plain_unit(attrs)
+        _note_passes("moe_experts", m, w, "act")
+        up = _gm.grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
+        h = act(up) if w is None else _act_live(act, up, jnp.sum(rows), w)
+        ys = _gm.grouped_matmul(h, wd.astype(xs.dtype), rows, **kw)
+        return {"Ys": [ys], "Up": [up]}
+    wg = _x(ins, "WGate")
     glu = _gated_unit(attrs)
     # (the pass keeps its name whatever the activation: readers know it)
     _note_passes("moe_experts", m, w, "swiglu")
@@ -564,6 +595,49 @@ def _moe_experts(ins, attrs):
     return {"Ys": [ys], "Gate": [gate], "Up": [up]}
 
 
+def _plain_experts_grad(ins, attrs):
+    """``moe_experts_grad`` for experts that are not gated units: the
+    four grouped matmuls of the backward pass from the forward's saved
+    Up; a held share gathers Xs again and runs the activation and its
+    gradient over the windows that hold a live row."""
+    xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
+    wu, wd = _x(ins, "WUp"), _x(ins, "WDown")
+    m, dtype = xs.shape[0], xs.dtype
+    up = _x(ins, "Up").astype(dtype)
+    g = _x(ins, "GRAD::Ys").astype(dtype)
+    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    act = _plain_unit(attrs)
+    _note_passes("moe_experts_grad", m, w, "gather_xs", "act", "act_grad")
+    if w is None:
+        h, act_vjp = jax.vjp(act, up)
+        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g)
+        dup, = act_vjp(dh)
+        dx, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup)
+    else:
+        live = jnp.sum(rows)
+        # gathered again (behind a barrier, or XLA merges this gather
+        # with moe_dispatch's and keeps that one's result)
+        x, order = jax.lax.optimization_barrier(
+            (_x(ins, "X"), _x(ins, "Order")))
+        xs = _gather_live(x.reshape(-1, x.shape[-1]).astype(dtype), order,
+                          live, w)
+        h = _act_live(act, up, live, w)
+        # dh is read by window alone; dx is handed on: zeros behind
+        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
+                                           **kw, zero_behind=False)
+
+        def act_grad(r0):
+            _, vjp = jax.vjp(act, rows_at(up, r0, w))
+            return vjp(rows_at(dh, r0, w))
+
+        dup, = _live_pass(live, w, m, [(up.shape[1], dtype)], act_grad)
+        dx, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
+                                           **kw)
+    return {"GRAD::Xs": [dx],
+            "GRAD::WUp": [dwu.astype(wu.dtype)],
+            "GRAD::WDown": [dwd.astype(wd.dtype)]}
+
+
 @register_op("moe_experts_grad", no_grad=True)
 def _moe_experts_grad(ins, attrs):
     """The six grouped matmuls of the backward pass from the forward's
@@ -571,7 +645,10 @@ def _moe_experts_grad(ins, attrs):
     grad op would trace the forward again, and a custom call that is
     traced twice executes twice). A held share gathers Xs again and runs
     SwiGLU, its gradient and the sum of the rows' two gradients over the
-    windows that hold a live row."""
+    windows that hold a live row. Experts that are not gated units
+    (attr ``gated`` false): ``_plain_experts_grad``, four."""
+    if not attrs.get("gated", True):
+        return _plain_experts_grad(ins, attrs)
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
     wg, wu, wd = _x(ins, "WGate"), _x(ins, "WUp"), _x(ins, "WDown")
     m, dtype = xs.shape[0], xs.dtype

@@ -117,7 +117,9 @@ def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None, live_rows=None):
     this process's, with the interpreter counting as one), operands
     that are not bf16, a program under a mesh (a Mosaic call is not
     auto-partitioned, and no expert-parallel path calls this yet), a k
-    or n off the 128 lanes, no row tile that divides m, or fewer rows an
+    or n that is no whole number of half lane tiles (64), no tile of
+    ``_width_tiles`` that the VMEM cap admits, no row tile that divides
+    m, or fewer rows an
     expert than the smallest tile holds (every tile would be visited by
     several experts and most of each visit masked: serving a few rows a
     step is bound by the weights' bytes and wants another kernel; not
@@ -145,15 +147,45 @@ def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None, live_rows=None):
     live = m if live_rows is None else max(1, min(int(live_rows), m))
     rows = [t for t in _ROW_TILE_RATE if m % t == 0]
     if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
-            or k % 128 or n % 128 or not rows or live // e < min(rows)):
+            or k % 64 or n % 64 or not rows or live // e < min(rows)):
         return None
     tm = min(rows,
              key=lambda t: (1 + (e - 1) * t / live) / _ROW_TILE_RATE[t])
-    for tk in [t for t in (k,) + _WIDTH_TILES if t <= k and k % t == 0]:
-        for tn in [t for t in (n,) + _WIDTH_TILES if t <= n and n % t == 0]:
+    for tk in _width_tiles(k, True):
+        for tn in _width_tiles(n, False):
             if _vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES:
                 return tm, tk, tn
     return None
+
+
+def _width_tiles(size, contraction):
+    """The tiles ``gmm_tile`` tries for a contraction or a width of
+    ``size``, widest first: the whole of it, then the powers of two that
+    divide it. A size that is an odd number of lane tiles (2688 = 21 x
+    128: no power of two above 128 divides it) is also tried at the
+    other multiples of 128 that divide it (896, 384), where it had the
+    whole and 128 alone. A size off the 128 lanes (1856 = 14.5 x 128):
+    as a CONTRACTION it is taken whole or not at all (a block as wide as
+    the array, its last 64 lanes masked in VMEM by Mosaic: exact against
+    ``ragged_dot`` on the chip); as a WIDTH it is tiled as the next whole
+    number of lane tiles would be (1920: 1920, 640, 384, 128) and the
+    last block hangs over the array's edge, where what it reads only
+    makes columns that are never written. (The width whole, 1856 lanes
+    of accumulator, ran at a quarter of the speed: 3.4 ms for 0.9 at
+    24,576 rows of which 1536 live; my chip run, PR 45.)"""
+    if size % 128 and contraction:
+        return [size]
+    lanes = -(-size // 128)
+    tiles = [t for t in (lanes * 128,) + _WIDTH_TILES
+             if t <= lanes * 128 and lanes * 128 % t == 0]
+    if lanes % 2:
+        tiles += [128 * f for f in range(3, lanes, 2) if lanes % f == 0]
+    return sorted(set(tiles), reverse=True)
+
+
+def _lane_padded(n):
+    """n, or for an n off the 128 lanes the next whole number of them."""
+    return -(-n // 128) * 128
 
 
 def tile_label(tile) -> str:
@@ -291,7 +323,9 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
     """``lhs [m, k]`` x ``rhs [E, k, n]`` -> [m, n] over the row groups;
     ``transpose_rhs``: rhs is [E, n, k] and is read transposed. ``tile``
     (tm, tk, tn) are the rows, the contraction and the result's width of
-    one grid step: tm divides m, tk k and tn n. ``zero_behind``, for
+    one grid step: tm divides m and tk k; tn divides n or, for an n off
+    the 128 lanes, the next whole number of lane tiles (the last block
+    hangs over the edge: ``_width_tiles``). ``zero_behind``, for
     group sizes that sum to less than m: the result is an array of zeros
     that the call's tiles are written into (it rides in as an operand
     the result aliases and no step reads), so the tiles no visit
@@ -302,8 +336,8 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
     e = rhs.shape[0]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm, tk, tn = tile
-    assert m % tm == 0 and k % tk == 0 and n % tn == 0, (lhs.shape,
-                                                         rhs.shape, tile)
+    assert m % tm == 0 and k % tk == 0 and _lane_padded(n) % tn == 0, (
+        lhs.shape, rhs.shape, tile)
     tiles_k = k // tk
     meta = _visits(group_sizes, m, tm, visit_empty=False)
 
@@ -323,7 +357,7 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, m // tm + e - 1, tiles_k),
+            grid=(-(-n // tn), m // tm + e - 1, tiles_k),
             in_specs=[
                 pl.BlockSpec((tm, tk),
                              lambda j, v, kk, o, g, t, nv: (t[v], kk)),
@@ -340,7 +374,7 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
             vmem_limit_bytes=_vmem_limit(tm, tk, tn, item)),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
-            bytes_accessed=item * (m * k * (n // tn) + e * k * n + m * n)),
+            bytes_accessed=item * (m * k * -(-n // tn) + e * k * n + m * n)),
         interpret=_INTERPRET,
     )(*meta, lhs, rhs, *zeros)
     if not zero_behind:
@@ -401,8 +435,8 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
     n = g.shape[1]
     e = group_sizes.shape[0]
     tm, tk, tn = tile
-    assert m % tm == 0 and k % tk == 0 and n % tn == 0, (lhs.shape,
-                                                         g.shape, tile)
+    assert m % tm == 0 and k % tk == 0 and _lane_padded(n) % tn == 0, (
+        lhs.shape, g.shape, tile)
     meta = _visits(group_sizes, m, tm, visit_empty=True)
     item = jnp.dtype(lhs.dtype).itemsize
     return pl.pallas_call(
@@ -411,7 +445,7 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
         out_shape=jax.ShapeDtypeStruct((e, k, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, k // tk, m // tm + e - 1),
+            grid=(-(-n // tn), k // tk, m // tm + e - 1),
             in_specs=[
                 pl.BlockSpec((tm, tk),
                              lambda j, i, v, o, gi, t, nv: (t[v], i)),
@@ -427,7 +461,7 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
             vmem_limit_bytes=_vmem_limit(tm, tk, tn, item)),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
-            bytes_accessed=item * (m * k * (n // tn) + m * n * (k // tk)
+            bytes_accessed=item * (m * k * -(-n // tn) + m * n * (k // tk)
                                    + e * k * n)),
         interpret=_INTERPRET,
     )(*meta, lhs, g)

@@ -537,3 +537,65 @@ def test_ssm_phase_fails_on_a_scan_without_the_kernel(telemetry,
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="on the ssm.scan kernels"):
         chip_smoke.ssm_phase(seq=512, t_check=64, **SSM_TINY)
+
+
+MAMBA2_TINY = dict(
+    vocab_size=50, hidden_size=128, mamba_num_heads=4, n_groups=2,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+    moe_intermediate_size=192, moe_shared_expert_intermediate_size=128,
+    n_routed_experts=4, num_experts_per_tok=2, held_experts=(0, 2))
+
+
+def _mamba2_interpreters(monkeypatch):
+    from paddle_tpu.parallel import causal_conv as cc
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import mamba2_scan as m2
+    from paddle_tpu.parallel import pair_sum as ps
+
+    for module in (fa, cc, gm, m2, ps):
+        monkeypatch.setattr(module, "_INTERPRET", True)
+
+
+def test_mamba2_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (heads of 64
+    over a state of 128 in chunks of 128 are the model's; experts of 192
+    = 1.5 lane tiles as 1856 = 14.5): blocks 34-42 lower four scans and
+    four convolutions each way through their kernels, 24 grouped matmuls
+    each on a tile and one attention call each way, its backward one
+    call; on the device (here: the CPU) the scan's kernels agree with
+    the XLA writing and the grouped matmuls with ragged_dot."""
+    _mamba2_interpreters(monkeypatch)
+    row = chip_smoke.mamba2_phase(seq=512, t_check=256, **MAMBA2_TINY)
+    assert row["mamba2_scans"] == {
+        f"kernel {d} b1 t512 h4 p64 g2 n128 chunk128": 4
+        for d in ("fwd", "bwd")}
+    assert row["convolutions"] == {
+        f"kernel {d} b1 t512 c768 taps4": 4 for d in ("fwd", "bwd")}
+    assert sum(row["grouped_matmuls"].values()) == 24
+    # (192 whole as a contraction, one block of 256 over the edge as a
+    # width)
+    assert all("tk192" in k or "tn256" in k for k in row["grouped_matmuls"])
+    attn = row["attention"]
+    assert sum(attn.values()) == 2 and all(
+        k.startswith("bhtd ") and " h4 kv2 " in k for k in attn)
+    assert all(k.endswith(" form=fused") for k in attn if " bwd " in k)
+    assert row["kernel_ms"] == {}               # (a trace needs the chip)
+    assert set(row["rel_err"]) == {
+        "scan Out", *(f"scan GRAD::{s}" for s in (
+            "X", "Dt", "ALog", "B", "C", "D", "DtBias")),
+        "gmm y", "gmm dx", "gmm dw"}
+    assert max(row["rel_err"].values()) < 2e-2
+
+
+def test_mamba2_phase_fails_on_a_scan_without_the_kernel(telemetry,
+                                                         monkeypatch):
+    # the scan's kernels off (no interpreter): the calls are the chunked
+    # XLA form, and the phase says so
+    from paddle_tpu.parallel import mamba2_scan as m2
+
+    _mamba2_interpreters(monkeypatch)
+    monkeypatch.setattr(m2, "_INTERPRET", False)
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="on the mamba2.chunk kernels"):
+        chip_smoke.mamba2_phase(seq=512, t_check=256, **MAMBA2_TINY)
