@@ -111,9 +111,11 @@ def embedding(
     dtype: str = "float32",
     name: Optional[str] = None,
 ):
-    """Embedding lookup (reference: layers/nn.py embedding). ``is_sparse`` /
-    ``is_distributed`` are accepted for API parity; on TPU the gradient is a
-    dense XLA scatter-add and sharding is a pjit spec (SURVEY.md section 2.3)."""
+    """Embedding lookup (reference: layers/nn.py embedding). On TPU the
+    gradient is the dense [vocab, d] sum of the cotangent's rows by id
+    (``lookup_table_grad``) unless ``is_sparse`` asks for the row-sparse
+    pair, and ``is_distributed`` sharding is a pjit spec (SURVEY.md
+    section 2.3)."""
     helper = LayerHelper("embedding", name=name)
     w = helper.create_parameter(
         ParamAttr._to_attr(param_attr), shape=list(size), dtype=dtype

@@ -17,7 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.core.registry import register_op
+from paddle_tpu import monitor as _monitor
+from paddle_tpu.core import autodiff, interp
+from paddle_tpu.core.registry import OpDef, register_op
 
 
 def _x(ins, slot="X", i=0):
@@ -216,14 +218,17 @@ def _one_hot(ins, attrs):
 def _lookup_table_grad_maker(op, block, out_grads, provide, should_skip):
     """Emit the row-sparse grad pair when the layer asked for
     ``is_sparse=True`` (the SelectedRows capability, reference:
-    lookup_table_op.cc grad -> SelectedRows); dense lookups defer to the
+    lookup_table_op.cc grad -> SelectedRows), the dense table's own grad
+    op ``lookup_table_grad`` (W for its shape, Ids, GRAD::Out -> GRAD::W)
+    otherwise; a row-sharded lookup (``is_distributed``) defers to the
     generic auto-vjp grad emitter (return None). The sparse pair is two IR
     vars named ``{W}@GRAD@ROWS`` / ``{W}@GRAD@VALUES``; the ``{W}@GRAD``
     variable itself becomes a never-materialized marker carrying
     ``is_selected_rows`` so the optimizer dispatches to its sparse op."""
     from paddle_tpu.core.registry import get_op_def
 
-    if not op.attrs.get("is_sparse", False):
+    sparse = op.attrs.get("is_sparse", False)
+    if op.attrs.get("is_distributed", False) and not sparse:
         return None  # generic dense path
     w = op.inputs["W"][0]
     g_out = (out_grads.get("Out") or [""])[0]
@@ -234,6 +239,16 @@ def _lookup_table_grad_maker(op, block, out_grads, provide, should_skip):
         return []
     src = block._find_var_recursive(w)
     gname = provide(w)
+    if not sparse:
+        block.create_var(name=gname, shape=src.shape if src else None,
+                         dtype=src.dtype if src else "float32")
+        return [dict(
+            type="lookup_table_grad",
+            inputs={"W": [w], "Ids": list(op.inputs["Ids"]),
+                    "GRAD::Out": [g_out]},
+            outputs={"GRAD::W": [gname]},
+            attrs=dict(op.attrs),
+        )]
     if "@RENAME@" in gname:
         raise ValueError(
             f"lookup_table(is_sparse=True): table '{w}' is consumed by "
@@ -267,21 +282,15 @@ def _lookup_table_grad_maker(op, block, out_grads, provide, should_skip):
 
 @register_op("lookup_table", diff_inputs=("W",),
              grad_maker=_lookup_table_grad_maker,
-             doc="embedding lookup; grad is a dense XLA scatter-add, or a "
+             doc="embedding lookup; grad is the dense [vocab, d] sum of the "
+                 "cotangent's rows by id (lookup_table_grad: the embed.grad "
+                 "kernel over the sorted rows where embed_grad_tile gives "
+                 "the call a tile, XLA's scatter-add elsewhere), or a "
                  "row-sparse {rows, values} pair under is_sparse=True "
                  "(the reference's SelectedRows, lookup_table_op.cc)")
 def _lookup_table(ins, attrs):
     w, ids = _x(ins, "W"), _x(ins, "Ids")
-    # [N, 1] column-ids convention: squeeze unless the layer says the ids
-    # are already a padded [b, t] batch (a [b, 1] batch is ambiguous).
-    squeeze_last = attrs.get(
-        "squeeze_last", jnp.ndim(ids) > 1 and jnp.shape(ids)[-1] == 1
-    )
-    if squeeze_last:
-        ids = jnp.squeeze(ids, axis=-1)
-    # Reference semantics: kNoPadding when absent; negative = vocab + idx
-    # (lookup_table_op.cc). The layer omits the attr when padding is off.
-    padding_idx = attrs.get("padding_idx", None)
+    ids, padding_idx = _lookup_ids(w, ids, attrs)
     out = None
     if attrs.get("is_distributed", False):
         # Row-sharded table (replaces the reference's pserver-distributed
@@ -307,11 +316,71 @@ def _lookup_table(ins, attrs):
     if out is None:
         out = jnp.take(w, ids, axis=0)
     if padding_idx is not None:
-        if padding_idx < 0:
-            padding_idx = jnp.shape(w)[0] + padding_idx
         mask = (ids != padding_idx)[..., None]
         out = out * mask.astype(out.dtype)
     return {"Out": [out]}
+
+
+def _lookup_ids(w, ids, attrs):
+    """(ids as the lookup reads them, the padding row or None)."""
+    # [N, 1] column-ids convention: squeeze unless the layer says the ids
+    # are already a padded [b, t] batch (a [b, 1] batch is ambiguous).
+    squeeze_last = attrs.get(
+        "squeeze_last", jnp.ndim(ids) > 1 and jnp.shape(ids)[-1] == 1
+    )
+    if squeeze_last:
+        ids = jnp.squeeze(ids, axis=-1)
+    # Reference semantics: kNoPadding when absent; negative = vocab + idx
+    # (lookup_table_op.cc). The layer omits the attr when padding is off.
+    padding_idx = attrs.get("padding_idx", None)
+    if padding_idx is not None and padding_idx < 0:
+        padding_idx = jnp.shape(w)[0] + padding_idx
+    return ids, padding_idx
+
+
+_M_EMBED_GRAD = _monitor.counter(
+    "pt_embedding_grad_dispatch_total",
+    "dense embedding gradient implementation chosen at trace time, one "
+    "row a lowered lookup_table_grad: impl kernel (embed.grad, "
+    "parallel/embed_grad.py) or xla (the scatter-add); "
+    "parallel/embed_grad.embed_grad_tile's answer for the call")
+
+# (the rule of the XLA form, and of the generic emitter's op: the vjp of
+# _lookup_table, a scatter-add into zeros)
+_LOOKUP_TABLE_XLA_GRAD = autodiff.make_grad_compute(OpDef(
+    type="lookup_table", compute=_lookup_table, diff_inputs=("W",)))
+
+
+@register_op("lookup_table_grad", no_grad=True)
+def _lookup_table_grad(ins, attrs):
+    """GRAD::W [vocab, d] of a dense lookup_table, in W's dtype: the sum
+    of GRAD::Out's rows by id, float32. Padding rows, negative ids and
+    ids outside the table add what the forward's vjp adds (nothing, the
+    row vocab + id, nothing). ONE kernel, ``parallel/embed_grad``, at the
+    tile ``embed_grad_tile`` gives the call from its shapes, the
+    cotangent's dtype, the backend and the mesh; where it gives none,
+    and for a row-sharded table (the generic emitter's op), the vjp of
+    the forward: XLA's scatter-add."""
+    from paddle_tpu.parallel import embed_grad
+
+    w, g = _x(ins, "W"), _x(ins, "GRAD::Out")
+    vocab, d = jnp.shape(w)
+    tile = None
+    if not attrs.get("is_distributed", False) and jnp.ndim(g) >= 2:
+        tile = embed_grad.embed_grad_tile(g.size // d, vocab, d, g.dtype)
+    # off with telemetry; build-time shape inference is not a lowering
+    if _monitor.enabled() and interp.lowering_active():
+        _M_EMBED_GRAD.inc(labels={"impl": "kernel" if tile else "xla"})
+    if tile is None:
+        return _LOOKUP_TABLE_XLA_GRAD(ins, {
+            "fwd_input_slots": ["W", "Ids"], "fwd_output_slots": ["Out"],
+            **attrs})
+    ids, padding_idx = _lookup_ids(w, _x(ins, "Ids"), attrs)
+    keys = jnp.where(ids < 0, ids + vocab, ids).reshape(-1)
+    if padding_idx is not None:   # the forward's mask, on the ids as fed
+        keys = jnp.where(ids.reshape(-1) == padding_idx, -1, keys)
+    dw = embed_grad.embed_grad(g.reshape(-1, d), keys, vocab, tile)
+    return {"GRAD::W": [dw.astype(w.dtype)]}
 
 
 @register_op("top_k", no_grad=True)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.parallel import causal_conv as cc
+from paddle_tpu.parallel import embed_grad as eg
 from paddle_tpu.parallel import flash_attention as fa
 from paddle_tpu.parallel import gated_delta_rule as gdr
 from paddle_tpu.parallel import grouped_matmul as gm
@@ -580,3 +581,29 @@ def test_differential_attention_maps_compile(window, one_chip, real_kernels):
     for name in ("attn.bhtd.fwd", "attn.bhtd.bwd"):
         assert name in text, name
     assert "attn.bhtd.bwd_dq" not in text
+
+
+# rows a step, rows of the table, width: the two cells whose table is
+# 2560 wide, and the widest table
+_EMBED_GRADS = {"phi4flash": (4096, 25008, 2560),
+                "smallthinker": (16384, 18992, 2560),
+                "olmoe": (8192, 50304, 2048)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("cell", sorted(_EMBED_GRADS))
+def test_embed_grad_kernel_compiles_at_the_cells_calls(cell, dtype, one_chip,
+                                                       real_kernels):
+    """``embed.grad`` (PR 46) as the cells lower it: the prefetched list
+    of (tile, group) steps in the index maps, a table that ends inside a
+    tile, the float32 rows' three bf16 pieces pass Mosaic, and XLA's
+    row-by-row scatter is not in the program."""
+    n, vocab, d = _EMBED_GRADS[cell]
+    tile = eg.embed_grad_tile(n, vocab, d, dtype, "tpu", False)
+    assert tile == (128, 128)
+    g = jax.ShapeDtypeStruct((n, d), dtype, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda g, ids: eg.embed_grad(g, ids, vocab, tile)).lower(
+        g, ids).compile().as_text()
+    assert "embed.grad" in text and " scatter(" not in text
