@@ -40,7 +40,9 @@ Two writings of that chunkwise form, chosen per call by
 (never by a flag): the ``gdn.rule.fwd`` / ``gdn.rule.bwd`` Pallas
 kernels where it gives a tile (bf16 operands, dk = dv = 128, chunk 64,
 a TPU backend, no mesh: the state stays in VMEM across the chunks, each
-chunk's triangle is inverted once, nothing is staged through HBM), and
+chunk's triangle is inverted once a pass, its 16 x 16 diagonal blocks by
+substitution and the rest by two exact block merges on the MXU, nothing
+is staged through HBM), and
 XLA ops everywhere else (``_chunk_parts`` / ``_chunk_scan`` below, with
 ``jax.vjp`` of the parallel part behind a barrier): every CPU run,
 float32 operands, other widths or chunks, a program under a mesh. The

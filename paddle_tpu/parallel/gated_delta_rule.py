@@ -27,12 +27,21 @@ What XLA's ops cannot do and a grid step does:
   norm (qwen3next-train-s8192: 24,396 -> 26,049 tokens/s for this alone;
   dO, dq, dk, dv heads first as well: no gain or a loss; my chip runs,
   PR 33).
-- **One inversion a chunk, applied as products.** T = (I + A)^-1 by
-  forward substitution in float32 on the VPU (row j of T is final after
-  step j - 1 and is subtracted, times A[i, j], from every row i > j),
-  for all the chunks and heads of the grid step at once: 63 steps whose
-  latency the (heads x chunks) independent matrices hide. Exact to
-  float32 rounding whatever the keys (no power of A is formed). Then
+- **One inversion a chunk, in blocks, applied as products.**
+  T = (I + A)^-1 in float32, for all the chunks and heads of the grid
+  step at once, two value heads side by side over the 128 lanes. The
+  four 16 x 16 diagonal blocks of a triangle invert on their own: by
+  forward substitution on the VPU (row j of a block's inverse is final
+  after step j - 1 and is subtracted, times A[i, j], from every row
+  i > j), eight blocks across the lanes, 15 steps (``_substitute``;
+  until PR 47 the whole [64, 64] went this way, 63 steps on half-empty
+  lanes, 2.36 ms of a pass's 5.36 / 8.61 alone at the cell's call, 0.55
+  now: my chip runs, PR 47).
+  Then two merges, 16 -> 32 -> 64, each the exact inverse of a 2 x 2
+  block triangle, [[T11, 0], [-T22 (A21 T11), T22]], as ``HIGHEST``
+  products on the MXU against a block diagonal (``_merge``). Exact to
+  float32 rounding whatever the keys (no power of A or of a block is
+  formed). Then
   U = T (beta V), W = T (beta K e^G) and, backward, with dU = dV' and
   dW = -dV' S^T: d(beta V) = T^T dU, d(beta K e^G) = T^T dW and
   dA = -(T^T dU) U^T - (T^T dW) W^T: no transposed solve.
@@ -46,7 +55,8 @@ What XLA's ops cannot do and a grid step does:
   PR 33: ``setup_s`` 35.7 -> 40.2 s warm over three layers).
 
 float32: g, its running sums and their exps, beta, the normalisation,
-A, T and its application (``precision=HIGHEST``), U, the state and dS.
+A, T, its merges and its application (``precision=HIGHEST``), U, the
+state and dS.
 bf16 operands with float32 accumulation where the chunked XLA form has
 them: K K^T, Q K^T, W, Q e^G, K e^{G_C - G}, the chunk's attention, V',
 the state as an operand, and the cotangents' products.
@@ -72,6 +82,7 @@ _INTERPRET = False
 
 CHUNK = 64      # the chunk the kernels are written for (gdn_chunk)
 _LANES = 128    # dk and dv: a head is one lane tile
+_BLOCK = 16     # T's diagonal blocks by substitution, the rest by merges
 # Chunks a grid step: 8 chunks are 512 rows of q, k, v a block, and 8
 # rows of g and beta [.., chunks, 64] are one float32 sublane tile.
 _STEP_CHUNKS = 8
@@ -99,12 +110,15 @@ def _vmem_bytes(heads, chunks, dk, dv):
     """What one grid step of the backward kernel (the larger) keeps in
     VMEM: its blocks double-buffered (q, k, dq, dk; v, dO, dv; the
     states; g, beta and their gradients padded to a sublane tile) and
-    the scratch (A and T as [64, 128] float32 tiles a matrix, S or dS)."""
+    the scratch (``_scratch``: a [64, 128] float32 tile for each pair's
+    A and each matrix's T, a [16, 128] one for a pair's diagonal blocks,
+    S or dS)."""
     rows = chunks * CHUNK
+    pairs = -(-heads // 2) * chunks
     blocks = (4 * rows * dk * 2 + 3 * rows * heads * dv * 2
               + chunks * heads * dk * dv * 2
               + 4 * heads * max(chunks, 8) * _LANES * 4)
-    scratch = (2 * heads * chunks * CHUNK * _LANES * 4
+    scratch = ((3 * CHUNK + _BLOCK) * pairs * _LANES * 4
                + heads * dk * dv * 4)
     return 2 * blocks + scratch
 
@@ -203,36 +217,138 @@ def _row(c):
     return pl.ds(c, 1)
 
 
+def _slot(r, c, chunks):
+    """Where value head ``r``'s chunk ``c`` lies: (the pair's index in
+    the scratch of A, its half of the lanes), which is T's index too. A
+    key head's value heads go two and two, an odd last one alone."""
+    return r // 2 * chunks + c, r % 2
+
+
 def _triangles(k_ref, g_ref, beta_ref, a_ref, *, heads, chunks, eps):
-    """a_ref[r * chunks + c] <- A = strictly_lower(beta_i (k_i . k_j)
-    D_ij) of every chunk c and value head r of a block."""
+    """a_ref[pair] <- A = strictly_lower(beta_i (k_i . k_j) D_ij) of
+    every chunk c of a block, two value heads side by side over the 128
+    lanes (``_slot``; zeros beside an odd last head)."""
     ii, jj = _iotas()
 
     def chunk(c, carry):
         kn, _ = _l2(k_ref[_rows(c), :], eps)
         kb = kn.astype(k_ref.dtype)
         kk = _dot(kb, kb, 1, 1)
-        for r in range(heads):
-            gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
-            a_ref[r * chunks + c] = jnp.where(
-                ii > jj, gt["beta"] * kk * gt["decay"], 0.0)
+        for r0 in range(0, heads, 2):
+            halves = []
+            for r in range(r0, min(r0 + 2, heads)):
+                gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :],
+                            ii, jj)
+                halves.append(jnp.where(
+                    ii > jj, gt["beta"] * kk * gt["decay"], 0.0))
+            halves += [jnp.zeros_like(kk)] * (2 - len(halves))
+            a_ref[_slot(r0, c, chunks)[0]] = jnp.concatenate(halves, axis=1)
         return carry
 
     jax.lax.fori_loop(0, chunks, chunk, None)
 
 
-def _invert(a_ref, t_ref):
-    """t_ref[m] <- (I + a_ref[m])^-1 for every strictly lower a_ref[m]
-    [C, C]: forward substitution, step j on every matrix at once (row j
-    of T, final since step j - 1, times A[i, j] leaves every row
-    i > j). Rows above the sublane tile of row j hold A[i, j] = 0:
-    skipped whole."""
-    ii, jj = _iotas()
-    t_ref[...] = jnp.broadcast_to((ii == jj).astype(_F32), t_ref.shape)
-    for j in range(CHUNK - 1):
+def _columns(d):
+    """d [pairs, rows, 128] -> 16 arrays: column j of every block of 16
+    lanes over all of its block's lanes. A lane broadcast cannot stop
+    at a block's edge, and one column at a time costs five lane rolls a
+    vreg; halving the distance, each array gives the two that hold the
+    lower and the upper half of its columns (its neighbours 8, 4, 2, 1
+    lanes away, either side): two rolls an array made."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 2)
+    cols, s = [d], _BLOCK // 2
+    while s:
+        low = lane % (2 * s) < s
+        cols = [y for x in cols for y in (
+            jnp.where(low, x, pltpu.roll(x, s, axis=2)),
+            jnp.where(low, pltpu.roll(x, _LANES - s, axis=2), x))]
+        s //= 2
+    return cols
+
+
+def _substitute(a_ref, x_ref):
+    """x_ref[pair] [16, 128] <- the inverses of I + the eight diagonal
+    16 x 16 blocks of a_ref[pair] (block b of the left matrix at lanes
+    16 b, of the right at 64 + 16 b), by forward substitution on every
+    block of the grid step at once: row j of a block's inverse, final
+    since step j - 1, times A[i, j] leaves every row i > j. A's column
+    j, which each block needs across its own 16 lanes, comes from
+    ``_columns`` (the XLU's work; nothing of it waits for the
+    substitution). Rows above the sublane tile of row j hold
+    A[i, j] = 0: skipped whole."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 2)
+    x_ref[...] = (rows == lane % _BLOCK).astype(_F32)
+    d = a_ref[:, :_BLOCK, :]
+    for b in range(1, CHUNK // _BLOCK):
+        d = jnp.where(lane // _BLOCK % (CHUNK // _BLOCK) == b,
+                      a_ref[:, b * _BLOCK:(b + 1) * _BLOCK, :], d)
+    for j, col in enumerate(_columns(d)[:-1]):
         r0 = j // 8 * 8
-        t_ref[:, r0:, :] = (t_ref[:, r0:, :]
-                            - a_ref[:, r0:, j:j + 1] * t_ref[:, j:j + 1, :])
+        x_ref[:, r0:, :] = (x_ref[:, r0:, :]
+                            - col[:, r0:, :] * x_ref[:, j:j + 1, :])
+
+
+def _diagonal(y, size, below):
+    """y [pairs, size, 128] -> [pairs, 128, 128]: each y's even blocks
+    of ``size`` lanes down the diagonal (``below``: each one block of
+    rows further down), zeros elsewhere."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, 2) // size
+    zeros = jnp.zeros_like(y)
+    rows = [jnp.where(lane == q, y, zeros) if q % 2 == 0 else zeros
+            for q in range(_LANES // size)]
+    if below:
+        rows = rows[-1:] + rows[:-1]
+    return jnp.concatenate(rows, axis=1)
+
+
+def _times_blocks(x, y, size, below):
+    """x, y [pairs, size, 128]: x times y's even blocks of ``size``
+    lanes, each its own product: the lanes of even block q of the
+    result are x's lanes of block q (``below``: of block q + 1) times
+    y's block q. One product a pair against ``_diagonal``, float32 as
+    every application of T."""
+    return jax.lax.dot_general(
+        x, _diagonal(y, size, below), (((2,), (1,)), ((0,), (0,))),
+        precision=_HIGHEST, preferred_element_type=_F32)
+
+
+def _merge(a_ref, y, size):
+    """y [pairs, size, 128]: the inverses of I + the diagonal blocks of
+    ``size`` of each pair's two triangles, block q at lanes size q ->
+    [pairs, 2 size, 128]: of the blocks of 2 size. The inverse of a
+    2 x 2 block triangle is exact in its blocks, [[T11, 0], [-T22 (A21
+    T11), T22]] (no power of A is formed), and in this layout T11, A21
+    (A's rows under each T11, at its lanes) and the result share their
+    lanes and T22 sits one block on: no lane moves. Every pair's first
+    product, then every pair's second: an MXU takes its products in
+    the order they are written, and a pair's second waits for its first
+    (a pair at a time the inversion takes 1.19 ms a call for 0.55:
+    benchmarks/gdn_candidates.py, my chip run, PR 47)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, 2) // size
+    a21 = a_ref[:, size:2 * size, :]
+    for i in range(1, CHUNK // (2 * size)):     # the i-th pair of blocks
+        a21 = jnp.where(lane // 2 % (CHUNK // (2 * size)) == i,
+                        a_ref[:, (2 * i + 1) * size:(2 * i + 2) * size, :],
+                        a21)
+    x = _times_blocks(a21, y, size, False)                  # A21 T11
+    x = _times_blocks(y, x, size, True)                     # T22 (A21 T11)
+    even = lane % 2 == 0
+    return jnp.concatenate([jnp.where(even, y, 0.0),
+                            jnp.where(even, -x, y)], axis=1)
+
+
+def _invert(a_ref, t_ref, x_ref):
+    """t_ref[pair, half] <- (I + A)^-1 for each of the two strictly
+    lower A [C, C] of every a_ref[pair] [C, 2 C]: the four diagonal
+    blocks of 16 by substitution (``_substitute``), then two merges,
+    16 -> 32 -> 64 (``_merge``)."""
+    _substitute(a_ref, x_ref)
+    t, size = x_ref[...], _BLOCK
+    while size < CHUNK:
+        t, size = _merge(a_ref, t, size), 2 * size
+    for half in range(2):
+        t_ref[:, half] = t[:, :, half * CHUNK:(half + 1) * CHUNK]
 
 
 def _chunk_parts(qn, kn, v, gt, t, dtype):
@@ -257,7 +373,7 @@ def _chunk_parts(qn, kn, v, gt, t, dtype):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
-                s_ref, a_ref, t_ref, *, heads, chunks, eps, scale):
+                s_ref, a_ref, t_ref, x_ref, *, heads, chunks, eps, scale):
     dtype = q_ref.dtype
     dv = v_ref.shape[-1] // heads
     ii, jj = _iotas()
@@ -268,7 +384,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
 
     _triangles(k_ref, g_ref, beta_ref, a_ref, heads=heads, chunks=chunks,
                eps=eps)
-    _invert(a_ref, t_ref)
+    _invert(a_ref, t_ref, x_ref)
 
     def chunk(c, carry):
         rows = _rows(c)
@@ -279,7 +395,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
             cols = slice(r * dv, (r + 1) * dv)
             gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
             p = _chunk_parts(qn, kn, v_ref[rows, cols], gt,
-                             t_ref[r * chunks + c], dtype)
+                             t_ref[_slot(r, c, chunks)], dtype)
             s = s_ref[r]
             sb = s.astype(dtype)
             states_ref[c, r] = sb
@@ -342,9 +458,13 @@ def _specs(heads, chunks, dk, dv, blk):
 
 
 def _scratch(heads, chunks, dk, dv):
-    """S or dS; A and T of the block's chunks and heads."""
-    tri = pltpu.VMEM((heads * chunks, CHUNK, CHUNK), _F32)
-    return [pltpu.VMEM((heads, dk, dv), _F32), tri, tri]
+    """S or dS; A of the block's chunks and pairs of heads (``_slot``),
+    T of each matrix, the pairs' diagonal blocks (``_substitute``)."""
+    pairs = -(-heads // 2) * chunks
+    return [pltpu.VMEM((heads, dk, dv), _F32),
+            pltpu.VMEM((pairs, CHUNK, 2 * CHUNK), _F32),
+            pltpu.VMEM((pairs, 2, CHUNK, CHUNK), _F32),
+            pltpu.VMEM((pairs, _BLOCK, _LANES), _F32)]
 
 
 def _cost(b, hv, n, dk, dv, passes, bytes_accessed):
@@ -353,8 +473,11 @@ def _cost(b, hv, n, dk, dv, passes, bytes_accessed):
     # ``passes`` (1 forward, 3 backward: recomputed + two transposes)
     flops = 2 * CHUNK * (2 * CHUNK * dk + CHUNK * (dk + dv)
                          + 3 * dk * dv + CHUNK * dv)
+    # T's merges, once a pass: four products of half of a pair's rows
+    # (16 + 16 + 32 + 32) against a block diagonal [128, 128]
+    merges = 2 * (2 * _BLOCK + CHUNK) * _LANES * _LANES // 2
     return pl.CostEstimate(
-        flops=passes * b * hv * n * flops,
+        flops=b * hv * n * (passes * flops + merges),
         transcendentals=b * hv * n * (CHUNK * CHUNK + 3 * CHUNK),
         bytes_accessed=bytes_accessed)
 
@@ -404,7 +527,7 @@ def gated_delta_rule_fwd(q, k, v, g, beta, tile, eps=1e-6):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                ds_ref, a_ref, t_ref, *, heads, chunks, eps, scale):
+                ds_ref, a_ref, t_ref, x_ref, *, heads, chunks, eps, scale):
     dtype = q_ref.dtype
     dv = v_ref.shape[-1] // heads
     ii, jj = _iotas()
@@ -418,7 +541,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
 
     _triangles(k_ref, g_ref, beta_ref, a_ref, heads=heads, chunks=chunks,
                eps=eps)
-    _invert(a_ref, t_ref)
+    _invert(a_ref, t_ref, x_ref)
 
     def rowsum(x):
         return jnp.sum(x, axis=1, keepdims=True)
@@ -438,7 +561,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
             gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
             beta, decay, eg, ekd, dec = (gt[x] for x in (
                 "beta", "decay", "eg", "ekd", "dec"))
-            t = t_ref[r * chunks + c]
+            t = t_ref[_slot(r, c, chunks)]
             vf = v_ref[rows, cols].astype(_F32)
             p = _chunk_parts(qn, kn, vf, gt, t, dtype)
             u, w, qk, attn, qg, kd = (p[x] for x in (

@@ -384,7 +384,8 @@ def test_gated_delta_rule_kernels_compile(t, hk, hv, one_chip, real_kernels):
     """Qwen3-Next's DeltaNet layer as qwen3next-train-s8192 lowers it:
     16 key and 32 value heads of 128, a key head's two value heads and
     8 chunks of 64 a grid step, forward and the backward pass from the
-    saved states: the substitution's lane slices, the transposed
+    saved states: the substitution's lane rolls, the merges' batched
+    float32 products against a block diagonal, the transposed
     float32 products and the blocks' VMEM pass Mosaic."""
     bf = jnp.bfloat16
     tile = gdr.gdn_tile(t, hk, hv, 128, 128, 64, bf, "tpu", False)

@@ -2,8 +2,9 @@
 (paddle_tpu/parallel/gated_delta_rule.py) on the CPU through the Pallas
 interpreter, at dk = dv = 128 and a few chunks: against the float32
 recurrence and its ``jax.vjp`` (Out and all five gradients), against the
-chunked XLA form they replace (``States``), the picker's table, the
-dispatch counter, and the op through a Program under AMP. The chip's
+chunked XLA form they replace (``States``), the triangle's inverse alone
+against float64, the picker's table, the dispatch counter, and the op
+through a Program under AMP. The chip's
 run of the cell's shapes is tests/test_gated_delta_rule_tpu.py.
 """
 
@@ -11,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 import paddle_tpu as fluid
 from paddle_tpu import flags, layers, monitor
@@ -145,6 +147,86 @@ def test_repeated_keys_with_beta_near_one(interpreted, monkeypatch):
         # entry both ways, 1e-6 for the others)
         assert rel(a, b) < max(2 * rel(c, b), 1e-5), (name, rel(a, b),
                                                        rel(c, b))
+
+
+def triangles(kind, n, seed=0):
+    """n strictly lower [64, 64] float32: random entries; equal keys
+    with beta near 1 (entries 0.98-1 down whole columns: the powers of
+    such a triangle grow to binomial size); g down to -21 a position
+    (most entries underflow); entries near -1 (the inverse doubles down
+    every column, to 1e18); zeros."""
+    r = np.random.RandomState(seed)
+    if kind == "random":
+        a = r.randn(n, 64, 64) * 0.3
+    elif kind == "repeated_keys":
+        a = (0.98 + 0.02 * r.rand(n, 64, 1)) * np.ones((1, 1, 64))
+    elif kind == "strong_decay":
+        gc = np.cumsum(-21.0 * r.rand(n, 64), axis=1)
+        a = r.randn(n, 64, 64) * np.exp(
+            np.minimum(gc[:, :, None] - gc[:, None, :], 0.0))
+    elif kind == "growth":
+        a = -(0.9 + 0.1 * r.rand(n, 64, 64))
+    else:
+        a = np.zeros((n, 64, 64))
+    return np.tril(a, -1).astype(np.float32)
+
+
+def rank_one_substitution(a):
+    """The form ``_invert`` had until PR 46, in numpy's float32: row j
+    of T, final since step j - 1, times A[i, j] leaves every row i > j."""
+    t = np.broadcast_to(np.eye(64, dtype=np.float32), a.shape).copy()
+    for j in range(63):
+        t[:, j + 1:, :] -= a[:, j + 1:, j:j + 1] * t[:, j:j + 1, :]
+    return t
+
+
+def inverted(a, heads, chunks):
+    """``_invert`` alone through the interpreter on the triangles of
+    ``heads`` value heads x ``chunks`` chunks, laid out as ``_triangles``
+    leaves them and read back as the chunk loops read T."""
+    scratch = gdr._scratch(heads, chunks, 128, 128)[1:]
+    packed = np.zeros(scratch[0].shape, np.float32)
+    for r in range(heads):
+        for c in range(chunks):
+            pair, half = gdr._slot(r, c, chunks)
+            packed[pair, :, half * 64:(half + 1) * 64] = a[r * chunks + c]
+
+    def kernel(a_ref, out_ref, t_ref, x_ref):
+        gdr._invert(a_ref, t_ref, x_ref)
+        for r in range(heads):
+            for c in range(chunks):
+                out_ref[r * chunks + c] = t_ref[gdr._slot(r, c, chunks)]
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(a.shape, F32),
+        scratch_shapes=scratch[1:], interpret=True)(jnp.asarray(packed))
+
+
+@pytest.mark.parametrize("kind", ["random", "repeated_keys", "strong_decay",
+                                  "growth", "zero"])
+@pytest.mark.parametrize("heads,chunks", [(1, 1), (2, 8), (4, 3)])
+def test_the_inverse_alone_is_float64s_to_float32_rounding(heads, chunks,
+                                                           kind):
+    """T = (I + A)^-1 as the blocks and merges leave it against
+    numpy's float64 inverse, every entry within float32's rounding of
+    what the triangle itself makes of a rounding error, |T| (I + |A|)
+    |T| (the bound a triangular inverse by substitution meets, Higham
+    ch. 14: the rank-1 substitution it replaced is held to the same
+    line), for one matrix, a grid step of the cell's and an odd pair."""
+    n = heads * chunks
+    a = triangles(kind, n, seed=n)
+    want = np.tril(np.linalg.inv(np.eye(64) + a.astype(np.float64)))
+    grown = np.abs(want) @ (np.eye(64) + np.abs(a)) @ np.abs(want)
+    # (+ 64 of float32's smallest: a row of products that underflow)
+    bound = np.finfo(np.float32).eps * grown + 64 * np.finfo(np.float32).tiny
+    got = np.asarray(inverted(a, heads, chunks))
+    for name, t in (("blocked", got), ("rank-1", rank_one_substitution(a))):
+        assert np.isfinite(t).all(), name
+        worst = float((np.abs(t - want) / bound).max())
+        assert worst < 1.0, (name, worst)
+    assert bool((got[:, np.triu_indices(64, 1)[0],
+                     np.triu_indices(64, 1)[1]] == 0).all())
+    assert bool((got[:, np.arange(64), np.arange(64)] == 1).all())
 
 
 # (t, hk, hv, dk, dv, chunk, dtype, backend, on_mesh) -> tile
