@@ -15,7 +15,10 @@ points a user calls:
    ``joyai-train-s4096`` and hold their dispatch rows (the delta rule
    and grouped-query attention; latent attention at queries and keys
    of 192 over values of 128, sigmoid routers with a selection bias)
-   and run the new kernels against their plain forms;
+   and run the new kernels against their plain forms; the ``sconv``
+   phase lowers ``lfm2moe-train-s8192`` (four gated short convolutions
+   each way on the ``sconv.gated.*`` kernels) and runs them against the
+   composition;
 2. train   — ``T.build`` + ``Adam.minimize`` under bf16 AMP, a few
    ``Executor.run`` steps and one ``Executor.run_steps`` window at
    b=64 s=256 with dropout 0.1 (no OOM back-off: full batch or fail);
@@ -1299,6 +1302,120 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
     return row
 
 
+def sconv_phase(seq=8192, t_check=2048, **overrides):
+    """The short-convolution / attention hybrid's new mechanisms
+    (models/lfm2_moe.py).
+
+    1. The cell ``lfm2moe-train-s8192``'s train step (layers 1-5 of
+       LFM2-24B-A2B at its published widths, 8 of 64 experts held, an
+       eighth of the vocabulary, bf16 AMP, Adam) is LOWERED, not run
+       (perf/run.py runs it), and the dispatch counters are held to what
+       the cell must lower: its four gated short convolutions each way
+       on the ``sconv.gated.*`` kernels (rows of
+       ``pt_causal_conv_dispatch_total`` that carry ``gated``,
+       ``impl=kernel``), and the one attention call each way through the
+       BHTD kernels at 32 / 8 heads of 64, the backward one call
+       (``form=fused``). The rotary embedding's rows are printed, not
+       held: ``rope_tile`` refuses a head of 64 (two heads a vreg) and
+       the cell's one call each way is ``impl=xla`` until a kernel takes
+       it. ``overrides`` cut the config for the CPU tests.
+    2. On the device, at the cell's channels and ``t_check`` positions:
+       the gated kernels against the composition in XLA ops (Y, dB, dC,
+       du, dW), and the kernels' ms a call by name."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import lfm2_moe as M
+    from paddle_tpu.ops import linear_attention_ops as L
+    from paddle_tpu.parallel import causal_conv as K
+
+    cfg = M.Lfm2MoeConfig(**{**dict(
+        num_hidden_layers=5, first_layer=1, vocab_size=8192,
+        held_experts=(0, 8)), **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    reads = (attention_dispatch, conv_dispatch, rope_dispatch)
+    before = [read() for read in reads]
+    _lower_train_step(main, model["loss"], seq)
+    attn, convs, ropes = (_dispatch_since(b, read)
+                          for b, read in zip(before, reads))
+    say(f"  lowered: attention {attn}; convolutions {convs}; rotary "
+        f"embeddings {ropes}")
+    kinds = [k for _, k, _ in cfg.blocks]
+    n_conv, n_attn = kinds.count("sconv"), kinds.count("attn")
+    c, taps = cfg.hidden_size, cfg.conv_L_cache
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in convs.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_conv and all(
+            k == f"kernel {direction} b1 t{seq} c{c} taps{taps} gated"
+            for k in rows),
+            f"expected {n_conv} gated convolutions {direction} on the "
+            f"sconv.gated kernels: {convs}")
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_attn and all(
+            k.startswith("bhtd ") and f" h{cfg.num_attention_heads} "
+            f"kv{cfg.num_key_value_heads} dh{cfg.head_dim} " in k
+            for k in rows),
+            f"expected {n_attn} bhtd attention call {direction} at "
+            f"{cfg.num_attention_heads} / {cfg.num_key_value_heads} heads "
+            f"of {cfg.head_dim}, none dense: {attn}")
+    _one_backward_call(attn)
+    check(sum(ropes.values()) == 2 * n_attn,
+          f"expected {n_attn} rotary embedding each way: {ropes}")
+
+    # --- on the device ----------------------------------------------------
+    r = np.random.RandomState(7)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    t = t_check
+    x = jnp.asarray(r.randn(1, t, 3 * c), bf)
+    w = jnp.asarray(r.uniform(-1, 1, (c, taps)) * taps ** -0.5, f32)
+    dy = jnp.asarray(r.randn(1, t, c), bf)
+    check(K.conv_tile(t, c, taps, bf, gated=True) is not None,
+          f"no tile for the gated convolution at t{t} c{c} taps{taps}")
+
+    def conv(x, w, dy):
+        wrapped = {"X": [x], "W": [w]}
+        y = L._gated_short_conv(wrapped, {})["Y"][0]
+        g = L._gated_short_conv_grad({**wrapped, "GRAD::Y": [dy]}, {})
+        dx = g["GRAD::X"][0]
+        return {"Y": y, "dB": dx[..., :c], "dC": dx[..., c:2 * c],
+                "du": dx[..., 2 * c:], "dW": g["GRAD::W"][0]}
+
+    def rel(a, b):
+        a, b = (jnp.asarray(v, f32) for v in (a, b))
+        return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(),
+                                                        1e-6))
+
+    kernels = jax.jit(conv)
+    got = jax.block_until_ready(kernels(x, w, dy))
+    y_ref, vjp = jax.vjp(L._gated_conv_xla, x, w)
+    dx_ref, dw_ref = vjp(dy)
+    want = {"Y": y_ref, "dB": dx_ref[..., :c], "dC": dx_ref[..., c:2 * c],
+            "du": dx_ref[..., 2 * c:], "dW": dw_ref}
+    errs = {k: rel(got[k], want[k]) for k in want}
+    # (bf16 results of float32 sums in another order)
+    check(max(errs.values()) < 2e-2,
+          f"sconv.gated kernels against the XLA writing: {errs}")
+    ms, _ = _traced_kernel_ms("chip_smoke_sconv",
+                              lambda: kernels(x, w, dy), "")
+    ms = {k: v for k, v in ms.items() if k.startswith("sconv.")}
+    # (a trace needs the chip: the CPU tests run this phase through the
+    # interpreters and read {})
+    check(jax.default_backend() != "tpu"
+          or {"sconv.gated.fwd", "sconv.gated.bwd"} <= set(ms),
+          f"kernels in the trace: {ms}")
+    row = {"attention": attn, "convolutions": convs,
+           "rotary_embeddings": ropes, "kernel_ms": ms,
+           "rel_err": {k_: round(v, 5) for k_, v in errs.items()}}
+    say(f"  sconv kernels, ms a call at t{t} c{c} taps{taps}: {ms}")
+    say(f"  sconv {row['rel_err']}")
+    return row
+
+
 # ---------------------------------------------------------------------------
 # phase 2: train
 # ---------------------------------------------------------------------------
@@ -1803,6 +1920,7 @@ def main() -> int:
     report["mla"], _ = phase("mla", mla_phase)
     report["ssm"], _ = phase("ssm", ssm_phase)
     report["mamba2"], _ = phase("mamba2", mamba2_phase)
+    report["sconv"], _ = phase("sconv", sconv_phase)
     report["rope"], _ = phase("rope", rope_phase)
 
     # 2. train: the step and the window contain the kernels, and no

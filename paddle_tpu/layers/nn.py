@@ -34,7 +34,8 @@ __all__ = [
     "mean_iou",
     "linear_chain_crf", "crf_decoding", "warpctc", "edit_distance",
     "bilinear_tensor_product", "nce", "switch_moe", "topk_moe",
-    "rms_norm", "rotary_embedding", "causal_conv1d", "gdn_gates",
+    "rms_norm", "rotary_embedding", "causal_conv1d", "short_conv_gate",
+    "gdn_gates",
     "gated_delta_rule", "gated_rms_norm", "silu", "selective_scan",
     "mamba2_scan", "diff_attention_combine",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
@@ -429,9 +430,11 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
 def causal_conv1d(input, taps=4, act="silu", param_attr=None,
                   bias_attr=False, name=None):
     """Depthwise convolution over the sequence of ``input`` [b, t, c]
-    that sees no later position, ``taps`` wide, then ``act`` ("silu" or
-    None): the short convolution in front of a linear attention or a
-    selective scan (ops/linear_attention_ops.py). Parameter [c, taps];
+    that sees no later position, ``taps`` wide, then ``act``: "silu"
+    (the short convolution in front of a linear attention or a selective
+    scan) or None (no activation: the output is the taps' sum, plus the
+    bias where there is one) (ops/linear_attention_ops.py). Parameter
+    [c, taps];
     ``bias_attr`` (False: none, as Qwen3-Next's) adds a bias [c] in
     front of ``act``, as Mamba's."""
     helper = LayerHelper("causal_conv1d", name=name)
@@ -446,6 +449,28 @@ def causal_conv1d(input, taps=4, act="silu", param_attr=None,
     out = helper.create_variable_for_type_inference(dtype=input.dtype)
     helper.append_op("causal_conv1d", inputs=inputs,
                      outputs={"Y": out}, attrs={"act": act or ""})
+    return out
+
+
+def short_conv_gate(input, taps=3, param_attr=None, name=None):
+    """LFM2's gated short convolution as one op: ``input`` [b, t, 3c] is
+    the fused projection [B | C | u] of the token (thirds in that
+    order), the result [b, t, c] is C * conv(B * u), the convolution
+    depthwise and causal over ``taps`` positions with no bias and no
+    activation (ops/linear_attention_ops.gated_short_conv: one kernel a
+    pass on a TPU, the ranges read in place; its backward pass saves
+    nothing but ``input``). Parameter [c, taps]."""
+    helper = LayerHelper("gated_short_conv", name=name)
+    if input.shape[-1] % 3:
+        raise ValueError(f"short_conv_gate: {input.shape[-1]} channels are "
+                         f"not three equal ranges")
+    c = input.shape[-1] // 3
+    w = helper.create_parameter(
+        ParamAttr._to_attr(param_attr), shape=[c, int(taps)],
+        dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(dtype=input.dtype)
+    helper.append_op("gated_short_conv", inputs={"X": input, "W": w},
+                     outputs={"Y": out})
     return out
 
 

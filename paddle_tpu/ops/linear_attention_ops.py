@@ -59,6 +59,18 @@ copy of X everywhere else (``_conv_xla``); its backward pass is its own
 grad op (``causal_conv1d_grad``), which saves nothing but X;
 ``pt_causal_conv_dispatch_total`` records which (``kernel`` or ``xla``).
 
+``gated_short_conv`` is the convolution as a sequence mixer of its own
+(LFM2): the fused projection [b, t, 3c] = [B | C | u] in, y = C *
+taps(B * u) out, no activation. A sibling op and not an attribute of
+``causal_conv1d``: its output is a third as wide as its input and its
+grad op returns d[B | C | u] as ONE tensor, so neither op's rule would
+share more than ``_conv_xla``, which they do share, and the plain op's
+lowering stays what it was byte for byte. ``sconv.gated.fwd`` /
+``sconv.gated.bwd`` where ``conv_tile(..., gated=True)`` gives a tile
+(the three ranges read in place through lane-offset index maps), the
+composition as float32 XLA ops elsewhere; its rows in
+``pt_causal_conv_dispatch_total`` carry a further label ``gated``.
+
 ``impl="recurrent"`` is the recurrence step by step (``lax.scan`` over
 positions, differentiated by jax): the fallback a caller asks for, never
 taken silently: ``pt_linear_attention_dispatch_total`` records the
@@ -91,7 +103,9 @@ _M_CONV_DISPATCH = _monitor.counter(
     "pt_causal_conv_dispatch_total",
     "causal_conv1d calls lowered, by pass (fwd, bwd), shape (batch, "
     "positions, channels), taps and impl (kernel: a gdn.conv.* Pallas "
-    "kernel; xla: float32 XLA ops over a padded copy of X)")
+    "kernel; xla: float32 XLA ops over a padded copy of X); a "
+    "gated_short_conv call's rows carry a further label gated=1 "
+    "(channels: one of its three ranges'; kernel: sconv.gated.*)")
 
 
 def _x(ins, slot, i=0):
@@ -112,15 +126,18 @@ def _note_dispatch(direction, q, v, chunk, impl):
         "chunk": str(chunk), "impl": impl})
 
 
-def _note_conv(direction, x, taps, impl):
+def _note_conv(direction, x, taps, impl, gated=False):
     from paddle_tpu.core import interp
 
     if not _monitor.enabled() or not interp.lowering_active():
         return
-    _M_CONV_DISPATCH.inc(labels={
-        "pass": direction, "shape": " ".join(
-            f"{n}{d}" for n, d in zip("btc", x.shape)),
-        "taps": str(taps), "impl": impl})
+    shape = x.shape[:-1] + (x.shape[-1] // 3,) if gated else x.shape
+    labels = {"pass": direction, "shape": " ".join(
+        f"{n}{d}" for n, d in zip("btc", shape)),
+        "taps": str(taps), "impl": impl}
+    if gated:    # a plain call's rows have no such label
+        labels["gated"] = "1"
+    _M_CONV_DISPATCH.inc(labels=labels)
 
 
 def _counts(counter, name_of):
@@ -136,7 +153,8 @@ def conv_dispatch_counts():
     counter as chip_smoke.py prints it."""
     return _counts(_M_CONV_DISPATCH, lambda lb: (
         f"{lb.get('impl', '?')} {lb.get('pass', '?')} "
-        f"{lb.get('shape', '?')} taps{lb.get('taps', '?')}"))
+        f"{lb.get('shape', '?')} taps{lb.get('taps', '?')}"
+        + (" gated" if lb.get("gated") else "")))
 
 
 def dispatch_counts():
@@ -184,7 +202,8 @@ def _causal_conv1d(ins, attrs):
     y_t = sum_j W[:, j] * x_{t - (taps - 1) + j} (positions before the
     first count as zeros; HF's ``Conv1d(groups=c, padding=taps - 1)`` cut
     to t), plus the optional Bias [c] (Mamba's convolution has one,
-    Qwen3-Next's none), then ``act`` ("silu" or ""). Products and the sum
+    Qwen3-Next's none), then ``act``: "silu", or "" for no activation
+    (Y is the taps' sum, plus the bias). Products and the sum
     in float32, the result in X's dtype: the ``gdn.conv.fwd`` kernel
     where ``parallel/causal_conv.conv_tile`` gives the call a tile (bf16
     on a TPU, no mesh, channels a multiple of 128), XLA ops everywhere
@@ -216,6 +235,59 @@ def _causal_conv1d_grad(ins, attrs):
             lambda x, w, b: _conv_xla(x, w, act, b), x, w, bias)[1](dy)
     return {"GRAD::X": [dx], "GRAD::W": [dw.astype(w.dtype)],
             "GRAD::Bias": [db.astype(bias.dtype)]}
+
+
+def _gated_conv_xla(x, w):
+    """``gated_short_conv`` as XLA ops: the composition, float32
+    throughout (the split, B * u, the taps over a padded copy, C * c)."""
+    gate, c, u = jnp.split(x.astype(jnp.float32), 3, axis=-1)
+    return (c * _conv_xla(gate * u, w, "")).astype(x.dtype)
+
+
+def _gated_conv_args(ins, direction):
+    """(X, W, ``conv_tile``'s answer for the gated call), noted."""
+    x, w = _x(ins, "X"), _x(ins, "W")
+    tile = None
+    if x.ndim == 3 and w.ndim == 2 and x.shape[2] == 3 * w.shape[0]:
+        tile = _conv.conv_tile(x.shape[1], w.shape[0], w.shape[1], x.dtype,
+                               gated=True)
+    _note_conv(direction, x, w.shape[-1], "kernel" if tile else "xla",
+               gated=True)
+    return x, w, tile
+
+
+@register_op("gated_short_conv", diff_inputs=("X", "W"))
+def _gated_short_conv(ins, attrs):
+    """X [b, t, 3c] = [B | C | u] (one projection of the token, its
+    thirds in that order), W [c, taps] -> Y [b, t, c] = C * conv(B * u):
+    LFM2's short convolution with its two input-dependent gates, the
+    convolution ``causal_conv1d``'s (depthwise, causal, zeros before the
+    first position) without bias or activation. X and Y in X's dtype
+    (bf16 in HBM under AMP); every product and the taps' sum in float32,
+    nothing rounded between them: the ``sconv.gated.fwd`` kernel where
+    ``parallel/causal_conv.conv_tile(gated=True)`` gives the call a tile
+    (bf16 on a TPU, no mesh, c a multiple of 128: no slice of X reaches
+    HBM), XLA ops everywhere else."""
+    x, w, tile = _gated_conv_args(ins, "fwd")
+    if tile:
+        return {"Y": [_conv.gated_conv_fwd(x, w, tile)]}
+    return {"Y": [_gated_conv_xla(x, w)]}
+
+
+@register_op("gated_short_conv_grad", no_grad=True)
+def _gated_short_conv_grad(ins, attrs):
+    """The backward pass of ``gated_short_conv`` from X, W and Y's
+    cotangent (nothing else is saved: B * u and its convolution are made
+    again): dX = [dB | dC | du] as ONE [b, t, 3c] tensor in X's dtype,
+    dW [c, taps] in W's. The ``sconv.gated.bwd`` kernel where the call
+    has a tile, else jax's vjp of the XLA form."""
+    x, w, tile = _gated_conv_args(ins, "bwd")
+    dy = _x(ins, "GRAD::Y").astype(x.dtype)
+    if tile:
+        dx, dw = _conv.gated_conv_bwd(x, w, dy, tile)
+    else:
+        dx, dw = jax.vjp(_gated_conv_xla, x, w)[1](dy)
+    return {"GRAD::X": [dx], "GRAD::W": [dw.astype(w.dtype)]}
 
 
 @register_op("gdn_gates", diff_inputs=("B", "A", "ALog", "DtBias"))

@@ -1,7 +1,10 @@
-"""Pallas TPU kernels for the causal depthwise convolution in front of
-the gated delta rule (ops/linear_attention_ops.causal_conv1d: a few taps
-over the sequence, every channel its own filter, then ``silu``; the
-mathematics and the precision contract are that op's docstring).
+"""Pallas TPU kernels for the causal depthwise convolution over the
+sequence (ops/linear_attention_ops.causal_conv1d: a few taps, every
+channel its own filter, then ``act``: ``"silu"`` in front of a gated
+delta rule, a selective scan or a Mamba-2 scan, ``""`` for no
+activation at all, the taps' sum as it is; the mathematics and the
+precision contract are that op's docstring), and for the gated short
+convolution that IS a sequence mixer (``gated_short_conv``, below).
 
 ``gdn.conv.fwd`` and ``gdn.conv.bwd``, one call a pass. A grid step
 works on a block of (rows of t) x (a multiple of 128 channels) of X
@@ -40,9 +43,26 @@ What XLA's ops do not do and a grid step does:
   blocks of t: the last two sequential). The wrapper folds the 8
   sublanes and hands dW back as [c, taps].
 
+``sconv.gated.fwd`` and ``sconv.gated.bwd`` (LFM2's mixer,
+ops/linear_attention_ops.gated_short_conv): X is the fused projection
+[b, t, 3c] = [B | C | u] and y = C * taps(B * u), no activation. The
+same blocks, halo, passes and reversed walk; what is new is the operand
+form: the three channel ranges are three BlockSpecs on the ONE operand
+whose index maps differ by a lane-block offset (c a multiple of 128, so
+every offset is aligned), B and u each with their halo. No slice of the
+projection, no v = B * u and no c = taps(v) reaches HBM: the forward
+reads [B | C | u] once and writes y; the backward reads [B | C | u] and
+dy once, makes v and c again in VMEM, and writes dB, dC, du into the
+three ranges of ONE [b, t, 3c] output. An output has one BlockSpec, so
+the backward grid has an innermost axis of 3 over the ranges: step 0
+does the block's whole work, writes dB and leaves dC and du in a VMEM
+scratch, steps 1 and 2 copy them out (their input blocks are step 0's,
+so nothing is fetched again).
+
 ``conv_tile`` is the one function that says tile or the XLA form
 (ops/linear_attention_ops._conv_xla, the parent's five lines), from the
-call's own shapes, the dtype, the backend and the mesh;
+call's own shapes, the dtype, the backend and the mesh, for the plain
+and the gated call alike (``gated=True`` counts the wider blocks);
 ``pt_causal_conv_dispatch_total{impl}`` records its answer.
 """
 
@@ -91,15 +111,20 @@ def _under_mesh() -> bool:
     return interp.spmd_ctx() is not None
 
 
-def _vmem_bytes(rows, lanes, taps):
+def _vmem_bytes(rows, lanes, taps, gated=False):
     """What one grid step of the backward kernel (the larger) keeps in
     VMEM: X, dY and dX blocks and the halo as bf16, W and the dW block
-    as float32, all double-buffered, and the scratch."""
-    blocks = (3 * rows + _HALO) * lanes * 2 + (taps + taps * _TAIL) * lanes * 4
-    return 2 * blocks + _TAIL * lanes * 4
+    as float32, all double-buffered, and the scratch. ``gated``: three
+    ranges of X and two halos in, and the two ranges that wait in the
+    scratch for their step."""
+    x_blocks, halos = (3, 2) if gated else (1, 1)
+    blocks = (((x_blocks + 2) * rows + halos * _HALO) * lanes * 2
+              + (taps + taps * _TAIL) * lanes * 4)
+    return (2 * blocks + _TAIL * lanes * 4
+            + (2 * rows * lanes * 2 if gated else 0))
 
 
-def conv_tile(t, c, taps, dtype, backend=None, on_mesh=None):
+def conv_tile(t, c, taps, dtype, backend=None, on_mesh=None, gated=False):
     """-> (rows, lanes): the block of X one grid step of ``gdn.conv.*``
     works on, or None where the call runs as the XLA form: no TPU
     backend (``backend``: None for this process's, with the interpreter
@@ -110,7 +135,8 @@ def conv_tile(t, c, taps, dtype, backend=None, on_mesh=None):
 
     The tile follows the shape, not a flag: 1024 rows, or all of a
     shorter sequence (padded to whole passes), by the widest lane block
-    of 512, 256, 128 that divides c."""
+    of 512, 256, 128 that divides c. ``gated``: the call is
+    ``sconv.gated.*``'s, c the channels of ONE of the three ranges."""
     on_tpu = kernels_enabled() if backend is None else backend == "tpu"
     if on_mesh is None:
         on_mesh = _under_mesh()
@@ -119,7 +145,8 @@ def conv_tile(t, c, taps, dtype, backend=None, on_mesh=None):
         return None
     rows = min(_BLOCK_ROWS, -(-t // _PASS_ROWS) * _PASS_ROWS)
     for lanes in _BLOCK_LANES:
-        if c % lanes == 0 and _vmem_bytes(rows, lanes, taps) <= _VMEM_CAP_BYTES:
+        if (c % lanes == 0
+                and _vmem_bytes(rows, lanes, taps, gated) <= _VMEM_CAP_BYTES):
             return rows, lanes
     return None
 
@@ -147,6 +174,19 @@ def _taps_sum(xs, w):
     for j in range(1, taps):
         acc = acc + xs[taps - 1 - j] * w[j]
     return acc
+
+
+def _taps_back(dpre, behind, w, rows):
+    """dx_r = sum_s w[taps - 1 - s] dpre_{r + s}: the taps walked
+    backward. Shifts UP, into the rows behind: ``behind`` [8, L] holds
+    dpre's first rows of the pass (or block) behind this one."""
+    taps = len(w)
+    ext = jnp.concatenate([dpre, behind], axis=0)
+    dx = dpre * w[taps - 1]
+    for s in range(1, taps):
+        dx = dx + (pltpu.roll(ext, rows + _TAIL - s, 0)[:rows]
+                   * w[taps - 1 - s])
+    return dx
 
 
 def _fold(p):
@@ -318,14 +358,8 @@ def _bwd_kernel(x_ref, front_ref, dy_ref, w_ref, dx_ref, dw_ref, behind_ref,
                 pre = pre + b
             sig = jax.nn.sigmoid(pre)
             dpre = dpre * (sig * (1.0 + pre * (1.0 - sig)))
-        # dx_r = sum_s w[taps - 1 - s] dpre_{r + s}: shifts UP, into the
-        # rows behind
-        ext = jnp.concatenate([dpre, behind], axis=0)
-        dx = dpre * w[taps - 1]
-        for s in range(1, taps):
-            dx = dx + (pltpu.roll(ext, rows + _TAIL - s, 0)[:rows]
-                       * w[taps - 1 - s])
-        dx_ref[at, :] = dx.astype(dx_ref.dtype)
+        dx_ref[at, :] = _taps_back(dpre, behind, w, rows).astype(
+            dx_ref.dtype)
         # (the bias's gradient, where there is one, is dpre's column
         # sum: a last accumulator beside the taps')
         return dpre[:_TAIL], tuple(
@@ -385,3 +419,190 @@ def causal_conv_bwd(x, w, dy, tile, act="silu", bias=None):
     if bias is None:
         return dx[:, :t], dw.T
     return dx[:, :t], dw[:taps].T, dw[taps]
+
+
+# ---------------------------------------------------------------------------
+# sconv.gated.fwd / sconv.gated.bwd: y = C * taps(B * u) of [B | C | u]
+# ---------------------------------------------------------------------------
+
+
+def _gate(a, b):
+    return a.astype(_F32) * b.astype(_F32)
+
+
+def _gated_block_in_front(b_front_ref, u_front_ref, first):
+    """v = B * u on the 16 rows in front of a block (zeros for a
+    sequence's first), float32."""
+    return _gate(_block_in_front(b_front_ref, first),
+                 _block_in_front(u_front_ref, first))
+
+
+def _gated_rows_in_front(b_ref, u_ref, p, rows):
+    """... on the 16 rows of the block in front of pass ``p`` > 0."""
+    return _gate(_rows_in_front(b_ref, p, rows),
+                 _rows_in_front(u_ref, p, rows))
+
+
+def _range_specs(rows, lanes, w_rows, c, blk):
+    """(the block of B, of C and of u; the 16 rows in front of B's and
+    of u's; W): ``_specs`` three times over the ONE operand [b, t, 3c],
+    range r's lane blocks ``c // lanes`` blocks behind range r - 1's."""
+    per = c // lanes
+
+    def of_range(r):
+        def blk_r(*g):
+            i, j, k = blk(*g)
+            return i, j + r * per, k
+        return _specs(rows, lanes, w_rows, blk_r)
+
+    (b_spec, b_front, w_spec), (c_spec, _, _), (u_spec, u_front, _) = (
+        of_range(r) for r in range(3))
+    return b_spec, c_spec, u_spec, b_front, u_front, w_spec
+
+
+def _gated_fwd_kernel(b_ref, c_ref, u_ref, b_front_ref, u_front_ref, w_ref,
+                      y_ref, *, taps, rows):
+    first = pl.program_id(2) == 0
+    w = _w_rows(w_ref, taps)
+
+    def one(p, front):
+        at = _pass_rows(p, rows)
+        conv = _taps_sum(
+            _shifted(front, _gate(b_ref[at, :], u_ref[at, :]), taps), w)
+        y_ref[at, :] = (c_ref[at, :].astype(_F32) * conv).astype(y_ref.dtype)
+
+    one(0, _gated_block_in_front(b_front_ref, u_front_ref, first))
+
+    def later(p, carry):
+        one(p, _gated_rows_in_front(b_ref, u_ref, p, rows))
+        return carry
+
+    jax.lax.fori_loop(1, b_ref.shape[0] // rows, later, None)
+
+
+def gated_conv_fwd(x, w, tile):
+    """x [b, t, 3c] (bf16: the fused projection [B | C | u]), w [c,
+    taps] -> y [b, t, c] in x's dtype: y_t = C_t * sum_j w[:, j] v_{t -
+    (taps - 1) + j}, v = B * u, zeros before the first position, no
+    activation. ``tile``: ``conv_tile(t, c, taps, dtype, gated=True)``'s
+    answer for the call."""
+    b, t, c3 = x.shape
+    c, taps = c3 // 3, w.shape[-1]
+    rows, lanes = tile
+    x2, wt = _operands(x, w, tile)
+    b_spec, c_spec, u_spec, b_front, u_front, w_spec = _range_specs(
+        rows, lanes, taps, c, lambda i, j, k: (i, j, k))
+    size = b * x2.shape[1] * c
+    y = pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, taps=taps,
+                          rows=min(rows, _PASS_ROWS)),
+        name="sconv.gated.fwd",
+        out_shape=jax.ShapeDtypeStruct((b, x2.shape[1], c), x.dtype),
+        grid=(b, c // lanes, x2.shape[1] // rows),
+        in_specs=[b_spec, c_spec, u_spec, b_front, u_front, w_spec],
+        out_specs=b_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        cost_estimate=pl.CostEstimate(
+            flops=size * (2 * taps + 2), transcendentals=0,
+            bytes_accessed=4 * size * x.dtype.itemsize + 4 * wt.size),
+        interpret=_INTERPRET,
+    )(x2, x2, x2, x2, x2, wt)
+    return y[:, :t]
+
+
+def _gated_bwd_kernel(b_ref, c_ref, u_ref, b_front_ref, u_front_ref, dy_ref,
+                      w_ref, dx_ref, dw_ref, behind_ref, wait_ref,
+                      *, taps, rows):
+    k = pl.program_id(2)        # the blocks of a sequence, last to first
+    r = pl.program_id(3)        # the range of dX this step writes
+    passes = b_ref.shape[0] // rows
+    # (read out here: the interpreter has no grid inside a branch)
+    first_walked = k == 0
+    first_of_lanes = first_walked & (pl.program_id(1) == 0)
+    first = k == pl.num_programs(2) - 1
+
+    @pl.when(r == 0)
+    def _():
+        w = _w_rows(w_ref, taps)
+
+        @pl.when(first_walked)
+        def _():
+            behind_ref[...] = jnp.zeros_like(behind_ref)
+
+        @pl.when(first_of_lanes)
+        def _():
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+
+        def one(p, front, carry):
+            behind, dws = carry
+            at = _pass_rows(p, rows)
+            gate, u = b_ref[at, :].astype(_F32), u_ref[at, :].astype(_F32)
+            vs = _shifted(front, gate * u, taps)
+            dy = dy_ref[at, :].astype(_F32)
+            # dC = dy * c, c = taps(v) made again
+            wait_ref[0, at, :] = (dy * _taps_sum(vs, w)).astype(
+                wait_ref.dtype)
+            dc = dy * c_ref[at, :].astype(_F32)
+            dv = _taps_back(dc, behind, w, rows)
+            dx_ref[at, :] = (dv * u).astype(dx_ref.dtype)           # dB
+            wait_ref[1, at, :] = (dv * gate).astype(wait_ref.dtype)  # du
+            return dc[:_TAIL], tuple(
+                dw + _fold(v * dc) for dw, v in zip(dws, vs))
+
+        def earlier(i, carry):
+            p = passes - 1 - i
+            return one(p, _gated_rows_in_front(b_ref, u_ref, p, rows), carry)
+
+        zeros = jnp.zeros(behind_ref.shape, _F32)
+        carry = jax.lax.fori_loop(0, passes - 1, earlier,
+                                  (behind_ref[...], (zeros,) * taps))
+        behind, dws = one(
+            0, _gated_block_in_front(b_front_ref, u_front_ref, first), carry)
+        behind_ref[...] = behind
+        for s, dw in enumerate(dws):
+            dw_ref[taps - 1 - s] += dw
+
+    for waiting in (0, 1):
+        @pl.when(r == waiting + 1)
+        def _(waiting=waiting):
+            dx_ref[...] = wait_ref[waiting]
+
+
+def gated_conv_bwd(x, w, dy, tile):
+    """The cotangents (dx [b, t, 3c] = [dB | dC | du] in x's dtype, dw
+    [c, taps] float32) of ``gated_conv_fwd`` for the cotangent ``dy`` of
+    y, from x alone: v = B * u and c = taps(v) are made again in VMEM."""
+    b, t, c3 = x.shape
+    c, taps = c3 // 3, w.shape[-1]
+    rows, lanes = tile
+    x2, wt = _operands(x, w, tile)
+    dy2 = _padded(dy.astype(x.dtype), x2.shape[1])
+    last = x2.shape[1] // rows - 1
+    per = c // lanes
+    b_spec, c_spec, u_spec, b_front, u_front, w_spec = _range_specs(
+        rows, lanes, taps, c, lambda j, i, k, r: (i, j, last - k))
+    size = b * x2.shape[1] * c
+    dx, dw = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, taps=taps,
+                          rows=min(rows, _PASS_ROWS)),
+        name="sconv.gated.bwd",
+        out_shape=(jax.ShapeDtypeStruct(x2.shape, x.dtype),
+                   jax.ShapeDtypeStruct((taps, _TAIL, c), _F32)),
+        grid=(per, b, last + 1, 3),
+        in_specs=[b_spec, c_spec, u_spec, b_front, u_front, b_spec, w_spec],
+        out_specs=(pl.BlockSpec((None, rows, lanes),
+                                lambda j, i, k, r: (i, last - k, j + r * per)),
+                   pl.BlockSpec((taps, _TAIL, lanes),
+                                lambda j, i, k, r: (0, 0, j))),
+        scratch_shapes=[pltpu.VMEM((_TAIL, lanes), _F32),
+                        pltpu.VMEM((2, rows, lanes), x.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=size * (6 * taps + 6), transcendentals=0,
+            bytes_accessed=7 * size * x.dtype.itemsize + 8 * wt.size),
+        interpret=_INTERPRET,
+    )(x2, x2, x2, x2, x2, dy2, wt)
+    return dx[:, :t], jnp.sum(dw, axis=1).T

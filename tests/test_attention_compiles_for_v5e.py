@@ -433,6 +433,31 @@ def test_causal_conv_kernels_compile(one_chip, real_kernels):
     assert f"f32[1,{t}," not in text and f"f32[1,{t + taps - 1}," not in text
 
 
+def test_gated_short_conv_kernels_compile(one_chip, real_kernels):
+    """LFM2's mixer as lfm2moe-train-s8192 lowers it: the fused
+    projection [1, 8192, 6144] bf16 = [B | C | u], 3 taps, blocks of
+    1024 x 256: three BlockSpecs on the ONE operand at lane-block
+    offsets of 8 and 16 blocks, the backward's grid axis over the three
+    ranges of its one output and its two waiting ranges in VMEM pass
+    Mosaic; no slice of the projection, nothing float32 of its size and
+    no concatenation reaches HBM."""
+    bf, t, c, taps = jnp.bfloat16, 8192, 2048, 3
+    tile = cc.conv_tile(t, c, taps, bf, "tpu", False, gated=True)
+    assert tile == (1024, 256)
+    x = jax.ShapeDtypeStruct((1, t, 3 * c), bf, sharding=one_chip)
+    dy = jax.ShapeDtypeStruct((1, t, c), bf, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((c, taps), jnp.float32, sharding=one_chip)
+
+    def both(x, w, dy):
+        return (cc.gated_conv_fwd(x, w, tile),
+                cc.gated_conv_bwd(x, w, dy, tile))
+
+    text = jax.jit(both).lower(x, w, dy).compile().as_text()
+    for name in ("sconv.gated.fwd", "sconv.gated.bwd"):
+        assert name in text, name
+    assert f"f32[1,{t}," not in text and "concatenate" not in text
+
+
 # n tokens, k a token, d, held experts: the four cells with expert layers
 _PAIR_SUMS = {"smallthinker": (16384, 6, 2560, 8),
               "qwen3next": (8192, 10, 2048, 32),

@@ -599,3 +599,54 @@ def test_mamba2_phase_fails_on_a_scan_without_the_kernel(telemetry,
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="on the mamba2.chunk kernels"):
         chip_smoke.mamba2_phase(seq=512, t_check=256, **MAMBA2_TINY)
+
+
+SCONV_TINY = dict(
+    vocab_size=50, hidden_size=128, intermediate_size=256,
+    num_attention_heads=2, num_key_value_heads=1, moe_intermediate_size=128,
+    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2))
+
+
+def _sconv_interpreters(monkeypatch):
+    from paddle_tpu.parallel import causal_conv as cc
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import pair_sum as ps
+
+    for module in (fa, cc, gm, ps):
+        monkeypatch.setattr(module, "_INTERPRET", True)
+
+
+def test_sconv_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (heads of 64
+    and three taps are the model's): layers 1-5 lower four gated
+    convolutions each way through ``sconv.gated.*`` and one attention
+    call each way, its backward one call; the rotary embedding of a
+    head of 64 is XLA's; on the device (here: the CPU) the kernels agree
+    with the composition."""
+    _sconv_interpreters(monkeypatch)
+    row = chip_smoke.sconv_phase(seq=512, t_check=256, **SCONV_TINY)
+    assert row["convolutions"] == {
+        f"kernel {d} b1 t512 c128 taps3 gated": 4 for d in ("fwd", "bwd")}
+    attn = row["attention"]
+    assert sum(attn.values()) == 2 and all(
+        k.startswith("bhtd ") and " h2 kv1 dh64 " in k for k in attn)
+    assert all(k.endswith(" form=fused") for k in attn if " bwd " in k)
+    assert row["rotary_embeddings"] == {"xla fwd bthd 64": 1,
+                                        "xla bwd bthd 64": 1}
+    assert row["kernel_ms"] == {}               # (a trace needs the chip)
+    assert set(row["rel_err"]) == {"Y", "dB", "dC", "du", "dW"}
+    assert max(row["rel_err"].values()) < 2e-2
+
+
+def test_sconv_phase_fails_on_a_convolution_without_the_kernel(
+        telemetry, monkeypatch):
+    # the convolution's kernels off (no interpreter): the calls are the
+    # composition in XLA ops, and the phase says so
+    from paddle_tpu.parallel import causal_conv as cc
+
+    _sconv_interpreters(monkeypatch)
+    monkeypatch.setattr(cc, "_INTERPRET", False)
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="on the sconv.gated kernels"):
+        chip_smoke.sconv_phase(seq=512, t_check=256, **SCONV_TINY)
