@@ -1346,10 +1346,9 @@ def bthd_family(tq, tk, h, dh) -> str:
 
 
 def _small_dropout(seed_ref, i, jc, hi, shape, p_drop):
-    """Scaled keep mask for (batch i, row-block jc, head hi). bf16 mask;
-    the bf16 rounding of 1/p_keep (~0.2%) shifts the inverted-dropout
-    scale identically in both directions, so gradients stay exact for
-    the actual forward. 16-bit random words: RNG throughput is
+    """Keep mask (bool) for (batch i, row-block jc, head hi). The callers
+    select with it and apply 1/p_keep in float32 where their algebra lets
+    it leave the (cq, tk) block. 16-bit random words: RNG throughput is
     bits-bound (uint32 masks measured 0.165 ms/call extra across
     fwd+bwd at b=64 t=256 h=8); 1/65536 keep-rate granularity is far
     below dropout's statistical noise."""
@@ -1367,7 +1366,7 @@ def _small_dropout(seed_ref, i, jc, hi, shape, p_drop):
     else:
         bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
         thresh = jnp.uint32(int(p_keep * float(2**32 - 1)))
-    return (bits < thresh).astype(jnp.bfloat16) * jnp.bfloat16(1.0 / p_keep)
+    return bits < thresh
 
 
 def _chunked_dropout(seed_ref, i, j, cq, hi, tk, p_drop, key_of_jabs):
@@ -1419,11 +1418,46 @@ def _head(x2, hi, dh):
     return x2[:, hi * dh:(hi + 1) * dh]   # lane slice: (t, dh)
 
 
+def _times(x, c):
+    return x if c == 1.0 else x * c
+
+
+def _fold_scale(q2, scale):
+    """-> (q2, what is left for the scores). A power of two times q is
+    exact in q's own type, so the forward kernels apply it to the
+    (cq, h*dh) block once and it leaves the h (cq, tk) score blocks; any
+    other scale stays on the scores. The products are the same bits
+    either way, so the backward kernels may keep the multiply on their
+    scores: with q a computed MXU operand they measured a fifth slower
+    (PERF.md section 6, PR 49)."""
+    if math.frexp(scale)[0] == 0.5:
+        return q2 * jnp.asarray(scale, q2.dtype), None
+    return q2, scale
+
+
+def _head_sums(x2, h, dh):
+    """[(rows, 1)] * h: x2 (rows, h*dh) summed over each head's lanes,
+    on whole 128-lane blocks under a mask: a head of 64 sliced out first
+    costs every odd head a lane rotation."""
+    sums = []
+    for hi in range(h):
+        lo, up = hi * dh, (hi + 1) * dh
+        a, b = lo // 128 * 128, min(-(-up // 128) * 128, h * dh)
+        blk = x2[:, a:b]
+        if (a, b) != (lo, up):
+            lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1) + a
+            blk = jnp.where((lane >= lo) & (lane < up), blk, 0.0)
+        sums.append(jnp.sum(blk, axis=-1, keepdims=True))
+    return sums
+
+
 def _scores_head(q2, k2, hi, dh, scale, bias_ref, hb, extra_mask=None):
     s = jax.lax.dot_general(
         _head(q2, hi, dh), _head(k2, hi, dh), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * scale                              # (cq, tk)
+    )                                      # (cq, tk)
+    if scale is not None:                  # not folded into q
+        s = s * scale
     if bias_ref is not None:
         b2 = bias_ref[0, min(hi, hb - 1)]  # (1|cq, tk)
         s = s + b2.astype(jnp.float32)
@@ -1438,55 +1472,71 @@ def _fwd_small_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     # all pv matmuls): groups the independent per-head matmuls so Mosaic
     # keeps the MXU busy instead of draining it at every head's softmax.
     # Measured 0.220 -> 0.152 ms/call with cq=256 (b=64 t=256 h=8 dh=64).
+    # Between a head's two matmuls its (cq, tk) block is passed over for
+    # the bias, max, exp, sum and the dropout select alone: the scale is
+    # on q, 1 / (l * p_keep) on the (cq, dh) product (PERF.md, PR 49).
     i, j = pl.program_id(0), pl.program_id(1)
-    q2, k2, v2 = q_ref[0], k_ref[0], v_ref[0]   # (cq|tk, h*dh)
+    q2, scale = _fold_scale(q_ref[0], scale)
+    k2, v2 = k_ref[0], v_ref[0]                 # (cq|tk, h*dh)
     cq, tk = q2.shape[0], k2.shape[0]
     ss = [_scores_head(q2, k2, hi, dh, scale, bias_ref, hb)
           for hi in range(h)]
     ms = [jnp.max(s, axis=-1, keepdims=True) for s in ss]
     ps = [jnp.exp(s - m) for s, m in zip(ss, ms)]
     ls = [jnp.sum(p, axis=-1, keepdims=True) for p in ps]
-    ps = [p * jax.lax.reciprocal(l) for p, l in zip(ps, ls)]
+    # the heads' statistics side by side, (cq, h): a (cq, 1) column a
+    # head takes a vreg for every 8 rows and fills one lane of it
+    m_all, l_all = (jnp.concatenate(x, axis=-1) for x in (ms, ls))
+    r_all = jax.lax.reciprocal(_times(l_all, 1.0 - p_drop))
+    lse_ref[0] = m_all + jnp.log(l_all)             # (cq, h)
     if p_drop > 0.0:
-        ps = [p * _small_dropout_abs(seed_ref, i, j, cq, hi, tk, p_drop)
-              for hi, p in enumerate(ps)]
+        keeps = [_small_dropout_abs(seed_ref, i, j, cq, hi, tk, p_drop)
+                 for hi in range(h)]
+        ps = [jnp.where(kp, p, 0.0) for kp, p in zip(keeps, ps)]
     outs = [
         jax.lax.dot_general(
             p.astype(v2.dtype), _head(v2, hi, dh), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)
+        )
         for hi, p in enumerate(ps)
     ]
-    o_ref[0] = jnp.concatenate(outs, axis=-1)       # (cq, h*dh)
-    lse_ref[0] = jnp.concatenate(
-        [m + jnp.log(l) for m, l in zip(ms, ls)], axis=-1)  # (cq, h)
+    o_ref[0] = jnp.concatenate(
+        [(o * r_all[:, hi:hi + 1]).astype(o_ref.dtype)
+         for hi, o in enumerate(outs)], axis=-1)    # (cq, h*dh)
 
 
-def _bwd_head_grads(q2, k2, v2, do2, lse2, delta2, bias_ref, scale, p_drop,
+def _bwd_head_grads(q2, k2, v2, do2, out2, lse2, bias_ref, scale, p_drop,
                     h, dh, hb, drop_fn, extra_mask=None):
     """Shared per-head backward phase: recompute scores, p = exp(s - lse),
-    dp = do @ v^T, then (pds, dss) with the dropout mask applied
-    identically to p and dp while dss uses the UNdropped p — the invariant
-    both the single-block and K-blocked fused backwards must hold."""
+    dp = do @ v^T and delta = sum(do * out) over the head's lanes, then
+    (pds, dss) with the SAME positions dropped from p and dp while dss
+    uses the UNdropped p — the invariant both the single-block and
+    K-blocked fused backwards must hold. Every factor that is constant
+    over the call is left to the caller's (., dh) results: dss lacks
+    ``scale / p_keep`` (dq and dk take it), pds ``1 / p_keep`` (dv).
+    ``scale`` multiplies the scores here as in the parent: see
+    ``_fold_scale``."""
+    p_keep = 1.0 - p_drop
     ss = [_scores_head(q2, k2, hi, dh, scale, bias_ref, hb, extra_mask)
           for hi in range(h)]
     ps = [jnp.exp(s - lse2[:, hi:hi + 1]) for hi, s in enumerate(ss)]
     dps = [jax.lax.dot_general(
         _head(do2, hi, dh), _head(v2, hi, dh), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) for hi in range(h)]
+    deltas = _head_sums(do2.astype(jnp.float32)
+                        * _times(out2.astype(jnp.float32), p_keep), h, dh)
     if p_drop > 0.0:
-        drops = [drop_fn(hi) for hi in range(h)]
-        pds = [p * d for p, d in zip(ps, drops)]
-        dps = [dp * d for dp, d in zip(dps, drops)]
+        keeps = [drop_fn(hi) for hi in range(h)]
+        pds = [jnp.where(kp, p, 0.0) for kp, p in zip(keeps, ps)]
+        dps = [jnp.where(kp, dp, 0.0) for kp, dp in zip(keeps, dps)]
     else:
         pds = ps
-    dss = [p * (dp - delta2[:, hi:hi + 1]) * scale
-           for hi, (p, dp) in enumerate(zip(ps, dps))]
+    dss = [p * (dp - d) for p, dp, d in zip(ps, dps, deltas)]
     return pds, dss
 
 
 def _dqdkv_small_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                        lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                        out_ref, lse_ref, dq_ref, dk_ref, dv_ref,
                         dk_scr, dv_scr, *, scale, p_drop, nq, h, dh, hb):
     """Fused backward: one kernel computes dq, dk, dv.
 
@@ -1504,29 +1554,30 @@ def _dqdkv_small_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     q2, k2, v2, do2 = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    lse2, delta2 = lse_ref[0], delta_ref[0]         # (cq, h)
     cq, tk = q2.shape[0], k2.shape[0]
     pds, dss = _bwd_head_grads(
-        q2, k2, v2, do2, lse2, delta2, bias_ref, scale, p_drop, h, dh, hb,
+        q2, k2, v2, do2, out_ref[0], lse_ref[0], bias_ref, scale, p_drop,
+        h, dh, hb,
         lambda hi: _small_dropout_abs(seed_ref, i, j, cq, hi, tk, p_drop))
+    inv_keep = 1.0 / (1.0 - p_drop)
     dqs = [jax.lax.dot_general(
         ds.astype(k2.dtype), _head(k2, hi, dh), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-        for hi, ds in enumerate(dss)]
-    dq_ref[0] = jnp.concatenate(dqs, axis=-1)       # (cq, h*dh)
-    for hi in range(h):
-        # dv_h += pd^T @ do_h ; dk_h += ds^T @ q_h   (K = cq, full fill)
-        dv_scr[:, hi * dh:(hi + 1) * dh] += jax.lax.dot_general(
-            pds[hi].astype(do2.dtype), _head(do2, hi, dh),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dk_scr[:, hi * dh:(hi + 1) * dh] += jax.lax.dot_general(
-            dss[hi].astype(q2.dtype), _head(q2, hi, dh),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32) for hi, ds in enumerate(dss)]
+    dq_ref[0] = _times(jnp.concatenate(dqs, axis=-1),
+                       scale * inv_keep).astype(dq_ref.dtype)  # (cq, h*dh)
+    # dv_h += pd^T @ do_h ; dk_h += ds^T @ q_h   (K = cq, full fill),
+    # ONE read-modify-write a scratch as in the K-blocked kernel
+    for scr, xs, y2 in ((dv_scr, pds, do2), (dk_scr, dss, q2)):
+        scr[...] += jnp.concatenate([jax.lax.dot_general(
+            x.astype(y2.dtype), _head(y2, hi, dh), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            for hi, x in enumerate(xs)], axis=-1)
 
     @pl.when(j == nq - 1)
     def _emit():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[0] = _times(dk_scr[...],
+                           scale * inv_keep).astype(dk_ref.dtype)
+        dv_ref[0] = _times(dv_scr[...], inv_keep).astype(dv_ref.dtype)
 
 
 def _bias_spec_bthd(bias, cq, tk):
@@ -1600,13 +1651,14 @@ def _fwd_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _compute():
-        q2, k2, v2 = q_ref[0], k_ref[0], v_ref[0]  # (cq, hdh) / (bk, hdh)
+        q2, s_scale = _fold_scale(q_ref[0], scale)
+        k2, v2 = k_ref[0], v_ref[0]                # (cq, hdh) / (bk, hdh)
         cq = q2.shape[0]
         mask = _kb_causal_mask(cq, bk, j, kk) if causal else None
         # Phase-split with ONE batched read-modify-write of each scratch
         # per program (per-head scratch RMW serialized the loop: measured
         # 0.78 ms/call before, vs 0.087 analytic, at t=1024).
-        ss = [_scores_head(q2, k2, hi, dh, scale, bias_ref, hb, mask)
+        ss = [_scores_head(q2, k2, hi, dh, s_scale, bias_ref, hb, mask)
               for hi in range(h)]                    # (cq, bk) each
         m_prev = m_scr[...]                          # (cq, h)
         l_prev = l_scr[...]
@@ -1620,7 +1672,8 @@ def _fwd_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             [jnp.sum(p, axis=-1, keepdims=True) for p in ps], axis=-1)
         m_scr[...] = m_new
         if p_drop > 0.0:
-            ps = [p * _kb_dropout(seed_ref, i, j, cq, hi, kk, bk, p_drop)
+            ps = [jnp.where(_kb_dropout(seed_ref, i, j, cq, hi, kk, bk,
+                                        p_drop), p, 0.0)
                   for hi, p in enumerate(ps)]
         pv = jnp.concatenate(
             [jax.lax.dot_general(
@@ -1646,14 +1699,15 @@ def _fwd_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         cq = q_ref.shape[1]
         l_all = l_scr[...]
         recip_full = jnp.concatenate(
-            [jnp.broadcast_to(jax.lax.reciprocal(l_all[:, hi:hi + 1]),
-                              (cq, dh)) for hi in range(h)], axis=-1)
+            [jnp.broadcast_to(
+                jax.lax.reciprocal(l_all[:, hi:hi + 1] * (1.0 - p_drop)),
+                (cq, dh)) for hi in range(h)], axis=-1)
         o_ref[0] = (acc_scr[...] * recip_full).astype(o_ref.dtype)
         lse_ref[0] = m_scr[...] + jnp.log(l_all)
 
 
 def _dqdkv_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                     out_ref, lse_ref, dq_ref, dk_ref, dv_ref,
                      dq_scr, dk_scr, dv_scr, *, scale, p_drop, nq, nk, h,
                      dh, hb, bk, causal=False):
     """Fused k-blocked backward: dq accumulates over kk per q-chunk;
@@ -1670,14 +1724,15 @@ def _dqdkv_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    inv_keep = 1.0 / (1.0 - p_drop)
+
     def _compute():
         q2, k2, v2, do2 = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse2, delta2 = lse_ref[0], delta_ref[0]         # (cq, h)
         cq = q2.shape[0]
         mask = _kb_causal_mask(cq, bk, j, kk) if causal else None
         pds, dss = _bwd_head_grads(
-            q2, k2, v2, do2, lse2, delta2, bias_ref, scale, p_drop, h, dh,
-            hb,
+            q2, k2, v2, do2, out_ref[0], lse_ref[0], bias_ref, scale,
+            p_drop, h, dh, hb,
             lambda hi: _kb_dropout(seed_ref, i, j, cq, hi, kk, bk, p_drop),
             extra_mask=mask)
         # Batched scratch RMW: one load+store per scratch per program
@@ -1710,12 +1765,14 @@ def _dqdkv_kb_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
     @pl.when(kk == nk - 1)
     def _emit_dq():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = _times(dq_scr[...],
+                           scale * inv_keep).astype(dq_ref.dtype)
 
     @pl.when(jnp.logical_and(j == nq - 1, kk == nk - 1))
     def _emit_dkv():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[0] = _times(dk_scr[...],
+                           scale * inv_keep).astype(dk_ref.dtype)
+        dv_ref[0] = _times(dv_scr[...], inv_keep).astype(dv_ref.dtype)
 
 
 def _bthd_kb_fwd(q, k, v, bias, seed, scale, p_drop, causal=False):
@@ -1781,8 +1838,6 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
     cq = min(_pick_cq(tq, bk, h), _CQ)
     nq, nk = tq // cq, tk // bk
     hdh = h * dh
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
     base_specs = [
         pl.BlockSpec((1, cq, hdh), lambda i, j, kk, *_: (i, j, 0)),
         pl.BlockSpec((1, bk, hdh), lambda i, j, kk, *_: (i, kk, 0)),
@@ -1795,19 +1850,20 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
         base_specs.append(_bias_spec_kb(bias, cq, bk))
         base_args.append(bias)
     tail_specs = [
-        pl.BlockSpec((1, cq, hdh), lambda i, j, kk, *_: (i, j, 0)),
-        pl.BlockSpec((1, cq, h), lambda i, j, kk, *_: (i, j, 0)),
-        pl.BlockSpec((1, cq, h), lambda i, j, kk, *_: (i, j, 0)),
+        pl.BlockSpec((1, cq, hdh), lambda i, j, kk, *_: (i, j, 0)),   # do
+        pl.BlockSpec((1, cq, hdh), lambda i, j, kk, *_: (i, j, 0)),   # out
+        pl.BlockSpec((1, cq, h), lambda i, j, kk, *_: (i, j, 0)),     # lse
     ]
-    tail_args = [g.reshape(b, tq, hdh), lse[..., 0], delta[..., 0]]
+    tail_args = [g.reshape(b, tq, hdh), out.reshape(b, tq, hdh),
+                 lse[..., 0]]
     if bias is not None:
         kernel = functools.partial(_dqdkv_kb_kernel, scale=scale,
                                    p_drop=p_drop, nq=nq, nk=nk, h=h, dh=dh,
                                    hb=hb, bk=bk, causal=causal)
     else:
         kernel = functools.partial(
-            lambda sr, qr, kr, vr, dor, lr, der, dqr, dkr, dvr, dqs, dks,
-            dvs, **kw: _dqdkv_kb_kernel(sr, qr, kr, vr, None, dor, lr, der,
+            lambda sr, qr, kr, vr, dor, outr, lr, dqr, dkr, dvr, dqs, dks,
+            dvs, **kw: _dqdkv_kb_kernel(sr, qr, kr, vr, None, dor, outr, lr,
                                         dqr, dkr, dvr, dqs, dks, dvs, **kw),
             scale=scale, p_drop=p_drop, nq=nq, nk=nk, h=h, dh=dh, hb=hb,
             bk=bk, causal=causal,
@@ -1850,10 +1906,11 @@ def _bthd_kb_bwd(q, k, v, bias, seed, out, lse, g, scale, p_drop,
 
 
 def bthd_dropout_masks(b, tq, tk, h, dh, p_drop, seed):
-    """The BTHD kernels' scaled keep masks as one [b, tq, h, tk] f32
-    array, regenerated on the device by the kernels' OWN helpers and
-    keys. A dense reference fed these masks must agree with the kernels
-    (the hardware tests and chip_smoke.py use it); needs the TPU PRNG."""
+    """The BTHD kernels' scaled keep masks (keep / p_keep) as one
+    [b, tq, h, tk] f32 array, regenerated on the device by the kernels'
+    OWN helpers and keys. A dense reference fed these masks must agree
+    with the kernels (the hardware tests and chip_smoke.py use it);
+    needs the TPU PRNG."""
     family = bthd_family(tq, tk, h, dh)
     if family not in ("bthd_small", "bthd_kblock"):
         raise ValueError(f"no BTHD dropout stream for family '{family}'")
@@ -1869,7 +1926,8 @@ def bthd_dropout_masks(b, tq, tk, h, dh, p_drop, seed):
                      for kk in range(tk // bk)], axis=-1)
             else:
                 m = _small_dropout_abs(seed_ref, i, j, cq, hi, tk, p_drop)
-            o_ref[0, :, hi * tk:(hi + 1) * tk] = m.astype(jnp.float32)
+            o_ref[0, :, hi * tk:(hi + 1) * tk] = jnp.where(
+                m, jnp.float32(1.0 / (1.0 - p_drop)), 0.0)
 
     operands = (_seed_arr(seed),)
     out = pl.pallas_call(
@@ -1879,6 +1937,7 @@ def bthd_dropout_masks(b, tq, tk, h, dh, p_drop, seed):
             out_specs=pl.BlockSpec((1, cq, h * tk),
                                    lambda i, j, *_: (i, j, 0))),
         out_shape=_result(operands, (b, tq, h * tk), jnp.float32),
+        interpret=_INTERPRET,
     )(*operands)
     return out.reshape(b, tq, h, tk)
 
@@ -2014,13 +2073,14 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         _, vjp = jax.vjp(f, q, k, v)
         return vjp(g)
 
-    # The fused kernel keeps four (cq, tk) f32 temps per head live; halve
-    # the chunk relative to the forward so the per-head phase temps fit
-    # Mosaic's scoped-vmem budget. Dropout streams are chunk-independent.
-    cq = min(_pick_cq(tq, tk, h), _CQ)
+    # The forward's chunk: at tq = 256 ONE step a batch row, so dk and dv
+    # are gathered once (two steps of 128 rows count 8596 bundles of
+    # libtpu's schedule a row, one of 256 counts 7240: PERF.md section 6,
+    # PR 49). It fits Mosaic's default scoped VMEM, alone and inside a
+    # While body, and a higher limit only slows it: 2% at 32M, 6% at 64M
+    # (same section). Dropout streams are chunk-independent.
+    cq = _pick_cq(tq, tk, h)
     nq = tq // cq
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)         # [b, tq, h, 1]
     hdh = h * dh
     base_specs = [
         pl.BlockSpec((1, cq, hdh), lambda i, j, *_: (i, j, 0)),   # q
@@ -2034,10 +2094,14 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         base_args = base_args + [bias]
     tail_specs = [
         pl.BlockSpec((1, cq, hdh), lambda i, j, *_: (i, j, 0)),   # do
+        pl.BlockSpec((1, cq, hdh), lambda i, j, *_: (i, j, 0)),   # out
         pl.BlockSpec((1, cq, h), lambda i, j, *_: (i, j, 0)),     # lse
-        pl.BlockSpec((1, cq, h), lambda i, j, *_: (i, j, 0)),     # delta
     ]
-    tail_args = [g.reshape(b, tq, hdh), lse[..., 0], delta[..., 0]]
+    # delta = sum(do * out) is made in the kernel from the two blocks it
+    # holds: in XLA it was a float32 pass over [b, tq, h, dh] and a
+    # [b, tq, h] result with h on the lanes (PERF.md, PR 49)
+    tail_args = [g.reshape(b, tq, hdh), out.reshape(b, tq, hdh),
+                 lse[..., 0]]
 
     hb = 1 if bias is None else bias.shape[1]
     if bias is not None:
@@ -2045,8 +2109,8 @@ def flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                                    p_drop=p_drop, nq=nq, h=h, dh=dh, hb=hb)
     else:
         kernel = functools.partial(
-            lambda sr, qr, kr, vr, dor, lr, der, dqr, dkr, dvr, dks, dvs,
-            **kw: _dqdkv_small_kernel(sr, qr, kr, vr, None, dor, lr, der,
+            lambda sr, qr, kr, vr, dor, outr, lr, dqr, dkr, dvr, dks, dvs,
+            **kw: _dqdkv_small_kernel(sr, qr, kr, vr, None, dor, outr, lr,
                                       dqr, dkr, dvr, dks, dvs, **kw),
             scale=scale, p_drop=p_drop, nq=nq, h=h, dh=dh, hb=hb,
         )

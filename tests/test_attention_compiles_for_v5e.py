@@ -633,3 +633,69 @@ def test_embed_grad_kernel_compiles_at_the_cells_calls(cell, dtype, one_chip,
     text = jax.jit(lambda g, ids: eg.embed_grad(g, ids, vocab, tile)).lower(
         g, ids).compile().as_text()
     assert "embed.grad" in text and " scatter(" not in text
+
+
+# The BTHD-small pair at the calls of the three cells that lower it (PR
+# 49): (b, t, heads of 64, rows of the bias [b, 1, rows, t]); dropout 0.1
+# as the cells have it. transformer-base's encoder and cross attention
+# carry the pad bias, its decoder's self attention the causal one.
+_SMALL_CALLS = {
+    "tbase_pad": (128, 256, 8, 1),
+    "tbase_causal": (128, 256, 8, 256),
+    "dp4_pad": (32, 256, 8, 1),
+    "bert": (256, 128, 12, 1),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_SMALL_CALLS))
+def test_bthd_small_pair_compiles_at_the_cells_calls(call, one_chip,
+                                                     real_kernels):
+    """Forward and backward are ONE Mosaic call each; the backward makes
+    delta itself, so XLA reduces nothing beside it."""
+    b, t, h, bias_rows = _SMALL_CALLS[call]
+    assert fa.bthd_family(t, t, h, 64) == "bthd_small"
+
+    def arg(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x, bias = arg(b, t, h, 64), arg(b, 1, bias_rows, t, dt=jnp.float32)
+    seed, lse = arg(dt=jnp.int32), arg(b, t, h, 1, dt=jnp.float32)
+    fwd = jax.jit(lambda q, k, v, bias, seed: fa.flash_attention_bthd_fwd(
+        q, k, v, bias, seed, None, 0.1)).lower(x, x, x, bias, seed)
+    bwd = jax.jit(
+        lambda q, k, v, bias, seed, out, lse, g: fa.flash_attention_bthd_bwd(
+            q, k, v, bias, seed, out, lse, g, None, 0.1)
+    ).lower(x, x, x, bias, seed, x, lse, x)
+    for lowered, name in ((fwd, "fwd"), (bwd, "bwd")):
+        text = lowered.compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert f"attn.bthd_small.{name}/pallas_call" in text
+        assert " reduce(" not in text
+
+
+def test_bthd_small_pair_compiles_inside_a_while_body(one_chip, real_kernels):
+    """As a ``run_steps`` window lowers it: the backward at its chunk of
+    256 rows asks for no VMEM beyond Mosaic's default (a higher limit
+    measured slower), and a While body is where the K-blocked backward
+    once landed over it."""
+    b, t, h, _ = _SMALL_CALLS["tbase_causal"]
+
+    def arg(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x, bias = arg(b, t, h, 64), arg(b, 1, t, t, dt=jnp.float32)
+    seed, lse = arg(dt=jnp.int32), arg(b, t, h, 1, dt=jnp.float32)
+
+    def window(q, k, v, bias, seed, out, lse, g):
+        def step(_, qkv):
+            dq, dk, dv = fa.flash_attention_bthd_bwd(
+                *qkv, bias, seed, out, lse, g, None, 0.1)
+            o, _ = fa.flash_attention_bthd_fwd(dq, dk, dv, bias, seed, None,
+                                               0.1)
+            return o, dk, dv
+        return jax.lax.fori_loop(0, 3, step, (q, k, v))
+
+    text = jax.jit(window).lower(
+        x, x, x, bias, seed, x, lse, x).compile().as_text()
+    assert " while(" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

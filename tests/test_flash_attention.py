@@ -8,6 +8,8 @@ hardware PRNG (``prng_seed`` has no CPU lowering): those cases live in
 tests/test_flash_attention_tpu.py and run on the chip.
 """
 
+import math
+
 import numpy as np
 import pytest
 
@@ -689,3 +691,125 @@ def test_a_window_as_long_as_the_row_lowers_the_plain_fused_call():
 
     assert text(384) == text(None) == text(1000)
     assert text(383) != text(None)
+
+
+# --- BTHD-small: the score block is passed over once (PR 49) ---
+# The scale on q where it is a power of two, 1 / (l * p_keep) behind P.V,
+# dropout as one select, delta made in the backward kernel. The TPU's
+# PRNG has no CPU lowering, so the dropout cases swap the kernels' ONE
+# source of kept positions (``fa._small_dropout``) for a pattern the
+# interpreter can make; tests/test_flash_attention_tpu.py holds the real
+# stream to the same composition on the chip.
+
+
+def _stand_in_keep(seed_ref, i, jc, hi, shape, p_drop):
+    """About 1 - p_drop of (row, col) kept, another set for every
+    (seed, batch row, 128-row block, head), as the PRNG's keys give."""
+    r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    x = (r * 7 + c * 13 + seed_ref[0] + (i + seed_ref[1]) * 5 + jc * 11
+         + hi * 3)
+    return x % 10 >= int(round(p_drop * 10))
+
+
+def _small_case(kind, dh, cq):
+    """q, k, v, g, bias at a call that walks tq in blocks of ``cq``
+    rows, forward and backward."""
+    b, h = 2, 2
+    tq, tk = (cq, 128 if cq == 256 else 256) if kind == "cross" \
+        else (cq, cq)
+    assert fa.bthd_family(tq, tk, h, dh) == "bthd_small"
+    assert fa._pick_cq(tq, tk, h) == cq
+    q, g = (jnp.asarray(_rand((b, tq, h, dh), s) * 0.3) for s in (11, 14))
+    k, v = (jnp.asarray(_rand((b, tk, h, dh), s) * 0.3) for s in (12, 13))
+    bias = {"none": None, "pad": _pad_bias(b, tk, 17),
+            "cross": _pad_bias(b, tk, 5)}.get(kind)
+    if kind == "causal":
+        bias = _pad_bias(b, tk, 9) + _causal_bias(b, tq)
+    return q, k, v, g, bias
+
+
+def _dense_bthd(q, k, v, bias, masks):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * np.float32(q.shape[-1] ** -0.5)
+    if bias is not None:
+        s = s + bias
+    p = jax.nn.softmax(s, axis=-1)
+    if masks is not None:               # [b, tq, h, tk]: keep / p_keep
+        p = p * jnp.swapaxes(masks, 1, 2)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("cq", [128, 256])
+@pytest.mark.parametrize("dh", [64, 32], ids=["scale_pow2", "scale_other"])
+@pytest.mark.parametrize("p_drop", [0.0, 0.1])
+@pytest.mark.parametrize("kind", ["none", "pad", "causal", "cross"])
+def test_bthd_small_matches_dense_fed_its_own_masks(kind, p_drop, dh, cq,
+                                                    monkeypatch):
+    monkeypatch.setattr(fa, "_small_dropout", _stand_in_keep)
+    q, k, v, g, bias = _small_case(kind, dh, cq)
+    (b, tq, h, _), tk = q.shape, k.shape[1]
+    assert (math.frexp(dh ** -0.5)[0] == 0.5) == (dh == 64)
+    seed = jnp.int32(5) if p_drop else None
+    masks = fa.bthd_dropout_masks(b, tq, tk, h, dh, p_drop, seed) \
+        if p_drop else None
+    if p_drop:      # float32, keep / p_keep, about p_keep of them kept
+        kept = np.asarray(masks) > 0
+        assert masks.dtype == jnp.float32 and 0.85 < kept.mean() < 0.95
+        np.testing.assert_array_equal(
+            np.asarray(masks)[kept], np.float32(1.0 / (1.0 - p_drop)))
+    out, lse = fa.flash_attention_bthd_fwd(q, k, v, bias, seed,
+                                           p_drop=p_drop)
+    dq, dk, dv = fa.flash_attention_bthd_bwd(q, k, v, bias, seed, out, lse,
+                                             g, p_drop=p_drop)
+    want, vjp = jax.vjp(lambda q, k, v: _dense_bthd(q, k, v, bias, masks),
+                        q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    for got, ref, name in zip((dq, dk, dv), vjp(g), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-5, err_msg=name)
+
+
+def test_bthd_small_forward_and_backward_keep_the_masks_positions(
+        monkeypatch):
+    """Read the kept positions back out of each pass: with q = 0 every
+    p is 1 / tk, so v = I shows the forward's in out and do = I the
+    backward's in dv; both are ``bthd_dropout_masks``'s."""
+    monkeypatch.setattr(fa, "_small_dropout", _stand_in_keep)
+    b, t, h, p_drop, seed = 2, 128, 2, 0.1, jnp.int32(3)
+    eye = jnp.broadcast_to(jnp.eye(t, dtype=jnp.float32)[None, :, None, :],
+                           (b, t, h, t))
+    q = jnp.zeros_like(eye)
+    kept = np.asarray(fa.bthd_dropout_masks(b, t, t, h, t, p_drop, seed)) > 0
+    out, lse = fa.flash_attention_bthd_fwd(q, q, eye, None, seed,
+                                           p_drop=p_drop)
+    np.testing.assert_array_equal(np.asarray(out) > 0, kept)
+    _, _, dv = fa.flash_attention_bthd_bwd(q, q, eye, None, seed, out, lse,
+                                           eye, p_drop=p_drop)
+    # dv[b, k, h, d] = sum_q keep[q, k] p do[q, d] = keep[d, k] / tk / p_keep
+    np.testing.assert_array_equal(
+        np.asarray(dv).transpose(0, 3, 2, 1) > 0, kept)
+
+
+@pytest.mark.parametrize("family,tk", [("bthd_small", 256),
+                                       ("bthd_kblock", 1024)])
+def test_bthd_backward_makes_delta_in_the_kernel(family, tk):
+    """Nothing of g * out is reduced outside the ONE Mosaic call: it
+    takes ``out`` as an operand and makes delta from the blocks it
+    holds."""
+    b, tq, h, dh = 2, 128, 2, 64
+    assert fa.bthd_family(tq, tk, h, dh) == family
+    q, out, g = (jnp.zeros((b, tq, h, dh), jnp.float32) for _ in range(3))
+    k = v = jnp.zeros((b, tk, h, dh), jnp.float32)
+    lse = jnp.zeros((b, tq, h, 1), jnp.float32)
+
+    def bwd(q, k, v, out, lse, g):
+        return fa.flash_attention_bthd_bwd(q, k, v, None, None, out, lse, g)
+
+    jaxpr = jax.make_jaxpr(bwd)(q, k, v, out, lse, g).jaxpr
+    calls = _pallas_calls(bwd, q, k, v, out, lse, g)
+    assert [name for name, _, _ in calls] == [f"attn.{family}.bwd"]
+    outside = [e.primitive.name for e in jaxpr.eqns
+               if e.primitive.name != "pallas_call"]
+    assert not {"reduce_sum", "mul", "dot_general"} & set(outside), outside
+    shapes = [tuple(x.aval.shape) for x in calls[0][2].invars]
+    assert shapes.count((b, tq, h * dh)) == 3       # q, do, out

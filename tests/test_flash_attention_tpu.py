@@ -34,8 +34,15 @@ def _rand(shape, seed):
 
 
 @pytest.mark.parametrize("b,tq,tk,h,dh,pd", [
-    (2, 256, 256, 3, 64, 0.3),     # single-block, fwd cq=256 vs bwd 128
+    (2, 256, 256, 3, 64, 0.3),     # single-block, cq=256 in both passes
     (1, 128, 1024, 2, 64, 0.3),    # K-blocked
+    # PR 49, the three cells' heads and rate: transformer-base's 8 and
+    # BERT's 12 heads of 64 (the scale a power of two, folded into q),
+    # cross attention with tq != tk, and a scale that stays on the scores
+    (2, 256, 256, 8, 64, 0.1),
+    (2, 128, 128, 12, 64, 0.1),
+    (2, 128, 256, 8, 64, 0.1),
+    (2, 256, 256, 4, 32, 0.1),
 ])
 def test_dropout_fwd_bwd_mask_consistency(b, tq, tk, h, dh, pd):
     seedv = 11
@@ -64,6 +71,29 @@ def test_dropout_fwd_bwd_mask_consistency(b, tq, tk, h, dh, pd):
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b_, np.float32), atol=0.05)
+    # keep / p_keep in float32: 1 / 0.9, not bf16's 1.109375
+    kept = np.asarray(masks)[np.asarray(masks) > 0]
+    np.testing.assert_array_equal(kept, np.float32(1.0 / (1.0 - pd)))
+
+
+# CRC32 of np.packbits(bthd_dropout_masks(...) > 0), read on a v5e from
+# the commit BEFORE PR 49 (my chip run, PR 49): the select keeps exactly
+# the positions the bf16 mask chain kept, for the same seed.
+_STREAM = {
+    (2, 256, 256, 8, 64, 0.1, 5): 1760836917,
+    (2, 128, 128, 12, 64, 0.1, 5): 2692083996,
+    (2, 256, 256, 3, 64, 0.3, 11): 2881656903,
+    (1, 128, 1024, 2, 64, 0.3, 11): 1113453391,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STREAM))
+def test_dropout_stream_keeps_the_positions_it_kept_before(case):
+    import zlib
+
+    *shape, pd, seed = case
+    kept = np.asarray(fa.bthd_dropout_masks(*shape, pd, seed)) > 0
+    assert zlib.crc32(np.packbits(kept).tobytes()) == _STREAM[case]
 
 
 @pytest.mark.parametrize("b,tq,tk,h,dh", [
