@@ -887,6 +887,7 @@ class Executor:
                 # post-mortem wants; self-gating on the sampling period
                 _monitor.sample_device_memory(start, n)
             if rec is not None:
+                rec["t0"] = t_run0
                 rec["wall_ms"] = (time.perf_counter() - t_run0) * 1e3
                 if t_x1 > 0.0:  # phases only for calls that completed (a
                     # window's are whole-window totals, one verdict entry)

@@ -115,6 +115,7 @@ from __future__ import annotations
 import atexit
 import collections
 import contextlib
+import gc
 import io
 import json
 import os
@@ -157,8 +158,14 @@ def enabled() -> bool:
 def _sync_from_flags(_value=None):
     global _enabled
     _enabled = bool(_flags.get_flag("telemetry"))
+    # the collector's hook is held only while telemetry is on: with it
+    # off a collection calls nothing of ours
     if _enabled:
         _listen_to_jax()
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+    elif _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def enable(step_log_path: Optional[str] = None,
@@ -480,6 +487,7 @@ def reset():
         _step_log_path = ""
         _step_seq = 0
         _STEP_RING.clear()
+        _GC_PAUSES.clear()
     with _COMPILE_LOCK:
         _COMPILE_REPORTS.clear()
     _STALLS.clear()
@@ -644,6 +652,14 @@ STEP_LOG_FIELDS: Dict[str, tuple] = {
     "steps": ((int,), False, "window length (kind == 'window' only)"),
     "wall_ms": ((float, int), True,
                 "host wall time of the run call, perf_counter-based"),
+    "t0": ((float, int), False,
+           "perf_counter (seconds) at the call's entry, the clock of "
+           "wall_ms: the distance between two records' t0 is the step's "
+           "cadence as the caller lived it"),
+    "gc_ms": ((float, int), False,
+              "pauses of CPython's collector (pt_gc_pause_seconds) "
+              "since the previous record: the call a pause fell inside, "
+              "or the one behind the wait it fell inside"),
     "compile_ms": ((float, int, type(None)), True,
                    "the executor's own build of a missed entry (block "
                    "analysis and the jit wrap; the trace, the lowering "
@@ -731,8 +747,9 @@ def step_records_active() -> bool:
 # Bounded flight-recorder ring of the last N step records. Fed by every
 # log_step call; served by /steps and dumped by the stall watchdog. The
 # deque bound is the memory contract — a week-long job holds the same
-# 256 records as a smoke test.
-STEP_RING_CAPACITY = 256
+# 2048 records (flat dicts of some fifteen scalars: about 2 MB; a 20 s
+# window at 10 ms a step) as a smoke test.
+STEP_RING_CAPACITY = 2048
 _STEP_RING: collections.deque = collections.deque(maxlen=STEP_RING_CAPACITY)
 
 
@@ -748,9 +765,55 @@ def recent_steps(n: Optional[int] = None) -> List[Dict[str, Any]]:
 
 _step_log_warned = False
 
+# The collector's pauses, (generation, seconds) in order, between the
+# hook and the next step record. The hook runs wherever an allocation
+# trips the collector, inside a metric's mutation under _LOCK too: it
+# takes no lock and appends here, log_step drains.
+# (bounded for a process that logs no step)
+_GC_PAUSES: collections.deque = collections.deque(maxlen=STEP_RING_CAPACITY)
+_gc_pause_seconds: Optional[Histogram] = None
+_gc_t0 = 0.0
+_gc_span = None     # the open "gc.collect" annotation
+
+
+def _on_gc(phase: str, info: Dict[str, int]):
+    """``gc.callbacks`` hook (one collection at a time, a process): the
+    pause on perf_counter and, inside a ``jax.profiler`` session, a
+    ``gc.collect`` annotation on the device trace's clock, as
+    ``span`` gives the executor's."""
+    global _gc_t0, _gc_span
+    if phase == "start":
+        _gc_span = _TraceAnnotation("gc.collect",
+                                    generation=info["generation"])
+        _gc_span.__enter__()
+        _gc_t0 = time.perf_counter()
+    elif _gc_span is not None:
+        _GC_PAUSES.append((info["generation"],
+                           time.perf_counter() - _gc_t0))
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def _take_gc_ms() -> float:
+    """Drain the pauses into ``pt_gc_pause_seconds``: their sum, ms."""
+    global _gc_pause_seconds
+    if _gc_pause_seconds is None:
+        _gc_pause_seconds = histogram(
+            "pt_gc_pause_seconds",
+            "pauses of CPython's cyclic collector, by generation")
+    total = 0.0
+    while True:
+        try:    # (two threads may log at once: the loser finds it empty)
+            gen, s = _GC_PAUSES.popleft()
+        except IndexError:
+            return total * 1e3
+        _gc_pause_seconds.observe(s, labels={"generation": gen})
+        total += s
+
 
 def log_step(record: Dict[str, Any]):
-    """Record one step: fills ``v``, ``ts`` and ``seq``, appends to the
+    """Record one step: fills ``v``, ``ts``, ``seq`` and ``gc_ms`` (the
+    collector's pauses since the previous record), appends to the
     bounded ring buffer, and — when ``step_log_path`` is configured —
     appends a JSONL line (flushed per record so a live tail sees every
     one). No-op when telemetry is off. An unwritable path warns once and
@@ -761,8 +824,10 @@ def log_step(record: Dict[str, Any]):
     if not _enabled:
         return
     path = _flags.get_flag("step_log_path")
+    gc_ms = _take_gc_ms()
     with _STEP_LOG_LOCK:
         record = dict(record)
+        record.setdefault("gc_ms", gc_ms)
         record.setdefault("v", STEP_LOG_SCHEMA_VERSION)
         record.setdefault("ts", time.time())  # human-readable anchor
         record["seq"] = _step_seq
@@ -839,17 +904,15 @@ def span(name: str, **ids):
     return _timed_span(name, ids)
 
 
-_TraceAnnotation = None   # jax.profiler's, imported at the first span
+_TraceAnnotation = None   # jax.profiler's: _listen_to_jax imports it
 
 
 @contextlib.contextmanager
 def _timed_span(name: str, ids: Dict[str, Any]):
-    global _span_seconds, _TraceAnnotation
+    global _span_seconds
     if _span_seconds is None:
         _span_seconds = histogram(
             "pt_span_seconds", "host span durations by span name")
-    if _TraceAnnotation is None:
-        from jax.profiler import TraceAnnotation as _TraceAnnotation
     stack = getattr(_TLS, "spans", None)
     if stack is None:
         stack = _TLS.spans = []
@@ -1138,12 +1201,14 @@ def _on_jax_cache_event(event, **kw):
 def _listen_to_jax():
     """Register the three listeners, once a process, the first time
     telemetry is turned on: a process that never turns it on carries
-    none."""
-    global _jax_listening
+    none. (And jax.profiler's annotation, for ``span`` and the
+    collector's hook, which may not import inside a collection.)"""
+    global _jax_listening, _TraceAnnotation
     if _jax_listening:
         return
     _jax_listening = True
     import jax.monitoring as jm
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
 
     jm.register_scalar_listener(_on_jax_stage_begin)
     jm.register_event_time_span_listener(_on_jax_stage_end)
