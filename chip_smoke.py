@@ -18,7 +18,9 @@ points a user calls:
    and run the new kernels against their plain forms; the ``sconv``
    phase lowers ``lfm2moe-train-s8192`` (four gated short convolutions
    each way on the ``sconv.gated.*`` kernels) and runs them against the
-   composition;
+   composition; the ``loss_head`` phase compiles a Program that is only
+   ``olmoe-train-s4096``'s head and holds its temporaries under the
+   float32 [tokens, vocab] tensor the loss op no longer writes;
 2. train   — ``T.build`` + ``Adam.minimize`` under bf16 AMP, a few
    ``Executor.run`` steps and one ``Executor.run_steps`` window at
    b=64 s=256 with dropout 0.1 (no OOM back-off: full batch or fail);
@@ -477,10 +479,12 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     return row
 
 
-def _lower_train_step(main, loss, seq):
+def lower_train_step(main, loss, seq, batch=1, sharding=None):
     """Lower (not run) the train step of a language-model program whose
-    feeds are ``input_ids`` and ``labels`` [1, seq], as Executor.run
-    would: the dispatch counters then hold what the step lowers."""
+    feeds are ``input_ids`` and ``labels`` [batch, seq], as Executor.run
+    would (for ``sharding``'s device, where one is described): the
+    dispatch counters then hold what the step lowers, and the result
+    compiles."""
     import jax
     import jax.numpy as jnp
 
@@ -490,19 +494,21 @@ def _lower_train_step(main, loss, seq):
     low = lowering.lower_block(main, 0, ("input_ids", "labels"), (loss.name,))
     block = main.global_block()
 
-    def aval(name):
-        var = block._find_var_recursive(name)
-        dtype = jnp.dtype(var.dtype)
-        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(
-            {"int64": "int32", "float64": "float32"}.get(dtype.name,
-                                                         dtype.name)))
+    def aval(shape, dtype):
+        dtype = jnp.dtype(dtype).name
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(
+            {"int64": "int32", "float64": "float32"}.get(dtype, dtype)),
+            sharding=sharding)
 
-    ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
-    Executor._jit_for(low, None).lower(
-        {n: aval(n) for n in low.state_in_names},
+    def of(name):
+        var = block._find_var_recursive(name)
+        return aval(var.shape, var.dtype)
+
+    ids = aval((batch, seq), "int32")
+    return Executor._jit_for(low, None).lower(
+        {n: of(n) for n in low.state_in_names},
         {"input_ids": ids, "labels": ids},
-        jax.ShapeDtypeStruct((2,), jnp.uint32),
-        jax.ShapeDtypeStruct((), jnp.uint32))
+        aval((2,), "uint32"), aval((), "uint32"))
 
 
 def _traced_kernel_ms(name, run, prefix, calls=3):
@@ -647,7 +653,7 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     main._amp = True
     reads = (attention_dispatch, gmm_dispatch, gdn_dispatch, conv_dispatch)
     before = tuple(read() for read in reads)
-    _lower_train_step(main, model["loss"], seq)
+    lower_train_step(main, model["loss"], seq)
     attn, gmm, gdn, conv = (_dispatch_since(b, read)
                             for b, read in zip(before, reads))
     n_gdn = sum(not cfg.is_full_attention(i)
@@ -823,7 +829,7 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
     main._amp = True
     reads = (attention_dispatch, gmm_dispatch, router_dispatch)
     before = [read() for read in reads]
-    _lower_train_step(main, model["loss"], seq)
+    lower_train_step(main, model["loss"], seq)
     attn, gmm, routers = (_dispatch_since(b, read)
                           for b, read in zip(before, reads))
     say(f"  lowered: attention {attn}; routers {routers}; grouped "
@@ -966,6 +972,102 @@ def rope_phase(seq=4096, heads=(28, 4), dh=128, **overrides):
     return row
 
 
+def loss_head_program(batch, seq, width, vocab, soft=False, xent=None,
+                      table_rows=1024):
+    """(main, startup, loss, feeds) of a language model that is only its
+    head, as the cells' models build theirs, under bf16 AMP with Adam:
+    ``embed`` (a small table, so the step is fed ids as a cell's is),
+    ``final_norm``, and under ``loss_head`` the projection to ``vocab``,
+    ``xent`` (layers.softmax_with_cross_entropy) on hard labels, or on
+    smoothed one-hot rows as models/transformer.py makes them, and the
+    mean. ``feeds(seed)`` draws a step's ids and labels."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+
+    xent = xent or layers.softmax_with_cross_entropy
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        ids = layers.data("input_ids", shape=[batch, seq], dtype="int64",
+                          append_batch_size=False)
+        lbl = layers.data("labels", shape=[batch, seq], dtype="int64",
+                          append_batch_size=False)
+        with fluid.name_scope("embed"):
+            x = layers.embedding(ids, size=[table_rows, width],
+                                 param_attr=fluid.ParamAttr(name="tok_emb.w"))
+        with fluid.name_scope("final_norm"):
+            x = layers.rms_norm(x, param_attr=fluid.ParamAttr(
+                name="final_norm.scale"))
+        with fluid.name_scope("loss_head"):
+            logits = layers.fc(x, vocab, num_flatten_dims=2, bias_attr=False,
+                               param_attr=fluid.ParamAttr(name="lm_head.w"))
+            if soft:
+                ce = xent(logits, layers.label_smooth(
+                    layers.one_hot(lbl, vocab), epsilon=0.1), soft_label=True)
+            else:
+                ce = xent(logits, layers.unsqueeze(lbl, [2]))
+            loss = layers.mean(ce)
+        fluid.optimizer.Adam(1e-4).minimize(loss)
+    main._amp = True
+
+    def feeds(seed):
+        r = np.random.RandomState(seed)
+        return {"input_ids": r.randint(0, table_rows, (batch, seq)),
+                "labels": r.randint(0, vocab, (batch, seq))}
+
+    return main, startup, loss, feeds
+
+
+def loss_head_phase(batch=2, seq=4096, width=2048, vocab=50304, steps=6,
+                    temp_share=1.03):
+    """softmax_with_cross_entropy and its grad op at
+    ``olmoe-train-s4096``'s call (8192 tokens x 2048 -> 50304, bf16
+    logits) in a Program that is only the head.
+
+    1. The step lowers one call each way, on hard labels, with no
+       gradient of Softmax (``pt_loss_head_dispatch_total``).
+    2. Compiled for this device, ``memory_analysis()``'s temporaries
+       are under ``temp_share`` of what the float32 [tokens, vocab]
+       tensor alone would take (1.65 GB there: the bf16 logits, the
+       weight's gradient and the rest fit under it; the composition
+       with the float32 log-probabilities read over 2.4 GB). None: not
+       held (the CPU's compiler fuses otherwise).
+    3. ``steps`` calls of Executor.run: the loss is finite, and the
+       wall ms a call is printed (an observation)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.ops.nn_ops import loss_head_dispatch_counts
+
+    main, startup, loss, feeds = loss_head_program(batch, seq, width, vocab)
+    before = loss_head_dispatch_counts()
+    mem = lower_train_step(main, loss, seq, batch).compile().memory_analysis()
+    lowered = _dispatch_since(before, loss_head_dispatch_counts)
+    f32_logits = 4 * batch * seq * vocab
+    say(f"  lowered: loss heads {lowered}; temporaries "
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB (float32 logits alone "
+        f"{f32_logits / 1e9:.3f}), peak {mem.peak_memory_in_bytes / 1e9:.3f}")
+    check(lowered == {"hard fwd 0": 1, "hard bwd 0": 1},
+          f"expected one hard-label call each way and no gradient of "
+          f"Softmax: {lowered}")
+    if temp_share is not None:
+        check(mem.temp_size_in_bytes < temp_share * f32_logits,
+              f"the step's temporaries ({mem.temp_size_in_bytes / 1e9:.3f} "
+              f"GB) have room for a float32 [tokens, vocab] tensor "
+              f"({f32_logits / 1e9:.3f} GB)")
+    scope, exe = fluid.Scope(), fluid.Executor()
+    exe.run(startup, scope=scope)
+    batches = [feeds(s) for s in range(2)]
+    losses, secs = _timed_steps(exe, main, batches, steps + 1, loss, scope)
+    exe.close()
+    check(bool(np.isfinite(losses).all()), f"the head's losses: {losses}")
+    row = {"lowered": lowered, "shape": [batch * seq, width, vocab],
+           "temp_gb": round(mem.temp_size_in_bytes / 1e9, 3),
+           "peak_gb": round(mem.peak_memory_in_bytes / 1e9, 3),
+           "loss": [round(v, 4) for v in (losses[0], losses[-1])],
+           "ms_per_call": round(float(np.median(secs[1:])) * 1e3, 3)}
+    say(f"  loss_head {row}")
+    return row
+
+
 def ssm_dispatch():
     from paddle_tpu.ops import selective_scan_ops
 
@@ -1010,7 +1112,7 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
     main._amp = True
     reads = (attention_dispatch, ssm_dispatch, conv_dispatch)
     before = [read() for read in reads]
-    _lower_train_step(main, model["loss"], seq)
+    lower_train_step(main, model["loss"], seq)
     attn, scans, convs = (_dispatch_since(b, read)
                           for b, read in zip(before, reads))
     say(f"  lowered: attention {attn}; selective scans {scans}; "
@@ -1167,7 +1269,7 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
     reads = (attention_dispatch, mamba2_dispatch, conv_dispatch,
              gmm_dispatch)
     before = [read() for read in reads]
-    _lower_train_step(main, model["loss"], seq)
+    lower_train_step(main, model["loss"], seq)
     attn, scans, convs, gmms = (_dispatch_since(b, read)
                                 for b, read in zip(before, reads))
     say(f"  lowered: attention {attn}; mamba2 scans {scans}; convolutions "
@@ -1340,7 +1442,7 @@ def sconv_phase(seq=8192, t_check=2048, **overrides):
     main._amp = True
     reads = (attention_dispatch, conv_dispatch, rope_dispatch)
     before = [read() for read in reads]
-    _lower_train_step(main, model["loss"], seq)
+    lower_train_step(main, model["loss"], seq)
     attn, convs, ropes = (_dispatch_since(b, read)
                           for b, read in zip(before, reads))
     say(f"  lowered: attention {attn}; convolutions {convs}; rotary "
@@ -1922,6 +2024,7 @@ def main() -> int:
     report["mamba2"], _ = phase("mamba2", mamba2_phase)
     report["sconv"], _ = phase("sconv", sconv_phase)
     report["rope"], _ = phase("rope", rope_phase)
+    report["loss_head"], _ = phase("loss_head", loss_head_phase)
 
     # 2. train: the step and the window contain the kernels, and no
     # attention call fell to the dense composition

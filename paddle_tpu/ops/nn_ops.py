@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from paddle_tpu import monitor as _monitor
 from paddle_tpu.core import interp
+from paddle_tpu.core.autodiff import GRAD_SLOT_PREFIX as GRAD_SLOT
 from paddle_tpu.core.registry import register_op
 
 # Runs at TRACE time (once per compile, like pt_attention_dispatch_total):
@@ -420,28 +421,147 @@ def _cross_entropy(ins, attrs):
     return {"Y": [loss]}
 
 
-@register_op("softmax_with_cross_entropy", diff_inputs=("Logits",))
+_M_LOSS_HEAD = _monitor.counter(
+    "pt_loss_head_dispatch_total",
+    "softmax_with_cross_entropy calls lowered, one row a lowered call of "
+    "the op (pass fwd) or of its grad op (pass bwd): labels hard / soft, "
+    "softmax_grad 1 where the program reads Softmax and its gradient "
+    "reaches the grad op (the softmax's own vjp term is then paid for)")
+
+
+def loss_head_dispatch_counts():
+    """{"hard|soft fwd|bwd 0|1": calls lowered so far}:
+    pt_loss_head_dispatch_total as rng_draw_counts() gives the draws."""
+    out = {}
+    for row in _monitor.snapshot()[_M_LOSS_HEAD.name]["values"]:
+        lb = row["labels"]
+        name = (f"{lb.get('labels', '?')} {lb.get('pass', '?')} "
+                f"{lb.get('softmax_grad', '?')}")
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
+
+
+def _note_loss_head(soft_label, bwd, softmax_grad=False):
+    # off with telemetry; build-time shape inference is not a lowering
+    if _monitor.enabled() and interp.lowering_active():
+        _M_LOSS_HEAD.inc(labels={
+            "labels": "soft" if soft_label else "hard",
+            "pass": "bwd" if bwd else "fwd",
+            "softmax_grad": "1" if softmax_grad else "0"})
+
+
+def _xent_rows(logits):
+    """(the logits in >= float32, their rows' logsumexp [..., 1]). The
+    reduction over the vocabulary is precision-sensitive, so it runs in
+    float32 even where the logits stream is bf16 (AMP); the cast fuses.
+    The op and its grad op both come here, so XLA's CSE finds ONE."""
+    x = logits.astype(jnp.promote_types(logits.dtype, jnp.float32))
+    return x, jax.nn.logsumexp(x, axis=-1, keepdims=True)
+
+
+def _xent_hard_label(logits, label, ignore_index):
+    """(hit [..., vocab]: the label's column of each row; keep [..., 1]:
+    False on a row whose label is ignore_index, None where nothing is
+    ignored). A compare against an iota: the select it feeds sits inside
+    the pass that already walks the row, where a gather would need its
+    operand written out first."""
+    if jnp.ndim(label) == jnp.ndim(logits):
+        label = jnp.squeeze(label, axis=-1)
+    lbl = label.astype(jnp.int32)
+    cols = jax.lax.broadcasted_iota(
+        jnp.int32, jnp.shape(logits), jnp.ndim(logits) - 1)
+    hit = cols == jnp.maximum(lbl, 0)[..., None]
+    keep = (lbl != ignore_index)[..., None] if ignore_index >= 0 else None
+    return hit, keep
+
+
+def _softmax_with_cross_entropy_grad_maker(op, block, out_grads, provide,
+                                           should_skip):
+    """softmax_with_cross_entropy_grad over Logits, Label, GRAD::Loss
+    and, only where the program made one, GRAD::Softmax -> GRAD::Logits.
+    Nothing stands in for a gradient the program did not give."""
+    from paddle_tpu.core.registry import get_op_def
+
+    logits = op.inputs["Logits"][0]
+    grads = {}
+    for slot in ("Loss", "Softmax"):
+        g = (out_grads.get(slot) or [""])[0]
+        if g:
+            grads[GRAD_SLOT + slot] = [g]
+    if not grads or should_skip(
+            logits, "Logits", get_op_def("softmax_with_cross_entropy")):
+        return []
+    src = block._find_var_recursive(logits)
+    gname = provide(logits)
+    block.create_var(name=gname, shape=src.shape if src else None,
+                     dtype=src.dtype if src else "float32")
+    return [dict(
+        type="softmax_with_cross_entropy_grad",
+        inputs={"Logits": [logits], "Label": list(op.inputs["Label"]),
+                **grads},
+        outputs={GRAD_SLOT + "Logits": [gname]},
+        attrs=dict(op.attrs),
+    )]
+
+
+@register_op("softmax_with_cross_entropy", diff_inputs=("Logits",),
+             grad_maker=_softmax_with_cross_entropy_grad_maker,
+             doc="Loss [..., 1] = logsumexp(logits) - logits[label] (hard "
+                 "labels, 0 on a row whose label is ignore_index) or "
+                 "logsumexp * sum(label) - sum(label * logits) (soft "
+                 "ones), in float32; Softmax = exp(logits - logsumexp). "
+                 "The logits are read, never copied: no log-probability "
+                 "tensor exists. Its grad op is its own "
+                 "(softmax_with_cross_entropy_grad)")
 def _softmax_with_cross_entropy(ins, attrs):
     logits, label = _x(ins, "Logits"), _x(ins, "Label")
     soft_label = attrs.get("soft_label", False)
-    ignore_index = attrs.get("ignore_index", -100)
-    # logsumexp over the vocab in >=f32 even when the logits stream is bf16
-    # (AMP): the reduction is precision-sensitive, the cast fuses.
-    logp = jax.nn.log_softmax(
-        logits.astype(jnp.promote_types(logits.dtype, jnp.float32)), axis=-1)
+    _note_loss_head(soft_label, bwd=False)
+    x, lse = _xent_rows(logits)
     if soft_label:
-        loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
+        loss = (lse * jnp.sum(label, axis=-1, keepdims=True)
+                - jnp.sum(label * x, axis=-1, keepdims=True))
     else:
-        lbl = label
-        if jnp.ndim(lbl) == jnp.ndim(logits):
-            lbl = jnp.squeeze(lbl, axis=-1)
-        lbl_i = lbl.astype(jnp.int32)
-        picked = jnp.take_along_axis(logp, jnp.maximum(lbl_i, 0)[..., None], axis=-1)
-        loss = -picked
-        if ignore_index >= 0:
-            mask = (lbl_i != ignore_index)[..., None]
-            loss = loss * mask.astype(loss.dtype)
-    return {"Softmax": [jnp.exp(logp)], "Loss": [loss]}
+        hit, keep = _xent_hard_label(
+            logits, label, attrs.get("ignore_index", -100))
+        loss = lse - jnp.sum(jnp.where(hit, x, 0.0), axis=-1, keepdims=True)
+        if keep is not None:
+            loss = jnp.where(keep, loss, 0.0)
+    # dead code unless the program reads it
+    return {"Softmax": [jnp.exp(x - lse)], "Loss": [loss]}
+
+
+@register_op("softmax_with_cross_entropy_grad", no_grad=True)
+def _softmax_with_cross_entropy_grad(ins, attrs):
+    """GRAD::Logits, in the logits' dtype, computed in float32:
+    g * (softmax - onehot) for hard labels (0 on an ignored row),
+    g * (softmax * sum(label) - label) for soft ones, g = GRAD::Loss;
+    with a GRAD::Softmax (a program that reads Softmax), the softmax's
+    own vjp softmax * (gs - sum(gs * softmax)) besides. The row
+    statistics are the forward's (_xent_rows): one pass over the logits
+    for them, one that writes dlogits."""
+    logits, label = _x(ins, "Logits"), _x(ins, "Label")
+    g, gs = _x(ins, GRAD_SLOT + "Loss"), _x(ins, GRAD_SLOT + "Softmax")
+    soft_label = attrs.get("soft_label", False)
+    _note_loss_head(soft_label, bwd=True, softmax_grad=gs is not None)
+    x, lse = _xent_rows(logits)
+    p = jnp.exp(x - lse)
+    d = None
+    if g is not None:
+        g = jnp.reshape(g.astype(x.dtype), jnp.shape(lse))
+        if soft_label:
+            d = g * (p * jnp.sum(label, axis=-1, keepdims=True) - label)
+        else:
+            hit, keep = _xent_hard_label(
+                logits, label, attrs.get("ignore_index", -100))
+            if keep is not None:
+                g = jnp.where(keep, g, 0.0)
+            d = g * jnp.where(hit, p - 1.0, p)
+    if gs is not None:
+        gs = gs.astype(x.dtype)
+        ds = p * (gs - jnp.sum(gs * p, axis=-1, keepdims=True))
+        d = ds if d is None else d + ds
+    return {GRAD_SLOT + "Logits": [d.astype(logits.dtype)]}
 
 
 @register_op("sigmoid_cross_entropy_with_logits", diff_inputs=("X",))

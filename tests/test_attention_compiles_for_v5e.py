@@ -635,6 +635,41 @@ def test_embed_grad_kernel_compiles_at_the_cells_calls(cell, dtype, one_chip,
     assert "embed.grad" in text and " scatter(" not in text
 
 
+# batch, positions, width, vocabulary, soft labels: the cell whose head
+# is its largest scope, the longest call (a vocabulary off the 128
+# lanes), and the label-smoothed one
+_LOSS_HEADS = {"olmoe": (2, 4096, 2048, 50304, False),
+               "smallthinker": (1, 16384, 2560, 18992, False),
+               "tbase": (128, 256, 512, 10000, True)}
+
+
+@pytest.mark.parametrize("call", sorted(_LOSS_HEADS))
+def test_loss_head_step_reads_its_logits_and_copies_none(call, one_chip,
+                                                         real_kernels):
+    """A Program that is only a head (chip_smoke.loss_head_program), its
+    train step compiled for the v5e: no fusion of it writes a float32
+    tensor of the logits' size (on hard labels: smoothed labels ARE
+    one), none gathers from one, none holds a second exponential over
+    one; and on hard labels the step's temporaries would not hold that
+    tensor (PR 52: softmax_with_cross_entropy and its own grad op)."""
+    import chip_smoke
+    from benchmarks import xent_candidates
+
+    batch, seq, width, vocab, soft = _LOSS_HEADS[call]
+    main, _, loss, _ = chip_smoke.loss_head_program(batch, seq, width, vocab,
+                                                    soft=soft)
+    compiled = chip_smoke.lower_train_step(main, loss, seq, batch,
+                                          one_chip).compile()
+    rows = xent_candidates.fusions(compiled.as_text(), batch * seq, vocab)
+    assert len(rows) >= 4, rows     # the pass, the three matmuls
+    for row in rows:
+        assert row["exp"] <= 1 and not row["gather"], row
+        assert soft or not row["writes_f32_logits"], row
+    if not soft:
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < 1.03 * 4 * batch * seq * vocab)
+
+
 # The BTHD-small pair at the calls of the three cells that lower it (PR
 # 49): (b, t, heads of 64, rows of the bias [b, 1, rows, t]); dropout 0.1
 # as the cells have it. transformer-base's encoder and cross attention

@@ -147,6 +147,33 @@ def test_rope_phase_fails_without_the_kernels(telemetry):
                               moe_num_primary_experts=4)
 
 
+def test_loss_head_phase_holds_the_program_to_one_call_each_way(telemetry):
+    """The phase at 2 x 64 tokens, 32 wide, into 384 columns, compiled
+    for the CPU (whose temporaries are not held): the head's step lowers
+    one hard-label call each way with no gradient of Softmax, and
+    trains."""
+    row = chip_smoke.loss_head_phase(batch=2, seq=64, width=32, vocab=384,
+                                     steps=2, temp_share=None)
+    assert row["lowered"] == {"hard fwd 0": 1, "hard bwd 0": 1}
+    assert row["shape"] == [128, 32, 384] and row["temp_gb"] >= 0
+    assert np.isfinite(row["loss"]).all()
+
+
+@pytest.mark.parametrize("fault, match", [
+    ("silent", "one hard-label call each way"),
+    ("temporaries", "have room for a float32"),
+])
+def test_loss_head_phase_fails(telemetry, monkeypatch, fault, match):
+    from paddle_tpu.ops import nn_ops
+
+    if fault == "silent":   # the op lowered without saying so
+        monkeypatch.setattr(nn_ops, "_note_loss_head", lambda *a, **k: None)
+    with pytest.raises(chip_smoke.SmokeFailure, match=match):
+        chip_smoke.loss_head_phase(
+            batch=2, seq=64, width=32, vocab=384, steps=1,
+            temp_share=None if fault == "silent" else 1e-3)
+
+
 GDN_TINY = dict(
     vocab_size=50, hidden_size=128, num_attention_heads=4,
     num_key_value_heads=2, head_dim=128, linear_key_head_dim=128,
