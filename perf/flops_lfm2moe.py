@@ -42,16 +42,23 @@ def sizes(cfg: Dict) -> Dict[str, int]:
 def sconv_cost(cfg: Dict, batch: int, t: int, bytes_per_el: int = 2
                ) -> Dict[str, float]:
     """Bytes the gated-convolution calls of one train step move,
-    forward + backward, each tensor once at the stream's width, whichever
-    memory XLA keeps it in (perf/metrics/sconv.roofline.py): forward
-    reads [B | C | u] (3 c) and writes y (c); backward reads [B | C | u]
-    and dy and writes d[B | C | u]: 11 t c elements a layer. No matmul,
-    so no FLOPs bound it; the filter and its gradient (c x taps) are
-    left out."""
+    forward + backward, each tensor once at the stream's width. Two
+    counts. ``bytes``: every operand, whichever memory XLA keeps it in:
+    forward reads [B | C | u] (3 c) and writes y (c); backward reads [B |
+    C | u] and dy and writes d[B | C | u]: 11 t c elements a layer.
+    ``hbm_bytes``: the 5 t c of them that cross HBM inside the kernels
+    in every placement XLA has chosen (y forward; dy and d[B | C | u]
+    backward); the 6 t c of [B | C | u], which the compiled step keeps
+    in the memory beside the core (written there by the projection
+    forward, prefetched there in front of the call backward), are NOT
+    counted, so a share of the HBM peak taken from this count can only
+    under-read (perf/metrics/sconv.roofline.py). No matmul, so no FLOPs
+    bound it; the filter and its gradient (c x taps) are left out of
+    both."""
     n, c = count(cfg, "sconv"), sizes(cfg)["d"]
-    return {"flops": 0.0,
-            "bytes": float(n * 11 * batch * t * c * bytes_per_el),
-            "calls": 2 * n}
+    elements = n * batch * t * c * bytes_per_el
+    return {"flops": 0.0, "bytes": float(11 * elements),
+            "hbm_bytes": float(5 * elements), "calls": 2 * n}
 
 
 def held_share(cfg: Dict) -> float:

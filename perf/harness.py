@@ -11,7 +11,7 @@ import os
 import shutil
 import sys
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -168,6 +168,18 @@ def program_counters() -> Dict[str, Any]:
                         for p in ("feed", "dispatch", "device", "fetch")},
         "phase_count": phases.count(labels={"phase": "dispatch"}),
     }
+
+
+def counter_rows(name: str) -> List[Tuple[Dict[str, str], int]]:
+    """[(labels, count)] of the rows of one of the program's counters
+    that counted anything in this process; [] where the program has no
+    such counter (any tree before it) or it counted nothing. The
+    ``*_dispatch_total`` counters count at lowering with telemetry on,
+    that is in traced runs."""
+    from paddle_tpu import monitor
+
+    rows = monitor.snapshot().get(name, {}).get("values", [])
+    return [(r["labels"], int(r["value"])) for r in rows if r["value"]]
 
 
 def telemetry(on: bool, phases_every_n: int = 0,

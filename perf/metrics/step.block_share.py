@@ -2,11 +2,16 @@
 ``*/blk*/``, forward and backward; XLA's own grouped-matmul calls, which
 carry no scope, added: perf/moe_spans.py): how much of the step the
 block is, beside embedding, head and optimizer. With one block of
-sixteen it is far below a deployment's."""
+sixteen it is far below a deployment's. A block needs no expert layer
+to count (a state-space or attention block with a dense MLP is one);
+None where the trace holds no ``blk*`` scope at all."""
 
-from perf import moe_spans
+from perf import moe_spans, spans
 
 
 def read(run):
-    s = moe_spans.summary(run)
-    return s and 100.0 * moe_spans.block_ns(run, s) / s["busy_ns"]
+    s = spans.for_run(run)
+    if not s or not s["busy_ns"]:
+        return None
+    ns = moe_spans.block_ns(run, s)
+    return 100.0 * ns / s["busy_ns"] if ns else None

@@ -3,7 +3,7 @@ calls that lowered as the pair ``attn.bhtd.bwd_dq`` +
 ``attn.bhtd.bwd_dkv`` and not as the one call ``attn.bhtd.bwd``, from
 the ``form`` label of the program's ``pt_attention_dispatch_total``
 (ops/attention_ops.py; ``flash_attention.bhtd_bwd_form``'s answer). The
-four decoder cells report it in a traced run. On the CPU a cell's
+decoder cells report it in a traced run. On the CPU a cell's
 attention is the dense composition and no row carries the label (None:
 the line leaves the metric out); through the kernels' interpreter, at
 the families' tiny sizes, a configuration with grouped key/value heads
@@ -37,16 +37,15 @@ def bwd_rows():
 
 
 def test_the_metric_lists_the_decoder_cells_and_moves_the_step():
-    entry = tiny.BENCH["per_layer"][-1]
-    assert entry["name"] == METRIC      # appended, nothing before it moved
+    entry = next(m for m in tiny.BENCH["per_layer"] if m["name"] == METRIC)
     assert entry["moves"] == "train_tokens_per_s"
     assert entry["source"] == "program_counter"
     assert entry["layer"] == "Program lowering"
     assert entry["unit"] == "count" and entry["better"] == "lower"
-    # the cells whose attention is the BHTD kernels': those with a
-    # decoder block
+    # the cells whose attention is the BHTD kernels' are among those with
+    # a decoder block
     blocks = tiny.cells_named(tiny.BENCH, "step.block_share.train")
-    assert CELLS == blocks and len(CELLS) == 4
+    assert CELLS and set(CELLS) <= set(blocks)
     assert set(CELLS) <= set(tiny.cells_named(tiny.BENCH,
                                               "train_tokens_per_s"))
 

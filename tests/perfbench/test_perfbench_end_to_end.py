@@ -18,6 +18,15 @@ LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 V5E = harness.load_json("perf", "peaks.json")["TPU v5 lite"]
 
 
+@pytest.fixture(autouse=True)
+def _trace_into_tmp(monkeypatch, tmp_path):
+    """A traced tiny run traces under the test's own directory, not
+    into the checkout's one directory a cell, which
+    ``harness.DeviceTrace`` empties on entry: two workers of one test
+    run would delete each other's trace."""
+    monkeypatch.setattr(harness, "TRACE_ROOT", str(tmp_path))
+
+
 def line_of(run, capsys):
     line = harness.result_line(run)
     text = json.dumps(line)          # what run.py prints last

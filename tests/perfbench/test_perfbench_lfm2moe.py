@@ -54,8 +54,8 @@ def test_configuration_cuts_depth_experts_and_vocabulary_and_no_width():
     assert sorted(cfg["reduced"]) == sorted(cfg["reduced_from"])
     assert cfg["vocab_size"] * 8 == cfg["reduced_from"]["vocab_size"]
     assert cfg["num_experts"] * 8 == cfg["router_experts"] == 64
-    # the share is stated WITHOUT a held_first key (section 7 (18))
-    assert "held_first" not in cfg and "held share" in cfg["assumed"]
+    # the share starts at expert 0 and the file says so (since PR 54)
+    assert cfg["held_first"] == 0 and "held share" in cfg["assumed"]
     assert next(iter(cfg["assumed"])) == "tied table"
     for key in ("source", "the_cut", "assumed", "deployment"):
         assert cfg[key], key
@@ -151,9 +151,16 @@ def test_gated_convolution_bytes():
     cfg = full_config()
     cost = fl.sconv_cost(cfg, 1, 8192)
     a_layer = 11 * 8192 * 2048 * 2               # 369 MB a layer and step
-    assert cost == {"flops": 0.0, "bytes": float(4 * a_layer), "calls": 8}
+    # by placement: the 5 t c that cross HBM wherever XLA keeps the
+    # projection (y; dy, d[B | C | u]), which the roofline divides
+    in_hbm = 5 * 8192 * 2048 * 2                 # 168 MB a layer and step
+    assert cost == {"flops": 0.0, "bytes": float(4 * a_layer),
+                    "hbm_bytes": float(4 * in_hbm), "calls": 8}
     assert a_layer == pytest.approx(369.1e6, rel=1e-3)
     assert cost["bytes"] == pytest.approx(1.476e9, rel=1e-3)
+    assert cost["hbm_bytes"] == pytest.approx(0.671e9, rel=1e-3)
+    # 0.82 ms of HBM time a step: under the kernels' 1.82 ms on the chip
+    assert cost["hbm_bytes"] / 819e9 == pytest.approx(0.82e-3, rel=5e-3)
     # no such block, no cost
     none = dict(cfg, num_hidden_layers=1, first_layer=2)
     assert fl.sconv_cost(none, 1, 8192)["bytes"] == 0
@@ -367,7 +374,8 @@ def test_roofline_reads_the_kernels_time_and_the_counters_gated_rows():
     run = scopes_run(BY_SCOPE, kernel_s=20e-9)
     traffic = run.cell["traffic"]               # the tiny cell: 8 x 16
     cost = fl.sconv_cost(cfg, traffic["batch"], traffic["seq_len"])
-    least = cost["bytes"] / peaks["hbm_bytes_per_s"]
+    least = cost["hbm_bytes"] / peaks["hbm_bytes_per_s"]
+    assert 11 * cost["hbm_bytes"] == 5 * cost["bytes"]
     assert read("sconv.roofline.train", run) == pytest.approx(
         100 * least / 20e-9)
     two = scopes_run(BY_SCOPE, traced_steps=2, kernel_s=20e-9)
@@ -422,12 +430,19 @@ def test_readers_report_nothing_for_a_program_without_the_layers():
     assert sconv_spans.gated_rows() == []
 
 
-def test_the_new_readers_wait_for_a_benchmark_pr():
-    """The four are files and tests, not entries: a pin in
-    tests/perfbench/ holds ``per_layer[-1]`` (PERF.md section 7 (20))."""
-    listed = {m["name"] for m in tiny.BENCH["per_layer"]}
-    assert not listed & set(NEW)
-    for metric in NEW:
+def test_the_new_readers_are_entries_that_list_the_cell():
+    """The four are entries since PR 54 (they waited as files while a
+    pin in tests/perfbench/ held ``per_layer``'s last entry), with the
+    keys PERF.md section 3 gives them."""
+    PL, K = "Program lowering", "Kernels"
+    for metric, unit, better, source, layer in (
+            ("sconv.step_share.train", "%", "lower", "program_span", PL),
+            ("sconv.gate_share.train", "%", "lower", "program_span", PL),
+            ("sconv.roofline.train", "%", "higher", "device_trace", K),
+            ("lower.xla_sconv_calls.train", "count", "lower",
+             "program_counter", PL)):
+        assert metric in NEW
+        assert tiny.listed_as(metric, unit, better, source, layer, CELL)
         assert callable(harness.reader_for(metric).read)
     on = {m["name"] for m in tiny.BENCH["end_to_end"] + tiny.BENCH["per_layer"]
           if CELL in m.get("workloads", ())}
@@ -435,15 +450,18 @@ def test_the_new_readers_wait_for_a_benchmark_pr():
             "moe.route_share.train", "moe.max_expert_load.train",
             "step.mfu.train", "train_attn_roofline",
             "device.peak_hbm_gb.train"} <= on
-    assert not on & {"step.block_share.train",
-                     "lower.split_bwd_attn_calls.train",
-                     "lower.whole_buffer_moe_calls.train",
-                     "lower.xla_conv_calls.train", "moe.gmm_roofline.train"}
+    # the lists that were closed to the cell until PR 54, the rotary
+    # embedding's pair and the embedding gradient's
+    assert {"step.block_share.train", "lower.split_bwd_attn_calls.train",
+            "lower.whole_buffer_moe_calls.train",
+            "lower.xla_conv_calls.train", "lower.xla_rope_calls.train",
+            "rope.step_share.train", "lower.xla_embed_grad_calls.train",
+            "embed.grad_share.train"} <= on
+    assert "moe.gmm_roofline.train" not in on   # held cells: PERF.md 7
     entry = next(w for w in tiny.BENCH["workloads"] if w["name"] == CELL)
-    assert entry == tiny.BENCH["workloads"][-1]
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         CONFIG, "b1-s8192", 1)
-    assert tiny.BENCH["configs"][-1]["name"] == CONFIG
+    assert CONFIG in tiny.CONFIGS
 
 
 def test_a_traced_tiny_run_counts_its_convolutions_and_passes_both_checks(
@@ -461,7 +479,7 @@ def test_a_traced_tiny_run_counts_its_convolutions_and_passes_both_checks(
     monitor.reset()
     cell = tiny.train_cell(CELL)
     cfg = tiny.config(cell["config"])
-    assert "held_first" not in cfg
+    assert cfg["held_first"] == 0
     run = tiny.make_run(cell, cfg, seconds=0.3, traced=True)
     train.run(run)
     line = json.loads(json.dumps(harness.result_line(run)))

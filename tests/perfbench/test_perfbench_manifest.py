@@ -3,8 +3,13 @@ reader can check without a chip. The rules that need no file on disk
 run twice: on the file as it is, and on an in-memory copy with a later
 PR's entries APPENDED (perfbench_tiny.appended: a configuration, a
 one-chip cell, three per-layer metrics), so that a check which only
-holds for today's lists fails here and not in that PR's review."""
+holds for today's lists fails here and not in that PR's review. And the
+directory's own sources are searched for what made appending impossible
+from PR 38 to PR 54: a test that reads one of the four lists by a
+position, or holds a list's length to a number."""
 
+import ast
+import glob
 import json
 import os
 import re
@@ -43,6 +48,128 @@ def test_the_rehearsal_appends_and_edits_no_entry_that_is_there():
     assert cell in tiny.cells_named(more, "train_tokens_per_s")
     assert cell in tiny.cells_named(more, "attn.time_share.train")
     assert cell not in tiny.cells_named(more, "mesh.collective_share")
+    for metric in tiny.REHEARSED_LISTS:     # at the END of a shorter list
+        assert tiny.cells_named(more, metric) \
+            == tiny.cells_named(BENCH, metric) + [cell]
+
+
+# --- no test pins a list --------------------------------------------------
+
+LISTS = ("per_layer", "workloads", "configs", "end_to_end")
+LOOKUPS = ("cells_named", "cells_of")    # perfbench_tiny's, lists too
+
+
+def _is_list(node):
+    """``x["per_layer"]`` (or another of the four), or a call of
+    perfbench_tiny's that returns such a list's cells."""
+    if isinstance(node, ast.Subscript):
+        key = node.slice
+        return isinstance(key, ast.Constant) and key.value in LISTS
+    if isinstance(node, ast.Call):
+        f = node.func
+        return getattr(f, "attr", getattr(f, "id", None)) in LOOKUPS
+    return False
+
+
+def pins(source):
+    """[(line, what)] of the places a test file reads one of
+    BENCHMARK.json's lists by a position or holds its length to a
+    number. A name bound to such a list (``CELLS = cells_named(...)``,
+    ``held = {... for c in BENCH["workloads"] ...}``) counts as the
+    list. What does NOT count: a lookup by name (``next(m for m in
+    ... if m["name"] == X)``, an unpacking of a list filtered by name),
+    the contract's own limits (``1 <= len(...) <= 24``), a length held
+    to another length, and the FIRST cell of a metric's list through a
+    bound name (``CELLS[0]``: a list only grows at its end)."""
+    tree = ast.parse(source)
+    bound = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Assign) and any(
+                _is_list(n) for n in ast.walk(node.value)):
+            bound |= {t.id for t in node.targets if isinstance(t, ast.Name)}
+
+    def is_list(node):
+        return _is_list(node) or (isinstance(node, ast.Name)
+                                  and node.id in bound)
+
+    found = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Subscript):
+            base, at = node.value, node.slice
+            number = isinstance(at, ast.UnaryOp) or (
+                isinstance(at, ast.Constant) and isinstance(at.value, int))
+            first = number and getattr(at, "value", None) == 0
+            if isinstance(base, ast.Subscript) and _is_list(base):
+                # the list itself: no entry is reached by a place
+                pinned = number or isinstance(at, ast.Slice)
+            else:   # a metric's cells, or a name bound to a list
+                pinned = is_list(base) and number and not first
+            if pinned:
+                found.append((node.lineno, "a list read by a position"))
+        if isinstance(node, ast.Compare) and any(
+                isinstance(op, (ast.Eq, ast.NotEq)) for op in node.ops):
+            sides = [node.left] + node.comparators
+            lens = [s for s in sides if isinstance(s, ast.Call)
+                    and getattr(s.func, "id", None) == "len"
+                    and any(is_list(n) for n in ast.walk(s.args[0]))]
+            if lens and any(isinstance(s, ast.Constant)
+                            and isinstance(s.value, int) for s in sides):
+                found.append((node.lineno, "a list's length held to a "
+                                           "number"))
+        if isinstance(node, ast.Assign) and _is_list(node.value) and any(
+                isinstance(t, (ast.Tuple, ast.List)) for t in node.targets):
+            found.append((node.lineno, "a list unpacked into a fixed "
+                                       "number of cells"))
+    return found
+
+
+def test_no_test_reads_a_list_by_position_or_holds_its_length():
+    """The rule of perf/README.md ("Adding things"), held on the
+    sources: an entry is found by its name. The one exception is the
+    rehearsal's own look at what ``perfbench_tiny.appended`` has just
+    appended, ``more["workloads"][-1]`` in this file: it reads the
+    copy, not BENCHMARK.json."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    found = {}
+    for path in sorted(glob.glob(os.path.join(here, "*.py"))):
+        with open(path) as f:
+            source = f.read()
+        lines = source.splitlines()
+        hits = [(n, what, lines[n - 1].strip()) for n, what in pins(source)
+                if 'more["workloads"][-1]' not in lines[n - 1]]
+        if hits:
+            found[os.path.basename(path)] = hits
+    assert not found, found
+
+
+# (spelt with other quotes and names than the lines they stand for, so
+# that a grep of this directory for a pin finds a pin and not this list)
+@pytest.mark.parametrize("put_back", [
+    "entry = tiny.BENCH['per_layer'][-1]",
+    "cells = tiny.cells_named(tiny.BENCH, M)\nassert len(cells) == 4",
+    "some = {c['name'] for c in tiny.BENCH['workloads']}\n"
+    "assert set(e['workloads']) == some and len(some) == 2",
+    "(one_cell,) ="
+    " tiny.cells_named(tiny.BENCH, METRIC)",
+    "assert entry == tiny.BENCH['workloads'][-1]",
+    "assert tiny.BENCH['configs'][-1]['name'] == CONFIG",
+    "assert e['workloads'][:6] == [w for w in B['workloads'][:6]]",
+    "assert len(tiny.BENCH['per_layer']) == 44"])
+def test_the_guard_sees_a_pin_put_back(put_back):
+    """The pins PR 54 lifted (PERF.md section 6), each alone."""
+    assert pins(put_back), put_back
+
+
+@pytest.mark.parametrize("fine", [
+    'entry = next(m for m in B["per_layer"] if m["name"] == METRIC)',
+    '(entry,) = [m for m in bench["per_layer"] if m["name"] == NAME]',
+    'assert 1 <= len(bench["configs"]) <= 24',
+    'old, new = B[g], more[g]\nassert len(new) == len(old) + added',
+    'CELLS = tiny.cells_named(tiny.BENCH, M)\nrun(CELLS[0])',
+    'names = [m["name"] for m in B["per_layer"]]\n'
+    'assert names[:len(OLDER)] == OLDER'])
+def test_the_guard_lets_a_lookup_by_name_be(fine):
+    assert not pins(fine), fine
 
 
 @both

@@ -1,7 +1,8 @@
 """``lower.whole_buffer_moe_calls.train``: the passes of an expert
 layer over its row buffer that walk it whole, from the program's
-``pt_moe_rows_dispatch_total`` (ops/moe_ops.py). The two cells whose
-expert layers hold a share of their experts report it in a traced run,
+``pt_moe_rows_dispatch_total`` (ops/moe_ops.py). The cells whose
+expert layers hold a share of their experts (their configuration file
+says which with ``held_first``) report it in a traced run,
 at their families' tiny sizes here, and read 0: every pass of a held
 layer is a loop over the windows of live rows."""
 
@@ -32,7 +33,7 @@ def test_the_metric_lists_the_cells_with_held_experts_and_moves_the_step():
     held = {c["name"] for c in tiny.BENCH["workloads"]
             if "held_first" in harness.load_json(
                 "perf", "configs", f"{c['config']}.json")}
-    assert set(entry["workloads"]) == held and len(held) == 2
+    assert held and set(entry["workloads"]) == held
 
 
 @pytest.mark.parametrize("cell_name", tiny.cells_named(tiny.BENCH, METRIC))

@@ -54,8 +54,8 @@ def test_configuration_cuts_depth_experts_and_vocabulary_and_no_width():
     assert sorted(cfg["reduced"]) == sorted(cfg["reduced_from"])
     assert cfg["vocab_size"] * 8 == cfg["reduced_from"]["vocab_size"]
     assert cfg["n_routed_experts"] * 16 == cfg["router_experts"] == 128
-    # the share is stated WITHOUT a held_first key (section 7 (18))
-    assert "held_first" not in cfg and "held share" in cfg["assumed"]
+    # the share starts at expert 0 and the file says so (since PR 54)
+    assert cfg["held_first"] == 0 and "held share" in cfg["assumed"]
     for key in ("source", "the_cut", "assumed", "deployment"):
         assert cfg[key], key
     # the blocks keep their published indices and every kind is held
@@ -386,21 +386,34 @@ def test_readers_report_nothing_for_a_program_without_the_layers():
     assert mamba2_spans.dispatch_rows() == [] == mamba2_spans.gmm_rows()
 
 
-def test_the_new_readers_wait_for_a_benchmark_pr():
-    """The five are files and tests, not entries: a pin in
-    tests/perfbench/ holds ``per_layer[-1]`` (PERF.md section 7 (20))."""
-    listed = {m["name"] for m in tiny.BENCH["per_layer"]}
-    assert not listed & set(NEW)
-    for metric in NEW:
+def test_the_new_readers_are_entries_that_list_the_cell():
+    """The five are entries since PR 54 (they waited as files while a
+    pin in tests/perfbench/ held ``per_layer``'s last entry), with the
+    keys PERF.md section 3 gives them."""
+    PL, K = "Program lowering", "Kernels"
+    for metric, unit, better, source, layer in (
+            ("mamba2.step_share.train", "%", "lower", "program_span", PL),
+            ("mamba2.scan_share.train", "%", "lower", "program_span", PL),
+            ("mamba2.scan_roofline.train", "%", "higher", "device_trace", K),
+            ("lower.xla_mamba2_calls.train", "count", "lower",
+             "program_counter", PL),
+            ("lower.ragged_moe_calls.train", "count", "lower",
+             "program_counter", PL)):
+        assert metric in NEW
+        assert tiny.listed_as(metric, unit, better, source, layer, CELL)
         assert callable(harness.reader_for(metric).read)
+    # the guard of a width off the lanes is every expert cell's
+    assert tiny.cells_named(tiny.BENCH, "lower.ragged_moe_calls.train") \
+        == tiny.cells_named(tiny.BENCH, "moe.step_share.train")
     on = {m["name"] for m in tiny.BENCH["end_to_end"] + tiny.BENCH["per_layer"]
           if CELL in m.get("workloads", ())}
     assert {"train_tokens_per_s", "moe.step_share.train",
             "moe.route_share.train", "moe.max_expert_load.train",
             "step.mfu.train", "train_attn_roofline"} <= on
-    assert not on & {"step.block_share.train",
-                     "lower.split_bwd_attn_calls.train",
-                     "lower.whole_buffer_moe_calls.train"}
+    # the lists that were closed to the cell until PR 54
+    assert {"step.block_share.train", "lower.split_bwd_attn_calls.train",
+            "lower.whole_buffer_moe_calls.train",
+            "lower.xla_conv_calls.train"} <= on
 
 
 def test_a_traced_tiny_run_counts_its_scans_and_passes_both_checks(
@@ -418,7 +431,7 @@ def test_a_traced_tiny_run_counts_its_scans_and_passes_both_checks(
     monitor.reset()
     cell = tiny.train_cell(CELL)
     cfg = tiny.config(cell["config"])
-    assert "held_first" not in cfg
+    assert cfg["held_first"] == 0
     run = tiny.make_run(cell, cfg, seconds=0.3, traced=True)
     train.run(run)
     line = json.loads(json.dumps(harness.result_line(run)))

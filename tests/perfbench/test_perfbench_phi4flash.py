@@ -311,9 +311,13 @@ def test_ssm_readers_sum_their_scopes():
     # the readers that exist count these layers as blocks, the window
     # layer as a window, and none takes them for a delta rule or experts
     assert read("swa.step_share.train", run) == pytest.approx(2.0)
-    # (step.block_share.train reads only programs with an expert layer,
-    # perf/moe_spans.summary: the cell is not on its list)
-    for metric in ("step.block_share.train", "gdn.step_share.train", "gdn.scan_share.train",
+    # (since PR 54 step.block_share.train starts from the whole table
+    # by scope and not from the expert layers': a block needs no ``moe``
+    # scope to count, and the cell is on its list)
+    blocks = sum(v for k, v in BY_SCOPE.items() if "/blk" in k)
+    assert read("step.block_share.train", run) == pytest.approx(blocks)
+    assert CELL in tiny.cells_named(tiny.BENCH, "step.block_share.train")
+    for metric in ("gdn.step_share.train", "gdn.scan_share.train",
                    "gdn.scan_roofline.train", "moe.step_share.train",
                    "mla.step_share.train"):
         assert read(metric, run) is None, metric
@@ -375,6 +379,34 @@ def test_readers_report_nothing_for_a_program_without_the_layers():
         assert read(metric, run) is None, metric
     assert ssm_spans.summary(run) is None and ssm_spans.kernel_s(run) == 0.0
     assert ssm_spans.dispatch_rows() == []
+
+
+def test_the_new_readers_are_entries_that_list_the_cell():
+    """The five are entries since PR 54 (they waited as files while a
+    pin in tests/perfbench/ held ``per_layer``'s last entry), with the
+    keys PERF.md section 3 gives them; and the cell is on the lists that
+    were closed to it."""
+    PL, K = "Program lowering", "Kernels"
+    for metric, unit, better, source, layer in (
+            ("ssm.step_share.train", "%", "lower", "program_span", PL),
+            ("ssm.scan_share.train", "%", "lower", "program_span", PL),
+            ("ssm.scan_roofline.train", "%", "higher", "device_trace", K),
+            ("lower.recurrent_ssm_calls.train", "count", "lower",
+             "program_counter", PL),
+            ("attn.diff_share.train", "%", "lower", "program_span", PL)):
+        assert metric in NEW
+        assert tiny.listed_as(metric, unit, better, source, layer, CELL)
+        assert tiny.cells_named(tiny.BENCH, metric) == [CELL]
+    for metric in ("step.block_share.train", "lower.xla_conv_calls.train",
+                   "lower.split_bwd_attn_calls.train",
+                   "lower.xla_embed_grad_calls.train",
+                   "embed.grad_share.train"):
+        assert CELL in tiny.cells_named(tiny.BENCH, metric), metric
+    # no expert layer, no rotary embedding: not on those lists
+    for metric in ("lower.ragged_moe_calls.train",
+                   "lower.whole_buffer_moe_calls.train",
+                   "lower.xla_rope_calls.train", "rope.step_share.train"):
+        assert CELL not in tiny.cells_named(tiny.BENCH, metric), metric
 
 
 def test_a_traced_tiny_run_counts_its_scans_and_passes_both_checks(

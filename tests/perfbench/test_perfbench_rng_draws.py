@@ -13,6 +13,15 @@ BENCH = harness.load_json("BENCHMARK.json")
 V5E = harness.load_json("perf", "peaks.json")["TPU v5 lite"]
 
 
+@pytest.fixture(autouse=True)
+def _trace_into_tmp(monkeypatch, tmp_path):
+    """A traced tiny run traces under the test's own directory, not
+    into the checkout's one directory a cell, which
+    ``harness.DeviceTrace`` empties on entry: two workers of one test
+    run would delete each other's trace."""
+    monkeypatch.setattr(harness, "TRACE_ROOT", str(tmp_path))
+
+
 @pytest.mark.parametrize("bench", tiny.BENCHES.values(),
                          ids=tiny.BENCHES.keys())
 def test_the_entry_is_found_by_name_and_lists_the_three_train_cells(bench):

@@ -382,19 +382,20 @@ def test_full_band_counter_reads_the_band_label():
 
 def test_a_traced_tiny_run_walks_no_expert_buffer_whole(monkeypatch,
                                                         tmp_path):
-    """The cell holds a share of its experts (experts 0-7, stated in the
-    file without a ``held_first`` key: test_perfbench_moe_rows.py pins
-    the files with that key, and with them the workloads of
-    ``lower.whole_buffer_moe_calls.train``, to two), so the line does
-    not carry that metric; its reader, asked here, reads 0 all the
-    same: every pass of the held layers is a loop over live rows."""
+    """The cell holds a share of its experts (experts 0-7; the file
+    says ``held_first`` 0 since PR 54, which lifted the pin that held
+    the files with that key to two), so it is on the list of
+    ``lower.whole_buffer_moe_calls.train`` and its line carries the
+    metric, which reads 0: every pass of the held layers is a loop over
+    live rows."""
     import json
 
     from paddle_tpu import monitor
 
     metric = "lower.whole_buffer_moe_calls.train"
-    assert CELL not in tiny.cells_named(tiny.BENCH, metric)
-    assert "held_first" not in full_config()
+    assert tiny.listed_as(metric, "count", "lower", "program_counter",
+                          "Program lowering", CELL)
+    assert full_config()["held_first"] == 0
     monkeypatch.setattr(harness, "peaks_for", lambda kind: {
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
         "hbm_bytes": 16e9})
@@ -406,7 +407,7 @@ def test_a_traced_tiny_run_walks_no_expert_buffer_whole(monkeypatch,
     train.run(run)
     line = json.loads(json.dumps(harness.result_line(run)))
     assert line["correct"], line
-    assert metric not in line["metrics"]
+    assert line["metrics"][metric]["value"] == 0
     assert read(metric, run) == 0
     # (without a TPU the windowed calls are the dense composition's)
     assert line["metrics"]["lower.full_band_swa_calls.train"]["value"] > 0

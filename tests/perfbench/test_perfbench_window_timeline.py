@@ -9,7 +9,6 @@ how far the traced stretch stands from it
 (``exec.window_run_ms_per_call.train``). The reduction is held on
 records written by hand; a tiny traced run reports all five."""
 
-import copy
 import json
 import math
 
@@ -25,7 +24,7 @@ NEW = {"window.step_drift.train": "ratio",
        "window.late_share.train": "%",
        "window.gc_ms.train": "ms",
        "exec.window_run_ms_per_call.train": "ms"}
-# per_layer as it stood before the five, in its order
+# per_layer as it stood before PR 54 appended to it, in its order
 OLDER = """exec.host_ms_per_step.train cache.first_call_s
 lower.dense_attn_calls.train step.mfu.train mesh.collective_share
 attn.time_share.train train_attn_roofline device.idle_share.train
@@ -50,37 +49,33 @@ V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
 # --- BENCHMARK.json ------------------------------------------------------
 
 
-def entry(name):
-    """The entry a ``benchmark`` PR gives the reader (PERF.md section 7
-    (20))."""
-    return {"name": name, "unit": NEW[name], "better": "lower",
-            "source": "program_span", "layer": "Executor",
-            "moves": "train_tokens_per_s",
-            "workloads": tiny.cells_named(tiny.BENCH, "train_tokens_per_s")}
-
-
-def listed():
-    """An in-memory copy of BENCHMARK.json with the five entries."""
-    bench = copy.deepcopy(tiny.BENCH)
-    bench["per_layer"] += [entry(n) for n in NEW]
-    return bench
+def unlisted():
+    """An in-memory copy of BENCHMARK.json without the five entries: a
+    tree in which the readers are files and no more, as the parent's."""
+    return dict(tiny.BENCH, per_layer=[
+        m for m in tiny.BENCH["per_layer"] if m["name"] not in NEW])
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_the_reader_waits_as_a_file_for_a_benchmark_pr(name):
-    """The five are files and tests, not entries: the driver takes a new
-    entry at the END of ``per_layer`` alone, and a pin in
-    tests/perfbench/ holds ``per_layer[-1]`` (PERF.md section 7 (20))."""
-    assert name not in {m["name"] for m in tiny.BENCH["per_layer"]}
+def test_the_reader_is_an_entry_that_lists_every_train_cell(name):
+    """The five are entries since PR 54, which appended them at the END
+    of ``per_layer`` (the driver takes a new entry there alone), each
+    with every cell that trains."""
+    train_cells = tiny.cells_named(tiny.BENCH, "train_tokens_per_s")
+    assert tiny.listed_as(name, NEW[name], "lower", "program_span",
+                          "Executor", *train_cells)
     assert callable(harness.reader_for(name).read)
-    assert len(entry(name)["workloads"]) == 10
-    assert entry(name) in harness.cell_metrics(listed(), "tbase-train-dp4",
-                                               "per_layer")
+    assert tiny.entry(tiny.BENCH, name) in harness.cell_metrics(
+        tiny.BENCH, "tbase-train-dp4", "per_layer")
 
 
 def test_every_older_entry_is_where_it_was():
+    """What PR 54 appended stands behind the entries that were there,
+    and those stand in their order: the driver reads an entry anywhere
+    but at the end of its list as a change to what was there."""
     names = [m["name"] for m in tiny.BENCH["per_layer"]]
-    assert len(OLDER) == 44 and names == OLDER
+    assert names[:len(OLDER)] == OLDER
+    assert set(NEW) <= set(names[len(OLDER):])
 
 
 # --- the reduction, on records written by hand ---------------------------
@@ -336,7 +331,7 @@ def test_a_tiny_run_reports_the_five_only_when_traced(
     monitor.reset()
     cell = dict(tiny.train_cell("tbase-train"), trace_seconds=0.5)
     run = tiny.make_run(cell, tiny.config(cell["config"]), seconds=1.0,
-                        traced=traced, bench=listed() if entries else None)
+                        traced=traced, bench=None if entries else unlisted())
     train.run(run)
     line = json.loads(json.dumps(harness.result_line(run)))
     assert line["correct"], line
@@ -351,7 +346,7 @@ def test_a_tiny_run_reports_the_five_only_when_traced(
     values = {n: harness.reader_for(n).read(run) for n in NEW}
     assert all(math.isfinite(v) for v in values.values())
     assert values["exec.window_run_ms_per_call.train"] > 0.0
-    if not entries:     # the committed BENCHMARK.json: files, no entries
+    if not entries:     # a BENCHMARK.json without them: files, no entries
         assert got == {}
         return
     assert {n: m["unit"] for n, m in got.items()} == NEW
