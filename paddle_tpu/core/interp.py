@@ -79,6 +79,11 @@ AMP_OP_TYPES = {
     # gated_rms_norm are not listed: each computes in float32 and
     # returns its input's dtype (gdn_gates float32) by itself.
     "gated_delta_rule",
+    # the vocabulary projection and the loss over the rows whose label
+    # counts (ops/nn_ops.py): X and W to bf16 as mul's, the logsumexp and
+    # the label's logit in float32 inside the op, the grad op's
+    # GRAD::Loss kept float32 (AMP_KEEP_F32_SLOTS below).
+    "linear_cross_entropy",
 }
 
 # Precision-following ops: when any input is already bf16, their remaining
@@ -105,7 +110,8 @@ AMP_FLOW_OP_TYPES = {
 # internal math, x-dtype output — so no input casting is wanted.)
 
 # Slots that must stay f32 under AMP (saved numerical stats, not streams).
-AMP_KEEP_F32_SLOTS = frozenset({"Lse", "GRAD::Lse", "G", "Beta"})
+AMP_KEEP_F32_SLOTS = frozenset(
+    {"Lse", "GRAD::Lse", "G", "Beta", "GRAD::Loss"})
 
 # Whether AMP casting is active for the block currently being traced;
 # None while no block is (core/lowering.run_block sets it for the length

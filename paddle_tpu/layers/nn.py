@@ -16,7 +16,7 @@ __all__ = [
     "layer_norm", "dropout", "relu", "sigmoid", "tanh", "sqrt", "exp", "log",
     "abs", "square", "gelu", "leaky_relu", "softplus", "softsign", "elu",
     "relu6", "swish", "hard_swish", "hard_sigmoid", "softmax", "log_softmax",
-    "cross_entropy", "softmax_with_cross_entropy",
+    "cross_entropy", "softmax_with_cross_entropy", "linear_cross_entropy",
     "sigmoid_cross_entropy_with_logits", "square_error_cost", "huber_loss",
     "smooth_l1", "mean", "mul", "matmul", "elementwise_op", "elementwise_add",
     "elementwise_sub", "elementwise_mul", "elementwise_div", "elementwise_pow",
@@ -859,6 +859,29 @@ def softmax_with_cross_entropy(
     )
     if return_softmax:
         return loss, softmax_out
+    return loss
+
+
+def linear_cross_entropy(x, size, label, ignore_index=-100, param_attr=None,
+                         name=None):
+    """The loss of ``softmax_with_cross_entropy(fc(x, size, bias_attr=
+    False, num_flatten_dims=x's rank - 1), label)`` on hard labels, [..., 1]
+    float32 and 0 on a row whose label is ``ignore_index`` (any integer),
+    as ONE op that projects the rows that count alone, in chunks
+    (ops/nn_ops.py linear_cross_entropy): for a head most of whose rows
+    carry no label. The [d, size] parameter is fc's (same initialiser,
+    same naming)."""
+    helper = LayerHelper("linear_cross_entropy", name=name)
+    w = helper.create_parameter(
+        ParamAttr._to_attr(param_attr), shape=[x.shape[-1], size],
+        dtype=x.dtype)
+    loss = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        "linear_cross_entropy",
+        inputs={"X": x, "W": w, "Label": label},
+        outputs={"Loss": loss},
+        attrs={"ignore_index": ignore_index},
+    )
     return loss
 
 

@@ -116,7 +116,9 @@ def build(cfg: Optional[BertConfig] = None, is_test: bool = False):
     x = T._ln(x, "enc_post")
 
     with fluid.name_scope("mlm_head"):
-        # MLM head: transform + vocab projection
+        # MLM head: the transform over every position; the vocabulary
+        # projection and the loss in one op over the masked positions
+        # alone (mlm_labels == -1: not masked, skipped)
         mlm = layers.fc(
             x, cfg.d_model, num_flatten_dims=2, act="gelu",
             param_attr=ParamAttr(name="mlm_tr_colp.w"),
@@ -127,10 +129,14 @@ def build(cfg: Optional[BertConfig] = None, is_test: bool = False):
             param_attr=ParamAttr(name="mlm_ln.scale"),
             bias_attr=ParamAttr(name="mlm_ln.bias"),
         )
-        mlm_logits = layers.fc(
-            mlm, cfg.vocab_size, num_flatten_dims=2,
-            param_attr=ParamAttr(name="mlm_proj_colp.w"), bias_attr=False,
-        )
+        ce = layers.linear_cross_entropy(
+            mlm, cfg.vocab_size, mlm_lbl, ignore_index=-1,
+            param_attr=ParamAttr(name="mlm_proj_colp.w"))
+        # every position's logits, for whoever fetches them (inference,
+        # a Predictor): dead in a step that fetches the loss
+        mlm_logits = layers.mul(
+            mlm, mlm.block.program.global_block().var("mlm_proj_colp.w"),
+            x_num_col_dims=2)
 
     with fluid.name_scope("nsp_head"):
         # NSP head over the [CLS] (first) position
@@ -143,13 +149,7 @@ def build(cfg: Optional[BertConfig] = None, is_test: bool = False):
         )
 
     with fluid.name_scope("mlm_head"):
-        # masked-LM loss over masked positions only (mlm_labels == -1
-        # ignored)
-        safe_lbl = layers.elementwise_max(
-            mlm_lbl, layers.fill_constant_like(mlm_lbl, 0.0))
-        ce = layers.softmax_with_cross_entropy(
-            mlm_logits, layers.unsqueeze(safe_lbl, [2]))
-        ce = layers.reshape(ce, [0, -1])
+        # the mean over the masked positions
         is_masked = layers.cast(
             layers.greater_than(
                 layers.cast(mlm_lbl, "float32"),
@@ -160,8 +160,7 @@ def build(cfg: Optional[BertConfig] = None, is_test: bool = False):
         mlm_count = layers.elementwise_max(
             layers.reduce_sum(is_masked),
             layers.fill_constant([], "float32", 1.0))
-        mlm_loss = layers.elementwise_div(
-            layers.reduce_sum(layers.elementwise_mul(ce, is_masked)), mlm_count)
+        mlm_loss = layers.elementwise_div(layers.reduce_sum(ce), mlm_count)
 
     with fluid.name_scope("nsp_head"):
         nsp_loss = layers.mean(

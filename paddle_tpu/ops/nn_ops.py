@@ -10,6 +10,8 @@ the NCHW API convention.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -17,6 +19,8 @@ from paddle_tpu import monitor as _monitor
 from paddle_tpu.core import interp
 from paddle_tpu.core.autodiff import GRAD_SLOT_PREFIX as GRAD_SLOT
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.parallel.grouped_matmul import (over_live_rows, put_rows,
+                                                rows_at)
 
 # Runs at TRACE time (once per compile, like pt_attention_dispatch_total):
 # how each lowered draw of random words was laid over the program's mesh.
@@ -426,11 +430,13 @@ _M_LOSS_HEAD = _monitor.counter(
     "softmax_with_cross_entropy calls lowered, one row a lowered call of "
     "the op (pass fwd) or of its grad op (pass bwd): labels hard / soft, "
     "softmax_grad 1 where the program reads Softmax and its gradient "
-    "reaches the grad op (the softmax's own vjp term is then paid for)")
+    "reaches the grad op (the softmax's own vjp term is then paid for); "
+    "labels hard_rows: linear_cross_entropy and its grad op, the "
+    "projection and the loss over the rows whose label counts")
 
 
 def loss_head_dispatch_counts():
-    """{"hard|soft fwd|bwd 0|1": calls lowered so far}:
+    """{"hard|soft|hard_rows fwd|bwd 0|1": calls lowered so far}:
     pt_loss_head_dispatch_total as rng_draw_counts() gives the draws."""
     out = {}
     for row in _monitor.snapshot()[_M_LOSS_HEAD.name]["values"]:
@@ -441,11 +447,11 @@ def loss_head_dispatch_counts():
     return out
 
 
-def _note_loss_head(soft_label, bwd, softmax_grad=False):
+def _note_loss_head(labels, bwd, softmax_grad=False):
     # off with telemetry; build-time shape inference is not a lowering
     if _monitor.enabled() and interp.lowering_active():
         _M_LOSS_HEAD.inc(labels={
-            "labels": "soft" if soft_label else "hard",
+            "labels": labels,
             "pass": "bwd" if bwd else "fwd",
             "softmax_grad": "1" if softmax_grad else "0"})
 
@@ -516,7 +522,7 @@ def _softmax_with_cross_entropy_grad_maker(op, block, out_grads, provide,
 def _softmax_with_cross_entropy(ins, attrs):
     logits, label = _x(ins, "Logits"), _x(ins, "Label")
     soft_label = attrs.get("soft_label", False)
-    _note_loss_head(soft_label, bwd=False)
+    _note_loss_head("soft" if soft_label else "hard", bwd=False)
     x, lse = _xent_rows(logits)
     if soft_label:
         loss = (lse * jnp.sum(label, axis=-1, keepdims=True)
@@ -543,7 +549,8 @@ def _softmax_with_cross_entropy_grad(ins, attrs):
     logits, label = _x(ins, "Logits"), _x(ins, "Label")
     g, gs = _x(ins, GRAD_SLOT + "Loss"), _x(ins, GRAD_SLOT + "Softmax")
     soft_label = attrs.get("soft_label", False)
-    _note_loss_head(soft_label, bwd=True, softmax_grad=gs is not None)
+    _note_loss_head("soft" if soft_label else "hard", bwd=True,
+                    softmax_grad=gs is not None)
     x, lse = _xent_rows(logits)
     p = jnp.exp(x - lse)
     d = None
@@ -562,6 +569,182 @@ def _softmax_with_cross_entropy_grad(ins, attrs):
         ds = p * (gs - jnp.sum(gs * p, axis=-1, keepdims=True))
         d = ds if d is None else d + ds
     return {GRAD_SLOT + "Logits": [d.astype(logits.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# linear_cross_entropy: the projection onto the vocabulary and the loss in
+# one op, over the rows whose label counts. A masked-LM head labels a
+# seventh of its positions; the rows that count are put first (a stable
+# sort of the flags) and walked in chunks of _ROWS_CHUNK, as many as the
+# live count asks for (a device scalar: the trip count is the data's and
+# one compiled loop serves every labelling; grouped_matmul.over_live_rows,
+# the expert layers' walk over their live rows). A chunk's logits
+# [_ROWS_CHUNK, vocab] are the only logits that ever exist; the grad op
+# makes them again. Every move of rows is a gather (XLA's scatter costs
+# more a row the more rows it takes: PERF.md section 6, PR 35): the
+# chunks gather their rows of X by the sorted order, the results are
+# written chunk by chunk into a compact buffer, and a row finds its own
+# there by the count of live rows before it.
+# ---------------------------------------------------------------------------
+
+# rows a trip of the loop projects. On bert-train (b256 x 128, 4,864 of
+# 32,768 rows live, vocabulary 30,522, one v5e; my chip run, PR 55, one
+# run each on one seed): 512 rows 178.26 ms a step, 1024 rows 176.89,
+# 2048 rows 178.60 (the parent 200.61). At 1024 a trip's matmuls run at
+# three quarters to nine tenths of the MXU's peak and hide the float32
+# dW carry's trip through HBM; ten trips of 512 do not, and three of
+# 2048 project 6,144 rows where five of 1024 project 5,120.
+_ROWS_CHUNK = 1024
+
+
+def _rows_that_count(x, label, ignore_index):
+    """(x [n, d], label [n] int32, c the chunk, keep [n], live, order
+    [a multiple of c >= n]: the live rows' indices first and in their
+    own order, place [n]: where a LIVE row sits in that order)."""
+    x = jnp.reshape(x, (-1, jnp.shape(x)[-1]))
+    label = jnp.reshape(label, (-1,)).astype(jnp.int32)
+    n = label.shape[0]
+    c = min(_ROWS_CHUNK, n)
+    keep = label != ignore_index
+    k = keep.astype(jnp.int32)
+    order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, -n % c))
+    return x, label, c, keep, jnp.sum(k), order, jnp.cumsum(k) - k
+
+
+def _rows(x, idx):
+    """x[idx] for indices this op made: all inside x."""
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+def _chunk(x, w, label, order, r0, c):
+    """A chunk of the compacted rows: (their indices, their rows of X,
+    those rows' logits, hit [c, vocab]: the label's column of each)."""
+    idx = rows_at(order, r0, c)
+    xc = _rows(x, idx)
+    logits = xc @ w
+    hit, _ = _xent_hard_label(logits, _rows(label, idx)[:, None], -1)
+    return idx, xc, logits, hit
+
+
+def _to_own_rows(buf, keep, place, shape):
+    """The compact buffer's rows at their own places, zeros on the rows
+    that do not count."""
+    return jnp.reshape(
+        jnp.where(keep[:, None], _rows(buf, place), 0), shape)
+
+
+# the eager engine (dygraph/tracer.py) differentiates an op's forward
+# itself and cannot walk a loop whose trip count is data: the forward's
+# vjp IS the grad op's function
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _linear_xent(x, w, label, ignore_index):
+    lead = jnp.shape(x)[:-1]
+    x, label, c, keep, live, order, place = _rows_that_count(
+        x, label, ignore_index)
+
+    def trip(r0, alive, lossc):
+        _, _, logits, hit = _chunk(x, w, label, order, r0, c)
+        xf, lse = _xent_rows(logits)
+        loss = lse - jnp.sum(jnp.where(hit, xf, 0.0), axis=-1,
+                             keepdims=True)
+        return put_rows(lossc, r0, jnp.where(alive, loss, 0.0))
+
+    lossc = over_live_rows(
+        live, c, trip, jnp.zeros((order.shape[0], 1), jnp.float32))
+    return _to_own_rows(lossc, keep, place, (*lead, 1))
+
+
+def _linear_xent_grads(x, w, label, g, ignore_index):
+    """(dX in X's dtype and shape, dW in W's): a chunk's logits made
+    again, dlogits = g * (softmax - onehot) in float32 and cast to the
+    logits' dtype, dX's rows written into the compact buffer, dW summed
+    in float32 in the loop's carry."""
+    shape = jnp.shape(x)
+    x, label, c, keep, live, order, place = _rows_that_count(
+        x, label, ignore_index)
+    g = jnp.reshape(g, (-1, 1)).astype(jnp.float32)
+
+    def trip(r0, alive, carry):
+        dxc, dw = carry
+        idx, xc, logits, hit = _chunk(x, w, label, order, r0, c)
+        xf, lse = _xent_rows(logits)
+        p = jnp.exp(xf - lse)
+        d = jnp.where(alive, _rows(g, idx), 0.0) * jnp.where(hit, p - 1.0, p)
+        d = d.astype(logits.dtype)
+        dw = dw + jax.lax.dot_general(
+            xc, d, (((0,), (0,)), ((), ())),
+            preferred_element_type=dw.dtype)
+        return put_rows(dxc, r0, d @ w.T), dw
+
+    dxc, dw = over_live_rows(
+        live, c, trip,
+        (jnp.zeros((order.shape[0], shape[-1]), x.dtype),
+         jnp.zeros(jnp.shape(w), jnp.promote_types(w.dtype, jnp.float32))))
+    return _to_own_rows(dxc, keep, place, shape), dw.astype(w.dtype)
+
+
+_linear_xent.defvjp(
+    lambda x, w, label, ignore_index: (
+        _linear_xent(x, w, label, ignore_index), (x, w, label)),
+    lambda ignore_index, res, g: (
+        *_linear_xent_grads(*res, g, ignore_index), None))
+
+
+def _linear_cross_entropy_grad_maker(op, block, out_grads, provide,
+                                     should_skip):
+    """linear_cross_entropy_grad over X, W, Label, GRAD::Loss ->
+    GRAD::X, GRAD::W (a hole where the program wants none)."""
+    from paddle_tpu.core.registry import get_op_def
+
+    g = (out_grads.get("Loss") or [""])[0]
+    opdef = get_op_def("linear_cross_entropy")
+    outs = {}
+    for slot in ("X", "W"):
+        name = op.inputs[slot][0]
+        if not g or should_skip(name, slot, opdef):
+            outs[GRAD_SLOT + slot] = [""]
+            continue
+        src = block._find_var_recursive(name)
+        gname = provide(name)
+        block.create_var(name=gname, shape=src.shape if src else None,
+                         dtype=src.dtype if src else "float32")
+        outs[GRAD_SLOT + slot] = [gname]
+    if not any(n for names in outs.values() for n in names):
+        return []
+    return [dict(
+        type="linear_cross_entropy_grad",
+        inputs={"X": list(op.inputs["X"]), "W": list(op.inputs["W"]),
+                "Label": list(op.inputs["Label"]), GRAD_SLOT + "Loss": [g]},
+        outputs=outs,
+        attrs=dict(op.attrs),
+    )]
+
+
+@register_op("linear_cross_entropy", diff_inputs=("X", "W"),
+             grad_maker=_linear_cross_entropy_grad_maker,
+             doc="Loss [..., 1] float32 = what mul(X [..., d], W [d, "
+                 "vocab]) + softmax_with_cross_entropy give on hard "
+                 "labels, 0 on a row whose Label is ignore_index (any "
+                 "integer), computed over the rows that count alone, in "
+                 "chunks: no [rows, vocab] tensor exists. Its grad op is "
+                 "its own (linear_cross_entropy_grad)")
+def _linear_cross_entropy(ins, attrs):
+    _note_loss_head("hard_rows", bwd=False)
+    return {"Loss": [_linear_xent(
+        _x(ins), _x(ins, "W"), _x(ins, "Label"),
+        int(attrs.get("ignore_index", -100)))]}
+
+
+@register_op("linear_cross_entropy_grad", no_grad=True)
+def _linear_cross_entropy_grad(ins, attrs):
+    """GRAD::X and GRAD::W in their operands' dtypes, by the forward's
+    own walk over the rows that count (_linear_xent_grads)."""
+    _note_loss_head("hard_rows", bwd=True)
+    dx, dw = _linear_xent_grads(
+        _x(ins), _x(ins, "W"), _x(ins, "Label"), _x(ins, GRAD_SLOT + "Loss"),
+        int(attrs.get("ignore_index", -100)))
+    return {GRAD_SLOT + "X": [dx], GRAD_SLOT + "W": [dw]}
 
 
 @register_op("sigmoid_cross_entropy_with_logits", diff_inputs=("X",))

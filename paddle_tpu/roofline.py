@@ -432,7 +432,7 @@ for _g, _names in (
 FRAMEWORK_GROUPS: Dict[str, Tuple[str, ...]] = {
     "matmul": ("matmul", "mul", "fc", "conv2d", "depthwise_conv2d",
                "conv2d_transpose", "sdpa", "flash_attention",
-               "sequence_conv"),
+               "sequence_conv", "linear_cross_entropy"),
     "elementwise": ("elementwise_add", "elementwise_sub",
                     "elementwise_mul", "elementwise_div", "relu",
                     "sigmoid", "tanh", "gelu", "scale", "dropout",

@@ -166,8 +166,16 @@ def test_the_compiled_step_carries_phase_scope_and_op_type(family):
     assert has(r"/bwd/enc0/ffn/mul_grad/.*dot_general$")
     assert has(r"/bwd/enc1/attn/scaled_dot_product_attention_grad/")
     assert has(r"/opt/adam/")
-    assert has(rf"/fwd/{head}/softmax_with_cross_entropy/")
+    # BERT's head projects and scores in one op, whose loop's ops carry
+    # while/body behind the op's name
+    loss_op = ("softmax_with_cross_entropy" if family == "transformer"
+               else "linear_cross_entropy")
+    assert has(rf"/fwd/{head}/{loss_op}/")
+    assert has(rf"/bwd/{head}/{loss_op}_grad/")
     assert has(rf"/bwd/{head}/mul_grad/")
+    if family == "bert":
+        assert has(rf"/fwd/{head}/{loss_op}/while/body/.*dot_general$")
+        assert has(rf"/bwd/{head}/{loss_op}_grad/while/body/.*dot_general$")
     if family == "transformer":
         assert has(r"/fwd/dec0/cross/mul/") and has(r"/bwd/dec1/self/")
         assert has(r"/fwd/embed_trg/lookup_table")
