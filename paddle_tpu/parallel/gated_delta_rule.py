@@ -11,7 +11,8 @@ and reaches HBM only as the bf16 ``States`` the backward pass reads.
 The backward kernel walks the blocks and the chunks inside one in
 reverse with dS in the scratch, and recomputes what the forward made of
 a chunk from q, k, v, g, beta and ``States``: nothing else is kept
-between the passes and no kernel runs twice.
+between the passes and no kernel runs twice. What a grid step makes of
+its chunks lives in VMEM scratch and never in HBM.
 
 What XLA's ops cannot do and a grid step does:
 
@@ -45,14 +46,44 @@ What XLA's ops cannot do and a grid step does:
   U = T (beta V), W = T (beta K e^G) and, backward, with dU = dV' and
   dW = -dV' S^T: d(beta V) = T^T dU, d(beta K e^G) = T^T dW and
   dA = -(T^T dU) U^T - (T^T dW) W^T: no transposed solve.
-- **The sequential part is two products a chunk and head**: V' = U - W S
-  and S <- e^{G_C} S + (K e^{G_C - G})^T V' (dV' and dS backward);
-  the rest of a chunk overlaps with them in the same loop body. The
-  chunks of a grid step are a ``fori_loop``, traced once: unrolled into
-  one straight line a call runs 0.55 ms sooner of 5.5 and 8.7 (forward,
-  backward, at b1 t8192 hk16 hv32 on a v5e) and costs a second of
-  tracing and lowering a layer, every process start (my chip runs,
-  PR 33: ``setup_s`` 35.7 -> 40.2 s warm over three layers).
+- **Each per-chunk quantity is made once, in passes no chunk waits
+  in.** A grid step (one key head's group, 8 chunks) is three parts
+  where it was one loop until PR 56:
+  1. the *pre-pass* (``_prepare``, ``_invert``, ``_apply``), for all the
+     chunks at once [chunks, C, .] and not a loop: the normalised q, k,
+     K K^T and Q K^T once a key head (one product against K^T); the
+     gates once a value head and chunk; A, T; [U | W] = T [beta V |
+     beta K e^G] as one batched product a head; the chunk's attention,
+     [W; Q e^G] and K e^{G_C - G} as matmul operands and e^{G_C}: all to
+     VMEM scratch (``_parts``: 2.1 MB a grid step of the cell's
+     forward, 8.6 MB backward, where the gates, q, k and their
+     products stay for the pass behind the loop);
+  2. the *state loop*, all that is sequential: forward three products a
+     head, [W; Q e^G] S (one push of S), V' = U - W S, o = (Q e^G) S +
+     attn V' and S <- e^{G_C} S + (K e^{G_C - G})^T V'; backward their
+     transposes around the saved state and V' (made again), dQ e^G and
+     dW as one product against S^T and dS as one contraction of 128
+     over [W; Q e^G]; it leaves dV' | dW and the cotangents of the
+     operands in scratch. A ``fori_loop`` of ``_UNROLL`` chunks a body;
+  3. backward, the *pass behind it*, again all the chunks at once: T^T
+     [dV' | dW] and dA (one contraction of 256) as batched products a
+     head, then what multiplies beta, G, q, k and v, with two sums
+     over the lanes a head and chunk where there were six.
+  What each part gave, alone at b1 t8192 hk16 hv32 on a v5e (my chip
+  runs, PR 56; ms a call, forward / backward): the parent's one loop
+  3.69 / 6.95; the gates, Q K^T, K K^T and the norms once and the
+  passes apart, each a loop over the chunks, 3.34 / 6.50; the lane sums
+  merged and the pass behind the loop one straight line 3.30 / 5.16;
+  the pre-pass for all the chunks at once 3.08 / 4.97; 4 chunks a body
+  of the state loop **2.80 / 4.75** (in the cell **2.34 / 3.86** of the
+  parent's 3.23 / 6.07). A product that shares an operand with its
+  neighbour (T against 256 lanes, dA over 256) costs what the two
+  cost: a v5e's MXU pops every 8 x 128 of a result once a pass
+  whatever the depth (benchmarks/gdn_candidates.py, ``*.apart``). What
+  did not pay: the products of chunk c + 1 written into the state
+  loop's body (an MXU takes its products in the order written, so the
+  chain waits behind them: 3.18-3.40 / 6.17-6.33); 8 chunks a body
+  (2.70 / 4.75, and a third more to trace).
 
 float32: g, its running sums and their exps, beta, the normalisation,
 A, T, its merges and its application (``precision=HIGHEST``), U, the
@@ -86,6 +117,12 @@ _BLOCK = 16     # T's diagonal blocks by substitution, the rest by merges
 # Chunks a grid step: 8 chunks are 512 rows of q, k, v a block, and 8
 # rows of g and beta [.., chunks, 64] are one float32 sublane tile.
 _STEP_CHUNKS = 8
+# Chunks of the state chain a loop body: the next chunk's loads and casts
+# fill the slots its products' latencies leave (my chip runs, PR 56:
+# 1 / 2 / 4 / 8 a body 3.08 / 2.90 / 2.80 / 2.70 ms a forward call and
+# 4.99 / 4.84 / 4.75 / 4.75 backward; a chunk more in a body is 48
+# equations more to trace forward and 87 backward, every layer).
+_UNROLL = 4
 # What a call's blocks and scratch may take of the v5e's 128 MiB of VMEM
 # (the calls raise Mosaic's scoped limit to what they need, _vmem_limit).
 _VMEM_CAP_BYTES = 48 * 2**20
@@ -106,20 +143,59 @@ def _under_mesh() -> bool:
     return interp.spmd_ctx() is not None
 
 
+def _parts(heads, chunks, dk, dv, dtype, backward):
+    """name -> (shape, dtype) of what a grid step makes ONCE of its
+    chunks and keeps in VMEM for the loops behind (the module
+    docstring's pre-pass), one entry a matrix (``_mat``) or a chunk:
+    ``uw`` [beta V | beta K e^G], then [U | W] in its place; the chunk's
+    attention, Q e^G and K e^{G_C - G} as matmul operands; e^{G_C} over
+    a sublane tile. The backward pass also keeps the decay, beta, e^G
+    and e^{G_C - G} (over all the lanes: no lane broadcast at a use),
+    the normalised q, k and 1 / |x|, K K^T and Q K^T, and what its state
+    loop leaves for the pass behind it: ``dd`` [dV' | dW], then
+    [T^T dV' | T^T dW] in its place, dA, and the cotangents of the
+    chunk's attention, Q e^G, K e^{G_C - G} and e^{G_C}."""
+    mats = heads * chunks
+    mat, tri = (mats, CHUNK, _LANES), (mats, CHUNK, CHUNK)
+    parts = dict(uw=((mats, CHUNK, dv + dk), _F32), attn=(tri, dtype),
+                 wq=((mats, 2 * CHUNK, dk), dtype),
+                 kd=((mats, CHUNK, dk), dtype), dec=((mats, 8, _LANES), _F32))
+    if backward:
+        row = (chunks, CHUNK, _LANES)
+        parts.update(
+            decay=(tri, _F32), beta=(mat, _F32), eg=(mat, _F32),
+            ekd=(mat, _F32), kn=(row, _F32), yq=(row, _F32), rk=(row, _F32),
+            rq=(row, _F32), kk=((chunks, CHUNK, CHUNK), _F32),
+            qk=((chunks, CHUNK, CHUNK), _F32),
+            dd=((mats, CHUNK, dv + dk), _F32), da=(tri, _F32),
+            dattn=(tri, _F32), dqg=((mats, CHUNK, dk), _F32),
+            dkd=((mats, CHUNK, dk), _F32), ddec=((mats, 8, _LANES), _F32))
+    return parts
+
+
+def _tiled_bytes(shape, dtype):
+    """What an array takes of VMEM: its last axis padded to the 128
+    lanes, the one before to a sublane tile (8 rows of 32 bits)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    tile = 8 * 4 // item
+    n = -(-rows // tile) * tile * -(-lanes // _LANES) * _LANES * item
+    for d in lead:
+        n *= d
+    return n
+
+
 def _vmem_bytes(heads, chunks, dk, dv):
     """What one grid step of the backward kernel (the larger) keeps in
     VMEM: its blocks double-buffered (q, k, dq, dk; v, dO, dv; the
     states; g, beta and their gradients padded to a sublane tile) and
-    the scratch (``_scratch``: a [64, 128] float32 tile for each pair's
-    A and each matrix's T, a [16, 128] one for a pair's diagonal blocks,
-    S or dS)."""
+    the scratch (``_scratch``)."""
     rows = chunks * CHUNK
-    pairs = -(-heads // 2) * chunks
     blocks = (4 * rows * dk * 2 + 3 * rows * heads * dv * 2
               + chunks * heads * dk * dv * 2
               + 4 * heads * max(chunks, 8) * _LANES * 4)
-    scratch = ((3 * CHUNK + _BLOCK) * pairs * _LANES * 4
-               + heads * dk * dv * 4)
+    scratch = sum(_tiled_bytes(x.shape, x.dtype) for x in _scratch(
+        heads, chunks, dk, dv, jnp.bfloat16, True))
     return 2 * blocks + scratch
 
 
@@ -171,6 +247,14 @@ def _dot(a, b, ca, cb, precision=None):
                                preferred_element_type=_F32)
 
 
+def _bdot(a, b, ca, cb, precision=None):
+    """``_dot`` of every pair a[i], b[i] (the axes count the leading
+    one)."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((0,), (0,))),
+                               precision=precision,
+                               preferred_element_type=_F32)
+
+
 def _iotas():
     shape = (CHUNK, CHUNK)
     return (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
@@ -178,43 +262,40 @@ def _iotas():
 
 
 def _col(row, ii, jj):
-    """[1, C] -> [C, 1]: the row's entries down a column (a masked sum
-    over the lanes: no transpose of a 1-row array)."""
-    return jnp.sum(jnp.where(ii == jj, row, 0.0), axis=1, keepdims=True)
+    """[.., 1, C] -> [.., C, 1]: the row's entries down a column (a
+    masked sum over the lanes: no transpose of a 1-row array)."""
+    return jnp.sum(jnp.where(ii == jj, row, 0.0), axis=-1, keepdims=True)
 
 
 def _l2(x, eps):
     """float32 x, x / |x| and 1 / |x| over the last axis (HF's l2norm:
     x * rsqrt(sum(x^2) + eps))."""
     xf = x.astype(_F32)
-    r = jax.lax.rsqrt(jnp.sum(xf * xf, axis=1, keepdims=True) + eps)
+    r = jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
     return xf * r, r
 
 
 def _gates(g_row, b_row, ii, jj):
-    """From a chunk's g and beta as rows [1, C]: the running sum of g
-    down a column, beta down a column, D = exp(G_i - G_j) for i >= j
+    """From the chunks' g and beta as rows [n, 1, C]: the running sum of
+    g down a column, beta down a column, D = exp(G_i - G_j) for i >= j
     (the inner where: exp of a masked, positive difference overflows),
-    exp(G), exp(G_C - G) and exp(G_C) [1, 1]."""
+    exp(G), exp(G_C - G) and exp(G_C) [n, 1, 1]."""
     lower = ii >= jj
-    gc = jnp.sum(jnp.where(lower, g_row, 0.0), axis=1, keepdims=True)
+    gc = jnp.sum(jnp.where(lower, g_row, 0.0), axis=-1, keepdims=True)
     gc_row = jnp.sum(jnp.where(ii <= jj, _col(g_row, ii, jj), 0.0),
-                     axis=0, keepdims=True)
+                     axis=-2, keepdims=True)
     decay = jnp.where(lower, jnp.exp(jnp.where(lower, gc - gc_row, 0.0)),
                       0.0)
-    g_last = jnp.sum(g_row, axis=1, keepdims=True)
+    g_last = jnp.sum(g_row, axis=-1, keepdims=True)
     return dict(beta=_col(b_row, ii, jj), decay=decay, eg=jnp.exp(gc),
                 ekd=jnp.exp(g_last - gc), dec=jnp.exp(g_last))
 
 
 def _rows(c):
     """The rows of chunk ``c`` in a block of q, k, v."""
+    if isinstance(c, int):
+        return pl.ds(c * CHUNK, CHUNK)
     return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
-
-
-def _row(c):
-    """Chunk ``c``'s row of a block of g or beta [heads, chunks, C]."""
-    return pl.ds(c, 1)
 
 
 def _slot(r, c, chunks):
@@ -224,28 +305,85 @@ def _slot(r, c, chunks):
     return r // 2 * chunks + c, r % 2
 
 
-def _triangles(k_ref, g_ref, beta_ref, a_ref, *, heads, chunks, eps):
-    """a_ref[pair] <- A = strictly_lower(beta_i (k_i . k_j) D_ij) of
-    every chunk c of a block, two value heads side by side over the 128
-    lanes (``_slot``; zeros beside an odd last head)."""
+def _mat(r, c, chunks):
+    """Value head ``r``'s chunk ``c`` among a grid step's matrices
+    (``_parts``' leading axis): a head's chunks side by side."""
+    return r * chunks + c
+
+
+def _mats(r, chunks):
+    """All of value head ``r``'s matrices (``_mat``)."""
+    return slice(r * chunks, (r + 1) * chunks)
+
+
+def _pairs(r, chunks):
+    """The pairs that hold value head ``r``'s chunks (``_slot``)."""
+    first = _slot(r, 0, chunks)[0]
+    return slice(first, first + chunks)
+
+
+def _by_chunk(ref, chunks, cols=slice(None)):
+    """A block of q, k or v [chunks * C, .] -> [chunks, C, .]."""
+    x = ref[:, cols]
+    return x.reshape(chunks, CHUNK, x.shape[-1])
+
+
+def _gate_rows_of(ref, r, chunks):
+    """Head ``r``'s rows of a block of g or beta [heads, chunks, C] ->
+    [chunks, 1, C]."""
+    return ref[r].reshape(chunks, 1, CHUNK)
+
+
+def _prepare(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, *, heads,
+             chunks, eps, scale):
+    """The pre-pass: what depends on no state, for all the chunks of
+    the grid step at once [chunks, C, .]: once a key head the normalised
+    q, k, K K^T and Q K^T (one product against K^T); once a value head
+    the gates, then a_ref[pair] <- A = strictly_lower(beta_i (k_i . k_j)
+    D_ij), two value heads side by side over the 128 lanes (``_slot``;
+    zeros beside an odd last head), and ``_parts``' operands of the
+    loops behind. No chunk waits for another and nothing is a loop."""
+    dtype = q_ref.dtype
+    dv = v_ref.shape[-1] // heads
     ii, jj = _iotas()
+    backward = "decay" in p
 
-    def chunk(c, carry):
-        kn, _ = _l2(k_ref[_rows(c), :], eps)
-        kb = kn.astype(k_ref.dtype)
-        kk = _dot(kb, kb, 1, 1)
-        for r0 in range(0, heads, 2):
-            halves = []
-            for r in range(r0, min(r0 + 2, heads)):
-                gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :],
-                            ii, jj)
-                halves.append(jnp.where(
-                    ii > jj, gt["beta"] * kk * gt["decay"], 0.0))
-            halves += [jnp.zeros_like(kk)] * (2 - len(halves))
-            a_ref[_slot(r0, c, chunks)[0]] = jnp.concatenate(halves, axis=1)
-        return carry
+    def wide(col):
+        return jnp.broadcast_to(col, (chunks, CHUNK, _LANES))
 
-    jax.lax.fori_loop(0, chunks, chunk, None)
+    yq, rq = _l2(_by_chunk(q_ref, chunks), eps)
+    kn, rk = _l2(_by_chunk(k_ref, chunks), eps)
+    qn = yq * scale
+    kb = kn.astype(dtype)
+    both = _bdot(jnp.concatenate([qn.astype(dtype), kb], axis=1), kb, 2, 2)
+    qk, kk = both[:, :CHUNK], both[:, CHUNK:]
+    if backward:
+        p["kn"][...], p["yq"][...] = kn, yq
+        p["rk"][...], p["rq"][...] = wide(rk), wide(rq)
+        p["kk"][...], p["qk"][...] = kk, qk
+    for r0 in range(0, heads, 2):
+        halves = []
+        for r in range(r0, min(r0 + 2, heads)):
+            ms = _mats(r, chunks)
+            gt = _gates(_gate_rows_of(g_ref, r, chunks),
+                        _gate_rows_of(beta_ref, r, chunks), ii, jj)
+            beta, decay, eg, ekd = (gt[x] for x in (
+                "beta", "decay", "eg", "ekd"))
+            halves.append(jnp.where(ii > jj, beta * kk * decay, 0.0))
+            # (the decay is 0 above the diagonal: no mask)
+            p["attn"][ms] = (qk * decay).astype(dtype)
+            p["wq"][ms, CHUNK:] = (qn * eg).astype(dtype)
+            p["kd"][ms] = (kn * ekd).astype(dtype)
+            v = _by_chunk(v_ref, chunks, slice(r * dv, (r + 1) * dv))
+            p["uw"][ms] = jnp.concatenate(
+                [beta * v.astype(_F32), (beta * eg) * kn], axis=2)
+            p["dec"][ms] = jnp.broadcast_to(gt["dec"], (chunks, 8, _LANES))
+            if backward:
+                p["decay"][ms] = decay
+                p["beta"][ms], p["eg"][ms] = wide(beta), wide(eg)
+                p["ekd"][ms] = wide(ekd)
+        halves += [jnp.zeros_like(kk)] * (2 - len(halves))
+        a_ref[_pairs(r0, chunks)] = jnp.concatenate(halves, axis=2)
 
 
 def _columns(d):
@@ -351,20 +489,54 @@ def _invert(a_ref, t_ref, x_ref):
         t_ref[:, half] = t[:, :, half * CHUNK:(half + 1) * CHUNK]
 
 
-def _chunk_parts(qn, kn, v, gt, t, dtype):
-    """The module docstring's per-chunk quantities of one value head
-    from the normalised q, k [C, dk] (float32), v [C, dv], the gates
-    and T: U, W (float32), Q K^T, and Q e^G, K e^{G_C - G}, the chunk's
-    attention as matmul operands."""
-    ii, jj = _iotas()
-    beta = gt["beta"]
-    u = _dot(t, beta * v.astype(_F32), 1, 0, _HIGHEST)
-    w = _dot(t, (beta * gt["eg"]) * kn, 1, 0, _HIGHEST)
-    qk = _dot(qn.astype(dtype), kn.astype(dtype), 1, 1)
-    attn = jnp.where(ii >= jj, qk * gt["decay"], 0.0)
-    return dict(u=u, w=w, qk=qk, attn=attn.astype(dtype),
-                qg=(qn * gt["eg"]).astype(dtype),
-                kd=(kn * gt["ekd"]).astype(dtype))
+def _apply(t_ref, p, *, heads, chunks):
+    """[U | W] = T [beta V | beta K e^G] in its place for every matrix
+    of the grid step (two right-hand sides side by side): a batched
+    product a head over its chunks, float32 as every application of T,
+    written for all the matrices at once so that none waits for its
+    neighbour's (``_merge``); W as a matmul operand over Q e^G."""
+    dv = p["uw"].shape[-1] - p["wq"].shape[-1]
+    for r in range(heads):
+        ms = _mats(r, chunks)
+        uw = _bdot(_t_of(t_ref, r, chunks), p["uw"][ms], 2, 1, _HIGHEST)
+        p["uw"][ms] = uw
+        p["wq"][ms, :CHUNK] = uw[:, :, dv:].astype(p["wq"].dtype)
+
+
+def _t_of(t_ref, r, chunks):
+    """Head ``r``'s T of every chunk [chunks, C, C] (``_slot``)."""
+    return t_ref[_pairs(r, chunks), r % 2]
+
+
+def _in_turn(n, body):
+    """``body(i)`` for i in 0 .. n - 1, in turn: a ``fori_loop`` whose
+    body holds ``_UNROLL`` of them (the last iterations of an n that is
+    no multiple stand behind it)."""
+    whole = n // _UNROLL
+    if whole > 1:
+        def step(i, carry):
+            for j in range(_UNROLL):
+                body(_UNROLL * i + j)
+            return carry
+
+        jax.lax.fori_loop(0, whole, step, None)
+    else:
+        whole = 0
+    for i in range(whole * _UNROLL, n):
+        body(i)
+
+
+def _through_t(t, dd):
+    """T^T [dV' | dW] of every matrix [mats, C, .]: d(beta V) and
+    d(beta K e^G) side by side, float32 as every application of T."""
+    return _bdot(t, dd, 1, 1, _HIGHEST)
+
+
+def _d_triangle(dr, uw):
+    """dA = -(T^T dV') U^T - (T^T dW) W^T of every matrix as ONE
+    contraction over [. | .] and [U | W], 256 deep (the mask is its
+    reader's)."""
+    return -_bdot(dr, uw, 2, 2, _HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -373,39 +545,36 @@ def _chunk_parts(qn, kn, v, gt, t, dtype):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
-                s_ref, a_ref, t_ref, x_ref, *, heads, chunks, eps, scale):
+                s_ref, a_ref, t_ref, x_ref, *parts, heads, chunks, eps,
+                scale):
     dtype = q_ref.dtype
     dv = v_ref.shape[-1] // heads
-    ii, jj = _iotas()
+    p = dict(zip(_parts(heads, chunks, q_ref.shape[-1], dv, dtype, False),
+                 parts))
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    _triangles(k_ref, g_ref, beta_ref, a_ref, heads=heads, chunks=chunks,
-               eps=eps)
+    _prepare(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, heads=heads,
+             chunks=chunks, eps=eps, scale=scale)
     _invert(a_ref, t_ref, x_ref)
 
-    def chunk(c, carry):
+    def chain(c):               # the state chain: three products a head
         rows = _rows(c)
-        qn, _ = _l2(q_ref[rows, :], eps)
-        kn, _ = _l2(k_ref[rows, :], eps)
-        qn = qn * scale
         for r in range(heads):
-            cols = slice(r * dv, (r + 1) * dv)
-            gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
-            p = _chunk_parts(qn, kn, v_ref[rows, cols], gt,
-                             t_ref[_slot(r, c, chunks)], dtype)
+            m = _mat(r, c, chunks)
             s = s_ref[r]
             sb = s.astype(dtype)
             states_ref[c, r] = sb
-            vn = (p["u"] - _dot(p["w"].astype(dtype), sb, 1, 0)).astype(dtype)
-            o = _dot(p["qg"], sb, 1, 0) + _dot(p["attn"], vn, 1, 0)
+            ws_qs = _dot(p["wq"][m], sb, 1, 0)      # W S over (Q e^G) S
+            vn = (p["uw"][m, :, :dv] - ws_qs[:CHUNK]).astype(dtype)
+            o = ws_qs[CHUNK:] + _dot(p["attn"][m], vn, 1, 0)
             o_ref[r, rows, :] = o.astype(o_ref.dtype)
-            s_ref[r] = s * gt["dec"] + _dot(p["kd"], vn, 0, 0)
-        return carry
+            s_ref[r] = s * p["dec"][m, :1] + _dot(p["kd"][m], vn, 0, 0)
 
-    jax.lax.fori_loop(0, chunks, chunk, None)
+    _apply(t_ref, p, heads=heads, chunks=chunks)
+    _in_turn(chunks, chain)
 
 
 def _padded(x, axis, size):
@@ -457,14 +626,17 @@ def _specs(heads, chunks, dk, dv, blk):
                          lambda i, h, c: (blk(c), i, h, 0, 0)))
 
 
-def _scratch(heads, chunks, dk, dv):
+def _scratch(heads, chunks, dk, dv, dtype, backward):
     """S or dS; A of the block's chunks and pairs of heads (``_slot``),
-    T of each matrix, the pairs' diagonal blocks (``_substitute``)."""
+    T of each matrix, the pairs' diagonal blocks (``_substitute``); then
+    ``_parts``, in its order."""
     pairs = -(-heads // 2) * chunks
     return [pltpu.VMEM((heads, dk, dv), _F32),
             pltpu.VMEM((pairs, CHUNK, 2 * CHUNK), _F32),
             pltpu.VMEM((pairs, 2, CHUNK, CHUNK), _F32),
-            pltpu.VMEM((pairs, _BLOCK, _LANES), _F32)]
+            pltpu.VMEM((pairs, _BLOCK, _LANES), _F32)] + [
+        pltpu.VMEM(shape, dt) for shape, dt in _parts(
+            heads, chunks, dk, dv, dtype, backward).values()]
 
 
 def _cost(b, hv, n, dk, dv, passes, bytes_accessed):
@@ -507,7 +679,7 @@ def gated_delta_rule_fwd(q, k, v, g, beta, tile, eps=1e-6):
             grid=(b, hk, n_pad // chunks),
             in_specs=[qk_spec, qk_spec, v_spec, gate_spec, gate_spec],
             out_specs=(o_spec, st_spec),
-            scratch_shapes=_scratch(heads, chunks, dk, dv)),
+            scratch_shapes=_scratch(heads, chunks, dk, dv, q.dtype, False)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv)),
@@ -527,9 +699,11 @@ def gated_delta_rule_fwd(q, k, v, g, beta, tile, eps=1e-6):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                ds_ref, a_ref, t_ref, x_ref, *, heads, chunks, eps, scale):
+                ds_ref, a_ref, t_ref, x_ref, *parts, heads, chunks, eps,
+                scale):
     dtype = q_ref.dtype
-    dv = v_ref.shape[-1] // heads
+    dk, dv = q_ref.shape[-1], v_ref.shape[-1] // heads
+    p = dict(zip(_parts(heads, chunks, dk, dv, dtype, True), parts))
     ii, jj = _iotas()
     lower = ii >= jj
     last_row = jax.lax.broadcasted_iota(
@@ -539,88 +713,111 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    _triangles(k_ref, g_ref, beta_ref, a_ref, heads=heads, chunks=chunks,
-               eps=eps)
+    _prepare(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, heads=heads,
+             chunks=chunks, eps=eps, scale=scale)
     _invert(a_ref, t_ref, x_ref)
 
-    def rowsum(x):
-        return jnp.sum(x, axis=1, keepdims=True)
-
-    def chunk(i, carry):
-        c = chunks - 1 - i       # a block's chunks as the blocks: in reverse
+    def chain(c):
+        # the state pass backwards: the transposes of its products
+        # around the saved state and V' (made again)
         rows = _rows(c)
-        yq, rq = _l2(q_ref[rows, :], eps)
-        kn, rk = _l2(k_ref[rows, :], eps)
-        qn = yq * scale
-        qb, kb = qn.astype(dtype), kn.astype(dtype)
-        kk = _dot(kb, kb, 1, 1)
-        dqn = jnp.zeros_like(qn)
-        dkn = jnp.zeros_like(kn)
         for r in range(heads):
-            cols = slice(r * dv, (r + 1) * dv)
-            gt = _gates(g_ref[r, _row(c), :], beta_ref[r, _row(c), :], ii, jj)
-            beta, decay, eg, ekd, dec = (gt[x] for x in (
-                "beta", "decay", "eg", "ekd", "dec"))
-            t = t_ref[_slot(r, c, chunks)]
-            vf = v_ref[rows, cols].astype(_F32)
-            p = _chunk_parts(qn, kn, vf, gt, t, dtype)
-            u, w, qk, attn, qg, kd = (p[x] for x in (
-                "u", "w", "qk", "attn", "qg", "kd"))
-            wb = w.astype(dtype)
+            m = _mat(r, c, chunks)
             sb = states_ref[c, r]
             ds = ds_ref[r]
             dsb = ds.astype(dtype)
-            dob = do_ref[rows, cols]
-            # the state pass backwards: the transposes of its four
-            # products around the saved state and V' (made again)
-            vn = (u - _dot(wb, sb, 1, 0)).astype(dtype)
+            dob = do_ref[rows, r * dv:(r + 1) * dv]
+            wq, kd, attn = p["wq"][m], p["kd"][m], p["attn"][m]
+            vn = (p["uw"][m, :, :dv] - _dot(wq[:CHUNK], sb, 1, 0)
+                  ).astype(dtype)
             dvn = _dot(attn, dob, 0, 0) + _dot(kd, dsb, 1, 0)
-            dvnb = dvn.astype(dtype)
-            dattn = jnp.where(lower, _dot(dob, vn, 1, 1), 0.0)
-            dqg = _dot(dob, sb, 1, 1)
-            dkd = _dot(vn, dsb, 1, 1)
-            dw = -_dot(dvnb, sb, 1, 1)
-            ddec = jnp.sum(rowsum(ds * sb.astype(_F32)), axis=0,
-                           keepdims=True)
-            ds_ref[r] = (ds * dec + _dot(qg, dob, 0, 0)
-                         - _dot(wb, dvnb, 0, 0))
-            # through U = T (beta V), W = T (beta K e^G), T = (I + A)^-1
-            dru = _dot(t, dvn, 0, 0, _HIGHEST)
-            drw = _dot(t, dw, 0, 0, _HIGHEST)
-            da = jnp.where(ii > jj, -(_dot(dru, u, 1, 1, _HIGHEST)
-                                      + _dot(drw, w, 1, 1, _HIGHEST)), 0.0)
-            f = da * kk * decay
-            drw_k = rowsum(drw * kn)
-            dbeta = rowsum(f) + rowsum(dru * vf) + eg * drw_k
-            deg = beta * drw_k + rowsum(dqg * qn)
-            dekd = rowsum(dkd * kn)
-            e = beta * f + dattn * qk * decay   # d/d(G_i - G_j), i >= j
-            dgc = (rowsum(e)
-                   - _col(jnp.sum(e, axis=0, keepdims=True), ii, jj)
-                   + deg * eg - dekd * ekd)
-            dg_last = (jnp.sum(dekd * ekd, axis=0, keepdims=True)
-                       + ddec * dec)
-            dgc = dgc + jnp.where(last_row, dg_last, 0.0)
-            # G is g's running sum: dg_m = sum of dG_i over i >= m
-            dg_ref[r, _row(c), :] = jnp.sum(
-                jnp.where(lower, dgc, 0.0), axis=0, keepdims=True)
-            dbeta_ref[r, _row(c), :] = jnp.sum(
-                jnp.where(ii == jj, dbeta, 0.0), axis=0, keepdims=True)
-            dv_ref[rows, cols] = (beta * dru).astype(dv_ref.dtype)
-            dkk = (da * (beta * decay)).astype(dtype)
-            dqk = (dattn * decay).astype(dtype)
-            dqn = dqn + _dot(dqk, kb, 1, 0) + dqg * eg
-            dkn = (dkn + _dot(dqk, qb, 0, 0) + _dot(dkk, kb, 1, 0)
-                   + _dot(dkk, kb, 0, 0) + dkd * ekd + (beta * eg) * drw)
-        # through y = x rsqrt(|x|^2 + eps): dx = r (dy - y (y . dy))
-        dyq = dqn * scale
-        dq_ref[rows, :] = (rq * (dyq - yq * rowsum(yq * dyq))).astype(
-            dq_ref.dtype)
-        dk_ref[rows, :] = (rk * (dkn - kn * rowsum(kn * dkn))).astype(
-            dk_ref.dtype)
-        return carry
+            minus = (-dvn).astype(dtype)
+            p["dattn"][m] = _dot(dob, vn, 1, 1)
+            # d(Q e^G) over dW = -dV' S^T: one product against S^T
+            dqg_dw = _dot(jnp.concatenate([dob, minus], axis=0), sb, 1, 1)
+            p["dqg"][m] = dqg_dw[:CHUNK]
+            p["dkd"][m] = _dot(vn, dsb, 1, 1)
+            p["dd"][m] = jnp.concatenate([dvn, dqg_dw[CHUNK:]], axis=1)
+            p["ddec"][m] = jnp.broadcast_to(jnp.sum(
+                ds * sb.astype(_F32), axis=0, keepdims=True), (8, _LANES))
+            # dS: (Q e^G)^T dO - W^T dV', one contraction of 128
+            ds_ref[r] = ds * p["dec"][m, :1] + _dot(
+                wq, jnp.concatenate([minus, dob], axis=0), 0, 0)
 
-    jax.lax.fori_loop(0, chunks, chunk, None)
+    # a block's chunks as the blocks: in reverse
+    _apply(t_ref, p, heads=heads, chunks=chunks)
+    _in_turn(chunks, lambda i: chain(chunks - 1 - i))
+
+    # through U = T (beta V), W = T (beta K e^G), T = (I + A)^-1, every
+    # matrix of the grid step at once
+    for r in range(heads):
+        ms = _mats(r, chunks)
+        p["dd"][ms] = _through_t(_t_of(t_ref, r, chunks), p["dd"][ms])
+    for r in range(heads):
+        ms = _mats(r, chunks)
+        p["da"][ms] = _d_triangle(p["dd"][ms], p["uw"][ms])
+
+    def rowsum(x):
+        return jnp.sum(x, axis=-1, keepdims=True)
+
+    def half(x):                # [.., C, C] -> [.., C, 128], zeros beside
+        return jnp.concatenate([x, jnp.zeros_like(x)], axis=-1)
+
+    # what is left, all the chunks of the grid step at once [chunks, C,
+    # .], a value head at a time: no chunk waits for another and nothing
+    # is a loop, one straight line under the products above
+    kn, yq, kk, qk = (p[x][...] for x in ("kn", "yq", "kk", "qk"))
+    qn = yq * scale
+    kb = kn.astype(dtype)
+    dqn = dkn = dqk = dkk = 0.0
+    for r in range(heads):
+        ms = _mats(r, chunks)
+        cols = slice(r * dv, (r + 1) * dv)
+        beta_w, eg_w, ekd_w = (p[x][ms] for x in ("beta", "eg", "ekd"))
+        beta = beta_w[:, :, :1]
+        decay = p["decay"][ms]
+        dd = p["dd"][ms]
+        dru, drw = dd[:, :, :dv], dd[:, :, dv:]
+        dqg, dkd = p["dqg"][ms], p["dkd"][ms]
+        vf = _by_chunk(v_ref, chunks, cols).astype(_F32)
+        da = jnp.where(ii > jj, p["da"][ms], 0.0)
+        dattn = jnp.where(lower, p["dattn"][ms], 0.0)
+        f = da * kk * decay
+        e = beta * f + dattn * qk * decay       # d/d(G_i - G_j), i >= j
+        # the sums over a row's 128 features, two a head: what beta and
+        # what G_i multiply (f and e ride in their lower lanes)
+        drw_kn, dkd_kn = drw * kn, ekd_w * (dkd * kn)
+        to_beta = dru * vf + eg_w * drw_kn
+        to_g = eg_w * (beta_w * drw_kn + dqg * qn) - dkd_kn
+        dbeta = rowsum(to_beta + half(f))
+        dgc = (rowsum(to_g + half(e))
+               - _col(jnp.sum(e, axis=1, keepdims=True), ii, jj))
+        dg_last = rowsum(jnp.sum(dkd_kn, axis=1, keepdims=True)
+                         + p["ddec"][ms, :1] * p["dec"][ms, :1, :1])
+        dgc = dgc + jnp.where(last_row, dg_last, 0.0)
+        # G is g's running sum: dg_m = sum of dG_i over i >= m
+        dg = jnp.sum(jnp.where(lower, dgc, 0.0), axis=1, keepdims=True)
+        db = jnp.sum(jnp.where(ii == jj, dbeta, 0.0), axis=1, keepdims=True)
+        dvs = (beta_w * dru).astype(dv_ref.dtype)
+        dg_ref[r] = dg.reshape(chunks, CHUNK)
+        dbeta_ref[r] = db.reshape(chunks, CHUNK)
+        dv_ref[:, cols] = dvs.reshape(chunks * CHUNK, dv)
+        # dQ K^T and dK K^T summed over the group before their products
+        # (in float32: a cast a key head, not a value head)
+        dqk = dqk + dattn * decay
+        dkk = dkk + da * (beta * decay)
+        dqn = dqn + dqg * eg_w
+        dkn = dkn + dkd * ekd_w + (beta_w * eg_w) * drw
+    dqk, dkk = dqk.astype(dtype), dkk.astype(dtype)
+    dqn = dqn + _bdot(dqk, kb, 2, 1)
+    dkn = (dkn + _bdot(dqk, qn.astype(dtype), 1, 1) + _bdot(dkk, kb, 2, 1)
+           + _bdot(dkk, kb, 1, 1))
+    # through y = x rsqrt(|x|^2 + eps): dx = r (dy - y (y . dy))
+    dyq = dqn * scale
+    dq_ref[...] = (p["rq"][...] * (dyq - yq * rowsum(yq * dyq))).astype(
+        dq_ref.dtype).reshape(dq_ref.shape)
+    dk_ref[...] = (p["rk"][...] * (dkn - kn * rowsum(kn * dkn))).astype(
+        dk_ref.dtype).reshape(dk_ref.shape)
 
 
 def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
@@ -652,7 +849,7 @@ def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
             in_specs=[qk_spec, qk_spec, v_spec, gate_spec, gate_spec,
                       st_spec, v_spec],
             out_specs=(qk_spec, qk_spec, v_spec, gate_spec, gate_spec),
-            scratch_shapes=_scratch(heads, chunks, dk, dv)),
+            scratch_shapes=_scratch(heads, chunks, dk, dv, q.dtype, True)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv)),

@@ -410,6 +410,65 @@ def test_gated_delta_rule_kernels_compile(t, hk, hv, one_chip, real_kernels):
     assert f"f32[1,{hv},{t},128]" not in text
 
 
+def _gdn_cell_call(one_chip):
+    """qwen3next-train-s8192's call of the delta rule as shapes on the
+    described chip: (q, k, v, g, beta, dO, States), its tile."""
+    bf, t, hk, hv = jnp.bfloat16, 8192, 16, 32
+
+    def arg(shape, dt=bf):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    qk, v = arg((1, t, hk, 128)), arg((1, t, hv, 128))
+    gate = arg((1, t, hv), jnp.float32)
+    states = arg((t // 64, 1, hv, 128, 128))
+    tile = gdr.gdn_tile(t, hk, hv, 128, 128, 64, bf, "tpu", False)
+    assert tile == (2, 8)
+    return (qk, qk, v, gate, gate, v, states), tile
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_gated_delta_rule_passes_are_one_mosaic_call_each(which, one_chip,
+                                                          real_kernels):
+    """At the cell's call each pass is ONE Mosaic call: the pre-pass
+    (what a grid step makes once of its chunks), the state loop and the
+    pass behind it are parts of one kernel with their hand-offs in VMEM
+    scratch, not calls with tensors in HBM between them."""
+    (q, k, v, g, beta, do, states), tile = _gdn_cell_call(one_chip)
+    if which == "fwd":
+        lowered = jax.jit(lambda *x: gdr.gated_delta_rule_fwd(
+            *x, tile)).lower(q, k, v, g, beta)
+    else:
+        lowered = jax.jit(lambda *x: gdr.gated_delta_rule_bwd(
+            *x, tile)).lower(q, k, v, g, beta, states, do)
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"gdn.rule.{which}" in text
+    assert " while(" not in text
+
+
+def test_gated_delta_rule_passes_compile_inside_a_while_body(one_chip,
+                                                             real_kernels):
+    """As a ``run_steps`` window lowers them: both passes inside a While
+    body, still one Mosaic call each, with the scratch of the pre-pass
+    under the VMEM limit the calls ask for there too."""
+    (q, k, v, g, beta, do, _), tile = _gdn_cell_call(one_chip)
+
+    def window(q, k, v, g, beta, do):
+        def step(_, qkv):
+            q, k, v = qkv
+            o, states = gdr.gated_delta_rule_fwd(q, k, v, g, beta, tile)
+            dq, dk, dv, _, _ = gdr.gated_delta_rule_bwd(
+                q, k, v, g, beta, states, do + o, tile)
+            return dq, dk, dv
+        return jax.lax.fori_loop(0, 3, step, (q, k, v))
+
+    text = jax.jit(window).lower(q, k, v, g, beta, do).compile().as_text()
+    assert " while(" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("gdn.rule.fwd", "gdn.rule.bwd"):
+        assert name in text, name
+
+
 def test_causal_conv_kernels_compile(one_chip, real_kernels):
     """The conv in front of Qwen3-Next's delta rule as
     qwen3next-train-s8192 lowers it: [1, 8192, 8192] bf16, 4 taps, silu,

@@ -85,6 +85,74 @@ def test_kernels_are_the_recurrence(t, hk, hv, interpreted):
         assert rel(a, b) < 0.012, (name, rel(a, b))
 
 
+# (positions, key heads, value heads): groups of 1, 2 and 3 value heads a
+# key head (an odd last head alone in its pair) over 8 x 64 + 37
+# positions (nine chunks: a whole grid step and a second of one chunk and
+# seven of padding); a group of 3 under two key heads in a sequence
+# shorter than a grid step; two whole grid steps (the state and dS cross
+# a block with every chunk live)
+BLOCKS = [(549, 1, 1), (549, 1, 2), (549, 1, 3), (200, 2, 6), (1024, 1, 2)]
+
+
+@pytest.mark.parametrize("t,hk,hv", BLOCKS)
+def test_groups_and_blocks_against_both_references(t, hk, hv, interpreted,
+                                                   monkeypatch):
+    """What a grid step makes once (the pre-pass), the state loop and
+    the pass behind it, across group sizes and block boundaries: Out and
+    all five gradients against the float32 recurrence AND against the
+    chunked XLA form the kernels replace, the states against the
+    chunked form's."""
+    args = operands(t, hk, hv, seed=t + hv)
+    assert gdr.gdn_tile(t, hk, hv, 128, 128, 64, BF) == (
+        hv // hk, min(8, -(-t // 64)))
+    got, states = through_the_op(*args, chunk=64)
+    for name, a, b in zip(NAMES, got, recurrence(*args)):
+        assert a.shape == b.shape, name
+        assert rel(a, b) < 0.012, (name, rel(a, b))
+    monkeypatch.setattr(gdr, "_INTERPRET", False)      # no tile: XLA ops
+    want, want_states = through_the_op(*args, chunk=64)
+    assert states.shape == want_states.shape == (-(-t // 64), 1, hv, 128,
+                                                 128)
+    assert rel(states, want_states) < 0.01
+    for name, a, b in zip(NAMES, got, want):
+        assert rel(a, b) < 0.012, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("heads,chunks", [(1, 1), (1, 8), (2, 8), (3, 8),
+                                          (4, 3), (8, 8)])
+def test_the_vmem_count_covers_the_blocks_and_the_scratch(heads, chunks):
+    """``_vmem_bytes`` (what ``gdn_tile`` holds to the cap and the calls
+    raise Mosaic's limit by) is not under what the backward call's
+    blocks, double-buffered, and its scratch take when each is laid out
+    in (8, 128) tiles of 32 bits."""
+    def tiled(shape, dtype):
+        item = jnp.dtype(dtype).itemsize
+        shape = [d for d in shape if d is not None]
+        rows = -(-shape[-2] // (32 // item)) * (32 // item)
+        lanes = -(-shape[-1] // 128) * 128
+        return int(np.prod(shape[:-2])) * rows * lanes * item
+
+    qk, v, _, gate, st = gdr._specs(heads, chunks, 128, 128, lambda c: c)
+    blocks = sum(tiled(spec.block_shape, dt) for spec, dt in (
+        [(qk, BF)] * 4 + [(v, BF)] * 3 + [(st, BF)] + [(gate, F32)] * 4))
+    scratch = sum(tiled(x.shape, x.dtype) for x in gdr._scratch(
+        heads, chunks, 128, 128, BF, True))
+    forward = sum(tiled(x.shape, x.dtype) for x in gdr._scratch(
+        heads, chunks, 128, 128, BF, False))
+    assert forward < scratch
+    assert gdr._vmem_bytes(heads, chunks, 128, 128) >= 2 * blocks + scratch
+
+
+def test_the_cells_call_keeps_its_tile_under_the_cap():
+    """b1 t8192 hk16 hv32: a key head's two value heads and 8 chunks a
+    grid step, whatever the pre-pass keeps in VMEM; the limit the calls
+    ask Mosaic for stays inside the chip's 128 MiB."""
+    assert gdr.gdn_tile(8192, 16, 32, 128, 128, 64, BF, "tpu", False) == (2, 8)
+    assert gdr._vmem_bytes(2, 8, 128, 128) <= gdr._VMEM_CAP_BYTES
+    assert gdr._vmem_limit(2, 8, 128, 128) <= 128 * 2**20
+    assert gdr._vmem_limit(8, 8, 128, 128) <= 128 * 2**20
+
+
 @pytest.mark.parametrize("t,hk,hv", [(150, 1, 2), (600, 1, 1)])
 def test_float32_operands_show_the_same_mathematics(t, hk, hv, interpreted):
     """The kernels' algebra without bf16's rounding (the picker gives
@@ -184,7 +252,7 @@ def inverted(a, heads, chunks):
     """``_invert`` alone through the interpreter on the triangles of
     ``heads`` value heads x ``chunks`` chunks, laid out as ``_triangles``
     leaves them and read back as the chunk loops read T."""
-    scratch = gdr._scratch(heads, chunks, 128, 128)[1:]
+    scratch = gdr._scratch(heads, chunks, 128, 128, BF, False)[1:4]
     packed = np.zeros(scratch[0].shape, np.float32)
     for r in range(heads):
         for c in range(chunks):
