@@ -399,7 +399,7 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
 
 
 def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
-                     interleaved=False, layout="bhtd"):
+                     interleaved=False, layout="bhtd", scaling=None):
     """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
     may have fewer heads); position p of the sequence is p.
     ``rotary_dim``: only the first rotary_dim features of a head turn,
@@ -408,7 +408,26 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
     i + dh/2). ``layout`` "bthd": q and k come token-major
     [b, t, h, dh], as a projection leaves them (the op transposes as it
     rotates: one pass). Returns the rotated (q, k), head-major
-    [b, h, t, dh] whatever the layout."""
+    [b, h, t, dh] whatever the layout.
+
+    ``scaling``: a yarn scaling (Peng et al. 2023, as HF's
+    ``rope_type: "yarn"`` computes it), a dict of plain numbers:
+    ``factor`` and ``original_max_position_embeddings`` (required),
+    ``beta_fast`` (32), ``beta_slow`` (1), ``attention_factor`` (HF's
+    default 0.1 ln(factor) + 1). The inverse frequencies of the features
+    that turn (``rotary_dim`` of them, or the head) become f_j / factor
+    * ramp_j + f_j * (1 - ramp_j) (``parallel/rope.inv_freq``: waves
+    that make more than beta_fast rotations over the original length
+    keep their frequency, those under beta_slow are interpolated, a
+    linear ramp between), and cos and sin are multiplied by the
+    attention factor. They become attributes of the op, which stays a
+    function of its attributes. Which calls the ``rope.*`` kernels take
+    (bf16, a TPU, no mesh): the whole head in rotate-half form at a
+    width on the 128 lanes, and the first ``rotary_dim`` features of a
+    head exactly 128 wide, each with or without a scaling (they read
+    tables); a call that turns part of a wider head or pairs neighbours
+    runs as XLA's ops (``parallel/rope.rope_tile`` decides,
+    ``pt_rope_dispatch_total{impl, scaling}`` says which)."""
     helper = LayerHelper("rotary_embedding", name=name)
     q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
     k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
@@ -422,6 +441,19 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
             raise ValueError(f"rotary_embedding: layout {layout!r} is "
                              "neither 'bhtd' nor 'bthd'")
         attrs["layout"] = layout
+    if scaling:
+        import math
+
+        factor = float(scaling["factor"])
+        attrs.update(
+            yarn_factor=factor,
+            yarn_original_length=float(
+                scaling["original_max_position_embeddings"]),
+            yarn_beta_fast=float(scaling.get("beta_fast") or 32.0),
+            yarn_beta_slow=float(scaling.get("beta_slow") or 1.0),
+            yarn_attention_factor=float(
+                scaling.get("attention_factor")
+                or 0.1 * math.log(factor) + 1.0))
     helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
                      outputs={"QOut": q_out, "KOut": k_out}, attrs=attrs)
     return q_out, k_out

@@ -35,7 +35,9 @@ _M_DISPATCH = _monitor.counter(
     "bhtd) and replicated_over (mesh axes whose every rank repeats that "
     "same call). A windowed call's shape ends in w<window> and the row "
     "carries band: skip (the kernels walk the band, no block outside it "
-    "is a step), mask (the triangle walked and masked) or dense. A bwd "
+    "is a step), mask (the triangle walked and masked) or dense, and "
+    "heads, the call's query heads (a model's window layers may have a "
+    "head count of their own). A bwd "
     "row of family bhtd carries form: fused (ONE call, attn.bhtd.bwd) or "
     "split (the pair bwd_dq + bwd_dkv), flash_attention.bhtd_bwd_form's "
     "answer for the call")
@@ -84,6 +86,7 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
     if window is not None:
         labels["shape"] += f" w{window}"
         labels["band"] = "skip" if family == "bhtd" else "dense"
+        labels["heads"] = str(h)
     if form is not None:
         labels["form"] = form
     _M_DISPATCH.inc(labels=labels)
@@ -166,29 +169,32 @@ def _attn_bias(ins, attrs):
     return {"Out": [out]}
 
 
-def _rotate(x, theta, rotary_dim=None, interleaved=False):
+def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None):
     """Rotary position embedding of x [b, h, t, dh], rotate-half form
     (Su et al. 2021 as GPT-NeoX and HF lay it out): feature i pairs
     with i + dh/2, position p turns the pair by p * theta^(-2i/dh).
     ``rotary_dim`` < dh: only the FIRST rotary_dim features turn (as a
     head of that width would), the others pass. ``interleaved``: the
     pairs are the neighbours (2i, 2i + 1) instead (the paper's own
-    layout, DeepSeek's ``rope_interleave``), the angles the same."""
+    layout, DeepSeek's ``rope_interleave``), the angles the same.
+    ``scaling`` (a ``parallel/rope.Yarn``): yarn's frequencies over the
+    features that turn and its attention factor on cos and sin
+    (``parallel/rope.cos_sin``, which the kernels' tables call too)."""
+    from paddle_tpu.parallel import rope
+
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         return jnp.concatenate(
-            [_rotate(x[..., :rotary_dim], theta, None, interleaved),
+            [_rotate(x[..., :rotary_dim], theta, None, interleaved, scaling),
              x[..., rotary_dim:]], -1)
     t, dh = x.shape[-2], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = rope.cos_sin(t, dh, theta, scaling)
     if interleaved:
         pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (dh // 2, 2))
         x1, x2 = pairs[..., 0], pairs[..., 1]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
         out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
         return out.reshape(x.shape).astype(x.dtype)
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    cos = jnp.concatenate([cos, cos], -1)
+    sin = jnp.concatenate([sin, sin], -1)
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :dh // 2], xf[..., dh // 2:]
     out = xf * cos + jnp.concatenate([-x2, x1], -1) * sin
@@ -201,8 +207,9 @@ _M_ROPE = _monitor.counter(
     "lowered call (q and k together): impl kernel (rope.fwd / rope.bwd, "
     "parallel/rope.py) or xla (_rotate, behind XLA's transpose where "
     "layout is bthd), pass (fwd/bwd), layout (bthd: q and k come "
-    "token-major; bhtd: head-major) and dh; parallel/rope.rope_tile's "
-    "answer for the call")
+    "token-major; bhtd: head-major), dh and scaling (yarn: the call's "
+    "tables hold yarn's frequencies and attention factor; none: plain); "
+    "parallel/rope.rope_tile's answer for the call")
 
 
 def _rope_attrs(attrs):
@@ -212,6 +219,17 @@ def _rope_attrs(attrs):
             int(attrs.get("rotary_dim", 0)) or None,
             bool(attrs.get("interleaved", False)),
             attrs.get("layout", "bhtd") == "bthd")
+
+
+def _rope_scaling(attrs):
+    """The op's yarn scaling (``parallel/rope.Yarn``) from its five
+    plain attributes ``yarn_<field>``, which ``layers.rotary_embedding``
+    writes together; None where it has none."""
+    from paddle_tpu.parallel import rope
+
+    if not attrs.get("yarn_factor"):
+        return None
+    return rope.Yarn(*(float(attrs[f"yarn_{f}"]) for f in rope.Yarn._fields))
 
 
 def _rope_tile(q, k, attrs, direction):
@@ -232,7 +250,9 @@ def _rope_tile(q, k, attrs, direction):
         _M_ROPE.inc(labels={"impl": "kernel" if tile else "xla",
                             "pass": direction,
                             "layout": "bthd" if tokens else "bhtd",
-                            "dh": str(q.shape[-1])})
+                            "dh": str(q.shape[-1]),
+                            "scaling": "yarn" if attrs.get("yarn_factor")
+                            else "none"})
     return tile
 
 
@@ -240,11 +260,12 @@ def _rotary_xla(ins, attrs):
     """rotary_embedding as XLA's ops: ``_rotate`` on head-major Q and
     K, behind a transpose where they come token-major."""
     theta, rd, il, tokens = _rope_attrs(attrs)
+    scaling = _rope_scaling(attrs)
     q, k = _x(ins, "Q"), _x(ins, "K")
     if tokens:
         q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
-    return {"QOut": [_rotate(q, theta, rd, il)],
-            "KOut": [_rotate(k, theta, rd, il)]}
+    return {"QOut": [_rotate(q, theta, rd, il, scaling)],
+            "KOut": [_rotate(k, theta, rd, il, scaling)]}
 
 
 # (the generic grad op's rule for the XLA form: the vjp of _rotary_xla)
@@ -258,7 +279,11 @@ def _rotary_embedding(ins, attrs):
     rotary positions 0..t-1 applied (attr ``theta``, the base;
     ``rotary_dim``, 0 or absent for the whole head: the leading
     features that turn; ``interleaved``: pairs of neighbours, not
-    rotate-half). The angles and the rotation are f32; the results
+    rotate-half; ``yarn_factor``, ``yarn_original_length``,
+    ``yarn_beta_fast``, ``yarn_beta_slow``, ``yarn_attention_factor``:
+    a yarn scaling of the frequencies of the features that turn and its
+    factor on cos and sin, plain numbers, so the op stays a function of
+    its attributes). The angles and the rotation are f32; the results
     return to the inputs' dtype. ``layout`` "bthd": Q and K come
     token-major [b, t, h, dh], as a projection leaves them; the results
     are head-major [b, h, t, dh] all the same.
@@ -272,8 +297,9 @@ def _rotary_embedding(ins, attrs):
         return _rotary_xla(ins, attrs)
     from paddle_tpu.parallel import rope
 
-    theta, _, _, tokens = _rope_attrs(attrs)
-    q, k = rope.rope_fwd(q, k, theta, tile, tokens=tokens)
+    theta, rd, _, tokens = _rope_attrs(attrs)
+    q, k = rope.rope_fwd(q, k, theta, tile, tokens=tokens,
+                         scaling=_rope_scaling(attrs), rotary_dim=rd)
     return {"QOut": [q], "KOut": [k]}
 
 
@@ -291,7 +317,7 @@ def _rotary_embedding_grad(ins, attrs):
         return _ROTARY_XLA_GRAD(ins, attrs)
     from paddle_tpu.parallel import rope
 
-    theta, _, _, tokens = _rope_attrs(attrs)
+    theta, rd, _, tokens = _rope_attrs(attrs)
 
     def cotangent(g, x):   # head-major; zeros where the program gave none
         if g is not None:
@@ -300,7 +326,8 @@ def _rotary_embedding_grad(ins, attrs):
 
     dq, dk = rope.rope_bwd(cotangent(_x(ins, "GRAD::QOut"), q),
                            cotangent(_x(ins, "GRAD::KOut"), k), theta, tile,
-                           tokens=tokens)
+                           tokens=tokens, scaling=_rope_scaling(attrs),
+                           rotary_dim=rd)
     return {"GRAD::Q": [dq], "GRAD::K": [dk]}
 
 

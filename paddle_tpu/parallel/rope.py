@@ -31,11 +31,31 @@ each is fetched once a block of rows.
 (``ops/attention_ops._rotate`` behind XLA's transpose), from the call's
 own shapes, the dtype, the backend and the mesh;
 ``pt_rope_dispatch_total{impl}`` records its answer.
+
+A SCALING (``Yarn``: YaRN, Peng et al. 2023, arXiv:2309.00071, as HF's
+``_compute_yarn_parameters`` computes it) changes what the tables hold
+and nothing else: ``cos_sin`` is the one place that makes the inverse
+frequencies (plain ``theta^(-2j/dim)``, or yarn's blend of them with the
+same divided by ``factor``) and the angles, and multiplies cos and sin
+by the scaling's attention factor; ``tables`` (the kernels') and
+``_rotate`` (the XLA form) both call it. The kernels read tables, so a
+whole-head call with a scaling takes them unchanged.
+
+A call that turns PART of a head (``rotary_dim`` < dh, with or without a
+scaling) takes the kernels where a head is ONE vreg of 128 lanes wide:
+the tables hold cos 1 and sin 0 on the features that pass, and the
+partner of lane j is j + rotary_dim / 2 in the first half of the part
+and j - rotary_dim / 2 in the second (two lane rolls and a select where
+the whole head takes one roll). At another width (64 of 256) the call is
+refused by ``rope_tile`` and runs as ``_rotate``, counted under
+``pt_rope_dispatch_total{impl="xla", scaling=...}``.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +86,58 @@ _VMEM_CAP_BYTES = 40 * 2**20
 
 _F32 = jnp.float32
 
+# A yarn scaling of the rotary frequencies, plain numbers (hashable: a
+# static argument of the jitted calls): the context's growth ``factor``
+# over ``original_length`` positions, the rotations (``beta_fast``,
+# ``beta_slow``) between which the frequencies go from extrapolated to
+# interpolated, and the ``attention_factor`` on cos and sin.
+Yarn = collections.namedtuple(
+    "Yarn", "factor original_length beta_fast beta_slow attention_factor")
+
+
+def yarn_correction_range(dim, theta, scaling):
+    """(low, high): the indices j of ``theta^(-2j/dim)`` between which
+    yarn's ramp runs: the dimension whose wave makes ``beta`` rotations
+    over the original length, dim ln(L / (2 pi beta)) / (2 ln theta), at
+    beta_fast floored and at beta_slow ceiled, clipped to [0, dim - 1]
+    (HF's ``find_correction_range`` with ``truncate``)."""
+    def at(beta):
+        return (dim * math.log(scaling.original_length / (beta * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(at(scaling.beta_fast)), 0),
+            min(math.ceil(at(scaling.beta_slow)), dim - 1))
+
+
+def inv_freq(dim, theta, scaling=None):
+    """[dim / 2] float32 inverse frequencies of ``dim`` rotated
+    features: f_j = theta^(-2j/dim); under a yarn scaling f_j / factor
+    * ramp_j + f_j * (1 - ramp_j), ramp_j = clip((j - low) / (high -
+    low), 0, 1): the fast waves (j <= low) keep their frequency, the
+    slow ones (j >= high) are interpolated by ``factor``."""
+    f = theta ** (-jnp.arange(0, dim, 2, dtype=_F32) / dim)
+    if scaling is None:
+        return f
+    low, high = yarn_correction_range(dim, theta, scaling)
+    if low == high:
+        high += 0.001   # HF: no division by zero
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=_F32) - low) / (high - low),
+                    0.0, 1.0)
+    return f / scaling.factor * ramp + f * (1.0 - ramp)
+
+
+def cos_sin(t, dim, theta, scaling=None):
+    """(cos, sin) [t, dim / 2] float32 of positions 0 .. t - 1 turning
+    ``dim`` features, each times the scaling's attention factor: the one
+    place the angles are made, for the kernels' tables and ``_rotate``."""
+    freq = inv_freq(dim, theta, scaling)
+    ang = jnp.arange(t, dtype=_F32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.attention_factor != 1.0:
+        cos, sin = (cos * scaling.attention_factor,
+                    sin * scaling.attention_factor)
+    return cos, sin
+
 
 def kernels_enabled() -> bool:
     """The Pallas kernels need a TPU backend (tests reach them on CPU
@@ -92,10 +164,12 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     TPU backend (``backend``: None for this process's, with the
     interpreter counting as one), values that are not bf16, a program
     under a mesh (a Mosaic call is not auto-partitioned), a head of
-    which only a part turns (``rotary_dim``) or whose pairs are
-    neighbours (``interleaved``), a head that is not whole vregs of 128
-    lanes, a sequence no block of rows divides, or blocks over the VMEM
-    cap. ``h`` and ``hk`` (None: as many) are q's and k's heads.
+    which only a part turns (``rotary_dim``) unless it is exactly one
+    vreg of 128 lanes wide and the part is whole pairs, a head whose
+    pairs are neighbours (``interleaved``), a head that is not whole
+    vregs of 128 lanes, a sequence no block of rows divides, or blocks
+    over the VMEM cap. ``h`` and ``hk`` (None: as many) are q's and k's
+    heads.
 
     The tile follows the shape, not a flag: all of q's heads (a block of
     the token-major side is then whole rows of q, contiguous in HBM) by
@@ -104,9 +178,11 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     if on_mesh is None:
         on_mesh = _under_mesh()
     hk = h if hk is None else hk
+    part = rotary_dim or dh
+    if part != dh and (dh != _LANES or part % 2 or not 0 < part < dh):
+        return None
     if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
-            or interleaved or (rotary_dim or dh) != dh or dh % _LANES
-            or min(b, t, h, hk) < 1):
+            or interleaved or dh % _LANES or min(b, t, h, hk) < 1):
         return None
     for rows in _BLOCK_ROWS:
         if t % rows == 0 and _vmem_bytes(rows, h, hk, dh) <= _VMEM_CAP_BYTES:
@@ -114,27 +190,40 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     return None
 
 
-def tables(t, dh, theta):
+def tables(t, dh, theta, scaling=None, rotary_dim=None):
     """(cos, signed sin) [t, dh] float32 of positions 0 .. t - 1, the
-    angles as ``_rotate`` computes them: feature i and i + dh / 2 share
-    angle p * theta^(-2i / dh); sin is [-sin | +sin], the sign the
-    forward's swapped halves take."""
-    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=_F32) / dh)
-    ang = jnp.arange(t, dtype=_F32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    return (jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1))
+    angles as ``_rotate`` computes them (``cos_sin``): feature i and
+    i + dh / 2 share angle p * theta^(-2i / dh), or the scaling's; sin
+    is [-sin | +sin], the sign the forward's swapped halves take.
+    ``rotary_dim`` < dh: the same over the first rotary_dim features,
+    then cos 1 and sin 0 on the features that pass."""
+    part = rotary_dim or dh
+    cos, sin = cos_sin(t, part, theta, scaling)
+    cos, sin = (jnp.concatenate([cos, cos], -1),
+                jnp.concatenate([-sin, sin], -1))
+    if part == dh:
+        return cos, sin
+    return (jnp.concatenate([cos, jnp.ones((t, dh - part), _F32)], -1),
+            jnp.concatenate([sin, jnp.zeros((t, dh - part), _F32)], -1))
 
 
-def _swap_halves(x):
-    """[p, dh] -> the same with its two halves of lanes exchanged."""
-    half = x.shape[-1] // 2
+def _swap_halves(x, part=None):
+    """[p, dh] -> the same with its two halves of lanes exchanged; with
+    ``part`` < dh (a head one vreg wide) the two halves of the first
+    ``part`` lanes (what lands on the lanes behind them meets sin 0)."""
+    dh = x.shape[-1]
+    if part is not None:   # (``_part``: None for the whole head)
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        return jnp.where(lane < part // 2, pltpu.roll(x, dh - part // 2, 1),
+                         pltpu.roll(x, part // 2, 1))
+    half = dh // 2
     if half % _LANES:
         return pltpu.roll(x, half, 1)
     return jnp.concatenate([x[:, half:], x[:, :half]], axis=-1)
 
 
 def _kernel(q_ref, k_ref, cos_ref, sin_ref, qo_ref, ko_ref, *, rows, hb, hk,
-            dh, tokens_in, tokens_out, sign):
+            dh, tokens_in, tokens_out, sign, part=None):
     def head(tokens, n, at):
         """Rows ``at`` of head ``n`` of a block, as an index."""
         if tokens:
@@ -149,7 +238,7 @@ def _kernel(q_ref, k_ref, cos_ref, sin_ref, qo_ref, ko_ref, *, rows, hb, hk,
             sin = sin_ref[at, :] * sign
             for n in range(heads):
                 x = src[head(tokens_in, n, at)].astype(_F32)
-                y = x * cos + _swap_halves(x) * sin
+                y = x * cos + _swap_halves(x, part) * sin
                 dst[head(tokens_out, n, at)] = y.astype(dst.dtype)
             return carry
 
@@ -174,8 +263,10 @@ def _specs(rows, hb, hk, dh, tokens):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "theta", "tile", "tokens_in", "tokens_out", "sign", "name", "interpret"))
-def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret):
+    "theta", "tile", "tokens_in", "tokens_out", "sign", "name", "interpret",
+    "scaling", "rotary_dim"))
+def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret,
+          scaling=None, rotary_dim=None):
     rows, hb = tile
     if tokens_in:
         (b, t, h, dh), hk = q.shape, k.shape[2]
@@ -186,13 +277,13 @@ def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret):
         q.shape, tile)
     out_shapes = [(b, t, n * dh) if tokens_out else (b, n, t, dh)
                   for n in (h, hk)]
-    cos, sin = tables(t, dh, theta)
+    cos, sin = tables(t, dh, theta, scaling, rotary_dim)
     table = pl.BlockSpec((rows, dh), lambda bi, i, j: (i, 0))
     moved = (h + hk) * b * t * dh
     qo, ko = pl.pallas_call(
         functools.partial(_kernel, rows=rows, hb=hb, hk=hk, dh=dh,
                           tokens_in=tokens_in, tokens_out=tokens_out,
-                          sign=sign),
+                          sign=sign, part=rotary_dim),
         name=name,
         out_shape=[jax.ShapeDtypeStruct(s, x.dtype)
                    for s, x in zip(out_shapes, (q, k))],
@@ -213,22 +304,35 @@ def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret):
     return qo, ko
 
 
-def rope_fwd(q, k, theta, tile, tokens=False):
+def _part(x, rotary_dim):
+    """``rotary_dim`` as the jitted call's static argument: None for the
+    whole head, so that a whole-head call is one function however it
+    was said."""
+    return None if rotary_dim in (None, x.shape[-1]) else int(rotary_dim)
+
+
+def rope_fwd(q, k, theta, tile, tokens=False, scaling=None,
+             rotary_dim=None):
     """(q, k) with rotary positions 0 .. t - 1 applied, head-major
     [b, h, t, dh] (k may have fewer heads), at ``tile`` as ``rope_tile``
     gives it. ``tokens``: q and k come token-major, [b, t, h, dh]. One
     jitted function a (shape, layout): the layers of a model make the
-    same call, and a step traces and lowers the kernel once for all."""
+    same call, and a step traces and lowers the kernel once for all.
+    ``scaling``: a ``Yarn`` or None, what the tables hold;
+    ``rotary_dim``: the leading features that turn (None: the head)."""
     return _rope(q, k, theta=float(theta), tile=tuple(tile),
                  tokens_in=bool(tokens), tokens_out=False, sign=1.0,
-                 name="rope.fwd", interpret=bool(_INTERPRET))
+                 name="rope.fwd", interpret=bool(_INTERPRET),
+                 scaling=scaling, rotary_dim=_part(q, rotary_dim))
 
 
-def rope_bwd(dq, dk, theta, tile, tokens=False):
+def rope_bwd(dq, dk, theta, tile, tokens=False, scaling=None,
+             rotary_dim=None):
     """The cotangents of ``rope_fwd``'s q and k from those of its
     results (head-major): the rotation's transpose, which is the
     rotation by the negated angles, written token-major where the
     forward read so."""
     return _rope(dq, dk, theta=float(theta), tile=tuple(tile),
                  tokens_in=False, tokens_out=bool(tokens), sign=-1.0,
-                 name="rope.bwd", interpret=bool(_INTERPRET))
+                 name="rope.bwd", interpret=bool(_INTERPRET),
+                 scaling=scaling, rotary_dim=_part(dq, rotary_dim))

@@ -553,10 +553,15 @@ def test_pair_sum_kernel_compiles_at_the_cells_calls(cell, one_chip,
     assert f"[{k},{n},{d}]" not in text and f"f32[{n},{d}]" not in text
 
 
-# (b, t, q heads, k heads, dh) of the cells whose rotary embedding
-# rope_tile takes, and a head two vregs wide
+# (b, t, q heads, k heads, dh[, rotary_dim, yarn scaling]) of the cells
+# whose rotary embedding rope_tile takes, and a head two vregs wide.
+# Laguna's window layers turn the whole head of 64 + 8 heads plainly, its
+# full layers 64 of a head's 128 features of 48 + 8 heads under yarn
 _ROPES = {"smallthinker": (1, 16384, 28, 4, 128),
-          "olmoe": (2, 4096, 16, 16, 128), "dh256": (1, 8192, 16, 2, 256)}
+          "olmoe": (2, 4096, 16, 16, 128), "dh256": (1, 8192, 16, 2, 256),
+          "laguna_window": (1, 8192, 64, 8, 128),
+          "laguna_full": (1, 8192, 48, 8, 128, 64, rope.Yarn(
+              64.0, 4096.0, 64.0, 1.0, 1.4158883083359672))}
 
 
 @pytest.mark.parametrize("tokens", [True, False],
@@ -569,9 +574,10 @@ def test_rope_kernels_compile_at_the_cells_calls(cell, tokens, one_chip,
     both ways: the lane-blocks of a head in a [rows, h dh] block, the
     lane roll by half a head (whole vregs at dh 256) and k's blocks
     riding in the first head step pass Mosaic, and no float32 copy of q
-    is left in the program."""
-    b, t, h, hk, dh = _ROPES[cell]
-    tile = rope.rope_tile(b, t, h, dh, None, False, jnp.bfloat16, hk=hk,
+    is left in the program. A part of a head one vreg wide (PR 57): the
+    two lane rolls and the select of its partner lanes pass too."""
+    b, t, h, hk, dh, part, scaling = (*_ROPES[cell], None, None)[:7]
+    tile = rope.rope_tile(b, t, h, dh, part, False, jnp.bfloat16, hk=hk,
                           backend="tpu", on_mesh=False)
     assert tile == (256, h)
 
@@ -579,8 +585,9 @@ def test_rope_kernels_compile_at_the_cells_calls(cell, tokens, one_chip,
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def both(q, k, dq, dk):
-        return (rope.rope_fwd(q, k, 1e6, tile, tokens=tokens),
-                rope.rope_bwd(dq, dk, 1e6, tile, tokens=tokens))
+        kw = dict(tokens=tokens, scaling=scaling, rotary_dim=part)
+        return (rope.rope_fwd(q, k, 1e6, tile, **kw),
+                rope.rope_bwd(dq, dk, 1e6, tile, **kw))
 
     heads = (at(b, h, t, dh), at(b, hk, t, dh))
     ins = (at(b, t, h, dh), at(b, t, hk, dh)) if tokens else heads

@@ -148,7 +148,10 @@ TAKEN = dict(b=1, t=16384, h=28, dh=128, rotary_dim=None, interleaved=False,
     (dict(dtype=jnp.float32), None),
     (dict(on_mesh=True), None),
     (dict(backend="cpu"), None),
-    (dict(rotary_dim=64), None),                       # a part of the head
+    (dict(rotary_dim=64), (256, 28)),    # a part of a head one vreg wide
+    (dict(t=8192, h=48, hk=8, rotary_dim=64), (256, 48)),   # laguna, full
+    (dict(rotary_dim=63), None),                       # not whole pairs
+    (dict(rotary_dim=64, interleaved=True), None),
     (dict(dh=256, rotary_dim=64), None),               # qwen3next
     (dict(dh=64, interleaved=True, hk=1), None),       # joyai
     (dict(interleaved=True), None),
