@@ -1,26 +1,42 @@
-"""Time the BHTD attention backward's candidates alone on the chip, at the
-calls of the four decoder cells (bf16, causal; smallthinker's both with
-its window of 4096 and without).
+"""Time the forms an EDGE block of the BHTD attention kernels can take,
+alone on the chip, at the eight decoder cells' calls (bf16, causal; the
+windowed calls with their band).
 
-    chiprun -- python benchmarks/attn_bwd_candidates.py [--calls olmoe ...]
+    chiprun -- python benchmarks/attn_bwd_candidates.py --parent .parent \
+        [--calls laguna_w512 ...] [--forms whole sub256 ...]
 
-``flash_attention_bwd`` (the delta reduction in front included) as the
-split pair (``attn.bhtd.bwd_dq`` + ``attn.bhtd.bwd_dkv``: 7 matmuls and
-two walks of the grid a live block) and as ONE call (5 matmuls, one
-walk) on each of the two walks that keep nothing gradient-sized in HBM:
-q inner (``attn.bhtd.bwd`` as the program lowers it: dq resident in
-VMEM for a query head, dk and dv in a block's scratch, or resident too
-where a group shares them) and k inner (the candidate it was weighed
-against, ``bwd_k_inner`` below: dq in a block's scratch, dk and dv
-resident for a key/value head). Each fused form is held to the pair's
-gradients first; then ms a call, the median of five stretches of 10
+A block that the diagonal or a band's far edge crosses holds dead
+sub-tiles (all in the future, or all forgotten). The forms:
+
+- ``whole``: the parent checkout's module (``--parent``: a ``git
+  archive`` of the parent commit): every edge block computed whole and
+  masked, and the forward under plain ``causal`` masking every live
+  block;
+- ``nomask``: this tree with no sub-tiles (``_edge_tile`` answering
+  None): edge blocks whole, the forward's interior blocks unmasked: what
+  the interior masks alone cost (the backward is the parent's);
+- ``sub256`` / ``sub128`` / ``sub<sq>x<sk>``: the ONE backward call
+  walks an edge block in sub-tiles of that shape, each dead, plain or
+  masked by the block's own predicates (``_when_live``); the forward
+  works on an edge block whole, so it is ``nomask``'s and is not timed
+  again ("=");
+- ``grid256``: no sub-tiles, the whole grid at blocks of 256 (four
+  times the steps);
+- ``program``: the tree as it stands (``_EDGE_SUB`` untouched).
+
+Each form's (out, lse) and (dq, dk, dv) are held to ``whole``'s first;
+then ms a call forward and backward, the median of five stretches of 10
 calls dispatched back to back (host clock around one
-``block_until_ready``), and us a live (q-block, k-block) pair. The table
-goes to chiprun_out/attn_bwd_candidates.json. How ``attn.bhtd.bwd``'s
-walk was chosen (PERF.md section 6, PR 39). Needs a TPU.
+``block_until_ready``), and the score pairs a head's steps compute
+against those the mask lets through (``bhtd_pairs``). A call timed alone
+reads up to 1.7x its ms inside a cell's step: rank forms by this table,
+price them by the cell. The table goes to
+chiprun_out/attn_bwd_candidates.json and .md. Needs a TPU. (How the fused
+backward's walk was chosen, PR 39: PERF.md section 6.)
 """
 
 import argparse
+import importlib.util
 import json
 import os
 import statistics
@@ -29,120 +45,58 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-OUT = os.path.join(ROOT, "chiprun_out", "attn_bwd_candidates.json")
+OUT = os.path.join(ROOT, "chiprun_out", "attn_bwd_candidates")
 # call: (b, heads, key/value heads, t, dh, dv, window)
 CALLS = {
+    "laguna_w512": (1, 64, 8, 8192, 128, 128, 512),
+    "laguna_global": (1, 48, 8, 8192, 128, 128, None),
     "smallthinker_w4096": (1, 28, 4, 16384, 128, 128, 4096),
     "smallthinker_global": (1, 28, 4, 16384, 128, 128, None),
     "joyai": (1, 32, 32, 4096, 192, 128, None),
     "qwen3next": (1, 16, 2, 8192, 256, 256, None),
     "olmoe": (2, 16, 16, 4096, 128, 128, None),
+    "phi4flash_w512": (1, 20, 10, 4096, 64, 128, 512),
+    "phi4flash_global": (1, 20, 10, 4096, 64, 128, None),
+    "nemotron3nano": (1, 32, 2, 4096, 128, 128, None),
+    "lfm2moe": (1, 32, 8, 8192, 64, 64, None),
 }
+FORMS = ("whole", "nomask", "sub256", "sub128", "grid256")
 
 
-def live_blocks(t, bq, bk, window):
-    """(q-block, k-block) pairs of one head that hold a visible pair."""
-    return sum(
-        1 for j in range(t // bq) for kk in range(t // bk)
-        if kk * bk <= (j + 1) * bq - 1
-        and (window is None or j * bq <= (kk + 1) * bk + window - 2))
+def load_parent(path):
+    """The parent checkout's flash_attention module, beside this tree's."""
+    spec = importlib.util.spec_from_file_location(
+        "parent_flash_attention",
+        os.path.join(path, "paddle_tpu", "parallel", "flash_attention.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def bwd_k_inner(q, k, v, out, lse, g, causal=True, window=None):
-    """The fused backward on the dq kernel's walk: grid (batch row,
-    key/value head, member of its group, q-block, step over the row's
-    k-blocks); dq gathers in a block's scratch, dk and dv [tk, .] stay
-    resident for the key/value head and its group. The block's
-    arithmetic is the program's (``_bwd_block``)."""
-    import functools
+def form_setup(form, fa, parent):
+    """-> (module, its _edge_tile while the form is traced, blocks)."""
+    if form == "whole":
+        return parent, None, None
+    if form == "program":
+        return fa, fa._edge_tile, None
+    if form == "nomask":
+        return fa, lambda bq, bk: None, None
+    if form == "grid256":
+        return fa, lambda bq, bk: None, 256
+    sides = [int(x) for x in form[3:].split("x")]
+    sq, sk = sides if len(sides) == 2 else sides * 2
 
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from paddle_tpu.parallel import flash_attention as fa
-
-    b, h, tq, dh = q.shape
-    tk, dv = k.shape[2], v.shape[3]
-    group = h // k.shape[1]
-    window = fa._band(window, causal, tq, tk)
-    tile = fa.bhtd_tile(h, tq, tk, dh=dh, group=group, dv=dv)
-    _, bq, bk = tile
-    nq, nk = tq // bq, tk // bk
-    steps = fa._k_steps(window, nq, nk, bq, bk)
-    scale = dh ** -0.5
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-
-    def kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-               delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
-        m, j, r = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-        kk = r if window is None else fa._first_k(j, bq, bk, window) + r
-        first = (m == 0) & (j == 0) & (r == 0)
-        last = (m == group - 1) & (j == nq - 1) & (r == steps - 1)
-
-        def zero(acc, at):
-            acc[at, :] = jnp.zeros((acc[at, :].shape), acc.dtype)
-
-        def write(acc, ref, at):
-            ref[0, 0, at, :] = acc[at, :].astype(ref.dtype)
-
-        pl.when(r == 0)(lambda: zero(dq_acc, slice(None)))
-        for acc in (dk_acc, dv_acc):
-            pl.when(first)(functools.partial(
-                fa._each_block, acc, bk, functools.partial(zero, acc)))
-
-        def compute(masked=False):
-            mask = None
-            if masked:
-                mask = lambda s_t: fa._causal_mask(
-                    s_t[None], j, kk, bq, bk, transposed=True,
-                    window=window)[0]
-            dq, dk, dv_ = fa._bwd_block(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-                lse_ref[0, 0], delta_ref[0, 0], None, scale, mask)
-            dq_acc[:] += dq
-            at = fa._block_rows(dk_acc, kk, bk)
-            dk_acc[at, :] += dk
-            dv_acc[at, :] += dv_
-
-        fa._when_live(compute, fa._causal_live(j, kk, bq, bk),
-                      fa._on_edge(j, kk, bq, bk, window))
-        pl.when(r == steps - 1)(lambda: write(dq_acc, dq_ref, slice(None)))
-        for acc, ref in ((dk_acc, dk_ref), (dv_acc, dv_ref)):
-            pl.when(last)(functools.partial(
-                fa._each_block, acc, bk, functools.partial(write, acc, ref)))
-
-    block_of = fa._step_blocks(causal, True, bq, bk, nq, 1, window, steps)
-
-    def at(i, hk, m, j, r, *_):
-        return block_of(i, hk * group + m, j, r)
-
-    kernel, specs, args, rows = fa._call_parts(kernel, at, tile, q, k, v,
-                                               None)
-    whole = [pl.BlockSpec((1, 1, tk, d), lambda i, hk, *_: (i, hk, 0, 0))
-             for d in (dh, dv)]
-    operands = (fa._seed_arr(None), *args, g, lse.reshape(b, h, 1, tq),
-                delta.reshape(b, h, 1, tq))
-    resident = 4 * tk * (dh + dv)       # and the outputs, double-buffered
-    return pl.pallas_call(
-        kernel, name="attn.bhtd.bwd_k_inner",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, h // group, group, nq, steps),
-            in_specs=specs + [rows.o, rows.row, rows.row],
-            out_specs=[rows.q, *whole],
-            scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32),
-                            pltpu.VMEM((tk, dh), jnp.float32),
-                            pltpu.VMEM((tk, dv), jnp.float32)]),
-        out_shape=[fa._result(operands, x.shape, x.dtype) for x in (q, k, v)],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=2 * resident + 24 * 2**20),
-    )(*operands)
+    def edge(bq, bk):
+        fits = bq % sq == 0 and bk % sk == 0 and (sq, sk) != (bq, bk)
+        return (sq, sk) if fits else None
+    return fa, edge, None
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--calls", nargs="*", default=list(CALLS))
+    ap.add_argument("--forms", nargs="*", default=list(FORMS))
+    ap.add_argument("--parent", default=os.path.join(ROOT, ".parent"))
     args = ap.parse_args()
 
     import jax
@@ -153,6 +107,7 @@ def main():
         print("attn_bwd_candidates: no TPU", file=sys.stderr)
         return 2
     from paddle_tpu.parallel import flash_attention as fa
+    parent = load_parent(args.parent)
 
     def ms(f, *a):
         jax.block_until_ready(f(*a))
@@ -179,49 +134,61 @@ def main():
 
         q, k = rand(b, h, t, dh, s=0.5), rand(b, hk, t, dh, s=0.5)
         v, g = rand(b, hk, t, dv), rand(b, h, t, dv)
-        kw = dict(causal=True, window=window)
-        out, lse = jax.jit(
-            lambda q, k, v: fa.flash_attention_fwd(q, k, v, **kw))(q, k, v)
-        tile = fa.bhtd_tile(h, t, t, dh=dh, group=h // hk, dv=dv)
-        blocks = b * h * live_blocks(t, tile[1], tile[2], window)
-
-        def bwd(form):
-            def f(q, k, v, out, lse, g):
-                if form == "k_inner":
-                    return bwd_k_inner(q, k, v, out, lse, g, **kw)
-                return fa.flash_attention_bwd(q, k, v, None, None, out, lse,
-                                              g, **kw)
-            # (bhtd_bwd_form reads the cap while the call is traced: no
-            # room for a resident row is the pair)
-            cap = fa._BWD_VMEM_CAP_BYTES
-            fa._BWD_VMEM_CAP_BYTES = 0 if form == "split" else cap
-            try:
-                return jax.jit(f).lower(q, k, v, out, lse, g).compile()
-            finally:
-                fa._BWD_VMEM_CAP_BYTES = cap
-
-        row = {"call": name, "shape": [b, h, hk, t, dh, dv, window],
-               "tile": list(tile), "live_blocks": blocks}
+        row = {"call": name, "shape": [b, h, hk, t, dh, dv, window]}
         want = None
-        for form in ("split", "q_inner", "k_inner"):
+        for form in args.forms:
+            mod, edge, block = form_setup(form, fa, parent)
+            kw = dict(causal=True, window=window, q_block=block,
+                      k_block=block)
+            held = getattr(mod, "_edge_tile", None)
+            if edge is not None:
+                mod._edge_tile = edge
             try:
-                f = bwd(form)
-                got = f(q, k, v, out, lse, g)
+                tile = mod.bhtd_tile(h, t, t, block, block, dh=dh,
+                                     group=h // hk, dv=dv)
+                cell = {"tile": mod.tile_label(tile)}
+                if hasattr(mod, "bhtd_pairs"):
+                    computed, live = mod.bhtd_pairs(t, t, tile, True, window)
+                    cell.update(edge=mod.edge_label(mod.bhtd_edge_tile(
+                        tile, True)), pairs_computed=computed,
+                        pairs_live=live)
+                fwd = jax.jit(lambda q, k, v: mod.flash_attention_fwd(
+                    q, k, v, **kw)).lower(q, k, v).compile()
+                out, lse = fwd(q, k, v)
+                bwd = jax.jit(
+                    lambda q, k, v, out, lse, g: mod.flash_attention_bwd(
+                        q, k, v, None, None, out, lse, g, **kw)
+                ).lower(q, k, v, out, lse, g).compile()
+                got = (out, lse, *bwd(q, k, v, out, lse, g))
                 if want is None:
                     want = got
-                row[form] = {
-                    "ms": ms(f, q, k, v, out, lse, g),
-                    "worst_vs_split": [round(worst(a, w), 5)
-                                       for a, w in zip(got, want)]}
-                row[form]["us_a_live_block"] = round(
-                    row[form]["ms"] * 1e3 / blocks, 4)
-            except Exception as e:  # a candidate Mosaic refuses is a row
-                row[form] = {"error": str(e)[:400]}
-            print(name, form, row[form], flush=True)
+                cell["worst_vs_first"] = [round(worst(a, w), 5)
+                                          for a, w in zip(got, want)]
+                # (sub-tiles are the backward's: the forward is nomask's)
+                cell["fwd_ms"] = "=" if form.startswith(
+                    ("sub", "program")) else ms(fwd, q, k, v)
+                cell["bwd_ms"] = ms(bwd, q, k, v, out, lse, g)
+            except Exception as e:  # a form Mosaic refuses is a row
+                cell = {"error": str(e)[:400]}
+            finally:
+                if edge is not None:
+                    mod._edge_tile = held
+            row[form] = cell
+            print(name, form, cell, flush=True)
         table.append(row)
+
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
-    with open(OUT, "w") as f:
+    with open(OUT + ".json", "w") as f:
         json.dump(table, f, indent=1)
+    with open(OUT + ".md", "w") as f:
+        f.write("| call | " + " | ".join(
+            f"{x} fwd / bwd ms" for x in args.forms) + " |\n")
+        f.write("| --- |" + " --- |" * len(args.forms) + "\n")
+        for row in table:
+            cells = [
+                "%s / %s" % (row[x].get("fwd_ms", "-"),
+                             row[x].get("bwd_ms", "-")) for x in args.forms]
+            f.write(f"| {row['call']} | " + " | ".join(cells) + " |\n")
     return 0
 
 

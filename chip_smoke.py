@@ -117,10 +117,36 @@ def attention_dispatch():
     which implementation every attention call lowered so far took, and
     the tile of a family that picks one by the shape: "bhtd fwd <shape>
     [hb1 bq512 bk512]", a backward row of that family also whether it is
-    one call or the pair: "... form=fused" (pt_attention_dispatch_total)."""
+    one call or the pair and the sub-tiles it walks its edge blocks in:
+    "... form=fused edge=256x256" (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
-    return attention_ops.dispatch_counts(tiles=True, forms=True)
+    return attention_ops.dispatch_counts(tiles=True, forms=True, edges=True)
+
+
+# (t, window) of the decoder cells' BHTD calls, all on hb1 bq512 bk512
+ATTN_GEOMETRIES = {
+    "laguna w512": (8192, 512), "smallthinker w4096": (16384, 4096),
+    "t4096": (4096, None), "t8192": (8192, None), "t16384": (16384, None)}
+
+
+def attention_pairs(tile=(1, 512, 512)):
+    """{call: the sub-tiles its backward walks its edge blocks in, the
+    score pairs a head's steps compute there (``computed_fwd``: in the
+    forward, on whole blocks) and those the mask lets through}
+    (flash_attention.bhtd_pairs: the call's geometry alone)."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    out = {}
+    for name, (t, window) in ATTN_GEOMETRIES.items():
+        computed, live = fa.bhtd_pairs(t, t, tile, True, window)
+        out[name] = {
+            "edge": fa.edge_label(fa.bhtd_edge_tile(tile, True)),
+            "computed": computed,
+            "computed_fwd": fa.bhtd_pairs(t, t, tile, True, window,
+                                          form=None)[0],
+            "live": live, "live_share": round(live / computed, 4)}
+    return out
 
 
 def _one_backward_call(attn):
@@ -128,7 +154,7 @@ def _one_backward_call(attn):
     ``attn.bhtd.bwd`` (flash_attention.bhtd_bwd_form: the cells' rows
     fit the kernel's VMEM cap), none the pair bwd_dq + bwd_dkv."""
     rows = [k for k in attn if k.startswith("bhtd bwd ")]
-    check(rows and all(k.endswith(" form=fused") for k in rows),
+    check(rows and all(" form=fused" in k for k in rows),
           f"expected every bhtd backward call as one fused kernel "
           f"(form=fused), none split: {attn}")
 
@@ -1998,6 +2024,9 @@ def main() -> int:
         say(f"[{name}] passed in {report['phase_s'][name]} s (observation)")
         return out, ir.take()
 
+    report["attention_pairs"] = attention_pairs()
+    say(f"  score pairs a head computed / visible at the cells' BHTD "
+        f"calls: {report['attention_pairs']}")
     # 1. kernels: every case really is a Pallas call
     report["kernels"], _ = phase("kernels", kernel_phase)
     # did this machine compile them or read them? jax's own account

@@ -40,7 +40,11 @@ _M_DISPATCH = _monitor.counter(
     "head count of their own). A bwd "
     "row of family bhtd carries form: fused (ONE call, attn.bhtd.bwd) or "
     "split (the pair bwd_dq + bwd_dkv), flash_attention.bhtd_bwd_form's "
-    "answer for the call")
+    "answer for the call. A row of family bhtd carries edge: the "
+    "sub-tiles in which the call walks the blocks its diagonal or its "
+    "band's far edge crosses, \"256x256\" on a fused bwd row of blocks "
+    "of 512 (flash_attention.bhtd_edge_tile), \"\" where it works on "
+    "them whole (every fwd row)")
 
 
 def _windowed(attrs, q, k, bthd, ring):
@@ -60,21 +64,22 @@ def _windowed(attrs, q, k, bthd, ring):
 
 
 def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
-                   form=None):
+                   form=None, causal=False):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
     b, tq, tk, h, dh = dims[:5]
     # (behind them, where the call is not plain: key/value heads, dv)
     hk, dv = dims[5:] if len(dims) > 5 else (h, dh)
-    tile = ""
+    tile, edge = "", None
     if family == "bhtd":
-        # the kernel layer's own answer for the call the op hands it (the
-        # op passes no q_block / k_block)
+        # the kernel layer's own answers for the call the op hands it
+        # (the op passes no q_block / k_block)
         from paddle_tpu.parallel import flash_attention as fa
 
-        tile = fa.tile_label(fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk,
-                                          dv=dv))
+        picked = fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk, dv=dv)
+        tile = fa.tile_label(picked)
+        edge = fa.edge_label(fa.bhtd_edge_tile(picked, causal, form))
     # (grouped-query attention names its key/value heads: "h16 kv2";
     # values narrower than queries and keys both widths: "dk192 dv128")
     heads = f"h{h}" if hk == h else f"h{h} kv{hk}"
@@ -89,16 +94,19 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         labels["heads"] = str(h)
     if form is not None:
         labels["form"] = form
+    if edge is not None:
+        labels["edge"] = edge
     _M_DISPATCH.inc(labels=labels)
 
 
-def dispatch_counts(tiles=False, forms=False):
+def dispatch_counts(tiles=False, forms=False, edges=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
     run print it. ``tiles``: a row whose family tiles by the shape names
     its tile too, "bhtd fwd <shape> [hb1 bq512 bk512]". ``forms``: a
     backward row of that family says whether it is one call or the
-    pair, "bhtd bwd <shape> form=fused"."""
+    pair, "bhtd bwd <shape> form=fused". ``edges``: a row whose edge
+    blocks are walked in sub-tiles names them, "... edge=256x256"."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
@@ -109,6 +117,8 @@ def dispatch_counts(tiles=False, forms=False):
             name += f" [{lb['tile']}]"
         if forms and lb.get("form"):
             name += f" form={lb['form']}"
+        if edges and lb.get("edge"):
+            name += f" edge={lb['edge']}"
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 
@@ -383,7 +393,7 @@ def _sdpa_config(ins, attrs, rng):
 
 
 def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
-             form=None):
+             form=None, causal=False):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -396,7 +406,8 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     the batch."""
     split = interp.mesh_batch_split()
     if split is None:
-        _note_dispatch(family, direction, dims, window=window, form=form)
+        _note_dispatch(family, direction, dims, window=window, form=form,
+                       causal=causal)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -411,7 +422,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     _note_dispatch(
         family, direction, (b // n,) + tuple(dims[1:]),
         sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window,
-        form)
+        form, causal)
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -617,5 +628,5 @@ def _sdpa_grad(ins, attrs, rng=None):
                 q, k, v, bias, seed, out, lse, g, scale=scale,
                 p_drop=drop, causal=causal),
             (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
-            "bwd", dims, window, form)
+            "bwd", dims, window, form, causal=causal)
     return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

@@ -44,6 +44,28 @@ ridge of 240.
   dead steps (the first rows' bands are shorter) repeat the row's last
   live block, and the two-sided mask runs only on the blocks the
   diagonal or the band's far edge crosses.
+- An EDGE block is one that the diagonal or a band's far edge crosses;
+  every other live block is plain: all visible, no mask, in every
+  kernel and under plain ``causal`` too (``_when_live``). The ONE
+  backward call walks an edge block in sub-tiles (``_edge_tile``: 256 on
+  a side where the block is 512 x 512, from the block's shape alone;
+  none where the block is too small or not square): each sub-tile dead,
+  plain or edge by the block's own predicates at its own corners
+  (``_band_live``, ``_on_edge``), the dead ones dropped and the live
+  ones of a query sub-tile gathered into one slab of the block as it
+  lies in VMEM (``_edge_slabs``). What the mask does to a block goes by
+  how far its first query is behind its first key, and a call's edge
+  blocks have two or three such kinds (the diagonal's, the far edge's),
+  so the classification is made when the call is traced and a kind's
+  slabs are ONE straight line of code: no grid step, DMA, operand or
+  scratch is added, only the order of the float32 sums inside an edge
+  block changes. The forward and the split pair work on an edge block
+  whole (the forward's time does not go by its pairs: PERF.md section 6,
+  PR 58). ``bhtd_edge_tile`` says which sub-tile a backward call takes
+  (the dispatch counter's ``edge`` label), ``bhtd_pairs(tq, tk, tile,
+  causal, window) -> (computed, live)`` the score pairs a head's steps
+  compute there and those the mask lets through (laguna's band of one
+  block: 6.09M for 4.06M; 8.13M on whole blocks, ``form=None``).
 - Attention dropout runs inside the kernels via the TPU PRNG: the mask for
   score block (b, jq, jk) is regenerated from a hash of (seed, b, jq, jk)
   in every kernel (and of the head group, where hb < h), so forward and
@@ -199,6 +221,41 @@ def tile_label(tile) -> str:
     return "hb%d bq%d bk%d" % tile if tile else ""
 
 
+# A side of the sub-tiles an edge block is walked in (_when_live).
+_EDGE_SUB = 256
+
+
+def _edge_tile(bq, bk):
+    """-> (sq, sk), the sub-tiles a (bq, bk) block that the diagonal or a
+    band's far edge crosses is walked in, from the block's shape alone,
+    or None where the block is worked on whole: ``_EDGE_SUB`` on a side
+    where the block is square and that cuts its side in whole parts
+    (whole lane tiles of the score block and of the [1, bq] statistics'
+    rows). Square, because each kind of edge block (_edge_slabs' d) is a
+    branch of the kernel's body and a call of square blocks has at most
+    three: the diagonal's and the far edge's one or two."""
+    if bq != bk or bq <= _EDGE_SUB or bq % _EDGE_SUB:
+        return None
+    return _EDGE_SUB, _EDGE_SUB
+
+
+def bhtd_edge_tile(tile, causal, form="fused"):
+    """-> (sq, sk) or None: the sub-tiles in which the backward call of
+    this tile walks its edge blocks. The one place that decides:
+    ``_fused_bwd``, ``bhtd_pairs`` and the dispatch counter's ``edge``
+    label read it. No edge without ``causal``; the forward (``form``
+    None) and the split pair work on an edge block whole."""
+    if tile is None or not causal or form != "fused":
+        return None
+    return _edge_tile(tile[1], tile[2])
+
+
+def edge_label(sub) -> str:
+    """A sub-tile as the dispatch counter's ``edge`` label has it:
+    "256x256" ("" for None)."""
+    return "%dx%d" % sub if sub else ""
+
+
 def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
                 group=1, dv=None) -> str:
     """"bhtd" (the K-blocked [b, h, t, dh] kernels) when the picked
@@ -275,16 +332,20 @@ def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
 # ---------------------------------------------------------------------------
 
 
-def _causal_mask(s, j, kk, bq, bk, transposed=False, window=None):
+def _causal_mask(s, j, kk, bq, bk, transposed=False, window=None,
+                 at=(0, 0)):
     """Mask future positions inside score block (hb, bq, bk) for q-block
     j / k-block kk (``transposed``: block is (hb, bk, bq)); with a
-    ``window`` also the positions it has forgotten (p - s >= window)."""
+    ``window`` also the positions it has forgotten (p - s >= window).
+    ``at``: where ``s`` starts inside the block, (query row, key row),
+    where it is a slab of it."""
+    q0, k0 = j * bq + at[0], kk * bk + at[1]
     if transposed:
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + kk * bk
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + j * bq
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k0
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + q0
     else:
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * bq
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + kk * bk
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + q0
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + k0
     seen = q_pos >= k_pos
     if window is not None:
         seen = jnp.logical_and(seen, q_pos - k_pos < window)
@@ -345,20 +406,121 @@ def _band_steps(n_rows, first, last):
 def _on_edge(j, kk, bq, bk, window):
     """Does the diagonal or the band's far edge cross block (j, kk):
     is some pair of it in the future, or forgotten? Every other live
-    block is all visible and takes no mask."""
+    block is all visible and takes no mask. (Grid indices in a kernel,
+    Python ints where a call's geometry is reckoned.)"""
     diagonal = (kk + 1) * bk - 1 > j * bq
     if window is None:
         return diagonal
-    return jnp.logical_or(diagonal, (j + 1) * bq - 1 - kk * bk >= window)
+    return diagonal | ((j + 1) * bq - 1 - kk * bk >= window)
 
 
-def _when_live(compute, live, edge):
-    """Run a windowed step: masked on an edge block, plain inside the
-    band, not at all where dead."""
-    pl.when(jnp.logical_and(live, edge))(
-        functools.partial(compute, masked=True))
+def _band_live(j, kk, bq, bk, window):
+    """Does block (j, kk) hold ANY visible pair: is it neither all in the
+    future nor, with a ``window``, all forgotten?"""
+    live = _causal_live(j, kk, bq, bk)
+    if window is None:
+        return live
+    return live & (j <= _last_q(kk, bq, bk, window))
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_slabs(tq, tk, bq, bk, sub, window):
+    """How a call's edge blocks are walked in sub-tiles of ``sub`` = (sq,
+    sk): -> {d: [((q0, rows, k0, cols), masked), ..]}. Whether a pair is
+    visible goes by p - s alone, so what the mask does to block (j, kk)
+    goes by d = j * bq - kk * bk alone, how far the block's first query
+    is behind its first key, and a call's edge blocks have a few d (the
+    diagonal's 0 and the far edge's one or two, where bq == bk). For each
+    the block's sub-tiles are classified by the block's own predicates
+    at their own corners (``_band_live``, ``_on_edge`` in units of the
+    sub-tile): the dead ones dropped, the live ones of a query sub-tile
+    gathered into ONE slab, rows q0 .. q0 + rows by key rows k0 .. k0 +
+    cols of the block (a row's live sub-tiles are contiguous: the band
+    is convex; a slab's keys are the rows the backward's transposed
+    matmuls stream), ``masked`` where one of them is an edge. The slabs
+    of a block are straight-line code, in rising order. (Reckoned once a
+    geometry: ``_fused_bwd`` and ``bhtd_pairs`` both ask; nobody writes
+    into the answer.)"""
+    (sq, sk), slabs = sub, {}
+    na, nc = bq // sq, bk // sk
+    for j in range(tq // bq):
+        for kk in range(tk // bk):
+            if j * bq - kk * bk in slabs or not (
+                    _band_live(j, kk, bq, bk, window)
+                    and _on_edge(j, kk, bq, bk, window)):
+                continue
+            parts = []
+            for a in range(na):
+                # (key sub-tile, is it an edge) of query sub-tile a's
+                # live ones
+                live = [(c, _on_edge(j * na + a, kk * nc + c, sq, sk, window))
+                        for c in range(nc)
+                        if _band_live(j * na + a, kk * nc + c, sq, sk,
+                                      window)]
+                if live:
+                    first, n = live[0][0], len(live)
+                    assert [c for c, _ in live] == list(range(first,
+                                                              first + n))
+                    parts.append(((a * sq, sq, first * sk, n * sk),
+                                  any(edge for _, edge in live)))
+            slabs[j * bq - kk * bk] = parts
+    return slabs
+
+
+def _when_live(compute, live, j, kk, bq, bk, window, slabs=None):
+    """Run a causal step on block (j, kk), ``live`` or dead: not at all
+    where dead, ``compute(masked=False)`` on a plain block (all of it
+    visible), ``compute(masked=True)`` on an edge block (the diagonal or
+    the band's far edge crosses it). With ``slabs`` (_edge_slabs) an
+    edge block is walked in the slabs of its live sub-tiles instead, in
+    VMEM as the block lies there: ``compute(masked, at=(q0, rows, k0,
+    cols))``, one straight line a block; its dead sub-tiles cost
+    nothing."""
+    edge = _on_edge(j, kk, bq, bk, window)
     pl.when(jnp.logical_and(live, jnp.logical_not(edge)))(
         functools.partial(compute, masked=False))
+    if not slabs:
+        pl.when(jnp.logical_and(live, edge))(
+            functools.partial(compute, masked=True))
+        return
+    for d, parts in slabs.items():
+        def _walk(parts=parts):
+            for at, masked in parts:
+                compute(masked=masked, at=at)
+        pl.when(jnp.logical_and(live, j * bq - kk * bk == d))(_walk)
+
+
+def _slab(at, bq, bk):
+    """-> (rows of the q block, rows of the k block, where they start):
+    all of both for ``at`` None."""
+    q0, rows, k0, cols = at or (0, bq, 0, bk)
+    return slice(q0, q0 + rows), slice(k0, k0 + cols), (q0, k0)
+
+
+def bhtd_pairs(tq, tk, tile, causal, window=None, form="fused"):
+    """-> (computed, live): the score pairs (query position, key
+    position) that the steps of ONE head compute in the backward call of
+    this tile (``form`` None: in the forward, or in the split pair, whose
+    edge blocks are whole), and those of them the mask lets through. A
+    pure function of the call's geometry, by the kernels' own
+    predicates: a dead block or sub-tile is not computed, a plain or an
+    edge one is computed whole."""
+    _, bq, bk = tile
+    if not causal:
+        return tq * tk, tq * tk
+    window = _band(window, causal, tq, tk)
+    live = sum(max(min(p + 1, tk) - max(p - (window or tq) + 1, 0), 0)
+               for p in range(tq))
+    sub = bhtd_edge_tile(tile, causal, form)
+    slabs = _edge_slabs(tq, tk, bq, bk, sub, window) if sub else {}
+    walked = {d: sum(rows * cols for (_, rows, _, cols), _ in parts)
+              for d, parts in slabs.items()}
+    computed = sum(
+        walked.get(j * bq - kk * bk, bq * bk)
+        if _on_edge(j, kk, bq, bk, window) else bq * bk
+        for j in range(tq // bq) for kk in range(tk // bk)
+        if _band_live(j, kk, bq, bk, window))
+    return computed, live
 
 
 def _seed_step(seed_ref, ng, j, kk):
@@ -395,7 +557,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute(masked=causal):
+    def _compute(masked=False):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -432,11 +594,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    if window is not None:
-        _when_live(_compute, _causal_live(j, kk, bq, bk),
-                   _on_edge(j, kk, bq, bk, window))
-    elif causal:
-        pl.when(_causal_live(j, kk, bq, bk))(_compute)
+    if causal:
+        # (a band's steps start at the q-row's first live k-block)
+        _when_live(_compute, _causal_live(j, kk, bq, bk), j, kk, bq, bk,
+                   window)
     else:
         _compute()
 
@@ -458,7 +619,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute(masked=causal):
+    def _compute(masked=False):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -489,11 +650,9 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if window is not None:
-        _when_live(_compute, _causal_live(j, kk, bq, bk),
-                   _on_edge(j, kk, bq, bk, window))
-    elif causal:
-        pl.when(_causal_live(j, kk, bq, bk))(_compute)
+    if causal:
+        _when_live(_compute, _causal_live(j, kk, bq, bk), j, kk, bq, bk,
+                   window)
     else:
         _compute()
 
@@ -523,7 +682,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute(masked=causal):
+    def _compute(masked=False):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -578,13 +737,11 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if window is not None:
-        _when_live(
-            _compute,
-            jq <= jnp.minimum(_last_q(kk, bq, bk, window), last_q),
-            _on_edge(jq, kk, bq, bk, window))
-    elif causal:
-        pl.when(_causal_live(jq, kk, bq, bk))(_compute)
+    if causal:
+        # (a band's steps start at the k-row's first live q-block)
+        live = (_causal_live(jq, kk, bq, bk) if window is None else
+                jq <= jnp.minimum(_last_q(kk, bq, bk, window), last_q))
+        _when_live(_compute, live, jq, kk, bq, bk, window)
     else:
         _compute()
 
@@ -620,12 +777,15 @@ def _bwd_block(q, k, v, do, lse, delta, bias, scale, mask):
     return dq, dk, dv
 
 
-def _block_rows(acc, idx, rows):
-    """Block ``idx`` of an accumulator's rows: all of one that holds a
-    single block, else that block of a resident one."""
+def _block_rows(acc, idx, rows, inside=slice(None)):
+    """Block ``idx`` of an accumulator's rows: of the one block it holds,
+    or of a resident one. ``inside``: a slab's rows of that block alone
+    (a static slice)."""
     if acc.shape[0] == rows:
-        return slice(None)
-    return pl.ds(pl.multiple_of(idx * rows, rows), rows)
+        return inside
+    first, n = inside.start or 0, (inside.stop or rows) - (inside.start or 0)
+    return pl.ds(pl.multiple_of(idx * rows + first, math.gcd(rows, first)),
+                 n)
 
 
 def _each_block(acc, rows, body):
@@ -640,7 +800,7 @@ def _each_block(acc, rows, body):
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
                 *, scale, nq, nk, group, causal=False, window=None,
-                last_q=None):
+                last_q=None, slabs=None):
     """attn.bhtd.bwd: grid (batch row, key/value head, member of its
     group, k-block, step), one head a step: the dk/dv kernel's walk, a
     k-row's ``nq`` steps over the q-blocks (with a window: over its
@@ -681,23 +841,32 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             acc[at, :] = jnp.zeros((rows, acc.shape[1]), acc.dtype)
         pl.when(first)(functools.partial(_each_block, acc, rows, _zero))
 
-    def _compute(masked=False):
-        mask = None
+    def _compute(masked=False, at=None):
+        # (the whole block, or slab ``at`` of an edge block: its rows of
+        # q, do, lse and delta, of k and v, added into the matching rows
+        # of the accumulators)
+        qs, ks, start = _slab(at, bq, bk)
+        mask = bias = None
         if masked:
             mask = lambda s_t: _causal_mask(
-                s_t[None], j, kk, bq, bk, transposed=True, window=window)[0]
+                s_t[None], j, kk, bq, bk, transposed=True, window=window,
+                at=start)[0]
+        if bias_ref is not None:
+            per_row = bias_ref.shape[2] > 1
+            bias = bias_ref[0, 0, qs if per_row else slice(None), ks]
         parts = _bwd_block(
-            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
-            lse_ref[0, 0], delta_ref[0, 0],
-            None if bias_ref is None else bias_ref[0, 0], scale, mask)
-        for (acc, _, rows, idx, _), part in zip(accs, parts):
-            acc[_block_rows(acc, idx, rows), :] += part
+            q_ref[0, 0, qs, :], k_ref[0, 0, ks, :], v_ref[0, 0, ks, :],
+            do_ref[0, 0, qs, :], lse_ref[0, 0, :, qs], delta_ref[0, 0, :, qs],
+            bias, scale, mask)
+        for (acc, _, rows, idx, _), part, inside in zip(
+                accs, parts, (qs, ks, ks)):
+            acc[_block_rows(acc, idx, rows, inside), :] += part
 
     if causal:
         # (a band's steps start at the k-row's first live q-block)
         live = (_causal_live(j, kk, bq, bk) if window is None else
                 j <= jnp.minimum(_last_q(kk, bq, bk, window), last_q))
-        _when_live(_compute, live, _on_edge(j, kk, bq, bk, window))
+        _when_live(_compute, live, j, kk, bq, bk, window, slabs)
     else:
         _compute()
 
@@ -1034,9 +1203,11 @@ def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
 
     kernel, specs, args, rows = _call_parts(_bwd_kernel, at, tile, q, k, v,
                                             bias)
+    sub = bhtd_edge_tile(tile, causal)
     kernel = functools.partial(
         kernel, scale=scale, nq=q_steps, nk=nk, group=group, causal=causal,
-        window=window, last_q=nq - 1)
+        window=window, last_q=nq - 1,
+        slabs=sub and _edge_slabs(tq, tk, bq, bk, sub, window))
     # a resident gradient: all rows of one head, one block of the output
     dq_spec = pl.BlockSpec((1, 1, tq, dh),
                            lambda i, hk, m, *_: (i, hk * group + m, 0, 0))

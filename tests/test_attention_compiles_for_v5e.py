@@ -290,9 +290,11 @@ def test_windowed_attention_compiles(one_chip, real_kernels):
     assert "bf16[1,4,16384,128]" in text
 
 
-# (b, query heads, key/value heads, t, dh, dv, window) of the four decoder
+# (b, query heads, key/value heads, t, dh, dv, window) of the decoder
 # cells' attention calls
 _CELL_CALLS = {
+    "laguna_w512": (1, 64, 8, 8192, 128, 128, 512),
+    "laguna_global": (1, 48, 8, 8192, 128, 128, None),
     "smallthinker_w4096": (1, 28, 4, 16384, 128, 128, 4096),
     "smallthinker_global": (1, 28, 4, 16384, 128, 128, None),
     "joyai": (1, 32, 32, 4096, 192, 128, None),
@@ -335,6 +337,47 @@ def test_fused_backward_compiles_at_the_cells_calls(call, one_chip,
     # (the result tuple's table besides)
     assert 0 <= compiled.memory_analysis().output_size_in_bytes \
         - 2 * b * t * (h * dh + hk * (dh + dv)) < 4096
+
+
+@pytest.mark.parametrize("call", ["laguna_w512", "smallthinker_w4096",
+                                  "joyai", "qwen3next"])
+def test_edge_sub_tiles_compile_alone_and_inside_a_while_body(
+        call, one_chip, real_kernels):
+    """The calls whose backward walks its edge blocks in sub-tiles (a
+    band of one block where every block is an edge, a band of eight, a
+    head of 192 over 128, a head of 256 under a group of 8), forward
+    and the ONE backward call: alone, and as a ``run_steps`` window
+    lowers them, inside a While body, under the VMEM limits the calls
+    set themselves (the backward's ``_bwd_vmem_limit``, Mosaic's default
+    forward)."""
+    b, h, hk, t, dh, dv, window = _CELL_CALLS[call]
+    tile = fa.bhtd_tile(h, t, t, dh=dh, group=h // hk, dv=dv)
+    assert tile == (1, 512, 512)
+    sub = fa.bhtd_edge_tile(tile, True)
+    assert sub == fa._edge_tile(512, 512) and sub is not None
+    assert fa._bwd_vmem_limit(t, t, dh, dv, h // hk, 512, 512, 2) \
+        <= fa._BWD_VMEM_CAP_BYTES * 5 // 4
+
+    def arg(heads, width):
+        return jax.ShapeDtypeStruct((b, heads, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def step(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=True,
+                                          window=window)
+        dq, dk, dv_ = fa.flash_attention_bwd(
+            q, k, v, None, None, out, lse, g, causal=True, window=window)
+        return dq, dk, dv_, out
+
+    def steps(q, k, v, g):
+        return jax.lax.fori_loop(0, 3, lambda _, x: step(*x), (q, k, v, g))
+
+    args = (arg(h, dh), arg(hk, dh), arg(hk, dv), arg(h, dv))
+    for f, loop in ((step, False), (steps, True)):
+        text = jax.jit(f).lower(*args).compile().as_text()
+        assert (" while(" in text) == loop
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert _calls(text) == {"attn.bhtd.fwd", "attn.bhtd.bwd"}
 
 
 def test_the_split_pair_compiles_where_the_rows_pass_the_cap(

@@ -211,7 +211,7 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
                            "kernel bwd b1 t512 c512 taps4": 3}
     assert sorted(row["attention"]) == [
         f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]{form}"
-        for d, form in (("bwd", " form=fused"), ("fwd", ""))]
+        for d, form in (("bwd", " form=fused edge=256x256"), ("fwd", ""))]
     assert row["attn_bwd_kernel_ms"] == {}      # (a trace needs the chip)
     assert sum(row["grouped_matmuls"].values()) == 36
     assert set(row["rel_err"]) == {
@@ -544,7 +544,8 @@ def test_ssm_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert sum(attn.values()) == 12 and all(
         k.startswith("bhtd ") and " h4 kv2 dk64 dv128" in k for k in attn)
     assert sum(v for k, v in attn.items() if " w128" in k) == 4
-    assert all(k.endswith(" form=fused") for k in attn if " bwd " in k)
+    assert all(k.endswith(" form=fused edge=256x256") for k in attn
+               if " bwd " in k)
     assert row["kernel_ms"] == {}               # (a trace needs the chip)
     assert set(row["rel_err"]) == {
         "scan Out", *(f"scan GRAD::{s}" for s in (
@@ -606,7 +607,8 @@ def test_mamba2_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     attn = row["attention"]
     assert sum(attn.values()) == 2 and all(
         k.startswith("bhtd ") and " h4 kv2 " in k for k in attn)
-    assert all(k.endswith(" form=fused") for k in attn if " bwd " in k)
+    assert all(k.endswith(" form=fused edge=256x256") for k in attn
+               if " bwd " in k)
     assert row["kernel_ms"] == {}               # (a trace needs the chip)
     assert set(row["rel_err"]) == {
         "scan Out", *(f"scan GRAD::{s}" for s in (
@@ -658,7 +660,8 @@ def test_sconv_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     attn = row["attention"]
     assert sum(attn.values()) == 2 and all(
         k.startswith("bhtd ") and " h2 kv1 dh64 " in k for k in attn)
-    assert all(k.endswith(" form=fused") for k in attn if " bwd " in k)
+    assert all(k.endswith(" form=fused edge=256x256") for k in attn
+               if " bwd " in k)
     assert row["rotary_embeddings"] == {"xla fwd bthd 64": 1,
                                         "xla bwd bthd 64": 1}
     assert row["kernel_ms"] == {}               # (a trace needs the chip)
@@ -677,3 +680,23 @@ def test_sconv_phase_fails_on_a_convolution_without_the_kernel(
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="on the sconv.gated kernels"):
         chip_smoke.sconv_phase(seq=512, t_check=256, **SCONV_TINY)
+
+
+def test_attention_pairs_of_the_cells_calls(monkeypatch):
+    """chip_smoke prints, for the decoder cells' BHTD calls, the score
+    pairs a head's steps compute against those the mask lets through:
+    laguna's band of one block keeps two thirds in the backward at
+    sub-tiles of 256 where whole edge blocks (the forward's) keep
+    half."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    pairs = chip_smoke.attention_pairs()
+    assert set(pairs) == set(chip_smoke.ATTN_GEOMETRIES)
+    sub = fa.edge_label((fa._EDGE_SUB,) * 2)
+    assert all(row["edge"] == sub for row in pairs.values())
+    band = pairs["laguna w512"]
+    assert band["live"] == 4063488
+    assert band["computed"] == {256: 6094848, 128: 5079040}[fa._EDGE_SUB]
+    assert band["computed_fwd"] == 8126464
+    assert all(0.5 < row["live_share"] <= 1.0 for row in pairs.values())

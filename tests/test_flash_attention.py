@@ -559,11 +559,25 @@ def _fused_case(case):
     return (q, k, v, bias, g, g_lse), kw
 
 
-@pytest.mark.parametrize("case", sorted(_FUSED_CASES))
-def test_fused_backward_matches_the_pair_and_the_composition(case,
+# the cases with an edge block, again with the edge blocks of the ONE
+# call walked in sub-tiles of 64 x 64: the forward and the pair keep
+# their edge blocks whole
+_WALKED = ["causal", "band", "band_group7", "band_group8", "group7",
+           "group8_two_kv_heads", "dk192_dv128", "lse_cotangent",
+           "pad_bias"]
+
+
+@pytest.mark.parametrize("case,edge_sub", [
+    *((c, None) for c in sorted(_FUSED_CASES)), *((c, 64) for c in _WALKED),
+    ("band", 32), ("causal", 32)])
+def test_fused_backward_matches_the_pair_and_the_composition(case, edge_sub,
                                                              monkeypatch):
     (q, k, v, bias, g, g_lse), kw = _fused_case(case)
     h, hk, tq, tk, dh, dv, blk = _FUSED_CASES[case][:7]
+    if edge_sub:
+        monkeypatch.setattr(fa, "_EDGE_SUB", edge_sub)
+    assert fa.bhtd_edge_tile((1, blk, blk), kw["causal"]) == (
+        (edge_sub, edge_sub) if edge_sub else None)
     form = dict(dh=dh, group=h // hk, dv=dv, itemsize=4)
     assert fa.bhtd_tile(h, tq, tk, blk, blk, dh=dh, group=h // hk,
                         dv=dv) == (1, blk, blk)
@@ -622,14 +636,23 @@ def _count(jaxpr, primitive):
     return n
 
 
-@pytest.mark.parametrize("case,branches", [
-    ("non_causal", 1), ("causal", 2), ("band_group7", 2), ("pad_bias", 2)])
-def test_fused_backward_is_one_call_of_five_matmuls_and_one_exp(case,
-                                                                branches):
+@pytest.mark.parametrize("case,edge_sub,branches", [
+    ("non_causal", None, 1), ("causal", None, 2), ("band_group7", None, 2),
+    ("pad_bias", None, 2), ("non_causal", 64, 1), ("causal", 64, 3),
+    ("band_group7", 32, 12)])
+def test_fused_backward_is_one_call_of_five_matmuls_and_one_exp(
+        case, edge_sub, branches, monkeypatch):
     """ONE Mosaic call named attn.bhtd.bwd; the body of a live step
     holds five dot_generals and one exp (a causal call has two such
     bodies, masked for an edge block and plain inside, of which a step
-    runs one); its only results are dq, dk and dv."""
+    runs one; with sub-tiles the plain block's body and one for each
+    slab of each kind of edge block: a query sub-tile's live key
+    sub-tiles, two on the diagonal at sub-tiles of half a block; four,
+    four and three for the diagonal and the two far-edge kinds of a
+    band of 200 at sub-tiles of a quarter); its only results are dq, dk
+    and dv."""
+    if edge_sub:
+        monkeypatch.setattr(fa, "_EDGE_SUB", edge_sub)
     (q, k, v, bias, g, _), kw = _fused_case(case)
     out, lse = jax.eval_shape(
         lambda q, k, v: fa.flash_attention_fwd(q, k, v, bias, **kw), q, k, v)

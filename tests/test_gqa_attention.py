@@ -27,10 +27,14 @@ def qkv(h, hk, dh, t, seed=0, b=1):
             jnp.asarray(r.randn(b, h, t, dh), jnp.float32))
 
 
+@pytest.mark.parametrize("edge_sub", [None, 64], ids=["whole", "sub64"])
 @pytest.mark.parametrize("dh", [128, 256])
 @pytest.mark.parametrize("group", [1, 2, 8])
-def test_kernels_agree_with_the_dense_composition(group, dh, interpreted):
+def test_kernels_agree_with_the_dense_composition(group, dh, edge_sub,
+                                                  interpreted, monkeypatch):
     h, t, blk = 8, 256, 128
+    if edge_sub:    # the diagonal's blocks walked in sub-tiles of 64 x 64
+        monkeypatch.setattr(fa, "_EDGE_SUB", edge_sub)
     q, k, v, g = qkv(h, h // group, dh, t)
     tile = fa.bhtd_tile(h, t, t, blk, blk, dh=dh, group=group)
     # a group's heads go onto the grid, one a step; without groups the

@@ -32,8 +32,12 @@ def qkv(h, dk, dv, t, seed=0, b=1):
 @pytest.mark.parametrize("h,dk,dv,t,blk", [
     (4, 24, 16, 256, 128), (2, 192, 128, 256, 128), (2, 64, 128, 256, 128)])
 @pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("edge_sub", [None, 64], ids=["whole", "sub64"])
 def test_kernels_agree_with_the_dense_composition(h, dk, dv, t, blk, causal,
-                                                  interpreted):
+                                                  edge_sub, interpreted,
+                                                  monkeypatch):
+    if edge_sub:    # the diagonal's blocks walked in sub-tiles of 64 x 64
+        monkeypatch.setattr(fa, "_EDGE_SUB", edge_sub)
     q, k, v, g = qkv(h, dk, dv, t)
     assert fa.bhtd_tile(h, t, t, blk, blk, dh=dk, dv=dv) is not None
     scale = dk ** -0.5      # the default: 1 / sqrt of the QUERY's width

@@ -1,10 +1,12 @@
 """Causal attention that also FORGETS (a sliding window: a query sees
 the last ``window`` positions, itself among them) through the BHTD
 Pallas kernels (interpreter mode on the CPU) and the dense composition,
-against explicit float32 scores: forward and the three gradients;
+against explicit float32 scores: forward and the three gradients, with
+an edge block worked on whole and walked in sub-tiles;
 ``window=None`` and a window as long as the row give the causal call bit
-for bit; the live-step predicate and both index maps against a
-brute-force table of visible pairs; the sdpa op's dispatch row."""
+for bit; the live-step predicate, both index maps, the sub-tiles'
+predicates and ``bhtd_pairs`` against a brute-force table of visible
+pairs; the sdpa op's dispatch row."""
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +21,16 @@ from paddle_tpu.parallel import flash_attention as fa
 @pytest.fixture
 def interpreted(monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
+
+
+@pytest.fixture(params=[None, 64, 32], ids=["whole", "sub64", "sub32"])
+def edge_sub(request, monkeypatch):
+    """An edge block worked on whole (the blocks here are under the
+    program's sub-tile), or walked in sub-tiles of that side: the helper
+    reached at small blocks."""
+    if request.param:
+        monkeypatch.setattr(fa, "_EDGE_SUB", request.param)
+    return request.param
 
 
 def qkv(h, hk, t, dh=16, seed=0, b=1):
@@ -49,19 +61,28 @@ def explicit(q, k, v, window):
 # (query heads, key/value heads, t, block, window): windows smaller than,
 # equal to and larger than a block, one that no block divides and that
 # does not divide the row, one a position short of the row; groups 1, 7
-# and 8
+# and 8; at blocks of 128 (the ONE backward call; of 64 the pair, whose
+# edge blocks stay whole) a window of a block, of two, one no sub-tile
+# divides, one under a sub-tile (a row's first live sub-tile then holds
+# no key it sees: rows 32.. of a block under w20 start in keys 0..31),
+# 1000 (none: the plain causal call)
 CASES = [
     (2, 2, 256, 64, 16), (2, 2, 256, 64, 64), (2, 2, 256, 64, 100),
     (7, 1, 256, 64, 96), (8, 1, 256, 128, 129), (14, 2, 192, 64, 191),
     (2, 2, 384, 128, 1), (7, 1, 320, 64, 200),
+    (2, 2, 512, 128, 128), (2, 1, 512, 128, 256), (7, 1, 384, 128, 100),
+    (1, 1, 256, 128, 20), (8, 1, 384, 128, 1000),
 ]
 
 
 @pytest.mark.parametrize("h,hk,t,blk,window", CASES)
 def test_kernels_agree_with_explicit_scores(h, hk, t, blk, window,
-                                            interpreted):
+                                            interpreted, edge_sub):
     q, k, v, g = qkv(h, hk, t)
-    assert fa.bhtd_tile(h, t, t, blk, blk, dh=16, group=h // hk) is not None
+    tile = fa.bhtd_tile(h, t, t, blk, blk, dh=16, group=h // hk)
+    assert tile is not None
+    assert fa.bhtd_edge_tile(tile, True) == (
+        (edge_sub, edge_sub) if edge_sub and edge_sub < blk else None)
     with jax.default_matmul_precision("highest"):
         out, lse = fa.flash_attention_fwd(
             q, k, v, causal=True, window=window, q_block=blk, k_block=blk)
@@ -99,10 +120,11 @@ def test_dense_composition_agrees_with_explicit_scores(h, hk, t, window):
 
 
 @pytest.mark.parametrize("window", [None, 256, 1000])
-@pytest.mark.parametrize("hk", [2, 1])
-def test_no_window_is_the_causal_call_bit_for_bit(window, hk, interpreted):
+@pytest.mark.parametrize("hk,blk", [(2, 64), (1, 64), (1, 128)])
+def test_no_window_is_the_causal_call_bit_for_bit(window, hk, blk,
+                                                  interpreted, edge_sub):
     q, k, v, g = qkv(2, hk, 256, seed=4)
-    kw = dict(causal=True, q_block=64, k_block=64)
+    kw = dict(causal=True, q_block=blk, k_block=blk)
     out, lse = fa.flash_attention_fwd(q, k, v, **kw)
     got = fa.flash_attention_fwd(q, k, v, window=window, **kw)
     assert bool((got[0] == out).all()) and bool((got[1] == lse).all())
@@ -181,6 +203,120 @@ def test_band_geometry_against_a_table_of_visible_pairs(t, bq, bk, window):
         assert np.tril(np.ones((nq, nk), bool)).sum() == 528
 
 
+@pytest.mark.parametrize("t,bq,bk,sub,window", [
+    (512, 128, 128, (64, 64), 128), (512, 128, 128, (32, 32), 256),
+    (512, 128, 128, (32, 32), 100), (384, 128, 128, (64, 32), 1),
+    (512, 128, 64, (32, 64), 129), (512, 128, 128, (64, 64), None),
+    (640, 128, 128, (32, 64), 20), (1024, 512, 512, (256, 256), 512),
+    (1024, 512, 512, (128, 128), 1000)])
+def test_sub_tile_geometry_against_a_table_of_visible_pairs(
+        t, bq, bk, sub, window, monkeypatch):
+    """A sub-tile of an edge block is dead, plain or edge by the block's
+    own predicates at its own corners, as the table of visible pairs
+    has it; the slabs of an edge block (one a query sub-tile: what the
+    ONE backward call walks) hold exactly its live sub-tiles, each once,
+    and take the mask iff they hold a pair that is not visible; every
+    edge block of the call has its kind; ``bhtd_pairs`` counts the pairs
+    of plain blocks and of slabs (``form=None``: of whole blocks, the
+    forward's)."""
+    monkeypatch.setattr(fa, "_edge_tile", lambda *_: sub)
+    nq, nk, (sq, sk) = t // bq, t // bk, sub
+    na, nc = bq // sq, bk // sk
+    table = visible(t, window)
+    slabs = fa._edge_slabs(t, t, bq, bk, sub, window)
+    computed = whole = 0
+    for j in range(nq):
+        for kk in range(nk):
+            block = table[j * bq:(j + 1) * bq, kk * bk:(kk + 1) * bk]
+            live, full = block.any(), block.all()
+            assert bool(fa._band_live(j, kk, bq, bk, window)) == live
+            if live:
+                assert bool(fa._on_edge(j, kk, bq, bk, window)) == (not full)
+            whole += bq * bk * live
+            if not live or full:
+                computed += bq * bk * live
+                continue
+            alive = np.zeros((bq, bk), bool)
+            for a in range(na):
+                for c in range(nc):
+                    part = block[a * sq:(a + 1) * sq, c * sk:(c + 1) * sk]
+                    place = (j * na + a, kk * nc + c, sq, sk, window)
+                    assert bool(fa._band_live(*place)) == part.any()
+                    if part.any():
+                        assert bool(fa._on_edge(*place)) == (not part.all())
+                    alive[a * sq:(a + 1) * sq,
+                          c * sk:(c + 1) * sk] = part.any()
+            walked = np.zeros((bq, bk), int)
+            for (q0, rows, k0, cols), masked in slabs[j * bq - kk * bk]:
+                assert (rows, q0 % sq) == (sq, 0)    # one a query sub-tile
+                walked[q0:q0 + rows, k0:k0 + cols] += 1
+                assert masked == (
+                    not block[q0:q0 + rows, k0:k0 + cols].all())
+            assert (walked == alive).all()
+            computed += alive.sum()
+    assert fa.bhtd_edge_tile((1, bq, bk), True) == sub
+    assert fa.bhtd_pairs(t, t, (1, bq, bk), True, window) \
+        == (computed, table.sum())
+    assert fa.bhtd_pairs(t, t, (1, bq, bk), True, window, form=None) \
+        == (whole, table.sum())
+    assert fa.bhtd_pairs(t, t, (1, bq, bk), False) == (t * t, t * t)
+
+
+@pytest.mark.parametrize("t,window,whole,walked", [
+    (8192, 512, 8126464, {256: 6094848, 128: 5079040}),
+    (16384, 4096, 66060288, {256: 62390272, 128: 60555264}),
+    (4096, None, 9437184, {256: 8912896, 128: 8650752})])
+def test_pairs_of_the_cells_geometries(t, window, whole, walked,
+                                       monkeypatch):
+    """The three geometries the decoder cells run at blocks of 512:
+    laguna's band of one block (every block an edge: half of what a
+    whole block computes is dead), smallthinker's of eight, the plain
+    triangle at 4096; the live pairs by rows. The backward walks its
+    edge blocks in sub-tiles, the forward works on them whole."""
+    tile = (1, 512, 512)
+    live = sum(min(p + 1, window or t) for p in range(t))
+    assert fa.bhtd_edge_tile(tile, True) == (fa._EDGE_SUB,) * 2
+    assert fa.bhtd_pairs(t, t, tile, True, window) \
+        == (walked[fa._EDGE_SUB], live)
+    assert fa.bhtd_pairs(t, t, tile, True, window, form=None) == (whole, live)
+    assert fa.bhtd_pairs(t, t, tile, True, window, form="split") \
+        == (whole, live)
+    for side, want in walked.items():
+        monkeypatch.setattr(fa, "_EDGE_SUB", side)
+        assert fa.bhtd_pairs(t, t, tile, True, window) == (want, live)
+
+
+def test_the_sub_tile_follows_the_blocks_shape():
+    """``_EDGE_SUB`` on a side where the block is square and that cuts
+    its side in whole parts: one rule, from the block's shape alone;
+    nothing to walk where the block is not square or no larger than a
+    sub-tile, without ``causal``, in the forward and in the split pair.
+    A call of square blocks has the diagonal's kind of edge block and
+    the far edge's one or two."""
+    sub = fa._EDGE_SUB
+    assert fa._edge_tile(2 * sub, 2 * sub) == (sub, sub)
+    assert fa._edge_tile(4 * sub, 4 * sub) == (sub, sub)
+    assert fa._edge_tile(2 * sub, sub) is None
+    assert fa._edge_tile(sub, 4 * sub) is None
+    assert fa._edge_tile(sub, sub) is None
+    assert fa._edge_tile(sub // 2, sub // 2) is None
+    assert fa._edge_tile(sub + sub // 2, sub + sub // 2) is None
+    tile, t = (1, 2 * sub, 2 * sub), 8 * sub
+    assert fa.bhtd_edge_tile(tile, True) == (sub, sub)
+    assert fa.bhtd_edge_tile(tile, False) is None
+    assert fa.bhtd_edge_tile(tile, True, form=None) is None
+    assert fa.bhtd_edge_tile(tile, True, form="split") is None
+    assert fa.bhtd_edge_tile(None, True) is None
+    assert fa.bhtd_edge_tile((1, 4 * sub, sub), True) is None
+    for window, kinds in ((None, [0]), (2 * sub, [0, 2 * sub]),
+                          (3 * sub + 1, [0, 2 * sub, 4 * sub]),
+                          (1, [0])):
+        assert sorted(fa._edge_slabs(t, t, 2 * sub, 2 * sub, (sub, sub),
+                                     window)) == kinds
+    assert fa.edge_label((sub, sub)) == f"{sub}x{sub}"
+    assert fa.edge_label(None) == ""
+
+
 def test_grouped_dkv_index_map_walks_each_heads_band():
     """Group 7: the dk/dv grid's inner axis walks 7 heads x the band's
     steps; step r reads query head kv * 7 + r // steps."""
@@ -230,6 +366,9 @@ def test_sdpa_op_takes_the_window_and_names_it(interpreted):
         f"dense fwd {shape} w96": 1}
     bands = {(r["labels"]["family"], r["labels"].get("band")) for r in rows}
     assert bands == {("bhtd", "skip"), ("bhtd", None), ("dense", "dense")}
+    # blocks of 256 are under the program's sub-tile: worked on whole
+    assert {(r["labels"]["family"], r["labels"].get("edge"))
+            for r in rows} == {("bhtd", ""), ("dense", None)}
     with jax.default_matmul_precision("highest"):
         want, vjp = jax.vjp(lambda q, k, v: explicit(q, k, v, 96)[0],
                             q, k, v)
@@ -242,3 +381,51 @@ def test_sdpa_op_takes_the_window_and_names_it(interpreted):
             {"Q": [jnp.swapaxes(q, 1, 2)[:, :, :1]],
              "K": [jnp.swapaxes(k, 1, 2)], "V": [jnp.swapaxes(v, 1, 2)]},
             dict(attrs, layout="bthd"))
+
+
+def test_dispatch_rows_name_the_sub_tile(interpreted, monkeypatch):
+    """A BHTD row's ``edge``: the sub-tiles in which the call walks its
+    edge blocks, "" where it works on them whole (the forward; no
+    sub-tile cuts the blocks, or nothing is causal);
+    ``dispatch_counts(edges=True)`` appends it; with telemetry off
+    nothing is counted."""
+    from paddle_tpu.core import interp
+
+    monkeypatch.setattr(fa, "_EDGE_SUB", 128)
+    q, k, v, g = qkv(2, 1, 256, dh=128, seed=5)
+    ins = {"Q": [q], "K": [k], "V": [v]}
+    attrs = {"layout": "bhtd", "causal": True, "is_test": True}
+
+    def lower(attrs):
+        out = attention_ops._sdpa(ins, attrs)
+        attention_ops._sdpa_grad(
+            dict(ins, Out=out["Out"], Lse=out["Lse"], **{"GRAD::Out": [g]}),
+            attrs)
+
+    monitor.reset()
+    tok = interp.set_amp_active(False)
+    try:
+        lower(attrs)                            # telemetry off: silent
+        assert "pt_attention_dispatch_total" not in monitor.snapshot() or \
+            not monitor.snapshot()["pt_attention_dispatch_total"]["values"]
+        flags.set_flags({"telemetry": True})
+        lower(attrs)
+        lower(dict(attrs, window=100))
+        lower(dict(attrs, causal=False))
+        counts = attention_ops.dispatch_counts(forms=True, edges=True)
+        rows = monitor.snapshot()["pt_attention_dispatch_total"]["values"]
+    finally:
+        interp._AMP_ACTIVE.reset(tok)
+        flags.set_flags({"telemetry": False})
+        monitor.reset()
+    shape = "b1 tq256 tk256 h2 kv1 dh128"
+    assert counts == {
+        f"bhtd fwd {shape}": 2, f"bhtd fwd {shape} w100": 1,
+        f"bhtd bwd {shape} form=fused edge=128x128": 1,
+        f"bhtd bwd {shape} w100 form=fused edge=128x128": 1,
+        f"bhtd bwd {shape} form=fused": 1}
+    assert all("edge" in r["labels"] for r in rows)
+    assert sorted((r["labels"]["pass"], r["labels"]["edge"])
+                  for r in rows) == [("bwd", ""), ("bwd", "128x128"),
+                                     ("bwd", "128x128"), ("fwd", ""),
+                                     ("fwd", "")]
