@@ -328,8 +328,8 @@ class _Call:
 
 def _prng_impl():
     """Program-level PRNG implementation. On TPU, threefry random-bit
-    generation is slow enough to dominate dropout (ablation: 21.5ms of a
-    63ms transformer step, benchmarks/ablate.py), so the hardware 'rbg'
+    generation is slow enough to dominate dropout (an ablation before the
+    chip era: 21.5ms of a 63ms transformer step), so the hardware 'rbg'
     generator is the default there; CPU keeps threefry so test streams
     stay stable. Override with the 'prng_impl' flag."""
     from paddle_tpu import flags as _flags

@@ -1,7 +1,6 @@
 """Where compiled programs are cached between processes.
 
 One rule, used by every entry point (perf/run.py, chip_smoke.py,
-bench_common.py for the bench*.py family, benchmarks/fluid_benchmark.py,
 tests/conftest.py): where ``JAX_COMPILATION_CACHE_DIR`` is set, jax
 already reads it and nothing is set in code, so whoever runs the program
 places the cache; otherwise jax's persistent compilation cache lives in

@@ -34,7 +34,8 @@ __all__ = [
     "mean_iou",
     "linear_chain_crf", "crf_decoding", "warpctc", "edit_distance",
     "bilinear_tensor_product", "nce", "switch_moe", "topk_moe",
-    "rms_norm", "rotary_embedding", "causal_conv1d", "short_conv_gate",
+    "rms_norm", "rotary_embedding", "scaled_dot_product_attention",
+    "causal_conv1d", "short_conv_gate",
     "gdn_gates",
     "gated_delta_rule", "gated_rms_norm", "silu", "selective_scan",
     "mamba2_scan", "diff_attention_combine",
@@ -457,6 +458,34 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
     helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
                      outputs={"QOut": q_out, "KOut": k_out}, attrs=attrs)
     return q_out, k_out
+
+
+def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
+                                 name=None):
+    """softmax(scale q k^T) v of head-major q [b, h, t, dk], k
+    [b, hk, t, dk] and v [b, hk, t, dv] -> [b, h, t, dv]: ONE op, which
+    the flash kernels take on a TPU (``ops/attention_ops.py``). hk may
+    divide h (grouped queries: query head i reads key/value head
+    i // (h / hk), nothing is copied) and dv may differ from dk.
+    ``causal``: the mask rides in the kernel, no bias tensor exists;
+    ``window``: a query sees its last ``window`` positions only, itself
+    among them. Every position is real and nothing is dropped: the
+    packed decoders' call (``models/decoder.py``'s families).
+    ``models/transformer.py`` appends the op itself, token-major with
+    dropout and a padding bias. ``name`` names the layer's temporaries."""
+    helper = LayerHelper(name or "scaled_dot_product_attention")
+    out = helper.create_variable_for_type_inference(dtype=q.dtype)
+    # logsumexp rows, consumed by the paired grad op (DCE'd at inference)
+    lse = helper.create_variable_for_type_inference(dtype="float32")
+    lse.stop_gradient = True
+    attrs = {"scale": float(scale), "dropout_prob": 0.0, "is_test": True,
+             "layout": "bhtd", "causal": bool(causal)}
+    if window:
+        attrs["window"] = int(window)
+    helper.append_op("scaled_dot_product_attention",
+                     inputs={"Q": q, "K": k, "V": v},
+                     outputs={"Out": out, "Lse": lse}, attrs=attrs)
+    return out
 
 
 def causal_conv1d(input, taps=4, act="silu", param_attr=None,

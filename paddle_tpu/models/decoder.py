@@ -1,0 +1,121 @@
+"""The frame the decoder-only builders share: what every one of them
+writes the same way around its own layers. A family keeps its config
+class, its per-layer decisions, its mixers, its ``topk_moe`` call and its
+auxiliary-loss policy; it calls this module for the parameters' attribute,
+the plain RMSNorm and the bias-free projection, the two feeds, the
+embedding, the vocabulary head with its loss, the last positions' logits
+and the batch of packed tokens:
+
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, vocab, width, "<family>_tok_emb.w")
+    for i in ...: x = <the family's layer i>(x)
+    with fluid.name_scope("final_norm"): x = <the family's norm>(x)
+    logits, lm_loss = decoder.lm_head(x, lbl, vocab)    # or tied_lm_head
+    with fluid.name_scope("loss_head"): loss = <lm_loss and the family's terms>
+    last = decoder.last_logits(logits, LAST_POSITIONS)
+
+No function here knows a family: none takes a config class or a family's
+name, none branches on its caller. A helper that would need to stays in
+the families. The name scopes written here (``embed``, ``loss_head``) are
+keys of the per-layer metrics (README "Names in the device trace").
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+import numpy as np
+
+import paddle_tpu as fluid
+from paddle_tpu import layers
+from paddle_tpu.initializer import NormalInitializer
+from paddle_tpu.param_attr import ParamAttr
+
+_END = 2 ** 31 - 1   # a slice's "to the end"
+
+
+def weight(name, std=0.02):
+    """A matrix's attribute: its name, drawn from normal(0, std)."""
+    return ParamAttr(name=name, initializer=NormalInitializer(0.0, std))
+
+
+def rms_norm(x, eps, name):
+    """RMSNorm with a plain gain ``<name>.scale`` (1 at the start)."""
+    return layers.rms_norm(x, epsilon=eps,
+                           param_attr=ParamAttr(name=f"{name}.scale"))
+
+
+def linear(x, size, name):
+    """x [b, t, d] W, no bias; ``name`` is W's (the ``*_colp`` /
+    ``*_rowp`` names are parallel/strategy's tensor-parallel rules')."""
+    return layers.fc(x, size, num_flatten_dims=2, param_attr=weight(name),
+                     bias_attr=False)
+
+
+def token_feeds():
+    """(``input_ids``, ``labels``), each [b, t] int64: the label of a
+    position is the token after it, and every position is real (packed
+    documents)."""
+    return (layers.data("input_ids", shape=[-1], dtype="int64"),
+            layers.data("labels", shape=[-1], dtype="int64"))
+
+
+def embed(ids, vocab, width, name, std=0.02):
+    """The rows of table ``name`` [vocab, width] at ``ids``, under scope
+    ``embed``."""
+    with fluid.name_scope("embed"):
+        return layers.embedding(ids, size=[vocab, width],
+                                param_attr=weight(name, std))
+
+
+def cross_entropy(logits, lbl):
+    """Each position's cross entropy [b, t, 1] against ``lbl`` [b, t]."""
+    return layers.softmax_with_cross_entropy(logits,
+                                             layers.unsqueeze(lbl, [2]))
+
+
+def lm_head(x, lbl, vocab, name="lm_head_colp.w"):
+    """(logits [b, t, vocab] of an untied head ``name``, the mean
+    next-token cross entropy), under scope ``loss_head``."""
+    with fluid.name_scope("loss_head"):
+        logits = linear(x, vocab, name)
+        return logits, layers.mean(cross_entropy(logits, lbl))
+
+
+def tied_lm_head(x, lbl, table):
+    """``lm_head`` where the embedding's rows are the head's columns:
+    ``table`` is the embedding's parameter name."""
+    with fluid.name_scope("loss_head"):
+        w = fluid.default_main_program().global_block().var(table)
+        logits = layers.matmul(x, w, transpose_y=True)
+        return logits, layers.mean(cross_entropy(logits, lbl))
+
+
+def last_logits(logits, n):
+    """The logits of a row's last ``n`` positions (what a comparison
+    with a reference can hold at a full vocabulary), under scope
+    ``loss_head``."""
+    with fluid.name_scope("loss_head"):
+        return layers.slice(logits, axes=[1], starts=[-n], ends=[_END])
+
+
+def sum_of(xs):
+    """The sum of a list of like tensors; one tensor is itself."""
+    return xs[0] if len(xs) == 1 else layers.sums(xs)
+
+
+def swiglu_mlp(x, width, out, gate_name, up_name, down_name):
+    """(silu(x Wgate) * (x Wup)) Wdown with two separate projections of
+    ``width``, back to ``out`` features."""
+    h = layers.elementwise_mul(layers.silu(linear(x, width, gate_name)),
+                               linear(x, width, up_name))
+    return linear(h, out, down_name)
+
+
+def make_batch(cfg, batch: int, seq_len: int,
+               seed: int = 0) -> Dict[str, np.ndarray]:
+    """Packed tokens below ``cfg.vocab_size``: ``seq_len + 1`` of them a
+    row, inputs the first ``seq_len``, labels the same shifted by one."""
+    r = np.random.RandomState(seed)
+    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
+    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

@@ -58,15 +58,12 @@ through the head ``loss_head/mtp``; ``final_norm``, ``loss_head``.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
-
-import numpy as np
+from typing import Optional, Tuple
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer
-from paddle_tpu.layer_helper import LayerHelper
-from paddle_tpu.param_attr import ParamAttr
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 
 # logits of the last positions a build offers (model["last_logits"] and
 # ["mtp_last_logits"]): the second check of perf/reference/joyai.py
@@ -143,20 +140,6 @@ def joyai_llm_flash() -> JoyaiFlashConfig:
     return JoyaiFlashConfig()
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps,
-                           param_attr=ParamAttr(name=f"{name}.scale"))
-
-
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _heads_first(z):   # [b, t, heads, dh] -> [b, heads, t, dh]
     return layers.transpose(z, [0, 2, 1, 3])
 
@@ -164,16 +147,19 @@ def _heads_first(z):   # [b, t, heads, dh] -> [b, heads, t, dh]
 def _latent_attention(x, cfg: JoyaiFlashConfig, p: str):
     h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
-    xn = _norm(x, cfg, f"{p}_attn_norm")
+    eps = cfg.rms_norm_eps
+    xn = decoder.rms_norm(x, eps, f"{p}_attn_norm")
     with fluid.name_scope("q_lora"):
-        c_q = _norm(_linear(xn, cfg.q_lora_rank, f"{p}_attn_q_a.w"), cfg,
-                    f"{p}_attn_q_a_norm")
-        q = _linear(c_q, h * (nope + rope), f"{p}_attn_q_b_colp.w")
+        c_q = decoder.rms_norm(
+            decoder.linear(xn, cfg.q_lora_rank, f"{p}_attn_q_a.w"), eps,
+            f"{p}_attn_q_a_norm")
+        q = decoder.linear(c_q, h * (nope + rope), f"{p}_attn_q_b_colp.w")
     with fluid.name_scope("kv_lora"):
-        kva = _linear(xn, cfg.kv_lora_rank + rope, f"{p}_attn_kv_a.w")
+        kva = decoder.linear(xn, cfg.kv_lora_rank + rope, f"{p}_attn_kv_a.w")
         c_kv, k_rope = layers.split(kva, [cfg.kv_lora_rank, rope], dim=-1)
-        kv = _linear(_norm(c_kv, cfg, f"{p}_attn_kv_a_norm"),
-                     h * (nope + dv), f"{p}_attn_kv_b_colp.w")
+        kv = decoder.linear(
+            decoder.rms_norm(c_kv, eps, f"{p}_attn_kv_a_norm"),
+            h * (nope + dv), f"{p}_attn_kv_b_colp.w")
     with fluid.name_scope("rope"):
         q_nope, q_rope = layers.split(
             _heads_first(layers.reshape(q, [0, 0, h, nope + rope])),
@@ -189,36 +175,26 @@ def _latent_attention(x, cfg: JoyaiFlashConfig, p: str):
         k = layers.concat(
             [k_nope, layers.expand(k_rope, [1, h, 1, 1])], axis=3)
     with fluid.name_scope("core"):
-        helper = LayerHelper(f"{p}_attn_sdpa")
-        ctx = helper.create_variable_for_type_inference(dtype=x.dtype)
-        # logsumexp rows, consumed by the paired grad op
-        lse = helper.create_variable_for_type_inference(dtype="float32")
-        lse.stop_gradient = True
-        helper.append_op(
-            "scaled_dot_product_attention",
-            # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
-            inputs={"Q": q, "K": k, "V": v},
-            outputs={"Out": ctx, "Lse": lse},
-            attrs={"scale": 1.0 / math.sqrt(nope + rope),
-                   "dropout_prob": 0.0, "is_test": True, "layout": "bhtd",
-                   "causal": True})
+        # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, 1.0 / math.sqrt(nope + rope), name=f"{p}_attn_sdpa")
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dv])
-        return _linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
 
 
 def _dense_ffn(x, cfg: JoyaiFlashConfig, p: str):
-    xn = _norm(x, cfg, f"{p}_ffn_norm")
-    h = layers.elementwise_mul(
-        layers.silu(_linear(xn, cfg.intermediate_size, f"{p}_ffn_gate_colp.w")),
-        _linear(xn, cfg.intermediate_size, f"{p}_ffn_up_colp.w"))
-    return _linear(h, cfg.hidden_size, f"{p}_ffn_down_rowp.w")
+    return decoder.swiglu_mlp(
+        decoder.rms_norm(x, cfg.rms_norm_eps, f"{p}_ffn_norm"),
+        cfg.intermediate_size, cfg.hidden_size, f"{p}_ffn_gate_colp.w",
+        f"{p}_ffn_up_colp.w", f"{p}_ffn_down_rowp.w")
 
 
 def _moe(x, cfg: JoyaiFlashConfig, p: str):
     return layers.topk_moe(
-        _norm(x, cfg, f"{p}_moe_norm"), cfg.n_routed_experts,
+        decoder.rms_norm(x, cfg.rms_norm_eps, f"{p}_moe_norm"),
+        cfg.n_routed_experts,
         cfg.num_experts_per_tok, cfg.moe_intermediate_size,
         norm_topk_prob=cfg.norm_topk_prob, name=f"{p}_moe",
         held=cfg.held_experts,
@@ -241,16 +217,6 @@ def _layer_body(x, cfg: JoyaiFlashConfig, p: str, dense: bool):
         return layers.elementwise_add(x, out), (lb, rows, top_i)
 
 
-def _cross_entropy(logits, labels):
-    return layers.softmax_with_cross_entropy(logits,
-                                             layers.unsqueeze(labels, [2]))
-
-
-def _last(logits):
-    return layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                        ends=[_END])
-
-
 def build(cfg: Optional[JoyaiFlashConfig] = None, is_test: bool = False):
     """Language-modelling graph. Feeds: ``input_ids`` [b, t] and
     ``labels`` [b, t] (the next token of every position; the MTP
@@ -259,15 +225,9 @@ def build(cfg: Optional[JoyaiFlashConfig] = None, is_test: bool = False):
     their boundaries. The graph has no dropout, so ``is_test`` changes
     nothing."""
     cfg = cfg or joyai_llm_flash()
-    mtp = bool(cfg.num_nextn_predict_layers)
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-    feeds = [ids, lbl]
-    emb_attr = _w("joyai_tok_emb.w")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                             param_attr=emb_attr)
+    eps, table = cfg.rms_norm_eps, "joyai_tok_emb.w"
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size, table)
     lbs, rows, top_i = [], [], []
 
     def keep(routing):
@@ -282,32 +242,31 @@ def build(cfg: Optional[JoyaiFlashConfig] = None, is_test: bool = False):
         if routing:
             keep(routing)
     with fluid.name_scope("final_norm"):
-        xn = _norm(x, cfg, "final_norm")
-    with fluid.name_scope("loss_head"):
-        logits = _linear(xn, cfg.vocab_size, "lm_head_colp.w")
-        lm_loss = layers.mean(_cross_entropy(logits, lbl))
+        xn = decoder.rms_norm(x, eps, "final_norm")
+    logits, lm_loss = decoder.lm_head(xn, lbl, cfg.vocab_size)
     model = {"logits": logits, "lm_loss": lm_loss}
 
     losses = [lm_loss]
-    if mtp:
+    if cfg.num_nextn_predict_layers:
         with fluid.name_scope("blk_mtp"):
             with fluid.name_scope("merge"):
                 # the next token's embedding, from the model's own table
                 nxt = layers.embedding(
                     lbl, size=[cfg.vocab_size, cfg.hidden_size],
-                    param_attr=emb_attr)
-                z = _linear(layers.concat(
-                    [_norm(x, cfg, "mtp_hnorm"),
-                     _norm(nxt, cfg, "mtp_enorm")], axis=2),
+                    param_attr=decoder.weight(table))
+                z = decoder.linear(layers.concat(
+                    [decoder.rms_norm(x, eps, "mtp_hnorm"),
+                     decoder.rms_norm(nxt, eps, "mtp_enorm")], axis=2),
                     cfg.hidden_size, "mtp_eh_proj.w")
             z, routing = _layer_body(z, cfg, "mtp", dense=False)
             keep(routing)
             with fluid.name_scope("merge"):
-                zn = _norm(z, cfg, "mtp_final_norm")
+                zn = decoder.rms_norm(z, eps, "mtp_final_norm")
         with fluid.name_scope("loss_head"):
             with fluid.name_scope("mtp"):
                 # the model's own head, a second time
-                mtp_logits = _linear(zn, cfg.vocab_size, "lm_head_colp.w")
+                mtp_logits = decoder.linear(zn, cfg.vocab_size,
+                                            "lm_head_colp.w")
                 # position i's second target is position i + 1's first;
                 # the row's last position has none: it runs (every
                 # kernel sees the whole row) and its loss is left out
@@ -316,26 +275,21 @@ def build(cfg: Optional[JoyaiFlashConfig] = None, is_test: bool = False):
                      layers.slice(lbl, axes=[1], starts=[-1], ends=[_END])],
                     axis=1)
                 mtp_loss = layers.mean(layers.slice(
-                    _cross_entropy(mtp_logits, lbl2), axes=[1], starts=[0],
-                    ends=[-1]))
+                    decoder.cross_entropy(mtp_logits, lbl2), axes=[1],
+                    starts=[0], ends=[-1]))
         losses.append(layers.scale(mtp_loss, scale=cfg.mtp_lambda))
+        # this slice carries no scope and never did: a scope is an attr of
+        # the op, and the cell's compiled step is keyed by the program
         model.update(mtp_logits=mtp_logits, mtp_loss=mtp_loss,
-                     mtp_last_logits=_last(mtp_logits))
+                     mtp_last_logits=layers.slice(
+                         mtp_logits, axes=[1], starts=[-LAST_POSITIONS],
+                         ends=[_END]))
 
     with fluid.name_scope("loss_head"):
-        lb_loss = lbs[0] if len(lbs) == 1 else layers.sums(lbs)
+        lb_loss = decoder.sum_of(lbs)
         losses.append(layers.scale(lb_loss, scale=cfg.balance_alpha))
         loss = layers.sums(losses)
-        last = _last(logits)
-    model.update(feeds=feeds, loss=loss, lb_loss=lb_loss, last_logits=last,
+    model.update(feeds=[ids, lbl], loss=loss, lb_loss=lb_loss,
+                 last_logits=decoder.last_logits(logits, LAST_POSITIONS),
                  expert_rows=rows, top_i=top_i, config=cfg)
     return model
-
-
-def make_batch(cfg: JoyaiFlashConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

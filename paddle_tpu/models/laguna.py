@@ -47,13 +47,10 @@ from __future__ import annotations
 import math
 from typing import Dict, Optional, Sequence, Tuple
 
-import numpy as np
-
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer
-from paddle_tpu.layer_helper import LayerHelper
-from paddle_tpu.param_attr import ParamAttr
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 
 # logits of the last positions a build offers (model["last_logits"]):
 # 64 of one row (perf/reference/laguna.py says why)
@@ -180,20 +177,6 @@ def laguna_xs_2() -> LagunaConfig:
     return LagunaConfig()
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps,
-                           param_attr=ParamAttr(name=f"{name}.scale"))
-
-
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _attention(a, cfg: LagunaConfig, p: str, i: int):
     """Attn_i of the normalised input ``a`` [b, t, d]."""
     h, hk, dh = cfg.heads(i), cfg.num_key_value_heads, cfg.head_dim
@@ -205,7 +188,8 @@ def _attention(a, cfg: LagunaConfig, p: str, i: int):
 
     with fluid.name_scope("qkv"):
         # the layer's own width: (h + 2 hk) dh + h
-        qkvg = _linear(a, (h + 2 * hk) * dh + h, f"{p}_attn_qkvg_colp.w")
+        qkvg = decoder.linear(a, (h + 2 * hk) * dh + h,
+                              f"{p}_attn_qkvg_colp.w")
         q, k, v, g = layers.split(qkvg, [h * dh, hk * dh, hk * dh, h],
                                   dim=-1)
         v = layers.transpose(by_head(v, hk), [0, 2, 1, 3])
@@ -216,20 +200,10 @@ def _attention(a, cfg: LagunaConfig, p: str, i: int):
             by_head(q, h), by_head(k, hk), theta=theta,
             rotary_dim=rotary_dim, layout="bthd", scaling=scaling)
     with fluid.name_scope("swa" if window else "core"):
-        helper = LayerHelper(f"{p}_attn_sdpa")
-        ctx = helper.create_variable_for_type_inference(dtype=a.dtype)
-        # logsumexp rows, consumed by the paired grad op
-        lse = helper.create_variable_for_type_inference(dtype="float32")
-        lse.stop_gradient = True
-        attrs = {"scale": 1.0 / math.sqrt(dh), "dropout_prob": 0.0,
-                 "is_test": True, "layout": "bhtd", "causal": True}
-        if window:
-            attrs["window"] = int(window)
-        helper.append_op(
-            "scaled_dot_product_attention",
-            # K and V keep their hk heads: the kernels read head q // (h / hk)
-            inputs={"Q": q, "K": k, "V": v},
-            outputs={"Out": ctx, "Lse": lse}, attrs=attrs)
+        # K and V keep their hk heads: the kernels read head q // (h / hk)
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, 1.0 / math.sqrt(dh), window=window,
+            name=f"{p}_attn_sdpa")
     with fluid.name_scope("gate"):
         # one value a head and position, float32 through the sigmoid,
         # broadcast over the head's dh features: [b, t, h] -> [b, h, t, 1]
@@ -239,32 +213,28 @@ def _attention(a, cfg: LagunaConfig, p: str, i: int):
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dh])
-        return _linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
-
-
-def _dense_mlp(z, cfg: LagunaConfig, p: str):
-    h = layers.elementwise_mul(
-        layers.silu(_linear(z, cfg.intermediate_size,
-                            f"{p}_mlp_gate_colp.w")),
-        _linear(z, cfg.intermediate_size, f"{p}_mlp_up_colp.w"))
-    return _linear(h, cfg.hidden_size, f"{p}_mlp_down_rowp.w")
+        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
 
 
 def decoder_layer(x, cfg: LagunaConfig, i: int):
     """(y, routing) of layer i: routing is None for a dense layer, else
     (balance loss, rows per held expert, experts chosen per token)."""
-    p = f"blk{i}"
+    p, eps = f"blk{i}", cfg.rms_norm_eps
     with fluid.name_scope(p):
         with fluid.name_scope("attn"):
-            a = _norm(x, cfg, f"{p}_attn_norm")
+            a = decoder.rms_norm(x, eps, f"{p}_attn_norm")
             x = layers.elementwise_add(x, _attention(a, cfg, p, i))
         if cfg.dense(i):
             with fluid.name_scope("mlp"):
-                z = _norm(x, cfg, f"{p}_mlp_norm")
-                return layers.elementwise_add(x, _dense_mlp(z, cfg, p)), None
+                out = decoder.swiglu_mlp(
+                    decoder.rms_norm(x, eps, f"{p}_mlp_norm"),
+                    cfg.intermediate_size, cfg.hidden_size,
+                    f"{p}_mlp_gate_colp.w", f"{p}_mlp_up_colp.w",
+                    f"{p}_mlp_down_rowp.w")
+                return layers.elementwise_add(x, out), None
         with fluid.name_scope("moe"):
             out, lb, _, rows, top_i = layers.topk_moe(
-                _norm(x, cfg, f"{p}_moe_norm"), cfg.num_experts,
+                decoder.rms_norm(x, eps, f"{p}_moe_norm"), cfg.num_experts,
                 cfg.num_experts_per_tok, cfg.moe_intermediate_size,
                 norm_topk_prob=True, name=f"{p}_moe", held=cfg.held_experts,
                 shared_d_ff=cfg.shared_expert_intermediate_size,
@@ -280,15 +250,9 @@ def build(cfg: Optional[LagunaConfig] = None, is_test: bool = False):
     is real: packed documents, attended across their boundaries). The
     graph has no dropout, so ``is_test`` changes nothing."""
     cfg = cfg or laguna_xs_2()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size],
-            param_attr=ParamAttr(
-                name="laguna_tok_emb.w",
-                initializer=NormalInitializer(0.0, EMBEDDING_INIT_STD)))
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size,
+                      "laguna_tok_emb.w", EMBEDDING_INIT_STD)
     lbs, rows, top_i = [], [], []
     for i in range(cfg.num_hidden_layers):
         x, routing = decoder_layer(x, cfg, i)
@@ -297,38 +261,24 @@ def build(cfg: Optional[LagunaConfig] = None, is_test: bool = False):
             rows.append(routing[1])
             top_i.append(routing[2])
     with fluid.name_scope("final_norm"):
-        x = _norm(x, cfg, "final_norm")
+        x = decoder.rms_norm(x, cfg.rms_norm_eps, "final_norm")
 
-    with fluid.name_scope("loss_head"):
-        logits = _linear(x, cfg.vocab_size, "lm_head_colp.w")
-        lm_loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
-        losses = [lm_loss]
-        lb_loss = None
-        if lbs:   # the sum over the expert layers, as DeepSeek-V3's
-            lb_loss = lbs[0] if len(lbs) == 1 else layers.sums(lbs)
-            losses.append(layers.scale(lb_loss,
-                                       scale=cfg.router_aux_loss_coef))
-        loss = layers.sums(losses) if len(losses) > 1 else lm_loss
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
+    logits, lm_loss = decoder.lm_head(x, lbl, cfg.vocab_size)
+    loss, lb_loss = lm_loss, None
+    if lbs:   # the sum over the expert layers, as DeepSeek-V3's
+        with fluid.name_scope("loss_head"):
+            lb_loss = decoder.sum_of(lbs)
+            loss = layers.sums([
+                lm_loss,
+                layers.scale(lb_loss, scale=cfg.router_aux_loss_coef)])
     return {
         "feeds": [ids, lbl],
         "loss": loss,
         "lm_loss": lm_loss,
         "lb_loss": lb_loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "expert_rows": rows,
         "top_i": top_i,
         "config": cfg,
     }
-
-
-def make_batch(cfg: LagunaConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

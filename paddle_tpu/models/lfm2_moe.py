@@ -57,14 +57,13 @@ branch's pre-norm lies in the branch's scope.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import Optional, Sequence, Tuple
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer, UniformInitializer
-from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.initializer import UniformInitializer
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 from paddle_tpu.param_attr import ParamAttr
 
 # logits of the last positions a build offers (model["last_logits"]):
@@ -163,26 +162,11 @@ def lfm2_24b_a2b() -> Lfm2MoeConfig:
     return Lfm2MoeConfig()
 
 
-def _w(name):
-    # HF's initializer_range, the table's too
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.norm_eps,
-                           param_attr=ParamAttr(name=f"{name}.scale"))
-
-
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _short_conv(n, cfg: Lfm2MoeConfig, p: str):
     """The gated short convolution of the normalised input n [b, t, d]."""
     d = cfg.hidden_size
     with fluid.name_scope("in_proj"):
-        bcu = _linear(n, 3 * d, f"{p}_sconv_in_colp.w")
+        bcu = decoder.linear(n, 3 * d, f"{p}_sconv_in_colp.w")
     with fluid.name_scope("gconv"):
         # torch's Conv1d default (HF's _init_weights re-draws Linear and
         # Embedding only): uniform(+-1 / sqrt(taps)), as the other
@@ -193,7 +177,7 @@ def _short_conv(n, cfg: Lfm2MoeConfig, p: str):
                 name=f"{p}_sconv_conv.w",
                 initializer=UniformInitializer(-bound, bound)))
     with fluid.name_scope("out_proj"):
-        return _linear(y, d, f"{p}_sconv_out_rowp.w")
+        return decoder.linear(y, d, f"{p}_sconv_out_rowp.w")
 
 
 def _attention(n, cfg: Lfm2MoeConfig, p: str):
@@ -206,41 +190,32 @@ def _attention(n, cfg: Lfm2MoeConfig, p: str):
         return layers.reshape(z, [0, 0, heads, dh])
 
     with fluid.name_scope("qkv"):
-        qkv = _linear(n, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
+        qkv = decoder.linear(n, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
         q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
         v = layers.transpose(by_head(v, hk), [0, 2, 1, 3])
     with fluid.name_scope("qk_norm"):
-        q = _norm(by_head(q, h), cfg, f"{p}_attn_qnorm")   # over each
-        k = _norm(by_head(k, hk), cfg, f"{p}_attn_knorm")  # head's dh
+        # over each head's dh
+        q = decoder.rms_norm(by_head(q, h), cfg.norm_eps, f"{p}_attn_qnorm")
+        k = decoder.rms_norm(by_head(k, hk), cfg.norm_eps, f"{p}_attn_knorm")
     with fluid.name_scope("rope"):
         # q and k where the projection left them: the op transposes as
         # it rotates
         q, k = layers.rotary_embedding(q, k, theta=cfg.rope_theta,
                                        layout="bthd")
     with fluid.name_scope("core"):
-        helper = LayerHelper(f"{p}_attn_sdpa")
-        ctx = helper.create_variable_for_type_inference(dtype=n.dtype)
-        # logsumexp rows, consumed by the paired grad op
-        lse = helper.create_variable_for_type_inference(dtype="float32")
-        lse.stop_gradient = True
-        helper.append_op(
-            "scaled_dot_product_attention",
-            # K and V keep their hk heads: the kernels read head q // (h / hk)
-            inputs={"Q": q, "K": k, "V": v},
-            outputs={"Out": ctx, "Lse": lse},
-            attrs={"scale": 1.0 / math.sqrt(dh), "dropout_prob": 0.0,
-                   "is_test": True, "layout": "bhtd", "causal": True})
+        # K and V keep their hk heads: the kernels read head q // (h / hk)
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, 1.0 / math.sqrt(dh), name=f"{p}_attn_sdpa")
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dh])
-        return _linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
 
 
 def _dense_ffn(n, cfg: Lfm2MoeConfig, p: str):
-    h = layers.elementwise_mul(
-        layers.silu(_linear(n, cfg.intermediate_size, f"{p}_ffn_w1_colp.w")),
-        _linear(n, cfg.intermediate_size, f"{p}_ffn_w3_colp.w"))
-    return _linear(h, cfg.hidden_size, f"{p}_ffn_w2_rowp.w")
+    return decoder.swiglu_mlp(
+        n, cfg.intermediate_size, cfg.hidden_size, f"{p}_ffn_w1_colp.w",
+        f"{p}_ffn_w3_colp.w", f"{p}_ffn_w2_rowp.w")
 
 
 def _moe(n, cfg: Lfm2MoeConfig, p: str):
@@ -260,11 +235,11 @@ def block(x, cfg: Lfm2MoeConfig, i: int, kind: str, dense: bool):
     routing = None
     with fluid.name_scope(p):
         with fluid.name_scope(kind):
-            n = _norm(x, cfg, f"{p}_op_norm")
+            n = decoder.rms_norm(x, cfg.norm_eps, f"{p}_op_norm")
             op = (_short_conv if kind == "sconv" else _attention)(n, cfg, p)
             x = layers.elementwise_add(x, op)
         with fluid.name_scope("ffn" if dense else "moe"):
-            n = _norm(x, cfg, f"{p}_ffn_norm")
+            n = decoder.rms_norm(x, cfg.norm_eps, f"{p}_ffn_norm")
             if dense:
                 out = _dense_ffn(n, cfg, p)
             else:
@@ -281,12 +256,9 @@ def build(cfg: Optional[Lfm2MoeConfig] = None, is_test: bool = False):
     boundaries, no reset of the taps). The graph has no dropout, so
     ``is_test`` changes nothing."""
     cfg = cfg or lfm2_24b_a2b()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                             param_attr=_w(TABLE))
+    ids, lbl = decoder.token_feeds()
+    # HF's initializer_range, the table's too
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size, TABLE)
     lbs, rows, top_i = [], [], []
     for i, kind, dense in cfg.blocks:
         x, routing = block(x, cfg, i, kind, dense)
@@ -295,38 +267,23 @@ def build(cfg: Optional[Lfm2MoeConfig] = None, is_test: bool = False):
             rows.append(routing[1])
             top_i.append(routing[2])
     with fluid.name_scope("final_norm"):
-        x = _norm(x, cfg, "final_norm")
+        x = decoder.rms_norm(x, cfg.norm_eps, "final_norm")
 
-    with fluid.name_scope("loss_head"):
-        # the tied table: the embedding's rows are the head's columns
-        table = fluid.default_main_program().global_block().var(TABLE)
-        logits = layers.matmul(x, table, transpose_y=True)
-        lm_loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
-        loss, lb_loss = lm_loss, None
-        if lbs:
-            lb_loss = lbs[0] if len(lbs) == 1 else layers.sums(lbs)
+    logits, lm_loss = decoder.tied_lm_head(x, lbl, TABLE)
+    loss, lb_loss = lm_loss, None
+    if lbs:
+        with fluid.name_scope("loss_head"):
+            lb_loss = decoder.sum_of(lbs)
             loss = layers.sums([
                 lm_loss, layers.scale(lb_loss, scale=cfg.balance_alpha)])
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
     return {
         "feeds": [ids, lbl],
         "loss": loss,
         "lm_loss": lm_loss,
         "lb_loss": lb_loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "expert_rows": rows,
         "top_i": top_i,
         "config": cfg,
     }
-
-
-def make_batch(cfg: Lfm2MoeConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

@@ -54,14 +54,13 @@ with ``qkv``, ``core`` (the sdpa op) and ``out``; ``final_norm``,
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
-
-import numpy as np
+from typing import Optional, Tuple
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer, UniformInitializer
-from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.initializer import UniformInitializer
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 from paddle_tpu.models.phi4flash import DtBiasInitializer
 from paddle_tpu.param_attr import ParamAttr
 
@@ -167,27 +166,14 @@ def nemotron_3_nano_30b_a3b() -> NemotronHConfig:
     return NemotronHConfig()
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.layer_norm_epsilon,
-                           param_attr=ParamAttr(name=f"{name}.scale"))
-
-
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _mamba2(u, cfg: NemotronHConfig, p: str):
     """The Mamba-2 mixer of the normalised input u [b, t, d]."""
     e, heads = cfg.mamba_d_inner, cfg.mamba_num_heads
     gn = cfg.n_groups * cfg.ssm_state_size
     with fluid.name_scope("proj"):
         z, xbc, dt = layers.split(
-            _linear(u, 2 * e + 2 * gn + heads, f"{p}_mamba_in_colp.w"),
+            decoder.linear(u, 2 * e + 2 * gn + heads,
+                           f"{p}_mamba_in_colp.w"),
             [e, e + 2 * gn, heads], dim=-1)
     with fluid.name_scope("conv"):
         # torch's Conv1d default (HF's _init_weights re-draws Linear and
@@ -219,7 +205,7 @@ def _mamba2(u, cfg: NemotronHConfig, p: str):
             group_size=e // cfg.n_groups,
             param_attr=ParamAttr(name=f"{p}_mamba_norm.scale"))
     with fluid.name_scope("out"):
-        return _linear(y, cfg.hidden_size, f"{p}_mamba_out_rowp.w")
+        return decoder.linear(y, cfg.hidden_size, f"{p}_mamba_out_rowp.w")
 
 
 def _attention(u, cfg: NemotronHConfig, p: str):
@@ -233,26 +219,17 @@ def _attention(u, cfg: NemotronHConfig, p: str):
                                 [0, 2, 1, 3])
 
     with fluid.name_scope("qkv"):
-        qkv = _linear(u, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
+        qkv = decoder.linear(u, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
         q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
         q, k, v = heads_first(q, h), heads_first(k, hk), heads_first(v, hk)
     with fluid.name_scope("core"):
-        helper = LayerHelper(f"{p}_attn_sdpa")
-        ctx = helper.create_variable_for_type_inference(dtype=u.dtype)
-        # logsumexp rows, consumed by the paired grad op
-        lse = helper.create_variable_for_type_inference(dtype="float32")
-        lse.stop_gradient = True
-        helper.append_op(
-            "scaled_dot_product_attention",
-            # K and V keep their hk heads: the kernels read head q // (h / hk)
-            inputs={"Q": q, "K": k, "V": v},
-            outputs={"Out": ctx, "Lse": lse},
-            attrs={"scale": 1.0 / math.sqrt(dh), "dropout_prob": 0.0,
-                   "is_test": True, "layout": "bhtd", "causal": True})
+        # K and V keep their hk heads: the kernels read head q // (h / hk)
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, 1.0 / math.sqrt(dh), name=f"{p}_attn_sdpa")
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dh])
-        return _linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
 
 
 def _moe(u, cfg: NemotronHConfig, p: str):
@@ -273,7 +250,7 @@ def block(x, cfg: NemotronHConfig, i: int, kind: str):
     routing = None
     with fluid.name_scope(p):
         with fluid.name_scope(kind):
-            u = _norm(x, cfg, f"{p}_norm")
+            u = decoder.rms_norm(x, cfg.layer_norm_epsilon, f"{p}_norm")
             if kind == "mamba2":
                 out = _mamba2(u, cfg, p)
             elif kind == "attn":
@@ -292,15 +269,9 @@ def build(cfg: Optional[NemotronHConfig] = None, is_test: bool = False):
     boundaries, no state reset). The graph has no dropout, so
     ``is_test`` changes nothing."""
     cfg = cfg or nemotron_3_nano_30b_a3b()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size],
-            param_attr=ParamAttr(
-                name="nemotronh_tok_emb.w",
-                initializer=NormalInitializer(0.0, cfg.embedding_init_std)))
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size,
+                      "nemotronh_tok_emb.w", cfg.embedding_init_std)
     lbs, rows, top_i = [], [], []
     for i, kind in cfg.blocks:
         x, routing = block(x, cfg, i, kind)
@@ -309,36 +280,23 @@ def build(cfg: Optional[NemotronHConfig] = None, is_test: bool = False):
             rows.append(routing[1])
             top_i.append(routing[2])
     with fluid.name_scope("final_norm"):
-        x = _norm(x, cfg, "final_norm")
+        x = decoder.rms_norm(x, cfg.layer_norm_epsilon, "final_norm")
 
-    with fluid.name_scope("loss_head"):
-        logits = _linear(x, cfg.vocab_size, "lm_head_colp.w")
-        lm_loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
-        loss, lb_loss = lm_loss, None
-        if lbs:
-            lb_loss = lbs[0] if len(lbs) == 1 else layers.sums(lbs)
+    logits, lm_loss = decoder.lm_head(x, lbl, cfg.vocab_size)
+    loss, lb_loss = lm_loss, None
+    if lbs:
+        with fluid.name_scope("loss_head"):
+            lb_loss = decoder.sum_of(lbs)
             loss = layers.sums([
                 lm_loss, layers.scale(lb_loss, scale=cfg.balance_alpha)])
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
     return {
         "feeds": [ids, lbl],
         "loss": loss,
         "lm_loss": lm_loss,
         "lb_loss": lb_loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "expert_rows": rows,
         "top_i": top_i,
         "config": cfg,
     }
-
-
-def make_batch(cfg: NemotronHConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

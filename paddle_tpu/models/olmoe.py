@@ -26,15 +26,12 @@ Name scopes (framework.name_scope; README "Names in the device trace"):
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional
-
-import numpy as np
+from typing import Optional
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer
-from paddle_tpu.layer_helper import LayerHelper
-from paddle_tpu.param_attr import ParamAttr
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 
 # logits of the last positions a build offers (model["last_logits"]): what
 # a comparison with a reference can hold at a 50k vocabulary
@@ -83,27 +80,14 @@ def olmoe_1b_7b() -> OlmoeConfig:
     return OlmoeConfig()
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps,
-                           param_attr=ParamAttr(name=f"{name}.scale"))
-
-
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _attention(x, cfg: OlmoeConfig, p: str):
     h, dh, d = cfg.num_attention_heads, cfg.head_dim, cfg.hidden_size
-    qkv = _linear(_norm(x, cfg, f"{p}_attn_norm"), 3 * d,
-                  f"{p}_attn_qkv_colp.w")
+    eps = cfg.rms_norm_eps
+    qkv = decoder.linear(decoder.rms_norm(x, eps, f"{p}_attn_norm"), 3 * d,
+                         f"{p}_attn_qkv_colp.w")
     q, k, v = layers.split(qkv, 3, dim=-1)
-    q = _norm(q, cfg, f"{p}_attn_qnorm")
-    k = _norm(k, cfg, f"{p}_attn_knorm")
+    q = decoder.rms_norm(q, eps, f"{p}_attn_qnorm")
+    k = decoder.rms_norm(k, eps, f"{p}_attn_knorm")
 
     def by_head(z):   # [b, t, d] -> [b, t, h, dh]
         return layers.reshape(z, [0, 0, h, dh])
@@ -114,26 +98,16 @@ def _attention(x, cfg: OlmoeConfig, p: str):
     # q and k where the norms left them: the op transposes as it rotates
     q, k = layers.rotary_embedding(by_head(q), by_head(k),
                                    theta=cfg.rope_theta, layout="bthd")
-    helper = LayerHelper(f"{p}_attn_sdpa")
-    ctx = helper.create_variable_for_type_inference(dtype=x.dtype)
-    # logsumexp rows, consumed by the paired grad op (DCE'd at inference)
-    lse = helper.create_variable_for_type_inference(dtype="float32")
-    lse.stop_gradient = True
-    helper.append_op(
-        "scaled_dot_product_attention",
-        inputs={"Q": q, "K": k, "V": heads(v)},
-        outputs={"Out": ctx, "Lse": lse},
-        # packed sequences: every position real, the causal mask rides
-        # in the kernel and no bias tensor exists
-        attrs={"scale": 1.0 / math.sqrt(dh), "dropout_prob": 0.0,
-               "is_test": True, "layout": "bhtd", "causal": True})
+    ctx = layers.scaled_dot_product_attention(
+        q, k, heads(v), 1.0 / math.sqrt(dh), name=f"{p}_attn_sdpa")
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]), [0, 0, d])
-    return _linear(ctx, d, f"{p}_attn_out_rowp.w")
+    return decoder.linear(ctx, d, f"{p}_attn_out_rowp.w")
 
 
 def _moe(x, cfg: OlmoeConfig, p: str):
     return layers.topk_moe(
-        _norm(x, cfg, f"{p}_moe_norm"), cfg.num_experts,
+        decoder.rms_norm(x, cfg.rms_norm_eps, f"{p}_moe_norm"),
+        cfg.num_experts,
         cfg.num_experts_per_tok, cfg.intermediate_size,
         norm_topk_prob=cfg.norm_topk_prob, name=f"{p}_moe")
 
@@ -152,8 +126,7 @@ def decoder_block(x, cfg: OlmoeConfig, i: int):
 
 
 def _mean_of(xs):
-    total = xs[0] if len(xs) == 1 else layers.sums(xs)
-    return layers.scale(total, scale=1.0 / len(xs))
+    return layers.scale(decoder.sum_of(xs), scale=1.0 / len(xs))
 
 
 def build(cfg: Optional[OlmoeConfig] = None, is_test: bool = False):
@@ -162,31 +135,24 @@ def build(cfg: Optional[OlmoeConfig] = None, is_test: bool = False):
     is real: packed documents, attended across their boundaries). The
     graph has no dropout, so ``is_test`` changes nothing."""
     cfg = cfg or olmoe_1b_7b()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                             param_attr=_w("olmoe_tok_emb.w"))
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size,
+                      "olmoe_tok_emb.w")
     routing = []
     for i in range(cfg.num_hidden_layers):
         x, *r = decoder_block(x, cfg, i)
         routing.append(r)
     lbs, zs, rows, top_i = (list(col) for col in zip(*routing))
     with fluid.name_scope("final_norm"):
-        x = _norm(x, cfg, "final_norm")
+        x = decoder.rms_norm(x, cfg.rms_norm_eps, "final_norm")
 
+    logits, lm_loss = decoder.lm_head(x, lbl, cfg.vocab_size)
     with fluid.name_scope("loss_head"):
-        logits = _linear(x, cfg.vocab_size, "lm_head_colp.w")
-        lm_loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
         lb_loss, z_loss = _mean_of(lbs), _mean_of(zs)
         loss = layers.sums([
             lm_loss,
             layers.scale(lb_loss, scale=cfg.router_aux_loss_coef),
             layers.scale(z_loss, scale=cfg.router_z_loss_coef)])
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
     return {
         "feeds": [ids, lbl],
         "loss": loss,
@@ -194,17 +160,8 @@ def build(cfg: Optional[OlmoeConfig] = None, is_test: bool = False):
         "lb_loss": lb_loss,
         "z_loss": z_loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "expert_rows": rows,
         "top_i": top_i,
         "config": cfg,
     }
-
-
-def make_batch(cfg: OlmoeConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

@@ -64,13 +64,11 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
-import numpy as np
-
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import (Initializer, NormalInitializer,
-                                    UniformInitializer)
-from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.initializer import Initializer, UniformInitializer
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 from paddle_tpu.param_attr import ParamAttr
 
 # logits of the last positions a build offers (model["last_logits"]):
@@ -187,10 +185,6 @@ class DtBiasInitializer(Initializer):
                             outputs={"Out": var.name}, attrs=attrs)
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
 def _norm(x, cfg, name):
     return layers.layer_norm(
         x, begin_norm_axis=2, epsilon=cfg.layer_norm_eps,
@@ -200,7 +194,7 @@ def _norm(x, cfg, name):
 
 def _linear(x, size, name, bias=False):
     return layers.fc(
-        x, size, num_flatten_dims=2, param_attr=_w(name + ".w"),
+        x, size, num_flatten_dims=2, param_attr=decoder.weight(name + ".w"),
         bias_attr=ParamAttr(name=name + ".b") if bias else False)
 
 
@@ -252,24 +246,6 @@ def _gmu(u, memory, cfg: Phi4FlashConfig, p: str):
                    f"{p}_gmu_out_rowp")
 
 
-def _sdpa(q, k, v, cfg, p, j, window):
-    helper = LayerHelper(f"{p}_attn_sdpa{j}")
-    ctx = helper.create_variable_for_type_inference(dtype=q.dtype)
-    # logsumexp rows, consumed by the paired grad op
-    lse = helper.create_variable_for_type_inference(dtype="float32")
-    lse.stop_gradient = True
-    attrs = {"scale": 1.0 / math.sqrt(cfg.head_dim), "dropout_prob": 0.0,
-             "is_test": True, "layout": "bhtd", "causal": True}
-    if window:
-        attrs["window"] = int(window)
-    helper.append_op(
-        "scaled_dot_product_attention",
-        # K and V keep their heads: the kernels read head q // group
-        inputs={"Q": q, "K": k, "V": v},
-        outputs={"Out": ctx, "Lse": lse}, attrs=attrs)
-    return ctx
-
-
 def _attention(u, cfg: Phi4FlashConfig, p: str, i: int, kind: str, shared):
     """Differential attention of the normalised input u [b, t, d];
     ``shared``: the key/value source's (k1, k2, V) for a cross layer.
@@ -298,8 +274,13 @@ def _attention(u, cfg: Phi4FlashConfig, p: str, i: int, kind: str, shared):
     window = cfg.sliding_window if kind == "swa" else None
     with fluid.name_scope({"swa": "swa", "full": "core",
                            "cross": "cross"}[kind]):
-        o1 = _sdpa(q1, k1, v, cfg, p, 1, window)
-        o2 = _sdpa(q2, k2, v, cfg, p, 2, window)
+        def attend(j, q, k):
+            # K and V keep their heads: the kernels read head q // group
+            return layers.scaled_dot_product_attention(
+                q, k, v, 1.0 / math.sqrt(dh), window=window,
+                name=f"{p}_attn_sdpa{j}")
+
+        o1, o2 = attend(1, q1, k1), attend(2, q2, k2)
     with fluid.name_scope("diff"):
         o = layers.diff_attention_combine(
             o1, o2, lambda_init(i), dh, epsilon=cfg.layer_norm_eps,
@@ -360,44 +341,22 @@ def build(cfg: Optional[Phi4FlashConfig] = None, is_test: bool = False):
     ``resid_pdrop`` are 0 as published), so ``is_test`` changes
     nothing."""
     cfg = cfg or phi4_mini_flash()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size],
-            param_attr=ParamAttr(
-                name=TABLE,
-                initializer=NormalInitializer(0.0, EMBEDDING_INIT_STD)))
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size, TABLE,
+                      EMBEDDING_INIT_STD)
     shared: Dict = {}
     for i, kind in layer_kinds(cfg):
         x = decoder_layer(x, cfg, i, kind, shared)
     with fluid.name_scope("final_norm"):
         x = _norm(x, cfg, "final_norm")
 
-    with fluid.name_scope("loss_head"):
-        # the tied table: the embedding's rows are the head's columns
-        table = fluid.default_main_program().global_block().var(TABLE)
-        logits = layers.matmul(x, table, transpose_y=True)
-        loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
+    logits, loss = decoder.tied_lm_head(x, lbl, TABLE)
     return {
         "feeds": [ids, lbl],
         "loss": loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "memory": shared.get("memory"),
         "kv": list(shared["kv"]) if "kv" in shared else None,
         "config": cfg,
     }
-
-
-def make_batch(cfg: Phi4FlashConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

@@ -45,14 +45,12 @@ names itself and a reader of the trace stops at it), ``blk<i>/attn``,
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
-
-import numpy as np
+from typing import Optional, Tuple
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer
-from paddle_tpu.layer_helper import LayerHelper
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 from paddle_tpu.param_attr import ParamAttr
 
 # logits of the last positions a build offers (model["last_logits"]): 64
@@ -132,25 +130,16 @@ def qwen3_next_80b_a3b() -> Qwen3NextConfig:
     return Qwen3NextConfig()
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
 def _norm(x, cfg, name):
     return layers.rms_norm(x, epsilon=cfg.rms_norm_eps, zero_centered=True,
                            param_attr=ParamAttr(name=f"{name}.scale"))
 
 
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _attention(x, cfg: Qwen3NextConfig, p: str):
     h, hk, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
-    qgkv = _linear(_norm(x, cfg, f"{p}_attn_norm"), 2 * (h + hk) * dh,
-                   f"{p}_attn_qgkv_colp.w")
+    qgkv = decoder.linear(_norm(x, cfg, f"{p}_attn_norm"),
+                          2 * (h + hk) * dh, f"{p}_attn_qgkv_colp.w")
     qg, k, v = layers.split(qgkv, [2 * h * dh, hk * dh, hk * dh], dim=-1)
     # per head: the query's dh features, then its gate's
     q, gate = layers.split(layers.reshape(qg, [0, 0, h, 2 * dh]), 2, dim=-1)
@@ -164,22 +153,13 @@ def _attention(x, cfg: Qwen3NextConfig, p: str):
     q, k = layers.rotary_embedding(
         heads_first(q), heads_first(k), theta=cfg.rope_theta,
         rotary_dim=cfg.rotary_dim)
-    helper = LayerHelper(f"{p}_attn_sdpa")
-    ctx = helper.create_variable_for_type_inference(dtype=x.dtype)
-    # logsumexp rows, consumed by the paired grad op (DCE'd at inference)
-    lse = helper.create_variable_for_type_inference(dtype="float32")
-    lse.stop_gradient = True
-    helper.append_op(
-        "scaled_dot_product_attention",
-        # K and V keep their hk heads: the kernels read head q // (h / hk)
-        inputs={"Q": q, "K": k, "V": heads_first(v)},
-        outputs={"Out": ctx, "Lse": lse},
-        attrs={"scale": 1.0 / math.sqrt(dh), "dropout_prob": 0.0,
-               "is_test": True, "layout": "bhtd", "causal": True})
+    # K and V keep their hk heads: the kernels read head q // (h / hk)
+    ctx = layers.scaled_dot_product_attention(
+        q, k, heads_first(v), 1.0 / math.sqrt(dh), name=f"{p}_attn_sdpa")
     ctx = layers.elementwise_mul(layers.transpose(ctx, [0, 2, 1, 3]),
                                  layers.sigmoid(gate))
-    return _linear(layers.reshape(ctx, [0, 0, h * dh]), cfg.hidden_size,
-                   f"{p}_attn_out_rowp.w")
+    return decoder.linear(layers.reshape(ctx, [0, 0, h * dh]),
+                          cfg.hidden_size, f"{p}_attn_out_rowp.w")
 
 
 def _delta_net(x, cfg: Qwen3NextConfig, p: str):
@@ -188,13 +168,14 @@ def _delta_net(x, cfg: Qwen3NextConfig, p: str):
     kd, vd = hk * dk, hv * dv
     xn = _norm(x, cfg, f"{p}_gdn_norm")
     with fluid.name_scope("proj"):
-        qkvz = _linear(xn, 2 * kd + 2 * vd, f"{p}_gdn_qkvz_colp.w")
-        b, a = layers.split(_linear(xn, 2 * hv, f"{p}_gdn_ba.w"), 2, dim=-1)
+        qkvz = decoder.linear(xn, 2 * kd + 2 * vd, f"{p}_gdn_qkvz_colp.w")
+        b, a = layers.split(decoder.linear(xn, 2 * hv, f"{p}_gdn_ba.w"), 2,
+                            dim=-1)
         qkv, z = layers.split(qkvz, [2 * kd + vd, vd], dim=-1)
     with fluid.name_scope("conv"):
         qkv = layers.causal_conv1d(
             qkv, taps=cfg.linear_conv_kernel_dim, act="silu",
-            param_attr=_w(f"{p}_gdn_conv.w"))
+            param_attr=decoder.weight(f"{p}_gdn_conv.w"))
         q, k, v = layers.split(qkv, [kd, kd, vd], dim=-1)
     with fluid.name_scope("rule"):
         beta, g = layers.gdn_gates(
@@ -210,8 +191,8 @@ def _delta_net(x, cfg: Qwen3NextConfig, p: str):
             o, layers.reshape(z, [0, 0, hv, dv]), epsilon=cfg.rms_norm_eps,
             param_attr=ParamAttr(name=f"{p}_gdn_onorm.scale"))
     with fluid.name_scope("out"):
-        return _linear(layers.reshape(o, [0, 0, vd]), cfg.hidden_size,
-                       f"{p}_gdn_out_rowp.w")
+        return decoder.linear(layers.reshape(o, [0, 0, vd]),
+                              cfg.hidden_size, f"{p}_gdn_out_rowp.w")
 
 
 def _moe(x, cfg: Qwen3NextConfig, p: str):
@@ -246,12 +227,9 @@ def build(cfg: Optional[Qwen3NextConfig] = None, is_test: bool = False):
     is real: packed documents, attended across their boundaries). The
     graph has no dropout, so ``is_test`` changes nothing."""
     cfg = cfg or qwen3_next_80b_a3b()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
-                             param_attr=_w("qwen3next_tok_emb.w"))
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size,
+                      "qwen3next_tok_emb.w")
     lbs, rows, top_i = [], [], []
     for i in range(cfg.num_hidden_layers):
         x, lb, r, ti = decoder_layer(x, cfg, i)
@@ -261,33 +239,19 @@ def build(cfg: Optional[Qwen3NextConfig] = None, is_test: bool = False):
     with fluid.name_scope("final_norm"):
         x = _norm(x, cfg, "final_norm")
 
+    logits, lm_loss = decoder.lm_head(x, lbl, cfg.vocab_size)
     with fluid.name_scope("loss_head"):
-        logits = _linear(x, cfg.vocab_size, "lm_head_colp.w")
-        lm_loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
-        lb_loss = layers.scale(lbs[0] if len(lbs) == 1 else layers.sums(lbs),
-                               scale=1.0 / len(lbs))
+        lb_loss = layers.scale(decoder.sum_of(lbs), scale=1.0 / len(lbs))
         loss = layers.sums([
             lm_loss, layers.scale(lb_loss, scale=cfg.router_aux_loss_coef)])
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
     return {
         "feeds": [ids, lbl],
         "loss": loss,
         "lm_loss": lm_loss,
         "lb_loss": lb_loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "expert_rows": rows,
         "top_i": top_i,
         "config": cfg,
     }
-
-
-def make_batch(cfg: Qwen3NextConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

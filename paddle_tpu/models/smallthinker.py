@@ -40,15 +40,12 @@ under ``swa`` in a window layer and under ``core`` in a global one, and
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import Optional, Sequence, Tuple
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer
-from paddle_tpu.layer_helper import LayerHelper
-from paddle_tpu.param_attr import ParamAttr
+from paddle_tpu.models import decoder
+from paddle_tpu.models.decoder import make_batch  # noqa: F401
 
 # logits of the last positions a build offers (model["last_logits"]):
 # 64 of one row (perf/reference/smallthinker.py says why)
@@ -120,20 +117,6 @@ def smallthinker_21b_a3b() -> SmallThinkerConfig:
     return SmallThinkerConfig()
 
 
-def _w(name):
-    return ParamAttr(name=name, initializer=NormalInitializer(0.0, 0.02))
-
-
-def _norm(x, cfg, name):
-    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps,
-                           param_attr=ParamAttr(name=f"{name}.scale"))
-
-
-def _linear(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2, param_attr=_w(name),
-                     bias_attr=False)
-
-
 def _attention(a, cfg: SmallThinkerConfig, p: str, i: int):
     """Attn_i of the normalised input ``a`` [b, t, d]."""
     h, hk, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -147,7 +130,7 @@ def _attention(a, cfg: SmallThinkerConfig, p: str, i: int):
         return layers.transpose(by_head(z, n), [0, 2, 1, 3])
 
     with fluid.name_scope("qkv"):
-        qkv = _linear(a, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
+        qkv = decoder.linear(a, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
         q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
         v = heads_first(v, hk)
         if not cfg.rotates(i):
@@ -160,37 +143,28 @@ def _attention(a, cfg: SmallThinkerConfig, p: str, i: int):
                                            theta=cfg.rope_theta,
                                            layout="bthd")
     with fluid.name_scope("swa" if window else "core"):
-        helper = LayerHelper(f"{p}_attn_sdpa")
-        ctx = helper.create_variable_for_type_inference(dtype=a.dtype)
-        # logsumexp rows, consumed by the paired grad op
-        lse = helper.create_variable_for_type_inference(dtype="float32")
-        lse.stop_gradient = True
-        attrs = {"scale": 1.0 / math.sqrt(dh), "dropout_prob": 0.0,
-                 "is_test": True, "layout": "bhtd", "causal": True}
-        if window:
-            attrs["window"] = int(window)
-        helper.append_op(
-            "scaled_dot_product_attention",
-            # K and V keep their hk heads: the kernels read head q // (h / hk)
-            inputs={"Q": q, "K": k, "V": v},
-            outputs={"Out": ctx, "Lse": lse}, attrs=attrs)
+        # K and V keep their hk heads: the kernels read head q // (h / hk)
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, 1.0 / math.sqrt(dh), window=window,
+            name=f"{p}_attn_sdpa")
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dh])
-        return _linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
 
 
 def decoder_layer(x, cfg: SmallThinkerConfig, i: int):
     """(y, load-balancing loss, rows per held expert, experts chosen per
     token) of layer i."""
-    p = f"blk{i}"
+    p, eps = f"blk{i}", cfg.rms_norm_eps
     with fluid.name_scope(p):
         with fluid.name_scope("attn"):
-            a = _norm(x, cfg, f"{p}_attn_norm")
+            a = decoder.rms_norm(x, eps, f"{p}_attn_norm")
             x = layers.elementwise_add(x, _attention(a, cfg, p, i))
         with fluid.name_scope("moe"):
             out, lb, _, rows, top_i = layers.topk_moe(
-                _norm(x, cfg, f"{p}_moe_norm"), cfg.moe_num_primary_experts,
+                decoder.rms_norm(x, eps, f"{p}_moe_norm"),
+                cfg.moe_num_primary_experts,
                 cfg.moe_num_active_primary_experts, cfg.moe_ffn_hidden_size,
                 norm_topk_prob=cfg.norm_topk_prob, name=f"{p}_moe",
                 held=cfg.held_experts, act="relu", router_input=a)
@@ -204,15 +178,9 @@ def build(cfg: Optional[SmallThinkerConfig] = None, is_test: bool = False):
     is real: packed documents, attended across their boundaries). The
     graph has no dropout, so ``is_test`` changes nothing."""
     cfg = cfg or smallthinker_21b_a3b()
-    ids = layers.data("input_ids", shape=[-1], dtype="int64")
-    lbl = layers.data("labels", shape=[-1], dtype="int64")
-
-    with fluid.name_scope("embed"):
-        x = layers.embedding(
-            ids, size=[cfg.vocab_size, cfg.hidden_size],
-            param_attr=ParamAttr(
-                name="smallthinker_tok_emb.w",
-                initializer=NormalInitializer(0.0, EMBEDDING_INIT_STD)))
+    ids, lbl = decoder.token_feeds()
+    x = decoder.embed(ids, cfg.vocab_size, cfg.hidden_size,
+                      "smallthinker_tok_emb.w", EMBEDDING_INIT_STD)
     lbs, rows, top_i = [], [], []
     for i in range(cfg.num_hidden_layers):
         x, lb, r, ti = decoder_layer(x, cfg, i)
@@ -220,35 +188,21 @@ def build(cfg: Optional[SmallThinkerConfig] = None, is_test: bool = False):
         rows.append(r)
         top_i.append(ti)
     with fluid.name_scope("final_norm"):
-        x = _norm(x, cfg, "final_norm")
+        x = decoder.rms_norm(x, cfg.rms_norm_eps, "final_norm")
 
+    logits, lm_loss = decoder.lm_head(x, lbl, cfg.vocab_size)
     with fluid.name_scope("loss_head"):
-        logits = _linear(x, cfg.vocab_size, "lm_head_colp.w")
-        lm_loss = layers.mean(layers.softmax_with_cross_entropy(
-            logits, layers.unsqueeze(lbl, [2])))
-        lb_loss = layers.scale(lbs[0] if len(lbs) == 1 else layers.sums(lbs),
-                               scale=1.0 / len(lbs))
+        lb_loss = layers.scale(decoder.sum_of(lbs), scale=1.0 / len(lbs))
         loss = layers.sums([
             lm_loss, layers.scale(lb_loss, scale=cfg.router_aux_loss_coef)])
-        last = layers.slice(logits, axes=[1], starts=[-LAST_POSITIONS],
-                            ends=[2 ** 31 - 1])
     return {
         "feeds": [ids, lbl],
         "loss": loss,
         "lm_loss": lm_loss,
         "lb_loss": lb_loss,
         "logits": logits,
-        "last_logits": last,
+        "last_logits": decoder.last_logits(logits, LAST_POSITIONS),
         "expert_rows": rows,
         "top_i": top_i,
         "config": cfg,
     }
-
-
-def make_batch(cfg: SmallThinkerConfig, batch: int, seq_len: int,
-               seed: int = 0) -> Dict[str, np.ndarray]:
-    """Packed tokens: ``seq_len + 1`` of them a row, inputs the first
-    ``seq_len``, labels the same shifted by one."""
-    r = np.random.RandomState(seed)
-    toks = r.randint(0, cfg.vocab_size, (batch, seq_len + 1)).astype(np.int64)
-    return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}

@@ -1904,8 +1904,7 @@ def _oom_counter():
 def is_oom_error(exc) -> bool:
     """Whether ``exc`` is a device out-of-memory failure — jax surfaces
     OOM as XlaRuntimeError text, not a dedicated type, so this is a
-    message heuristic (the single copy: bench_common's OOM backoff
-    delegates here)."""
+    message heuristic (the single copy)."""
     msg = f"{type(exc).__name__}: {exc}"
     return "RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
 

@@ -70,9 +70,8 @@ from paddle_tpu import monitor as _monitor
 # backend peaks + ridge point
 # ---------------------------------------------------------------------------
 
-# Peak dense-matmul FLOP/s (bf16) per v5e chip — THE single definition:
-# bench_common.mfu reads it through backend_peaks, so the bench tables
-# and the roofline verdicts share one denominator.
+# Peak dense-matmul FLOP/s (bf16) per v5e chip — THE single definition
+# in the program (perf/peaks.json is the benchmark's own table).
 V5E_PEAK_BF16 = 197e12
 
 # device_kind (the string jax reports for the device) -> (peak FLOP/s,

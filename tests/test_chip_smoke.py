@@ -1,10 +1,8 @@
 """chip_smoke.py off the chip: its phases driven at a tiny config on the
 CPU (so the script cannot rot between chip runs), its refusal to pass
 without a TPU, and the fallbacks this bring-up removed — decorative
-places, a cache dir forced in code, a default peaks row, a bench parent
-that holds the chip and shrugs off a failed child."""
+places, a cache dir forced in code, a default peaks row."""
 
-import json
 import os
 import subprocess
 import sys
@@ -13,7 +11,6 @@ import jax
 import numpy as np
 import pytest
 
-import bench
 import chip_smoke
 import paddle_tpu as fluid
 from paddle_tpu import flags, jax_cache, monitor, roofline
@@ -478,37 +475,6 @@ def test_jax_cache_placement(case, tmp_path):
         assert used == after == jax_cache.JAX_CACHE_DIR
         assert used == os.path.join(REPO, ".cache", "jax")
         assert threshold == 1.0
-
-
-def test_bench_parent_is_jax_free_when_it_launches_children():
-    """The launcher must never touch jax (the chip belongs to whichever
-    process does): checked from inside a child it launched."""
-    probe = ("import json, sys, os; "
-             "print(json.dumps({'metric': 'probe', 'value': 1}))")
-    code = (
-        "import sys, bench\n"
-        f"rows = [('headline', [sys.executable, '-c', {probe!r}], {{}})]\n"
-        "rc = bench.main(rows)\n"
-        "assert 'jax' not in sys.modules, 'bench parent imported jax'\n"
-        "assert 'paddle_tpu' not in sys.modules\n"
-        "sys.exit(rc)\n")
-    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
-                         capture_output=True, text=True, timeout=120)
-    assert out.returncode == 0, out.stderr[-800:]
-    assert json.loads(out.stdout.strip().splitlines()[-1])["value"] == 1
-
-
-def test_bench_exits_nonzero_when_a_child_fails(capsys):
-    ok = [sys.executable, "-c",
-          "print('{\"metric\": \"m\", \"value\": 2}')"]
-    boom = [sys.executable, "-c", "import sys; sys.exit(3)"]
-    mute = [sys.executable, "-c", "print('no row here')"]
-    assert bench.main([("headline", ok, {})]) == 0
-    assert bench.main([("headline", ok, {}), ("resnet50", boom, {})]) == 1
-    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert row["value"] == 2 and row["resnet50"] is None
-    assert bench.main([("headline", mute, {})]) == 1
-    capsys.readouterr()
 
 
 SSM_TINY = dict(
