@@ -118,10 +118,13 @@ def attention_dispatch():
     the tile of a family that picks one by the shape: "bhtd fwd <shape>
     [hb1 bq512 bk512]", a backward row of that family also whether it is
     one call or the pair and the sub-tiles it walks its edge blocks in:
-    "... form=fused edge=256x256" (pt_attention_dispatch_total)."""
+    "... form=fused edge=256x256", a forward row the layout in which its
+    logsumexp leaves the kernel: "... stats=rows"
+    (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
-    return attention_ops.dispatch_counts(tiles=True, forms=True, edges=True)
+    return attention_ops.dispatch_counts(tiles=True, forms=True, edges=True,
+                                         stats=True)
 
 
 # (t, window) of the decoder cells' BHTD calls, all on hb1 bq512 bk512
@@ -157,6 +160,16 @@ def _one_backward_call(attn):
     check(rows and all(" form=fused" in k for k in rows),
           f"expected every bhtd backward call as one fused kernel "
           f"(form=fused), none split: {attn}")
+
+
+def _statistics_in_rows(attn):
+    """Every BHTD forward row of a lowered cell hands the backward its
+    logsumexp as [b, h, 1, t] rows (flash_attention.bhtd_stats_form),
+    none as the column the chip pads to 512 bytes a position."""
+    rows = [k for k in attn if k.startswith("bhtd fwd ")]
+    check(rows and all(k.endswith(" stats=rows") for k in rows),
+          f"expected every bhtd forward call to write its logsumexp as "
+          f"rows (stats=rows), none as the column: {attn}")
 
 
 def _dispatch_since(before, read=attention_dispatch):
@@ -705,6 +718,7 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
         f"expected one bhtd attention call each way at {kv} with its tile: "
         f"{attn}")
     _one_backward_call(attn)
+    _statistics_in_rows(attn)
     n_layers = cfg.num_hidden_layers
     check(sum(gmm.values()) == 9 * n_layers and all(
         "[tm128 " in k for k in gmm),
@@ -871,6 +885,7 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
             f"expected {blocks} bhtd attention calls {direction} at "
             f"dk{dk} dv{dv} with their tile, none dense: {attn}")
     _one_backward_call(attn)
+    _statistics_in_rows(attn)
     want = (f"score=sigmoid bias=1 k={cfg.num_experts_per_tok} "
             f"experts={cfg.n_routed_experts}")
     # (a router's grad op runs it again: a row counts both lowerings)
@@ -1167,6 +1182,7 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
               f"expected the window layer's two calls {direction} with "
               f"their window: {attn}")
     _one_backward_call(attn)
+    _statistics_in_rows(attn)
 
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(7)
@@ -1324,6 +1340,7 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
             f"{cfg.num_attention_heads} / {cfg.num_key_value_heads} heads, "
             f"none dense: {attn}")
     _one_backward_call(attn)
+    _statistics_in_rows(attn)
     check(sum(gmms.values()) == 6 * n_moe and all(
         k.endswith("]") for k in gmms),
         f"expected {6 * n_moe} grouped matmuls (two matrices an expert, "
@@ -1492,6 +1509,7 @@ def sconv_phase(seq=8192, t_check=2048, **overrides):
             f"{cfg.num_attention_heads} / {cfg.num_key_value_heads} heads "
             f"of {cfg.head_dim}, none dense: {attn}")
     _one_backward_call(attn)
+    _statistics_in_rows(attn)
     check(sum(ropes.values()) == 2 * n_attn,
           f"expected {n_attn} rotary embedding each way: {ropes}")
 

@@ -44,7 +44,12 @@ _M_DISPATCH = _monitor.counter(
     "sub-tiles in which the call walks the blocks its diagonal or its "
     "band's far edge crosses, \"256x256\" on a fused bwd row of blocks "
     "of 512 (flash_attention.bhtd_edge_tile), \"\" where it works on "
-    "them whole (every fwd row)")
+    "them whole (every fwd row). A fwd row of family bhtd carries stats: "
+    "the layout in which attn.bhtd.fwd writes the call's logsumexp for "
+    "the backward, rows ([b, h, 1, tq], four bytes a position, what "
+    "attn.bhtd.bwd reads) or column ([b, h, tq, 1], which the chip pads "
+    "to a lane tile of 512 bytes a position and XLA copies back into "
+    "rows), flash_attention.bhtd_stats_form's answer for the tile")
 
 
 def _windowed(attrs, q, k, bthd, ring):
@@ -71,7 +76,7 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
     b, tq, tk, h, dh = dims[:5]
     # (behind them, where the call is not plain: key/value heads, dv)
     hk, dv = dims[5:] if len(dims) > 5 else (h, dh)
-    tile, edge = "", None
+    tile, edge, stats = "", None, None
     if family == "bhtd":
         # the kernel layer's own answers for the call the op hands it
         # (the op passes no q_block / k_block)
@@ -80,6 +85,8 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         picked = fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk, dv=dv)
         tile = fa.tile_label(picked)
         edge = fa.edge_label(fa.bhtd_edge_tile(picked, causal, form))
+        if direction == "fwd":
+            stats = fa.bhtd_stats_form(picked, tq)
     # (grouped-query attention names its key/value heads: "h16 kv2";
     # values narrower than queries and keys both widths: "dk192 dv128")
     heads = f"h{h}" if hk == h else f"h{h} kv{hk}"
@@ -96,17 +103,21 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         labels["form"] = form
     if edge is not None:
         labels["edge"] = edge
+    if stats is not None:
+        labels["stats"] = stats
     _M_DISPATCH.inc(labels=labels)
 
 
-def dispatch_counts(tiles=False, forms=False, edges=False):
+def dispatch_counts(tiles=False, forms=False, edges=False, stats=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
     run print it. ``tiles``: a row whose family tiles by the shape names
     its tile too, "bhtd fwd <shape> [hb1 bq512 bk512]". ``forms``: a
     backward row of that family says whether it is one call or the
     pair, "bhtd bwd <shape> form=fused". ``edges``: a row whose edge
-    blocks are walked in sub-tiles names them, "... edge=256x256"."""
+    blocks are walked in sub-tiles names them, "... edge=256x256".
+    ``stats``: a forward row of that family says in which layout the
+    call's logsumexp leaves the kernel, "... stats=rows"."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
@@ -119,6 +130,8 @@ def dispatch_counts(tiles=False, forms=False, edges=False):
             name += f" form={lb['form']}"
         if edges and lb.get("edge"):
             name += f" edge={lb['edge']}"
+        if stats and lb.get("stats"):
+            name += f" stats={lb['stats']}"
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 

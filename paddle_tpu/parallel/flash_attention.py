@@ -19,7 +19,19 @@ sequence blocks of 128 x 128, 128 FLOPs per byte fetched against the v5e's
 ridge of 240.
 
 - Forward: k-blocks inner; running (m, l, acc) in VMEM scratch across
-  the k-block loop; emits the output AND the logsumexp rows.
+  the k-block loop; emits the output AND the logsumexp. The logsumexp
+  crosses HBM in the layout its consumer reads: [b, h, 1, tq] float32
+  ROWS (``bhtd_stats_form``), four bytes a position, cut by the ONE
+  backward call into [1, bq] blocks along the lanes. A [.., tq, 1]
+  float32 COLUMN is tiled (8, 128) on the chip, so each row's one value
+  owns a lane tile of 512 bytes: a q block of 512 rows writes 256 KB
+  of it (as much as the step's K and V blocks together), the residual
+  lives to the backward at 128 times its bytes (201 MB a layer at 48
+  heads x 8192), and XLA copies it back into rows in front of the
+  backward. So the column is written only where a q block cannot be
+  cut from a row (a caller's ``q_block`` of 64).
+  ``flash_attention_fwd`` RETURNS [b, h, tq, 1] either way: a reshape,
+  which folds against the backward's inside one jit.
 - Backward: recompute p = exp(s - lse) per block (no stored attention),
   using the standard delta = rowsum(do * o) reduction. ONE kernel
   (``attn.bhtd.bwd``, q-blocks inner) computes a live block's scores,
@@ -256,6 +268,26 @@ def edge_label(sub) -> str:
     return "%dx%d" % sub if sub else ""
 
 
+def bhtd_stats_form(tile, tq):
+    """-> "rows" | "column" (None for no tile): the layout in which
+    ``attn.bhtd.fwd`` writes a call's logsumexp to HBM. "rows":
+    [b, h, 1, tq] float32, the positions along the 128 lanes, four bytes
+    a row, and what ``attn.bhtd.bwd`` and ``attn.bhtd.bwd_dkv`` read.
+    "column": [b, h, tq, 1], which the chip tiles (8, 128), so that
+    every row's one value owns a lane tile of 512 bytes: 128 times the
+    bytes, written by the forward, kept to the backward, and copied back
+    into rows by XLA in front of it. Rows where the q block can be cut
+    from a row (whole lane tiles of it, or all of it: the test
+    ``bhtd_bwd_form`` puts to a fused call); the column where it cannot
+    (a caller's ``q_block`` of 64). The one place that decides:
+    ``flash_attention_fwd``, the dispatch counter's ``stats`` label and
+    the tests read it."""
+    if tile is None:
+        return None
+    bq = tile[1]
+    return "rows" if bq % 128 == 0 or bq == tq else "column"
+
+
 def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
                 group=1, dv=None) -> str:
     """"bhtd" (the K-blocked [b, h, t, dh] kernels) when the picked
@@ -317,7 +349,7 @@ def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
     if tile is None:
         return None
     hb, bq, bk = tile
-    if (hb > 1 or p_drop > 0.0 or (bq % 128 and bq != tq)
+    if (hb > 1 or p_drop > 0.0 or bhtd_stats_form(tile, tq) != "rows"
             or _bwd_vmem_bytes(tq, tk, dh, dv, group, bq, bk, itemsize)
             > _BWD_VMEM_CAP_BYTES):
         return "split"
@@ -328,7 +360,9 @@ def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
 # kernels — refs are blocks of the native [b, h, t, dh] layout over the
 # grid (batch row, head group, q-block, k-block); index 0 drops the
 # leading size-1 batch-block dim, so shapes below are q (hb, bq, dh) /
-# k, v (hb, bk, dh) / bias (1|hb, 1|bq, bk) / lse (hb, bq, 1).
+# k, v (hb, bk, dh) / bias (1|hb, 1|bq, bk) / lse (hb, 1, bq) rows or,
+# where a q block cannot be cut from a row, (hb, bq, 1) columns
+# (bhtd_stats_form).
 # ---------------------------------------------------------------------------
 
 
@@ -605,7 +639,15 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     def _finish():
         l = l_scr[:, :, :1]
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:, :, :1] + jnp.log(l)
+        if lse_ref.shape[3] == 1:       # the column (bhtd_stats_form)
+            lse_ref[0] = m_scr[:, :, :1] + jnp.log(l)
+        else:
+            # rows: every lane of the scratch holds its row's value, so
+            # a head's block transposed has the [1, bq] row in every
+            # sublane
+            lse = m_scr[:] + jnp.log(l_scr[:])
+            for i in range(lse.shape[0]):
+                lse_ref[0, i] = lse[i].T[:1]
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
@@ -1124,6 +1166,15 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     every path including the dense fallback (the ring-attention merge
     consumes them; the fallback backward still recomputes via vjp).
 
+    The kernel writes lse as ``bhtd_stats_form`` says. "rows": a
+    [b, h, 1, tq] result, reshaped here to the contract's [b, h, tq, 1];
+    ``flash_attention_bwd`` reshapes it back and inside one jit the two
+    fold to nothing, so the backward reads what the forward wrote. As a
+    [.., tq, 1] float32 result of the kernel each row's value owns a
+    (8, 128) tile's lane row on the chip, 512 bytes for 4: the "column"
+    form, written only where a q block is no whole number of lane tiles
+    of a row.
+
     ``causal=True`` applies the future mask IN-KERNEL (block-position
     iota compare) and skips fully-masked k-blocks outright — no [tq, tk]
     bias tensor exists anywhere, preserving the O(t) HBM property for
@@ -1163,13 +1214,16 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
                                p_drop=p_drop, causal=causal, window=window)
     operands = (_seed_arr(seed), *args)
+    lse_spec, lse_shape = rows.stat, (b, h, tq, 1)
+    if bhtd_stats_form(tile, tq) == "rows":
+        lse_spec, lse_shape = rows.row, (b, h, 1, tq)
     out, lse = pl.pallas_call(
         kernel, name="attn.bhtd.fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, ng, nq, nk),
             in_specs=in_specs,
-            out_specs=[rows.o, rows.stat],
+            out_specs=[rows.o, lse_spec],
             scratch_shapes=[
                 pltpu.VMEM((hb, bq, 128), jnp.float32),
                 pltpu.VMEM((hb, bq, 128), jnp.float32),
@@ -1178,11 +1232,13 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         ),
         out_shape=[
             _result(operands, (b, h, tq, dv), q.dtype),
-            _result(operands, (b, h, tq, 1), jnp.float32),
+            _result(operands, lse_shape, jnp.float32),
         ],
         interpret=_INTERPRET,
     )(*operands)
-    return out, lse
+    # (inside one jit this reshape and the backward's, back into rows,
+    # fold to nothing; a consumer of the column gets it from XLA)
+    return out, lse.reshape(b, h, tq, 1)
 
 
 def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
@@ -1334,7 +1390,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
         kernel = functools.partial(kernel, group=group)
         dkv_grid = (b, h // group, nk, group * q_steps)
     stats, stat_spec = [lse, delta], rows.stat
-    if bq % 128 == 0 or bq == tq:
+    if bhtd_stats_form(tile, tq) == "rows":
         stat_spec = rows.row
         stats = [x.reshape(b, h, 1, tq) for x in stats]
     operands = (seed_arr, *args, g, *stats)

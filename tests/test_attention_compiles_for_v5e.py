@@ -300,6 +300,12 @@ _CELL_CALLS = {
     "joyai": (1, 32, 32, 4096, 192, 128, None),
     "qwen3next": (1, 16, 2, 8192, 256, 256, None),
     "olmoe": (2, 16, 16, 4096, 128, 128, None),
+    "lfm2moe": (1, 32, 8, 8192, 64, 64, None),
+    # (one softmax map of a differential layer's 40 / 20 heads of 64:
+    # 20 / 10 pair-heads over values of 128)
+    "phi4flash_w512": (1, 20, 10, 4096, 64, 128, 512),
+    "phi4flash_global": (1, 20, 10, 4096, 64, 128, None),
+    "nemotron3nano": (1, 32, 2, 4096, 128, 128, None),
 }
 
 
@@ -322,7 +328,7 @@ def _lowered_bwd(call, one_chip):
 @pytest.mark.parametrize("call", sorted(_CELL_CALLS))
 def test_fused_backward_compiles_at_the_cells_calls(call, one_chip,
                                                     real_kernels):
-    """The backward of every BHTD call of the four decoder cells is ONE
+    """The backward of every BHTD call of the eight decoder cells is ONE
     Mosaic call whose resident rows (dq for a query head; dk and dv for
     a group's key/value head) fit the VMEM it asks for, and nothing
     gradient-sized leaves it besides dq, dk and dv."""
@@ -337,6 +343,66 @@ def test_fused_backward_compiles_at_the_cells_calls(call, one_chip,
     # (the result tuple's table besides)
     assert 0 <= compiled.memory_analysis().output_size_in_bytes \
         - 2 * b * t * (h * dh + hk * (dh + dv)) < 4096
+
+
+def _compiled_step(call, one_chip, q_block=None):
+    """A call's forward and backward jitted as ONE function, as a cell's
+    step holds them: the compiled text."""
+    b, h, hk, t, dh, dv, window = _CELL_CALLS[call]
+
+    def arg(heads, width):
+        return jax.ShapeDtypeStruct((b, heads, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def step(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, q_block=q_block,
+                                          causal=True, window=window)
+        return out, fa.flash_attention_bwd(
+            q, k, v, None, None, out, lse, g, q_block=q_block, causal=True,
+            window=window)
+
+    return jax.jit(step).lower(
+        arg(h, dh), arg(hk, dh), arg(hk, dv), arg(h, dv)).compile().as_text()
+
+
+def _fwd_results(text):
+    """The result shapes of the ``attn.bhtd.fwd`` custom call."""
+    (line,) = [l for l in text.splitlines()
+               if "custom-call(" in l and "/attn.bhtd.fwd/" in l]
+    return re.findall(r"\w+\[[\d,]*\]", line.split(" custom-call(")[0])
+
+
+@pytest.mark.parametrize("call", sorted(_CELL_CALLS))
+def test_the_logsumexp_crosses_the_step_as_rows(call, one_chip,
+                                                real_kernels):
+    """Every BHTD call of the eight decoder cells, forward and backward
+    in one jit: ``attn.bhtd.fwd`` writes its logsumexp as [b, h, 1, t]
+    rows, ``attn.bhtd.bwd`` reads that buffer, and no float32
+    [b, h, t, 1] (a lane tile of 512 bytes a row on the chip) exists,
+    neither lse nor delta; nothing runs between the two but delta's
+    sum."""
+    b, h, hk, t, dh, dv, _ = _CELL_CALLS[call]
+    tile = fa.bhtd_tile(h, t, t, dh=dh, group=h // hk, dv=dv)
+    assert fa.bhtd_stats_form(tile, t) == "rows"
+    text = _compiled_step(call, one_chip)
+    assert _fwd_results(text) == [f"bf16[{b},{h},{t},{dv}]",
+                                  f"f32[{b},{h},1,{t}]"]
+    assert f"f32[{b},{h},{t},1]" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _calls(text) == {"attn.bhtd.fwd", "attn.bhtd.bwd"}
+
+
+def test_a_q_block_off_the_lanes_keeps_the_column(one_chip, real_kernels):
+    """A caller's q block of 64 cannot be cut from a row: the forward
+    writes the [b, h, t, 1] column and the pair reads it, as before."""
+    b, h, hk, t, dh, dv, _ = _CELL_CALLS["olmoe"]
+    tile = fa.bhtd_tile(h, t, t, 64, dh=dh)
+    assert tile[1] == 64 and fa.bhtd_stats_form(tile, t) == "column"
+    assert fa.bhtd_bwd_form(h, t, t, 64, dh=dh) == "split"
+    text = _compiled_step("olmoe", one_chip, q_block=64)
+    assert _fwd_results(text) == [f"bf16[{b},{h},{t},{dv}]",
+                                  f"f32[{b},{h},{t},1]"]
+    _holds_the_calls(text, "split")
 
 
 @pytest.mark.parametrize("call", ["laguna_w512", "smallthinker_w4096",

@@ -208,7 +208,8 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
                            "kernel bwd b1 t512 c512 taps4": 3}
     assert sorted(row["attention"]) == [
         f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]{form}"
-        for d, form in (("bwd", " form=fused edge=256x256"), ("fwd", ""))]
+        for d, form in (("bwd", " form=fused edge=256x256"),
+                        ("fwd", " stats=rows"))]
     assert row["attn_bwd_kernel_ms"] == {}      # (a trace needs the chip)
     assert sum(row["grouped_matmuls"].values()) == 36
     assert set(row["rel_err"]) == {
@@ -274,7 +275,7 @@ def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(
                                **dict(MLA_TINY, num_attention_heads=1))
     assert row["attention"] == {
         f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}": 3
-        for d, form in (("bwd", " form=fused"), ("fwd", ""))}
+        for d, form in (("bwd", " form=fused"), ("fwd", " stats=rows"))}
     assert list(row["routers"]) == ["score=sigmoid bias=1 k=2 experts=8"]
     assert sum(row["grouped_matmuls"].values()) == 18
     assert set(row["rel_err"]) == {"attn_o", "attn_dq", "attn_dk", "attn_dv"}

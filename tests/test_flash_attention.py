@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.parallel import flash_attention as fa
+from test_attention_compiles_for_v5e import _CELL_CALLS
 
 
 @pytest.fixture(autouse=True)
@@ -714,6 +715,78 @@ def test_a_window_as_long_as_the_row_lowers_the_plain_fused_call():
 
     assert text(384) == text(None) == text(1000)
     assert text(383) != text(None)
+
+
+# --- the forward's logsumexp in the layout the backward reads (PR 60) ---
+# (h, t, dh, q_block, k_block, the tile, bhtd_stats_form's answer): one
+# head a step, two, sixteen (the kernel writes a row a head); blocks of
+# the whole of a row that is no whole number of lane tiles; a caller's q
+# block of 64, which cannot be cut from a row
+_STATS_CASES = {
+    "hb1": (3, 256, 1024, 128, 128, (1, 128, 128), "rows"),
+    "hb2": (4, 256, 768, 128, 128, (2, 128, 128), "rows"),
+    "hb16": (32, 256, 16, 128, 128, (16, 128, 128), "rows"),
+    "the_whole_row": (2, 200, 16, None, None, (2, 200, 200), "rows"),
+    "q_block_64": (8, 256, 64, 64, 128, (8, 64, 128), "column"),
+}
+
+
+def _kernel_results(*args, **kw):
+    """The result shapes of the ONE call ``flash_attention_fwd`` lowers."""
+    ((name, _, eqn),) = _pallas_calls(
+        lambda *a: fa.flash_attention_fwd(*a, **kw), *args)
+    assert name == "attn.bhtd.fwd"
+    return [x.aval.shape for x in eqn.outvars]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("case", sorted(_STATS_CASES))
+def test_forward_lse_as_rows_or_a_column_is_the_references(case, causal):
+    """``attn.bhtd.fwd`` writes the logsumexp as [b, h, 1, tq] rows where
+    a q block can be cut from a row and as the [b, h, tq, 1] column where
+    it cannot; ``flash_attention_fwd`` returns [b, h, tq, 1] float32
+    either way, the reference's to float32 rounding."""
+    h, t, dh, q_block, k_block, tile, form = _STATS_CASES[case]
+    assert fa.bhtd_tile(h, t, t, q_block, k_block, dh=dh) == tile
+    assert fa.bhtd_stats_form(tile, t) == form
+    b = 2
+    q, k, v = _make_qkv(b=b, h=h, tq=t, tk=t, dh=dh)
+    bias = _pad_bias(b, t, 19)
+    kw = dict(q_block=q_block, k_block=k_block, causal=causal)
+    assert _kernel_results(q, k, v, bias, **kw) == [
+        (b, h, t, dh), (b, h, 1, t) if form == "rows" else (b, h, t, 1)]
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa.flash_attention_fwd(q, k, v, bias, **kw)
+        ref_out, ref_lse = fa._reference_attention_with_lse(
+            q, k, v, bias, dh ** -0.5, causal=causal)
+    assert lse.shape == (b, h, t, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, ref_lse, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(out, ref_out, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["hb1", "hb16", "q_block_64"])
+def test_lse_cotangent_through_rows_and_through_the_column(case):
+    """``flash_attention_with_lse`` under a loss that weighs the lse:
+    the gradient the dense (out, lse) vjp gives, whichever layout the
+    statistic crossed HBM in (one call reading rows, the pair reading
+    rows, the pair reading the column)."""
+    h, t, dh, q_block, k_block, _, _ = _STATS_CASES[case]
+    _assert_kernels_match_dense(1, h, t, dh, q_block, k_block, True, "pad")
+
+
+@pytest.mark.parametrize("call", sorted(_CELL_CALLS))
+def test_every_cells_call_writes_its_statistics_as_rows(call):
+    """The eight decoder cells' BHTD calls (perf/configs; the table the
+    compile tests hold) all take blocks of 512: rows, and the ONE
+    backward call that reads them."""
+    _, h, hk, t, dh, dv, _ = _CELL_CALLS[call]
+    tile = fa.bhtd_tile(h, t, t, dh=dh, group=h // hk, dv=dv)
+    assert tile == (1, 512, 512)
+    assert fa.bhtd_stats_form(tile, t) == "rows"
+    # the one test behind the backward's form too
+    assert fa.bhtd_bwd_form(h, t, t, dh=dh, group=h // hk, dv=dv) == "fused"
+    assert fa.bhtd_stats_form((1, 64, 512), t) == "column"
+    assert fa.bhtd_stats_form(None, t) is None
 
 
 # --- BTHD-small: the score block is passed over once (PR 49) ---

@@ -60,6 +60,29 @@ def test_kernels_agree_with_the_dense_composition(group, dh, edge_sub,
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("group,blk,form", [(2, 128, "rows"),
+                                            (8, 128, "rows"),
+                                            (8, 64, "column")])
+def test_grouped_forward_lse_as_rows_or_a_column(group, blk, form,
+                                                 interpreted):
+    """A group's heads go onto the grid one a step, and each writes its
+    own logsumexp row (at blocks of 64: its column): [b, h, tq, 1] from
+    ``flash_attention_fwd``, the dense composition's to float32
+    rounding."""
+    h, t, dh = 8, 256, 128
+    q, k, v, _ = qkv(h, h // group, dh, t, seed=4)
+    kw = dict(causal=True, q_block=blk, k_block=128)
+    tile = fa.bhtd_tile(h, t, t, blk, 128, dh=dh, group=group)
+    assert tile == (1, blk, 128) and fa.bhtd_stats_form(tile, t) == form
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+        want_out, want_lse = fa._reference_attention_with_lse(
+            q, k, v, None, dh ** -0.5, causal=True)
+    assert lse.shape == (1, h, t, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(out, want_out, rtol=1e-5, atol=1e-5)
+
+
 def test_non_causal_and_bias_per_query_head(interpreted):
     h, hk, dh, t = 4, 2, 128, 256
     q, k, v, g = qkv(h, hk, dh, t, seed=1)

@@ -59,6 +59,30 @@ def test_kernels_agree_with_the_dense_composition(h, dk, dv, t, blk, causal,
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("h,blk,tile,form", [
+    (1, 128, (1, 128, 128), "rows"), (2, 128, (2, 128, 128), "rows"),
+    (2, 64, (2, 64, 128), "column")])
+def test_latent_forward_lse_as_rows_or_a_column(h, blk, tile, form,
+                                                interpreted):
+    """Queries and keys of 192 over values of 128: the statistics'
+    scratch is as wide as the lanes whatever the head's widths are, and
+    the kernel writes the logsumexp as rows (one head a step, two) or,
+    at a q block of 64, as the column; the dense composition's to
+    float32 rounding."""
+    dk, dv, t = 192, 128, 256
+    q, k, v, _ = qkv(h, dk, dv, t, seed=6)
+    kw = dict(causal=True, q_block=blk, k_block=128)
+    assert fa.bhtd_tile(h, t, t, blk, 128, dh=dk, dv=dv) == tile
+    assert fa.bhtd_stats_form(tile, t) == form
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+        want_out, want_lse = fa._reference_attention_with_lse(
+            q, k, v, None, dk ** -0.5, causal=True)
+    assert lse.shape == (1, h, t, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(out, want_out, rtol=1e-5, atol=1e-5)
+
+
 def test_custom_vjp_takes_two_widths(interpreted):
     q, k, v, g = qkv(2, 24, 16, 128, seed=5)
 

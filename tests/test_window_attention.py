@@ -99,6 +99,30 @@ def test_kernels_agree_with_explicit_scores(h, hk, t, blk, window,
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
 
 
+# (query heads, key/value heads, t, block, window, the kernel's layout):
+# a band of two blocks, of one under a group of 7, and blocks of 64,
+# which cannot be cut from a row
+@pytest.mark.parametrize("h,hk,t,blk,window,form", [
+    (2, 2, 512, 128, 200, "rows"), (7, 1, 384, 128, 100, "rows"),
+    (2, 1, 256, 64, 100, "column")])
+def test_windowed_forward_lse_as_rows_or_a_column(h, hk, t, blk, window,
+                                                  form, interpreted):
+    """The band's forward writes its logsumexp as rows (a dead step of a
+    short band repeats the row's last live block and the last step
+    still writes) or, at blocks of 64, as the column: the explicit
+    scores' to float32 rounding either way."""
+    q, k, v, _ = qkv(h, hk, t)
+    kw = dict(causal=True, window=window, q_block=blk, k_block=blk)
+    tile = fa.bhtd_tile(h, t, t, blk, blk, dh=16, group=h // hk)
+    assert fa.bhtd_stats_form(tile, t) == form
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+        want_out, want_lse = explicit(q, k, v, window)
+    assert lse.shape == (1, h, t, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(out, want_out, rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("h,hk,t,window", [(2, 2, 48, 7), (7, 1, 40, 40),
                                            (8, 1, 33, 12)])
 def test_dense_composition_agrees_with_explicit_scores(h, hk, t, window):
@@ -429,3 +453,67 @@ def test_dispatch_rows_name_the_sub_tile(interpreted, monkeypatch):
                   for r in rows) == [("bwd", ""), ("bwd", "128x128"),
                                      ("bwd", "128x128"), ("fwd", ""),
                                      ("fwd", "")]
+
+
+def test_dispatch_rows_name_the_statistics_layout(interpreted, monkeypatch):
+    """A BHTD ``fwd`` row's ``stats``: the layout in which the call's
+    logsumexp leaves the kernel (``fa.bhtd_stats_form``), rows at the
+    cells' shapes and wherever a q block is whole lane tiles of a row,
+    column where the kernels' q block is 64;
+    ``dispatch_counts(stats=True)`` appends it; no ``bwd`` row and no
+    row of another family carries the label."""
+    from paddle_tpu.core import interp
+
+    q, k, v, g = qkv(2, 2, 256, dh=128, seed=5)
+    ins = {"Q": [q], "K": [k], "V": [v]}
+    attrs = {"layout": "bhtd", "causal": True, "is_test": True}
+
+    def lower(attrs):
+        out = attention_ops._sdpa(ins, attrs)
+        attention_ops._sdpa_grad(
+            dict(ins, Out=out["Out"], Lse=out["Lse"], **{"GRAD::Out": [g]}),
+            attrs)
+
+    monitor.reset()
+    flags.set_flags({"telemetry": True})
+    tok = interp.set_amp_active(False)
+    try:
+        lower(attrs)
+        lower(dict(attrs, use_pallas=False))
+        # laguna's window layers and joyai's latent call, as shapes
+        attention_ops._note_dispatch(
+            "bhtd", "fwd", (1, 8192, 8192, 64, 128, 8, 128), window=512,
+            causal=True)
+        attention_ops._note_dispatch(
+            "bhtd", "fwd", (1, 4096, 4096, 32, 192, 32, 128), causal=True)
+        attention_ops._note_dispatch("bthd_small", "fwd",
+                                     (64, 256, 256, 8, 64))
+        attention_ops._note_dispatch("ring", "fwd", (1, 256, 256, 2, 128))
+        with monkeypatch.context() as m:
+            m.setattr(fa, "DEFAULT_Q_BLOCK", 64)    # as a caller's q_block
+            assert fa.bhtd_tile(2, 256, 256, dh=128) == (2, 64, 256)
+            lower(attrs)
+        counts = attention_ops.dispatch_counts(stats=True)
+        plain = attention_ops.dispatch_counts()
+        rows = monitor.snapshot()["pt_attention_dispatch_total"]["values"]
+    finally:
+        interp._AMP_ACTIVE.reset(tok)
+        flags.set_flags({"telemetry": False})
+        monitor.reset()
+    shape = "b1 tq256 tk256 h2 dh128"
+    assert counts == {
+        f"bhtd fwd {shape} stats=rows": 1, f"bhtd fwd {shape} stats=column": 1,
+        f"bhtd bwd {shape}": 2,
+        f"dense fwd {shape}": 1, f"dense bwd {shape}": 1,
+        "bhtd fwd b1 tq8192 tk8192 h64 kv8 dh128 w512 stats=rows": 1,
+        "bhtd fwd b1 tq4096 tk4096 h32 dk192 dv128 stats=rows": 1,
+        "bthd_small fwd b64 tq256 tk256 h8 dh64": 1,
+        f"ring fwd {shape}": 1}
+    # (the keys without the label are the parent's)
+    assert plain[f"bhtd fwd {shape}"] == 2
+    assert sorted({(r["labels"]["family"], r["labels"]["pass"],
+                    r["labels"].get("stats")) for r in rows}) == sorted({
+        ("bhtd", "fwd", "rows"), ("bhtd", "fwd", "column"),
+        ("bhtd", "bwd", None), ("dense", "fwd", None),
+        ("dense", "bwd", None), ("bthd_small", "fwd", None),
+        ("ring", "fwd", None)}, key=str)
