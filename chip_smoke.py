@@ -119,12 +119,13 @@ def attention_dispatch():
     [hb1 bq512 bk512]", a backward row of that family also whether it is
     one call or the pair and the sub-tiles it walks its edge blocks in:
     "... form=fused edge=256x256", a forward row the layout in which its
-    logsumexp leaves the kernel: "... stats=rows"
+    logsumexp leaves the kernel: "... stats=rows", a block-masked row
+    its mask: "... mask=block_diffusion block=4 band=skip"
     (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
     return attention_ops.dispatch_counts(tiles=True, forms=True, edges=True,
-                                         stats=True)
+                                         stats=True, masks=True)
 
 
 # (t, window) of the decoder cells' BHTD calls, all on hb1 bq512 bk512
@@ -167,7 +168,7 @@ def _statistics_in_rows(attn):
     logsumexp as [b, h, 1, t] rows (flash_attention.bhtd_stats_form),
     none as the column the chip pads to 512 bytes a position."""
     rows = [k for k in attn if k.startswith("bhtd fwd ")]
-    check(rows and all(k.endswith(" stats=rows") for k in rows),
+    check(rows and all(" stats=rows" in k for k in rows),
           f"expected every bhtd forward call to write its logsumexp as "
           f"rows (stats=rows), none as the column: {attn}")
 
@@ -518,9 +519,10 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     return row
 
 
-def lower_train_step(main, loss, seq, batch=1, sharding=None):
+def lower_train_step(main, loss, seq, batch=1, sharding=None, feeds=None):
     """Lower (not run) the train step of a language-model program whose
-    feeds are ``input_ids`` and ``labels`` [batch, seq], as Executor.run
+    feeds are ``input_ids`` and ``labels`` [batch, seq] (``feeds``:
+    {name: (shape, dtype)} of a program with others), as Executor.run
     would (for ``sharding``'s device, where one is described): the
     dispatch counters then hold what the step lowers, and the result
     compiles."""
@@ -530,7 +532,9 @@ def lower_train_step(main, loss, seq, batch=1, sharding=None):
     from paddle_tpu.core import lowering
     from paddle_tpu.executor import Executor
 
-    low = lowering.lower_block(main, 0, ("input_ids", "labels"), (loss.name,))
+    feeds = feeds or {"input_ids": ((batch, seq), "int32"),
+                      "labels": ((batch, seq), "int32")}
+    low = lowering.lower_block(main, 0, tuple(feeds), (loss.name,))
     block = main.global_block()
 
     def aval(shape, dtype):
@@ -543,10 +547,9 @@ def lower_train_step(main, loss, seq, batch=1, sharding=None):
         var = block._find_var_recursive(name)
         return aval(var.shape, var.dtype)
 
-    ids = aval((batch, seq), "int32")
     return Executor._jit_for(low, None).lower(
         {n: of(n) for n in low.state_in_names},
-        {"input_ids": ids, "labels": ids},
+        {name: aval(*spec) for name, spec in feeds.items()},
         aval((2,), "uint32"), aval((), "uint32"))
 
 
@@ -1562,6 +1565,120 @@ def sconv_phase(seq=8192, t_check=2048, **overrides):
     return row
 
 
+def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
+    """Training by block diffusion (models/sdar.py): a row of [noised
+    copy ; clean copy] under the three-part block mask inside the BHTD
+    kernels.
+
+    1. The cell ``sdar-train-s4096``'s train step (five layers of
+       SDAR-30B-A3B at its published widths, 16 of 128 experts held, an
+       eighth of the vocabulary, bf16 AMP, Adam) is LOWERED, not run
+       (perf/run.py runs it), and the dispatch counters are held to what
+       the cell must lower: five block-masked attention calls each way
+       over 2 x ``seq`` positions on the BHTD kernels (``mask=
+       block_diffusion band=skip``: the kernels walk the mask's live
+       blocks; none ``dense``), the backward one call (``form=fused``),
+       the logsumexp in rows, and five rotary embeddings each way on the
+       ``rope.*`` kernels (the positions run twice over the row: one
+       run's tables read twice). ``overrides`` cut the config for the
+       CPU tests.
+    2. On the device, at the cell's heads and 2 x ``t_check`` positions:
+       the kernels under the mask, forward and the three gradients,
+       against the dense composition (``flash_attention.bd_visible``),
+       the score pairs the two walks compute beside those the mask lets
+       through, and the kernels' ms a call by name."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import sdar as M
+    from paddle_tpu.parallel import flash_attention as fa
+
+    cfg = M.SdarConfig(**{**dict(
+        num_hidden_layers=5, vocab_size=18992, mask_token_id=18991,
+        held_experts=(0, 16)), **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    reads = (attention_dispatch, rope_dispatch)
+    before = [read() for read in reads]
+    lower_train_step(main, model["loss"], seq, feeds={
+        "input_ids": ((1, 2 * seq), "int32"), "labels": ((1, seq), "int32"),
+        "loss_weight": ((1, seq), "float32")})
+    attn, ropes = (_dispatch_since(b, read) for b, read in zip(before, reads))
+    say(f"  lowered: attention {attn}; rotary embeddings {ropes}")
+    n, block = cfg.num_hidden_layers, cfg.block_length
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n and all(
+            k.startswith(f"bhtd {direction} b1 tq{2 * seq} tk{2 * seq} ")
+            and k.endswith(f" mask=block_diffusion block={block} band=skip")
+            for k in rows),
+            f"expected {n} block-masked attention calls {direction} over "
+            f"{2 * seq} positions in the bhtd kernels (band=skip), none "
+            f"dense: {attn}")
+    _one_backward_call(attn)
+    _statistics_in_rows(attn)
+    check(sum(ropes.values()) == 2 * n
+          and all(k.startswith("kernel ") for k in ropes),
+          f"expected {n} rotary embeddings each way on the rope kernels: "
+          f"{ropes}")
+
+    # --- on the device ----------------------------------------------------
+    (h, hk), t = heads, 2 * t_check
+    tile = fa.bhtd_tile(h, t, t, dh=dh, group=h // hk, block_diffusion=block)
+    check(tile is not None, f"no tile for the block-masked call at t{t} "
+          f"h{h} kv{hk} dh{dh}")
+    r = np.random.RandomState(7)
+    q, k, v, g = (jnp.asarray(r.randn(1, n_heads, t, dh), jnp.bfloat16)
+                  for n_heads in (h, hk, hk, h))
+
+    @jax.jit
+    def kernels(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, block_diffusion=block)
+        return (out, *fa.flash_attention_bwd(q, k, v, None, None, out, lse,
+                                             g, block_diffusion=block))
+
+    @jax.jit
+    def dense(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
+            q, k, v, None, dh ** -0.5,
+            block_diffusion=block).astype(q.dtype), q, k, v)
+        return (out, *vjp(g))
+
+    errs = {}
+    for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
+                          kernels(q, k, v, g), dense(q, k, v, g)):
+        a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
+        errs[name] = float(jnp.abs(a - b).max()
+                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        check(errs[name] <= KERNEL_REL_TOL,
+              f"block-masked {name} off the dense composition by "
+              f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
+    pairs = {form or "fwd": fa.bhtd_pairs(t, t, tile, False, form=form,
+                                          block_diffusion=block)
+             for form in (None, "fused")}
+    check(all(live == t_check * t_check + block * t_check
+              for _, live in pairs.values()),
+          f"the mask's live pairs are L^2 + B L: {pairs}")
+    ms, _ = _traced_kernel_ms("chip_smoke_bd",
+                              lambda: kernels(q, k, v, g), "attn.bhtd.")
+    # (a trace needs the chip: the CPU tests run this phase through the
+    # interpreters and read {})
+    check(jax.default_backend() != "tpu"
+          or sorted(ms) == ["attn.bhtd.bwd", "attn.bhtd.fwd"],
+          f"kernels in the trace: {ms}")
+    row = {"attention": attn, "rotary_embeddings": ropes, "kernel_ms": ms,
+           "pairs": {k_: list(v_) for k_, v_ in pairs.items()},
+           "rel_err": {k_: round(v_, 5) for k_, v_ in errs.items()}}
+    say(f"  block-masked kernels, ms a call at t{t} h{h} kv{hk} dh{dh}: "
+        f"{ms}; pairs computed / live {row['pairs']}")
+    say(f"  bd {row['rel_err']}")
+    return row
+
+
 # ---------------------------------------------------------------------------
 # phase 2: train
 # ---------------------------------------------------------------------------
@@ -2070,6 +2187,7 @@ def main() -> int:
     report["ssm"], _ = phase("ssm", ssm_phase)
     report["mamba2"], _ = phase("mamba2", mamba2_phase)
     report["sconv"], _ = phase("sconv", sconv_phase)
+    report["bd"], _ = phase("bd", bd_phase)
     report["rope"], _ = phase("rope", rope_phase)
     report["loss_head"], _ = phase("loss_head", loss_head_phase)
 

@@ -134,6 +134,16 @@ def lowering_active() -> bool:
     return _AMP_ACTIVE.get() is not None
 
 
+def stands_for_dynamic(n) -> bool:
+    """Is ``n`` the stand-in of a dynamic (-1) dim in build-time shape
+    inference (framework._BATCH_SENTINEL, a prime)? An op that cuts a
+    dim in parts takes the stand-in as it is: a part of a dynamic dim is
+    dynamic. Never inside a lowering: there every dim is the data's."""
+    from paddle_tpu.framework import _BATCH_SENTINEL
+
+    return not lowering_active() and n == _BATCH_SENTINEL
+
+
 def set_amp_active(flag: bool):
     return _AMP_ACTIVE.set(bool(flag))
 

@@ -400,9 +400,13 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
 
 
 def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
-                     interleaved=False, layout="bhtd", scaling=None):
+                     interleaved=False, layout="bhtd", scaling=None,
+                     periods=1):
     """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
-    may have fewer heads); position p of the sequence is p.
+    may have fewer heads); position p of the sequence is p, or, with
+    ``periods`` = n, p mod t / n: the positions 0 .. t / n - 1 run n
+    times over the row (a row of a noised and a clean copy of the same
+    tokens: 2; the kernels read one run's tables n times).
     ``rotary_dim``: only the first rotary_dim features of a head turn,
     as a head of that width would, and the others pass.
     ``interleaved``: feature 2i pairs with 2i + 1 (not i with
@@ -442,6 +446,8 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
             raise ValueError(f"rotary_embedding: layout {layout!r} is "
                              "neither 'bhtd' nor 'bthd'")
         attrs["layout"] = layout
+    if int(periods) != 1:
+        attrs["periods"] = int(periods)
     if scaling:
         import math
 
@@ -461,7 +467,7 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
 
 
 def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
-                                 name=None):
+                                 name=None, block_diffusion=None):
     """softmax(scale q k^T) v of head-major q [b, h, t, dk], k
     [b, hk, t, dk] and v [b, hk, t, dv] -> [b, h, t, dv]: ONE op, which
     the flash kernels take on a TPU (``ops/attention_ops.py``). hk may
@@ -469,7 +475,13 @@ def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
     i // (h / hk), nothing is copied) and dv may differ from dk.
     ``causal``: the mask rides in the kernel, no bias tensor exists;
     ``window``: a query sees its last ``window`` positions only, itself
-    among them. Every position is real and nothing is dropped: the
+    among them. ``block_diffusion=B`` (instead of either: the mask is
+    not causal): the row is two halves of t / 2, a noised copy and the
+    clean copy of the same positions in blocks of B, under block
+    diffusion's training mask: a noised block sees itself, both ways,
+    and the clean blocks before it; the clean half is block-causal and
+    sees no noised key (``parallel/flash_attention.bd_visible``). Every
+    position is real and nothing is dropped: the
     packed decoders' call (``models/decoder.py``'s families).
     ``models/transformer.py`` appends the op itself, token-major with
     dropout and a padding bias. ``name`` names the layer's temporaries."""
@@ -478,10 +490,16 @@ def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
     # logsumexp rows, consumed by the paired grad op (DCE'd at inference)
     lse = helper.create_variable_for_type_inference(dtype="float32")
     lse.stop_gradient = True
+    if block_diffusion and window:
+        raise ValueError("scaled_dot_product_attention: block_diffusion "
+                         "takes no window")
     attrs = {"scale": float(scale), "dropout_prob": 0.0, "is_test": True,
-             "layout": "bhtd", "causal": bool(causal)}
+             "layout": "bhtd",
+             "causal": bool(causal) and not block_diffusion}
     if window:
         attrs["window"] = int(window)
+    if block_diffusion:
+        attrs["block_diffusion"] = int(block_diffusion)
     helper.append_op("scaled_dot_product_attention",
                      inputs={"Q": q, "K": k, "V": v},
                      outputs={"Out": out, "Lse": lse}, attrs=attrs)

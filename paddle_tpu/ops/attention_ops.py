@@ -49,7 +49,12 @@ _M_DISPATCH = _monitor.counter(
     "the backward, rows ([b, h, 1, tq], four bytes a position, what "
     "attn.bhtd.bwd reads) or column ([b, h, tq, 1], which the chip pads "
     "to a lane tile of 512 bytes a position and XLA copies back into "
-    "rows), flash_attention.bhtd_stats_form's answer for the tile")
+    "rows), flash_attention.bhtd_stats_form's answer for the tile. A "
+    "block-masked call (the op's block_diffusion attribute: a row of a "
+    "noised and a clean copy under block diffusion's training mask) "
+    "carries mask: block_diffusion, block: the block's length, and band: "
+    "skip (the BHTD kernels walk the mask's live blocks) or dense (the "
+    "composition, with its [t, t] scores)")
 
 
 def _windowed(attrs, q, k, bthd, ring):
@@ -68,21 +73,42 @@ def _windowed(attrs, q, k, bthd, ring):
     return window
 
 
+def _block_masked(attrs, q, k, bthd, ring):
+    """The op's ``block_diffusion`` attr (the block's length), None where
+    absent; the kernel layer holds it to the row (``fa._halves``)."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    block = attrs.get("block_diffusion") or None
+    # (build-time shape inference stands a prime in for a dynamic row:
+    # no two halves, and no mask moves a shape)
+    if block is None or interp.stands_for_dynamic(q.shape[2]):
+        return None
+    if bthd or ring is not None:
+        raise NotImplementedError(
+            "scaled_dot_product_attention: block_diffusion needs "
+            "layout='bhtd' and no context-parallel ring")
+    return fa._halves(block, bool(attrs.get("causal", False)),
+                      attrs.get("window") or None, q.shape[2],
+                      k.shape[2])[0]
+
+
 def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
-                   form=None, causal=False):
+                   form=None, causal=False, block_diffusion=None):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
     b, tq, tk, h, dh = dims[:5]
     # (behind them, where the call is not plain: key/value heads, dv)
-    hk, dv = dims[5:] if len(dims) > 5 else (h, dh)
+    hk, dv = dims[5:7] if len(dims) > 5 else (h, dh)
     tile, edge, stats = "", None, None
     if family == "bhtd":
         # the kernel layer's own answers for the call the op hands it
         # (the op passes no q_block / k_block)
         from paddle_tpu.parallel import flash_attention as fa
 
-        picked = fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk, dv=dv)
+        picked = fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk, dv=dv,
+                              block_diffusion=block_diffusion,
+                              itemsize=dims[7] if len(dims) > 7 else 2)
         tile = fa.tile_label(picked)
         edge = fa.edge_label(fa.bhtd_edge_tile(picked, causal, form))
         if direction == "fwd":
@@ -99,6 +125,9 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         labels["shape"] += f" w{window}"
         labels["band"] = "skip" if family == "bhtd" else "dense"
         labels["heads"] = str(h)
+    if block_diffusion is not None:
+        labels.update(mask="block_diffusion", block=str(block_diffusion),
+                      band="skip" if family == "bhtd" else "dense")
     if form is not None:
         labels["form"] = form
     if edge is not None:
@@ -108,7 +137,8 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
     _M_DISPATCH.inc(labels=labels)
 
 
-def dispatch_counts(tiles=False, forms=False, edges=False, stats=False):
+def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
+                    masks=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
     run print it. ``tiles``: a row whose family tiles by the shape names
@@ -117,7 +147,9 @@ def dispatch_counts(tiles=False, forms=False, edges=False, stats=False):
     pair, "bhtd bwd <shape> form=fused". ``edges``: a row whose edge
     blocks are walked in sub-tiles names them, "... edge=256x256".
     ``stats``: a forward row of that family says in which layout the
-    call's logsumexp leaves the kernel, "... stats=rows"."""
+    call's logsumexp leaves the kernel, "... stats=rows". ``masks``: a
+    block-masked row says so, "... mask=block_diffusion block=4
+    band=skip"."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
@@ -132,6 +164,9 @@ def dispatch_counts(tiles=False, forms=False, edges=False, stats=False):
             name += f" edge={lb['edge']}"
         if stats and lb.get("stats"):
             name += f" stats={lb['stats']}"
+        if masks and lb.get("mask"):
+            name += (f" mask={lb['mask']} block={lb['block']} "
+                     f"band={lb['band']}")
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 
@@ -192,7 +227,8 @@ def _attn_bias(ins, attrs):
     return {"Out": [out]}
 
 
-def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None):
+def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None,
+            periods=1):
     """Rotary position embedding of x [b, h, t, dh], rotate-half form
     (Su et al. 2021 as GPT-NeoX and HF lay it out): feature i pairs
     with i + dh/2, position p turns the pair by p * theta^(-2i/dh).
@@ -202,15 +238,25 @@ def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None):
     layout, DeepSeek's ``rope_interleave``), the angles the same.
     ``scaling`` (a ``parallel/rope.Yarn``): yarn's frequencies over the
     features that turn and its attention factor on cos and sin
-    (``parallel/rope.cos_sin``, which the kernels' tables call too)."""
+    (``parallel/rope.cos_sin``, which the kernels' tables call too).
+    ``periods``: the positions 0 .. t / periods - 1 run that many times
+    over the row (index i is position i mod t / periods)."""
     from paddle_tpu.parallel import rope
 
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         return jnp.concatenate(
-            [_rotate(x[..., :rotary_dim], theta, None, interleaved, scaling),
+            [_rotate(x[..., :rotary_dim], theta, None, interleaved, scaling,
+                     periods),
              x[..., rotary_dim:]], -1)
     t, dh = x.shape[-2], x.shape[-1]
-    cos, sin = rope.cos_sin(t, dh, theta, scaling)
+    if interp.stands_for_dynamic(t):
+        periods = 1   # (build-time shape inference: no angle moves a shape)
+    if t % periods:
+        raise ValueError(f"rotary_embedding: a row of {t} positions is "
+                         f"not {periods} runs of the same positions")
+    cos, sin = rope.cos_sin(t // periods, dh, theta, scaling)
+    if periods > 1:
+        cos, sin = jnp.tile(cos, (periods, 1)), jnp.tile(sin, (periods, 1))
     if interleaved:
         pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (dh // 2, 2))
         x1, x2 = pairs[..., 0], pairs[..., 1]
@@ -244,6 +290,11 @@ def _rope_attrs(attrs):
             attrs.get("layout", "bhtd") == "bthd")
 
 
+def _rope_periods(attrs):
+    """How many times the positions run over the row (attr ``periods``)."""
+    return int(attrs.get("periods", 0)) or 1
+
+
 def _rope_scaling(attrs):
     """The op's yarn scaling (``parallel/rope.Yarn``) from its five
     plain attributes ``yarn_<field>``, which ``layers.rotary_embedding``
@@ -267,7 +318,7 @@ def _rope_tile(q, k, attrs, direction):
     if q.dtype == k.dtype and q.shape[-1] == k.shape[-1]:
         tile = rope.rope_tile(
             q.shape[0], q.shape[t_axis], q.shape[h_axis], q.shape[-1], rd,
-            il, q.dtype, hk=k.shape[h_axis])
+            il, q.dtype, hk=k.shape[h_axis], periods=_rope_periods(attrs))
     # off with telemetry; build-time shape inference is not a lowering
     if _monitor.enabled() and interp.lowering_active():
         _M_ROPE.inc(labels={"impl": "kernel" if tile else "xla",
@@ -287,8 +338,9 @@ def _rotary_xla(ins, attrs):
     q, k = _x(ins, "Q"), _x(ins, "K")
     if tokens:
         q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
-    return {"QOut": [_rotate(q, theta, rd, il, scaling)],
-            "KOut": [_rotate(k, theta, rd, il, scaling)]}
+    periods = _rope_periods(attrs)
+    return {"QOut": [_rotate(q, theta, rd, il, scaling, periods)],
+            "KOut": [_rotate(k, theta, rd, il, scaling, periods)]}
 
 
 # (the generic grad op's rule for the XLA form: the vjp of _rotary_xla)
@@ -306,7 +358,10 @@ def _rotary_embedding(ins, attrs):
     ``yarn_beta_fast``, ``yarn_beta_slow``, ``yarn_attention_factor``:
     a yarn scaling of the frequencies of the features that turn and its
     factor on cos and sin, plain numbers, so the op stays a function of
-    its attributes). The angles and the rotation are f32; the results
+    its attributes; ``periods``, absent for 1: the positions 0 .. t /
+    periods - 1 run that many times over the row, index i is position
+    i mod t / periods, as a row of a noised and a clean copy of the same
+    tokens needs). The angles and the rotation are f32; the results
     return to the inputs' dtype. ``layout`` "bthd": Q and K come
     token-major [b, t, h, dh], as a projection leaves them; the results
     are head-major [b, h, t, dh] all the same.
@@ -322,7 +377,8 @@ def _rotary_embedding(ins, attrs):
 
     theta, rd, _, tokens = _rope_attrs(attrs)
     q, k = rope.rope_fwd(q, k, theta, tile, tokens=tokens,
-                         scaling=_rope_scaling(attrs), rotary_dim=rd)
+                         scaling=_rope_scaling(attrs), rotary_dim=rd,
+                         periods=_rope_periods(attrs))
     return {"QOut": [q], "KOut": [k]}
 
 
@@ -350,7 +406,7 @@ def _rotary_embedding_grad(ins, attrs):
     dq, dk = rope.rope_bwd(cotangent(_x(ins, "GRAD::QOut"), q),
                            cotangent(_x(ins, "GRAD::KOut"), k), theta, tile,
                            tokens=tokens, scaling=_rope_scaling(attrs),
-                           rotary_dim=rd)
+                           rotary_dim=rd, periods=_rope_periods(attrs))
     return {"GRAD::Q": [dq], "GRAD::K": [dk]}
 
 
@@ -362,8 +418,8 @@ def _sdpa_config(ins, attrs, rng):
     the in-kernel mask — is identical in both directions. ``family`` is
     the Pallas kernel family the shapes take on this backend, or "dense"
     for the jnp composition (parallel/flash_attention.py); ``dims`` is
-    (b, tq, tk, h, dh, key/value heads, dv), the dispatch record's
-    shape (``_note_dispatch`` takes the first five alone too). K and V with fewer heads than Q (grouped-query attention) and
+    (b, tq, tk, h, dh, key/value heads, dv, q's itemsize), the dispatch
+    record's shape (``_note_dispatch`` takes the first five alone too). K and V with fewer heads than Q (grouped-query attention) and
     V narrower than Q and K (dv != dh: latent attention) take the BHTD
     layout only, no dropout, no mesh.
     """
@@ -392,21 +448,25 @@ def _sdpa_config(ins, attrs, rng):
     else:
         b, h, tq, dh = q.shape
         tk, hk, dv = k.shape[2], k.shape[1], v.shape[3]
-        family = fa.bhtd_family(h, tq, tk, dh=dh, group=h // hk, dv=dv)
-        dims = (b, tq, tk, h, dh, hk, dv)
-        if (hk != h or dv != dh) and (
+        itemsize = jnp.dtype(q.dtype).itemsize
+        family = fa.bhtd_family(
+            h, tq, tk, dh=dh, group=h // hk, dv=dv,
+            block_diffusion=attrs.get("block_diffusion") or None,
+            itemsize=itemsize)
+        dims = (b, tq, tk, h, dh, hk, dv, itemsize)
+        if (hk != h or dv != dh or attrs.get("block_diffusion")) and (
                 training_dropout or interp.spmd_ctx() is not None):
             raise NotImplementedError(
                 "scaled_dot_product_attention: grouped key/value heads, "
-                "or values of another width than the keys, with "
-                "attention dropout or under a mesh")
+                "values of another width than the keys, or a block-"
+                "diffusion mask, with attention dropout or under a mesh")
     if not attrs.get("use_pallas", True):
         family = "dense"
     return scale, drop, seed, family, dims
 
 
 def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
-             form=None, causal=False):
+             form=None, causal=False, block_diffusion=None):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -420,7 +480,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     split = interp.mesh_batch_split()
     if split is None:
         _note_dispatch(family, direction, dims, window=window, form=form,
-                       causal=causal)
+                       causal=causal, block_diffusion=block_diffusion)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -435,7 +495,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     _note_dispatch(
         family, direction, (b // n,) + tuple(dims[1:]),
         sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window,
-        form, causal)
+        form, causal, block_diffusion)
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -503,7 +563,11 @@ def _sdpa(ins, attrs, rng=None):
     head i reads key/value head i // (h / kv heads)); the BHTD kernels
     pick the head in their index maps and never copy K or V. Attr
     ``window`` (with ``causal``, layout bhtd, no ring): a query sees the
-    last ``window`` positions only, itself among them.
+    last ``window`` positions only, itself among them. Attr
+    ``block_diffusion`` = B (with neither, layout bhtd, no ring, no
+    dropout): the row is a noised and a clean copy of t / 2 positions in
+    blocks of B under block diffusion's training mask
+    (``flash_attention.bd_visible``).
 
     On TPU this routes to the Pallas flash-attention kernel
     (paddle_tpu/parallel/flash_attention.py), including training-time
@@ -523,6 +587,7 @@ def _sdpa(ins, attrs, rng=None):
     t_axis = 1 if bthd else 2
     ring = _ring_config_t(q, k, t_axis)
     window = _windowed(attrs, q, k, bthd, ring)
+    block = _block_masked(attrs, q, k, bthd, ring)
     if ring is not None:
         _note_dispatch("ring", "fwd", dims)
         mesh, ctx_axis, data_axis = ring
@@ -542,7 +607,8 @@ def _sdpa(ins, attrs, rng=None):
                                     p_drop=float(drop), seed=seed)
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif family == "dense":
-        _note_dispatch("dense", "fwd", dims, window=window)
+        _note_dispatch("dense", "fwd", dims, window=window,
+                       block_diffusion=block)
         sd = seed if drop > 0.0 else None
         if bthd:
             out = fa._reference_attention_bthd(
@@ -552,7 +618,8 @@ def _sdpa(ins, attrs, rng=None):
                 scale, drop, sd)
         else:
             out = fa._reference_attention(q, k, v, bias, scale, drop, sd,
-                                          causal=causal, window=window)
+                                          causal=causal, window=window,
+                                          block_diffusion=block)
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif bthd:
         out, lse = _on_mesh(
@@ -566,8 +633,9 @@ def _sdpa(ins, attrs, rng=None):
         out, lse = _on_mesh(
             lambda q, k, v, bias, seed: fa.flash_attention_with_lse(
                 q, k, v, bias, seed, scale, float(drop), causal=causal,
-                window=window),
-            (q, k, v, bias), seed, family, "fwd", dims, window)
+                window=window, block_diffusion=block),
+            (q, k, v, bias), seed, family, "fwd", dims, window,
+            block_diffusion=block)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -589,6 +657,7 @@ def _sdpa_grad(ins, attrs, rng=None):
     t_axis = 1 if bthd else 2
     ring = _ring_config_t(q, k, t_axis)
     window = _windowed(attrs, q, k, bthd, ring)
+    block = _block_masked(attrs, q, k, bthd, ring)
     if ring is not None:
         _note_dispatch("ring", "bwd", dims)
         mesh, ctx_axis, data_axis = ring
@@ -611,7 +680,8 @@ def _sdpa_grad(ins, attrs, rng=None):
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
     elif family == "dense":
-        _note_dispatch("dense", "bwd", dims, window=window)
+        _note_dispatch("dense", "bwd", dims, window=window,
+                       block_diffusion=block)
         sd = seed if drop > 0.0 else None
         if bthd:
             eff_bias = fa._combined_causal_bias(
@@ -624,22 +694,24 @@ def _sdpa_grad(ins, attrs, rng=None):
             def f(q, k, v):
                 return fa._reference_attention(
                     q, k, v, bias, scale, drop, sd, causal=causal,
-                    window=window).astype(q.dtype)
+                    window=window, block_diffusion=block).astype(q.dtype)
 
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
     else:
         bwd = (fa.flash_attention_bthd_bwd if bthd
-               else functools.partial(fa.flash_attention_bwd, window=window))
+               else functools.partial(fa.flash_attention_bwd, window=window,
+                                      block_diffusion=block))
         # one call or the pair: the kernel layer's own answer, as the
         # entry point reads it (the op passes no q_block / k_block)
         form = None if bthd else fa.bhtd_bwd_form(
             dims[3], dims[1], dims[2], dh=dims[4], group=dims[3] // dims[5],
-            dv=dims[6], itemsize=q.dtype.itemsize, p_drop=drop)
+            dv=dims[6], itemsize=q.dtype.itemsize, p_drop=drop,
+            block_diffusion=block)
         dq, dk, dv = _on_mesh(
             lambda q, k, v, bias, out, lse, g, seed: bwd(
                 q, k, v, bias, seed, out, lse, g, scale=scale,
                 p_drop=drop, causal=causal),
             (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
-            "bwd", dims, window, form, causal=causal)
+            "bwd", dims, window, form, causal=causal, block_diffusion=block)
     return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

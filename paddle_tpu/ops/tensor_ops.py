@@ -132,6 +132,8 @@ def _split(ins, attrs):
     if sections:
         idx = np.cumsum(sections[:-1]).tolist()
         parts = jnp.split(x, idx, axis=axis)
+    elif interp.stands_for_dynamic(jnp.shape(x)[axis]):
+        parts = [x] * num   # (a part of a dynamic dim is dynamic)
     else:
         parts = jnp.split(x, num, axis=axis)
     return {"Out": list(parts)}

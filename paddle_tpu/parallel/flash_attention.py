@@ -56,6 +56,27 @@ ridge of 240.
   dead steps (the first rows' bands are shorter) repeat the row's last
   live block, and the two-sided mask runs only on the blocks the
   diagonal or the band's far edge crosses.
+- ``block_diffusion=B`` (exclusive with ``causal`` and ``window``): the
+  row is two halves of t / 2, a NOISED copy and the CLEAN copy of the
+  same L positions cut into blocks of B, under block diffusion's
+  training mask (Arriola et al. 2025, arXiv:2503.09573; ``bd_visible``
+  is the rule's one dense copy): a noised block sees itself, both
+  ways, and the clean blocks before it; the clean half is block-causal;
+  no clean query sees a noised key. The mask is no function of p - s,
+  so the walk is its own (``_bd_k_step``, ``_bd_q_step``): a q-row's
+  steps are its own diagonal block and THEN the clean half's blocks
+  from the first, a clean k-row's steps the noised q-blocks from its
+  own on and then the clean ones, a noised k-row's its own q-block
+  alone; a clean q-row never fetches a noised block. The edge blocks
+  (a q-row's own diagonal block; a noised q-row's clean block of its
+  own positions) are masked at block granularity and worked on WHOLE,
+  in the forward and in the one backward call alike
+  (``bhtd_edge_tile``: None). A block-masked call runs in
+  ``attn.bhtd.fwd`` and the ONE ``attn.bhtd.bwd``, or not in kernels at
+  all: where its tile is not square, does not cut a half into whole
+  blocks, is not whole blocks of B, or the backward would be the pair
+  (``_fused_fits``), ``bhtd_tile`` gives it none and it runs as the
+  dense composition, which the dispatch counter says.
 - An EDGE block is one that the diagonal or a band's far edge crosses;
   every other live block is plain: all visible, no mask, in every
   kernel and under plain ``causal`` too (``_when_live``). The ONE
@@ -214,17 +235,27 @@ def _pick_tile(h, tq, tk, q_block, k_block, dh, group=1, dv=None):
 
 
 def bhtd_tile(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
-              dv=None):
+              dv=None, block_diffusion=None, itemsize=2):
     """-> (hb, bq, bk), the tile the K-blocked [b, h, t, dh] kernels take
     for a call of this shape, or None where they do not take it (no TPU
     backend, or blocks that do not tile both sequence lengths) and it
     runs as the dense composition. The one place that decides either:
     the kernels' entry points, ``bhtd_family`` and the dispatch counter's
-    ``tile`` label all read it."""
-    hb, bq, bk = _pick_tile(h, tq, tk, q_block, k_block, dh, group, dv)
-    if kernels_enabled() and tq % bq == 0 and tk % bk == 0:
-        return hb, bq, bk
-    return None
+    ``tile`` label all read it. ``block_diffusion=B``: the call is
+    block-masked, and is taken where its blocks are square, cut a half
+    of the row into whole blocks and are whole blocks of B, and its
+    backward is the ONE call (``_fused_fits``; ``itemsize``: of q, which
+    that count reads)."""
+    hb, bq, bk = tile = _pick_tile(h, tq, tk, q_block, k_block, dh, group,
+                                   dv)
+    if not (kernels_enabled() and tq % bq == 0 and tk % bk == 0):
+        return None
+    if block_diffusion and not (
+            bq == bk and tq == tk and (tq // 2) % bq == 0
+            and bq % block_diffusion == 0
+            and _fused_fits(tile, tq, tk, dh, dv or dh, group, itemsize)):
+        return None
+    return tile
 
 
 def tile_label(tile) -> str:
@@ -255,8 +286,10 @@ def bhtd_edge_tile(tile, causal, form="fused"):
     """-> (sq, sk) or None: the sub-tiles in which the backward call of
     this tile walks its edge blocks. The one place that decides:
     ``_fused_bwd``, ``bhtd_pairs`` and the dispatch counter's ``edge``
-    label read it. No edge without ``causal``; the forward (``form``
-    None) and the split pair work on an edge block whole."""
+    label read it. No edge without ``causal`` (a block-masked call's
+    edge blocks are not the diagonal's and are worked on whole: its
+    ``causal`` is False); the forward (``form`` None) and the split pair
+    work on an edge block whole."""
     if tile is None or not causal or form != "fused":
         return None
     return _edge_tile(tile[1], tile[2])
@@ -289,11 +322,13 @@ def bhtd_stats_form(tile, tq):
 
 
 def bhtd_family(h, tq, tk, q_block=None, k_block=None, *, dh,
-                group=1, dv=None) -> str:
+                group=1, dv=None, block_diffusion=None, itemsize=2) -> str:
     """"bhtd" (the K-blocked [b, h, t, dh] kernels) when the picked
-    blocks tile both sequence lengths, else "dense"."""
+    blocks tile both sequence lengths (and, block-masked, the call is
+    one ``bhtd_tile`` takes), else "dense"."""
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group,
-                     dv=dv)
+                     dv=dv, block_diffusion=block_diffusion,
+                     itemsize=itemsize)
     return "bhtd" if tile else "dense"
 
 
@@ -327,8 +362,17 @@ def _bwd_vmem_limit(*call):
     return max(16 * 2**20, _bwd_vmem_bytes(*call) * 5 // 4)
 
 
+def _fused_fits(tile, tq, tk, dh, dv, group, itemsize, p_drop=0.0):
+    """Does the ONE backward call take this tile (``bhtd_bwd_form`` says
+    why each condition)?"""
+    hb, bq, bk = tile
+    return not (hb > 1 or p_drop > 0.0 or bhtd_stats_form(tile, tq) != "rows"
+                or _bwd_vmem_bytes(tq, tk, dh, dv, group, bq, bk, itemsize)
+                > _BWD_VMEM_CAP_BYTES)
+
+
 def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
-                  dv=None, itemsize=2, p_drop=0.0):
+                  dv=None, itemsize=2, p_drop=0.0, block_diffusion=None):
     """-> "fused" (ONE call, ``attn.bhtd.bwd``: a live block's scores,
     exp and dp computed once, dq, dk and dv taken from them), "split"
     (the pair ``attn.bhtd.bwd_dq`` + ``attn.bhtd.bwd_dkv``) or None (no
@@ -343,17 +387,16 @@ def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
     that cut lse and delta from [1, tq] rows (a multiple of the 128
     lanes, or the whole row); no dropout (the mask stream is keyed by
     the split grids' head group). A bias does not matter: both forms
-    carry it."""
+    carry it. A block-masked call (``block_diffusion``) has a tile only
+    where it is fused (``bhtd_tile``)."""
     dv = dv or dh
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+                     block_diffusion=block_diffusion, itemsize=itemsize)
     if tile is None:
         return None
-    hb, bq, bk = tile
-    if (hb > 1 or p_drop > 0.0 or bhtd_stats_form(tile, tq) != "rows"
-            or _bwd_vmem_bytes(tq, tk, dh, dv, group, bq, bk, itemsize)
-            > _BWD_VMEM_CAP_BYTES):
-        return "split"
-    return "fused"
+    if _fused_fits(tile, tq, tk, dh, dv, group, itemsize, p_drop):
+        return "fused"
+    return "split"
 
 
 # ---------------------------------------------------------------------------
@@ -457,6 +500,122 @@ def _band_live(j, kk, bq, bk, window):
     return live & (j <= _last_q(kk, bq, bk, window))
 
 
+# Block diffusion's mask. ``bd`` = (B, L): the row is a noised and a
+# clean copy of L positions in blocks of B, and with P = p mod L, S = s
+# mod L, bp = P // B, bs = S // B a pair is visible where
+#
+#   p <  L, s <  L:  bp == bs     a noised block sees itself, both ways
+#   p <  L, s >= L:  bs <  bp     and the clean blocks before it
+#   p >= L, s >= L:  bs <= bp     the clean half is block-causal
+#   p >= L, s <  L:  never
+#
+# Kernel blocks are square (bq), cut a half into nh = L // bq of them and
+# hold whole blocks of B (bhtd_tile), so q-block j of the noised half has
+# live k-blocks j (its diagonal block: an edge), then nh .. nh + j of the
+# clean half (the last, its own positions' clean copy, an edge: bs < bp),
+# and q-block nh + j of the clean half has nh .. nh + j (the last, its
+# diagonal block, an edge: bs <= bp). Both walks visit a row's own
+# diagonal block FIRST, so a row's running maximum is finite before it
+# meets a block in which some of its queries see nothing.
+
+
+def bd_visible(t, block):
+    """[t, t] bool: the rule above over a row of t = 2 L positions, the
+    ONE dense copy of it (the composition's mask, the tests')."""
+    i = jnp.arange(t)
+    noised, blk = i < t // 2, (i % (t // 2)) // block
+    bp, bs = blk[:, None], blk[None, :]
+    return jnp.where(noised[:, None],
+                     jnp.where(noised[None, :], bp == bs, bs < bp),
+                     jnp.logical_and(~noised[None, :], bs <= bp))
+
+
+def _halves(block_diffusion, causal, window, tq, tk):
+    """``bd`` = (B, L) of a block-masked call, None for any other."""
+    if not block_diffusion:
+        return None
+    block = int(block_diffusion)
+    if (causal or window is not None or tq != tk or tq % 2
+            or block < 1 or (tq // 2) % block):
+        raise ValueError(
+            f"attention: block_diffusion={block_diffusion} needs "
+            f"self-attention over a row of two halves of whole blocks, "
+            f"and neither causal nor a window (causal={causal}, "
+            f"window={window}, tq={tq}, tk={tk})")
+    return block, tq // 2
+
+
+def _bd_k_step(j, r, bq, bd):
+    """Step r of q-row j, k-blocks inner -> (the k-block it works on,
+    is it live, is it an edge): the row's own block (r = 0), then the
+    clean half's from its first."""
+    nh = bd[1] // bq
+    # clean blocks the row sees: a noised row j its own positions' too
+    seen = jnp.where(j < nh, j + 1, j - nh)
+    kk = jnp.where(r == 0, j, nh + r - 1)
+    return (kk, jnp.logical_or(r == 0, r - 1 < seen),
+            jnp.logical_or(r == 0, r - 1 == j))
+
+
+def _bd_k_fetch(j, r, bq, bd):
+    """The k-block step r of q-row j READS: a dead step the row's last
+    live one."""
+    nh = bd[1] // bq
+    last = jnp.where(j < nh, j, j - nh - 1)
+    return jnp.where(r == 0, j,
+                     nh + jnp.clip(r - 1, 0, jnp.maximum(last, 0)))
+
+
+def _bd_q_step(kk, r, bq, bd):
+    """Step r of k-row kk, q-blocks inner -> (the q-block it works on,
+    is it live, is it an edge). A noised k-row is seen by its own
+    q-block alone (step 0). Clean k-row nh + c: by the noised q-blocks
+    c .. nh - 1 (the first an edge), then by the clean ones nh + c .. 2
+    nh - 1 (the first, its diagonal block, an edge)."""
+    nh = bd[1] // bq
+    c = kk - nh
+    noised = kk < nh
+    j = jnp.where(noised, kk, jnp.where(r < nh - c, c + r, r + 2 * c))
+    return (j, jnp.where(noised, r == 0, r < 2 * (nh - c)),
+            jnp.logical_or(r == 0, r == nh - c))
+
+
+def _bd_q_fetch(kk, r, bq, bd):
+    """The q-block step r of k-row kk READS: a dead step the row's last
+    live one."""
+    return jnp.minimum(_bd_q_step(kk, r, bq, bd)[0], 2 * (bd[1] // bq) - 1)
+
+
+def _block_of(x, block):
+    """x // block of int32 positions (a shift where it is one)."""
+    if block & (block - 1) == 0:
+        return jax.lax.shift_right_logical(
+            x, jnp.int32(block.bit_length() - 1))
+    return x // block
+
+
+def _bd_mask(s, j, kk, bq, bd, transposed=False):
+    """Mask edge block (q=j, k=kk)'s scores [.., bq, bk] (``transposed``:
+    [.., bk, bq]). An edge block's queries and keys start at the same
+    position of their halves, so a pair's blocks compare by the rows'
+    and the columns' own indices: a noised query sees its own block of
+    the noised keys, the blocks before its own of the clean ones; a
+    clean query its own and those before."""
+    nh = bd[1] // bq
+    qa, ka = ((s.ndim - 1, s.ndim - 2) if transposed
+              else (s.ndim - 2, s.ndim - 1))
+    ahead = (_block_of(jax.lax.broadcasted_iota(jnp.int32, s.shape, qa),
+                       bd[0])
+             - _block_of(jax.lax.broadcasted_iota(jnp.int32, s.shape, ka),
+                         bd[0]))                 # bp - bs
+    q_noised, k_noised = j < nh, kk < nh
+    least = jnp.where(
+        jnp.logical_and(q_noised, jnp.logical_not(k_noised)), 1, 0)
+    most = jnp.where(jnp.logical_and(q_noised, k_noised), 0, bq)
+    seen = jnp.logical_and(ahead >= least, ahead <= most)
+    return jnp.where(seen, s, _NEG_INF)
+
+
 @functools.lru_cache(maxsize=None)
 def _edge_slabs(tq, tk, bq, bk, sub, window):
     """How a call's edge blocks are walked in sub-tiles of ``sub`` = (sq,
@@ -501,16 +660,18 @@ def _edge_slabs(tq, tk, bq, bk, sub, window):
     return slabs
 
 
-def _when_live(compute, live, j, kk, bq, bk, window, slabs=None):
-    """Run a causal step on block (j, kk), ``live`` or dead: not at all
+def _when_live(compute, live, j, kk, bq, bk, window, slabs=None, edge=None):
+    """Run a masked step on block (j, kk), ``live`` or dead: not at all
     where dead, ``compute(masked=False)`` on a plain block (all of it
     visible), ``compute(masked=True)`` on an edge block (the diagonal or
-    the band's far edge crosses it). With ``slabs`` (_edge_slabs) an
+    the band's far edge crosses it; ``edge``: the caller's own answer,
+    a block-masked call's). With ``slabs`` (_edge_slabs) an
     edge block is walked in the slabs of its live sub-tiles instead, in
     VMEM as the block lies there: ``compute(masked, at=(q0, rows, k0,
     cols))``, one straight line a block; its dead sub-tiles cost
     nothing."""
-    edge = _on_edge(j, kk, bq, bk, window)
+    if edge is None:
+        edge = _on_edge(j, kk, bq, bk, window)
     pl.when(jnp.logical_and(live, jnp.logical_not(edge)))(
         functools.partial(compute, masked=False))
     if not slabs:
@@ -531,15 +692,28 @@ def _slab(at, bq, bk):
     return slice(q0, q0 + rows), slice(k0, k0 + cols), (q0, k0)
 
 
-def bhtd_pairs(tq, tk, tile, causal, window=None, form="fused"):
+def bhtd_pairs(tq, tk, tile, causal, window=None, form="fused",
+               block_diffusion=None):
     """-> (computed, live): the score pairs (query position, key
     position) that the steps of ONE head compute in the backward call of
     this tile (``form`` None: in the forward, or in the split pair, whose
     edge blocks are whole), and those of them the mask lets through. A
     pure function of the call's geometry, by the kernels' own
     predicates: a dead block or sub-tile is not computed, a plain or an
-    edge one is computed whole."""
+    edge one is computed whole. ``block_diffusion``: the block-masked
+    call's, L^2 + B L live pairs, the same blocks either pass (its edge
+    blocks are whole)."""
     _, bq, bk = tile
+    bd = _halves(block_diffusion, causal, window, tq, tk)
+    if bd is not None:
+        nq, (block, half) = tq // bq, bd
+        rows = jnp.arange(nq)[:, None]
+        if form is None:    # the forward's walk: nh + 1 steps a q-row
+            steps = jnp.arange(half // bq + 1)[None, :]
+            live = _bd_k_step(rows, steps, bq, bd)[1]
+        else:               # the backward's: nq steps a k-row
+            live = _bd_q_step(rows, jnp.arange(nq)[None, :], bq, bd)[1]
+        return int(live.sum()) * bq * bk, half * half + block * half
     if not causal:
         return tq * tk, tq * tk
     window = _band(window, causal, tq, tk)
@@ -579,11 +753,14 @@ def _lanes(x, n):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, nk, ng, p_drop,
-                causal=False, window=None):
+                causal=False, window=None, bd=None):
     # r: the inner axis's step, nk of them; kk the k-block it works on
     j, r = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
-    kk = r if window is None else _first_k(j, bq, bk, window) + r
+    if bd is not None:
+        kk, bd_live, bd_edge = _bd_k_step(j, r, bq, bd)
+    else:
+        kk = r if window is None else _first_k(j, bq, bk, window) + r
 
     @pl.when(r == 0)
     def _init():
@@ -601,7 +778,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         ) * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
-        if masked:
+        if masked and bd is not None:
+            s = _bd_mask(s, j, kk, bq, bd)
+        elif masked:
             s = _causal_mask(s, j, kk, bq, bk, window=window)
 
         # m and l live replicated along the 128 lanes of their scratch:
@@ -628,7 +807,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    if causal:
+    if bd is not None:
+        _when_live(_compute, bd_live, j, kk, bq, bk, None, edge=bd_edge)
+    elif causal:
         # (a band's steps start at the q-row's first live k-block)
         _when_live(_compute, _causal_live(j, kk, bq, bk), j, kk, bq, bk,
                    window)
@@ -842,11 +1023,12 @@ def _each_block(acc, rows, body):
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
                 *, scale, nq, nk, group, causal=False, window=None,
-                last_q=None, slabs=None):
+                last_q=None, slabs=None, bd=None):
     """attn.bhtd.bwd: grid (batch row, key/value head, member of its
     group, k-block, step), one head a step: the dk/dv kernel's walk, a
     k-row's ``nq`` steps over the q-blocks (with a window: over its
-    band, from its first q-block; ``last_q``: the sequence's last one).
+    band, from its first q-block; ``last_q``: the sequence's last one;
+    block-masked, ``bd``: over the q-blocks that see it, _bd_q_step).
     Every live block adds to all three gradients, so what a row's
     scratch cannot gather stays RESIDENT in VMEM: dq [tq, dh] for the
     query head (every k-row adds to the q-blocks it sees), and, where a
@@ -857,7 +1039,10 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     del seed_ref                        # (no dropout: bhtd_bwd_form)
     m, kk, r = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
-    j = r if window is None else (kk * bk) // bq + r
+    if bd is not None:
+        j, bd_live, bd_edge = _bd_q_step(kk, r, bq, bd)
+    else:
+        j = r if window is None else (kk * bk) // bq + r
 
     def span(resident, shared):
         """(first, last) step of what an accumulator gathers: a k-row's
@@ -889,7 +1074,9 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         # of the accumulators)
         qs, ks, start = _slab(at, bq, bk)
         mask = bias = None
-        if masked:
+        if masked and bd is not None:
+            mask = lambda s_t: _bd_mask(s_t, j, kk, bq, bd, transposed=True)
+        elif masked:
             mask = lambda s_t: _causal_mask(
                 s_t[None], j, kk, bq, bk, transposed=True, window=window,
                 at=start)[0]
@@ -904,7 +1091,9 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 accs, parts, (qs, ks, ks)):
             acc[_block_rows(acc, idx, rows, inside), :] += part
 
-    if causal:
+    if bd is not None:
+        _when_live(_compute, bd_live, j, kk, bq, bk, None, edge=bd_edge)
+    elif causal:
         # (a band's steps start at the k-row's first live q-block)
         live = (_causal_live(j, kk, bq, bk) if window is None else
                 j <= jnp.minimum(_last_q(kk, bq, bk, window), last_q))
@@ -919,7 +1108,7 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 
 def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
-                 steps=None):
+                 steps=None, bd=None):
     """-> f(*grid ids) = (i, g, j, kk): batch row, head group, q-block
     and k-block a grid step READS. The grid is (i, g, j, kk) with the k
     axis inner (forward, dq) or (i, g, kk, j) with the q axis inner
@@ -930,7 +1119,8 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
     the group's heads and, inside one, its q-blocks. With a ``window``
     the inner axis has ``steps`` steps (a head), the band's width in
     blocks: step r reads the r-th block of its row's band, a dead step
-    the band's last."""
+    the band's last. Block-masked (``bd``): step r reads the r-th block
+    of its row's own walk (_bd_k_fetch, _bd_q_fetch)."""
     steps = steps or nq
 
     def f(*ids):
@@ -938,7 +1128,11 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
         j, kk = (ids[2], ids[3]) if k_inner else (ids[3], ids[2])
         if group > 1 and not k_inner:
             g, j = g * group + j // steps, j % steps
-        if window is not None and k_inner:
+        if bd is not None and k_inner:
+            kk = _bd_k_fetch(j, kk, bq, bd)
+        elif bd is not None:
+            j = _bd_q_fetch(kk, j, bq, bd)
+        elif window is not None and k_inner:
             kk = jnp.minimum(_first_k(j, bq, bk, window) + kk,
                              ((j + 1) * bq - 1) // bk)
         elif window is not None:
@@ -1005,8 +1199,10 @@ def _bias_spec(bias, at, hb, bq, bk):
         (1, hb if per_head else 1, bq if per_row else 1, bk), idx)
 
 
-def _reference_scores(q, k, bias, scale, causal, window=None):
-    """Scaled scores + bias + causal (and window) mask — the ONE copy
+def _reference_scores(q, k, bias, scale, causal, window=None,
+                      block_diffusion=None):
+    """Scaled scores + bias + causal (and window) mask, or block
+    diffusion's (``bd_visible``) — the ONE copy
     both the dense forward and its lse statistic derive from (the
     ring-attention merge combines (out, lse), so they must never
     desynchronize)."""
@@ -1014,6 +1210,9 @@ def _reference_scores(q, k, bias, scale, causal, window=None):
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias.astype(s.dtype)
+    if _halves(block_diffusion, causal, window, q.shape[2], k.shape[2]):
+        s = jnp.where(bd_visible(q.shape[2], int(block_diffusion))[None, None],
+                      s, _NEG_INF)
     if causal:
         tq, tk = q.shape[2], k.shape[2]
         ago = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
@@ -1025,7 +1224,8 @@ def _reference_scores(q, k, bias, scale, causal, window=None):
 
 
 def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
-                                  seed=None, causal=False, window=None):
+                                  seed=None, causal=False, window=None,
+                                  block_diffusion=None):
     """(out, lse) from ONE score tensor — the fallback twin of the
     kernels' contract. out and lse must never derive from separately
     constructed scores (different dtype promotion would desynchronize
@@ -1035,7 +1235,7 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    s = _reference_scores(q, k, bias, scale, causal, window)
+    s = _reference_scores(q, k, bias, scale, causal, window, block_diffusion)
     lse = jax.scipy.special.logsumexp(s, axis=-1, keepdims=True)
     p = jax.nn.softmax(s, axis=-1)
     if p_drop > 0.0:
@@ -1046,9 +1246,10 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
 
 
 def _reference_attention(q, k, v, bias, scale, p_drop=0.0, seed=None,
-                         causal=False, window=None):
+                         causal=False, window=None, block_diffusion=None):
     return _reference_attention_with_lse(q, k, v, bias, scale, p_drop,
-                                         seed, causal, window)[0]
+                                         seed, causal, window,
+                                         block_diffusion)[0]
 
 
 def _seed_arr(seed):
@@ -1161,7 +1362,8 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
                         q_block: Optional[int] = None,
                         k_block: Optional[int] = None,
                         causal: bool = False,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        block_diffusion: Optional[int] = None):
     """-> (out, lse) with lse [b, h, tq, 1] f32 — REAL logsumexp rows on
     every path including the dense fallback (the ring-attention merge
     consumes them; the fallback backward still recomputes via vjp).
@@ -1181,7 +1383,10 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     decoder self-attention, and the dead upper-triangle blocks cost
     neither MXU time nor a fetch (the causal ~2x). ``window``: each
     query sees the last ``window`` positions only; the grid walks the
-    band and no block outside it is a step at all."""
+    band and no block outside it is a step at all. ``block_diffusion``
+    (with neither): the row is a noised and a clean copy in blocks of
+    that many positions, under block diffusion's mask (module
+    docstring)."""
     if p_drop > 0.0 and seed is None:
         raise ValueError(
             "flash_attention: p_drop > 0 requires a per-step `seed`; "
@@ -1193,26 +1398,33 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
+    bd = _halves(block_diffusion, causal, window, tq, tk)
     window = _band(window, causal, tq, tk)
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+                     block_diffusion=block_diffusion,
+                     itemsize=q.dtype.itemsize)
     if tile is None:
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
         # derive from one score tensor (_reference_attention_with_lse).
         return _reference_attention_with_lse(
             q, k, v, bias, scale, p_drop,
-            seed if p_drop > 0.0 else None, causal=causal, window=window)
+            seed if p_drop > 0.0 else None, causal=causal, window=window,
+            block_diffusion=block_diffusion)
 
     hb, bq, bk = tile
     ng, nq = h // hb, tq // bq
-    # the inner axis: the key blocks, or those of a row's band
-    nk = _k_steps(window, nq, tk // bk, bq, bk)
+    # the inner axis: the key blocks, or those of a row's band, or a
+    # block-masked row's own block and the clean half's
+    nk = (bd[1] // bq + 1 if bd else
+          _k_steps(window, nq, tk // bk, bq, bk))
     kernel, in_specs, args, rows = _call_parts(
         _fwd_kernel,
-        _step_blocks(causal, True, bq, bk, nq, group, window, nk), tile,
+        _step_blocks(causal, True, bq, bk, nq, group, window, nk, bd), tile,
         q, k, v, bias)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
-                               p_drop=p_drop, causal=causal, window=window)
+                               p_drop=p_drop, causal=causal, window=window,
+                               bd=bd)
     operands = (_seed_arr(seed), *args)
     lse_spec, lse_shape = rows.stat, (b, h, tq, 1)
     if bhtd_stats_form(tile, tq) == "rows":
@@ -1242,16 +1454,19 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
 
 
 def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
-               window):
+               window, bd=None):
     """dq, dk, dv as ONE call (_bwd_kernel); ``delta`` with the lse
-    cotangent folded in, ``window`` as _band gives it."""
+    cotangent folded in, ``window`` as _band gives it, ``bd`` as
+    _halves."""
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
     _, bq, bk = tile
     nq, nk = tq // bq, tk // bk
-    q_steps = _q_steps(window, nq, nk, bq, bk)
-    block_of = _step_blocks(causal, False, bq, bk, nq, 1, window, q_steps)
+    # (block-masked: the first clean k-row is seen by every q-block)
+    q_steps = nq if bd else _q_steps(window, nq, nk, bq, bk)
+    block_of = _step_blocks(causal, False, bq, bk, nq, 1, window, q_steps,
+                            bd)
 
     def at(i, hk, m, kk, r, *_):
         # (the grid's heads: key/value head, then the member of its group)
@@ -1263,7 +1478,7 @@ def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
     kernel = functools.partial(
         kernel, scale=scale, nq=q_steps, nk=nk, group=group, causal=causal,
         window=window, last_q=nq - 1,
-        slabs=sub and _edge_slabs(tq, tk, bq, bk, sub, window))
+        slabs=sub and _edge_slabs(tq, tk, bq, bk, sub, window), bd=bd)
     # a resident gradient: all rows of one head, one block of the output
     dq_spec = pl.BlockSpec((1, 1, tq, dh),
                            lambda i, hk, m, *_: (i, hk * group + m, 0, 0))
@@ -1305,7 +1520,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                         q_block: Optional[int] = None,
                         k_block: Optional[int] = None,
                         causal: bool = False, g_lse=None,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        block_diffusion: Optional[int] = None):
     """-> (dq, dk, dv), consuming the forward's saved (out, lse).
 
     ``g_lse``: optional cotangent of the lse OUTPUT ([b, h, tq, 1]).
@@ -1319,14 +1535,17 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
+    bd = _halves(block_diffusion, causal, window, tq, tk)
     window = _band(window, causal, tq, tk)
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv)
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+                     block_diffusion=block_diffusion,
+                     itemsize=q.dtype.itemsize)
     if tile is None:
         def f(q, k, v):
             return _reference_attention_with_lse(
                 q, k, v, bias, scale, p_drop,
                 seed if p_drop > 0.0 else None, causal=causal,
-                window=window)
+                window=window, block_diffusion=block_diffusion)
 
         _, vjp = jax.vjp(f, q, k, v)
         return vjp((g, jnp.zeros((b, h, tq, 1), jnp.float32)
@@ -1339,10 +1558,12 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     seed_arr = _seed_arr(seed)
-    if bhtd_bwd_form(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
-                     itemsize=q.dtype.itemsize, p_drop=p_drop) == "fused":
+    # (a block-masked call has a tile only where it is fused and has no
+    # dropout: bhtd_tile)
+    if bd is not None or _fused_fits(tile, tq, tk, dh, dv, group,
+                                     q.dtype.itemsize, p_drop):
         return _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile,
-                          scale, causal, window)
+                          scale, causal, window, bd)
     kw = dict(scale=scale, ng=ng, p_drop=p_drop, causal=causal,
               window=window)
     # the inner axes: all nk key blocks a q-row and all nq query blocks
@@ -1421,42 +1642,48 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def flash_attention(q, k, v, bias=None, seed=None,
                     scale: Optional[float] = None, p_drop: float = 0.0,
                     q_block: Optional[int] = None,
                     k_block: Optional[int] = None,
                     causal: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None):
     """o = dropout(softmax(q k^T * scale + bias)) v.
 
     ``seed``: int32 scalar array driving attention dropout (ignored when
     p_drop == 0). See the module docstring for the bias-gradient caveat.
     """
     out, _ = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                                 q_block, k_block, causal, window)
+                                 q_block, k_block, causal, window,
+                                 block_diffusion)
     return out
 
 
 def _vjp_fwd(q, k, v, bias, seed, scale, p_drop, q_block, k_block,
-             causal=False, window=None):
+             causal=False, window=None, block_diffusion=None):
     out, lse = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                                   q_block, k_block, causal, window)
+                                   q_block, k_block, causal, window,
+                                   block_diffusion)
     return out, (q, k, v, bias, seed, out, lse)
 
 
-def _vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res, g,
-             g_lse=None):
+def _vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
+             block_diffusion, res, g, g_lse=None):
     q, k, v, bias, seed, out, lse = res
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if bhtd_family(q.shape[1], q.shape[2], k.shape[2],
                     q_block, k_block, dh=q.shape[3],
                     group=q.shape[1] // k.shape[1],
-                    dv=v.shape[3]) == "bhtd":
+                    dv=v.shape[3], block_diffusion=block_diffusion,
+                    itemsize=q.dtype.itemsize) == "bhtd":
         dq, dk, dv = flash_attention_bwd(q, k, v, bias, seed, out, lse, g,
                                          scale, p_drop, q_block, k_block,
-                                         causal, g_lse=g_lse, window=window)
+                                         causal, g_lse=g_lse, window=window,
+                                         block_diffusion=block_diffusion)
         # Pallas path: bias is mask plumbing, cotangent intentionally zero
         # (see module docstring).
         dbias = None if bias is None else jnp.zeros_like(bias)
@@ -1467,7 +1694,8 @@ def _vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res, g,
         def out_and_lse(a, b, c, bb):
             return _reference_attention_with_lse(
                 a, b, c, bb, scale, p_drop, sd, causal,
-                _band(window, causal, a.shape[2], b.shape[2]))
+                _band(window, causal, a.shape[2], b.shape[2]),
+                block_diffusion)
 
         if bias is None:
             _, vjp = jax.vjp(
@@ -1493,36 +1721,40 @@ flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 # path, sharing the same kernels.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def flash_attention_with_lse(q, k, v, bias=None, seed=None,
                              scale: Optional[float] = None,
                              p_drop: float = 0.0,
                              q_block: Optional[int] = None,
                              k_block: Optional[int] = None,
                              causal: bool = False,
-                             window: Optional[int] = None):
+                             window: Optional[int] = None,
+                             block_diffusion: Optional[int] = None):
     """(out, lse) variant of ``flash_attention`` — same backward rule
     (shared ``_vjp_bwd``: blocked Pallas kernels, true dbias on the dense
     fallback, float0 seed cotangent). The sdpa op uses this so its saved
     Lse output exists AND jax.vjp through the op (scan-over-layers grad)
     works despite pallas_call having no JVP rule."""
     return flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                               q_block, k_block, causal, window)
+                               q_block, k_block, causal, window,
+                               block_diffusion)
 
 
 def _fa_lse_vjp_fwd(q, k, v, bias, seed, scale, p_drop, q_block, k_block,
-                    causal=False, window=None):
+                    causal=False, window=None, block_diffusion=None):
     out, lse = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
-                                   q_block, k_block, causal, window)
+                                   q_block, k_block, causal, window,
+                                   block_diffusion)
     return (out, lse), (q, k, v, bias, seed, out, lse)
 
 
-def _fa_lse_vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res,
-                    gs):
+def _fa_lse_vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
+                    block_diffusion, res, gs):
     g, g_lse = gs
     q = res[0]
-    return _vjp_bwd(scale, p_drop, q_block, k_block, causal, window, res,
-                    g.astype(q.dtype), g_lse=g_lse)
+    return _vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
+                    block_diffusion, res, g.astype(q.dtype), g_lse=g_lse)
 
 
 flash_attention_with_lse.defvjp(_fa_lse_vjp_fwd, _fa_lse_vjp_bwd)

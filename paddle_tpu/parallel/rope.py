@@ -158,7 +158,7 @@ def _vmem_bytes(rows, hb, hk, dh):
 
 
 def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
-              backend=None, on_mesh=None):
+              backend=None, on_mesh=None, periods=1):
     """-> (rows, hb): the positions and the heads of q one grid step of
     ``rope.*`` works on, or None where the call runs as the XLA form: no
     TPU backend (``backend``: None for this process's, with the
@@ -169,7 +169,9 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     pairs are neighbours (``interleaved``), a head that is not whole
     vregs of 128 lanes, a sequence no block of rows divides, or blocks
     over the VMEM cap. ``h`` and ``hk`` (None: as many) are q's and k's
-    heads.
+    heads. ``periods``: the positions run that many times over the row
+    (index i is position i mod t / periods); a block of rows then lies
+    inside one run.
 
     The tile follows the shape, not a flag: all of q's heads (a block of
     the token-major side is then whole rows of q, contiguous in HBM) by
@@ -184,8 +186,11 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
             or interleaved or dh % _LANES or min(b, t, h, hk) < 1):
         return None
+    if t % periods:
+        return None
     for rows in _BLOCK_ROWS:
-        if t % rows == 0 and _vmem_bytes(rows, h, hk, dh) <= _VMEM_CAP_BYTES:
+        if ((t // periods) % rows == 0
+                and _vmem_bytes(rows, h, hk, dh) <= _VMEM_CAP_BYTES):
             return rows, h
     return None
 
@@ -264,21 +269,25 @@ def _specs(rows, hb, hk, dh, tokens):
 
 @functools.partial(jax.jit, static_argnames=(
     "theta", "tile", "tokens_in", "tokens_out", "sign", "name", "interpret",
-    "scaling", "rotary_dim"))
+    "scaling", "rotary_dim", "periods"))
 def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret,
-          scaling=None, rotary_dim=None):
+          scaling=None, rotary_dim=None, periods=1):
     rows, hb = tile
     if tokens_in:
         (b, t, h, dh), hk = q.shape, k.shape[2]
         q, k = q.reshape(b, t, h * dh), k.reshape(b, t, hk * dh)
     else:
         (b, h, t, dh), hk = q.shape, k.shape[1]
-    assert t % rows == 0 and rows % _PASS_ROWS == 0 and h % hb == 0, (
-        q.shape, tile)
+    assert (t % periods == 0 and (t // periods) % rows == 0
+            and rows % _PASS_ROWS == 0 and h % hb == 0), (
+        q.shape, tile, periods)
     out_shapes = [(b, t, n * dh) if tokens_out else (b, n, t, dh)
                   for n in (h, hk)]
-    cos, sin = tables(t, dh, theta, scaling, rotary_dim)
-    table = pl.BlockSpec((rows, dh), lambda bi, i, j: (i, 0))
+    # the tables of ONE run of the positions, read once a run
+    run = t // periods // rows
+    cos, sin = tables(t // periods, dh, theta, scaling, rotary_dim)
+    table = pl.BlockSpec((rows, dh), (lambda bi, i, j: (i % run, 0))
+                         if periods > 1 else (lambda bi, i, j: (i, 0)))
     moved = (h + hk) * b * t * dh
     qo, ko = pl.pallas_call(
         functools.partial(_kernel, rows=rows, hb=hb, hk=hk, dh=dh,
@@ -312,22 +321,25 @@ def _part(x, rotary_dim):
 
 
 def rope_fwd(q, k, theta, tile, tokens=False, scaling=None,
-             rotary_dim=None):
+             rotary_dim=None, periods=1):
     """(q, k) with rotary positions 0 .. t - 1 applied, head-major
     [b, h, t, dh] (k may have fewer heads), at ``tile`` as ``rope_tile``
     gives it. ``tokens``: q and k come token-major, [b, t, h, dh]. One
     jitted function a (shape, layout): the layers of a model make the
     same call, and a step traces and lowers the kernel once for all.
     ``scaling``: a ``Yarn`` or None, what the tables hold;
-    ``rotary_dim``: the leading features that turn (None: the head)."""
+    ``rotary_dim``: the leading features that turn (None: the head);
+    ``periods``: the positions 0 .. t / periods - 1 run that many times
+    over the row (the tables hold one run)."""
     return _rope(q, k, theta=float(theta), tile=tuple(tile),
                  tokens_in=bool(tokens), tokens_out=False, sign=1.0,
                  name="rope.fwd", interpret=bool(_INTERPRET),
-                 scaling=scaling, rotary_dim=_part(q, rotary_dim))
+                 scaling=scaling, rotary_dim=_part(q, rotary_dim),
+                 periods=int(periods))
 
 
 def rope_bwd(dq, dk, theta, tile, tokens=False, scaling=None,
-             rotary_dim=None):
+             rotary_dim=None, periods=1):
     """The cotangents of ``rope_fwd``'s q and k from those of its
     results (head-major): the rotation's transpose, which is the
     rotation by the negated angles, written token-major where the
@@ -335,4 +347,5 @@ def rope_bwd(dq, dk, theta, tile, tokens=False, scaling=None,
     return _rope(dq, dk, theta=float(theta), tile=tuple(tile),
                  tokens_in=False, tokens_out=bool(tokens), sign=-1.0,
                  name="rope.bwd", interpret=bool(_INTERPRET),
-                 scaling=scaling, rotary_dim=_part(dq, rotary_dim))
+                 scaling=scaling, rotary_dim=_part(dq, rotary_dim),
+                 periods=int(periods))

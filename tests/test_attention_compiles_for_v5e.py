@@ -909,3 +909,92 @@ def test_bthd_small_pair_compiles_inside_a_while_body(one_chip, real_kernels):
         x, x, x, bias, seed, x, lse, x).compile().as_text()
     assert " while(" in text
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+# sdar-train-s4096's attention call: a row of 8192 positions (the noised
+# copy and the clean copy of 4096 data tokens) at 32 / 4 heads of 128
+# under block diffusion's mask, blocks of 4 (PR 61)
+_BD_CALL = (1, 32, 4, 8192, 128, 4)
+
+
+def test_the_block_masked_call_compiles_in_the_two_kernels(one_chip,
+                                                           real_kernels):
+    """Forward and backward in one jit: ``attn.bhtd.fwd`` and the ONE
+    ``attn.bhtd.bwd``, the logsumexp handed over as rows, and no tensor
+    of [t, t] scores anywhere in the compiled step (32 heads of them
+    would be 8.6 GB in float32)."""
+    b, h, hk, t, dh, block = _BD_CALL
+    kw = dict(dh=dh, group=h // hk, block_diffusion=block)
+    assert fa.bhtd_tile(h, t, t, **kw) == (1, 512, 512)
+    assert fa.bhtd_bwd_form(h, t, t, **kw) == "fused"
+    assert fa.bhtd_family(h, t, t, **kw) == "bhtd"
+    # 80 blocks of 512 x 512 a head either way for L^2 + B L live pairs
+    for form in (None, "fused"):
+        assert fa.bhtd_pairs(t, t, (1, 512, 512), False, form=form,
+                             block_diffusion=block) \
+            == (80 * 512 * 512, 4096 * 4096 + 4 * 4096)
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, t, dh), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def step(q, k, v, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, block_diffusion=block)
+        return out, fa.flash_attention_bwd(q, k, v, None, None, out, lse, g,
+                                           block_diffusion=block)
+
+    compiled = jax.jit(step).lower(arg(h), arg(hk), arg(hk), arg(h)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _calls(text) == {"attn.bhtd.fwd", "attn.bhtd.bwd"}
+    assert _fwd_results(text) == [f"bf16[{b},{h},{t},{dh}]",
+                                  f"f32[{b},{h},1,{t}]"]
+    assert f"{t},{t}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+def test_the_block_masked_op_reports_band_skip(one_chip, real_kernels):
+    """The cell's call through the op, forward and backward, lowered for
+    the described v5e: one row each way on the BHTD kernels, ``band=skip``
+    (the kernels walk the mask's live blocks), the backward one call."""
+    import paddle_tpu as fluid
+    from paddle_tpu import flags, layers, monitor
+    from paddle_tpu.core import lowering
+    from paddle_tpu.ops import attention_ops
+
+    b, h, hk, t, dh, block = _BD_CALL
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        q = layers.data("q", shape=[h, t, dh], dtype="float32")
+        k = layers.data("k", shape=[hk, t, dh], dtype="float32")
+        q.stop_gradient = k.stop_gradient = False
+        out = layers.scaled_dot_product_attention(q, k, k, dh ** -0.5,
+                                                  block_diffusion=block)
+        loss = layers.mean(out)
+        fluid.backward.append_backward(loss)
+    main._amp = True
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    flags.set_flags({"telemetry": True})
+    monitor.reset()
+    try:
+        low = lowering.lower_block(main, 0, ("q", "k"),
+                                   (loss.name, "q@GRAD", "k@GRAD"))
+        text = fluid.Executor._jit_for(low, None).lower(
+            {}, {"q": aval((b, h, t, dh), "bfloat16"),
+                 "k": aval((b, hk, t, dh), "bfloat16")},
+            aval((2,), "uint32"), aval((), "uint32")).compile().as_text()
+        rows = attention_ops.dispatch_counts(tiles=True, forms=True,
+                                             stats=True, masks=True)
+    finally:
+        flags.set_flags({"telemetry": False})
+        monitor.reset()
+    shape = f"b{b} tq{t} tk{t} h{h} kv{hk} dh{dh} [hb1 bq512 bk512]"
+    mask = f"mask=block_diffusion block={block} band=skip"
+    assert rows == {f"bhtd fwd {shape} stats=rows {mask}": 1,
+                    f"bhtd bwd {shape} form=fused {mask}": 1}
+    assert _calls(text) == {"attn.bhtd.fwd", "attn.bhtd.bwd"}
+    assert f"{t},{t}]" not in text

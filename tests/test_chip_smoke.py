@@ -649,6 +649,57 @@ def test_sconv_phase_fails_on_a_convolution_without_the_kernel(
         chip_smoke.sconv_phase(seq=512, t_check=256, **SCONV_TINY)
 
 
+BD_TINY = dict(
+    vocab_size=50, mask_token_id=49, hidden_size=128, num_attention_heads=8,
+    num_key_value_heads=1, head_dim=128, moe_intermediate_size=128,
+    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2),
+    num_hidden_layers=2)
+
+
+def _bd_interpreters(monkeypatch):
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import pair_sum as ps
+    from paddle_tpu.parallel import rope
+
+    for module in (fa, gm, ps, rope):
+        monkeypatch.setattr(module, "_INTERPRET", True)
+
+
+def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (8 query heads
+    a key/value head of 128 and blocks of 4 are the model's): every
+    layer lowers one block-masked attention call each way over the 2 x
+    512 positions in the BHTD kernels (``band=skip``), the backward one
+    call, the logsumexp in rows, and one rotary embedding each way on
+    the ``rope.*`` kernels; on the device (here: the CPU) the kernels
+    agree with the dense composition."""
+    _bd_interpreters(monkeypatch)
+    row = chip_smoke.bd_phase(seq=512, t_check=512, heads=(8, 1), **BD_TINY)
+    shape = "b1 tq1024 tk1024 h8 kv1 dh128 [hb1 bq512 bk512]"
+    mask = "mask=block_diffusion block=4 band=skip"
+    assert row["attention"] == {
+        f"bhtd fwd {shape} stats=rows {mask}": 2,
+        f"bhtd bwd {shape} form=fused {mask}": 2}
+    assert row["rotary_embeddings"] == {"kernel fwd bthd 128": 2,
+                                        "kernel bwd bthd 128": 2}
+    assert row["kernel_ms"] == {}               # (a trace needs the chip)
+    # one tile a half: the noised half's diagonal block, its clean block
+    # and the clean half's diagonal block, each an edge worked on whole
+    assert row["pairs"] == {"fwd": [3 * 512 * 512, 512 * 512 + 4 * 512],
+                            "fused": [3 * 512 * 512, 512 * 512 + 4 * 512]}
+    assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+def test_bd_phase_fails_on_a_block_masked_call_that_runs_dense(
+        telemetry, monkeypatch):
+    # a row whose half is no whole number of tiles: the calls are the
+    # dense composition, and the phase says so
+    _bd_interpreters(monkeypatch)
+    with pytest.raises(chip_smoke.SmokeFailure, match="none dense"):
+        chip_smoke.bd_phase(seq=384, t_check=512, heads=(8, 1), **BD_TINY)
+
+
 def test_attention_pairs_of_the_cells_calls(monkeypatch):
     """chip_smoke prints, for the decoder cells' BHTD calls, the score
     pairs a head's steps compute against those the mask lets through:

@@ -24,8 +24,13 @@ CHUNK = nn_ops._ROWS_CHUNK
 ROWS, WIDTH = 2 * CHUNK + 256, 32
 VOCAB = 3052            # BERT's 30522 scaled down: off the 128 lanes
 # name: rows of ROWS whose label counts
+# ("one", "chunk_and_a_row", "every_row": the last trip is the short one;
+# "a_short_trip_and_a_row": one row too many for it)
+SHORT = CHUNK // nn_ops._SHORT_TRIP
 LIVE = {"none": 0, "one": 1, "one_chunk": CHUNK, "chunk_and_a_row": CHUNK + 1,
-        "every_row": ROWS, "random_15_percent": None}
+        "every_row": ROWS, "random_15_percent": None,
+        "chunk_and_a_short_trip": CHUNK + SHORT,
+        "a_short_trip_and_a_row": SHORT + 1}
 
 
 def _operands(live, ignore_index, seed=0):
@@ -362,3 +367,27 @@ def test_berts_lowered_step_counts_no_hard_row_for_its_vocabulary(telemetry):
     assert nn_ops.loss_head_dispatch_counts() == {
         "hard_rows fwd 0": 1, "hard_rows bwd 0": 1,
         "hard fwd 0": 1, "hard bwd 0": 1}
+
+
+def test_the_last_trip_is_a_short_one_with_a_body_of_its_own():
+    """One loop of whole chunks each way, and in front of it a branch
+    that is the short trip, of a quarter of a chunk, or nothing
+    (nn_ops._over_chunks); rows that are no whole sublane tiles a
+    quarter have no branch, nor have more rows than four chunks."""
+    x, label, _, _ = _operands("random_15_percent", -1)
+    w = jnp.zeros((WIDTH, VOCAB), jnp.float32)
+
+    def projected(rows):
+        lbl, xs = (jnp.asarray(np.resize(a, (rows, a.shape[1])))
+                   for a in (label, x))
+        text = str(jax.make_jaxpr(lambda a, b: nn_ops._linear_xent(
+            a, b, lbl, -1))(xs, w))
+        return {n for n in (rows, CHUNK, SHORT, rows // nn_ops._SHORT_TRIP)
+                if f"f32[{n},{VOCAB}]" in text}, (
+                    text.count("while["), text.count("cond["))
+
+    assert projected(ROWS) == ({CHUNK, SHORT}, (1, 1))
+    assert projected(64) == ({64, 16}, (1, 1))
+    assert projected(60) == ({60}, (1, 0))
+    assert projected(4 * CHUNK) == ({CHUNK, SHORT}, (1, 1))
+    assert projected(4 * CHUNK + 8) == ({CHUNK}, (1, 0))
