@@ -109,9 +109,12 @@ AMP_FLOW_OP_TYPES = {
 # (layer_norm is absent: its kernel handles mixed dtypes itself — f32
 # internal math, x-dtype output — so no input casting is wanted.)
 
-# Slots that must stay f32 under AMP (saved numerical stats, not streams).
+# Slots that must stay f32 under AMP (saved numerical stats, not streams;
+# and the optimizer state an experts' grad op carries when its matrices'
+# Adam is taken inside it: ops/moe_ops._ADAM_IN).
 AMP_KEEP_F32_SLOTS = frozenset(
-    {"Lse", "GRAD::Lse", "G", "Beta", "GRAD::Loss"})
+    {"Lse", "GRAD::Lse", "G", "Beta", "GRAD::Loss",
+     "Param", "Moment1", "Moment2", "Beta1Pow", "Beta2Pow", "LearningRate"})
 
 # Whether AMP casting is active for the block currently being traced;
 # None while no block is (core/lowering.run_block sets it for the length

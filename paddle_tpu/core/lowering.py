@@ -17,6 +17,7 @@ here to the whole program.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -102,6 +103,27 @@ def analyze_state(
     return state_in, state_out
 
 
+def _with_read_grads(ops, fetch_names):
+    """``ops`` where an experts' grad op that took its matrices' Adam
+    (optimizer.AdamOptimizer._fold_into_experts_grad) writes a matrix's
+    gradient after all if something reads it: a fetch, or an op that was
+    appended behind ``minimize``. Nothing does in a program that only
+    trains, and the ops are the block's own."""
+    read = set(fetch_names).union(*(op.input_arg_names for op in ops))
+    out = []
+    for op in ops:
+        kept = {slot: g for slot, g in zip(op.attrs.get("adam_slots", ()),
+                                           op.attrs.get("adam_grads", ()))
+                if g in read}
+        if kept:
+            op = copy.copy(op)
+            op.attrs = {**op.attrs, "adam_keep_grads": list(kept)}
+            op.outputs = {**op.outputs, **{
+                "GRAD::" + slot: [g] for slot, g in kept.items()}}
+        out.append(op)
+    return out
+
+
 def lower_block(
     program: Program,
     block_idx: int,
@@ -120,7 +142,7 @@ def lower_block(
     op_defs = [resolve_op_def(op.type) for op in block.ops]
     needs_rng = any(d.needs_rng for d in op_defs)
 
-    ops = list(block.ops)
+    ops = _with_read_grads(block.ops, fetch_names)
 
     def run_block(state: Dict[str, Any], feeds: Dict[str, Any], key):
         env: Dict[str, Any] = {}

@@ -595,6 +595,66 @@ def _moe_experts(ins, attrs):
     return {"Ys": [ys], "Gate": [gate], "Up": [up]}
 
 
+# An experts' grad op that carries its matrices' Adam
+# (optimizer.AdamOptimizer._fold_into_experts_grad): attr ``adam_slots``
+# names the matrices, and each slot below holds one entry a matrix in
+# that order, under the names the ``adam`` op has them. The gradient of
+# such a matrix (attr ``adam_grads`` keeps its name) is no output of the
+# op, unless the lowering finds that something reads it after all (a
+# fetch, an op appended behind minimize): ``adam_keep_grads`` then names
+# the matrix (core/lowering._with_read_grads), its gradient is made and
+# written as ever and the step follows it.
+_ADAM_IN = ("Param", "Moment1", "Moment2", "Beta1Pow", "Beta2Pow",
+            "LearningRate")
+_ADAM_OUT = ("ParamOut", "Moment1Out", "Moment2Out", "Beta1PowOut",
+             "Beta2PowOut")
+
+
+def _adam_steps(ins, attrs):
+    """{matrix slot: (``grouped_matmul.AdamStep``, (Beta1PowOut,
+    Beta2PowOut))} for the matrices whose update this grad op takes:
+    the scalars as the ``adam`` / ``adamw`` op computes them."""
+    from paddle_tpu.ops import optimizer_ops as opt
+
+    steps, kept = {}, attrs.get("adam_keep_grads", ())
+    b1, b2 = attrs.get("beta1", 0.9), attrs.get("beta2", 0.999)
+    for i, slot in enumerate(attrs.get("adam_slots", ())):
+        p, m1, m2, b1p, b2p, lr = (_x(ins, name, i) for name in _ADAM_IN)
+        lr = lr.reshape(())
+        b1pn, b2pn = b1p * b1, b2p * b2
+        decay = None
+        if attrs["adam_op"] == "adamw":
+            decay = lr.astype(p.dtype) * attrs.get("weight_decay", 0.01)
+        steps[slot] = (_gm.AdamStep(
+            (p, m1, m2), opt.adam_lr_t(lr, b1pn, b2pn), decay, b1, b2,
+            attrs.get("epsilon", 1e-8)), (b1pn, b2pn), slot in kept)
+    return steps
+
+
+def _adam_of(steps, slot):
+    """``grouped_matmul_grads``' keyword for the matrix ``slot``."""
+    step = steps.get(slot)
+    return {"adam": step[0]} if step and not step[2] else {}
+
+
+def _experts_grad_outs(attrs, steps, dx, **dws):
+    """The grad op's results: GRAD::Xs, and for each matrix (WGate=..)
+    its gradient in the weight's dtype or, where its Adam step was taken
+    with it (``steps``), the ``adam`` op's five results."""
+    outs = {"GRAD::Xs": [dx]}
+    for slot in attrs.get("adam_slots", ()):
+        step, pows, kept = steps[slot]
+        state, dtype = dws.pop(slot)
+        if kept:    # state: the gradient, which somebody reads
+            outs["GRAD::" + slot] = [state.astype(dtype)]
+            state = step.after(outs["GRAD::" + slot][0])
+        for name, value in zip(_ADAM_OUT, (*state, *pows)):
+            outs.setdefault(name, []).append(value)
+    for slot, (dw, dtype) in dws.items():
+        outs["GRAD::" + slot] = [dw.astype(dtype)]
+    return outs
+
+
 def _plain_experts_grad(ins, attrs):
     """``moe_experts_grad`` for experts that are not gated units: the
     four grouped matmuls of the backward pass from the forward's saved
@@ -607,12 +667,16 @@ def _plain_experts_grad(ins, attrs):
     g = _x(ins, "GRAD::Ys").astype(dtype)
     kw, w = _live_rows(attrs, m), _window(attrs, m)
     act = _plain_unit(attrs)
+    steps = _adam_steps(ins, attrs)
+    adam = functools.partial(_adam_of, steps)
     _note_passes("moe_experts_grad", m, w, "gather_xs", "act", "act_grad")
     if w is None:
         h, act_vjp = jax.vjp(act, up)
-        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g)
+        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
+                                           **adam("WDown"))
         dup, = act_vjp(dh)
-        dx, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup)
+        dx, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
+                                           **adam("WUp"))
     else:
         live = jnp.sum(rows)
         # gathered again (behind a barrier, or XLA merges this gather
@@ -624,7 +688,8 @@ def _plain_experts_grad(ins, attrs):
         h = _act_live(act, up, live, w)
         # dh is read by window alone; dx is handed on: zeros behind
         dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
-                                           **kw, zero_behind=False)
+                                           **kw, zero_behind=False,
+                                           **adam("WDown"))
 
         def act_grad(r0):
             _, vjp = jax.vjp(act, rows_at(up, r0, w))
@@ -632,10 +697,9 @@ def _plain_experts_grad(ins, attrs):
 
         dup, = _live_pass(live, w, m, [(up.shape[1], dtype)], act_grad)
         dx, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
-                                           **kw)
-    return {"GRAD::Xs": [dx],
-            "GRAD::WUp": [dwu.astype(wu.dtype)],
-            "GRAD::WDown": [dwd.astype(wd.dtype)]}
+                                           **kw, **adam("WUp"))
+    return _experts_grad_outs(attrs, steps, dx, WUp=(dwu, wu.dtype),
+                              WDown=(dwd, wd.dtype))
 
 
 @register_op("moe_experts_grad", no_grad=True)
@@ -657,13 +721,16 @@ def _moe_experts_grad(ins, attrs):
     g = _x(ins, "GRAD::Ys").astype(dtype)
     kw, w = _live_rows(attrs, m), _window(attrs, m)
     glu = _gated_unit(attrs)
+    steps = _adam_steps(ins, attrs)
+    adam = functools.partial(_adam_of, steps)
     if w is not None:
         kw["zero_behind"] = False   # dh, dx_gate, dx_up: read by window
     _note_passes("moe_experts_grad", m, w, "gather_xs", "swiglu",
                  "swiglu_grad", "sum_dx")
     if w is None:
         h, swiglu_vjp = jax.vjp(glu, gate, up)
-        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g)
+        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
+                                           **adam("WDown"))
         dgate, dup = swiglu_vjp(dh)
     else:
         live = jnp.sum(rows)
@@ -675,7 +742,7 @@ def _moe_experts_grad(ins, attrs):
                           live, w)
         h = _glu_live(glu, gate, up, live, w)
         dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
-                                           **kw)
+                                           **kw, **adam("WDown"))
 
         def swiglu_grad(r0):
             _, vjp = jax.vjp(glu, rows_at(gate, r0, w),
@@ -685,18 +752,16 @@ def _moe_experts_grad(ins, attrs):
         dgate, dup = _live_pass(live, w, m, [(gate.shape[1], dtype)] * 2,
                                 swiglu_grad)
     dx_gate, dwg = _gm.grouped_matmul_grads(xs, wg.astype(dtype), rows,
-                                            dgate, **kw)
+                                            dgate, **kw, **adam("WGate"))
     dx_up, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
-                                          **kw)
+                                          **kw, **adam("WUp"))
     if w is None:
         dx = dx_gate + dx_up
     else:
         dx, = _live_pass(live, w, m, [(xs.shape[1], dtype)], lambda r0: (
             rows_at(dx_gate, r0, w) + rows_at(dx_up, r0, w),))
-    return {"GRAD::Xs": [dx],
-            "GRAD::WGate": [dwg.astype(wg.dtype)],
-            "GRAD::WUp": [dwu.astype(wu.dtype)],
-            "GRAD::WDown": [dwd.astype(wd.dtype)]}
+    return _experts_grad_outs(attrs, steps, dx, WGate=(dwg, wg.dtype),
+                              WUp=(dwu, wu.dtype), WDown=(dwd, wd.dtype))
 
 
 @register_op("moe_combine", diff_inputs=("Ys", "TopW"))

@@ -56,6 +56,44 @@ def _lars_momentum(ins, attrs):
     return {"ParamOut": [p - v_new], "VelocityOut": [v_new]}
 
 
+def adam_moments(g, m1, m2, b1, b2):
+    """(Moment1Out, Moment2Out) for the gradient ``g`` in the moments'
+    dtype. This and the four functions below are Adam's arithmetic, for
+    the ``adam`` / ``adamw`` ops and for the experts' weight-gradient
+    kernel, which runs them on its accumulator's tiles
+    (parallel/grouped_matmul.tgmm_adam)."""
+    return b1 * m1 + (1 - b1) * g, b2 * m2 + (1 - b2) * jnp.square(g)
+
+
+def adam_lr_t(lr, b1pn, b2pn):
+    """The learning rate with this step's bias correction in it."""
+    return lr * jnp.sqrt(1 - b2pn.reshape(())) / (1 - b1pn.reshape(()))
+
+
+def adam_param(p, m1n, m2n, lr_t, eps):
+    """ParamOut of ``adam`` from the new moments."""
+    upd = lr_t.astype(p.dtype) * (m1n / (jnp.sqrt(m2n) + eps)).astype(p.dtype)
+    return p - upd
+
+
+def adamw_param(p_new, p, lr_decay):
+    """ParamOut of ``adamw``: ``adam``'s less the decoupled decay, the
+    learning rate times ``weight_decay`` of the weight as it was."""
+    return p_new - lr_decay * p
+
+
+def adam_step(p, g, m1, m2, lr_t, b1, b2, eps, lr_decay=None):
+    """(ParamOut, Moment1Out, Moment2Out) of ``adam`` (``adamw`` with
+    ``lr_decay``, its learning rate times its decay) from the learning
+    rate with the bias correction in it: the three functions above in
+    the ops' order, for a caller that holds ``lr_t`` already."""
+    m1n, m2n = adam_moments(g, m1, m2, b1, b2)
+    new = adam_param(p, m1n, m2n, lr_t, eps)
+    if lr_decay is not None:
+        new = adamw_param(new, p, lr_decay)
+    return new, m1n, m2n
+
+
 @register_op("adam", no_grad=True)
 def _adam(ins, attrs):
     p, g = _g(ins, "Param"), _g(ins, "Grad")
@@ -65,14 +103,10 @@ def _adam(ins, attrs):
     b1 = attrs.get("beta1", 0.9)
     b2 = attrs.get("beta2", 0.999)
     eps = attrs.get("epsilon", 1e-8)
-    g = g.astype(m1.dtype)
-    m1n = b1 * m1 + (1 - b1) * g
-    m2n = b2 * m2 + (1 - b2) * jnp.square(g)
+    m1n, m2n = adam_moments(g.astype(m1.dtype), m1, m2, b1, b2)
     b1pn, b2pn = b1p * b1, b2p * b2
-    lr_t = lr * jnp.sqrt(1 - b2pn.reshape(())) / (1 - b1pn.reshape(()))
-    upd = lr_t.astype(p.dtype) * (m1n / (jnp.sqrt(m2n) + eps)).astype(p.dtype)
     return {
-        "ParamOut": [p - upd],
+        "ParamOut": [adam_param(p, m1n, m2n, adam_lr_t(lr, b1pn, b2pn), eps)],
         "Moment1Out": [m1n],
         "Moment2Out": [m2n],
         "Beta1PowOut": [b1pn],
@@ -86,7 +120,7 @@ def _adamw(ins, attrs):
     wd = attrs.get("weight_decay", 0.01)
     lr = _g(ins, "LearningRate").reshape(()).astype(p.dtype)
     outs = _adam(ins, attrs)
-    outs["ParamOut"][0] = outs["ParamOut"][0] - lr * wd * p
+    outs["ParamOut"][0] = adamw_param(outs["ParamOut"][0], p, lr * wd)
     return outs
 
 

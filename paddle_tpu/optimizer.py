@@ -16,6 +16,7 @@ from paddle_tpu.backward import append_backward
 from paddle_tpu.framework import (
     Parameter,
     Variable,
+    _normalize_slots,
     default_main_program,
     op_role_guard,
     program_guard,
@@ -173,7 +174,7 @@ class Optimizer:
         for pg in params_grads:
             if getattr(pg[1], "is_selected_rows", False):
                 self._append_sparse_optimize_op(block, pg)
-            else:
+            elif not self._fold_into_experts_grad(block, pg):
                 self._append_optimize_op(block, pg)
         self._finish_update(block, params_grads)
         # what the step moves without a gradient (Program._step_updates)
@@ -181,6 +182,13 @@ class Optimizer:
             block.append_op(**spec)
         prog._step_updates = []
         return block.ops[n_before:]
+
+    def _fold_into_experts_grad(self, block, param_and_grad) -> bool:
+        """True where the parameter's update was made part of the op
+        that computes its gradient and no update op is to be appended
+        (AdamOptimizer, for the matrices of a top-k MoE layer's
+        experts)."""
+        return False
 
     def _append_sparse_optimize_op(self, block, param_and_grad):
         raise NotImplementedError(
@@ -549,23 +557,87 @@ class AdamOptimizer(Optimizer):
     def _extra_attrs(self):
         return {}
 
-    def _append_optimize_op(self, block, param_and_grad):
-        p, g = param_and_grad
+    def _update_op(self, p, g):
+        """(inputs, outputs, attrs) of the parameter's update op."""
         m1 = self._get_accumulator("moment1", p)
         m2 = self._get_accumulator("moment2", p)
         b1p = self._get_accumulator("beta1_pow", p)
         b2p = self._get_accumulator("beta2_pow", p)
-        block.append_op(
-            self._op_type,
-            inputs={"Param": p, "Grad": g, "Moment1": m1, "Moment2": m2,
-                    "Beta1Pow": b1p, "Beta2Pow": b2p,
-                    "LearningRate": self._param_lr(p)},
-            outputs={"ParamOut": p.name, "Moment1Out": m1.name,
-                     "Moment2Out": m2.name, "Beta1PowOut": b1p.name,
-                     "Beta2PowOut": b2p.name},
-            attrs={"beta1": self._beta1, "beta2": self._beta2,
-                   "epsilon": self._epsilon, **self._extra_attrs()},
-        )
+        return ({"Param": p, "Grad": g, "Moment1": m1, "Moment2": m2,
+                 "Beta1Pow": b1p, "Beta2Pow": b2p,
+                 "LearningRate": self._param_lr(p)},
+                {"ParamOut": p.name, "Moment1Out": m1.name,
+                 "Moment2Out": m2.name, "Beta1PowOut": b1p.name,
+                 "Beta2PowOut": b2p.name},
+                {"beta1": self._beta1, "beta2": self._beta2,
+                 "epsilon": self._epsilon, **self._extra_attrs()})
+
+    def _append_optimize_op(self, block, param_and_grad):
+        inputs, outputs, attrs = self._update_op(*param_and_grad)
+        block.append_op(self._op_type, inputs=inputs, outputs=outputs,
+                        attrs=attrs)
+
+    def _fold_into_experts_grad(self, block, param_and_grad) -> bool:
+        """An expert matrix's Adam is taken by the op that makes its
+        gradient (``moe_experts_grad``, ops/moe_ops.py: inside the
+        weight-gradient kernel where the call has one, so the gradient
+        never goes to memory) where the program's graph allows it: the
+        gradient is written by that op as the gradient of this very
+        matrix and read by nothing (no clip, regulariser, sum of partial
+        gradients or norm has taken it: each of those hands on a
+        variable of its own), the learning rate is the optimizer's own
+        variable, and between that op and the end of the block nothing
+        reads or writes the weight or its state, so taking the step
+        there and at the end are one program. The op then takes the
+        ``adam`` op's inputs and writes its outputs, under the same
+        variable names, one entry a matrix (attr ``adam_slots``), and
+        the gradient leaves its outputs."""
+        p, g = param_and_grad
+        if self._op_type not in ("adam", "adamw") or \
+                (p.optimize_attr or {}).get("learning_rate", 1.0) != 1.0:
+            return False
+        at = next((i for i, op in enumerate(block.ops)
+                   if op.type == "moe_experts_grad"
+                   and g.name in op.output_arg_names), None)
+        if at is None:
+            return False
+        op = block.ops[at]
+        slot = next((s for s in ("WGate", "WUp", "WDown")
+                     if op.output("GRAD::" + s) == [g.name]
+                     and op.input(s) == [p.name]), None)
+        inputs, outputs, attrs = self._update_op(p, g)
+        attrs = {"adam_op": self._op_type, **attrs}
+        state = {v.name for k, v in inputs.items() if k != "Grad"}
+        lr = inputs["LearningRate"].name
+        if slot is None or any(
+                op.attrs.get(k, v) != v for k, v in attrs.items()):
+            return False
+        for b in block.program.blocks:
+            for i, other in enumerate(b.ops):
+                reads = set(other.input_arg_names)
+                writes = set(other.output_arg_names)
+                behind = b is not block or i > at
+                if g.name in reads or (g.name in writes and other is not op) \
+                        or (behind and ((reads | writes) & (state - {lr})
+                                        or lr in writes)):
+                    return False
+        # (and what apply_gradients appends behind the update ops)
+        for spec in block.program._step_updates:
+            for slots in (spec.get("inputs"), spec.get("outputs")):
+                if state & {n for names in _normalize_slots(slots).values()
+                            for n in names}:
+                    return False
+        del op.outputs["GRAD::" + slot]
+        op.attrs.update(attrs)
+        op.attrs["adam_slots"] = [*op.attrs.get("adam_slots", ()), slot]
+        op.attrs["adam_grads"] = [*op.attrs.get("adam_grads", ()), g.name]
+        for k, v in inputs.items():
+            if k != "Grad":
+                op.inputs.setdefault(k, []).append(v.name)
+        for k, name in outputs.items():
+            op.outputs.setdefault(k, []).append(name)
+        block.program._bump_version()
+        return True
 
     def _append_sparse_optimize_op(self, block, param_and_grad):
         # Lazy Adam on the touched rows (reference: adam_op.h lazy_mode)

@@ -26,7 +26,10 @@ sees or sets, at 45% of the v5e's FLOP roofline at OLMoE's widths):
   and the matmul's dimension numbers, not by a copy.
 - ``moe.tgmm.bwd_dw``: ``tgmm(lhs [m, k], g [m, n]) -> [E, k, n]``, the
   matrix's gradient lhs_e^T g_e over each expert's rows, zeros for an
-  expert that got none.
+  expert that got none. Where the gradient goes to the matrix's Adam
+  step and nowhere else (``grouped_matmul_grads(adam=)``) the same body
+  is ``moe.tgmm.bwd_dw_adam``, ``tgmm_adam``: the step is taken on the
+  float32 accumulator and the gradient is no array at all.
 
 A grid step works on one VISIT: one tile of ``tm`` rows and one expert
 with rows in it (``_visits``; the bookkeeping of
@@ -50,6 +53,7 @@ so ``ops/moe_ops.moe_experts`` saves what its backward needs.
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +81,9 @@ _WIDTH_TILES = (2048, 1024, 512, 256, 128)
 _M_DISPATCH = _monitor.counter(
     "pt_moe_gmm_dispatch_total",
     "grouped matmuls of a top-k MoE layer lowered, by pass (fwd, bwd_dx, "
-    "bwd_dw), shape (m rows, k x n an expert, E experts: the forward "
+    "bwd_dw; bwd_dw_adam: the matrix's gradient with its Adam step "
+    "inside the kernel, where bwd_dw with a tile wrote the gradient to "
+    "HBM), shape (m rows, k x n an expert, E experts: the forward "
     "product's) and tile (rows, contraction and width of one grid step "
     "of the pass's moe.* Pallas kernel; empty where the call ran as "
     "jax.lax.ragged_dot)")
@@ -156,6 +162,54 @@ def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None, live_rows=None):
             if _vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES:
                 return tm, tk, tn
     return None
+
+
+def _adam_vmem_bytes(tm, tk, tn, itemsize):
+    """What a grid step of ``tgmm_adam`` keeps in VMEM: the two row
+    blocks double-buffered, three buffers of the weight's and its
+    moments' float32 [tk, tn] block (one coming in, one stepped in
+    place, one going out: ``_MatrixState``), the accumulator and the
+    product that is added to it."""
+    return 2 * (tm * tk + tm * tn) * itemsize + 4 * (9 + 1 + 1) * tk * tn
+
+
+def adam_tile(tile, k, n, e, rows):
+    """-> (tm, tk, tn) for ``tgmm_adam`` where the weight-gradient call
+    ``[rows, k]^T x [rows, n]`` over e experts has ``tile`` (``rows``:
+    those expected inside groups), or None where the call runs as
+    ``tgmm`` with the update behind it. The row tile is the call's. The
+    [tk, tn] tile of the matrix is not: three buffers of its weight and
+    moments ride beside the accumulator, so it is the pair of
+    ``_width_tiles`` under the VMEM cap that reads the rows least often
+    (lhs once a width tile, g once a contraction tile), the larger tile
+    among equals: 1024 x 1024 at OLMoE's 2048 x 1024 (6.49 ms a call,
+    6.95 at 1024 x 512, 7.35 at 512 x 512, 8.34 for the two passes; my
+    chip run, PR 62), the matrix whole at a held share's 2048 x 512.
+
+    None: where no tile fits; for a width off the 128 lanes (1856: the
+    kernel copies the matrix's tiles itself, and Mosaic slices no memory
+    reference off the lane tiling; a contraction off them is taken
+    whole, as ever: it is the tile's sublanes); and where the form moves
+    MORE bytes than the two passes: it saves the gradient's write and
+    read, 4 bytes a parameter, and reads the rows again for every
+    further tile of the matrix. SmallThinker's share (12,288 rows over 8
+    experts of 2560 x 768, no tile of which is a whole side) would save
+    63 MB and read 75 MB more: 1.74 ms a call for the two passes' 1.70,
+    and its cell 0.45% slower in both pairs (my chip run, PR 62)."""
+    tm = tile[0]
+    fits = [(tk, tn) for tk in _width_tiles(k, True)
+            for tn in ([] if n % 128 else _width_tiles(n, False))
+            if _adam_vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES]
+    if not fits:
+        return None
+
+    def passes(t):      # over lhs's k columns and g's n, in columns read
+        return k * (n // t[1]) + n * (k // t[0])
+
+    tk, tn = min(fits, key=lambda t: (passes(t), -t[0] * t[1]))
+    if 2 * rows * (passes((tk, tn)) - k - n) >= 4 * e * k * n:
+        return None
+    return tm, tk, tn
 
 
 def _width_tiles(size, contraction):
@@ -391,7 +445,10 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
 
 
 def _tgmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, g_ref,
-                 out_ref, acc_ref, *, tm):
+                 *refs, tm, adam=None):
+    # refs: the result's block and the accumulator; with ``adam`` (an
+    # AdamStep's attributes) what _MatrixState takes, the accumulator
+    # among it
     v, last_v = pl.program_id(2), pl.num_programs(2) - 1
     group = gids_ref[v]
     first = jnp.logical_or(
@@ -402,10 +459,17 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, g_ref,
     live = jnp.logical_and(v < nvis_ref[0], end > start)
     whole = jnp.logical_and(start <= row0, end >= row0 + tm)
     dims = (((0,), (0,)), ((), ()))
+    if adam is None:
+        out_ref, acc_ref = refs
+    else:
+        state = _MatrixState(group, *refs)
+        acc_ref = state.acc
 
     @pl.when(first)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if adam is not None:
+            state.fetch_ahead()
 
     @pl.when(jnp.logical_and(live, whole))
     def _():
@@ -423,7 +487,114 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, nvis_ref, lhs_ref, g_ref,
 
     @pl.when(last)
     def _():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        if adam is None:
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        else:
+            state.step(**adam)
+
+
+class _MatrixState:
+    """The weight and its two moments inside ``tgmm_adam``: [E, k, n]
+    float32 in HBM, moved by the kernel's own copies and not by the
+    grid's pipeline, which would bring a block in during the one grid
+    step before its first use. A BLOCK is one group's [tk, tn] tile of
+    the three; the grid walks the blocks in one order (width tile, then
+    contraction tile, then group: every group is visited, ``_visits``)
+    and spends a group's row tiles on each. While block s is
+    contracted, block s + 1 comes in (started at s's first row tile,
+    awaited at s + 1's last) and block s - 1, stepped in place, goes out
+    (started behind its step, awaited at s + 1's first row tile, whose
+    fetch takes its buffer): three buffers, block s in buffer s % 3. The
+    copies are started at the low priority: at the priority of the
+    pipeline's copies of the row blocks a 6 MB block queues in front of
+    the next grid step's rows and the step waits for all of it (8.9 ms a
+    call for 7.0 at OLMoE's gate matrix; my chip run, PR 62). The
+    results alias the operands, and a block is read before it is
+    written and touched by no other."""
+
+    def __init__(self, group, scal_ref, *refs):
+        *hbm, self.acc, self.buf, self.in_sem, self.out_sem = refs
+        self.scal, self.src, self.dst = scal_ref, hbm[:3], hbm[3:]
+        self.tk, self.tn = self.acc.shape
+        j, i = pl.program_id(0), pl.program_id(1)
+        self.tiles_k, self.e = pl.num_programs(1), self.src[0].shape[0]
+        self.s = (j * self.tiles_k + i) * self.e + group
+        self.blocks = pl.num_programs(0) * self.tiles_k * self.e
+
+    def _copies(self, s, inward):
+        """The three copies of block ``s``, in or out of buffer s % 3."""
+        ji = s // self.e
+        at = (s % self.e, pl.ds(ji % self.tiles_k * self.tk, self.tk),
+              pl.ds(ji // self.tiles_k * self.tn, self.tn))
+        slot = s % 3
+        if inward:
+            return [pltpu.make_async_copy(
+                ref.at[at], self.buf.at[slot, a], self.in_sem.at[slot, a])
+                for a, ref in enumerate(self.src)]
+        return [pltpu.make_async_copy(
+            self.buf.at[slot, a], ref.at[at], self.out_sem.at[slot, a])
+            for a, ref in enumerate(self.dst)]
+
+    def fetch_ahead(self):
+        """At a block's first row tile: the next block starts coming in
+        (and, at the grid's first step, this one), into the buffer that
+        block s - 2 has left by now."""
+        s = self.s
+
+        @pl.when(s == 0)
+        def _():
+            for copy in self._copies(s, True):
+                copy.start(priority=1)
+
+        @pl.when(s >= 2)
+        def _():
+            for copy in self._copies(s - 2, False):
+                copy.wait()
+
+        @pl.when(s + 1 < self.blocks)
+        def _():
+            for copy in self._copies(s + 1, True):
+                copy.start(priority=1)
+
+    def step(self, *, beta1, beta2, epsilon, decay):
+        """At a block's last row tile: the accumulator is the block's
+        gradient, float32 as accumulated; Adam's step on it in place
+        (``ops/optimizer_ops.adam_step``, the ops' own expressions), a
+        strip of rows at a time so that a strip's chain of values stays
+        in registers; the block starts going out. ``scal`` (SMEM): the
+        learning rate with the bias correction in it, and AdamW's
+        (``decay``) learning rate times its decay."""
+        from paddle_tpu.ops.optimizer_ops import adam_step
+
+        s, slot = self.s, self.s % 3
+        for copy in self._copies(s, True):
+            copy.wait()
+        rows = 8
+        while rows * 2 * self.tn <= 8192 and self.tk % (rows * 2) == 0:
+            rows *= 2
+        lr_t, lr_decay = self.scal[0], self.scal[1] if decay else None
+
+        def strip(r, carry):
+            at = (pl.ds(pl.multiple_of(r * rows, rows), rows), slice(None))
+            after = adam_step(
+                self.buf[(slot, 0, *at)], self.acc[at],
+                self.buf[(slot, 1, *at)], self.buf[(slot, 2, *at)], lr_t,
+                beta1, beta2, epsilon, lr_decay)
+            for a, value in enumerate(after):
+                self.buf[(slot, a, *at)] = value
+            return carry
+
+        jax.lax.fori_loop(0, self.tk // rows, strip, None)
+        for copy in self._copies(s, False):
+            copy.start(priority=1)
+
+        @pl.when(s == self.blocks - 1)      # the grid's last step
+        def _():
+            for back in range(2):
+                @pl.when(s >= back)
+                def _():
+                    for copy in self._copies(s - back, False):
+                        copy.wait()
 
 
 def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
@@ -431,6 +602,26 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
     rows of each group e, exact zeros for a group without rows. ``tile``
     (tm, tk, tn): tm rows are contracted a grid step into a float32
     accumulator [tk, tn] that is held across a group's row tiles."""
+    return _tgmm_call(lhs, g, group_sizes, tile, name, None)
+
+
+def tgmm_adam(lhs, g, group_sizes, tile, adam, *,
+              name="moe.tgmm.bwd_dw_adam"):
+    """``tgmm`` whose result never leaves the kernel: at a group's last
+    row tile the float32 accumulator IS the gradient of that [tk, tn]
+    tile of the expert's matrix, and the Adam step ``adam`` (an
+    ``AdamStep``) is taken on it there (``_MatrixState.step``). Its
+    state, the float32 weight and its two moments [E, k, n], stays in
+    HBM, is copied by the kernel a tile at a time beside the matmuls of
+    a group's row tiles and written back through three results that
+    alias it (no second buffer a tensor). -> (weight, moment1, moment2)
+    after the step. A group without rows is visited once all the same:
+    its gradient is zero and its moments decay. ``tile`` from
+    ``adam_tile``: tn divides n."""
+    return _tgmm_call(lhs, g, group_sizes, tile, name, adam)
+
+
+def _tgmm_call(lhs, g, group_sizes, tile, name, adam):
     m, k = lhs.shape
     n = g.shape[1]
     e = group_sizes.shape[0]
@@ -439,10 +630,41 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
         lhs.shape, g.shape, tile)
     meta = _visits(group_sizes, m, tm, visit_empty=True)
     item = jnp.dtype(lhs.dtype).itemsize
+    scratch = [pltpu.VMEM((tk, tn), jnp.float32)]
+    if adam is None:
+        state, state_specs, kernel, aliases = [], [], None, {}
+        out_shape = jax.ShapeDtypeStruct((e, k, n), lhs.dtype)
+        out_specs = pl.BlockSpec(
+            (None, tk, tn), lambda j, i, v, o, gi, t, nv: (gi[v], i, j))
+        order = ("parallel", "parallel", "arbitrary")
+        vmem, moved = _vmem_limit(tm, tk, tn, item), item * e * k * n
+    else:
+        decay = adam.lr_decay is not None
+        # (SMEM) the learning rates, with the bias correction and times
+        # AdamW's decay
+        state = [jnp.stack([jnp.asarray(x, jnp.float32) for x in (
+            adam.lr_t, adam.lr_decay if decay else 0.0)]), *adam.state]
+        kernel = dict(beta1=adam.beta1, beta2=adam.beta2,
+                      epsilon=adam.epsilon, decay=decay)
+        assert n % tn == 0 and all(
+            x.shape == (e, k, n) and x.dtype == jnp.float32
+            for x in adam.state), (tile, [x.shape for x in adam.state])
+        in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+        state_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + [in_hbm] * 3
+        out_shape = [jax.ShapeDtypeStruct((e, k, n), jnp.float32)] * 3
+        out_specs = [in_hbm] * 3
+        # (operands are counted from the scalar prefetch's four)
+        aliases = {7: 0, 8: 1, 9: 2}
+        scratch += [pltpu.VMEM((3, 3, tk, tn), jnp.float32)]
+        scratch += [pltpu.SemaphoreType.DMA((3, 3))] * 2
+        # one walk over the blocks, in the grid's order (_MatrixState)
+        order = ("arbitrary", "arbitrary", "arbitrary")
+        vmem = max(16 * 2**20, _adam_vmem_bytes(tm, tk, tn, item) * 3 // 2)
+        moved = 4 * 6 * e * k * n
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm),
+        functools.partial(_tgmm_kernel, tm=tm, adam=kernel),
         name=name,
-        out_shape=jax.ShapeDtypeStruct((e, k, n), lhs.dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(-(-n // tn), k // tk, m // tm + e - 1),
@@ -451,20 +673,19 @@ def tgmm(lhs, g, group_sizes, tile, *, name="moe.tgmm.bwd_dw"):
                              lambda j, i, v, o, gi, t, nv: (t[v], i)),
                 pl.BlockSpec((tm, tn),
                              lambda j, i, v, o, gi, t, nv: (t[v], j)),
-            ],
-            out_specs=pl.BlockSpec(
-                (None, tk, tn), lambda j, i, v, o, gi, t, nv: (gi[v], i, j)),
-            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+            ] + state_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
         ),
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(tm, tk, tn, item)),
+            dimension_semantics=order, vmem_limit_bytes=vmem),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
-            bytes_accessed=item * (m * k * -(-n // tn) + m * n * (k // tk)
-                                   + e * k * n)),
+            bytes_accessed=item * (m * k * -(-n // tn) + m * n * (k // tk))
+            + moved),
         interpret=_INTERPRET,
-    )(*meta, lhs, g)
+    )(*meta, lhs, g, *state)
 
 
 # ---------------------------------------------------------------------------
@@ -574,8 +795,32 @@ def grouped_matmul(lhs, rhs, group_sizes, live_rows=None, zero_behind=True):
     return out
 
 
+class AdamStep(NamedTuple):
+    """One expert matrix's Adam step, for the call that makes its
+    gradient (``grouped_matmul_grads(adam=)``): ``state`` the weight and
+    its two moments [E, k, n], ``lr_t`` the learning rate with the bias
+    correction in it, ``lr_decay`` AdamW's learning rate times its decay
+    (None: Adam), and the op's attributes."""
+    state: Tuple[Any, Any, Any]
+    lr_t: Any
+    lr_decay: Any
+    beta1: float
+    beta2: float
+    epsilon: float
+
+    def after(self, dw):
+        """The state after the step for a gradient that is an array: the
+        ``adam`` / ``adamw`` op's arithmetic, as that op would run it
+        behind the gradient's call."""
+        from paddle_tpu.ops.optimizer_ops import adam_step
+
+        p, m1, m2 = self.state
+        return adam_step(p, dw.astype(m1.dtype), m1, m2, self.lr_t,
+                         self.beta1, self.beta2, self.epsilon, self.lr_decay)
+
+
 def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None,
-                         zero_behind=True):
+                         zero_behind=True, adam=None):
     """(d lhs, d rhs) of ``grouped_matmul(lhs, rhs, group_sizes)`` for
     the cotangent ``g`` [m, n], in the operands' dtypes: for a caller
     that saved its forward's results and does not run it again
@@ -583,10 +828,25 @@ def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None,
     and ``zero_behind`` as ``grouped_matmul``'s: d lhs has zeros behind
     the last group (or, not zeroed, is not defined there), and what g
     holds there is never read into d rhs (lhs is multiplied by zeros
-    there: it has to be finite in every tile a group reaches)."""
+    there: it has to be finite in every tile a group reaches).
+
+    ``adam`` (an ``AdamStep``): d rhs goes to that step and nowhere
+    else, and the second result is the matrix's (weight, moment1,
+    moment2) after it. Where the call has a tile, ``adam_tile`` has one
+    for the matrix and the state is float32, the step is taken inside
+    the weight-gradient kernel on its float32 accumulator
+    (``tgmm_adam``, counted as pass ``bwd_dw_adam``) and d rhs is no
+    array at all; otherwise (no TPU, a mesh: a chip's partial gradient
+    is summed before any step reads it) d rhs is made as ever and the
+    step follows it (``AdamStep.after``)."""
     dims, tile, dx_tile = _call_tiles(lhs, rhs, live_rows)
+    fused = None
+    if adam is not None and tile is not None and all(
+            x.dtype == jnp.float32 for x in adam.state):
+        fused = adam_tile(tile, *dims[1:], live_rows or dims[0])
     _note_dispatch("bwd_dx", *dims, dx_tile)
-    _note_dispatch("bwd_dw", *dims, tile)
+    _note_dispatch("bwd_dw_adam" if fused else "bwd_dw", *dims,
+                   fused or tile)
     g = g.astype(lhs.dtype)
     if tile is None:
         # jax's transposes of ragged_dot; the forward it traces is dead
@@ -598,5 +858,7 @@ def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None,
         dx = gmm(g, rhs, group_sizes, dx_tile, transpose_rhs=True,
                  name="moe.gmm.bwd_dx",
                  zero_behind=live_rows is not None and zero_behind)
+        if fused:
+            return dx, tgmm_adam(lhs, g, group_sizes, fused, adam)
         dw = tgmm(lhs, g, group_sizes, tile)
-    return dx, dw
+    return dx, dw if adam is None else adam.after(dw)

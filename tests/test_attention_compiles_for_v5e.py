@@ -186,6 +186,63 @@ def test_grouped_matmul_kernels_compile(case, one_chip, real_kernels):
         assert name in text, name
 
 
+# (m, k, n, e, live rows or None) of a weight-gradient call whose Adam
+# step is taken inside the kernel: OLMoE's two matrices, a held share's
+# (SDAR's 16 of 128 experts of 768, LFM2's 8 of 64 of 1536) and
+# Nemotron's 1856 as the contraction (as the width it has no tile: tgmm
+# and the update behind)
+_ADAM_CASES = {
+    "olmoe_gate_up": (65536, 2048, 1024, 64, None),
+    "olmoe_down": (65536, 1024, 2048, 64, None),
+    "sdar_gate_up": (65536, 2048, 768, 16, 8192),
+    "sdar_down": (65536, 768, 2048, 16, 8192),
+    "nemotron_down": (24576, 1856, 2688, 8, 1536),
+    "lfm2moe_down": (32768, 1536, 2048, 8, 4096),
+}
+
+
+@pytest.mark.parametrize("decay", [False, True], ids=["adam", "adamw"])
+@pytest.mark.parametrize("case", sorted(_ADAM_CASES))
+def test_weight_gradient_kernel_with_adam_compiles(case, decay, one_chip,
+                                                   real_kernels):
+    """Mosaic takes ``moe.tgmm.bwd_dw_adam`` at ``adam_tile``'s tile
+    inside the scoped VMEM the call asks for, and the compiled call
+    writes the weight and both moments where it read them: no second
+    buffer the size of a state tensor is allocated (the temporaries are
+    the visits' bookkeeping and, for a contraction off the lanes, the
+    rows' lane-padded copy that ``tgmm`` has too)."""
+    m, k, n, e, live = _ADAM_CASES[case]
+    bf, f32 = jnp.bfloat16, jnp.float32
+    tile = gm.adam_tile(
+        gm.gmm_tile(m, k, n, e, bf, "tpu", False, live_rows=live), k, n, e,
+        live or m)
+    assert tile and gm._adam_vmem_bytes(*tile, 2) <= gm._VMEM_CAP_BYTES
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(lhs, g, sizes, lr_t, lr_decay, *state):
+        return gm.tgmm_adam(lhs, g, sizes, tile, gm.AdamStep(
+            state, lr_t, lr_decay if decay else None, 0.9, 0.999, 1e-8))
+
+    compiled = jax.jit(step, donate_argnums=(5, 6, 7)).lower(
+        arg((m, k), bf), arg((m, n), bf), arg((e,), jnp.int32),
+        arg((), f32), arg((), f32), *[arg((e, k, n), f32)] * 3).compile()
+    assert "moe.tgmm.bwd_dw_adam" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 3 * 4 * e * k * n
+    assert mem.temp_size_in_bytes < 4 * e * k * n
+
+
+def test_a_width_off_the_lanes_keeps_the_two_passes():
+    """Nemotron's up projection, 1856 wide: the kernel's own copies
+    cannot slice a width off the lane tiling, so no fused tile."""
+    tile = gm.gmm_tile(24576, 2688, 1856, 8, jnp.bfloat16, "tpu", False,
+                       live_rows=1536)
+    assert tile == (128, 2688, 640)
+    assert gm.adam_tile(tile, 2688, 1856, 8, 1536) is None
+
+
 def test_grouped_query_attention_compiles(one_chip, real_kernels):
     """Qwen3-Next's attention layer: 16 query heads over 2 key/value
     heads of 256 at 8192 positions, one head a step at blocks of 512;
