@@ -417,7 +417,9 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     chip of an expert-parallel group) under bf16 AMP with its backward
     pass through the ``moe.*`` kernels, once as the program lowers it,
     every pass a loop over the windows of live rows
-    (ops/moe_ops.over_live_rows), and once, the same weights and tokens,
+    (ops/moe_ops.over_live_rows) that starts from memory nothing filled
+    (no row of ``pt_moe_buffer_fills_total`` on a TPU), and once, the
+    same weights and tokens,
     with ONE window of all n * k rows, so that every pass walks the
     whole buffer: output and gradients must agree, no row of the
     counter may say ``whole``, the two token-major sums (``moe_combine
@@ -442,7 +444,8 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     feed = {"x": r.randn(tokens, d).astype(np.float32),
             "p": r.randn(tokens, d).astype(np.float32)}
     scope, exe = fluid.Scope(), fluid.Executor()
-    before = (gmm_dispatch(), moe_ops.rows_dispatch_counts())
+    before = (gmm_dispatch(), moe_ops.rows_dispatch_counts(),
+              moe_ops.buffer_fill_counts())
     results, step_ms, kernel_ms = {}, {}, {}
     window = moe_ops.live_window
     for form in ("windowed", "whole"):
@@ -476,6 +479,7 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
                 f"{kernel_ms}")
     gmm = _dispatch_since(before[0], gmm_dispatch)
     passes = _dispatch_since(before[1], moe_ops.rows_dispatch_counts)
+    fills = _dispatch_since(before[2], moe_ops.buffer_fill_counts)
     exe.close()
 
     m = tokens * top_k
@@ -488,6 +492,10 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     check(len(sums) == 4 and all(" kernel " in k for k in sums),
           f"a token-major sum of the held layer is not the pairs.sum.* "
           f"kernel's: {sums}")
+    # on a TPU every pass starts from memory nothing filled
+    # (grouped_matmul.unfilled); the CPU's carries are zeros, as ever
+    check(not fills or jax.default_backend() != "tpu",
+          f"a pass of the held layer fills its whole buffer: {fills}")
     w = window(m, -(-m * held[1] // experts))
     check({k.rsplit(" w", 1)[1] for k in passes} == {str(w), str(m)},
           f"the passes' windows are not {w} (and {m} for the whole "
@@ -514,6 +522,7 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     row = {"rows": m, "live": live, "live_share": round(live / m, 4),
            "held": list(held), "experts": experts, "window": w,
            "step_ms": step_ms, "kernel_ms": kernel_ms, "passes": passes,
+           "fills": fills,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  moe_held {row}")
     return row

@@ -289,17 +289,31 @@ _rows.defvjp(_rows_fwd, _rows_bwd)
 # buffer is a loop over the windows that hold a live row
 # (``over_live_rows``: the trip count is sum(Rows), the step's own, a
 # device scalar), as the grouped matmuls visit no tile behind the last
-# group. Row-major passes write their windows into zeros; the two
-# token-major ones (a token's sum over its pairs) are one Pallas kernel
-# that fetches the groups of 16 rows a token tile's pairs lie in and adds
-# them on the MXU (``_add_into_tokens``, parallel/pair_sum.py), and
-# without a TPU a gather of all k rows of every token and their sum
-# (``_sum_by_token``). Every buffer an op hands on (Xs, Gate, Up,
-# Ys and the cotangents) has zeros behind the last live row: the experts'
-# products are written into zeros. The three that stay inside an op (dh
-# and the two halves of d Xs) are taken as their kernels leave them in
-# memory nothing filled (``zero_behind=False``: not defined behind the
-# last live row) and read by window alone, under ``keep``.
+# group. The two token-major ones (a token's sum over its pairs) are one
+# Pallas kernel that fetches the groups of 16 rows a token tile's pairs
+# lie in and adds them on the MXU (``_add_into_tokens``,
+# parallel/pair_sum.py), and without a TPU a gather of all k rows of
+# every token and their sum (``_sum_by_token``).
+#
+# The buffers' contract (PR 63): every buffer, inside an op and handed
+# on (Xs, Gate, Up, h, Ys and the cotangents), is FINITE TO THE END OF
+# THE ROW TILE THE LAST LIVE ROW LIES IN (zeros behind that row) AND NOT
+# DEFINED BEHIND IT, and nothing writes behind: no buffer is born as n *
+# k rows of zeros. Every reader keeps to that: the grouped matmuls visit
+# no tile behind the last group and mask a straddling tile themselves
+# (``gmm`` on the write, ``tgmm`` on the read, where rows of its lhs are
+# multiplied by zeros: hence finite to the tile's end), ``pairs.sum.*``
+# fetches only groups of rows a live pair lies in, ``_sum_by_token``
+# selects, and a row-major pass reads by window under ``keep``. A
+# row-major pass starts from memory nothing filled (``_carry``:
+# ``grouped_matmul.unfilled``) and the ``jnp.where(keep, v, 0)`` of its
+# last trip zeroes the window's tail, which covers the row tile because
+# the window is whole row tiles (``_carry`` looks: where it is not, or
+# the layer's matmuls run as no kernel, the carry is zeros as it was and
+# ``pt_moe_buffer_fills_total`` says so); a grouped matmul's result has
+# the one tile the last group ends in zeroed behind it
+# (``zero_behind="tile"``), and the three that stay inside an op (dh and
+# the two halves of d Xs) not even that (``zero_behind=False``).
 
 _M_PASSES = _monitor.counter(
     "pt_moe_rows_dispatch_total",
@@ -312,6 +326,15 @@ _M_PASSES = _monitor.counter(
     "of all k rows of every token of which the live ones are added (the "
     "label is older than the form: readers know it); whole: the pass "
     "walks all buffer_rows) and buffer_rows")
+
+
+_M_FILLS = _monitor.counter(
+    "pt_moe_buffer_fills_total",
+    "whole-buffer zero fills a held top-k MoE layer lowers (trace time, "
+    "telemetry on), by op, buffer (the pass's result: Xs, h, GRAD::Ys, "
+    "..), rows and width: a row-major pass whose first carry is zeros "
+    "and not memory nothing filled (no TPU, a mesh, matmuls that run as "
+    "no kernel, a window that is not whole row tiles)")
 
 
 def _live_rows(attrs, m):
@@ -364,25 +387,53 @@ def rows_dispatch_counts():
     return out
 
 
-def _live_pass(live, w, m, widths, body):
+def _carry(attrs, op, w, m, dtype, buffer, width):
+    """The first carry of a held layer's row-major pass into ``buffer``
+    [m, width]: memory nothing filled (``grouped_matmul.unfilled``)
+    where the layer's grouped matmuls are kernels (``row_tile``) and the
+    window ``w`` is whole row tiles of theirs, so that the last trip's
+    zeros reach the end of the tile the last live row lies in; zeros,
+    and a row of ``pt_moe_buffer_fills_total``, anywhere else."""
+    tm = _gm.row_tile(m, int(attrs["held_count"]), dtype,
+                      **_live_rows(attrs, m))
+    if tm is not None and w % tm == 0:
+        return _gm.unfilled((m, width), dtype)
+    if _monitor.enabled() and interp.lowering_active():
+        _M_FILLS.inc(labels={"op": op, "buffer": buffer, "rows": str(m),
+                             "width": str(width)})
+    return jnp.zeros((m, width), dtype)
+
+
+def buffer_fill_counts():
+    """{"op buffer rows x width": fills lowered so far}: the counter
+    above as chip_smoke.py prints it."""
+    out = {}
+    for row in _monitor.snapshot()[_M_FILLS.name]["values"]:
+        lb = row["labels"]
+        name = "%s %s %sx%s" % tuple(
+            lb.get(key, "?") for key in ("op", "buffer", "rows", "width"))
+        out[name] = out.get(name, 0) + int(row["value"])
+    return out
+
+
+def _live_pass(live, w, into, body):
     """A row-major pass over the live rows: ``body(r0) -> rows`` ([w,
-    width] each, one per (width, dtype) of ``widths``), written in place
-    into [m, width] buffers that are zeros in every window no trip
-    reached and behind the last live row."""
+    width] each, one for each buffer of ``into``, ``_carry``'s), written
+    in place: zeros behind the last live row to its window's end, and
+    what ``into`` held in every window no trip reached."""
     def trip(r0, keep, bufs):
         return tuple(put_rows(b, r0, jnp.where(keep, v, 0))
                      for b, v in zip(bufs, body(r0)))
 
-    return over_live_rows(live, w, trip, tuple(
-        jnp.zeros((m, width), dtype) for width, dtype in widths))
+    return over_live_rows(live, w, trip, tuple(into))
 
 
-def _gather_live(x, order, live, w):
-    """Xs of the tokens x [n, d]: row r < live is the token of pair
-    ``order[r]``; zeros behind."""
+def _gather_live(x, order, live, w, into):
+    """Xs of the tokens x [n, d], written into ``into``: row r < live
+    is the token of pair ``order[r]``."""
     k = order.shape[0] // x.shape[0]
     return _live_pass(
-        live, w, order.shape[0], [(x.shape[1], x.dtype)],
+        live, w, [into],
         lambda r0: (jnp.take(x, rows_at(order, r0, w) // k, axis=0),))[0]
 
 
@@ -432,24 +483,25 @@ def _add_into_tokens(note, rows, slot, sizes, w, top_w=None):
         rows.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_pairs(x, order, slot, sizes, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rows_of_pairs(x, order, slot, sizes, into, w):
     """Xs of the tokens x: the token of pair ``order[r]`` at every row r
-    inside a group (``w``: the layer's window, None for one that holds
-    every expert), whose cotangent is those rows added into their
-    tokens, not a scatter-add over repeated indices."""
+    inside a group (``w``: the layer's window and ``into`` its loop's
+    first carry, both None for a layer that holds every expert), whose
+    cotangent is those rows added into their tokens, not a scatter-add
+    over repeated indices."""
     if w is None:
         return jnp.take(x, order // slot.shape[1], axis=0)
-    return _gather_live(x, order, jnp.sum(sizes), w)
+    return _gather_live(x, order, jnp.sum(sizes), w, into)
 
 
-def _rows_of_pairs_fwd(x, order, slot, sizes, w):
-    return _rows_of_pairs(x, order, slot, sizes, w), (slot, sizes)
+def _rows_of_pairs_fwd(x, order, slot, sizes, into, w):
+    return _rows_of_pairs(x, order, slot, sizes, into, w), (slot, sizes)
 
 
 def _rows_of_pairs_bwd(w, res, g):
     return (_add_into_tokens(("moe_dispatch_grad", "d_x"), g, *res, w),
-            None, None, None)
+            None, None, None, None)
 
 
 _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
@@ -470,7 +522,9 @@ def _moe_dispatch(ins, attrs):
     held experts come first in Xs, sorted by expert, and the pairs on
     experts held elsewhere lie behind them (by token): they keep their
     place in the buffer, which has a row for every pair whatever the
-    routing; no expert here reads them, and Xs has zeros there."""
+    routing; no expert here reads them, and Xs has zeros to the end of
+    the last live row's window and is NOT DEFINED behind it (the
+    contract above: nothing fills the buffer)."""
     x, top_i = _x(ins, "X"), _x(ins, "TopI")
     x = x.reshape(-1, x.shape[-1])
     n, k = top_i.shape
@@ -485,7 +539,9 @@ def _moe_dispatch(ins, attrs):
     rows = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
     w = _window(attrs, n * k)
     _note_passes("moe_dispatch", n * k, w, "gather_xs")
-    xs = _rows_of_pairs(x, order, slot, rows, w)
+    into = None if w is None else _carry(
+        attrs, "moe_dispatch", w, n * k, x.dtype, "Xs", x.shape[1])
+    xs = _rows_of_pairs(x, order, slot, rows, into, w)
     return {"Xs": [xs], "Rows": [rows], "Order": [order], "Slot": [slot]}
 
 
@@ -529,18 +585,30 @@ def _plain_unit(attrs):
             "relu": lambda up: jax.nn.relu(up)}[attrs.get("act", "relu2")]
 
 
-def _glu_live(glu, gate, up, live, w):
-    """``glu`` on the live rows of Gate and Up [m, f], zeros behind."""
+def _glu_live(glu, gate, up, live, w, into):
+    """``glu`` on the live rows of Gate and Up [m, f], into ``into``."""
     return _live_pass(
-        live, w, gate.shape[0], [(gate.shape[1], gate.dtype)],
+        live, w, [into],
         lambda r0: (glu(rows_at(gate, r0, w), rows_at(up, r0, w)),))[0]
 
 
-def _act_live(act, up, live, w):
-    """``act`` on the live rows of Up [m, f], zeros behind."""
+def _act_live(act, up, live, w, into):
+    """``act`` on the live rows of Up [m, f], into ``into``."""
     return _live_pass(
-        live, w, up.shape[0], [(up.shape[1], up.dtype)],
-        lambda r0: (act(rows_at(up, r0, w)),))[0]
+        live, w, [into], lambda r0: (act(rows_at(up, r0, w)),))[0]
+
+
+def _held(attrs, op, m, dtype):
+    """(grouped_matmul's keywords, the window, ``carry(buffer, width)``)
+    of an experts op over m rows: for a held share the expected live
+    rows with results zeroed to their last row tile's end and no
+    further, and ``_carry``; ({}, None, None) for a layer that holds
+    every expert."""
+    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    if w is None:
+        return kw, w, None
+    return (dict(kw, zero_behind="tile"), w,
+            functools.partial(_carry, attrs, op, w, m, dtype))
 
 
 @register_op("moe_experts", diff_inputs=("Xs", "WGate", "WUp", "WDown"))
@@ -564,9 +632,10 @@ def _moe_experts(ins, attrs):
 
     ``held_count`` of ``num_experts`` (a held share of the experts,
     see moe_dispatch): Rows sum to less than m; the rows behind the last
-    group are multiplied by nothing, and Ys, Gate and Up have zeros
-    there. The grouped matmuls are told the rows
-    an even router would put on the held experts (``live_rows``: their
+    group are multiplied by nothing, and Ys, Gate and Up have zeros to
+    the end of the row tile the last group ends in and are not defined
+    behind it (``zero_behind="tile"``). The grouped matmuls are told the
+    rows an even router would put on the held experts (``live_rows``: their
     row tile goes with those, not with the buffer), and SwiGLU runs over
     the windows that hold a live row. Such a layer also hands over X,
     the tokens, and Order (moe_dispatch's): the grad op gathers Xs again
@@ -575,12 +644,13 @@ def _moe_experts(ins, attrs):
     xs, rows = _x(ins, "Xs"), _x(ins, "Rows")
     wu, wd = _x(ins, "WUp"), _x(ins, "WDown")
     m = xs.shape[0]
-    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    kw, w, carry = _held(attrs, "moe_experts", m, xs.dtype)
     if not attrs.get("gated", True):
         act = _plain_unit(attrs)
         _note_passes("moe_experts", m, w, "act")
         up = _gm.grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
-        h = act(up) if w is None else _act_live(act, up, jnp.sum(rows), w)
+        h = (act(up) if w is None else _act_live(
+            act, up, jnp.sum(rows), w, carry("h", up.shape[1])))
         ys = _gm.grouped_matmul(h, wd.astype(xs.dtype), rows, **kw)
         return {"Ys": [ys], "Up": [up]}
     wg = _x(ins, "WGate")
@@ -589,8 +659,8 @@ def _moe_experts(ins, attrs):
     _note_passes("moe_experts", m, w, "swiglu")
     gate = _gm.grouped_matmul(xs, wg.astype(xs.dtype), rows, **kw)
     up = _gm.grouped_matmul(xs, wu.astype(xs.dtype), rows, **kw)
-    h = (glu(gate, up) if w is None
-         else _glu_live(glu, gate, up, jnp.sum(rows), w))
+    h = (glu(gate, up) if w is None else _glu_live(
+        glu, gate, up, jnp.sum(rows), w, carry("h", gate.shape[1])))
     ys = _gm.grouped_matmul(h, wd.astype(xs.dtype), rows, **kw)
     return {"Ys": [ys], "Gate": [gate], "Up": [up]}
 
@@ -665,7 +735,7 @@ def _plain_experts_grad(ins, attrs):
     m, dtype = xs.shape[0], xs.dtype
     up = _x(ins, "Up").astype(dtype)
     g = _x(ins, "GRAD::Ys").astype(dtype)
-    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    kw, w, carry = _held(attrs, "moe_experts_grad", m, dtype)
     act = _plain_unit(attrs)
     steps = _adam_steps(ins, attrs)
     adam = functools.partial(_adam_of, steps)
@@ -684,18 +754,19 @@ def _plain_experts_grad(ins, attrs):
         x, order = jax.lax.optimization_barrier(
             (_x(ins, "X"), _x(ins, "Order")))
         xs = _gather_live(x.reshape(-1, x.shape[-1]).astype(dtype), order,
-                          live, w)
-        h = _act_live(act, up, live, w)
-        # dh is read by window alone; dx is handed on: zeros behind
-        dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
-                                           **kw, zero_behind=False,
-                                           **adam("WDown"))
+                          live, w, carry("Xs", xs.shape[1]))
+        h = _act_live(act, up, live, w, carry("h", up.shape[1]))
+        # dh is read by window alone; dx is handed on: its last row
+        # tile zeroed behind the last live row
+        dh, dwd = _gm.grouped_matmul_grads(
+            h, wd.astype(dtype), rows, g, **dict(kw, zero_behind=False),
+            **adam("WDown"))
 
         def act_grad(r0):
             _, vjp = jax.vjp(act, rows_at(up, r0, w))
             return vjp(rows_at(dh, r0, w))
 
-        dup, = _live_pass(live, w, m, [(up.shape[1], dtype)], act_grad)
+        dup, = _live_pass(live, w, [carry("dup", up.shape[1])], act_grad)
         dx, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
                                            **kw, **adam("WUp"))
     return _experts_grad_outs(attrs, steps, dx, WUp=(dwu, wu.dtype),
@@ -719,7 +790,7 @@ def _moe_experts_grad(ins, attrs):
     gate = _x(ins, "Gate").astype(dtype)
     up = _x(ins, "Up").astype(dtype)
     g = _x(ins, "GRAD::Ys").astype(dtype)
-    kw, w = _live_rows(attrs, m), _window(attrs, m)
+    kw, w, carry = _held(attrs, "moe_experts_grad", m, dtype)
     glu = _gated_unit(attrs)
     steps = _adam_steps(ins, attrs)
     adam = functools.partial(_adam_of, steps)
@@ -739,8 +810,8 @@ def _moe_experts_grad(ins, attrs):
         x, order = jax.lax.optimization_barrier(
             (_x(ins, "X"), _x(ins, "Order")))
         xs = _gather_live(x.reshape(-1, x.shape[-1]).astype(dtype), order,
-                          live, w)
-        h = _glu_live(glu, gate, up, live, w)
+                          live, w, carry("Xs", xs.shape[1]))
+        h = _glu_live(glu, gate, up, live, w, carry("h", gate.shape[1]))
         dh, dwd = _gm.grouped_matmul_grads(h, wd.astype(dtype), rows, g,
                                            **kw, **adam("WDown"))
 
@@ -749,8 +820,9 @@ def _moe_experts_grad(ins, attrs):
                              rows_at(up, r0, w))
             return vjp(rows_at(dh, r0, w))
 
-        dgate, dup = _live_pass(live, w, m, [(gate.shape[1], dtype)] * 2,
-                                swiglu_grad)
+        dgate, dup = _live_pass(
+            live, w, [carry(name, gate.shape[1]) for name in ("dgate", "dup")],
+            swiglu_grad)
     dx_gate, dwg = _gm.grouped_matmul_grads(xs, wg.astype(dtype), rows,
                                             dgate, **kw, **adam("WGate"))
     dx_up, dwu = _gm.grouped_matmul_grads(xs, wu.astype(dtype), rows, dup,
@@ -758,8 +830,9 @@ def _moe_experts_grad(ins, attrs):
     if w is None:
         dx = dx_gate + dx_up
     else:
-        dx, = _live_pass(live, w, m, [(xs.shape[1], dtype)], lambda r0: (
-            rows_at(dx_gate, r0, w) + rows_at(dx_up, r0, w),))
+        dx, = _live_pass(live, w, [carry("GRAD::Xs", xs.shape[1])],
+                         lambda r0: (rows_at(dx_gate, r0, w)
+                                     + rows_at(dx_up, r0, w),))
     return _experts_grad_outs(attrs, steps, dx, WGate=(dwg, wg.dtype),
                               WUp=(dwu, wu.dtype), WDown=(dwd, wd.dtype))
 
@@ -804,7 +877,9 @@ def _moe_combine_grad(ins, attrs):
     compiled for a v5e): a window of the cotangent's rows is gathered by
     token once; GRAD::Ys is that times the pair's weight, and GRAD::TopW
     its row-wise product with Ys's window, summed in float32 and put at
-    the pair's own place. Both are zeros for a pair held elsewhere."""
+    the pair's own place. GRAD::TopW is zero for a pair held elsewhere;
+    GRAD::Ys is zeros to the end of the last live row's window and not
+    defined behind it (``_carry``: nothing fills it)."""
     m = _x(ins, "Order").shape[0]
     w = _window(attrs, m)
     _note_passes("moe_combine_grad", m, w, "d_ys", "d_w")
@@ -833,6 +908,7 @@ def _moe_combine_grad(ins, attrs):
 
     d_ys, d_w = over_live_rows(
         jnp.sum(_x(ins, "Rows")), w, trip,
-        (jnp.zeros_like(ys), jnp.zeros(n * k, jnp.float32)))
+        (_carry(attrs, "moe_combine_grad", w, m, ys.dtype, "GRAD::Ys",
+                ys.shape[1]), jnp.zeros(n * k, jnp.float32)))
     return {"GRAD::Ys": [d_ys],
             "GRAD::TopW": [d_w.reshape(n, k).astype(top_w.dtype)]}

@@ -10,9 +10,14 @@ scores (``live_rows``: an expert-parallel share, ops/moe_ops.py) passes
 group sizes that sum to LESS than m: the rows behind the last group
 belong to no expert here, no visit reaches their tiles, and the result
 has zeros there, as ``ragged_dot`` has (the kernel writes its tiles INTO
-zeros, ``gmm(zero_behind=True)``; ``zero_behind=False`` hands out what a
-kernel left in memory nothing filled to a caller that reads the live
-rows alone). Operands keep their dtype (bf16 under AMP), a product is
+zeros, ``gmm(zero_behind=True)``: the default, for any caller). A held
+layer's own ops ask for less and fill nothing: ``zero_behind="tile"``
+zeroes the rows behind the last group in the ONE row tile it ends in
+and leaves every tile behind as the memory was (finite to the end of
+the row tile the last live row lies in, not defined behind it: the
+contract of every buffer such a layer hands on), ``zero_behind=False``
+not even that, for a result read by windows of live rows alone.
+Operands keep their dtype (bf16 under AMP), a product is
 accumulated in float32 inside the kernel and returned in the operands'
 dtype, as ``ragged_dot`` returns it.
 
@@ -147,21 +152,33 @@ def gmm_tile(m, k, n, e, dtype, backend=None, on_mesh=None, live_rows=None):
     holds every pair the router made, the groups only the held ones').
     The visits, and so the row tile, go with the live rows, not with
     the buffer: 5,120 live rows of 81,920 over 32 experts take tm 128."""
+    tm = row_tile(m, e, dtype, backend, on_mesh, live_rows)
+    if tm is None or k % 64 or n % 64:
+        return None
+    for tk in _width_tiles(k, True):
+        for tn in _width_tiles(n, False):
+            if _vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES:
+                return tm, tk, tn
+    return None
+
+
+def row_tile(m, e, dtype, backend=None, on_mesh=None, live_rows=None):
+    """``gmm_tile``'s tm alone, which no width decides: the row tile of
+    every grouped matmul over m rows in e groups (of which
+    ``live_rows`` are expected inside groups), or None where none runs
+    as a kernel whatever its widths. What a caller that leaves rows
+    unwritten (``unfilled``) has to cover: a kernel reads its lhs a
+    whole row tile at a time."""
     on_tpu = kernels_enabled() if backend is None else backend == "tpu"
     if on_mesh is None:
         on_mesh = _under_mesh()
     live = m if live_rows is None else max(1, min(int(live_rows), m))
     rows = [t for t in _ROW_TILE_RATE if m % t == 0]
     if (not on_tpu or on_mesh or jnp.dtype(dtype) != jnp.bfloat16
-            or k % 64 or n % 64 or not rows or live // e < min(rows)):
+            or not rows or live // e < min(rows)):
         return None
-    tm = min(rows,
-             key=lambda t: (1 + (e - 1) * t / live) / _ROW_TILE_RATE[t])
-    for tk in _width_tiles(k, True):
-        for tn in _width_tiles(n, False):
-            if _vmem_bytes(tm, tk, tn, 2) <= _VMEM_CAP_BYTES:
-                return tm, tk, tn
-    return None
+    return min(rows,
+               key=lambda t: (1 + (e - 1) * t / live) / _ROW_TILE_RATE[t])
 
 
 def _adam_vmem_bytes(tm, tk, tn, itemsize):
@@ -380,12 +397,17 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
     one grid step: tm divides m and tk k; tn divides n or, for an n off
     the 128 lanes, the next whole number of lane tiles (the last block
     hangs over the edge: ``_width_tiles``). ``zero_behind``, for
-    group sizes that sum to less than m: the result is an array of zeros
-    that the call's tiles are written into (it rides in as an operand
-    the result aliases and no step reads), so the tiles no visit
-    reaches hold zeros; the one tile the last group ends in (or, with
-    no row in any group, the tile an idle grid still writes back) has
-    its rows behind the last group zeroed afterwards."""
+    group sizes that sum to less than m: False, the result is what the
+    kernel left in memory nothing filled, not defined behind the last
+    group; "tile", the one tile the last group ends in (or, with no row
+    in any group, the tile an idle grid still writes back) has its rows
+    behind the last group zeroed afterwards: finite to the end of the
+    row tile the last live row lies in, not defined behind it (a held
+    share's contract, ops/moe_ops.py: one [tm, n] window written, no
+    fill); True, the result is besides an array of zeros that the
+    call's tiles are written into (it rides in as an operand the result
+    aliases and no step reads), so the tiles no visit reaches hold
+    zeros too: ``ragged_dot``'s result, at a fill of the whole [m, n]."""
     m, k = lhs.shape
     e = rhs.shape[0]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
@@ -402,7 +424,8 @@ def gmm(lhs, rhs, group_sizes, tile, *, transpose_rhs=False,
         rhs_spec = pl.BlockSpec(
             (None, tk, tn), lambda j, v, kk, o, g, t, nv: (g[v], kk, j))
     item = jnp.dtype(lhs.dtype).itemsize
-    zeros = ([jnp.zeros((m, n), lhs.dtype)] if zero_behind else [])
+    assert zero_behind in (False, True, "tile"), zero_behind
+    zeros = ([jnp.zeros((m, n), lhs.dtype)] if zero_behind is True else [])
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tiles_k=tiles_k,
                           transpose_rhs=transpose_rhs,
@@ -762,6 +785,26 @@ def put_rows(buf, r0, rows):
         buf, rows.astype(buf.dtype), r0, axis=0)
 
 
+def unfilled(shape, dtype):
+    """An array of ``shape`` that nothing has written: the first carry
+    of a loop that writes its windows in place (``over_live_rows``)
+    into a held share's row buffer, whose rows behind the last window
+    no reader reads. On a TPU a Pallas call whose result stays where
+    XLA allocated it and whose body touches nothing (``rows.unfilled``:
+    no byte moves, where ``jnp.zeros`` writes the whole buffer at the
+    HBM's rate); under the interpreter hook NaN everywhere, so that the
+    CPU suite proves nobody reads behind; under a mesh (a Mosaic call
+    is not auto-partitioned) and anywhere else, zeros."""
+    if not kernels_enabled() or _under_mesh():
+        return jnp.zeros(shape, dtype)
+    if _INTERPRET:
+        return jnp.full(shape, jnp.nan, dtype)
+    return pl.pallas_call(
+        lambda out_ref: None, name="rows.unfilled",
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY))()
+
+
 def _call_tiles(lhs, rhs, live_rows=None):
     """((m, k, n, e), the call's tile, the tile of its rows' gradient):
     both tiles, or neither."""
@@ -782,9 +825,13 @@ def grouped_matmul(lhs, rhs, group_sizes, live_rows=None, zero_behind=True):
     number, not traced): the group sizes may sum to less than the rows,
     about that many are expected inside groups, and the rows behind the
     last group come back as zeros; None: they sum to all of them.
-    ``zero_behind=False``, for a caller that reads the result by
-    ``over_live_rows`` and nowhere else: what it holds behind the last
-    group is then NOT DEFINED (a kernel leaves what the buffer held)."""
+    ``zero_behind="tile"``, for a caller whose readers are the kernels
+    and windows of live rows (a held share, ops/moe_ops.py): zeros to
+    the end of the row tile the last group ends in, NOT DEFINED behind
+    it (a kernel leaves what the buffer held; ``ragged_dot``, where the
+    call has no tile, what it leaves). ``zero_behind=False``, for a
+    caller that reads the result by ``over_live_rows`` and nowhere else:
+    not defined anywhere behind the last group."""
     dims, tile, _ = _call_tiles(lhs, rhs, live_rows)
     _note_dispatch("fwd", *dims, tile)
     if tile is None:
@@ -826,9 +873,10 @@ def grouped_matmul_grads(lhs, rhs, group_sizes, g, live_rows=None,
     that saved its forward's results and does not run it again
     (``moe_experts_grad``), and the kernels' own vjp rule. ``live_rows``
     and ``zero_behind`` as ``grouped_matmul``'s: d lhs has zeros behind
-    the last group (or, not zeroed, is not defined there), and what g
-    holds there is never read into d rhs (lhs is multiplied by zeros
-    there: it has to be finite in every tile a group reaches).
+    the last group (or to its row tile's end, or is not defined there),
+    and what g holds there is never read into d rhs (lhs is multiplied
+    by zeros there: it has to be finite in every row tile a group
+    reaches, and need not be defined in any tile behind).
 
     ``adam`` (an ``AdamStep``): d rhs goes to that step and nowhere
     else, and the second result is the matrix's (weight, moment1,

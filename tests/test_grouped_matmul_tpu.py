@@ -99,10 +99,18 @@ def test_the_cells_shapes_against_ragged_dot(k, n, which):
 HELD_M, HELD_E = 81920, 32
 
 
+@pytest.mark.parametrize("behind", [True, "tile"],
+                         ids=["zeros_behind", "zeros_to_the_tiles_end"])
 @pytest.mark.parametrize("which", ["even_160", "skewed", "all_rows", "none"])
 @pytest.mark.parametrize("k,n", [(2048, 512), (512, 2048)],
                          ids=["gate_up", "down"])
-def test_a_held_share_against_ragged_dot(k, n, which):
+def test_a_held_share_against_ragged_dot(k, n, which, behind):
+    """``zero_behind=True`` (any caller's default): zeros behind the
+    last group, ``ragged_dot``'s result. ``"tile"`` (a held layer's own
+    ops, which fill no buffer): zeros to the end of the row tile the
+    last group ends in and nothing promised behind it; NaN in lhs and g
+    behind that tile (what memory nothing filled may hold) changes no
+    product and neither gradient."""
     r = np.random.RandomState(k + len(which))
     sizes = {"even_160": [160] * HELD_E,
              "skewed": (r.multinomial(5120, r.dirichlet([0.3] * HELD_E))
@@ -117,12 +125,18 @@ def test_a_held_share_against_ragged_dot(k, n, which):
     gs = jnp.asarray(sizes, jnp.int32)
     assert gm.gmm_tile(HELD_M, k, n, HELD_E, bf, live_rows=5120) \
         == (128, k, n)
+    end = HELD_M
+    if behind == "tile":    # of the last group's row tile (tile 0: none)
+        end = max(-(-live // 128), 1) * 128
+        rows = jnp.arange(HELD_M)[:, None] < end
+        lhs, g = jnp.where(rows, lhs, jnp.nan), jnp.where(rows, g, jnp.nan)
     got = jax.jit(lambda a, b, s: gm.grouped_matmul(
-        a, b, s, live_rows=5120))(lhs, rhs, gs)
+        a, b, s, live_rows=5120, zero_behind=behind))(lhs, rhs, gs)
     dx, dw = jax.jit(lambda a, b, s, c: gm.grouped_matmul_grads(
-        a, b, s, c, live_rows=5120))(lhs, rhs, gs, g)
-    assert not bool(jnp.any(got[live:] != 0))
-    assert not bool(jnp.any(dx[live:] != 0))
+        a, b, s, c, live_rows=5120, zero_behind=behind))(lhs, rhs, gs, g)
+    assert not bool(jnp.any(got[live:end] != 0))
+    assert not bool(jnp.any(dx[live:end] != 0))
+    assert bool(jnp.isfinite(dw.astype(jnp.float32)).all())
     if not live:
         assert not bool(jnp.any(dw != 0))
         return

@@ -231,15 +231,21 @@ PARTIAL = {"a_sixteenth": [9, 0, 16, 7], "half": [100, 28, 0, 128],
            "one_row_short": [128, 128, 128, 127], "none": [0, 0, 0, 0]}
 
 
+@pytest.mark.parametrize("behind", [True, "tile"],
+                         ids=["zeros_behind", "zeros_to_the_tiles_end"])
 @pytest.mark.parametrize("kernels", [False, True], ids=["ragged_dot",
                                                         "kernels"])
 @pytest.mark.parametrize("groups", sorted(PARTIAL))
 def test_grouped_matmul_with_rows_behind_the_last_group(groups, kernels,
-                                                        monkeypatch):
+                                                        behind, monkeypatch):
     """Group sizes that sum to less than the rows: ``ragged_dot``'s
     semantics (zeros behind the last group, in the product and in the
     rows' gradient; the matrix's gradient never reads them), through
-    the interpreted kernels and through the fallback."""
+    the interpreted kernels and through the fallback. A held layer's
+    own ops ask for ``zero_behind="tile"``: zeros to the end of the row
+    tile the last group ends in (tile 0 where no group has a row), and
+    behind it what the memory held (NaN under the interpreter: nothing
+    filled it), which neither gradient reads from g or lhs."""
     monkeypatch.setattr(gm, "_INTERPRET", kernels)
     sizes = jnp.asarray(PARTIAL[groups], jnp.int32)
     live = int(sizes.sum())
@@ -255,8 +261,22 @@ def test_grouped_matmul_with_rows_behind_the_last_group(groups, kernels,
                        live_rows=5120) == (128, 2048, 512)
     assert gm.gmm_tile(8192 * 10, 2048, 512, 32, bf, "tpu", False) \
         == (256, 2048, 512)
-    out = gm.grouped_matmul(lhs, rhs, sizes, live_rows=ROWS)
-    dx, dw = gm.grouped_matmul_grads(lhs, rhs, sizes, g, live_rows=ROWS)
+    end = ROWS
+    if behind == "tile" and kernels:    # of the last group's row tile
+        end = max(-(-live // tile[0]), 1) * tile[0]
+        rows = jnp.arange(ROWS)[:, None] < end
+        lhs, g = jnp.where(rows, lhs, jnp.nan), jnp.where(rows, g, jnp.nan)
+    out = gm.grouped_matmul(lhs, rhs, sizes, live_rows=ROWS,
+                            zero_behind=behind)
+    dx, dw = gm.grouped_matmul_grads(lhs, rhs, sizes, g, live_rows=ROWS,
+                                     zero_behind=behind)
+    # (a last group that ends ON a tile's edge: the tile behind it is
+    # the one zeroed, whole)
+    nothing_wrote = end + tile[0] * (live % tile[0] == 0) if kernels else end
+    assert np.isnan(np.asarray(out[nothing_wrote:], np.float32)).all()
+    assert np.isnan(np.asarray(dx[nothing_wrote:], np.float32)).all()
+    assert np.isfinite(np.asarray(dw, np.float32)).all()
+    out, dx = out[:end], dx[:end]
     want = jax.lax.ragged_dot(lhs[:live], rhs, sizes)
     _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes),
                      lhs[:live], rhs)
