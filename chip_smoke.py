@@ -18,7 +18,11 @@ points a user calls:
    and run the new kernels against their plain forms; the ``sconv``
    phase lowers ``lfm2moe-train-s8192`` (four gated short convolutions
    each way on the ``sconv.gated.*`` kernels) and runs them against the
-   composition; the ``loss_head`` phase compiles a Program that is only
+   composition; the ``kda`` phase lowers ``kimilinear-train-s4096``
+   (four delta-rule calls with a decay a key feature each way on the
+   ``kda.rule.*`` kernels, no rotary embedding) and runs them against
+   the float32 recurrence with G below -200 inside a chunk; the
+   ``loss_head`` phase compiles a Program that is only
    ``olmoe-train-s4096``'s head and holds its temporaries under the
    float32 [tokens, vocab] tensor the loss op no longer writes;
 2. train   — ``T.build`` + ``Adam.minimize`` under bf16 AMP, a few
@@ -828,6 +832,150 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
            "attn_bwd_kernel_ms": attn_ms, "gqa_tile": fa.tile_label(tile),
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  gdn {row['rel_err']}")
+    return row
+
+
+def kda_dispatch():
+    """{"impl gate pass shape chunk<C>": calls}: the delta-rule calls
+    lowered so far with the label that says whose decay it is
+    (pt_linear_attention_dispatch_total: ``gate=feature`` is Kimi Delta
+    Attention's, ``gate=head`` the scalar rule's)."""
+    from paddle_tpu.ops import linear_attention_ops as la
+
+    return la._counts(la._M_DISPATCH, lambda lb: (
+        f"{lb['impl']} {lb['gate']} {lb['pass']} {lb['shape']} "
+        f"chunk{lb['chunk']}"))
+
+
+def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
+    """The Kimi Linear decoder's new mechanisms (models/kimi_linear.py).
+
+    1. The cell ``kimilinear-train-s4096``'s train step (published
+       layers 1-5 at their published widths, 8 of 256 experts held,
+       bf16 AMP, Adam) is LOWERED, not run, and the dispatch counters
+       are held to what the cell must lower: every delta-rule call with
+       a decay a key feature through the ``kda.rule.*`` kernels
+       (``impl=kernel gate=feature``), none chunked, none recurrent; one
+       causal convolution a KDA layer each way on the ``gdn.conv.*``
+       kernels; the latent layer's one attention call each way through
+       the BHTD kernels at queries and keys of 192 over values of 128,
+       the backward one call; NO rotary embedding lowered at all.
+       ``overrides`` cut the config for the CPU tests.
+    2. On the device: ``kda.rule.fwd`` / ``kda.rule.bwd`` at ``heads``
+       heads of 128 x ``t_check`` positions against the float32
+       recurrence, Out and all five gradients, with gates that take G
+       below -200 inside a chunk and with mild ones (the state crosses
+       the chunks), and their ms a call from a trace."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import kimi_linear as M
+    from paddle_tpu.ops import linear_attention_ops as la
+
+    cfg = M.KimiLinearConfig(**{**dict(
+        num_hidden_layers=5, vocab_size=20480, held_experts=(0, 8)),
+        **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    reads = (kda_dispatch, conv_dispatch, attention_dispatch, rope_dispatch)
+    before = [read() for read in reads]
+    lower_train_step(main, model["loss"], seq)
+    kda, conv, attn, rope = (_dispatch_since(b, read)
+                             for b, read in zip(before, reads))
+    say(f"  lowered: kda {kda}; conv {conv}; attention {attn}; rotary "
+        f"embeddings {rope}")
+    n_kda = sum(cfg.is_kda(i) for i in range(cfg.num_hidden_layers))
+    n_mla = cfg.num_hidden_layers - n_kda
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in kda.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_kda and all(
+            k.startswith("kernel feature ")
+            and k.endswith(f"chunk{cfg.kda_chunk}") for k in rows),
+            f"expected {n_kda} delta-rule calls {direction} with a decay a "
+            f"key feature through the kda.rule.* kernels at chunk "
+            f"{cfg.kda_chunk}, none chunked, none recurrent: {kda}")
+        rows = {k: v for k, v in conv.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_kda and all(
+            k.split()[0] == "kernel" for k in rows),
+            f"expected {n_kda} causal convolutions {direction} through the "
+            f"gdn.conv.* kernels, none as XLA ops: {conv}")
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        dk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        check(sum(rows.values()) == n_mla and all(
+            k.startswith("bhtd ") and f" dk{dk} dv{cfg.v_head_dim} [" in k
+            for k in rows),
+            f"expected {n_mla} bhtd attention calls {direction} at dk{dk} "
+            f"dv{cfg.v_head_dim} with their tile, none dense: {attn}")
+    _one_backward_call(attn)
+    _statistics_in_rows(attn)
+    check(not rope, f"the model rotates nothing (mla_use_nope): {rope}")
+
+    # --- on the device ----------------------------------------------------
+    f32, bf = jnp.float32, jnp.bfloat16
+    chunk = cfg.kda_chunk
+
+    def draw(dt):
+        r = np.random.RandomState(7)
+        q, k, v, do = (jnp.asarray(r.randn(1, t_check, heads, 128), f32)
+                       for _ in "qkvd")
+        x = r.randn(1, t_check, heads, 128) * 0.3 + np.log(np.expm1(dt))
+        return (q, k, v, -16.0 * jax.nn.softplus(jnp.asarray(x, f32)),
+                jax.nn.sigmoid(jnp.asarray(r.randn(1, t_check, heads), f32)),
+                do)
+
+    @jax.jit
+    def kernels(q, k, v, g, beta, do):
+        ins = {"Q": [q.astype(bf)], "K": [k.astype(bf)],
+               "V": [v.astype(bf)], "G": [g], "Beta": [beta]}
+        out = la._gated_delta_rule(ins, {"chunk": chunk})
+        grads = la._gated_delta_rule_grad(
+            {**ins, "States": out["States"], "GRAD::Out": [do.astype(bf)]},
+            {"chunk": chunk})
+        return (out["Out"][0], *(grads[f"GRAD::{s}"][0]
+                                 for s in ("Q", "K", "V", "G", "Beta")))
+
+    @jax.jit
+    def recurrent(q, k, v, g, beta, do):
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(
+                la.recurrent_gated_delta_rule, *(
+                    x.astype(bf).astype(f32) for x in (q, k, v)), g, beta)
+            return (out, *vjp(do.astype(bf).astype(f32)))
+
+    errs, kernel_ms = {}, {}
+    for gates, dt in (("steep", 0.5), ("mild", 0.002)):
+        args = draw(dt)
+        lowest = float(jnp.min(jnp.cumsum(args[3][:, :chunk], 1)))
+        check((lowest < -200) == (gates == "steep"),
+              f"{gates} gates: G reaches {lowest:.1f} inside a chunk")
+        got = jax.block_until_ready(kernels(*args))
+        for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got,
+                              recurrent(*args)):
+            a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
+            check(bool(jnp.isfinite(a).all()),
+                  f"kda rule {name} not finite with {gates} gates")
+            key = f"{gates}.{name}"
+            errs[key] = float(jnp.abs(a - b).max()
+                              / jnp.maximum(jnp.abs(b).max(), 1e-30))
+            check(errs[key] <= GDN_REL_TOL,
+                  f"kda rule {name}, {gates} gates: the kernels (bf16 "
+                  f"operands) are off the float32 recurrence by "
+                  f"{errs[key]:.4f} of its max (tolerance {GDN_REL_TOL})")
+    if jax.default_backend() == "tpu":
+        kernel_ms, seen = _traced_kernel_ms(
+            "kda_trace", lambda: kernels(*args), "kda.")
+        say(f"  kda kernels, ms a call at t{t_check} h{heads}: {kernel_ms}")
+        check(sorted(kernel_ms) == ["kda.rule.bwd", "kda.rule.fwd"],
+              f"expected the forward and the backward kda.rule.* kernels "
+              f"in the trace: {seen}")
+    row = {"kda": kda, "conv": conv, "attention": attn,
+           "rotary_embeddings": rope, "kda_kernel_ms": kernel_ms,
+           "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
+    say(f"  kda {row['rel_err']}")
     return row
 
 
@@ -2197,6 +2345,7 @@ def main() -> int:
     report["mamba2"], _ = phase("mamba2", mamba2_phase)
     report["sconv"], _ = phase("sconv", sconv_phase)
     report["bd"], _ = phase("bd", bd_phase)
+    report["kda"], _ = phase("kda", kda_phase)
     report["rope"], _ = phase("rope", rope_phase)
     report["loss_head"], _ = phase("loss_head", loss_head_phase)
 

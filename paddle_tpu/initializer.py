@@ -60,6 +60,23 @@ class LogUniformInitializer(UniformInitializer):
                         outputs={"Out": var.name})
 
 
+class InverseSoftplusInitializer(UniformInitializer):
+    """softplus^-1 of dt, log dt ~ uniform(log low, log high): a step
+    size's bias (Kimi Delta Attention's ``dt_bias``, Mamba's), so that
+    softplus(bias) starts log-uniform in [low, high]."""
+
+    def __init__(self, low, high, seed=0):
+        super().__init__(math.log(low), math.log(high), seed)
+
+    def __call__(self, var, block):
+        super().__call__(var, block)
+        # dt = exp(u);  softplus^-1(dt) = log(exp(dt) - 1)
+        for op, attrs in (("exp", {}), ("exp", {}), ("scale", {"bias": -1.0}),
+                          ("log", {})):
+            block.append_op(op, inputs={"X": var.name},
+                            outputs={"Out": var.name}, attrs=attrs)
+
+
 class LogRangeInitializer(ConstantInitializer):
     """log(1), log(2), .. along the last axis, the same in every row: a
     state-space layer's ``A_log`` (Mamba's S4D-real initialisation)."""

@@ -557,7 +557,9 @@ def gdn_gates(b, a, a_log_attr=None, dt_bias_attr=None, name=None):
     """(beta, g) of a gated delta rule from two projections of the
     token, ``b`` and ``a`` [b, t, h]: beta = sigmoid(b), g = -exp(A_log)
     * softplus(a + dt_bias), float32 both. Parameters A_log and dt_bias
-    [h] (defaults: log of uniform(0, 16), and 1)."""
+    [h] (defaults: log of uniform(0, 16), and 1). ``a`` [b, t, h, dk]
+    (a decay a key feature, Kimi Delta Attention's): dt_bias [h, dk],
+    A_log still [h], g [b, t, h, dk]."""
     from paddle_tpu.initializer import LogUniformInitializer
 
     helper = LayerHelper("gdn_gates", name=name)
@@ -566,8 +568,8 @@ def gdn_gates(b, a, a_log_attr=None, dt_bias_attr=None, name=None):
         ParamAttr._to_attr(a_log_attr), shape=[h], dtype="float32",
         default_initializer=LogUniformInitializer(1e-4, 16.0))
     dt_bias = helper.create_parameter(
-        ParamAttr._to_attr(dt_bias_attr), shape=[h], dtype="float32",
-        default_initializer=ConstantInitializer(1.0))
+        ParamAttr._to_attr(dt_bias_attr), shape=list(a.shape[2:]),
+        dtype="float32", default_initializer=ConstantInitializer(1.0))
     beta = helper.create_variable_for_type_inference(dtype="float32")
     g = helper.create_variable_for_type_inference(dtype="float32")
     helper.append_op(
@@ -586,7 +588,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, impl="chunked",
     [b, t, hv] the log of the state's decay and beta [b, t, hv] the
     write strength -> o [b, t, hv, dv]. Per value head a state S
     [dk, dv] from zero: S = exp(g_t) S; S += k_t (beta_t (v_t - S^T
-    k_t))^T; o_t = S^T q_t. ``impl``: "chunked" (the chunkwise form,
+    k_t))^T; o_t = S^T q_t. g [b, t, hv, dk] is a decay a key FEATURE
+    (Kimi Delta Attention, arXiv:2510.26692): S = Diag(exp(g_t)) S; the
+    rank of g decides, and the kernels are ``kda.rule.*``
+    (``kda_tile``). ``impl``: "chunked" (the chunkwise form,
     ``chunk`` positions a step; a sequence the chunk does not divide is
     padded: as the ``gdn.rule.*`` Pallas kernels where
     ``parallel/gated_delta_rule.gdn_tile`` gives the call a tile (bf16
@@ -744,13 +749,16 @@ def diff_attention_combine(o1, o2, lambda_init, head_dim, epsilon=1e-5,
 
 
 def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
-                   gate_first=False, group_size=None):
+                   gate_first=False, group_size=None, gate_act="silu"):
     """rms_norm(input) * gain * silu(gate) over the last axis (a plain
     gain that starts at 1): the norm behind a gated delta rule.
     ``gate_first``: rms_norm(input * silu(gate)) * gain, the gate in
     front of the statistics (Mamba-2's); ``group_size``: the statistics
     over each group of that many features of the last axis, not over all
-    of it (the gain stays one a feature)."""
+    of it (the gain stays one a feature); ``gate_act="sigmoid"``:
+    sigmoid(gate) where silu(gate) stands (Kimi Delta Attention's)."""
+    if gate_act not in ("silu", "sigmoid"):
+        raise ValueError(f"gated_rms_norm: gate_act {gate_act!r}")
     helper = LayerHelper("gated_rms_norm", name=name)
     scale = helper.create_parameter(
         ParamAttr._to_attr(param_attr), shape=[input.shape[-1]],
@@ -761,6 +769,8 @@ def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
     # today's)
     if gate_first:
         attrs["gate_first"] = True
+    if gate_act != "silu":
+        attrs["gate_act"] = gate_act
     if group_size is not None and int(group_size) != input.shape[-1]:
         if input.shape[-1] % int(group_size):
             raise ValueError(f"gated_rms_norm: groups of {group_size} in "

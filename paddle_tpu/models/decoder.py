@@ -3,8 +3,10 @@ writes the same way around its own layers. A family keeps its config
 class, its per-layer decisions, its mixers, its ``topk_moe`` call and its
 auxiliary-loss policy; it calls this module for the parameters' attribute,
 the plain RMSNorm and the bias-free projection, the two feeds, the
-embedding, the vocabulary head with its loss, the last positions' logits
-and the batch of packed tokens:
+embedding, the vocabulary head with its loss, the last positions' logits,
+the batch of packed tokens, and the one mixer two families write the same
+way, multi-head latent attention (``latent_attention``, from its sizes
+alone):
 
     ids, lbl = decoder.token_feeds()
     x = decoder.embed(ids, vocab, width, "<family>_tok_emb.w")
@@ -22,7 +24,8 @@ keys of the per-layer metrics (README "Names in the device trace").
 
 from __future__ import annotations
 
-from typing import Dict
+import math
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -110,6 +113,73 @@ def swiglu_mlp(x, width, out, gate_name, up_name, down_name):
     h = layers.elementwise_mul(layers.silu(linear(x, width, gate_name)),
                                linear(x, width, up_name))
     return linear(h, out, down_name)
+
+
+def _heads_first(z):   # [b, t, heads, dh] -> [b, heads, t, dh]
+    return layers.transpose(z, [0, 2, 1, 3])
+
+
+def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
+                     kv_lora_rank: int, hidden: int, eps: float,
+                     q_lora_rank: Optional[int] = None,
+                     rope_theta: Optional[float] = None):
+    """Multi-head latent attention (DeepSeek-V3's, arXiv:2412.19437
+    2.1.1) on x [b, t, d], parameters ``<p>_attn_*``, inside the caller's
+    ``attn`` scope:
+
+        [c_kv | k_pe] = norm(x) Wkva;  [k_nope | v] = norm(c_kv) Wkvb per head
+        q = norm(norm(x) Wqa) Wqb  (``q_lora_rank`` None: q = norm(x) Wq,
+        no low rank and no norm of it) -> per head [q_nope | q_pe]
+        q_pe, k_pe <- RoPE, pairs (2i, 2i + 1), k_pe ONE head of ``rope``
+        features that all query heads share (``rope_theta`` None: NOTHING
+        is rotated, the features are kept as they come; q then needs no
+        split and no concat)
+        o = causal softmax(q [k_nope | k_pe]^T / sqrt(nope + rope)) v;  o Wo
+
+    Scopes under the caller's: ``q_lora`` (``q`` without a low rank),
+    ``kv_lora``, ``rope`` (the splits, the rotation where there is one,
+    the shared key head's copies and the assembly of the wide k),
+    ``core`` (the sdpa op), ``out``."""
+    h = heads
+    xn = rms_norm(x, eps, f"{p}_attn_norm")
+    if q_lora_rank is None:
+        with fluid.name_scope("q"):
+            q = linear(xn, h * (nope + rope), f"{p}_attn_q_colp.w")
+    else:
+        with fluid.name_scope("q_lora"):
+            c_q = rms_norm(
+                linear(xn, q_lora_rank, f"{p}_attn_q_a.w"), eps,
+                f"{p}_attn_q_a_norm")
+            q = linear(c_q, h * (nope + rope), f"{p}_attn_q_b_colp.w")
+    with fluid.name_scope("kv_lora"):
+        kva = linear(xn, kv_lora_rank + rope, f"{p}_attn_kv_a.w")
+        c_kv, k_rope = layers.split(kva, [kv_lora_rank, rope], dim=-1)
+        kv = linear(
+            rms_norm(c_kv, eps, f"{p}_attn_kv_a_norm"),
+            h * (nope + dv), f"{p}_attn_kv_b_colp.w")
+    with fluid.name_scope("rope"):
+        q = _heads_first(layers.reshape(q, [0, 0, h, nope + rope]))
+        if rope_theta is not None:
+            q_nope, q_rope = layers.split(q, [nope, rope], dim=-1)
+        k_nope, v = layers.split(
+            _heads_first(layers.reshape(kv, [0, 0, h, nope + dv])),
+            [nope, dv], dim=-1)
+        # the shared key features are one head: [b, 1, t, rope]
+        k_rope = layers.unsqueeze(k_rope, [1])
+        if rope_theta is not None:
+            q_rope, k_rope = layers.rotary_embedding(
+                q_rope, k_rope, theta=rope_theta, interleaved=True)
+            q = layers.concat([q_nope, q_rope], axis=3)
+        k = layers.concat(
+            [k_nope, layers.expand(k_rope, [1, h, 1, 1])], axis=3)
+    with fluid.name_scope("core"):
+        # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, 1.0 / math.sqrt(nope + rope), name=f"{p}_attn_sdpa")
+    with fluid.name_scope("out"):
+        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                             [0, 0, h * dv])
+        return linear(ctx, hidden, f"{p}_attn_out_rowp.w")
 
 
 def make_batch(cfg, batch: int, seq_len: int,

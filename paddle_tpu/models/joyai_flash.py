@@ -57,7 +57,6 @@ through the head ``loss_head/mtp``; ``final_norm``, ``loss_head``.
 
 from __future__ import annotations
 
-import math
 from typing import Optional, Tuple
 
 import paddle_tpu as fluid
@@ -140,48 +139,13 @@ def joyai_llm_flash() -> JoyaiFlashConfig:
     return JoyaiFlashConfig()
 
 
-def _heads_first(z):   # [b, t, heads, dh] -> [b, heads, t, dh]
-    return layers.transpose(z, [0, 2, 1, 3])
-
-
 def _latent_attention(x, cfg: JoyaiFlashConfig, p: str):
-    h, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                         cfg.qk_rope_head_dim, cfg.v_head_dim)
-    eps = cfg.rms_norm_eps
-    xn = decoder.rms_norm(x, eps, f"{p}_attn_norm")
-    with fluid.name_scope("q_lora"):
-        c_q = decoder.rms_norm(
-            decoder.linear(xn, cfg.q_lora_rank, f"{p}_attn_q_a.w"), eps,
-            f"{p}_attn_q_a_norm")
-        q = decoder.linear(c_q, h * (nope + rope), f"{p}_attn_q_b_colp.w")
-    with fluid.name_scope("kv_lora"):
-        kva = decoder.linear(xn, cfg.kv_lora_rank + rope, f"{p}_attn_kv_a.w")
-        c_kv, k_rope = layers.split(kva, [cfg.kv_lora_rank, rope], dim=-1)
-        kv = decoder.linear(
-            decoder.rms_norm(c_kv, eps, f"{p}_attn_kv_a_norm"),
-            h * (nope + dv), f"{p}_attn_kv_b_colp.w")
-    with fluid.name_scope("rope"):
-        q_nope, q_rope = layers.split(
-            _heads_first(layers.reshape(q, [0, 0, h, nope + rope])),
-            [nope, rope], dim=-1)
-        k_nope, v = layers.split(
-            _heads_first(layers.reshape(kv, [0, 0, h, nope + dv])),
-            [nope, dv], dim=-1)
-        # the rotary key is one head: [b, 1, t, rope]
-        q_rope, k_rope = layers.rotary_embedding(
-            q_rope, layers.unsqueeze(k_rope, [1]), theta=cfg.rope_theta,
-            interleaved=True)
-        q = layers.concat([q_nope, q_rope], axis=3)
-        k = layers.concat(
-            [k_nope, layers.expand(k_rope, [1, h, 1, 1])], axis=3)
-    with fluid.name_scope("core"):
-        # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
-        ctx = layers.scaled_dot_product_attention(
-            q, k, v, 1.0 / math.sqrt(nope + rope), name=f"{p}_attn_sdpa")
-    with fluid.name_scope("out"):
-        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                             [0, 0, h * dv])
-        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+    return decoder.latent_attention(
+        x, p, heads=cfg.num_attention_heads, nope=cfg.qk_nope_head_dim,
+        rope=cfg.qk_rope_head_dim, dv=cfg.v_head_dim,
+        kv_lora_rank=cfg.kv_lora_rank, hidden=cfg.hidden_size,
+        eps=cfg.rms_norm_eps, q_lora_rank=cfg.q_lora_rank,
+        rope_theta=cfg.rope_theta)
 
 
 def _dense_ffn(x, cfg: JoyaiFlashConfig, p: str):

@@ -95,8 +95,9 @@ _M_DISPATCH = _monitor.counter(
     "gated delta-rule calls lowered, by pass (fwd, bwd), shape (batch, "
     "positions, key heads, value heads and their widths), chunk (the "
     "positions of one scan step; 1 for the recurrent form) and impl "
-    "(kernel: a gdn.* Pallas kernel; chunked: the chunkwise form as XLA "
-    "ops; recurrent: one scan step a position)")
+    "(kernel: a gdn.* or kda.* Pallas kernel; chunked: the chunkwise form "
+    "as XLA ops; recurrent: one scan step a position) and gate (head: one "
+    "decay a value head and position; feature: one a key feature)")
 
 
 _M_CONV_DISPATCH = _monitor.counter(
@@ -113,7 +114,13 @@ def _x(ins, slot, i=0):
     return v[i] if v else None
 
 
-def _note_dispatch(direction, q, v, chunk, impl):
+def _by_feature(g, q):
+    """Whether G is a decay a key FEATURE [b, t, hv, dk] (Kimi Delta
+    Attention) and not one a head [b, t, hv]: its rank says, no flag."""
+    return g.ndim == q.ndim
+
+
+def _note_dispatch(direction, q, v, chunk, impl, g=None):
     # off with telemetry; build-time shape inference is not a lowering
     from paddle_tpu.core import interp
 
@@ -123,7 +130,9 @@ def _note_dispatch(direction, q, v, chunk, impl):
     _M_DISPATCH.inc(labels={
         "pass": direction,
         "shape": f"b{b} t{t} hk{hk} hv{v.shape[2]} dk{dk} dv{v.shape[3]}",
-        "chunk": str(chunk), "impl": impl})
+        "chunk": str(chunk), "impl": impl,
+        "gate": ("feature" if g is not None and _by_feature(g, q)
+                 else "head")})
 
 
 def _note_conv(direction, x, taps, impl, gated=False):
@@ -296,10 +305,14 @@ def _gdn_gates(ins, attrs):
     -> Beta = sigmoid(B), the write strength, and G = -exp(ALog) *
     softplus(A + DtBias), the log of the state's decay (<= 0). Float32
     whatever the inputs' dtype: both are exponentiated and summed over
-    a chunk."""
+    a chunk. A decay a key feature (Kimi Delta Attention): A
+    [b, t, h, dk] with DtBias [h, dk] and ALog still [h] -> G
+    [b, t, h, dk]."""
     f32 = jnp.float32
     b, a = _x(ins, "B").astype(f32), _x(ins, "A").astype(f32)
     a_log, dt = _x(ins, "ALog").astype(f32), _x(ins, "DtBias").astype(f32)
+    if a.ndim == b.ndim + 1:
+        a_log = a_log[:, None]
     return {"Beta": [jax.nn.sigmoid(b)],
             "G": [-jnp.exp(a_log) * jax.nn.softplus(a + dt)]}
 
@@ -311,16 +324,20 @@ def _gated_rms_norm(ins, attrs):
     width, a plain gain. Statistics, gain and gate in float32, Y in X's
     dtype. ``gate_first``: Y = norm(X * silu(Z)) * Scale, the gate in
     front of the statistics (Mamba-2's); ``group_size``: the mean over
-    each group of that many features of the last axis."""
+    each group of that many features of the last axis; ``gate_act``:
+    "sigmoid" for sigmoid(Z) where silu(Z) stands (Kimi Delta
+    Attention's output gate)."""
     f32 = jnp.float32
     x, z, scale = _x(ins, "X"), _x(ins, "Z"), _x(ins, "Scale")
     xf = x.astype(f32)
     eps = attrs.get("epsilon", 1e-6)
+    act = (jax.nn.sigmoid if attrs.get("gate_act") == "sigmoid"
+           else jax.nn.silu)
     if not attrs.get("gate_first") and not attrs.get("group_size"):
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-        y = y * scale.astype(f32) * jax.nn.silu(z.astype(f32))
+        y = y * scale.astype(f32) * act(z.astype(f32))
         return {"Y": [y.astype(x.dtype)]}
-    gate = jax.nn.silu(z.astype(f32))
+    gate = act(z.astype(f32))
     if attrs.get("gate_first"):
         xf = xf * gate
     size = int(attrs.get("group_size") or x.shape[-1])
@@ -361,6 +378,70 @@ def _mm(spec, a, b, dtype):
                       preferred_element_type=jnp.float32)
 
 
+def _decayed_products(q, k, gc, dtype):
+    """A decay a key feature sits INSIDE the contraction: -> (P, K')
+    [.., C, C] float32, P_ij = sum_d q_id k_jd exp(G_id - G_jd) for
+    i >= j and K'_ij the same of k and k for i > j, zeros elsewhere,
+    from q, k, G [.., C, dk]. Every exponent that is formed is <= 0
+    (``(k e^G) (k e^-G)^T`` ends at e^88, and G passes -100 inside a
+    chunk at the family's initialisation): positions i > j part at ONE
+    level of halving, the s with i // 2s == j // 2s and i // s ==
+    j // s + 1; there r = (i // s) s, the first row of i's block, lies
+    between them, j < r <= i, and
+    exp(G_i - G_j) = exp(G_i - G_r) exp(G_r - G_j): two factors <= 1,
+    one on each operand of that level's product, which is masked to the
+    level's blocks. log2(C) products of [C, dk] x [dk, C] for one, each
+    a plain matmul; the diagonal of P is q_i . k_i. Exact whatever the
+    gates (no clamp, no reference further than a block away)."""
+    c = q.shape[-2]
+    pos = jnp.arange(c)
+    p = jnp.where(jnp.eye(c, dtype=bool),
+                  jnp.sum(q * k, -1)[..., :, None], 0.0)
+    kk = jnp.zeros_like(p)
+    s = 1
+    while s < c:
+        blk = pos // s
+        odd = (blk % 2 == 1)[:, None]
+        # rows of an odd block: down from the block's first row r; rows
+        # of the even block in front of it: up to r
+        e = jnp.exp(jnp.where(
+            odd, gc - jnp.take(gc, blk * s, axis=-2), 0.0))
+        f = jnp.exp(jnp.where(
+            odd, 0.0,
+            jnp.take(gc, jnp.minimum((blk + 1) * s, c - 1), axis=-2) - gc))
+        level = odd & (blk[:, None] == blk[None, :] + 1)
+        kf = k * f
+        p = p + jnp.where(
+            level, _mm("...ik,...jk->...ij", q * e, kf, dtype), 0.0)
+        kk = kk + jnp.where(
+            level, _mm("...ik,...jk->...ij", k * e, kf, dtype), 0.0)
+        s *= 2
+    return p, kk
+
+
+def _feature_parts(q, k, v, g, beta, dtype):
+    """``_chunk_parts`` where g is [b, h, n, C, dk], a decay a key
+    feature: every exp(G) a [C, dk] array where it was a column, A and
+    the chunk's attention from ``_decayed_products``, exp(G_C) [.., dk]
+    (a factor a ROW of the state)."""
+    c = q.shape[-2]
+    gc = jnp.cumsum(g, -2)
+    eg = jnp.exp(gc)
+    attn, kk = _decayed_products(q, k, gc, dtype)
+    rhs = jnp.concatenate(
+        [v.astype(jnp.float32) * beta[..., None],
+         k * beta[..., None] * eg], -1)
+    sol = jax.lax.linalg.triangular_solve(
+        kk * beta[..., None] + jnp.eye(c, dtype=kk.dtype), rhs,
+        left_side=True, lower=True, unit_diagonal=True)
+    dv = v.shape[-1]
+    u, w = sol[..., :dv], sol[..., dv:]
+    g_last = gc[..., -1:, :]
+    return (w.astype(dtype), u, (q * eg).astype(dtype),
+            (k * jnp.exp(g_last - gc)).astype(dtype), attn.astype(dtype),
+            eg[..., -1, :])
+
+
 def _chunk_parts(q, k, v, g, beta, dtype):
     """The part of the chunkwise form that is parallel over chunks.
     q, k [b, h, n, C, dk] float32 (normalised), v [b, h, n, C, dv], g,
@@ -369,7 +450,9 @@ def _chunk_parts(q, k, v, g, beta, dtype):
     module docstring's W, U, Q exp(G), K exp(G_C - G), lower(Q K^T . D)
     and exp(G_C). U (subtracted from) and exp(G_C) (the state's decay)
     are float32; the other four are only ever matmul operands and leave
-    in ``dtype``."""
+    in ``dtype``. g [b, h, n, C, dk]: ``_feature_parts``."""
+    if _by_feature(g, q):
+        return _feature_parts(q, k, v, g, beta, dtype)
     c = q.shape[-2]
     gc = jnp.cumsum(g, -1)
     lower = jnp.tril(jnp.ones((c, c), bool))
@@ -400,6 +483,13 @@ def _chunks_first(x):
     return jnp.moveaxis(x, 2, 0)
 
 
+def _over_state(dec):
+    """exp(G_C) of one chunk as a factor of the state [b, h, dk, dv]: one
+    a head [b, h], or one a key feature [b, h, dk], a row of the state
+    each."""
+    return dec[..., None, None] if dec.ndim == 2 else dec[..., None]
+
+
 def _chunk_scan(parts, dtype):
     """The scan over chunks -> (o [b, h, n, C, dv] float32, the state
     each chunk started from [n, b, h, dk, dv] in ``dtype``)."""
@@ -413,7 +503,7 @@ def _chunk_scan(parts, dtype):
         vn = u - _mm("bhck,bhkv->bhcv", w, sm, dtype)
         o = (_mm("bhck,bhkv->bhcv", qg, sm, dtype)
              + _mm("bhij,bhjv->bhiv", attn, vn, dtype))
-        s = (s * dec[..., None, None]
+        s = (s * _over_state(dec)
              + _mm("bhck,bhcv->bhkv", kd, vn, dtype))
         return s, (o, sm)
 
@@ -436,8 +526,9 @@ def _chunk_scan_bwd(parts, states, do, dtype):
         dqg = _mm("bhcv,bhkv->bhck", do, sm, dtype)
         dkd = _mm("bhcv,bhkv->bhck", vn, ds, dtype)
         dw = -_mm("bhcv,bhkv->bhck", dvn, sm, dtype)
-        ddec = jnp.sum(ds * sm.astype(jnp.float32), (-2, -1))
-        ds = (ds * dec[..., None, None]
+        ddec = jnp.sum(ds * sm.astype(jnp.float32),
+                       (-2, -1) if dec.ndim == 2 else -1)
+        ds = (ds * _over_state(dec)
               + _mm("bhck,bhcv->bhkv", qg, do, dtype)
               - _mm("bhck,bhcv->bhkv", w, dvn, dtype))
         return ds, (dw.astype(dtype), dvn, dqg.astype(dtype),
@@ -482,10 +573,14 @@ def _unchunked(o, t, dtype):
 def recurrent_gated_delta_rule(q, k, v, g, beta, eps=1e-6):
     """The recurrence of the module docstring, one scan step a position:
     q, k [b, t, hk, dk], v [b, t, hv, dv], g, beta [b, t, hv] -> o
-    [b, t, hv, dv] in v's dtype. Float32 throughout."""
+    [b, t, hv, dv] in v's dtype. g [b, t, hv, dk]: S_t = Diag(exp(g_t))
+    S_{t-1}, a decay a ROW of the state (one broadcast apart). Float32
+    throughout."""
     rep = v.shape[2] // q.shape[2]
     qn, kn = _normalised(q, k, eps)
     f32 = jnp.float32
+    if not _by_feature(g, q):
+        g = g[..., None]
     xs = tuple(jnp.moveaxis(x, 2, 0) for x in (
         _heads_first(qn, rep), _heads_first(kn, rep),
         _heads_first(v.astype(f32)), _heads_first(g.astype(f32)),
@@ -493,7 +588,7 @@ def recurrent_gated_delta_rule(q, k, v, g, beta, eps=1e-6):
 
     def step(s, x):
         q_t, k_t, v_t, g_t, b_t = x
-        s = s * jnp.exp(g_t)[..., None, None]
+        s = s * jnp.exp(g_t)[..., None]
         delta = (v_t - jnp.einsum("bhkv,bhk->bhv", s, k_t)) * b_t[..., None]
         s = s + k_t[..., :, None] * delta[..., None, :]
         return s, jnp.einsum("bhkv,bhk->bhv", s, q_t)
@@ -511,31 +606,34 @@ def _args(ins, attrs):
             float(attrs.get("epsilon", 1e-6)))
 
 
-def _kernel_tile(q, k, v, chunk):
-    """``gdn_tile``'s answer for a chunked call: the tile of the gdn.*
+def _kernel_tile(q, k, v, g, chunk):
+    """``gdn_tile``'s answer for a chunked call (``kda_tile``'s where
+    the decay is one a key feature): the tile of the gdn.* or kda.*
     kernels, or None for the XLA ops below."""
     if not q.dtype == k.dtype == v.dtype:
         return None
     _, t, hk, dk = q.shape
-    return _kernels.gdn_tile(t, hk, v.shape[2], dk, v.shape[3], chunk,
-                             q.dtype)
+    tile = _kernels.kda_tile if _by_feature(g, q) else _kernels.gdn_tile
+    return tile(t, hk, v.shape[2], dk, v.shape[3], chunk, q.dtype)
 
 
 @register_op("gated_delta_rule", diff_inputs=("Q", "K", "V", "G", "Beta"))
 def _gated_delta_rule(ins, attrs):
     """Q, K [b, t, hk, dk] (normalised here), V [b, t, hv, dv] (hv a
-    multiple of hk), G [b, t, hv] (log decay, <= 0) and Beta [b, t, hv]
+    multiple of hk), G [b, t, hv] (log decay, <= 0; [b, t, hv, dk] for
+    a decay a key feature, Kimi Delta Attention's: the rank decides)
+    and Beta [b, t, hv]
     (write strength), float32 both -> Out [b, t, hv, dv] in V's dtype
     and States [n, b, hv, dk, dv], the state each chunk of ``chunk``
     positions started from, for the paired grad op (dead at inference;
     one zero for ``impl="recurrent"``). See the module docstring."""
     (q, k, v, g, beta), chunk, impl, eps = _args(ins, attrs)
     if impl == "recurrent":
-        _note_dispatch("fwd", q, v, 1, impl)
+        _note_dispatch("fwd", q, v, 1, impl, g)
         return {"Out": [recurrent_gated_delta_rule(q, k, v, g, beta, eps)],
                 "States": [jnp.zeros((1,), q.dtype)]}
-    tile = _kernel_tile(q, k, v, chunk)
-    _note_dispatch("fwd", q, v, chunk, "kernel" if tile else impl)
+    tile = _kernel_tile(q, k, v, g, chunk)
+    _note_dispatch("fwd", q, v, chunk, "kernel" if tile else impl, g)
     if tile:
         o, states = _kernels.gated_delta_rule_fwd(q, k, v, g, beta, tile,
                                                   eps)
@@ -557,16 +655,16 @@ def _gated_delta_rule_grad(ins, attrs):
     (q, k, v, g, beta), chunk, impl, eps = _args(ins, attrs)
     do = _x(ins, "GRAD::Out")
     if impl == "recurrent":
-        _note_dispatch("bwd", q, v, 1, impl)
+        _note_dispatch("bwd", q, v, 1, impl, g)
         _, vjp = jax.vjp(
             lambda *a: recurrent_gated_delta_rule(*a, eps), q, k, v, g, beta)
         grads = vjp(do.astype(v.dtype))
-    elif tile := _kernel_tile(q, k, v, chunk):
-        _note_dispatch("bwd", q, v, chunk, "kernel")
+    elif tile := _kernel_tile(q, k, v, g, chunk):
+        _note_dispatch("bwd", q, v, chunk, "kernel", g)
         grads = _kernels.gated_delta_rule_bwd(
             q, k, v, g, beta, _x(ins, "States"), do, tile, eps)
     else:
-        _note_dispatch("bwd", q, v, chunk, impl)
+        _note_dispatch("bwd", q, v, chunk, impl, g)
         dtype = q.dtype
 
         def parallel_part(q, k, v, g, beta):

@@ -96,6 +96,49 @@ the state as an operand, and the cotangents' products.
 (ops/linear_attention_ops._chunk_parts / _chunk_scan, unchanged), from
 the call's own shapes, the dtype, the backend and the mesh;
 ``pt_linear_attention_dispatch_total{impl}`` records its answer.
+
+**A decay a key feature** (Kimi Delta Attention, arXiv:2510.26692: g
+[b, t, hv, dk], S_t = Diag(exp(g_t)) S_{t-1}; the RANK of g decides):
+the same two calls under the names ``kda.rule.fwd`` / ``kda.rule.bwd``
+(family ``kda``), where ``kda_tile`` gives a tile (``gdn_tile``'s
+conditions and hk == hv: every head has keys of its own). The inversion
+(``_substitute``, ``_merge``, ``_invert``), ``_apply`` and the state
+chain are the ONE copy above; a grid step takes two heads, so that the
+inversion's two triangles side by side over the lanes are two heads of
+different keys. What is the vector rule's own:
+
+- *the pre-pass* (``_prepare_kda``): g is read in place, a float32 block
+  [rows, heads * 128] beside q and k; its running sum down a chunk is
+  six sublane rolls (``_running``). With a decay a feature the factor
+  exp(G_id - G_jd) sits INSIDE the contraction over d, and
+  ``(K e^G)(K e^-G)^T`` ends at e^88 where G passes -100 inside a chunk
+  at the family's initialisation. ``_levels`` halves the chunk instead:
+  rows i > j part at the one block size s with i // 2s == j // 2s and
+  i // s == j // s + 1, the first row r of i's block lies between them,
+  and exp(G_i - G_j) = exp(G_i - G_r) exp(G_r - G_j) puts a factor <= 1
+  on each operand: six products [Q E; K E] (K F)^T a head and chunk,
+  masked to their level's blocks, give P = lower(Q K^T . D) and
+  A / beta at once, exactly, whatever the gates (no clamp; G_r reaches
+  its block by log2(s) sublane rolls). Q . e^G, beta K . e^G and
+  K . e^{G_C - G} are element-wise products; e^{G_C} multiplies the ROWS
+  of the state and is kept as a [dk, dv] block (``_down_rows``: one
+  transpose a chunk in the pre-pass, none in the chain);
+- *the pass behind the backward loop* (``_behind_kda``): two products a
+  level give dq and dk through the decays, and dG = q . dq + k .
+  (dk_row - dk_col) over the same terms needs no third; the element-wise
+  operands give theirs feature by feature; dg [b, t, hv * dk] float32
+  is dG's running sum up the chunk.
+
+The forward saves ``States`` and nothing of size t x C x dk; the
+backward recomputes once, as ``gdn.rule.bwd`` does. Alone at
+kimilinear-train-s4096's call on a v5e (b1 t4096, 32 heads of 128: 256
+grid steps of 2 heads x 8 chunks; benchmarks/kda_rule_time.py, my chip
+run, PR 64; ms a call, forward / backward): ``kda.rule.*`` **2.28 /
+4.44**, beside ``gdn.rule.*`` at the same shape with one decay a head
+(hk = hv = 32) 2.15 / 3.32 and the chunked XLA form of the vector rule
+16.8 forward; both kernels within 0.5% of the float32 recurrence's
+largest entry, Out and the five gradients, with G at -574 inside a
+chunk and with mild gates.
 """
 
 from __future__ import annotations
@@ -143,7 +186,7 @@ def _under_mesh() -> bool:
     return interp.spmd_ctx() is not None
 
 
-def _parts(heads, chunks, dk, dv, dtype, backward):
+def _parts(heads, chunks, dk, dv, dtype, backward, vector=False):
     """name -> (shape, dtype) of what a grid step makes ONCE of its
     chunks and keeps in VMEM for the loops behind (the module
     docstring's pre-pass), one entry a matrix (``_mat``) or a chunk:
@@ -154,13 +197,29 @@ def _parts(heads, chunks, dk, dv, dtype, backward):
     the normalised q, k and 1 / |x|, K K^T and Q K^T, and what its state
     loop leaves for the pass behind it: ``dd`` [dV' | dW], then
     [T^T dV' | T^T dW] in its place, dA, and the cotangents of the
-    chunk's attention, Q e^G, K e^{G_C - G} and e^{G_C}."""
+    chunk's attention, Q e^G, K e^{G_C - G} and e^{G_C}.
+
+    ``vector`` (``kda.rule.*``: a decay a key feature, every head its
+    own keys): e^{G_C} is a factor a ROW of the state and is kept over
+    the whole [dk, dv]; backward, the running sum G itself (the levels'
+    factors are made again from it), K' = A / beta where K K^T . D
+    stood, q, k and their norms a head, and ds . S whole (its sum over
+    a row is the pass behind the loop's)."""
     mats = heads * chunks
     mat, tri = (mats, CHUNK, _LANES), (mats, CHUNK, CHUNK)
+    whole = (mats, dk, dv) if vector else (mats, 8, _LANES)
     parts = dict(uw=((mats, CHUNK, dv + dk), _F32), attn=(tri, dtype),
                  wq=((mats, 2 * CHUNK, dk), dtype),
-                 kd=((mats, CHUNK, dk), dtype), dec=((mats, 8, _LANES), _F32))
-    if backward:
+                 kd=((mats, CHUNK, dk), dtype), dec=(whole, _F32))
+    if backward and vector:
+        parts.update(
+            beta=(mat, _F32), eg=(mat, _F32), ekd=(mat, _F32),
+            gc=(mat, _F32), kn=(mat, _F32), yq=(mat, _F32), rk=(mat, _F32),
+            rq=(mat, _F32), kk=(tri, _F32),
+            dd=((mats, CHUNK, dv + dk), _F32), da=(tri, _F32),
+            dattn=(tri, _F32), dqg=((mats, CHUNK, dk), _F32),
+            dkd=((mats, CHUNK, dk), _F32), ddec=(whole, _F32))
+    elif backward:
         row = (chunks, CHUNK, _LANES)
         parts.update(
             decay=(tri, _F32), beta=(mat, _F32), eg=(mat, _F32),
@@ -185,25 +244,28 @@ def _tiled_bytes(shape, dtype):
     return n
 
 
-def _vmem_bytes(heads, chunks, dk, dv):
+def _vmem_bytes(heads, chunks, dk, dv, vector=False):
     """What one grid step of the backward kernel (the larger) keeps in
     VMEM: its blocks double-buffered (q, k, dq, dk; v, dO, dv; the
     states; g, beta and their gradients padded to a sublane tile) and
-    the scratch (``_scratch``)."""
+    the scratch (``_scratch``). ``vector``: q, k and their gradients
+    ``heads`` wide, g and dg float32 blocks as wide."""
     rows = chunks * CHUNK
     blocks = (4 * rows * dk * 2 + 3 * rows * heads * dv * 2
               + chunks * heads * dk * dv * 2
               + 4 * heads * max(chunks, 8) * _LANES * 4)
+    if vector:
+        blocks += 4 * rows * (heads - 1) * dk * 2 + 2 * rows * heads * dk * 4
     scratch = sum(_tiled_bytes(x.shape, x.dtype) for x in _scratch(
-        heads, chunks, dk, dv, jnp.bfloat16, True))
+        heads, chunks, dk, dv, jnp.bfloat16, True, vector))
     return 2 * blocks + scratch
 
 
-def _vmem_limit(heads, chunks, dk, dv):
+def _vmem_limit(heads, chunks, dk, dv, vector=False):
     """Mosaic's scoped limit for a call at this tile: the blocks and the
     scratch, and as much again for the values of a loop body, not under
     its default of 16 MiB."""
-    return max(16 * 2**20, 2 * _vmem_bytes(heads, chunks, dk, dv))
+    return max(16 * 2**20, 2 * _vmem_bytes(heads, chunks, dk, dv, vector))
 
 
 def gdn_tile(t, hk, hv, dk, dv, chunk, dtype, backend=None, on_mesh=None):
@@ -231,6 +293,25 @@ def gdn_tile(t, hk, hv, dk, dv, chunk, dtype, backend=None, on_mesh=None):
     heads = hv // hk
     chunks = min(_STEP_CHUNKS, -(-t // CHUNK))
     if _vmem_bytes(heads, chunks, dk, dv) > _VMEM_CAP_BYTES:
+        return None
+    return heads, chunks
+
+
+def kda_tile(t, hk, hv, dk, dv, chunk, dtype, backend=None, on_mesh=None):
+    """``gdn_tile`` for a decay a key feature (g [b, t, hv, dk]): ->
+    (heads, chunks) of one grid step of ``kda.rule.*``, or None for the
+    chunked XLA form, on ``gdn_tile``'s conditions and one more: every
+    value head has keys of its own (hk == hv; Kimi Delta Attention has
+    no group). With no group to share a key head, a grid step takes TWO
+    heads where the count is even: the inversion works on two triangles
+    side by side over the 128 lanes (``_slot``), and here they are two
+    heads of different keys."""
+    if hk != hv or gdn_tile(t, hk, hv, dk, dv, chunk, dtype, backend,
+                            on_mesh) is None:
+        return None
+    heads = 2 if hv % 2 == 0 else 1
+    chunks = min(_STEP_CHUNKS, -(-t // CHUNK))
+    if _vmem_bytes(heads, chunks, dk, dv, True) > _VMEM_CAP_BYTES:
         return None
     return heads, chunks
 
@@ -383,6 +464,120 @@ def _prepare(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, *, heads,
                 p["beta"][ms], p["eg"][ms] = wide(beta), wide(eg)
                 p["ekd"][ms] = wide(ekd)
         halves += [jnp.zeros_like(kk)] * (2 - len(halves))
+        a_ref[_pairs(r0, chunks)] = jnp.concatenate(halves, axis=2)
+
+
+def _positions(chunks):
+    """Each row's position in its chunk, [chunks, C, 128]."""
+    return jax.lax.broadcasted_iota(jnp.int32, (chunks, CHUNK, _LANES), 1)
+
+
+def _running(x, pos, back=False):
+    """x [chunks, C, 128] -> its running sum down a chunk's rows
+    (``back``: up them, row i the sum of rows i ..): six sublane rolls,
+    float32 adds in the order of a tree."""
+    s = 1
+    while s < CHUNK:
+        if back:
+            x = x + jnp.where(pos < CHUNK - s,
+                              pltpu.roll(x, CHUNK - s, axis=1), 0.0)
+        else:
+            x = x + jnp.where(pos >= s, pltpu.roll(x, s, axis=1), 0.0)
+        s *= 2
+    return x
+
+
+def _levels(gc, pos, ii, jj):
+    """The halving of ``ops/linear_attention_ops._decayed_products`` on
+    the running sum G [chunks, C, 128] of a head's chunks: for each
+    block size s = 1, 2, .. C / 2 -> (E, F [chunks, C, 128], the level's
+    mask [C, C]). Rows i > j part at ONE level, the s with i // 2s ==
+    j // 2s and i // s == j // s + 1 (i in an odd block, j in the even
+    one in front); with r the first row of i's block, j < r <= i and
+    exp(G_i - G_j) = E_i F_j, E_i = exp(G_i - G_r), F_j = exp(G_r - G_j):
+    no exponent is above 0 whatever the gates (rows a level does not
+    use get exp(0)). G_r reaches the rows of its block by log2(s)
+    sublane rolls and the block in front by one more."""
+    out, k = [], 0
+    while 1 << k < CHUNK:
+        s = 1 << k
+        odd = (pos >> k) & 1 == 1
+        first = jnp.where(pos & (s - 1) == 0, gc, 0.0)
+        d = 1
+        while d < s:
+            first = first + pltpu.roll(first, d, axis=1)
+            d *= 2
+        e = jnp.exp(jnp.where(odd, gc - first, 0.0))
+        f = jnp.exp(jnp.where(
+            odd, 0.0, pltpu.roll(first, CHUNK - s, axis=1) - gc))
+        level = ((ii >> k) & 1 == 1) & (ii >> k == (jj >> k) + 1)
+        out.append((e, f, level))
+        k += 1
+    return out
+
+
+def _down_rows(row):
+    """[chunks, 1, 128] -> [chunks, 128, 128]: entry d of the row all
+    along ROW d (a factor a row of the state [dk, dv])."""
+    wide = jnp.broadcast_to(row, (row.shape[0], _LANES, _LANES))
+    return jnp.swapaxes(wide, 1, 2)
+
+
+def _prepare_kda(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, *, heads,
+                 chunks, eps, scale):
+    """``_prepare`` for a decay a key feature: g_ref [chunks * C, heads *
+    dk] float32 read in place beside q and k, every head its own q and
+    k. Per head: the running sum G [chunks, C, 128] (``_running``), then
+    P_ij = sum_d q_id k_jd exp(G_id - G_jd) (i >= j) and K' the same of
+    k, k (i > j) one level of halving at a time (``_levels``: a product
+    of [Q E; K E] against K F a level, masked to the level's blocks; the
+    diagonal of P is q_i . k_i), a_ref[pair] <- A = beta K', two heads
+    side by side, and ``_parts``' operands with every exp(G) a [C, dk]
+    array: Q . e^G, beta K . e^G, K . e^{G_C - G}, and e^{G_C} down the
+    rows of a [dk, dv] block."""
+    dtype = q_ref.dtype
+    dk, dv = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+    ii, jj = _iotas()
+    pos = _positions(chunks)
+    backward = "gc" in p
+    for r0 in range(0, heads, 2):
+        halves = []
+        for r in range(r0, min(r0 + 2, heads)):
+            ms = _mats(r, chunks)
+            cols = slice(r * dk, (r + 1) * dk)
+            yq, rq = _l2(_by_chunk(q_ref, chunks, cols), eps)
+            kn, rk = _l2(_by_chunk(k_ref, chunks, cols), eps)
+            qn = yq * scale
+            gc = _running(_by_chunk(g_ref, chunks, cols), pos)
+            beta = _col(_gate_rows_of(beta_ref, r, chunks), ii, jj)
+            attn = jnp.where(
+                ii == jj, jnp.sum(qn * kn, axis=-1, keepdims=True), 0.0)
+            kk = jnp.zeros_like(attn)
+            for e, f, level in _levels(gc, pos, ii, jj):
+                both = _bdot(
+                    jnp.concatenate([qn * e, kn * e], axis=1).astype(dtype),
+                    (kn * f).astype(dtype), 2, 2)
+                attn = attn + jnp.where(level, both[:, :CHUNK], 0.0)
+                kk = kk + jnp.where(level, both[:, CHUNK:], 0.0)
+            eg = jnp.exp(gc)
+            g_last = gc[:, CHUNK - 1:, :]
+            ekd = jnp.exp(g_last - gc)
+            halves.append(beta * kk)
+            p["attn"][ms] = attn.astype(dtype)
+            p["wq"][ms, CHUNK:] = (qn * eg).astype(dtype)
+            p["kd"][ms] = (kn * ekd).astype(dtype)
+            v = _by_chunk(v_ref, chunks, slice(r * dv, (r + 1) * dv))
+            p["uw"][ms] = jnp.concatenate(
+                [beta * v.astype(_F32), (beta * eg) * kn], axis=2)
+            p["dec"][ms] = _down_rows(jnp.exp(g_last))
+            if backward:
+                shape = (chunks, CHUNK, _LANES)
+                p["kn"][ms], p["yq"][ms], p["gc"][ms] = kn, yq, gc
+                p["rk"][ms] = jnp.broadcast_to(rk, shape)
+                p["rq"][ms] = jnp.broadcast_to(rq, shape)
+                p["beta"][ms] = jnp.broadcast_to(beta, shape)
+                p["eg"][ms], p["ekd"][ms], p["kk"][ms] = eg, ekd, kk
+        halves += [jnp.zeros_like(halves[0])] * (2 - len(halves))
         a_ref[_pairs(r0, chunks)] = jnp.concatenate(halves, axis=2)
 
 
@@ -544,20 +739,27 @@ def _d_triangle(dr, uw):
 # ---------------------------------------------------------------------------
 
 
+def _decay_of(p, m, vector):
+    """e^{G_C} of matrix ``m`` as the state's factor: one a head over a
+    row of lanes, or (``vector``) one a row of the state [dk, dv]."""
+    return p["dec"][m] if vector else p["dec"][m, :1]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
                 s_ref, a_ref, t_ref, x_ref, *parts, heads, chunks, eps,
-                scale):
+                scale, vector=False):
     dtype = q_ref.dtype
     dv = v_ref.shape[-1] // heads
-    p = dict(zip(_parts(heads, chunks, q_ref.shape[-1], dv, dtype, False),
-                 parts))
+    dk = q_ref.shape[-1] // heads if vector else q_ref.shape[-1]
+    p = dict(zip(_parts(heads, chunks, dk, dv, dtype, False, vector), parts))
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    _prepare(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, heads=heads,
-             chunks=chunks, eps=eps, scale=scale)
+    (_prepare_kda if vector else _prepare)(
+        q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, heads=heads,
+        chunks=chunks, eps=eps, scale=scale)
     _invert(a_ref, t_ref, x_ref)
 
     def chain(c):               # the state chain: three products a head
@@ -571,7 +773,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, states_ref,
             vn = (p["uw"][m, :, :dv] - ws_qs[:CHUNK]).astype(dtype)
             o = ws_qs[CHUNK:] + _dot(p["attn"][m], vn, 1, 0)
             o_ref[r, rows, :] = o.astype(o_ref.dtype)
-            s_ref[r] = s * p["dec"][m, :1] + _dot(p["kd"][m], vn, 0, 0)
+            s_ref[r] = (s * _decay_of(p, m, vector)
+                        + _dot(p["kd"][m], vn, 0, 0))
 
     _apply(t_ref, p, heads=heads, chunks=chunks)
     _in_turn(chunks, chain)
@@ -595,27 +798,36 @@ def _gate_rows(x, n_chunks):
 def _operands(q, k, v, g, beta, tile):
     """The op's inputs as the kernels' blocks read them: the heads
     folded into the lanes (no copy), the gates chunked and heads first
-    (a megabyte), everything padded to whole grid steps with zeros
-    (beta 0 writes nothing, g 0 forgets nothing, q 0 reads nothing)."""
+    (a megabyte; a decay a key feature [b, t, hv, dk] folded as q is,
+    float32, and read in place), everything padded to whole grid steps
+    with zeros (beta 0 writes nothing, g 0 forgets nothing, q 0 reads
+    nothing)."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     chunks = tile[1]
     n = -(-t // CHUNK)
     n_pad = -(-n // chunks) * chunks
     tp = n_pad * CHUNK
+    def gates(g):
+        if g.ndim == q.ndim:
+            return _padded(g.astype(_F32), 1, tp).reshape(b, tp, hv * dk)
+        return _gate_rows(g, n_pad)
+
     return (_padded(q, 1, tp).reshape(b, tp, hk * dk),
             _padded(k, 1, tp).reshape(b, tp, hk * dk),
             _padded(v, 1, tp).reshape(b, tp, hv * dv),
-            _gate_rows(g, n_pad), _gate_rows(beta, n_pad)), n, n_pad
+            gates(g), _gate_rows(beta, n_pad)), n, n_pad
 
 
-def _specs(heads, chunks, dk, dv, blk):
+def _specs(heads, chunks, dk, dv, blk, key_heads=1):
     """BlockSpecs of (q or k [b, t, hk * dk], v-like [b, t, hv * dv], o
     [b, hv, t, dv], gate-like [b, hv, n, C], states) for a grid (batch,
     key head, chunk block) whose block index along the sequence is
-    ``blk(c)``."""
+    ``blk(c)``. ``key_heads``: the key heads of a grid step (kda.rule.*:
+    as many as value heads, and its g a block like q's)."""
     rows = chunks * CHUNK
-    return (pl.BlockSpec((None, rows, dk), lambda i, h, c: (i, blk(c), h)),
+    return (pl.BlockSpec((None, rows, key_heads * dk),
+                         lambda i, h, c: (i, blk(c), h)),
             pl.BlockSpec((None, rows, heads * dv),
                          lambda i, h, c: (i, blk(c), h)),
             pl.BlockSpec((None, heads, rows, dv),
@@ -626,7 +838,7 @@ def _specs(heads, chunks, dk, dv, blk):
                          lambda i, h, c: (blk(c), i, h, 0, 0)))
 
 
-def _scratch(heads, chunks, dk, dv, dtype, backward):
+def _scratch(heads, chunks, dk, dv, dtype, backward, vector=False):
     """S or dS; A of the block's chunks and pairs of heads (``_slot``),
     T of each matrix, the pairs' diagonal blocks (``_substitute``); then
     ``_parts``, in its order."""
@@ -636,7 +848,7 @@ def _scratch(heads, chunks, dk, dv, dtype, backward):
             pltpu.VMEM((pairs, 2, CHUNK, CHUNK), _F32),
             pltpu.VMEM((pairs, _BLOCK, _LANES), _F32)] + [
         pltpu.VMEM(shape, dt) for shape, dt in _parts(
-            heads, chunks, dk, dv, dtype, backward).values()]
+            heads, chunks, dk, dv, dtype, backward, vector).values()]
 
 
 def _cost(b, hv, n, dk, dv, passes, bytes_accessed):
@@ -658,35 +870,39 @@ def gated_delta_rule_fwd(q, k, v, g, beta, tile, eps=1e-6):
     """q, k [b, t, hk, dk], v [b, t, hv, dv] (bf16), g, beta [b, t, hv]
     -> (o [b, t, hv, dv] in v's dtype, states [n, b, hv, dk, dv] in q's:
     the state each of the n = ceil(t / 64) chunks started from).
-    ``tile``: ``gdn_tile``'s answer for the call."""
+    ``tile``: ``gdn_tile``'s answer for the call. g [b, t, hv, dk] (a
+    decay a key feature) with ``kda_tile``'s: the call ``kda.rule.fwd``."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     heads, chunks = tile
-    assert heads * hk == hv and dk == _LANES and dv == _LANES, (q.shape,
-                                                                 v.shape)
+    vector = g.ndim == q.ndim
+    assert (hk == hv and not hv % heads if vector else heads * hk == hv
+            ) and dk == _LANES and dv == _LANES, (q.shape, v.shape, g.shape)
     (q2, k2, v2, g4, b4), n, n_pad = _operands(q, k, v, g, beta, tile)
     qk_spec, v_spec, o_spec, gate_spec, st_spec = _specs(
-        heads, chunks, dk, dv, lambda c: c)
+        heads, chunks, dk, dv, lambda c: c, heads if vector else 1)
+    g_spec = qk_spec if vector else gate_spec
     item = jnp.dtype(q.dtype).itemsize
     o, states = pl.pallas_call(
         functools.partial(_fwd_kernel, heads=heads, chunks=chunks, eps=eps,
-                          scale=dk ** -0.5),
-        name="gdn.rule.fwd",
+                          scale=dk ** -0.5, vector=vector),
+        name="kda.rule.fwd" if vector else "gdn.rule.fwd",
         out_shape=(jax.ShapeDtypeStruct((b, hv, n_pad * CHUNK, dv), v.dtype),
                    jax.ShapeDtypeStruct((n_pad, b, hv, dk, dv), q.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            grid=(b, hk, n_pad // chunks),
-            in_specs=[qk_spec, qk_spec, v_spec, gate_spec, gate_spec],
+            grid=(b, hv // heads, n_pad // chunks),
+            in_specs=[qk_spec, qk_spec, v_spec, g_spec, gate_spec],
             out_specs=(o_spec, st_spec),
-            scratch_shapes=_scratch(heads, chunks, dk, dv, q.dtype, False)),
+            scratch_shapes=_scratch(heads, chunks, dk, dv, q.dtype, False,
+                                    vector)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv)),
+            vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv, vector)),
         cost_estimate=_cost(
             b, hv, n_pad, dk, dv, 1,
             item * (2 * q2.size + 2 * v2.size + n_pad * b * hv * dk * dv)
-            + 8 * g4.size),
+            + 4 * g4.size + 4 * b4.size),
         interpret=_INTERPRET,
     )(q2, k2, v2, g4, b4)
     return jnp.moveaxis(o[:, :, :t], 1, 2), states[:n]
@@ -700,21 +916,20 @@ def gated_delta_rule_fwd(q, k, v, g, beta, tile, eps=1e-6):
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
                 ds_ref, a_ref, t_ref, x_ref, *parts, heads, chunks, eps,
-                scale):
+                scale, vector=False):
     dtype = q_ref.dtype
-    dk, dv = q_ref.shape[-1], v_ref.shape[-1] // heads
-    p = dict(zip(_parts(heads, chunks, dk, dv, dtype, True), parts))
-    ii, jj = _iotas()
-    lower = ii >= jj
-    last_row = jax.lax.broadcasted_iota(
-        jnp.int32, (CHUNK, 1), 0) == CHUNK - 1
+    dv = v_ref.shape[-1] // heads
+    dk = q_ref.shape[-1] // heads if vector else q_ref.shape[-1]
+    p = dict(zip(_parts(heads, chunks, dk, dv, dtype, True, vector), parts))
+    masks = None if vector else _behind_masks()
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    _prepare(q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, heads=heads,
-             chunks=chunks, eps=eps, scale=scale)
+    (_prepare_kda if vector else _prepare)(
+        q_ref, k_ref, v_ref, g_ref, beta_ref, a_ref, p, heads=heads,
+        chunks=chunks, eps=eps, scale=scale)
     _invert(a_ref, t_ref, x_ref)
 
     def chain(c):
@@ -738,10 +953,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
             p["dqg"][m] = dqg_dw[:CHUNK]
             p["dkd"][m] = _dot(vn, dsb, 1, 1)
             p["dd"][m] = jnp.concatenate([dvn, dqg_dw[CHUNK:]], axis=1)
-            p["ddec"][m] = jnp.broadcast_to(jnp.sum(
-                ds * sb.astype(_F32), axis=0, keepdims=True), (8, _LANES))
+            if vector:      # summed over each row behind the loop
+                p["ddec"][m] = ds * sb.astype(_F32)
+            else:
+                p["ddec"][m] = jnp.broadcast_to(jnp.sum(
+                    ds * sb.astype(_F32), axis=0, keepdims=True),
+                    (8, _LANES))
             # dS: (Q e^G)^T dO - W^T dV', one contraction of 128
-            ds_ref[r] = ds * p["dec"][m, :1] + _dot(
+            ds_ref[r] = ds * _decay_of(p, m, vector) + _dot(
                 wq, jnp.concatenate([minus, dob], axis=0), 0, 0)
 
     # a block's chunks as the blocks: in reverse
@@ -756,12 +975,38 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
     for r in range(heads):
         ms = _mats(r, chunks)
         p["da"][ms] = _d_triangle(p["dd"][ms], p["uw"][ms])
+    if vector:
+        _behind_kda(v_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, p,
+                    heads=heads, chunks=chunks, scale=scale)
+    else:
+        _behind(v_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, p, masks,
+                heads=heads, chunks=chunks, scale=scale)
 
-    def rowsum(x):
-        return jnp.sum(x, axis=-1, keepdims=True)
 
-    def half(x):                # [.., C, C] -> [.., C, 128], zeros beside
-        return jnp.concatenate([x, jnp.zeros_like(x)], axis=-1)
+def _rowsum(x):
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def _half(x):                   # [.., C, C] -> [.., C, 128], zeros beside
+    return jnp.concatenate([x, jnp.zeros_like(x)], axis=-1)
+
+
+def _behind_masks():
+    """(ii, jj, i >= j, the chunk's last row): ``_behind``'s masks, made
+    at the head of the kernel."""
+    ii, jj = _iotas()
+    return ii, jj, ii >= jj, jax.lax.broadcasted_iota(
+        jnp.int32, (CHUNK, 1), 0) == CHUNK - 1
+
+
+def _behind(v_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, p, masks, *,
+            heads, chunks, scale):
+    """gdn.rule.bwd's pass behind the state loop: from what the loop and
+    the products through T left in ``p`` to the cotangents of beta, g,
+    v and (summed over the key head's group) q and k."""
+    dtype = dq_ref.dtype
+    dv = v_ref.shape[-1] // heads
+    ii, jj, lower, last_row = masks
 
     # what is left, all the chunks of the grid step at once [chunks, C,
     # .], a value head at a time: no chunk waits for another and nothing
@@ -789,10 +1034,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
         drw_kn, dkd_kn = drw * kn, ekd_w * (dkd * kn)
         to_beta = dru * vf + eg_w * drw_kn
         to_g = eg_w * (beta_w * drw_kn + dqg * qn) - dkd_kn
-        dbeta = rowsum(to_beta + half(f))
-        dgc = (rowsum(to_g + half(e))
+        dbeta = _rowsum(to_beta + _half(f))
+        dgc = (_rowsum(to_g + _half(e))
                - _col(jnp.sum(e, axis=1, keepdims=True), ii, jj))
-        dg_last = rowsum(jnp.sum(dkd_kn, axis=1, keepdims=True)
+        dg_last = _rowsum(jnp.sum(dkd_kn, axis=1, keepdims=True)
                          + p["ddec"][ms, :1] * p["dec"][ms, :1, :1])
         dgc = dgc + jnp.where(last_row, dg_last, 0.0)
         # G is g's running sum: dg_m = sum of dG_i over i >= m
@@ -814,10 +1059,87 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
            + _bdot(dkk, kb, 1, 1))
     # through y = x rsqrt(|x|^2 + eps): dx = r (dy - y (y . dy))
     dyq = dqn * scale
-    dq_ref[...] = (p["rq"][...] * (dyq - yq * rowsum(yq * dyq))).astype(
+    dq_ref[...] = (p["rq"][...] * (dyq - yq * _rowsum(yq * dyq))).astype(
         dq_ref.dtype).reshape(dq_ref.shape)
-    dk_ref[...] = (p["rk"][...] * (dkn - kn * rowsum(kn * dkn))).astype(
+    dk_ref[...] = (p["rk"][...] * (dkn - kn * _rowsum(kn * dkn))).astype(
         dk_ref.dtype).reshape(dk_ref.shape)
+
+
+def _behind_kda(v_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, p, *,
+                heads, chunks, scale):
+    """kda.rule.bwd's pass behind the state loop, a head at a time, all
+    its chunks at once. With M = lower(dP) and N = beta . strictly_lower
+    (dA), the cotangents of ``_prepare_kda``'s two decayed products, a
+    level of the halving gives back, through the factors E, F it was
+    made of (``_levels``, made again from G),
+
+        X = [M; N] (K F)        dq += X_M . E,   dk_row += X_N . E
+        Y = [M; N]^T [Q E; K E] dk_col += Y . F
+
+    two products a level, and the decays' own cotangent needs no third:
+    G_i enters a product only as exp(G_id) beside q_id or k_id and G_j
+    as exp(-G_jd) beside k_jd, so dG = q . dq + k . (dk_row - dk_col)
+    over these terms (the diagonal of P, q_i . k_i, cancels in it). The
+    element-wise operands Q . e^G, beta K . e^G, K . e^{G_C - G} and the
+    state's row factors e^{G_C} give theirs feature by feature, where
+    gdn.rule.bwd sums over a row's lanes; dg is dG's running sum up the
+    chunk."""
+    dtype = dq_ref.dtype
+    dk, dv = dq_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+    ii, jj = _iotas()
+    pos = _positions(chunks)
+    last_row = pos == CHUNK - 1
+    ones = jnp.ones((chunks, 8, dv), _F32)
+    for r in range(heads):
+        ms = _mats(r, chunks)
+        cols, kcols = slice(r * dv, (r + 1) * dv), slice(r * dk, (r + 1) * dk)
+        beta_w, eg, ekd, gc, kn, yq = (p[x][ms] for x in (
+            "beta", "eg", "ekd", "gc", "kn", "yq"))
+        qn = yq * scale
+        beta = beta_w[:, :, :1]
+        dd = p["dd"][ms]
+        dru, drw = dd[:, :, :dv], dd[:, :, dv:]
+        dqg, dkd = p["dqg"][ms], p["dkd"][ms]
+        vf = _by_chunk(v_ref, chunks, cols).astype(_F32)
+        da = jnp.where(ii > jj, p["da"][ms], 0.0)
+        m = jnp.where(ii >= jj, p["dattn"][ms], 0.0)
+        mn = jnp.concatenate([m, beta * da], axis=1)      # [chunks, 2C, C]
+        # the diagonal of P: q_i . k_i
+        on_diag = jnp.sum(jnp.where(ii == jj, m, 0.0), axis=-1,
+                          keepdims=True)
+        dq_p, dk_row, dk_col = on_diag * kn, 0.0, on_diag * qn
+        for e, f, level in _levels(gc, pos, ii, jj):
+            lv = jnp.where(jnp.concatenate([level, level], axis=0), mn,
+                           0.0).astype(dtype)
+            x = _bdot(lv, (kn * f).astype(dtype), 2, 1)
+            y = _bdot(lv, jnp.concatenate(
+                [qn * e, kn * e], axis=1).astype(dtype), 1, 1)
+            dq_p = dq_p + x[:, :CHUNK] * e
+            dk_row = dk_row + x[:, CHUNK:] * e
+            dk_col = dk_col + y * f
+        drw_kn, dkd_kn = drw * kn, ekd * (dkd * kn)
+        dbeta = _rowsum(dru * vf + eg * drw_kn + _half(da * p["kk"][ms]))
+        dgc = (eg * (beta_w * drw_kn + dqg * qn) - dkd_kn
+               + qn * dq_p + kn * (dk_row - dk_col))
+        # G_C: K e^{G_C - G} and the state's row factors e^{G_C}, the
+        # sum over each row of ds . S as a row of lanes (one product)
+        on_rows = _bdot(ones, p["ddec"][ms] * p["dec"][ms], 2, 2, _HIGHEST)
+        dg_last = jnp.sum(dkd_kn, axis=1, keepdims=True) + on_rows[:, :1]
+        dgc = dgc + jnp.where(last_row, dg_last, 0.0)
+        db = jnp.sum(jnp.where(ii == jj, dbeta, 0.0), axis=1, keepdims=True)
+        dbeta_ref[r] = db.reshape(chunks, CHUNK)
+        # G is g's running sum: dg_m = sum of dG_i over i >= m
+        dg_ref[:, kcols] = _running(dgc, pos, back=True).reshape(
+            chunks * CHUNK, dk)
+        dv_ref[:, cols] = (beta_w * dru).astype(dv_ref.dtype).reshape(
+            chunks * CHUNK, dv)
+        # through y = x rsqrt(|x|^2 + eps): dx = r (dy - y (y . dy))
+        dyq = (dqg * eg + dq_p) * scale
+        dkn = dkd * ekd + (beta_w * eg) * drw + dk_row + dk_col
+        dq_ref[:, kcols] = (p["rq"][ms] * (dyq - yq * _rowsum(yq * dyq))
+                            ).astype(dtype).reshape(chunks * CHUNK, dk)
+        dk_ref[:, kcols] = (p["rk"][ms] * (dkn - kn * _rowsum(kn * dkn))
+                            ).astype(dtype).reshape(chunks * CHUNK, dk)
 
 
 def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
@@ -827,17 +1149,19 @@ def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     heads, chunks = tile
+    vector = g.ndim == q.ndim
     (q2, k2, v2, g4, b4), n, n_pad = _operands(q, k, v, g, beta, tile)
     do2 = _padded(do.astype(v.dtype), 1, n_pad * CHUNK).reshape(v2.shape)
     states = _padded(states, 0, n_pad)
     last = n_pad // chunks - 1
     qk_spec, v_spec, _, gate_spec, st_spec = _specs(
-        heads, chunks, dk, dv, lambda c: last - c)
+        heads, chunks, dk, dv, lambda c: last - c, heads if vector else 1)
+    g_spec = qk_spec if vector else gate_spec
     item = jnp.dtype(q.dtype).itemsize
     dq2, dk2, dv2, dg4, db4 = pl.pallas_call(
         functools.partial(_bwd_kernel, heads=heads, chunks=chunks, eps=eps,
-                          scale=dk ** -0.5),
-        name="gdn.rule.bwd",
+                          scale=dk ** -0.5, vector=vector),
+        name="kda.rule.bwd" if vector else "gdn.rule.bwd",
         out_shape=(jax.ShapeDtypeStruct(q2.shape, q.dtype),
                    jax.ShapeDtypeStruct(k2.shape, k.dtype),
                    jax.ShapeDtypeStruct(v2.shape, v.dtype),
@@ -845,18 +1169,19 @@ def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
                    jax.ShapeDtypeStruct(b4.shape, _F32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            grid=(b, hk, last + 1),
-            in_specs=[qk_spec, qk_spec, v_spec, gate_spec, gate_spec,
+            grid=(b, hv // heads, last + 1),
+            in_specs=[qk_spec, qk_spec, v_spec, g_spec, gate_spec,
                       st_spec, v_spec],
-            out_specs=(qk_spec, qk_spec, v_spec, gate_spec, gate_spec),
-            scratch_shapes=_scratch(heads, chunks, dk, dv, q.dtype, True)),
+            out_specs=(qk_spec, qk_spec, v_spec, g_spec, gate_spec),
+            scratch_shapes=_scratch(heads, chunks, dk, dv, q.dtype, True,
+                                    vector)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv)),
+            vmem_limit_bytes=_vmem_limit(heads, chunks, dk, dv, vector)),
         cost_estimate=_cost(
             b, hv, n_pad, dk, dv, 3,
             item * (4 * q2.size + 3 * v2.size + n_pad * b * hv * dk * dv)
-            + 16 * g4.size),
+            + 8 * g4.size + 8 * b4.size),
         interpret=_INTERPRET,
     )(q2, k2, v2, g4, b4, states, do2)
 
@@ -864,4 +1189,6 @@ def gated_delta_rule_bwd(q, k, v, g, beta, states, do, tile, eps=1e-6):
         return jnp.moveaxis(x.reshape(b, hv, -1)[:, :, :t], 1, 2)
 
     return (dq2[:, :t].reshape(q.shape), dk2[:, :t].reshape(k.shape),
-            dv2[:, :t].reshape(v.shape), gate(dg4), gate(db4))
+            dv2[:, :t].reshape(v.shape),
+            dg4[:, :t].reshape(g.shape) if vector else gate(dg4), gate(db4))
+

@@ -718,3 +718,62 @@ def test_attention_pairs_of_the_cells_calls(monkeypatch):
     assert band["computed"] == {256: 6094848, 128: 5079040}[fa._EDGE_SUB]
     assert band["computed_fwd"] == 8126464
     assert all(0.5 < row["live_share"] <= 1.0 for row in pairs.values())
+
+
+KDA_TINY = dict(
+    vocab_size=50, hidden_size=128, num_hidden_layers=3,
+    intermediate_size=128, num_attention_heads=1, kv_lora_rank=32,
+    moe_intermediate_size=128, num_experts=8, held_experts=(0, 4),
+    num_experts_per_token=2,
+    linear_attn_config={"kda_layers": [1, 3], "full_attn_layers": [2],
+                        "head_dim": 128, "num_heads": 2,
+                        "short_conv_kernel_size": 4})
+
+
+def _kda_interpreters(monkeypatch):
+    from paddle_tpu.parallel import causal_conv as cc
+    from paddle_tpu.parallel import gated_delta_rule as gdr
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import pair_sum as ps
+
+    for module in (fa, gm, ps, cc, gdr):
+        monkeypatch.setattr(module, "_INTERPRET", True)
+
+
+def test_kda_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (a head's 128
+    features and the chunk of 64 are the model's): two KDA layers and a
+    latent layer between them lower two delta-rule calls each way as
+    ``kernel feature``, two convolutions each way on the kernels, one
+    attention call each way at ``dk192 dv128`` with the fused backward
+    and no rotary embedding; on the device (here: the CPU) the kernels
+    agree with the float32 recurrence with G below -200 inside a chunk
+    and with mild gates."""
+    _kda_interpreters(monkeypatch)
+    row = chip_smoke.kda_phase(seq=512, t_check=128, **KDA_TINY)
+    shape = "b1 t512 hk2 hv2 dk128 dv128 chunk64"
+    assert row["kda"] == {f"kernel feature {d} {shape}": 2
+                          for d in ("fwd", "bwd")}
+    assert row["conv"] == {f"kernel {d} b1 t512 c768 taps4": 2
+                           for d in ("fwd", "bwd")}
+    assert row["attention"] == {
+        f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}": 1
+        for d, form in (("bwd", " form=fused"), ("fwd", " stats=rows"))}
+    assert row["rotary_embeddings"] == {} and row["kda_kernel_ms"] == {}
+    assert set(row["rel_err"]) == {
+        f"{gates}.{n}" for gates in ("steep", "mild")
+        for n in ("o", "dq", "dk", "dv", "dg", "dbeta")}
+    assert max(row["rel_err"].values()) < 0.012
+
+
+def test_kda_phase_fails_on_a_call_without_the_kernel(telemetry,
+                                                      monkeypatch):
+    # the rule's kernels off (CPU, no interpreter): the chunked XLA form
+    from paddle_tpu.parallel import gated_delta_rule as gdr
+
+    _kda_interpreters(monkeypatch)
+    monkeypatch.setattr(gdr, "_INTERPRET", False)
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="through the kda.rule"):
+        chip_smoke.kda_phase(seq=512, t_check=128, **KDA_TINY)

@@ -19,7 +19,8 @@ from paddle_tpu.models import decoder
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "paddle_tpu", "models")
 BUILDERS = ("olmoe", "qwen3_next", "joyai_flash", "smallthinker",
-            "phi4flash", "nemotron_h", "lfm2_moe", "laguna")
+            "phi4flash", "nemotron_h", "lfm2_moe", "laguna",
+            "kimi_linear")
 # functions of these names that are NOT the frame's: a zero-centred
 # RMSNorm, a LayerNorm and a projection with a bias
 OWN = {("qwen3_next", "_norm"), ("phi4flash", "_norm"),

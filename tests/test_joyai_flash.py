@@ -416,3 +416,42 @@ def test_all_pairs_held_drops_no_token():
                                atol=1e-8)
     want_gx = jax.grad(lambda xs: jnp.sum(layer(xs) ** 2))(jnp.asarray(x))
     np.testing.assert_allclose(gx, want_gx, rtol=1e-4, atol=1e-8)
+
+
+# --- the latent block lives in models/decoder.py (PR 64) ----------------
+
+
+def test_the_moved_latent_block_builds_the_program_it_built():
+    """``_latent_attention`` is ``decoder.latent_attention`` with the
+    family's query low rank and rotation: the block's ops, their scopes
+    and their order are what this file's builder wrote before the move
+    (the cell's compiled step is keyed by the program), and the family's
+    tiny program still digests to what it did at the parent commit."""
+    _, main, startup, _, _ = built(1)
+    block = [(op.attrs.get("op_namescope").strip("/"), op.type)
+             for op in main.global_block().ops
+             if "blk0/attn" in (op.attrs.get("op_namescope") or "")
+             and not op.type.endswith("_grad")]
+    p = "blk0/attn"
+    assert block == [
+        (p, "rms_norm"), (f"{p}/q_lora", "mul"), (f"{p}/q_lora", "rms_norm"),
+        (f"{p}/q_lora", "mul"), (f"{p}/kv_lora", "mul"),
+        (f"{p}/kv_lora", "split"), (f"{p}/kv_lora", "rms_norm"),
+        (f"{p}/kv_lora", "mul"), (f"{p}/rope", "reshape2"),
+        (f"{p}/rope", "transpose2"), (f"{p}/rope", "split"),
+        (f"{p}/rope", "reshape2"), (f"{p}/rope", "transpose2"),
+        (f"{p}/rope", "split"), (f"{p}/rope", "unsqueeze2"),
+        (f"{p}/rope", "rotary_embedding"), (f"{p}/rope", "concat"),
+        (f"{p}/rope", "expand"), (f"{p}/rope", "concat"),
+        (f"{p}/core", "scaled_dot_product_attention"),
+        (f"{p}/out", "transpose2"), (f"{p}/out", "reshape2"),
+        (f"{p}/out", "mul"), (p, "elementwise_add")]
+    rope = next(op for op in main.global_block().ops
+                if op.type == "rotary_embedding")
+    assert rope.attrs["theta"] == TINY["rope_theta"] \
+        and rope.attrs["interleaved"]
+    assert {q.name for q in main.all_parameters()
+            if q.name.startswith("blk0_attn")} == {
+        f"blk0_{s}" for s in MLA}
+    assert M._latent_attention.__code__.co_names[:2] == (
+        "decoder", "latent_attention")
