@@ -21,6 +21,15 @@ interleaved 64-wide part and Qwen3-Next's 64 of 256 have no kernel
 chiprun_out/rope_candidates.json (PERF.md section 6, PR 42). Needs a
 TPU; ``--lower`` compiles every candidate for a described v5e instead
 and prints the bytes XLA's compiled module accesses.
+
+``--norm`` times, in the same process and nothing else, the per-head
+QK-norm's two forms at ``sdar-train-s4096``'s call ([1, 8192, 32 + 4
+heads of 128], two runs of the positions): N3 ``rms_norm``'s lines on q
+and on k as XLA's ops in front of ``rope.fwd`` (backward: ``rope.bwd``,
+then the two norms' vjp), and NF the ONE call that brings the gains
+(PR 66), NF held to N3's results first. A call alone on an idle chip
+reads about 1.7x its time inside a step (PERF.md section 6, PR 49):
+compare the forms, never add their ms into a step.
 """
 
 import argparse
@@ -43,6 +52,8 @@ CALLS = {
     "qwen3next": (1, 8192, 16, 2, 256, 0, 64, False),
     "joyai": (1, 4096, 32, 1, 64, 0, None, True),
 }
+# --norm: (b, t, q heads, k heads, dh, lanes behind k, periods, epsilon)
+NORM_CALL = (1, 8192, 32, 4, 128, 512, 2, 1e-6)
 # (rows, heads of q) of a grid step B is also timed at; "h" all of q's
 # heads, "hk" as many as k has
 BLOCKS = ((64, "h"), (128, "h"), (256, "h"), (512, "h"), (1024, "h"),
@@ -57,6 +68,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--calls", nargs="*", default=list(CALLS))
     ap.add_argument("--lower", action="store_true")
+    ap.add_argument("--norm", action="store_true")
     args = ap.parse_args()
 
     import jax
@@ -94,6 +106,9 @@ def main():
     def worst(a, b):
         a, b = (np.asarray(x, np.float32) for x in (a, b))
         return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-6))
+
+    if args.norm:
+        return norm_forms(args.lower, sharding, ms, worst)
 
     table = []
     for name in args.calls:
@@ -221,6 +236,88 @@ def main():
         os.makedirs(os.path.dirname(OUT), exist_ok=True)
         with open(OUT, "w") as f:
             json.dump(table, f, indent=1)
+    return 0
+
+
+def norm_forms(lower, sharding, ms, worst):
+    """N3 (norm, norm, rope: three ops) against NF (the one call with
+    the gains), forward and backward, at ``NORM_CALL``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops import nn_ops
+    from paddle_tpu.parallel import rope
+
+    b, t, h, hk, dh, rest, periods, eps = NORM_CALL
+    tile = rope.rope_tile(b, t, h, dh, None, False, jnp.bfloat16, hk=hk,
+                          backend="tpu", on_mesh=False, periods=periods,
+                          norm=True)
+    kw = dict(tokens=True, periods=periods)
+
+    def split(qkv):
+        q, k = jnp.split(qkv, [h * dh, (h + hk) * dh], axis=-1)[:2]
+        return q.reshape(b, t, h, dh), k.reshape(b, t, hk, dh)
+
+    def joined(dq, dk):
+        return jnp.concatenate([dq.reshape(b, t, -1), dk.reshape(b, t, -1),
+                                jnp.zeros((b, t, rest), dq.dtype)], -1)
+
+    def norm(x, gain):
+        return nn_ops._rms_norm({"X": [x], "Scale": [gain]},
+                                {"epsilon": eps})["Y"][0]
+
+    def n3(qkv, sq, sk):
+        q, k = split(qkv)
+        return rope.rope_fwd(norm(q, sq), norm(k, sk), THETA, tile, **kw)
+
+    def n3_bwd(qkv, sq, sk, dq, dk):
+        q, k = split(qkv)
+        dqn, dkn = rope.rope_bwd(dq, dk, THETA, tile, **kw)
+        (dq, dsq), (dk, dsk) = (jax.vjp(norm, x, g)[1](d) for x, g, d in
+                                ((q, sq, dqn), (k, sk, dkn)))
+        return joined(dq, dk), dsq, dsk
+
+    def nf(qkv, sq, sk):
+        return rope.rope_fwd(*split(qkv), THETA, tile, gains=(sq, sk),
+                             eps=eps, **kw)
+
+    def nf_bwd(qkv, sq, sk, dq, dk):
+        dq, dk, dsq, dsk = rope.rope_bwd(dq, dk, THETA, tile, gains=(sq, sk),
+                                         eps=eps, x=split(qkv), **kw)
+        return joined(dq, dk), dsq, dsk
+
+    r = np.random.RandomState(7)
+    shapes = [(b, t, (h + hk) * dh + rest), (dh,), (dh,), (b, h, t, dh),
+              (b, hk, t, dh)]
+    dtypes = [jnp.bfloat16, jnp.float32, jnp.float32, jnp.bfloat16,
+              jnp.bfloat16]
+    if lower:
+        ins = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+               for s, d in zip(shapes, dtypes)]
+    else:
+        ins = [jnp.asarray(r.randn(*s) * (0.2 if len(s) == 1 else 1.0)
+                           + (2.0 if len(s) == 1 else 0.0), d)
+               for s, d in zip(shapes, dtypes)]
+    row, want = {"call": list(NORM_CALL), "tile": list(tile)}, {}
+    for form, fwd, bwd in (("N3", n3, n3_bwd), ("NF", nf, nf_bwd)):
+        got = row[form] = {}
+        for which, f, a in (("fwd", fwd, ins[:3]), ("bwd", bwd, ins)):
+            c = jax.jit(f).lower(*a).compile()
+            if lower:
+                got[which] = {"gb_accessed": round(
+                    c.cost_analysis()["bytes accessed"] / 1e9, 3)}
+                continue
+            out = c(*a)
+            want.setdefault(which, out)
+            got[which] = {"ms": ms(c, *a),
+                          "worst_vs_N3": [round(worst(x, w), 5) for x, w
+                                          in zip(out, want[which])]}
+        print("sdar", form, got, flush=True)
+    if not lower:
+        os.makedirs(os.path.dirname(OUT), exist_ok=True)
+        with open(OUT.replace(".json", "_norm.json"), "w") as f:
+            json.dump(row, f, indent=1)
     return 0
 
 

@@ -1074,13 +1074,20 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
 
 
 def rope_dispatch():
-    """{"impl pass layout dh": calls} of the rotary embeddings lowered
-    so far (pt_rope_dispatch_total)."""
+    """{"impl pass layout dh[ norm=head]": calls} of the rotary
+    embeddings lowered so far (pt_rope_dispatch_total; ``norm=head``: a
+    call that brings the heads' gains, the per-head QK-norm in the same
+    pass)."""
     from paddle_tpu import monitor
 
+    def key(labels):
+        said = [labels[k] for k in ("impl", "pass", "layout", "dh")]
+        if "norm" in labels:
+            said.append(f"norm={labels['norm']}")
+        return " ".join(said)
+
     rows = monitor.snapshot().get("pt_rope_dispatch_total", {})
-    return {" ".join(r["labels"][k] for k in ("impl", "pass", "layout", "dh")):
-            int(r["value"]) for r in rows.get("values", [])}
+    return {key(r["labels"]): int(r["value"]) for r in rows.get("values", [])}
 
 
 def rope_phase(seq=4096, heads=(28, 4), dh=128, **overrides):
@@ -1737,8 +1744,10 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
        blocks; none ``dense``), the backward one call (``form=fused``),
        the logsumexp in rows, and five rotary embeddings each way on the
        ``rope.*`` kernels (the positions run twice over the row: one
-       run's tables read twice). ``overrides`` cut the config for the
-       CPU tests.
+       run's tables read twice), every one with the heads' gains
+       (``norm=head``: the per-head QK-norm rides in the kernels' pass,
+       no ``rms_norm`` op is left in a block's attention), none as
+       XLA's ops. ``overrides`` cut the config for the CPU tests.
     2. On the device, at the cell's heads and 2 x ``t_check`` positions:
        the kernels under the mask, forward and the three gradients,
        against the dense composition (``flash_attention.bd_visible``),
@@ -1778,10 +1787,17 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
             f"dense: {attn}")
     _one_backward_call(attn)
     _statistics_in_rows(attn)
-    check(sum(ropes.values()) == 2 * n
-          and all(k.startswith("kernel ") for k in ropes),
-          f"expected {n} rotary embeddings each way on the rope kernels: "
-          f"{ropes}")
+    check(sum(ropes.values()) == 2 * n and all(
+        k.startswith("kernel ") and k.endswith(" norm=head") for k in ropes),
+        f"expected {n} rotary embeddings each way on the rope kernels "
+        f"with the heads' gains (norm=head), none as XLA's ops: {ropes}")
+    normed = [op.type for op in main.global_block().ops
+              if op.type.startswith("rms_norm") and "/attn/" in
+              (op.namescope or "") + "/"]
+    check(len(normed) == 2 * n,
+          f"expected one rms_norm (the pre-norm) and its grad op a block's "
+          f"attention, the QK-norm inside the rotary op: {len(normed)} "
+          f"under the {n} blocks' attn scopes")
 
     # --- on the device ----------------------------------------------------
     (h, hk), t = heads, 2 * t_check

@@ -401,7 +401,7 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
 
 def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
                      interleaved=False, layout="bhtd", scaling=None,
-                     periods=1):
+                     periods=1, norm_param_attrs=None, norm_epsilon=1e-5):
     """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
     may have fewer heads); position p of the sequence is p, or, with
     ``periods`` = n, p mod t / n: the positions 0 .. t / n - 1 run n
@@ -432,11 +432,27 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
     head exactly 128 wide, each with or without a scaling (they read
     tables); a call that turns part of a wider head or pairs neighbours
     runs as XLA's ops (``parallel/rope.rope_tile`` decides,
-    ``pt_rope_dispatch_total{impl, scaling}`` says which)."""
+    ``pt_rope_dispatch_total{impl, scaling}`` says which).
+
+    ``norm_param_attrs`` (q's, k's): each head of q and of k is first
+    RMS-normalised over its dh with a learned gain [dh] (QK-norm per
+    head: ``rms_norm``'s arithmetic with ``norm_epsilon``, its
+    parameters, created here in that order, 1 at the start), in the same
+    op: where the kernels take the call the statistics, the gains and
+    their gradients ride in the rotation's pass, elsewhere the op is
+    rms_norm's lines in front of the rotation's."""
     helper = LayerHelper("rotary_embedding", name=name)
+    inputs = {"Q": q, "K": k}
+    attrs = {"theta": float(theta)}
+    if norm_param_attrs is not None:
+        for slot, x, attr in zip(("QScale", "KScale"), (q, k),
+                                 norm_param_attrs):
+            inputs[slot] = helper.create_parameter(
+                ParamAttr._to_attr(attr), shape=[x.shape[-1]], dtype=x.dtype,
+                default_initializer=ConstantInitializer(1.0))
+        attrs["norm_epsilon"] = float(norm_epsilon)
     q_out = helper.create_variable_for_type_inference(dtype=q.dtype)
     k_out = helper.create_variable_for_type_inference(dtype=k.dtype)
-    attrs = {"theta": float(theta)}
     if rotary_dim is not None and rotary_dim != q.shape[-1]:
         attrs["rotary_dim"] = int(rotary_dim)
     if interleaved:
@@ -461,7 +477,7 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
             yarn_attention_factor=float(
                 scaling.get("attention_factor")
                 or 0.1 * math.log(factor) + 1.0))
-    helper.append_op("rotary_embedding", inputs={"Q": q, "K": k},
+    helper.append_op("rotary_embedding", inputs=inputs,
                      outputs={"QOut": q_out, "KOut": k_out}, attrs=attrs)
     return q_out, k_out
 

@@ -46,8 +46,8 @@ storage. ``held_experts=(first, count)`` builds one chip's share of
 every expert layer (``layers.topk_moe(held=...)``).
 
 Name scopes (README "Names in the device trace"): ``embed``,
-``blk<i>/attn`` with ``qkv``, ``qk_norm``, ``rope``, the sdpa op under
-``bd`` and ``out``; ``blk<i>/moe`` with ``router``, ``dispatch``,
+``blk<i>/attn`` with ``qkv``, ``rope`` (the per-head QK-norm is inside
+the rotary op), the sdpa op under ``bd`` and ``out``; ``blk<i>/moe`` with ``router``, ``dispatch``,
 ``experts`` and ``combine``; ``final_norm``, ``loss_head``.
 """
 
@@ -61,6 +61,7 @@ import numpy as np
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.models import decoder
+from paddle_tpu.param_attr import ParamAttr
 
 # logits a build offers for a comparison with a reference
 # (model["last_logits"]): those of the noised half's last positions, of
@@ -134,18 +135,16 @@ def _attention(a, cfg: SdarConfig, p: str):
         qkv = decoder.linear(a, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
         q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
         v = layers.transpose(by_head(v, hk), [0, 2, 1, 3])
-    with fluid.name_scope("qk_norm"):
-        # over each head's dh
-        q = decoder.rms_norm(by_head(q, h), cfg.rms_norm_eps,
-                             f"{p}_attn_qnorm")
-        k = decoder.rms_norm(by_head(k, hk), cfg.rms_norm_eps,
-                             f"{p}_attn_knorm")
     with fluid.name_scope("rope"):
-        # q and k where the projection left them: the op transposes as
-        # it rotates. Two runs of the positions: the noised copy's, then
-        # the clean copy's
-        q, k = layers.rotary_embedding(q, k, theta=cfg.rope_theta,
-                                       layout="bthd", periods=2)
+        # q and k where the projection left them: the op norms each head
+        # over its dh (QK-norm, gains ``<p>_attn_qnorm.scale`` and
+        # ``_knorm``) and transposes as it rotates, one pass. Two runs
+        # of the positions: the noised copy's, then the clean copy's
+        q, k = layers.rotary_embedding(
+            by_head(q, h), by_head(k, hk), theta=cfg.rope_theta,
+            layout="bthd", periods=2, norm_epsilon=cfg.rms_norm_eps,
+            norm_param_attrs=[ParamAttr(name=f"{p}_attn_{z}norm.scale")
+                              for z in "qk"])
     with fluid.name_scope("bd"):
         # K and V keep their hk heads: the kernels read head q // (h / hk)
         ctx = layers.scaled_dot_product_attention(

@@ -278,7 +278,9 @@ _M_ROPE = _monitor.counter(
     "layout is bthd), pass (fwd/bwd), layout (bthd: q and k come "
     "token-major; bhtd: head-major), dh and scaling (yarn: the call's "
     "tables hold yarn's frequencies and attention factor; none: plain); "
-    "parallel/rope.rope_tile's answer for the call")
+    "parallel/rope.rope_tile's answer for the call. A call that brings "
+    "the heads' gains (QScale, KScale: the per-head RMSNorm of q and k "
+    "in the same pass) carries norm: head")
 
 
 def _rope_attrs(attrs):
@@ -306,10 +308,24 @@ def _rope_scaling(attrs):
     return rope.Yarn(*(float(attrs[f"yarn_{f}"]) for f in rope.Yarn._fields))
 
 
-def _rope_tile(q, k, attrs, direction):
+def _rope_gains(ins):
+    """(q's, k's) gain [dh] of a rotary_embedding call that norms each
+    head in the same pass (``QScale``, ``KScale``: both or neither), or
+    None."""
+    gains = _x(ins, "QScale"), _x(ins, "KScale")
+    if gains == (None, None):
+        return None
+    if None in gains:
+        raise ValueError("rotary_embedding: QScale and KScale come "
+                         "together (the per-head norm of q AND of k)")
+    return gains
+
+
+def _rope_tile(q, k, attrs, direction, norm=False):
     """``parallel/rope.rope_tile``'s answer for a rotary_embedding call
     on Q and K (None: the XLA form), noted in
-    ``pt_rope_dispatch_total``."""
+    ``pt_rope_dispatch_total``. ``norm``: the call brings the heads'
+    gains."""
     from paddle_tpu.parallel import rope
 
     _, rd, il, tokens = _rope_attrs(attrs)
@@ -318,7 +334,8 @@ def _rope_tile(q, k, attrs, direction):
     if q.dtype == k.dtype and q.shape[-1] == k.shape[-1]:
         tile = rope.rope_tile(
             q.shape[0], q.shape[t_axis], q.shape[h_axis], q.shape[-1], rd,
-            il, q.dtype, hk=k.shape[h_axis], periods=_rope_periods(attrs))
+            il, q.dtype, hk=k.shape[h_axis], periods=_rope_periods(attrs),
+            norm=norm)
     # off with telemetry; build-time shape inference is not a lowering
     if _monitor.enabled() and interp.lowering_active():
         _M_ROPE.inc(labels={"impl": "kernel" if tile else "xla",
@@ -326,16 +343,27 @@ def _rope_tile(q, k, attrs, direction):
                             "layout": "bthd" if tokens else "bhtd",
                             "dh": str(q.shape[-1]),
                             "scaling": "yarn" if attrs.get("yarn_factor")
-                            else "none"})
+                            else "none",
+                            **({"norm": "head"} if norm else {})})
     return tile
 
 
 def _rotary_xla(ins, attrs):
     """rotary_embedding as XLA's ops: ``_rotate`` on head-major Q and
-    K, behind a transpose where they come token-major."""
+    K, behind a transpose where they come token-major, and behind the
+    op ``rms_norm``'s own lines over each head where the call brings
+    the heads' gains."""
+    from paddle_tpu.ops import nn_ops
+
     theta, rd, il, tokens = _rope_attrs(attrs)
     scaling = _rope_scaling(attrs)
     q, k = _x(ins, "Q"), _x(ins, "K")
+    gains = _rope_gains(ins)
+    if gains is not None:
+        q, k = (nn_ops._rms_norm(
+            {"X": [z], "Scale": [g]},
+            {"epsilon": attrs["norm_epsilon"]})["Y"][0]
+            for z, g in zip((q, k), gains))
     if tokens:
         q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
     periods = _rope_periods(attrs)
@@ -344,11 +372,13 @@ def _rotary_xla(ins, attrs):
 
 
 # (the generic grad op's rule for the XLA form: the vjp of _rotary_xla)
+_ROPE_DIFF_INPUTS = ("Q", "K", "QScale", "KScale")
 _ROTARY_XLA_GRAD = autodiff.make_grad_compute(OpDef(
-    type="rotary_embedding", compute=_rotary_xla, diff_inputs=("Q", "K")))
+    type="rotary_embedding", compute=_rotary_xla,
+    diff_inputs=_ROPE_DIFF_INPUTS))
 
 
-@register_op("rotary_embedding", diff_inputs=("Q", "K"))
+@register_op("rotary_embedding", diff_inputs=_ROPE_DIFF_INPUTS)
 def _rotary_embedding(ins, attrs):
     """Q, K [b, h, t, dh] (K may have fewer heads) -> the same with
     rotary positions 0..t-1 applied (attr ``theta``, the base;
@@ -366,11 +396,20 @@ def _rotary_embedding(ins, attrs):
     token-major [b, t, h, dh], as a projection leaves them; the results
     are head-major [b, h, t, dh] all the same.
 
+    Optional inputs ``QScale`` and ``KScale`` [dh] (float32 under AMP
+    too, as rms_norm's ``Scale``; both or neither) with the attribute
+    ``norm_epsilon``: the per-head RMSNorm of Q and of K in the same
+    pass. The op is then, per head, rotate(rms_norm(x) * gain) with
+    ``ops/nn_ops._rms_norm``'s arithmetic (float32 mean of squares over
+    dh, rsqrt(ms + eps), the float32 gain, the result in x's dtype) in
+    front of the rotation's.
+
     ONE kernel for Q and K, ``parallel/rope.rope_fwd``, at the tile
     ``rope_tile`` gives the call from its shapes, dtype, backend and
     mesh; where it gives none, ``_rotary_xla``."""
     q, k = _x(ins, "Q"), _x(ins, "K")
-    tile = _rope_tile(q, k, attrs, "fwd")
+    gains = _rope_gains(ins)
+    tile = _rope_tile(q, k, attrs, "fwd", norm=gains is not None)
     if tile is None:
         return _rotary_xla(ins, attrs)
     from paddle_tpu.parallel import rope
@@ -378,7 +417,8 @@ def _rotary_embedding(ins, attrs):
     theta, rd, _, tokens = _rope_attrs(attrs)
     q, k = rope.rope_fwd(q, k, theta, tile, tokens=tokens,
                          scaling=_rope_scaling(attrs), rotary_dim=rd,
-                         periods=_rope_periods(attrs))
+                         periods=_rope_periods(attrs), gains=gains,
+                         eps=attrs.get("norm_epsilon"))
     return {"QOut": [q], "KOut": [k]}
 
 
@@ -389,9 +429,13 @@ def _rotary_embedding_grad(ins, attrs):
     the head-major cotangents, ``parallel/rope.rope_bwd`` where the
     forward took the kernel (a kernel called from a ``custom_vjp`` rule
     would be traced twice and named by jax), else the vjp of
-    ``_rotary_xla``, as the generic grad op took it."""
+    ``_rotary_xla``, as the generic grad op took it. A call with the
+    heads' gains also gives GRAD::QScale and GRAD::KScale, float32 sums;
+    the kernel reads Q and K again (the one activation this stretch
+    keeps) and makes the norm's statistic from them."""
     q, k = _x(ins, "Q"), _x(ins, "K")
-    tile = _rope_tile(q, k, attrs, "bwd")
+    gains = _rope_gains(ins)
+    tile = _rope_tile(q, k, attrs, "bwd", norm=gains is not None)
     if tile is None:
         return _ROTARY_XLA_GRAD(ins, attrs)
     from paddle_tpu.parallel import rope
@@ -403,11 +447,17 @@ def _rotary_embedding_grad(ins, attrs):
             return g.astype(x.dtype)
         return jnp.zeros_like(jnp.swapaxes(x, 1, 2) if tokens else x)
 
-    dq, dk = rope.rope_bwd(cotangent(_x(ins, "GRAD::QOut"), q),
-                           cotangent(_x(ins, "GRAD::KOut"), k), theta, tile,
-                           tokens=tokens, scaling=_rope_scaling(attrs),
-                           rotary_dim=rd, periods=_rope_periods(attrs))
-    return {"GRAD::Q": [dq], "GRAD::K": [dk]}
+    dq, dk, *dgains = rope.rope_bwd(
+        cotangent(_x(ins, "GRAD::QOut"), q),
+        cotangent(_x(ins, "GRAD::KOut"), k), theta, tile, tokens=tokens,
+        scaling=_rope_scaling(attrs), rotary_dim=rd,
+        periods=_rope_periods(attrs), gains=gains,
+        eps=attrs.get("norm_epsilon"), x=(q, k))
+    grads = {"GRAD::Q": [dq], "GRAD::K": [dk]}
+    for slot, d, g in zip(("GRAD::QScale", "GRAD::KScale"), dgains,
+                          gains or ()):
+        grads[slot] = [d.astype(g.dtype)]
+    return grads
 
 
 def _sdpa_config(ins, attrs, rng):

@@ -151,14 +151,18 @@ def _under_mesh() -> bool:
     return interp.spmd_ctx() is not None
 
 
-def _vmem_bytes(rows, hb, hk, dh):
+def _vmem_bytes(rows, hb, hk, dh, norm=False):
     """What a grid step keeps in VMEM: q's and k's blocks in and out as
-    bf16 and the two tables' as float32, all double-buffered."""
-    return 2 * (2 * rows * (hb + hk) * dh * 2 + 2 * rows * dh * 4)
+    bf16 and the two tables' as float32, all double-buffered. ``norm``
+    (a call with the heads' gains): the backward's third operand, the
+    projection's q and k, as well, and a pass's 1 / rms in float32."""
+    return (2 * ((3 if norm else 2) * rows * (hb + hk) * dh * 2
+                 + 2 * rows * dh * 4)
+            + (_PASS_ROWS * max(hb, hk) * dh * 4 if norm else 0))
 
 
 def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
-              backend=None, on_mesh=None, periods=1):
+              backend=None, on_mesh=None, periods=1, norm=False):
     """-> (rows, hb): the positions and the heads of q one grid step of
     ``rope.*`` works on, or None where the call runs as the XLA form: no
     TPU backend (``backend``: None for this process's, with the
@@ -171,7 +175,9 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     over the VMEM cap. ``h`` and ``hk`` (None: as many) are q's and k's
     heads. ``periods``: the positions run that many times over the row
     (index i is position i mod t / periods); a block of rows then lies
-    inside one run.
+    inside one run. ``norm``: the call brings the heads' gains (the
+    per-head RMSNorm in the same pass), and its backward a third block
+    of q and k.
 
     The tile follows the shape, not a flag: all of q's heads (a block of
     the token-major side is then whole rows of q, contiguous in HBM) by
@@ -190,7 +196,7 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
         return None
     for rows in _BLOCK_ROWS:
         if ((t // periods) % rows == 0
-                and _vmem_bytes(rows, h, hk, dh) <= _VMEM_CAP_BYTES):
+                and _vmem_bytes(rows, h, hk, dh, norm) <= _VMEM_CAP_BYTES):
             return rows, h
     return None
 
@@ -227,33 +233,114 @@ def _swap_halves(x, part=None):
     return jnp.concatenate([x[:, half:], x[:, :half]], axis=-1)
 
 
-def _kernel(q_ref, k_ref, cos_ref, sin_ref, qo_ref, ko_ref, *, rows, hb, hk,
-            dh, tokens_in, tokens_out, sign, part=None):
+def _kernel(q_ref, k_ref, cos_ref, sin_ref, *rest, rows, hb, hk, dh,
+            tokens_in, tokens_out, sign, part=None, eps=None, grads=False):
+    """A grid step. ``rest``: the results' blocks (q's, k's). With
+    ``eps`` (the per-head RMSNorm in the same pass) the gains' [1, dh]
+    float32 blocks come in front of them and a float32 scratch [pass
+    rows, heads dh] behind; with ``grads`` (the backward of that call)
+    also the blocks of the projection's q and k in the results' layout
+    behind the gains, and behind the results an (8, dh) float32 tile
+    each for the partial sums of the two gains' gradients."""
+    if eps is None:
+        qo_ref, ko_ref = rest
+        q_more = k_more = ()
+    elif grads:
+        (qg_ref, kg_ref, xq_ref, xk_ref, qo_ref, ko_ref, dqg_ref, dkg_ref,
+         r_ref) = rest
+        q_more, k_more = (qg_ref, xq_ref, dqg_ref), (kg_ref, xk_ref, dkg_ref)
+    else:
+        qg_ref, kg_ref, qo_ref, ko_ref, r_ref = rest
+        q_more, k_more = (qg_ref,), (kg_ref,)
+
     def head(tokens, n, at):
         """Rows ``at`` of head ``n`` of a block, as an index."""
         if tokens:
             return (0, at, pl.ds(n * dh, dh))
         return (0, n, at, slice(None))
 
-    def turn(src, dst, heads):
+    def lane_sum(z):
+        return jnp.sum(z, axis=-1, keepdims=True)
+
+    def inv_rms(x_ref, tokens, heads, at):
+        """1 / sqrt(mean of squares over the head + eps) of every row of
+        a pass and head of ``x_ref``, as ``ops/nn_ops._rms_norm`` has it,
+        into ``r_ref`` [pass rows, heads dh] across each head's lanes (a
+        lane sum leaves a row's statistic on every lane of its vreg). A
+        STAGE of its own in front of a pass's rotations, through VMEM:
+        the XLU gives its results in the order they were asked for, so a
+        head that makes its trip for the sum and its trip for the
+        rotation in one chain waits for every head in front of it twice
+        (1.18 / 1.55 ms a forward / backward call alone at sdar's
+        [1, 8192, 32 + 4, 128]; in stages 0.45 / 0.75: my chip runs,
+        PR 66). Also tried: the heads' statistics gathered one a lane for
+        ONE rsqrt a pass and spread again by a masked lane sum (0.49 /
+        0.85 there: two trips a vreg for one, and the XLU takes a lane
+        sum about every seven cycles a unit); and, by libtpu's bundle
+        count alone, the sum as a matmul with ones (the weights are
+        pushed again for every tile) and the next pass's statistics
+        written between this pass's rotations."""
+        for n in range(heads):
+            x = x_ref[head(tokens, n, at)].astype(_F32)
+            r_ref[:, pl.ds(n * dh, dh)] = jnp.broadcast_to(jax.lax.rsqrt(
+                lane_sum(x * x) * (1.0 / dh) + eps), (_PASS_ROWS, dh))
+
+    def turn(src, dst, heads, gain_ref=None, x_ref=None, dgain_ref=None):
+        if gain_ref is not None:
+            gain = jnp.broadcast_to(gain_ref[...], (_PASS_ROWS, dh))
+
         def a_pass(p, carry):
             at = pl.ds(pl.multiple_of(p * _PASS_ROWS, _PASS_ROWS),
                        _PASS_ROWS)
             cos = cos_ref[at, :]
             sin = sin_ref[at, :] * sign
+            if x_ref is not None:
+                inv_rms(x_ref, tokens_out, heads, at)
+            elif gain_ref is not None:
+                inv_rms(src, tokens_in, heads, at)
             for n in range(heads):
                 x = src[head(tokens_in, n, at)].astype(_F32)
+                if gain_ref is not None and x_ref is None:
+                    # rms_norm(x) * gain, rounded to the values' dtype
+                    # where the op rms_norm in front of this one rounded
+                    # its Y (and, backward, the cotangent of Y where
+                    # this op's grad op rounded it): the call's results
+                    # are the ops' own up to the order of a float32 lane
+                    # sum, so a seed's routing and the cell's readings
+                    # against its reference stay the parent's, for a
+                    # pack and an unpack a vreg.
+                    x = (x * r_ref[:, pl.ds(n * dh, dh)] * gain).astype(
+                        dst.dtype).astype(_F32)
                 y = x * cos + _swap_halves(x, part) * sin
                 dst[head(tokens_out, n, at)] = y.astype(dst.dtype)
+            if x_ref is None:
+                return carry
+            # dst holds the cotangent of rms_norm's Y, rounded; a stage
+            # of its own: a head's trip for the rotation and its trip
+            # for the mean, one after the other for ALL heads
+            for n in range(heads):
+                at_n = head(tokens_out, n, at)
+                r = r_ref[:, pl.ds(n * dh, dh)]
+                xhat = x_ref[at_n].astype(_F32) * r
+                y = dst[at_n].astype(_F32)
+                carry = carry + y * xhat
+                y = y * gain
+                y = r * (y - xhat * (lane_sum(y * xhat) * (1.0 / dh)))
+                dst[at_n] = y.astype(dst.dtype)
             return carry
 
-        jax.lax.fori_loop(0, rows // _PASS_ROWS, a_pass, 0)
+        carry = jax.lax.fori_loop(
+            0, rows // _PASS_ROWS, a_pass,
+            0 if x_ref is None else jnp.zeros((_PASS_ROWS, dh), _F32))
+        if x_ref is not None:   # float32 all the way; XLA adds the tiles
+            dgain_ref[0, 0] = functools.reduce(
+                jnp.add, [carry[i:i + 8] for i in range(0, _PASS_ROWS, 8)])
 
-    turn(q_ref, qo_ref, hb)
+    turn(q_ref, qo_ref, hb, *q_more)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
-        turn(k_ref, ko_ref, hk)
+        turn(k_ref, ko_ref, hk, *k_more)
 
 
 def _specs(rows, hb, hk, dh, tokens):
@@ -269,9 +356,14 @@ def _specs(rows, hb, hk, dh, tokens):
 
 @functools.partial(jax.jit, static_argnames=(
     "theta", "tile", "tokens_in", "tokens_out", "sign", "name", "interpret",
-    "scaling", "rotary_dim", "periods"))
-def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret,
-          scaling=None, rotary_dim=None, periods=1):
+    "scaling", "rotary_dim", "periods", "eps"))
+def _rope(q, k, gains=None, x=None, *, theta, tile, tokens_in, tokens_out,
+          sign, name, interpret, scaling=None, rotary_dim=None, periods=1,
+          eps=None):
+    """``gains`` (q's, k's: [dh] float32) with ``eps``: the per-head
+    RMSNorm in the same pass; ``x`` (the projection's q and k in the
+    results' layout): the backward of that call, which also returns the
+    two gains' gradients."""
     rows, hb = tile
     if tokens_in:
         (b, t, h, dh), hk = q.shape, k.shape[2]
@@ -289,28 +381,58 @@ def _rope(q, k, *, theta, tile, tokens_in, tokens_out, sign, name, interpret,
     table = pl.BlockSpec((rows, dh), (lambda bi, i, j: (i % run, 0))
                          if periods > 1 else (lambda bi, i, j: (i, 0)))
     moved = (h + hk) * b * t * dh
-    qo, ko = pl.pallas_call(
+    operands = [q, k, cos, sin]
+    in_specs = [*_specs(rows, hb, hk, dh, tokens_in), table, table]
+    out_shape = [jax.ShapeDtypeStruct(s, z.dtype)
+                 for s, z in zip(out_shapes, (q, k))]
+    out_specs = list(_specs(rows, hb, hk, dh, tokens_out))
+    flops, reads = 3, 1
+    if gains is not None:
+        # the gains' blocks do not move: fetched once
+        operands += [g.astype(_F32).reshape(1, dh) for g in gains]
+        in_specs += [pl.BlockSpec((1, dh), lambda bi, i, j: (0, 0))] * 2
+        flops = 8
+    if x is not None:
+        operands += [z.reshape(s) for z, s in zip(x, out_shapes)]
+        in_specs += list(_specs(rows, hb, hk, dh, tokens_out))
+        # a tile of partial sums a grid step for q's gain, one a block
+        # of rows for k's
+        out_shape += [jax.ShapeDtypeStruct((b, t // rows, n * 8, dh), _F32)
+                      for n in (h // hb, 1)]
+        out_specs += [pl.BlockSpec((1, 1, 8, dh), lambda bi, i, j: (bi, i, j, 0)),
+                      pl.BlockSpec((1, 1, 8, dh), lambda bi, i, j: (bi, i, 0, 0))]
+        flops, reads = 16, 2
+    scratch = []
+    if gains is not None:   # a pass's 1 / rms, across each head's lanes
+        scratch = [pltpu.VMEM((_PASS_ROWS, max(hb, hk) * dh), _F32)]
+    qo, ko, *dgains = pl.pallas_call(
         functools.partial(_kernel, rows=rows, hb=hb, hk=hk, dh=dh,
                           tokens_in=tokens_in, tokens_out=tokens_out,
-                          sign=sign, part=rotary_dim),
+                          sign=sign, part=rotary_dim, eps=eps,
+                          grads=x is not None),
         name=name,
-        out_shape=[jax.ShapeDtypeStruct(s, x.dtype)
-                   for s, x in zip(out_shapes, (q, k))],
+        out_shape=out_shape,
         grid=(b, t // rows, h // hb),
-        in_specs=[*_specs(rows, hb, hk, dh, tokens_in), table, table],
-        out_specs=list(_specs(rows, hb, hk, dh, tokens_out)),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=max(16 * 2**20,
-                                 _vmem_bytes(rows, hb, hk, dh) * 3 // 2)),
+            vmem_limit_bytes=max(
+                16 * 2**20,
+                _vmem_bytes(rows, hb, hk, dh, x is not None) * 3 // 2)),
         cost_estimate=pl.CostEstimate(
-            flops=3 * moved, transcendentals=0,
-            bytes_accessed=2 * q.dtype.itemsize * moved + 8 * t * dh),
+            flops=flops * moved,
+            transcendentals=0 if gains is None else moved // dh,
+            bytes_accessed=(1 + reads) * q.dtype.itemsize * moved
+            + 8 * t * dh),
         interpret=interpret,
-    )(q, k, cos, sin)
+    )(*operands)
     if tokens_out:
-        return qo.reshape(b, t, h, dh), ko.reshape(b, t, hk, dh)
-    return qo, ko
+        qo, ko = qo.reshape(b, t, h, dh), ko.reshape(b, t, hk, dh)
+    if x is None:
+        return qo, ko
+    return (qo, ko, *(d.sum((0, 1, 2)) for d in dgains))
 
 
 def _part(x, rotary_dim):
@@ -320,8 +442,15 @@ def _part(x, rotary_dim):
     return None if rotary_dim in (None, x.shape[-1]) else int(rotary_dim)
 
 
+def _norm(gains, eps):
+    """(gains, epsilon) as the jitted call takes them: (None, None) for
+    a call without gains, whatever epsilon was said, so that such a call
+    is one function."""
+    return (None, None) if gains is None else (tuple(gains), float(eps))
+
+
 def rope_fwd(q, k, theta, tile, tokens=False, scaling=None,
-             rotary_dim=None, periods=1):
+             rotary_dim=None, periods=1, gains=None, eps=None):
     """(q, k) with rotary positions 0 .. t - 1 applied, head-major
     [b, h, t, dh] (k may have fewer heads), at ``tile`` as ``rope_tile``
     gives it. ``tokens``: q and k come token-major, [b, t, h, dh]. One
@@ -330,22 +459,32 @@ def rope_fwd(q, k, theta, tile, tokens=False, scaling=None,
     ``scaling``: a ``Yarn`` or None, what the tables hold;
     ``rotary_dim``: the leading features that turn (None: the head);
     ``periods``: the positions 0 .. t / periods - 1 run that many times
-    over the row (the tables hold one run)."""
-    return _rope(q, k, theta=float(theta), tile=tuple(tile),
+    over the row (the tables hold one run). ``gains`` (q's, k's, each
+    [dh] float32) and ``eps``: every head of q and of k is first
+    RMS-normalised over its dh and multiplied by its gain
+    (``ops/nn_ops._rms_norm``'s arithmetic in float32, rounded to the
+    values' dtype as that op's result is), in the same pass."""
+    gains, eps = _norm(gains, eps)
+    return _rope(q, k, gains, theta=float(theta), tile=tuple(tile),
                  tokens_in=bool(tokens), tokens_out=False, sign=1.0,
                  name="rope.fwd", interpret=bool(_INTERPRET),
                  scaling=scaling, rotary_dim=_part(q, rotary_dim),
-                 periods=int(periods))
+                 periods=int(periods), eps=eps)
 
 
 def rope_bwd(dq, dk, theta, tile, tokens=False, scaling=None,
-             rotary_dim=None, periods=1):
+             rotary_dim=None, periods=1, gains=None, eps=None, x=None):
     """The cotangents of ``rope_fwd``'s q and k from those of its
     results (head-major): the rotation's transpose, which is the
     rotation by the negated angles, written token-major where the
-    forward read so."""
-    return _rope(dq, dk, theta=float(theta), tile=tuple(tile),
+    forward read so. With ``gains``, ``eps`` and ``x`` (the q and k the
+    forward read, in its layout): (dq, dk, q's gain's gradient, k's
+    gain's gradient) of the call with the per-head norm; the statistic
+    is made again from x, the gains' gradients are float32 sums."""
+    gains, eps = _norm(gains, eps)
+    return _rope(dq, dk, gains, None if gains is None else tuple(x),
+                 theta=float(theta), tile=tuple(tile),
                  tokens_in=False, tokens_out=bool(tokens), sign=-1.0,
                  name="rope.bwd", interpret=bool(_INTERPRET),
                  scaling=scaling, rotary_dim=_part(dq, rotary_dim),
-                 periods=int(periods))
+                 periods=int(periods), eps=eps)

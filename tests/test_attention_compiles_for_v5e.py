@@ -719,15 +719,18 @@ def test_pair_sum_kernel_compiles_at_the_cells_calls(cell, one_chip,
     assert f"[{k},{n},{d}]" not in text and f"f32[{n},{d}]" not in text
 
 
-# (b, t, q heads, k heads, dh[, rotary_dim, yarn scaling]) of the cells
-# whose rotary embedding rope_tile takes, and a head two vregs wide.
+# (b, t, q heads, k heads, dh[, rotary_dim, yarn scaling, periods, with
+# the heads' gains]) of the cells whose rotary embedding rope_tile
+# takes, and a head two vregs wide. sdar's call norms each head in the
+# same pass over a row of two runs of the positions.
 # Laguna's window layers turn the whole head of 64 + 8 heads plainly, its
 # full layers 64 of a head's 128 features of 48 + 8 heads under yarn
 _ROPES = {"smallthinker": (1, 16384, 28, 4, 128),
           "olmoe": (2, 4096, 16, 16, 128), "dh256": (1, 8192, 16, 2, 256),
           "laguna_window": (1, 8192, 64, 8, 128),
           "laguna_full": (1, 8192, 48, 8, 128, 64, rope.Yarn(
-              64.0, 4096.0, 64.0, 1.0, 1.4158883083359672))}
+              64.0, 4096.0, 64.0, 1.0, 1.4158883083359672)),
+          "sdar_gains": (1, 8192, 32, 4, 128, None, None, 2, True)}
 
 
 @pytest.mark.parametrize("tokens", [True, False],
@@ -741,23 +744,35 @@ def test_rope_kernels_compile_at_the_cells_calls(cell, tokens, one_chip,
     lane roll by half a head (whole vregs at dh 256) and k's blocks
     riding in the first head step pass Mosaic, and no float32 copy of q
     is left in the program. A part of a head one vreg wide (PR 57): the
-    two lane rolls and the select of its partner lanes pass too."""
-    b, t, h, hk, dh, part, scaling = (*_ROPES[cell], None, None)[:7]
+    two lane rolls and the select of its partner lanes pass too. With
+    the heads' gains (PR 66): the lane sums, the rsqrt of a [rows, 1]
+    column, the backward's third operand (counted by ``rope_tile``) and
+    the (8, dh) tiles of the gains' partial sums pass as well."""
+    call = _ROPES[cell]
+    b, t, h, hk, dh, part, scaling, periods, norm = (
+        *call, *(None, None, 1, False)[len(call) - 5:])
     tile = rope.rope_tile(b, t, h, dh, part, False, jnp.bfloat16, hk=hk,
-                          backend="tpu", on_mesh=False)
+                          backend="tpu", on_mesh=False, periods=periods,
+                          norm=norm)
     assert tile == (256, h)
 
-    def at(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    def at(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def both(q, k, dq, dk):
-        kw = dict(tokens=tokens, scaling=scaling, rotary_dim=part)
+    def both(q, k, dq, dk, *gains):
+        kw = dict(tokens=tokens, scaling=scaling, rotary_dim=part,
+                  periods=periods)
+        if norm:
+            kw.update(gains=gains, eps=1e-6)
         return (rope.rope_fwd(q, k, 1e6, tile, **kw),
-                rope.rope_bwd(dq, dk, 1e6, tile, **kw))
+                rope.rope_bwd(dq, dk, 1e6, tile, **kw,
+                              **({"x": (q, k)} if norm else {})))
 
     heads = (at(b, h, t, dh), at(b, hk, t, dh))
     ins = (at(b, t, h, dh), at(b, t, hk, dh)) if tokens else heads
-    text = jax.jit(both).lower(*ins, *heads).compile().as_text()
+    text = jax.jit(both).lower(
+        *ins, *heads, *[at(dh, dtype=jnp.float32)] * (2 * norm)
+    ).compile().as_text()
     for name in ("rope.fwd", "rope.bwd"):
         assert name in text, name
     assert f"f32[{b},{h},{t}," not in text and f"f32[{b},{t},{h}" not in text

@@ -672,8 +672,9 @@ def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     layer lowers one block-masked attention call each way over the 2 x
     512 positions in the BHTD kernels (``band=skip``), the backward one
     call, the logsumexp in rows, and one rotary embedding each way on
-    the ``rope.*`` kernels; on the device (here: the CPU) the kernels
-    agree with the dense composition."""
+    the ``rope.*`` kernels with the heads' gains (``norm=head``); on the
+    device (here: the CPU) the kernels agree with the dense
+    composition."""
     _bd_interpreters(monkeypatch)
     row = chip_smoke.bd_phase(seq=512, t_check=512, heads=(8, 1), **BD_TINY)
     shape = "b1 tq1024 tk1024 h8 kv1 dh128 [hb1 bq512 bk512]"
@@ -681,8 +682,8 @@ def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert row["attention"] == {
         f"bhtd fwd {shape} stats=rows {mask}": 2,
         f"bhtd bwd {shape} form=fused {mask}": 2}
-    assert row["rotary_embeddings"] == {"kernel fwd bthd 128": 2,
-                                        "kernel bwd bthd 128": 2}
+    assert row["rotary_embeddings"] == {"kernel fwd bthd 128 norm=head": 2,
+                                        "kernel bwd bthd 128 norm=head": 2}
     assert row["kernel_ms"] == {}               # (a trace needs the chip)
     # one tile a half: the noised half's diagonal block, its clean block
     # and the clean half's diagonal block, each an edge worked on whole

@@ -7,7 +7,11 @@ layouts and several blocks; forward then backward returns the input;
 what ``rope_tile`` takes and refuses; the op under ``layout="bthd"``
 against transpose-then-rotate, with and without the kernel; the rows of
 ``pt_rope_dispatch_total``; and SmallThinker's and OLMoE's tiny Programs
-under AMP at heads of 128, kernel against the XLA form."""
+under AMP at heads of 128, kernel against the XLA form. The op with the
+heads' gains (``QScale``, ``KScale``: the per-head RMSNorm in the same
+pass) against rms_norm, rms_norm and rotary_embedding as three ops, the
+shapes ``rope_tile`` refuses through the XLA form, and a call without
+gains as the same ``pallas_call`` it was."""
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +23,7 @@ from paddle_tpu import flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.models import olmoe, smallthinker
 from paddle_tpu.ops import attention_ops as ao
+from paddle_tpu.ops import nn_ops
 from paddle_tpu.parallel import rope
 
 BF16 = jnp.bfloat16
@@ -160,6 +165,14 @@ TAKEN = dict(b=1, t=16384, h=28, dh=128, rotary_dim=None, interleaved=False,
     (dict(t=40), None),                                # off every block
     (dict(t=0), None),
     (dict(h=1024, hk=1024), None),                     # over the VMEM cap
+    # with the heads' gains: sdar's call, the backward's third operand
+    # counted (which takes a block of rows off a call of 128 heads)
+    (dict(t=8192, h=32, hk=4, periods=2, norm=True), (256, 32)),
+    (dict(h=96, hk=32), (256, 96)),
+    (dict(h=96, hk=32, norm=True), (128, 96)),
+    (dict(dh=64, norm=True), None),
+    (dict(dh=256, rotary_dim=64, norm=True), None),
+    (dict(dtype=jnp.float32, norm=True), None),
 ], ids=lambda v: "_".join(f"{k}{getattr(x, '__name__', x)}"
                           for k, x in v.items()) or "smallthinker"
    if isinstance(v, dict) else None)
@@ -239,6 +252,226 @@ def test_a_cotangent_the_program_does_not_give_is_zeros(interpreter):
     want = jax.vjp(rotate32, g.astype(jnp.float32))[1](
         g.astype(jnp.float32))[0]
     to_bf16_rounding(heads_first(grad["GRAD::Q"][0]), want)
+
+
+# --- the op with the heads' gains -------------------------------------------
+
+EPS = 1e-6
+
+
+def gains(dh, seed):
+    """q's and k's gain [dh] float32, normal(2.0, 0.2) as a run of
+    sdar-train-s4096 lays them."""
+    r = np.random.RandomState(seed)
+    return tuple(jnp.asarray(2.0 + 0.2 * r.randn(dh), jnp.float32)
+                 for _ in "qk")
+
+
+def fused_op(q, k, sq, sk, gq, gk, **attrs):
+    """(QOut, KOut, GRAD::Q, GRAD::K, GRAD::QScale, GRAD::KScale) of the
+    op with QScale and KScale."""
+    attrs = {"theta": THETA, "norm_epsilon": EPS, **attrs}
+    ins = {"Q": [q], "K": [k], "QScale": [sq], "KScale": [sk]}
+    out = ao._rotary_embedding(ins, attrs)
+    grad = ao._rotary_embedding_grad(
+        {**ins, **out, "GRAD::QOut": [gq], "GRAD::KOut": [gk]},
+        {**attrs, "fwd_input_slots": list(ins),
+         "fwd_output_slots": ["QOut", "KOut"]})
+    return (out["QOut"][0], out["KOut"][0],
+            *(grad[f"GRAD::{slot}"][0] for slot in ins))
+
+
+def three_ops(q, k, sq, sk, gq, gk, **attrs):
+    """The same of rms_norm, rms_norm and rotary_embedding without
+    gains, each op's rule and its grad op's in the Program's order."""
+    def norm(x, scale):
+        return nn_ops._rms_norm({"X": [x], "Scale": [scale]},
+                                {"epsilon": EPS})["Y"][0]
+
+    (qn, q_vjp), (kn, k_vjp) = jax.vjp(norm, q, sq), jax.vjp(norm, k, sk)
+    qo, ko, dqn, dkn = op_pair(qn, kn, gq, gk, attrs.pop("layout", "bhtd"),
+                               **attrs)
+    (dq, dsq), (dk, dsk) = q_vjp(dqn), k_vjp(dkn)
+    return qo, ko, dq, dk, dsq, dsk
+
+
+def as_the_three_ops(got, want, unequal=1e-4):
+    """The values to a bf16 rounding (a float32 lane sum in another
+    order moves the statistic's last bit, and with it a value that lay
+    on a tie), all but ``unequal`` of them equal; the gains' gradients,
+    float32 sums over every row and head, to 2e-4 of the largest (a
+    cotangent that rounds the other way is 2^-8 of one term)."""
+    for g, w in zip(got[:4], want[:4]):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        g, w = (np.asarray(z.astype(jnp.float32)) for z in (g, w))
+        np.testing.assert_array_less(np.abs(g - w),
+                                     np.abs(w) * 2.0 ** -7 + 1e-6)
+        assert (g != w).mean() <= unequal
+    for g, w in zip(got[4:], want[4:]):
+        assert g.dtype == jnp.float32 and g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=0,
+                                   atol=2e-4 * float(np.abs(w).max()))
+
+
+@pytest.mark.parametrize("periods", [1, 2])
+def test_the_op_with_gains_is_the_three_ops(periods, interpreter, calls):
+    """[1, 512, 8 + 2, 128] token-major, gains of normal(2.0, 0.2):
+    one call of each kernel, and what rms_norm, rms_norm and the rotary
+    op give as three."""
+    q, k = values(1, 512, 8, 2, 128, seed=8)
+    q = q * 3                  # (a projection's q is not of unit length)
+    gq, gk = (heads_first(z) for z in values(1, 512, 8, 2, 128, seed=9))
+    attrs = {"layout": "bthd", **({"periods": 2} if periods == 2 else {})}
+    got = fused_op(q, k, *gains(128, 10), gq, gk, **attrs)
+    assert calls == [("rope.fwd", True), ("rope.bwd", True)]
+    want = three_ops(q, k, *gains(128, 10), gq, gk, **attrs)
+    assert len(calls) == 4
+    as_the_three_ops(got, want)
+    assert got[2].shape == q.shape and got[3].shape == k.shape
+
+
+def test_the_op_with_gains_head_major_and_a_block_of_heads(interpreter):
+    """Head-major q and k, and a tile that takes q's heads in two blocks
+    (k's in the first: its gain's tile of partial sums is written once a
+    block of rows)."""
+    q, k = (heads_first(z) for z in values(2, 64, 4, 2, 128, seed=11))
+    gq, gk = (heads_first(z) for z in values(2, 64, 4, 2, 128, seed=12))
+    sq, sk = gains(128, 13)
+    want = three_ops(q, k, sq, sk, gq, gk)
+    as_the_three_ops(fused_op(q, k, sq, sk, gq, gk), want, unequal=1e-3)
+    qo, ko = rope.rope_fwd(q, k, THETA, (32, 2), gains=(sq, sk), eps=EPS)
+    got = rope.rope_bwd(gq, gk, THETA, (32, 2), gains=(sq, sk), eps=EPS,
+                        x=(q, k))
+    as_the_three_ops((qo, ko, *got), want, unequal=1e-3)
+
+
+def test_the_op_with_gains_and_a_cotangent_for_q_only(interpreter):
+    q, k = values(1, 64, 4, 2, 128, seed=14)
+    gq = heads_first(values(1, 64, 4, 2, 128, seed=15)[0])
+    sq, sk = gains(128, 16)
+    got = fused_op(q, k, sq, sk, gq, None, layout="bthd")
+    want = three_ops(q, k, sq, sk, gq, None, layout="bthd")
+    as_the_three_ops(got, want, unequal=1e-3)
+    assert not np.asarray(got[3].astype(jnp.float32)).any()
+    assert not np.asarray(got[5]).any() and np.asarray(got[4]).any()
+
+
+@pytest.mark.parametrize("dh,dtype,attrs", [
+    (64, "bfloat16", {}),                          # lfm2moe's heads
+    (256, "bfloat16", {"rotary_dim": 64}),         # qwen3next's part
+    (128, "float32", {}),                          # the reference's path
+], ids=["heads_of_64", "64_of_256", "float32"])
+def test_a_refused_call_with_gains_is_the_three_ops_as_xla_s(
+        dh, dtype, attrs, interpreter, calls):
+    """Where ``rope_tile`` gives no tile the op is rms_norm's lines in
+    front of ``_rotary_xla`` and its grad op that composition's vjp."""
+    q, k = (z.astype(dtype) for z in values(1, 64, 4, 2, dh, seed=17))
+    gq, gk = (heads_first(z).astype(dtype)
+              for z in values(1, 64, 4, 2, dh, seed=18))
+    sq, sk = gains(dh, 19)
+    got = fused_op(q, k, sq, sk, gq, gk, layout="bthd", **attrs)
+    want = three_ops(q, k, sq, sk, gq, gk, layout="bthd", **attrs)
+    assert calls == []
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_allclose(
+            np.asarray(g.astype(jnp.float32)),
+            np.asarray(w.astype(jnp.float32)), rtol=1e-5, atol=1e-6)
+
+
+def test_one_gain_alone_is_refused():
+    q, k = values(1, 64, 4, 2, 128)
+    with pytest.raises(ValueError, match="QScale and KScale"):
+        ao._rotary_embedding({"Q": [q], "K": [k], "QScale": [gains(128, 0)[0]]},
+                             {"theta": THETA, "norm_epsilon": EPS})
+
+
+def pallas_calls(fn, *args):
+    """(operands, results) of every ``pallas_call`` in fn's jaxpr."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((len(eqn.invars), len(eqn.outvars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_a_call_without_gains_is_the_call_it_was(interpreter):
+    """q, k and the two tables in, q and k out: the norm is a static
+    part of the kernel's body that a call without gains does not trace,
+    and its operands ride only in a call that brings them."""
+    q, k = values(1, 64, 4, 2, 128, seed=20)
+    g = tuple(heads_first(z) for z in (q, k))
+    sq, sk = gains(128, 21)
+    tile = (32, 4)
+    assert pallas_calls(lambda a, b: rope.rope_fwd(
+        a, b, THETA, tile, tokens=True), q, k) == [(4, 2)]
+    assert pallas_calls(lambda a, b: rope.rope_bwd(
+        a, b, THETA, tile, tokens=True), *g) == [(4, 2)]
+    assert pallas_calls(lambda a, b: rope.rope_fwd(
+        a, b, THETA, tile, tokens=True, gains=(sq, sk), eps=EPS),
+        q, k) == [(6, 2)]
+    assert pallas_calls(lambda a, b: rope.rope_bwd(
+        a, b, THETA, tile, tokens=True, gains=(sq, sk), eps=EPS, x=(q, k)),
+        *g) == [(8, 4)]
+    # said or not, a call without gains is one jitted function
+    plain = rope.rope_fwd(q, k, THETA, tile, tokens=True)
+    said = rope.rope_fwd(q, k, THETA, tile, tokens=True, gains=None,
+                         eps=EPS)
+    for a, b in zip(plain, said):
+        np.testing.assert_array_equal(a, b)
+
+
+def rotary_program(norm):
+    """A Program under AMP that is a projection to q | k, one rotary op
+    on them [b, 64, 4 + 2, 128] token-major, with or without the heads'
+    gains, and its backward."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[64, 16])
+        qk = layers.fc(x, 6 * 128, num_flatten_dims=2, bias_attr=False,
+                       param_attr=fluid.ParamAttr(name="w"))
+        q, k = layers.split(qk, [4 * 128, 2 * 128], dim=-1)
+        qo, ko = layers.rotary_embedding(
+            layers.reshape(q, [0, 0, 4, 128]),
+            layers.reshape(k, [0, 0, 2, 128]), theta=THETA, layout="bthd",
+            norm_epsilon=EPS,
+            norm_param_attrs=["qnorm.scale", "knorm.scale"] if norm else None)
+        loss = layers.elementwise_add(layers.reduce_sum(qo),
+                                      layers.reduce_sum(ko))
+        grads = append_backward(loss)
+    main._amp = True
+    return main, startup, loss, grads
+
+
+@pytest.mark.parametrize("norm", [False, True], ids=["plain", "gains"])
+def test_the_rows_of_a_call_with_gains_carry_norm_head(norm, interpreter):
+    """One row a lowered call: ``norm="head"`` on the rows of a call
+    that brings the gains, no such label on the others (the parent's
+    rows)."""
+    main, startup, loss, grads = rotary_program(norm)
+    assert [p.name for p, _ in grads] == [
+        "w", *["qnorm.scale", "knorm.scale"] * norm]
+    scope, exe = fluid.Scope(), fluid.Executor()
+    exe.run(startup, scope=scope)
+    x = np.random.RandomState(22).randn(1, 64, 16).astype(np.float32)
+    flags.set_flags({"telemetry": True})
+    try:
+        before = rope_rows()
+        exe.run(main, feed={"x": x}, scope=scope,
+                fetch_list=[loss, *(g for _, g in grads)])
+        rows = rope_rows(before)
+    finally:
+        flags.set_flags({"telemetry": False})
+    want = {"impl": "kernel", "layout": "bthd", "dh": "128",
+            "scaling": "none", **({"norm": "head"} if norm else {})}
+    assert {frozenset(dict(k).items()): n for k, n in rows.items()} == {
+        frozenset({**want, "pass": d}.items()): 1 for d in ("fwd", "bwd")}
 
 
 def test_the_layer_names_the_layout_and_refuses_another():

@@ -5,8 +5,11 @@ changing tokens and comparing hidden states, the BHTD kernels under the
 block mask through the interpreter against the dense rule (forward and
 the ONE backward call, grouped queries, one to three tiles a half), the
 walks' index maps and ``bhtd_pairs`` against a brute-force table, the
-rotary op's ``periods``, the weighted loss head, and the held shares'
-sum."""
+rotary op's ``periods``, the per-head QK-norm inside the rotary op (every
+parameter's gradient against the reference, the startup program's
+parameters in the parent's order, the model at heads of 128 through the
+``rope.*`` kernels against the XLA form), the weighted loss head, and
+the held shares' sum."""
 
 import jax
 import jax.numpy as jnp
@@ -393,6 +396,132 @@ def test_rotary_periods_turn_both_halves_by_the_same_angles(monkeypatch):
     back = rope.rope_bwd(qo, ko, 1e6, tile, tokens=True, periods=2)
     np.testing.assert_allclose(back[0].astype(jnp.float32),
                                q.astype(jnp.float32), atol=0.08)
+
+
+# --- the per-head QK-norm inside the rotary op ---------------------------------
+
+# the parameters the startup program creates for two layers, in its
+# order, as the tree before PR 66 created them (two rms_norm layers
+# between the projection and the rotary op): a run's draws from --seed
+# go by this order, and perf/families/sdar.build_graph finds the gains
+# by their suffix
+PARAMETERS = [
+    "sdar_tok_emb.w",
+    "blk0_attn_norm.scale", "blk0_attn_qkv_colp.w", "blk0_attn_qnorm.scale",
+    "blk0_attn_knorm.scale", "blk0_attn_out_rowp.w", "blk0_moe_norm.scale",
+    "blk0_moe_router.w", "blk0_moe_gate.w", "blk0_moe_up.w",
+    "blk0_moe_down.w",
+    "blk1_attn_norm.scale", "blk1_attn_qkv_colp.w", "blk1_attn_qnorm.scale",
+    "blk1_attn_knorm.scale", "blk1_attn_out_rowp.w", "blk1_moe_norm.scale",
+    "blk1_moe_router.w", "blk1_moe_gate.w", "blk1_moe_up.w",
+    "blk1_moe_down.w",
+    "final_norm.scale", "lm_head_colp.w"]
+
+
+@pytest.mark.parametrize("is_test", [False, True], ids=["train", "eval"])
+def test_the_qk_norm_is_the_rotary_op_s_and_the_parameters_keep_their_place(
+        is_test):
+    """One rotary op a layer with QScale and KScale, no ``qk_norm``
+    scope, the pre-norm the one ``rms_norm`` of a block's attention; the
+    startup program fills the same parameters in the same order with
+    the same ops as the parent's (a constant 1 for every gain)."""
+    cfg = tiny(num_hidden_layers=2)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        M.build(cfg, is_test=is_test)
+    made = [(op.type, op.output_arg_names[0])
+            for op in startup.global_block().ops]
+    assert [name for _, name in made] == PARAMETERS
+    assert all((kind == "fill_constant") == name.endswith(".scale")
+               for kind, name in made)
+    assert [p.name for p in main.all_parameters()] == PARAMETERS
+    for p in main.all_parameters():
+        if p.name.endswith(("qnorm.scale", "knorm.scale")):
+            assert tuple(p.shape) == (cfg.head_dim,) and p.dtype == "float32"
+    ops = main.global_block().ops
+    rotary = [op for op in ops if op.type == "rotary_embedding"]
+    assert [(op.namescope, op.inputs["QScale"], op.inputs["KScale"],
+             op.attrs["norm_epsilon"], op.attrs["periods"],
+             op.attrs["layout"]) for op in rotary] == [
+        (f"blk{i}/attn/rope", [f"blk{i}_attn_qnorm.scale"],
+         [f"blk{i}_attn_knorm.scale"], cfg.rms_norm_eps, 2, "bthd")
+        for i in range(2)]
+    assert not any("qk_norm" in op.namescope for op in ops)
+    assert [op.namescope for op in ops if op.type == "rms_norm"
+            and "/attn" in op.namescope] == ["blk0/attn", "blk1/attn"]
+
+
+def test_every_parameter_s_gradient_agrees_with_the_plain_reference():
+    """The logits and ALL the gradients, the gains' among them, against
+    perf/reference/sdar.py (which norms and rotates as two steps)."""
+    cfg = tiny(num_hidden_layers=2)
+    main, model, scope, exe = built(cfg, train=True)
+    feed = M.make_batch(cfg, 2, L, seed=6)
+    w = {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+    # gains off 1, as a run's start state has them
+    r = np.random.RandomState(0)
+    for name in PARAMETERS:
+        if name.endswith(("qnorm.scale", "knorm.scale")):
+            w[name] = (2.0 + 0.2 * r.randn(*w[name].shape)).astype(np.float32)
+            scope.set(name, w[name])
+    got = exe.run(main, feed=feed, scope=scope, fetch_list=[
+        model["loss"], model["last_logits"]]
+        + [f"{n}@GRAD" for n in PARAMETERS])
+    with jax.default_matmul_precision("highest"):
+        want, grads = jax.value_and_grad(
+            lambda w_: ref.loss(w_, as_file(cfg), feed))(w)
+        logits = ref.forward(w, as_file(cfg), feed["input_ids"])["logits"]
+    assert float(got[0]) == pytest.approx(float(want), rel=2e-5)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(logits),
+                               rtol=2e-3, atol=2e-5)
+    for name, g in zip(PARAMETERS, got[2:]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(grads[name]),
+                                   rtol=2e-3, atol=2e-6, err_msg=name)
+        assert np.abs(np.asarray(g)).max() > 0, name
+
+
+def test_the_model_through_the_rope_kernels_is_the_model_through_xla(
+        monkeypatch):
+    """The tiny model at heads of 128 under AMP, a row of 2 x 64
+    positions: the rotary op with the gains through ``rope.fwd`` /
+    ``rope.bwd`` (the interpreter), against the same Program where
+    ``rope_tile`` gives no tile (rms_norm's lines, ``_rotate`` and their
+    vjp): the loss, the logits and every parameter's gradient."""
+    cfg = tiny(num_hidden_layers=2, head_dim=128, num_attention_heads=4,
+               num_key_value_heads=2)
+
+    def run():
+        main, model, scope, exe = built(cfg, train=True)
+        main._amp = True
+        r = np.random.RandomState(0)
+        for name in PARAMETERS:
+            if name.endswith(("qnorm.scale", "knorm.scale")):
+                scope.set(name, (2.0 + 0.2 * r.randn(128)).astype(np.float32))
+        got = exe.run(main, feed=M.make_batch(cfg, 1, 64, seed=7),
+                      scope=scope, fetch_list=[
+                          model["loss"], model["last_logits"]]
+                      + [f"{n}@GRAD" for n in PARAMETERS])
+        return [np.asarray(g, np.float32) for g in got]
+
+    monkeypatch.setattr(rope, "_INTERPRET", True)
+    made = []
+    for fn in (rope.rope_fwd, rope.rope_bwd):
+        def spy(*a, _fn=fn, **kw):
+            made.append((_fn.__name__, kw.get("gains") is not None))
+            return _fn(*a, **kw)
+        monkeypatch.setattr(rope, fn.__name__, spy)
+    got = run()
+    assert made == [("rope_fwd", True)] * 2 + [("rope_bwd", True)] * 2
+    monkeypatch.setattr(rope, "rope_tile", lambda *a, **kw: None)
+    want = run()
+    assert len(made) == 4
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-3)
+    np.testing.assert_allclose(got[1], want[1], atol=3e-2 * np.abs(
+        want[1]).max())
+    for name, g, w in zip(PARAMETERS, got[2:], want[2:]):
+        scale = float(np.abs(w).max()) or 1.0
+        np.testing.assert_allclose(g / scale, w / scale, atol=3e-2,
+                                   err_msg=name)
 
 
 # --- the held shares ---------------------------------------------------------
