@@ -22,6 +22,10 @@ points a user calls:
    (four delta-rule calls with a decay a key feature each way on the
    ``kda.rule.*`` kernels, no rotary embedding) and runs them against
    the float32 recurrence with G below -200 inside a chunk; the
+   ``xing4`` phase lowers ``xing4-train-s4096`` (a mix, a read and a
+   write-back of four residual streams a sublayer each way, the mixes'
+   Sinkhorn iterations on the ``hc.mix.*`` kernels, yarn tables) and
+   runs the kernels against XLA's ops; the
    ``loss_head`` phase compiles a Program that is only
    ``olmoe-train-s4096``'s head and holds its temporaries under the
    float32 [tokens, vocab] tensor the loss op no longer writes;
@@ -976,6 +980,142 @@ def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
            "rotary_embeddings": rope, "kda_kernel_ms": kernel_ms,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  kda {row['rel_err']}")
+    return row
+
+
+def hc_dispatch():
+    """{"impl op pass": calls}: the hyper-connection calls lowered so
+    far (pt_hc_dispatch_total)."""
+    from paddle_tpu.ops import hc_ops
+
+    return hc_ops.dispatch_counts()
+
+
+HC_REL_TOL = 2e-3   # float32 kernels against float32 XLA ops
+
+
+def xing4_phase(seq=4096, t_check=2048, **overrides):
+    """The hyper-connected decoder's new mechanisms (models/xing4.py).
+
+    1. The cell ``xing4-train-s4096``'s train step (a dense layer and
+       four expert layers at the published widths, 8 of 64 experts held,
+       no MTP module, bf16 AMP, Adam) is LOWERED, not run, and the
+       dispatch counters are held to what the cell must lower: a mix, a
+       read and a write-back a sublayer each way (two sublayers a
+       layer), every mix's Sinkhorn iterations through the ``hc.mix.*``
+       kernels; one attention call a layer each way through the BHTD
+       kernels at queries and keys of 192 over values of 128, the
+       backward one call; every rotary embedding with yarn's table;
+       every router in its sigmoid form with a selection bias over 64
+       experts, 4 a token. ``overrides`` cut the config for the CPU
+       tests.
+    2. On the device: ``hc.mix.fwd`` / ``hc.mix.bwd`` at 20 iterations
+       over ``t_check`` tokens against the same iterations as XLA's ops,
+       H_res and dZ, with logits beyond the clamp among them; H_res's
+       row and column sums; and the kernels' ms a call from a trace."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import xing4 as M
+    from paddle_tpu.ops import hc_ops
+    from paddle_tpu.parallel import hc_mix
+
+    cfg = M.Xing4Config(**{**dict(
+        num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16384,
+        num_nextn_predict_layers=0, held_experts=(0, 8),
+        rope_scaling=M.YARN), **overrides})
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    reads = (hc_dispatch, attention_dispatch, rope_dispatch, router_dispatch)
+    before = [read() for read in reads]
+    lower_train_step(main, model["loss"], seq)
+    hc, attn, rope, routers = (_dispatch_since(b, read)
+                               for b, read in zip(before, reads))
+    say(f"  lowered: hyper-connections {hc}; attention {attn}; rotary "
+        f"embeddings {rope}; routers {routers}")
+    layers_, subs = cfg.num_hidden_layers, 2 * cfg.num_hidden_layers
+    tile = hc_mix.mix_tile(cfg.hc_mult, seq)
+    for direction in ("fwd", "bwd"):
+        for op in ("mix", "pre", "post"):
+            impl = "kernel" if op == "mix" and tile else "xla"
+            check(hc.get(f"{impl} {op} {direction}") == subs
+                  and sum(v for k, v in hc.items() if k.endswith(
+                      f" {op} {direction}")) == subs,
+                  f"expected {subs} hc_{op} calls {direction} as {impl}: "
+                  f"{hc}")
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        dk = cfg.qk_head_dim
+        check(sum(rows.values()) == layers_ and all(
+            k.startswith("bhtd ") and f" dk{dk} dv{cfg.v_head_dim} [" in k
+            for k in rows),
+            f"expected {layers_} bhtd attention calls {direction} at dk{dk} "
+            f"dv{cfg.v_head_dim} with their tile, none dense: {attn}")
+    check(sum(rope.values()) == 2 * layers_ and all(
+        k.split()[3] == f"{cfg.qk_rope_head_dim}" for k in rope),
+        f"expected {layers_} rotary embeddings each way over the "
+        f"{cfg.qk_rope_head_dim} shared features: {rope}")
+    n_moe = layers_ - cfg.first_k_dense_replace
+    check(routers and all(
+        k == f"score=sigmoid bias=1 k={cfg.num_experts_per_tok} "
+        f"experts={cfg.n_routed_experts}" for k in routers)
+        and sum(routers.values()) >= n_moe,
+        f"expected {n_moe} sigmoid routers with a selection bias: {routers}")
+    if tile:
+        _one_backward_call(attn)
+        _statistics_in_rows(attn)
+
+    # --- on the device ----------------------------------------------------
+    n, iters = cfg.hc_mult, cfg.hc_sinkhorn_iters
+    attrs = {"n": n, "epsilon": cfg.rms_norm_eps, "iters": iters,
+             "hc_eps": cfg.hc_eps, "clamp_min": cfg.mhc_h_res_clamp_min,
+             "clamp_max": cfg.mhc_h_res_clamp_max}
+    r = np.random.RandomState(5)
+    z = 2.0 * r.randn(n * n, t_check)
+    z[1, :64], z[n + 1, 64:128] = 40.0, -40.0          # beyond the clamp
+    z = jnp.asarray(z, jnp.float32)
+    d = jnp.asarray(r.randn(n, n, t_check), jnp.float32)
+
+    def both(z_, d_):
+        return hc_ops._res(z_, n, attrs), hc_ops._res_grad(z_, d_, n, attrs)
+
+    row = {"hc": hc, "attention": attn, "rotary_embeddings": rope,
+           "routers": routers, "rel_err": {}, "hc_kernel_ms": {}}
+    got = jax.block_until_ready(jax.jit(both)(z, d))
+    res = np.asarray(got[0])
+    row["rows_off_one"] = float(np.abs(res.sum(1) - 1.0).max())
+    row["columns_off_one"] = float(np.abs(res.sum(0) - 1.0).max())
+    check(np.isfinite(res).all() and row["columns_off_one"] < 1e-4,
+          f"H_res's columns sum to 1 after the last half-step: off by "
+          f"{row['columns_off_one']}")
+    if hc_mix.mix_tile(n, t_check):
+        keep, hc_mix.mix_tile = hc_mix.mix_tile, lambda *a: None
+        try:
+            want = jax.jit(both)(z, d)
+        finally:
+            hc_mix.mix_tile = keep
+        for name, a, b in zip(("h_res", "dz"), got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            row["rel_err"][name] = float(
+                np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+            check(row["rel_err"][name] <= HC_REL_TOL,
+                  f"hc.mix {name}: the kernel is off XLA's ops by "
+                  f"{row['rel_err'][name]:.5f} of their max (tolerance "
+                  f"{HC_REL_TOL})")
+        if jax.default_backend() == "tpu":
+            run = jax.jit(both)
+            row["hc_kernel_ms"], seen = _traced_kernel_ms(
+                "hc_trace", lambda: run(z, d), "hc.")
+            say(f"  hc kernels, ms a call at t{t_check}: "
+                f"{row['hc_kernel_ms']}")
+            check(sorted(row["hc_kernel_ms"]) == ["hc.mix.bwd",
+                                                  "hc.mix.fwd"],
+                  f"expected the forward and the backward hc.mix.* "
+                  f"kernels in the trace: {seen}")
+    say(f"  hc {row['rel_err']}, rows off one {row['rows_off_one']:.2e}")
     return row
 
 
@@ -2362,6 +2502,7 @@ def main() -> int:
     report["sconv"], _ = phase("sconv", sconv_phase)
     report["bd"], _ = phase("bd", bd_phase)
     report["kda"], _ = phase("kda", kda_phase)
+    report["xing4"], _ = phase("xing4", xing4_phase)
     report["rope"], _ = phase("rope", rope_phase)
     report["loss_head"], _ = phase("loss_head", loss_head_phase)
 

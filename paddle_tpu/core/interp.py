@@ -84,6 +84,13 @@ AMP_OP_TYPES = {
     # the label's logit in float32 inside the op, the grad op's
     # GRAD::Loss kept float32 (AMP_KEEP_F32_SLOTS below).
     "linear_cross_entropy",
+    # the read and the write-back of hyper-connected residual streams
+    # (ops/hc_ops.py): the streams, the sublayer's output and their
+    # cotangents to bf16; the mixes HPre, HPost, HRes stay float32
+    # (AMP_KEEP_F32_SLOTS below) and the sums inside are float32. hc_mix
+    # is not listed: it reads the streams as they come and is float32.
+    "hc_pre",
+    "hc_post",
 }
 
 # Precision-following ops: when any input is already bf16, their remaining
@@ -114,7 +121,8 @@ AMP_FLOW_OP_TYPES = {
 # Adam is taken inside it: ops/moe_ops._ADAM_IN).
 AMP_KEEP_F32_SLOTS = frozenset(
     {"Lse", "GRAD::Lse", "G", "Beta", "GRAD::Loss",
-     "Param", "Moment1", "Moment2", "Beta1Pow", "Beta2Pow", "LearningRate"})
+     "Param", "Moment1", "Moment2", "Beta1Pow", "Beta2Pow", "LearningRate",
+     "HPre", "HPost", "HRes"})
 
 # Whether AMP casting is active for the block currently being traced;
 # None while no block is (core/lowering.run_block sets it for the length

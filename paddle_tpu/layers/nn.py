@@ -38,7 +38,7 @@ __all__ = [
     "causal_conv1d", "short_conv_gate",
     "gdn_gates",
     "gated_delta_rule", "gated_rms_norm", "silu", "selective_scan",
-    "mamba2_scan", "diff_attention_combine",
+    "mamba2_scan", "diff_attention_combine", "hc_mix", "hc_pre", "hc_post",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
     "yolo_box", "sequence_conv", "add_position_encoding", "conv3d",
     "spectral_norm", "hsigmoid", "sample_logits",
@@ -795,6 +795,77 @@ def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None,
     helper.append_op(
         "gated_rms_norm", inputs={"X": input, "Z": gate, "Scale": scale},
         outputs={"Y": out}, attrs=attrs)
+    return out
+
+
+def hc_mix(x, n, epsilon=1e-6, iters=20, hc_eps=1e-6, clamp=(-30.0, 30.0),
+           phi_attr=None, bias_attr=None, alpha_attr=None, name=None):
+    """The mix of one sublayer's manifold-constrained hyper-connection
+    (arXiv:2512.24880) from the ``n`` streams ``x`` [b, t, n d] (side by
+    side along the features, stream i at i d .. (i + 1) d):
+    ``(h_pre [b, n, t], h_post [b, n, t], h_res [b, n, n, t])``, float32
+    and token-minor, by ``ops/hc_ops.py``'s equations (one norm statistic
+    and a [n d] -> n^2 + 2n projection a token, two sigmoids, ``iters``
+    Sinkhorn iterations on exp of the clamped n x n part). Parameters,
+    created here in this order: ``Phi`` [n d, n^2 + 2n] (normal(0, 0.02)
+    unless the attribute says), ``Bias`` [n^2 + 2n] (the start at which
+    the layer is a plain pre-norm residual over the streams' mean:
+    logit(1 / n) for H_pre, 0 for H_post = 1, 0 on and -8 off H_res's
+    diagonal) and ``Alpha`` [3] (0.01 each: the gates that open the
+    token's part of pre, post and res)."""
+    import math
+
+    import numpy as np
+
+    from paddle_tpu.initializer import NormalInitializer, NumpyArrayInitializer
+
+    helper = LayerHelper("hc_mix", name=name)
+    n = int(n)
+    k = n * n + 2 * n
+    phi = helper.create_parameter(
+        ParamAttr._to_attr(phi_attr), shape=[int(x.shape[-1]), k],
+        dtype="float32",
+        default_initializer=NormalInitializer(0.0, 0.02))
+    start = np.concatenate([
+        np.full(n, math.log(1.0 / n / (1.0 - 1.0 / n)) if n > 1 else 30.0),
+        np.zeros(n), (-8.0 * (1.0 - np.eye(n))).reshape(-1)])
+    bias = helper.create_parameter(
+        ParamAttr._to_attr(bias_attr), shape=[k], dtype="float32",
+        default_initializer=NumpyArrayInitializer(start.astype("float32")))
+    alpha = helper.create_parameter(
+        ParamAttr._to_attr(alpha_attr), shape=[3], dtype="float32",
+        default_initializer=ConstantInitializer(0.01))
+    outs = [helper.create_variable_for_type_inference(dtype="float32")
+            for _ in range(3)]
+    helper.append_op(
+        "hc_mix", inputs={"X": x, "Phi": phi, "Bias": bias, "Alpha": alpha},
+        outputs={"HPre": outs[0], "HPost": outs[1], "HRes": outs[2]},
+        attrs={"n": n, "epsilon": float(epsilon), "iters": int(iters),
+               "hc_eps": float(hc_eps), "clamp_min": float(clamp[0]),
+               "clamp_max": float(clamp[1])})
+    return tuple(outs)
+
+
+def hc_pre(x, h_pre, name=None):
+    """A sublayer's input from the streams x [b, t, n d] (n: h_pre's
+    second dim): sum_i h_pre[:, i] x_i, [b, t, d] in x's dtype (float32
+    sums, rounded once)."""
+    helper = LayerHelper("hc_pre", name=name)
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op("hc_pre", inputs={"X": x, "HPre": h_pre},
+                     outputs={"Out": out}, attrs={"n": int(h_pre.shape[1])})
+    return out
+
+
+def hc_post(x, y, h_res, h_post, name=None):
+    """The streams behind a sublayer whose output is ``y`` [b, t, d]:
+    stream j is sum_i h_res[:, j, i] x_i + h_post[:, j] y, [b, t, n d]
+    in x's dtype (float32 sums, rounded once)."""
+    helper = LayerHelper("hc_post", name=name)
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op(
+        "hc_post", inputs={"X": x, "Y": y, "HRes": h_res, "HPost": h_post},
+        outputs={"Out": out}, attrs={"n": int(h_post.shape[1])})
     return out
 
 

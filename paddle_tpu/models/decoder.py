@@ -122,7 +122,9 @@ def _heads_first(z):   # [b, t, heads, dh] -> [b, heads, t, dh]
 def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
                      kv_lora_rank: int, hidden: int, eps: float,
                      q_lora_rank: Optional[int] = None,
-                     rope_theta: Optional[float] = None):
+                     rope_theta: Optional[float] = None,
+                     rope_scaling: Optional[dict] = None,
+                     softmax_scale: Optional[float] = None):
     """Multi-head latent attention (DeepSeek-V3's, arXiv:2412.19437
     2.1.1) on x [b, t, d], parameters ``<p>_attn_*``, inside the caller's
     ``attn`` scope:
@@ -133,8 +135,11 @@ def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
         q_pe, k_pe <- RoPE, pairs (2i, 2i + 1), k_pe ONE head of ``rope``
         features that all query heads share (``rope_theta`` None: NOTHING
         is rotated, the features are kept as they come; q then needs no
-        split and no concat)
+        split and no concat; ``rope_scaling``: a yarn scaling of the
+        table, ``layers.rotary_embedding(scaling=)``'s dict)
         o = causal softmax(q [k_nope | k_pe]^T / sqrt(nope + rope)) v;  o Wo
+        (``softmax_scale``: that number where 1 / sqrt(nope + rope) stands,
+        as DeepSeek's yarn puts mscale^2 on it)
 
     Scopes under the caller's: ``q_lora`` (``q`` without a low rank),
     ``kv_lora``, ``rope`` (the splits, the rotation where there is one,
@@ -168,14 +173,17 @@ def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
         k_rope = layers.unsqueeze(k_rope, [1])
         if rope_theta is not None:
             q_rope, k_rope = layers.rotary_embedding(
-                q_rope, k_rope, theta=rope_theta, interleaved=True)
+                q_rope, k_rope, theta=rope_theta, interleaved=True,
+                scaling=rope_scaling)
             q = layers.concat([q_nope, q_rope], axis=3)
         k = layers.concat(
             [k_nope, layers.expand(k_rope, [1, h, 1, 1])], axis=3)
     with fluid.name_scope("core"):
         # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
+        if softmax_scale is None:
+            softmax_scale = 1.0 / math.sqrt(nope + rope)
         ctx = layers.scaled_dot_product_attention(
-            q, k, v, 1.0 / math.sqrt(nope + rope), name=f"{p}_attn_sdpa")
+            q, k, v, softmax_scale, name=f"{p}_attn_sdpa")
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dv])

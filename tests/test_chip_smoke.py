@@ -778,3 +778,56 @@ def test_kda_phase_fails_on_a_call_without_the_kernel(telemetry,
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="through the kda.rule"):
         chip_smoke.kda_phase(seq=512, t_check=128, **KDA_TINY)
+
+
+XING4_TINY = dict(
+    vocab_size=50, hidden_size=128, num_hidden_layers=2,
+    intermediate_size=128, num_attention_heads=1, q_lora_rank=32,
+    kv_lora_rank=32, moe_intermediate_size=128, n_routed_experts=8,
+    held_experts=(0, 4), num_experts_per_tok=2, hc_sinkhorn_iters=3)
+
+
+def _xing4_interpreters(monkeypatch):
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import hc_mix
+    from paddle_tpu.parallel import pair_sum as ps
+
+    for module in (fa, gm, ps, hc_mix):
+        monkeypatch.setattr(module, "_INTERPRET", True)
+
+
+def test_xing4_phase_holds_the_lowered_cell_to_its_dispatch_rows(
+        telemetry, monkeypatch):
+    """The phase at a cut config through the interpreters (four streams,
+    a head of 192 over 128 and the yarn table are the model's): a dense
+    and an expert layer lower four mixes each way on the ``hc.mix.*``
+    kernels with their reads and write-backs as XLA's ops, one attention
+    call a layer each way at ``dk192 dv128`` with the fused backward, a
+    rotary embedding a layer each way, a sigmoid router with a selection
+    bias; on the device (here: the CPU) the kernels agree with XLA's ops
+    with logits beyond the clamp among them."""
+    _xing4_interpreters(monkeypatch)
+    row = chip_smoke.xing4_phase(seq=1024, t_check=1024, **XING4_TINY)
+    assert row["hc"] == {f"{impl} {op} {d}": 4 for op, impl in (
+        ("mix", "kernel"), ("pre", "xla"), ("post", "xla"))
+        for d in ("fwd", "bwd")}
+    assert sum(row["attention"].values()) == 4 and all(
+        " dk192 dv128 [" in k for k in row["attention"])
+    assert sum(row["rotary_embeddings"].values()) == 4
+    assert list(row["routers"]) == ["score=sigmoid bias=1 k=2 experts=8"]
+    assert set(row["rel_err"]) == {"h_res", "dz"}
+    assert max(row["rel_err"].values()) < 1e-4
+    assert row["columns_off_one"] < 1e-5 and row["hc_kernel_ms"] == {}
+
+
+def test_xing4_phase_fails_on_a_mix_without_the_kernel(telemetry,
+                                                       monkeypatch):
+    # the mixes lowered as XLA's ops where mix_tile gives the call a
+    # tile: the counter's rows say so and the phase refuses them
+    from paddle_tpu.ops import hc_ops
+
+    _xing4_interpreters(monkeypatch)
+    monkeypatch.setattr(hc_ops, "_res_tile", lambda z, n, d: (
+        hc_ops._note("mix", d, "xla"), None)[1])
+    with pytest.raises(chip_smoke.SmokeFailure, match="as kernel"):
+        chip_smoke.xing4_phase(seq=1024, t_check=1024, **XING4_TINY)

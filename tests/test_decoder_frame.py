@@ -20,7 +20,7 @@ MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "paddle_tpu", "models")
 BUILDERS = ("olmoe", "qwen3_next", "joyai_flash", "smallthinker",
             "phi4flash", "nemotron_h", "lfm2_moe", "laguna",
-            "kimi_linear")
+            "kimi_linear", "xing4")
 # functions of these names that are NOT the frame's: a zero-centred
 # RMSNorm, a LayerNorm and a projection with a bias
 OWN = {("qwen3_next", "_norm"), ("phi4flash", "_norm"),
