@@ -53,6 +53,7 @@ them at a tiny config on the CPU.
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import sys
@@ -289,8 +290,7 @@ def kernel_phase(cases=KERNEL_CASES, h=8, dh=64):
             ref = np.asarray(flat(want_out)[name], np.float32)
             a = np.asarray(a, np.float32)
             check(np.isfinite(a).all(), f"{family} t{t}: {name} not finite")
-            errs[name] = float(np.abs(a - ref).max()
-                               / max(np.abs(ref).max(), 1e-6))
+            errs[name] = _rel(a, ref)
             check(errs[name] <= KERNEL_REL_TOL,
                   f"{family} b{b} t{t}: {name} off the reference by "
                   f"{errs[name]:.4f} of its max (tolerance "
@@ -403,11 +403,9 @@ def moe_phase(tokens=8192, d=2048, d_ff=1024, experts=64, top_k=8):
     for name in names:
         if name == "rows":
             continue
-        a = jnp.asarray(results["kernels"][name], jnp.float32)
-        b = jnp.asarray(results["ragged_dot"][name], jnp.float32)
+        a, b = results["kernels"][name], results["ragged_dot"][name]
         check(bool(jnp.isfinite(a).all()), f"moe {name} not finite")
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        errs[name] = _rel(a, b)
         check(errs[name] <= KERNEL_REL_TOL,
               f"moe {name}: the kernels are off ragged_dot by "
               f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
@@ -518,11 +516,9 @@ def moe_held_phase(tokens=8192, d=2048, d_ff=512, experts=512, top_k=10,
     for name in names:
         if name == "rows":
             continue
-        a = jnp.asarray(results["windowed"][name], jnp.float32)
-        b = jnp.asarray(results["whole"][name], jnp.float32)
+        a, b = results["windowed"][name], results["whole"][name]
         check(bool(jnp.isfinite(a).all()), f"held moe {name} not finite")
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        errs[name] = _rel(a, b)
         check(errs[name] <= KERNEL_REL_TOL,
               f"held moe {name}: the windowed form is off the whole "
               f"buffer's by {errs[name]:.4f} of its max (tolerance "
@@ -570,6 +566,68 @@ def lower_train_step(main, loss, seq, batch=1, sharding=None, feeds=None):
         aval((2,), "uint32"), aval((), "uint32"))
 
 
+def cell(name, **overrides):
+    """(models module, config) of the cell the phase ``name`` lowers: the
+    published widths with the cell's cut of depth, vocabulary and held
+    experts; ``overrides`` cut the config for the CPU tests."""
+    module, config, cut = {
+        "gdn": ("qwen3_next", "Qwen3NextConfig", dict(
+            num_hidden_layers=4, vocab_size=18992, held_experts=(0, 32))),
+        "kda": ("kimi_linear", "KimiLinearConfig", dict(
+            num_hidden_layers=5, vocab_size=20480, held_experts=(0, 8))),
+        "xing4": ("xing4", "Xing4Config", dict(
+            num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16384,
+            num_nextn_predict_layers=0, held_experts=(0, 8))),
+        "mla": ("joyai_flash", "JoyaiFlashConfig", dict(
+            num_hidden_layers=5, vocab_size=16160, held_experts=(0, 16))),
+        "ssm": ("phi4flash", "Phi4FlashConfig", dict(
+            num_hidden_layers=6, first_layer=14, model_layers=32,
+            vocab_size=25008)),
+        "mamba2": ("nemotron_h", "NemotronHConfig", dict(
+            num_hidden_layers=9, first_layer=34, vocab_size=16384,
+            held_experts=(0, 8))),
+        "sconv": ("lfm2_moe", "Lfm2MoeConfig", dict(
+            num_hidden_layers=5, first_layer=1, vocab_size=8192,
+            held_experts=(0, 8))),
+        "bd": ("sdar", "SdarConfig", dict(
+            num_hidden_layers=5, vocab_size=18992, mask_token_id=18991,
+            held_experts=(0, 16))),
+    }[name]
+    M = importlib.import_module(f"paddle_tpu.models.{module}")
+    if name == "xing4":
+        cut["rope_scaling"] = M.YARN
+    return M, getattr(M, config)(**{**cut, **overrides})
+
+
+def lower_cell(name, seq, overrides, feeds=None, **reads):
+    """The train step of ``cell(name, **overrides)`` (bf16 AMP, Adam)
+    LOWERED, not run (perf/run.py runs it) -> (cfg, main, {row: the rows
+    ``reads[row]()``'s dispatch counter gained}): what the phase's
+    ``*_rows_hold`` holds to what the cell must lower."""
+    import paddle_tpu as fluid
+
+    M, cfg = cell(name, **overrides)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        model = M.build(cfg)
+        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
+    main._amp = True
+    before = {row: read() for row, read in reads.items()}
+    lower_train_step(main, model["loss"], seq, feeds=feeds)
+    rows = {row: _dispatch_since(before[row], read)
+            for row, read in reads.items()}
+    say("  lowered: " + "; ".join(f"{k} {v}" for k, v in rows.items()))
+    return cfg, main, rows
+
+
+def _rel(a, b, floor=1e-6):
+    """max |a - b| over max |b| (at least ``floor``), in float32."""
+    import jax.numpy as jnp
+
+    a, b = (jnp.asarray(x, jnp.float32) for x in (a, b))
+    return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(), floor))
+
+
 def _traced_kernel_ms(name, run, prefix, calls=3):
     """{kernel: ms a call} of the Mosaic kernels whose name starts with
     ``prefix``, from a short trace of ``calls`` calls of ``run()``
@@ -600,7 +658,6 @@ def _bhtd_against_dense(q, k, v, g, errs, what):
     backward kernels' ms a call by name from a short trace:
     ``attn.bhtd.bwd`` beside the pair it replaces."""
     import jax
-    import jax.numpy as jnp
 
     from paddle_tpu.parallel import flash_attention as fa
 
@@ -620,9 +677,7 @@ def _bhtd_against_dense(q, k, v, g, errs, what):
     for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
                           kernels(q, k, v, g), dense(q, k, v, g)):
         check(a.shape == b.shape, f"{what} {name}: {a.shape} != {b.shape}")
-        a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        errs[name] = _rel(a, b)
         check(errs[name] <= KERNEL_REL_TOL,
               f"{what} {name} off the dense composition by "
               f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
@@ -672,53 +727,12 @@ def conv_dispatch():
     return la.conv_dispatch_counts()
 
 
-def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
-              conv_c=1024, **overrides):
-    """The hybrid decoder's new mechanisms (models/qwen3_next.py).
-
-    1. The cell ``qwen3next-train-s8192``'s train step (one period of
-       Qwen3-Next-80B-A3B at its published widths, 32 of 512 experts
-       held, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
-       and the dispatch counters are held to what the cell must lower:
-       three delta-rule calls forward and three backward, all ``kernel``
-       at the configuration's chunk, and as many causal convolutions in
-       front of them, all ``kernel`` too; one attention call each way at 2
-       key/value heads of 256 with its tile; every grouped matmul of
-       the held experts on a tile chosen for 160 rows an expert (tm128),
-       none through ``ragged_dot``. ``overrides`` cut the config for the CPU tests.
-    2. On the device: the chunkwise delta rule with bf16 operands (the
-       ``gdn.rule.*`` kernels, whose time by name a short trace gives),
-       forward and its own backward, against the step-by-step
-       recurrence in float32; the causal convolution's ``gdn.conv.*``
-       kernels at ``conv_c`` channels against the XLA form they replace
-       (Y, dX, dW); and grouped-query attention through the BHTD kernels
-       against the dense composition that copies K and V."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.models import qwen3_next as M
-    from paddle_tpu.ops import linear_attention_ops as la
-    from paddle_tpu.parallel import causal_conv
-    from paddle_tpu.parallel import flash_attention as fa
-
-    cfg = M.Qwen3NextConfig(**{**dict(
-        num_hidden_layers=4, vocab_size=18992, held_experts=(0, 32)),
-        **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (attention_dispatch, gmm_dispatch, gdn_dispatch, conv_dispatch)
-    before = tuple(read() for read in reads)
-    lower_train_step(main, model["loss"], seq)
-    attn, gmm, gdn, conv = (_dispatch_since(b, read)
-                            for b, read in zip(before, reads))
+def gdn_rows_hold(cfg, seq, lowered):
+    """What ``qwen3next-train-s8192``'s step must lower (gdn_phase, 1.)."""
+    attn, gmm, gdn, conv = (lowered[k] for k in (
+        "attention", "grouped_matmuls", "gdn", "conv"))
     n_gdn = sum(not cfg.is_full_attention(i)
                 for i in range(cfg.num_hidden_layers))
-    say(f"  lowered: gdn {gdn}; conv {conv}; attention {attn}; grouped "
-        f"matmuls {gmm}")
     for direction in ("fwd", "bwd"):
         rows = {k: v for k, v in gdn.items() if f" {direction} " in k}
         check(sum(rows.values()) == n_gdn and all(
@@ -744,6 +758,40 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
         "[tm128 " in k for k in gmm),
         f"expected {9 * n_layers} grouped matmuls on a tile of 128 rows "
         f"(160 rows an expert), none through ragged_dot: {gmm}")
+
+
+def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
+              conv_c=1024, **overrides):
+    """The hybrid decoder's new mechanisms (models/qwen3_next.py).
+
+    1. The cell ``qwen3next-train-s8192``'s train step (one period of
+       Qwen3-Next-80B-A3B at its published widths, 32 of 512 experts
+       held, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
+       and the dispatch counters are held to what the cell must lower:
+       three delta-rule calls forward and three backward, all ``kernel``
+       at the configuration's chunk, and as many causal convolutions in
+       front of them, all ``kernel`` too; one attention call each way at 2
+       key/value heads of 256 with its tile; every grouped matmul of
+       the held experts on a tile chosen for 160 rows an expert (tm128),
+       none through ``ragged_dot``. ``overrides`` cut the config for the CPU tests.
+    2. On the device: the chunkwise delta rule with bf16 operands (the
+       ``gdn.rule.*`` kernels, whose time by name a short trace gives),
+       forward and its own backward, against the step-by-step
+       recurrence in float32; the causal convolution's ``gdn.conv.*``
+       kernels at ``conv_c`` channels against the XLA form they replace
+       (Y, dX, dW); and grouped-query attention through the BHTD kernels
+       against the dense composition that copies K and V."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attention_ops as la
+    from paddle_tpu.parallel import causal_conv
+    from paddle_tpu.parallel import flash_attention as fa
+
+    cfg, _, rows = lower_cell(
+        "gdn", seq, overrides, attention=attention_dispatch,
+        grouped_matmuls=gmm_dispatch, gdn=gdn_dispatch, conv=conv_dispatch)
+    gdn_rows_hold(cfg, seq, rows)
 
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(3)
@@ -806,20 +854,16 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
               f"expected the forward and the backward gdn.rule.* and "
               f"gdn.conv.* kernels in the trace: {seen}")
     for name, a, b in zip(names, got, recurrent(q, k, v, g, beta, do)):
-        a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
         check(bool(jnp.isfinite(a).all()), f"delta rule {name} not finite")
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        errs[name] = _rel(a, b)
         check(errs[name] <= GDN_REL_TOL,
               f"delta rule {name}: the chunkwise form (bf16 operands) is "
               f"off the float32 recurrence by {errs[name]:.4f} of its max "
               f"(tolerance {GDN_REL_TOL})")
     for name, a, b in zip(("conv_y", "conv_dx", "conv_dw"), got_conv,
                           conv_xla(xc, wc, dyc)):
-        a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
         check(bool(jnp.isfinite(a).all()), f"{name} not finite")
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        errs[name] = _rel(a, b)
         check(errs[name] <= KERNEL_REL_TOL,
               f"{name}: the gdn.conv.* kernels are off the XLA form by "
               f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
@@ -831,8 +875,7 @@ def gdn_phase(seq=8192, t_check=1024, heads=(2, 4), width=128, gqa=(8, 2, 256),
     va, ga = (jnp.asarray(r.randn(1, n, t_check, dh), bf) for n in (hkv, h))
     attn_ms = _bhtd_against_dense(qa, ka, va, ga, errs,
                                   "grouped-query attention")
-    row = {"gdn": gdn, "conv": conv, "attention": attn,
-           "grouped_matmuls": gmm, "gdn_kernel_ms": kernel_ms,
+    row = {**rows, "gdn_kernel_ms": kernel_ms,
            "attn_bwd_kernel_ms": attn_ms, "gqa_tile": fa.tile_label(tile),
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  gdn {row['rel_err']}")
@@ -851,47 +894,10 @@ def kda_dispatch():
         f"chunk{lb['chunk']}"))
 
 
-def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
-    """The Kimi Linear decoder's new mechanisms (models/kimi_linear.py).
-
-    1. The cell ``kimilinear-train-s4096``'s train step (published
-       layers 1-5 at their published widths, 8 of 256 experts held,
-       bf16 AMP, Adam) is LOWERED, not run, and the dispatch counters
-       are held to what the cell must lower: every delta-rule call with
-       a decay a key feature through the ``kda.rule.*`` kernels
-       (``impl=kernel gate=feature``), none chunked, none recurrent; one
-       causal convolution a KDA layer each way on the ``gdn.conv.*``
-       kernels; the latent layer's one attention call each way through
-       the BHTD kernels at queries and keys of 192 over values of 128,
-       the backward one call; NO rotary embedding lowered at all.
-       ``overrides`` cut the config for the CPU tests.
-    2. On the device: ``kda.rule.fwd`` / ``kda.rule.bwd`` at ``heads``
-       heads of 128 x ``t_check`` positions against the float32
-       recurrence, Out and all five gradients, with gates that take G
-       below -200 inside a chunk and with mild ones (the state crosses
-       the chunks), and their ms a call from a trace."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.models import kimi_linear as M
-    from paddle_tpu.ops import linear_attention_ops as la
-
-    cfg = M.KimiLinearConfig(**{**dict(
-        num_hidden_layers=5, vocab_size=20480, held_experts=(0, 8)),
-        **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (kda_dispatch, conv_dispatch, attention_dispatch, rope_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq)
-    kda, conv, attn, rope = (_dispatch_since(b, read)
-                             for b, read in zip(before, reads))
-    say(f"  lowered: kda {kda}; conv {conv}; attention {attn}; rotary "
-        f"embeddings {rope}")
+def kda_rows_hold(cfg, seq, lowered):
+    """What ``kimilinear-train-s4096``'s step must lower (kda_phase, 1.)."""
+    kda, conv, attn, rope = (lowered[k] for k in (
+        "kda", "conv", "attention", "rotary_embeddings"))
     n_kda = sum(cfg.is_kda(i) for i in range(cfg.num_hidden_layers))
     n_mla = cfg.num_hidden_layers - n_kda
     for direction in ("fwd", "bwd"):
@@ -917,6 +923,36 @@ def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
     _one_backward_call(attn)
     _statistics_in_rows(attn)
     check(not rope, f"the model rotates nothing (mla_use_nope): {rope}")
+
+
+def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
+    """The Kimi Linear decoder's new mechanisms (models/kimi_linear.py).
+
+    1. The cell ``kimilinear-train-s4096``'s train step (published
+       layers 1-5 at their published widths, 8 of 256 experts held,
+       bf16 AMP, Adam) is LOWERED, not run, and the dispatch counters
+       are held to what the cell must lower: every delta-rule call with
+       a decay a key feature through the ``kda.rule.*`` kernels
+       (``impl=kernel gate=feature``), none chunked, none recurrent; one
+       causal convolution a KDA layer each way on the ``gdn.conv.*``
+       kernels; the latent layer's one attention call each way through
+       the BHTD kernels at queries and keys of 192 over values of 128,
+       the backward one call; NO rotary embedding lowered at all.
+       ``overrides`` cut the config for the CPU tests.
+    2. On the device: ``kda.rule.fwd`` / ``kda.rule.bwd`` at ``heads``
+       heads of 128 x ``t_check`` positions against the float32
+       recurrence, Out and all five gradients, with gates that take G
+       below -200 inside a chunk and with mild ones (the state crosses
+       the chunks), and their ms a call from a trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attention_ops as la
+
+    cfg, _, rows = lower_cell(
+        "kda", seq, overrides, kda=kda_dispatch, conv=conv_dispatch,
+        attention=attention_dispatch, rotary_embeddings=rope_dispatch)
+    kda_rows_hold(cfg, seq, rows)
 
     # --- on the device ----------------------------------------------------
     f32, bf = jnp.float32, jnp.bfloat16
@@ -959,12 +995,10 @@ def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
         got = jax.block_until_ready(kernels(*args))
         for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got,
                               recurrent(*args)):
-            a, b = jnp.asarray(a, f32), jnp.asarray(b, f32)
             check(bool(jnp.isfinite(a).all()),
                   f"kda rule {name} not finite with {gates} gates")
             key = f"{gates}.{name}"
-            errs[key] = float(jnp.abs(a - b).max()
-                              / jnp.maximum(jnp.abs(b).max(), 1e-30))
+            errs[key] = _rel(a, b, 1e-30)
             check(errs[key] <= GDN_REL_TOL,
                   f"kda rule {name}, {gates} gates: the kernels (bf16 "
                   f"operands) are off the float32 recurrence by "
@@ -976,8 +1010,7 @@ def kda_phase(seq=4096, t_check=512, heads=2, **overrides):
         check(sorted(kernel_ms) == ["kda.rule.bwd", "kda.rule.fwd"],
               f"expected the forward and the backward kda.rule.* kernels "
               f"in the trace: {seen}")
-    row = {"kda": kda, "conv": conv, "attention": attn,
-           "rotary_embeddings": rope, "kda_kernel_ms": kernel_ms,
+    row = {**rows, "kda_kernel_ms": kernel_ms,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  kda {row['rel_err']}")
     return row
@@ -994,49 +1027,12 @@ def hc_dispatch():
 HC_REL_TOL = 2e-3   # float32 kernels against float32 XLA ops
 
 
-def xing4_phase(seq=4096, t_check=2048, **overrides):
-    """The hyper-connected decoder's new mechanisms (models/xing4.py).
-
-    1. The cell ``xing4-train-s4096``'s train step (a dense layer and
-       four expert layers at the published widths, 8 of 64 experts held,
-       no MTP module, bf16 AMP, Adam) is LOWERED, not run, and the
-       dispatch counters are held to what the cell must lower: a mix, a
-       read and a write-back a sublayer each way (two sublayers a
-       layer), every mix's Sinkhorn iterations through the ``hc.mix.*``
-       kernels; one attention call a layer each way through the BHTD
-       kernels at queries and keys of 192 over values of 128, the
-       backward one call; every rotary embedding with yarn's table;
-       every router in its sigmoid form with a selection bias over 64
-       experts, 4 a token. ``overrides`` cut the config for the CPU
-       tests.
-    2. On the device: ``hc.mix.fwd`` / ``hc.mix.bwd`` at 20 iterations
-       over ``t_check`` tokens against the same iterations as XLA's ops,
-       H_res and dZ, with logits beyond the clamp among them; H_res's
-       row and column sums; and the kernels' ms a call from a trace."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.models import xing4 as M
-    from paddle_tpu.ops import hc_ops
+def xing4_rows_hold(cfg, seq, lowered):
+    """What ``xing4-train-s4096``'s step must lower (xing4_phase, 1.)."""
     from paddle_tpu.parallel import hc_mix
 
-    cfg = M.Xing4Config(**{**dict(
-        num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16384,
-        num_nextn_predict_layers=0, held_experts=(0, 8),
-        rope_scaling=M.YARN), **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (hc_dispatch, attention_dispatch, rope_dispatch, router_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq)
-    hc, attn, rope, routers = (_dispatch_since(b, read)
-                               for b, read in zip(before, reads))
-    say(f"  lowered: hyper-connections {hc}; attention {attn}; rotary "
-        f"embeddings {rope}; routers {routers}")
+    hc, attn, rope, routers = (lowered[k] for k in (
+        "hc", "attention", "rotary_embeddings", "routers"))
     layers_, subs = cfg.num_hidden_layers, 2 * cfg.num_hidden_layers
     tile = hc_mix.mix_tile(cfg.hc_mult, seq)
     for direction in ("fwd", "bwd"):
@@ -1068,6 +1064,37 @@ def xing4_phase(seq=4096, t_check=2048, **overrides):
         _one_backward_call(attn)
         _statistics_in_rows(attn)
 
+
+def xing4_phase(seq=4096, t_check=2048, **overrides):
+    """The hyper-connected decoder's new mechanisms (models/xing4.py).
+
+    1. The cell ``xing4-train-s4096``'s train step (a dense layer and
+       four expert layers at the published widths, 8 of 64 experts held,
+       no MTP module, bf16 AMP, Adam) is LOWERED, not run, and the
+       dispatch counters are held to what the cell must lower: a mix, a
+       read and a write-back a sublayer each way (two sublayers a
+       layer), every mix's Sinkhorn iterations through the ``hc.mix.*``
+       kernels; one attention call a layer each way through the BHTD
+       kernels at queries and keys of 192 over values of 128, the
+       backward one call; every rotary embedding with yarn's table;
+       every router in its sigmoid form with a selection bias over 64
+       experts, 4 a token. ``overrides`` cut the config for the CPU
+       tests.
+    2. On the device: ``hc.mix.fwd`` / ``hc.mix.bwd`` at 20 iterations
+       over ``t_check`` tokens against the same iterations as XLA's ops,
+       H_res and dZ, with logits beyond the clamp among them; H_res's
+       row and column sums; and the kernels' ms a call from a trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import hc_ops
+    from paddle_tpu.parallel import hc_mix
+
+    cfg, _, rows = lower_cell(
+        "xing4", seq, overrides, hc=hc_dispatch, attention=attention_dispatch,
+        rotary_embeddings=rope_dispatch, routers=router_dispatch)
+    xing4_rows_hold(cfg, seq, rows)
+
     # --- on the device ----------------------------------------------------
     n, iters = cfg.hc_mult, cfg.hc_sinkhorn_iters
     attrs = {"n": n, "epsilon": cfg.rms_norm_eps, "iters": iters,
@@ -1082,8 +1109,7 @@ def xing4_phase(seq=4096, t_check=2048, **overrides):
     def both(z_, d_):
         return hc_ops._res(z_, n, attrs), hc_ops._res_grad(z_, d_, n, attrs)
 
-    row = {"hc": hc, "attention": attn, "rotary_embeddings": rope,
-           "routers": routers, "rel_err": {}, "hc_kernel_ms": {}}
+    row = {**rows, "rel_err": {}, "hc_kernel_ms": {}}
     got = jax.block_until_ready(jax.jit(both)(z, d))
     res = np.asarray(got[0])
     row["rows_off_one"] = float(np.abs(res.sum(1) - 1.0).max())
@@ -1098,9 +1124,7 @@ def xing4_phase(seq=4096, t_check=2048, **overrides):
         finally:
             hc_mix.mix_tile = keep
         for name, a, b in zip(("h_res", "dz"), got, want):
-            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-            row["rel_err"][name] = float(
-                np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+            row["rel_err"][name] = _rel(a, b, 1e-30)
             check(row["rel_err"][name] <= HC_REL_TOL,
                   f"hc.mix {name}: the kernel is off XLA's ops by "
                   f"{row['rel_err'][name]:.5f} of their max (tolerance "
@@ -1133,47 +1157,10 @@ def router_dispatch():
     return out
 
 
-def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
-    """The latent-attention decoder's new mechanisms
-    (models/joyai_flash.py).
-
-    1. The cell ``joyai-train-s4096``'s train step (the dense layer,
-       four expert layers and the MTP module of JoyAI-LLM-Flash at its
-       published widths, 16 of 256 experts held, bf16 AMP, Adam) is
-       LOWERED, not run (perf/run.py runs it), and the dispatch
-       counters are held to what the cell must lower: one attention
-       call a block each way through the BHTD kernels at queries and
-       keys of 192 over values of 128 with its tile, none dense; every
-       router in its sigmoid form with a selection bias; every grouped
-       matmul of the held experts on a tile chosen for 128 rows an
-       expert (tm128), none through ``ragged_dot``. ``overrides`` cut
-       the config for the CPU tests.
-    2. On the device: the BHTD kernels at the model's two widths (8
-       heads: one a step at blocks of 512, the cell's tile, so the
-       backward is the fused call), forward and the three gradients,
-       against the dense composition."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.models import joyai_flash as M
-    from paddle_tpu.parallel import flash_attention as fa
-
-    cfg = M.JoyaiFlashConfig(**{**dict(
-        num_hidden_layers=5, vocab_size=16160, held_experts=(0, 16)),
-        **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (attention_dispatch, gmm_dispatch, router_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq)
-    attn, gmm, routers = (_dispatch_since(b, read)
-                          for b, read in zip(before, reads))
-    say(f"  lowered: attention {attn}; routers {routers}; grouped "
-        f"matmuls {gmm}")
+def mla_rows_hold(cfg, seq, lowered):
+    """What ``joyai-train-s4096``'s step must lower (mla_phase, 1.)."""
+    attn, gmm, routers = (lowered[k] for k in (
+        "attention", "grouped_matmuls", "routers"))
     blocks = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
     n_moe = blocks - cfg.first_k_dense_replace
     dk, dv = cfg.qk_head_dim, cfg.v_head_dim
@@ -1196,6 +1183,36 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
         f"expected {9 * n_moe} grouped matmuls on a tile of 128 rows "
         f"(128 rows an expert), none through ragged_dot: {gmm}")
 
+
+def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
+    """The latent-attention decoder's new mechanisms
+    (models/joyai_flash.py).
+
+    1. The cell ``joyai-train-s4096``'s train step (the dense layer,
+       four expert layers and the MTP module of JoyAI-LLM-Flash at its
+       published widths, 16 of 256 experts held, bf16 AMP, Adam) is
+       LOWERED, not run (perf/run.py runs it), and the dispatch
+       counters are held to what the cell must lower: one attention
+       call a block each way through the BHTD kernels at queries and
+       keys of 192 over values of 128 with its tile, none dense; every
+       router in its sigmoid form with a selection bias; every grouped
+       matmul of the held experts on a tile chosen for 128 rows an
+       expert (tm128), none through ``ragged_dot``. ``overrides`` cut
+       the config for the CPU tests.
+    2. On the device: the BHTD kernels at the model's two widths (8
+       heads: one a step at blocks of 512, the cell's tile, so the
+       backward is the fused call), forward and the three gradients,
+       against the dense composition."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import flash_attention as fa
+
+    cfg, _, rows = lower_cell(
+        "mla", seq, overrides, attention=attention_dispatch,
+        grouped_matmuls=gmm_dispatch, routers=router_dispatch)
+    mla_rows_hold(cfg, seq, rows)
+    dk, dv = cfg.qk_head_dim, cfg.v_head_dim
+
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(5)
     bf = jnp.bfloat16
@@ -1206,8 +1223,7 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
     va, ga = (jnp.asarray(r.randn(1, heads, t_check, dv), bf) for _ in "vg")
     errs = {}
     attn_ms = _bhtd_against_dense(qa, ka, va, ga, errs, "latent attention")
-    row = {"attention": attn, "routers": routers, "grouped_matmuls": gmm,
-           "tile": fa.tile_label(tile), "attn_bwd_kernel_ms": attn_ms,
+    row = {**rows, "tile": fa.tile_label(tile), "attn_bwd_kernel_ms": attn_ms,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  mla {row['rel_err']}")
     return row
@@ -1226,8 +1242,14 @@ def rope_dispatch():
             said.append(f"norm={labels['norm']}")
         return " ".join(said)
 
-    rows = monitor.snapshot().get("pt_rope_dispatch_total", {})
-    return {key(r["labels"]): int(r["value"]) for r in rows.get("values", [])}
+    # (summed: rows that differ in a label the key leaves out, as
+    # ``scaling``, share a key; the last one alone hid xing4's yarn calls
+    # behind lfm2's plain ones at the same head of 64, PR 68's chip run)
+    out = {}
+    for r in monitor.snapshot().get(
+            "pt_rope_dispatch_total", {}).get("values", []):
+        out[key(r["labels"])] = out.get(key(r["labels"]), 0) + int(r["value"])
+    return out
 
 
 def rope_phase(seq=4096, heads=(28, 4), dh=128, **overrides):
@@ -1422,49 +1444,10 @@ def ssm_dispatch():
     return selective_scan_ops.dispatch_counts()
 
 
-def ssm_phase(seq=4096, t_check=1024, **overrides):
-    """The state-space hybrid's new mechanisms (models/phi4flash.py).
-
-    1. The cell ``phi4flash-train-s4096``'s train step (layers 14-19 of
-       Phi-4-mini-flash at its published widths, an eighth of the tied
-       table, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
-       and the dispatch counters are held to what the cell must lower:
-       its two selective scans each way on the ``ssm.scan.*`` kernels
-       (``impl=kernel``), its two convolutions (with a bias) on the
-       ``gdn.conv.*`` kernels, and six attention calls each way (two
-       softmax maps in each of the window, the full and the cross layer)
-       through the BHTD kernels at dk64 dv128, none dense, the window
-       layer's with a band, every backward one call (``form=fused``).
-       ``overrides`` cut the config for the CPU tests.
-    2. On the device, at the cell's channels and ``t_check`` positions:
-       the scan kernels against the chunked XLA writing (gated, as layer
-       14's call, forward and every gradient), the convolution kernels
-       with a bias against the XLA writing, and the kernels' ms a call
-       by name."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.models import phi4flash as M
-    from paddle_tpu.ops import linear_attention_ops as L
-    from paddle_tpu.ops import selective_scan_ops as S
-    from paddle_tpu.parallel import selective_scan as K
-
-    cfg = M.Phi4FlashConfig(**{**dict(
-        num_hidden_layers=6, first_layer=14, model_layers=32,
-        vocab_size=25008), **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (attention_dispatch, ssm_dispatch, conv_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq)
-    attn, scans, convs = (_dispatch_since(b, read)
-                          for b, read in zip(before, reads))
-    say(f"  lowered: attention {attn}; selective scans {scans}; "
-        f"convolutions {convs}")
+def ssm_rows_hold(cfg, seq, lowered):
+    """What ``phi4flash-train-s4096``'s step must lower (ssm_phase, 1.)."""
+    attn, scans, convs = (lowered[k] for k in (
+        "attention", "selective_scans", "convolutions"))
     e, n = cfg.mamba_d_inner, cfg.mamba_d_state
     for direction in ("fwd", "bwd"):
         rows = {k: v for k, v in scans.items() if f" {direction} " in k}
@@ -1491,6 +1474,39 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
     _one_backward_call(attn)
     _statistics_in_rows(attn)
 
+
+def ssm_phase(seq=4096, t_check=1024, **overrides):
+    """The state-space hybrid's new mechanisms (models/phi4flash.py).
+
+    1. The cell ``phi4flash-train-s4096``'s train step (layers 14-19 of
+       Phi-4-mini-flash at its published widths, an eighth of the tied
+       table, bf16 AMP, Adam) is LOWERED, not run (perf/run.py runs it),
+       and the dispatch counters are held to what the cell must lower:
+       its two selective scans each way on the ``ssm.scan.*`` kernels
+       (``impl=kernel``), its two convolutions (with a bias) on the
+       ``gdn.conv.*`` kernels, and six attention calls each way (two
+       softmax maps in each of the window, the full and the cross layer)
+       through the BHTD kernels at dk64 dv128, none dense, the window
+       layer's with a band, every backward one call (``form=fused``).
+       ``overrides`` cut the config for the CPU tests.
+    2. On the device, at the cell's channels and ``t_check`` positions:
+       the scan kernels against the chunked XLA writing (gated, as layer
+       14's call, forward and every gradient), the convolution kernels
+       with a bias against the XLA writing, and the kernels' ms a call
+       by name."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attention_ops as L
+    from paddle_tpu.ops import selective_scan_ops as S
+    from paddle_tpu.parallel import selective_scan as K
+
+    cfg, _, rows = lower_cell(
+        "ssm", seq, overrides, attention=attention_dispatch,
+        selective_scans=ssm_dispatch, convolutions=conv_dispatch)
+    ssm_rows_hold(cfg, seq, rows)
+    e, n = cfg.mamba_d_inner, cfg.mamba_d_state
+
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(7)
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -1514,11 +1530,6 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
             {**wrapped, "States": out["States"], "GRAD::Out": [dy]}, {})
         return {"Out": out["Out"][0], **{k: v[0] for k, v in grads.items()}}
 
-    def rel(a, b):
-        a, b = (jnp.asarray(x, f32) for x in (a, b))
-        return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(),
-                                                        1e-6))
-
     kernels = jax.jit(scan)
     got = jax.block_until_ready(kernels(ins, dy))
     tile, K.ssm_tile = K.ssm_tile, lambda *a, **k: None
@@ -1533,7 +1544,7 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
         bool(jnp.any(got[k] != want[k])) for k in want),
         "the XLA writing's results are the kernels' bit for bit: the "
         "comparison ran one of them twice")
-    errs = {f"scan {k}": rel(got[k], want[k]) for k in want}
+    errs = {f"scan {k}": _rel(got[k], want[k]) for k in want}
     x, w = ins["X"], jnp.asarray(r.randn(e, cfg.mamba_d_conv) * 0.5, f32)
     bias = jnp.asarray(r.randn(e), f32)
 
@@ -1550,7 +1561,7 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
     y_ref, vjp = jax.vjp(xla, x, w, bias)
     for k, ref in zip(("Y", "GRAD::X", "GRAD::W", "GRAD::Bias"),
                       (y_ref, *vjp(dy))):
-        errs[f"conv {k}"] = rel(got[k], ref)
+        errs[f"conv {k}"] = _rel(got[k], ref)
     # (bf16 results of float32 sums in another order: 0.002-0.004 seen
     # for the scan, 0.004-0.008 for the convolution: my chip runs, PR 40)
     check(max(errs.values()) < 2e-2,
@@ -1564,8 +1575,7 @@ def ssm_phase(seq=4096, t_check=1024, **overrides):
     check(jax.default_backend() != "tpu"
           or {"ssm.scan.fwd", "ssm.scan.bwd", "gdn.conv.fwd",
               "gdn.conv.bwd"} <= set(ms), f"kernels in the trace: {ms}")
-    row = {"attention": attn, "selective_scans": scans,
-           "convolutions": convs, "kernel_ms": ms,
+    row = {**rows, "kernel_ms": ms,
            "rel_err": {k_: round(v, 5) for k_, v in errs.items()}}
     say(f"  ssm kernels, ms a call at t{t} e{e} n{n}: {ms}")
     say(f"  ssm {row['rel_err']}")
@@ -1578,51 +1588,11 @@ def mamba2_dispatch():
     return mamba2_scan_ops.dispatch_counts()
 
 
-def mamba2_phase(seq=4096, t_check=1024, **overrides):
-    """The Mamba-2 / attention hybrid's new mechanisms
-    (models/nemotron_h.py).
-
-    1. The cell ``nemotron3nano-train-s4096``'s train step (blocks 34-42
-       of NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, 8 of
-       128 experts held, an eighth of the vocabulary, bf16 AMP, Adam) is
-       LOWERED, not run (perf/run.py runs it), and the dispatch counters
-       are held to what the cell must lower: its four Mamba-2 scans each
-       way on the ``mamba2.chunk.*`` kernels (``impl=kernel``), their
-       four convolutions (with a bias) on the ``gdn.conv.*`` kernels,
-       every grouped matmul of the four expert layers on a tile (none as
-       ``ragged_dot``: the experts' width of 1856 is off the 128 lanes),
-       and the one attention call each way through the BHTD kernels at
-       32 / 2 heads, the backward one call (``form=fused``).
-       ``overrides`` cut the config for the CPU tests.
-    2. On the device, at the cell's heads and ``t_check`` positions: the
-       scan kernels against the chunked XLA writing (forward and every
-       gradient), the grouped matmuls at the experts' widths against
-       ``ragged_dot``, and the kernels' ms a call by name."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.models import nemotron_h as M
-    from paddle_tpu.ops import mamba2_scan_ops as S
-    from paddle_tpu.parallel import grouped_matmul as gm
-    from paddle_tpu.parallel import mamba2_scan as K
-
-    cfg = M.NemotronHConfig(**{**dict(
-        num_hidden_layers=9, first_layer=34, vocab_size=16384,
-        held_experts=(0, 8)), **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (attention_dispatch, mamba2_dispatch, conv_dispatch,
-             gmm_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq)
-    attn, scans, convs, gmms = (_dispatch_since(b, read)
-                                for b, read in zip(before, reads))
-    say(f"  lowered: attention {attn}; mamba2 scans {scans}; convolutions "
-        f"{convs}; grouped matmuls {gmms}")
+def mamba2_rows_hold(cfg, seq, lowered):
+    """What ``nemotron3nano-train-s4096``'s step must lower (mamba2_phase,
+    1.)."""
+    attn, scans, convs, gmms = (lowered[k] for k in (
+        "attention", "mamba2_scans", "convolutions", "grouped_matmuls"))
     kinds = [k for _, k in cfg.blocks]
     n_scan, n_moe, n_attn = (kinds.count(k)
                              for k in ("mamba2", "moe", "attn"))
@@ -1653,6 +1623,42 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
         f"expected {6 * n_moe} grouped matmuls (two matrices an expert, "
         f"three products each), every one on a tile: {gmms}")
 
+
+def mamba2_phase(seq=4096, t_check=1024, **overrides):
+    """The Mamba-2 / attention hybrid's new mechanisms
+    (models/nemotron_h.py).
+
+    1. The cell ``nemotron3nano-train-s4096``'s train step (blocks 34-42
+       of NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, 8 of
+       128 experts held, an eighth of the vocabulary, bf16 AMP, Adam) is
+       LOWERED, not run (perf/run.py runs it), and the dispatch counters
+       are held to what the cell must lower: its four Mamba-2 scans each
+       way on the ``mamba2.chunk.*`` kernels (``impl=kernel``), their
+       four convolutions (with a bias) on the ``gdn.conv.*`` kernels,
+       every grouped matmul of the four expert layers on a tile (none as
+       ``ragged_dot``: the experts' width of 1856 is off the 128 lanes),
+       and the one attention call each way through the BHTD kernels at
+       32 / 2 heads, the backward one call (``form=fused``).
+       ``overrides`` cut the config for the CPU tests.
+    2. On the device, at the cell's heads and ``t_check`` positions: the
+       scan kernels against the chunked XLA writing (forward and every
+       gradient), the grouped matmuls at the experts' widths against
+       ``ragged_dot``, and the kernels' ms a call by name."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import mamba2_scan_ops as S
+    from paddle_tpu.parallel import grouped_matmul as gm
+    from paddle_tpu.parallel import mamba2_scan as K
+
+    cfg, _, rows = lower_cell(
+        "mamba2", seq, overrides, attention=attention_dispatch,
+        mamba2_scans=mamba2_dispatch, convolutions=conv_dispatch,
+        grouped_matmuls=gmm_dispatch)
+    mamba2_rows_hold(cfg, seq, rows)
+    shape = (f"t{seq} h{cfg.mamba_num_heads} p{cfg.mamba_head_dim} "
+             f"g{cfg.n_groups} n{cfg.ssm_state_size}")
+
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(7)
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -1677,11 +1683,6 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
             {**wrapped, "States": out["States"], "GRAD::Out": [dy]}, attrs)
         return {"Out": out["Out"][0], **{k: v[0] for k, v in grads.items()}}
 
-    def rel(a, b):
-        a, b = (jnp.asarray(x, f32) for x in (a, b))
-        return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(),
-                                                        1e-6))
-
     kernels = jax.jit(scan)
     got = jax.block_until_ready(kernels(ins, dy))
     tile, K.mamba2_tile = K.mamba2_tile, lambda *a, **k: None
@@ -1696,7 +1697,7 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
         bool(jnp.any(got[k] != want[k])) for k in want),
         "the XLA writing's results are the kernels' bit for bit: the "
         "comparison ran one of them twice")
-    errs = {f"scan {k}": rel(got[k], want[k]) for k in want}
+    errs = {f"scan {k}": _rel(got[k], want[k]) for k in want}
 
     # the experts' grouped matmuls at their widths and the cell's own
     # rows (a held share: an eighth of a row tile an expert at t_check
@@ -1729,10 +1730,10 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
     want = jax.jit(ragged)(x, w, jnp.where(
         jnp.arange(m)[:, None] < live, g, 0), sizes)
     for name, a, b in zip(("gmm y", "gmm dx"), got, want):
-        errs[name] = rel(a[:live], b[:live])
+        errs[name] = _rel(a[:live], b[:live])
         check(not bool(jnp.any(a[live:] != 0)),
               f"{name} holds something behind the last live row")
-    errs["gmm dw"] = rel(got[2], want[2])
+    errs["gmm dw"] = _rel(got[2], want[2])
     check(max(errs.values()) < 2e-2,
           f"mamba2 and off-lane moe kernels against the XLA writings: "
           f"{errs}")
@@ -1746,12 +1747,39 @@ def mamba2_phase(seq=4096, t_check=1024, **overrides):
           or {"mamba2.chunk.fwd", "mamba2.chunk.bwd", "moe.gmm.fwd",
               "moe.gmm.bwd_dx", "moe.tgmm.bwd_dw"} <= set(ms),
           f"kernels in the trace: {ms}")
-    row = {"attention": attn, "mamba2_scans": scans, "convolutions": convs,
-           "grouped_matmuls": gmms, "kernel_ms": ms,
+    row = {**rows, "kernel_ms": ms,
            "rel_err": {k_: round(v, 5) for k_, v in errs.items()}}
     say(f"  mamba2 kernels, ms a call at t{t} {shape}: {ms}")
     say(f"  mamba2 {row['rel_err']}")
     return row
+
+
+def sconv_rows_hold(cfg, seq, lowered):
+    """What ``lfm2moe-train-s8192``'s step must lower (sconv_phase, 1.)."""
+    attn, convs, ropes = (lowered[k] for k in (
+        "attention", "convolutions", "rotary_embeddings"))
+    kinds = [k for _, k, _ in cfg.blocks]
+    n_conv, n_attn = kinds.count("sconv"), kinds.count("attn")
+    c, taps = cfg.hidden_size, cfg.conv_L_cache
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in convs.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_conv and all(
+            k == f"kernel {direction} b1 t{seq} c{c} taps{taps} gated"
+            for k in rows),
+            f"expected {n_conv} gated convolutions {direction} on the "
+            f"sconv.gated kernels: {convs}")
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n_attn and all(
+            k.startswith("bhtd ") and f" h{cfg.num_attention_heads} "
+            f"kv{cfg.num_key_value_heads} dh{cfg.head_dim} " in k
+            for k in rows),
+            f"expected {n_attn} bhtd attention call {direction} at "
+            f"{cfg.num_attention_heads} / {cfg.num_key_value_heads} heads "
+            f"of {cfg.head_dim}, none dense: {attn}")
+    _one_backward_call(attn)
+    _statistics_in_rows(attn)
+    check(sum(ropes.values()) == 2 * n_attn,
+          f"expected {n_attn} rotary embedding each way: {ropes}")
 
 
 def sconv_phase(seq=8192, t_check=2048, **overrides):
@@ -1777,48 +1805,14 @@ def sconv_phase(seq=8192, t_check=2048, **overrides):
     import jax
     import jax.numpy as jnp
 
-    import paddle_tpu as fluid
-    from paddle_tpu.models import lfm2_moe as M
     from paddle_tpu.ops import linear_attention_ops as L
     from paddle_tpu.parallel import causal_conv as K
 
-    cfg = M.Lfm2MoeConfig(**{**dict(
-        num_hidden_layers=5, first_layer=1, vocab_size=8192,
-        held_experts=(0, 8)), **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (attention_dispatch, conv_dispatch, rope_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq)
-    attn, convs, ropes = (_dispatch_since(b, read)
-                          for b, read in zip(before, reads))
-    say(f"  lowered: attention {attn}; convolutions {convs}; rotary "
-        f"embeddings {ropes}")
-    kinds = [k for _, k, _ in cfg.blocks]
-    n_conv, n_attn = kinds.count("sconv"), kinds.count("attn")
+    cfg, _, rows = lower_cell(
+        "sconv", seq, overrides, attention=attention_dispatch,
+        convolutions=conv_dispatch, rotary_embeddings=rope_dispatch)
+    sconv_rows_hold(cfg, seq, rows)
     c, taps = cfg.hidden_size, cfg.conv_L_cache
-    for direction in ("fwd", "bwd"):
-        rows = {k: v for k, v in convs.items() if f" {direction} " in k}
-        check(sum(rows.values()) == n_conv and all(
-            k == f"kernel {direction} b1 t{seq} c{c} taps{taps} gated"
-            for k in rows),
-            f"expected {n_conv} gated convolutions {direction} on the "
-            f"sconv.gated kernels: {convs}")
-        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
-        check(sum(rows.values()) == n_attn and all(
-            k.startswith("bhtd ") and f" h{cfg.num_attention_heads} "
-            f"kv{cfg.num_key_value_heads} dh{cfg.head_dim} " in k
-            for k in rows),
-            f"expected {n_attn} bhtd attention call {direction} at "
-            f"{cfg.num_attention_heads} / {cfg.num_key_value_heads} heads "
-            f"of {cfg.head_dim}, none dense: {attn}")
-    _one_backward_call(attn)
-    _statistics_in_rows(attn)
-    check(sum(ropes.values()) == 2 * n_attn,
-          f"expected {n_attn} rotary embedding each way: {ropes}")
 
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(7)
@@ -1838,18 +1832,13 @@ def sconv_phase(seq=8192, t_check=2048, **overrides):
         return {"Y": y, "dB": dx[..., :c], "dC": dx[..., c:2 * c],
                 "du": dx[..., 2 * c:], "dW": g["GRAD::W"][0]}
 
-    def rel(a, b):
-        a, b = (jnp.asarray(v, f32) for v in (a, b))
-        return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(),
-                                                        1e-6))
-
     kernels = jax.jit(conv)
     got = jax.block_until_ready(kernels(x, w, dy))
     y_ref, vjp = jax.vjp(L._gated_conv_xla, x, w)
     dx_ref, dw_ref = vjp(dy)
     want = {"Y": y_ref, "dB": dx_ref[..., :c], "dC": dx_ref[..., c:2 * c],
             "du": dx_ref[..., 2 * c:], "dW": dw_ref}
-    errs = {k: rel(got[k], want[k]) for k in want}
+    errs = {k: _rel(got[k], want[k]) for k in want}
     # (bf16 results of float32 sums in another order)
     check(max(errs.values()) < 2e-2,
           f"sconv.gated kernels against the XLA writing: {errs}")
@@ -1861,12 +1850,32 @@ def sconv_phase(seq=8192, t_check=2048, **overrides):
     check(jax.default_backend() != "tpu"
           or {"sconv.gated.fwd", "sconv.gated.bwd"} <= set(ms),
           f"kernels in the trace: {ms}")
-    row = {"attention": attn, "convolutions": convs,
-           "rotary_embeddings": ropes, "kernel_ms": ms,
+    row = {**rows, "kernel_ms": ms,
            "rel_err": {k_: round(v, 5) for k_, v in errs.items()}}
     say(f"  sconv kernels, ms a call at t{t} c{c} taps{taps}: {ms}")
     say(f"  sconv {row['rel_err']}")
     return row
+
+
+def bd_rows_hold(cfg, seq, lowered):
+    """What ``sdar-train-s4096``'s step must lower (bd_phase, 1.)."""
+    attn, ropes = lowered["attention"], lowered["rotary_embeddings"]
+    n, block = cfg.num_hidden_layers, cfg.block_length
+    for direction in ("fwd", "bwd"):
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n and all(
+            k.startswith(f"bhtd {direction} b1 tq{2 * seq} tk{2 * seq} ")
+            and k.endswith(f" mask=block_diffusion block={block} band=skip")
+            for k in rows),
+            f"expected {n} block-masked attention calls {direction} over "
+            f"{2 * seq} positions in the bhtd kernels (band=skip), none "
+            f"dense: {attn}")
+    _one_backward_call(attn)
+    _statistics_in_rows(attn)
+    check(sum(ropes.values()) == 2 * n and all(
+        k.startswith("kernel ") and k.endswith(" norm=head") for k in ropes),
+        f"expected {n} rotary embeddings each way on the rope kernels "
+        f"with the heads' gains (norm=head), none as XLA's ops: {ropes}")
 
 
 def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
@@ -1896,41 +1905,16 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
     import jax
     import jax.numpy as jnp
 
-    import paddle_tpu as fluid
-    from paddle_tpu.models import sdar as M
     from paddle_tpu.parallel import flash_attention as fa
 
-    cfg = M.SdarConfig(**{**dict(
-        num_hidden_layers=5, vocab_size=18992, mask_token_id=18991,
-        held_experts=(0, 16)), **overrides})
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        fluid.optimizer.Adam(1e-4).minimize(model["loss"])
-    main._amp = True
-    reads = (attention_dispatch, rope_dispatch)
-    before = [read() for read in reads]
-    lower_train_step(main, model["loss"], seq, feeds={
-        "input_ids": ((1, 2 * seq), "int32"), "labels": ((1, seq), "int32"),
-        "loss_weight": ((1, seq), "float32")})
-    attn, ropes = (_dispatch_since(b, read) for b, read in zip(before, reads))
-    say(f"  lowered: attention {attn}; rotary embeddings {ropes}")
+    cfg, main, rows = lower_cell(
+        "bd", seq, overrides, attention=attention_dispatch,
+        rotary_embeddings=rope_dispatch, feeds={
+            "input_ids": ((1, 2 * seq), "int32"),
+            "labels": ((1, seq), "int32"),
+            "loss_weight": ((1, seq), "float32")})
+    bd_rows_hold(cfg, seq, rows)
     n, block = cfg.num_hidden_layers, cfg.block_length
-    for direction in ("fwd", "bwd"):
-        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
-        check(sum(rows.values()) == n and all(
-            k.startswith(f"bhtd {direction} b1 tq{2 * seq} tk{2 * seq} ")
-            and k.endswith(f" mask=block_diffusion block={block} band=skip")
-            for k in rows),
-            f"expected {n} block-masked attention calls {direction} over "
-            f"{2 * seq} positions in the bhtd kernels (band=skip), none "
-            f"dense: {attn}")
-    _one_backward_call(attn)
-    _statistics_in_rows(attn)
-    check(sum(ropes.values()) == 2 * n and all(
-        k.startswith("kernel ") and k.endswith(" norm=head") for k in ropes),
-        f"expected {n} rotary embeddings each way on the rope kernels "
-        f"with the heads' gains (norm=head), none as XLA's ops: {ropes}")
     normed = [op.type for op in main.global_block().ops
               if op.type.startswith("rms_norm") and "/attn/" in
               (op.namescope or "") + "/"]
@@ -1964,9 +1948,7 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
     errs = {}
     for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
                           kernels(q, k, v, g), dense(q, k, v, g)):
-        a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-6))
+        errs[name] = _rel(a, b)
         check(errs[name] <= KERNEL_REL_TOL,
               f"block-masked {name} off the dense composition by "
               f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
@@ -1983,7 +1965,7 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
     check(jax.default_backend() != "tpu"
           or sorted(ms) == ["attn.bhtd.bwd", "attn.bhtd.fwd"],
           f"kernels in the trace: {ms}")
-    row = {"attention": attn, "rotary_embeddings": ropes, "kernel_ms": ms,
+    row = {**rows, "kernel_ms": ms,
            "pairs": {k_: list(v_) for k_, v_ in pairs.items()},
            "rel_err": {k_: round(v_, 5) for k_, v_ in errs.items()}}
     say(f"  block-masked kernels, ms a call at t{t} h{h} kv{hk} dh{dh}: "

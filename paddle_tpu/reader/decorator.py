@@ -222,6 +222,11 @@ def xmap_readers(mapper, reader, process_num: int, buffer_size: int,
     return data_reader
 
 
+# seconds the consumer of multiprocess_reader waits on an empty queue
+# before it looks at its children
+_POLL_S = 5.0
+
+
 def multiprocess_reader(readers, use_pipe: bool = True,
                         queue_size: int = 1000):
     """Run each reader in its own OS process, interleaving their samples
@@ -237,7 +242,9 @@ def multiprocess_reader(readers, use_pipe: bool = True,
     exception is re-raised in the consumer (truncated silent epochs are
     the reference's failure mode too — it forwards an error sentinel);
     a worker killed without cleanup (OOM/SIGKILL) is detected by a
-    liveness poll instead of hanging the training loop.
+    liveness poll instead of hanging the training loop: the ends are
+    kept a child, and a child that is dead and has sent neither ``end``
+    nor ``error`` is that error whatever its siblings do.
     """
     if not isinstance(readers, (list, tuple)) or not readers:
         raise ValueError("multiprocess_reader needs a non-empty reader list")
@@ -249,37 +256,45 @@ def multiprocess_reader(readers, use_pipe: bool = True,
         ctx = mp.get_context("fork")
         q = ctx.Queue(queue_size)
 
-        def worker(r):
+        def worker(i, r):
             try:
                 for sample in r():
                     q.put(("data", sample))
-                q.put(("end", None))
+                q.put(("end", i))
             except BaseException as e:  # propagated to the consumer
                 q.put(("error", repr(e)))
 
         procs = [
-            ctx.Process(target=worker, args=(r,), daemon=True)
-            for r in readers
+            ctx.Process(target=worker, args=(i, r), daemon=True)
+            for i, r in enumerate(readers)
         ]
         for p in procs:
             p.start()
-        ended = 0
+        ended = set()       # the children whose ``end`` has arrived
         try:
-            while ended < len(readers):
+            while len(ended) < len(readers):
                 # gate snapshotted across the wait: a runtime telemetry
                 # flip mid-get must not record perf_counter() - 0.0
                 obs = _monitor.enabled()
                 t_wait0 = time.perf_counter() if obs else 0.0
+                dead = []
                 while True:
                     try:
-                        tag, payload = q.get(timeout=5.0)
+                        tag, payload = q.get(timeout=_POLL_S)
                         break
                     except _queue.Empty:
-                        if not any(p.is_alive() for p in procs):
+                        # a dead child has flushed all it will ever
+                        # send: an empty poll AFTER the one that found
+                        # it dead means its end is not coming, whether
+                        # or not a sibling lives
+                        if dead:
                             raise RuntimeError(
-                                "multiprocess_reader: worker process died "
-                                "without an end/error message (killed?)"
-                            )
+                                f"multiprocess_reader: worker process "
+                                f"{dead[0]} of {len(procs)} died without "
+                                f"an end/error message (exit code "
+                                f"{procs[dead[0]].exitcode}: killed?)")
+                        dead = [i for i, p in enumerate(procs)
+                                if i not in ended and not p.is_alive()]
                 if obs:
                     # the total blocked time, Empty-timeout polls included
                     _monitor.reader_wait("multiprocess", "consumer",
@@ -289,7 +304,7 @@ def multiprocess_reader(readers, use_pipe: bool = True,
                     except NotImplementedError:  # qsize unsupported on
                         pass                     # some platforms (macOS)
                 if tag == "end":
-                    ended += 1
+                    ended.add(payload)
                 elif tag == "error":
                     raise RuntimeError(
                         f"multiprocess_reader worker failed: {payload}"

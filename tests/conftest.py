@@ -10,9 +10,13 @@ the mode of the hardware kernel suite, tests/test_flash_attention_tpu.py,
 run on the chip in one pytest process.
 """
 
+import faulthandler
 import os
+import signal
+import tempfile
 
 import jax
+import pytest
 
 if os.environ.get("PT_TEST_TPU") != "1":
     jax.config.update("jax_platforms", "cpu")
@@ -27,7 +31,7 @@ if os.environ.get("PT_TEST_TPU") != "1":
         jax.config.update("jax_disable_most_optimizations", True)
 
 # Persistent compile cache: repeat suite runs skip the slow XLA compiles.
-from paddle_tpu import jax_cache  # noqa: E402
+from paddle_tpu import jax_cache, monitor  # noqa: E402
 
 jax_cache.configure()
 
@@ -41,6 +45,65 @@ if os.environ.get("PYTEST_XDIST_WORKER"):
 
     _perf_harness.TRACE_ROOT = os.path.join(
         _perf_harness.TRACE_ROOT, os.environ["PYTEST_XDIST_WORKER"])
+
+
+# --- a time limit a test ---
+#
+# Seconds one test (setup, call and teardown together) may take off the
+# run's clock. No option, no variable, no marker raises it: a test that
+# needs more is a test to cut. The slowest test of the whole run PR 68
+# started from took 64 s under six workers
+# (tests/test_ring_attention.py::test_ring_attention_dropout, 14 s since),
+# the slowest of its own tree 42 s (a chip_smoke phase).
+# The multi-process drills hold their children to a deadline of their
+# own (tests/test_fleet_*: 150 s), which is the shorter and ends them
+# first.
+TEST_LIMIT_S = 240.0
+_STDERR_FD = 2      # (pytest_configure: the run's own, behind the capture)
+
+
+def _all_stacks():
+    with tempfile.TemporaryFile(mode="w+") as f:
+        faulthandler.dump_traceback(file=f, all_threads=True)
+        f.seek(0)
+        return f.read()
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item):
+    """A hang prints ``F`` with the test's name and every thread's stack,
+    and the worker goes on to its next test. SIGALRM reaches Python code
+    only between bytecodes: a hang inside native code ends the worker at
+    twice the limit (faulthandler's watchdog thread needs no bytecode),
+    which xdist reports as the test's crash before it starts another.
+    (pytest and xdist's workers run tests in their main thread, where
+    a signal's handler may be set.)"""
+    def expired(signum, frame):
+        pytest.fail(
+            f"{item.nodeid} took more than the {TEST_LIMIT_S:g} s a test "
+            f"may take (tests/conftest.py TEST_LIMIT_S). Every thread's "
+            f"stack:\n{_all_stacks()}", pytrace=False)
+
+    before = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    faulthandler.dump_traceback_later(2 * TEST_LIMIT_S, exit=True,
+                                      file=_STDERR_FD)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.fixture(autouse=True)
+def _zeroed_metrics():
+    """A test starts from zeroed metrics. The monitor's rows (the
+    dispatch counters among them) are process-wide, and a file's traced
+    run used to leave its rows to whatever its xdist worker ran next:
+    tests/test_grouped_matmul_adam.py's ``..._where_the_call_has_a_tile
+    [adam]`` read tests/test_checkpoint.py's grouped matmuls (PR 68)."""
+    monitor.reset()
 
 
 # --- suite tiering (VERDICT r4 item 3) ---
@@ -59,6 +122,8 @@ def pytest_addoption(parser):
 
 
 def pytest_configure(config):
+    global _STDERR_FD
+    _STDERR_FD = os.dup(2)      # capture is suspended while plugins configure
     config.addinivalue_line(
         "markers",
         "full: expensive deep-parity test, excluded from the default "

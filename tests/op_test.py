@@ -161,3 +161,40 @@ class OpHarness:
                 rtol=rtol,
                 err_msg=f"{self.op_type} grad wrt {name} mismatch",
             )
+
+
+def delta_rule_op(**attrs):
+    """q, k, v, g, beta, dO -> (Out, the five gradients), States of
+    ``gated_delta_rule`` and its grad op as the Program runs them: ONE
+    jitted computation (op by op a case is some 240 executables), a
+    fresh one a call of this: the interpreter hook is read while it is
+    traced."""
+    import jax
+
+    from paddle_tpu.ops import linear_attention_ops as L
+
+    def both(q, k, v, g, beta, do):
+        ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
+        out = L._gated_delta_rule(ins, attrs)
+        grads = L._gated_delta_rule_grad(
+            {**ins, "States": out["States"], "GRAD::Out": [do]}, attrs)
+        return (out["Out"][0], *(grads[f"GRAD::{s}"][0] for s in (
+            "Q", "K", "V", "G", "Beta"))), out["States"][0]
+
+    return jax.jit(both)
+
+
+def delta_rule_recurrence(q, k, v, g, beta, do):
+    """The float32 recurrence on the operands as given, and jax's vjp."""
+    import jax
+
+    from paddle_tpu.ops import linear_attention_ops as L
+
+    @jax.jit
+    def both(q, k, v, g, beta, do):
+        f32 = (x.astype("float32") for x in (q, k, v))
+        out, vjp = jax.vjp(L.recurrent_gated_delta_rule, *f32, g, beta)
+        return (out, *vjp(do.astype("float32")))
+
+    with jax.default_matmul_precision("highest"):
+        return both(q, k, v, g, beta, do)

@@ -106,10 +106,8 @@ from paddle_tpu import amp, flags, layers, monitor
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": False, "numerics": False})
     yield
-    monitor.reset()
     flags.set_flags({"telemetry": False, "numerics": False})
 
 

@@ -24,11 +24,9 @@ _RESET_FLAGS = {"telemetry": False, "step_phases": True,
 
 @pytest.fixture(autouse=True)
 def _clean():
-    monitor.reset()
     faults.disarm()
     flags.set_flags(dict(_RESET_FLAGS))
     yield
-    monitor.reset()
     faults.disarm()
     flags.set_flags(dict(_RESET_FLAGS))
 

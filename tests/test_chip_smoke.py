@@ -3,6 +3,7 @@ CPU (so the script cannot rot between chip runs), its refusal to pass
 without a TPU, and the fallbacks this bring-up removed — decorative
 places, a cache dir forced in code, a default peaks row."""
 
+import importlib
 import os
 import subprocess
 import sys
@@ -21,11 +22,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture
 def telemetry(tmp_path):
-    monitor.reset()
     flags.set_flags({"telemetry": True, "trace_dir": str(tmp_path)})
     yield
     flags.set_flags({"telemetry": False, "trace_dir": ""})
-    monitor.reset()
 
 
 def tiny(**kw):
@@ -171,6 +170,8 @@ def test_loss_head_phase_fails(telemetry, monkeypatch, fault, match):
             temp_share=None if fault == "silent" else 1e-3)
 
 
+# --- the phases that lower a cell: one run a phase, shared ---
+
 GDN_TINY = dict(
     vocab_size=50, hidden_size=128, num_attention_heads=4,
     num_key_value_heads=2, head_dim=128, linear_key_head_dim=128,
@@ -178,10 +179,112 @@ GDN_TINY = dict(
     linear_num_value_heads=2, moe_intermediate_size=128,
     shared_expert_intermediate_size=128, num_experts=8,
     held_experts=(0, 4), num_experts_per_tok=2)
+MLA_TINY = dict(
+    vocab_size=50, hidden_size=128, num_hidden_layers=2,
+    intermediate_size=128, num_attention_heads=1, q_lora_rank=32,
+    kv_lora_rank=32, moe_intermediate_size=128, n_routed_experts=8,
+    held_experts=(0, 4), num_experts_per_tok=2)
+SSM_TINY = dict(
+    vocab_size=50, hidden_size=512, num_attention_heads=8,
+    num_key_value_heads=4, intermediate_size=128, sliding_window=128)
+MAMBA2_TINY = dict(
+    vocab_size=50, hidden_size=128, mamba_num_heads=4, n_groups=2,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+    moe_intermediate_size=192, moe_shared_expert_intermediate_size=128,
+    n_routed_experts=4, num_experts_per_tok=2, held_experts=(0, 2))
+SCONV_TINY = dict(
+    vocab_size=50, hidden_size=128, intermediate_size=256,
+    num_attention_heads=2, num_key_value_heads=1, moe_intermediate_size=128,
+    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2))
+BD_TINY = dict(
+    vocab_size=50, mask_token_id=49, hidden_size=128, num_attention_heads=8,
+    num_key_value_heads=1, head_dim=128, moe_intermediate_size=128,
+    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2),
+    num_hidden_layers=2)
+KDA_TINY = dict(
+    vocab_size=50, hidden_size=128, num_hidden_layers=3,
+    intermediate_size=128, num_attention_heads=1, kv_lora_rank=32,
+    moe_intermediate_size=128, num_experts=8, held_experts=(0, 4),
+    num_experts_per_token=2,
+    linear_attn_config={"kda_layers": [1, 3], "full_attn_layers": [2],
+                        "head_dim": 128, "num_heads": 2,
+                        "short_conv_kernel_size": 4})
+XING4_TINY = dict(
+    vocab_size=50, hidden_size=128, num_hidden_layers=2,
+    intermediate_size=128, num_attention_heads=1, q_lora_rank=32,
+    kv_lora_rank=32, moe_intermediate_size=128, n_routed_experts=8,
+    held_experts=(0, 4), num_experts_per_tok=2, hc_sinkhorn_iters=3)
+# phase: (the interpreters its kernels run through, the sequence its cut
+# cell is lowered at, the rest of the phase's arguments, the cut config)
+PHASES = {
+    "gdn": ("grouped_matmul flash_attention gated_delta_rule causal_conv",
+            512, dict(t_check=128, heads=(1, 2), width=16, gqa=(4, 2, 128),
+                      conv_c=256), GDN_TINY),
+    # (one head: a tile that batches heads lowers the backward as the
+    # pair, which the phase refuses: the split-backward test below)
+    "mla": ("grouped_matmul flash_attention", 512,
+            dict(t_check=256, heads=1), MLA_TINY),
+    "ssm": ("flash_attention causal_conv selective_scan", 512,
+            dict(t_check=64), SSM_TINY),
+    "mamba2": ("flash_attention causal_conv grouped_matmul mamba2_scan "
+               "pair_sum", 512, dict(t_check=256), MAMBA2_TINY),
+    "sconv": ("flash_attention causal_conv grouped_matmul pair_sum", 512,
+              dict(t_check=256), SCONV_TINY),
+    "bd": ("flash_attention grouped_matmul pair_sum rope", 512,
+           dict(t_check=512, heads=(8, 1)), BD_TINY),
+    "kda": ("flash_attention grouped_matmul pair_sum causal_conv "
+            "gated_delta_rule", 512, dict(t_check=128), KDA_TINY),
+    "xing4": ("flash_attention grouped_matmul pair_sum hc_mix", 1024,
+              dict(t_check=1024), XING4_TINY),
+}
 
 
-def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+@pytest.fixture(scope="module")
+def phase_row(tmp_path_factory):
+    """``phase_row(name)`` -> the row of the phase's ONE run in this
+    module (its cut config through the interpreters, under telemetry):
+    the ``holds`` test reads it, and the ``fails`` tests feed the
+    phase's ``*_rows_hold`` an altered copy of its dispatch rows where
+    they used to lower the cell again with one kernel off."""
+    rows = {}
+
+    def run(name):
+        if name not in rows:
+            interpreters, seq, args, tiny = PHASES[name]
+            flags.set_flags({"telemetry": True, "trace_dir": str(
+                tmp_path_factory.mktemp(name))})
+            try:
+                with pytest.MonkeyPatch.context() as patch:
+                    for module in interpreters.split():
+                        patch.setattr(importlib.import_module(
+                            f"paddle_tpu.parallel.{module}"),
+                            "_INTERPRET", True)
+                    rows[name] = getattr(chip_smoke, f"{name}_phase")(
+                        seq=seq, **args, **tiny)
+            finally:
+                flags.set_flags({"telemetry": False, "trace_dir": ""})
+        return rows[name]
+
+    return run
+
+
+def fails(name, match, **altered):
+    """The phase's check refuses its own run's rows with ``altered``
+    ones in their place."""
+    _, seq, _, tiny = PHASES[name]
+    _, cfg = chip_smoke.cell(name, **tiny)
+    with pytest.raises(chip_smoke.SmokeFailure, match=match):
+        getattr(chip_smoke, f"{name}_rows_hold")(cfg, seq, altered)
+
+
+def renamed(rows, old, new):
+    """The dispatch rows with ``old`` in every key as ``new``: what the
+    counter says where the call took the other implementation."""
+    assert all(old in k for k in rows)
+    return {k.replace(old, new): v for k, v in rows.items()}
+
+
+def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters: a period of
     three delta-rule layers and one grouped-query attention layer
     lowers 3 + 3 delta-rule calls through the gdn.* kernels and 3 + 3
@@ -190,17 +293,7 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     at a width that gets no tile) the chunkwise form agrees with the
     recurrence, the conv's kernels with the XLA form and the attention
     kernels with the dense composition."""
-    from paddle_tpu.parallel import causal_conv as cc
-    from paddle_tpu.parallel import gated_delta_rule as gdr
-    from paddle_tpu.parallel import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(gdr, "_INTERPRET", True)
-    monkeypatch.setattr(cc, "_INTERPRET", True)
-    row = chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2),
-                               width=16, gqa=(4, 2, 128), conv_c=256,
-                               **GDN_TINY)
+    row = phase_row("gdn")
     shape = "b1 t512 hk1 hv2 dk128 dv128 chunk64"
     assert row["gdn"] == {f"kernel fwd {shape}": 3,
                           f"kernel bwd {shape}": 3}
@@ -221,43 +314,26 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(
 
 
 @pytest.mark.parametrize("why,overrides", [
-    ("recurrent", dict(gdn_impl="recurrent")),      # the caller's fallback
-    ("chunked", {})])                 # no tile: the kernels are off here
-def test_gdn_phase_fails_on_a_call_without_the_kernel(
-        why, overrides, telemetry, monkeypatch):
-    from paddle_tpu.parallel import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match="none chunked, none recurrent"):
-        chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2), width=16,
-                             gqa=(4, 2, 128), **overrides, **GDN_TINY)
+    # the caller's fallback (gdn_impl="recurrent"): rows at chunk 1
+    ("recurrent", {"kernel": "recurrent", "chunk64": "chunk1"}),
+    ("chunked", {"kernel": "chunked"})])     # no tile: the kernels are off
+def test_gdn_phase_fails_on_a_call_without_the_kernel(why, overrides,
+                                                      phase_row):
+    rows = phase_row("gdn")["gdn"]
+    for old, new in overrides.items():
+        rows = renamed(rows, old, new)
+    fails("gdn", "none chunked, none recurrent",
+          **dict(phase_row("gdn"), gdn=rows))
 
 
-def test_gdn_phase_fails_on_a_convolution_without_the_kernel(
-        telemetry, monkeypatch):
-    # the conv's kernels off (CPU, no interpreter): six XLA forms
-    from paddle_tpu.parallel import gated_delta_rule as gdr
-    from paddle_tpu.parallel import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(gdr, "_INTERPRET", True)
-    with pytest.raises(chip_smoke.SmokeFailure, match="none as XLA ops"):
-        chip_smoke.gdn_phase(seq=512, t_check=128, heads=(1, 2), width=16,
-                             gqa=(4, 2, 128), conv_c=256, **GDN_TINY)
+def test_gdn_phase_fails_on_a_convolution_without_the_kernel(phase_row):
+    # the conv's kernels off: six XLA forms
+    row = phase_row("gdn")
+    fails("gdn", "none as XLA ops",
+          **dict(row, conv=renamed(row["conv"], "kernel", "xla")))
 
 
-MLA_TINY = dict(
-    vocab_size=50, hidden_size=128, num_hidden_layers=2,
-    intermediate_size=128, num_attention_heads=2, q_lora_rank=32,
-    kv_lora_rank=32, moe_intermediate_size=128, n_routed_experts=8,
-    held_experts=(0, 4), num_experts_per_tok=2)
-
-
-def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (the widths
     of a head are the model's: 192 over 128): the dense layer, one
     expert layer and the MTP module lower three attention calls each way
@@ -265,14 +341,7 @@ def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     with a bias, and 18 grouped matmuls on tiles of 128 rows; on the
     device (here: the CPU) the kernels agree with the dense
     composition."""
-    from paddle_tpu.parallel import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-    # (one head: a tile that batches heads lowers the backward as the
-    # pair, which the phase refuses: the test below)
-    row = chip_smoke.mla_phase(seq=512, t_check=256, heads=1,
-                               **dict(MLA_TINY, num_attention_heads=1))
+    row = phase_row("mla")
     assert row["attention"] == {
         f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}": 3
         for d, form in (("bwd", " form=fused"), ("fwd", " stats=rows"))}
@@ -282,24 +351,26 @@ def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
 
 
-def test_mla_phase_fails_on_a_split_backward_call(telemetry, monkeypatch):
+def test_mla_phase_fails_on_a_split_backward_call(phase_row, monkeypatch):
     """Two heads in a step (a tile of a short sequence): the backward
-    lowers as bwd_dq + bwd_dkv, and the phase says so."""
-    from paddle_tpu.parallel import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "_INTERPRET", True)
+    lowers as bwd_dq + bwd_dkv (the row the counter gives then, from
+    ``bhtd_bwd_form``), and the phase says so."""
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    with pytest.raises(chip_smoke.SmokeFailure, match="none split"):
-        chip_smoke.mla_phase(seq=512, t_check=256, heads=2, **MLA_TINY)
+    assert fa.bhtd_bwd_form(2, 512, 512, dh=192, dv=128, itemsize=2) == (
+        "split")
+    row = phase_row("mla")
+    two = renamed(renamed(row["attention"], " h1 ", " h2 "), "[hb1", "[hb2")
+    fails("mla", "none split", **dict(
+        row, attention={k.replace("form=fused", "form=split"): v
+                        for k, v in two.items()}))
 
 
-def test_mla_phase_fails_on_a_dense_attention_call(telemetry, monkeypatch):
-    # the attention kernels off (CPU, no interpreter): every call is dense
-    from paddle_tpu.parallel import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "_INTERPRET", True)
-    with pytest.raises(chip_smoke.SmokeFailure, match="none dense"):
-        chip_smoke.mla_phase(seq=512, t_check=256, heads=2, **MLA_TINY)
+def test_mla_phase_fails_on_a_dense_attention_call(phase_row):
+    # the attention kernels off: every call is the dense composition
+    row = phase_row("mla")
+    fails("mla", "none dense", **dict(row, attention={
+        k.replace("bhtd ", "dense ").split(" [")[0]: v
+        for k, v in row["attention"].items()}))
 
 
 @pytest.mark.parametrize("dropout,tol", [
@@ -437,6 +508,19 @@ def test_dispatch_rows_name_the_bhtd_tile_and_the_keys_stay(telemetry,
         "bthd_small fwd b64 tq256 tk256 h8 dh64": 1}
 
 
+def test_rope_rows_that_differ_in_scaling_alone_add_up(telemetry):
+    """``rope_dispatch`` leaves ``scaling`` out of its keys: lfm2's plain
+    calls and xing4's yarn calls at one head width are one key, and the
+    key's count is their sum (the last row alone read no new call in
+    ``xing4_phase`` behind ``sconv_phase`` on the chip, PR 68)."""
+    from paddle_tpu.ops import attention_ops
+
+    row = {"impl": "xla", "pass": "fwd", "layout": "bthd", "dh": "64"}
+    attention_ops._M_ROPE.inc(5, labels=dict(row, scaling="yarn"))
+    attention_ops._M_ROPE.inc(labels=dict(row, scaling="none"))
+    assert chip_smoke.rope_dispatch() == {"xla fwd bthd 64": 6}
+
+
 def test_backend_peaks_raises_for_an_unknown_device():
     assert roofline.backend_peaks("cpu") == roofline.DEVICE_PEAKS["cpu"]
     assert roofline.backend_peaks("TPU v5 lite")[0] == roofline.V5E_PEAK_BF16
@@ -478,22 +562,7 @@ def test_jax_cache_placement(case, tmp_path):
         assert threshold == 1.0
 
 
-SSM_TINY = dict(
-    vocab_size=50, hidden_size=512, num_attention_heads=8,
-    num_key_value_heads=4, intermediate_size=128, sliding_window=128)
-
-
-def _ssm_interpreters(monkeypatch):
-    from paddle_tpu.parallel import causal_conv as cc
-    from paddle_tpu.parallel import selective_scan as ss
-
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(cc, "_INTERPRET", True)
-    monkeypatch.setattr(ss, "_INTERPRET", True)
-
-
-def test_ssm_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_ssm_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (heads of 64
     over values of 128 and a state of 16 are the model's; 1024 channels):
     layers 14-19 lower two selective scans and two convolutions each way
@@ -501,8 +570,7 @@ def test_ssm_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     dv128, the window layer's two with their band, every backward one
     call; on the device (here: the CPU) the scan's and the convolution's
     kernels agree with the XLA writings."""
-    _ssm_interpreters(monkeypatch)
-    row = chip_smoke.ssm_phase(seq=512, t_check=64, **SSM_TINY)
+    row = phase_row("ssm")
     assert row["selective_scans"] == {
         f"kernel {d} b1 t512 e1024 n16 chunk128": 2 for d in ("fwd", "bwd")}
     assert row["convolutions"] == {
@@ -521,38 +589,17 @@ def test_ssm_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert max(row["rel_err"].values()) < 2e-2
 
 
-def test_ssm_phase_fails_on_a_scan_without_the_kernel(telemetry,
-                                                      monkeypatch):
-    # the scan's kernels off (no interpreter): both calls are the
-    # chunked XLA form, and the phase says so
-    from paddle_tpu.parallel import causal_conv as cc
-
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(cc, "_INTERPRET", True)
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match="on the ssm.scan kernels"):
-        chip_smoke.ssm_phase(seq=512, t_check=64, **SSM_TINY)
+def test_ssm_phase_fails_on_a_scan_without_the_kernel(phase_row):
+    # the scan's kernels off: both calls are the chunked XLA form (at its
+    # own chunk), and the phase says so
+    row = phase_row("ssm")
+    fails("ssm", "on the ssm.scan kernels", **dict(
+        row, selective_scans=renamed(renamed(
+            row["selective_scans"], "kernel", "chunked"),
+            "chunk128", "chunk64")))
 
 
-MAMBA2_TINY = dict(
-    vocab_size=50, hidden_size=128, mamba_num_heads=4, n_groups=2,
-    num_attention_heads=4, num_key_value_heads=2, head_dim=64,
-    moe_intermediate_size=192, moe_shared_expert_intermediate_size=128,
-    n_routed_experts=4, num_experts_per_tok=2, held_experts=(0, 2))
-
-
-def _mamba2_interpreters(monkeypatch):
-    from paddle_tpu.parallel import causal_conv as cc
-    from paddle_tpu.parallel import grouped_matmul as gm
-    from paddle_tpu.parallel import mamba2_scan as m2
-    from paddle_tpu.parallel import pair_sum as ps
-
-    for module in (fa, cc, gm, m2, ps):
-        monkeypatch.setattr(module, "_INTERPRET", True)
-
-
-def test_mamba2_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_mamba2_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (heads of 64
     over a state of 128 in chunks of 128 are the model's; experts of 192
     = 1.5 lane tiles as 1856 = 14.5): blocks 34-42 lower four scans and
@@ -560,8 +607,7 @@ def test_mamba2_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     each on a tile and one attention call each way, its backward one
     call; on the device (here: the CPU) the scan's kernels agree with
     the XLA writing and the grouped matmuls with ragged_dot."""
-    _mamba2_interpreters(monkeypatch)
-    row = chip_smoke.mamba2_phase(seq=512, t_check=256, **MAMBA2_TINY)
+    row = phase_row("mamba2")
     assert row["mamba2_scans"] == {
         f"kernel {d} b1 t512 h4 p64 g2 n128 chunk128": 4
         for d in ("fwd", "bwd")}
@@ -584,44 +630,22 @@ def test_mamba2_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert max(row["rel_err"].values()) < 2e-2
 
 
-def test_mamba2_phase_fails_on_a_scan_without_the_kernel(telemetry,
-                                                         monkeypatch):
-    # the scan's kernels off (no interpreter): the calls are the chunked
-    # XLA form, and the phase says so
-    from paddle_tpu.parallel import mamba2_scan as m2
-
-    _mamba2_interpreters(monkeypatch)
-    monkeypatch.setattr(m2, "_INTERPRET", False)
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match="on the mamba2.chunk kernels"):
-        chip_smoke.mamba2_phase(seq=512, t_check=256, **MAMBA2_TINY)
+def test_mamba2_phase_fails_on_a_scan_without_the_kernel(phase_row):
+    # the scan's kernels off: the calls are the chunked XLA form, and the
+    # phase says so
+    row = phase_row("mamba2")
+    fails("mamba2", "on the mamba2.chunk kernels", **dict(
+        row, mamba2_scans=renamed(row["mamba2_scans"], "kernel", "chunked")))
 
 
-SCONV_TINY = dict(
-    vocab_size=50, hidden_size=128, intermediate_size=256,
-    num_attention_heads=2, num_key_value_heads=1, moe_intermediate_size=128,
-    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2))
-
-
-def _sconv_interpreters(monkeypatch):
-    from paddle_tpu.parallel import causal_conv as cc
-    from paddle_tpu.parallel import grouped_matmul as gm
-    from paddle_tpu.parallel import pair_sum as ps
-
-    for module in (fa, cc, gm, ps):
-        monkeypatch.setattr(module, "_INTERPRET", True)
-
-
-def test_sconv_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_sconv_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (heads of 64
     and three taps are the model's): layers 1-5 lower four gated
     convolutions each way through ``sconv.gated.*`` and one attention
     call each way, its backward one call; the rotary embedding of a
     head of 64 is XLA's; on the device (here: the CPU) the kernels agree
     with the composition."""
-    _sconv_interpreters(monkeypatch)
-    row = chip_smoke.sconv_phase(seq=512, t_check=256, **SCONV_TINY)
+    row = phase_row("sconv")
     assert row["convolutions"] == {
         f"kernel {d} b1 t512 c128 taps3 gated": 4 for d in ("fwd", "bwd")}
     attn = row["attention"]
@@ -636,37 +660,15 @@ def test_sconv_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert max(row["rel_err"].values()) < 2e-2
 
 
-def test_sconv_phase_fails_on_a_convolution_without_the_kernel(
-        telemetry, monkeypatch):
-    # the convolution's kernels off (no interpreter): the calls are the
-    # composition in XLA ops, and the phase says so
-    from paddle_tpu.parallel import causal_conv as cc
-
-    _sconv_interpreters(monkeypatch)
-    monkeypatch.setattr(cc, "_INTERPRET", False)
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match="on the sconv.gated kernels"):
-        chip_smoke.sconv_phase(seq=512, t_check=256, **SCONV_TINY)
+def test_sconv_phase_fails_on_a_convolution_without_the_kernel(phase_row):
+    # the convolution's kernels off: the calls are the composition in XLA
+    # ops, and the phase says so
+    row = phase_row("sconv")
+    fails("sconv", "on the sconv.gated kernels", **dict(
+        row, convolutions=renamed(row["convolutions"], "kernel", "xla")))
 
 
-BD_TINY = dict(
-    vocab_size=50, mask_token_id=49, hidden_size=128, num_attention_heads=8,
-    num_key_value_heads=1, head_dim=128, moe_intermediate_size=128,
-    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2),
-    num_hidden_layers=2)
-
-
-def _bd_interpreters(monkeypatch):
-    from paddle_tpu.parallel import grouped_matmul as gm
-    from paddle_tpu.parallel import pair_sum as ps
-    from paddle_tpu.parallel import rope
-
-    for module in (fa, gm, ps, rope):
-        monkeypatch.setattr(module, "_INTERPRET", True)
-
-
-def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (8 query heads
     a key/value head of 128 and blocks of 4 are the model's): every
     layer lowers one block-masked attention call each way over the 2 x
@@ -675,8 +677,7 @@ def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     the ``rope.*`` kernels with the heads' gains (``norm=head``); on the
     device (here: the CPU) the kernels agree with the dense
     composition."""
-    _bd_interpreters(monkeypatch)
-    row = chip_smoke.bd_phase(seq=512, t_check=512, heads=(8, 1), **BD_TINY)
+    row = phase_row("bd")
     shape = "b1 tq1024 tk1024 h8 kv1 dh128 [hb1 bq512 bk512]"
     mask = "mask=block_diffusion block=4 band=skip"
     assert row["attention"] == {
@@ -693,12 +694,17 @@ def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(
 
 
 def test_bd_phase_fails_on_a_block_masked_call_that_runs_dense(
-        telemetry, monkeypatch):
-    # a row whose half is no whole number of tiles: the calls are the
-    # dense composition, and the phase says so
-    _bd_interpreters(monkeypatch)
-    with pytest.raises(chip_smoke.SmokeFailure, match="none dense"):
-        chip_smoke.bd_phase(seq=384, t_check=512, heads=(8, 1), **BD_TINY)
+        phase_row, monkeypatch):
+    """A row whose half is no whole number of tiles (384) has no tile
+    under the mask: its calls are the dense composition, and the phase
+    says so of the rows the counter gives then."""
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    assert fa.bhtd_tile(8, 768, 768, dh=128, group=8,
+                        block_diffusion=4) is None
+    shape = "b1 tq1024 tk1024 h8 kv1 dh128"
+    fails("bd", "none dense", **dict(phase_row("bd"), attention={
+        f"dense {d} {shape} mask=block_diffusion block=4 band=dense": 2
+        for d in ("fwd", "bwd")}))
 
 
 def test_attention_pairs_of_the_cells_calls(monkeypatch):
@@ -721,28 +727,7 @@ def test_attention_pairs_of_the_cells_calls(monkeypatch):
     assert all(0.5 < row["live_share"] <= 1.0 for row in pairs.values())
 
 
-KDA_TINY = dict(
-    vocab_size=50, hidden_size=128, num_hidden_layers=3,
-    intermediate_size=128, num_attention_heads=1, kv_lora_rank=32,
-    moe_intermediate_size=128, num_experts=8, held_experts=(0, 4),
-    num_experts_per_token=2,
-    linear_attn_config={"kda_layers": [1, 3], "full_attn_layers": [2],
-                        "head_dim": 128, "num_heads": 2,
-                        "short_conv_kernel_size": 4})
-
-
-def _kda_interpreters(monkeypatch):
-    from paddle_tpu.parallel import causal_conv as cc
-    from paddle_tpu.parallel import gated_delta_rule as gdr
-    from paddle_tpu.parallel import grouped_matmul as gm
-    from paddle_tpu.parallel import pair_sum as ps
-
-    for module in (fa, gm, ps, cc, gdr):
-        monkeypatch.setattr(module, "_INTERPRET", True)
-
-
-def test_kda_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_kda_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (a head's 128
     features and the chunk of 64 are the model's): two KDA layers and a
     latent layer between them lower two delta-rule calls each way as
@@ -751,8 +736,7 @@ def test_kda_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     and no rotary embedding; on the device (here: the CPU) the kernels
     agree with the float32 recurrence with G below -200 inside a chunk
     and with mild gates."""
-    _kda_interpreters(monkeypatch)
-    row = chip_smoke.kda_phase(seq=512, t_check=128, **KDA_TINY)
+    row = phase_row("kda")
     shape = "b1 t512 hk2 hv2 dk128 dv128 chunk64"
     assert row["kda"] == {f"kernel feature {d} {shape}": 2
                           for d in ("fwd", "bwd")}
@@ -768,36 +752,14 @@ def test_kda_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert max(row["rel_err"].values()) < 0.012
 
 
-def test_kda_phase_fails_on_a_call_without_the_kernel(telemetry,
-                                                      monkeypatch):
-    # the rule's kernels off (CPU, no interpreter): the chunked XLA form
-    from paddle_tpu.parallel import gated_delta_rule as gdr
-
-    _kda_interpreters(monkeypatch)
-    monkeypatch.setattr(gdr, "_INTERPRET", False)
-    with pytest.raises(chip_smoke.SmokeFailure,
-                       match="through the kda.rule"):
-        chip_smoke.kda_phase(seq=512, t_check=128, **KDA_TINY)
+def test_kda_phase_fails_on_a_call_without_the_kernel(phase_row):
+    # the rule's kernels off: the chunked XLA form
+    row = phase_row("kda")
+    fails("kda", "through the kda.rule", **dict(
+        row, kda=renamed(row["kda"], "kernel", "chunked")))
 
 
-XING4_TINY = dict(
-    vocab_size=50, hidden_size=128, num_hidden_layers=2,
-    intermediate_size=128, num_attention_heads=1, q_lora_rank=32,
-    kv_lora_rank=32, moe_intermediate_size=128, n_routed_experts=8,
-    held_experts=(0, 4), num_experts_per_tok=2, hc_sinkhorn_iters=3)
-
-
-def _xing4_interpreters(monkeypatch):
-    from paddle_tpu.parallel import grouped_matmul as gm
-    from paddle_tpu.parallel import hc_mix
-    from paddle_tpu.parallel import pair_sum as ps
-
-    for module in (fa, gm, ps, hc_mix):
-        monkeypatch.setattr(module, "_INTERPRET", True)
-
-
-def test_xing4_phase_holds_the_lowered_cell_to_its_dispatch_rows(
-        telemetry, monkeypatch):
+def test_xing4_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     """The phase at a cut config through the interpreters (four streams,
     a head of 192 over 128 and the yarn table are the model's): a dense
     and an expert layer lower four mixes each way on the ``hc.mix.*``
@@ -806,8 +768,7 @@ def test_xing4_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     rotary embedding a layer each way, a sigmoid router with a selection
     bias; on the device (here: the CPU) the kernels agree with XLA's ops
     with logits beyond the clamp among them."""
-    _xing4_interpreters(monkeypatch)
-    row = chip_smoke.xing4_phase(seq=1024, t_check=1024, **XING4_TINY)
+    row = phase_row("xing4")
     assert row["hc"] == {f"{impl} {op} {d}": 4 for op, impl in (
         ("mix", "kernel"), ("pre", "xla"), ("post", "xla"))
         for d in ("fwd", "bwd")}
@@ -820,14 +781,13 @@ def test_xing4_phase_holds_the_lowered_cell_to_its_dispatch_rows(
     assert row["columns_off_one"] < 1e-5 and row["hc_kernel_ms"] == {}
 
 
-def test_xing4_phase_fails_on_a_mix_without_the_kernel(telemetry,
+def test_xing4_phase_fails_on_a_mix_without_the_kernel(phase_row,
                                                        monkeypatch):
     # the mixes lowered as XLA's ops where mix_tile gives the call a
     # tile: the counter's rows say so and the phase refuses them
-    from paddle_tpu.ops import hc_ops
+    from paddle_tpu.parallel import hc_mix
 
-    _xing4_interpreters(monkeypatch)
-    monkeypatch.setattr(hc_ops, "_res_tile", lambda z, n, d: (
-        hc_ops._note("mix", d, "xla"), None)[1])
-    with pytest.raises(chip_smoke.SmokeFailure, match="as kernel"):
-        chip_smoke.xing4_phase(seq=1024, t_check=1024, **XING4_TINY)
+    monkeypatch.setattr(hc_mix, "_INTERPRET", True)
+    row = phase_row("xing4")
+    fails("xing4", "as kernel", **dict(row, hc={
+        k.replace("kernel mix", "xla mix"): v for k, v in row["hc"].items()}))

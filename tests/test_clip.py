@@ -13,13 +13,11 @@ from paddle_tpu import flags, layers, monitor, numerics
 
 @pytest.fixture(autouse=True)
 def _clean():
-    monitor.reset()
     clip_mod.set_gradient_clip.__globals__["_clip_attr"] = None
     clip_mod.set_gradient_clip.__globals__["_clip_param_names"] = None
     flags.set_flags({"telemetry": False, "numerics": False,
                      "numerics_vars": ""})
     yield
-    monitor.reset()
     clip_mod.set_gradient_clip.__globals__["_clip_attr"] = None
     clip_mod.set_gradient_clip.__globals__["_clip_param_names"] = None
     flags.set_flags({"telemetry": False, "numerics": False,

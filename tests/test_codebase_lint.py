@@ -12,6 +12,7 @@ import ast
 import os
 import shutil
 import subprocess
+import sys
 
 import pytest
 
@@ -96,3 +97,30 @@ def test_ruff_config_present():
     cfg = open(os.path.join(ROOT, "pyproject.toml")).read()
     assert "[tool.ruff.lint]" in cfg
     assert '"F"' in cfg and '"B"' in cfg
+
+
+def test_a_test_that_hangs_fails_by_its_name_and_the_run_goes_on(tmp_path):
+    """tests/conftest.py's limit a test, with the constant patched to
+    1 s in a pytest of its own over two tests: the sleeper is ``F`` by
+    its name with the threads' stacks, the next test runs and passes."""
+    (tmp_path / "test_two.py").write_text(
+        "import time\n\n"
+        "def test_sleeps():\n    time.sleep(60)\n\n"
+        "def test_after():\n    pass\n")
+    run = ("import sys, pytest, conftest\n"
+           "conftest.TEST_LIMIT_S = 1.0\n"
+           "sys.exit(pytest.main(['-q', '-p', 'conftest', '-p', "
+           f"'no:cacheprovider', '--rootdir', {str(tmp_path)!r}, "
+           f"{str(tmp_path / 'test_two.py')!r}]))\n")
+    out = subprocess.run(
+        [sys.executable, "-c", run], capture_output=True, text=True,
+        timeout=120, cwd=ROOT, env={
+            **os.environ, "JAX_PLATFORMS": "cpu",
+            "PYTHONPATH": os.pathsep.join(
+                [os.path.join(ROOT, "tests"), ROOT])})
+    said = out.stdout + out.stderr
+    assert out.returncode == 1, said[-2000:]
+    assert "1 failed, 1 passed" in said, said[-2000:]
+    assert "test_two.py::test_sleeps took more than the 1 s" in said
+    # the stack of the thread that slept, down to the test's own line
+    assert "in test_sleeps" in said and "Current thread" in said

@@ -18,13 +18,11 @@ from paddle_tpu.core import lowering
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     defaults = {"telemetry": False, "step_log_path": "",
                 "metrics_dump_path": "", "compile_report_dir": "",
                 "device_memory_budget_bytes": 0}
     flags.set_flags(defaults)
     yield
-    monitor.reset()
     flags.set_flags(defaults)
 
 

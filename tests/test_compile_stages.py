@@ -28,11 +28,9 @@ CLOCKS_APART_S = 5e-3
 def _telemetry():
     keep = {k: flags.get_flag(k) for k in (
         "telemetry", "step_phases", "executor_cache_capacity")}
-    monitor.reset()
     flags.set_flags({"telemetry": True, "step_phases": False})
     yield
     flags.set_flags(keep)
-    monitor.reset()
 
 
 def _build(width=4):

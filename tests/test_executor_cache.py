@@ -17,10 +17,8 @@ from paddle_tpu.core import fingerprint
 
 @pytest.fixture(autouse=True)
 def _clean():
-    monitor.reset()
     flags.set_flags({"telemetry": True, "executor_cache_capacity": 0})
     yield
-    monitor.reset()
     flags.set_flags({"telemetry": False, "executor_cache_capacity": 0})
 
 

@@ -16,7 +16,6 @@ from paddle_tpu import faults, flags, monitor
 @pytest.fixture(autouse=True)
 def _clean():
     faults.disarm()
-    monitor.reset()
     yield
     faults.disarm()
     flags.set_flags({"fault_plan": "", "telemetry": False})

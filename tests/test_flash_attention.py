@@ -8,6 +8,7 @@ hardware PRNG (``prng_seed`` has no CPU lowering): those cases live in
 tests/test_flash_attention_tpu.py and run on the chip.
 """
 
+import functools
 import math
 
 import numpy as np
@@ -568,6 +569,38 @@ _WALKED = ["causal", "band", "band_group7", "band_group8", "group7",
            "pad_bias"]
 
 
+@functools.cache
+def _fused_case_against(case):
+    """(out, lse, the pair's gradients, the composition's) of a case:
+    what the ONE call is held to, the same whatever sub-tiles it walks
+    its edge blocks in (the forward and the pair keep theirs whole), so
+    made once a case."""
+    (q, k, v, bias, g, g_lse), kw = _fused_case(case)
+    h, hk, tq, tk, dh, dv, blk = _FUSED_CASES[case][:7]
+    window = fa._band(kw["window"], kw["causal"], tq, tk)
+
+    @jax.jit
+    def composition(q, k, v, g, g_lse):
+        _, vjp = jax.vjp(
+            lambda q, k, v: fa._reference_attention_with_lse(
+                q, k, v, bias, dh ** -0.5, causal=kw["causal"],
+                window=window), q, k, v)
+        return vjp((g, g_lse))
+
+    with jax.default_matmul_precision("highest"), \
+            pytest.MonkeyPatch.context() as patch:
+        out, lse = fa.flash_attention_fwd(q, k, v, bias, **kw)
+        # no room for a resident row: the pair
+        patch.setattr(fa, "_BWD_VMEM_CAP_BYTES", 0)
+        assert fa.bhtd_bwd_form(h, tq, tk, blk, blk, dh=dh, group=h // hk,
+                                dv=dv, itemsize=4) == "split"
+        pair = fa.flash_attention_bwd(q, k, v, bias, None, out, lse, g,
+                                      g_lse=g_lse, **kw)
+        want = composition(
+            q, k, v, g, jnp.zeros_like(lse) if g_lse is None else g_lse)
+    return out, lse, pair, want
+
+
 @pytest.mark.parametrize("case,edge_sub", [
     *((c, None) for c in sorted(_FUSED_CASES)), *((c, 64) for c in _WALKED),
     ("band", 32), ("causal", 32)])
@@ -575,6 +608,7 @@ def test_fused_backward_matches_the_pair_and_the_composition(case, edge_sub,
                                                              monkeypatch):
     (q, k, v, bias, g, g_lse), kw = _fused_case(case)
     h, hk, tq, tk, dh, dv, blk = _FUSED_CASES[case][:7]
+    out, lse, pair, want = _fused_case_against(case)
     if edge_sub:
         monkeypatch.setattr(fa, "_EDGE_SUB", edge_sub)
     assert fa.bhtd_edge_tile((1, blk, blk), kw["causal"]) == (
@@ -583,22 +617,9 @@ def test_fused_backward_matches_the_pair_and_the_composition(case, edge_sub,
     assert fa.bhtd_tile(h, tq, tk, blk, blk, dh=dh, group=h // hk,
                         dv=dv) == (1, blk, blk)
     assert fa.bhtd_bwd_form(h, tq, tk, blk, blk, **form) == "fused"
-    window = fa._band(kw["window"], kw["causal"], tq, tk)
-    scale = dh ** -0.5
     with jax.default_matmul_precision("highest"):
-        out, lse = fa.flash_attention_fwd(q, k, v, bias, **kw)
         got = fa.flash_attention_bwd(q, k, v, bias, None, out, lse, g,
                                      g_lse=g_lse, **kw)
-        # no room for a resident row: the pair
-        monkeypatch.setattr(fa, "_BWD_VMEM_CAP_BYTES", 0)
-        assert fa.bhtd_bwd_form(h, tq, tk, blk, blk, **form) == "split"
-        pair = fa.flash_attention_bwd(q, k, v, bias, None, out, lse, g,
-                                      g_lse=g_lse, **kw)
-        _, vjp = jax.vjp(
-            lambda q, k, v: fa._reference_attention_with_lse(
-                q, k, v, bias, scale, causal=kw["causal"], window=window),
-            q, k, v)
-        want = vjp((g, jnp.zeros_like(lse) if g_lse is None else g_lse))
     for a, p, w, name in zip(got, pair, want, ("dq", "dk", "dv")):
         assert a.shape == w.shape and a.dtype == w.dtype
         # the pair's arithmetic in another order of float32 additions

@@ -31,7 +31,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     faults.disarm()
     flags.set_flags({"telemetry": False, "step_log_path": "",
                      "stall_dump_dir": "", "fault_plan": "",
@@ -43,7 +42,6 @@ def _clean_telemetry():
                      "step_phases_every_n": 1})
     yield
     monitor.stop_server()
-    monitor.reset()
     faults.disarm()
     flags.set_flags({"telemetry": False, "step_log_path": "",
                      "stall_dump_dir": "", "fault_plan": "",

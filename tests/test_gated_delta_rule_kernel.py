@@ -15,6 +15,8 @@ import pytest
 from jax.experimental import pallas as pl
 
 import paddle_tpu as fluid
+from op_test import delta_rule_op
+from op_test import delta_rule_recurrence as recurrence
 from paddle_tpu import flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.ops import linear_attention_ops as L
@@ -38,22 +40,7 @@ def operands(t, hk, hv, seed=0, dtype=BF, b=1):
 
 
 def through_the_op(q, k, v, g, beta, do, **attrs):
-    """(Out, the five gradients), States: the registered op and its
-    grad op, as the Program runs them."""
-    ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
-    out = L._gated_delta_rule(ins, attrs)
-    grads = L._gated_delta_rule_grad(
-        {**ins, "States": out["States"], "GRAD::Out": [do]}, attrs)
-    return (out["Out"][0], *(grads[f"GRAD::{s}"][0] for s in (
-        "Q", "K", "V", "G", "Beta"))), out["States"][0]
-
-
-def recurrence(q, k, v, g, beta, do):
-    """The float32 recurrence on the operands as given, and jax's vjp."""
-    with jax.default_matmul_precision("highest"):
-        out, vjp = jax.vjp(L.recurrent_gated_delta_rule, q.astype(F32),
-                           k.astype(F32), v.astype(F32), g, beta)
-        return (out, *vjp(do.astype(F32)))
+    return delta_rule_op(**attrs)(q, k, v, g, beta, do)
 
 
 def rel(a, b):

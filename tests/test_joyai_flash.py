@@ -14,13 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
-from paddle_tpu import analysis, flags, layers, monitor
-from paddle_tpu.backward import append_backward
+from model_test import drawn, highest, moved, reference, snapshot
+from paddle_tpu import analysis, flags, monitor
 from paddle_tpu.models import joyai_flash as M
 from paddle_tpu.ops import moe_ops
 from perf.reference import joyai as ref
-from perf.reference.common import weights_from_scope
 
 TINY = dict(vocab_size=50, hidden_size=32, num_hidden_layers=3,
             first_k_dense_replace=1, intermediate_size=64,
@@ -42,40 +42,20 @@ MOE = ["moe_norm.scale", "moe_router.w", "moe_gate.w", "moe_up.w",
        "moe_shared_down.w"]
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains, routers and selection biases away from their initial 1 / 0.02 /
+# 0, so that every parameter matters, the routing has no near-ties and
+# the bias moves some choices
+PERTURB = [((".scale",), moved(0.2)), (("_router.w",), drawn()),
+           (("_router.bias",), drawn(0.3))]
 
 
 def perturb(scope, seed):
-    """Gains, routers and selection biases away from their initial 1 /
-    0.02 / 0, so that every parameter matters, the routing has no
-    near-ties and the bias moves some choices."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        shape = np.shape(scope.find_var(n))
-        if n.endswith(".scale"):
-            scope.set(n, jnp.asarray(
-                np.asarray(scope.find_var(n)) + 0.2 * r.randn(*shape),
-                jnp.float32))
-        if n.endswith("_router.w"):
-            scope.set(n, jnp.asarray(r.randn(*shape), jnp.float32))
-        if n.endswith("_router.bias"):
-            scope.set(n, jnp.asarray(0.3 * r.randn(*shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, optimizer=None):
     cfg = M.JoyaiFlashConfig(**TINY, n_routed_experts=16, held_experts=HELD)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = None
-        if optimizer is None:
-            grads = append_backward(model["loss"])
-        else:
-            optimizer().minimize(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed, optimizer))
 
 
 def test_model_loss_both_logits_and_every_parameters_gradient():
@@ -92,11 +72,8 @@ def test_model_loss_both_logits_and_every_parameters_gradient():
         model["loss"], model["last_logits"], model["mtp_last_logits"],
         model["lb_loss"], model["mtp_loss"], *model["top_i"],
         *model["expert_rows"], *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, REF_CFG, feed["input_ids"], feed["labels"],
-                           last=M.LAST_POSITIONS)
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, REF_CFG, feed))(w)
+    want, want_loss, want_g = reference(
+        ref, w, REF_CFG, feed, feed["labels"], last=M.LAST_POSITIONS)
     # float32 on both sides; the same mathematics in another order
     # (sorted groups against a dense loop, one 24-wide score product
     # against its two parts, rotate-by-reshape against a 2 x 2 rotation
@@ -143,17 +120,15 @@ def test_model_loss_both_logits_and_every_parameters_gradient():
 
     # the table and the head are ONE parameter with two uses each: the
     # gradient is the sum of both uses' (each alone is not it)
-    def part(main_w, mtp_w):
-        def f(w_):
-            out = ref.forward(w_, REF_CFG, feed["input_ids"], feed["labels"])
-            lbl = jnp.asarray(feed["labels"])
-            return (main_w * jnp.mean(ref._ce(out["logits"], lbl))
-                    + mtp_w * ref.MTP_LAMBDA * jnp.mean(
-                        ref._ce(out["mtp_logits"][:, :-1], lbl[:, 1:])))
-        with jax.default_matmul_precision("highest"):
-            return jax.grad(f)(w)
+    def weighted(w_, main_w, mtp_w):
+        out = ref.forward(w_, REF_CFG, feed["input_ids"], feed["labels"])
+        lbl = jnp.asarray(feed["labels"])
+        return (main_w * jnp.mean(ref._ce(out["logits"], lbl))
+                + mtp_w * ref.MTP_LAMBDA * jnp.mean(
+                    ref._ce(out["mtp_logits"][:, :-1], lbl[:, 1:])))
 
-    only_main, only_mtp = part(1.0, 0.0), part(0.0, 1.0)
+    part = highest(jax.grad(weighted))      # (one executable for both)
+    only_main, only_mtp = part(w, 1.0, 0.0), part(w, 0.0, 1.0)
     for n in ("joyai_tok_emb.w", "lm_head_colp.w"):
         both = only_main[n] + only_mtp[n]
         scale = np.abs(both).max()
@@ -338,26 +313,9 @@ def moe_layer(held, shared, x, weights=None, seed=3):
     """(out, rows, d loss / d x, {param: value}) of a sigmoid-routed
     topk_moe layer; ``weights``: the uncut layer's, cut to the held
     share."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        xv = layers.data("x", shape=list(x.shape), dtype="float32",
-                         append_batch_size=False)
-        xv.stop_gradient = False
-        out, _, _, rows, _ = layers.topk_moe(
-            xv, E, K, F, name="m", held=held, shared_d_ff=shared, **KW)
-        append_backward(layers.reduce_sum(layers.square(out)))
-    scope, exe = fluid.Scope(), fluid.Executor()
-    exe.run(startup, scope=scope)
-    for n, v in (weights or {}).items():
-        if n in scope.var_names():
-            if held and v.ndim == 3 and v.shape[0] == E:
-                v = v[held[0]:held[0] + held[1]]
-            scope.set(n, jnp.asarray(v))
-    w = snapshot(scope)
-    got = exe.run(main, feed={"x": x}, scope=scope,
-                  fetch_list=[out, rows, "x@GRAD"])
-    return (*got, w)
+    return model_test.moe_layer(
+        E, K, F, held, x, weights, seed, grad=True,
+        shared_d_ff=shared, **KW)
 
 
 def test_shares_of_an_expert_layer_sum_to_the_uncut_layer():

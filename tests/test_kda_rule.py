@@ -17,6 +17,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from op_test import delta_rule_op
+from op_test import delta_rule_recurrence as recurrence
 from paddle_tpu import flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.ops import linear_attention_ops as L
@@ -44,19 +46,7 @@ def operands(t, h, seed=0, dtype=F32, dt=0.5, width=16, b=1):
 
 
 def through_the_op(q, k, v, g, beta, do, **attrs):
-    ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
-    out = L._gated_delta_rule(ins, attrs)
-    grads = L._gated_delta_rule_grad(
-        {**ins, "States": out["States"], "GRAD::Out": [do]}, attrs)
-    return (out["Out"][0], *(grads[f"GRAD::{s}"][0] for s in (
-        "Q", "K", "V", "G", "Beta"))), out["States"][0]
-
-
-def recurrence(q, k, v, g, beta, do):
-    with jax.default_matmul_precision("highest"):
-        out, vjp = jax.vjp(L.recurrent_gated_delta_rule, q.astype(F32),
-                           k.astype(F32), v.astype(F32), g, beta)
-        return (out, *vjp(do.astype(F32)))
+    return delta_rule_op(**attrs)(q, k, v, g, beta, do)
 
 
 def rel(a, b):
@@ -182,8 +172,9 @@ def test_a_late_token_changes_nothing_before_it(kernels, monkeypatch):
                                     width=width)
     at = 100
     edit = [x.at[:, at].set(x[:, at] * 0.5 + 0.25) for x in (q, k, v, g)]
-    a, _ = through_the_op(q, k, v, g, beta, do, chunk=64)
-    b, _ = through_the_op(*edit, beta.at[:, at].set(0.9), do, chunk=64)
+    run = delta_rule_op(chunk=64)
+    a, _ = run(q, k, v, g, beta, do)
+    b, _ = run(*edit, beta.at[:, at].set(0.9), do)
     np.testing.assert_array_equal(np.asarray(a[0][:, :at], np.float32),
                                   np.asarray(b[0][:, :at], np.float32))
     assert rel(a[0][:, at:], b[0][:, at:]) > 1e-3

@@ -16,13 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, moved, reference, snapshot
 from paddle_tpu import analysis, layers
-from paddle_tpu.backward import append_backward
 from paddle_tpu.models import decoder, joyai_flash
 from paddle_tpu.models import kimi_linear as M
 from perf.reference import kimilinear as ref
-from perf.reference.common import weights_from_scope
 
 # published layers 1-5 in small: KDA + dense, KDA, KDA, MLA, KDA
 LINEAR = {"kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
@@ -49,44 +49,24 @@ MOE = ["moe_norm.scale", "moe_router.w", "moe_gate.w", "moe_up.w",
        "moe_shared_down.w"]
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains, taps, routers and selection biases away from their initial 1 /
+# 0.02 / 0, so that every parameter matters, the routing has no
+# near-ties and the bias moves some choices; the low-rank pairs' second
+# matrices large enough for the decay and the gate to move
+PERTURB = [((".scale",), moved(0.2)),
+           (("_router.w", "_b_colp.w"), drawn()),
+           (("_router.bias",), drawn(0.3)),
+           (("_conv.w", "_kda_fgb.w"), drawn(0.5))]
 
 
 def perturb(scope, seed):
-    """Gains, taps, routers and selection biases away from their initial
-    1 / 0.02 / 0, so that every parameter matters, the routing has no
-    near-ties and the bias moves some choices; the low-rank pairs' second
-    matrices large enough for the decay and the gate to move."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        shape = np.shape(scope.find_var(n))
-        if n.endswith(".scale"):
-            scope.set(n, jnp.asarray(
-                np.asarray(scope.find_var(n)) + 0.2 * r.randn(*shape),
-                jnp.float32))
-        if n.endswith("_router.w") or n.endswith("_b_colp.w"):
-            scope.set(n, jnp.asarray(r.randn(*shape), jnp.float32))
-        if n.endswith("_router.bias"):
-            scope.set(n, jnp.asarray(0.3 * r.randn(*shape), jnp.float32))
-        if n.endswith("_conv.w") or n.endswith("_kda_fgb.w"):
-            scope.set(n, jnp.asarray(0.5 * r.randn(*shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, optimizer=None, **over):
     cfg = M.KimiLinearConfig(**dict(TINY, **over), num_experts=16,
                              held_experts=HELD, kda_chunk=8)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = None
-        if optimizer is None:
-            grads = append_backward(model["loss"])
-        else:
-            optimizer().minimize(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed, optimizer))
 
 
 def test_model_loss_logits_and_every_parameters_gradient():
@@ -101,11 +81,8 @@ def test_model_loss_logits_and_every_parameters_gradient():
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["last_logits"], model["lb_loss"],
         *model["top_i"], *model["expert_rows"], *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, REF_CFG, feed["input_ids"],
-                           last=M.LAST_POSITIONS)
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, REF_CFG, feed))(w)
+    want, want_loss, want_g = reference(ref, w, REF_CFG, feed,
+                                        last=M.LAST_POSITIONS)
     # float32 on both sides; the same mathematics in another order (the
     # chunkwise rule with its halved decays against the recurrence,
     # sorted groups against a dense loop, fused projections)
@@ -212,23 +189,8 @@ KW = dict(norm_topk_prob=True, score="sigmoid", routed_scale=2.446,
 def moe_layer(held, shared, x, weights=None, seed=3):
     """(out, rows, {param: value}) of the family's topk_moe layer;
     ``weights``: the uncut layer's, cut to the held share."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        xv = layers.data("x", shape=list(x.shape), dtype="float32",
-                         append_batch_size=False)
-        out, _, _, rows, _ = layers.topk_moe(
-            xv, E, K, F, name="m", held=held, shared_d_ff=shared, **KW)
-    scope, exe = fluid.Scope(), fluid.Executor()
-    exe.run(startup, scope=scope)
-    for n, v in (weights or {}).items():
-        if n in scope.var_names():
-            if held and v.ndim == 3 and v.shape[0] == E:
-                v = v[held[0]:held[0] + held[1]]
-            scope.set(n, jnp.asarray(v))
-    w = snapshot(scope)
-    got = exe.run(main, feed={"x": x}, scope=scope, fetch_list=[out, rows])
-    return (*got, w)
+    return model_test.moe_layer(
+        E, K, F, held, x, weights, seed, shared_d_ff=shared, **KW)
 
 
 def test_the_32_shares_of_an_expert_layer_sum_to_the_uncut_layer():

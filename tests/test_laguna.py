@@ -11,6 +11,7 @@ another model; the expert layer as one chip's share. Gradients of the
 reference are ``jax.grad`` of its functions; the program's come from
 ``append_backward``."""
 
+import functools
 import math
 
 import jax
@@ -18,14 +19,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, highest, moved, reference, snapshot
 from paddle_tpu import analysis, flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.models import laguna as M
 from paddle_tpu.ops import attention_ops as ao
 from paddle_tpu.parallel import rope
 from perf.reference import laguna as ref
-from perf.reference.common import weights_from_scope
 
 # 16 positions, a window of 5; 3 and 4 query heads a key/value head;
 # yarn over 8 of a head's 16 features, its ramp 0, 0.2, 0.4, 0.6
@@ -61,45 +63,32 @@ SPARSE = ATTN + ["moe_norm.scale", "moe_router.w", "moe_gate.w", "moe_up.w",
 YARN = rope.Yarn(64.0, 4096.0, 64.0, 1.0, 1.4158883083359672)
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains and routers away from their initial 1 / 0.02, so that every
+# parameter matters and the routing has no near-ties; the attention
+# projections larger, so that what a query sees (and how its head is
+# gated) moves its output
+PERTURB = [((".scale",), moved(0.2)), (("_router.w",), drawn()),
+           (("_attn_qkvg_colp.w",), drawn(0.3))]
 
 
 def perturb(scope, seed):
-    """Gains and routers away from their initial 1 / 0.02, so that every
-    parameter matters and the routing has no near-ties; the attention
-    projections larger, so that what a query sees (and how its head is
-    gated) moves its output."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        shape = np.shape(scope.find_var(n))
-        if n.endswith(".scale"):
-            scope.set(n, jnp.asarray(
-                np.asarray(scope.find_var(n)) + 0.2 * r.randn(*shape),
-                jnp.float32))
-        if n.endswith("_router.w"):
-            scope.set(n, jnp.asarray(r.randn(*shape), jnp.float32))
-        if n.endswith("_attn_qkvg_colp.w"):
-            scope.set(n, jnp.asarray(0.3 * r.randn(*shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, optimizer=None, **overrides):
     cfg = M.LagunaConfig(**dict(TINY, **overrides), num_experts=16,
                          held_experts=HELD)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = None
-        if optimizer is None:
-            grads = append_backward(model["loss"])
-        else:
-            optimizer().minimize(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed, optimizer))
 
 
-def run_against_reference(main, startup, model, grads, feed):
+@functools.cache
+def run_against_reference():
+    """The one run the gradient test and the three controls read: the
+    program built at seed 11 on the batch of seed 9, and the reference
+    on the same weights."""
+    cfg, main, startup, model, grads = built(11)
+    feed = M.make_batch(cfg, 2, 16, seed=9)
+    assert analysis.lint(main) == [] and analysis.lint(startup) == []
     scope, exe = fluid.Scope(), fluid.Executor()
     exe.run(startup, scope=scope)
     perturb(scope, 12)
@@ -107,22 +96,14 @@ def run_against_reference(main, startup, model, grads, feed):
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["logits"], model["lb_loss"], *model["top_i"],
         *model["expert_rows"], *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, REF_CFG, feed["input_ids"])
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, REF_CFG, feed))(w)
-    return w, got, want, want_loss, want_g
+    return (grads, feed, w, got, *reference(ref, w, REF_CFG, feed))
 
 
 # --- the model against the reference ---------------------------------------
 
 
 def test_model_loss_logits_and_every_parameters_gradient():
-    cfg, main, startup, model, grads = built(11)
-    feed = M.make_batch(cfg, 2, 16, seed=9)
-    assert analysis.lint(main) == [] and analysis.lint(startup) == []
-    w, got, want, want_loss, want_g = run_against_reference(
-        main, startup, model, grads, feed)
+    grads, _, w, got, want, want_loss, want_g = run_against_reference()
     names = [p.name for p, _ in grads]
     # float32 on both sides; the same mathematics in another order
     np.testing.assert_allclose(got[0], want_loss, rtol=2e-6)
@@ -221,13 +202,11 @@ def test_a_reference_without_one_mechanism_is_another_model(control):
     """The controls: the reference with the gate at 1, the window
     dropped or yarn dropped must not agree with the program, by loss and
     by logits."""
-    cfg, main, startup, model, grads = built(11)
-    feed = M.make_batch(cfg, 2, 16, seed=9)
-    w, got, want, want_loss, _ = run_against_reference(
-        main, startup, model, grads, feed)
-    with jax.default_matmul_precision("highest"):
-        other = ref.forward(w, REF_CFG, feed["input_ids"], **{control: True})
-        other_loss = float(ref.loss(w, REF_CFG, feed, **{control: True}))
+    _, feed, w, got, _, _, _ = run_against_reference()
+    other = highest(lambda w_: ref.forward(
+        w_, REF_CFG, feed["input_ids"], **{control: True}))(w)
+    other_loss = float(highest(lambda w_: ref.loss(
+        w_, REF_CFG, feed, **{control: True}))(w))
     logit_err = np.abs(got[1] - np.asarray(other["logits"])).max()
     assert logit_err > 100 * 2e-5 and logit_err > 1e-2 * np.abs(got[1]).max()
     assert abs(float(got[0]) - other_loss) > 100 * 2e-6 * float(got[0])
@@ -488,10 +467,9 @@ def test_the_per_head_gates_gradient():
     got = dict(zip(names, exe.run(main, feed=feed, scope=scope,
                                   fetch_list=[g for _, g in grads])))
     ref_cfg = dict(REF_CFG, num_hidden_layers=2)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(lambda w_: ref.loss(w_, ref_cfg, feed))(w)
-        ungated = jax.grad(lambda w_: ref.loss(w_, ref_cfg, feed,
-                                               no_gate=True))(w)
+    want = highest(jax.grad(lambda w_: ref.loss(w_, ref_cfg, feed)))(w)
+    ungated = highest(jax.grad(lambda w_: ref.loss(
+        w_, ref_cfg, feed, no_gate=True)))(w)
     for i, h in ((0, 6), (1, 8)):
         n = f"blk{i}_attn_qkvg_colp.w"
         gate, gate_want = got[n][:, -h:], np.asarray(want[n])[:, -h:]
@@ -516,23 +494,9 @@ KW = dict(norm_topk_prob=True, shared_gate=False, score="sigmoid",
 def moe_layer(held, shared, x, weights=None, seed=3):
     """(out, rows, {param: value}) of a topk_moe layer as models/laguna
     builds it; ``weights``: the uncut layer's, cut to the held share."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        xv = layers.data("x", shape=list(x.shape), dtype="float32",
-                         append_batch_size=False)
-        out, _, _, rows, _ = layers.topk_moe(
-            xv, E, K, F, name="p_moe", held=held, shared_d_ff=shared, **KW)
-    scope, exe = fluid.Scope(), fluid.Executor()
-    exe.run(startup, scope=scope)
-    for n, v in (weights or {}).items():
-        if n in scope.var_names():
-            if held and v.ndim == 3 and v.shape[0] == E:
-                v = v[held[0]:held[0] + held[1]]
-            scope.set(n, jnp.asarray(v))
-    w = snapshot(scope)
-    got = exe.run(main, feed={"x": x}, scope=scope, fetch_list=[out, rows])
-    return (*got, w)
+    return model_test.moe_layer(
+        E, K, F, held, x, weights, seed, name="p_moe",
+        shared_d_ff=shared, **KW)
 
 
 def test_shares_of_an_expert_layer_sum_to_the_uncut_layer():

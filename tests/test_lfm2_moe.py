@@ -13,12 +13,16 @@ router; that each of the reference's ablations is another model. The
 program's gradients come from ``append_backward``. The chip's run is
 chip_smoke.py's ``sconv`` phase."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, moved, reference, snapshot
 from paddle_tpu import analysis, flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.models import lfm2_moe as M
@@ -27,7 +31,6 @@ from paddle_tpu.param_attr import ParamAttr
 from paddle_tpu.parallel import causal_conv as cc
 from perf import flops_lfm2moe
 from perf.reference import lfm2moe as ref
-from perf.reference.common import weights_from_scope
 
 BF, F32 = jnp.bfloat16, jnp.float32
 
@@ -280,36 +283,21 @@ def ref_cfg(layout):
     return cfg
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains and the routers' selection biases away from their initial
+# values, so that every parameter matters; the projections larger, so
+# that what a query sees and what the taps keep move the output
+PERTURB = [((".scale",), moved(0.2)), (("_router.bias",), drawn(0.1)),
+           (("_colp.w", "_rowp.w", "_conv.w", "_gate.w", "_up.w", "_down.w",
+             "_router.w", "_tok_emb.w"), drawn(0.3))]
 
 
 def perturb(scope, seed):
-    """Gains and the routers' selection biases away from their initial
-    values, so that every parameter matters; the projections larger, so
-    that what a query sees and what the taps keep move the output."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        v = np.asarray(scope.find_var(n))
-        if n.endswith(".scale"):
-            scope.set(n, jnp.asarray(v + 0.2 * r.randn(*v.shape),
-                                     jnp.float32))
-        if n.endswith("_router.bias"):
-            scope.set(n, jnp.asarray(0.1 * r.randn(*v.shape), jnp.float32))
-        if n.endswith(("_colp.w", "_rowp.w", "_conv.w", "_gate.w", "_up.w",
-                       "_down.w", "_router.w", "_tok_emb.w")):
-            scope.set(n, jnp.asarray(0.3 * r.randn(*v.shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, **layout):
     cfg = M.Lfm2MoeConfig(**TINY, **layout)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = append_backward(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed))
 
 
 def block_parameters(kind, dense):
@@ -342,10 +330,7 @@ def test_model_loss_logits_and_every_parameters_gradient(layout, blocks):
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["logits"], *model["top_i"],
         *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, rcfg, feed["input_ids"])
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, rcfg, feed))(w)
+    want, want_loss, want_g = reference(ref, w, rcfg, feed)
     names = [p.name for p, _ in grads]
     expected = [M.TABLE, "final_norm.scale"]      # the tied table: once
     expected += [f"blk{i}_{s}" for i, k, dense in blocks
@@ -472,16 +457,24 @@ def test_the_four_held_shares_add_up_to_the_uncut_layer():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("ablation", ref.ABLATIONS)
-def test_an_ablated_reference_is_another_model(ablation):
-    cfg, main, startup, model, _ = built(5, **CUT)
+@functools.cache
+def unablated():
+    """(weights, ids, the reference's logits) every ablation is held
+    against: one startup and one forward for all of them."""
+    cfg, _, startup, _, _ = built(5, **CUT)
     scope, exe = fluid.Scope(), fluid.Executor()
     exe.run(startup, scope=scope)
     perturb(scope, 4)
     w = snapshot(scope)
     ids = M.make_batch(cfg, 2, 16, seed=1)["input_ids"]
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(ref.forward(w, ref_cfg(CUT), ids)["logits"])
+        return w, ids, np.asarray(ref.forward(w, ref_cfg(CUT), ids)["logits"])
+
+
+@pytest.mark.parametrize("ablation", ref.ABLATIONS)
+def test_an_ablated_reference_is_another_model(ablation):
+    w, ids, want = unablated()
+    with jax.default_matmul_precision("highest"):
         other = np.asarray(ref.forward(w, ref_cfg(CUT), ids,
                                        ablate=ablation)["logits"])
     scale = np.sqrt(np.mean(want ** 2))

@@ -44,17 +44,23 @@ def op_ins(q, k, v, g, beta):
                                      (150, 64), (150, 16), (11, 64)])
 def test_chunked_form_is_the_recurrence(t, chunk):
     q, k, v, g, beta, do = operands(t)
-    with jax.default_matmul_precision("highest"):
+
+    @jax.jit    # (op by op each case is some hundreds of executables)
+    def both(q, k, v, g, beta, do):
         out = L._gated_delta_rule(op_ins(q, k, v, g, beta), {"chunk": chunk})
         want, vjp = jax.vjp(L.recurrent_gated_delta_rule, q, k, v, g, beta)
         grads = L._gated_delta_rule_grad(
             {**op_ins(q, k, v, g, beta), "States": out["States"],
              "GRAD::Out": [do]}, {"chunk": chunk})
+        return out, want, grads, vjp(do)
+
+    with jax.default_matmul_precision("highest"):
+        out, want, grads, wants = both(q, k, v, g, beta, do)
     # a ragged last chunk is PADDED (zeros behind the last position
     # write, forget and read nothing): one state a chunk, the last too
     assert out["States"][0].shape[0] == -(-t // chunk)
     np.testing.assert_allclose(out["Out"][0], want, **TOL)
-    for slot, w in zip(("Q", "K", "V", "G", "Beta"), vjp(do)):
+    for slot, w in zip(("Q", "K", "V", "G", "Beta"), wants):
         np.testing.assert_allclose(grads[f"GRAD::{slot}"][0], w,
                                    err_msg=slot, **TOL)
 

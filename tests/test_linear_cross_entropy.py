@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import flags, layers, monitor
+from paddle_tpu import flags, layers
 from paddle_tpu.core import autodiff
 from paddle_tpu.core.registry import OpDef
 from paddle_tpu.models import bert as B
@@ -347,11 +347,9 @@ def test_berts_step_is_the_parent_graphs(seed, labels, monkeypatch):
 
 @pytest.fixture
 def telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": True})
     yield
     flags.set_flags({"telemetry": False})
-    monitor.reset()
 
 
 def test_dispatch_counter_rows(telemetry):

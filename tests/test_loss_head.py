@@ -15,7 +15,7 @@ import pytest
 from jax.extend.core import Literal
 
 import paddle_tpu as fluid
-from paddle_tpu import flags, layers, monitor
+from paddle_tpu import flags, layers
 from paddle_tpu.core import autodiff
 from paddle_tpu.core.registry import OpDef
 from paddle_tpu.ops import nn_ops
@@ -221,11 +221,9 @@ def test_hard_label_pair_holds_no_gather_and_no_zero_cotangent(
 
 @pytest.fixture
 def telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": True})
     yield
     flags.set_flags({"telemetry": False})
-    monitor.reset()
 
 
 @pytest.mark.parametrize("case, reads_softmax, rows", [

@@ -8,6 +8,7 @@ tile against the recurrence; the tile picker; the dispatch counter."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
@@ -31,12 +32,18 @@ def operands(b, t, heads, p, groups, n, dtype, seed=0):
 
 
 def op(ins, dy, **attrs):
-    wrapped = {k: [v] for k, v in ins.items()}
-    out = S._mamba2_scan(wrapped, attrs)
-    grads = S._mamba2_scan_grad(
-        {**wrapped, "Out": out["Out"], "States": out["States"],
-         "GRAD::Out": [dy]}, attrs)
-    return out["Out"][0], {k: v[0] for k, v in grads.items()}, out["States"][0]
+    """(Out, {GRAD::slot}, States) of the op and its grad op: one jitted
+    computation (a fresh one a call: the hooks are read as it is traced)."""
+    def both(ins, dy):
+        wrapped = {k: [v] for k, v in ins.items()}
+        out = S._mamba2_scan(wrapped, attrs)
+        grads = S._mamba2_scan_grad(
+            {**wrapped, "Out": out["Out"], "States": out["States"],
+             "GRAD::Out": [dy]}, attrs)
+        return (out["Out"][0], {k: v[0] for k, v in grads.items()},
+                out["States"][0])
+
+    return jax.jit(both)(ins, dy)
 
 
 def rel(a, b):

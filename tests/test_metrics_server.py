@@ -15,13 +15,11 @@ from paddle_tpu import flags, layers, monitor
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": False, "step_log_path": "",
                      "metrics_dump_path": "", "compile_report_dir": "",
                      "metrics_port": 0})
     yield
     monitor.stop_server()
-    monitor.reset()
     flags.set_flags({"telemetry": False, "step_log_path": "",
                      "metrics_dump_path": "", "compile_report_dir": "",
                      "metrics_port": 0})

@@ -406,6 +406,16 @@ def as_numpy(arrays):
     return {k: np.asarray(a, np.float32) for k, a in arrays.items()}
 
 
+@functools.cache
+def kernel_step(gated, dtype, build):
+    """``kernel_layer`` as ONE executable a (gated, dtype, build): the
+    live count is a device value, so the six routings of a pair run
+    what the first of them traced, under the ``build``'s hooks (what
+    ``test_four_routings_run_one_executable...`` holds)."""
+    return jax.jit(lambda v, top_w, top_i: kernel_layer(
+        v, top_w, top_i, gated, jnp.dtype(dtype)))
+
+
 @pytest.fixture
 def hooked(monkeypatch):
     """The kernels through the interpreter, and with them the hook:
@@ -446,11 +456,11 @@ def test_no_buffer_is_filled_and_the_layer_is_the_filled_builds_to_the_bit(
     tm = moe_ops._gm.row_tile(KM, K_COUNT, dtype, live_rows=KM // 2)
     assert tm == (128 if dtype == "bfloat16" else None)
     assert kernel_window() % 128 == 0
-    got, buffers = map(as_numpy, kernel_layer(v, top_w, top_i, gated,
-                                              jnp.dtype(dtype)))
+    got, buffers = map(as_numpy, kernel_step(gated, dtype, "unfilled")(
+        v, top_w, top_i))
     filled_build(monkeypatch)
-    want, want_buffers = map(as_numpy, kernel_layer(v, top_w, top_i, gated,
-                                                    jnp.dtype(dtype)))
+    want, want_buffers = map(as_numpy, kernel_step(gated, dtype, "filled")(
+        v, top_w, top_i))
     assert sorted(got) == sorted(want) and len(got) == 3 + 3 * (2 + gated)
     for key in want:
         assert np.isfinite(got[key]).all(), key
@@ -488,7 +498,8 @@ def test_four_routings_run_one_executable_where_nothing_is_filled(
     filled_build(monkeypatch)
     for live, handed in zip(lives, got):
         top_i, top_w = kernel_routing(live, seed=live)
-        want = as_numpy(kernel_layer(v, top_w, top_i, True, bf)[0])
+        want = as_numpy(kernel_step(True, "bfloat16", "filled")(
+            v, top_w, top_i)[0])
         handed = as_numpy(handed)
         for key in want:
             assert np.isfinite(handed[key]).all(), (live, key)

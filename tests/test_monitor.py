@@ -17,11 +17,9 @@ from paddle_tpu import flags, layers, monitor, profiler
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": False, "step_log_path": "",
                      "metrics_dump_path": ""})
     yield
-    monitor.reset()
     flags.set_flags({"telemetry": False, "step_log_path": "",
                      "metrics_dump_path": ""})
 

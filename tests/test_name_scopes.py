@@ -356,7 +356,6 @@ def telemetry_flags():
     keep = {k: flags.get_flag(k) for k in ("telemetry", "step_phases")}
     yield
     flags.set_flags(keep)
-    monitor.reset()
 
 
 def test_spans_reach_the_profilers_trace_with_telemetry_on(

@@ -8,18 +8,20 @@ shares' routed parts, with the shared expert counted once, are the uncut
 layer's output); the reference's ablations each change what it
 computes. The program's gradients come from ``append_backward``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, moved, reference, snapshot
 from paddle_tpu import analysis, layers
-from paddle_tpu.backward import append_backward
 from paddle_tpu.models import nemotron_h as M
 from perf import flops_nemotronh
 from perf.reference import nemotronh as ref
-from perf.reference.common import weights_from_scope
 
 TINY = dict(vocab_size=50, hidden_size=32, mamba_num_heads=4,
             mamba_head_dim=8, n_groups=2, ssm_state_size=8, chunk_size=8,
@@ -43,41 +45,25 @@ def ref_cfg(layout):
     return cfg
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains, biases, D, A_log and the routers' selection biases away from
+# their initial values, so that every parameter matters; the projections
+# larger, so that what a query sees and what a state keeps move the
+# output; step sizes near 0.3, so that a state of 16 positions decays
+# within the row
+PERTURB = [((".scale", "_conv.b", "_mamba_d", "_mamba_a_log"), moved(0.2)),
+           (("_router.bias",), drawn(0.1)),
+           (("_mamba_dt.b",), lambda v, r: -1.0 + 0.2 * r.randn(*v.shape)),
+           (("_colp.w", "_rowp.w", "_conv.w", "_up.w", "_down.w",
+             "_router.w", "_tok_emb.w"), drawn(0.3))]
 
 
 def perturb(scope, seed):
-    """Gains, biases, D, A_log and the routers' selection biases away
-    from their initial values, so that every parameter matters; the
-    projections larger, so that what a query sees and what a state keeps
-    move the output; step sizes near 0.3, so that a state of 16
-    positions decays within the row."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        v = np.asarray(scope.find_var(n))
-        if n.endswith((".scale", "_conv.b", "_mamba_d", "_mamba_a_log")):
-            scope.set(n, jnp.asarray(v + 0.2 * r.randn(*v.shape),
-                                     jnp.float32))
-        if n.endswith("_router.bias"):
-            scope.set(n, jnp.asarray(0.1 * r.randn(*v.shape), jnp.float32))
-        if n.endswith("_mamba_dt.b"):
-            scope.set(n, jnp.asarray(-1.0 + 0.2 * r.randn(*v.shape),
-                                     jnp.float32))
-        if n.endswith(("_colp.w", "_rowp.w", "_conv.w", "_up.w", "_down.w",
-                       "_router.w", "_tok_emb.w")):
-            scope.set(n, jnp.asarray(0.3 * r.randn(*v.shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, **layout):
     cfg = M.NemotronHConfig(**TINY, **layout)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = append_backward(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed))
 
 
 BLOCK = {
@@ -111,10 +97,7 @@ def test_model_loss_logits_and_every_parameters_gradient(layout, kinds):
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["logits"], *model["top_i"],
         *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, rcfg, feed["input_ids"])
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, rcfg, feed))(w)
+    want, want_loss, want_g = reference(ref, w, rcfg, feed)
     names = [p.name for p, _ in grads]
     expected = ["nemotronh_tok_emb.w", "final_norm.scale", "lm_head_colp.w"]
     expected += [f"blk{i}_{s}" for i, k in cfg.blocks for s in BLOCK[k]]
@@ -217,16 +200,24 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("ablation", ref.ABLATIONS)
-def test_an_ablated_reference_is_another_model(ablation):
-    cfg, main, startup, model, _ = built(5, **CUT)
+@functools.cache
+def unablated():
+    """(weights, ids, the reference's logits) every ablation is held
+    against: one startup and one forward for all of them."""
+    cfg, _, startup, _, _ = built(5, **CUT)
     scope, exe = fluid.Scope(), fluid.Executor()
     exe.run(startup, scope=scope)
     perturb(scope, 4)
     w = snapshot(scope)
     ids = M.make_batch(cfg, 2, 16, seed=1)["input_ids"]
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(ref.forward(w, ref_cfg(CUT), ids)["logits"])
+        return w, ids, np.asarray(ref.forward(w, ref_cfg(CUT), ids)["logits"])
+
+
+@pytest.mark.parametrize("ablation", ref.ABLATIONS)
+def test_an_ablated_reference_is_another_model(ablation):
+    w, ids, want = unablated()
+    with jax.default_matmul_precision("highest"):
         other = np.asarray(ref.forward(w, ref_cfg(CUT), ids,
                                        ablate=ablation)["logits"])
     scale = np.sqrt(np.mean(want ** 2))

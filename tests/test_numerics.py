@@ -24,13 +24,11 @@ from paddle_tpu import (
 
 @pytest.fixture(autouse=True)
 def _clean_numerics():
-    monitor.reset()
     flags.set_flags({"telemetry": False, "numerics": False,
                      "numerics_every_n_steps": 1, "numerics_vars": "",
                      "check_nan_inf": False, "step_log_path": ""})
     yield
     monitor.stop_server()
-    monitor.reset()
     flags.set_flags({"telemetry": False, "numerics": False,
                      "numerics_every_n_steps": 1, "numerics_vars": "",
                      "check_nan_inf": False, "step_log_path": ""})

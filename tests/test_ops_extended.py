@@ -512,9 +512,12 @@ def test_multiprocess_reader_interleaves_all_samples():
         decorator.multiprocess_reader([])
 
 
-def test_multiprocess_reader_ndarray_samples_and_errors():
-    """Bare ndarray samples work, worker exceptions surface, and early
-    exit doesn't stall (code-review findings, round 2)."""
+def test_multiprocess_reader_ndarray_samples_and_errors(monkeypatch):
+    """Bare ndarray samples work, worker exceptions surface, a killed
+    worker is an error while its sibling lives, and early exit doesn't
+    stall (code-review findings, round 2)."""
+    import os
+    import signal
     import time
 
     from paddle_tpu.reader import decorator
@@ -532,6 +535,25 @@ def test_multiprocess_reader_ndarray_samples_and_errors():
 
     with pytest.raises(RuntimeError, match="worker failed"):
         list(decorator.multiprocess_reader([bad_reader])())
+
+    # a child killed before its end beside one that lives: the error the
+    # docstring promises, two polls after the death and not when the
+    # sleeper ends (30 s)
+    def killed_reader():
+        yield np.zeros(2)
+        os.kill(os.getpid(), signal.SIGKILL)
+
+    def sleeping_reader():
+        time.sleep(30.0)
+        yield np.zeros(2)
+
+    monkeypatch.setattr(decorator, "_POLL_S", 0.5)
+    t0 = time.perf_counter()
+    with pytest.raises(RuntimeError, match="process 0 of 2 died without "
+                                           "an end/error.*code -9"):
+        list(decorator.multiprocess_reader([killed_reader,
+                                            sleeping_reader])())
+    assert time.perf_counter() - t0 < 5.0
 
     def big_reader():
         for i in range(100000):

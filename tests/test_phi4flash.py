@@ -15,16 +15,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, highest, moved, reference, snapshot
 from paddle_tpu import analysis, flags
-from paddle_tpu.backward import append_backward
 from paddle_tpu.framework import grad_var_name
 from paddle_tpu.models import phi4flash as M
 from paddle_tpu.ops import attention_ops
 from paddle_tpu.parallel import flash_attention as fa
 from perf import flops_phi4flash
 from perf.reference import phi4flash as ref
-from perf.reference.common import weights_from_scope
 
 TINY = dict(vocab_size=50, hidden_size=32, num_attention_heads=4,
             num_key_value_heads=2, intermediate_size=48, sliding_window=5,
@@ -33,35 +33,22 @@ CUT = dict(num_hidden_layers=6, first_layer=14, model_layers=32)
 REF_BASE = dict(TINY, layer_norm_eps=1e-5, mb_per_layer=2)
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains, biases, D and the lambda vectors away from their initial
+# values, so that every parameter matters; the projections larger, so
+# that what a query sees and what a state keeps move the output
+PERTURB = [(lambda n: n.endswith((".scale", ".bias", ".b", "_ssm_d"))
+            or "_lambda_" in n, moved(0.2)),
+           (lambda n: n.endswith(("_colp.w", "_rowp.w", "_ssm_dt.w",
+                                  "_conv.w")) or n == M.TABLE, drawn(0.3))]
 
 
 def perturb(scope, seed):
-    """Gains, biases, D and the lambda vectors away from their initial
-    values, so that every parameter matters; the projections larger, so
-    that what a query sees and what a state keeps move the output."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        v = np.asarray(scope.find_var(n))
-        if n.endswith((".scale", ".bias", ".b", "_ssm_d")) \
-                or "_lambda_" in n:
-            scope.set(n, jnp.asarray(v + 0.2 * r.randn(*v.shape),
-                                     jnp.float32))
-        if n.endswith(("_colp.w", "_rowp.w", "_ssm_dt.w", "_conv.w")) \
-                or n == M.TABLE:
-            scope.set(n, jnp.asarray(0.3 * r.randn(*v.shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, **layout):
     cfg = M.Phi4FlashConfig(**TINY, **layout)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = append_backward(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed))
 
 
 def ref_cfg(layout):
@@ -75,11 +62,7 @@ def run_against_reference(main, startup, model, grads, feed, cfg, extra=()):
     w = snapshot(scope)
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["logits"], *(g for _, g in grads), *extra])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, cfg, feed["input_ids"])
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, cfg, feed))(w)
-    return w, got, want, want_loss, want_g
+    return (w, got, *reference(ref, w, cfg, feed))
 
 
 LAYER = {
@@ -199,33 +182,35 @@ def test_what_crosses_layers_sums_its_readers_gradients(monkeypatch):
     w, got, _, _, _ = run_against_reference(main, startup, model, grads,
                                             feed, rcfg, extra)
     got = dict(zip(shared, got[-len(shared):]))
+    # (jax's arrays: a traced batch of ids indexes the table under jit)
+    w = {k: jnp.asarray(v) for k, v in w.items()}
     zeros = {"memory": np.zeros((2, 16, 64), np.float32),
              "k1": np.zeros((2, 1, 16, 8), np.float32),
              "k2": np.zeros((2, 1, 16, 8), np.float32),
              "v": np.zeros((2, 1, 16, 16), np.float32)}
     loss = lambda added, cfg_=rcfg: loss_with_added(
         monkeypatch, w, cfg_, feed, added, memory=6, full=7)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(loss)(zeros)
-        for name, at in (("memory", (1, 7, 20)), ("k1", (0, 0, 5, 3)),
-                         ("k2", (1, 0, 2, 6)), ("v", (0, 0, 9, 11))):
-            assert got[name].shape == zeros[name].shape
-            scale = np.abs(want[name]).max()
-            assert scale > 0
-            np.testing.assert_allclose(got[name], want[name], rtol=3e-3,
-                                       atol=2e-4 * scale, err_msg=name)
-            eps = 0.05
-            bump = {k: v.copy() for k, v in zeros.items()}
-            bump[name][at] = eps
-            up = float(loss(bump))
-            bump[name][at] = -eps
-            fd = (up - float(loss(bump))) / (2 * eps)
-            assert fd == pytest.approx(float(got[name][at]), rel=0.05,
-                                       abs=0.02 * scale), name
-        # one reader alone is not the sum: the last cross layer's part
-        # of K's gradient is missing when the others are cut off
-        alone = jax.grad(lambda a: loss(
-            a, dict(rcfg, num_hidden_layers=10, model_layers=12)))(zeros)
+    want = highest(jax.grad(loss))(zeros)
+    bumped = highest(loss)      # (one executable for the eight losses)
+    for name, at in (("memory", (1, 7, 20)), ("k1", (0, 0, 5, 3)),
+                     ("k2", (1, 0, 2, 6)), ("v", (0, 0, 9, 11))):
+        assert got[name].shape == zeros[name].shape
+        scale = np.abs(want[name]).max()
+        assert scale > 0
+        np.testing.assert_allclose(got[name], want[name], rtol=3e-3,
+                                   atol=2e-4 * scale, err_msg=name)
+        eps = 0.05
+        bump = {k: v.copy() for k, v in zeros.items()}
+        bump[name][at] = eps
+        up = float(bumped(bump))
+        bump[name][at] = -eps
+        fd = (up - float(bumped(bump))) / (2 * eps)
+        assert fd == pytest.approx(float(got[name][at]), rel=0.05,
+                                   abs=0.02 * scale), name
+    # one reader alone is not the sum: the last cross layer's part of
+    # K's gradient is missing when the others are cut off
+    alone = highest(jax.grad(lambda a: loss(
+        a, dict(rcfg, num_hidden_layers=10, model_layers=12))))(zeros)
     assert np.abs(alone["k1"] - want["k1"]).max() > 1e-3 * np.abs(
         want["k1"]).max()
 
@@ -250,10 +235,9 @@ def test_the_tied_tables_gradient_is_the_gathers_plus_the_heads():
     names = [p.name for p, _ in grads]
     assert names.count(M.TABLE) == 1
     table_grad = got[2 + names.index(M.TABLE)]
-    with jax.default_matmul_precision("highest"):
-        split = lambda rows, head: ref.loss(
-            dict(w, **{M.TABLE: SplitTable(rows, head)}), rcfg, feed)
-        gather, head = jax.grad(split, (0, 1))(w[M.TABLE], w[M.TABLE])
+    split = lambda rows, head: ref.loss(
+        dict(w, **{M.TABLE: SplitTable(rows, head)}), rcfg, feed)
+    gather, head = highest(jax.grad(split, (0, 1)))(w[M.TABLE], w[M.TABLE])
     scale = np.abs(table_grad).max()
     for part in (gather, head):
         assert np.abs(part).max() > 1e-3 * scale

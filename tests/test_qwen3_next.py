@@ -12,13 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, moved, reference, snapshot
 from paddle_tpu import analysis, flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.models import qwen3_next as M
 from paddle_tpu.parallel import grouped_matmul as gm
 from perf.reference import qwen3next as ref
-from perf.reference.common import weights_from_scope
 
 TINY = dict(vocab_size=50, hidden_size=32, num_hidden_layers=4,
             full_attention_interval=4, num_attention_heads=4,
@@ -34,36 +35,21 @@ REF_CFG = dict(TINY, num_experts=HELD[1], held_first=HELD[0],
                router_experts=16)
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains, gates and routers away from their initial 0 / 1 / 0.02, so that
+# every parameter matters and the routing has no near-ties
+PERTURB = [((".scale", "_dt_bias"), moved(0.2)), (("_router.w",), drawn()),
+           (("_conv.w", "_shared_mix.w"), drawn(0.5))]
 
 
 def perturb(scope, seed):
-    """Gains, gates and routers away from their initial 0 / 1 / 0.02, so
-    that every parameter matters and the routing has no near-ties."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        shape = np.shape(scope.find_var(n))
-        if n.endswith(".scale") or n.endswith("_dt_bias"):
-            scope.set(n, jnp.asarray(
-                np.asarray(scope.find_var(n)) + 0.2 * r.randn(*shape),
-                jnp.float32))
-        if n.endswith("_router.w"):
-            scope.set(n, jnp.asarray(r.randn(*shape), jnp.float32))
-        if n.endswith("_conv.w") or n.endswith("_shared_mix.w"):
-            scope.set(n, jnp.asarray(0.5 * r.randn(*shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def test_model_loss_logits_and_every_parameters_gradient():
     cfg = M.Qwen3NextConfig(**TINY, num_experts=16, held_experts=HELD,
                             gdn_chunk=8)
     feed = M.make_batch(cfg, 2, 16, seed=9)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 11
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = append_backward(model["loss"])
+    main, startup, model, grads = model_test.built(M, cfg, 11)
     assert analysis.lint(main) == [] and analysis.lint(startup) == []
     scope, exe = fluid.Scope(), fluid.Executor()
     exe.run(startup, scope=scope)
@@ -73,11 +59,8 @@ def test_model_loss_logits_and_every_parameters_gradient():
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["last_logits"], model["lb_loss"],
         *model["top_i"], *model["expert_rows"], *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, REF_CFG, feed["input_ids"],
-                           last=M.LAST_POSITIONS)
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, REF_CFG, feed))(w)
+    want, want_loss, want_g = reference(ref, w, REF_CFG, feed,
+                                        last=M.LAST_POSITIONS)
     # float32 on both sides; the same mathematics in another order (the
     # chunkwise delta rule against the recurrence, sorted groups against
     # a dense loop, fused projections): sums over 8..64 terms
@@ -146,27 +129,9 @@ N, D, F, E, K = 15, 8, 6, 16, 4
 def moe_layer(held, shared, x, weights=None, seed=3):
     """(out, rows, d loss / d x, {param: value}) of a topk_moe layer;
     ``weights``: the uncut layer's, cut to the held share."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        xv = layers.data("x", shape=list(x.shape), dtype="float32",
-                         append_batch_size=False)
-        xv.stop_gradient = False
-        out, _, _, rows, _ = layers.topk_moe(
-            xv, E, K, F, norm_topk_prob=True, name="m", held=held,
-            shared_d_ff=shared)
-        append_backward(layers.reduce_sum(layers.square(out)))
-    scope, exe = fluid.Scope(), fluid.Executor()
-    exe.run(startup, scope=scope)
-    for n, v in (weights or {}).items():
-        if n in scope.var_names():
-            if held and v.ndim == 3 and v.shape[0] == E:
-                v = v[held[0]:held[0] + held[1]]
-            scope.set(n, jnp.asarray(v))
-    w = snapshot(scope)
-    got = exe.run(main, feed={"x": x}, scope=scope,
-                  fetch_list=[out, rows, "x@GRAD"])
-    return (*got, w)
+    return model_test.moe_layer(
+        E, K, F, held, x, weights, seed, grad=True,
+        shared_d_ff=shared, norm_topk_prob=True)
 
 
 def test_shares_of_an_expert_layer_sum_to_the_uncut_layer():

@@ -17,10 +17,8 @@ from paddle_tpu.reader.pipeline import DeviceLoader
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": False})
     yield
-    monitor.reset()
     flags.set_flags({"telemetry": False})
 
 

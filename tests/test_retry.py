@@ -16,7 +16,6 @@ from paddle_tpu import flags, monitor, retry
 
 @pytest.fixture(autouse=True)
 def _clean():
-    monitor.reset()
     yield
     flags.set_flags({"telemetry": False,
                      "retry_base_delay_ms": 100,

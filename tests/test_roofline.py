@@ -18,7 +18,6 @@ from paddle_tpu import debugger
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     _defaults = {
         "telemetry": False, "step_log_path": "", "compile_report_dir": "",
         "metrics_port": 0, "step_phases": True, "step_phases_every_n": 16,
@@ -29,7 +28,6 @@ def _clean_telemetry():
     flags.set_flags(_defaults)
     yield
     monitor.stop_server()
-    monitor.reset()
     flags.set_flags(_defaults)
 
 

@@ -17,13 +17,13 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from model_test import highest, reference, snapshot
 from paddle_tpu import flags, layers, monitor
 from paddle_tpu.models import sdar as M
 from paddle_tpu.ops import attention_ops
 from paddle_tpu.parallel import flash_attention as fa
 from paddle_tpu.parallel import rope
 from perf.reference import sdar as ref
-from perf.reference.common import weights_from_scope
 
 L, B = 24, 4
 
@@ -72,12 +72,11 @@ def test_loss_and_gradients_agree_with_the_plain_reference(held):
     names = ["sdar_tok_emb.w", "blk0_attn_qkv_colp.w", "blk1_moe_router.w",
              "blk2_moe_down.w", "blk1_attn_knorm.scale", "lm_head_colp.w",
              "final_norm.scale", "blk2_attn_out_rowp.w"]
-    w = {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+    w = snapshot(scope)
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["lm_loss"]] + [f"{n}@GRAD" for n in names])
-    with jax.default_matmul_precision("highest"):
-        want, grads = jax.value_and_grad(
-            lambda w_: ref.loss(w_, as_file(cfg), feed))(w)
+    want, grads = highest(jax.value_and_grad(
+        lambda w_: ref.loss(w_, as_file(cfg), feed)))(w)
     assert float(got[0]) == pytest.approx(float(want), rel=2e-5)
     assert float(got[1]) > 1.0     # ln(50) a masked position, 1 / p each
     for name, g in zip(names, got[2:]):
@@ -114,7 +113,7 @@ def hidden_states(cfg, ids):
     the reference's own forward on the program's weights, and the
     program's logits beside them."""
     main, model, scope, exe = built(cfg)
-    w = {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+    w = snapshot(scope)
     file = as_file(cfg)
     feed = {"input_ids": ids,
             "labels": np.full((ids.shape[0], L), M.IGNORE_INDEX, np.int64),
@@ -457,7 +456,7 @@ def test_every_parameter_s_gradient_agrees_with_the_plain_reference():
     cfg = tiny(num_hidden_layers=2)
     main, model, scope, exe = built(cfg, train=True)
     feed = M.make_batch(cfg, 2, L, seed=6)
-    w = {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+    w = snapshot(scope)
     # gains off 1, as a run's start state has them
     r = np.random.RandomState(0)
     for name in PARAMETERS:
@@ -467,10 +466,8 @@ def test_every_parameter_s_gradient_agrees_with_the_plain_reference():
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["last_logits"]]
         + [f"{n}@GRAD" for n in PARAMETERS])
-    with jax.default_matmul_precision("highest"):
-        want, grads = jax.value_and_grad(
-            lambda w_: ref.loss(w_, as_file(cfg), feed))(w)
-        logits = ref.forward(w, as_file(cfg), feed["input_ids"])["logits"]
+    want_out, want, grads = reference(ref, w, as_file(cfg), feed)
+    logits = want_out["logits"]
     assert float(got[0]) == pytest.approx(float(want), rel=2e-5)
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(logits),
                                rtol=2e-3, atol=2e-5)

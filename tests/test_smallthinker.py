@@ -14,13 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import model_test
 import paddle_tpu as fluid
+from model_test import drawn, highest, moved, reference, snapshot
 from paddle_tpu import analysis, flags, layers, monitor
 from paddle_tpu.backward import append_backward
 from paddle_tpu.models import smallthinker as M
 from paddle_tpu.ops import moe_ops
 from perf.reference import smallthinker as ref
-from perf.reference.common import weights_from_scope
 
 # 16 positions, a window of 5: window layers forget from position 5 on
 TINY = dict(vocab_size=50, hidden_size=32, num_hidden_layers=4,
@@ -38,41 +39,21 @@ LAYER = ["attn_norm.scale", "attn_qkv_colp.w", "attn_out_rowp.w",
          "moe_down.w"]
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains and routers away from their initial 1 / 0.02, so that every
+# parameter matters and the routing has no near-ties; the attention
+# projections larger, so that what a query sees moves its output
+PERTURB = [((".scale",), moved(0.2)), (("_router.w",), drawn()),
+           (("_attn_qkv_colp.w",), drawn(0.3))]
 
 
 def perturb(scope, seed):
-    """Gains and routers away from their initial 1 / 0.02, so that every
-    parameter matters and the routing has no near-ties; the attention
-    projections larger, so that what a query sees moves its output."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        shape = np.shape(scope.find_var(n))
-        if n.endswith(".scale"):
-            scope.set(n, jnp.asarray(
-                np.asarray(scope.find_var(n)) + 0.2 * r.randn(*shape),
-                jnp.float32))
-        if n.endswith("_router.w"):
-            scope.set(n, jnp.asarray(r.randn(*shape), jnp.float32))
-        if n.endswith("_attn_qkv_colp.w"):
-            scope.set(n, jnp.asarray(0.3 * r.randn(*shape), jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, optimizer=None, **overrides):
     cfg = M.SmallThinkerConfig(**dict(TINY, **overrides),
                                moe_num_primary_experts=16, held_experts=HELD)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = None
-        if optimizer is None:
-            grads = append_backward(model["loss"])
-        else:
-            optimizer().minimize(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed, optimizer))
 
 
 def run_against_reference(main, startup, model, grads, feed):
@@ -83,11 +64,7 @@ def run_against_reference(main, startup, model, grads, feed):
     got = exe.run(main, feed=feed, scope=scope, fetch_list=[
         model["loss"], model["logits"], model["lb_loss"], *model["top_i"],
         *model["expert_rows"], *(g for _, g in grads)])
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, REF_CFG, feed["input_ids"])
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, REF_CFG, feed))(w)
-    return w, got, want, want_loss, want_g
+    return (w, got, *reference(ref, w, REF_CFG, feed))
 
 
 def test_model_loss_logits_and_every_parameters_gradient(monkeypatch):
@@ -132,8 +109,7 @@ def test_model_loss_logits_and_every_parameters_gradient(monkeypatch):
     route = ref.route
     monkeypatch.setattr(ref, "route", lambda r, *a, **k: route(
         jax.lax.stop_gradient(r), *a, **k))
-    with jax.default_matmul_precision("highest"):
-        cut_g = jax.grad(lambda w_: ref.loss(w_, REF_CFG, feed))(w)
+    cut_g = highest(jax.grad(lambda w_: ref.loss(w_, REF_CFG, feed)))(w)
     for i in range(4):
         n = f"blk{i}_attn_norm.scale"
         scale = np.abs(want_g[n]).max()
@@ -186,8 +162,8 @@ def test_a_program_without_the_window_fails_against_the_reference():
     logit_err = np.abs(got[1] - np.asarray(want["logits"])).max()
     assert logit_err > 100 * 2e-5 and logit_err > 1e-2 * np.abs(got[1]).max()
     assert abs(float(got[0]) - float(want_loss)) > 100 * 2e-6 * float(got[0])
-    with jax.default_matmul_precision("highest"):
-        dropped = ref.forward(w, REF_CFG, feed["input_ids"], no_window=True)
+    dropped = highest(lambda w_: ref.forward(
+        w_, REF_CFG, feed["input_ids"], no_window=True))(w)
     np.testing.assert_allclose(got[1], dropped["logits"], rtol=2e-4,
                                atol=2e-5)
 

@@ -15,12 +15,10 @@ from paddle_tpu import flags, monitor
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     defaults = {"telemetry": False, "step_log_path": "",
                 "stall_timeout_ms": 0, "stall_dump_dir": ""}
     flags.set_flags(defaults)
     yield
-    monitor.reset()
     flags.set_flags(defaults)
 
 

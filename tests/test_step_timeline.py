@@ -17,10 +17,8 @@ from paddle_tpu import executor, flags, layers, monitor
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     flags.set_flags({"telemetry": False, "step_log_path": ""})
     yield
-    monitor.reset()
     flags.set_flags({"telemetry": False, "step_log_path": ""})
 
 

@@ -26,11 +26,9 @@ _RESET_FLAGS = {"telemetry": False, "step_log_path": "",
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    monitor.reset()
     flags.set_flags(dict(_RESET_FLAGS))
     yield
     monitor.stop_server()
-    monitor.reset()
     flags.set_flags(dict(_RESET_FLAGS))
 
 

@@ -8,6 +8,8 @@ for bit; the live-step predicate, both index maps, the sub-tiles'
 predicates and ``bhtd_pairs`` against a brute-force table of visible
 pairs; the sdpa op's dispatch row."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -75,6 +77,26 @@ CASES = [
 ]
 
 
+@functools.cache
+def forward_and_explicit(h, hk, t, blk, window):
+    """(the forward kernel's out and lse, the explicit scores' out, lse
+    and three gradients) of a case, made once: neither walks an edge
+    block in sub-tiles, so the three ``edge_sub`` of a case share them.
+    Called under ``interpreted``."""
+    q, k, v, g = qkv(h, hk, t)
+
+    @jax.jit
+    def scores(q, k, v, g):
+        want, vjp = jax.vjp(lambda q, k, v: explicit(q, k, v, window),
+                            q, k, v)
+        return want, vjp((g, jnp.zeros_like(want[1])))
+
+    with jax.default_matmul_precision("highest"):
+        return (fa.flash_attention_fwd(q, k, v, causal=True, window=window,
+                                       q_block=blk, k_block=blk),
+                *scores(q, k, v, g))
+
+
 @pytest.mark.parametrize("h,hk,t,blk,window", CASES)
 def test_kernels_agree_with_explicit_scores(h, hk, t, blk, window,
                                             interpreted, edge_sub):
@@ -83,15 +105,11 @@ def test_kernels_agree_with_explicit_scores(h, hk, t, blk, window,
     assert tile is not None
     assert fa.bhtd_edge_tile(tile, True) == (
         (edge_sub, edge_sub) if edge_sub and edge_sub < blk else None)
+    (out, lse), want, wants = forward_and_explicit(h, hk, t, blk, window)
     with jax.default_matmul_precision("highest"):
-        out, lse = fa.flash_attention_fwd(
-            q, k, v, causal=True, window=window, q_block=blk, k_block=blk)
-        want, vjp = jax.vjp(lambda q, k, v: explicit(q, k, v, window),
-                            q, k, v)
         grads = fa.flash_attention_bwd(
             q, k, v, None, None, out, lse, g, causal=True, window=window,
             q_block=blk, k_block=blk)
-        wants = vjp((g, jnp.zeros_like(lse)))
     np.testing.assert_allclose(out, want[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(lse, want[1], rtol=1e-5, atol=1e-5)
     for a, b, name in zip(grads, wants, ("dq", "dk", "dv")):
@@ -149,18 +167,32 @@ def test_no_window_is_the_causal_call_bit_for_bit(window, hk, blk,
                                                   interpreted, edge_sub):
     q, k, v, g = qkv(2, hk, 256, seed=4)
     kw = dict(causal=True, q_block=blk, k_block=blk)
-    out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+    (out, lse), grads, plain = the_causal_call(hk, blk, edge_sub)
     got = fa.flash_attention_fwd(q, k, v, window=window, **kw)
     assert bool((got[0] == out).all()) and bool((got[1] == lse).all())
-    grads = fa.flash_attention_bwd(q, k, v, None, None, out, lse, g, **kw)
     got = fa.flash_attention_bwd(q, k, v, None, None, out, lse, g,
                                  window=window, **kw)
     assert all(bool((a == b).all()) for a, b in zip(got, grads))
     # and lowers the same program: no band, the sequence's own grid
-    def text(w):
-        return jax.jit(lambda q, k, v: fa.flash_attention_fwd(
-            q, k, v, window=w, **kw)).lower(q, k, v).as_text()
-    assert text(window) == text(None)
+    assert forward_text(q, k, v, window, kw) == plain
+
+
+def forward_text(q, k, v, window, kw):
+    return jax.jit(lambda q, k, v: fa.flash_attention_fwd(
+        q, k, v, window=window, **kw)).lower(q, k, v).as_text()
+
+
+@functools.cache
+def the_causal_call(hk, blk, edge_sub):
+    """((out, lse), the three gradients, the forward's lowered text) of
+    the call WITHOUT a window, made once for the three windows it is
+    held against. Called under ``interpreted`` and ``edge_sub``."""
+    q, k, v, g = qkv(2, hk, 256, seed=4)
+    kw = dict(causal=True, q_block=blk, k_block=blk)
+    out, lse = fa.flash_attention_fwd(q, k, v, **kw)
+    return ((out, lse), fa.flash_attention_bwd(
+        q, k, v, None, None, out, lse, g, **kw),
+        forward_text(q, k, v, None, kw))
 
 
 def test_a_window_needs_causal_self_attention(interpreted):

@@ -19,12 +19,12 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import analysis, layers
-from paddle_tpu.backward import append_backward
+import model_test
+from model_test import drawn, highest, moved, reference, snapshot
+from paddle_tpu import analysis
 from paddle_tpu.models import xing4 as M
 from paddle_tpu.parallel import rope
 from perf.reference import xing4 as ref
-from perf.reference.common import weights_from_scope
 
 # a yarn table whose original length (8) the tests' 16 positions pass
 YARN = dict(M.YARN, factor=4, original_max_position_embeddings=8)
@@ -54,49 +54,24 @@ def ref_cfg(mtp):
                 held_first=HELD[0], router_experts=16)
 
 
-def snapshot(scope):
-    """Host copies of a scope's weights (a run donates its state)."""
-    return {k: np.asarray(v) for k, v in weights_from_scope(scope).items()}
+# gains, routers, selection biases and every mix away from their start
+# (1 / 0.02 / 0 / gates of 0.01), so that every parameter matters, the
+# routing has no near-ties and each H depends on the token
+PERTURB = [((".scale",), moved(0.2)), (("_router.w",), drawn()),
+           (("_router.bias",), drawn(0.3)),
+           (("_hc.alpha",), lambda v, r: 0.5 + 0.2 * r.randn(*v.shape)),
+           (("_hc.bias",), lambda v, r: 0.3 * v + 0.5 * r.randn(*v.shape)),
+           (("_hc_phi.w",), drawn(0.2))]
 
 
 def perturb(scope, seed):
-    """Gains, routers, selection biases and every mix away from their
-    start (1 / 0.02 / 0 / gates of 0.01), so that every parameter
-    matters, the routing has no near-ties and each H depends on the
-    token."""
-    r = np.random.RandomState(seed)
-    for n in scope.var_names():
-        v = np.asarray(scope.find_var(n))
-        new = None
-        if n.endswith(".scale"):
-            new = v + 0.2 * r.randn(*v.shape)
-        elif n.endswith("_router.w"):
-            new = r.randn(*v.shape)
-        elif n.endswith("_router.bias"):
-            new = 0.3 * r.randn(*v.shape)
-        elif n.endswith("_hc.alpha"):
-            new = 0.5 + 0.2 * r.randn(*v.shape)
-        elif n.endswith("_hc.bias"):
-            new = 0.3 * v + 0.5 * r.randn(*v.shape)
-        elif n.endswith("_hc_phi.w"):
-            new = 0.2 * r.randn(*v.shape)
-        if new is not None:
-            scope.set(n, jnp.asarray(new, jnp.float32))
+    model_test.perturb(scope, seed, PERTURB)
 
 
 def built(seed, mtp, optimizer=None):
     cfg = M.Xing4Config(**TINY, num_nextn_predict_layers=mtp,
                         n_routed_experts=16, held_experts=HELD)
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        model = M.build(cfg)
-        grads = None
-        if optimizer is None:
-            grads = append_backward(model["loss"])
-        else:
-            optimizer().minimize(model["loss"])
-    return cfg, main, startup, model, grads
+    return (cfg, *model_test.built(M, cfg, seed, optimizer))
 
 
 @pytest.mark.parametrize("mtp", [1, 0], ids=["with_mtp", "without_mtp"])
@@ -115,12 +90,8 @@ def test_model_loss_logits_and_every_parameters_gradient(mtp):
     if mtp:
         fetch += [model["mtp_last_logits"], model["mtp_loss"]]
     got = exe.run(main, feed=feed, scope=scope, fetch_list=fetch)
-    rc = ref_cfg(mtp)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(w, rc, feed["input_ids"], feed["labels"],
-                           last=M.LAST_POSITIONS)
-        want_loss, want_g = jax.value_and_grad(
-            lambda w_: ref.loss(w_, rc, feed))(w)
+    want, want_loss, want_g = reference(
+        ref, w, ref_cfg(mtp), feed, feed["labels"], last=M.LAST_POSITIONS)
     # float32 on both sides; the same mathematics in another order
     # (token-minor mixes written as adds of slices against jnp.sum over a
     # token's matrix, sorted groups against a dense loop, one score
@@ -192,6 +163,8 @@ def test_every_reference_control_is_another_model():
     got = exe.run(main, feed=feed, scope=scope,
                   fetch_list=[model["last_logits"]])[0]
     rc = ref_cfg(0)
+    # (op by op: the seven models share their ops' executables, where
+    # one jitted computation a control compiles the forward seven times)
     with jax.default_matmul_precision("highest"):
         want = ref.forward(w, rc, feed["input_ids"], feed["labels"],
                            last=M.LAST_POSITIONS)["logits"]
@@ -305,8 +278,8 @@ def test_one_step_of_adam_is_the_references_gradient_step():
     before = snapshot(scope)
     exe.run(main, feed=feed, scope=scope, fetch_list=[model["loss"]])
     after = snapshot(scope)
-    with jax.default_matmul_precision("highest"):
-        grad = jax.grad(lambda w_: ref.loss(w_, ref_cfg(0), feed))(before)
+    grad = highest(jax.grad(
+        lambda w_: ref.loss(w_, ref_cfg(0), feed)))(before)
     for n in ("blk0_attn_hc_phi.w", "blk1_moe_hc.bias", "blk2_attn_hc.alpha",
               "blk1_attn_q_b_colp.w", "final_norm.scale"):
         g = np.asarray(grad[n])
@@ -327,23 +300,8 @@ def moe_layer(held, shared, x, weights=None, seed=3):
     """(out, rows, {param: value}) of the model's expert layer
     (``layers.topk_moe`` as ``xing4._moe`` calls it); ``weights``: the
     uncut layer's, cut to the held share."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    with fluid.program_guard(main, startup):
-        xv = layers.data("x", shape=list(x.shape), dtype="float32",
-                         append_batch_size=False)
-        out, _, _, rows, _ = layers.topk_moe(
-            xv, E, K, F, name="m", held=held, shared_d_ff=shared, **KW)
-    scope, exe = fluid.Scope(), fluid.Executor()
-    exe.run(startup, scope=scope)
-    for n, v in (weights or {}).items():
-        if n in scope.var_names():
-            if held and v.ndim == 3 and v.shape[0] == E:
-                v = v[held[0]:held[0] + held[1]]
-            scope.set(n, jnp.asarray(v))
-    w = snapshot(scope)
-    got = exe.run(main, feed={"x": x}, scope=scope, fetch_list=[out, rows])
-    return (*got, w)
+    return model_test.moe_layer(
+        E, K, F, held, x, weights, seed, shared_d_ff=shared, **KW)
 
 
 def test_the_eight_shares_add_up_to_the_uncut_references_layer():
