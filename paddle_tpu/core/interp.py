@@ -302,12 +302,19 @@ def exec_ops(
     key=None,
     amp: Optional[bool] = None,
     op_defs: Optional[List[OpDef]] = None,
+    ledger=None,
 ):
     """Execute an op list against ``env`` in place; returns ``env``.
 
     ``key`` is the PRNG key for this execution; per-op keys are derived by
     folding in the op's ``forward_op_idx`` attr (so a grad op replays its
     forward's key) or its position.
+
+    ``ledger``: what core/lowering.run_block hands the OUTERMOST call of
+    a block's lowering while telemetry is on (a control-flow op's
+    sub-block comes here without one: its values are its op's business,
+    as its seconds are): it is told every op's reads and the values it
+    writes, as traced (``lowering.ValueLedger.note``).
     """
     if amp is None:
         amp = amp_active()
@@ -354,6 +361,8 @@ def exec_ops(
             _M_OP_TRACE.observe(
                 time.perf_counter() - t_op - (_NESTED.s - nested_op),
                 labels={"op": op.type})
+            if ledger is not None:
+                ledger.note(idx, op, outs)
         for slot, names in op.outputs.items():
             vals = outs.get(slot, [])
             for i, n in enumerate(names):

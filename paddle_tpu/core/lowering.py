@@ -19,11 +19,15 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import math
+import re
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import monitor as _monitor
 from paddle_tpu.framework import Block, Program
 
 # Ops handled by the lowering itself rather than a registered kernel.
@@ -148,15 +152,22 @@ def lower_block(
         env: Dict[str, Any] = {}
         env.update(state)
         env.update(feeds)
+        # the memory ledger of this trace (monitor.memory_ledgers): with
+        # telemetry off there is none and exec_ops gathers nothing
+        ledger = ValueLedger(state, feeds) if _monitor.enabled() else None
         tok = set_amp_active(amp)
         try:
-            exec_ops(ops, env, key=key, amp=amp, op_defs=op_defs)
+            exec_ops(ops, env, key=key, amp=amp, op_defs=op_defs,
+                     ledger=ledger)
         finally:
             from paddle_tpu.core.interp import _AMP_ACTIVE
 
             _AMP_ACTIVE.reset(tok)
         fetches = [env[n] for n in fetch_names]
         new_state = {n: env[n] for n in state_out}
+        if ledger is not None:
+            _monitor.record_memory_ledger(lambda: build_memory_ledger(
+                ledger, ops, program, amp, fetch_names + state_out))
         return fetches, new_state
 
     op_histogram: Dict[str, int] = {}
@@ -299,6 +310,210 @@ def jit_lowered_multi(lowered: LoweredBlock, n_feeds: int,
 
 
 # ---------------------------------------------------------------------------
+# the memory ledger (monitor.py memory ledgers): what a step's state
+# weighs and what its forward pass keeps for its backward pass
+# ---------------------------------------------------------------------------
+
+# the saved rows a ledger keeps (its totals run over all of them)
+MEMORY_LEDGER_ROWS = 32
+# a layer's index in a name scope, folded so that the layers' rows are
+# one row with a count (perf/tools/scope_table.py's spelling: blk#)
+_LAYER_INDEX = re.compile(r"\b(blk|enc|dec)\d+\b")
+# input slots through which a persistable is read as OPTIMIZER state
+# even by a bwd op: an experts' grad op that took its matrices' Adam
+# (optimizer.AdamOptimizer._fold_into_experts_grad) reads the moments
+# there, and they are no parameters for that
+_OPTIMIZER_SLOTS = frozenset(
+    {"Moment1", "Moment2", "Beta1Pow", "Beta2Pow", "LearningRate"})
+
+
+def tile_padded_bytes(shape, dtype) -> int:
+    """Bytes of an array of ``shape`` and ``dtype`` in the chip's
+    default tiled layout, from the shape alone: the minor dimension up
+    to whole lanes (128), the second-minor up to whole sublanes of a
+    32-bit word (8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit elements),
+    rank 1 up to 1024 elements; a scalar is its element. What XLA lays
+    out otherwise (a dimension of 1 it moves outward, a short 1-D
+    array's smaller tile, a reshape's view that shares its source's
+    buffer) PERF.md section 3 lists: the rule says what the Program's
+    shapes would cost as they stand."""
+    itemsize = jnp.dtype(dtype).itemsize
+    dims = [int(d) for d in shape]
+    if not dims:
+        return itemsize
+    if len(dims) == 1:
+        dims[0] = -(-dims[0] // 1024) * 1024
+    else:
+        sublanes = 8 * max(1, 4 // itemsize)
+        dims[-1] = -(-dims[-1] // 128) * 128
+        dims[-2] = -(-dims[-2] // sublanes) * sublanes
+    return math.prod(dims) * itemsize
+
+
+class _Value:
+    """One value of a lowered block: a state array, a feed, or what one
+    op wrote under one name (a name written again is a value again)."""
+
+    __slots__ = ("name", "kind", "idx", "role", "scope", "op", "slot",
+                 "shape", "dtype", "bytes", "padded", "last_idx",
+                 "last_role", "model_read")
+
+    def __init__(self, name, kind, idx, role, scope, op, slot, v):
+        self.name, self.kind, self.idx = name, kind, idx
+        self.role, self.scope, self.op, self.slot = role, scope, op, slot
+        self.shape = tuple(int(d) for d in v.shape)
+        dtype = jnp.dtype(v.dtype)
+        self.dtype = dtype.name
+        self.bytes = math.prod(self.shape) * dtype.itemsize
+        self.padded = tile_padded_bytes(self.shape, dtype)
+        self.last_idx, self.last_role = None, None
+        self.model_read = False
+
+
+class ValueLedger:
+    """What ONE trace of a block gathers for its memory ledger:
+    ``run_block`` opens it with the state and the feeds as their avals
+    came, ``interp.exec_ops`` tells it every op (``note``), and
+    ``build_memory_ledger`` reduces it when the trace ends. Shapes and
+    dtypes are the traced values' own: under AMP a bf16 stream counts 2
+    bytes whatever its variable declares."""
+
+    def __init__(self, state, feeds):
+        self.values: List[_Value] = []
+        self.current: Dict[str, _Value] = {}
+        for kind, group in (("state", state), ("feed", feeds)):
+            for name, v in group.items():
+                self._add(name, kind, -1, kind, "", kind, name, v)
+
+    def _add(self, name, kind, idx, role, scope, op, slot, v):
+        try:
+            val = _Value(name, kind, idx, role, scope, op, slot, v)
+        except (AttributeError, TypeError):
+            return   # no array (a list of them, a typed key): not counted
+        self.values.append(val)
+        self.current[name] = val
+
+    def note(self, idx, op, outs):
+        """Op ``idx`` of the block ran: it read its inputs' values as
+        they stood and wrote ``outs``."""
+        role, current = op.role, self.current
+        for slot, names in op.inputs.items():
+            for n in names:
+                val = current.get(n)
+                if val is not None:
+                    val.last_idx, val.last_role = idx, role
+                    if role != "opt" and slot not in _OPTIMIZER_SLOTS:
+                        val.model_read = True
+        for slot, names in op.outputs.items():
+            vals = outs.get(slot, [])
+            for i, n in enumerate(names):
+                if not n or i >= len(vals) or vals[i] is None:
+                    continue
+                held = current.get(n)
+                if held is not None and held.kind == "state":
+                    continue   # a state array updated in place: state
+                self._add(n, "value", idx, role, op.namescope, op.type,
+                          slot, vals[i])
+
+
+def _ledger_row(scope: str, val: _Value) -> Dict[str, Any]:
+    return {"scope": scope, "op": val.op, "slot": val.slot,
+            "shape": list(val.shape), "dtype": val.dtype, "count": 1,
+            "bytes": val.bytes, "padded_bytes": val.padded}
+
+
+def build_memory_ledger(ledger: ValueLedger, ops, program, amp: bool,
+                        kept: Sequence[str]) -> Dict[str, Any]:
+    """The memory ledger of one lowering (schema:
+    monitor.MEMORY_LEDGER_FIELDS) from what its trace gathered. ``kept``
+    are the names alive to the end: the fetches and the state handed
+    back. It counts the PROGRAM's variables: what an op's compute makes
+    inside itself (AMP's bf16 casts of the weights) and whatever XLA
+    decides afterwards (fusion, rematerialisation, its temporaries) it
+    cannot see."""
+    values, n_ops = ledger.values, len(ops)
+    state = [v for v in values if v.kind == "state"]
+    feeds = [v for v in values if v.kind == "feed"]
+    end = n_ops - 1
+    for name in kept:
+        val = ledger.current.get(name)
+        if val is not None and val.kind == "value":
+            val.last_idx = end   # (its last reader's role stays)
+
+    # what the forward pass keeps: written under fwd, or fed, and last
+    # read by a bwd or opt op
+    rows: Dict[tuple, Dict[str, Any]] = {}
+    saved_bytes = saved_padded = n_saved = 0
+    for v in values:
+        if v.kind == "state" or v.role not in ("fwd", "feed") \
+                or v.last_role not in ("bwd", "opt"):
+            continue
+        n_saved += 1
+        saved_bytes += v.bytes
+        saved_padded += v.padded
+        scope = _LAYER_INDEX.sub(r"\1#", v.scope)
+        key = (scope, v.op, v.slot, v.shape, v.dtype)
+        row = rows.get(key)
+        if row is None:
+            rows[key] = _ledger_row(scope, v)
+        else:
+            row["count"] += 1
+            row["bytes"] += v.bytes
+            row["padded_bytes"] += v.padded
+    top = sorted(rows.values(), key=lambda r: (
+        -r["padded_bytes"], r["scope"], r["op"], r["slot"]))
+
+    # the walk: a value is alive from its write to its last read (to
+    # the end where kept), the state and the feeds throughout; what
+    # nothing reads (an op's output for its grad op's sake that the
+    # grad op makes again) is dead code to XLA and takes no room
+    base = sum(v.padded for v in state) + sum(v.padded for v in feeds)
+    delta = [0] * (n_ops + 1)
+    spans = []
+    for v in values:
+        if v.kind != "value" or v.last_idx is None:
+            continue
+        until = max(v.idx, v.last_idx)
+        spans.append((v, until))
+        delta[v.idx] += v.padded
+        delta[until + 1] -= v.padded
+    peak, at, alive_now = base, -1, 0
+    for i in range(n_ops):
+        alive_now += delta[i]
+        if base + alive_now > peak:
+            peak, at = base + alive_now, i
+    alive = sorted((v for v, until in spans if v.idx <= at <= until),
+                   key=lambda v: (-v.padded, v.idx, v.name))[:5]
+    op_at = ops[at] if at >= 0 else None
+    return {
+        "v": _monitor.MEMORY_LEDGER_SCHEMA_VERSION,
+        "ts": time.time(),
+        "program": f"program{program._uid}",
+        "program_uid": int(program._uid),
+        "n_ops": n_ops,
+        "amp": bool(amp),
+        "has_backward": any(op.role == "bwd" for op in ops),
+        "state": {
+            "param": sum(v.bytes for v in state if v.model_read),
+            "optimizer": sum(v.bytes for v in state if not v.model_read),
+            "padded_bytes": sum(v.padded for v in state),
+            "arrays": len(state)},
+        "feed": {"bytes": sum(v.bytes for v in feeds),
+                 "padded_bytes": sum(v.padded for v in feeds),
+                 "arrays": len(feeds)},
+        "saved": {"bytes": saved_bytes, "padded_bytes": saved_padded,
+                  "values": n_saved, "rows": top[:MEMORY_LEDGER_ROWS]},
+        "walk_peak": {
+            "bytes": peak, "index": at,
+            "role": op_at.role if op_at is not None else "",
+            "scope": op_at.namescope if op_at is not None else "",
+            "op": op_at.type if op_at is not None else "",
+            "alive": [dict(_ledger_row(v.scope, v), name=v.name)
+                      for v in alive]},
+    }
+
+
+# ---------------------------------------------------------------------------
 # compile-cost analysis (monitor.py compile reports)
 # ---------------------------------------------------------------------------
 
@@ -338,8 +553,6 @@ def build_compile_report(
     opt-in per monitor.compile_reports_active()."""
     import hashlib
     import time as _time
-
-    from paddle_tpu import monitor as _monitor
 
     key_digest = hashlib.sha1(
         repr(cache_key).encode()).hexdigest()[:16]

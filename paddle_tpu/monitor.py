@@ -105,6 +105,17 @@ Grown in PR 36 with set-up seen from inside:
     first call (``compiling``) was on the thread, ``(outside)`` when
     none was.
 
+Grown in PR 69 with memory seen from the lowering:
+
+12. **Memory ledgers** — with telemetry on, every lowering of a program
+    block (``core/lowering.run_block``'s trace) leaves one record,
+    ``memory_ledgers()[program]``: the state arrays' bytes split
+    parameter / optimizer, one step's feeds, every value the forward
+    pass keeps for the backward pass by name scope, op and slot at the
+    shape and dtype it was traced with (plain and padded to the chip's
+    tiles), and the peak of a liveness walk over the block's ops; the
+    six totals are ``pt_program_memory_bytes{program, kind}``.
+
 Everything is off by default behind typed flags (flags.py); flipping
 ``telemetry`` at runtime takes effect immediately via a flag watcher,
 and every disabled instrument call costs one module-level boolean check.
@@ -490,6 +501,7 @@ def reset():
         _GC_PAUSES.clear()
     with _COMPILE_LOCK:
         _COMPILE_REPORTS.clear()
+        _MEMORY_LEDGERS.clear()
     _STALLS.clear()
     _stall_seq = 0
     global _oom_seq
@@ -1092,6 +1104,148 @@ def compile_reports() -> Dict[str, Dict[str, Any]]:
 
 
 # ---------------------------------------------------------------------------
+# memory ledgers: what a lowered program's state weighs and what its
+# forward pass keeps for its backward pass (core/lowering.py builds them)
+# ---------------------------------------------------------------------------
+
+MEMORY_LEDGER_SCHEMA_VERSION = 1
+
+# field name -> (accepted types, required, doc), as COMPILE_REPORT_FIELDS.
+# Bytes are of the values AS TRACED (under AMP a bf16 stream counts 2
+# bytes whatever its variable declares); "padded" bytes are
+# lowering.tile_padded_bytes's, the chip's default tiling from the shape
+# and dtype alone. The ledger counts the Program's variables: what an
+# op's compute makes inside itself and whatever XLA decides afterwards
+# (fusion, rematerialisation, temporaries) it does not see.
+MEMORY_LEDGER_FIELDS: Dict[str, tuple] = {
+    "v": ((int,), True,
+          "schema version (MEMORY_LEDGER_SCHEMA_VERSION)"),
+    "ts": ((float, int), True, "wall-clock unix timestamp of the trace"),
+    "program": ((str,), True, "program id ('program<uid>')"),
+    "program_uid": ((int,), True, "Program._uid of the lowered program"),
+    "n_ops": ((int,), True, "ops of the lowered block"),
+    "amp": ((bool,), True, "whether the block was lowered under AMP"),
+    "has_backward": ((bool,), True,
+                     "whether the block holds a bwd op: a run's train "
+                     "step, against the eval clones beside it"),
+    "state": ((dict,), True,
+              "the state arrays (persistables read before written): "
+              "'param' bytes (some fwd or bwd op reads it), 'optimizer' "
+              "bytes (only opt ops do, or an op through an optimizer's "
+              "slot: moments, powers, rates), 'padded_bytes' of both, "
+              "'arrays'"),
+    "feed": ((dict,), True,
+             "ONE step's feeds: 'bytes', 'padded_bytes', 'arrays'"),
+    "saved": ((dict,), True,
+              "the values the forward pass keeps (written under fwd, or "
+              "fed, and last read by a bwd or opt op): 'bytes', "
+              "'padded_bytes' and 'values' over all of them, 'rows' the "
+              "largest by padded bytes (lowering.MEMORY_LEDGER_ROWS): "
+              "{scope (layer index folded: blk#), op, slot, shape, "
+              "dtype, count, bytes, padded_bytes}, the bytes those of "
+              "all 'count' values that agree in all but the layer"),
+    "walk_peak": ((dict,), True,
+                  "the most that is alive at one op, a value alive from "
+                  "its write to its last read (one nothing reads never), "
+                  "state, feeds and fetches throughout: padded 'bytes', "
+                  "the op's 'index', 'role', "
+                  "'scope' and 'op' type, and 'alive', the five largest "
+                  "values alive there (a saved row's keys and 'name')"),
+}
+
+_LEDGER_PARTS = {
+    "state": {"param": int, "optimizer": int, "padded_bytes": int,
+              "arrays": int},
+    "feed": {"bytes": int, "padded_bytes": int, "arrays": int},
+    "saved": {"bytes": int, "padded_bytes": int, "values": int,
+              "rows": list},
+    "walk_peak": {"bytes": int, "index": int, "role": str, "scope": str,
+                  "op": str, "alive": list},
+}
+_LEDGER_ROW = {"scope": str, "op": str, "slot": str, "shape": list,
+               "dtype": str, "count": int, "bytes": int,
+               "padded_bytes": int}
+
+
+def _validate_keys(rec, keys: Dict[str, type], what: str):
+    if not isinstance(rec, dict) or set(rec) != set(keys):
+        raise ValueError(f"{what} must be a dict of {sorted(keys)}, got "
+                         f"{sorted(rec) if isinstance(rec, dict) else rec!r}")
+    for k, t in keys.items():
+        # (a bool is an int to isinstance: no count or byte is one)
+        if not isinstance(rec[k], t) or isinstance(rec[k], bool):
+            raise ValueError(f"{what} field '{k}' has type "
+                             f"{type(rec[k]).__name__}, expected "
+                             f"{t.__name__}")
+
+
+def validate_memory_ledger(rec: Dict[str, Any]):
+    """Raise ValueError unless ``rec`` conforms to MEMORY_LEDGER_FIELDS,
+    its parts and rows included."""
+    _validate_fields(rec, MEMORY_LEDGER_FIELDS,
+                     MEMORY_LEDGER_SCHEMA_VERSION, "memory ledger")
+    for part, keys in _LEDGER_PARTS.items():
+        _validate_keys(rec[part], keys, f"memory ledger '{part}'")
+    for row in rec["saved"]["rows"]:
+        _validate_keys(row, _LEDGER_ROW, "memory ledger saved row")
+    for row in rec["walk_peak"]["alive"]:
+        _validate_keys(row, {**_LEDGER_ROW, "name": str},
+                       "memory ledger alive row")
+
+
+# program id -> the ledger of its newest lowering, kept as compile
+# reports are (under _COMPILE_LOCK, oldest program dropped first)
+_MEMORY_LEDGERS: Dict[str, Dict[str, Any]] = {}
+
+_M_PROGRAM_MEMORY = gauge(
+    "pt_program_memory_bytes",
+    "a lowered program's memory ledger (monitor.memory_ledgers), by "
+    "program and kind: param, optimizer and feed (bytes as traced), "
+    "saved (the values the forward pass keeps for the backward pass, "
+    "padded to the chip's tiles), saved_padding (the padding in that) "
+    "and walk_peak (the most alive at one op, padded)")
+
+
+def record_memory_ledger(ledger):
+    """Store the memory ledger of a lowering (core/lowering.run_block
+    records one a trace, telemetry on; ``ledger`` the record or a call
+    that builds it): the newest of a program replaces its record, and
+    its six totals go to pt_program_memory_bytes. Never raises —
+    telemetry must not fail a lowering."""
+    if not _enabled:
+        return
+    try:
+        if callable(ledger):
+            ledger = ledger()
+        prog = ledger["program"]
+        saved = ledger["saved"]
+        kinds = {"param": ledger["state"]["param"],
+                 "optimizer": ledger["state"]["optimizer"],
+                 "feed": ledger["feed"]["bytes"],
+                 "saved": saved["padded_bytes"],
+                 "saved_padding": saved["padded_bytes"] - saved["bytes"],
+                 "walk_peak": ledger["walk_peak"]["bytes"]}
+        with _COMPILE_LOCK:
+            _MEMORY_LEDGERS.pop(prog, None)
+            _MEMORY_LEDGERS[prog] = ledger
+            while len(_MEMORY_LEDGERS) > MAX_COMPILE_REPORTS:
+                _MEMORY_LEDGERS.pop(next(iter(_MEMORY_LEDGERS)))
+        for kind, nbytes in kinds.items():
+            _M_PROGRAM_MEMORY.set(
+                nbytes, labels={"program": prog, "kind": kind})
+    except Exception as e:
+        warnings.warn(f"memory ledger dropped: {e!r}", RuntimeWarning)
+
+
+def memory_ledgers() -> Dict[str, Dict[str, Any]]:
+    """The memory ledger of each lowered program's newest lowering
+    (insertion order = lowering order, oldest first): recorded while
+    ``telemetry`` is on, empty without."""
+    with _COMPILE_LOCK:
+        return {k: dict(v) for k, v in _MEMORY_LEDGERS.items()}
+
+
+# ---------------------------------------------------------------------------
 # compile stages: jax's own compile events, by the program that caused them
 # ---------------------------------------------------------------------------
 
@@ -1233,12 +1387,19 @@ def _var_nbytes(shape, dtype, batch: int) -> int:
 def estimate_memory(program, feed_shapes: Optional[Dict[str, Any]] = None,
                     budget_bytes: Optional[int] = None) -> Dict[str, Any]:
     """Static pre-flight device-memory estimate for ``program``: sums
-    declared var shapes in block 0 (``-1`` batch dims resolved from
-    ``feed_shapes``' leading dim, else 1) into parameter / feed /
-    activation byte totals. A LOWER BOUND — XLA temps, donation aliasing
-    and fusion are unknowable before the compile — but params +
+    EVERY declared variable of block 0 at its DECLARED shape and dtype
+    (``-1`` batch dims resolved from ``feed_shapes``' leading dim, else
+    1) into parameter / feed / activation byte totals. That sum bounds
+    the step's memory from neither side: "activations" holds forward
+    values, gradients and optimizer temporaries as if all were alive at
+    once and at float32 where AMP runs them in bf16 (too much), and
+    knows nothing of tile padding, XLA's temporaries, donation or fusion
+    (too little). It needs no lowering, which is its use: params +
     activations catch the common will-it-OOM case before paying a
-    multi-minute compile for an OOM.
+    multi-minute compile for an OOM. Once a program HAS been lowered
+    with telemetry on, ``memory_ledgers()`` holds the lowering's own
+    count: the values as traced, what crosses from the forward to the
+    backward pass, and the peak of a liveness walk.
 
     Returns ``{param_bytes, feed_bytes, activation_bytes, total_bytes,
     budget_bytes, fits}`` (``fits`` is None when no budget applies, from
