@@ -129,12 +129,13 @@ def attention_dispatch():
     one call or the pair and the sub-tiles it walks its edge blocks in:
     "... form=fused edge=256x256", a forward row the layout in which its
     logsumexp leaves the kernel: "... stats=rows", a block-masked row
-    its mask: "... mask=block_diffusion block=4 band=skip"
+    its mask: "... mask=block_diffusion block=4 band=skip", a row of a
+    call given q and k in two parts who read them: "... parts=own"
     (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
     return attention_ops.dispatch_counts(tiles=True, forms=True, edges=True,
-                                         stats=True, masks=True)
+                                         stats=True, masks=True, parts=True)
 
 
 # (t, window) of the decoder cells' BHTD calls, all on hb1 bq512 bk512
@@ -1173,6 +1174,9 @@ def mla_rows_hold(cfg, seq, lowered):
             f"dk{dk} dv{dv} with their tile, none dense: {attn}")
     _one_backward_call(attn)
     _statistics_in_rows(attn)
+    # (the kernels read QPe and KPe's one head as operands of their own)
+    check(all(k.endswith(" parts=own") for k in attn),
+          f"a latent call's q and k were assembled by the op: {attn}")
     want = (f"score=sigmoid bias=1 k={cfg.num_experts_per_tok} "
             f"experts={cfg.n_routed_experts}")
     # (a router's grad op runs it again: a row counts both lowerings)

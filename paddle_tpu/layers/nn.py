@@ -483,7 +483,8 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
 
 
 def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
-                                 name=None, block_diffusion=None):
+                                 name=None, block_diffusion=None,
+                                 q_pe=None, k_pe=None):
     """softmax(scale q k^T) v of head-major q [b, h, t, dk], k
     [b, hk, t, dk] and v [b, hk, t, dv] -> [b, h, t, dv]: ONE op, which
     the flash kernels take on a TPU (``ops/attention_ops.py``). hk may
@@ -499,6 +500,13 @@ def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
     sees no noised key (``parallel/flash_attention.bd_visible``). Every
     position is real and nothing is dropped: the
     packed decoders' call (``models/decoder.py``'s families).
+    ``q_pe`` [b, h, t, r] and ``k_pe`` [b, hp, t, r], hp dividing h
+    (both or neither): the queries and keys come in TWO parts, the
+    scores are scale * (q k^T + q_pe k_pe^T) with query head i reading
+    k_pe's head i // (h / hp): latent attention's rotary features, the
+    keys' ONE head shared by all. The kernels read the parts where they
+    lie; nobody builds a wide q or copies the shared head (the op does,
+    itself, where no kernel takes the call).
     ``models/transformer.py`` appends the op itself, token-major with
     dropout and a padding bias. ``name`` names the layer's temporaries."""
     helper = LayerHelper(name or "scaled_dot_product_attention")
@@ -516,8 +524,10 @@ def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
         attrs["window"] = int(window)
     if block_diffusion:
         attrs["block_diffusion"] = int(block_diffusion)
-    helper.append_op("scaled_dot_product_attention",
-                     inputs={"Q": q, "K": k, "V": v},
+    inputs = {"Q": q, "K": k, "V": v}
+    if q_pe is not None or k_pe is not None:
+        inputs.update(QPe=q_pe, KPe=k_pe)
+    helper.append_op("scaled_dot_product_attention", inputs=inputs,
                      outputs={"Out": out, "Lse": lse}, attrs=attrs)
     return out
 

@@ -134,17 +134,21 @@ def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
         no low rank and no norm of it) -> per head [q_nope | q_pe]
         q_pe, k_pe <- RoPE, pairs (2i, 2i + 1), k_pe ONE head of ``rope``
         features that all query heads share (``rope_theta`` None: NOTHING
-        is rotated, the features are kept as they come; q then needs no
-        split and no concat; ``rope_scaling``: a yarn scaling of the
-        table, ``layers.rotary_embedding(scaling=)``'s dict)
-        o = causal softmax(q [k_nope | k_pe]^T / sqrt(nope + rope)) v;  o Wo
+        is rotated, the features are kept as they come; ``rope_scaling``:
+        a yarn scaling of the table, ``layers.rotary_embedding(scaling=)``'s
+        dict)
+        o = causal softmax((q_nope k_nope^T + q_pe k_pe^T)
+                           / sqrt(nope + rope)) v;  o Wo
         (``softmax_scale``: that number where 1 / sqrt(nope + rope) stands,
         as DeepSeek's yarn puts mscale^2 on it)
 
+    The attention call takes the four parts as they are
+    (``layers.scaled_dot_product_attention(q_pe=, k_pe=)``): no wide q or
+    k is assembled here and the shared head is not copied.
+
     Scopes under the caller's: ``q_lora`` (``q`` without a low rank),
-    ``kv_lora``, ``rope`` (the splits, the rotation where there is one,
-    the shared key head's copies and the assembly of the wide k),
-    ``core`` (the sdpa op), ``out``."""
+    ``kv_lora``, ``rope`` (the heads to the front, the splits and the
+    rotation where there is one), ``core`` (the sdpa op), ``out``."""
     h = heads
     xn = rms_norm(x, eps, f"{p}_attn_norm")
     if q_lora_rank is None:
@@ -163,9 +167,9 @@ def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
             rms_norm(c_kv, eps, f"{p}_attn_kv_a_norm"),
             h * (nope + dv), f"{p}_attn_kv_b_colp.w")
     with fluid.name_scope("rope"):
-        q = _heads_first(layers.reshape(q, [0, 0, h, nope + rope]))
-        if rope_theta is not None:
-            q_nope, q_rope = layers.split(q, [nope, rope], dim=-1)
+        q_nope, q_rope = layers.split(
+            _heads_first(layers.reshape(q, [0, 0, h, nope + rope])),
+            [nope, rope], dim=-1)
         k_nope, v = layers.split(
             _heads_first(layers.reshape(kv, [0, 0, h, nope + dv])),
             [nope, dv], dim=-1)
@@ -175,15 +179,14 @@ def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
             q_rope, k_rope = layers.rotary_embedding(
                 q_rope, k_rope, theta=rope_theta, interleaved=True,
                 scaling=rope_scaling)
-            q = layers.concat([q_nope, q_rope], axis=3)
-        k = layers.concat(
-            [k_nope, layers.expand(k_rope, [1, h, 1, 1])], axis=3)
     with fluid.name_scope("core"):
-        # Q, K [b, h, t, nope + rope], V and Out [b, h, t, dv]
+        # Q, K [b, h, t, nope], QPe [b, h, t, rope], KPe [b, 1, t, rope],
+        # V and Out [b, h, t, dv]
         if softmax_scale is None:
             softmax_scale = 1.0 / math.sqrt(nope + rope)
         ctx = layers.scaled_dot_product_attention(
-            q, k, v, softmax_scale, name=f"{p}_attn_sdpa")
+            q_nope, k_nope, v, softmax_scale, name=f"{p}_attn_sdpa",
+            q_pe=q_rope, k_pe=k_rope)
     with fluid.name_scope("out"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dv])

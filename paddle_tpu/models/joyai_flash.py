@@ -47,9 +47,9 @@ with two uses; ``append_backward`` sums the two gradients.
 layer (``layers.topk_moe(held=...)``), the MTP module's too.
 
 Name scopes (README "Names in the device trace"): ``embed``,
-``blk<i>/attn`` with ``q_lora``, ``kv_lora``, ``rope`` (the splits, the
-rotation, the shared key head's copies and the assembly of the wide q
-and k), ``core`` (the sdpa op) and ``out`` under it, ``blk0/ffn``,
+``blk<i>/attn`` with ``q_lora``, ``kv_lora``, ``rope`` (the heads to
+the front, the splits and the rotation: the sdpa op takes q and k in two
+parts), ``core`` (the sdpa op) and ``out`` under it, ``blk0/ffn``,
 ``blk<i>/moe`` with ``router``, ``dispatch``, ``experts``, ``shared`` and
 ``combine``; the MTP module ``blk_mtp/{merge,attn,moe}`` and its pass
 through the head ``loss_head/mtp``; ``final_norm``, ``loss_head``.

@@ -56,8 +56,8 @@ Name scopes (README "Names in the device trace"): ``embed``;
 ``blk<i>/kda`` with ``proj`` (the three projections, the two low-rank
 pairs, Wb), ``conv``, ``rule`` (the gates and the op), ``gate_norm`` and
 ``out`` under it; ``blk<i>/attn`` with ``q``, ``kv_lora``, ``rope``
-(here only the shared key head's copies and the assembly of the wide k:
-the name stays so that one reader serves both latent families), ``core``
+(here only the heads' move to the front and the splits: the name stays
+so that one reader serves every latent family), ``core``
 and ``out``; ``blk0/ffn``; ``blk<i>/moe`` with ``router``, ``dispatch``,
 ``experts``, ``shared`` and ``combine``; ``final_norm``, ``loss_head``.
 """

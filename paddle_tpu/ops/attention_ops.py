@@ -54,7 +54,13 @@ _M_DISPATCH = _monitor.counter(
     "noised and a clean copy under block diffusion's training mask) "
     "carries mask: block_diffusion, block: the block's length, and band: "
     "skip (the BHTD kernels walk the mask's live blocks) or dense (the "
-    "composition, with its [t, t] scores)")
+    "composition, with its [t, t] scores). A call that was given its "
+    "queries and keys in two parts (QPe, KPe: latent attention's rotary "
+    "features, the keys' ONE head that all query heads share) carries "
+    "parts: own (attn.bhtd.fwd and attn.bhtd.bwd read the parts as "
+    "operands of their own: no wide q or k exists) or assembled (the op "
+    "concatenated q and k and copied the shared head, and the row is the "
+    "wide call's); its shape names the whole head either way")
 
 
 def _windowed(attrs, q, k, bthd, ring):
@@ -93,7 +99,7 @@ def _block_masked(attrs, q, k, bthd, ring):
 
 
 def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
-                   form=None, causal=False, block_diffusion=None):
+                   form=None, causal=False, block_diffusion=None, parts=None):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
@@ -134,11 +140,13 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         labels["edge"] = edge
     if stats is not None:
         labels["stats"] = stats
+    if parts is not None:
+        labels["parts"] = parts
     _M_DISPATCH.inc(labels=labels)
 
 
 def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
-                    masks=False):
+                    masks=False, parts=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
     run print it. ``tiles``: a row whose family tiles by the shape names
@@ -149,7 +157,9 @@ def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
     ``stats``: a forward row of that family says in which layout the
     call's logsumexp leaves the kernel, "... stats=rows". ``masks``: a
     block-masked row says so, "... mask=block_diffusion block=4
-    band=skip"."""
+    band=skip". ``parts``: a row of a call given q and k in two parts
+    says who read them, "... parts=own" (the kernels) or "...
+    parts=assembled" (the op concatenated them)."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
@@ -167,6 +177,8 @@ def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
         if masks and lb.get("mask"):
             name += (f" mask={lb['mask']} block={lb['block']} "
                      f"band={lb['band']}")
+        if parts and lb.get("parts"):
+            name += f" parts={lb['parts']}"
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 
@@ -460,6 +472,12 @@ def _rotary_embedding_grad(ins, attrs):
     return grads
 
 
+def _drops(attrs):
+    """Does the call drop attention probabilities (a rate, in training)?"""
+    return attrs.get("dropout_prob", 0.0) > 0.0 and not attrs.get(
+        "is_test", False)
+
+
 def _sdpa_config(ins, attrs, rng):
     """Shared fwd/grad config: (scale, p_drop, seed, family, dims).
 
@@ -471,16 +489,20 @@ def _sdpa_config(ins, attrs, rng):
     (b, tq, tk, h, dh, key/value heads, dv, q's itemsize), the dispatch
     record's shape (``_note_dispatch`` takes the first five alone too). K and V with fewer heads than Q (grouped-query attention) and
     V narrower than Q and K (dv != dh: latent attention) take the BHTD
-    layout only, no dropout, no mesh.
+    layout only, no dropout, no mesh. Where the kernels read QPe and KPe
+    as operands of their own (``_two_parts`` left them in ``ins``), dh
+    is the whole head's, Q's and QPe's features together.
     """
     from paddle_tpu.parallel import flash_attention as fa
 
     q, k, v = _x(ins, "Q"), _x(ins, "K"), _x(ins, "V")
+    q_pe = _x(ins, "QPe")
+    r = 0 if q_pe is None else q_pe.shape[-1]
     scale = attrs.get("scale", None)
     if scale is None:
-        scale = 1.0 / math.sqrt(jnp.shape(q)[-1])
+        scale = 1.0 / math.sqrt(jnp.shape(q)[-1] + r)
     p_drop = attrs.get("dropout_prob", 0.0)
-    training_dropout = p_drop > 0.0 and not attrs.get("is_test", False)
+    training_dropout = _drops(attrs)
     seed = None
     drop = 0.0
     if training_dropout:
@@ -497,6 +519,7 @@ def _sdpa_config(ins, attrs, rng):
         dims = (b, tq, tk, h, dh, h, dh)
     else:
         b, h, tq, dh = q.shape
+        dh += r
         tk, hk, dv = k.shape[2], k.shape[1], v.shape[3]
         itemsize = jnp.dtype(q.dtype).itemsize
         family = fa.bhtd_family(
@@ -515,8 +538,63 @@ def _sdpa_config(ins, attrs, rng):
     return scale, drop, seed, family, dims
 
 
+def _assemble(q, k, q_pe, k_pe):
+    """The wide queries and keys of a call given in two parts, [Q | QPe]
+    and [K | KPe]: the fewer of K's and KPe's heads copied up to the
+    other's (latent attention's ONE rotary key head, as often as there
+    are query heads). What the kernels spare a call whose parts they
+    read where they lie (``_two_parts``)."""
+    hk, hp = k.shape[1], k_pe.shape[1]
+    heads = max(hk, hp)
+    if heads % hk or heads % hp:
+        raise ValueError(f"scaled_dot_product_attention: K's {hk} heads "
+                         f"and KPe's {hp} do not divide one another")
+    return (jnp.concatenate([q, q_pe], -1),
+            jnp.concatenate([jnp.repeat(k, heads // hk, axis=1),
+                             jnp.repeat(k_pe, heads // hp, axis=1)], -1))
+
+
+def _two_parts(ins, attrs):
+    """-> (ins, parts, apart) of an sdpa or sdpa_grad call: ``parts``
+    None and ``ins`` as they are for a call without QPe and KPe; "own"
+    and ``ins`` as they are where ``attn.bhtd.fwd`` and the ONE
+    ``attn.bhtd.bwd`` read the parts as operands of their own
+    (``flash_attention.bhtd_parts``: a TPU, kernels on, no mesh, no
+    bias, dropout, window or block mask, one head a step); "assembled"
+    elsewhere, with Q and K in ``ins`` the wide ones (``_assemble``) and
+    no QPe and KPe, so that the rest of the op is the call in one part,
+    and ``apart`` (else None): the wide (dq, dk) -> the gradients of Q,
+    K, QPe and KPe, ``_assemble``'s transpose. The dispatch counter's
+    ``parts`` label says which."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    q_pe, k_pe = _x(ins, "QPe"), _x(ins, "KPe")
+    if q_pe is None and k_pe is None:
+        return ins, None, None
+    if q_pe is None or k_pe is None or attrs.get("layout", "bhtd") != "bhtd":
+        raise ValueError("scaled_dot_product_attention: QPe and KPe come "
+                         "together, with layout='bhtd'")
+    q, k, v = _x(ins, "Q"), _x(ins, "K"), _x(ins, "V")
+    (_, h, tq, dh), (_, hk, tk, _) = q.shape, k.shape
+    plain = (
+        attrs.get("use_pallas", True) and interp.spmd_ctx() is None
+        and _x(ins, "Bias") is None and not attrs.get("block_diffusion")
+        and not _drops(attrs)
+        and fa._band(attrs.get("window") or None,
+                     bool(attrs.get("causal", False)), tq, tk) is None)
+    if h % hk == 0 and fa.bhtd_parts(
+            h, tq, tk, dh=dh, r=q_pe.shape[3], hp=k_pe.shape[1],
+            group=h // hk, dv=v.shape[3],
+            itemsize=jnp.dtype(q.dtype).itemsize, plain=plain):
+        return ins, "own", None
+    (wide_q, wide_k), apart = jax.vjp(_assemble, q, k, q_pe, k_pe)
+    return {**{slot: x for slot, x in ins.items()
+               if slot not in ("QPe", "KPe")},
+            "Q": [wide_q], "K": [wide_k]}, "assembled", apart
+
+
 def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
-             form=None, causal=False, block_diffusion=None):
+             form=None, causal=False, block_diffusion=None, parts=None):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -530,7 +608,8 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     split = interp.mesh_batch_split()
     if split is None:
         _note_dispatch(family, direction, dims, window=window, form=form,
-                       causal=causal, block_diffusion=block_diffusion)
+                       causal=causal, block_diffusion=block_diffusion,
+                       parts=parts)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -545,7 +624,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     _note_dispatch(
         family, direction, (b // n,) + tuple(dims[1:]),
         sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window,
-        form, causal, block_diffusion)
+        form, causal, block_diffusion, parts)
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -602,8 +681,8 @@ def _ring_config(q, k):
     return _ring_config_t(q, k, 2)
 
 
-@register_op("scaled_dot_product_attention", diff_inputs=("Q", "K", "V"),
-             needs_rng=True)
+@register_op("scaled_dot_product_attention",
+             diff_inputs=("Q", "K", "V", "QPe", "KPe"), needs_rng=True)
 def _sdpa(ins, attrs, rng=None):
     """Fused attention: Q,K,V [b, h, t, dh] + optional additive Bias.
     V (and Out with it) may be narrower or wider than Q and K (latent
@@ -619,6 +698,21 @@ def _sdpa(ins, attrs, rng=None):
     blocks of B under block diffusion's training mask
     (``flash_attention.bd_visible``).
 
+    Optional inputs ``QPe`` [b, h, t, r] and ``KPe`` [b, hp, t, r], hp
+    dividing h (both or neither, layout bhtd): the queries and keys come
+    in TWO parts, the scores are scale * (Q K^T + QPe KPe^T) with query
+    head i reading KPe's head i // (h / hp), and the default scale is
+    1 / sqrt of both widths together; everything behind the scores is
+    as it is. Latent attention's call: Q | QPe the 128 features without
+    a position and the 64 rotated ones, KPe the ONE rotary key head all
+    query heads share. The BHTD kernels read the parts as operands of
+    their own where ``flash_attention.bhtd_parts`` takes the call;
+    elsewhere the op concatenates them and copies the shared head
+    itself and runs the call in one part (``_two_parts``), and the grad
+    op slices the wide gradients and sums the copies'. The dispatch
+    counter's ``parts`` label says which, so the fallback is never
+    silent.
+
     On TPU this routes to the Pallas flash-attention kernel
     (paddle_tpu/parallel/flash_attention.py), including training-time
     attention dropout, which runs inside the kernel from a per-step seed.
@@ -627,6 +721,7 @@ def _sdpa(ins, attrs, rng=None):
     grad op below can run the blocked backward kernels WITHOUT re-running
     the forward (XLA cannot CSE custom calls; DCE'd when unused).
     """
+    ins, parts, _ = _two_parts(ins, attrs)
     q, k, v = _x(ins, "Q"), _x(ins, "K"), _x(ins, "V")
     bias = _x(ins, "Bias")
     scale, drop, seed, family, dims = _sdpa_config(ins, attrs, rng)
@@ -639,7 +734,7 @@ def _sdpa(ins, attrs, rng=None):
     window = _windowed(attrs, q, k, bthd, ring)
     block = _block_masked(attrs, q, k, bthd, ring)
     if ring is not None:
-        _note_dispatch("ring", "fwd", dims)
+        _note_dispatch("ring", "fwd", dims, parts=parts)
         mesh, ctx_axis, data_axis = ring
         from paddle_tpu.parallel import ring_attention as ra
 
@@ -658,7 +753,7 @@ def _sdpa(ins, attrs, rng=None):
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif family == "dense":
         _note_dispatch("dense", "fwd", dims, window=window,
-                       block_diffusion=block)
+                       block_diffusion=block, parts=parts)
         sd = seed if drop > 0.0 else None
         if bthd:
             out = fa._reference_attention_bthd(
@@ -680,12 +775,14 @@ def _sdpa(ins, attrs, rng=None):
         # the custom-vjp wrapper makes the op differentiable through
         # jax.vjp too (scan-over-layers grad); the paired grad op below
         # remains the unrolled path's backward
+        # (QPe, KPe: None but where the kernels read the parts, "own")
         out, lse = _on_mesh(
             lambda q, k, v, bias, seed: fa.flash_attention_with_lse(
                 q, k, v, bias, seed, scale, float(drop), causal=causal,
-                window=window, block_diffusion=block),
+                window=window, block_diffusion=block,
+                q_pe=_x(ins, "QPe"), k_pe=_x(ins, "KPe")),
             (q, k, v, bias), seed, family, "fwd", dims, window,
-            block_diffusion=block)
+            block_diffusion=block, parts=parts)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -694,7 +791,11 @@ def _sdpa(ins, attrs, rng=None):
 def _sdpa_grad(ins, attrs, rng=None):
     """Blocked flash-attention backward consuming the forward's saved
     (Out, Lse) — no forward re-execution (cf. the auto vjp path, which
-    would re-run the kernel because custom calls are opaque to CSE)."""
+    would re-run the kernel because custom calls are opaque to CSE).
+    A call in two parts (QPe, KPe) also gives GRAD::QPe and GRAD::KPe:
+    the ONE call's own where the kernels read the parts, else the wide
+    gradients' slices with the copied heads' summed (``_two_parts``)."""
+    ins, parts, apart = _two_parts(ins, attrs)
     q, k, v = _x(ins, "Q"), _x(ins, "K"), _x(ins, "V")
     bias = _x(ins, "Bias")
     out, lse = _x(ins, "Out"), _x(ins, "Lse")
@@ -709,7 +810,7 @@ def _sdpa_grad(ins, attrs, rng=None):
     window = _windowed(attrs, q, k, bthd, ring)
     block = _block_masked(attrs, q, k, bthd, ring)
     if ring is not None:
-        _note_dispatch("ring", "bwd", dims)
+        _note_dispatch("ring", "bwd", dims, parts=parts)
         mesh, ctx_axis, data_axis = ring
         from paddle_tpu.parallel import ring_attention as ra
 
@@ -731,7 +832,7 @@ def _sdpa_grad(ins, attrs, rng=None):
         dq, dk, dv = vjp(g.astype(q.dtype))
     elif family == "dense":
         _note_dispatch("dense", "bwd", dims, window=window,
-                       block_diffusion=block)
+                       block_diffusion=block, parts=parts)
         sd = seed if drop > 0.0 else None
         if bthd:
             eff_bias = fa._combined_causal_bias(
@@ -758,10 +859,18 @@ def _sdpa_grad(ins, attrs, rng=None):
             dims[3], dims[1], dims[2], dh=dims[4], group=dims[3] // dims[5],
             dv=dims[6], itemsize=q.dtype.itemsize, p_drop=drop,
             block_diffusion=block)
-        dq, dk, dv = _on_mesh(
+        pe = {} if parts != "own" else dict(q_pe=_x(ins, "QPe"),
+                                            k_pe=_x(ins, "KPe"))
+        dq, dk, dv, *d_pe = _on_mesh(
             lambda q, k, v, bias, out, lse, g, seed: bwd(
                 q, k, v, bias, seed, out, lse, g, scale=scale,
-                p_drop=drop, causal=causal),
+                p_drop=drop, causal=causal, **pe),
             (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
-            "bwd", dims, window, form, causal=causal, block_diffusion=block)
-    return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}
+            "bwd", dims, window, form, causal=causal, block_diffusion=block,
+            parts=parts)
+    if apart is not None:
+        dq, dk, *d_pe = apart((dq, dk))
+    grads = {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}
+    if parts is not None:
+        grads["GRAD::QPe"], grads["GRAD::KPe"] = [d_pe[0]], [d_pe[1]]
+    return grads

@@ -104,6 +104,18 @@ ridge of 240.
   in every kernel (and of the head group, where hb < h), so forward and
   backward see identical masks and nothing is stored.
 
+- Queries and keys in TWO parts (``q_pe`` [b, h, t, r], ``k_pe`` [b, hp,
+  t, r] beside q and k: latent attention's rotary features, the keys'
+  ONE head shared by all query heads): ``attn.bhtd.fwd`` and the ONE
+  ``attn.bhtd.bwd`` take the parts as operands of their own where
+  ``bhtd_parts`` says so, at the tile and the form of the call with
+  heads of dh + r, and read a shared head of k_pe through its index map
+  (``_row_specs``: query head // (h / hp), as a group's K and V). No
+  wide q or k exists in HBM, no copy of the shared head, and the
+  backward writes dq_pe and a dk_pe a query head beside dq and dk. The
+  forward's scores are two products in one float32 sum; the backward
+  puts a step's blocks together in VMEM and is the wide call's step.
+
 ``bias`` is additive [b, 1|h, 1|tq, tk] mask plumbing, NOT a trainable
 input: its cotangent is zeros on the Pallas path (computing it would
 materialize a t x t gradient). Use the dense composition for a learnable
@@ -397,6 +409,26 @@ def bhtd_bwd_form(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
     if _fused_fits(tile, tq, tk, dh, dv, group, itemsize, p_drop):
         return "fused"
     return "split"
+
+
+def bhtd_parts(h, tq, tk, q_block=None, k_block=None, *, dh, r, hp,
+               group=1, dv=None, itemsize=2, plain=True):
+    """Do ``attn.bhtd.fwd`` and the ONE ``attn.bhtd.bwd`` take this call's
+    queries and keys in TWO parts each, Q | QPe of dh | r features a
+    head and K | KPe with KPe's hp heads shared by h / hp query heads
+    each (latent attention: 128 | 64, ONE rotary key head), as operands
+    of their own, so that nobody assembles a wide q or copies a shared
+    head? Where the call is ``plain`` (the caller's word: no bias, no
+    dropout, no window, no block mask; causal or not), hp divides h, K
+    has a head a query head (``group`` 1: the backward gathers dk_pe,
+    a query head's own, in the k-row's scratch that gathers dk) and the
+    call at dh + r features has a tile whose backward is the ONE call
+    (one head a step). The one place that decides: the entry
+    points, the sdpa op's fallback (which assembles q and k where this
+    says no) and the dispatch counter's ``parts`` label read it."""
+    return bool(plain and h % hp == 0 and group == 1 and bhtd_bwd_form(
+        h, tq, tk, q_block, k_block, dh=dh + r, dv=dv or dh,
+        itemsize=itemsize) == "fused")
 
 
 # ---------------------------------------------------------------------------
@@ -753,8 +785,10 @@ def _lanes(x, n):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, nk, ng, p_drop,
-                causal=False, window=None, bd=None):
+                causal=False, window=None, bd=None, pe_refs=None):
     # r: the inner axis's step, nk of them; kk the k-block it works on
+    # (``pe_refs``: blocks of QPe and KPe where q and k come in two parts,
+    # _call_parts)
     j, r = pl.program_id(2), pl.program_id(3)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
     if bd is not None:
@@ -775,7 +809,12 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale
+        )
+        if pe_refs is not None:     # q k^T + q_pe k_pe^T, one float32 sum
+            s = s + jax.lax.dot_general(
+                pe_refs[0][0], pe_refs[1][0], (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+        s = s * scale
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
         if masked and bd is not None:
@@ -1021,9 +1060,8 @@ def _each_block(acc, rows, body):
 
 
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                *, scale, nq, nk, group, causal=False, window=None,
-                last_q=None, slabs=None, bd=None):
+                delta_ref, *grads, scale, nq, nk, group, causal=False,
+                window=None, last_q=None, slabs=None, bd=None, pe_refs=None):
     """attn.bhtd.bwd: grid (batch row, key/value head, member of its
     group, k-block, step), one head a step: the dk/dv kernel's walk, a
     k-row's ``nq`` steps over the q-blocks (with a window: over its
@@ -1035,7 +1073,18 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     group shares a key/value head, dk and dv [tk, .] for the group
     (under one head a step they gather in a k-row's scratch, as in
     _dkv_kernel). Each is zeroed at the first step of what it gathers
-    and written, once, at the last."""
+    and written, once, at the last. ``grads``: the output refs of dq,
+    dk and dv, then their three float32 accumulators. Where q and k come
+    in two parts (``pe_refs``: blocks of QPe and KPe, _call_parts; one
+    key head a query head, ``bhtd_parts``) a step puts its blocks
+    together in VMEM, [q | q_pe] and [k | k_pe], and is the wide call's
+    step from there: the same five products at the same shapes, so the
+    same bits. The accumulators of dq and dk are as wide as both parts,
+    and at the end their lanes go to two results each: ``grads`` then
+    holds the refs of dq_pe and dk_pe (a QUERY head's own block of it)
+    behind dv's. (Measured against the parts as products of their own, 8
+    a block, with accumulators of their own: 3.96 ms a call for 4.03 at
+    [1, 32, 4096, 128 | 64] alone on a v5e, PR 70.)"""
     del seed_ref                        # (no dropout: bhtd_bwd_form)
     m, kk, r = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
@@ -1057,11 +1106,18 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             last = jnp.logical_and(last, m == group - 1)
         return first, last
 
-    # (accumulator, its output, rows of a block, the block a step adds
+    *outs, dq_acc, dk_acc, dv_acc = grads
+    # the results an accumulator is written to, each with its lanes of it
+    dq_out, dk_out, dv_out = (((out, slice(None)),) for out in outs[:3])
+    if pe_refs is not None:
+        own, pe = slice(0, q_ref.shape[3]), slice(q_ref.shape[3], None)
+        dq_out = ((outs[0], own), (outs[3], pe))
+        dk_out = ((outs[1], own), (outs[4], pe))
+    # (accumulator, its outputs, rows of a block, the block a step adds
     # to, its first and last step)
-    accs = ((dq_acc, dq_ref, bq, j, span(True, False)),
-            (dk_acc, dk_ref, bk, kk, span(group > 1, True)),
-            (dv_acc, dv_ref, bk, kk, span(group > 1, True)))
+    accs = ((dq_acc, dq_out, bq, j, span(True, False)),
+            (dk_acc, dk_out, bk, kk, span(group > 1, True)),
+            (dv_acc, dv_out, bk, kk, span(group > 1, True)))
 
     for acc, _, rows, _, (first, _) in accs:
         def _zero(at, acc=acc, rows=rows):
@@ -1083,8 +1139,12 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         if bias_ref is not None:
             per_row = bias_ref.shape[2] > 1
             bias = bias_ref[0, 0, qs if per_row else slice(None), ks]
+        q, k = q_ref[0, 0, qs, :], k_ref[0, 0, ks, :]
+        if pe_refs is not None:
+            q = jnp.concatenate([q, pe_refs[0][0, 0, qs, :]], axis=-1)
+            k = jnp.concatenate([k, pe_refs[1][0, 0, ks, :]], axis=-1)
         parts = _bwd_block(
-            q_ref[0, 0, qs, :], k_ref[0, 0, ks, :], v_ref[0, 0, ks, :],
+            q, k, v_ref[0, 0, ks, :],
             do_ref[0, 0, qs, :], lse_ref[0, 0, :, qs], delta_ref[0, 0, :, qs],
             bias, scale, mask)
         for (acc, _, rows, idx, _), part, inside in zip(
@@ -1101,9 +1161,10 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     else:
         _compute()
 
-    for acc, out_ref, rows, _, (_, last) in accs:
-        def _write(at, acc=acc, out_ref=out_ref):
-            out_ref[0, 0, at, :] = acc[at, :].astype(out_ref.dtype)
+    for acc, acc_outs, rows, _, (_, last) in accs:
+        def _write(at, acc=acc, acc_outs=acc_outs):
+            for out_ref, lanes in acc_outs:
+                out_ref[0, 0, at, :] = acc[at, lanes].astype(out_ref.dtype)
         pl.when(last)(functools.partial(_each_block, acc, rows, _write))
 
 
@@ -1148,23 +1209,32 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
 
 class _Specs(NamedTuple):
     """_row_specs' answer: the specs of q, a [b, h, tq, 1] statistic, the
-    same statistic as [b, h, 1, tq] rows, k, out and v."""
+    same statistic as [b, h, 1, tq] rows, k, out and v; where q and k
+    come in two parts, of QPe, KPe and KPe's gradient a query head."""
     q: pl.BlockSpec
     stat: pl.BlockSpec
     row: pl.BlockSpec
     k: pl.BlockSpec
     o: pl.BlockSpec
     v: pl.BlockSpec
+    q_pe: Optional[pl.BlockSpec] = None
+    k_pe: Optional[pl.BlockSpec] = None
+    dk_pe: Optional[pl.BlockSpec] = None
 
 
-def _row_specs(at, hb, bq, bk, dh, group=1, dv=None):
+def _row_specs(at, hb, bq, bk, dh, group=1, dv=None, pe=None):
     """Specs read at ``at``'s blocks: a (1, hb, bq, dh) block of q or its
     gradient; a (1, hb, bq, 1) block of a [b, h, tq, 1] statistic;
     a (1, hb, 1, bq) block of the same statistic laid out [b, h, 1, tq];
     a (1, hb, bk, dh) block of k or its gradient (``group`` > 1: of the
     key/value head the step's query head reads); and (1, hb, bq, dv) /
     (1, hb, bk, dv) blocks of out and v or their gradients (``dv``: dh
-    where the call has one width)."""
+    where the call has one width). ``pe`` = (r, query heads a head of
+    KPe) where q and k come in two parts (one head a step, a key head a
+    query head): a (1, 1, bq, r) block of QPe or its gradient at q's
+    index, a (1, 1, bk, r) block of KPe at the head the step's query
+    head reads (nothing is copied), and the same block of KPe's gradient
+    at k's index, the QUERY head's."""
     dv = dv or dh
     def q_idx(*ids):
         i, g, j, _ = at(*ids)
@@ -1178,12 +1248,24 @@ def _row_specs(at, hb, bq, bk, dh, group=1, dv=None):
         i, g, _, kk = at(*ids)
         return i, g if group == 1 else g // group, kk, 0
 
-    return _Specs(q=pl.BlockSpec((1, hb, bq, dh), q_idx),
-                  stat=pl.BlockSpec((1, hb, bq, 1), q_idx),
-                  row=pl.BlockSpec((1, hb, 1, bq), row_idx),
-                  k=pl.BlockSpec((1, hb, bk, dh), k_idx),
-                  o=pl.BlockSpec((1, hb, bq, dv), q_idx),
-                  v=pl.BlockSpec((1, hb, bk, dv), k_idx))
+    specs = _Specs(q=pl.BlockSpec((1, hb, bq, dh), q_idx),
+                   stat=pl.BlockSpec((1, hb, bq, 1), q_idx),
+                   row=pl.BlockSpec((1, hb, 1, bq), row_idx),
+                   k=pl.BlockSpec((1, hb, bk, dh), k_idx),
+                   o=pl.BlockSpec((1, hb, bq, dv), q_idx),
+                   v=pl.BlockSpec((1, hb, bk, dv), k_idx))
+    if pe is None:
+        return specs
+    r, pe_group = pe
+
+    def k_pe_idx(*ids):
+        i, g, _, kk = at(*ids)
+        return i, g // pe_group, kk, 0
+
+    return specs._replace(
+        q_pe=pl.BlockSpec((1, 1, bq, r), q_idx),
+        k_pe=pl.BlockSpec((1, 1, bk, r), k_pe_idx),
+        dk_pe=pl.BlockSpec((1, 1, bk, r), k_idx))
 
 
 def _bias_spec(bias, at, hb, bq, bk):
@@ -1298,14 +1380,23 @@ def _seed_cotangent(seed):
 # ---------------------------------------------------------------------------
 
 
-def _call_parts(kernel, at, tile, q, k, v, bias):
+def _call_parts(kernel, at, tile, q, k, v, bias, pe=None):
     """What the three calls share: -> (the kernel, the specs and the
     operands of q, k, v and the bias if there is one, _row_specs). With
-    no bias the kernel's bias_ref slot (the fifth) is None."""
+    no bias the kernel's bias_ref slot (the fifth) is None. ``pe`` =
+    (QPe, KPe): two more operands behind v, which the kernel gets as
+    ``pe_refs`` (such a call has no bias: ``bhtd_parts``)."""
     rows = _row_specs(at, *tile, q.shape[3], q.shape[1] // k.shape[1],
-                      v.shape[3])
+                      v.shape[3],
+                      pe and (pe[0].shape[3], q.shape[1] // pe[1].shape[1]))
     specs, args = [rows.q, rows.k, rows.v], [q, k, v]
-    if bias is None:
+    if pe is not None:
+        body = kernel
+        kernel = lambda *refs, **kw: body(*refs[:4], None, *refs[6:],
+                                          pe_refs=refs[4:6], **kw)
+        specs += [rows.q_pe, rows.k_pe]
+        args += pe
+    elif bias is None:
         body = kernel
         kernel = lambda *refs, **kw: body(*refs[:4], None, *refs[4:], **kw)
     else:
@@ -1357,13 +1448,39 @@ def _q_steps(window, nq, nk, bq, bk):
         ((kk + 1) * bk + window - 2) // bq, nq - 1))
 
 
+def _two_parts(q, k, v, q_pe, k_pe, q_block, k_block, *not_plain):
+    """(q_pe, k_pe) of a call whose queries and keys come in two parts,
+    None for a call in one; an error where the kernels do not take the
+    parts (``bhtd_parts``: the caller assembles q and k then).
+    ``not_plain``: the call's bias, dropout rate, window and block mask,
+    as the entry points hold them."""
+    if q_pe is None and k_pe is None:
+        return None
+    if q_pe is None or k_pe is None:
+        raise ValueError("attention: q_pe and k_pe come together")
+    h, r, hp = q.shape[1], q_pe.shape[3], k_pe.shape[1]
+    if not (q_pe.shape == q.shape[:3] + (r,) and q_pe.dtype == q.dtype
+            and k_pe.shape == (k.shape[0], hp, k.shape[2], r)
+            and k_pe.dtype == k.dtype and bhtd_parts(
+                h, q.shape[2], k.shape[2], q_block, k_block, dh=q.shape[3],
+                r=r, hp=hp, group=h // k.shape[1], dv=v.shape[3],
+                itemsize=q.dtype.itemsize, plain=not any(not_plain))):
+        raise ValueError(
+            f"attention: the kernels do not take q_pe {q_pe.shape} "
+            f"{q_pe.dtype} and k_pe {k_pe.shape} {k_pe.dtype} beside q "
+            f"{q.shape} and k {k.shape} as operands of their own "
+            f"(flash_attention.bhtd_parts)")
+    return q_pe, k_pe
+
+
 def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
                         p_drop: float = 0.0,
                         q_block: Optional[int] = None,
                         k_block: Optional[int] = None,
                         causal: bool = False,
                         window: Optional[int] = None,
-                        block_diffusion: Optional[int] = None):
+                        block_diffusion: Optional[int] = None,
+                        q_pe=None, k_pe=None):
     """-> (out, lse) with lse [b, h, tq, 1] f32 — REAL logsumexp rows on
     every path including the dense fallback (the ring-attention merge
     consumes them; the fallback backward still recomputes via vjp).
@@ -1386,20 +1503,28 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     band and no block outside it is a step at all. ``block_diffusion``
     (with neither): the row is a noised and a clean copy in blocks of
     that many positions, under block diffusion's mask (module
-    docstring)."""
+    docstring). ``q_pe`` [b, h, tq, r], ``k_pe`` [b, hp, tk, r]: the
+    second part of the queries and keys where they come in two, the
+    scores scale * (q k^T + q_pe k_pe^T) with query head i reading
+    k_pe's head i // (h / hp), and the default scale 1 / sqrt(dh + r);
+    only a call ``bhtd_parts`` takes."""
     if p_drop > 0.0 and seed is None:
         raise ValueError(
             "flash_attention: p_drop > 0 requires a per-step `seed`; "
             "without one the SAME mask would be applied every step, which "
             "is not dropout"
         )
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
     bd = _halves(block_diffusion, causal, window, tq, tk)
     window = _band(window, causal, tq, tk)
+    pe = _two_parts(q, k, v, q_pe, k_pe, q_block, k_block, bias is not None,
+                    p_drop, window, bd)
+    if pe is not None:      # (the tile and the scale of the WHOLE head)
+        dh += q_pe.shape[3]
+    if scale is None:
+        scale = 1.0 / math.sqrt(dh)
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
                      block_diffusion=block_diffusion,
                      itemsize=q.dtype.itemsize)
@@ -1421,7 +1546,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     kernel, in_specs, args, rows = _call_parts(
         _fwd_kernel,
         _step_blocks(causal, True, bq, bk, nq, group, window, nk, bd), tile,
-        q, k, v, bias)
+        q, k, v, bias, pe)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
                                p_drop=p_drop, causal=causal, window=window,
                                bd=bd)
@@ -1454,10 +1579,11 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
 
 
 def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
-               window, bd=None):
+               window, bd=None, pe=None):
     """dq, dk, dv as ONE call (_bwd_kernel); ``delta`` with the lse
     cotangent folded in, ``window`` as _band gives it, ``bd`` as
-    _halves."""
+    _halves. ``pe`` = (q_pe, k_pe): dq_pe and dk_pe [b, h, tk, r], a
+    QUERY head's each, behind them."""
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
@@ -1473,7 +1599,7 @@ def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
         return block_of(i, hk * group + m, kk, r)
 
     kernel, specs, args, rows = _call_parts(_bwd_kernel, at, tile, q, k, v,
-                                            bias)
+                                            bias, pe)
     sub = bhtd_edge_tile(tile, causal)
     kernel = functools.partial(
         kernel, scale=scale, nq=q_steps, nk=nk, group=group, causal=causal,
@@ -1491,26 +1617,30 @@ def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
     # lse and delta as [b, h, 1, tq] rows, as the dk/dv kernel takes them
     operands = (seed_arr, *args, g, lse.reshape(b, h, 1, tq),
                 delta.reshape(b, h, 1, tq))
+    # (a gradient's output block and its result)
+    grads = [(dq_spec, q), (dk_spec, k), (dv_spec, v)]
+    r = 0
+    if pe is not None:      # (group 1: bhtd_parts)
+        r = pe[0].shape[3]
+        grads += [
+            (pl.BlockSpec((1, 1, tq, r), dq_spec.index_map), pe[0]),
+            (rows.dk_pe, jax.ShapeDtypeStruct((b, h, tk, r), pe[1].dtype))]
     return pl.pallas_call(
         kernel, name="attn.bhtd.bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h // group, group, nk, q_steps),
             in_specs=specs + [rows.o, rows.row, rows.row],
-            out_specs=[dq_spec, dk_spec, dv_spec],
+            out_specs=[spec for spec, _ in grads],
             scratch_shapes=[
-                pltpu.VMEM((tq, dh), jnp.float32),
-                pltpu.VMEM((kv_rows, dh), jnp.float32),
+                pltpu.VMEM((tq, dh + r), jnp.float32),
+                pltpu.VMEM((kv_rows, dh + r), jnp.float32),
                 pltpu.VMEM((kv_rows, dv), jnp.float32)],
         ),
-        out_shape=[
-            _result(operands, q.shape, q.dtype),
-            _result(operands, k.shape, k.dtype),
-            _result(operands, v.shape, v.dtype),
-        ],
+        out_shape=[_result(operands, x.shape, x.dtype) for _, x in grads],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_bwd_vmem_limit(
-                tq, tk, dh, dv, group, bq, bk, q.dtype.itemsize)),
+                tq, tk, dh + r, dv, group, bq, bk, q.dtype.itemsize)),
         interpret=_INTERPRET,
     )(*operands)
 
@@ -1521,8 +1651,14 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                         k_block: Optional[int] = None,
                         causal: bool = False, g_lse=None,
                         window: Optional[int] = None,
-                        block_diffusion: Optional[int] = None):
-    """-> (dq, dk, dv), consuming the forward's saved (out, lse).
+                        block_diffusion: Optional[int] = None,
+                        q_pe=None, k_pe=None):
+    """-> (dq, dk, dv), consuming the forward's saved (out, lse); of a
+    call in two parts (``q_pe``, ``k_pe``: ``flash_attention_fwd``) ->
+    (dq, dk, dv, dq_pe, dk_pe). The kernel writes dk_pe a QUERY head, in
+    the call's dtype, and XLA sums the heads that share a head of k_pe
+    behind it: the rounding and the sum of a shared head copied h / hp
+    times.
 
     ``g_lse``: optional cotangent of the lse OUTPUT ([b, h, tq, 1]).
     The lse rows are a real differentiated quantity for consumers like
@@ -1530,13 +1666,17 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     dlse/ds = p, so the lse cotangent phi folds EXACTLY into the
     existing backward as ds = p*(dp - (delta - phi)) — one subtraction
     on the per-row delta, no kernel changes."""
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
     bd = _halves(block_diffusion, causal, window, tq, tk)
     window = _band(window, causal, tq, tk)
+    pe = _two_parts(q, k, v, q_pe, k_pe, q_block, k_block, bias is not None,
+                    p_drop, window, bd)
+    if pe is not None:      # (the tile and the scale of the WHOLE head)
+        dh += q_pe.shape[3]
+    if scale is None:
+        scale = 1.0 / math.sqrt(dh)
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
                      block_diffusion=block_diffusion,
                      itemsize=q.dtype.itemsize)
@@ -1558,6 +1698,12 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     seed_arr = _seed_arr(seed)
+    if pe is not None:      # (bhtd_parts: the ONE call)
+        *grads, dk_pe = _fused_bwd(q, k, v, None, seed_arr, g, lse, delta,
+                                   tile, scale, causal, None, pe=pe)
+        hp = k_pe.shape[1]
+        return (*grads, jnp.sum(
+            dk_pe.reshape(b, hp, h // hp, tk, -1), axis=2))
     # (a block-masked call has a tile only where it is fused and has no
     # dropout: bhtd_tile)
     if bd is not None or _fused_fits(tile, tq, tk, dh, dv, group,
@@ -1730,31 +1876,42 @@ def flash_attention_with_lse(q, k, v, bias=None, seed=None,
                              k_block: Optional[int] = None,
                              causal: bool = False,
                              window: Optional[int] = None,
-                             block_diffusion: Optional[int] = None):
+                             block_diffusion: Optional[int] = None,
+                             q_pe=None, k_pe=None):
     """(out, lse) variant of ``flash_attention`` — same backward rule
     (shared ``_vjp_bwd``: blocked Pallas kernels, true dbias on the dense
     fallback, float0 seed cotangent). The sdpa op uses this so its saved
     Lse output exists AND jax.vjp through the op (scan-over-layers grad)
-    works despite pallas_call having no JVP rule."""
+    works despite pallas_call having no JVP rule. ``q_pe``, ``k_pe``:
+    the queries' and keys' second part (``flash_attention_fwd``), with
+    cotangents of their own."""
     return flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
                                q_block, k_block, causal, window,
-                               block_diffusion)
+                               block_diffusion, q_pe, k_pe)
 
 
 def _fa_lse_vjp_fwd(q, k, v, bias, seed, scale, p_drop, q_block, k_block,
-                    causal=False, window=None, block_diffusion=None):
+                    causal=False, window=None, block_diffusion=None,
+                    q_pe=None, k_pe=None):
     out, lse = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
                                    q_block, k_block, causal, window,
-                                   block_diffusion)
-    return (out, lse), (q, k, v, bias, seed, out, lse)
+                                   block_diffusion, q_pe, k_pe)
+    return (out, lse), (q, k, v, bias, seed, out, lse, q_pe, k_pe)
 
 
 def _fa_lse_vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
                     block_diffusion, res, gs):
     g, g_lse = gs
-    q = res[0]
-    return _vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
-                    block_diffusion, res, g.astype(q.dtype), g_lse=g_lse)
+    *res, q_pe, k_pe = res
+    q, k, v, bias, seed, out, lse = res
+    if q_pe is None:
+        return (*_vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
+                          block_diffusion, res, g.astype(q.dtype),
+                          g_lse=g_lse), None, None)
+    dq, dk, dv, dq_pe, dk_pe = flash_attention_bwd(
+        q, k, v, bias, seed, out, lse, g.astype(q.dtype), scale, p_drop,
+        q_block, k_block, causal, g_lse=g_lse, q_pe=q_pe, k_pe=k_pe)
+    return dq, dk, dv, None, _seed_cotangent(seed), dq_pe, dk_pe
 
 
 flash_attention_with_lse.defvjp(_fa_lse_vjp_fwd, _fa_lse_vjp_bwd)

@@ -295,6 +295,41 @@ def test_latent_attention_compiles(one_chip, real_kernels):
         == b * h * t * (2 * dk + dv) * 2
 
 
+def test_latent_attention_in_two_parts_compiles(one_chip, real_kernels):
+    """The latent cells' call since PR 70: q and k of 128 features, QPe
+    [b, 32, t, 64] and KPe's ONE head [b, 1, t, 64] as operands of the
+    kernels' own, at the wide call's tile and form; nothing 192 or 256
+    wide exists, no copy of the shared head ([b, 32, t, 64] keys), and
+    the backward's results are dq, dk, dv, dq_pe and a dk_pe a QUERY
+    head, which XLA sums into KPe's one head."""
+    b, h, t, dh, r, dv = 1, 32, 4096, 128, 64, 128
+    assert fa.bhtd_parts(h, t, t, dh=dh, r=r, hp=1, dv=dv)
+
+    def arg(heads, width):
+        return jax.ShapeDtypeStruct((b, heads, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v, q_pe, k_pe):
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=True,
+                                               q_pe=q_pe, k_pe=k_pe)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg(h, dh), arg(h, dh), arg(h, dv), arg(h, r), arg(1, r)).compile()
+    text = compiled.as_text()
+    _holds_the_calls(text, "fused")
+    assert "4096,192]" not in text and "4096,256]" not in text
+    bwd = next(line for line in text.splitlines()
+               if "custom-call(" in line and "attn.bhtd.bwd" in line)
+    results = bwd.split(" custom-call(")[0]
+    assert results.count("bf16[1,32,4096,128]") == 3
+    assert results.count("bf16[1,32,4096,64]") == 2
+    assert "bf16[1,1,4096,64]" in text      # KPe and its summed gradient
+    # q, k, v, QPe and KPe in, no padded or copied one among them
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        == b * t * (h * (2 * dh + dv + r) + r) * 2
+
+
 def test_held_share_grouped_matmuls_compile(one_chip, real_kernels):
     """One chip's 32 of 512 experts: a buffer of 81,920 rows of which an
     even router fills 5,120, so the row tile is 128."""

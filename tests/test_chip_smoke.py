@@ -342,8 +342,10 @@ def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     device (here: the CPU) the kernels agree with the dense
     composition."""
     row = phase_row("mla")
+    # (since PR 70 the kernels read QPe and KPe's one head themselves)
     assert row["attention"] == {
-        f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}": 3
+        f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}"
+        " parts=own": 3
         for d, form in (("bwd", " form=fused"), ("fwd", " stats=rows"))}
     assert list(row["routers"]) == ["score=sigmoid bias=1 k=2 experts=8"]
     assert sum(row["grouped_matmuls"].values()) == 18
@@ -363,6 +365,13 @@ def test_mla_phase_fails_on_a_split_backward_call(phase_row, monkeypatch):
     fails("mla", "none split", **dict(
         row, attention={k.replace("form=fused", "form=split"): v
                         for k, v in two.items()}))
+
+
+def test_mla_phase_fails_on_a_latent_call_the_op_assembled(phase_row):
+    # the fused kernels took the call, but with a wide q and k
+    row = phase_row("mla")
+    fails("mla", "assembled by the op", **dict(row, attention=renamed(
+        row["attention"], "parts=own", "parts=assembled")))
 
 
 def test_mla_phase_fails_on_a_dense_attention_call(phase_row):
@@ -743,7 +752,8 @@ def test_kda_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     assert row["conv"] == {f"kernel {d} b1 t512 c768 taps4": 2
                            for d in ("fwd", "bwd")}
     assert row["attention"] == {
-        f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}": 1
+        f"bhtd {d} b1 tq512 tk512 h1 dk192 dv128 [hb1 bq256 bk256]{form}"
+        " parts=own": 1
         for d, form in (("bwd", " form=fused"), ("fwd", " stats=rows"))}
     assert row["rotary_embeddings"] == {} and row["kda_kernel_ms"] == {}
     assert set(row["rel_err"]) == {

@@ -293,6 +293,67 @@ def test_latent_attention_at_192_over_128_matches_dense(t):
                                    err_msg=name)
 
 
+def test_latent_attention_in_two_parts_is_the_wide_call(capsys):
+    # The latent cells' call since PR 70: q and k of 128 features, QPe
+    # [b, 32, t, 64] and KPe's ONE head [b, 1, t, 64] as operands of the
+    # kernels' own, against the wide call of [q | q_pe] and [k | k_pe
+    # copied 32 times] through the same kernels: bf16 on both sides, the
+    # same MXU passes, so out and every gradient's slice agree to a bf16
+    # rounding of a sum in another order; dk_pe against the copies'
+    # summed slice. Prints a call's ms each way.
+    import time
+
+    b, h, t, dh, r, dv = 1, 32, 4096, 128, 64, 128
+    assert fa.bhtd_parts(h, t, t, dh=dh, r=r, hp=1, dv=dv)
+    rs = np.random.RandomState(9)
+
+    def draw(heads, width, s=0.5):
+        return jnp.asarray(rs.normal(0, s, (b, heads, t, width))).astype(
+            jnp.bfloat16)
+
+    q, k, v, q_pe, k_pe = (draw(h, dh), draw(h, dh), draw(h, dv),
+                           draw(h, r), draw(1, r))
+    g = draw(h, dv, 1.0)
+
+    @jax.jit
+    def own(q, k, v, q_pe, k_pe, g):
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=True, q_pe=q_pe,
+                                          k_pe=k_pe)
+        return (out, lse, *fa.flash_attention_bwd(
+            q, k, v, None, None, out, lse, g, causal=True, q_pe=q_pe,
+            k_pe=k_pe))
+
+    @jax.jit
+    def wide(q, k, v, q_pe, k_pe, g):
+        wq = jnp.concatenate([q, q_pe], -1)
+        wk = jnp.concatenate([k, jnp.tile(k_pe, (1, h, 1, 1))], -1)
+        out, lse = fa.flash_attention_fwd(wq, wk, v, causal=True)
+        dq, dk, dv_ = fa.flash_attention_bwd(wq, wk, v, None, None, out, lse,
+                                             g, causal=True)
+        return (out, lse, dq[..., :dh], dk[..., :dh], dv_, dq[..., dh:],
+                jnp.sum(dk[..., dh:], axis=1, keepdims=True))
+
+    args = (q, k, v, q_pe, k_pe, g)
+    got, want = own(*args), wide(*args)
+    ms = {}
+    for name, f in (("own", own), ("wide", wide)):
+        jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            res = f(*args)
+        jax.block_until_ready(res)
+        ms[name] = (time.perf_counter() - t0) * 100.0
+    with capsys.disabled():
+        print(f"\nlatent call, fwd + bwd, ms: parts as operands "
+              f"{ms['own']:.3f}, assembled by XLA in front {ms['wide']:.3f}")
+    for name, a, b_ in zip(("out", "lse", "dq", "dk", "dv", "dq_pe", "dk_pe"),
+                           got, want):
+        assert a.shape == b_.shape and a.dtype == b_.dtype, name
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        np.testing.assert_allclose(a, b_, atol=0.01 * np.abs(b_).max(),
+                                   err_msg=name)
+
+
 # --- in-kernel dropout: determinism, keep-rate, exact-linear dv ---
 
 

@@ -260,10 +260,11 @@ def test_no_low_rank_and_no_rotation_leave_the_ops_out():
         q_lora_rank=24, rope_theta=1e4).global_block().ops]
     assert "rotary_embedding" in with_both \
         and "rotary_embedding" not in kinds
-    # no rotation: q needs no split and no concat (k keeps its concat
-    # with the shared head's copies, and kva and kv their splits)
-    assert with_both.count("split") - kinds.count("split") == 1
-    assert with_both.count("concat") - kinds.count("concat") == 1
+    # no rotation: the same three splits (kva, q, kv: since PR 70 the
+    # sdpa op takes q and k in two parts either way), and neither form
+    # copies the shared head or assembles a wide q or k
+    assert with_both.count("split") == kinds.count("split") == 3
+    assert not {"concat", "expand"} & set(with_both + kinds)
     # no low rank: one projection and one norm fewer
     assert with_both.count("mul") - kinds.count("mul") == 1
     assert with_both.count("rms_norm") - kinds.count("rms_norm") == 1
