@@ -25,7 +25,10 @@ points a user calls:
    ``xing4`` phase lowers ``xing4-train-s4096`` (a mix, a read and a
    write-back of four residual streams a sublayer each way, the mixes'
    Sinkhorn iterations on the ``hc.mix.*`` kernels, yarn tables) and
-   runs the kernels against XLA's ops; the
+   runs the kernels against XLA's ops; the ``keye`` phase lowers
+   ``keye-train-s16384`` (a ``dsa_select`` a layer on ``dsa.score.fwd``,
+   the attention under its selection in the BHTD kernels, the indexer's
+   loss) and runs both against their plain forms; the
    ``loss_head`` phase compiles a Program that is only
    ``olmoe-train-s4096``'s head and holds its temporaries under the
    float32 [tokens, vocab] tensor the loss op no longer writes;
@@ -130,12 +133,14 @@ def attention_dispatch():
     "... form=fused edge=256x256", a forward row the layout in which its
     logsumexp leaves the kernel: "... stats=rows", a block-masked row
     its mask: "... mask=block_diffusion block=4 band=skip", a row of a
-    call given q and k in two parts who read them: "... parts=own"
+    call given q and k in two parts who read them: "... parts=own", a
+    row of a call under a selection who read it: "... sel=operand"
     (pt_attention_dispatch_total)."""
     from paddle_tpu.ops import attention_ops
 
     return attention_ops.dispatch_counts(tiles=True, forms=True, edges=True,
-                                         stats=True, masks=True, parts=True)
+                                         stats=True, masks=True, parts=True,
+                                         sels=True)
 
 
 # (t, window) of the decoder cells' BHTD calls, all on hb1 bq512 bk512
@@ -593,6 +598,8 @@ def cell(name, **overrides):
         "bd": ("sdar", "SdarConfig", dict(
             num_hidden_layers=5, vocab_size=18992, mask_token_id=18991,
             held_experts=(0, 16))),
+        "keye": ("keye", "KeyeConfig", dict(
+            num_hidden_layers=4, vocab_size=18992, held_experts=(0, 16))),
     }[name]
     M = importlib.import_module(f"paddle_tpu.models.{module}")
     if name == "xing4":
@@ -1978,6 +1985,208 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
     return row
 
 
+def dsa_dispatch():
+    """{"impl op pass shape": calls}: the sparse-attention indexer's
+    calls lowered so far (pt_dsa_dispatch_total)."""
+    from paddle_tpu.ops import dsa_ops
+
+    return dsa_ops.dispatch_counts()
+
+
+def keye_rows_hold(cfg, seq, lowered):
+    """What ``keye-train-s16384``'s step must lower (keye_phase, 1.)."""
+    from paddle_tpu.ops import dsa_ops
+    from paddle_tpu.parallel import dsa_score
+
+    dsa, attn, ropes = (lowered[k] for k in (
+        "dsa", "attention", "rotary_embeddings"))
+    n = cfg.num_hidden_layers
+    cq, ck = (dsa_ops.chunk(seq, c) for c in (cfg.q_chunk_size,
+                                              cfg.kv_chunk_size))
+    impl = "kernel" if dsa_score.score_tile(
+        cq, ck, cfg.indexer_num_heads, cfg.indexer_head_dim) else "xla"
+    shape = (f"b1 t{seq} hI{cfg.indexer_num_heads} "
+             f"dI{cfg.indexer_head_dim}")
+    check(dsa.get(f"{impl} select fwd {shape} k{min(cfg.topk, seq)} "
+                  f"cq{cq} ck{ck}") == n
+          and sum(v for k, v in dsa.items() if " select " in k) == n,
+          f"expected {n} dsa_select calls as {impl} at {shape}: {dsa}")
+    loss = "kernel" if dsa_score.loss_tile(
+        cq, ck, cfg.indexer_num_heads, cfg.indexer_head_dim) else "xla"
+    check(dsa.get(f"{loss} loss fwd {shape} k0 cq{cq} ck{ck}") == n,
+          f"expected {n} dsa_index_loss calls as {loss} at {shape}: {dsa}")
+    for direction in ("fwd", "bwd"):
+        check(sum(v for k, v in dsa.items()
+                  if f" loss {direction} " in k) == n,
+              f"expected {n} dsa_index_loss calls {direction}: {dsa}")
+        rows = {k: v for k, v in attn.items() if f" {direction} " in k}
+        check(sum(rows.values()) == n and all(
+            k.startswith(f"bhtd {direction} b1 tq{seq} tk{seq} ")
+            and k.endswith(" sel=operand") for k in rows),
+            f"expected {n} attention calls {direction} under a selection "
+            f"in the bhtd kernels (sel=operand), none dense: {attn}")
+        turned = {k: v for k, v in ropes.items() if f" {direction} " in k}
+        check(sum(turned.values()) == 2 * n and sum(
+            v for k, v in turned.items() if "norm=head" in k) == n,
+            f"expected {n} rotary embeddings {direction} with the heads' "
+            f"gains and {n} of the indexer's: {ropes}")
+    _one_backward_call(attn)
+    _statistics_in_rows(attn)
+
+
+def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
+    """Learned sparse attention (models/keye.py): a lightning indexer's
+    top-k read by the BHTD kernels as an operand.
+
+    1. The cell ``keye-train-s16384``'s train step (four layers of
+       Keye-VL-2.0-30B-A3B's language model at its published widths, 16
+       of 128 experts held, an eighth of the vocabulary, bf16 AMP, Adam)
+       is LOWERED, not run (perf/run.py runs it), and the dispatch
+       counters are held to what the cell must lower: a ``dsa_select``
+       a layer whose scores are ``dsa.score.fwd``, a ``dsa_index_loss``
+       a layer that is ``dsa.loss.bwd`` and its grad op, one attention call a layer each way under the
+       selection in the BHTD kernels (``sel=operand``; none ``dense``),
+       the backward one call, the logsumexp in rows, and two rotary
+       embeddings a layer each way (q and k with the heads' gains at the
+       fed positions; the indexer's). ``overrides`` cut the config for
+       the CPU tests.
+    2. On the device, over ``t_check`` positions at ``heads`` of ``dh``:
+       ``dsa_select`` with the kernel against XLA's ops (the share of
+       the selection that agrees, the logsumexp rows), every row's count
+       of keys, the BHTD kernels under that selection and its live
+       table, forward and the three gradients, against the dense
+       composition, and ``dsa_index_loss`` with the kernel against XLA's
+       ops a tile (the loss and its three gradients); the kernels' ms a
+       call by name."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import dsa_ops
+    from paddle_tpu.parallel import dsa_score
+    from paddle_tpu.parallel import flash_attention as fa
+
+    cfg, _, rows = lower_cell(
+        "keye", seq, overrides, dsa=dsa_dispatch,
+        attention=attention_dispatch, rotary_embeddings=rope_dispatch,
+        feeds={"input_ids": ((1, seq), "int32"),
+               "labels": ((1, seq), "int32"),
+               "position_ids": ((3, seq), "int32")})
+    keye_rows_hold(cfg, seq, rows)
+
+    # --- on the device ----------------------------------------------------
+    (h, hk), t = heads, t_check
+    hi, di = cfg.indexer_num_heads, cfg.indexer_head_dim
+    topk = min(cfg.topk, t // 4)
+    cq, ck = (dsa_ops.chunk(t, c) for c in (cfg.q_chunk_size,
+                                            cfg.kv_chunk_size))
+    r = np.random.RandomState(11)
+    qi, ki = (jnp.asarray(r.randn(1, n_heads, t, di), jnp.bfloat16)
+              for n_heads in (hi, 1))
+    w = jnp.asarray(r.randn(1, t, hi), jnp.bfloat16)
+    attrs = {"scale": dsa_ops.index_scale(hi, di), "topk": topk,
+             "q_chunk": cq, "kv_chunk": ck}
+
+    def select():
+        out = jax.jit(lambda *a: dsa_ops._dsa_select(
+            {"QI": [a[0]], "KI": [a[1]], "W": [a[2]]}, attrs))(qi, ki, w)
+        return [out[s][0] for s in ("Selected", "Live", "IndexLse")]
+
+    selected, live, lse = select()
+    mask = dsa_ops.unpack(selected, cq)
+    counts = np.asarray(mask).sum(-1)[0]
+    check((counts == np.minimum(np.arange(t) + 1, topk)).all(),
+          f"every query chooses min(p + 1, {topk}) keys: "
+          f"{counts[:4]} .. {counts[-4:]}")
+    row = {**rows, "rel_err": {}, "kernel_ms": {}}
+    if dsa_score.score_tile(cq, ck, hi, di):
+        keep, dsa_score.score_tile = dsa_score.score_tile, lambda *a: False
+        try:
+            plain = select()
+        finally:
+            dsa_score.score_tile = keep
+        row["selection_agrees"] = float(np.mean(
+            np.asarray(mask) == np.asarray(dsa_ops.unpack(plain[0], cq))))
+        row["rel_err"]["index_lse"] = _rel(lse, plain[2])
+        check(row["selection_agrees"] > 0.9999
+              and row["rel_err"]["index_lse"] < 1e-4,
+              f"dsa.score.fwd against XLA's ops: {row['selection_agrees']} "
+              f"of the selection agrees, the logsumexp rows are off by "
+              f"{row['rel_err']['index_lse']}")
+    tile = fa.bhtd_tile(h, t, t, dh=dh, group=h // hk)
+    check(tile is not None and fa.bhtd_selected(
+        h, t, t, dh=dh, group=h // hk, blocks=live.shape[1:]),
+          f"the kernels do not take the call under a selection at t{t} "
+          f"h{h} kv{hk}: tile {tile}, the selection's blocks "
+          f"{live.shape[1:]}")
+    q, k, v, g = (jnp.asarray(r.randn(1, n_heads, t, dh), jnp.bfloat16)
+                  for n_heads in (h, hk, hk, h))
+
+    @jax.jit
+    def kernels(q, k, v, g):
+        out, lse_ = fa.flash_attention_fwd(q, k, v, causal=True,
+                                           selected=selected, live=live)
+        return (out, *fa.flash_attention_bwd(
+            q, k, v, None, None, out, lse_, g, causal=True,
+            selected=selected, live=live))
+
+    @jax.jit
+    def dense(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: fa._reference_attention(
+            q, k, v, None, dh ** -0.5, causal=True,
+            selected=mask).astype(q.dtype), q, k, v)
+        return (out, *vjp(g))
+
+    for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
+                          kernels(q, k, v, g), dense(q, k, v, g)):
+        row["rel_err"][name] = _rel(a, b)
+        check(row["rel_err"][name] <= KERNEL_REL_TOL,
+              f"attention under a selection, {name}: off the dense "
+              f"composition by {row['rel_err'][name]:.4f} of its max "
+              f"(tolerance {KERNEL_REL_TOL})")
+    # the indexer's loss under that selection, at the attention's own
+    # logsumexp rows: the kernel against XLA's ops a tile
+    loss_ins = {"QI": [qi], "KI": [ki], "W": [w], "Q": [q], "K": [k],
+                "Lse": [jax.jit(lambda: fa.flash_attention_fwd(
+                    q, k, v, causal=True, selected=selected,
+                    live=live)[1])()],
+                "Selected": [selected], "IndexLse": [lse]}
+    loss_attrs = {**attrs, "attn_scale": dh ** -0.5}
+
+    def index_loss():
+        out = jax.jit(lambda: dsa_ops._dsa_index_loss(loss_ins,
+                                                      loss_attrs))()
+        return [out[s][0] for s in ("Loss", "DQI", "DKI", "DW")]
+
+    if dsa_score.loss_tile(cq, ck, hi, di):
+        with_kernel = index_loss()
+        keep, dsa_score.loss_tile = dsa_score.loss_tile, lambda *a: False
+        try:
+            plain = index_loss()
+        finally:
+            dsa_score.loss_tile = keep
+        for name, a, b in zip(("index_loss", "dqi", "dki", "dw"),
+                              with_kernel, plain):
+            row["rel_err"][name] = _rel(a, b)
+            check(row["rel_err"][name] <= KERNEL_REL_TOL,
+                  f"dsa.loss.bwd against XLA's ops, {name}: off by "
+                  f"{row['rel_err'][name]:.4f} of its max (tolerance "
+                  f"{KERNEL_REL_TOL})")
+    ms, _ = _traced_kernel_ms(
+        "chip_smoke_keye",
+        lambda: (select(), kernels(q, k, v, g), index_loss()), "")
+    row["kernel_ms"] = {k_: v_ for k_, v_ in ms.items()
+                        if k_.startswith(("attn.bhtd.", "dsa."))}
+    # (a trace needs the chip: the CPU tests run this phase through the
+    # interpreters and read {})
+    check(jax.default_backend() != "tpu" or sorted(row["kernel_ms"]) == [
+        "attn.bhtd.bwd", "attn.bhtd.fwd", "dsa.loss.bwd", "dsa.score.fwd"],
+        f"kernels in the trace: {ms}")
+    row["rel_err"] = {k_: round(v_, 6) for k_, v_ in row["rel_err"].items()}
+    say(f"  keye kernels, ms a call at t{t} h{h} kv{hk} dh{dh}: "
+        f"{row['kernel_ms']}; {row['rel_err']}")
+    return row
+
+
 # ---------------------------------------------------------------------------
 # phase 2: train
 # ---------------------------------------------------------------------------
@@ -2489,6 +2698,7 @@ def main() -> int:
     report["bd"], _ = phase("bd", bd_phase)
     report["kda"], _ = phase("kda", kda_phase)
     report["xing4"], _ = phase("xing4", xing4_phase)
+    report["keye"], _ = phase("keye", keye_phase)
     report["rope"], _ = phase("rope", rope_phase)
     report["loss_head"], _ = phase("loss_head", loss_head_phase)
 

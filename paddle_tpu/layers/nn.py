@@ -39,6 +39,7 @@ __all__ = [
     "gdn_gates",
     "gated_delta_rule", "gated_rms_norm", "silu", "selective_scan",
     "mamba2_scan", "diff_attention_combine", "hc_mix", "hc_pre", "hc_post",
+    "dsa_select", "dsa_selected_rows", "dsa_index_loss",
     "roi_align", "roi_pool", "lrn", "spp", "affine_grid", "multiclass_nms",
     "yolo_box", "sequence_conv", "add_position_encoding", "conv3d",
     "spectral_norm", "hsigmoid", "sample_logits",
@@ -401,7 +402,8 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, zero_centered=False,
 
 def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
                      interleaved=False, layout="bhtd", scaling=None,
-                     periods=1, norm_param_attrs=None, norm_epsilon=1e-5):
+                     periods=1, norm_param_attrs=None, norm_epsilon=1e-5,
+                     positions=None, mrope_section=None):
     """Rotary positions (rotate-half form) on q and k [b, h, t, dh] (k
     may have fewer heads); position p of the sequence is p, or, with
     ``periods`` = n, p mod t / n: the positions 0 .. t / n - 1 run n
@@ -440,10 +442,23 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
     parameters, created here in that order, 1 at the start), in the same
     op: where the kernels take the call the statistics, the gains and
     their gradients ride in the rotation's pass, elsewhere the op is
-    rms_norm's lines in front of the rotation's."""
+    rms_norm's lines in front of the rotation's.
+
+    ``positions`` [n, t] (a fed variable, int or float, shared by the
+    batch's rows) with ``mrope_section`` (n counts that sum to the
+    rotated frequency pairs): the positions are the FEED's and pair i
+    turns by the row its section gives it: multi-axis rotary (Qwen2-VL:
+    [temporal, height, width] over sections [16, 24, 24] of a head of
+    128); no ``mrope_section``: row 0 turns every pair. The tables are
+    then device values, made in ``parallel/rope.cos_sin`` as the
+    implicit ones are. Without ``positions`` the op is unchanged."""
     helper = LayerHelper("rotary_embedding", name=name)
     inputs = {"Q": q, "K": k}
     attrs = {"theta": float(theta)}
+    if positions is not None:
+        inputs["Positions"] = positions
+        if mrope_section:
+            attrs["mrope_section"] = [int(n) for n in mrope_section]
     if norm_param_attrs is not None:
         for slot, x, attr in zip(("QScale", "KScale"), (q, k),
                                  norm_param_attrs):
@@ -484,7 +499,8 @@ def rotary_embedding(q, k, theta=10000.0, rotary_dim=None, name=None,
 
 def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
                                  name=None, block_diffusion=None,
-                                 q_pe=None, k_pe=None):
+                                 q_pe=None, k_pe=None, selected=None,
+                                 live=None, with_lse=False):
     """softmax(scale q k^T) v of head-major q [b, h, t, dk], k
     [b, hk, t, dk] and v [b, hk, t, dv] -> [b, h, t, dv]: ONE op, which
     the flash kernels take on a TPU (``ops/attention_ops.py``). hk may
@@ -507,6 +523,15 @@ def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
     keys' ONE head shared by all. The kernels read the parts where they
     lie; nobody builds a wide q or copies the shared head (the op does,
     itself, where no kernel takes the call).
+    ``selected`` [b, t / 32, t] int32 with ``live`` (``dsa_select``'s
+    pair, beside ``causal``): query p reads key s only where its bit of
+    the selection is set, every head alike: a learned sparse attention's
+    choice, a device value that gets no gradient. The kernels read it in
+    blocks beside K and V and walk the causal triangle; a block the live
+    table calls empty computes nothing and fetches nothing.
+    ``with_lse``: return (out, lse), lse
+    [b, h, t, 1] float32 the scores' logsumexp rows (detached; real on
+    every path under a selection), as ``dsa_index_loss`` reads them.
     ``models/transformer.py`` appends the op itself, token-major with
     dropout and a padding bias. ``name`` names the layer's temporaries."""
     helper = LayerHelper(name or "scaled_dot_product_attention")
@@ -527,9 +552,89 @@ def scaled_dot_product_attention(q, k, v, scale, causal=True, window=None,
     inputs = {"Q": q, "K": k, "V": v}
     if q_pe is not None or k_pe is not None:
         inputs.update(QPe=q_pe, KPe=k_pe)
+    if selected is not None:
+        inputs.update(Selected=selected, Live=live)
     helper.append_op("scaled_dot_product_attention", inputs=inputs,
                      outputs={"Out": out, "Lse": lse}, attrs=attrs)
+    return (out, lse) if with_lse else out
+
+
+def dsa_select(q_index, k_index, weights, topk, q_chunk=512, kv_chunk=512,
+               name=None):
+    """A lightning indexer's choice (DeepSeek Sparse Attention,
+    ``ops/dsa_ops.py``): index queries ``q_index`` [b, hI, t, dI], the
+    ONE index key head ``k_index`` [b, 1, t, dI] and the per-head
+    weights ``weights`` [b, t, hI] -> ``(selected [b, t / 32, t] int32,
+    a bit a pair, live [b, t / cq, t / ck] int32, index_lse [b, t]
+    float32)``: each query's
+    min(p + 1, ``topk``) keys s <= p of largest I[p, s] = c0 sum_j
+    w[p, j] relu(qI[p, j] . kI[s]) (c0 = hI^-1/2 dI^-1/2, float32, ties
+    to the lower s; ``topk`` None: every s <= p, the dense warm-up
+    stage), the table of the blocks of ``q_chunk`` x ``kv_chunk`` that
+    hold a selected pair, and the logsumexp of I over a query's
+    selection. No gradient passes (a top-k has none):
+    ``scaled_dot_product_attention(selected=, live=)`` reads the first
+    two, ``dsa_index_loss`` the first and the last,
+    ``dsa_selected_rows`` unpacks. The scores are made a tile at a time
+    and the top-k is a bisection, not a sort."""
+    from paddle_tpu.ops.dsa_ops import index_scale
+
+    helper = LayerHelper("dsa_select", name=name)
+    outs = [helper.create_variable_for_type_inference(dtype=d,
+                                                      stop_gradient=True)
+            for d in ("int32", "int32", "float32")]
+    helper.append_op(
+        "dsa_select", inputs={"QI": q_index, "KI": k_index, "W": weights},
+        outputs={"Selected": outs[0], "Live": outs[1], "IndexLse": outs[2]},
+        attrs={"scale": index_scale(q_index.shape[1], q_index.shape[3]),
+               "topk": int(topk or 0), "q_chunk": int(q_chunk),
+               "kv_chunk": int(kv_chunk)})
+    return tuple(outs)
+
+
+def dsa_selected_rows(selected, live, last=0, name=None):
+    """``dsa_select``'s ``selected`` as a mask a pair, [b, t, t] int8
+    (1: query p reads key s), or its ``last`` rows [b, last, t]: what a
+    check or a test reads; the kernels read the bits."""
+    helper = LayerHelper("dsa_selected_rows", name=name)
+    out = helper.create_variable_for_type_inference(dtype="int8",
+                                                    stop_gradient=True)
+    helper.append_op("dsa_selected_rows",
+                     inputs={"Selected": selected, "Live": live},
+                     outputs={"Out": out}, attrs={"last": int(last)})
     return out
+
+
+def dsa_index_loss(q_index, k_index, weights, q, k, lse, selected, index_lse,
+                   attn_scale, q_chunk=512, kv_chunk=512, name=None):
+    """The indexer's own loss, a float32 scalar (``ops/dsa_ops.py``): L_I =
+    mean over the batch's positions p of KL(P[p, .] || softmax over the
+    selection of I[p, .]), P the main attention's probabilities under
+    the selection averaged over its heads, made again a tile at a time
+    from its ``q`` [b, h, t, dh], ``k`` [b, hk, t, dh] (as the attention
+    read them) and ``lse`` (``scaled_dot_product_attention(with_lse=
+    True)``'s) and DETACHED: only ``q_index``, ``k_index`` and
+    ``weights`` get a gradient, which the op makes in the same pass
+    (through the relu and the per-head weights, by hand)."""
+    from paddle_tpu.ops.dsa_ops import index_scale
+
+    helper = LayerHelper("dsa_index_loss", name=name)
+    loss = helper.create_variable_for_type_inference(dtype="float32")
+    saved = [helper.create_variable_for_type_inference(dtype=x.dtype,
+                                                       stop_gradient=True)
+             for x in (q_index, k_index)]
+    saved.append(helper.create_variable_for_type_inference(
+        dtype="float32", stop_gradient=True))
+    helper.append_op(
+        "dsa_index_loss",
+        inputs={"QI": q_index, "KI": k_index, "W": weights, "Q": q, "K": k,
+                "Lse": lse, "Selected": selected, "IndexLse": index_lse},
+        outputs={"Loss": loss, "DQI": saved[0], "DKI": saved[1],
+                 "DW": saved[2]},
+        attrs={"scale": index_scale(q_index.shape[1], q_index.shape[3]),
+               "attn_scale": float(attn_scale), "q_chunk": int(q_chunk),
+               "kv_chunk": int(kv_chunk)})
+    return loss
 
 
 def causal_conv1d(input, taps=4, act="silu", param_attr=None,
@@ -1309,6 +1414,13 @@ def accuracy(input, label, k=1, correct=None, total=None):
 
 
 def topk(input, k, name=None):
+    """(the ``k`` largest values along the last axis, their indices):
+    ``lax.top_k`` over the whole tensor, which on a TPU SORTS every row
+    (a bitonic network over the row's length, whatever k): the price of
+    a router's 8 of 128, not of 2048 of 16,384 positions a query. For a
+    top-k over POSITIONS that an attention reads as a mask, see
+    ``dsa_select`` (a threshold by bisection a tile of queries, no
+    sort, no indices)."""
     helper = LayerHelper("top_k", name=name)
     vals = helper.create_variable_for_type_inference(dtype=input.dtype)
     idx = helper.create_variable_for_type_inference(dtype="int64", stop_gradient=True)

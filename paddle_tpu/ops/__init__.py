@@ -14,6 +14,7 @@ from paddle_tpu.ops import (  # noqa: F401
     crf_ops,
     decode_ops,
     detection_ops,
+    dsa_ops,
     hc_ops,
     linear_attention_ops,
     mamba2_scan_ops,

@@ -60,7 +60,12 @@ _M_DISPATCH = _monitor.counter(
     "parts: own (attn.bhtd.fwd and attn.bhtd.bwd read the parts as "
     "operands of their own: no wide q or k exists) or assembled (the op "
     "concatenated q and k and copied the shared head, and the row is the "
-    "wide call's); its shape names the whole head either way")
+    "wide call's); its shape names the whole head either way. A call "
+    "under a SELECTION (the op's Selected input: which keys each query "
+    "reads, a device value all heads share) carries sel: operand "
+    "(attn.bhtd.fwd and the ONE attn.bhtd.bwd read it in blocks beside K "
+    "and V and walk the causal triangle) or dense (the composition under "
+    "a [t, t] mask)")
 
 
 def _windowed(attrs, q, k, bthd, ring):
@@ -99,7 +104,8 @@ def _block_masked(attrs, q, k, bthd, ring):
 
 
 def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
-                   form=None, causal=False, block_diffusion=None, parts=None):
+                   form=None, causal=False, block_diffusion=None, parts=None,
+                   sel=None):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
@@ -142,11 +148,13 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         labels["stats"] = stats
     if parts is not None:
         labels["parts"] = parts
+    if sel is not None:
+        labels["sel"] = sel
     _M_DISPATCH.inc(labels=labels)
 
 
 def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
-                    masks=False, parts=False):
+                    masks=False, parts=False, sels=False):
     """{"family pass shape[ replicated_over=axes]": calls lowered so
     far} — the dispatch counter as chip_smoke.py and the multi-chip dry
     run print it. ``tiles``: a row whose family tiles by the shape names
@@ -159,7 +167,9 @@ def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
     block-masked row says so, "... mask=block_diffusion block=4
     band=skip". ``parts``: a row of a call given q and k in two parts
     says who read them, "... parts=own" (the kernels) or "...
-    parts=assembled" (the op concatenated them)."""
+    parts=assembled" (the op concatenated them). ``sels``: a row of a
+    call under a selection says who read it, "... sel=operand" (the
+    kernels) or "... sel=dense" (the composition)."""
     out = {}
     for row in _monitor.snapshot()[_M_DISPATCH.name]["values"]:
         lb = row["labels"]
@@ -179,6 +189,8 @@ def dispatch_counts(tiles=False, forms=False, edges=False, stats=False,
                      f"band={lb['band']}")
         if parts and lb.get("parts"):
             name += f" parts={lb['parts']}"
+        if sels and lb.get("sel"):
+            name += f" sel={lb['sel']}"
         out[name] = out.get(name, 0) + int(row["value"])
     return out
 
@@ -240,7 +252,7 @@ def _attn_bias(ins, attrs):
 
 
 def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None,
-            periods=1):
+            periods=1, positions=None, sections=None):
     """Rotary position embedding of x [b, h, t, dh], rotate-half form
     (Su et al. 2021 as GPT-NeoX and HF lay it out): feature i pairs
     with i + dh/2, position p turns the pair by p * theta^(-2i/dh).
@@ -252,13 +264,15 @@ def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None,
     features that turn and its attention factor on cos and sin
     (``parallel/rope.cos_sin``, which the kernels' tables call too).
     ``periods``: the positions 0 .. t / periods - 1 run that many times
-    over the row (index i is position i mod t / periods)."""
+    over the row (index i is position i mod t / periods). ``positions``
+    [n, t] with ``sections``: the positions are fed, frequency pair i
+    turning by the row ``sections`` gives it (``parallel/rope.cos_sin``)."""
     from paddle_tpu.parallel import rope
 
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         return jnp.concatenate(
             [_rotate(x[..., :rotary_dim], theta, None, interleaved, scaling,
-                     periods),
+                     periods, positions, sections),
              x[..., rotary_dim:]], -1)
     t, dh = x.shape[-2], x.shape[-1]
     if interp.stands_for_dynamic(t):
@@ -266,7 +280,10 @@ def _rotate(x, theta, rotary_dim=None, interleaved=False, scaling=None,
     if t % periods:
         raise ValueError(f"rotary_embedding: a row of {t} positions is "
                          f"not {periods} runs of the same positions")
-    cos, sin = rope.cos_sin(t // periods, dh, theta, scaling)
+    if positions is not None and interp.stands_for_dynamic(t):
+        positions = None   # (build-time shape inference, as above)
+    cos, sin = rope.cos_sin(t // periods, dh, theta, scaling, positions,
+                            sections)
     if periods > 1:
         cos, sin = jnp.tile(cos, (periods, 1)), jnp.tile(sin, (periods, 1))
     if interleaved:
@@ -307,6 +324,17 @@ def _rope_attrs(attrs):
 def _rope_periods(attrs):
     """How many times the positions run over the row (attr ``periods``)."""
     return int(attrs.get("periods", 0)) or 1
+
+
+def _rope_positions(ins, attrs):
+    """(the fed positions [n, t] or None, their ``mrope_section`` or
+    None) of a rotary_embedding call: input ``Positions`` and attribute
+    ``mrope_section`` (``parallel/rope.cos_sin``)."""
+    positions = _x(ins, "Positions")
+    if positions is not None and _rope_periods(attrs) != 1:
+        raise ValueError("rotary_embedding: fed positions run once "
+                         "(periods=1)")
+    return positions, tuple(attrs.get("mrope_section") or ()) or None
 
 
 def _rope_scaling(attrs):
@@ -379,8 +407,9 @@ def _rotary_xla(ins, attrs):
     if tokens:
         q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
     periods = _rope_periods(attrs)
-    return {"QOut": [_rotate(q, theta, rd, il, scaling, periods)],
-            "KOut": [_rotate(k, theta, rd, il, scaling, periods)]}
+    at = _rope_positions(ins, attrs)
+    return {"QOut": [_rotate(q, theta, rd, il, scaling, periods, *at)],
+            "KOut": [_rotate(k, theta, rd, il, scaling, periods, *at)]}
 
 
 # (the generic grad op's rule for the XLA form: the vjp of _rotary_xla)
@@ -403,7 +432,13 @@ def _rotary_embedding(ins, attrs):
     its attributes; ``periods``, absent for 1: the positions 0 .. t /
     periods - 1 run that many times over the row, index i is position
     i mod t / periods, as a row of a noised and a clean copy of the same
-    tokens needs). The angles and the rotation are f32; the results
+    tokens needs). Optional input ``Positions`` [n, t] (any integer or
+    float dtype, shared by the batch's rows) with the attribute
+    ``mrope_section`` (n counts that sum to the rotated pairs; absent:
+    row 0 turns every pair): the positions are FED, and frequency pair i
+    turns by the row its section gives it (multi-axis rotary: temporal |
+    height | width); the tables are then device values of the feed, which
+    the kernels read as they read any table. The angles and the rotation are f32; the results
     return to the inputs' dtype. ``layout`` "bthd": Q and K come
     token-major [b, t, h, dh], as a projection leaves them; the results
     are head-major [b, h, t, dh] all the same.
@@ -427,10 +462,12 @@ def _rotary_embedding(ins, attrs):
     from paddle_tpu.parallel import rope
 
     theta, rd, _, tokens = _rope_attrs(attrs)
+    positions, sections = _rope_positions(ins, attrs)
     q, k = rope.rope_fwd(q, k, theta, tile, tokens=tokens,
                          scaling=_rope_scaling(attrs), rotary_dim=rd,
                          periods=_rope_periods(attrs), gains=gains,
-                         eps=attrs.get("norm_epsilon"))
+                         eps=attrs.get("norm_epsilon"),
+                         positions=positions, sections=sections)
     return {"QOut": [q], "KOut": [k]}
 
 
@@ -464,7 +501,8 @@ def _rotary_embedding_grad(ins, attrs):
         cotangent(_x(ins, "GRAD::KOut"), k), theta, tile, tokens=tokens,
         scaling=_rope_scaling(attrs), rotary_dim=rd,
         periods=_rope_periods(attrs), gains=gains,
-        eps=attrs.get("norm_epsilon"), x=(q, k))
+        eps=attrs.get("norm_epsilon"), x=(q, k),
+        **dict(zip(("positions", "sections"), _rope_positions(ins, attrs))))
     grads = {"GRAD::Q": [dq], "GRAD::K": [dk]}
     for slot, d, g in zip(("GRAD::QScale", "GRAD::KScale"), dgains,
                           gains or ()):
@@ -593,8 +631,40 @@ def _two_parts(ins, attrs):
             "Q": [wide_q], "K": [wide_k]}, "assembled", apart
 
 
+def _selected(ins, attrs, family, dims):
+    """-> (selection, Live, family, sel) of an sdpa or sdpa_grad call
+    (None, None and ``sel`` None for a call without), the family the
+    call takes under it and the dispatch counter's ``sel`` label:
+    "operand" where the BHTD kernels read the op's Selected
+    [b, t / 32, t] int32 and its live-block table
+    (``flash_attention.bhtd_selected``: causal self-attention, no bias,
+    dropout, window, block mask, second part or mesh, the ONE backward
+    call, the table's blocks the tile's), else "dense", the composition
+    under the selection unpacked to a [b, t, t] mask."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    selected = _x(ins, "Selected")
+    if selected is None:
+        return None, None, family, None
+    if attrs.get("layout", "bhtd") != "bhtd" or not attrs.get("causal"):
+        raise ValueError("scaled_dot_product_attention: Selected needs "
+                         "layout='bhtd' and causal")
+    b, tq, tk, h, dh, hk, dv, itemsize = dims
+    plain = (_x(ins, "Bias") is None and _x(ins, "QPe") is None
+             and not _drops(attrs) and not attrs.get("block_diffusion")
+             and interp.spmd_ctx() is None
+             and fa._band(attrs.get("window") or None, True, tq, tk) is None)
+    live = _x(ins, "Live")
+    if family == "bhtd" and fa.bhtd_selected(
+            h, tq, tk, dh=dh, group=h // hk, dv=dv, itemsize=itemsize,
+            plain=plain, blocks=live.shape[1:]):
+        return selected, live, family, "operand"
+    return fa._selected_mask(selected, live), None, "dense", "dense"
+
+
 def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
-             form=None, causal=False, block_diffusion=None, parts=None):
+             form=None, causal=False, block_diffusion=None, parts=None,
+             sel=None):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -609,7 +679,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     if split is None:
         _note_dispatch(family, direction, dims, window=window, form=form,
                        causal=causal, block_diffusion=block_diffusion,
-                       parts=parts)
+                       parts=parts, sel=sel)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -713,6 +783,16 @@ def _sdpa(ins, attrs, rng=None):
     counter's ``parts`` label says which, so the fallback is never
     silent.
 
+    Optional inputs ``Selected`` [b, t / 32, t] int32 and ``Live``
+    [b, t / bq, t / bk] int32 (``dsa_select``'s, with ``causal``, layout
+    bhtd): query p reads key s only where its bit of Selected is set,
+    every head alike; no gradient reaches either. The BHTD kernels read
+    the selection in blocks beside K and V where
+    ``flash_attention.bhtd_selected`` takes the call (``_selected``),
+    else the dense composition masks by it; the dispatch counter's
+    ``sel`` label says which. Lse is then the real logsumexp over the
+    selected keys on every path (``dsa_index_loss`` reads it).
+
     On TPU this routes to the Pallas flash-attention kernel
     (paddle_tpu/parallel/flash_attention.py), including training-time
     attention dropout, which runs inside the kernel from a per-step seed.
@@ -733,6 +813,7 @@ def _sdpa(ins, attrs, rng=None):
     ring = _ring_config_t(q, k, t_axis)
     window = _windowed(attrs, q, k, bthd, ring)
     block = _block_masked(attrs, q, k, bthd, ring)
+    selected, live, family, sel = _selected(ins, attrs, family, dims)
     if ring is not None:
         _note_dispatch("ring", "fwd", dims, parts=parts)
         mesh, ctx_axis, data_axis = ring
@@ -753,19 +834,24 @@ def _sdpa(ins, attrs, rng=None):
         lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif family == "dense":
         _note_dispatch("dense", "fwd", dims, window=window,
-                       block_diffusion=block, parts=parts)
+                       block_diffusion=block, parts=parts, sel=sel)
         sd = seed if drop > 0.0 else None
+        lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
         if bthd:
             out = fa._reference_attention_bthd(
                 q, k, v,
                 fa._combined_causal_bias(bias, q.shape[1], k.shape[1])
                 if causal else bias,
                 scale, drop, sd)
+        elif selected is not None:
+            # (REAL logsumexp rows: dsa_index_loss reads them)
+            out, lse = fa._reference_attention_with_lse(
+                q, k, v, bias, scale, drop, sd, causal=causal,
+                window=window, selected=selected)
         else:
             out = fa._reference_attention(q, k, v, bias, scale, drop, sd,
                                           causal=causal, window=window,
                                           block_diffusion=block)
-        lse = jnp.zeros(jnp.shape(q)[:3] + (1,), jnp.float32)
     elif bthd:
         out, lse = _on_mesh(
             lambda q, k, v, bias, seed: fa.flash_attention_bthd_with_lse(
@@ -780,9 +866,10 @@ def _sdpa(ins, attrs, rng=None):
             lambda q, k, v, bias, seed: fa.flash_attention_with_lse(
                 q, k, v, bias, seed, scale, float(drop), causal=causal,
                 window=window, block_diffusion=block,
-                q_pe=_x(ins, "QPe"), k_pe=_x(ins, "KPe")),
+                q_pe=_x(ins, "QPe"), k_pe=_x(ins, "KPe"),
+                selected=selected, live=live),
             (q, k, v, bias), seed, family, "fwd", dims, window,
-            block_diffusion=block, parts=parts)
+            block_diffusion=block, parts=parts, sel=sel)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 
@@ -809,6 +896,7 @@ def _sdpa_grad(ins, attrs, rng=None):
     ring = _ring_config_t(q, k, t_axis)
     window = _windowed(attrs, q, k, bthd, ring)
     block = _block_masked(attrs, q, k, bthd, ring)
+    selected, live, family, sel = _selected(ins, attrs, family, dims)
     if ring is not None:
         _note_dispatch("ring", "bwd", dims, parts=parts)
         mesh, ctx_axis, data_axis = ring
@@ -832,7 +920,7 @@ def _sdpa_grad(ins, attrs, rng=None):
         dq, dk, dv = vjp(g.astype(q.dtype))
     elif family == "dense":
         _note_dispatch("dense", "bwd", dims, window=window,
-                       block_diffusion=block, parts=parts)
+                       block_diffusion=block, parts=parts, sel=sel)
         sd = seed if drop > 0.0 else None
         if bthd:
             eff_bias = fa._combined_causal_bias(
@@ -845,7 +933,8 @@ def _sdpa_grad(ins, attrs, rng=None):
             def f(q, k, v):
                 return fa._reference_attention(
                     q, k, v, bias, scale, drop, sd, causal=causal,
-                    window=window, block_diffusion=block).astype(q.dtype)
+                    window=window, block_diffusion=block,
+                    selected=selected).astype(q.dtype)
 
         _, vjp = jax.vjp(f, q, k, v)
         dq, dk, dv = vjp(g.astype(q.dtype))
@@ -861,13 +950,15 @@ def _sdpa_grad(ins, attrs, rng=None):
             block_diffusion=block)
         pe = {} if parts != "own" else dict(q_pe=_x(ins, "QPe"),
                                             k_pe=_x(ins, "KPe"))
+        if selected is not None:
+            pe = dict(selected=selected, live=live)
         dq, dk, dv, *d_pe = _on_mesh(
             lambda q, k, v, bias, out, lse, g, seed: bwd(
                 q, k, v, bias, seed, out, lse, g, scale=scale,
                 p_drop=drop, causal=causal, **pe),
             (q, k, v, bias, out, lse, g.astype(q.dtype)), seed, family,
             "bwd", dims, window, form, causal=causal, block_diffusion=block,
-            parts=parts)
+            parts=parts, sel=sel)
     if apart is not None:
         dq, dk, *d_pe = apart((dq, dk))
     grads = {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}

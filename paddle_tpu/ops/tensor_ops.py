@@ -387,6 +387,11 @@ def _lookup_table_grad(ins, attrs):
 
 @register_op("top_k", no_grad=True)
 def _top_k(ins, attrs):
+    """``lax.top_k`` over the last axis of the whole tensor. On a TPU
+    that is a sort of every row, whatever ``k``: fine for a router's few
+    of a hundred, not for thousands of positions a query, where
+    ``ops/dsa_ops.choose`` (``layers.dsa_select``) finds the k-th value
+    by bisection and never sorts."""
     x = _x(ins)
     k = attrs["k"]
     vals, idx = jax.lax.top_k(x, k)

@@ -116,6 +116,22 @@ ridge of 240.
   forward's scores are two products in one float32 sum; the backward
   puts a step's blocks together in VMEM and is the wide call's step.
 
+- A SELECTION (``selected`` [b, tq / 32, tk] int32 beside ``causal``, a
+  bit a pair, with its ``live`` block table: a learned sparse
+  attention's top-k, which keys each query reads, all heads alike): the
+  mask is DATA, so it is an operand: ``attn.bhtd.fwd`` and the ONE
+  ``attn.bhtd.bwd`` read it in (bq / 32, bk) blocks of words through the
+  bias operand's slot, unpack a block in VMEM (``dsa_score.hit_rows``;
+  ``_biased`` masks) and walk the causal triangle; a block the live
+  table says holds no selected pair computes nothing and fetches
+  nothing: the table rides behind the seed pair in the scalar-prefetch
+  operand as the block each step FETCHES (``_selection``, ``_fetched``:
+  its own where it is live, else a live neighbour's, which Pallas does
+  not copy again), the index maps read it (``_step_blocks``) and a step
+  is live where it fetches its own (``_chosen_live``).
+  ``bhtd_selected`` says which calls; elsewhere the dense composition
+  masks by it. A call without a selection lowers as it did.
+
 ``bias`` is additive [b, 1|h, 1|tq, tk] mask plumbing, NOT a trainable
 input: its cotangent is zeros on the Pallas path (computing it would
 materialize a t x t gradient). Use the dense composition for a learnable
@@ -138,6 +154,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.parallel import dsa_score
 
 DEFAULT_Q_BLOCK = 256
 DEFAULT_K_BLOCK = 256
@@ -783,9 +801,49 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
 
 
+def _biased(s, bias, transposed=False):
+    """Scores ``s`` [.., bq, bk] behind the step's block of the bias
+    operand: a float bias is added; a SELECTION's block (``_unpacked``:
+    int32, nonzero where the query reads the key) masks.
+    ``transposed``: ``s`` is [bk, bq] and the block comes [bq, bk] (as
+    32-bit values: the chip transposes nothing narrower)."""
+    if bias.dtype == jnp.int32:
+        return jnp.where((bias.T if transposed else bias) != 0, s, _NEG_INF)
+    bias = bias.astype(jnp.float32)
+    return s + (bias.T if transposed else bias)
+
+
+def _unpacked(bias, first=0, rows=None):
+    """A block of the bias operand as ``_biased`` takes it: a
+    selection's (bq / 32, bk) words as rows ``first`` .. ``first +
+    rows`` of the block's pairs (all of them: None), int32 and nonzero
+    where the bit is set (``dsa_score.hit_rows``); a float block as it
+    is."""
+    if bias.dtype != jnp.int32:
+        return bias
+    return dsa_score.hit_rows(bias.reshape(bias.shape[-2:]), first, rows)
+
+
+def _table_at(seed_ref, live, i, j, kk):
+    """Entry (batch row i, q-block j, k-block kk) of a selection's table
+    behind the seed pair in the scalar-prefetch operand (``_selection``;
+    ``live`` = its (nq, nk)): the block the step fetches along its
+    call's inner axis."""
+    nq, nk = live
+    return seed_ref[2 + (i * nq + j) * nk + kk]
+
+
+def _chosen_live(seed_ref, live, j, kk, own):
+    """Does block (j, kk) of this batch row hold a selected pair? Where
+    its step fetches its ``own`` block of the inner axis (kk in the
+    forward, j in the backward: ``_fetched``)."""
+    return _table_at(seed_ref, live, pl.program_id(0), j, kk) == own
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, nk, ng, p_drop,
-                causal=False, window=None, bd=None, pe_refs=None):
+                causal=False, window=None, bd=None, pe_refs=None,
+                live=None):
     # r: the inner axis's step, nk of them; kk the k-block it works on
     # (``pe_refs``: blocks of QPe and KPe where q and k come in two parts,
     # _call_parts)
@@ -816,7 +874,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 preferred_element_type=jnp.float32)
         s = s * scale
         if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
+            s = _biased(s, _unpacked(bias_ref[0]))
         if masked and bd is not None:
             s = _bd_mask(s, j, kk, bq, bd)
         elif masked:
@@ -849,9 +907,13 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     if bd is not None:
         _when_live(_compute, bd_live, j, kk, bq, bk, None, edge=bd_edge)
     elif causal:
-        # (a band's steps start at the q-row's first live k-block)
-        _when_live(_compute, _causal_live(j, kk, bq, bk), j, kk, bq, bk,
-                   window)
+        # (a band's steps start at the q-row's first live k-block; under
+        # a selection a block in which nothing is chosen is dead too)
+        is_live = _causal_live(j, kk, bq, bk)
+        if live is not None:
+            is_live = jnp.logical_and(
+                is_live, _chosen_live(seed_ref, live, j, kk, kk))
+        _when_live(_compute, is_live, j, kk, bq, bk, window)
     else:
         _compute()
 
@@ -1026,7 +1088,7 @@ def _bwd_block(q, k, v, do, lse, delta, bias, scale, mask):
     nt = (((1,), (1,)), ((), ()))       # a b^T
     s_t = jax.lax.dot_general(k, q, nt, preferred_element_type=f32) * scale
     if bias is not None:
-        s_t = s_t + bias.astype(f32).T
+        s_t = _biased(s_t, bias, transposed=True)
     if mask is not None:
         s_t = mask(s_t)
     p_t = jnp.exp(s_t - lse)
@@ -1061,7 +1123,8 @@ def _each_block(acc, rows, body):
 
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                 delta_ref, *grads, scale, nq, nk, group, causal=False,
-                window=None, last_q=None, slabs=None, bd=None, pe_refs=None):
+                window=None, last_q=None, slabs=None, bd=None, pe_refs=None,
+                live=None):
     """attn.bhtd.bwd: grid (batch row, key/value head, member of its
     group, k-block, step), one head a step: the dk/dv kernel's walk, a
     k-row's ``nq`` steps over the q-blocks (with a window: over its
@@ -1085,7 +1148,7 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     behind dv's. (Measured against the parts as products of their own, 8
     a block, with accumulators of their own: 3.96 ms a call for 4.03 at
     [1, 32, 4096, 128 | 64] alone on a v5e, PR 70.)"""
-    del seed_ref                        # (no dropout: bhtd_bwd_form)
+    # (no dropout, bhtd_bwd_form: the operand is read for ``live`` alone)
     m, kk, r = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     bq, bk = q_ref.shape[2], k_ref.shape[2]
     if bd is not None:
@@ -1136,7 +1199,11 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             mask = lambda s_t: _causal_mask(
                 s_t[None], j, kk, bq, bk, transposed=True, window=window,
                 at=start)[0]
-        if bias_ref is not None:
+        if bias_ref is not None and bias_ref.dtype == jnp.int32:
+            # (a selection's words hold the block's rows 32 a word)
+            bias = _unpacked(bias_ref[0, 0, :, ks], start[0],
+                             qs.stop - qs.start)
+        elif bias_ref is not None:
             per_row = bias_ref.shape[2] > 1
             bias = bias_ref[0, 0, qs if per_row else slice(None), ks]
         q, k = q_ref[0, 0, qs, :], k_ref[0, 0, ks, :]
@@ -1155,9 +1222,12 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         _when_live(_compute, bd_live, j, kk, bq, bk, None, edge=bd_edge)
     elif causal:
         # (a band's steps start at the k-row's first live q-block)
-        live = (_causal_live(j, kk, bq, bk) if window is None else
-                j <= jnp.minimum(_last_q(kk, bq, bk, window), last_q))
-        _when_live(_compute, live, j, kk, bq, bk, window, slabs)
+        is_live = (_causal_live(j, kk, bq, bk) if window is None else
+                   j <= jnp.minimum(_last_q(kk, bq, bk, window), last_q))
+        if live is not None:
+            is_live = jnp.logical_and(
+                is_live, _chosen_live(seed_ref, live, j, kk, j))
+        _when_live(_compute, is_live, j, kk, bq, bk, window, slabs)
     else:
         _compute()
 
@@ -1169,7 +1239,7 @@ def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 
 def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
-                 steps=None, bd=None):
+                 steps=None, bd=None, live=None):
     """-> f(*grid ids) = (i, g, j, kk): batch row, head group, q-block
     and k-block a grid step READS. The grid is (i, g, j, kk) with the k
     axis inner (forward, dq) or (i, g, kk, j) with the q axis inner
@@ -1181,7 +1251,11 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
     the inner axis has ``steps`` steps (a head), the band's width in
     blocks: step r reads the r-th block of its row's band, a dead step
     the band's last. Block-masked (``bd``): step r reads the r-th block
-    of its row's own walk (_bd_k_fetch, _bd_q_fetch)."""
+    of its row's own walk (_bd_k_fetch, _bd_q_fetch). Under a selection
+    (``live``: its table's (nq, nk), ``_selection``; the scalar-prefetch
+    operand is then the last of ``ids``) a step reads the block of the
+    inner axis that the table names: its own where it holds a selected
+    pair, else a live neighbour's."""
     steps = steps or nq
 
     def f(*ids):
@@ -1189,6 +1263,7 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
         j, kk = (ids[2], ids[3]) if k_inner else (ids[3], ids[2])
         if group > 1 and not k_inner:
             g, j = g * group + j // steps, j % steps
+        own = (j, kk)
         if bd is not None and k_inner:
             kk = _bd_k_fetch(j, kk, bq, bd)
         elif bd is not None:
@@ -1203,6 +1278,13 @@ def _step_blocks(causal, k_inner, bq, bk, nq, group=1, window=None,
             kk = _live_k(j, kk, bq, bk)
         elif causal:
             j = jnp.minimum(_live_q(j, kk, bq, bk), nq - 1)
+        if live is not None:
+            # (-1: nothing of the k-row is selected; the triangle's then)
+            named = _table_at(ids[-1], live, i, *own)
+            if k_inner:
+                kk = jnp.where(named < 0, kk, named)
+            else:
+                j = jnp.where(named < 0, j, named)
         return i, g, j, kk
     return f
 
@@ -1270,8 +1352,11 @@ def _row_specs(at, hb, bq, bk, dh, group=1, dv=None, pe=None):
 
 def _bias_spec(bias, at, hb, bq, bk):
     """BlockSpec for the stored-rank bias [b, 1|h, 1|tq, tk], read at
-    ``at``'s blocks."""
+    ``at``'s blocks; of a selection [b, 1, tq / 32, tk] int32
+    (``_selection``), the (bq / 32, bk) words of a block's pairs."""
     per_head, per_row = bias.shape[1] > 1, bias.shape[2] > 1
+    if bias.dtype == jnp.int32:
+        bq //= 32
 
     def idx(*ids):
         i, g, j, kk = at(*ids)
@@ -1282,7 +1367,7 @@ def _bias_spec(bias, at, hb, bq, bk):
 
 
 def _reference_scores(q, k, bias, scale, causal, window=None,
-                      block_diffusion=None):
+                      block_diffusion=None, selected=None):
     """Scaled scores + bias + causal (and window) mask, or block
     diffusion's (``bd_visible``) — the ONE copy
     both the dense forward and its lse statistic derive from (the
@@ -1302,12 +1387,14 @@ def _reference_scores(q, k, bias, scale, causal, window=None,
         if window is not None:
             mask = jnp.logical_and(mask, ago < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
+    if selected is not None:    # [b, tq, tk]: every head's mask
+        s = jnp.where(selected[:, None] != 0, s, _NEG_INF)
     return s
 
 
 def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
                                   seed=None, causal=False, window=None,
-                                  block_diffusion=None):
+                                  block_diffusion=None, selected=None):
     """(out, lse) from ONE score tensor — the fallback twin of the
     kernels' contract. out and lse must never derive from separately
     constructed scores (different dtype promotion would desynchronize
@@ -1317,7 +1404,8 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    s = _reference_scores(q, k, bias, scale, causal, window, block_diffusion)
+    s = _reference_scores(q, k, bias, scale, causal, window, block_diffusion,
+                          selected)
     lse = jax.scipy.special.logsumexp(s, axis=-1, keepdims=True)
     p = jax.nn.softmax(s, axis=-1)
     if p_drop > 0.0:
@@ -1328,10 +1416,11 @@ def _reference_attention_with_lse(q, k, v, bias, scale, p_drop=0.0,
 
 
 def _reference_attention(q, k, v, bias, scale, p_drop=0.0, seed=None,
-                         causal=False, window=None, block_diffusion=None):
+                         causal=False, window=None, block_diffusion=None,
+                         selected=None):
     return _reference_attention_with_lse(q, k, v, bias, scale, p_drop,
                                          seed, causal, window,
-                                         block_diffusion)[0]
+                                         block_diffusion, selected)[0]
 
 
 def _seed_arr(seed):
@@ -1473,6 +1562,81 @@ def _two_parts(q, k, v, q_pe, k_pe, q_block, k_block, *not_plain):
     return q_pe, k_pe
 
 
+def bhtd_selected(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
+                  dv=None, itemsize=2, plain=True, blocks=None):
+    """Do ``attn.bhtd.fwd`` and the ONE ``attn.bhtd.bwd`` take this call's
+    SELECTION (``flash_attention_fwd``'s ``selected``: which keys each
+    query reads, a device value) as an operand? Where the call is
+    ``plain`` beside ``causal`` (the caller's word: self-attention, no
+    bias, dropout, window, block mask or second part), has a tile whose
+    backward is the ONE call (the split pair carries no selection) and
+    that tile's blocks are the selection's own, ``blocks`` = its live
+    table's (nq, nk): a q-block's bits are packed together
+    (``dsa_score.pack_rows``). The one place that decides: the entry
+    points and the sdpa op, which runs the dense composition under a
+    [t, t] mask where this says no (the dispatch counter's ``sel``
+    label)."""
+    if not (plain and tq == tk and bhtd_bwd_form(
+            h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+            itemsize=itemsize) == "fused"):
+        return False
+    _, bq, bk = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group,
+                          dv=dv, itemsize=itemsize)
+    return tuple(blocks) == (tq // bq, tk // bk) and bq % 32 == 0
+
+
+def _only_causal(causal, bias, p_drop, window, bd, pe):
+    """``bhtd_selected``'s ``plain`` as the entry points hold it: causal,
+    and neither a bias, dropout, a window, a block mask nor a second
+    part."""
+    return bool(causal and bias is None
+                and not (p_drop or window or bd or pe))
+
+
+def _fetched(live, axis):
+    """``live`` [b, nq, nk] (nonzero: some pair of block (j, kk) is
+    selected) -> per block the index along ``axis`` (the call's inner
+    one: 2, the k-blocks of a q-row; 1, the q-blocks of a k-row) of the
+    block its step fetches: its own where it is live, else the next live
+    one of the walk, else the last one before, else -1 (a k-row nobody
+    reads). Along a walk the answer never falls, so a row's live blocks
+    are each copied once and nothing else is."""
+    n = live.shape[axis]
+    at = jnp.arange(n, dtype=jnp.int32).reshape(
+        [-1 if a == axis else 1 for a in range(3)])
+    ahead = jax.lax.cummin(jnp.where(live != 0, at, n), axis=axis,
+                           reverse=True)
+    behind = jax.lax.cummax(jnp.where(live != 0, at, -1), axis=axis)
+    return jnp.where(ahead < n, ahead, behind)
+
+
+def _selected_mask(selected, live):
+    """A selection as the dense composition's [b, tq, tk] mask (None:
+    the call has none)."""
+    if selected is None:
+        return None
+    return dsa_score.unpack(selected, selected.shape[2] // live.shape[1])
+
+
+def _selection(selected, live, q, k, seed_arr, axis):
+    """-> (the selection as the kernels' bias-slot operand
+    [b, 1, tq / 32, tk] int32, the scalar-prefetch operand with the
+    table of the blocks to fetch behind the seed pair (``_fetched``
+    along ``axis``), the table's (nq, nk)) of a call ``bhtd_selected``
+    takes: ``selected`` [b, tq / 32, tk] int32, ``live`` [b, nq, nk]
+    int32, its blocks the tile's."""
+    b, _, tq, _ = q.shape
+    if selected.shape != (b, tq // 32, k.shape[2]) or (
+            selected.dtype != jnp.int32):
+        raise ValueError(
+            f"attention: a selection is [b, tq / 32, tk] int32 beside "
+            f"causal (got {selected.shape} {selected.dtype} for q "
+            f"{q.shape})")
+    table = _fetched(live.astype(jnp.int32), axis)
+    return (selected[:, None],
+            jnp.concatenate([seed_arr, table.reshape(-1)]), live.shape[1:])
+
+
 def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
                         p_drop: float = 0.0,
                         q_block: Optional[int] = None,
@@ -1480,7 +1644,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
                         causal: bool = False,
                         window: Optional[int] = None,
                         block_diffusion: Optional[int] = None,
-                        q_pe=None, k_pe=None):
+                        q_pe=None, k_pe=None, selected=None, live=None):
     """-> (out, lse) with lse [b, h, tq, 1] f32 — REAL logsumexp rows on
     every path including the dense fallback (the ring-attention merge
     consumes them; the fallback backward still recomputes via vjp).
@@ -1507,7 +1671,18 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     second part of the queries and keys where they come in two, the
     scores scale * (q k^T + q_pe k_pe^T) with query head i reading
     k_pe's head i // (h / hp), and the default scale 1 / sqrt(dh + r);
-    only a call ``bhtd_parts`` takes."""
+    only a call ``bhtd_parts`` takes.
+
+    ``selected`` [b, tq / 32, tk] int32 with ``live`` [b, nq, nk] int32
+    (beside ``causal``; ``dsa_select``'s pair): query p reads key s only
+    where its bit is set (``dsa_score.pack_rows``, a q-block of tq / nq
+    queries), every head alike: a mask that is DATA (a learned sparse
+    attention's top-k), read in blocks of words beside K and V through
+    the bias operand's slot; the walk is the causal triangle's. ``live``
+    is 0 where a block holds no selected pair: by scalar prefetch such
+    a block's step computes nothing and fetches nothing (``_fetched``).
+    Only a call ``bhtd_selected`` takes; elsewhere the dense composition
+    under the mask."""
     if p_drop > 0.0 and seed is None:
         raise ValueError(
             "flash_attention: p_drop > 0 requires a per-step `seed`; "
@@ -1528,16 +1703,25 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
                      block_diffusion=block_diffusion,
                      itemsize=q.dtype.itemsize)
-    if tile is None:
+    chosen = selected is not None and bhtd_selected(
+        h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+        itemsize=q.dtype.itemsize, blocks=live.shape[1:],
+        plain=_only_causal(causal, bias, p_drop, window, bd, pe))
+    if tile is None or (selected is not None and not chosen):
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
         # derive from one score tensor (_reference_attention_with_lse).
         return _reference_attention_with_lse(
             q, k, v, bias, scale, p_drop,
             seed if p_drop > 0.0 else None, causal=causal, window=window,
-            block_diffusion=block_diffusion)
+            block_diffusion=block_diffusion,
+            selected=_selected_mask(selected, live))
 
     hb, bq, bk = tile
+    seed_arr, live_at = _seed_arr(seed), None
+    if chosen:
+        bias, seed_arr, live_at = _selection(selected, live, q, k, seed_arr,
+                                             2)
     ng, nq = h // hb, tq // bq
     # the inner axis: the key blocks, or those of a row's band, or a
     # block-masked row's own block and the clean half's
@@ -1545,12 +1729,14 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
           _k_steps(window, nq, tk // bk, bq, bk))
     kernel, in_specs, args, rows = _call_parts(
         _fwd_kernel,
-        _step_blocks(causal, True, bq, bk, nq, group, window, nk, bd), tile,
-        q, k, v, bias, pe)
+        _step_blocks(causal, True, bq, bk, nq, group, window, nk, bd,
+                     live_at), tile, q, k, v, bias, pe)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
                                p_drop=p_drop, causal=causal, window=window,
                                bd=bd)
-    operands = (_seed_arr(seed), *args)
+    if live_at is not None:
+        kernel = functools.partial(kernel, live=live_at)
+    operands = (seed_arr, *args)
     lse_spec, lse_shape = rows.stat, (b, h, tq, 1)
     if bhtd_stats_form(tile, tq) == "rows":
         lse_spec, lse_shape = rows.row, (b, h, 1, tq)
@@ -1579,7 +1765,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
 
 
 def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
-               window, bd=None, pe=None):
+               window, bd=None, pe=None, live=None):
     """dq, dk, dv as ONE call (_bwd_kernel); ``delta`` with the lse
     cotangent folded in, ``window`` as _band gives it, ``bd`` as
     _halves. ``pe`` = (q_pe, k_pe): dq_pe and dk_pe [b, h, tk, r], a
@@ -1592,11 +1778,11 @@ def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
     # (block-masked: the first clean k-row is seen by every q-block)
     q_steps = nq if bd else _q_steps(window, nq, nk, bq, bk)
     block_of = _step_blocks(causal, False, bq, bk, nq, 1, window, q_steps,
-                            bd)
+                            bd, live)
 
-    def at(i, hk, m, kk, r, *_):
+    def at(i, hk, m, kk, r, *seed_ref):
         # (the grid's heads: key/value head, then the member of its group)
-        return block_of(i, hk * group + m, kk, r)
+        return block_of(i, hk * group + m, kk, r, *seed_ref)
 
     kernel, specs, args, rows = _call_parts(_bwd_kernel, at, tile, q, k, v,
                                             bias, pe)
@@ -1605,6 +1791,8 @@ def _fused_bwd(q, k, v, bias, seed_arr, g, lse, delta, tile, scale, causal,
         kernel, scale=scale, nq=q_steps, nk=nk, group=group, causal=causal,
         window=window, last_q=nq - 1,
         slabs=sub and _edge_slabs(tq, tk, bq, bk, sub, window), bd=bd)
+    if live is not None:    # (the live table's (nq, nk): _selection)
+        kernel = functools.partial(kernel, live=live)
     # a resident gradient: all rows of one head, one block of the output
     dq_spec = pl.BlockSpec((1, 1, tq, dh),
                            lambda i, hk, m, *_: (i, hk * group + m, 0, 0))
@@ -1652,7 +1840,7 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
                         causal: bool = False, g_lse=None,
                         window: Optional[int] = None,
                         block_diffusion: Optional[int] = None,
-                        q_pe=None, k_pe=None):
+                        q_pe=None, k_pe=None, selected=None, live=None):
     """-> (dq, dk, dv), consuming the forward's saved (out, lse); of a
     call in two parts (``q_pe``, ``k_pe``: ``flash_attention_fwd``) ->
     (dq, dk, dv, dq_pe, dk_pe). The kernel writes dk_pe a QUERY head, in
@@ -1665,7 +1853,8 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     the ring-attention merge (block weights exp(lse_blk - lse_comb)).
     dlse/ds = p, so the lse cotangent phi folds EXACTLY into the
     existing backward as ds = p*(dp - (delta - phi)) — one subtraction
-    on the per-row delta, no kernel changes."""
+    on the per-row delta, no kernel changes. ``selected``, ``live``: the
+    forward's (``flash_attention_fwd``); no gradient reaches them."""
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     group = _kv_group(q, k, p_drop)
@@ -1680,12 +1869,19 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
                      block_diffusion=block_diffusion,
                      itemsize=q.dtype.itemsize)
-    if tile is None:
+    chosen = selected is not None and bhtd_selected(
+        h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+        itemsize=q.dtype.itemsize, blocks=live.shape[1:],
+        plain=_only_causal(causal, bias, p_drop, window, bd, pe))
+    if tile is None or (selected is not None and not chosen):
+        mask = _selected_mask(selected, live)
+
         def f(q, k, v):
             return _reference_attention_with_lse(
                 q, k, v, bias, scale, p_drop,
                 seed if p_drop > 0.0 else None, causal=causal,
-                window=window, block_diffusion=block_diffusion)
+                window=window, block_diffusion=block_diffusion,
+                selected=mask)
 
         _, vjp = jax.vjp(f, q, k, v)
         return vjp((g, jnp.zeros((b, h, tq, 1), jnp.float32)
@@ -1698,6 +1894,11 @@ def flash_attention_bwd(q, k, v, bias, seed, out, lse, g, scale=None,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     seed_arr = _seed_arr(seed)
+    if chosen:              # (bhtd_selected: the ONE call)
+        sel, seed_arr, live_at = _selection(selected, live, q, k, seed_arr,
+                                            1)
+        return _fused_bwd(q, k, v, sel, seed_arr, g, lse, delta, tile,
+                          scale, causal, None, live=live_at)
     if pe is not None:      # (bhtd_parts: the ONE call)
         *grads, dk_pe = _fused_bwd(q, k, v, None, seed_arr, g, lse, delta,
                                    tile, scale, causal, None, pe=pe)
@@ -1877,41 +2078,51 @@ def flash_attention_with_lse(q, k, v, bias=None, seed=None,
                              causal: bool = False,
                              window: Optional[int] = None,
                              block_diffusion: Optional[int] = None,
-                             q_pe=None, k_pe=None):
+                             q_pe=None, k_pe=None, selected=None, live=None):
     """(out, lse) variant of ``flash_attention`` — same backward rule
     (shared ``_vjp_bwd``: blocked Pallas kernels, true dbias on the dense
     fallback, float0 seed cotangent). The sdpa op uses this so its saved
     Lse output exists AND jax.vjp through the op (scan-over-layers grad)
     works despite pallas_call having no JVP rule. ``q_pe``, ``k_pe``:
     the queries' and keys' second part (``flash_attention_fwd``), with
-    cotangents of their own."""
+    cotangents of their own. ``selected``, ``live``: a selection and its
+    live table (``flash_attention_fwd``), integers without a cotangent."""
     return flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
                                q_block, k_block, causal, window,
-                               block_diffusion, q_pe, k_pe)
+                               block_diffusion, q_pe, k_pe, selected, live)
 
 
 def _fa_lse_vjp_fwd(q, k, v, bias, seed, scale, p_drop, q_block, k_block,
                     causal=False, window=None, block_diffusion=None,
-                    q_pe=None, k_pe=None):
+                    q_pe=None, k_pe=None, selected=None, live=None):
     out, lse = flash_attention_fwd(q, k, v, bias, seed, scale, p_drop,
                                    q_block, k_block, causal, window,
-                                   block_diffusion, q_pe, k_pe)
-    return (out, lse), (q, k, v, bias, seed, out, lse, q_pe, k_pe)
+                                   block_diffusion, q_pe, k_pe, selected,
+                                   live)
+    return (out, lse), (q, k, v, bias, seed, out, lse, q_pe, k_pe, selected,
+                        live)
 
 
 def _fa_lse_vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
                     block_diffusion, res, gs):
     g, g_lse = gs
-    *res, q_pe, k_pe = res
+    *res, q_pe, k_pe, selected, live = res
     q, k, v, bias, seed, out, lse = res
+    if selected is not None:
+        dq, dk, dv = flash_attention_bwd(
+            q, k, v, bias, seed, out, lse, g.astype(q.dtype), scale, p_drop,
+            q_block, k_block, causal, g_lse=g_lse, selected=selected,
+            live=live)
+        return (dq, dk, dv, None, _seed_cotangent(seed), None, None,
+                _seed_cotangent(selected), _seed_cotangent(live))
     if q_pe is None:
         return (*_vjp_bwd(scale, p_drop, q_block, k_block, causal, window,
                           block_diffusion, res, g.astype(q.dtype),
-                          g_lse=g_lse), None, None)
+                          g_lse=g_lse), None, None, None, None)
     dq, dk, dv, dq_pe, dk_pe = flash_attention_bwd(
         q, k, v, bias, seed, out, lse, g.astype(q.dtype), scale, p_drop,
         q_block, k_block, causal, g_lse=g_lse, q_pe=q_pe, k_pe=k_pe)
-    return dq, dk, dv, None, _seed_cotangent(seed), dq_pe, dk_pe
+    return dq, dk, dv, None, _seed_cotangent(seed), dq_pe, dk_pe, None, None
 
 
 flash_attention_with_lse.defvjp(_fa_lse_vjp_fwd, _fa_lse_vjp_bwd)

@@ -126,12 +126,28 @@ def inv_freq(dim, theta, scaling=None):
     return f / scaling.factor * ramp + f * (1.0 - ramp)
 
 
-def cos_sin(t, dim, theta, scaling=None):
+def cos_sin(t, dim, theta, scaling=None, positions=None, sections=None):
     """(cos, sin) [t, dim / 2] float32 of positions 0 .. t - 1 turning
     ``dim`` features, each times the scaling's attention factor: the one
-    place the angles are made, for the kernels' tables and ``_rotate``."""
+    place the angles are made, for the kernels' tables and ``_rotate``.
+    ``positions`` [n, t] (or [t]: one row), a device value: the
+    positions are the FEED's, and frequency pair i turns by row
+    axis(i)'s, axis(i) the index of the run of ``sections`` (n counts
+    that sum to dim / 2: Qwen2-VL's multi-axis rotary, temporal | height
+    | width) that i falls in; no ``sections``: row 0 for every pair."""
     freq = inv_freq(dim, theta, scaling)
-    ang = jnp.arange(t, dtype=_F32)[:, None] * freq[None, :]
+    if positions is None:
+        ang = jnp.arange(t, dtype=_F32)[:, None] * freq[None, :]
+    else:
+        pos = jnp.atleast_2d(positions).astype(_F32)
+        sections = tuple(sections or (dim // 2,))
+        if sum(sections) != dim // 2 or len(sections) > pos.shape[0] or (
+                pos.shape[1] != t):
+            raise ValueError(
+                f"rotary positions {pos.shape} with sections {sections} do "
+                f"not cover {dim // 2} frequency pairs over {t} positions")
+        axis = [a for a, n in enumerate(sections) for _ in range(n)]
+        ang = pos[jnp.asarray(axis)].T * freq[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scaling is not None and scaling.attention_factor != 1.0:
         cos, sin = (cos * scaling.attention_factor,
@@ -201,15 +217,17 @@ def rope_tile(b, t, h, dh, rotary_dim, interleaved, dtype, hk=None,
     return None
 
 
-def tables(t, dh, theta, scaling=None, rotary_dim=None):
+def tables(t, dh, theta, scaling=None, rotary_dim=None, positions=None,
+           sections=None):
     """(cos, signed sin) [t, dh] float32 of positions 0 .. t - 1, the
     angles as ``_rotate`` computes them (``cos_sin``): feature i and
     i + dh / 2 share angle p * theta^(-2i / dh), or the scaling's; sin
     is [-sin | +sin], the sign the forward's swapped halves take.
     ``rotary_dim`` < dh: the same over the first rotary_dim features,
-    then cos 1 and sin 0 on the features that pass."""
+    then cos 1 and sin 0 on the features that pass. ``positions``,
+    ``sections``: the fed positions' (``cos_sin``)."""
     part = rotary_dim or dh
-    cos, sin = cos_sin(t, part, theta, scaling)
+    cos, sin = cos_sin(t, part, theta, scaling, positions, sections)
     cos, sin = (jnp.concatenate([cos, cos], -1),
                 jnp.concatenate([-sin, sin], -1))
     if part == dh:
@@ -356,14 +374,16 @@ def _specs(rows, hb, hk, dh, tokens):
 
 @functools.partial(jax.jit, static_argnames=(
     "theta", "tile", "tokens_in", "tokens_out", "sign", "name", "interpret",
-    "scaling", "rotary_dim", "periods", "eps"))
-def _rope(q, k, gains=None, x=None, *, theta, tile, tokens_in, tokens_out,
-          sign, name, interpret, scaling=None, rotary_dim=None, periods=1,
-          eps=None):
+    "scaling", "rotary_dim", "periods", "eps", "sections"))
+def _rope(q, k, gains=None, x=None, positions=None, *, theta, tile,
+          tokens_in, tokens_out, sign, name, interpret, scaling=None,
+          rotary_dim=None, periods=1, eps=None, sections=None):
     """``gains`` (q's, k's: [dh] float32) with ``eps``: the per-head
     RMSNorm in the same pass; ``x`` (the projection's q and k in the
     results' layout): the backward of that call, which also returns the
-    two gains' gradients."""
+    two gains' gradients. ``positions`` [n, t] with ``sections``: the
+    tables are the fed positions' (``cos_sin``), device values the
+    kernel reads as it reads any table."""
     rows, hb = tile
     if tokens_in:
         (b, t, h, dh), hk = q.shape, k.shape[2]
@@ -377,7 +397,8 @@ def _rope(q, k, gains=None, x=None, *, theta, tile, tokens_in, tokens_out,
                   for n in (h, hk)]
     # the tables of ONE run of the positions, read once a run
     run = t // periods // rows
-    cos, sin = tables(t // periods, dh, theta, scaling, rotary_dim)
+    cos, sin = tables(t // periods, dh, theta, scaling, rotary_dim,
+                      positions, sections)
     table = pl.BlockSpec((rows, dh), (lambda bi, i, j: (i % run, 0))
                          if periods > 1 else (lambda bi, i, j: (i, 0)))
     moved = (h + hk) * b * t * dh
@@ -449,8 +470,14 @@ def _norm(gains, eps):
     return (None, None) if gains is None else (tuple(gains), float(eps))
 
 
+def _sections(sections):
+    """``sections`` as the jitted call's static argument."""
+    return None if not sections else tuple(int(n) for n in sections)
+
+
 def rope_fwd(q, k, theta, tile, tokens=False, scaling=None,
-             rotary_dim=None, periods=1, gains=None, eps=None):
+             rotary_dim=None, periods=1, gains=None, eps=None,
+             positions=None, sections=None):
     """(q, k) with rotary positions 0 .. t - 1 applied, head-major
     [b, h, t, dh] (k may have fewer heads), at ``tile`` as ``rope_tile``
     gives it. ``tokens``: q and k come token-major, [b, t, h, dh]. One
@@ -463,17 +490,22 @@ def rope_fwd(q, k, theta, tile, tokens=False, scaling=None,
     [dh] float32) and ``eps``: every head of q and of k is first
     RMS-normalised over its dh and multiplied by its gain
     (``ops/nn_ops._rms_norm``'s arithmetic in float32, rounded to the
-    values' dtype as that op's result is), in the same pass."""
+    values' dtype as that op's result is), in the same pass.
+    ``positions`` [n, t] with ``sections``: the positions are fed
+    (``cos_sin``; one run of them: ``periods`` 1)."""
     gains, eps = _norm(gains, eps)
-    return _rope(q, k, gains, theta=float(theta), tile=tuple(tile),
+    return _rope(q, k, gains, None, positions, theta=float(theta),
+                 tile=tuple(tile),
                  tokens_in=bool(tokens), tokens_out=False, sign=1.0,
                  name="rope.fwd", interpret=bool(_INTERPRET),
                  scaling=scaling, rotary_dim=_part(q, rotary_dim),
-                 periods=int(periods), eps=eps)
+                 periods=int(periods), eps=eps,
+                 sections=_sections(sections))
 
 
 def rope_bwd(dq, dk, theta, tile, tokens=False, scaling=None,
-             rotary_dim=None, periods=1, gains=None, eps=None, x=None):
+             rotary_dim=None, periods=1, gains=None, eps=None, x=None,
+             positions=None, sections=None):
     """The cotangents of ``rope_fwd``'s q and k from those of its
     results (head-major): the rotation's transpose, which is the
     rotation by the negated angles, written token-major where the
@@ -483,8 +515,9 @@ def rope_bwd(dq, dk, theta, tile, tokens=False, scaling=None,
     is made again from x, the gains' gradients are float32 sums."""
     gains, eps = _norm(gains, eps)
     return _rope(dq, dk, gains, None if gains is None else tuple(x),
-                 theta=float(theta), tile=tuple(tile),
+                 positions, theta=float(theta), tile=tuple(tile),
                  tokens_in=False, tokens_out=bool(tokens), sign=-1.0,
                  name="rope.bwd", interpret=bool(_INTERPRET),
                  scaling=scaling, rotary_dim=_part(dq, rotary_dim),
-                 periods=int(periods), eps=eps)
+                 periods=int(periods), eps=eps,
+                 sections=_sections(sections))

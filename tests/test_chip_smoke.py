@@ -201,6 +201,12 @@ BD_TINY = dict(
     num_key_value_heads=1, head_dim=128, moe_intermediate_size=128,
     num_experts=4, num_experts_per_tok=2, held_experts=(0, 2),
     num_hidden_layers=2)
+KEYE_TINY = dict(
+    vocab_size=50, hidden_size=128, num_attention_heads=8,
+    num_key_value_heads=2, head_dim=128, moe_intermediate_size=128,
+    num_experts=4, num_experts_per_tok=2, held_experts=(0, 2),
+    num_hidden_layers=2, indexer_num_heads=2, indexer_head_dim=64,
+    topk=96)
 KDA_TINY = dict(
     vocab_size=50, hidden_size=128, num_hidden_layers=3,
     intermediate_size=128, num_attention_heads=1, kv_lora_rank=32,
@@ -236,6 +242,8 @@ PHASES = {
             "gated_delta_rule", 512, dict(t_check=128), KDA_TINY),
     "xing4": ("flash_attention grouped_matmul pair_sum hc_mix", 1024,
               dict(t_check=1024), XING4_TINY),
+    "keye": ("flash_attention grouped_matmul pair_sum rope dsa_score", 1024,
+             dict(t_check=1024), KEYE_TINY),
 }
 
 
@@ -789,6 +797,51 @@ def test_xing4_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     assert set(row["rel_err"]) == {"h_res", "dz"}
     assert max(row["rel_err"].values()) < 1e-4
     assert row["columns_off_one"] < 1e-5 and row["hc_kernel_ms"] == {}
+
+
+def test_keye_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
+    """The phase at a cut config through the interpreters (8 / 2 heads
+    of 128, an index head of 64 and the fed positions are the model's):
+    every layer lowers a ``dsa_select`` on ``dsa.score.fwd``, a
+    ``dsa_index_loss`` on ``dsa.loss.bwd`` and its grad op, one attention
+    call each way under the selection in the BHTD kernels
+    (``sel=operand``: the tile's blocks are the selection's chunks of
+    512), the backward one call, and two rotary embeddings each way; on the device (here: the
+    CPU) the kernel's selection is XLA's and the kernels under it agree
+    with the dense composition."""
+    row = phase_row("keye")
+    shape = "b1 t1024 hI2 dI64"
+    assert row["dsa"] == {
+        f"kernel select fwd {shape} k96 cq512 ck512": 2,
+        f"kernel loss fwd {shape} k0 cq512 ck512": 2,
+        f"xla loss bwd {shape} k0 cq512 ck512": 2}
+    call = "b1 tq1024 tk1024 h8 kv2 dh128 [hb1 bq512 bk512]"
+    assert row["attention"] == {
+        f"bhtd fwd {call} stats=rows sel=operand": 2,
+        f"bhtd bwd {call} form=fused edge=256x256 sel=operand": 2}
+    assert row["rotary_embeddings"] == {
+        "kernel fwd bthd 128 norm=head": 2, "kernel bwd bthd 128 norm=head": 2,
+        "xla fwd bthd 64": 2, "xla bwd bthd 64": 2}
+    assert row["kernel_ms"] == {}               # (a trace needs the chip)
+    assert row["selection_agrees"] > 0.9999
+    assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+def test_keye_phase_fails_on_a_selection_that_runs_dense(phase_row,
+                                                         monkeypatch):
+    # the calls under a selection as the dense composition, and the
+    # scores as XLA's ops where score_tile takes the call: the counters'
+    # rows say so and the phase refuses them
+    from paddle_tpu.parallel import dsa_score
+
+    monkeypatch.setattr(dsa_score, "_INTERPRET", True)
+    row = phase_row("keye")
+    fails("keye", "none dense", **dict(row, attention={
+        f"dense {d} b1 tq1024 tk1024 h8 kv2 dh128 sel=dense": 2
+        for d in ("fwd", "bwd")}))
+    fails("keye", "as kernel", **dict(row, dsa={
+        k.replace("kernel select", "xla select"): v
+        for k, v in row["dsa"].items()}))
 
 
 def test_xing4_phase_fails_on_a_mix_without_the_kernel(phase_row,
