@@ -26,8 +26,8 @@ points a user calls:
    write-back of four residual streams a sublayer each way, the mixes'
    Sinkhorn iterations on the ``hc.mix.*`` kernels, yarn tables) and
    runs the kernels against XLA's ops; the ``keye`` phase lowers
-   ``keye-train-s16384`` (a ``dsa_select`` a layer on ``dsa.score.fwd``,
-   the attention under its selection in the BHTD kernels, the indexer's
+   ``keye-train-s16384`` (a ``dsa_select`` a layer on ``dsa.score.fwd``
+   and ``dsa.topk.fwd``, the attention under its selection in the BHTD kernels, the indexer's
    loss) and runs both against their plain forms; the
    ``loss_head`` phase compiles a Program that is only
    ``olmoe-train-s4096``'s head and holds its temporaries under the
@@ -2032,6 +2032,15 @@ def keye_rows_hold(cfg, seq, lowered):
             f"gains and {n} of the indexer's: {ropes}")
     _one_backward_call(attn)
     _statistics_in_rows(attn)
+    # the top-k's thresholds: dsa.topk.fwd's passes read a chunk's causal
+    # prefix, XLA's ops the row's width (pt_dsa_topk_columns_total)
+    want = {kind: n * a_row for kind, a_row in dsa_ops.columns(
+        seq, min(cfg.topk, seq), cq, ck,
+        dsa_score.topk_tile(cq, ck, seq)).items()}
+    check(lowered["topk_columns"] == want,
+          f"expected the selects' score columns {want} (walked: what the "
+          f"thresholds' passes read, the causal prefixes where they are "
+          f"dsa.topk.fwd's): {lowered['topk_columns']}")
 
 
 def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
@@ -2043,7 +2052,9 @@ def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
        of 128 experts held, an eighth of the vocabulary, bf16 AMP, Adam)
        is LOWERED, not run (perf/run.py runs it), and the dispatch
        counters are held to what the cell must lower: a ``dsa_select``
-       a layer whose scores are ``dsa.score.fwd``, a ``dsa_index_loss``
+       a layer whose scores are ``dsa.score.fwd`` and whose thresholds'
+       passes read the chunks' causal prefixes (``dsa.topk.fwd``:
+       ``pt_dsa_topk_columns_total``), a ``dsa_index_loss``
        a layer that is ``dsa.loss.bwd`` and its grad op, one attention call a layer each way under the
        selection in the BHTD kernels (``sel=operand``; none ``dense``),
        the backward one call, the logsumexp in rows, and two rotary
@@ -2053,7 +2064,9 @@ def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
     2. On the device, over ``t_check`` positions at ``heads`` of ``dh``:
        ``dsa_select`` with the kernel against XLA's ops (the share of
        the selection that agrees, the logsumexp rows), every row's count
-       of keys, the BHTD kernels under that selection and its live
+       of keys, the selection against ``lax.top_k`` over the same scores
+       position for position (one chunk without a top-k, three with
+       ``dsa.topk.fwd``'s thresholds), the BHTD kernels under that selection and its live
        table, forward and the three gradients, against the dense
        composition, and ``dsa_index_loss`` with the kernel against XLA's
        ops a tile (the loss and its three gradients); the kernels' ms a
@@ -2068,6 +2081,7 @@ def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
     cfg, _, rows = lower_cell(
         "keye", seq, overrides, dsa=dsa_dispatch,
         attention=attention_dispatch, rotary_embeddings=rope_dispatch,
+        topk_columns=dsa_ops.topk_columns,
         feeds={"input_ids": ((1, seq), "int32"),
                "labels": ((1, seq), "int32"),
                "position_ids": ((3, seq), "int32")})
@@ -2098,7 +2112,22 @@ def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
           f"every query chooses min(p + 1, {topk}) keys: "
           f"{counts[:4]} .. {counts[-4:]}")
     row = {**rows, "rel_err": {}, "kernel_ms": {}}
-    if dsa_score.score_tile(cq, ck, hi, di):
+    kernel = dsa_score.score_tile(cq, ck, hi, di)
+    w32 = w[0].astype(jnp.float32)
+    scores = jnp.concatenate([
+        dsa_score.score_rows(c, qi[0, :, c * cq:(c + 1) * cq], ki[0, 0],
+                             w32[c * cq:(c + 1) * cq], attrs["scale"], ck)
+        for c in range(t // cq)]) if kernel else dsa_ops.score_tile(
+            qi[0], ki[0, 0], w32, attrs["scale"])
+    causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    row["top_k_agrees"] = float(np.mean(
+        np.asarray(mask[0]) == np.asarray(jax.jit(
+            dsa_ops.choose_by_sort, static_argnums=2)(scores, causal,
+                                                      topk))))
+    check(row["top_k_agrees"] == 1.0,
+          f"the selection is lax.top_k's over the same scores at "
+          f"{row['top_k_agrees']} of its positions, not every one")
+    if kernel:
         keep, dsa_score.score_tile = dsa_score.score_tile, lambda *a: False
         try:
             plain = select()
@@ -2179,7 +2208,8 @@ def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
     # (a trace needs the chip: the CPU tests run this phase through the
     # interpreters and read {})
     check(jax.default_backend() != "tpu" or sorted(row["kernel_ms"]) == [
-        "attn.bhtd.bwd", "attn.bhtd.fwd", "dsa.loss.bwd", "dsa.score.fwd"],
+        "attn.bhtd.bwd", "attn.bhtd.fwd", "dsa.loss.bwd", "dsa.score.fwd",
+        "dsa.topk.fwd"],
         f"kernels in the trace: {ms}")
     row["rel_err"] = {k_: round(v_, 6) for k_, v_ in row["rel_err"].items()}
     say(f"  keye kernels, ms a call at t{t} h{h} kv{hk} dh{dh}: "

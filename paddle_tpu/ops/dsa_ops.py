@@ -36,10 +36,20 @@ The scores are made a ``q_chunk`` of queries at a time against
 ``kv_chunk`` keys a tile ([hI, cq, ck] float32 is the largest value that
 exists), tiles above the diagonal not at all, and never held whole. The
 top-k is no sort: a row's threshold is found by BISECTION over the float32
-bit pattern (32 counting passes over the chunk's [cq, t] keys: the k-th
-largest value exactly), and among the keys that tie with it the lowest
-positions by a second bisection over the position (log2 t passes), so
-the tie rule is the reference's (``lax.top_k``'s) bit for bit.
+bit pattern (32 counting passes: the k-th largest value exactly), and,
+only in a chunk where some row has MORE keys equal to its threshold than
+it still needs, the lowest positions among those by a second bisection
+over the position (log2 t passes), so the tie rule is the reference's
+(``lax.top_k``'s) bit for bit; elsewhere the keys at or over the
+threshold are the answer. The chunks whose last query has at most
+``topk`` earlier keys make no pass at all (every valid key is chosen: a
+``lax.map`` of their own; the dense stage's every chunk). Where
+``dsa_score.topk_tile`` takes the call the 32 passes are the kernel
+``dsa.topk.fwd``'s, which counts over the chunk's CAUSAL PREFIX and no
+more (the keys behind are -inf that nobody need count); XLA's ops count
+over the chunk's whole [cq, t] row. ``pt_dsa_topk_columns_total{kind}``
+counts, a lowered call, the columns those passes read and the causal
+prefixes of the chunks that make them.
 
 ``dsa_index_loss`` walks the same tiles once and returns L_I TOGETHER
 with its gradient: the target is detached, so dL/dI = (softmax_S(I) - P)
@@ -55,12 +65,15 @@ A chunk's scores are the kernel ``dsa.score.fwd``
 (parallel/dsa_score.py) where ``dsa_score.score_tile`` takes the call (a
 TPU, no mesh, tiles on the lanes), else XLA's ops a tile; the loss pass
 is the kernel ``dsa.loss.bwd`` where ``dsa_score.loss_tile`` takes it,
-else XLA's ops a tile under ``lax.scan``; the top-k is XLA's ops.
+else XLA's ops a tile under ``lax.scan``; the top-k's thresholds are the
+kernel ``dsa.topk.fwd`` where ``dsa_score.topk_tile`` takes them, the
+rest of it XLA's ops.
 ``pt_dsa_dispatch_total{op, pass, impl, shape}`` counts the lowered
 calls and says which."""
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -83,16 +96,30 @@ _M_DISPATCH = _monitor.counter(
     "k, the tiles cq x ck)")
 
 
+_M_COLUMNS = _monitor.counter(
+    "pt_dsa_topk_columns_total",
+    "score columns of dsa_select calls lowered, over the q-chunks that "
+    "make a top-k (a chunk whose queries have at most topk keys makes no "
+    "pass): kind walked (the columns the threshold's 32 counting passes "
+    "read: the chunk's live key blocks where they are dsa.topk.fwd's, the "
+    "row's t as XLA's ops) and causal (the keys the chunk's last query can "
+    "choose from, (c + 1) cq)")
+
+
 def _x(ins, slot, i=0):
     v = ins.get(slot)
     return v[i] if v else None
 
 
-def _note(op, direction, shape, impl="xla"):
+def _lowering():
     # off with telemetry; build-time shape inference is not a lowering
     from paddle_tpu.core import interp
 
-    if _monitor.enabled() and interp.lowering_active():
+    return _monitor.enabled() and interp.lowering_active()
+
+
+def _note(op, direction, shape, impl="xla"):
+    if _lowering():
         _M_DISPATCH.inc(labels={"op": op, "pass": direction, "impl": impl,
                                 "shape": shape})
 
@@ -107,6 +134,13 @@ def dispatch_counts():
                                                  "shape"))
         out[name] = out.get(name, 0) + int(row["value"])
     return out
+
+
+def topk_columns():
+    """{"walked": .., "causal": ..}: ``pt_dsa_topk_columns_total`` over
+    the ``dsa_select`` calls lowered so far."""
+    return {row["labels"]["kind"]: int(row["value"])
+            for row in _monitor.snapshot()[_M_COLUMNS.name]["values"]}
 
 
 def chunk(t, want):
@@ -154,54 +188,107 @@ def _sortable(x):
     return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
 
 
-def choose(scores, valid, k):
+def columns(t, topk, cq, ck, kernel):
+    """{"walked", "causal"}: a row's score columns over the q-chunks that
+    make a top-k (``pt_dsa_topk_columns_total``'s two kinds): what the
+    threshold's counting passes read (``kernel``: ``dsa.topk.fwd``'s, a
+    chunk's live key blocks; else the row's t) and the chunks' causal
+    prefixes."""
+    chunks = range(min(topk // cq, t // cq), t // cq)
+    return {"walked": sum(min(-(-(c + 1) * cq // ck) * ck, t) if kernel
+                          else t for c in chunks),
+            "causal": sum((c + 1) * cq for c in chunks)}
+
+
+def choose(scores, valid, k, threshold=None):
     """[n, t] bool: each row's min(valid count, k) valid entries of
     largest ``scores`` [n, t] float32, ties to the lower index: what
     ``lax.top_k`` over the valid entries would list, without a sort.
     The row's threshold T (its k-th largest key) is built bit by bit
-    from the top: a bit stays where at least k keys reach the candidate.
-    Of the keys equal to T the first ``need`` = k - count(key > T) are
-    taken, up to the position P found the same way over the index."""
+    from the top: a bit stays where at least k keys reach the candidate
+    (32 counting passes over the [n, t] keys). Where every row has just
+    as many keys at or over T as it wants, those are the answer. Where
+    some row has more (a surplus of keys EQUAL to T), of those the first
+    ``need`` = k - count(key > T) are taken, up to the position P found
+    the same way over the index (one pass and log2 t more, for every row
+    of the call: never more passes than 33 + log2 t). ``threshold``: (T
+    [n] uint32, the count of keys at or over it [n] int32) where the
+    caller has them (``dsa_score.threshold_rows``), so the 32 passes are
+    not made here."""
     n, t = scores.shape
     keys = jnp.where(valid, _sortable(scores.astype(_F32)), jnp.uint32(0))
-    want = jnp.minimum(jnp.sum(valid, axis=1), k).astype(jnp.int32)
+    held = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    want = jnp.minimum(held, k)
 
     def count(m):
         return jnp.sum(m, axis=1, dtype=jnp.int32)
 
-    def value_bit(i, thr):
+    def value_bit(i, at):
+        thr, reach = at         # reach: the keys at or over thr
         cand = thr | jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
-        keep = count(jnp.logical_and(valid, keys >= cand[:, None])) >= want
-        return jnp.where(keep, cand, thr)
+        # (an entry that is not valid is key 0, under every candidate)
+        reach_c = count(keys >= cand[:, None])
+        keep = reach_c >= want
+        return jnp.where(keep, cand, thr), jnp.where(keep, reach_c, reach)
 
-    thr = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((n,), jnp.uint32))
-    above = jnp.logical_and(valid, keys > thr[:, None])
-    tied = jnp.logical_and(valid, keys == thr[:, None])
-    need = want - count(above)
-    at = jnp.arange(t, dtype=jnp.int32)[None, :]
-    bits = max(int(t - 1).bit_length(), 1)
+    thr, reach = threshold or jax.lax.fori_loop(
+        0, 32, value_bit, (jnp.zeros((n,), jnp.uint32), held))
 
-    def index_bit(i, pos):
-        cand = pos | jnp.left_shift(jnp.int32(1), (bits - 1 - i))
-        keep = count(jnp.logical_and(tied, at < cand[:, None])) < need
-        return jnp.where(keep, cand, pos)
+    def lowest_tied():
+        above = jnp.logical_and(valid, keys > thr[:, None])
+        tied = jnp.logical_and(valid, keys == thr[:, None])
+        need = want - count(above)
+        at = jnp.arange(t, dtype=jnp.int32)[None, :]
+        bits = max(int(t - 1).bit_length(), 1)
 
-    # the need-th tied entry lies at ``pos``: fewer than need lie before
-    pos = jax.lax.fori_loop(0, bits, index_bit, jnp.zeros((n,), jnp.int32))
-    chosen = jnp.logical_or(above,
-                            jnp.logical_and(tied, at <= pos[:, None]))
+        def index_bit(i, pos):
+            cand = pos | jnp.left_shift(jnp.int32(1), (bits - 1 - i))
+            keep = count(jnp.logical_and(tied, at < cand[:, None])) < need
+            return jnp.where(keep, cand, pos)
+
+        # the need-th tied entry lies at ``pos``: fewer than need lie before
+        pos = jax.lax.fori_loop(0, bits, index_bit,
+                                jnp.zeros((n,), jnp.int32))
+        return jnp.logical_or(above,
+                              jnp.logical_and(tied, at <= pos[:, None]))
+
+    chosen = jax.lax.cond(
+        jnp.any(reach > want), lowest_tied,
+        lambda: jnp.logical_and(valid, keys >= thr[:, None]))
     return jnp.logical_and(chosen, (want > 0)[:, None])
 
 
-def select_row(qi, ki, w, scale, topk, cq, ck, kernel=False):
-    """One batch row: qi [hI, t, dI], ki [t, dI], w [t, hI] float32 ->
-    (selected [(t / cq) n, t] int32 (``pack_rows`` a chunk: n word
-    rows), live [t / cq, t / ck] int32, the logsumexp of I over the
-    selected keys [t] float32). ``kernel``: a chunk's scores
-    are ``dsa.score.fwd``'s (parallel/dsa_score.py), else XLA's ops a
-    tile."""
-    t = qi.shape[1]
-    nq, nk = t // cq, t // ck
+def choose_by_sort(scores, valid, k):
+    """``choose`` by ``lax.top_k`` over the same sortable keys (as signed
+    words; ties to the lower index): what the checks hold it to
+    (chip_smoke.py, benchmarks/dsa_topk_candidates.py), a sort a row."""
+    n, t = scores.shape
+    keys = jnp.where(valid, _sortable(scores.astype(_F32)), jnp.uint32(0))
+    _, idx = jax.lax.top_k(jax.lax.bitcast_convert_type(
+        keys ^ jnp.uint32(0x80000000), jnp.int32), min(k, t))
+    want = jnp.minimum(jnp.sum(valid, axis=1, dtype=jnp.int32), k)
+    listed = jnp.arange(idx.shape[1], dtype=jnp.int32)[None, :] < want[:, None]
+    return jnp.zeros((n, t), jnp.int32).at[
+        jnp.arange(n)[:, None], idx].add(listed.astype(jnp.int32)) > 0
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "topk", "cq", "ck", "top", "kernel", "thresholds", "interpret"))
+def _select_run(chunks, qi, ki, w, *, scale, topk, cq, ck, top, kernel,
+                thresholds, interpret):
+    """The q-chunks ``chunks`` [n] int32 of a row: their index queries
+    qi [n, hI, cq, dI] and weights w [n, cq, hI] float32 against the
+    row's keys ki [t, dI] -> (``pack_rows`` a chunk [n, words, t] int32,
+    live [n, t / ck] int32, the logsumexp of I over the selected keys
+    [n, cq] float32). ``top``: a top-k is made (else every valid key is
+    chosen); ``kernel``, ``thresholds``: the scores are
+    ``dsa.score.fwd``'s, the top-k's thresholds ``dsa.topk.fwd``'s;
+    ``interpret``: the kernels' test hook, part of the trace. One jitted
+    function a shape: a model's layers make the same calls."""
+    from paddle_tpu.parallel import dsa_score
+
+    t = ki.shape[0]
+    nk = t // ck
     s_at = jnp.arange(t, dtype=jnp.int32)[None, :]
 
     def xla_scores(c, qi_c, w_c):
@@ -217,21 +304,47 @@ def select_row(qi, ki, w, scale, topk, cq, ck, kernel=False):
 
     def rows(args):
         c, qi_c, w_c = args                 # [hI, cq, dI], [cq, hI]
-        if kernel:
-            from paddle_tpu.parallel import dsa_score
-
-            scores = dsa_score.score_rows(c, qi_c, ki, w_c, scale, ck)
-        else:
-            scores = xla_scores(c, qi_c, w_c)
+        scores = (dsa_score.score_rows(c, qi_c, ki, w_c, scale, ck, interpret)
+                  if kernel else xla_scores(c, qi_c, w_c))
         p_at = c * cq + jnp.arange(cq, dtype=jnp.int32)[:, None]
-        chosen = choose(scores, s_at <= p_at, topk)
+        valid = s_at <= p_at
+        if not top:
+            chosen = valid
+        else:
+            chosen = choose(scores, valid, topk, dsa_score.threshold_rows(
+                c, scores, topk, ck, interpret) if thresholds else None)
         lse = jax.scipy.special.logsumexp(
             jnp.where(chosen, scores, _NEG_INF), axis=1)
         live = jnp.any(chosen.reshape(cq, nk, ck), axis=(0, 2))
         return pack_rows(chosen), live.astype(jnp.int32), lse
 
-    selected, live, lse = jax.lax.map(
-        rows, (jnp.arange(nq), _tiles(qi, cq), _tiles(w, cq)))
+    return jax.lax.map(rows, (chunks, qi, w))
+
+
+def select_row(qi, ki, w, scale, topk, cq, ck, kernel=False,
+               thresholds=False):
+    """One batch row: qi [hI, t, dI], ki [t, dI], w [t, hI] float32 ->
+    (selected [(t / cq) n, t] int32 (``pack_rows`` a chunk: n word
+    rows), live [t / cq, t / ck] int32, the logsumexp of I over the
+    selected keys [t] float32). The chunks whose last query has at most
+    ``topk`` earlier keys go first, without a top-k; then the others.
+    ``kernel``: a chunk's scores are ``dsa.score.fwd``'s
+    (parallel/dsa_score.py), else XLA's ops a tile; ``thresholds``: the
+    top-k's thresholds are ``dsa.topk.fwd``'s, else XLA's ops."""
+    from paddle_tpu.parallel import dsa_score
+
+    t = qi.shape[1]
+    nq = t // cq
+    whole = min(topk // cq, nq)
+    qi_t, w_t = _tiles(qi, cq), _tiles(w, cq)
+    selected, live, lse = (jnp.concatenate(part) for part in zip(*(
+        _select_run(jnp.arange(first, behind), qi_t[first:behind], ki,
+                    w_t[first:behind], scale=float(scale), topk=int(topk),
+                    cq=cq, ck=ck, top=top, kernel=bool(kernel),
+                    thresholds=bool(top and thresholds),
+                    interpret=bool(dsa_score._INTERPRET))
+        for first, behind, top in ((0, whole, False), (whole, nq, True))
+        if behind > first)))
     return selected.reshape(-1, t), live, lse.reshape(t)
 
 
@@ -256,11 +369,15 @@ def _dsa_select(ins, attrs):
     qi, ki, w = _x(ins, "QI"), _x(ins, "KI"), _x(ins, "W")
     scale, topk, cq, ck = _select_attrs(attrs, qi.shape[2])
     kernel = dsa_score.score_tile(cq, ck, qi.shape[1], qi.shape[3])
+    thresholds = dsa_score.topk_tile(cq, ck, qi.shape[2])
     _note("select", "fwd", _shape(qi, topk, cq, ck),
           "kernel" if kernel else "xla")
+    if _lowering():
+        for kind, n in columns(qi.shape[2], topk, cq, ck, thresholds).items():
+            _M_COLUMNS.inc(qi.shape[0] * n, labels={"kind": kind})
     selected, live, lse = jax.lax.map(
         lambda a: select_row(a[0], a[1][0], a[2].astype(_F32), scale, topk,
-                             cq, ck, kernel), (qi, ki, w))
+                             cq, ck, kernel, thresholds), (qi, ki, w))
     return {"Selected": [selected], "Live": [live], "IndexLse": [lse]}
 
 

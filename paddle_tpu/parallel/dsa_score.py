@@ -19,6 +19,24 @@ index map repeats the chunk's last live block. The chunk's index is a
 traced value (``dsa_select`` walks the chunks under ``lax.map``), so it
 rides in as a scalar-prefetch operand.
 
+``dsa.topk.fwd``: the top-k's THRESHOLD for one chunk's rows, the k-th
+largest score of each by bisection over the float32's bits, counted over
+the chunk's CAUSAL PREFIX and no more. ``dsa_select``'s form in XLA's
+ops counts 32 times over a [cq, t] array whatever the chunk, because a
+traced chunk index cannot shape an array; a kernel's loop can end where
+the chunk's keys end. The grid walks the row's key blocks as
+``dsa.score.fwd`` does (a block behind the chunk's last live one fetches
+nothing); a live step turns its [cq, ck] scores into sortable keys (as
+signed words, a key behind the query's own position the lowest) in a
+VMEM scratch that holds the whole prefix, up to 32 MB; the last step
+builds the threshold bit by bit from the top, ``_ROWS`` rows at a time:
+a pass is a loop over the chunk's live blocks alone, whole vregs
+compared with the candidate and added into a [rows, 128] count that is
+summed across the lanes once a pass. It returns each row's threshold
+and how many keys reach it; the rest of the selection (the mask, the
+ties' positions where a row has a surplus of them, the packing, the
+logsumexp) stays ``ops/dsa_ops.choose``'s, one pass each.
+
 ``dsa.loss.bwd``: the indexer's KL loss of one batch row TOGETHER with
 its gradient (the target is detached, so dL/dI needs nothing from
 upstream), one call over the causal triangle's (cq, ck) tiles, the keys
@@ -39,8 +57,8 @@ in their output blocks over its tiles; dkI^T stays resident in VMEM for
 the whole call. XLA's form of the same lines keeps the [hI, cq, ck] and
 [h, cq, ck] float32 products of a tile in HBM.
 
-``score_tile`` / ``loss_tile`` say kernel or XLA's form, from the call's
-shapes, the backend and the mesh; ``pt_dsa_dispatch_total{impl}``
+``score_tile`` / ``topk_tile`` / ``loss_tile`` say kernel or XLA's form,
+from the call's shapes, the backend and the mesh; ``pt_dsa_dispatch_total{impl}``
 records it."""
 
 from __future__ import annotations
@@ -185,14 +203,124 @@ def _score_rows(c, qi, ki, w, *, scale, ck, interpret):
     )(jnp.asarray(c, jnp.int32).reshape(1), qi, ki, w.astype(_F32))
 
 
-def score_rows(c, qi, ki, w, scale, ck):
+def score_rows(c, qi, ki, w, scale, ck, interpret=None):
     """I of chunk ``c``'s queries against every key, [cq, t] float32:
     qi [hI, cq, dI] (the chunk's index queries), ki [t, dI] (the ONE
     index key head), w [cq, hI] (the chunk's per-head weights); a block
     of ``ck`` keys wholly after the chunk's queries reads -inf. One
-    jitted function a shape: a model's layers make the same call."""
+    jitted function a shape: a model's layers make the same call.
+    ``interpret``: the test hook's value as a jitted caller keyed its
+    own trace on it (None: as it stands)."""
     return _score_rows(c, qi, ki, w, scale=float(scale), ck=int(ck),
-                       interpret=bool(_INTERPRET))
+                       interpret=bool(_INTERPRET if interpret is None
+                                      else interpret))
+
+
+# dsa.topk.fwd's row block: a pass's candidate, count and running
+# threshold of this many rows stay in vector registers
+_ROWS = 64
+_INT_MIN = -2 ** 31
+
+
+def topk_tile(cq, ck, t, on_mesh=None):
+    """Does ``dsa.topk.fwd`` find the thresholds of a chunk of ``cq``
+    queries over a row of ``t`` keys in blocks of ``ck``? Where
+    ``dsa.score.fwd`` takes the tile (a TPU, no mesh, whole lane tiles of
+    keys), the chunk is whole row blocks and a chunk's keys fit the VMEM
+    scratch (32 MB)."""
+    if on_mesh is None:
+        from paddle_tpu.core import interp
+
+        on_mesh = interp.spmd_ctx() is not None
+    return bool(kernels_enabled() and not on_mesh and cq % _ROWS == 0
+                and ck % 128 == 0 and t % ck == 0
+                and 4 * cq * t <= 32 * 2**20)
+
+
+def _topk_kernel(c_ref, s_ref, o_ref, keys_ref, *, cq, ck, topk):
+    kk, c = pl.program_id(0), c_ref[0]
+    last = _last_live(c, cq, ck)
+
+    @pl.when(kk <= last)
+    def _keys():
+        # float32 -> words in the same order under a SIGNED compare
+        # (dsa_ops._sortable's keys less the top bit); a key after the
+        # query's own position is the lowest word
+        u = jax.lax.bitcast_convert_type(s_ref[...], jnp.int32)
+        key = u ^ (jnp.right_shift(u, 31) & jnp.int32(0x7FFFFFFF))
+        p_at = c * cq + jax.lax.broadcasted_iota(jnp.int32, (cq, ck), 0)
+        s_at = kk * ck + jax.lax.broadcasted_iota(jnp.int32, (cq, ck), 1)
+        keys_ref[kk] = jnp.where(s_at <= p_at, key, jnp.int32(_INT_MIN))
+
+    @pl.when(kk == pl.num_programs(0) - 1)
+    def _bisect():
+        for r0 in range(0, cq, _ROWS):
+            held = c * cq + r0 + 1 + jax.lax.broadcasted_iota(
+                jnp.int32, (_ROWS, 128), 0)
+            want = jnp.minimum(held, topk)
+
+            def bit(i, at, r0=r0, want=want):
+                thr, reach = at
+                cand = thr | jnp.left_shift(jnp.int32(1),
+                                            (31 - i).astype(jnp.int32))
+                signed = cand ^ jnp.int32(_INT_MIN)
+
+                def block(j, n):
+                    for l0 in range(0, ck, 128):
+                        n = n + (keys_ref[j, r0:r0 + _ROWS, l0:l0 + 128]
+                                 >= signed).astype(jnp.int32)
+                    return n
+
+                n = jax.lax.fori_loop(0, last + 1, block,
+                                      jnp.zeros((_ROWS, 128), jnp.int32))
+                n = jnp.broadcast_to(jnp.sum(
+                    n, axis=1, keepdims=True, dtype=jnp.int32), (_ROWS, 128))
+                keep = n >= want
+                return jnp.where(keep, cand, thr), jnp.where(keep, n, reach)
+
+            thr, reach = jax.lax.fori_loop(
+                0, 32, bit, (jnp.zeros((_ROWS, 128), jnp.int32), held))
+            o_ref[0, r0:r0 + _ROWS] = thr
+            o_ref[1, r0:r0 + _ROWS] = reach
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "ck", "interpret"))
+def _threshold_rows(c, scores, *, topk, ck, interpret):
+    cq, t = scores.shape
+    out = pl.pallas_call(
+        functools.partial(_topk_kernel, cq=cq, ck=ck, topk=topk),
+        name="dsa.topk.fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t // ck,),
+            in_specs=[pl.BlockSpec((cq, ck), lambda kk, c_: (
+                0, jnp.minimum(kk, _last_live(c_[0], cq, ck))))],
+            out_specs=pl.BlockSpec((2, cq, 128), lambda kk, c_: (0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((t // ck, cq, ck), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((2, cq, 128), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * cq * t + 16 * 2**20),
+        cost_estimate=pl.CostEstimate(
+            flops=32 * cq * t, transcendentals=0,
+            bytes_accessed=4 * cq * t),
+        interpret=interpret,
+    )(jnp.asarray(c, jnp.int32).reshape(1), scores)
+    return (jax.lax.bitcast_convert_type(out[0, :, 0], jnp.uint32),
+            out[1, :, 0])
+
+
+def threshold_rows(c, scores, topk, ck, interpret=None):
+    """Chunk ``c``'s rows of scores [cq, t] float32 (``score_rows``'s) ->
+    (each row's threshold [cq] uint32: the min(p + 1, topk)-th largest
+    of its keys s <= p = c cq + r as ``dsa_ops._sortable`` orders them,
+    and how many of them are at or over it [cq] int32): what
+    ``dsa_ops.choose``'s 32 counting passes give, counted over the
+    chunk's causal prefix alone. ``interpret``: as ``score_rows``'s."""
+    return _threshold_rows(c, scores, topk=int(topk), ck=int(ck),
+                           interpret=bool(_INTERPRET if interpret is None
+                                          else interpret))
 
 
 def loss_tile(cq, ck, heads, dim, on_mesh=None):

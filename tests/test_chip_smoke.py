@@ -823,7 +823,9 @@ def test_keye_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
         "kernel fwd bthd 128 norm=head": 2, "kernel bwd bthd 128 norm=head": 2,
         "xla fwd bthd 64": 2, "xla bwd bthd 64": 2}
     assert row["kernel_ms"] == {}               # (a trace needs the chip)
-    assert row["selection_agrees"] > 0.9999
+    # two chunks a layer, dsa.topk.fwd's passes over each one's prefix
+    assert row["topk_columns"] == {"causal": 3072, "walked": 3072}
+    assert row["selection_agrees"] > 0.9999 and row["top_k_agrees"] == 1.0
     assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
 
 
@@ -842,6 +844,9 @@ def test_keye_phase_fails_on_a_selection_that_runs_dense(phase_row,
     fails("keye", "as kernel", **dict(row, dsa={
         k.replace("kernel select", "xla select"): v
         for k, v in row["dsa"].items()}))
+    # and every chunk walked at the row's whole width
+    fails("keye", "score columns", **dict(row, topk_columns={
+        "causal": 3072, "walked": 4096}))
 
 
 def test_xing4_phase_fails_on_a_mix_without_the_kernel(phase_row,

@@ -3,8 +3,9 @@ this CPU-only machine, in the way of
 tests/test_attention_compiles_for_v5e.py (one more file, so that one
 more worker loads libtpu): ``dsa.score.fwd`` (parallel/dsa_score.py) at
 keye-train-s16384's call (16 index heads of 64, a chunk of 512 queries
-against 16,384 keys in blocks of 512), alone and inside the op
-``dsa_select`` with its bisection; and ``attn.bhtd.fwd`` and the ONE
+against 16,384 keys in blocks of 512) and ``dsa.topk.fwd`` (a chunk's
+thresholds over its causal prefix, 32 MB of keys in VMEM), alone and
+inside the op ``dsa_select``; and ``attn.bhtd.fwd`` and the ONE
 ``attn.bhtd.bwd`` under a SELECTION with its live table, at the cell's
 32 / 4 heads of 128 over 16,384 positions. Nothing runs, so this says
 nothing about results or times: tests/test_dsa_ops.py holds the kernels
@@ -47,10 +48,25 @@ def test_the_score_kernel_compiles_at_the_cells_chunk(one_chip, on_a_tpu):
     assert "dsa.score.fwd" in text
 
 
+def test_the_threshold_kernel_compiles_at_the_cells_chunk(one_chip,
+                                                          on_a_tpu):
+    assert dsa_score.topk_tile(512, 512, 16384, on_mesh=False)
+    assert not dsa_score.topk_tile(512, 512, 16384, on_mesh=True)
+    assert not dsa_score.topk_tile(512, 512, 32768, on_mesh=False)
+    text = jax.jit(lambda c, scores: dsa_score.threshold_rows(
+        c, scores, 2048, 512)).lower(
+            arg((), jnp.int32, one_chip),
+            arg((512, 16384), jnp.float32, one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "dsa.topk.fwd" in text
+
+
 def test_the_select_op_compiles_around_the_kernel(one_chip, on_a_tpu):
-    """``dsa_select`` at the cell's row: the kernel a chunk and the two
-    bisections under ``lax.map``; its largest temporaries are a chunk's
-    [512, 16384] rows, never [16384, 16384] float32."""
+    """``dsa_select`` at the cell's row: the four chunks that need no
+    top-k under one ``lax.map`` (their scores), the others under a
+    second (scores, thresholds, the position bisection behind a
+    ``lax.cond``); its largest temporaries are a chunk's [512, 16384]
+    rows, never [16384, 16384] float32."""
     attrs = {"scale": dsa_ops.index_scale(16, 64), "topk": 2048,
              "q_chunk": 512, "kv_chunk": 512}
     compiled = jax.jit(lambda qi, ki, w: dsa_ops._dsa_select(
@@ -58,7 +74,9 @@ def test_the_select_op_compiles_around_the_kernel(one_chip, on_a_tpu):
             arg((1, 16, 16384, 64), jnp.bfloat16, one_chip),
             arg((1, 1, 16384, 64), jnp.bfloat16, one_chip),
             arg((1, 16384, 16), jnp.bfloat16, one_chip)).compile()
-    assert "dsa.score.fwd" in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count("dsa.score.fwd") >= 2 and "dsa.topk.fwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2**20
 
 

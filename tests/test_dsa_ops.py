@@ -32,30 +32,69 @@ def plain_scores(qi, ki, w):
 
 
 def plain_choice(scores, k):
-    """[t, t] bool by ``lax.top_k`` a row over the valid entries."""
+    """[t, t] bool by ``lax.top_k`` a row over the valid entries (the
+    others -inf, under every valid one, and dropped again)."""
     t = scores.shape[0]
+    valid = np.tril(np.ones((t, t), bool))
+    _, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), min(k, t))
     out = np.zeros((t, t), bool)
-    for p in range(t):
-        _, idx = jax.lax.top_k(scores[p, :p + 1], min(p + 1, k))
-        out[p, np.asarray(idx)] = True
-    return out
+    np.put_along_axis(out, np.asarray(idx), True, axis=1)
+    return out & valid
 
 
-def indexer(seed, b=2, hi=3, t=64, di=8, ties=False):
+def indexer(seed, b=2, hi=3, t=64, di=8, ties=False, flat=(),
+            positive=False):
+    """``flat``: the (row, chunk, chunk size) whose queries weigh every
+    head 0, so that each of them scores all its keys the same;
+    ``positive``: no product is under 0, so no score is the relu's exact
+    0 (an eighth of them are, at three heads: ties of their own)."""
     r = np.random.RandomState(seed)
     qi, ki = r.randn(b, hi, t, di), r.randn(b, 1, t, di)
-    if ties:    # values on a coarse grid: many exact ties a row
+    if positive:
+        qi, ki = np.abs(qi), np.abs(ki)
+    if ties is True:    # values on a coarse grid: many exact ties a row
         qi, ki = np.round(qi), np.round(ki)
-    w = np.round(r.rand(b, t, hi) * 4) / 4 if ties else r.rand(b, t, hi)
+    w = np.round(r.rand(b, t, hi) * 4) / 4 if ties is True else r.rand(
+        b, t, hi)
+    for row, c, cq in flat:
+        w[row, c * cq:(c + 1) * cq] = 0.0
     return (jnp.asarray(x, jnp.float32) for x in (qi, ki, w))
 
 
-@pytest.mark.parametrize("ties", [False, True])
-@pytest.mark.parametrize("topk,cq,ck", [(8, 16, 16), (16, 32, 16),
-                                        (0, 16, 64), (100, 64, 8)])
-def test_select_is_top_k_a_row_with_its_tie_rule(topk, cq, ck, ties):
-    qi, ki, w = indexer(3, ties=ties)
-    t = qi.shape[2]
+def surplus_ties(scores, want, cq):
+    """[t / cq] bool: has a chunk a row with more keys equal to its
+    threshold than the selection ``want`` [t, t] took of them? (The
+    top-k's second bisection runs for such a chunk alone.)"""
+    t = scores.shape[0]
+    s = np.where(np.tril(np.ones((t, t), bool)), np.asarray(scores), np.nan)
+    thr = np.where(want, s, np.inf).min(1, keepdims=True)
+    return ((s == thr) & ~want).any(1).reshape(t // cq, cq).any(1)
+
+
+# topk, cq, ck, ties [, t]; ties: True a coarse grid, "some" the
+# queries of two chunks flat (a surplus tie there and in no other
+# chunk), "all" every query flat (every valid key equal). The rows of 128
+# and 192 span 8 and 12 chunks, topk under, at and over a chunk's prefix
+# (chunks with and without a top-k), and there no score is the relu's 0,
+# so the only ties are the case's.
+_SELECT_CASES = [
+    (topk, cq, ck, ties) for ties in (False, True)
+    for topk, cq, ck in ((8, 16, 16), (16, 32, 16), (0, 16, 64),
+                         (100, 64, 8))] + [
+    (8, 16, 16, False, 128), (16, 16, 32, "some", 128),
+    (40, 16, 16, "some", 128), (48, 16, 16, "all", 128),
+    (40, 16, 8, True, 128), (24, 16, 32, "some", 192),
+    (64, 16, 16, False, 192), (100, 16, 64, "all", 192),
+    (0, 16, 16, "some", 192)]
+
+
+@pytest.mark.parametrize(
+    "case", _SELECT_CASES, ids=lambda c: "-".join(str(x) for x in c))
+def test_select_is_top_k_a_row_with_its_tie_rule(case):
+    topk, cq, ck, ties, t = (case + (64,))[:5]
+    flat = {"some": [(0, 2, cq), (1, 2, cq), (0, t // cq - 3, cq)],
+            "all": [(row, 0, t) for row in (0, 1)]}.get(ties, ())
+    qi, ki, w = indexer(3, t=t, ties=ties, flat=flat, positive=t > 64)
     out = dsa_ops._dsa_select(
         {"QI": [qi], "KI": [ki], "W": [w]},
         {"scale": dsa_ops.index_scale(3, 8), "topk": topk, "q_chunk": cq,
@@ -68,10 +107,17 @@ def test_select_is_top_k_a_row_with_its_tie_rule(topk, cq, ck, ties):
     k = topk or t
     for row in range(2):
         want = plain_choice(scores[row], k)
-        if ties:    # the case holds ties AT the threshold
+        if ties is True:    # the case holds ties AT the threshold
             assert any((np.asarray(scores[row, p, :p + 1])
                         == np.asarray(scores[row, p])[want[p]].min()).sum()
                        > 1 for p in range(k, t)) or k >= t
+        surplus = surplus_ties(scores[row], want, cq)
+        if ties == "some" and k < t:   # both branches of the top-k ran
+            assert surplus.any() and not surplus[-1] and not surplus[0]
+        elif ties == "all":
+            assert surplus[-(-k // cq):].all()
+        elif not ties and t > 64:
+            assert not surplus.any()
         np.testing.assert_array_equal(selected[row], want)
         masked = np.where(want, np.asarray(scores[row]), -np.inf)
         np.testing.assert_allclose(
@@ -86,6 +132,107 @@ def test_select_is_top_k_a_row_with_its_tie_rule(topk, cq, ck, ties):
         {"Selected": out["Selected"], "Live": out["Live"]}, {"last": 8})
     np.testing.assert_array_equal(rows["Out"][0], selected[:, -8:])
     assert rows["Out"][0].dtype == jnp.int8
+
+
+@pytest.mark.parametrize("t,topk,cq,ck,kernel,want", [
+    # the cell's row: chunks 4 .. 31 make a top-k; dsa.topk.fwd's passes
+    # read their live key blocks, XLA's ops the row's 16,384 each
+    (16384, 2048, 512, 512, True, (265216, 265216)),
+    (16384, 2048, 512, 512, False, (28 * 16384, 265216)),
+    # the dense stage: no chunk makes a pass
+    (16384, 16384, 512, 512, True, (0, 0)),
+    # key blocks that cut a chunk: the prefix in whole blocks
+    (192, 24, 16, 64, True, (64 * 3 + 128 * 4 + 192 * 4, 16 * 77)),
+    # a row shorter than a chunk, under and over topk
+    (48, 8, 48, 16, False, (48, 48)), (64, 100, 64, 8, True, (0, 0))])
+def test_the_columns_a_top_k_walks(t, topk, cq, ck, kernel, want):
+    assert dsa_ops.columns(t, topk, cq, ck, kernel) == dict(
+        zip(("walked", "causal"), want))
+
+
+def test_the_columns_counter_holds_a_lowered_call():
+    from paddle_tpu import flags, monitor
+
+    t, cq = 192, 16
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        qi, ki, w = (layers.data(n, shape=list(shape), dtype="float32",
+                                 append_batch_size=False)
+                     for n, shape in (("qi", (2, 3, t, 8)),
+                                      ("ki", (2, 1, t, 8)),
+                                      ("w", (2, t, 3))))
+        outs = layers.dsa_select(qi, ki, w, 24, q_chunk=cq, kv_chunk=32)
+    flags.set_flags({"telemetry": True})
+    try:
+        monitor.reset()
+        assert dsa_ops.topk_columns() == {}     # (building lowers nothing)
+        a, b, c = indexer(0, t=t)
+        fluid.Executor().run(main, feed={"qi": a, "ki": b, "w": c},
+                             scope=fluid.Scope(), fetch_list=list(outs))
+        got = dsa_ops.topk_columns()
+    finally:
+        flags.set_flags({"telemetry": False})
+        monitor.reset()
+    # two rows; chunks 1 .. 11 make a top-k (the first has 16 keys for a
+    # topk of 24), here as XLA's ops: 192 columns a chunk for prefixes of
+    # 32 .. 192 keys
+    assert got == {"walked": 2 * 11 * 192, "causal": 2 * 16 * 77}
+
+
+@pytest.mark.parametrize("ties", [True, "all"])
+def test_the_selects_kernels_are_xlas_ops(monkeypatch, ties):
+    """``dsa.score.fwd`` and ``dsa.topk.fwd`` through the interpreter (8
+    chunks of 128, the first without a top-k): the op's three outputs
+    are those of XLA's ops, to the bit (the inputs lie on a grid on which
+    every sum is exact, ties included; "all": every valid key equal, so
+    every chunk takes the position passes behind the kernel's
+    thresholds)."""
+    qi, ki, w = indexer(11, b=1, t=1024, ties=True, flat=[
+        (0, 0, 1024)] if ties == "all" else ())
+    ins = {"QI": [qi], "KI": [ki], "W": [w]}
+    attrs = {"scale": dsa_ops.index_scale(3, 8), "topk": 200,
+             "q_chunk": 128, "kv_chunk": 128}
+    assert not dsa_score.score_tile(128, 128, 3, 8)
+    assert not dsa_score.topk_tile(128, 128, 1024)
+    want = dsa_ops._dsa_select(ins, attrs)
+    monkeypatch.setattr(dsa_score, "_INTERPRET", True)
+    assert dsa_score.score_tile(128, 128, 3, 8)
+    assert dsa_score.topk_tile(128, 128, 1024)
+    assert not dsa_score.topk_tile(96, 128, 1024)       # (row blocks of 64)
+    assert not dsa_score.topk_tile(512, 512, 32768)     # (32 MB of keys)
+    got = dsa_ops._dsa_select(ins, attrs)
+    for slot in ("Selected", "Live", "IndexLse"):
+        np.testing.assert_array_equal(got[slot][0], want[slot][0])
+    assert np.asarray(dsa_ops.unpack(got["Selected"][0], 128)).sum(-1)[
+        0].tolist() == np.minimum(np.arange(1024) + 1, 200).tolist()
+
+
+@pytest.mark.parametrize("c", [3, 7])     # (a row's every key; 2048 of 4096)
+def test_the_threshold_kernel_at_the_cells_chunk(monkeypatch, c):
+    """``dsa.topk.fwd`` through the interpreter at the cell's chunk of
+    512 queries and key blocks of 512, a row of 4096: chunk ``c``'s
+    thresholds and the keys that reach them are those ``choose`` counts
+    with XLA's ops over the whole row (scores with ties, a negative
+    zero and both infinities' neighbours among them)."""
+    monkeypatch.setattr(dsa_score, "_INTERPRET", True)
+    assert dsa_score.topk_tile(512, 512, 4096)
+    r = np.random.RandomState(c)
+    scores = np.round(r.randn(512, 4096) * 8) / 8
+    scores[:, 5], scores[:, 9], scores[3] = -0.0, 3e38, 0.125
+    scores[:, 11] = -3e38
+    scores = jnp.asarray(scores, jnp.float32)
+    valid = jnp.arange(4096)[None, :] <= c * 512 + jnp.arange(512)[:, None]
+    thr, reach = dsa_score.threshold_rows(c, scores, 2048, 512)
+    assert thr.dtype == jnp.uint32 and reach.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        dsa_ops.choose(scores, valid, 2048, (thr, reach)),
+        dsa_ops.choose(scores, valid, 2048))
+    keys = np.where(valid, np.asarray(dsa_ops._sortable(scores)), 0)
+    want = np.minimum(c * 512 + np.arange(512) + 1, 2048)
+    kth = -np.sort(-keys.astype(np.int64), axis=1)[np.arange(512), want - 1]
+    np.testing.assert_array_equal(np.asarray(thr).astype(np.int64), kth)
+    np.testing.assert_array_equal(
+        reach, (keys.astype(np.int64) >= kth[:, None]).sum(1))
 
 
 def test_choose_counts_exact_ties_by_position():
