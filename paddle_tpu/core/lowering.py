@@ -534,7 +534,6 @@ def build_compile_report(
     compile_ms: Optional[float] = None,
     strategy: Optional[str] = None,
     cache_key=None,
-    window_steps: Optional[int] = None,
 ) -> Dict[str, Any]:
     """Cost/memory report for a freshly compiled executor entry
     (schema: monitor.COMPILE_REPORT_FIELDS).
@@ -580,11 +579,6 @@ def build_compile_report(
         "op_histogram": hist,
         "strategy": strategy,
     }
-    if window_steps is not None:
-        # a window report's flops/bytes cover the WHOLE compiled window;
-        # recording its length lets the roofline plane recover per-step
-        # costs (optional field — compile-report schema stays v1)
-        report["window_steps"] = int(window_steps)
     try:
         t0 = _time.perf_counter()
         compiled = jitfn.lower(*args).compile()

@@ -71,45 +71,6 @@ def _time_attribution_lines() -> list:
     return lines
 
 
-def _roofline_lines(program: Program):
-    """(header lines, {op type -> per-op device ms}) from the roofline
-    plane's latest device profile for this program: measured MFU +
-    verdict + top device ops, and — on an xplane-sourced profile — a
-    per-op-type device-time estimate for the listing's annotation
-    column (an HLO op's seconds are attributed to every candidate
-    framework op type of its group, spread across that type's op
-    count, so the column is a shortlist-grade estimate, not a proof)."""
-    from paddle_tpu import roofline
-
-    prof = roofline.latest(program)
-    if prof is None:
-        return [], {}
-    mfu = prof.get("measured_mfu")
-    dev = prof.get("device_seconds")
-    lines = [
-        f"device profile (v{prof.get('v')}, source={prof.get('source')}, "
-        f"steps={prof.get('steps')}): verdict={prof.get('verdict')} "
-        f"measured_mfu={'null' if mfu is None else f'{mfu:.3f}'} "
-        f"device_s={'null' if dev is None else f'{dev:.4f}'}"
-    ]
-    timed = [o for o in prof.get("top_ops", ()) if o.get("seconds")]
-    if timed:
-        lines.append("  top device ops: " + " ".join(
-            f"{o['name']}={o['seconds'] * 1e3:.2f}ms"
-            f"({o['share']:.0%})" for o in timed[:5]))
-    # per-op-type device time for the annotation column
-    type_seconds: dict = {}
-    type_counts: dict = {}
-    for op in program.blocks[0].ops:
-        type_counts[op.type] = type_counts.get(op.type, 0) + 1
-    for o in timed:
-        for fw in o.get("framework_ops", ()):
-            type_seconds[fw] = type_seconds.get(fw, 0.0) + o["seconds"]
-    per_op_ms = {t: s * 1e3 / type_counts.get(t, 1)
-                 for t, s in type_seconds.items() if t in type_counts}
-    return lines, per_op_ms
-
-
 def _numerics_lines(program: Program):
     """(header lines, {op idx -> marker}) from the numerics plane's
     latest NaN/Inf provenance record for this program (if any)."""
@@ -165,8 +126,7 @@ def pprint_program(program: Program, with_shapes: bool = True,
                    with_compile_report: bool = True,
                    with_numerics: bool = True,
                    with_timeline: bool = True,
-                   with_lint: bool = True,
-                   with_roofline: bool = True) -> str:
+                   with_lint: bool = True) -> str:
     """Readable multi-block listing of a Program's vars and ops,
     prefixed with the latest compile-report annotation when telemetry
     recorded one (``with_compile_report=False`` opts out), the latest
@@ -174,21 +134,13 @@ def pprint_program(program: Program, with_shapes: bool = True,
     offending op line is marked inline (``with_numerics=False`` opts
     out) — the latest step's phase breakdown + boundedness verdict
     from the time-attribution plane (``with_timeline=False`` opts
-    out), the static verifier's latest findings for the program
-    with error sites marked inline (``with_lint=False`` opts out),
-    and the roofline plane's latest device profile — measured MFU +
-    verdict + top device ops in the header, and a per-op device-time
-    column on the op listing when an xplane-sourced profile attributes
-    HLO seconds to the op's type (``with_roofline=False`` opts out)."""
+    out), and the static verifier's latest findings for the program
+    with error sites marked inline (``with_lint=False`` opts out)."""
     lines = []
     if with_compile_report:
         lines.extend(_compile_report_lines(program))
     if with_timeline:
         lines.extend(_time_attribution_lines())
-    per_op_ms = {}
-    if with_roofline:
-        header, per_op_ms = _roofline_lines(program)
-        lines.extend(header)
     marks = {}
     if with_lint:
         header, marks = _lint_lines(program)
@@ -215,10 +167,7 @@ def pprint_program(program: Program, with_shapes: bool = True,
             outs = ", ".join(
                 f"{k}={v}" for k, v in op.outputs.items() if v)
             mark = marks.get(i, "") if block.idx == 0 else ""
-            dev = ""
-            if block.idx == 0 and op.type in per_op_ms:
-                dev = f"  [dev ~{per_op_ms[op.type]:.3f}ms]"
-            lines.append(f"  [{i}] {op.type}({ins}) -> {outs}{dev}{mark}")
+            lines.append(f"  [{i}] {op.type}({ins}) -> {outs}{mark}")
     return "\n".join(lines)
 
 

@@ -26,7 +26,6 @@ from paddle_tpu import analysis as _analysis
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor as _monitor
 from paddle_tpu import numerics as _numerics
-from paddle_tpu import roofline as _roofline
 from paddle_tpu.core import fingerprint as _fingerprint
 from paddle_tpu.core import lowering
 from paddle_tpu.framework import (
@@ -770,8 +769,7 @@ class Executor:
                             fn, lowered, (state, feeds, *tail),
                             program=program, kind=call.kind,
                             compile_ms=call.compile_ms,
-                            strategy=strat_label, cache_key=call.fp,
-                            window_steps=steps))
+                            strategy=strat_label, cache_key=call.fp))
             if _monitor.step_records_active():
                 rec = {"kind": call.kind, "step": start}
                 if steps is not None:
@@ -785,14 +783,6 @@ class Executor:
                     # honest sync (sampled=False walls are host-only —
                     # /trace and the fleet digest medians filter on it)
                     rec["sampled"] = sampled
-        # Roofline plane (roofline.py): profiles ride phase-SAMPLED
-        # calls — the honest device phase below supplies device time;
-        # take_sample counts them PER PROGRAM (a window is one sample and
-        # its profile covers all its steps) so the cadence is every
-        # Nth one, whatever else interleaves. Off (the default) this is
-        # the short-circuited `sampled` check.
-        roof = sampled and _roofline.take_sample(program)
-        cap = _roofline.begin_capture() if roof else None
         try:
             with _interp.spmd_ctx_scope(strategy), \
                     _monitor.span("executor.run_step"), call.first:
@@ -871,16 +861,6 @@ class Executor:
             # logged even when the call raises (NaN scan, device/runtime
             # error): the crashed call's record is the one an operator
             # needs for postmortem, and must be the last line of the log
-            if roof:
-                if t_b1 > 0.0:  # device drain completed: honest timing
-                    _roofline.note_step(
-                        program, lowered, steps=n,
-                        device_s=t_b1 - t_c1,
-                        wall_s=time.perf_counter() - t_run0,
-                        capture=cap)
-                elif cap is not None:  # failed call: abandon the capture
-                    cap.stop()
-                    cap.cleanup()
             if tele:
                 # watermarks read AFTER the call (success or failure):
                 # the post-step high-water is the number an OOM

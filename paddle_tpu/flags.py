@@ -136,33 +136,6 @@ _DEFS: Dict[str, tuple] = {
     "device_memory_every_n_steps": (int, 16,
                                     "device-memory watermark sampling "
                                     "period"),
-    # device-time roofline attribution (roofline.py): build a per-program
-    # device profile (top-K ops by device seconds, roofline verdict,
-    # measured MFU) every N phase-sampled executor steps; 0 = off (the
-    # executor hot path is one boolean check). Needs `telemetry` and
-    # `step_phases` (the device phase supplies the honest device time).
-    "device_profile_every_n_steps": (int, 0,
-                                     "device-profile sampling period"),
-    # how many ops the profile's top-ops list (and the
-    # pt_device_op_seconds{op=} gauge) keeps, by device seconds
-    "device_profile_top_k": (int, 10, "device-profile top-ops list size"),
-    # capture a jax.profiler xplane trace around each sampled step and
-    # parse per-op device timings from it (source: "xplane"); off = the
-    # profile is compile-report-derived (source: "estimate"). Parse
-    # failures / backends without a device plane (e.g. CPU) degrade to
-    # the estimate path with one warning.
-    "device_profile_xplane": (bool, False,
-                              "capture + parse xplane around sampled "
-                              "steps"),
-    # roofline peaks: override the device table (roofline.DEVICE_PEAKS);
-    # 0 = the table's row for the attached device_kind
-    "device_peak_flops": (float, 0.0,
-                          "peak device FLOP/s for roofline verdicts "
-                          "(0 = backend default)"),
-    "device_peak_bytes_per_sec": (float, 0.0,
-                                  "peak device memory bandwidth for "
-                                  "roofline verdicts (0 = backend "
-                                  "default)"),
     # pre-compile static program verifier (analysis.py): 'warn' lints
     # every program before its first compile and logs warning/error
     # findings; 'error' additionally raises LintError on error-severity

@@ -227,18 +227,12 @@ def registry_digest(rank: int = 0, world: int = 1,
     with _LOCK:
         seq = _pub_seq
         _pub_seq += 1
-    # roofline rollup (optional field, schema stays v1): per-program
-    # measured MFU + verdict, so /fleet names each rank's MFU without
-    # shipping whole profiles through KV. Lazy via sys.modules — a
-    # worker that never loaded the plane publishes no section.
+    # serving rollup (optional field, schema stays v1): per-replica
+    # engine rows + TTFT/token quantiles + SLO counts — the /fleet row a
+    # multi-replica router selects replicas on. Lazy via sys.modules — a
+    # rank that never served publishes no section.
     import sys as _sys
 
-    rl = _sys.modules.get("paddle_tpu.roofline")
-    roofline = rl.digest_section() if rl is not None else None
-    # serving rollup (same optional-field pattern): per-replica engine
-    # rows + TTFT/token quantiles + SLO counts — the /fleet row a
-    # multi-replica router selects replicas on. Absent on ranks that
-    # never served.
     st = _sys.modules.get("paddle_tpu.serving_trace")
     serving_sec = st.digest_section() if st is not None else None
     digest = {
@@ -260,8 +254,6 @@ def registry_digest(rank: int = 0, world: int = 1,
         "steps": int(_monitor.counter(
             "pt_executor_steps_total").value()),
     }
-    if roofline is not None:
-        digest["roofline"] = roofline
     if serving_sec is not None:
         digest["serving"] = serving_sec
     return digest

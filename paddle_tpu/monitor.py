@@ -316,8 +316,8 @@ class Gauge:
                                              float]]):
         """Atomically swap EVERY cell for ``values`` ([(labels, value),
         ...]) — for gauges that mirror one bounded snapshot at a time
-        (e.g. the roofline plane's top-K op seconds, whose per-compile
-        HLO label values would otherwise accrete stale cells forever).
+        (e.g. serving.py's per-engine states, whose engine-id label
+        values would otherwise accrete stale cells forever).
         A concurrent scrape sees either the old set or the new one,
         never a partial mix. The MAX_LABEL_SETS cap applies here too:
         values past it are dropped (first-listed win — callers pass
@@ -525,9 +525,6 @@ def reset():
     fm = sys.modules.get("paddle_tpu.fleet_monitor")
     if fm is not None:
         fm.reset()
-    rl = sys.modules.get("paddle_tpu.roofline")
-    if rl is not None:
-        rl.reset()
     st = sys.modules.get("paddle_tpu.serving_trace")
     if st is not None:
         st.reset()
@@ -1000,11 +997,6 @@ COMPILE_REPORT_FIELDS: Dict[str, tuple] = {
                      "op-lowering histogram)"),
     "strategy": ((str, type(None)), True,
                  "SPMD strategy id (mesh axes) or null"),
-    "window_steps": ((int, type(None)), False,
-                     "steps compiled into a 'window' report's program "
-                     "(its flops/bytes cover the WHOLE window; the "
-                     "roofline plane divides by this); absent on "
-                     "'step' reports"),
 }
 
 
@@ -1498,8 +1490,6 @@ ROUTES: Dict[str, str] = {
               "stragglers, OOM reports + the serving-fleet router "
               "section (per-replica state, queue depth, generation "
               "tag, last-heartbeat age) when a ServingFleet is live",
-    "/profile": "JSON roofline plane: latest device profile per "
-                "program (top ops, verdict, measured MFU)",
     "/serve": "JSON serving plane: per-engine slot/queue stats, token "
               "throughput, TTFT + per-token latency quantiles",
     "/requests": "JSON request plane: in-flight serving requests + the "
@@ -1529,9 +1519,6 @@ def serve(port: Optional[int] = None, host: str = "127.0.0.1") -> int:
     - ``/fleet``    JSON cluster view: one row per rank (digest + phase
       breakdown + heartbeat age + dead flag) plus straggler records and
       OOM reports (fleet_monitor.py)
-    - ``/profile``  JSON roofline plane: latest device profile per
-      program — top ops by device seconds, roofline verdict, measured
-      MFU (roofline.py)
 
     Binds localhost by default: metrics can carry program names — scrape
     through a sidecar or port-forward, don't expose it."""
@@ -1625,14 +1612,6 @@ def serve(port: Optional[int] = None, host: str = "127.0.0.1") -> int:
                             view = dict(view)
                             view["serving_fleet"] = sfleet
                     body = json.dumps(view, sort_keys=True,
-                                      default=str).encode()
-                    ctype = "application/json"
-                elif path == "/profile":
-                    # lazy import: roofline.py imports monitor.py
-                    from paddle_tpu import roofline as _roofline
-
-                    body = json.dumps(_roofline.summary(),
-                                      sort_keys=True,
                                       default=str).encode()
                     ctype = "application/json"
                 elif path == "/serve":
@@ -1897,11 +1876,6 @@ FLEET_DIGEST_FIELDS: Dict[str, tuple] = {
     "steps": ((int,), True,
               "pt_executor_steps_total at publish time (bounds straggler "
               "detection latency in steps)"),
-    "roofline": ((dict, type(None)), False,
-                 "per-program roofline rollup from the device-profile "
-                 "plane: program -> {measured_mfu, verdict, source} "
-                 "(roofline.digest_section); absent before the first "
-                 "profile — optional, schema stays v1"),
     "serving": ((dict, type(None)), False,
                 "per-replica serving rollup from the request plane: "
                 "engine rows (state, queue depth, active slots, token "

@@ -24,21 +24,6 @@ _host_enabled = False
 # and the new timeline share ONE clock (perf_counter) and one stream.
 _trace_hook = None
 
-# Directory of the most recent xplane capture this module started (the
-# seam the roofline plane parses: roofline.profile_from_xplane /
-# parse_xplane). Set whether or not the capture SUCCEEDED — a failed
-# start leaves the dir empty/absent, which the parser reports as one
-# degrade warning, not a crash.
-_last_xplane_dir: Optional[str] = None
-
-
-def last_xplane_dir() -> Optional[str]:
-    """Trace dir of the most recent ``profiler(with_xplane=True)``
-    capture (None before the first): pass it to
-    ``roofline.profile_from_xplane`` for per-op device attribution."""
-    return _last_xplane_dir
-
-
 def _trace_mark(name: str):
     """Instant event on the timeline (no-op unless monitor's trace
     collection is active) marking a legacy profiler lifecycle call."""
@@ -59,7 +44,7 @@ def profiler(state: str = "All", sorted_key: Optional[str] = None,
     <profile_path>_xplane/ via jax.profiler (opt-in: traces are large
     and tracing slows the host).
     """
-    global _host_enabled, _last_xplane_dir
+    global _host_enabled
     from paddle_tpu import native
 
     use_native = native.available()
@@ -70,7 +55,6 @@ def profiler(state: str = "All", sorted_key: Optional[str] = None,
     jax_trace_dir = profile_path + "_xplane"
     jax_started = False
     if with_xplane:
-        _last_xplane_dir = jax_trace_dir
         try:
             import jax
 

@@ -413,10 +413,10 @@ def requests_view() -> Dict[str, Any]:
 
 
 def digest_section() -> Optional[Dict[str, Any]]:
-    """Compact per-replica serving rollup for the fleet digest (the
-    roofline-section pattern: optional, absent on ranks that never
-    served, fleet-digest schema stays v1). ``/fleet`` renders this as
-    the per-replica SLO/latency row a multi-replica router selects on."""
+    """Compact per-replica serving rollup for the fleet digest
+    (optional: absent on ranks that never served, fleet-digest schema
+    stays v1). ``/fleet`` renders this as the per-replica SLO/latency
+    row a multi-replica router selects on."""
     engines: Dict[str, Any] = {}
     srv = sys.modules.get("paddle_tpu.serving")
     if srv is not None:

@@ -31,7 +31,7 @@ if os.environ.get("PT_TEST_TPU") != "1":
         jax.config.update("jax_disable_most_optimizations", True)
 
 # Persistent compile cache: repeat suite runs skip the slow XLA compiles.
-from paddle_tpu import jax_cache, monitor  # noqa: E402
+from paddle_tpu import flags, jax_cache, monitor  # noqa: E402
 
 jax_cache.configure()
 
@@ -102,8 +102,14 @@ def _zeroed_metrics():
     dispatch counters among them) are process-wide, and a file's traced
     run used to leave its rows to whatever its xdist worker ran next:
     tests/test_grouped_matmul_adam.py's ``..._where_the_call_has_a_tile
-    [adam]`` read tests/test_checkpoint.py's grouped matmuls (PR 68)."""
+    [adam]`` read tests/test_checkpoint.py's grouped matmuls (PR 68).
+    And it leaves ``telemetry`` as it found it: while the flag is on the
+    monitor holds a process-wide hook in ``gc.callbacks``, which a test
+    that left the flag on used to leave to every test after it."""
     monitor.reset()
+    telemetry = flags.get_flag("telemetry")
+    yield
+    flags.set_flags({"telemetry": telemetry})
 
 
 # --- suite tiering (VERDICT r4 item 3) ---

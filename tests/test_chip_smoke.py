@@ -1,7 +1,7 @@
 """chip_smoke.py off the chip: its phases driven at a tiny config on the
 CPU (so the script cannot rot between chip runs), its refusal to pass
 without a TPU, and the fallbacks this bring-up removed — decorative
-places, a cache dir forced in code, a default peaks row."""
+places, a cache dir forced in code."""
 
 import importlib
 import os
@@ -14,7 +14,7 @@ import pytest
 
 import chip_smoke
 import paddle_tpu as fluid
-from paddle_tpu import flags, jax_cache, monitor, roofline
+from paddle_tpu import flags, jax_cache, monitor
 from paddle_tpu.parallel import flash_attention as fa
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -536,13 +536,6 @@ def test_rope_rows_that_differ_in_scaling_alone_add_up(telemetry):
     attention_ops._M_ROPE.inc(5, labels=dict(row, scaling="yarn"))
     attention_ops._M_ROPE.inc(labels=dict(row, scaling="none"))
     assert chip_smoke.rope_dispatch() == {"xla fwd bthd 64": 6}
-
-
-def test_backend_peaks_raises_for_an_unknown_device():
-    assert roofline.backend_peaks("cpu") == roofline.DEVICE_PEAKS["cpu"]
-    assert roofline.backend_peaks("TPU v5 lite")[0] == roofline.V5E_PEAK_BF16
-    with pytest.raises(KeyError, match="no roofline peaks"):
-        roofline.backend_peaks("tpu")  # a platform is not a device kind
 
 
 @pytest.mark.parametrize("case", ["env", "default"])

@@ -99,16 +99,11 @@ def test_ruff_config_present():
     assert '"F"' in cfg and '"B"' in cfg
 
 
-def test_a_test_that_hangs_fails_by_its_name_and_the_run_goes_on(tmp_path):
-    """tests/conftest.py's limit a test, with the constant patched to
-    1 s in a pytest of its own over two tests: the sleeper is ``F`` by
-    its name with the threads' stacks, the next test runs and passes."""
-    (tmp_path / "test_two.py").write_text(
-        "import time\n\n"
-        "def test_sleeps():\n    time.sleep(60)\n\n"
-        "def test_after():\n    pass\n")
-    run = ("import sys, pytest, conftest\n"
-           "conftest.TEST_LIMIT_S = 1.0\n"
+def _a_pytest_of_its_own(tmp_path, tests, patch=""):
+    """``tests`` as a file run by a pytest of its own under
+    tests/conftest.py (``patch``: statements on ``conftest`` first)."""
+    (tmp_path / "test_two.py").write_text(tests)
+    run = (f"import sys, pytest, conftest\n{patch}"
            "sys.exit(pytest.main(['-q', '-p', 'conftest', '-p', "
            f"'no:cacheprovider', '--rootdir', {str(tmp_path)!r}, "
            f"{str(tmp_path / 'test_two.py')!r}]))\n")
@@ -118,9 +113,36 @@ def test_a_test_that_hangs_fails_by_its_name_and_the_run_goes_on(tmp_path):
             **os.environ, "JAX_PLATFORMS": "cpu",
             "PYTHONPATH": os.pathsep.join(
                 [os.path.join(ROOT, "tests"), ROOT])})
-    said = out.stdout + out.stderr
-    assert out.returncode == 1, said[-2000:]
+    return out.returncode, out.stdout + out.stderr
+
+
+def test_a_test_that_hangs_fails_by_its_name_and_the_run_goes_on(tmp_path):
+    """tests/conftest.py's limit a test, with the constant patched to
+    1 s in a pytest of its own over two tests: the sleeper is ``F`` by
+    its name with the threads' stacks, the next test runs and passes."""
+    rc, said = _a_pytest_of_its_own(
+        tmp_path,
+        "import time\n\n"
+        "def test_sleeps():\n    time.sleep(60)\n\n"
+        "def test_after():\n    pass\n",
+        patch="conftest.TEST_LIMIT_S = 1.0\n")
+    assert rc == 1, said[-2000:]
     assert "1 failed, 1 passed" in said, said[-2000:]
     assert "test_two.py::test_sleeps took more than the 1 s" in said
     # the stack of the thread that slept, down to the test's own line
     assert "in test_sleeps" in said and "Current thread" in said
+
+
+def test_a_test_that_leaves_telemetry_on_leaves_no_hook_behind(tmp_path):
+    """tests/conftest.py's ``_zeroed_metrics`` puts the flag back, in a
+    pytest of its own over two tests: the first turns telemetry on and
+    ends, the second finds it off and nothing of the monitor's in
+    ``gc.callbacks``."""
+    rc, said = _a_pytest_of_its_own(
+        tmp_path,
+        "import gc\n\nfrom paddle_tpu import monitor\n\n"
+        "def test_leaves_it_on():\n    monitor.enable()\n"
+        "    assert monitor._on_gc in gc.callbacks\n\n"
+        "def test_after():\n    assert not monitor.enabled()\n"
+        "    assert monitor._on_gc not in gc.callbacks\n")
+    assert rc == 0 and "2 passed" in said, said[-2000:]

@@ -49,6 +49,40 @@ def _feed(batch=4):
     return {"x": np.ones((batch, 8), np.float32)}
 
 
+def test_only_a_first_call_goes_through_the_frame_of_its_own(monkeypatch):
+    """A call that traces and lowers reaches its jitted function through
+    ``executor._first_call_fn``, whose 8,000 declared slots put it on a
+    data-stack chunk of its own (PERF.md section 6, PR 43: 40 slots of
+    executor frame had cost ``tbase-train-dp4``'s lowering 22 s); a
+    cache hit calls the function as it is. The frame looks like a
+    function that does nothing: inlined, this fails."""
+    from paddle_tpu import executor
+
+    frame = executor._first_call_fn
+    assert frame.__code__.co_stacksize == 8000
+    through = []
+
+    def counted(fn, *args):
+        through.append(fn)
+        return frame(fn, *args)
+
+    monkeypatch.setattr(executor, "_first_call_fn", counted)
+    main, startup, loss = _build()
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(scope):
+        exe.run(startup)                                # miss
+        exe.run(main, feed=_feed(), fetch_list=[loss])  # miss
+        assert len(through) == 2 == _counts()[1]
+        exe.run(main, feed=_feed(), fetch_list=[loss])  # hit
+        exe.run_steps(main, feed_list=[_feed()] * 2, fetch_list=[loss],
+                      steps=2)                          # a window: miss
+        exe.run_steps(main, feed_list=[_feed()] * 2, fetch_list=[loss],
+                      steps=2)                          # hit
+    assert len(through) == 3 and _counts() == (2, 3, 0)
+    assert len(set(map(id, through))) == 3      # each entry's own fn
+
+
 def test_hit_miss_counts_exact_across_repeated_runs():
     main, startup, loss = _build()
     scope = fluid.Scope()

@@ -147,38 +147,6 @@ def test_registry_digest_schema_and_trailing_medians():
     assert d["steps"] == 4
 
 
-def test_registry_digest_roofline_section_optional_and_validated():
-    """The digest's `roofline` section (optional field — schema stays
-    v1): absent before the first device profile, a per-program
-    {measured_mfu, verdict, source} rollup after one, and digests
-    WITHOUT the field still validate (backward compatibility with
-    pre-roofline publishers)."""
-    from paddle_tpu import roofline
-
-    monitor.enable()
-    d = fleet_monitor.registry_digest(rank=0, world=2)
-    assert "roofline" not in d  # no profile recorded yet
-    monitor.validate_fleet_digest(d)  # pre-roofline shape still valid
-    prog = fluid.Program()
-    roofline.record_profile(roofline.build_device_profile(
-        prog, source="estimate", device_seconds=0.1, steps=1,
-        compile_report={"flops": 1e9, "bytes_accessed": 1e7,
-                        "op_histogram": {"mul": 1}},
-        backend="cpu"))
-    d = fleet_monitor.registry_digest(rank=1, world=2)
-    monitor.validate_fleet_digest(d)
-    cell = d["roofline"][f"program{prog._uid}"]
-    assert set(cell) == {"measured_mfu", "verdict", "source"}
-    assert cell["source"] == "estimate"
-    assert cell["measured_mfu"] > 0
-    # the rollup rides aggregation into the per-rank /fleet rows
-    store, lock = {}, threading.Lock()
-    store["fleet/metrics/g0/0"] = json.dumps(d).encode()
-    f = _stub_fleet(0, 1, store, lock)
-    view = fleet_monitor.aggregate(f)
-    assert view["ranks"]["0"]["roofline"] == d["roofline"]
-
-
 def test_registry_digest_serving_section_optional_and_validated():
     """The digest's `serving` section (optional field — schema stays
     v1): absent on ranks that never served, a per-replica request-plane

@@ -121,30 +121,6 @@ def test_lint_endpoint_serves_latest_findings():
                for f in rec["findings"])
 
 
-def test_profile_endpoint_serves_latest_device_profiles():
-    """/profile: the roofline plane's latest device profile per
-    program plus the peaks its verdicts were scored against."""
-    from paddle_tpu import roofline
-
-    prog = fluid.Program()
-    prof = roofline.build_device_profile(
-        prog, source="estimate", device_seconds=0.25, steps=1,
-        compile_report={"flops": 1e9, "bytes_accessed": 1e7,
-                        "op_histogram": {"mul": 1}},
-        backend="cpu")
-    roofline.record_profile(prof)
-    monitor.enable()
-    port = monitor.serve(0)
-    status, ctype, body = _get(port, "/profile")
-    assert status == 200 and ctype == "application/json"
-    doc = json.loads(body)
-    assert set(doc) == {"profiles", "peak_flops", "peak_bytes_per_sec"}
-    served = doc["profiles"][f"program{prog._uid}"]
-    roofline.validate_device_profile(served)
-    assert served["source"] == "estimate"
-    assert served["measured_mfu"] == pytest.approx(prof["measured_mfu"])
-
-
 def test_trace_endpoint_serves_live_timeline():
     """A running server alone makes tracing visible (no trace_dir
     needed): /trace returns loadable Chrome-trace JSON of the ring."""

@@ -4,8 +4,10 @@ label-cardinality cap, quantile summaries, the step ring buffer, the
 profiler's no-native degrade path, metric doc coverage, and the flags
 plane's self-documentation contract."""
 
+import functools
 import json
 import os
+import re
 import tracemalloc
 
 import numpy as np
@@ -101,12 +103,11 @@ def test_disabled_calls_are_inert_and_allocation_free():
 
 
 def test_gauge_replace_swaps_cells_and_honors_label_cap():
-    """Gauge.replace (the roofline plane's wholesale top-K mirror):
-    the swap is total — no stale cells survive — and the
-    MAX_LABEL_SETS cap applies exactly like every other mutator (an
-    unclamped device_profile_top_k must not grow the registry without
-    bound): first-listed values win, drops warn once and count into
-    pt_metric_label_overflow_total."""
+    """Gauge.replace (serving.py's per-engine states, fleet_serving.py's
+    replicas by state: a bounded map mirrored wholesale): the swap is
+    total — no stale cells survive — and the MAX_LABEL_SETS cap applies
+    exactly like every other mutator: first-listed values win, drops
+    warn once and count into pt_metric_label_overflow_total."""
     monitor.enable()
     g = monitor.gauge("t_repl_g", "replaced gauge")
     g.set(1.0, labels={"op": "stale"})
@@ -515,6 +516,46 @@ def test_profiler_degrades_cleanly_without_native(tmp_path, monkeypatch):
     profiler.start_profiler()
     profiler.stop_profiler(profile_path=str(tmp_path / "prof2"))
     assert not (tmp_path / "prof2.json").exists()
+
+
+def test_profiler_with_xplane_leaves_a_trace_the_one_reader_reads(tmp_path):
+    """``profiler.profiler(with_xplane=True)`` captures jax's trace under
+    ``<profile_path>_xplane``, where ``perf/trace.py`` (the one reader of
+    a device trace) finds and loads it; a CPU's capture holds no
+    ``/device:TPU:*`` plane, so it loads to none."""
+    import jax.numpy as jnp
+
+    from perf import trace
+
+    with profiler.profiler(profile_path=str(tmp_path / "prof"),
+                           with_xplane=True):
+        jnp.ones((64, 64)).sum().block_until_ready()
+    path = trace.find_xplane(str(tmp_path / "prof_xplane"))
+    assert path.endswith(".xplane.pb") and os.path.getsize(path) > 0
+    assert trace.load(path) == {"planes": []}
+    # without it no capture is started
+    with profiler.profiler(profile_path=str(tmp_path / "bare")):
+        pass
+    assert not (tmp_path / "bare_xplane").exists()
+
+
+@functools.cache
+def _package_source_but_the_flags():
+    root = os.path.dirname(fluid.__file__)
+    return "".join(
+        open(os.path.join(d, f)).read()
+        for d, _, fs in os.walk(root) for f in sorted(fs)
+        if f.endswith(".py") and (d, f) != (root, "flags.py"))
+
+
+@pytest.mark.parametrize("name", sorted(flags.get_flags()))
+def test_a_flag_has_a_reader_in_the_package(name):
+    """A flag's name, quoted, stands somewhere in ``paddle_tpu/`` outside
+    ``flags.py`` (``get_flag``, ``watch_flag``, ``get_flags``): a flag
+    whose reader was deleted goes with it (ROADMAP Queue 3 item 12)."""
+    assert re.search(rf"""["']{name}["']""",
+                     _package_source_but_the_flags()), \
+        f"flag '{name}' is defined in flags.py and read nowhere"
 
 
 # --------------------------------------------------------------------------

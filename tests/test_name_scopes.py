@@ -416,64 +416,25 @@ def test_run_steps_has_the_same_span_tree(tmp_path, telemetry_flags):
     assert events[0][3]["step"] == first
 
 
-# --- the program's own device profile uses the scope ------------------------
-
-FIXTURES = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perf", "fixtures")
-
+# --- what a lowered op's name yields to the one reader of a device trace -----
 
 @pytest.mark.parametrize("tf_op,want", [
     ("jit(step_fn)/bwd/enc0/ffn/mul_grad/transpose(jvp())/dot_general:",
-     "bwd/enc0/ffn/mul_grad"),
-    ("jit(step_fn)/fwd/enc0/ffn/mul/dot_general", "fwd/enc0/ffn/mul"),
-    ("jit(step_fn)/opt/adam/mul:", "opt/adam"),
+     ("bwd", "enc0/ffn", "mul_grad")),
+    ("jit(step_fn)/fwd/enc0/ffn/mul/dot_general", ("fwd", "enc0/ffn", "mul")),
+    ("jit(step_fn)/opt/adam/mul:", ("opt", "", "adam")),
     ("jit(step_fn)/bwd/enc1/ffn/relu_grad/transpose(bwd/enc1/ffn/relu_grad)"
-     "/jvp()/select_n:", "bwd/enc1/ffn/relu_grad"),
+     "/jvp()/select_n:", ("bwd", "enc1/ffn", "relu_grad")),
     ("jit(main)/fwd/loop/while/while/body/fwd/loop/body/elementwise_add/add",
-     "fwd/loop/while"),
+     ("fwd", "loop", "while")),
     ("jit(step_fn)/transpose(jvp())/dot_general:", None),
     ("", None),
 ])
 def test_scope_of_an_op_name(tf_op, want):
-    from paddle_tpu import roofline
+    """``<phase>/<scope>/<op>`` of an ``op_name`` as the lowering writes
+    it, read by ``perf.spans.parse_scope``: every per-layer number on the
+    ledger that names a phase, a layer or a Fluid op comes through it."""
+    from perf import spans
 
-    assert roofline.scope_of(tf_op) == want
-
-
-def unzipped(tmp_path, name):
-    import gzip
-    import shutil
-
-    d = tmp_path / name / "plugins" / "profile" / "run"
-    d.mkdir(parents=True)
-    with gzip.open(os.path.join(FIXTURES, name + ".xplane.pb.gz")) as src, \
-            open(d / "host.xplane.pb", "wb") as dst:
-        shutil.copyfileobj(src, dst)
-    return str(tmp_path / name)
-
-
-def test_a_device_profile_row_carries_the_scope_where_the_trace_has_one(
-        tmp_path):
-    from paddle_tpu import roofline
-
-    hist = {"mul": 4, "mul_grad": 4, "adam": 2, "layer_norm": 2}
-    prof = roofline.profile_from_xplane(
-        unzipped(tmp_path, "tbase-train-v5e-scoped-one-step"),
-        fluid.Program(), op_histogram=hist, record=False)
-    roofline.validate_device_profile(prof)      # schema stays v1
-    assert prof["v"] == 1 and prof["source"] == "xplane"
-    top = prof["top_ops"][0]
-    assert top["name"].startswith("%multiply_subtract_fusion.2 ")
-    assert top["scope"] == "bwd/loss_head/mul_grad"
-    assert top["framework_ops"] == ["mul_grad"]       # named, not guessed
-    scoped = [o for o in prof["top_ops"] if "scope" in o]
-    assert len(scoped) >= 0.8 * len(prof["top_ops"])
-    assert all(re.match(r"(fwd|bwd|opt)/", o["scope"]) for o in scoped)
-    # the tree before the scopes: the group guess, as it was
-    old = roofline.profile_from_xplane(
-        unzipped(tmp_path, "tbase-train-v5e-one-step"), fluid.Program(),
-        op_histogram=hist, record=False)
-    roofline.validate_device_profile(old)
-    assert not [o for o in old["top_ops"] if "scope" in o]
-    assert old["top_ops"][0]["framework_ops"] == \
-        roofline.map_to_framework_ops(old["top_ops"][0]["name"], hist)
+    got = spans.parse_scope(tf_op)
+    assert (got and (got["phase"], got["scope"], got["op"])) == want
