@@ -188,6 +188,54 @@ def _statistics_in_rows(attn):
           f"rows (stats=rows), none as the column: {attn}")
 
 
+def _forward_tiles(attn, h, t, **call):
+    """Every BHTD forward row of a lowered cell carries the tile
+    ``flash_attention.bhtd_fwd_tile`` gives its call (``h`` heads over
+    ``t`` positions and what else that function reads: two query heads
+    a step where they pair, over ONE fetched K / V block under a group)
+    and every backward row ``bhtd_tile``'s, one head a step: a forward
+    that fell back to one head a step, or a backward that left it,
+    fails here and not in a trace."""
+    from paddle_tpu.parallel import flash_attention as fa
+
+    both = {k: v for k, v in call.items()
+            if k in ("dh", "group", "dv", "block_diffusion")}
+    for direction, by, tile in (
+            ("fwd", "bhtd_fwd_tile", fa.bhtd_fwd_tile(h, t, t, **call)),
+            ("bwd", "bhtd_tile", fa.bhtd_tile(h, t, t, **both))):
+        rows = [k for k in attn if k.startswith(f"bhtd {direction} ")]
+        check(rows and all(f" [{fa.tile_label(tile)}]" in k for k in rows),
+              f"expected every bhtd {direction} call on the tile "
+              f"{fa.tile_label(tile)} ({by}): {attn}")
+
+
+def _forward_on_its_tile(fn, args, h, tile, what):
+    """The ONE ``attn.bhtd.fwd`` call that ``fn(*args)`` lowers walks
+    ``h`` heads ``tile``'s heads a step (``bhtd_fwd_tile``'s answer for
+    the call): its grid (batch rows, head steps, q-blocks, k-steps) as
+    the jaxpr has it, so what Mosaic is handed."""
+    import jax
+
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                if eqn.params["name"] == "attn.bhtd.fwd":
+                    grids.append(tuple(eqn.params["grid_mapping"].grid))
+                continue
+            for v in eqn.params.values():
+                for x in v if isinstance(v, (list, tuple)) else [v]:
+                    inner = getattr(x, "jaxpr", x)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    check(len(grids) == 1 and grids[0][1] == h // tile[0],
+          f"{what}: expected one attn.bhtd.fwd call that walks {h} heads "
+          f"{tile[0]} a step (bhtd_fwd_tile: {tile}), its grids: {grids}")
+
+
 def _dispatch_since(before, read=attention_dispatch):
     now = read()
     return {k: v - before.get(k, 0) for k, v in sorted(now.items())
@@ -301,10 +349,16 @@ def kernel_phase(cases=KERNEL_CASES, h=8, dh=64):
                   f"{family} b{b} t{t}: {name} off the reference by "
                   f"{errs[name]:.4f} of its max (tolerance "
                   f"{KERNEL_REL_TOL})")
+        tile = None
+        if family == "bhtd":
+            # the forward's own tile (two heads a step; the pad bias's
+            # block is in its count), and the call on it
+            tile = fa.bhtd_fwd_tile(h, t, t, dh=dh, bias=bias.shape)
+            _forward_on_its_tile(lambda *a: kernel_loss(*a)[1], (q, k, v),
+                                 h, tile, f"bhtd b{b} t{t}")
         row = {"family": family, "b": b, "t": t, "causal": causal,
-               # the dispatch counter's ``tile`` label for this shape
-               "tile": fa.tile_label(fa.bhtd_tile(h, t, t, dh=dh))
-               if family == "bhtd" else "",
+               # the dispatch counter's ``tile`` label of the forward
+               "tile": fa.tile_label(tile),
                "p_drop": p_drop, "backward": backward,
                "compile_s": round(compile_s, 2),
                "pallas_calls": lowered.as_text().count("tpu_custom_call"),
@@ -690,6 +744,11 @@ def _bhtd_against_dense(q, k, v, g, errs, what):
               f"{what} {name} off the dense composition by "
               f"{errs[name]:.4f} of its max (tolerance {KERNEL_REL_TOL})")
     h, hk = q.shape[1], k.shape[1]
+    _forward_on_its_tile(
+        lambda *a: kernels.__wrapped__(*a)[0], (q, k, v, g), h,
+        fa.bhtd_fwd_tile(h, q.shape[2], k.shape[2], dh=q.shape[3],
+                         group=h // hk, dv=v.shape[3],
+                         itemsize=q.dtype.itemsize), what)
     if jax.default_backend() != "tpu" or fa.bhtd_bwd_form(
             h, q.shape[2], k.shape[2], dh=q.shape[3], group=h // hk,
             dv=v.shape[3], itemsize=q.dtype.itemsize) != "fused":
@@ -1193,6 +1252,9 @@ def mla_rows_hold(cfg, seq, lowered):
         "[tm128 " in k for k in gmm),
         f"expected {9 * n_moe} grouped matmuls on a tile of 128 rows "
         f"(128 rows an expert), none through ragged_dot: {gmm}")
+    # (two query heads a forward step, which share KPe's ONE head)
+    _forward_tiles(attn, cfg.num_attention_heads, seq, dh=dk, dv=dv,
+                   pe_group=cfg.num_attention_heads)
 
 
 def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
@@ -1211,9 +1273,12 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
        expert (tm128), none through ``ragged_dot``. ``overrides`` cut
        the config for the CPU tests.
     2. On the device: the BHTD kernels at the model's two widths (8
-       heads: one a step at blocks of 512, the cell's tile, so the
-       backward is the fused call), forward and the three gradients,
-       against the dense composition."""
+       heads at blocks of 512, the cell's: two a forward step, one a
+       backward step, so the backward is the fused call), forward and
+       the three gradients, against the dense composition; and the
+       forward given q and k in two parts, two heads a step over KPe's
+       ONE head, against the composition of the assembled call."""
+    import jax
     import jax.numpy as jnp
 
     from paddle_tpu.parallel import flash_attention as fa
@@ -1227,13 +1292,35 @@ def mla_phase(seq=4096, t_check=1024, heads=8, **overrides):
     # --- on the device ----------------------------------------------------
     r = np.random.RandomState(5)
     bf = jnp.bfloat16
-    tile = fa.bhtd_tile(heads, t_check, t_check, dh=dk, dv=dv)
+    tile = fa.bhtd_fwd_tile(heads, t_check, t_check, dh=dk, dv=dv)
     check(tile is not None, f"no bhtd tile for h{heads} dk{dk} dv{dv}")
     qa, ka = (jnp.asarray(r.randn(1, heads, t_check, dk) * 0.3, bf)
               for _ in "qk")
     va, ga = (jnp.asarray(r.randn(1, heads, t_check, dv), bf) for _ in "vg")
     errs = {}
     attn_ms = _bhtd_against_dense(qa, ka, va, ga, errs, "latent attention")
+    # the forward given its queries and keys in two parts, as the cell's
+    # layers give them: two heads a step read KPe's ONE head
+    nope = dk - cfg.qk_rope_head_dim
+    q_pe, k_pe = qa[..., nope:], ka[:, :1, :, nope:]
+    own = lambda q, k, v, q_pe, k_pe: fa.flash_attention_fwd(
+        q, k, v, causal=True, q_pe=q_pe, k_pe=k_pe)[0]
+    parts = (qa[..., :nope], ka[..., :nope], va, q_pe, k_pe)
+    check(fa.bhtd_parts(heads, t_check, t_check, dh=nope, r=dk - nope, hp=1,
+                        dv=dv), f"the kernels do not take h{heads} "
+          f"{nope} | {dk - nope} over {dv} in two parts at t{t_check}")
+    _forward_on_its_tile(
+        own, parts, heads, fa.bhtd_fwd_tile(
+            heads, t_check, t_check, dh=dk, dv=dv, pe_group=heads),
+        "latent attention in two parts")
+    errs["parts_o"] = _rel(jax.jit(own)(*parts), jax.jit(
+        lambda: fa._reference_attention(
+            qa, jnp.concatenate(
+                [ka[..., :nope], jnp.repeat(k_pe, heads, axis=1)], -1),
+            va, None, dk ** -0.5, causal=True).astype(bf))())
+    check(errs["parts_o"] <= KERNEL_REL_TOL,
+          f"latent attention in two parts off the dense composition by "
+          f"{errs['parts_o']:.4f} of its max (tolerance {KERNEL_REL_TOL})")
     row = {**rows, "tile": fa.tile_label(tile), "attn_bwd_kernel_ms": attn_ms,
            "rel_err": {k_: round(e, 5) for k_, e in errs.items()}}
     say(f"  mla {row['rel_err']}")
@@ -1887,6 +1974,10 @@ def bd_rows_hold(cfg, seq, lowered):
         k.startswith("kernel ") and k.endswith(" norm=head") for k in ropes),
         f"expected {n} rotary embeddings each way on the rope kernels "
         f"with the heads' gains (norm=head), none as XLA's ops: {ropes}")
+    _forward_tiles(attn, cfg.num_attention_heads, 2 * seq,
+                   dh=cfg.head_dim,
+                   group=cfg.num_attention_heads // cfg.num_key_value_heads,
+                   block_diffusion=block)
 
 
 def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
@@ -1956,6 +2047,10 @@ def bd_phase(seq=4096, t_check=1024, heads=(32, 4), dh=128, **overrides):
             block_diffusion=block).astype(q.dtype), q, k, v)
         return (out, *vjp(g))
 
+    _forward_on_its_tile(
+        lambda *a: kernels.__wrapped__(*a)[0], (q, k, v, g), h,
+        fa.bhtd_fwd_tile(h, t, t, dh=dh, group=h // hk,
+                         block_diffusion=block), "the block-masked call")
     errs = {}
     for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
                           kernels(q, k, v, g), dense(q, k, v, g)):
@@ -2041,6 +2136,11 @@ def keye_rows_hold(cfg, seq, lowered):
           f"expected the selects' score columns {want} (walked: what the "
           f"thresholds' passes read, the causal prefixes where they are "
           f"dsa.topk.fwd's): {lowered['topk_columns']}")
+    # (two query heads a forward step over ONE fetched block of K, V and
+    # of the selection's words)
+    _forward_tiles(attn, cfg.num_attention_heads, seq, dh=cfg.head_dim,
+                   group=cfg.num_attention_heads // cfg.num_key_value_heads,
+                   selected=True)
 
 
 def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
@@ -2165,6 +2265,10 @@ def keye_phase(seq=16384, t_check=2048, heads=(8, 2), dh=128, **overrides):
             selected=mask).astype(q.dtype), q, k, v)
         return (out, *vjp(g))
 
+    _forward_on_its_tile(
+        lambda *a: kernels.__wrapped__(*a)[0], (q, k, v, g), h,
+        fa.bhtd_fwd_tile(h, t, t, dh=dh, group=h // hk, selected=True),
+        "the call under a selection")
     for name, a, b in zip(("attn_o", "attn_dq", "attn_dk", "attn_dv"),
                           kernels(q, k, v, g), dense(q, k, v, g)):
         row["rel_err"][name] = _rel(a, b)

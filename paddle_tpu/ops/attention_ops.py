@@ -32,8 +32,12 @@ _M_DISPATCH = _monitor.counter(
     "\"dk192 dv128\" where queries and keys are wider than values), tile "
     "(heads, query rows and key "
     "rows of one grid step, where the family picks them by the shape: "
-    "bhtd) and replicated_over (mesh axes whose every rank repeats that "
-    "same call). A windowed call's shape ends in w<window> and the row "
+    "bhtd; a fwd row's is the forward's own, flash_attention."
+    "bhtd_fwd_tile: \"hb2 bq512 bk512\" where two query heads share a "
+    "step and, under a group, ONE fetched K / V block; a bwd row's "
+    "flash_attention.bhtd_tile's) and replicated_over (mesh axes whose "
+    "every rank repeats that same call). A windowed call's shape ends "
+    "in w<window> and the row "
     "carries band: skip (the kernels walk the band, no block outside it "
     "is a step), mask (the triangle walked and masked) or dense, and "
     "heads, the call's query heads (a model's window layers may have a "
@@ -105,7 +109,7 @@ def _block_masked(attrs, q, k, bthd, ring):
 
 def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
                    form=None, causal=False, block_diffusion=None, parts=None,
-                   sel=None):
+                   sel=None, p_drop=0.0, bias=None, pe_group=None):
     # off with telemetry; build-time shape inference is not a lowering
     if not _monitor.enabled() or not interp.lowering_active():
         return
@@ -118,9 +122,17 @@ def _note_dispatch(family, direction, dims, replicated_over=(), window=None,
         # (the op passes no q_block / k_block)
         from paddle_tpu.parallel import flash_attention as fa
 
-        picked = fa.bhtd_tile(h, tq, tk, dh=dh, group=h // hk, dv=dv,
-                              block_diffusion=block_diffusion,
-                              itemsize=dims[7] if len(dims) > 7 else 2)
+        call = dict(dh=dh, group=h // hk, dv=dv,
+                    block_diffusion=block_diffusion,
+                    itemsize=dims[7] if len(dims) > 7 else 2)
+        if direction == "fwd":
+            # (the forward's own heads a step; ``p_drop``, ``bias``,
+            # ``pe_group``: what else of the call that tile goes by)
+            picked = fa.bhtd_fwd_tile(
+                h, tq, tk, **call, p_drop=p_drop, bias=bias,
+                pe_group=pe_group, selected=sel == "operand")
+        else:
+            picked = fa.bhtd_tile(h, tq, tk, **call)
         tile = fa.tile_label(picked)
         edge = fa.edge_label(fa.bhtd_edge_tile(picked, causal, form))
         if direction == "fwd":
@@ -664,7 +676,7 @@ def _selected(ins, attrs, family, dims):
 
 def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
              form=None, causal=False, block_diffusion=None, parts=None,
-             sel=None):
+             sel=None, **fwd):
     """``kernel(*arrays, seed)`` — a Pallas attention call whose array
     arguments (None allowed) and results all lead with the batch dim —
     under the program's mesh. GSPMD cannot partition a Mosaic kernel
@@ -674,12 +686,13 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     ``replicated_over`` in the dispatch counter, never silent. Each
     shard hands the kernels its first GLOBAL batch row along with the
     seed, so the dropout masks do not depend on how many devices split
-    the batch."""
+    the batch. ``fwd``: what else a BHTD forward's own tile goes by
+    (``_note_dispatch``'s ``p_drop``, ``bias``, ``pe_group``)."""
     split = interp.mesh_batch_split()
     if split is None:
         _note_dispatch(family, direction, dims, window=window, form=form,
                        causal=causal, block_diffusion=block_diffusion,
-                       parts=parts, sel=sel)
+                       parts=parts, sel=sel, **fwd)
         return kernel(*arrays, seed)
     from jax.sharding import PartitionSpec as P
 
@@ -694,7 +707,7 @@ def _on_mesh(kernel, arrays, seed, family, direction, dims, window=None,
     _note_dispatch(
         family, direction, (b // n,) + tuple(dims[1:]),
         sorted(a for a in free - set(axis) if mesh.shape[a] > 1), window,
-        form, causal, block_diffusion, parts)
+        form, causal, block_diffusion, parts, **fwd)
     batch = P(axis) if axis else P()
     present = [a for a in arrays if a is not None]
     # a [1, ...] bias broadcasts over the batch: it stays replicated
@@ -869,7 +882,10 @@ def _sdpa(ins, attrs, rng=None):
                 q_pe=_x(ins, "QPe"), k_pe=_x(ins, "KPe"),
                 selected=selected, live=live),
             (q, k, v, bias), seed, family, "fwd", dims, window,
-            block_diffusion=block, parts=parts, sel=sel)
+            block_diffusion=block, parts=parts, sel=sel, p_drop=float(drop),
+            bias=None if bias is None else bias.shape,
+            pe_group=q.shape[1] // _x(ins, "KPe").shape[1]
+            if parts == "own" else None)
     return {"Out": [out.astype(q.dtype)], "Lse": [lse]}
 
 

@@ -19,7 +19,13 @@ sequence blocks of 128 x 128, 128 FLOPs per byte fetched against the v5e's
 ridge of 240.
 
 - Forward: k-blocks inner; running (m, l, acc) in VMEM scratch across
-  the k-block loop; emits the output AND the logsumexp. The logsumexp
+  the k-block loop; emits the output AND the logsumexp. Its tile is its
+  own (``bhtd_fwd_tile``): the blocks above and, where the heads are on
+  the grid, TWO query heads a step, a chain each in the step's body
+  (a call 11-22% shorter: PERF.md section 6, PR 74), the two heads
+  of one key head's group over ONE fetched block of K, of V and of a
+  selection's words (``_row_specs``), under the forward's own VMEM
+  count (``_fwd_vmem_bytes``); the backward keeps one head a step. The logsumexp
   crosses HBM in the layout its consumer reads: [b, h, 1, tq] float32
   ROWS (``bhtd_stats_form``), four bytes a position, cut by the ONE
   backward call into [1, bq] blocks along the lanes. A [.., tq, 1]
@@ -172,6 +178,11 @@ _NEG_INF = -1e30
 # kernel holds ~6 of them plus casts and scratch), so the per-block cap
 # must stay well under limit/6. 1.5MB admits 8 heads of 128 x 256 (with
 # a [*, tq, tk] bias at t=1024 and beyond) and one head of 512 x 512.
+# It is the BACKWARD's count, and the tile it gives (_pick_tile) is the
+# backward's and the blocks of both passes; how many heads a FORWARD
+# step takes at those blocks is the forward's own count
+# (``bhtd_fwd_tile``, ``_fwd_vmem_bytes``: about three score-sized temps,
+# under a limit the call sets itself).
 _SCORE_VMEM_BYTES = 3 * 2**19
 # Soft cap on what the dk/dv kernel keeps of (hb, bk, dh) besides its
 # score temps: k and v (bf16, double-buffered), dk and dv out (the same)
@@ -449,6 +460,106 @@ def bhtd_parts(h, tq, tk, q_block=None, k_block=None, *, dh, r, hp,
         itemsize=itemsize) == "fused")
 
 
+# Query heads a step of ``attn.bhtd.fwd`` works on where the call's heads
+# are on the grid (``bhtd_fwd_tile``), the side of the blocks from which
+# it does (what the chip timed: every cell's 512 x 512; a caller's
+# smaller block, a test's, keeps one head a step, and tests reach two
+# at blocks of 128 by setting it), and what such a step may keep in
+# VMEM by the forward's own count. A quarter of a v5e core's 128 MiB:
+# two heads of 512 x 512 count 9 to 13 MB, so the cap refuses only what
+# nobody measured.
+_FWD_HEADS = 2
+_FWD_PAIR_BLOCK = 512
+_FWD_VMEM_CAP_BYTES = 32 * 2**20
+
+
+def _fwd_vmem_bytes(hq, hkv, bq, bk, dh, dv, itemsize, stats="rows",
+                    selected=False, bias=None):
+    """What a step of ``attn.bhtd.fwd`` keeps in VMEM: ``hq`` query
+    heads' blocks of q and out and of the logsumexp, double-buffered;
+    the three statistics' scratch (m and l along 128 lanes, the float32
+    accumulator); ``hkv`` heads' blocks of K and V, double-buffered;
+    three float32 score blocks and p in the call's dtype; under a
+    selection its words, double-buffered, and their unpacked int32
+    block, ONE a step whatever the heads; a float ``bias`` (its
+    [b, 1 | h, 1 | tq, tk] shape) its block, double-buffered, as
+    float32. ``dh``: the whole head's width where q and k come in two
+    parts (the shared rotary key head is counted a head a K block:
+    over, by a few KB)."""
+    lse = 4 * bq if stats == "rows" else 512 * bq
+    rows = hq * (2 * itemsize * bq * (dh + dv) + 2 * lse
+                 + 4 * bq * (2 * 128 + dv))
+    keys = 2 * itemsize * hkv * bk * (dh + dv)
+    scores = hq * bq * bk * (3 * 4 + itemsize)
+    chosen = (2 * 4 * (bq // 32) * bk + 4 * bq * bk) if selected else 0
+    added = 0 if bias is None else 2 * 4 * bk * (
+        (hq if bias[1] > 1 else 1) * (bq if bias[2] > 1 else 1))
+    return rows + keys + scores + chosen + added
+
+
+def _fwd_vmem_limit(*step, **kw):
+    """Mosaic's scoped limit for the forward call: what a step keeps and
+    a quarter more, or None where that is within Mosaic's default of 16
+    MiB and the call asks for nothing (one head of 512 x 512 counts 5
+    MB: the call lowers as before there was a count)."""
+    limit = _fwd_vmem_bytes(*step, **kw) * 5 // 4
+    return limit if limit > 16 * 2**20 else None
+
+
+def _kv_blocks(hq, group):
+    """Heads of K and of V that a forward step of ``hq`` query heads
+    holds: ONE where they are of one key head's group, a head each
+    where a key head serves one query head."""
+    return hq if group == 1 else 1
+
+
+def bhtd_fwd_tile(h, tq, tk, q_block=None, k_block=None, *, dh, group=1,
+                  dv=None, block_diffusion=None, itemsize=2, p_drop=0.0,
+                  bias=None, pe_group=None, selected=False):
+    """-> (hq, bq, bk), the tile ``attn.bhtd.fwd`` takes for a call of
+    this shape (None where ``bhtd_tile`` gives none): the blocks are
+    ``bhtd_tile``'s, which the backward walks too (``bhtd_pairs`` counts
+    one walk), the query heads of a step the forward's own. Where the
+    heads are on the grid (``bhtd_tile``: one a step) a forward step
+    takes ``_FWD_HEADS`` = 2 of them, so that a step holds two heads'
+    chains and the two heads of one key head's group read ONE fetched
+    block of K, of V and of a selection's words (``_row_specs``); a key
+    head a query head (``group`` 1), the step holds both heads' K and V
+    blocks. The step's count (``_fwd_vmem_bytes``) is the forward's own,
+    under ``_FWD_VMEM_CAP_BYTES``, and the call sets Mosaic's limit from
+    it (``_fwd_vmem_limit``); ``_SCORE_VMEM_BYTES`` is the backward's.
+
+    One head a step stays where the two do not pair: an odd number of
+    heads; an odd ``group`` over 1 (28 heads on 4: heads 6 and 7 read
+    two key heads; a whole group of seven a step is 39 MB by the count,
+    over the cap; a K and a V block for each of two heads measured
+    -11.8% a call and is not taken: PERF.md section 7 (41a));
+    ``pe_group`` (query heads a head of KPe, where q and k come in two
+    parts) neither even nor 1; blocks under ``_FWD_PAIR_BLOCK`` on a
+    side (nobody timed them); ``p_drop`` > 0 (a block's
+    mask is keyed by the step's head GROUP, ``_seed_step``, and the
+    backward draws it again at one head a step: two heads a step would
+    train on another mask than they are differentiated under).
+    ``bias``: the shape of a float bias, which the count reads. A pure
+    function of what the call shows, as ``bhtd_tile`` is: the entry
+    point, the dispatch counter's ``tile`` label of a ``pass="fwd"`` row
+    and the tests read it."""
+    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+                     block_diffusion=block_diffusion, itemsize=itemsize)
+    if tile is None or tile[0] != 1 or p_drop > 0.0:
+        return tile
+    _, bq, bk = tile
+    hq, dv = _FWD_HEADS, dv or dh
+    pairs = (h % hq == 0 and (group == 1 or group % hq == 0)
+             and (pe_group in (None, 1) or pe_group % hq == 0)
+             and min(bq, bk) >= _FWD_PAIR_BLOCK)
+    if not pairs or _fwd_vmem_bytes(
+            hq, _kv_blocks(hq, group), bq, bk, dh, dv, itemsize,
+            bhtd_stats_form(tile, tq), selected, bias) > _FWD_VMEM_CAP_BYTES:
+        return tile
+    return hq, bq, bk
+
+
 # ---------------------------------------------------------------------------
 # kernels — refs are blocks of the native [b, h, t, dh] layout over the
 # grid (batch row, head group, q-block, k-block); index 0 drops the
@@ -461,18 +572,20 @@ def bhtd_parts(h, tq, tk, q_block=None, k_block=None, *, dh, r, hp,
 
 def _causal_mask(s, j, kk, bq, bk, transposed=False, window=None,
                  at=(0, 0)):
-    """Mask future positions inside score block (hb, bq, bk) for q-block
+    """Mask future positions inside score block (hb, bq, bk), or one
+    head's (bq, bk), for q-block
     j / k-block kk (``transposed``: block is (hb, bk, bq)); with a
     ``window`` also the positions it has forgotten (p - s >= window).
     ``at``: where ``s`` starts inside the block, (query row, key row),
     where it is a slab of it."""
     q0, k0 = j * bq + at[0], kk * bk + at[1]
+    rows, cols = s.ndim - 2, s.ndim - 1     # (one head's block: [bq, bk])
     if transposed:
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k0
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + q0
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, rows) + k0
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, cols) + q0
     else:
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + q0
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + k0
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, rows) + q0
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, cols) + k0
     seen = q_pos >= k_pos
     if window is not None:
         seen = jnp.logical_and(seen, q_pos - k_pos < window)
@@ -843,12 +956,21 @@ def _chosen_live(seed_ref, live, j, kk, own):
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, nk, ng, p_drop,
                 causal=False, window=None, bd=None, pe_refs=None,
-                live=None):
+                live=None, chain=None):
     # r: the inner axis's step, nk of them; kk the k-block it works on
     # (``pe_refs``: blocks of QPe and KPe where q and k come in two parts,
-    # _call_parts)
+    # _call_parts). ``chain``: heads a chain. None: the step's heads are
+    # ONE batched chain (a tile that batches a short row's heads,
+    # _pick_tile). 1 (the forward's own tile, bhtd_fwd_tile): a chain a
+    # head, one behind the other, each the one-head step's operations in
+    # its order on its operands, against its own head of K and V or the
+    # ONE head the step's heads share. (Timed against the heads batched,
+    # their rows through one product, and the heads' scores made first:
+    # 18.3 ms a call for 20.0-20.1 at [1, 32 / 4, 16384, 128] under a
+    # selection on a v5e, PR 74; benchmarks/attn_fwd_candidates.py.)
     j, r = pl.program_id(2), pl.program_id(3)
-    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    hq, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+    chain = chain or hq
     if bd is not None:
         kk, bd_live, bd_edge = _bd_k_step(j, r, bq, bd)
     else:
@@ -860,21 +982,43 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+    def heads(ref, first):
+        # a chain's heads of an operand's block. One batched chain: the
+        # block, [hq, ., .]. A chain a head: ONE head's [., .], the
+        # step's ``first``-th where the block has a head each, the
+        # block's one (a group's K or V, the rotary key head) where the
+        # step's heads share it
+        if chain == hq:
+            return ref[0]
+        return ref[0, first if ref.shape[1] == hq else 0]
+
     def _compute(masked=False):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
+        for first in range(0, hq, chain):
+            _chain(masked, first, slice(None) if chain == hq else first)
+
+    def _chain(masked, first, own):
+        # (``own``: the chain's rows of the statistics' scratch. The
+        # products' axes from the end: a batched chain's blocks lead
+        # with their heads, one head's do not)
+        q = heads(q_ref, first)
+        k = heads(k_ref, first)
+        v = heads(v_ref, first)
+        d, batch = q.ndim - 1, (tuple(range(q.ndim - 2)),) * 2
+
+        def scores(a, b):
+            return jax.lax.dot_general(a, b, (((d,), (d,)), batch),
+                                       preferred_element_type=jnp.float32)
+
+        s = scores(q, k)
         if pe_refs is not None:     # q k^T + q_pe k_pe^T, one float32 sum
-            s = s + jax.lax.dot_general(
-                pe_refs[0][0], pe_refs[1][0], (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
+            s = s + scores(heads(pe_refs[0], first),
+                           heads(pe_refs[1], first))
         s = s * scale
         if bias_ref is not None:
-            s = _biased(s, _unpacked(bias_ref[0]))
+            # (a selection's words are unpacked for each chain again: the
+            # block's MB of int32 kept from one chain to the next cost
+            # 3.5% of a call, 18.93 ms for 18.28 at keye's, PR 74)
+            s = _biased(s, _unpacked(heads(bias_ref, first)))
         if masked and bd is not None:
             s = _bd_mask(s, j, kk, bq, bd)
         elif masked:
@@ -885,24 +1029,23 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         # block and the accumulator read whole vregs of them (sliced to
         # one lane and broadcast again every step, the forward took 2.95
         # ms where it takes 1.65: OLMoE's shape on a v5e, PR 29)
-        m_prev = m_scr[:]
-        l_prev = l_scr[:]
+        m_prev = m_scr[own]
+        l_prev = l_scr[own]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, bk))
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
 
-        if p_drop > 0.0:
+        if p_drop > 0.0:    # (one chain: bhtd_fwd_tile)
             _seed_step(seed_ref, ng, j, kk)
             p = p * _dropout_mask(1.0 - p_drop, p.shape)
 
-        acc_scr[:] = (acc_scr[:] * _lanes(corr, acc_scr.shape[2])
-                      + jax.lax.dot_general(
-                          p.astype(v.dtype), v,
-                          (((2,), (1,)), ((0,), (0,))),
-                          preferred_element_type=jnp.float32))
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        acc_scr[own] = (acc_scr[own] * _lanes(corr, acc_scr.shape[2])
+                        + jax.lax.dot_general(
+                            p.astype(v.dtype), v, (((d,), (d - 1,)), batch),
+                            preferred_element_type=jnp.float32))
+        m_scr[own] = m_new
+        l_scr[own] = l_new
 
     if bd is not None:
         _when_live(_compute, bd_live, j, kk, bq, bk, None, edge=bd_edge)
@@ -1308,15 +1451,16 @@ def _row_specs(at, hb, bq, bk, dh, group=1, dv=None, pe=None):
     """Specs read at ``at``'s blocks: a (1, hb, bq, dh) block of q or its
     gradient; a (1, hb, bq, 1) block of a [b, h, tq, 1] statistic;
     a (1, hb, 1, bq) block of the same statistic laid out [b, h, 1, tq];
-    a (1, hb, bk, dh) block of k or its gradient (``group`` > 1: of the
-    key/value head the step's query head reads); and (1, hb, bq, dv) /
-    (1, hb, bk, dv) blocks of out and v or their gradients (``dv``: dh
-    where the call has one width). ``pe`` = (r, query heads a head of
-    KPe) where q and k come in two parts (one head a step, a key head a
-    query head): a (1, 1, bq, r) block of QPe or its gradient at q's
-    index, a (1, 1, bk, r) block of KPe at the head the step's query
-    head reads (nothing is copied), and the same block of KPe's gradient
-    at k's index, the QUERY head's."""
+    a (1, hb, bk, dh) block of k or its gradient (``group`` > 1: a
+    (1, 1, bk, dh) block, of the ONE key/value head the step's query
+    heads read); and (1, hb, bq, dv) / (1, hb | 1, bk, dv) blocks of out
+    and v or their gradients (``dv``: dh where the call has one width).
+    ``pe`` = (r, query heads a head of KPe) where q and k come in two
+    parts (a key head a query head): a (1, hb, bq, r) block of QPe or
+    its gradient at q's index, a (1, 1, bk, r) block of KPe at the ONE
+    head the step's query heads read (nothing is copied), and the same
+    block of KPe's gradient at k's index, the QUERY head's (the
+    backward's: one head a step)."""
     dv = dv or dh
     def q_idx(*ids):
         i, g, j, _ = at(*ids)
@@ -1326,28 +1470,33 @@ def _row_specs(at, hb, bq, bk, dh, group=1, dv=None, pe=None):
         i, g, j, _ = at(*ids)
         return i, g, 0, j
 
-    def k_idx(*ids):
-        i, g, _, kk = at(*ids)
-        return i, g if group == 1 else g // group, kk, 0
+    def read_by(heads, width):
+        # the spec of an operand [b, h / heads, tk, width] that ``heads``
+        # query heads read one head of: a block of the step's hb heads
+        # where each has its own (heads 1), else ONE head's block, which
+        # the step's heads share (hb divides ``heads``: _pick_tile gives
+        # a group one head a step, bhtd_fwd_tile an even one two)
+        def idx(*ids):
+            i, g, _, kk = at(*ids)
+            if heads > 1:
+                g = (g if hb == 1 else g * hb) // heads
+            return i, g, kk, 0
+        return pl.BlockSpec((1, hb if heads == 1 else 1, bk, width), idx)
 
     specs = _Specs(q=pl.BlockSpec((1, hb, bq, dh), q_idx),
                    stat=pl.BlockSpec((1, hb, bq, 1), q_idx),
                    row=pl.BlockSpec((1, hb, 1, bq), row_idx),
-                   k=pl.BlockSpec((1, hb, bk, dh), k_idx),
+                   k=read_by(group, dh),
                    o=pl.BlockSpec((1, hb, bq, dv), q_idx),
-                   v=pl.BlockSpec((1, hb, bk, dv), k_idx))
+                   v=read_by(group, dv))
     if pe is None:
         return specs
     r, pe_group = pe
-
-    def k_pe_idx(*ids):
-        i, g, _, kk = at(*ids)
-        return i, g // pe_group, kk, 0
-
     return specs._replace(
-        q_pe=pl.BlockSpec((1, 1, bq, r), q_idx),
-        k_pe=pl.BlockSpec((1, 1, bk, r), k_pe_idx),
-        dk_pe=pl.BlockSpec((1, 1, bk, r), k_idx))
+        q_pe=pl.BlockSpec((1, hb, bq, r), q_idx),
+        k_pe=read_by(pe_group, r),
+        # (the backward's: a query head's own block, one head a step)
+        dk_pe=read_by(1, r))
 
 
 def _bias_spec(bias, at, hb, bq, bk):
@@ -1700,13 +1849,17 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         dh += q_pe.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
-    tile = bhtd_tile(h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
-                     block_diffusion=block_diffusion,
-                     itemsize=q.dtype.itemsize)
     chosen = selected is not None and bhtd_selected(
         h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
         itemsize=q.dtype.itemsize, blocks=live.shape[1:],
         plain=_only_causal(causal, bias, p_drop, window, bd, pe))
+    # (the forward's own heads a step at the call's blocks; the rows of
+    # lse go by the blocks, which are the backward's tile's too)
+    held = dict(selected=chosen, bias=None if bias is None else bias.shape)
+    tile = bhtd_fwd_tile(
+        h, tq, tk, q_block, k_block, dh=dh, group=group, dv=dv,
+        block_diffusion=block_diffusion, itemsize=q.dtype.itemsize,
+        p_drop=p_drop, pe_group=pe and h // k_pe.shape[1], **held)
     if tile is None or (selected is not None and not chosen):
         # REAL logsumexp rows, not placeholder zeros: the ring-attention
         # merge combines per-block (o, lse) partials, and both must
@@ -1731,15 +1884,26 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
         _fwd_kernel,
         _step_blocks(causal, True, bq, bk, nq, group, window, nk, bd,
                      live_at), tile, q, k, v, bias, pe)
+    # (heads that the forward's own tile put into a step are a chain
+    # each; heads that bhtd_tile batched, a short row's, one chain)
+    batched = tile == bhtd_tile(h, tq, tk, q_block, k_block, dh=dh,
+                                group=group, dv=dv,
+                                block_diffusion=block_diffusion,
+                                itemsize=q.dtype.itemsize)
     kernel = functools.partial(kernel, scale=scale, nk=nk, ng=ng,
                                p_drop=p_drop, causal=causal, window=window,
-                               bd=bd)
+                               bd=bd, chain=None if batched else 1)
     if live_at is not None:
         kernel = functools.partial(kernel, live=live_at)
     operands = (seed_arr, *args)
     lse_spec, lse_shape = rows.stat, (b, h, tq, 1)
     if bhtd_stats_form(tile, tq) == "rows":
         lse_spec, lse_shape = rows.row, (b, h, 1, tq)
+    limit = _fwd_vmem_limit(hb, _kv_blocks(hb, group), bq, bk, dh, dv,
+                            q.dtype.itemsize, bhtd_stats_form(tile, tq),
+                            **held)
+    asked = {} if limit is None else dict(
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit))
     out, lse = pl.pallas_call(
         kernel, name="attn.bhtd.fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1757,7 +1921,7 @@ def flash_attention_fwd(q, k, v, bias=None, seed=None, scale=None,
             _result(operands, (b, h, tq, dv), q.dtype),
             _result(operands, lse_shape, jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=_INTERPRET, **asked,
     )(*operands)
     # (inside one jit this reshape and the backward's, back into rows,
     # fold to nothing; a consumer of the column gets it from XLA)

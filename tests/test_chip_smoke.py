@@ -281,7 +281,11 @@ def fails(name, match, **altered):
     ones in their place."""
     _, seq, _, tiny = PHASES[name]
     _, cfg = chip_smoke.cell(name, **tiny)
-    with pytest.raises(chip_smoke.SmokeFailure, match=match):
+    # (a check that asks the kernel layer for a call's tile, as the
+    # phase's own run did: through the interpreter)
+    with pytest.raises(chip_smoke.SmokeFailure, match=match), \
+            pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "_INTERPRET", True)
         getattr(chip_smoke, f"{name}_rows_hold")(cfg, seq, altered)
 
 
@@ -307,10 +311,12 @@ def test_gdn_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
                           f"kernel bwd {shape}": 3}
     assert row["conv"] == {"kernel fwd b1 t512 c512 taps4": 3,
                            "kernel bwd b1 t512 c512 taps4": 3}
+    # (the forward two query heads a step over their group's ONE K / V
+    # block, the backward one: bhtd_fwd_tile, bhtd_tile)
     assert sorted(row["attention"]) == [
-        f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb1 bq512 bk512]{form}"
-        for d, form in (("bwd", " form=fused edge=256x256"),
-                        ("fwd", " stats=rows"))]
+        f"bhtd {d} b1 tq512 tk512 h4 kv2 dh128 [hb{hb} bq512 bk512]{form}"
+        for d, hb, form in (("bwd", 1, " form=fused edge=256x256"),
+                            ("fwd", 2, " stats=rows"))]
     assert row["attn_bwd_kernel_ms"] == {}      # (a trace needs the chip)
     assert sum(row["grouped_matmuls"].values()) == 36
     assert set(row["rel_err"]) == {
@@ -357,8 +363,12 @@ def test_mla_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
         for d, form in (("bwd", " form=fused"), ("fwd", " stats=rows"))}
     assert list(row["routers"]) == ["score=sigmoid bias=1 k=2 experts=8"]
     assert sum(row["grouped_matmuls"].values()) == 18
-    assert set(row["rel_err"]) == {"attn_o", "attn_dq", "attn_dk", "attn_dv"}
+    # (``parts_o``: the forward given q and k in two parts, on the device)
+    assert set(row["rel_err"]) == {"attn_o", "attn_dq", "attn_dk", "attn_dv",
+                                   "parts_o"}
     assert max(row["rel_err"].values()) < chip_smoke.KERNEL_REL_TOL
+    # (the cut config's one head does not pair: one a forward step too)
+    assert row["tile"] == "hb1 bq256 bk256"
 
 
 def test_mla_phase_fails_on_a_split_backward_call(phase_row, monkeypatch):
@@ -512,7 +522,9 @@ def test_dispatch_rows_name_the_bhtd_tile_and_the_keys_stay(telemetry,
         interp._AMP_ACTIVE.reset(tok)
     rows = {(r["labels"]["family"], r["labels"]["pass"]): r["labels"]["tile"]
             for r in monitor.snapshot()["pt_attention_dispatch_total"]["values"]}
-    assert rows == {("bhtd", "fwd"): "hb1 bq512 bk512",
+    # (a forward row carries the forward's own tile, bhtd_fwd_tile: two
+    # of the 16 heads a step; a backward row bhtd_tile's)
+    assert rows == {("bhtd", "fwd"): "hb2 bq512 bk512",
                     ("bhtd", "bwd"): "hb2 bq256 bk256",
                     ("bthd_small", "fwd"): ""}
     assert attention_ops.dispatch_counts() == {
@@ -520,7 +532,7 @@ def test_dispatch_rows_name_the_bhtd_tile_and_the_keys_stay(telemetry,
         "bhtd bwd b2 tq1024 tk1024 h2 dh64": 1,
         "bthd_small fwd b64 tq256 tk256 h8 dh64": 1}
     assert attention_ops.dispatch_counts(tiles=True) == {
-        "bhtd fwd b2 tq4096 tk4096 h16 dh128 [hb1 bq512 bk512]": 1,
+        "bhtd fwd b2 tq4096 tk4096 h16 dh128 [hb2 bq512 bk512]": 1,
         "bhtd bwd b2 tq1024 tk1024 h2 dh64 [hb2 bq256 bk256]": 1,
         "bthd_small fwd b64 tq256 tk256 h8 dh64": 1}
 
@@ -688,11 +700,11 @@ def test_bd_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
     device (here: the CPU) the kernels agree with the dense
     composition."""
     row = phase_row("bd")
-    shape = "b1 tq1024 tk1024 h8 kv1 dh128 [hb1 bq512 bk512]"
+    shape = "b1 tq1024 tk1024 h8 kv1 dh128"
     mask = "mask=block_diffusion block=4 band=skip"
     assert row["attention"] == {
-        f"bhtd fwd {shape} stats=rows {mask}": 2,
-        f"bhtd bwd {shape} form=fused {mask}": 2}
+        f"bhtd fwd {shape} [hb2 bq512 bk512] stats=rows {mask}": 2,
+        f"bhtd bwd {shape} [hb1 bq512 bk512] form=fused {mask}": 2}
     assert row["rotary_embeddings"] == {"kernel fwd bthd 128 norm=head": 2,
                                         "kernel bwd bthd 128 norm=head": 2}
     assert row["kernel_ms"] == {}               # (a trace needs the chip)
@@ -808,10 +820,11 @@ def test_keye_phase_holds_the_lowered_cell_to_its_dispatch_rows(phase_row):
         f"kernel select fwd {shape} k96 cq512 ck512": 2,
         f"kernel loss fwd {shape} k0 cq512 ck512": 2,
         f"xla loss bwd {shape} k0 cq512 ck512": 2}
-    call = "b1 tq1024 tk1024 h8 kv2 dh128 [hb1 bq512 bk512]"
+    call = "b1 tq1024 tk1024 h8 kv2 dh128"
     assert row["attention"] == {
-        f"bhtd fwd {call} stats=rows sel=operand": 2,
-        f"bhtd bwd {call} form=fused edge=256x256 sel=operand": 2}
+        f"bhtd fwd {call} [hb2 bq512 bk512] stats=rows sel=operand": 2,
+        f"bhtd bwd {call} [hb1 bq512 bk512] form=fused edge=256x256 "
+        "sel=operand": 2}
     assert row["rotary_embeddings"] == {
         "kernel fwd bthd 128 norm=head": 2, "kernel bwd bthd 128 norm=head": 2,
         "xla fwd bthd 64": 2, "xla bwd bthd 64": 2}
@@ -840,6 +853,27 @@ def test_keye_phase_fails_on_a_selection_that_runs_dense(phase_row,
     # and every chunk walked at the row's whole width
     fails("keye", "score columns", **dict(row, topk_columns={
         "causal": 3072, "walked": 4096}))
+
+
+@pytest.mark.parametrize("name", ["bd", "keye"])
+def test_phase_fails_on_a_forward_back_at_one_head_a_step(name, phase_row,
+                                                          monkeypatch):
+    """The forward rows of a lowered cell carry ``bhtd_fwd_tile``'s tile
+    (two query heads a step over their group's ONE K / V block): a row
+    at the backward's one head a step, what a silent fall back would
+    count, is refused; so is a backward row that left one head."""
+    from paddle_tpu.parallel import dsa_score
+
+    # (keye's rows name the indexer's kernels, which score_tile gives)
+    monkeypatch.setattr(dsa_score, "_INTERPRET", True)
+    row = phase_row(name)
+    fwd = {k: v for k, v in row["attention"].items() if " fwd " in k}
+    bwd = {k: v for k, v in row["attention"].items() if " bwd " in k}
+    assert fwd and all("[hb2 " in k for k in fwd)
+    fails(name, "bhtd fwd call on the tile hb2 bq512 bk512",
+          **dict(row, attention={**renamed(fwd, "[hb2", "[hb1"), **bwd}))
+    fails(name, "bhtd bwd call on the tile hb1 bq512 bk512",
+          **dict(row, attention={**fwd, **renamed(bwd, "[hb1", "[hb2")}))
 
 
 def test_xing4_phase_fails_on_a_mix_without_the_kernel(phase_row,

@@ -810,6 +810,197 @@ def test_every_cells_call_writes_its_statistics_as_rows(call):
     assert fa.bhtd_stats_form(None, t) is None
 
 
+# --- the forward's own tile (PR 74) -----------------------------------
+# Two query heads a grid step where the heads are on the grid: the two
+# heads of one key head's group over ONE fetched block of K, V and of a
+# selection's words; a key head a query head, two blocks of K and V. The
+# blocks are the backward's tile's; a head's arithmetic is the one-head
+# step's, so Out and Lse are that step's to the bit.
+
+# the decoder cells' forward calls (perf/configs): (query heads,
+# key/value heads, t, the whole head's width, dv, what else the tile
+# goes by) -> the query heads of a step
+_FWD_CALLS = {
+    "keye_sel": ((32, 4, 16384, 128, 128, dict(selected=True)), 2),
+    "qwen3next_d256": ((16, 2, 8192, 256, 256, {}), 2),
+    "lfm2moe_d64": ((32, 8, 8192, 64, 64, {}), 2),
+    "laguna_full": ((48, 8, 8192, 128, 128, {}), 2),
+    "laguna_w512": ((64, 8, 8192, 128, 128, {}), 2),
+    "sdar_bd": ((32, 4, 8192, 128, 128, dict(block_diffusion=4)), 2),
+    "phi4flash_pairs": ((20, 10, 4096, 64, 128, {}), 2),
+    "nemotron3nano": ((32, 2, 4096, 128, 128, {}), 2),
+    "olmoe": ((16, 16, 4096, 128, 128, {}), 2),
+    # (joyai, xing4, kimilinear: 32 x (128 | 64) over 128, ONE rotary
+    # key head)
+    "latent_parts": ((32, 32, 4096, 192, 128, dict(pe_group=32)), 2),
+    # 28 heads on 4: heads 6 and 7 read two key heads, so one a step
+    "smallthinker_g7": ((28, 4, 16384, 128, 128, {}), 1),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_FWD_CALLS))
+def test_the_forwards_own_tile_at_the_cells_calls(call):
+    (h, hk, t, dh, dv, kw), hq = _FWD_CALLS[call]
+    group = h // hk
+    tile = fa.bhtd_fwd_tile(h, t, t, dh=dh, group=group, dv=dv, **kw)
+    assert tile == (hq, 512, 512)
+    # the blocks are the backward's, whose tile stays one head a step
+    assert fa.bhtd_tile(h, t, t, dh=dh, group=group, dv=dv,
+                        block_diffusion=kw.get("block_diffusion")) == (
+        1, 512, 512)
+    # (K and V blocks a step: ONE where the step's heads share a key
+    # head, a head each at a key head a query head)
+    held = hq if group == 1 else 1
+    assert fa._kv_blocks(hq, group) == held
+    kept = fa._fwd_vmem_bytes(hq, held, 512, 512, dh, dv, 2,
+                              selected=kw.get("selected", False))
+    limit = fa._fwd_vmem_limit(hq, held, 512, 512, dh, dv, 2,
+                               selected=kw.get("selected", False))
+    # (a count within Mosaic's default of 16 MiB asks for nothing; one
+    # head a step always is: the parent's call)
+    assert kept <= fa._FWD_VMEM_CAP_BYTES
+    assert limit == (kept * 5 // 4 if kept * 5 // 4 > 16 * 2**20 else None)
+    assert fa._fwd_vmem_limit(1, 1, 512, 512, dh, dv, 2) is None
+
+
+def test_what_keeps_the_forward_at_one_head_a_step():
+    call = dict(dh=128, group=8, dv=128)
+    assert fa.bhtd_fwd_tile(32, 4096, 4096, **call) == (2, 512, 512)
+    # attention dropout: a block's mask is keyed by the step's head
+    # group, and the backward draws it again at one head a step
+    assert fa.bhtd_fwd_tile(16, 4096, 4096, dh=128, p_drop=0.1) == (
+        1, 512, 512)
+    assert fa.bhtd_fwd_tile(16, 4096, 4096, dh=128) == (2, 512, 512)
+    # an odd number of heads; an odd group; a rotary key head that an
+    # odd number of query heads share
+    assert fa.bhtd_fwd_tile(15, 4096, 4096, dh=128)[0] == 1
+    assert fa.bhtd_fwd_tile(12, 4096, 4096, dh=128, group=3)[0] == 1
+    assert fa.bhtd_fwd_tile(12, 4096, 4096, dh=192, dv=128,
+                            pe_group=3)[0] == 1
+    # blocks under the side the chip timed (a caller's, or a sequence
+    # that 512 does not divide: 768 takes blocks of 256)
+    assert fa.bhtd_fwd_tile(32, 4096, 4096, 256, 256, **call) == (
+        1, 256, 256)
+    assert fa.bhtd_fwd_tile(32, 768, 768, **call) == (1, 256, 256)
+    assert fa.bhtd_fwd_tile(12, 4096, 4096, dh=192, dv=128,
+                            pe_group=1)[0] == 2
+    # heads that share a step already (a short row) stay as they are,
+    # and no tile is no tile
+    assert fa.bhtd_fwd_tile(2, 256, 256, dh=64) == (2, 256, 256)
+    assert fa.bhtd_fwd_tile(8, 700, 700, dh=64) is None
+    # a float bias is counted, not refused: a block a head and row of it
+    # at float32 is 4 MB of a step's 13
+    assert fa.bhtd_fwd_tile(16, 4096, 4096, dh=128,
+                            bias=(1, 16, 4096, 4096)) == (2, 512, 512)
+    assert fa._fwd_vmem_bytes(2, 2, 512, 512, 128, 128, 2,
+                              bias=(1, 16, 4096, 4096)) \
+        - fa._fwd_vmem_bytes(2, 2, 512, 512, 128, 128, 2) == 4 * 2**20
+    # the cap is the forward's own: it passes two heads and refuses a
+    # whole group of seven a step (39 MB)
+    assert fa._fwd_vmem_bytes(7, 1, 512, 512, 128, 128, 2) \
+        > fa._FWD_VMEM_CAP_BYTES
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "_FWD_VMEM_CAP_BYTES", 8 * 2**20)
+        assert fa.bhtd_fwd_tile(32, 4096, 4096, **call) == (1, 512, 512)
+
+
+def _fwd_variant(variant):
+    """(operands of ``flash_attention_fwd``, the query heads its step
+    takes) of a variant of the kernel, at blocks of 128 in bf16."""
+    r = np.random.RandomState(len(variant))
+
+    def rand(*shape, s=1.0):
+        return jnp.asarray(r.randn(*shape) * s, jnp.bfloat16)
+
+    h, hk, t, dh, dv, hq = 4, 2, 512, 128, 128, 2
+    kw = dict(causal=True, q_block=128, k_block=128)
+    if variant == "window":
+        kw["window"] = 200
+    elif variant == "block_diffusion":
+        kw.update(causal=False, block_diffusion=32)
+    elif variant == "group7":   # (heads 6 and 7 read two key heads)
+        h, hk, hq = 14, 2, 1
+    elif variant == "heads_of_64":
+        dh = dv = 64
+    elif variant == "a_key_head_a_query_head":
+        hk = h
+    elif variant == "pad_bias":
+        kw["bias"] = _pad_bias(1, t, 37)
+    elif variant == "two_parts":
+        hk = h
+    args = dict(q=rand(1, h, t, dh, s=0.5), k=rand(1, hk, t, dh, s=0.5),
+                v=rand(1, hk, t, dv))
+    if variant == "two_parts":
+        args.update(q_pe=rand(1, h, t, 64, s=0.5),
+                    k_pe=rand(1, 1, t, 64, s=0.5))
+    if variant == "selection":
+        from test_dsa_ops import attention_case
+
+        *_, sel, live = attention_case(0, dead="a block")
+        assert not np.asarray(live)[0, 2, 0]    # a dead block in the table
+        args.update(selected=sel, live=live)
+    return args, kw, hq
+
+
+@pytest.mark.parametrize("variant", [
+    "plain", "window", "block_diffusion", "selection", "two_parts",
+    "group7", "heads_of_64", "a_key_head_a_query_head", "pad_bias"])
+def test_forward_at_its_own_tile_is_one_head_a_step_to_the_bit(variant,
+                                                               monkeypatch):
+    # (a short row's heads onto the grid, as a long row's are: one
+    # head's K and V blocks of 128 rows fit the cap, two do not; and
+    # two heads a step at the blocks of 128 the interpreter can afford)
+    monkeypatch.setattr(fa, "_KV_VMEM_BYTES", 12 * 128 * 320)
+    monkeypatch.setattr(fa, "_FWD_PAIR_BLOCK", 128)
+    args, kw, hq = _fwd_variant(variant)
+
+    def tile_and_results():
+        ((name, _, eqn),) = _pallas_calls(
+            lambda: fa.flash_attention_fwd(**args, **kw))
+        assert name == "attn.bhtd.fwd"
+        return (eqn.params["grid_mapping"].grid,
+                fa.flash_attention_fwd(**args, **kw))
+
+    h = args["q"].shape[1]
+    grid, (out, lse) = tile_and_results()
+    assert grid[:2] == (1, h // hq)
+    monkeypatch.setattr(fa, "_FWD_HEADS", 1)
+    grid, (want, want_lse) = tile_and_results()
+    assert grid[:2] == (1, h)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(lse, want_lse)
+
+
+def test_a_call_with_dropout_keeps_one_head_a_step_and_its_masks_key():
+    """The forward of a call with attention dropout lowers one head a
+    step over the grid (b, h, nq, nk), the grid whose head axis
+    ``_seed_step`` mixes into a block's key, and the backward (the pair)
+    walks the same head axis: both draw a block's mask from (seed, row,
+    head, q-block, k-block)."""
+    q, k, v = (jnp.zeros((1, 16, 1024, 128), jnp.bfloat16),) * 3
+    seed = jnp.int32(3)
+
+    def both(q, k, v):
+        out, lse = fa.flash_attention_fwd(q, k, v, None, seed, p_drop=0.1,
+                                          causal=True)
+        return fa.flash_attention_bwd(q, k, v, None, seed, out, lse, out,
+                                      p_drop=0.1, causal=True)
+
+    assert fa.bhtd_fwd_tile(16, 1024, 1024, dh=128, p_drop=0.1) == (
+        1, 512, 512)
+    grids = {name: eqn.params["grid_mapping"].grid
+             for name, _, eqn in _pallas_calls(both, q, k, v)}
+    assert grids == {"attn.bhtd.fwd": (1, 16, 2, 2),
+                     "attn.bhtd.bwd_dq": (1, 16, 2, 2),
+                     "attn.bhtd.bwd_dkv": (1, 16, 2, 2)}
+    # without dropout the same call's forward takes two heads a step
+    ((_, _, eqn),) = _pallas_calls(
+        lambda q, k, v: fa.flash_attention_fwd(q, k, v, causal=True),
+        q, k, v)
+    assert eqn.params["grid_mapping"].grid == (1, 8, 2, 2)
+
+
 # --- BTHD-small: the score block is passed over once (PR 49) ---
 # The scale on q where it is a power of two, 1 / (l * p_keep) behind P.V,
 # dropout as one select, delta made in the backward kernel. The TPU's

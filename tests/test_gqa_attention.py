@@ -140,6 +140,8 @@ def test_sdpa_op_names_the_key_value_heads(interpreted):
         interp._AMP_ACTIVE.reset(tok)
         flags.set_flags({"telemetry": False})
         monitor.reset()
+    # (blocks of 256: under the side from which a forward step takes
+    # two heads, fa._FWD_PAIR_BLOCK)
     shape = "b1 tq256 tk256 h4 kv2 dh128 [hb1 bq256 bk256]"
     assert counts == {f"bhtd fwd {shape}": 1, f"bhtd bwd {shape}": 1}
     assert grads["GRAD::K"][0].shape == k.shape
