@@ -26,8 +26,12 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def one_side(cell_name, seed, steps):
-    """This directory's checkout: the cell's losses as float32 hex."""
+def one_side(cell_name, seed, steps, overrides=(), seq_len=None):
+    """This directory's checkout: the cell's losses as float32 hex.
+    ``overrides`` ("key=value": an integer where it reads as one, else
+    the string) are laid over the configuration file, ``seq_len`` over
+    the cell's row (``--set recompute=none --seq-len 4096``: a marked
+    cell's step without its marks, at a row where both fit)."""
     sys.path[0] = os.getcwd()
     import jax
     import numpy as np
@@ -38,6 +42,10 @@ def one_side(cell_name, seed, steps):
 
     cell = harness.load_json("perf", "workloads", f"{cell_name}.json")
     cfg = harness.load_json("perf", "configs", f"{cell['config']}.json")
+    for key, value in (kv.split("=", 1) for kv in overrides):
+        cfg[key] = int(value) if value.lstrip("-").isdigit() else value
+    if seq_len:
+        cell["traffic"].update(seq_len=seq_len, real_len=[seq_len, seq_len])
     harness.require_tpu(cell["chips"])
     jax_cache.configure()
     fam = models.family(cfg)
@@ -60,16 +68,21 @@ def main():
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--seed", type=int, default=2147491927)
     ap.add_argument("--one-side", action="store_true")
+    ap.add_argument("--set", nargs="*", default=[], metavar="KEY=VALUE")
+    ap.add_argument("--seq-len", type=int, default=None)
     args = ap.parse_args()
     if args.one_side:
-        print(json.dumps(one_side(args.cells[0], args.seed, args.steps)))
+        print(json.dumps(one_side(args.cells[0], args.seed, args.steps,
+                                  args.set, args.seq_len)))
         return 0
 
     def side(directory, cell):
         out = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--one-side",
              "--cells", cell, "--steps", str(args.steps), "--seed",
-             str(args.seed)], cwd=directory, capture_output=True, text=True)
+             str(args.seed), "--set", *args.set]
+            + (["--seq-len", str(args.seq_len)] if args.seq_len else []),
+            cwd=directory, capture_output=True, text=True)
         if out.returncode:
             print(out.stderr[-2000:], file=sys.stderr)
             raise SystemExit(f"{directory} {cell}: exit {out.returncode}")

@@ -4,8 +4,14 @@ The reference hand-writes a C++ grad kernel and a GradOpDescMaker per op
 (reference: framework/grad_op_desc_maker.h; e.g. operators/mul_op.cc). Here a
 ``<type>_grad`` kernel is derived mechanically from the forward JAX kernel
 with ``jax.vjp``: the grad op re-traces the forward inside the same XLA
-computation, XLA CSEs the duplicated forward work, and rematerialization
-policy is left to the compiler (HBM-friendly; see SURVEY.md section 7).
+computation and XLA CSEs the duplicated forward work. WHAT the backward
+pass keeps of the forward pass is the Program's to say: between two
+checkpoints a builder marked (``layers.checkpoint``),
+``backward.append_backward`` appends the forward ops again behind a
+``recompute_barrier`` and the grad ops' re-traces merge with that
+replay, not with the first run; a Program without marks leaves it to
+the compiler, which only makes values again once it is at its limit
+(SURVEY.md section 7; PERF.md section 6, PRs 71 and 75).
 
 Grad op desc convention (produced by backward.append_backward):
 - inputs:  every forward input slot (same slot names), every forward output

@@ -590,6 +590,10 @@ class Program:
         # updates under role "opt". A program without an optimizer (an
         # eval clone) never runs them.
         self._step_updates: List[Dict[str, Any]] = []
+        # the variables a builder marked for recomputation (checkpoint
+        # below): names, in the order they were marked.
+        # backward.append_backward replays the ops between two of them
+        self._checkpoints: List[str] = []
         # version-keyed def-use index cache (analysis.DefUseIndex per
         # block); every _bump_version invalidates it implicitly
         self._def_use_cache: Optional[tuple] = None
@@ -748,6 +752,7 @@ class Program:
                             op.attrs["is_test"] = True
             else:   # a training clone keeps what its optimizer will append
                 p._step_updates = [dict(u) for u in self._step_updates]
+                p._checkpoints = list(self._checkpoints)
             p._bump_version()
             return p
 
@@ -823,6 +828,38 @@ def name_scope(prefix: str):
         yield
     finally:
         _name_scope_.pop()
+
+
+def checkpoint(var: "Variable") -> "Variable":
+    """Mark ``var`` as a recomputation checkpoint of its Program (Fluid
+    1.6's ``checkpoints``): ``append_backward`` keeps it for the backward
+    pass and makes the ops between two marks again there instead of
+    keeping what they made (backward.py's module docstring). The mark
+    travels on the Program, so ``Optimizer.minimize(loss)`` needs no
+    argument. Returns ``var``. A variable of a sub-block (a ``while`` or
+    ``StaticRNN`` body) is refused: a body's values live an iteration."""
+    checkpoint_names(var.block.program, [var], mark=True)
+    return var
+
+
+def checkpoint_names(program: "Program", more=None, mark=False) -> List[str]:
+    """The Program's marks and ``more`` (variables or names) as names,
+    each once; ``mark`` records ``more`` on the Program."""
+    names = list(program._checkpoints)
+    block = program.global_block()
+    for v in more or ():
+        name = v.name if isinstance(v, Variable) else str(v)
+        if name not in block.vars and any(
+                name in b.vars for b in program.blocks[1:]):
+            raise ValueError(
+                f"checkpoint '{name}' lives in a sub-block (a while or "
+                f"StaticRNN body): recomputation is by segments of the "
+                f"global block; mark the loop's input or output instead")
+        if name not in names:
+            names.append(name)
+            if mark:
+                program._checkpoints.append(name)
+    return names
 
 
 @contextlib.contextmanager

@@ -5,13 +5,18 @@ from __future__ import annotations
 
 import numpy as np
 
-from paddle_tpu.framework import Variable, convert_np_dtype_to_dtype_
+from paddle_tpu.framework import (  # noqa: F401  (checkpoint: re-exported)
+    Variable,
+    checkpoint,
+    convert_np_dtype_to_dtype_,
+)
 from paddle_tpu.layer_helper import LayerHelper
 
 __all__ = [
     "create_tensor", "create_parameter", "create_global_var", "fill_constant",
     "assign", "zeros", "ones", "zeros_like", "ones_like", "range_",
     "linspace", "uniform_random", "gaussian_random", "shape", "slice",
+    "checkpoint",
 ]
 
 

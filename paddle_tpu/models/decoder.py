@@ -4,9 +4,10 @@ class, its per-layer decisions, its mixers, its ``topk_moe`` call and its
 auxiliary-loss policy; it calls this module for the parameters' attribute,
 the plain RMSNorm and the bias-free projection, the two feeds, the
 embedding, the vocabulary head with its loss, the last positions' logits,
-the batch of packed tokens, and the one mixer two families write the same
-way, multi-head latent attention (``latent_attention``, from its sizes
-alone):
+the batch of packed tokens, and the mixers two families write the same
+way, multi-head latent attention (``latent_attention``), the Mamba-2
+mixer (``mamba2_mixer``) and grouped-query attention without positions
+(``nope_attention``), each from its sizes alone:
 
     ids, lbl = decoder.token_feeds()
     x = decoder.embed(ids, vocab, width, "<family>_tok_emb.w")
@@ -31,7 +32,7 @@ import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import NormalInitializer
+from paddle_tpu.initializer import NormalInitializer, UniformInitializer
 from paddle_tpu.param_attr import ParamAttr
 
 _END = 2 ** 31 - 1   # a slice's "to the end"
@@ -191,6 +192,95 @@ def latent_attention(x, p: str, *, heads: int, nope: int, rope: int, dv: int,
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, h * dv])
         return linear(ctx, hidden, f"{p}_attn_out_rowp.w")
+
+
+def nope_attention(u, p: str, *, heads: int, kv_heads: int, head_dim: int,
+                   hidden: int, scale: float):
+    """Grouped-query attention of the normalised input u [b, t, d] with NO
+    positional embedding (Nemotron-H's and Granite-4.0-H's: the Mamba-2
+    layers carry position), parameters ``<p>_attn_*`` (Wq | Wk | Wv one
+    matrix), inside the caller's ``attn`` scope:
+
+        q = u Wq (``heads`` of ``head_dim``), k, v = u Wk, u Wv (``kv_heads``)
+        o = causal softmax(``scale`` * q k^T) v;  out = o Wo
+
+    Scopes under the caller's: ``qkv``, ``core`` (the sdpa op), ``out``."""
+    h, hk, dh = heads, kv_heads, head_dim
+
+    def heads_first(z, n):   # [b, t, n dh] -> [b, n, t, dh]
+        return layers.transpose(layers.reshape(z, [0, 0, n, dh]),
+                                [0, 2, 1, 3])
+
+    with fluid.name_scope("qkv"):
+        qkv = linear(u, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
+        q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
+        q, k, v = heads_first(q, h), heads_first(k, hk), heads_first(v, hk)
+    with fluid.name_scope("core"):
+        # K and V keep their hk heads: the kernels read head q // (h / hk)
+        ctx = layers.scaled_dot_product_attention(
+            q, k, v, scale, name=f"{p}_attn_sdpa")
+    with fluid.name_scope("out"):
+        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                             [0, 0, h * dh])
+        return linear(ctx, hidden, f"{p}_attn_out_rowp.w")
+
+
+def mamba2_mixer(u, p: str, *, heads: int, head_dim: int, groups: int,
+                 state: int, conv_kernel: int, chunk: int, eps: float,
+                 hidden: int, dt_bias_init, a_log_init=None):
+    """The Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060; as HF's
+    ``modeling_nemotron_h.py`` and ``modeling_granitemoehybrid.py`` write
+    it) of the normalised input u [b, t, d], parameters ``<p>_mamba_*``,
+    inside the caller's ``mamba2`` scope:
+
+        [z | xBC | dt] = u W_in        # H p + (H p + 2 G n) + H, no bias
+        xBC = silu(conv(xBC) + b)      # depthwise, causal, ``conv_kernel`` taps
+        [xs | B | C] = xBC             # H p + G n + G n
+        y = mamba2_scan(xs, dt, B, C)  # head h reads group h // (H / G)
+        y = norm_groups(y * silu(z)) * g   # the gate FIRST, statistics
+                                           # over each of G groups of H p / G
+        out = y W_out
+
+    ``groups`` is the B / C groups AND the gated norm's (Nemotron-3 8,
+    Granite-4.0-H 1); ``dt_bias_init`` / ``a_log_init`` the initializers
+    of the step size's bias and of ``A_log`` (None: log(1 .. H)).
+
+    Scopes under the caller's: ``proj``, ``conv``, ``chunks`` (the scan
+    op), ``gate_norm``, ``out``."""
+    e = heads * head_dim
+    gn = groups * state
+    with fluid.name_scope("proj"):
+        z, xbc, dt = layers.split(
+            linear(u, 2 * e + 2 * gn + heads, f"{p}_mamba_in_colp.w"),
+            [e, e + 2 * gn, heads], dim=-1)
+    with fluid.name_scope("conv"):
+        # torch's Conv1d default (HF's _init_weights re-draws Linear and
+        # Embedding only): uniform(+-1 / sqrt(taps)) for the filter and
+        # its bias, as the Mamba-1 builder's (models/phi4flash.py)
+        bound = conv_kernel ** -0.5
+        xbc = layers.causal_conv1d(
+            xbc, taps=conv_kernel, act="silu",
+            param_attr=ParamAttr(
+                name=f"{p}_mamba_conv.w",
+                initializer=UniformInitializer(-bound, bound)),
+            bias_attr=ParamAttr(
+                name=f"{p}_mamba_conv.b",
+                initializer=UniformInitializer(-bound, bound)))
+        xs, b, c = layers.split(xbc, [e, gn, gn], dim=-1)
+    with fluid.name_scope("chunks"):
+        y = layers.mamba2_scan(
+            xs, dt, b, c, heads=heads, groups=groups, chunk=chunk,
+            a_log_attr=ParamAttr(name=f"{p}_mamba_a_log",
+                                 initializer=a_log_init),
+            d_attr=ParamAttr(name=f"{p}_mamba_d"),
+            dt_bias_attr=ParamAttr(name=f"{p}_mamba_dt.b",
+                                   initializer=dt_bias_init))
+    with fluid.name_scope("gate_norm"):
+        y = layers.gated_rms_norm(
+            y, z, epsilon=eps, gate_first=True, group_size=e // groups,
+            param_attr=ParamAttr(name=f"{p}_mamba_norm.scale"))
+    with fluid.name_scope("out"):
+        return linear(y, hidden, f"{p}_mamba_out_rowp.w")
 
 
 def make_batch(cfg, batch: int, seq_len: int,

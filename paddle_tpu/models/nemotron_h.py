@@ -58,11 +58,9 @@ from typing import Optional, Tuple
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.initializer import UniformInitializer
 from paddle_tpu.models import decoder
 from paddle_tpu.models.decoder import make_batch  # noqa: F401
 from paddle_tpu.models.phi4flash import DtBiasInitializer
-from paddle_tpu.param_attr import ParamAttr
 
 # logits of the last positions a build offers (model["last_logits"]):
 # the second check of perf/reference/nemotronh.py. One whole chunk of
@@ -168,68 +166,22 @@ def nemotron_3_nano_30b_a3b() -> NemotronHConfig:
 
 def _mamba2(u, cfg: NemotronHConfig, p: str):
     """The Mamba-2 mixer of the normalised input u [b, t, d]."""
-    e, heads = cfg.mamba_d_inner, cfg.mamba_num_heads
-    gn = cfg.n_groups * cfg.ssm_state_size
-    with fluid.name_scope("proj"):
-        z, xbc, dt = layers.split(
-            decoder.linear(u, 2 * e + 2 * gn + heads,
-                           f"{p}_mamba_in_colp.w"),
-            [e, e + 2 * gn, heads], dim=-1)
-    with fluid.name_scope("conv"):
-        # torch's Conv1d default (HF's _init_weights re-draws Linear and
-        # Embedding only): uniform(+-1 / sqrt(taps)) for the filter and
-        # its bias, as the Mamba-1 builder's (models/phi4flash.py)
-        bound = cfg.conv_kernel ** -0.5
-        xbc = layers.causal_conv1d(
-            xbc, taps=cfg.conv_kernel, act="silu",
-            param_attr=ParamAttr(
-                name=f"{p}_mamba_conv.w",
-                initializer=UniformInitializer(-bound, bound)),
-            bias_attr=ParamAttr(
-                name=f"{p}_mamba_conv.b",
-                initializer=UniformInitializer(-bound, bound)))
-        xs, b, c = layers.split(xbc, [e, gn, gn], dim=-1)
-    with fluid.name_scope("chunks"):
-        y = layers.mamba2_scan(
-            xs, dt, b, c, heads=heads, groups=cfg.n_groups,
-            chunk=cfg.chunk_size,
-            a_log_attr=ParamAttr(name=f"{p}_mamba_a_log"),
-            d_attr=ParamAttr(name=f"{p}_mamba_d"),
-            dt_bias_attr=ParamAttr(
-                name=f"{p}_mamba_dt.b",
-                initializer=DtBiasInitializer(cfg.time_step_min,
-                                              cfg.time_step_max)))
-    with fluid.name_scope("gate_norm"):
-        y = layers.gated_rms_norm(
-            y, z, epsilon=cfg.layer_norm_epsilon, gate_first=True,
-            group_size=e // cfg.n_groups,
-            param_attr=ParamAttr(name=f"{p}_mamba_norm.scale"))
-    with fluid.name_scope("out"):
-        return decoder.linear(y, cfg.hidden_size, f"{p}_mamba_out_rowp.w")
+    return decoder.mamba2_mixer(
+        u, p, heads=cfg.mamba_num_heads, head_dim=cfg.mamba_head_dim,
+        groups=cfg.n_groups, state=cfg.ssm_state_size,
+        conv_kernel=cfg.conv_kernel, chunk=cfg.chunk_size,
+        eps=cfg.layer_norm_epsilon, hidden=cfg.hidden_size,
+        dt_bias_init=DtBiasInitializer(cfg.time_step_min,
+                                       cfg.time_step_max))
 
 
 def _attention(u, cfg: NemotronHConfig, p: str):
     """Grouped-query attention of the normalised input u [b, t, d], no
     positional embedding."""
-    h, hk, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                 cfg.head_dim)
-
-    def heads_first(z, n):   # [b, t, n dh] -> [b, n, t, dh]
-        return layers.transpose(layers.reshape(z, [0, 0, n, dh]),
-                                [0, 2, 1, 3])
-
-    with fluid.name_scope("qkv"):
-        qkv = decoder.linear(u, (h + 2 * hk) * dh, f"{p}_attn_qkv_colp.w")
-        q, k, v = layers.split(qkv, [h * dh, hk * dh, hk * dh], dim=-1)
-        q, k, v = heads_first(q, h), heads_first(k, hk), heads_first(v, hk)
-    with fluid.name_scope("core"):
-        # K and V keep their hk heads: the kernels read head q // (h / hk)
-        ctx = layers.scaled_dot_product_attention(
-            q, k, v, 1.0 / math.sqrt(dh), name=f"{p}_attn_sdpa")
-    with fluid.name_scope("out"):
-        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                             [0, 0, h * dh])
-        return decoder.linear(ctx, cfg.hidden_size, f"{p}_attn_out_rowp.w")
+    return decoder.nope_attention(
+        u, p, heads=cfg.num_attention_heads,
+        kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+        hidden=cfg.hidden_size, scale=1.0 / math.sqrt(cfg.head_dim))
 
 
 def _moe(u, cfg: NemotronHConfig, p: str):

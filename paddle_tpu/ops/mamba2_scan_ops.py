@@ -36,8 +36,8 @@ chunks in reverse with the state's cotangent and makes a chunk again.
 Three lowerings, chosen per call by ``parallel/mamba2_scan.mamba2_tile``
 from the call's own shapes (never by a flag): the ``mamba2.chunk.fwd`` /
 ``mamba2.chunk.bwd`` Pallas kernels where it gives a tile (a bf16
-stream, heads of 64 in even groups, a state of 128, chunk 128, a TPU
-backend, no mesh), XLA ops everywhere else (``_chunk_fn`` below under
+stream, heads of 64 in even groups of any size, a state of 128, chunk
+128, a TPU backend, no mesh), XLA ops everywhere else (``_chunk_fn`` below under
 ``lax.scan``, ``jax.vjp`` of it a chunk at a time backward): every CPU
 run, a float32 stream, other sizes, a program under a mesh.
 ``impl="recurrent"`` is the recurrence position by position (one
@@ -65,9 +65,12 @@ _M_DISPATCH = _monitor.counter(
     "pt_mamba2_scan_dispatch_total",
     "mamba2_scan calls lowered, by pass (fwd, bwd), shape (batch, "
     "positions, heads, a head's features, groups, state), chunk (the "
-    "positions between two saved states; 1 for the recurrent form) and "
+    "positions between two saved states; 1 for the recurrent form), "
     "impl (kernel: a mamba2.chunk.* Pallas kernel; chunked: a scan over "
-    "chunks as XLA ops; recurrent: one scan over all positions)")
+    "chunks as XLA ops; recurrent: one scan over all positions) and tile "
+    "(a kernel's grid step, hb<heads of a head block> c<chunks>: a head "
+    "block is a whole group or, of a group wider than a step, a part; "
+    "empty for the XLA forms)")
 
 
 def _x(ins, slot):
@@ -81,7 +84,7 @@ def _sizes(x, dt, b, groups):
     return heads, x.shape[-1] // heads, b.shape[-1] // groups
 
 
-def _note_dispatch(direction, x, dt, b, groups, chunk, impl):
+def _note_dispatch(direction, x, dt, b, groups, chunk, impl, tile=None):
     # off with telemetry; build-time shape inference is not a lowering
     from paddle_tpu.core import interp
 
@@ -91,7 +94,8 @@ def _note_dispatch(direction, x, dt, b, groups, chunk, impl):
     _M_DISPATCH.inc(labels={
         "pass": direction,
         "shape": f"b{x.shape[0]} t{x.shape[1]} h{heads} p{p} g{groups} n{n}",
-        "chunk": str(chunk), "impl": impl})
+        "chunk": str(chunk), "impl": impl,
+        "tile": f"hb{2 * tile[0]} c{tile[1]}" if tile else ""})
 
 
 def dispatch_counts():
@@ -232,7 +236,7 @@ def _mamba2_scan(ins, attrs):
                                               dt_bias, groups)],
                 "States": [jnp.zeros((1,), _F32)]}
     if tile := _kernel_tile(x, dt, b, c, groups, chunk):
-        _note_dispatch("fwd", x, dt, b, groups, chunk, "kernel")
+        _note_dispatch("fwd", x, dt, b, groups, chunk, "kernel", tile)
         y, states = _kernels.mamba2_scan_fwd(
             x, *step_sizes(dt, a_log, dt_bias), b, c, d, tile)
         return {"Out": [y], "States": [states]}
@@ -265,7 +269,7 @@ def _mamba2_scan_grad(ins, attrs):
             lambda *a: recurrent_mamba2_scan(*a, groups), *args)
         grads = vjp(dy.astype(x.dtype))
     elif tile := _kernel_tile(x, dt, b, c, groups, chunk):
-        _note_dispatch("bwd", x, dt, b, groups, chunk, "kernel")
+        _note_dispatch("bwd", x, dt, b, groups, chunk, "kernel", tile)
         (step, a), vjp = jax.vjp(step_sizes, dt, a_log, dt_bias)
         dx, db, dc, dstep, da, dd = _kernels.mamba2_scan_bwd(
             x, step, a, b, c, d, _x(ins, "States"), dy, tile)

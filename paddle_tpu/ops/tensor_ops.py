@@ -77,6 +77,15 @@ def _assign(ins, attrs):
     return {"Out": [_x(ins)]}
 
 
+@register_op("recompute_barrier", no_grad=True)
+def _recompute_barrier(ins, attrs):
+    """Out[i] = X[i], all behind ONE ``optimization_barrier``: what a
+    replayed segment reads (its checkpoint, the feeds) and the gradients
+    that arrive at its end (backward.py). XLA merges no op across it and
+    starts nothing that reads Out before every X is there."""
+    return {"Out": list(jax.lax.optimization_barrier(tuple(ins["X"])))}
+
+
 @register_op("assign_value", no_grad=True)
 def _assign_value(ins, attrs):
     shape = tuple(attrs["shape"])

@@ -387,13 +387,19 @@ _BWD_VMEM_CAP_BYTES = 64 * 2**20
 def _bwd_vmem_bytes(tq, tk, dh, dv, group, bq, bk, itemsize):
     """What ``attn.bhtd.bwd`` keeps in VMEM at this call: the float32
     accumulators (dq's resident rows; dk's and dv's, or a block of each
-    where no group shares them), the gradients' output blocks and the
+    where no group shares them; counted in whole lane tiles: a float32
+    row under 128 features takes 128), the gradients' output blocks and the
     operands' blocks, double-buffered, and eight score-sized float32
     temps."""
     rk = tk if group > 1 else bk
     grads = tq * dh + rk * (dh + dv)
+    # (a float32 row under 128 features still takes a lane tile: heads
+    # of 64 x 16,384 rows, Granite-4.0-H's, allocate 49 MB where the
+    # count by features says 32)
+    lanes = lambda d: -(-d // 128) * 128
+    accs = tq * lanes(dh) + rk * (lanes(dh) + lanes(dv))
     blocks = bq * (dh + dv) + bk * (dh + dv)
-    return (4 * grads + 2 * itemsize * (grads + blocks) + 2 * 4 * 2 * bq
+    return (4 * accs + 2 * itemsize * (grads + blocks) + 2 * 4 * 2 * bq
             + 8 * 4 * bq * bk)
 
 

@@ -4,9 +4,12 @@ mathematics and the precision contract are ops/mamba2_scan_ops.py's
 module docstring; this is the same chunkwise form, chunk 128).
 
 ``mamba2.chunk.fwd`` and ``mamba2.chunk.bwd``, one call a pass. A grid
-step works on one GROUP of heads (the heads that share B and C) and
-``chunks`` chunks of the sequence; the grid is (batch, groups, chunk
-blocks) with the last axis ``"arbitrary"``: the state of each head is a
+step works on one HEAD BLOCK (heads that share B and C: a whole group
+where it fits the VMEM cap, Nemotron-3's 8 heads; ``_BLOCK_PAIRS`` pairs
+of a wider group, Granite-4.0-H's ONE group of 64, which its head blocks
+walk) and ``chunks`` chunks of the sequence; the grid is (batch, head
+blocks, chunk blocks) with the last axis ``"arbitrary"``: the state of
+each head is a
 float32 VMEM scratch that lives from chunk to chunk and block to block
 and reaches HBM only as the ``States`` the backward pass reads (the
 state each chunk STARTS from; nothing of size t x heads x 64 x 128).
@@ -20,7 +23,10 @@ heads side by side), a pair's two states lie side by side as
 [n, 2 x 64] float32, and a per-head factor of a row (dt, a decay) is a
 ``where`` over the lanes' halves. A chunk and pair is then, on the MXU:
 
-- ``C B^T`` [128, 128], ONCE for the group's heads (they share B and C);
+- ``C B^T`` [128, 128], ONCE for the head block's heads (they share B
+  and C; a group wider than a block makes it once a BLOCK: one
+  [128, 128, 128] product beside the block's twelve, about 8% more MXU
+  work than sharing it over all 64 heads of a one-group layer);
 - each head's masked decay matrix times it, times the pair's ``x dt``
   [128, 128] (full width: the other head's half of the product is
   dropped, which costs the v5e's 128-wide MXU nothing);
@@ -28,8 +34,10 @@ heads side by side), a pair's two states lie side by side as
 - ``B^T`` times the pair's ``x dt`` decayed to the chunk's end, into
   the states.
 
-Backward the transposes of those, and the group's ``dC`` and ``dB``
-from ONE ``d(C B^T)`` summed over its heads in VMEM.
+Backward the transposes of those, and the head block's ``dC`` and
+``dB`` from ONE ``d(C B^T)`` summed over its heads in VMEM; where a
+group is several head blocks, each writes a float32 partial and one XLA
+sum over the blocks makes the group's.
 
 float32: dt, the log decays, their running sums and exps, the decay
 matrix, the state and its cotangent, every sum over a row. bf16
@@ -68,6 +76,9 @@ _LANES = 128
 # Chunks a grid step: 8 chunks are 1024 rows of x, B, C a block, and 8
 # rows of dt and the decay [.., chunks, 128] are one float32 sublane tile.
 _STEP_CHUNKS = 8
+# The pairs of heads a step takes of a group that is too wide for the cap
+# whole (the size Nemotron-3's group of 8 heads has).
+_BLOCK_PAIRS = 4
 # What a call's blocks and scratch may take of the v5e's 128 MiB of VMEM
 # (the calls raise Mosaic's scoped limit to what they need, _vmem_limit).
 _VMEM_CAP_BYTES = 48 * 2**20
@@ -87,44 +98,48 @@ def _under_mesh() -> bool:
     return interp.spmd_ctx() is not None
 
 
-def _vmem_bytes(pairs, chunks):
+def _vmem_bytes(pairs, chunks, partials=False):
     """What one grid step of the backward kernel (the larger) keeps in
-    VMEM: its blocks double-buffered (x, dy, dx: bf16 rows of a group's
-    lanes; B, C, dB, dC; the states; dt, the decay and their gradients
-    padded to a sublane tile; D and dD) and the scratch (the states'
-    cotangent)."""
+    VMEM: its blocks double-buffered (x, dy, dx: bf16 rows of a head
+    block's lanes; B, C, dB, dC; the states; dt, the decay and their
+    gradients padded to a sublane tile; D and dD) and the scratch (the
+    states' cotangent). ``partials``: dB and dC leave as float32 (a
+    group of several head blocks)."""
     rows = chunks * CHUNK
     blocks = (3 * rows * pairs * _LANES * 2 + 4 * rows * STATE * 2
               + chunks * pairs * STATE * _LANES * 4
               + 4 * 2 * pairs * max(chunks, 8) * CHUNK * 4
               + 2 * 8 * pairs * _LANES * 4)
+    if partials:
+        blocks += 2 * rows * STATE * 2
     return 2 * blocks + pairs * STATE * _LANES * 4
 
 
-def _vmem_limit(pairs, chunks):
+def _vmem_limit(pairs, chunks, partials=False):
     """Mosaic's scoped limit for a call at this tile: the blocks and the
     scratch, and as much again for the values of a loop body, not under
     its default of 16 MiB."""
-    return max(16 * 2**20, 2 * _vmem_bytes(pairs, chunks))
+    return max(16 * 2**20, 2 * _vmem_bytes(pairs, chunks, partials))
 
 
 def mamba2_tile(t, heads, groups, head_dim, state, chunk, dtype,
                 backend=None, on_mesh=None):
-    """-> (pairs, chunks): the pairs of heads (one group's) and the
+    """-> (pairs, chunks): the pairs of heads (a head block's) and the
     chunks of one grid step of ``mamba2.chunk.*``, or None where the call
     runs as the chunked XLA form: no TPU backend (``backend``: None for
     this process's, with the interpreter counting as one), operands that
     are not bf16, a program under a mesh (a Mosaic call is not
     auto-partitioned), heads other than 64 wide or a state other than
     128 (a pair of heads and a state are a lane tile each), a chunk
-    other than the 128 the kernels are written for, groups that do not
-    divide the heads into an even number each, or a group too large for
-    the VMEM cap.
+    other than the 128 the kernels are written for, or groups that do
+    not divide the heads into an even number each.
 
     The tile follows the shape, not a flag: a group's heads in a step
     (C B^T is theirs together, and dB and dC are summed over them in
-    VMEM), 8 chunks a step, or all of a sequence that has fewer (it is
-    padded to a multiple)."""
+    VMEM) where that fits the VMEM cap, else the largest head block of
+    at most ``_BLOCK_PAIRS`` pairs that divides the group; 8 chunks a
+    step, or all of a sequence that has fewer (it is padded to a
+    multiple)."""
     on_tpu = kernels_enabled() if backend is None else backend == "tpu"
     if on_mesh is None:
         on_mesh = _under_mesh()
@@ -136,7 +151,7 @@ def mamba2_tile(t, heads, groups, head_dim, state, chunk, dtype,
     pairs = heads // groups // 2
     chunks = min(_STEP_CHUNKS, -(-t // CHUNK))
     if _vmem_bytes(pairs, chunks) > _VMEM_CAP_BYTES:
-        return None
+        pairs = max(p for p in range(1, _BLOCK_PAIRS + 1) if pairs % p == 0)
     return pairs, chunks
 
 
@@ -292,27 +307,42 @@ def _operands(x, dt, a, bm, cm, tile):
             dt4, ac4), n, n_pad
 
 
-def _specs(pairs, chunks, blk):
+def _group_of(per_group):
+    """A head block's group: itself where a block is a whole group."""
+    return (lambda g: g) if per_group == 1 else (lambda g: g // per_group)
+
+
+def _specs(pairs, chunks, blk, per_group):
     """BlockSpecs of (x-like [b, t, heads * 64], B or C [b, t, groups *
-    128], a scalar's rows [b, heads, n, C], D-like [1 or b, 1, heads *
-    64], the states [n, b, pairs of all heads, 128, 128]) for a grid
-    (batch, group, chunk block) whose block index along the sequence is
-    ``blk(c)``."""
+    128], a scalar's rows [b, heads, n, C], the states [n, b, pairs of
+    all heads, 128, 128]) for a grid (batch, head block, chunk block)
+    whose block index along the sequence is ``blk(c)``; ``per_group``
+    head blocks read one group's B and C."""
     rows = chunks * CHUNK
+    grp = _group_of(per_group)
     return (pl.BlockSpec((None, rows, pairs * _LANES),
                          lambda i, g, c: (i, blk(c), g)),
-            pl.BlockSpec((None, rows, STATE), lambda i, g, c: (i, blk(c), g)),
+            pl.BlockSpec((None, rows, STATE),
+                         lambda i, g, c: (i, blk(c), grp(g))),
             pl.BlockSpec((None, 2 * pairs, chunks, CHUNK),
                          lambda i, g, c: (i, g, blk(c), 0)),
             pl.BlockSpec((chunks, None, pairs, STATE, _LANES),
                          lambda i, g, c: (blk(c), i, g, 0, 0)))
 
 
-def _cost(b, heads, n, passes, bytes_accessed):
-    # a chunk and head: C B^T (an eighth of one), the decay matrix's
-    # product, C S and B^T X (2 C C 64 MACs each, about), times
-    # ``passes`` (1 forward, 3 backward: made again + two transposes)
-    flops = 2 * (CHUNK * CHUNK * STATE // 8 + CHUNK * CHUNK * HEAD_DIM
+def _head_blocks(heads, bm, pairs):
+    """(head blocks of the call, head blocks a group)."""
+    blocks = heads // (2 * pairs)
+    return blocks, blocks // (bm.shape[2] // STATE)
+
+
+def _cost(b, heads, n, passes, bytes_accessed, pairs):
+    # a chunk and head: C B^T (its share of the head block's one), the
+    # decay matrix's product, C S and B^T X (2 C C 64 MACs each, about),
+    # times ``passes`` (1 forward, 3 backward: made again + two
+    # transposes)
+    flops = 2 * (CHUNK * CHUNK * STATE // (2 * pairs)
+                 + CHUNK * CHUNK * HEAD_DIM
                  + 2 * CHUNK * STATE * HEAD_DIM)
     return pl.CostEstimate(
         flops=passes * b * heads * n * flops,
@@ -335,11 +365,13 @@ def mamba2_scan_fwd(x, dt, a, bm, cm, d, tile):
     b, t, width = x.shape
     heads = dt.shape[2]
     pairs, chunks = tile
-    groups = heads // (2 * pairs)
-    assert width == heads * HEAD_DIM and bm.shape[2] == groups * STATE, (
+    blocks, per_group = _head_blocks(heads, bm, pairs)
+    assert (width == heads * HEAD_DIM
+            and bm.shape[2] * per_group == blocks * STATE), (
         x.shape, bm.shape, dt.shape)
     (x2, b2, c2, dt4, ac4), n, n_pad = _operands(x, dt, a, bm, cm, tile)
-    x_spec, bc_spec, row_spec, st_spec = _specs(pairs, chunks, lambda c: c)
+    x_spec, bc_spec, row_spec, st_spec = _specs(
+        pairs, chunks, lambda c: c, per_group)
     d_spec = pl.BlockSpec((1, pairs * _LANES), lambda i, g, c: (0, g))
     item = jnp.dtype(x.dtype).itemsize
     y, states = pl.pallas_call(
@@ -350,7 +382,7 @@ def mamba2_scan_fwd(x, dt, a, bm, cm, d, tile):
                        (n_pad, b, heads // 2, STATE, _LANES), _F32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            grid=(b, groups, n_pad // chunks),
+            grid=(b, blocks, n_pad // chunks),
             in_specs=[x_spec, bc_spec, bc_spec, row_spec, row_spec, d_spec],
             out_specs=(x_spec, st_spec),
             scratch_shapes=[pltpu.VMEM((pairs, STATE, _LANES), _F32)]),
@@ -359,8 +391,8 @@ def mamba2_scan_fwd(x, dt, a, bm, cm, d, tile):
             vmem_limit_bytes=_vmem_limit(pairs, chunks)),
         cost_estimate=_cost(
             b, heads, n_pad, 1,
-            item * (2 * x2.size + 2 * b2.size) + 8 * dt4.size
-            + 4 * n_pad * b * heads * HEAD_DIM * STATE),
+            item * (2 * x2.size + 2 * per_group * b2.size) + 8 * dt4.size
+            + 4 * n_pad * b * heads * HEAD_DIM * STATE, pairs),
         interpret=_INTERPRET,
     )(x2, b2, c2, dt4, ac4, _d_rows(d))
     return y[:, :t], states[:n]
@@ -463,13 +495,24 @@ def mamba2_scan_bwd(x, dt, a, bm, cm, d, states, dy, tile):
     b, t, _ = x.shape
     heads = dt.shape[2]
     pairs, chunks = tile
-    groups = heads // (2 * pairs)
+    blocks, per_group = _head_blocks(heads, bm, pairs)
+    partials = per_group > 1
     (x2, b2, c2, dt4, ac4), n, n_pad = _operands(x, dt, a, bm, cm, tile)
     dy2 = _padded(dy.astype(x.dtype), 1, n_pad * CHUNK)
     states = _padded(states, 0, n_pad)
     last = n_pad // chunks - 1
     x_spec, bc_spec, row_spec, st_spec = _specs(
-        pairs, chunks, lambda c: last - c)
+        pairs, chunks, lambda c: last - c, per_group)
+    if partials:
+        # a group's head blocks each write their own dB and dC, float32
+        dbc_spec = pl.BlockSpec(
+            (None, None, chunks * CHUNK, STATE),
+            lambda i, g, c: (g % per_group, i, last - c, g // per_group))
+        dbc_shape = lambda v: jax.ShapeDtypeStruct((per_group,) + v.shape,
+                                                   _F32)
+    else:
+        dbc_spec = bc_spec
+        dbc_shape = lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)
     d_spec = pl.BlockSpec((1, pairs * _LANES), lambda i, g, c: (0, g))
     dd_spec = pl.BlockSpec((None, 1, pairs * _LANES),
                            lambda i, g, c: (i, 0, g))
@@ -478,26 +521,26 @@ def mamba2_scan_bwd(x, dt, a, bm, cm, d, states, dy, tile):
         functools.partial(_bwd_kernel, pairs=pairs, chunks=chunks),
         name="mamba2.chunk.bwd",
         out_shape=(jax.ShapeDtypeStruct(x2.shape, x.dtype),
-                   jax.ShapeDtypeStruct(b2.shape, bm.dtype),
-                   jax.ShapeDtypeStruct(c2.shape, cm.dtype),
+                   dbc_shape(b2), dbc_shape(c2),
                    jax.ShapeDtypeStruct(dt4.shape, _F32),
                    jax.ShapeDtypeStruct(ac4.shape, _F32),
                    jax.ShapeDtypeStruct((b, 1, heads * HEAD_DIM), _F32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            grid=(b, groups, last + 1),
+            grid=(b, blocks, last + 1),
             in_specs=[x_spec, bc_spec, bc_spec, row_spec, row_spec, d_spec,
                       st_spec, x_spec],
-            out_specs=(x_spec, bc_spec, bc_spec, row_spec, row_spec,
+            out_specs=(x_spec, dbc_spec, dbc_spec, row_spec, row_spec,
                        dd_spec),
             scratch_shapes=[pltpu.VMEM((pairs, STATE, _LANES), _F32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(pairs, chunks)),
+            vmem_limit_bytes=_vmem_limit(pairs, chunks, partials)),
         cost_estimate=_cost(
             b, heads, n_pad, 3,
-            item * (3 * x2.size + 4 * b2.size) + 16 * dt4.size
-            + states.dtype.itemsize * states.size),
+            item * (3 * x2.size + 2 * per_group * b2.size)
+            + (8 if partials else 2 * item) * per_group * b2.size
+            + 16 * dt4.size + states.dtype.itemsize * states.size, pairs),
         interpret=_INTERPRET,
     )(x2, b2, c2, dt4, ac4, _d_rows(d), states, dy2)
 
@@ -507,5 +550,8 @@ def mamba2_scan_bwd(x, dt, a, bm, cm, d, states, dy, tile):
     # Acum is the running sum of a within a chunk: da_m = sum of dAcum_i
     # over the chunk's i >= m
     da4 = jnp.flip(jnp.cumsum(jnp.flip(dac4, -1), axis=-1), -1)
+    if partials:
+        db2, dc2 = (jnp.sum(v, axis=0).astype(like.dtype)
+                    for v, like in ((db2, bm), (dc2, cm)))
     return (dx2[:, :t], db2[:, :t], dc2[:, :t], scalar(ddt4), scalar(da4),
             jnp.sum(dd.reshape(b, heads, HEAD_DIM), axis=(0, 2)))

@@ -127,6 +127,36 @@ def test_kernels_match_the_recurrence(interpreter, t, heads, groups):
         assert rel(gk[k], g0[k]) < 3e-2, k
 
 
+# ONE group wider than a grid step (Granite-4.0-H: 64 heads share one B
+# and C): head blocks of _BLOCK_PAIRS pairs walk it, each writes its own
+# float32 dB and dC, one sum makes the group's. 16 heads fit the cap as
+# one block; with the block cut to 2 pairs they are four. (A short row's
+# blocks are small, so even 64 heads fit the cap whole: the cases that
+# want head blocks lower the cap too.)
+@pytest.mark.parametrize("t,heads,block_pairs,tile", [
+    (300, 16, None, (8, 3)), (300, 16, 2, (2, 3)), (140, 64, 4, (4, 2))],
+    ids=["16_whole", "16_in_blocks_of_4", "64_in_blocks_of_8"])
+def test_one_group_wider_than_a_step(interpreter, monkeypatch, t, heads,
+                                     block_pairs, tile):
+    if block_pairs:
+        monkeypatch.setattr(K, "_BLOCK_PAIRS", block_pairs)
+        monkeypatch.setattr(K, "_VMEM_CAP_BYTES", 2**20)
+    ins, dy = operands(1, t, heads, K.HEAD_DIM, 1, K.STATE, BF, seed=heads)
+    assert K.mamba2_tile(t, heads, 1, K.HEAD_DIM, K.STATE, K.CHUNK,
+                         BF) == tile
+    yk, gk, states = op(ins, dy, groups=1)
+    assert states.shape == (-(-t // K.CHUNK), 1, heads // 2, K.STATE, 128)
+    wide = {k: v.astype(F32) for k, v in ins.items()}
+    y0, g0, _ = op(wide, dy.astype(F32), impl="recurrent", groups=1)
+    y1, g1, _ = op(wide, dy.astype(F32), impl="chunked", groups=1)
+    assert rel(y1, y0) < 1e-5
+    assert yk.dtype == BF and rel(yk, y0) < 1.5e-2
+    for k in g0:
+        assert rel(g1[k], g0[k]) < 1e-4, k
+        assert gk[k].shape == g0[k].shape and gk[k].dtype == ins[k[6:]].dtype
+        assert rel(gk[k], g0[k]) < 3e-2, k
+
+
 def test_tile_follows_the_call():
     tile = K.mamba2_tile
     on = dict(backend="tpu", on_mesh=False)
@@ -141,6 +171,10 @@ def test_tile_follows_the_call():
     assert tile(4096, 64, 8, 128, 128, 128, BF, **on) is None   # a head
     assert tile(4096, 64, 8, 64, 16, 128, BF, **on) is None     # the state
     assert tile(4096, 24, 8, 64, 128, 128, BF, **on) is None    # 3 a group
+    # a group wider than the VMEM cap takes: head blocks walk it
+    assert tile(16384, 64, 1, 64, 128, 128, BF, **on) == (4, 8)
+    assert tile(16384, 16, 1, 64, 128, 128, BF, **on) == (8, 8)
+    assert tile(16384, 44, 1, 64, 128, 128, BF, **on) == (2, 8)  # 22 pairs
 
 
 def run_layer(impl, t=12):
